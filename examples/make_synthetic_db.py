@@ -6,7 +6,7 @@ meaningful) in the shapes of MNIST / CIFAR-10 / ILSVRC12 and writes train/test
 LMDBs + a mean binaryproto where the example expects them. Swap in real
 datasets (convert_imageset / partition_data) for accuracy-parity runs.
 
-Usage: python examples/make_synthetic_db.py [mnist|cifar10|imagenet] [--train N] [--test N]
+Usage: python examples/make_synthetic_db.py [mnist|cifar10|imagenet] [--train N] [--test N] [--seed S]
 """
 
 import argparse
@@ -35,8 +35,19 @@ SPECS = {
 }
 
 
-def build(name: str, n_train: int, n_test: int, seed: int = 0) -> None:
-    spec = SPECS[name]
+def build(name: str, n_train: int, n_test: int, seed: int = 0,
+          side: int = 0, out_dir: str = "") -> dict:
+    """Write the train/test LMDBs (+ mean file) for ``name`` from ``seed``
+    and return their paths. ``side``/``out_dir`` cut the record size and
+    move the outputs (chip_smoke.py's CPU-sized rehearsal); by default
+    everything lands where the example prototxts expect it."""
+    spec = dict(SPECS[name])
+    if side:
+        spec["shape"] = (spec["shape"][0], side, side)
+    if out_dir:
+        for k in ("train", "test", "mean"):
+            if spec[k]:
+                spec[k] = os.path.join(out_dir, os.path.basename(spec[k]))
     shape, classes = spec["shape"], spec["classes"]
     rs = np.random.RandomState(seed)
     templates = rs.randint(60, 196, size=(classes,) + shape)
@@ -62,6 +73,7 @@ def build(name: str, n_train: int, n_test: int, seed: int = 0) -> None:
         with open(spec["mean"], "wb") as f:
             f.write(encode_blob(mean))
         print(f"wrote mean -> {spec['mean']}")
+    return {k: spec[k] for k in ("train", "test", "mean")}
 
 
 if __name__ == "__main__":
@@ -69,7 +81,8 @@ if __name__ == "__main__":
     ap.add_argument("dataset", choices=list(SPECS) + ["all"])
     ap.add_argument("--train", type=int, default=2000)
     ap.add_argument("--test", type=int, default=400)
+    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     targets = list(SPECS) if args.dataset == "all" else [args.dataset]
     for t in targets:
-        build(t, args.train, args.test)
+        build(t, args.train, args.test, seed=args.seed)

@@ -189,7 +189,6 @@ def filter_new(findings: Sequence[Finding],
 # same lint (ISSUE 8 satellite): a host-sync or race added there rots the
 # telemetry story just as surely as one inside the package.
 EXTRA_SCRIPT_TARGETS = (
-    "scripts/layer_time_from_trace.py",
     "scripts/telemetry_smoke.py",
 )
 

@@ -306,13 +306,15 @@ def set_pipeline_config(**kwargs) -> None:
 
 @dataclass
 class CompileCacheConfig:
-    """Fast-restart policy (runtime/compile_cache.py): where the
-    persistent XLA compile cache lives and whether AOT-serialized step
-    executables ride alongside it. Empty cache_dir = both layers off —
-    every process start pays full JIT, the pre-elasticity behavior."""
+    """Fast-restart state (runtime/compile_cache.py): the directory
+    ``enable_compile_cache`` resolved (JAX_COMPILATION_CACHE_DIR, else
+    <checkout>/.jax_cache) and whether AOT-serialized step executables
+    ride alongside the XLA cache in it. Empty cache_dir = not enabled in
+    this process (a library embedder that never called the CLI entry
+    points) — every compile is a full JIT."""
 
-    # persistent XLA compile cache directory ("" = disabled); the AOT
-    # step-executable store lives under <cache_dir>/aot
+    # the enabled cache directory ("" = not enabled); the AOT step store
+    # lives under <cache_dir>/aot, the tuned-policy store under /tuned
     cache_dir: str = ""
     # serialize/reload the compiled train-step executable itself (skips
     # tracing AND compilation on a key match; best-effort — any mismatch
@@ -320,8 +322,7 @@ class CompileCacheConfig:
     aot_steps: bool = True
 
 
-_compile_cache = CompileCacheConfig(
-    cache_dir=os.environ.get("POSEIDON_COMPILE_CACHE_DIR", ""))
+_compile_cache = CompileCacheConfig()
 
 
 def compile_cache_config() -> CompileCacheConfig:

@@ -250,6 +250,7 @@ class Net:
             self._plan_epilogues()
         self._plan_layouts()
         self._plan_conv_strategies()
+        self._plan_kernel_routes()
 
     # ------------------------------------------------------------------ #
     def arena_layout(self, include=None, bucket_mb: float = 4.0,
@@ -336,7 +337,10 @@ class Net:
         so the next process with this job config skips the micro-runs."""
         req = self.conv_strategy
         convs = [l for l in self.layers if l.TYPE == "CONVOLUTION"]
-        if not req or not convs:
+        if not req:
+            self._route_one_channel_convs(convs)
+            return
+        if not convs:
             return
         if req != "auto":
             for layer in convs:
@@ -351,6 +355,52 @@ class Net:
                 layer.group, layer.params[0].shape[0], layer.run_layout, n)
             layer.conv_strategy = doc["winner"]
             log(f"[conv_strategy] {conv_tune.describe(doc)}")
+
+    def _route_one_channel_convs(self, convs) -> None:
+        """No strategy asked for, lowering for the TPU: a conv over ONE
+        input channel goes through patches + GEMM. libtpu 0.0.34 does not
+        finish compiling that conv's backward as a direct convolution at
+        f32 HIGHEST — examples/mnist LeNet, batch 64, ran past 600 s on
+        the v5e host inside `.compile()`; as im2col the whole step compiles
+        in 36 s and trains (PR 21, on the chip). Same sums, other order.
+        Logged per layer like every other routing decision."""
+        from ..ops.pallas_kernels import _interpret_default
+        from ..runtime.metrics import log
+        if not convs or _interpret_default():
+            return
+        for layer in convs:
+            if self.blob_shapes[layer.lp.bottom[0]][1] == 1:
+                layer.conv_strategy = "im2col"
+                log(f"[conv_strategy] {layer.name}: 1 input channel -> "
+                    f"im2col (the direct conv does not finish compiling "
+                    f"for the TPU)")
+
+    def _plan_kernel_routes(self) -> None:
+        """Which arm each pooling backward (TRAIN nets) and cross-channel
+        LRN lowers to — Pallas kernel or an XLA formulation — from the
+        SAME functions the ops consult at trace time, logged once per
+        layer. The routing is by platform and shape, which is legitimate;
+        what is not is a run that cannot say which arm it took."""
+        from ..ops.pallas_kernels import lrn_route
+        from ..runtime.metrics import log
+        self.kernel_routes: Dict[str, str] = {}
+        for layer in self.layers:
+            shape = (self.blob_shapes[layer.lp.bottom[0]]
+                     if layer.lp.bottom else ())
+            if layer.TYPE == "POOLING" and self.phase == "TRAIN" \
+                    and layer.method in ("MAX", "AVE"):
+                what = "pool_bwd"
+                arm, note = NN.pool_bwd_route(shape[2], shape[3],
+                                              layer.kernel, layer.stride,
+                                              layer.pad)
+            elif layer.TYPE == "LRN" and layer.region == "ACROSS_CHANNELS":
+                what = "lrn"
+                arm, note = lrn_route(shape[2] * shape[3], shape[1])
+            else:
+                continue
+            self.kernel_routes[layer.name] = f"{what}={arm}"
+            log(f"[kernel_route] {layer.name}: {what} -> {arm}"
+                + (f" ({note})" if note else ""))
 
     def conv_strategy_plan(self) -> Dict[str, Optional[str]]:
         """{conv layer name: resolved strategy} — what bench/tests print."""

@@ -279,14 +279,13 @@ def default_budget_bytes(device=None, reserve_bytes: int = 0) -> int:
     """The default ``--hbm_budget_gb``: the device's own memory limit
     minus ``reserve_bytes`` (arena + optimizer state the caller knows
     about). Returns 0 when the backend publishes no memory stats (the
-    CPU proxy) — callers must then pass an explicit budget."""
+    CPU backend returns None) — callers must then pass an explicit
+    budget. A backend that RAISES here is a real failure and propagates:
+    silently planning against a zero budget would hide it."""
     import jax
     if device is None:
-        device = jax.devices()[0]
-    try:
-        stats = device.memory_stats()
-    except Exception:                       # noqa: BLE001 — CPU has none
-        return 0
+        device = jax.local_devices()[0]
+    stats = device.memory_stats()
     if not stats:
         return 0
     limit = int(stats.get("bytes_limit", 0) or 0)

@@ -1,10 +1,11 @@
 """ctypes binding for the native data plane (native/poseidon_dataplane.cc).
 
-Builds the shared library on first use (g++, no external deps) and exposes
-``NativeLMDBBatcher``: indexed batch assembly (LMDB read + Datum decode +
-crop/mirror/mean/scale) running multithreaded in C++ with the GIL released —
-the reference's C++ data-layer role. Falls back cleanly when no compiler is
-available (``available()`` returns False and callers use the Python path).
+Builds the shared library from source on first use, and again whenever the
+source is newer (g++, no external deps), and exposes ``NativeLMDBBatcher``:
+indexed batch assembly (LMDB read + Datum decode + crop/mirror/mean/scale)
+running multithreaded in C++ with the GIL released — the reference's C++
+data-layer role. Without a working compiler ``available()`` returns False,
+the failure is logged once, and callers use the Python path.
 """
 
 from __future__ import annotations
@@ -39,23 +40,44 @@ class _TransformSpec(ctypes.Structure):
     ]
 
 
+def _stale() -> bool:
+    """The library is built from what git tracks (the ``.cc``); the
+    ``.so`` is a build output (``native/build/`` is git-ignored) and is
+    rebuilt whenever the source is newer."""
+    return (not os.path.exists(_LIB)
+            or os.path.getmtime(_SRC) > os.path.getmtime(_LIB))
+
+
+def _build() -> None:
+    os.makedirs(os.path.dirname(_LIB), exist_ok=True)
+    # tmp + rename: two processes of one job may both find the library
+    # stale; neither may ever dlopen a half-written file
+    tmp = f"{_LIB}.tmp.{os.getpid()}"
+    try:
+        subprocess.run(
+            ["g++", "-O3", "-std=c++17", "-fPIC", "-pthread", "-Wall",
+             "-shared", "-o", tmp, _SRC],
+            check=True, capture_output=True, text=True)
+        os.replace(tmp, _LIB)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _build_failed
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_LIB):
-            if not os.path.exists(_SRC):
-                _build_failed = True
-                return None
+        if _stale():
             try:
-                os.makedirs(os.path.dirname(_LIB), exist_ok=True)
-                subprocess.run(
-                    ["g++", "-O3", "-std=c++17", "-fPIC", "-pthread", "-Wall",
-                     "-shared", "-o", _LIB, _SRC],
-                    check=True, capture_output=True)
-            except (subprocess.CalledProcessError, FileNotFoundError):
+                _build()
+            except (subprocess.CalledProcessError, FileNotFoundError) as e:
                 _build_failed = True
+                from ..runtime.metrics import log
+                log(f"native data plane: build FAILED "
+                    f"({getattr(e, 'stderr', None) or e}); DATA layers use "
+                    f"the (much slower) Python reader")
                 return None
         lib = ctypes.CDLL(_LIB)
         lib.pdp_open.restype = ctypes.c_void_p
@@ -74,23 +96,19 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_int32,
         ]
         lib.pdp_close.argtypes = [ctypes.c_void_p]
-        if hasattr(lib, "pdp_batch_u8"):  # stale prebuilt .so tolerance
-            lib.pdp_batch_u8.restype = ctypes.c_int32
-            lib.pdp_batch_u8.argtypes = [
-                ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
-                ctypes.c_int32, ctypes.c_uint64,
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_int32), ctypes.c_int32,
-            ]
-        # newer symbol: a stale prebuilt .so may predate it — the batcher
-        # must keep working, only the snappy fast path degrades
-        if hasattr(lib, "pdp_snappy_uncompress"):
-            lib.pdp_snappy_uncompress.restype = ctypes.c_int64
-            lib.pdp_snappy_uncompress.argtypes = [
-                ctypes.c_char_p, ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
-            ]
+        lib.pdp_batch_u8.restype = ctypes.c_int32
+        lib.pdp_batch_u8.argtypes = [
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+            ctypes.c_int32, ctypes.c_uint64,
+            ctypes.POINTER(ctypes.c_uint8),
+            ctypes.POINTER(ctypes.c_int32), ctypes.c_int32,
+        ]
+        lib.pdp_snappy_uncompress.restype = ctypes.c_int64
+        lib.pdp_snappy_uncompress.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
+        ]
         _lib = lib
         return _lib
 
@@ -108,7 +126,7 @@ def snappy_uncompress(buf: bytes) -> Optional[bytes]:
     """Native snappy decode; None when the library is unavailable, raises
     on malformed input (same contract as the pure-Python codec)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "pdp_snappy_uncompress"):
+    if lib is None:
         return None
     need = lib.pdp_snappy_uncompress(buf, len(buf), None, 0)
     if need < 0:
@@ -184,9 +202,6 @@ class NativeLMDBBatcher:
 
     def __len__(self) -> int:
         return self.n
-
-    def supports_u8(self) -> bool:
-        return hasattr(self._lib, "pdp_batch_u8")
 
     def batch_u8(self, indices: np.ndarray,
                  seed: int = 0) -> Tuple[np.ndarray, np.ndarray]:

@@ -152,7 +152,7 @@ class BatchPipeline:
             # the mean by h_off/w_off), which the device cannot see — only
             # mean_value/no-mean configs move on-device
             if (self._want_device_transform and not tp.mean_file
-                    and self.native.supports_u8() and self._n_records):
+                    and self._n_records):
                 # probe a spread of records: float_data-backed Datums cannot
                 # ship as uint8 (rc=-4), and a MIXED byte/float DB detected
                 # here gets the host f32 path for the whole pipeline — the
@@ -191,14 +191,19 @@ class BatchPipeline:
 
     def _try_native(self, lp: LayerParameter, phase: str, shard: Shard):
         """C++ fast path for LMDB-backed DATA layers (native/...dataplane.cc);
-        any failure falls back to the Python source."""
+        a source the native reader cannot open takes the Python source.
+        Which reader runs is logged either way — the Python reader is
+        ~50x slower and a silent fall onto it reads as a slow chip."""
         if lp.canonical_type() != "DATA":
             return None
+        from ..runtime.metrics import log
+        from .native import NativeLMDBBatcher, available
+        dp = lp.data_param
+        what = f"[data] {lp.name} ({phase}, {dp.source})"
+        if not available():
+            log(f"{what}: Python reader (native data plane unavailable)")
+            return None
         try:
-            from .native import NativeLMDBBatcher, available
-            if not available():
-                return None
-            dp = lp.data_param
             path = sharded_source_path(dp.source, shard.index,
                                        dp.shared_file_system)
             tp = _effective_transform(lp)
@@ -206,13 +211,17 @@ class BatchPipeline:
             if tp.mean_file:
                 from ..proto.wire import read_blob_file
                 mean = read_blob_file(tp.mean_file)[0]
-            return NativeLMDBBatcher(
+            native = NativeLMDBBatcher(
                 path, crop_size=tp.crop_size, mirror=tp.mirror,
                 train=(phase == "TRAIN"), scale=tp.scale, mean=mean,
                 mean_values=np.asarray(tp.mean_value, np.float32)
                 if tp.mean_value else None)
-        except Exception:
+        except (OSError, ValueError) as e:
+            log(f"{what}: Python reader (native reader refused the "
+                f"source: {e})")
             return None
+        log(f"{what}: native C++ reader, {native.n_threads} threads")
+        return native
 
     # ------------------------------------------------------------------ #
     def _index_stream(self) -> Iterator[int]:

@@ -28,10 +28,9 @@ from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
 import jax
-from ..compat import shard_map
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..proto.messages import SolverParameter

@@ -19,10 +19,9 @@ from dataclasses import dataclass
 from typing import Dict, Optional
 
 import jax
-from ..compat import shard_map
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..config import matmul_precision, policy

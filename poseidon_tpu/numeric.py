@@ -83,10 +83,9 @@ def resolve_conv_layout(layout: str, backend: str = None,
     default arm) auto falls back to the built-in table:
 
     - **tpu**: NCHW. The NHWC plan wins the HLO-transpose count (exactly
-      the fc-boundary pair) but MEASURED 0.53x on the real v5e
-      (``nhwc_speedup`` in BENCH_r05) — the TPU compiler's own layout
-      assignment beats our forced channels-last plan for these nets, so
-      auto stays NCHW until a measured plan shows >= 1.0.
+      the fc-boundary pair) but ran 0.53x in the one chip A/B on record
+      (July 2026, before PRs 1-19; not re-measured on this code), so
+      auto stays NCHW until a measured plan shows >= 1.0 (ROADMAP S6).
     - **gpu**: NHWC (tensor-core native conv layout).
     - **cpu** (and anything unknown): NCHW — the Caffe-parity default the
       golden-value suites run under.

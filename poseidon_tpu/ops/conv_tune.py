@@ -142,7 +142,7 @@ def resolve(name: str, c: int, h: int, w: int, kernel: Tuple[int, int],
             # a TunedPlan auto-load (runtime/tuned_plan.py) that resolved
             # conv_strategy="auto" points here at the plan's own store, so
             # the per-layer winners the tune run persisted memo-hit even
-            # without --compile_cache_dir
+            # in a process that never enabled the compile cache
             from ..runtime.tuned_plan import active_store_dir
             cache_dir = active_store_dir()
 

@@ -420,10 +420,11 @@ POOL_TAPS_CAP = 64
 
 def _pool_bwd_strategy(kernel) -> str:
     """'pallas' | 'taps' | 'sas' (select-and-scatter via plain autodiff).
-    Measured defaults: the Pallas plane kernel on real TPU, the vectorized
-    tap-sum elsewhere (one strided-slice/pad-and-add pair per window tap —
-    what removes the per-window thunk chain from the CPU attribution
-    table). ``POSEIDON_POOL_BWD`` forces an arm for A/B."""
+    Defaults: the Pallas plane kernel on TPU (no chip wall clock against
+    select-and-scatter yet — ROADMAP S6), the vectorized tap-sum on the
+    CPU mesh (one strided-slice/pad-and-add pair per window tap — what
+    removes the per-window thunk chain from the CPU attribution table).
+    ``POSEIDON_POOL_BWD`` forces an arm for A/B."""
     import os
     env = os.environ.get("POSEIDON_POOL_BWD", "")
     if env in ("pallas", "taps", "sas"):
@@ -432,6 +433,24 @@ def _pool_bwd_strategy(kernel) -> str:
         return "sas"
     from .pallas_kernels import _interpret_default
     return "taps" if _interpret_default() else "pallas"
+
+
+def pool_bwd_route(h: int, w: int, kernel, stride, pad):
+    """``(arm, note)`` for one pooling layer geometry — THE routing
+    decision: ``_pool_bwd`` takes it at trace time and ``Net`` logs it per
+    layer at construction. ``_pool_bwd_strategy``'s arm, except that a
+    plane the Pallas kernel cannot hold in VMEM takes the tap-sum."""
+    arm = _pool_bwd_strategy(kernel)
+    if arm == "pallas":
+        from .pallas_kernels import pool_plane_feasible
+        oh = pool_out_size(h, kernel[0], stride[0], pad[0])
+        ow = pool_out_size(w, kernel[1], stride[1], pad[1])
+        ph = stride[0] * (oh - 1) + kernel[0]
+        pw = stride[1] * (ow - 1) + kernel[1]
+        if not pool_plane_feasible(ph, pw, oh, ow, kernel):
+            return "taps", (f"{ph}x{pw} plane exceeds the Pallas kernel's "
+                            f"VMEM budget")
+    return arm, ""
 
 
 def _pool_flat_ids(shape, ah, aw, pw, stride, dh, dw):
@@ -514,11 +533,7 @@ def _pool_bwd(x, g, kernel, stride, pad, layout: str, method: str):
     h, w, oh, ow = _pool_dims(x, kernel, stride, pad, layout)
     ph = stride[0] * (oh - 1) + kernel[0]
     pw = stride[1] * (ow - 1) + kernel[1]
-    strategy = _pool_bwd_strategy(kernel)
-    if strategy == "pallas":
-        from .pallas_kernels import pool_plane_feasible
-        if not pool_plane_feasible(ph, pw, oh, ow, kernel):
-            strategy = "taps"
+    strategy, _ = pool_bwd_route(h, w, kernel, stride, pad)
     if strategy == "sas":
         ref = _max_pool_ref if method == "max" else _ave_pool_ref
         _, vjp = jax.vjp(lambda x_: ref(x_, kernel, stride, pad, layout), x)

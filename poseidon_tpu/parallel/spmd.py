@@ -56,10 +56,9 @@ from typing import (Callable, Dict, List, NamedTuple, Optional, Tuple)
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..config import MeshConfig, matmul_precision, policy
 from .mesh import SPMD_AXES, make_mesh
 from .strategies import (CommConfig, CommContext, DENSE, DENSE_FUSED, LOCAL,

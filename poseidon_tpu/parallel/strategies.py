@@ -123,7 +123,7 @@ class CommConfig:
     adarev_init_step: float = 0.1
     # DWBP bucketing (solver.cpp:419-449 per-blob sync threads, recast).
     # None (default): plain in-backward taps — XLA's all-reduce combiner may
-    # merge them into one collective (it does: round-3 dwbp_schedule.json),
+    # merge them into one collective (it does: scripts/analyze_schedule.py),
     # which is optimal when the runtime cannot overlap anyway. A number:
     # chain the taps into ~this-many-MB buckets via ordering tokens, forcing
     # one DISTINCT collective per bucket that issues the moment its bucket's
@@ -227,8 +227,8 @@ def _chained_sync_tap(axes: tuple, reduce: str, wire: Optional[str] = None):
     would create a cycle): the compiled program keeps one distinct,
     schedulable collective per chain stage instead of one giant fused
     all-reduce at the end of backward. This is the fix for the round-3
-    degenerate DWBP A/B (evidence/dwbp_schedule.json: XLA merged all 18
-    per-layer taps into ONE all-reduce identical to DENSE_FUSED), restoring
+    degenerate DWBP A/B (XLA merged all 18 per-layer taps into ONE
+    all-reduce identical to DENSE_FUSED), restoring
     the reference's per-layer overlap structure (solver.cpp:419-449) at
     bucket granularity (CommConfig.dwbp_bucket_mb).
 

@@ -19,10 +19,9 @@ from dataclasses import dataclass
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
-from ..compat import shard_map
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.net import Net
@@ -190,8 +189,8 @@ def build_train_step(
     and runtime dispatch latency — the TPU-native analog of keeping the
     solver loop hot instead of paying a host round-trip per iteration
     (the reference pays this per-iteration cost in Solver::Step,
-    solver.cpp:405-531; on a remote/tunneled or multi-host runtime the
-    round-trip dominates). Incompatible with ``dump_blobs`` (stacking K
+    solver.cpp:405-531; on a multi-host runtime the round-trip can
+    dominate). Incompatible with ``dump_blobs`` (stacking K
     copies of every activation would defeat the memory plan).
 
     ``scan_reuse_batch=True`` (benchmarking mode) drops the leading [K]

@@ -165,18 +165,11 @@ def _engine_from_args(args, phase_nets=True):
 def _enable_compile_cache_from_args(args) -> None:
     """Stage the fast-restart layers (persistent XLA compile cache + AOT
     step store) before any program is compiled. Shared by train/serve/
-    bench_serve; empty --compile_cache_dir leaves both off."""
-    from .. import config
-    # the flag wins; the POSEIDON_COMPILE_CACHE_DIR env default (seeded
-    # into CompileCacheConfig at import) covers launcher-managed fleets
-    cache_dir = (getattr(args, "compile_cache_dir", "")
-                 or config.compile_cache_config().cache_dir)
-    if not cache_dir:
-        return
+    bench_serve/tune. The location is not a flag: it is
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``
+    (compile_cache.resolve_cache_dir)."""
     from .compile_cache import enable_compile_cache
-    resolved = enable_compile_cache(cache_dir)
-    config.set_compile_cache_config(
-        cache_dir=resolved,
+    resolved = enable_compile_cache(
         aot_steps=getattr(args, "aot_steps", "true") == "true")
     from .metrics import log
     log(f"compile cache: persistent XLA cache at {resolved} "
@@ -247,6 +240,25 @@ def _apply_tuned_plan_train(args) -> None:
 
 def cmd_train(args) -> int:
     from .cluster import init_distributed
+    if getattr(args, "async_ssp", False):
+        # async-SSP: the processes stay INDEPENDENT jax runtimes — no
+        # jax.distributed world, no collective rendezvous; the only
+        # cross-process channel is the tier's parameter service. The tier
+        # reads the LOCAL launcher's env contract; a hostfile launch does
+        # not set it, and silently degrading to N isolated full-data runs
+        # would be worse than refusing.
+        if args.hostfile and "POSEIDON_PROC_ID" not in os.environ:
+            raise SystemExit(
+                "--async_ssp currently rides the launch_local env contract "
+                "(POSEIDON_PROC_ID/NUM_PROCS/COORDINATOR); for a hostfile "
+                "cluster, start each node under that env (see "
+                "scripts/launch.py) instead of --hostfile/--node_id")
+    else:
+        # FIRST: jax.distributed.initialize refuses to run once anything
+        # has touched the backend, and the plan resolution below does
+        # (jax.default_backend() for the plan key)
+        init_distributed(hostfile=args.hostfile or None,
+                         node_id=args.node_id if args.node_id >= 0 else None)
     _enable_compile_cache_from_args(args)
     if args.bf16:
         from .. import config
@@ -257,23 +269,6 @@ def cmd_train(args) -> int:
     # explicit flags always win, plan values fill the gaps, built-in
     # defaults bat last, with every source recorded in stats.yaml
     _apply_tuned_plan_train(args)
-    if getattr(args, "async_ssp", False):
-        # async-SSP: the processes stay INDEPENDENT jax runtimes — no
-        # jax.distributed world, no collective rendezvous; the only
-        # cross-process channel is the tier's parameter service. The tier
-        # reads the LOCAL launcher's env contract; a hostfile launch does
-        # not set it, and silently degrading to N isolated full-data runs
-        # would be worse than refusing.
-        import os as _os
-        if args.hostfile and "POSEIDON_PROC_ID" not in _os.environ:
-            raise SystemExit(
-                "--async_ssp currently rides the launch_local env contract "
-                "(POSEIDON_PROC_ID/NUM_PROCS/COORDINATOR); for a hostfile "
-                "cluster, start each node under that env (see "
-                "scripts/launch.py) instead of --hostfile/--node_id")
-    else:
-        init_distributed(hostfile=args.hostfile or None,
-                         node_id=args.node_id if args.node_id >= 0 else None)
     eng = _engine_from_args(args)
     eng.profile_steps = args.profile
     if args.snapshot == "auto":
@@ -1001,17 +996,13 @@ def cmd_tune(args) -> int:
     JSON summary line."""
     import json
 
-    from .. import config
     from .tuned_plan import run_tune
 
-    # the plan store rides the compile-cache dir when one is configured
-    # (plans live next to the executables they tuned); the store_dir()
-    # default covers the zero-flag tune -> train round trip
+    # the plan store rides the compile-cache dir (plans live next to the
+    # executables they tuned), so a zero-flag tune -> train round trips
     _enable_compile_cache_from_args(args)
-    cache_dir = (getattr(args, "compile_cache_dir", "")
-                 or config.compile_cache_config().cache_dir)
     result = run_tune(args.model, smoke=args.smoke, force=args.force,
-                      cache_dir=cache_dir or None, deploy=args.deploy,
+                      deploy=args.deploy,
                       windows=args.windows or None,
                       iters=args.iters or None)
     doc = result["doc"]
@@ -1185,7 +1176,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="conv lowering strategy: 'auto' MEASURES direct/"
                         "im2col/s2d per conv layer at net construction "
                         "(short micro-runs; winners logged and persisted "
-                        "via --compile_cache_dir so the next run skips "
+                        "in the compile-cache dir so the next run skips "
                         "re-measurement), a concrete value forces one "
                         "strategy net-wide; empty = the TunedPlan value "
                         "if one is persisted, else the legacy global "
@@ -1327,18 +1318,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "atomic tmp-rename protocol and auto-resume "
                         "semantics are unchanged; default: the "
                         "PipelineConfig policy, off)")
-    t.add_argument("--compile_cache_dir", default="",
-                   help="fast restart: persistent XLA compile cache "
-                        "directory (every backend compile becomes a disk "
-                        "hit on restart) plus an AOT step-executable store "
-                        "under <dir>/aot keyed by (model, shapes, mesh) — "
-                        "a matching restart skips trace AND compile. "
-                        "Empty = off (full JIT per start). Env default: "
-                        "POSEIDON_COMPILE_CACHE_DIR")
     t.add_argument("--aot_steps", default="true", choices=["true", "false"],
-                   help="with --compile_cache_dir: also serialize/reload "
-                        "the compiled train-step executable itself "
-                        "(best-effort; false keeps only the XLA cache)")
+                   help="fast restart: besides the persistent XLA compile "
+                        "cache (JAX_COMPILATION_CACHE_DIR, else "
+                        "<checkout>/.jax_cache), serialize/reload the "
+                        "compiled train-step executable itself under "
+                        "<cache>/aot keyed by (model, shapes, mesh, code) — "
+                        "a matching restart skips trace AND compile; false "
+                        "keeps only the XLA cache")
     t.add_argument("--profile", type=int, default=0,
                    help="capture an xplane trace over N steps (from step 10)")
     t.add_argument("--trace_out", default="",
@@ -1441,10 +1428,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="serve live fleet health over HTTP on this port "
                          "(0 = ephemeral, printed at startup; the same "
                          "read-only endpoint as train's --metrics_port)")
-    sv.add_argument("--compile_cache_dir", default="",
-                    help="persistent XLA compile cache: a restarted "
-                         "replica's bucket warm-up compiles become disk "
-                         "reads (same flag as train; empty = off)")
     sv.add_argument("--generate", action="store_true",
                     help="LLM decode serving: --model names a transformer "
                          "preset (tiny|gpt_small) served through the "
@@ -1481,7 +1464,6 @@ def build_parser() -> argparse.ArgumentParser:
     bs.add_argument("--offered_rps", type=float, default=0.0,
                     help="open-loop mode: fixed arrival rate (req/s); "
                          "0 = closed loop")
-    bs.add_argument("--compile_cache_dir", default="")
     bs.set_defaults(fn=cmd_bench_serve)
 
     tu = sub.add_parser(
@@ -1512,10 +1494,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="also write the plan JSON here (evidence copy; "
                          "the store copy always lands next to the AOT "
                          "executables)")
-    tu.add_argument("--compile_cache_dir", default="",
-                    help="plan store override (default: the configured "
-                         "compile-cache dir, else POSEIDON_TUNED_DIR, "
-                         "else ~/.cache/poseidon_tpu)")
     tu.add_argument("--aot_steps", default="true",
                     choices=["true", "false"], help=argparse.SUPPRESS)
     tu.set_defaults(fn=cmd_tune)
@@ -1566,7 +1544,13 @@ def main(argv: Optional[List[str]] = None) -> int:
     # any command initializes the backend (it is the DWBP-overlap mechanism
     # on TPU; a no-op on CPU runs — see config.enable_tpu_async_collectives)
     from .. import config as _config
-    _config.enable_tpu_async_collectives()
+    if not _config.enable_tpu_async_collectives() and \
+            args.command == "train":
+        from .metrics import log
+        log("WARNING: async collective fusion NOT staged (an explicit "
+            "=false in LIBTPU_INIT_ARGS, or the jax backend was already "
+            "initialized before cli.main) — gradient all-reduces will not "
+            "overlap backward compute on TPU")
     return args.fn(args)
 
 

@@ -9,9 +9,10 @@ config hits them:
 1. **Persistent XLA compile cache** (``jax.experimental.compilation_cache``
    riding the ``jax_compilation_cache_dir`` config): every XLA compile is
    content-addressed into ``cache_dir``; a restart re-traces but the
-   multi-minute backend compile becomes a disk read. Wired through train
-   AND serve (``--compile_cache_dir``), because a serving replica's bucket
-   warm-up is the same cold-start bill.
+   multi-minute backend compile becomes a disk read. Wired through train,
+   serve, bench_serve and tune, because a serving replica's bucket
+   warm-up is the same cold-start bill. Where it lives is
+   :func:`resolve_cache_dir`'s one rule, not a flag.
 
 2. **AOT step-executable store** (``jax.experimental.serialize_executable``):
    the compiled train-step executable itself, serialized under
@@ -31,75 +32,71 @@ the jit-compiled program, byte-identified by its lowering.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import hashlib
 import json
 import os
 import pickle
+import zlib
 from typing import Any, Dict, Optional
 
-__all__ = ["enable_compile_cache", "disable_compile_cache",
-           "cache_entries", "step_key",
+__all__ = ["resolve_cache_dir", "enable_compile_cache",
+           "cache_entries", "watch_cache_hits", "step_key",
+           "code_fingerprint",
            "save_step_executable", "load_step_executable", "aot_entries",
            "load_tuned", "save_tuned", "tuned_path"]
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-def enable_compile_cache(cache_dir: str,
-                         min_compile_time_s: float = 0.0) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created
-    if missing). ``min_compile_time_s=0`` caches every program — the
-    tier-1/CPU default, where even sub-second compiles are worth a disk
-    hit; raise it on TPU if tiny-program churn ever matters. Returns the
-    resolved absolute path. Must run before the programs it should cache
-    are compiled (already-compiled programs in this process stay in the
-    in-memory jit cache either way)."""
+
+def resolve_cache_dir() -> str:
+    """THE cache location, for every layer (XLA cache, ``aot/``,
+    ``tuned/``) and every entry point: ``JAX_COMPILATION_CACHE_DIR`` when
+    the environment sets it — then jax itself already reads it and nothing
+    in code sets another — else the fixed ``<checkout>/.jax_cache``
+    (git-ignored). The path is part of the cache key, so it never comes
+    from ``tempfile``, a pid or the clock; nothing outside the checkout
+    (no ``~/.cache``) steers a run."""
+    env = os.environ.get(CACHE_ENV, "")
+    if env:
+        return os.path.abspath(os.path.expanduser(env))
+    return os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def enable_compile_cache(aot_steps: bool = True) -> str:
+    """Turn on the persistent compilation cache at
+    :func:`resolve_cache_dir` (created if missing) and point the
+    ``aot/``/``tuned/`` stores at the same directory. Every program is
+    cached, even sub-second ones. Returns the directory. Must run before
+    the programs it should cache are compiled (already-compiled programs
+    in this process stay in the in-memory jit cache either way)."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
+    from .. import config
+
+    cache_dir = resolve_cache_dir()
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_time_s))
-    try:
-        # cache even tiny programs (the knob exists from jax 0.4.16 on;
-        # -1 disables the entry-size floor)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # noqa: BLE001 — older jax: floor simply stays
-        pass
-    try:
-        # the cache object memoizes its first initialization: a process
-        # that already compiled something (with NO cache configured) must
-        # reset it or the new dir is silently ignored
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — fresh process: nothing to reset
-        pass
+    if not os.environ.get(CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # a Pallas kernel's Mosaic module is serialized WITH its MLIR locations
+    # into the custom call, where the cache key's debug-info stripping
+    # cannot reach; with full Python tracebacks in them, every program
+    # that holds a kernel keys on its caller's stack — the same eval step
+    # reached from another line of a driver script missed the cache on
+    # the v5e (PR 21). One frame per location is stable.
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # the cache object memoizes its first initialization: a process that
+    # already compiled something (before this call) must reset it or the
+    # directory is silently ignored
+    _cc.reset_cache()
+    config.set_compile_cache_config(cache_dir=cache_dir, aot_steps=aot_steps)
     return cache_dir
-
-
-def disable_compile_cache() -> None:
-    """Turn the persistent cache back OFF for this process.
-
-    The cache config is process-global: a test (or embedder) that enabled
-    it against a temporary directory and walks away leaves EVERY later
-    compile in the process serializing/deserializing through that path —
-    and once the directory is garbage-collected out from under jax
-    (pytest keeps only the last few tmp_path dirs), later cache reads
-    deserialize torn entries and take the whole process down with a
-    SIGSEGV/abort deep inside jax. This was the long-standing flaky
-    tier-1 crash: the PR-6 compile-cache tests enabled the cache at a
-    tmp_path and never disabled it. Pair every test-scoped
-    ``enable_compile_cache`` with a ``finally: disable_compile_cache()``.
-    """
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — nothing initialized: nothing to do
-        pass
 
 
 def cache_entries(cache_dir: str) -> int:
@@ -109,6 +106,28 @@ def cache_entries(cache_dir: str) -> int:
         return sum(1 for n in os.listdir(cache_dir) if n.endswith("-cache"))
     except OSError:
         return 0
+
+
+@contextlib.contextmanager
+def watch_cache_hits():
+    """Yields a list that grows by one for every compile inside the block
+    that the persistent XLA cache answered (jax's own monitoring event).
+    An executable that came back from the cache is not serialized into the
+    AOT store: XLA:CPU writes one that loads and then dies at its first
+    dispatch (``NOT_FOUND: Function ... not found``), and the cache already
+    makes that start cheap."""
+    import jax.monitoring as monitoring
+    hits: list = []
+
+    def on_event(event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            hits.append(event)
+
+    monitoring.register_event_listener(on_event)
+    try:
+        yield hits
+    finally:
+        monitoring.unregister_event_listener(on_event)
 
 
 # --------------------------------------------------------------------------- #
@@ -138,6 +157,26 @@ def step_key(**parts: Any) -> str:
     return hashlib.sha256(blob).hexdigest()[:32]
 
 
+@functools.lru_cache(maxsize=1)
+def code_fingerprint() -> str:
+    """Content hash of the package's own sources. The AOT store rides the
+    default cache directory, which outlives an edit to the step builder or
+    a kernel; the executable's key is (model, shapes, mesh, policy), none
+    of which an edit changes — so the sources are part of the key, and a
+    stale executable can never be replayed over new code."""
+    h = hashlib.sha256()
+    pkg = os.path.join(_CHECKOUT, "poseidon_tpu")
+    for root, dirs, files in os.walk(pkg):
+        dirs.sort()
+        for name in sorted(files):
+            if name.endswith(".py"):
+                path = os.path.join(root, name)
+                h.update(os.path.relpath(path, pkg).encode())
+                with open(path, "rb") as f:
+                    h.update(f.read())
+    return h.hexdigest()[:16]
+
+
 def _aot_dir(cache_dir: str) -> str:
     return os.path.join(cache_dir, "aot")
 
@@ -154,27 +193,57 @@ def aot_entries(cache_dir: str) -> int:
         return 0
 
 
+# A v5e step executable for full-width AlexNet serializes to 1.74 GB
+# (PR 21, on the chip); the store holds it compressed, with the codec the
+# XLA cache itself uses for its entries in this installation.
+_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
+
+
+def _pack(payload: bytes) -> bytes:
+    try:
+        import zstandard
+    except ImportError:
+        return zlib.compress(payload, 1)
+    return zstandard.ZstdCompressor(threads=-1).compress(payload)
+
+
+def _unpack(blob: bytes) -> bytes:
+    if blob[:4] == _ZSTD_MAGIC:
+        import zstandard
+        return zstandard.ZstdDecompressor().decompress(blob)
+    return zlib.decompress(blob)
+
+
 def save_step_executable(cache_dir: str, key: str, compiled) -> Optional[str]:
     """Serialize a jax Compiled object under the AOT store (atomic tmp +
     rename — a torn write can never shadow a good entry). Returns the
-    entry path, or None when serialization is unsupported for this
-    program/backend (best-effort by design)."""
+    entry path, or None — logged, never raised — when the program does not
+    serialize on this backend or the store cannot be written: the caller
+    holds a good executable either way (best-effort by design)."""
     from jax.experimental.serialize_executable import serialize
 
+    from .metrics import log
+    tmp = None
     try:
         payload = pickle.dumps(serialize(compiled))
+        blob = _pack(payload)
+        path = _aot_path(cache_dir, key)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        tmp = f"{path}.tmp.{os.getpid()}"
+        with open(tmp, "wb") as f:
+            f.write(blob)
+        os.replace(tmp, path)
+        log(f"compile_cache: step executable stored at {path} "
+            f"({len(blob) / 1e6:.1f} MB, {len(payload) / 1e6:.1f} MB "
+            f"serialized)")
+        return path
     except Exception as e:  # noqa: BLE001 — fall back to the compile cache
-        from .metrics import log
-        log(f"compile_cache: step executable not serializable "
-            f"({type(e).__name__}: {e}); persistent cache still applies")
+        log(f"compile_cache: step executable NOT stored under "
+            f"{_aot_dir(cache_dir)} ({type(e).__name__}: {e}); the "
+            f"persistent XLA cache still applies")
+        if tmp and os.path.exists(tmp):
+            os.unlink(tmp)             # no half-written gigabyte left behind
         return None
-    path = _aot_path(cache_dir, key)
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    tmp = f"{path}.tmp.{os.getpid()}"
-    with open(tmp, "wb") as f:
-        f.write(payload)
-    os.replace(tmp, path)
-    return path
 
 
 def load_step_executable(cache_dir: str, key: str):
@@ -189,7 +258,7 @@ def load_step_executable(cache_dir: str, key: str):
 
     try:
         with open(path, "rb") as f:
-            payload, in_tree, out_tree = pickle.loads(f.read())
+            payload, in_tree, out_tree = pickle.loads(_unpack(f.read()))
         return deserialize_and_load(payload, in_tree, out_tree)
     except Exception as e:  # noqa: BLE001 — miss, not abort
         from .metrics import log

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -405,7 +405,8 @@ class Engine:
 
         # --- multi-step dispatch (scan chunks) ---------------------------- #
         # K optimizer steps per compiled dispatch: amortizes the runtime's
-        # per-dispatch round-trip (dominant on tunneled/multi-host runtimes).
+        # per-dispatch round-trip (whether it buys anything on a local
+        # chip is ROADMAP S3's A/B).
         # The engine falls back to single steps near display/test/snapshot
         # boundaries so solver cadence semantics are exact.
         self.steps_per_dispatch = max(1, int(steps_per_dispatch))
@@ -480,11 +481,31 @@ class Engine:
         # the AOT step store calls lowerable.lower(params, state, batch,
         # rng) and replays the executable with those four args; the spmd
         # step carries bound trailing (sharded multiplier) arguments the
-        # replay would miss, so warm start stands down under a plan
+        # replay would miss, so warm start stands down under a plan. A
+        # jax.distributed world keeps the jit path too: one rank's
+        # serialized executable is not another's.
         self._aot_enabled = (bool(_ccc.cache_dir) and _ccc.aot_steps
                              and staleness == 0 and not self._h5_train
                              and self.iter_size == 1
-                             and self.plan is None)
+                             and self.plan is None
+                             and jax.process_count() == 1)
+
+        # what this run is, where a reader of stats.yaml will look for it:
+        # the device as jax reports it, which kernel arm each layer took,
+        # which reader feeds each data layer
+        dev = jax.local_devices()[0]
+        self.stats.set_section("device", {
+            "platform": dev.platform, "kind": dev.device_kind,
+            "count": jax.device_count(),
+            "processes": jax.process_count(),
+            "jax": jax.__version__})
+        self.stats.set_section("kernel_routes",
+                               dict(self.train_net.kernel_routes))
+        self.stats.set_section("data_reader", {
+            p.tops[0]: ("native" if getattr(p, "native", None) is not None
+                        else "python")
+            for p in self.train_pipelines})
+        self._placement_recorded = False
 
         self._h5_outputs = [
             [(l.lp.hdf5_output_param.file_name, list(l.lp.bottom))
@@ -694,6 +715,12 @@ class Engine:
         """One single-step dispatch, through the AOT warm-start path when
         configured (resolution is lazy: the store key needs the concrete
         batch shapes, which exist only once the first batch is drawn)."""
+        first = not self._placement_recorded
+        if first:
+            # read BEFORE the dispatch: the step donates the batch
+            sample = next(iter(batch.values()))
+            batch_devs = sorted(sh.device.id
+                                for sh in sample.addressable_shards)
         if self._aot_enabled and self._aot_exec is None \
                 and not self._aot_failed:
             self._resolve_aot_step(batch, rng)
@@ -702,9 +729,27 @@ class Engine:
             # disabled under HDF5_OUTPUT) dump slot; keep the step()
             # wrapper's 3-tuple contract
             out = self._aot_exec(self.params, self.state, batch, rng)
-            return out[:3] if isinstance(out, tuple) and len(out) > 3 \
-                else out
-        return self.train_step.step(self.params, self.state, batch, rng)
+            if isinstance(out, tuple) and len(out) > 3:
+                out = out[:3]
+        else:
+            out = self.train_step.step(self.params, self.state, batch, rng)
+        if first:
+            self._record_placement(sample.shape, batch_devs, out[0])
+        return out
+
+    def _record_placement(self, batch_shape, batch_devs,
+                          params) -> None:  # static-ok: JIT102
+        """First dispatch only: where the work actually sits — the devices
+        holding the batch's shards, and how many hold each parameter the
+        step returned — into stats.yaml, so a multi-chip run can show it
+        used every chip rather than assert it."""
+        self._placement_recorded = True
+        sharding = jax.tree_util.tree_leaves(params)[0].sharding
+        self.stats.set_section("placement", {
+            "batch_global_shape": "x".join(map(str, batch_shape)),
+            "batch_shard_devices": ",".join(map(str, batch_devs)),
+            "param_devices": len(sharding.device_set),
+            "param_fully_replicated": bool(sharding.is_fully_replicated)})
 
     # one-time AOT resolution at the FIRST dispatch (key hashing over
     # static shapes/mesh ints), never steady-state:
@@ -714,8 +759,13 @@ class Engine:
         any failure pins the jit path for the rest of the run (which the
         persistent compile cache still accelerates)."""
         from ..config import compile_cache_config, policy
-        from .compile_cache import (load_step_executable,
-                                    save_step_executable, step_key)
+        from ..ops.pallas_kernels import LOWERING_ENV
+        from .compile_cache import (code_fingerprint, load_step_executable,
+                                    save_step_executable, step_key,
+                                    watch_cache_hits)
+        from .hlo_comm import count_gradient_all_reduces
+        t0 = time.perf_counter()
+        doc: Dict[str, Any] = {"source": "jit"}
         try:
             cfg = compile_cache_config()
             key = step_key(
@@ -731,6 +781,9 @@ class Engine:
                 device_kind=jax.devices()[0].device_kind,
                 n_devices=self.n_dev,
                 jax_version=jax.__version__,
+                code=code_fingerprint(),
+                lowering_env={k: os.environ.get(k, "")
+                              for k in LOWERING_ENV},
                 numeric_policy=str(policy()),
                 conv_layout=self.train_net.conv_layout,
                 # compile-RELEVANT solver fields only: max_iter/display/
@@ -746,24 +799,50 @@ class Engine:
                 comm=str(self.comm),
                 donate_batch=self._donate_batch)
             exec_ = load_step_executable(cfg.cache_dir, key)
+            source, stored = "loaded", "found"
             if exec_ is None:
+                source = "compiled"
                 low = self.train_step.lowerable or self.train_step.step
-                compiled = low.lower(self.params, self.state, batch,
-                                     rng).compile()
-                save_step_executable(cfg.cache_dir, key, compiled)
-                exec_ = compiled
-                log(f"aot warm start: compiled + serialized train step "
-                    f"(key {key[:12]}); next start of this config skips "
-                    f"trace+compile", rank=self.rank)
-            else:
-                log(f"aot warm start: loaded serialized train step "
-                    f"(key {key[:12]}) — trace and compile skipped",
-                    rank=self.rank)
+                with watch_cache_hits() as hits:
+                    exec_ = low.lower(self.params, self.state, batch,
+                                      rng).compile()
+                if hits:
+                    # the XLA cache answered: trace paid, compile skipped;
+                    # what it hands back is not re-serialized (see
+                    # compile_cache.watch_cache_hits)
+                    source, stored = "xla_cache", "no (xla cache hit)"
+                else:
+                    stored = "yes" if save_step_executable(
+                        cfg.cache_dir, key, exec_) else "no (see log)"
+            # the executable is good from here on, whatever the store did
             self._aot_exec = exec_
+            log("aot warm start: " + {
+                "loaded": "loaded serialized train step — trace and "
+                          "compile skipped",
+                "compiled": "compiled train step",
+                "xla_cache": "train step answered by the XLA cache — "
+                             "compile skipped"}[source]
+                + f" (key {key[:12]}; in aot/: {stored})", rank=self.rank)
+            # what the step that will run actually contains: the Pallas
+            # custom calls the kernel routes promise (0 = interpreted or
+            # routed to XLA) and the arena's gradient all-reduces
+            doc.update(source=source, stored=stored)
+            text = exec_.as_text()
+            doc["pallas_custom_calls"] = text.count(
+                'custom_call_target="tpu_custom_call"')
+            doc["gradient_all_reduces"] = count_gradient_all_reduces(text)
         except Exception as e:  # noqa: BLE001 — warm start is best-effort
-            self._aot_failed = True
-            log(f"aot warm start unavailable ({type(e).__name__}: {e}); "
-                f"using the jit path", rank=self.rank)
+            # never silent: the reason goes to the log with its traceback
+            # and into stats.yaml, where chip_smoke.py reads it
+            import traceback
+            self._aot_failed = self._aot_exec is None
+            doc["error"] = f"{type(e).__name__}: {e}"[:500]
+            log(f"aot warm start: {doc['error']}; "
+                + ("using the jit path" if self._aot_failed else
+                   "the executable is in use, its text could not be read")
+                + "\n" + traceback.format_exc(), rank=self.rank)
+        doc["seconds"] = round(time.perf_counter() - t0, 3)
+        self.stats.set_section("compiled_step", doc)
 
     # ---------------------------------------------------------------- #
     def iteration(self) -> int:
@@ -1237,6 +1316,10 @@ class Engine:
             "summary": comm_summary(table, step_ms),
             "per_layer": table,
         })
+        mem = jax.local_devices()[0].memory_stats()
+        if mem:  # the CPU backend publishes none
+            self.stats.set_gauge("peak_bytes_in_use",
+                                 int(mem.get("peak_bytes_in_use", 0)))
         name = self.train_net.name or "net"
         self.metrics.to_csv(os.path.join(self.output_dir,
                                          f"{name}_train_outputs.csv"))

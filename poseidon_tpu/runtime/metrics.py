@@ -164,6 +164,30 @@ class StatsRegistry:
         os.replace(tmp, path)
 
 
+def read_stats_yaml(path: str) -> Dict[str, dict]:
+    """Read back a document :meth:`StatsRegistry.render_yaml` wrote: nested
+    dicts by two-space indent, every leaf kept as the string it was
+    written as (``"null"`` included). The inverse of ``_write_tree`` and
+    nothing more — not a YAML parser."""
+    root: Dict[str, dict] = {}
+    stack = [(-1, root)]
+    with open(path) as f:
+        for line in f:
+            if not line.strip():
+                continue
+            indent = len(line) - len(line.lstrip(" "))
+            key, _, val = line.strip().partition(": ")
+            while stack[-1][0] >= indent:
+                stack.pop()
+            if not val and key.endswith(":"):
+                child: Dict[str, dict] = {}
+                stack[-1][1][key[:-1]] = child
+                stack.append((indent, child))
+            else:
+                stack[-1][1][key] = val
+    return root
+
+
 class MetricsServer:
     """The ``--metrics_port`` one-liner: a read-only HTTP endpoint serving
     a StatsRegistry as ``text/plain`` key=value lines, curl-able mid-run.

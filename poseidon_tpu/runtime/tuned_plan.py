@@ -1,7 +1,8 @@
 """TunedPlan: one measured, persisted artifact for every policy knob.
 
-BENCH_r05 proved that hand-picked policies and HLO-level proxies can invert
-on real hardware (NHWC "won" the transpose count yet ran 0.53x on the v5e),
+The one chip A/B of July 2026 showed that hand-picked policies and HLO-level
+proxies can invert on real hardware (NHWC "won" the transpose count yet ran
+0.53x on the v5e; not re-measured on this code),
 and the per-layer conv-strategy tuner (ops/conv_tune.py, PR 11) proved the
 fix for ONE knob: measure short trials, persist the winner, memo-hit on the
 next process. This module generalizes that mechanism to the whole policy
@@ -108,16 +109,16 @@ TRAIN_KNOBS = ("conv_layout", "conv_strategy", "arena_bucket_mb", "mesh",
 
 def store_dir(cache_dir: Optional[str] = None) -> str:
     """The tuned-plan store directory: an explicit argument, else the
-    configured compile-cache dir (plans live next to the AOT executables),
-    else POSEIDON_TUNED_DIR, else a stable per-user default — so the
-    ``tune`` -> ``train`` auto-load round trip works with zero flags."""
+    compile-cache dir (plans live next to the AOT executables) — enabled
+    or not, it resolves by the one rule in
+    ``compile_cache.resolve_cache_dir``, so the ``tune`` -> ``train``
+    auto-load round trip works with zero flags and nothing outside the
+    checkout (or JAX_COMPILATION_CACHE_DIR) steers a run."""
     if cache_dir:
         return cache_dir
     from ..config import compile_cache_config
-    return (compile_cache_config().cache_dir
-            or os.environ.get("POSEIDON_TUNED_DIR", "")
-            or os.path.join(os.path.expanduser("~"), ".cache",
-                            "poseidon_tpu"))
+    from .compile_cache import resolve_cache_dir
+    return compile_cache_config().cache_dir or resolve_cache_dir()
 
 
 def plan_key(model: str, backend: str, n_devices: int) -> str:
@@ -143,8 +144,8 @@ def load_plan(model: str, backend: Optional[str] = None,
               cache_dir: Optional[str] = None) -> Optional[Dict]:
     """The persisted plan for (model, backend, n_devices), or None. A plan
     whose provenance names a different device kind or jax version REFUSES
-    to load (loudly — the BENCH_r05 lesson is precisely that measured
-    winners do not transfer across hardware); any store-level failure is a
+    to load (loudly — measured winners do not transfer across
+    hardware); any store-level failure is a
     clean miss (compile_cache.load_tuned logs torn entries)."""
     import jax
     backend = backend or jax.default_backend()
@@ -259,11 +260,10 @@ def active_plan_value(knob: str) -> Optional[Any]:
 def active_store_dir() -> str:
     """Where the active plan was loaded from — ops/conv_tune.py falls back
     here so a plan-applied ``conv_strategy=auto`` memo-hits the per-layer
-    winners the tune run persisted, even without --compile_cache_dir.
-    Empty unless a plan actually LOADED: a defaults-only resolution must
-    not route conv_tune's store at the directory we merely looked in (a
-    flagless ``train --conv_strategy auto`` would otherwise start
-    persisting winners into the user-level cache as a side effect)."""
+    winners the tune run persisted, even in a process that never enabled
+    the compile cache. Empty unless a plan actually LOADED: a
+    defaults-only resolution must not route conv_tune's store at the
+    directory we merely looked in."""
     if _active is None or _active.doc is None:
         return ""
     return _active.store

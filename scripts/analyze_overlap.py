@@ -145,7 +145,11 @@ def overlap_fraction(planes) -> dict:
 
 
 def main() -> int:
-    trace_dir = sys.argv[1] if len(sys.argv) > 1 else "evidence/xplane"
+    if len(sys.argv) < 2:
+        print("usage: analyze_overlap.py <profiler trace dir>",
+              file=sys.stderr)
+        return 2
+    trace_dir = sys.argv[1]
     try:
         path = find_xplane(trace_dir)
         events = load_device_events(path)

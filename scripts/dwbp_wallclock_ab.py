@@ -3,8 +3,8 @@
 The reference's signature result is per-layer sync threads overlapping the
 remaining backward (/root/reference/src/caffe/solver.cpp:419-449). Round 3
 showed the rebuild's A/B was degenerate: XLA's all-reduce combiner merged
-all per-layer taps into ONE collective identical to DENSE_FUSED
-(evidence/dwbp_schedule.json) — there was no overlap to measure. Round 4
+all per-layer taps into ONE collective identical to DENSE_FUSED — there
+was no overlap to measure. Round 4
 added chained taps (CommConfig.dwbp_bucket_mb) that force one DISTINCT
 collective per bucket. THIS script is the wall-clock half of the proof:
 time real train steps in four modes on the same mesh —

@@ -23,6 +23,7 @@ a bit-exact record of exactly which (worker, clock) increments applied —
 a duplicate or dropped apply cannot hide.
 """
 
+import os
 import socket
 import threading
 import time
@@ -674,28 +675,47 @@ def test_joiner_tier_is_admitted_without_operator_action(monkeypatch):
 # fast restart: compile cache + AOT step store
 # --------------------------------------------------------------------------- #
 
-def test_compile_cache_enable_and_entries(tmp_path):
+def test_cache_dir_from_env_is_never_overridden_in_code(jax_cache_env,
+                                                        monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: that directory IS the cache — the
+    program never points jax at another one, its aot/ and tuned/ stores
+    live under it, and compiles land in it."""
     import jax
     import jax.numpy as jnp
 
-    from poseidon_tpu.runtime.compile_cache import (cache_entries,
-                                                    disable_compile_cache,
-                                                    enable_compile_cache)
+    from poseidon_tpu import config
+    from poseidon_tpu.runtime import compile_cache as cc
+    from poseidon_tpu.runtime.tuned_plan import store_dir
 
-    cache = enable_compile_cache(str(tmp_path / "cc"))
-    try:
-        assert jax.config.jax_compilation_cache_dir == cache
-        before = cache_entries(cache)
-        x = jnp.ones((16, 16))
-        jax.block_until_ready(
-            jax.jit(lambda a: jnp.tanh(a) @ a.T, donate_argnums=())(x))
-        assert cache_entries(cache) > before, \
-            "the persistent cache recorded no entry for a fresh compile"
-    finally:
-        # the cache config is process-global and tmp_path gets garbage-
-        # collected: leaving it enabled made LATER tests' compiles
-        # deserialize torn entries and abort the whole tier-1 run
-        disable_compile_cache()
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1])
+    cache = cc.enable_compile_cache()
+    assert cache == jax_cache_env == cc.resolve_cache_dir()
+    assert "jax_compilation_cache_dir" not in updates
+    assert config.compile_cache_config().cache_dir == cache
+    assert store_dir() == cache
+    assert cc.tuned_path(store_dir(), "ns", "k").startswith(cache + os.sep)
+    before = cc.cache_entries(cache)
+    x = jnp.ones((16, 16))
+    jax.block_until_ready(jax.jit(lambda a: jnp.tanh(a) @ a.T)(x))
+    assert cc.cache_entries(cache) > before, \
+        "the persistent cache recorded no entry for a fresh compile"
+
+
+def test_cache_dir_defaults_to_checkout(monkeypatch):
+    """Unset: a fixed <checkout>/.jax_cache resolved from __file__ — never
+    tempfile, a pid, the clock or ~/.cache — for every store."""
+    from poseidon_tpu.runtime import compile_cache as cc
+    from poseidon_tpu.runtime.tuned_plan import store_dir
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.resolve_cache_dir() == os.path.join(repo, ".jax_cache")
+    assert store_dir() == cc.resolve_cache_dir()
+    assert cc.resolve_cache_dir() == cc.resolve_cache_dir()
 
 
 def test_step_key_stability_and_sensitivity():
@@ -763,30 +783,74 @@ def test_aot_step_store_roundtrip_bitwise(tmp_path):
                                           np.asarray(p2[l][p]))
 
 
-def test_engine_aot_warm_start_loads_across_engines(tmp_path):
+def test_engine_aot_warm_start_loads_across_engines(tmp_path,
+                                                    jax_cache_env):
     """Two engine incarnations of the same config against one cache dir:
     the first compiles + serializes, the second LOADS (trace and compile
     skipped) and trains to bit-identical final params."""
-    from poseidon_tpu import config
     from poseidon_tpu.runtime.compile_cache import (aot_entries,
-                                                    disable_compile_cache,
                                                     enable_compile_cache)
 
-    cache = enable_compile_cache(str(tmp_path / "cc"))
-    config.set_compile_cache_config(cache_dir=cache, aot_steps=True)
-    try:
-        eng1 = _small_engine(tmp_path / "r1", max_iter=3)
-        last1 = eng1.train()
-        eng1.close()
-        assert eng1._aot_exec is not None and not eng1._aot_failed
-        assert aot_entries(cache) == 1
+    cache = enable_compile_cache()
+    eng1 = _small_engine(tmp_path / "r1", max_iter=3)
+    last1 = eng1.train()
+    eng1.close()
+    assert eng1._aot_exec is not None and not eng1._aot_failed
+    assert aot_entries(cache) == 1
+    assert eng1.stats.sections["compiled_step"]["source"] == "compiled"
 
-        eng2 = _small_engine(tmp_path / "r2", max_iter=3)
-        last2 = eng2.train()
-        eng2.close()
-        assert eng2._aot_exec is not None
-        assert aot_entries(cache) == 1    # loaded, not re-serialized
-        assert last1["loss"] == last2["loss"]
-    finally:
-        config.set_compile_cache_config(cache_dir="", aot_steps=True)
-        disable_compile_cache()
+    eng2 = _small_engine(tmp_path / "r2", max_iter=3)
+    last2 = eng2.train()
+    eng2.close()
+    assert eng2._aot_exec is not None
+    assert aot_entries(cache) == 1    # loaded, not re-serialized
+    assert eng2.stats.sections["compiled_step"]["source"] == "loaded"
+    assert last1["loss"] == last2["loss"]
+
+
+def test_engine_aot_does_not_reserialize_an_xla_cache_hit(tmp_path,
+                                                          jax_cache_env):
+    """XLA cache warm, aot/ empty (any edit to the package leaves a cache
+    in this state): the step the XLA cache hands back runs, is reported as
+    such, and is NOT serialized — XLA:CPU re-serializes it into an entry
+    that loads and then dies at its first dispatch."""
+    import shutil
+
+    from poseidon_tpu.runtime.compile_cache import (aot_entries,
+                                                    enable_compile_cache)
+
+    cache = enable_compile_cache()
+    eng1 = _small_engine(tmp_path / "r1", max_iter=3)
+    last1 = eng1.train()
+    eng1.close()
+    assert eng1.stats.sections["compiled_step"]["stored"] == "yes"
+    shutil.rmtree(os.path.join(cache, "aot"))
+
+    for run in ("r2", "r3"):
+        eng = _small_engine(tmp_path / run, max_iter=3)
+        last = eng.train()
+        eng.close()
+        step = eng.stats.sections["compiled_step"]
+        assert step["source"] == "xla_cache" and "error" not in step
+        assert step["stored"].startswith("no") and aot_entries(cache) == 0
+        assert eng._aot_exec is not None and not eng._aot_failed
+        assert last["loss"] == last1["loss"]
+
+
+def test_engine_survives_an_unwritable_aot_store(tmp_path, jax_cache_env):
+    """The store is best-effort: where aot/ cannot be written the compiled
+    step still runs and still reports what it holds; only `stored` says
+    no (chip_smoke.py reads these fields — a KeyError there refused PR 21
+    on the driver's machine)."""
+    from poseidon_tpu.runtime.compile_cache import enable_compile_cache
+
+    cache = enable_compile_cache()
+    with open(os.path.join(cache, "aot"), "w") as f:
+        f.write("a file where the store's directory should be")
+    eng = _small_engine(tmp_path / "r1", max_iter=3)
+    last = eng.train()
+    eng.close()
+    step = eng.stats.sections["compiled_step"]
+    assert step["source"] == "compiled" and step["stored"] == "no (see log)"
+    assert "error" not in step and step["pallas_custom_calls"] == 0
+    assert eng._aot_exec is not None and np.isfinite(last["loss"])

@@ -109,7 +109,10 @@ def test_pool_bwd_first_max_wins_ties(pool_env):
 def test_pool_bwd_strategy_routing(monkeypatch):
     from poseidon_tpu.ops.nn import POOL_TAPS_CAP, _pool_bwd_strategy
     monkeypatch.delenv("POSEIDON_POOL_BWD", raising=False)
-    # off-TPU default: taps (the CPU thunk-runtime win)
+    # CPU-mesh default: taps (the CPU thunk-runtime win); the backend is
+    # pinned so the suite says the same thing when it runs on the chip
+    monkeypatch.setattr("poseidon_tpu.ops.pallas_kernels._interpret_default",
+                        lambda: True)
     assert _pool_bwd_strategy((3, 3)) == "taps"
     # a global pool's window exceeds the taps cap: the reference arm
     # (select-and-scatter degenerates to a broadcast there anyway)
@@ -122,6 +125,69 @@ def test_pool_bwd_strategy_routing(monkeypatch):
     # explicit override always wins
     monkeypatch.setenv("POSEIDON_POOL_BWD", "sas")
     assert _pool_bwd_strategy((3, 3)) == "sas"
+
+
+def test_interpret_default_refuses_unknown_backends(monkeypatch):
+    """tpu compiles, cpu interprets, anything else is refused — never
+    silently interpreted and reported as a device run."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+    monkeypatch.delenv("POSEIDON_FORCE_PALLAS", raising=False)
+    for backend, want in (("tpu", False), ("cpu", True)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert PK._interpret_default() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="only 'tpu'"):
+        PK._interpret_default()
+
+
+def test_net_logs_and_records_kernel_routes(capsys, monkeypatch):
+    """Which arm each pool backward / LRN takes is decided by the same
+    function the op consults at trace time, logged once per layer at Net
+    construction and kept on the net (the engine writes it to
+    stats.yaml): a shape the Pallas kernel cannot hold says so."""
+    from poseidon_tpu.core.net import Net
+    from poseidon_tpu.models import zoo
+    from poseidon_tpu.ops import pallas_kernels as PK
+    monkeypatch.delenv("POSEIDON_POOL_BWD", raising=False)
+    monkeypatch.delenv("POSEIDON_PALLAS_LRN", raising=False)
+    monkeypatch.setattr(PK, "_interpret_default", lambda: True)  # CPU mesh
+    net = Net(zoo.alexnet(num_classes=10), "TRAIN",
+              source_shapes={"data": (2, 3, 67, 67), "label": (2,)})
+    assert net.kernel_routes == {
+        "norm1": "lrn=xla", "pool1": "pool_bwd=taps", "norm2": "lrn=xla",
+        "pool2": "pool_bwd=taps", "pool5": "pool_bwd=taps"}
+    assert "[kernel_route] pool1: pool_bwd -> taps" in capsys.readouterr().out
+    assert NN.pool_bwd_route(15, 15, (3, 3), (2, 2), (0, 0)) == ("taps", "")
+    monkeypatch.setenv("POSEIDON_POOL_BWD", "pallas")
+    assert NN.pool_bwd_route(15, 15, (3, 3), (2, 2), (0, 0))[0] == "pallas"
+    arm, note = NN.pool_bwd_route(8000, 8000, (3, 3), (2, 2), (0, 0))
+    assert arm == "taps" and "VMEM" in note
+
+
+def test_one_channel_conv_takes_im2col_when_lowering_for_tpu(capsys,
+                                                            monkeypatch):
+    """libtpu 0.0.34 does not finish compiling LeNet's 1-input-channel
+    conv1 backward as a direct conv at f32 HIGHEST (PR 21, on the chip):
+    lowering for the TPU routes such a conv through im2col, says so, and
+    leaves every other conv — and the CPU mesh, and an explicit
+    --conv_strategy — alone."""
+    from poseidon_tpu.core.net import Net
+    from poseidon_tpu.models import zoo
+    from poseidon_tpu.ops import pallas_kernels as PK
+
+    def plan(**kw):
+        return Net(zoo.lenet(with_accuracy=False), "TRAIN",
+                   zoo.lenet_shapes(2), **kw).conv_strategy_plan()
+
+    monkeypatch.setattr(PK, "_interpret_default", lambda: True)   # CPU mesh
+    assert plan() == {"conv1": None, "conv2": None}
+    monkeypatch.setattr(PK, "_interpret_default", lambda: False)  # for TPU
+    capsys.readouterr()
+    assert plan() == {"conv1": "im2col", "conv2": None}
+    assert "[conv_strategy] conv1: 1 input channel -> im2col" in \
+        capsys.readouterr().out
+    assert plan(conv_strategy="direct") == {"conv1": "direct",
+                                            "conv2": "direct"}
 
 
 def test_pool_plane_feasibility_guard(rng_np, pool_env, monkeypatch):
@@ -174,22 +240,28 @@ def test_pool_bwd_under_jit_and_in_net(rng_np, pool_env):
 # --------------------------------------------------------------------------- #
 
 def test_lrn_routing_defaults(monkeypatch):
-    """Off-TPU: XLA formulation. On TPU (mocked): Pallas by default,
-    POSEIDON_PALLAS_LRN=0 opts out."""
+    """CPU mesh (mocked, so the suite says the same on the chip): XLA
+    formulation, POSEIDON_PALLAS_LRN=1 forces the interpreted kernels. TPU
+    (mocked): Pallas by default, POSEIDON_PALLAS_LRN=0 opts out."""
     from poseidon_tpu.ops import pallas_kernels as PK
     x = jnp.ones((1, 4, 4, 4), jnp.float32)
     calls = []
     monkeypatch.setattr(PK, "lrn_fused",
                         lambda *a, **kw: calls.append("pallas") or x)
     monkeypatch.delenv("POSEIDON_PALLAS_LRN", raising=False)
+    monkeypatch.setattr(PK, "_interpret_default", lambda: True)
     PK.maybe_lrn_fused(x, 5, 1e-4, 0.75)          # CPU: XLA
-    assert calls == []
+    assert calls == [] and PK.lrn_route(16, 4) == ("xla", "cpu backend")
+    monkeypatch.setenv("POSEIDON_PALLAS_LRN", "1")
+    assert PK.lrn_route(16, 4) == ("pallas", "")  # forced, interpreted
+    monkeypatch.delenv("POSEIDON_PALLAS_LRN")
     monkeypatch.setattr(PK, "_interpret_default", lambda: False)
     PK.maybe_lrn_fused(x, 5, 1e-4, 0.75)          # "TPU": Pallas default
     assert calls == ["pallas"]
     monkeypatch.setenv("POSEIDON_PALLAS_LRN", "0")
     PK.maybe_lrn_fused(x, 5, 1e-4, 0.75)          # opt-out honored
     assert calls == ["pallas"]
+    assert PK.lrn_route(16, 4) == ("xla", "POSEIDON_PALLAS_LRN=0")
 
 
 @pytest.mark.parametrize("layout", ["NCHW", "NHWC"])

@@ -434,7 +434,7 @@ def test_s2d_stem_rewrite_parity_nhwc():
 
 def test_conv_layout_auto_resolves_per_backend():
     """``conv_layout="auto"`` resolves at Net construction: NCHW on TPU
-    (NHWC measured 0.53x on the real v5e in BENCH_r05 despite winning the
+    (NHWC ran 0.53x in the July-2026 chip A/B despite winning the
     HLO-transpose count), NHWC on GPU (tensor-core native), NCHW on CPU /
     unknown backends; explicit overrides pass through untouched."""
     from poseidon_tpu.numeric import resolve_conv_layout

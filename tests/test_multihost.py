@@ -25,6 +25,34 @@ def _free_port():
     return port
 
 
+def test_train_touches_no_backend_before_init_distributed():
+    """jax.distributed.initialize refuses to run once any backend exists,
+    so `train` must call init_distributed before ANYTHING that touches
+    one (the TunedPlan resolution reads jax.default_backend() for its
+    key). A fresh process — backends already exist in this one."""
+    import subprocess
+    code = f"""
+import sys
+sys.path.insert(0, {REPO!r})
+from jax._src import xla_bridge
+from poseidon_tpu.runtime import cli, cluster
+
+def init_distributed(**kw):
+    assert not xla_bridge.backends_are_initialized(), \\
+        "a backend was initialized before init_distributed"
+    print("INIT_FIRST_OK", flush=True)
+    sys.exit(0)
+
+cluster.init_distributed = init_distributed
+cli.main(["train", "--solver={REPO}/examples/mnist/lenet_solver.prototxt"])
+"""
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True, timeout=120,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0 and "INIT_FIRST_OK" in r.stdout, \
+        r.stdout[-1000:] + r.stderr[-2000:]
+
+
 def _run_local_train(tmp_path, prefix: str, max_iter: int, extra_args=()):
     """Drive the REAL launcher (scripts/launch.py --local path): 2 processes
     x 4 virtual devices training lenet; returns (logs, per-process snapshot

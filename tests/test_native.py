@@ -1,15 +1,37 @@
 """Native data plane vs Python reference: bit-parity and throughput sanity."""
 
+import os
+import shutil
+
 import numpy as np
 import pytest
 
+from poseidon_tpu.data import native
 from poseidon_tpu.data.lmdb_reader import LMDBWriter
 from poseidon_tpu.proto.wire import Datum, encode_datum
 
-native = pytest.importorskip("poseidon_tpu.data.native")
-
-pytestmark = pytest.mark.skipif(not native.available(),
+# the library is a build output (native/build/ is git-ignored): with a
+# compiler present it MUST build from the tracked source — a failed build
+# fails test_library_builds_from_source instead of skipping the module
+pytestmark = pytest.mark.skipif(shutil.which("g++") is None,
                                 reason="no C++ toolchain")
+
+
+def test_library_builds_from_source():
+    assert native.available()
+    assert os.path.getmtime(native._LIB) >= os.path.getmtime(native._SRC)
+
+
+def test_library_is_stale_when_source_is_newer():
+    assert native.available() and not native._stale()
+    lib_mtime = os.path.getmtime(native._LIB)
+    src_stat = os.stat(native._SRC)
+    try:
+        os.utime(native._SRC, (lib_mtime + 10, lib_mtime + 10))
+        assert native._stale()
+    finally:
+        os.utime(native._SRC, (src_stat.st_atime, src_stat.st_mtime))
+    assert not native._stale()
 
 
 @pytest.fixture(scope="module")
@@ -145,7 +167,6 @@ def test_native_u8_matches_f32_pixels(datum_db):
     on-device) and dtype differ."""
     path, _, _ = datum_db
     b = native.NativeLMDBBatcher(path, crop_size=8, mirror=True, train=True)
-    assert b.supports_u8()
     f32, l1 = b.batch(np.arange(16), seed=11)
     u8, l2 = b.batch_u8(np.arange(16), seed=11)
     assert u8.dtype == np.uint8

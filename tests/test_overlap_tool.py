@@ -35,7 +35,7 @@ def test_overlap_fraction_interval_math():
 def test_overlap_tool_on_real_trace(tmp_path):
     import jax
     import jax.numpy as jnp
-    from poseidon_tpu.compat import shard_map
+    from jax import shard_map
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 

@@ -302,7 +302,7 @@ def test_dwbp_bucketed_matches_dense(mesh, lenet_net, rng_np):
     match plain dense bit-for-bit (the gate is the identity for any finite
     token), and the compiled program must keep the buckets' collectives
     DISTINCT (the whole point: round 3 showed the combiner merges unchained
-    taps into one all-reduce, evidence/dwbp_schedule.json)."""
+    taps into one all-reduce)."""
     sp = SolverParameter(base_lr=0.01, lr_policy="fixed", momentum=0.9)
     params = lenet_net.init(jax.random.PRNGKey(0))
     batch = _global_batch(rng_np)

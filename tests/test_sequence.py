@@ -3,10 +3,10 @@
 import functools
 
 import jax
-from poseidon_tpu.compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from poseidon_tpu.ops.attention import attention
