@@ -1,0 +1,131 @@
+"""The one command end to end: the --cpu-tiny rehearsal of an ``lmdb`` and a
+``resident`` cell (what runs on the chip, at cut widths, on the CPU and
+labelled so), and the ways it must refuse to run."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from conftest import BENCH_DIR, ROOT
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    BENCH = json.load(f)
+
+
+def run_cell(*args, cwd=ROOT, script=os.path.join(BENCH_DIR, "run.py")):
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    return subprocess.run([sys.executable, script, *args], cwd=cwd, env=env,
+                          capture_output=True, text=True, timeout=600)
+
+
+def declared(kind: str, cell: str) -> set:
+    return {m["name"] for m in BENCH[kind]
+            if "workloads" not in m or cell in m["workloads"]}
+
+
+@pytest.mark.parametrize("cell,trace", [
+    ("alexnet.lmdb", 0), ("alexnet.lmdb", 1),
+    ("alexnet.resident", 0), ("alexnet.dp4.resident", 1)])
+def test_cpu_tiny_rehearsal(cell, trace):
+    chips = next(w["chips"] for w in BENCH["workloads"] if w["name"] == cell)
+    done = run_cell("--workload", cell, "--seed", "3", "--seconds", "1",
+                    "--trace", str(trace), "--cpu-tiny")
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    keys = {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(line) == (keys | {"breakdown"} if trace else keys)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 4
+    assert line["device"]["platform"] == "cpu"       # never passes for a chip
+    assert line["device"]["count"] == chips
+    names = set(line["metrics"])
+    for m in line["metrics"].values():
+        assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
+    if trace:
+        # every per-layer metric of the cell but the ones that need a chip's
+        # peak or its memory statistics
+        assert names == declared("per_layer", cell) - {"busy_flops_util",
+                                                       "peak_hbm_gb"}
+        assert line["metrics"]["compiles_in_window"]["value"] == 0.0
+        assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
+        assert 0 < len(line["breakdown"]["device_ops"]) <= 10
+        assert 0 < len(line["breakdown"]["idle_gaps"]) <= 5
+        spans = {name for name, _ in line["breakdown"]["idle_gaps"]}
+        assert spans - {"unattributed"}, "no gap met an engine span"
+    else:
+        # no peak FLOP/s for a CPU: the MFU is left out, not made up
+        assert names == declared("end_to_end", cell) - {"mfu_required"}
+        assert line["metrics"]["images_per_s_per_chip"]["value"] > 0
+        assert line["metrics"]["setup_s"]["value"] > 0
+
+
+def test_refuses_to_measure_without_a_tpu():
+    done = run_cell("--workload", "alexnet.lmdb", "--seed", "0",
+                    "--seconds", "1", "--trace", "0")
+    assert done.returncode == 2
+    assert "REFUSING" in done.stderr and "tpu" in done.stderr
+    assert '"metrics"' not in done.stdout
+
+
+def test_unknown_cell_and_missing_files_fail_by_name(tmp_path):
+    done = run_cell("--workload", "nope", "--cpu-tiny")
+    assert done.returncode == 2 and "'nope'" in done.stderr
+    # a checkout that holds only BENCHMARK.json and the benchmark's paths:
+    # cells are found, the program is not
+    bare = tmp_path / "bare"
+    shutil.copytree(BENCH_DIR, bare / "benchmark",
+                    ignore=shutil.ignore_patterns(".work", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), bare)
+    script = str(bare / "benchmark" / "run.py")
+    done = run_cell("--workload", "alexnet.lmdb", "--cpu-tiny", cwd=bare,
+                    script=script)
+    assert done.returncode == 2 and "poseidon_tpu" in done.stderr
+    assert done.stdout == ""
+    # a cell that names a traffic mix / a configuration nobody brought
+    doc = json.loads((bare / "BENCHMARK.json").read_text())
+    doc["workloads"][0]["traffic"] = "bursty"
+    doc["configs"][1]["file"] = "benchmark/configs/gone.json"
+    (bare / "BENCHMARK.json").write_text(json.dumps(doc))
+    done = run_cell("--workload", doc["workloads"][0]["name"], "--cpu-tiny",
+                    cwd=bare, script=script)
+    assert done.returncode == 2
+    assert "traffic mix 'bursty'" in done.stderr
+    assert "benchmark/traffic/bursty.json" in done.stderr
+    done = run_cell("--workload", "googlenet.lmdb", "--cpu-tiny", cwd=bare,
+                    script=script)
+    assert done.returncode == 2
+    assert "configuration 'bvlc_googlenet'" in done.stderr
+    assert "benchmark/configs/gone.json" in done.stderr
+
+
+def test_unknown_device_kind_is_an_error():
+    import device
+    assert device.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+    for kind in ("TPU v9", "cpu", "comment"):
+        with pytest.raises(KeyError, match="no peak rates on record"):
+            device.peaks(kind)
+
+
+def test_run_py_names_no_cell_configuration_traffic_or_metric():
+    with open(os.path.join(BENCH_DIR, "run.py")) as f:
+        text = f.read()
+    names = [x["name"] for k in ("configs", "workloads", "end_to_end",
+                                 "per_layer") for x in BENCH[k]]
+    names += [w["traffic"] for w in BENCH["workloads"]]
+    assert not [n for n in names if n in text]
+
+
+def test_every_declared_name_has_its_file():
+    for w in BENCH["workloads"]:
+        assert os.path.exists(os.path.join(BENCH_DIR, "traffic",
+                                           f"{w['traffic']}.json"))
+    for c in BENCH["configs"]:
+        assert os.path.exists(os.path.join(ROOT, c["file"]))
+    for m in BENCH["per_layer"]:
+        assert os.path.exists(os.path.join(BENCH_DIR, "layer_metrics",
+                                           f"{m['name']}.py")), m["name"]
+        assert m["moves"] in {e["name"] for e in BENCH["end_to_end"]}
