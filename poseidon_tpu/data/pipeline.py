@@ -20,6 +20,7 @@ from typing import Dict, Iterator, Optional
 import numpy as np
 
 from ..proto.messages import LayerParameter, TransformationParameter
+from ..runtime.spans import recorder as _spans
 from .sources import (HDF5Source, ImageListSource, LMDBSource, LevelDBSource,
                       MemorySource, Source)
 from .transformer import DataTransformer
@@ -91,6 +92,12 @@ def layer_batch_size(lp: LayerParameter) -> int:
         "MEMORY_DATA": lp.memory_data_param.batch_size,
         "WINDOW_DATA": lp.window_data_param.batch_size,
     }[t]
+
+
+def _batch_args(batch_no: int) -> Optional[Dict]:
+    """Span args of a producer span — built only while the recorder is on,
+    so the producer loops of an untraced run allocate nothing for it."""
+    return {"batch": batch_no} if _spans.enabled else None
 
 
 class BatchPipeline:
@@ -234,73 +241,92 @@ class BatchPipeline:
             yield from idx
             epoch += 1
 
+    def _put(self, batch: Dict[str, np.ndarray], batch_no: int) -> None:
+        """Hand one batch to the consumer. ``producer_queue_full`` spans
+        only the time this thread is blocked on a full queue: its share of
+        a window is how far the reader is ahead of the step, and a reader
+        that never records one is the bottleneck."""
+        try:
+            self._queue.put_nowait(batch)
+        except queue.Full:
+            with _spans.span("producer_queue_full", "input",
+                             _batch_args(batch_no)):
+                self._queue.put(batch)
+
     def _worker(self):
+        # producer_read = the making of one batch (index draw + read +
+        # transform), numbered as the consumer will dequeue it
+        batch_no = 0
         if self.window is not None:
             try:
                 while not self._stop.is_set():
-                    data, labels = self.window.batch(self.batch_size)
+                    with _spans.span("producer_read", "input",
+                                     _batch_args(batch_no)):
+                        data, labels = self.window.batch(self.batch_size)
                     batch = {self.tops[0]: data}
                     if len(self.tops) > 1:
                         batch[self.tops[1]] = labels
-                    self._queue.put(batch)
+                    self._put(batch, batch_no)
+                    batch_no += 1
             except Exception as e:
                 self._queue.put(e)
             return
         stream = self._index_stream()
-        batch_no = 0
         self._warned_mixed = False
         try:
             while not self._stop.is_set():
-                idx = np.fromiter((next(stream)
-                                   for _ in range(self.batch_size)),
-                                  np.int64, count=self.batch_size)
-                if self.native is not None:
-                    seed = self.seed * 1_000_003 + batch_no
-                    if self._u8:
-                        try:
-                            data, labels = self.native.batch_u8(idx, seed=seed)
-                        except IOError:
-                            # mixed byte/float DB: the init probe saw record 0
-                            # byte-backed, but THIS batch hit a float_data
-                            # Datum (rc=-4). Keep the uint8 wire contract by
-                            # undoing the host transform's (x - mean) * scale
-                            # (same seed -> same crop/mirror), instead of
-                            # killing the prefetch worker mid-epoch.
-                            data, labels = self.native.batch(idx, seed=seed)
-                            spec = self.device_transform_spec or {}
-                            raw = data / (spec.get("scale") or 1.0)
-                            mv = spec.get("mean_values")
-                            if mv is not None:
-                                raw = raw + mv.reshape(1, -1, 1, 1)
-                            data = np.clip(np.rint(raw), 0, 255) \
-                                .astype(np.uint8)
-                            if not self._warned_mixed:
-                                self._warned_mixed = True
-                                import sys
-                                print("WARNING: mixed byte/float LMDB under "
-                                      "--device_transform; float_data "
-                                      "records are re-quantized to uint8 "
-                                      "per batch (lossy for values outside "
-                                      "[0,255])", file=sys.stderr, flush=True)
-                    else:
-                        data, labels = self.native.batch(idx, seed=seed)
-                else:
-                    raw = np.empty(
-                        (self.batch_size,) + self.source.record_shape,
-                        np.float32)
-                    labels = np.empty((self.batch_size,), np.int32)
-                    for i, j in enumerate(idx):
-                        arr, label = self.source.read(int(j))
-                        raw[i] = arr
-                        labels[i] = label
-                    data = self.transformer(raw)
-                batch_no += 1
+                with _spans.span("producer_read", "input",
+                                 _batch_args(batch_no)):
+                    data, labels = self._read_batch(stream, batch_no)
                 batch = {self.tops[0]: data}
                 if len(self.tops) > 1:
                     batch[self.tops[1]] = labels
-                self._queue.put(batch)
+                self._put(batch, batch_no)
+                batch_no += 1
         except Exception as e:  # surface worker death to the consumer
             self._queue.put(e)
+
+    def _read_batch(self, stream: Iterator[int], batch_no: int):
+        """One batch's ``(data, labels)``: the next ``batch_size`` indices
+        of the shard's stream through the native batcher, or read record
+        by record and transformed in Python."""
+        idx = np.fromiter((next(stream) for _ in range(self.batch_size)),
+                          np.int64, count=self.batch_size)
+        if self.native is None:
+            raw = np.empty((self.batch_size,) + self.source.record_shape,
+                           np.float32)
+            labels = np.empty((self.batch_size,), np.int32)
+            for i, j in enumerate(idx):
+                arr, label = self.source.read(int(j))
+                raw[i] = arr
+                labels[i] = label
+            return self.transformer(raw), labels
+        seed = self.seed * 1_000_003 + batch_no
+        if not self._u8:
+            return self.native.batch(idx, seed=seed)
+        try:
+            return self.native.batch_u8(idx, seed=seed)
+        except IOError:
+            # mixed byte/float DB: the init probe saw record 0 byte-backed,
+            # but THIS batch hit a float_data Datum (rc=-4). Keep the uint8
+            # wire contract by undoing the host transform's
+            # (x - mean) * scale (same seed -> same crop/mirror), instead
+            # of killing the prefetch worker mid-epoch.
+            data, labels = self.native.batch(idx, seed=seed)
+            spec = self.device_transform_spec or {}
+            raw = data / (spec.get("scale") or 1.0)
+            mv = spec.get("mean_values")
+            if mv is not None:
+                raw = raw + mv.reshape(1, -1, 1, 1)
+            data = np.clip(np.rint(raw), 0, 255).astype(np.uint8)
+            if not self._warned_mixed:
+                self._warned_mixed = True
+                import sys
+                print("WARNING: mixed byte/float LMDB under "
+                      "--device_transform; float_data records are "
+                      "re-quantized to uint8 per batch (lossy for values "
+                      "outside [0,255])", file=sys.stderr, flush=True)
+            return data, labels
 
     def __iter__(self):
         return self
@@ -366,6 +392,7 @@ class DevicePrefetcher:
                             else bool(passthrough))
         self._error: Optional[Exception] = None
         self._thread = None
+        self._taken = 0    # passthrough arm: batches handed out so far
         if not self.passthrough:
             self._queue: queue.Queue = queue.Queue(maxsize=self.depth)
             self._stop = threading.Event()
@@ -377,22 +404,43 @@ class DevicePrefetcher:
         import jax
         return jax.default_backend() == "cpu"
 
+    def _place_next(self, batch_no: int) -> Dict:
+        """Dequeue one host batch from every pipeline and place it.
+        ``producer_h2d`` spans the ``place_batch`` calls alone: what it
+        takes this thread to hand the bytes to the runtime. ``device_put``
+        returns before the copy has landed on the device, so the transfer
+        itself can end after the span does."""
+        host: Dict[str, np.ndarray] = {}
+        for pipe in self.pipes:
+            host.update(next(pipe))
+        args = _batch_args(batch_no)
+        if args is not None:
+            args["bytes"] = sum(v.nbytes for v in host.values())
+        with _spans.span("producer_h2d", "input", args):
+            return {k: place_batch(v, self.sharding)
+                    for k, v in host.items()}
+
     def _worker(self):
+        batch_no = -1
         try:
             while not self._stop.is_set():
-                host: Dict[str, np.ndarray] = {}
-                for pipe in self.pipes:
-                    host.update(next(pipe))
-                batch = {k: place_batch(v, self.sharding)
-                         for k, v in host.items()}
+                batch_no += 1
+                batch = self._place_next(batch_no)
+                try:
+                    self._queue.put_nowait(batch)
+                    continue
+                except queue.Full:
+                    pass
                 # bounded put that still honors close(): a full queue must
                 # not pin this thread forever after the consumer left
-                while not self._stop.is_set():
-                    try:
-                        self._queue.put(batch, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
+                with _spans.span("producer_queue_full", "input",
+                                 _batch_args(batch_no)):
+                    while not self._stop.is_set():
+                        try:
+                            self._queue.put(batch, timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
         except Exception as e:  # surface pipeline death to the consumer
             self._error = e  # sticky BEFORE the sentinel: set-then-put
             self._queue.put(e)
@@ -405,11 +453,9 @@ class DevicePrefetcher:
             if self._error is not None:
                 raise self._error
             try:
-                host: Dict[str, np.ndarray] = {}
-                for pipe in self.pipes:
-                    host.update(next(pipe))
-                return {k: place_batch(v, self.sharding)
-                        for k, v in host.items()}
+                batch = self._place_next(self._taken)
+                self._taken += 1
+                return batch
             except Exception as e:
                 self._error = e  # same sticky-death contract as threaded
                 raise

@@ -197,6 +197,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: int,
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_kb=n_kb,
                           chunk_mode=chunk),
+        name="flash_fwd",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)),
         grid=grid,
@@ -359,6 +360,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
         functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_kb=n_kb,
                           chunk_mode=chunk),
+        name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         grid=(bh, n_qb, n_kb),
         in_specs=smem + [qspec, kspec, kspec, qspec, rowq, rowq],
@@ -380,6 +382,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
         functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_qb=n_qb,
                           chunk_mode=chunk),
+        name="flash_bwd_dkv",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
         grid=(bh, n_kb, n_qb),
@@ -591,6 +594,7 @@ def _lrn_fused_fwd_impl(x, local_size: int, alpha: float, beta: float,
     out = pl.pallas_call(
         functools.partial(_lrn_kernel, local_size=local_size, alpha=alpha,
                           beta=beta, k=k, channels=c, channel_axis=caxis),
+        name="lrn_fwd",
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
         grid=(n, hw_p // tile),
         in_specs=[spec],
@@ -684,6 +688,7 @@ def lrn_fused_bwd(x, g, local_size: int, alpha: float, beta: float,
         functools.partial(_lrn_bwd_kernel, local_size=local_size,
                           alpha=alpha, beta=beta, k=k, channels=c,
                           channel_axis=caxis),
+        name="lrn_bwd",
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
         grid=(n, hw_p // tile),
         in_specs=[spec, spec],
@@ -849,6 +854,7 @@ def pool_bwd_plane(xp: Optional[jax.Array], g: jax.Array, kernel: tuple,
         functools.partial(_pool_bwd_kernel, kernel=tuple(kernel),
                           stride=tuple(stride), oh=oh, ow=ow, ph=ph, pw=pw,
                           method=method),
+        name=f"{method}pool_bwd",
         out_shape=jax.ShapeDtypeStruct((n, c, ph, pw), out_dtype),
         grid=(n, c),
         in_specs=in_specs,
@@ -903,6 +909,7 @@ def fused_sgd(w, g, h, local_rate, decay_vec, momentum: float,
                         memory_space=pltpu.VMEM)
     w2, h2 = pl.pallas_call(
         functools.partial(_sgd_update_kernel, momentum=momentum),
+        name="sgd_update",
         out_shape=(jax.ShapeDtypeStruct((grid_rows * rows_block, lanes),
                                         jnp.float32),) * 2,
         grid=(grid_rows,),

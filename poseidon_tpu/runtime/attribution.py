@@ -10,16 +10,18 @@ top-3 sinks). The pipeline:
    ``.../transpose(jvp(conv1))/...`` (autodiff preserves the scope). The
    arena/update phases (core/arena.py, solvers/updates.py) are scoped the
    same way.
-2. A profiled step dumps an xplane protobuf. ``parse_xspace`` reads it
-   with a ~100-line protobuf wire-format walker (shared varint helpers,
-   data/varint.py) — no ``tensorflow.python.profiler`` import, the
-   dependency the PR-4 attempt timed out fighting. A Chrome trace-event
-   JSON (``*.trace.json[.gz]``) parses as the fallback.
+2. A profiled step dumps an xplane protobuf, read with
+   ``jax.profiler.ProfileData`` (``load_trace_events``) — the one trace
+   reader of the repository; the benchmark's ``device_trace.py`` uses the
+   same class.
 3. Each op event joins back to its layer through the COMPILED module text
    (``compiled.as_text()``): instruction name -> op_name metadata ->
    layer scope (``hlo_scope_map``). This works identically on the CPU
    thunk runtime (events per HLO op on host threads) and the TPU device
-   planes, because both name events after HLO instructions.
+   planes, because both name events after HLO instructions. The Engine
+   publishes that map for the step it runs as stats section
+   ``step_scopes`` (``step_scopes`` below), which is what the benchmark's
+   per-pass and per-layer-type metrics join a device trace with.
 4. ``attribute`` folds event durations into a per-layer table — fwd/bwd
    ms, %-of-traced-op-time, analytic FLOPs (``layer_cost_table``), arithmetic
    intensity, per-layer MFU against a peak — with an ``(unattributed)``
@@ -34,181 +36,51 @@ capture after).
 from __future__ import annotations
 
 import glob
-import gzip
-import json
 import os
 import re
-import struct
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..data.varint import read_varint
-
 __all__ = [
-    "parse_xspace", "load_trace_events", "hlo_scope_map", "scope_of",
-    "comm_axis_of", "layer_cost_table", "attribute", "format_table",
-    "measure_then_trace",
+    "load_trace_events", "trace_events_from_xspace", "hlo_scope_map",
+    "step_scopes", "scope_of", "comm_axis_of", "layer_cost_table",
+    "attribute", "format_table", "measure_then_trace",
 ]
 
 
 # --------------------------------------------------------------------------- #
-# minimal protobuf wire-format walker (xplane.proto subset)
+# trace loading (jax.profiler.ProfileData — the benchmark's reader too)
 # --------------------------------------------------------------------------- #
 
-def _fields(buf: bytes):
-    """Yield (field_number, wire_type, value) over one message's bytes.
-    Varints decode to int; length-delimited fields yield their bytes;
-    fixed64/fixed32 yield raw bytes (decoded by the caller if needed)."""
-    pos = 0
-    n = len(buf)
-    while pos < n:
-        tag, pos = read_varint(buf, pos)
-        fno, wt = tag >> 3, tag & 7
-        if wt == 0:
-            v, pos = read_varint(buf, pos)
-        elif wt == 1:
-            v = buf[pos:pos + 8]
-            pos += 8
-        elif wt == 2:
-            ln, pos = read_varint(buf, pos)
-            v = buf[pos:pos + ln]
-            pos += ln
-        elif wt == 5:
-            v = buf[pos:pos + 4]
-            pos += 4
-        else:
-            raise ValueError(f"unsupported protobuf wire type {wt}")
-        yield fno, wt, v
+def _flatten(profile) -> List[Dict]:
+    """ProfileData -> op-level events ``[{name, dur_us, t0_us, plane, line,
+    stats}]``, ``t0_us`` on the trace's own clock."""
+    return [{"name": ev.name,
+             "dur_us": ev.duration_ns / 1e3,
+             "t0_us": ev.start_ns / 1e3,
+             "plane": plane.name,
+             "line": line.name,
+             "stats": dict(ev.stats)}
+            for plane in profile.planes
+            for line in plane.lines
+            for ev in line.events]
 
 
-def _map_entry(buf: bytes) -> Tuple[int, bytes]:
-    """proto3 map<int64, Message> entry: {1: key varint, 2: value bytes}."""
-    key, val = 0, b""
-    for fno, _wt, v in _fields(buf):
-        if fno == 1:
-            key = v
-        elif fno == 2:
-            val = v
-    return key, val
-
-
-def _parse_stat(buf: bytes, stat_names: Dict[int, str]):
-    """XStat -> (name, value). The oneof value: double(2)/uint64(3)/
-    int64(4)/str(5)/bytes(6)/ref(7 — an id into stat_metadata whose NAME
-    is the value, the xplane string-interning trick)."""
-    name, value = None, None
-    for fno, wt, v in _fields(buf):
-        if fno == 1:
-            name = stat_names.get(v, str(v))
-        elif fno == 2:
-            value = struct.unpack("<d", v)[0]
-        elif fno in (3, 4):
-            value = v
-        elif fno == 5:
-            value = v.decode("utf-8", "replace")
-        elif fno == 6:
-            value = v
-        elif fno == 7:
-            value = stat_names.get(v, str(v))
-    return name, value
-
-
-def parse_xspace(data: bytes) -> List[Dict]:
-    """XSpace bytes -> [{name, lines: [{name, timestamp_ns, events:
-    [{name, dur_ps, offset_ps, stats}]}]}] — exactly the subset
-    attribution needs, parsed with the wire walker above."""
-    planes: List[Dict] = []
-    for fno, _wt, pbuf in _fields(data):
-        if fno != 1:           # XSpace.planes
-            continue
-        plane = {"name": "", "lines": []}
-        event_names: Dict[int, str] = {}
-        stat_names: Dict[int, str] = {}
-        line_bufs: List[bytes] = []
-        for pf, _pw, pv in _fields(pbuf):
-            if pf == 2:
-                plane["name"] = pv.decode("utf-8", "replace")
-            elif pf == 3:      # XPlane.lines
-                line_bufs.append(pv)
-            elif pf == 4:      # map<int64, XEventMetadata>
-                k, mbuf = _map_entry(pv)
-                for mf, _mw, mv in _fields(mbuf):
-                    if mf == 2:
-                        event_names[k] = mv.decode("utf-8", "replace")
-            elif pf == 5:      # map<int64, XStatMetadata>
-                k, mbuf = _map_entry(pv)
-                for mf, _mw, mv in _fields(mbuf):
-                    if mf == 2:
-                        stat_names[k] = mv.decode("utf-8", "replace")
-        for lbuf in line_bufs:
-            line = {"name": "", "timestamp_ns": 0, "events": []}
-            for lf, _lw, lv in _fields(lbuf):
-                if lf == 2:
-                    line["name"] = lv.decode("utf-8", "replace")
-                elif lf == 3:
-                    line["timestamp_ns"] = lv
-                elif lf == 4:  # XLine.events
-                    ev = {"name": "", "dur_ps": 0, "offset_ps": 0,
-                          "stats": {}}
-                    for ef, _ew, evv in _fields(lv):
-                        if ef == 1:
-                            ev["name"] = event_names.get(evv, str(evv))
-                        elif ef == 2:
-                            ev["offset_ps"] = evv
-                        elif ef == 3:
-                            ev["dur_ps"] = evv
-                        elif ef == 4:
-                            sn, sv = _parse_stat(evv, stat_names)
-                            if sn is not None:
-                                ev["stats"][sn] = sv
-                    line["events"].append(ev)
-            plane["lines"].append(line)
-        planes.append(plane)
-    return planes
-
-
-# --------------------------------------------------------------------------- #
-# trace loading (xplane preferred, Chrome trace-event JSON fallback)
-# --------------------------------------------------------------------------- #
-
-def _newest_run_dir(trace_dir: str) -> Optional[str]:
-    runs = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile",
-                                         "*")))
-    return runs[-1] if runs else None
+def trace_events_from_xspace(data: bytes) -> List[Dict]:
+    """The events of one serialized XSpace (the bytes of an ``.xplane.pb``)."""
+    from jax.profiler import ProfileData
+    return _flatten(ProfileData.from_serialized_xspace(data))
 
 
 def load_trace_events(trace_dir: str) -> List[Dict]:
-    """Flatten a ``jax.profiler`` dump into op-level events:
-    ``[{name, dur_us, plane, line, stats}]``. Prefers the newest run's
-    ``*.xplane.pb``; falls back to ``*.trace.json[.gz]``."""
-    run = _newest_run_dir(trace_dir) or trace_dir
+    """Flatten a ``jax.profiler`` dump — the ``*.xplane.pb`` files of the
+    newest run under ``trace_dir`` — into op-level events."""
+    from jax.profiler import ProfileData
+    runs = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile",
+                                         "*")))
+    run = runs[-1] if runs else trace_dir
     out: List[Dict] = []
     for pb in sorted(glob.glob(os.path.join(run, "*.xplane.pb"))):
-        with open(pb, "rb") as f:
-            data = f.read()
-        for plane in parse_xspace(data):
-            for line in plane["lines"]:
-                for ev in line["events"]:
-                    out.append({"name": ev["name"],
-                                "dur_us": ev["dur_ps"] / 1e6,
-                                "t0_us": ev["offset_ps"] / 1e6,
-                                "plane": plane["name"],
-                                "line": line["name"],
-                                "stats": ev["stats"]})
-    if out:
-        return out
-    for tj in sorted(glob.glob(os.path.join(run, "*.trace.json*"))):
-        opener = gzip.open if tj.endswith(".gz") else open
-        with opener(tj, "rb") as f:
-            doc = json.loads(f.read())
-        for ev in doc.get("traceEvents", []):
-            if ev.get("ph") != "X":
-                continue
-            out.append({"name": ev.get("name", ""),
-                        "dur_us": float(ev.get("dur", 0.0)),
-                        "t0_us": float(ev.get("ts", 0.0)),
-                        "plane": str(ev.get("pid", "")),
-                        "line": str(ev.get("tid", "")),
-                        "stats": dict(ev.get("args", {}) or {})})
+        out.extend(_flatten(ProfileData.from_file(pb)))
     return out
 
 
@@ -293,38 +165,72 @@ def comm_axis_of(scope: str) -> Optional[str]:
     return None
 
 
+class _ScopeIndex:
+    """The layer names and extra scopes of one module, prepared once for
+    the lookups of every instruction: layers keyed by their first path
+    component (longest first under a key), lookups memoized by op_name —
+    a compiled step repeats a few hundred op_names over thousands of
+    instructions."""
+
+    def __init__(self, layer_names, extra_scopes=()):
+        self._by_first: Dict[str, List[Tuple[Tuple[str, ...], str]]] = {}
+        for name in layer_names:
+            parts = tuple(name.split("/"))
+            self._by_first.setdefault(parts[0], []).append((parts, name))
+        for cands in self._by_first.values():
+            cands.sort(key=lambda c: -len(c[0]))
+        self._extra = tuple(sorted(extra_scopes))
+        self._memo: Dict[str, Tuple[Optional[str], Optional[str]]] = {}
+
+    def lookup(self, op_name: str):
+        hit = self._memo.get(op_name)
+        if hit is None:
+            hit = self._memo[op_name] = self._find(op_name)
+        return hit
+
+    def _find(self, op_name: str):
+        comps = tuple(_scope_components(op_name))
+        best: Tuple[Tuple[str, ...], Optional[str]] = ((), None)
+        for i, comp in enumerate(comps):
+            for parts, name in self._by_first.get(comp, ()):
+                if len(parts) <= len(best[0]):
+                    break               # longest first: nothing better here
+                if comps[i:i + len(parts)] == parts:
+                    best = (parts, name)
+                    break
+        if best[1] is not None:
+            return best[1], ("bwd" if "transpose(" in op_name else "fwd")
+        for c in comps:
+            if COMM_SCOPE_RE.match(c):
+                return c, "misc"
+        joined = "/".join(comps)
+        for extra in self._extra:
+            if extra in joined:
+                return extra, "misc"
+        return None, None
+
+
 def scope_of(op_name: str, layer_names, extra_scopes=frozenset()):
     """(scope, phase) for one op_name metadata path, or (None, None).
 
     ``layer_names`` may contain '/' (GoogLeNet's inception blobs), so the
     peeled path components are matched against each layer's own component
-    sequence — longest layer first, contiguous subsequence. Phase is
-    'bwd' when the path went through an autodiff transpose, else 'fwd';
-    extra (non-layer) scopes — arena/update phases — report 'misc', and
-    the comm machinery's per-bucket/per-axis collective scopes
+    sequence — the layer of most components wins, then the earliest in the
+    path. Phase is 'bwd' when the path went through an autodiff transpose,
+    else 'fwd'; extra (non-layer) scopes — arena/update phases — report
+    'misc', and the comm machinery's per-bucket/per-axis collective scopes
     (``COMM_SCOPE_RE``) are recognized unconditionally so comm time
-    lands in named per-axis rows rather than the residual."""
-    comps = _scope_components(op_name)
-    joined = "/".join(comps)
-    for lname in sorted(layer_names, key=lambda s: -s.count("/")):
-        ln = lname.split("/")
-        for i in range(len(comps) - len(ln) + 1):
-            if comps[i:i + len(ln)] == ln:
-                phase = "bwd" if "transpose(" in op_name else "fwd"
-                return lname, phase
-    for c in comps:
-        if COMM_SCOPE_RE.match(c):
-            return c, "misc"
-    for extra in extra_scopes:
-        if extra in comps or extra in joined:
-            return extra, "misc"
-    return None, None
+    lands in named per-axis rows rather than the residual. For many
+    lookups against one set of names, ``hlo_scope_map`` prepares the names
+    once."""
+    return _ScopeIndex(layer_names, extra_scopes).lookup(op_name)
 
 
 _COMP_HDR = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(")
 _INST = re.compile(r"^(ROOT\s+)?%([\w.\-]+)\s*=")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLEE = re.compile(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)")
+_REF = re.compile(r"%([\w.\-]+)")
 
 
 def hlo_scope_map(hlo_text: str, layer_names,
@@ -342,15 +248,52 @@ def hlo_scope_map(hlo_text: str, layer_names,
     call/fusion/while instructions through their callee computations
     (root's scope, else the members' majority) to a fixpoint.
     Instructions that still name no known scope are simply absent — they
-    fall into the residual row."""
-    layer_names = frozenset(layer_names)
-    extra_scopes = frozenset(extra_scopes)
+    fall into the residual row. One pass over the text, the names prepared
+    once (``_ScopeIndex``)."""
+    return _resolve_scopes(hlo_text,
+                           _ScopeIndex(layer_names, extra_scopes))[0]
+
+
+# scopes outside the layer graph that a train step carries (core/arena.py,
+# solvers/updates.py), and the type each reports in ``step_scopes``
+STEP_EXTRA_SCOPES = {"optimizer_update": "update", "arena_pack": "arena",
+                     "arena_unpack": "arena", "arena_views": "arena",
+                     "arena_grads": "arena"}
+
+
+def step_scopes(hlo_text: str, net) -> Dict:
+    """The stats section ``step_scopes`` of one compiled train step:
+    ``ops`` = {instruction: "<scope>|<phase>"} for every instruction that
+    can execute as an operation of its own (fusion bodies are left out: a
+    trace names the fusion), ``types`` = {scope: the layer's TYPE, or
+    "update" / "arena" / "sync" for the scopes around the layer graph},
+    and how many of those ``instructions`` are ``mapped``."""
+    layer_types = {layer.name: layer.TYPE for layer in net.layers}
+    resolved, comp_insts, fusion_bodies = _resolve_scopes(
+        hlo_text, _ScopeIndex(layer_types, STEP_EXTRA_SCOPES))
+    executed = [i for comp, insts in comp_insts.items()
+                if comp not in fusion_bodies for i in insts]
+    mapped = {i: resolved[i] for i in executed if i in resolved}
+    types = {scope: (layer_types.get(scope) or STEP_EXTRA_SCOPES.get(scope)
+                     or "sync")
+             for scope in {scope for scope, _ in mapped.values()}}
+    return {"ops": {i: f"{scope}|{phase}"
+                    for i, (scope, phase) in mapped.items()},
+            "types": types, "instructions": len(executed),
+            "mapped": len(mapped)}
+
+
+def _resolve_scopes(hlo_text: str, index: "_ScopeIndex"):
+    """``(resolved, comp_insts, fusion_bodies)``: {instruction: (scope,
+    phase)}, {computation: [its instructions]} and the names of the
+    computations that are bodies of ``fusion`` instructions."""
     resolved: Dict[str, Tuple[str, str]] = {}
     direct: Dict[str, Tuple[str, str]] = {}   # from own metadata only
     inst_callees: Dict[str, List[str]] = {}
     operand_users: Dict[str, List[str]] = {}  # operand -> [user insts]
     comp_insts: Dict[str, List[str]] = {}
     comp_root: Dict[str, str] = {}
+    fusion_bodies = set()
     comp = None
     for line in hlo_text.splitlines():
         if line and not line[0].isspace():
@@ -365,14 +308,16 @@ def hlo_scope_map(hlo_text: str, layer_names,
         rhs = ls.split("=", 1)[1]
         om = _OP_NAME.search(ls)
         if om and inst not in resolved:
-            scope, phase = scope_of(om.group(1), layer_names, extra_scopes)
+            scope, phase = index.lookup(om.group(1))
             if scope is not None:
                 resolved[inst] = direct[inst] = (scope, phase)
-        callees = [c.group(1) for c in _CALLEE.finditer(ls)]
+        callees = _CALLEE.findall(ls)
         if callees:
             inst_callees.setdefault(inst, []).extend(callees)
-        for ref in re.finditer(r"%([\w.\-]+)", rhs):
-            operand_users.setdefault(ref.group(1), []).append(inst)
+            if " fusion(" in rhs:
+                fusion_bodies.update(callees)
+        for ref in _REF.findall(rhs):
+            operand_users.setdefault(ref, []).append(inst)
         if comp:
             comp_insts.setdefault(comp, []).append(inst)
             if m.group(1):
@@ -463,7 +408,7 @@ def hlo_scope_map(hlo_text: str, layer_names,
                 counts[s] = counts.get(s, 0) + 1
         if counts:
             resolved[inst] = max(counts.items(), key=lambda kv: kv[1])[0]
-    return resolved
+    return resolved, comp_insts, fusion_bodies
 
 
 # --------------------------------------------------------------------------- #

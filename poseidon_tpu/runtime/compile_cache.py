@@ -86,11 +86,14 @@ def enable_compile_cache(aot_steps: bool = True) -> str:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # a Pallas kernel's Mosaic module is serialized WITH its MLIR locations
     # into the custom call, where the cache key's debug-info stripping
-    # cannot reach; with full Python tracebacks in them, every program
+    # cannot reach; with jax's ten traceback frames in them, every program
     # that holds a kernel keys on its caller's stack — the same eval step
     # reached from another line of a driver script missed the cache on
-    # the v5e (PR 21). One frame per location is stable.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # the v5e (PR 21). One frame per location is stable. (Not
+    # jax_include_full_tracebacks_in_locations=False, PR 21's first answer:
+    # it also cuts every instruction's op_name down to the primitive, and
+    # the named scopes the step's layer map is built from are gone.)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     # the cache object memoizes its first initialization: a process that
     # already compiled something (before this call) must reset it or the
     # directory is silently ignored

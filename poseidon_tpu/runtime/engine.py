@@ -42,7 +42,23 @@ from .checkpoint import (AsyncSnapshotWriter, latest_snapshot,
                          load_caffemodel, restore, snapshot, sweep_stale_tmp)
 from .metrics import (AsyncScalarFetcher, MetricsServer, MetricsTable,
                       StatsRegistry, log)
-from .spans import recorder as span_recorder
+from .spans import NULL_SPAN, recorder as span_recorder
+
+# jax's monitoring event around every backend compile (or fetch from the
+# compilation cache); its listeners are told the duration when it ends
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _record_compile(event: str, duration: float, **_) -> None:
+    """A compile as a span on the host timeline, ending now: one inside a
+    measured window is then on the timeline and names its gap."""
+    if event == _COMPILE_EVENT:
+        span_recorder.complete("compile", duration, "runtime")
+
+
+# one listener per process, like the recorder it writes to (a no-op while
+# that is disabled)
+jax.monitoring.register_event_duration_secs_listener(_record_compile)
 
 
 class TrainingDivergedError(RuntimeError):
@@ -109,6 +125,7 @@ class Engine:
         hbm_budget_gb: Optional[float] = None,
         remat: Optional[str] = None,
     ):
+        t_build = time.perf_counter()
         self.sp = sp
         # step-pipeline knobs: explicit args win, else the global policy
         # (config.PipelineConfig; CLI flags land there or here directly)
@@ -157,11 +174,11 @@ class Engine:
         self.rank = jax.process_index()
         self.world = jax.process_count()
         # --- telemetry spine ------------------------------------------- #
-        # --trace_out enables the process-wide span recorder (dispatch /
-        # hard-sync / snapshot / prefetch-stall spans, plus whatever the
-        # async tier records) and dumps a Chrome trace-event JSON at every
-        # display boundary and at exit. (--metrics_port is wired up BELOW,
-        # after the async tier resolves this process's real rank.)
+        # --trace_out enables the process-wide span recorder (the span
+        # names are listed in runtime/spans.py) and dumps a Chrome
+        # trace-event JSON at snapshot boundaries, when train() returns
+        # and at close(). (--metrics_port is wired up BELOW, after the
+        # async tier resolves this process's real rank.)
         self._trace_out: Optional[str] = None
         self._owns_span_recorder = False
         if trace_out:
@@ -465,6 +482,10 @@ class Engine:
         self._snap_writer = AsyncSnapshotWriter() if self.async_snapshot \
             else None
         self._device_feed: Optional[DevicePrefetcher] = None
+        # train batches dequeued so far: the producer threads number the
+        # batches they make from 0 in the same order, so this is the
+        # number the next one was made under (prefetch_wait's ``batch``)
+        self._batches_taken = 0
 
         # fast restart (runtime/compile_cache.py): when a compile-cache
         # dir is configured, the single-step hot path resolves through the
@@ -547,6 +568,7 @@ class Engine:
                 return stats
 
             self._debug_fn = jax.jit(_debug)
+        self.stats.add_time("engine_build", time.perf_counter() - t_build)
 
     # ---------------------------------------------------------------- #
     def _build_pipelines(self, net_param: NetParameter, phase: str,
@@ -651,6 +673,7 @@ class Engine:
             p.close()
         self.train_pipelines, _ = self._build_pipelines(
             self._train_param, "TRAIN", shard=shard)
+        self._batches_taken = 0     # new producers number from 0 again
         self._data_shard = shard
         if self._use_prefetch:
             self._device_feed = DevicePrefetcher(
@@ -711,10 +734,11 @@ class Engine:
         return stack_batches(rows, sharding, lead_shape=lead_shape)
 
     # ---------------------------------------------------------------- #
-    def _dispatch_train_step(self, batch, rng):
+    def _dispatch_train_step(self, batch, rng, it: Optional[int] = None):
         """One single-step dispatch, through the AOT warm-start path when
         configured (resolution is lazy: the store key needs the concrete
-        batch shapes, which exist only once the first batch is drawn)."""
+        batch shapes, which exist only once the first batch is drawn).
+        ``it`` only labels the ``dispatch_execute`` span."""
         first = not self._placement_recorded
         if first:
             # read BEFORE the dispatch: the step donates the batch
@@ -724,18 +748,43 @@ class Engine:
         if self._aot_enabled and self._aot_exec is None \
                 and not self._aot_failed:
             self._resolve_aot_step(batch, rng)
-        if self._aot_exec is not None:
-            # the lowerable's raw signature carries the (empty — AOT is
-            # disabled under HDF5_OUTPUT) dump slot; keep the step()
-            # wrapper's 3-tuple contract
-            out = self._aot_exec(self.params, self.state, batch, rng)
-            if isinstance(out, tuple) and len(out) > 3:
-                out = out[:3]
-        else:
-            out = self.train_step.step(self.params, self.state, batch, rng)
+        if first and self._aot_exec is None:
+            self._publish_step_scopes(
+                None, self.stats.sections.get("compiled_step", {}).get(
+                    "error", "the step runs through jit and there is no "
+                    "executable to read: no compile-cache directory, SSP, "
+                    "an HDF5 dump, iter_size > 1, a sharding plan or a "
+                    "multi-process world"))
+        with span_recorder.span("dispatch_execute", "step",
+                                None if it is None else {"iter": it}):
+            if self._aot_exec is not None:
+                # the lowerable's raw signature carries the (empty — AOT
+                # is disabled under HDF5_OUTPUT) dump slot; keep the
+                # step() wrapper's 3-tuple contract
+                out = self._aot_exec(self.params, self.state, batch, rng)
+                if isinstance(out, tuple) and len(out) > 3:
+                    out = out[:3]
+            else:
+                out = self.train_step.step(self.params, self.state, batch,
+                                           rng)
         if first:
             self._record_placement(sample.shape, batch_devs, out[0])
         return out
+
+    def _publish_step_scopes(self, text: Optional[str],
+                             why: str = "") -> None:
+        """Stats section ``step_scopes``: which layer and pass each
+        instruction of the step that runs belongs to
+        (``attribution.step_scopes``), so that a device trace of this run
+        can be read by layer with no guess from shapes. The two maps stay
+        in ``stats.snapshot()`` and out of the rendered documents. Without
+        an executable's text the section is empty and says ``why``."""
+        from .attribution import step_scopes
+        doc = (step_scopes(text, self.train_net) if text is not None else
+               {"ops": {}, "types": {}, "instructions": 0, "mapped": 0,
+                "why": why[:500]})
+        self.stats.set_section("step_scopes", doc,
+                               snapshot_only=("ops", "types"))
 
     def _record_placement(self, batch_shape, batch_devs,
                           params) -> None:  # static-ok: JIT102
@@ -764,7 +813,13 @@ class Engine:
                                     save_step_executable, step_key,
                                     watch_cache_hits)
         from .hlo_comm import count_gradient_all_reduces
-        t0 = time.perf_counter()
+        # where the seconds go, phase by phase: set-up runs before any
+        # span recorder is on, so it gets timers instead
+        marks = [("load_s", time.perf_counter())]
+
+        def phase(name: str) -> None:
+            marks.append((name, time.perf_counter()))
+
         doc: Dict[str, Any] = {"source": "jit"}
         try:
             cfg = compile_cache_config()
@@ -803,9 +858,12 @@ class Engine:
             if exec_ is None:
                 source = "compiled"
                 low = self.train_step.lowerable or self.train_step.step
+                phase("trace_lower_s")
+                lowered = low.lower(self.params, self.state, batch, rng)
+                phase("compile_s")
                 with watch_cache_hits() as hits:
-                    exec_ = low.lower(self.params, self.state, batch,
-                                      rng).compile()
+                    exec_ = lowered.compile()
+                phase("store_s")
                 if hits:
                     # the XLA cache answered: trace paid, compile skipped;
                     # what it hands back is not re-serialized (see
@@ -827,10 +885,13 @@ class Engine:
             # custom calls the kernel routes promise (0 = interpreted or
             # routed to XLA) and the arena's gradient all-reduces
             doc.update(source=source, stored=stored)
+            phase("text_s")
             text = exec_.as_text()
             doc["pallas_custom_calls"] = text.count(
                 'custom_call_target="tpu_custom_call"')
             doc["gradient_all_reduces"] = count_gradient_all_reduces(text)
+            phase("scope_map_s")
+            self._publish_step_scopes(text)
         except Exception as e:  # noqa: BLE001 — warm start is best-effort
             # never silent: the reason goes to the log with its traceback
             # and into stats.yaml, where chip_smoke.py reads it
@@ -841,7 +902,15 @@ class Engine:
                 + ("using the jit path" if self._aot_failed else
                    "the executable is in use, its text could not be read")
                 + "\n" + traceback.format_exc(), rank=self.rank)
-        doc["seconds"] = round(time.perf_counter() - t0, 3)
+            if self._aot_exec is not None:
+                self._publish_step_scopes(None, doc["error"])
+        phase("")
+        doc["phases"] = dict.fromkeys(
+            ("load_s", "trace_lower_s", "compile_s", "store_s", "text_s",
+             "scope_map_s"), 0.0)
+        for (name, t), (_, t_next) in zip(marks, marks[1:]):
+            doc["phases"][name] = round(doc["phases"][name] + t_next - t, 3)
+        doc["seconds"] = round(marks[-1][1] - marks[0][1], 3)
         self.stats.set_section("compiled_step", doc)
 
     # ---------------------------------------------------------------- #
@@ -1019,185 +1088,210 @@ class Engine:
                     with span_recorder.span("snapshot", "ckpt",
                                             {"iter": it}):
                         self.snapshot_now()
+                    self._dump_span_timeline()
                 if self.profile_steps and it == profile_start:
                     jax.profiler.start_trace(
                         os.path.join(self.output_dir, "profile"))
                     profiling = True
 
-                # how many steps may run before the next host-side boundary
-                # (display flush / debug pre-step / test / snapshot /
-                # profile); a full steps_per_dispatch chunk runs as ONE
-                # compiled dispatch
-                chunk = 1
-                if self._scan_step is not None:
-                    room = max_iter - it
-                    if sp.display:
-                        d = sp.display - (it % sp.display)
-                        room = min(room, d - 1 if self._debug_fn else d)
-                    if sp.test_interval and self.test_nets:
-                        room = min(room, sp.test_interval -
-                                   (it % sp.test_interval))
-                    if sp.snapshot:
-                        room = min(room, sp.snapshot - (it % sp.snapshot))
-                    if self.profile_steps and \
-                            it < profile_start + self.profile_steps:
-                        # single-step dispatches only until the trace window
-                        # closes; afterwards chunking resumes
-                        room = min(room, profile_start - it) \
-                            if it < profile_start else 1
-                    if room >= self.steps_per_dispatch:
-                        chunk = self.steps_per_dispatch
+                # one iteration = one numbered step on the profiler's
+                # timeline (--profile's window and, with the span recorder
+                # on, any trace an outside profiler takes): the spans below
+                # lie inside it, beside the device ops
+                with (jax.profiler.StepTraceAnnotation("train", step_num=it)
+                      if profiling or span_recorder.enabled else NULL_SPAN):
+                    # how many steps may run before the next host-side
+                    # boundary (display flush / debug pre-step / test /
+                    # snapshot / profile); a full steps_per_dispatch chunk
+                    # runs as ONE compiled dispatch
+                    chunk = 1
+                    if self._scan_step is not None:
+                        room = max_iter - it
+                        if sp.display:
+                            d = sp.display - (it % sp.display)
+                            room = min(room, d - 1 if self._debug_fn else d)
+                        if sp.test_interval and self.test_nets:
+                            room = min(room, sp.test_interval -
+                                       (it % sp.test_interval))
+                        if sp.snapshot:
+                            room = min(room, sp.snapshot - (it % sp.snapshot))
+                        if self.profile_steps and \
+                                it < profile_start + self.profile_steps:
+                            # single-step dispatches only until the trace
+                            # window closes; afterwards chunking resumes
+                            room = min(room, profile_start - it) \
+                                if it < profile_start else 1
+                        if room >= self.steps_per_dispatch:
+                            chunk = self.steps_per_dispatch
 
-                if chunk > 1:
-                    t_in = time.perf_counter()
-                    with span_recorder.span("prefetch_wait", "input",
-                                            {"iter": it, "chunk": chunk}):
-                        batch = self._next_batch_stack(
-                            self.train_pipelines, chunk * self.iter_size,
-                            lead_shape=((chunk, self.iter_size)
-                                        if self.iter_size > 1 else None))
-                    self.stats.add_time("input_stall",
-                                        time.perf_counter() - t_in)
-                    t0 = time.time()
-                    # the scan step folds rng by global iteration internally
-                    # (solver.it + offset): pass the session rng unfolded so
-                    # a chunked run's per-step streams match single-step
-                    # dispatch
-                    with span_recorder.span("dispatch", "step",
-                                            {"iter": it, "chunk": chunk}):
-                        self.params, self.state, m = self._scan_step.step(
-                            self.params, self.state, batch, self.rng)
-                    it += chunk
-                    at_display = bool(sp.display) and it % sp.display == 0
-                else:
-                    t_in = time.perf_counter()
-                    with span_recorder.span("prefetch_wait", "input",
-                                            {"iter": it}):
-                        if self.iter_size > 1:
-                            # one optimizer step = iter_size stacked
-                            # micro-batches
+                    if chunk > 1:
+                        t_in = time.perf_counter()
+                        with span_recorder.span(
+                                "prefetch_wait", "input",
+                                {"iter": it, "chunk": chunk,
+                                 "batch": self._batches_taken}):
                             batch = self._next_batch_stack(
-                                self.train_pipelines, self.iter_size,
-                                sharding=self.train_step.batch_sharding)
-                        elif self._device_feed is not None:
-                            # the prefetch stage already placed this batch
-                            # on device with the step's sharding; steady
-                            # state this dequeue is instant and input_stall
-                            # measures any residual starvation
-                            batch = next(self._device_feed)
-                        else:
-                            batch = self._next_batch(self.train_pipelines)
-                    self.stats.add_time("input_stall",
-                                        time.perf_counter() - t_in)
-                    at_display = bool(sp.display) and \
-                        (it + 1) % sp.display == 0
-                    if at_display and self._debug_fn:
-                        # BEFORE the step, on the step's own inputs
-                        # (pre-update params, this iteration's rng/batch) —
-                        # the values Caffe's ForwardDebugInfo/UpdateDebugInfo
-                        # report for iteration it+1. Under iter_size the
-                        # debug pass reads the first micro-batch (one
-                        # representative forward).
-                        dbatch = ({k: v[0] for k, v in batch.items()}
-                                  if self.iter_size > 1 else batch)
-                        stats = self._debug_fn(
-                            self.params, dbatch,
-                            jax.random.fold_in(self.rng, it))
-                        for key in sorted(stats):
-                            kind, name = key.split("\x00")
-                            log(f"    [debug] {kind:<5} {name}: "
-                                f"{float(stats[key]):.6g}", rank=self.rank)
-                    t0 = time.time()
-                    with span_recorder.span("dispatch", "step",
-                                            {"iter": it}):
-                        result = self._dispatch_train_step(
-                            batch, jax.random.fold_in(self.rng, it))
-                    if self._h5_train:
-                        self.params, self.state, m, dumps = result
-                        self._write_train_h5(dumps)
+                                self.train_pipelines, chunk * self.iter_size,
+                                lead_shape=((chunk, self.iter_size)
+                                            if self.iter_size > 1 else None))
+                        self._batches_taken += chunk * self.iter_size
+                        self.stats.add_time("input_stall",
+                                            time.perf_counter() - t_in)
+                        t0 = time.time()
+                        # the scan step folds rng by global iteration
+                        # internally (solver.it + offset): pass the session
+                        # rng unfolded so a chunked run's per-step streams
+                        # match single-step dispatch
+                        with span_recorder.span("dispatch", "step",
+                                                {"iter": it, "chunk": chunk}):
+                            self.params, self.state, m = self._scan_step.step(
+                                self.params, self.state, batch, self.rng)
+                        it += chunk
+                        at_display = bool(sp.display) and it % sp.display == 0
                     else:
-                        self.params, self.state, m = result
-                    it += 1
+                        t_in = time.perf_counter()
+                        # ``batch`` is the number the producer threads made
+                        # this batch under: producer_read -> producer_h2d ->
+                        # prefetch_wait join on it, and on ``iter`` from here
+                        with span_recorder.span(
+                                "prefetch_wait", "input",
+                                {"iter": it, "batch": self._batches_taken}):
+                            if self.iter_size > 1:
+                                # one optimizer step = iter_size stacked
+                                # micro-batches
+                                batch = self._next_batch_stack(
+                                    self.train_pipelines, self.iter_size,
+                                    sharding=self.train_step.batch_sharding)
+                            elif self._device_feed is not None:
+                                # the prefetch stage already placed this
+                                # batch on device with the step's sharding;
+                                # steady state this dequeue is instant and
+                                # input_stall measures residual starvation
+                                batch = next(self._device_feed)
+                            else:
+                                batch = self._next_batch(self.train_pipelines)
+                        self._batches_taken += self.iter_size
+                        self.stats.add_time("input_stall",
+                                            time.perf_counter() - t_in)
+                        at_display = bool(sp.display) and \
+                            (it + 1) % sp.display == 0
+                        if at_display and self._debug_fn:
+                            # BEFORE the step, on the step's own inputs
+                            # (pre-update params, this iteration's
+                            # rng/batch) — the values Caffe's
+                            # ForwardDebugInfo/UpdateDebugInfo report for
+                            # iteration it+1. Under iter_size the debug pass
+                            # reads the first micro-batch (one
+                            # representative forward).
+                            dbatch = ({k: v[0] for k, v in batch.items()}
+                                      if self.iter_size > 1 else batch)
+                            stats = self._debug_fn(
+                                self.params, dbatch,
+                                jax.random.fold_in(self.rng, it))
+                            for key in sorted(stats):
+                                kind, name = key.split("\x00")
+                                log(f"    [debug] {kind:<5} {name}: "
+                                    f"{float(stats[key]):.6g}", rank=self.rank)
+                        t0 = time.time()
+                        with span_recorder.span("dispatch", "step",
+                                                {"iter": it}):
+                            with span_recorder.span("dispatch_rng", "step",
+                                                    {"iter": it}):
+                                step_rng = jax.random.fold_in(self.rng, it)
+                            result = self._dispatch_train_step(
+                                batch, step_rng, it)
+                        if self._h5_train:
+                            self.params, self.state, m, dumps = result
+                            self._write_train_h5(dumps)
+                        else:
+                            self.params, self.state, m = result
+                        it += 1
+                    # metrics stay device arrays on this thread: the
+                    # fetcher's drainer materializes them to host floats
+                    # off-thread, and put() blocks only when max_in_flight
+                    # dispatches are still un-materialized — the bounded
+                    # in-flight dispatch window (the span measures exactly
+                    # the window backpressure wait)
+                    with span_recorder.span("dispatch_window", "step",
+                                            {"iter": it}):
+                        fetcher.put(it - chunk, m)
+                    self._check_divergence(fetcher)
+                    self.stats.add("train_iters", chunk)
+                    self.stats.add_time("train_step", time.time() - t0)
+                    if self._async_tier is not None:
+                        self._async_tier.after_iters(self, chunk)
+
+                    # absorb whatever the drainer finished — no display
+                    # cadence needed to keep the metrics window bounded
+                    last = self._absorb(fetcher.take_drained(), last)
+                    if at_display:  # same boundary: it has incremented since
+                        # hard sync: the displayed window must cover every
+                        # step through `it` (the drainer may lag by the
+                        # in-flight window otherwise)
+                        with span_recorder.span("hard_sync", "sync",
+                                                {"boundary": "display"}):
+                            last = self._absorb(fetcher.sync(), last)
+                        self._check_divergence(fetcher)
+                        row = self.metrics.flush_row(it)
+                        lr = float(learning_rate(sp, jnp.asarray(it - 1)))
+                        extras = ", ".join(
+                            f"{k} = {v:.4f}" for k, v in sorted(row.items())
+                            if k not in ("iter", "time"))
+                        log(f"Iteration {it}, lr = {lr:.6g}, {extras}",
+                            rank=self.rank)
+                        # live telemetry rides the display cadence: gauges
+                        # for the metrics endpoint, plus the atomic
+                        # stats.yaml write (a preempted run keeps it)
+                        self.stats.set_gauge("iteration", it)
+                        self.stats.set_gauge("lr", lr)
+                        for k, v in row.items():
+                            if k not in ("iter", "time"):
+                                self.stats.set_gauge(f"train_{k}",
+                                                     round(v, 6))
+                        with span_recorder.span("telemetry_dump",
+                                                "artifact", {"iter": it}):
+                            self._dump_live_telemetry()
+                        if self._async_tier is not None:
+                            # membership churn rides the display cadence,
+                            # so admissions/evictions are visible without
+                            # log-grepping (comm_stats.membership_counters)
+                            from .comm_stats import (format_comm,
+                                                     format_membership)
+                            log("    [membership] " + format_membership(
+                                self._async_tier.membership_counters()),
+                                rank=self.rank)
+                            # the per-link managed-communication bill rides
+                            # the same cadence: bytes on the wire, deferred
+                            # fraction, measured goodput, cadence backoffs —
+                            # gauges feed stats.yaml + the metrics endpoint
+                            cc = self._async_tier.comm_counters()
+                            if cc:
+                                log("    [comm] " + format_comm(cc),
+                                    rank=self.rank)
+                                for k, v in cc.items():
+                                    self.stats.set_gauge(f"async_comm_{k}",
+                                                         round(float(v), 4))
+                    if sp.test_interval and it % sp.test_interval == 0 and \
+                            self.test_nets:
+                        # test boundary = hard sync point too: never spend
+                        # a full eval sweep on a model a still-draining NaN
+                        # has already poisoned
+                        with span_recorder.span("hard_sync", "sync",
+                                                {"boundary": "test"}):
+                            last = self._absorb(fetcher.sync(), last)
+                        self._check_divergence(fetcher)
+                        for i in range(len(self.test_nets)):
+                            self.test(i)
+                            self.test_metrics[i].flush_row(it)
                 if profiling and it >= profile_start + self.profile_steps:
+                    # after the step's annotation has closed, so the last
+                    # profiled step is whole in the trace
                     jax.block_until_ready(m["loss"])
                     jax.profiler.stop_trace()
                     profiling = False
                     log(f"Wrote profiler trace to "
                         f"{os.path.join(self.output_dir, 'profile')}",
                         rank=self.rank)
-                # metrics stay device arrays on this thread: the fetcher's
-                # drainer materializes them to host floats off-thread, and
-                # put() blocks only when max_in_flight dispatches are still
-                # un-materialized — the bounded in-flight dispatch window
-                # (the span measures exactly the window backpressure wait)
-                with span_recorder.span("dispatch_window", "step",
-                                        {"iter": it}):
-                    fetcher.put(it - chunk, m)
-                self._check_divergence(fetcher)
-                self.stats.add("train_iters", chunk)
-                self.stats.add_time("train_step", time.time() - t0)
-                if self._async_tier is not None:
-                    self._async_tier.after_iters(self, chunk)
-
-                # absorb whatever the drainer finished — no display cadence
-                # needed to keep the metrics window bounded
-                last = self._absorb(fetcher.take_drained(), last)
-                if at_display:  # same boundary: it has incremented since
-                    # hard sync: the displayed window must cover every step
-                    # through `it` (the drainer may lag by the in-flight
-                    # window otherwise)
-                    with span_recorder.span("hard_sync", "sync",
-                                            {"boundary": "display"}):
-                        last = self._absorb(fetcher.sync(), last)
-                    self._check_divergence(fetcher)
-                    row = self.metrics.flush_row(it)
-                    lr = float(learning_rate(sp, jnp.asarray(it - 1)))
-                    extras = ", ".join(
-                        f"{k} = {v:.4f}" for k, v in sorted(row.items())
-                        if k not in ("iter", "time"))
-                    log(f"Iteration {it}, lr = {lr:.6g}, {extras}",
-                        rank=self.rank)
-                    # live telemetry rides the display cadence: gauges for
-                    # the metrics endpoint, plus the atomic stats.yaml /
-                    # span-timeline dump (a preempted run keeps both)
-                    self.stats.set_gauge("iteration", it)
-                    self.stats.set_gauge("lr", lr)
-                    for k, v in row.items():
-                        if k not in ("iter", "time"):
-                            self.stats.set_gauge(f"train_{k}", round(v, 6))
-                    self._dump_live_telemetry()
-                    if self._async_tier is not None:
-                        # membership churn rides the display cadence, so
-                        # admissions/evictions are visible without
-                        # log-grepping (comm_stats.membership_counters)
-                        from .comm_stats import (format_comm,
-                                                 format_membership)
-                        log("    [membership] " + format_membership(
-                            self._async_tier.membership_counters()),
-                            rank=self.rank)
-                        # the per-link managed-communication bill rides
-                        # the same cadence: bytes on the wire, deferred
-                        # fraction, measured goodput, cadence backoffs —
-                        # gauges feed stats.yaml + the metrics endpoint
-                        cc = self._async_tier.comm_counters()
-                        if cc:
-                            log("    [comm] " + format_comm(cc),
-                                rank=self.rank)
-                            for k, v in cc.items():
-                                self.stats.set_gauge(f"async_comm_{k}",
-                                                     round(float(v), 4))
-                if sp.test_interval and it % sp.test_interval == 0 and \
-                        self.test_nets:
-                    # test boundary = hard sync point too: never spend a
-                    # full eval sweep on a model a still-draining NaN has
-                    # already poisoned
-                    with span_recorder.span("hard_sync", "sync",
-                                            {"boundary": "test"}):
-                        last = self._absorb(fetcher.sync(), last)
-                    self._check_divergence(fetcher)
-                    for i in range(len(self.test_nets)):
-                        self.test(i)
-                        self.test_metrics[i].flush_row(it)
 
             # tail iterations past the last display boundary
             with span_recorder.span("hard_sync", "sync",
@@ -1231,6 +1325,9 @@ class Engine:
             self._snap_writer.wait()
         self.stats.add_time("train_total", time.time() - t_start)
         self._write_artifacts()
+        written = self._dump_span_timeline()
+        if written:
+            log(f"Wrote span timeline to {written}", rank=self.rank)
         return last
 
     def _write_train_h5(self, dumps: Dict[str, jax.Array]):
@@ -1284,23 +1381,40 @@ class Engine:
     def _dump_live_telemetry(self):
         """Display-boundary telemetry flush: stats.yaml (atomic tmp +
         rename — a crashed/preempted run keeps everything through its
-        last boundary, rank 0 only) and, under --trace_out, this rank's
-        span timeline. Best-effort: a full disk or NFS blip at a display
-        boundary must never abort a training run that could keep going
-        (the exit-time writers retry the same paths anyway)."""
+        last boundary, rank 0 only). Best-effort: a full disk or NFS blip
+        at a display boundary must never abort a training run that could
+        keep going (the exit-time writer retries the same path anyway).
+        The span timeline is NOT written here: serializing the whole
+        buffer (up to 65,536 events) inside every display interval stalled
+        the loop it records; see ``_dump_span_timeline``."""
+        if self.rank != 0:
+            return
         try:
-            if self.rank == 0:
-                self.stats.dump_yaml(os.path.join(self.output_dir,
-                                                  "stats.yaml"))
-            path = self._trace_out_path()
-            if path is not None:
-                span_recorder.dump(path)
+            self.stats.dump_yaml(os.path.join(self.output_dir, "stats.yaml"))
         except OSError as e:
-            if not getattr(self, "_telemetry_write_warned", False):
-                self._telemetry_write_warned = True
-                log(f"WARNING: telemetry write failed ({e}); training "
-                    f"continues, will retry at the next boundary",
-                    rank=self.rank)
+            self._warn_telemetry_write(e)
+
+    def _dump_span_timeline(self) -> Optional[str]:
+        """Under --trace_out, write this rank's span timeline (atomic):
+        at snapshot boundaries, when train() returns and at close() —
+        where the loop stops anyway — so that a display boundary costs the
+        same however many spans the run has recorded. Best-effort like
+        ``_dump_live_telemetry``; returns the path written."""
+        path = self._trace_out_path()
+        if path is None:
+            return None
+        try:
+            return span_recorder.dump(path)
+        except OSError as e:
+            self._warn_telemetry_write(e)
+            return None
+
+    def _warn_telemetry_write(self, e: OSError) -> None:
+        if not getattr(self, "_telemetry_write_warned", False):
+            self._telemetry_write_warned = True
+            log(f"WARNING: telemetry write failed ({e}); training "
+                f"continues, will retry at the next boundary",
+                rank=self.rank)
 
     def _write_artifacts(self):
         if self.rank != 0:
@@ -1328,14 +1442,6 @@ class Engine:
                 tm.to_csv(os.path.join(self.output_dir,
                                        f"{name}_test{i}_outputs.csv"))
         self.stats.dump_yaml(os.path.join(self.output_dir, "stats.yaml"))
-        if self._trace_out is not None:
-            try:
-                log(f"Wrote span timeline to "
-                    f"{span_recorder.dump(self._trace_out)}",
-                    rank=self.rank)
-            except OSError as e:
-                log(f"WARNING: span timeline write failed: {e}",
-                    rank=self.rank)
 
     def close(self):
         # close EVERYTHING before surfacing any failure: a snapshot-write
@@ -1348,12 +1454,7 @@ class Engine:
             # stand the recorder down (it is process-global; a later
             # engine without --trace_out must not keep paying for spans
             # nobody will dump)
-            path = self._trace_out_path()
-            if path is not None:
-                try:
-                    span_recorder.dump(path)
-                except OSError:
-                    pass
+            self._dump_span_timeline()
             span_recorder.disable()
             self._owns_span_recorder = False
         if self._metrics_server is not None:

@@ -18,6 +18,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .spans import recorder as _spans
+
 
 class MetricsTable:
     def __init__(self, name: str):
@@ -69,6 +71,7 @@ class StatsRegistry:
         self.timers: Dict[str, float] = defaultdict(float)
         self.gauges: Dict[str, float] = {}
         self.sections: Dict[str, dict] = {}
+        self._snapshot_only: Dict[str, frozenset] = {}
         self._lock = threading.Lock()
 
     def add(self, name: str, value: float = 1.0) -> None:
@@ -85,25 +88,36 @@ class StatsRegistry:
         with self._lock:
             self.gauges[name] = value
 
-    def set_section(self, name: str, data: dict) -> None:
+    def set_section(self, name: str, data: dict,
+                    snapshot_only: Tuple[str, ...] = ()) -> None:
+        """``snapshot_only`` names keys of ``data`` that ``snapshot()``
+        hands out and the rendered documents (stats.yaml, the metrics
+        endpoint) leave out: bulk that a program reads and a person does
+        not (the step's instruction -> scope map), kept out of the file
+        that is rewritten at every display boundary."""
         with self._lock:
             self.sections[name] = data
+            self._snapshot_only[name] = frozenset(snapshot_only)
 
-    def snapshot(self) -> Dict[str, dict]:
-        """A consistent copy of everything (one lock hold)."""
+    def snapshot(self, rendered: bool = False) -> Dict[str, dict]:
+        """A consistent copy of everything (one lock hold); with
+        ``rendered``, less the sections' ``snapshot_only`` keys."""
         with self._lock:
+            skip = self._snapshot_only if rendered else {}
             return {"counters": dict(self.counters),
                     "timers_sec": {k: round(v, 6)
                                    for k, v in self.timers.items()},
                     "gauges": dict(self.gauges),
-                    "sections": {k: dict(v)
-                                 for k, v in self.sections.items()}}
+                    "sections": {
+                        name: {k: v for k, v in sec.items()
+                               if k not in skip.get(name, ())}
+                        for name, sec in self.sections.items()}}
 
     def render_text(self) -> str:
         """Flat ``key=value`` lines — what ``--metrics_port`` serves (one
         curl mid-run answers "where is this job"). Sections flatten with
         dotted keys; non-scalar leaves are skipped (the YAML has them)."""
-        snap = self.snapshot()
+        snap = self.snapshot(rendered=True)
         lines = []
 
         def emit(prefix: str, tree: dict) -> None:
@@ -135,7 +149,7 @@ class StatsRegistry:
         by ``dump_yaml`` and the live ``/yaml`` endpoint, so the two can
         never drift."""
         import io
-        snap = self.snapshot()
+        snap = self.snapshot(rendered=True)
         f = io.StringIO()
         f.write("counters:\n")
         for k in sorted(snap["counters"]):
@@ -376,9 +390,14 @@ class AsyncScalarFetcher:
 
     def _ingest(self, first_iter: int, rows) -> None:
         """Append materialized rows + run the divergence watch. Caller
-        holds the lock."""
+        holds the lock. This is the moment a step's metrics are host
+        floats — on the drainer's thread, or on the train thread when the
+        dispatch had already finished — and the ``step_done`` instant
+        marks it on the span timeline."""
         for i, row in enumerate(rows):
             it = first_iter + i
+            if _spans.enabled:
+                _spans.instant("step_done", "step", {"iter": it})
             self._drained.append((it, row))
             if self.divergence is None:
                 for k in self.watch_keys:

@@ -1,13 +1,39 @@
 """Host-side span timeline: the telemetry spine's wall-clock half.
 
-``jax.named_scope`` + the xplane trace (runtime/attribution.py) attribute
-DEVICE time; this module attributes HOST time — where the engine loop, the
-async tier, and the serving path actually block. A span is a context
-manager around one hot-path region (dispatch, hard sync, snapshot write,
-prefetch stall, async push/pull/gate/admit); the recorder buffers them in
-a bounded thread-safe deque and dumps Chrome trace-event JSON
-(``chrome://tracing`` / Perfetto load it directly) — the same artifact
-shape as the device trace, so one viewer shows both.
+``jax.named_scope`` + the compiled step's scope map (runtime/attribution.py,
+published by the Engine as stats section ``step_scopes``) attribute DEVICE
+time; this module attributes HOST time — wherever a thread of the training
+process can hold a step up. A span is a context manager around one such
+region; the recorder buffers them in a bounded thread-safe deque and dumps
+Chrome trace-event JSON (``chrome://tracing`` / Perfetto load it directly).
+
+Span names, by thread (``cat`` in brackets; PERF.md section 3 names the
+reader of each):
+
+- train thread: ``prefetch_wait`` [input], ``dispatch`` with its children
+  ``dispatch_rng`` and ``dispatch_execute``, ``dispatch_window`` [step],
+  ``hard_sync`` [sync], ``snapshot`` [ckpt], ``telemetry_dump`` [artifact];
+- reader thread (``BatchPipeline._worker``): ``producer_read``,
+  ``producer_queue_full`` [input];
+- prefetcher thread (``DevicePrefetcher._worker``; the train thread on the
+  CPU backend's passthrough arm): ``producer_h2d``, ``producer_queue_full``;
+- drainer thread (``AsyncScalarFetcher``): the instant ``step_done`` [step];
+- whichever thread they happen on: ``gc_pause`` (a collector run, through
+  ``gc.callbacks`` while enabled) and ``compile`` (jax's backend-compile
+  event, through the Engine's listener) [runtime];
+- async tier: ``async_push`` / ``async_pull`` / ``async_gate`` /
+  ``async_admit`` / ``async_flush`` [async].
+
+Spans of one step share identifiers: ``batch`` joins ``producer_read`` ->
+``producer_h2d`` -> ``prefetch_wait``, ``iter`` joins ``prefetch_wait`` ->
+``dispatch`` -> ``step_done``.
+
+One clock: while the recorder is enabled and jax is already imported, every
+span also enters ``jax.profiler.TraceAnnotation(name, **args)``, so under
+the profiler the same spans lie in the xplane's host plane, per thread,
+beside the device ops (``Engine.train`` numbers its iterations there with
+``StepTraceAnnotation``). With no profiler running the annotation is a
+~0.5 us no-op.
 
 Overhead discipline: the recorder ships DISABLED. ``span()`` on a
 disabled recorder returns a shared no-op context manager — one attribute
@@ -20,14 +46,16 @@ import.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
 
-__all__ = ["SpanRecorder", "recorder", "span", "enabled"]
+__all__ = ["SpanRecorder", "recorder", "span", "enabled", "NULL_SPAN"]
 
 
 class _NullSpan:
@@ -42,11 +70,23 @@ class _NullSpan:
         return False
 
 
-_NULL = _NullSpan()
+_NULL = NULL_SPAN = _NullSpan()
+
+
+def _annotation(name: str, args):
+    """``jax.profiler.TraceAnnotation(name, **args)``, entered — or None in
+    a process that has not imported jax (nothing here ever imports it)."""
+    cls = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                  "TraceAnnotation", None)
+    if cls is None:
+        return None
+    ann = cls(name, **args) if args else cls(name)
+    ann.__enter__()
+    return ann
 
 
 class _Span:
-    __slots__ = ("_rec", "name", "cat", "args", "_t0")
+    __slots__ = ("_rec", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, rec: "SpanRecorder", name: str, cat: str, args):
         self._rec = rec
@@ -55,13 +95,16 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        rec = self._rec
-        rec._record(self.name, self.cat, self._t0, t1 - self._t0, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._rec._record(self.name, self.cat, self._t0, t1 - self._t0,
+                          self.args)
         return False
 
 
@@ -77,7 +120,11 @@ class SpanRecorder:
     def __init__(self, maxlen: int = 65536):
         self.enabled = False
         self._events: deque = deque(maxlen=maxlen)
-        self._lock = threading.Lock()
+        # re-entrant: a collector run can start between any two bytecodes,
+        # this module's own included, and its gc_pause span is recorded on
+        # the thread it interrupted
+        self._lock = threading.RLock()
+        self._gc_span: Optional[_Span] = None
         self._t0 = time.perf_counter()
         self._epoch_us = time.time() * 1e6 - self._t0 * 1e6
         self.dropped = 0          # spans recorded past maxlen (overwrote)
@@ -85,9 +132,27 @@ class SpanRecorder:
     # ---- lifecycle ---------------------------------------------------- #
     def enable(self) -> None:
         self.enabled = True
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
 
     def disable(self) -> None:
         self.enabled = False
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        self._gc_span = None
+
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        """``gc.callbacks`` hook: one ``gc_pause`` span per collector run,
+        on the thread the run interrupted. Runs never nest, so one slot
+        holds the open span."""
+        if phase == "start":
+            self._gc_span = _Span(self, "gc_pause", "runtime",
+                                  {"generation": info["generation"]})
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            span, self._gc_span = self._gc_span, None
+            span.args["collected"] = info["collected"]
+            span.__exit__(None, None, None)
 
     def clear(self) -> None:
         with self._lock:
@@ -107,7 +172,18 @@ class SpanRecorder:
         """Zero-duration marker (Chrome trace 'i' events)."""
         if not self.enabled:
             return
+        ann = _annotation(name, args)
+        if ann is not None:
+            ann.__exit__(None, None, None)
         self._record(name, cat, time.perf_counter(), None, args)
+
+    def complete(self, name: str, dur_s: float, cat: str = "engine",
+                 args: Optional[Dict] = None) -> None:
+        """A span that ended now and lasted ``dur_s``, for regions only
+        their end reports (jax's compile-duration event)."""
+        if not self.enabled:
+            return
+        self._record(name, cat, time.perf_counter() - dur_s, dur_s, args)
 
     def _record(self, name, cat, t0, dur_s, args) -> None:
         ev = (name, cat, t0, dur_s, threading.get_ident(), args)
