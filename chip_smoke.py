@@ -151,8 +151,9 @@ def open_device(tiny: bool) -> dict:
         raise SystemExit(2)
     if tiny:
         check(dev.platform == "cpu", "--cpu-tiny is the CPU rehearsal")
-        # the rehearsal takes the Pallas arms too, through the interpreter
-        os.environ["POSEIDON_POOL_BWD"] = "pallas"
+        # the rehearsal takes the TPU's arms too: select-and-scatter pool
+        # backward, the Pallas LRN kernels through the interpreter
+        os.environ["POSEIDON_POOL_BWD"] = "sas"
         os.environ["POSEIDON_PALLAS_LRN"] = "1"
     else:
         check(staged, "async collective flags were not staged")
@@ -212,19 +213,21 @@ def check_run(name: str, run: dict, size: Size, device: dict, *,
     check(stats["device"]["platform"] == device["platform"]
           and int(stats["device"]["count"]) == n_dev,
           f"{name}: engine ran on {stats['device']}")
-    # nothing fell off the device path: every pool backward and LRN took
-    # its Pallas arm, and the step that ran holds exactly those kernels
-    # (a pool backward is one custom call, an LRN forward + backward two)
+    # nothing fell off the device path: every pool backward took XLA's
+    # select-and-scatter and every LRN its Pallas arm, and the step that
+    # ran holds exactly those kernels (an LRN forward + backward is two
+    # custom calls, a pool backward none)
     routes = stats["kernel_routes"]
-    check(sorted(routes) == ["norm1", "norm2", "pool1", "pool2", "pool5"]
-          and all(v.endswith("=pallas") for v in routes.values()),
+    check(routes == {"norm1": "lrn=pallas", "norm2": "lrn=pallas",
+                     "pool1": "pool_bwd=sas", "pool2": "pool_bwd=sas",
+                     "pool5": "pool_bwd=sas"},
           f"{name}: kernel routes {routes}")
     step = stats["compiled_step"]
     check("error" not in step and "pallas_custom_calls" in step,
           f"{name}: the engine could not resolve its step executable and "
           f"fell back: {step} (the log above has the traceback)")
-    expect = 0 if size.tiny else sum(
-        2 if v.startswith("lrn") else 1 for v in routes.values())
+    expect = 0 if size.tiny else 2 * sum(
+        v == "lrn=pallas" for v in routes.values())
     check(int(step["pallas_custom_calls"]) == expect,
           f"{name}: compiled step holds {step['pallas_custom_calls']} "
           f"Pallas custom calls, routing promises {expect}")
@@ -269,9 +272,11 @@ def steady_ms_per_step(rows: list, after_iter: int) -> float:
 
 
 def check_kernels(size: Size) -> dict:
-    """Each Pallas kernel on the train path against its XLA arm, on this
-    device, compiled (interpret only under --cpu-tiny), at AlexNet's
-    geometry, to tests/test_kernels.py's tolerances."""
+    """Each routed kernel on the train path against its other arm, on this
+    device, at AlexNet's geometry, to tests/test_kernels.py's tolerances:
+    the Pallas LRN (compiled; interpreted only under --cpu-tiny) against
+    the XLA formulation, the pool backward's select-and-scatter against
+    the tap-sum."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -319,15 +324,29 @@ def check_kernels(size: Size) -> dict:
         for name, (n, c, h) in size.pool:
             x = jnp.asarray(rs.randn(n, c, h, h), dtype)
             grads = {}
-            for arm, run in (("pallas", compiled_kernel),
-                             ("sas", lambda fn, x_: jax.jit(fn)(x_))):
+            for arm in ("sas", "taps"):
                 # the arm is read at trace time: a fresh function per arm
                 os.environ["POSEIDON_POOL_BWD"] = arm
-                grads[arm] = run(jax.grad(lambda x_: jnp.sum(
+                grads[arm] = jax.jit(jax.grad(lambda x_: jnp.sum(
                     NN.max_pool(x_, (3, 3), (2, 2), (0, 0), "NCHW").astype(
-                        jnp.float32) ** 2)), x)
-            close(grads["pallas"], grads["sas"], "pool_bwd", dtype,
-                  f"pool_bwd_plane max {name} {dtype}")
+                        jnp.float32) ** 2)))(x)
+            close(grads["sas"], grads["taps"], "pool_bwd", dtype,
+                  f"pool_bwd sas vs taps max {name} {dtype}")
+    # bf16: the four windows over input (2, 2) send it 256 + 1 + 1 + 1; an
+    # f32 sum rounds once, to 260, a bf16 accumulator stays at 256 (which
+    # is what the TPU's select-and-scatter does once it has a bf16 result)
+    os.environ["POSEIDON_POOL_BWD"] = "sas"
+    x = jnp.zeros((1, 1, 5, 5), jnp.bfloat16).at[0, 0, 2, 2].set(1)
+    g = jnp.asarray([[[[256, 1], [1, 1]]]], jnp.bfloat16)
+    for method, pool, scale in (("max", NN.max_pool, 1),
+                                ("ave", NN.ave_pool, 9)):    # 9: AVE's / 9
+        _, vjp = jax.vjp(jax.jit(lambda x_: pool(
+            x_, (3, 3), (2, 2), (0, 0), "NCHW")), x)
+        dx = jax.jit(vjp)(g * scale)[0]
+        got = facts[f"pool_bwd sas {method} bf16 overlap sum"] = float(
+            dx[0, 0, 2, 2])
+        check(got == 260.0, f"{method} pool backward summed its overlaps "
+                            f"in bf16: {dx[0, 0]}")
     if forced_arm is None:
         del os.environ["POSEIDON_POOL_BWD"]
     else:

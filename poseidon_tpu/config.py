@@ -283,8 +283,14 @@ class PipelineConfig:
     device_prefetch: int = 2
     # dispatches in flight before the loop blocks on the oldest one's
     # metrics (runtime/metrics.AsyncScalarFetcher window); 1 = the serial
-    # loop. NaN detection lags by at most this many steps.
-    max_in_flight: int = 2
+    # loop. NaN detection lags by at most this many steps. The depth is
+    # what the device runs on while the HOST is late: at 2 (the default
+    # until PR 24) one queued step of 51-68 ms was all the cover, and host
+    # stalls of 100-140 ms — a few per hundred seconds on a shared host,
+    # none of them ours to remove — each cost 45-75 ms of idle chip, more
+    # than the benchmark's bound on a whole 30 s run. At 4 the device has
+    # three steps queued when the host falls behind.
+    max_in_flight: int = 4
     # serialize mid-train snapshots on a background thread, from a host
     # copy taken at the sync point (runtime/checkpoint.AsyncSnapshotWriter)
     async_snapshot: bool = False

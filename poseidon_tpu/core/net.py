@@ -377,9 +377,9 @@ class Net:
 
     def _plan_kernel_routes(self) -> None:
         """Which arm each pooling backward (TRAIN nets) and cross-channel
-        LRN lowers to — Pallas kernel or an XLA formulation — from the
-        SAME functions the ops consult at trace time, logged once per
-        layer. The routing is by platform and shape, which is legitimate;
+        LRN lowers to — which XLA formulation, or the Pallas LRN kernels —
+        from the SAME functions the ops consult at trace time, logged once
+        per layer. The routing is by platform and shape, which is legitimate;
         what is not is a run that cannot say which arm it took."""
         from ..ops.pallas_kernels import lrn_route
         from ..runtime.metrics import log
@@ -390,9 +390,7 @@ class Net:
             if layer.TYPE == "POOLING" and self.phase == "TRAIN" \
                     and layer.method in ("MAX", "AVE"):
                 what = "pool_bwd"
-                arm, note = NN.pool_bwd_route(shape[2], shape[3],
-                                              layer.kernel, layer.stride,
-                                              layer.pad)
+                arm, note = NN.pool_bwd_route(layer.kernel)
             elif layer.TYPE == "LRN" and layer.region == "ACROSS_CHANNELS":
                 what = "lrn"
                 arm, note = lrn_route(shape[2] * shape[3], shape[1])

@@ -14,7 +14,8 @@ import ctypes
 import os
 import subprocess
 import threading
-from typing import Optional, Tuple
+import weakref
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -142,6 +143,45 @@ def snappy_uncompress(buf: bytes) -> Optional[bytes]:
     return bytes(out)
 
 
+class _BatchBuffers:
+    """Recycled output memory for one batcher's batches.
+
+    A batch of 512 AlexNet crops is 316 MB of float32. Taken fresh from
+    the allocator every batch it arrives as untouched pages, and the
+    kernel's page faults (and the unmap of the batch before) cost several
+    times what decoding the records does, on whatever number of threads:
+    on the v5e's host 170 ms a batch fresh against 15 ms into memory that
+    has been written before (PR 24, PERF.md). So a buffer goes back on the
+    free list when the LAST reference to the array handed out — or to any
+    view of it — is dropped, and never earlier: a consumer that keeps a
+    batch keeps its memory, and ``jax.device_put`` keeps its reference
+    until the transfer has landed. The owner of the memory is a
+    ``bytearray``, not an ndarray, so that numpy chains every view's
+    ``base`` to the one array the finalizer watches."""
+
+    KEEP = 8    # free buffers kept; beyond it memory returns to the allocator
+
+    def __init__(self):
+        self._free: List[bytearray] = []
+
+    def _give_back(self, raw: bytearray) -> None:
+        if len(self._free) < self.KEEP:
+            self._free.append(raw)
+
+    def take(self, shape: Tuple[int, ...], dtype) -> np.ndarray:
+        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+        raw = None
+        while self._free and raw is None:
+            raw = self._free.pop()
+            if len(raw) != nbytes:      # another batch size: let it go
+                raw = None
+        if raw is None:
+            raw = bytearray(nbytes)
+        flat = np.frombuffer(raw, dtype)
+        weakref.finalize(flat, self._give_back, raw).atexit = False
+        return flat.reshape(shape)
+
+
 class NativeLMDBBatcher:
     def __init__(self, path: str, *, crop_size: int = 0, mirror: bool = False,
                  train: bool = True, scale: float = 1.0,
@@ -167,6 +207,7 @@ class NativeLMDBBatcher:
         self.record_shape = (c.value, h.value, w.value)
         self.n = int(lib.pdp_count(self._h))
         self.n_threads = n_threads or min(8, os.cpu_count() or 1)
+        self._buffers = _BatchBuffers()
 
         if crop_size and (crop_size > self.record_shape[1]
                           or crop_size > self.record_shape[2]):
@@ -211,7 +252,7 @@ class NativeLMDBBatcher:
         on float_data-backed records (rc=-4): callers fall back to f32."""
         idx = np.ascontiguousarray(indices, np.int64)
         n = len(idx)
-        data = np.empty((n,) + self.out_shape, np.uint8)
+        data = self._buffers.take((n,) + self.out_shape, np.uint8)
         labels = np.empty((n,), np.int32)
         rc = self._lib.pdp_batch_u8(
             self._h, idx.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), n,
@@ -234,7 +275,7 @@ class NativeLMDBBatcher:
               seed: int = 0) -> Tuple[np.ndarray, np.ndarray]:
         idx = np.ascontiguousarray(indices, np.int64)
         n = len(idx)
-        data = np.empty((n,) + self.out_shape, np.float32)
+        data = self._buffers.take((n,) + self.out_shape, np.float32)
         labels = np.empty((n,), np.int32)
         rc = self._lib.pdp_batch(
             self._h, idx.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), n,

@@ -353,8 +353,8 @@ def _window_reduce(x, kernel, stride, pad, oh, ow, fill, combine,
     thunk runtime runs as one thunk per window and PR-7's attribution
     bills as the #1 AlexNet self-time sink — so ``max_pool``/``ave_pool``
     below carry a custom VJP that never differentiates through this op
-    (strategies: Pallas plane kernel on TPU, vectorized tap-sum on CPU,
-    select-and-scatter kept as the reference arm).
+    (``pool_bwd_route``: the vectorized tap-sum there, select-and-scatter
+    in f32 when lowering for the TPU, where it is the fast arm).
 
     ``layout`` selects which axes are spatial: (2, 3) for NCHW, (1, 2) for
     NHWC — the op is layout-native either way (no transposes)."""
@@ -378,8 +378,8 @@ def _window_reduce(x, kernel, stride, pad, oh, ow, fill, combine,
 
 def _max_pool_ref(x, kernel, stride, pad, layout: str = "NCHW"):
     """The reduce_window formulation (select-and-scatter backward under
-    plain autodiff) — the forward everywhere, and the reference backward
-    arm the kernel strategies are pinned against."""
+    plain autodiff) — the forward everywhere, the backward when lowering
+    for the TPU, and the reference the tap-sum is pinned against."""
     h, w, oh, ow = _pool_dims(x, kernel, stride, pad, layout)
     return _window_reduce(x, kernel, stride, pad, oh, ow,
                           -jnp.inf, jnp.maximum, layout)
@@ -411,46 +411,42 @@ def _ave_pool_ref(x, kernel, stride, pad, layout: str = "NCHW"):
 
 
 # ---- pooling backward strategies ------------------------------------------ #
+#
+# Two formulations, one per backend, each measured where it runs. On the
+# v5e the step keeps activations channel-minor (`{1,0,3,2}`: C on the lanes,
+# N on the sublanes, H and W major), where XLA's own select-and-scatter
+# costs 8.65 ms of an AlexNet step of 68.9 and 9.91 ms of a GoogLeNet step
+# of 50.6 (bf16, 512 / 128 images; PERF.md, PR 24), against 42.2 / 42.5 ms
+# for the tap-sum, whose k*k interior pads do not fuse there. A custom call
+# with a row-major operand layout in this place costs far more than its own
+# time: every neighbour is relaid out around it. On the CPU the thunk
+# runtime runs select-and-scatter as one thunk per window (PR 7's #1
+# AlexNet sink), and the vectorized tap-sum wins.
 
-# above this many window taps the unrolled tap-sum/kernel loops stop making
-# sense (a global pool is one window: its backward is a broadcast, which is
+# above this many window taps the unrolled tap-sum loop stops making sense
+# (a global pool is one window: its backward is a broadcast, which is
 # exactly what select-and-scatter degenerates to) — route to the reference
 POOL_TAPS_CAP = 64
 
 
-def _pool_bwd_strategy(kernel) -> str:
-    """'pallas' | 'taps' | 'sas' (select-and-scatter via plain autodiff).
-    Defaults: the Pallas plane kernel on TPU (no chip wall clock against
-    select-and-scatter yet — ROADMAP S6), the vectorized tap-sum on the
-    CPU mesh (one strided-slice/pad-and-add pair per window tap — what
-    removes the per-window thunk chain from the CPU attribution table).
-    ``POSEIDON_POOL_BWD`` forces an arm for A/B."""
+def pool_bwd_route(kernel):
+    """``(arm, note)`` for one pooling layer — THE routing decision:
+    ``_pool_bwd`` takes it at trace time and ``Net`` logs it per layer at
+    construction. ``'sas'`` (select-and-scatter: plain autodiff through
+    ``reduce_window``, in f32) when lowering for the TPU and for windows
+    above ``POOL_TAPS_CAP``; ``'taps'`` (one strided slice and one
+    pad-and-add per window tap) on the CPU mesh. The rule has no shape in
+    it: on the chip sas is 2.7-21x ahead at every geometry of both
+    benchmark configurations (3x3 s2 on 13..112, 3x3 s1 p1 on 7..28, AVE
+    5x5 s3 on 14; PERF.md, PR 24). ``POSEIDON_POOL_BWD`` forces an arm."""
     import os
     env = os.environ.get("POSEIDON_POOL_BWD", "")
-    if env in ("pallas", "taps", "sas"):
-        return env
+    if env in ("taps", "sas"):
+        return env, f"POSEIDON_POOL_BWD={env}"
     if kernel[0] * kernel[1] > POOL_TAPS_CAP:
-        return "sas"
+        return "sas", f"window above {POOL_TAPS_CAP} taps"
     from .pallas_kernels import _interpret_default
-    return "taps" if _interpret_default() else "pallas"
-
-
-def pool_bwd_route(h: int, w: int, kernel, stride, pad):
-    """``(arm, note)`` for one pooling layer geometry — THE routing
-    decision: ``_pool_bwd`` takes it at trace time and ``Net`` logs it per
-    layer at construction. ``_pool_bwd_strategy``'s arm, except that a
-    plane the Pallas kernel cannot hold in VMEM takes the tap-sum."""
-    arm = _pool_bwd_strategy(kernel)
-    if arm == "pallas":
-        from .pallas_kernels import pool_plane_feasible
-        oh = pool_out_size(h, kernel[0], stride[0], pad[0])
-        ow = pool_out_size(w, kernel[1], stride[1], pad[1])
-        ph = stride[0] * (oh - 1) + kernel[0]
-        pw = stride[1] * (ow - 1) + kernel[1]
-        if not pool_plane_feasible(ph, pw, oh, ow, kernel):
-            return "taps", (f"{ph}x{pw} plane exceeds the Pallas kernel's "
-                            f"VMEM budget")
-    return arm, ""
+    return ("taps", "cpu backend") if _interpret_default() else ("sas", "")
 
 
 def _pool_flat_ids(shape, ah, aw, pw, stride, dh, dw):
@@ -528,58 +524,44 @@ def _pool_unpad(dxp, x_shape, pad, layout: str):
 
 
 def _pool_bwd(x, g, kernel, stride, pad, layout: str, method: str):
-    """Route one pooling backward through the chosen strategy."""
+    """One pooling backward through ``pool_bwd_route``'s arm. Either way
+    overlapping windows' contributions are summed in f32 and cast once to
+    ``x.dtype``."""
+    if pool_bwd_route(kernel)[0] == "sas":
+        ref = _max_pool_ref if method == "max" else _ave_pool_ref
+        _, vjp = jax.vjp(lambda x_: ref(x_, kernel, stride, pad, layout),
+                         x.astype(jnp.float32))
+        dx = vjp(g.astype(jnp.float32))[0]
+        if x.dtype != jnp.float32:
+            # round here, so that the cast below is exact and stays out of
+            # the scatter: the TPU compiler folds a bare cast into it, and
+            # a select-and-scatter with a bf16 result accumulates in bf16
+            # (256 + 1 + 1 + 1 = 256 on the v5e; chip_smoke.py checks 260)
+            fi = jnp.finfo(x.dtype)
+            dx = lax.reduce_precision(dx, fi.nexp, fi.nmant)
+        return dx.astype(x.dtype)
+
     ah, aw = spatial_axes(layout)
     h, w, oh, ow = _pool_dims(x, kernel, stride, pad, layout)
     ph = stride[0] * (oh - 1) + kernel[0]
     pw = stride[1] * (ow - 1) + kernel[1]
-    strategy, _ = pool_bwd_route(h, w, kernel, stride, pad)
-    if strategy == "sas":
-        ref = _max_pool_ref if method == "max" else _ave_pool_ref
-        _, vjp = jax.vjp(lambda x_: ref(x_, kernel, stride, pad, layout), x)
-        return vjp(g)[0]
-
     gf = g.astype(jnp.float32)
     if method == "ave":
         denom = _ave_denom(h, w, oh, ow, kernel, stride, pad, layout)
         gf = gf / jnp.asarray(denom, jnp.float32)
-        xp = None
+
+        def contrib_of(dh, dw):
+            return gf
     else:
         xp = _pool_pad_crop(x, kernel, stride, pad, oh, ow, -jnp.inf,
                             layout)
+        arg = _pool_max_args(xp, g.shape, kernel, stride, layout)
 
-    if strategy == "pallas":
-        from .pallas_kernels import pool_bwd_plane
-        to_nchw = layout == "NHWC"
-        xpk = None
-        if method == "max":
-            # finite fill: the kernel's selection MATMULS would turn an
-            # -inf pad into 0 * -inf = NaN; finfo.min loses every
-            # comparison against real data, and a degenerate all-pad
-            # window routes its cotangent to a pad position that
-            # _pool_unpad drops — same zero gradient as the -inf arm
-            xpk = _pool_pad_crop(x.astype(jnp.float32), kernel, stride,
-                                 pad, oh, ow,
-                                 float(np.finfo(np.float32).min), layout)
-            if to_nchw:
-                xpk = nhwc_to_nchw(xpk)
-        gk = nhwc_to_nchw(gf) if to_nchw else gf
-        dxp = pool_bwd_plane(xpk, gk, kernel, stride, method)
-        if to_nchw:
-            dxp = nchw_to_nhwc(dxp)
-    else:                                   # taps
-        if method == "max":
-            arg = _pool_max_args(xp, g.shape, kernel, stride, layout)
-            pw_ = xp.shape[aw]
-
-            def contrib_of(dh, dw):
-                flat = _pool_flat_ids(g.shape, ah, aw, pw_, stride, dh, dw)
-                return jnp.where(arg == flat, gf, 0.0)
-        else:
-            def contrib_of(dh, dw):
-                return gf
-        dxp = _pool_scatter_taps(contrib_of, g.shape, ph, pw, kernel,
-                                 stride, layout)
+        def contrib_of(dh, dw):
+            flat = _pool_flat_ids(g.shape, ah, aw, pw, stride, dh, dw)
+            return jnp.where(arg == flat, gf, 0.0)
+    dxp = _pool_scatter_taps(contrib_of, g.shape, ph, pw, kernel, stride,
+                             layout)
     return _pool_unpad(dxp, x.shape, pad, layout).astype(x.dtype)
 
 

@@ -13,12 +13,6 @@ dq and dk/dv kernels below recompute scores blockwise from the saved
 path on TPU (``lrn_route``; ``POSEIDON_PALLAS_LRN=0`` opts back out) with
 the XLA formulation on the CPU test mesh and beyond the VMEM tiling cap.
 
-``pool_bwd_plane``: max/ave pooling backward for one (n, c) spatial plane
-per program — the custom-VJP replacement for the select-and-scatter /
-per-window-thunk chain the PR-7 attribution table bills as the #1 AlexNet
-self-time sink. Window gather/scatter is spelled as exact 0/1
-selection-matrix matmuls (MXU-friendly; Mosaic has no strided scatter).
-
 Kernels run in interpret mode on the CPU test mesh so it exercises the same
 code path; any backend other than tpu/cpu is refused (``_interpret_default``).
 """
@@ -725,145 +719,6 @@ _lrn_fused_cvjp.defvjp(_lrn_fused_vjp_fwd, _lrn_fused_vjp_bwd)
 
 
 # --------------------------------------------------------------------------- #
-# Fused pooling backward
-# --------------------------------------------------------------------------- #
-#
-# The PR-7 attribution table bills pooling BACKWARD as the #1 self-time sink
-# on AlexNet: XLA lowers reduce_window's max-backward to select-and-scatter,
-# which the CPU thunk runtime executes as one thunk PER WINDOW and the TPU
-# as a serial scatter loop. These kernels compute the whole backward for one
-# (n, c) spatial plane in a single VMEM pass:
-#
-#   max: recompute each window's max and FIRST-wins argmax (Caffe's
-#        `>`-update rule, pooling_layer.cpp) from the padded input, then
-#        route each window's cotangent to its argmax position;
-#   ave: route each window's divisor-scaled cotangent to every position it
-#        covers (the divisor is static per output position, applied by the
-#        caller).
-#
-# Window gather/scatter is expressed as exact 0/1 selection-matrix matmuls
-# (each row selects exactly one element, so f32 products/sums are exact and
-# run on the MXU) — Mosaic has no strided slice/scatter, and interior-padded
-# lax.pad does not lower, so matmuls are the portable spelling. Grid is
-# (N, C): pooling planes are small (AlexNet pool1: 55x55), so whole-plane
-# blocks are always layout-legal (minor dims are full array dims).
-
-_POOL_VMEM_BUDGET = 8 * 2 ** 20   # conservative per-program VMEM budget
-
-
-def pool_plane_feasible(ph: int, pw: int, oh: int, ow: int,
-                        kernel: tuple) -> bool:
-    """Whether the per-plane pool-backward kernel is VMEM-legal: the padded
-    plane, the output plane and the selection matrices (plus a few temps)
-    must fit the scoped budget, and the k x k tap loop must stay a sane
-    unroll (the SAME cap the routing uses — nn.POOL_TAPS_CAP — so a raised
-    cap never strands force-routed kernels on a silent fallback)."""
-    from .nn import POOL_TAPS_CAP
-    if kernel[0] * kernel[1] > POOL_TAPS_CAP:
-        return False
-    temps = (4 * ph * pw + 8 * oh * ow + 2 * (oh * ph + ow * pw)) * 4
-    return temps <= _POOL_VMEM_BUDGET
-
-
-def _sel_mat(n_out: int, n_in: int, off: int, stride: int):
-    """(n_out, n_in) 0/1 selection: row o picks column o*stride + off.
-    Exactly one 1 per row, so selection matmuls are exact in f32."""
-    r = lax.broadcasted_iota(jnp.int32, (n_out, n_in), 0)
-    c = lax.broadcasted_iota(jnp.int32, (n_out, n_in), 1)
-    return (c == r * stride + off).astype(jnp.float32)
-
-
-def _pool_bwd_kernel(*refs, kernel: tuple, stride: tuple, oh: int, ow: int,
-                     ph: int, pw: int, method: str):
-    """One (n, c) plane of the pooling backward. ``method`` 'max' takes
-    (x_ref, g_ref, o_ref) with x the PADDED plane; 'ave' takes
-    (g_ref, o_ref) with g already divisor-scaled."""
-    kh, kw = kernel
-    s0, s1 = stride
-    hi = lax.Precision.HIGHEST      # selection matmuls must stay exact
-    if method == "max":
-        x_ref, g_ref, o_ref = refs
-        x = x_ref[0, 0].astype(jnp.float32)          # (PH, PW)
-    else:
-        g_ref, o_ref = refs
-        x = None
-    g = g_ref[0, 0].astype(jnp.float32)              # (OH, OW)
-    ioh = lax.broadcasted_iota(jnp.int32, (oh, ow), 0)
-    iow = lax.broadcasted_iota(jnp.int32, (oh, ow), 1)
-
-    arg = None
-    if method == "max":
-        # first-max-wins argmax over the window, vectorized over all
-        # windows: row-major tap order + strict > keeps the FIRST max
-        # (-inf init, so even an all-pad finfo.min window picks ITS first
-        # tap — whose gradient the caller's un-pad then drops)
-        mx = jnp.full((oh, ow), -jnp.inf, jnp.float32)
-        arg = jnp.zeros((oh, ow), jnp.int32)
-        for dh in range(kh):
-            rows = jnp.dot(_sel_mat(oh, ph, dh, s0), x,
-                           preferred_element_type=jnp.float32, precision=hi)
-            for dw in range(kw):
-                v = jnp.dot(rows, _sel_mat(ow, pw, dw, s1).T,
-                            preferred_element_type=jnp.float32, precision=hi)
-                flat = (ioh * s0 + dh) * pw + (iow * s1 + dw)
-                better = v > mx
-                mx = jnp.where(better, v, mx)
-                arg = jnp.where(better, flat, arg)
-
-    dx = jnp.zeros((ph, pw), jnp.float32)
-    for dh in range(kh):
-        acc = jnp.zeros((oh, pw), jnp.float32)
-        for dw in range(kw):
-            if method == "max":
-                flat = (ioh * s0 + dh) * pw + (iow * s1 + dw)
-                contrib = jnp.where(arg == flat, g, 0.0)
-            else:
-                contrib = g
-            acc = acc + jnp.dot(contrib, _sel_mat(ow, pw, dw, s1),
-                                preferred_element_type=jnp.float32,
-                                precision=hi)
-        dx = dx + jnp.dot(_sel_mat(oh, ph, dh, s0).T, acc,
-                          preferred_element_type=jnp.float32, precision=hi)
-    o_ref[0, 0] = dx.astype(o_ref.dtype)
-
-
-def pool_bwd_plane(xp: Optional[jax.Array], g: jax.Array, kernel: tuple,
-                   stride: tuple, method: str,
-                   interpret: Optional[bool] = None) -> jax.Array:
-    """Pooling backward over NCHW planes. ``xp`` is the Caffe-padded and
-    cropped input (N, C, PH, PW) — required for 'max', ignored for 'ave';
-    ``g`` is the cotangent (N, C, OH, OW), divisor-scaled by the caller for
-    'ave'. Returns d(xp): the gradient on the PADDED extent (the caller
-    slices the pad off). Callers must check :func:`pool_plane_feasible`."""
-    if interpret is None:
-        interpret = _interpret_default()
-    n, c, oh, ow = g.shape
-    if method == "max":
-        ph, pw = xp.shape[2], xp.shape[3]
-    else:
-        ph = stride[0] * (oh - 1) + kernel[0]
-        pw = stride[1] * (ow - 1) + kernel[1]
-    gspec = pl.BlockSpec((1, 1, oh, ow), lambda i, j: (i, j, 0, 0),
-                         memory_space=pltpu.VMEM)
-    ospec = pl.BlockSpec((1, 1, ph, pw), lambda i, j: (i, j, 0, 0),
-                         memory_space=pltpu.VMEM)
-    in_specs = [gspec] if method == "ave" else [ospec, gspec]
-    args = (g,) if method == "ave" else (xp, g)
-    out_dtype = g.dtype if method == "ave" else xp.dtype
-    return pl.pallas_call(
-        functools.partial(_pool_bwd_kernel, kernel=tuple(kernel),
-                          stride=tuple(stride), oh=oh, ow=ow, ph=ph, pw=pw,
-                          method=method),
-        name=f"{method}pool_bwd",
-        out_shape=jax.ShapeDtypeStruct((n, c, ph, pw), out_dtype),
-        grid=(n, c),
-        in_specs=in_specs,
-        out_specs=ospec,
-        interpret=interpret,
-    )(*args)
-
-
-# --------------------------------------------------------------------------- #
 # Fused flat-arena optimizer update (SGD + momentum + L2)
 # --------------------------------------------------------------------------- #
 
@@ -957,9 +812,12 @@ def lrn_route(hw: int, channels: int):
 def maybe_lrn_fused(x, local_size: int, alpha: float, beta: float,
                     k: float = 1.0, layout: str = "NCHW"):
     """ACROSS_CHANNELS LRN through :func:`lrn_route`'s arm. The NCHW block
-    puts channels major, the NHWC entry keeps channels minor, so neither
-    pays an operand relayout at the custom-call boundary. Neither arm has
-    a wall clock on the chip yet (ROADMAP S6)."""
+    puts channels major, the NHWC entry keeps channels minor: no transpose
+    in the program, though on the v5e the compiler still copies between
+    its own channel-minor activation layout and the kernel's row-major
+    operands (1-1.5 ms each at AlexNet's norm1). One traced run each there
+    (PR 24): AlexNet's device step is 68.9 ms with the kernels and 75.95
+    through XLA, GoogLeNet's 50.6 and 50.4 (ROADMAP S6)."""
     from .nn import lrn_across_channels
     _, c, hw, _, _ = _lrn_shape(x, layout)
     if lrn_route(hw, c)[0] == "pallas":

@@ -1311,7 +1311,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "only when this many dispatches are un-"
                         "materialized; 1 = the serial loop. Loss display "
                         "and NaN detection lag by at most this many steps "
-                        "(default: the PipelineConfig policy, 2)")
+                        "(default: the PipelineConfig policy, 4)")
     t.add_argument("--async_snapshot", action="store_true", default=None,
                    help="serialize mid-train snapshots on a background "
                         "thread (host copy taken at the sync point; the "

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import os
 import time
+from collections import deque
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -486,6 +487,9 @@ class Engine:
         # batches they make from 0 in the same order, so this is the
         # number the next one was made under (prefetch_wait's ``batch``)
         self._batches_taken = 0
+        # display boundaries dispatched but not yet shown: (iteration, that
+        # step's learning rate as a device scalar), oldest first
+        self._displays: deque = deque()
 
         # fast restart (runtime/compile_cache.py): when a compile-cache
         # dir is configured, the single-step hot path resolves through the
@@ -1024,11 +1028,58 @@ class Engine:
             raise TrainingDivergedError(it, key, value)
 
     def _absorb(self, rows, last: Dict[str, float]) -> Dict[str, float]:
-        """Feed drained (iter, row) pairs into the metrics window."""
-        for _, row in rows:
+        """Feed drained (iter, row) pairs into the metrics window; a
+        display boundary that was waiting for its window's last step is
+        shown the moment that step's row arrives, before any later row
+        joins the window."""
+        for row_it, row in rows:
             self.metrics.accumulate(row)
             last = row
+            if self._displays and self._displays[0][0] == row_it + 1:
+                self._display(*self._displays.popleft())
         return last
+
+    def _display(self, it: int, lr_dev) -> None:  # static-ok: JIT102
+        """One display boundary: the window's mean row, Caffe's log line,
+        the live telemetry. Runs on the train thread when the boundary's
+        last step has drained (``_absorb``), with the steps dispatched
+        since then still on the device: a display does not wait for the
+        device to run dry, and the device does not wait for a display.
+        (The one device value read here, the rate, was dispatched behind
+        that drained step and ahead of the next: it is there already.)"""
+        row = self.metrics.flush_row(it)
+        lr = float(lr_dev)
+        extras = ", ".join(
+            f"{k} = {v:.4f}" for k, v in sorted(row.items())
+            if k not in ("iter", "time"))
+        log(f"Iteration {it}, lr = {lr:.6g}, {extras}", rank=self.rank)
+        # live telemetry rides the display cadence: gauges for the metrics
+        # endpoint, plus the atomic stats.yaml write (a preempted run
+        # keeps it)
+        self.stats.set_gauge("iteration", it)
+        self.stats.set_gauge("lr", lr)
+        for k, v in row.items():
+            if k not in ("iter", "time"):
+                self.stats.set_gauge(f"train_{k}", round(v, 6))
+        with span_recorder.span("telemetry_dump", "artifact", {"iter": it}):
+            self._dump_live_telemetry()
+        if self._async_tier is not None:
+            # membership churn rides the display cadence, so
+            # admissions/evictions are visible without log-grepping
+            # (comm_stats.membership_counters)
+            from .comm_stats import format_comm, format_membership
+            log("    [membership] " + format_membership(
+                self._async_tier.membership_counters()), rank=self.rank)
+            # the per-link managed-communication bill rides the same
+            # cadence: bytes on the wire, deferred fraction, measured
+            # goodput, cadence backoffs — gauges feed stats.yaml + the
+            # metrics endpoint
+            cc = self._async_tier.comm_counters()
+            if cc:
+                log("    [comm] " + format_comm(cc), rank=self.rank)
+                for k, v in cc.items():
+                    self.stats.set_gauge(f"async_comm_{k}",
+                                         round(float(v), 4))
 
     def train(self, max_iter: Optional[int] = None) -> Dict[str, float]:
         sp = self.sp
@@ -1041,6 +1092,7 @@ class Engine:
         # are un-materialized, so the loop runs ahead of the device by a
         # bounded number of steps instead of hard-syncing every iteration
         fetcher = AsyncScalarFetcher(self.max_in_flight)
+        self._displays.clear()      # a run that raised may have left some
         if self._use_prefetch and self._device_feed is None:
             self._device_feed = DevicePrefetcher(
                 self.train_pipelines, self._sample_sharding,
@@ -1185,6 +1237,10 @@ class Engine:
                             # representative forward).
                             dbatch = ({k: v[0] for k, v in batch.items()}
                                       if self.iter_size > 1 else batch)
+                            # the pass reads the device back anyway: show
+                            # a boundary still pending first, so the log
+                            # stays in step order
+                            last = self._absorb(fetcher.sync(), last)
                             stats = self._debug_fn(
                                 self.params, dbatch,
                                 jax.random.fold_in(self.rng, it))
@@ -1221,56 +1277,21 @@ class Engine:
                     if self._async_tier is not None:
                         self._async_tier.after_iters(self, chunk)
 
+                    if at_display:  # same boundary: it has incremented since
+                        # NOT a sync point: the boundary is shown when its
+                        # last step's row drains (_absorb), at most
+                        # max_in_flight - 1 dispatches from now. Waiting
+                        # for it here would leave the device with nothing
+                        # queued while the host writes a log line — once
+                        # per `display` steps, and a different stretch of
+                        # idle chip in every run. The rate is dispatched
+                        # now and read then: behind the step just
+                        # dispatched, ahead of the next.
+                        self._displays.append(
+                            (it, learning_rate(sp, jnp.asarray(it - 1))))
                     # absorb whatever the drainer finished — no display
                     # cadence needed to keep the metrics window bounded
                     last = self._absorb(fetcher.take_drained(), last)
-                    if at_display:  # same boundary: it has incremented since
-                        # hard sync: the displayed window must cover every
-                        # step through `it` (the drainer may lag by the
-                        # in-flight window otherwise)
-                        with span_recorder.span("hard_sync", "sync",
-                                                {"boundary": "display"}):
-                            last = self._absorb(fetcher.sync(), last)
-                        self._check_divergence(fetcher)
-                        row = self.metrics.flush_row(it)
-                        lr = float(learning_rate(sp, jnp.asarray(it - 1)))
-                        extras = ", ".join(
-                            f"{k} = {v:.4f}" for k, v in sorted(row.items())
-                            if k not in ("iter", "time"))
-                        log(f"Iteration {it}, lr = {lr:.6g}, {extras}",
-                            rank=self.rank)
-                        # live telemetry rides the display cadence: gauges
-                        # for the metrics endpoint, plus the atomic
-                        # stats.yaml write (a preempted run keeps it)
-                        self.stats.set_gauge("iteration", it)
-                        self.stats.set_gauge("lr", lr)
-                        for k, v in row.items():
-                            if k not in ("iter", "time"):
-                                self.stats.set_gauge(f"train_{k}",
-                                                     round(v, 6))
-                        with span_recorder.span("telemetry_dump",
-                                                "artifact", {"iter": it}):
-                            self._dump_live_telemetry()
-                        if self._async_tier is not None:
-                            # membership churn rides the display cadence,
-                            # so admissions/evictions are visible without
-                            # log-grepping (comm_stats.membership_counters)
-                            from .comm_stats import (format_comm,
-                                                     format_membership)
-                            log("    [membership] " + format_membership(
-                                self._async_tier.membership_counters()),
-                                rank=self.rank)
-                            # the per-link managed-communication bill rides
-                            # the same cadence: bytes on the wire, deferred
-                            # fraction, measured goodput, cadence backoffs —
-                            # gauges feed stats.yaml + the metrics endpoint
-                            cc = self._async_tier.comm_counters()
-                            if cc:
-                                log("    [comm] " + format_comm(cc),
-                                    rank=self.rank)
-                                for k, v in cc.items():
-                                    self.stats.set_gauge(f"async_comm_{k}",
-                                                         round(float(v), 4))
                     if sp.test_interval and it % sp.test_interval == 0 and \
                             self.test_nets:
                         # test boundary = hard sync point too: never spend

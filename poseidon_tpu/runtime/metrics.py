@@ -279,7 +279,8 @@ class AsyncScalarFetcher:
     the train thread), and ``put`` itself blocks only when more than
     ``max_in_flight`` dispatches are un-materialized — that backpressure
     IS the dispatch window. ``sync()`` is the hard host<->device sync
-    point (display/test/snapshot boundaries and end of training).
+    point (test/snapshot boundaries and end of training; a display
+    boundary is shown from the rows as they drain and syncs nothing).
 
     NaN/divergence detection rides the drain: the first non-finite value
     of a watched key records ``(iteration, key, value)`` in

@@ -1012,24 +1012,21 @@ def section_cnn_configs(topo) -> dict:
 
 
 # ------------------------------------------------------------------------- #
-# 10. New kernel entry points (PR 11): pool backward + default-path LRN
+# 10. Kernel entry points of the CNN path: the default-path Pallas LRN
 # ------------------------------------------------------------------------- #
 
 def section_kernels(topo) -> dict:
-    """AOT-compile + census the Pallas entry points the MFU-sink PR added:
-    the max/ave pool-backward plane kernels (through their custom-VJP
-    routing, POSEIDON_POOL_BWD=pallas) and the now-default LRN fwd+bwd in
-    both layouts, at the real AlexNet/GoogLeNet pooling geometries.
-    Lowering only; chip_smoke.py checks their numerics on the chip."""
+    """AOT-compile + census the Pallas entry points of the CNN train path:
+    the default LRN fwd+bwd. (The pool backward lowers to XLA's own
+    select-and-scatter on the TPU since PR 24: no kernel to register.)
+    Lowering only; chip_smoke.py checks the numerics on the chip."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from poseidon_tpu.ops import nn as NN
     from poseidon_tpu.ops.pallas_kernels import lrn_fused
 
     os.environ["POSEIDON_FORCE_PALLAS"] = "1"
-    os.environ["POSEIDON_POOL_BWD"] = "pallas"
     m1 = _mesh(topo, ("x",), (1,))
     sh = NamedSharding(m1, P())
 
@@ -1052,29 +1049,6 @@ def section_kernels(topo) -> dict:
         print(f"[aot]   {name}: "
               f"{'ok' if cases[name]['ok'] else 'FAIL'}", flush=True)
 
-    # AlexNet pool1/pool2 geometry (96 x 55x55 k3 s2, 256 x 27x27 k3 s2)
-    # and GoogLeNet's 7x7 ave head, max + ave, both layouts, f32 + bf16
-    geoms = (("alex_pool1", (8, 96, 55, 55), (3, 3), (2, 2), (0, 0)),
-             ("alex_pool2", (8, 256, 27, 27), (3, 3), (2, 2), (0, 0)),
-             ("goog_ave", (8, 832, 7, 7), (7, 7), (1, 1), (0, 0)))
-    for tag, shape, k, s, p in geoms:
-        for dt, dtag in ((jnp.float32, "f32"), (jnp.bfloat16, "bf16")):
-            for method, op in (("max", NN.max_pool), ("ave", NN.ave_pool)):
-                if tag == "goog_ave" and method == "max":
-                    continue
-
-                def bwd(x, op=op, k=k, s=s, p=p):
-                    f = lambda x_: jnp.sum(
-                        op(x_, k, s, p).astype(jnp.float32) ** 2)
-                    return jax.grad(f)(x)
-
-                check(f"pool_bwd_{method}_{tag}_{dtag}", bwd,
-                      aval(shape, dt))
-    # NHWC entry (transposes to the NCHW plane kernel at the boundary)
-    check("pool_bwd_max_nhwc",
-          lambda x: jax.grad(lambda x_: jnp.sum(NN.max_pool(
-              x_, (3, 3), (2, 2), (0, 0), "NHWC") ** 2))(x),
-          aval((8, 55, 55, 96)))
     # LRN through the DEFAULT routing (maybe_lrn_fused is Pallas-on here)
     x = aval((8, 96, 27, 27))
     check("lrn_default_fwd",

@@ -67,12 +67,25 @@ txt = jax.jit(jax.grad(lambda x: lrn_fused(
     .compile().as_text()
 assert txt.count("tpu_custom_call") >= 1, "lrn bwd"
 print("OK lrn_bwd")
+# the TPU route of a bf16 pool backward is select-and-scatter with an F32
+# result: the compiler folds a bare f32 -> bf16 cast into the scatter, which
+# then sums overlapping windows in bf16 (seen on the v5e, PR 24)
+os.environ["POSEIDON_FORCE_PALLAS"] = "1"      # lower as for the TPU
+from poseidon_tpu.ops import nn as NN
+xb = jax.ShapeDtypeStruct((8, 128, 27, 27), jnp.bfloat16, sharding=sh)
+txt = jax.jit(jax.grad(lambda x: jnp.sum(NN.max_pool(
+    x, (3, 3), (2, 2), (0, 0)).astype(jnp.float32) ** 2))).lower(xb) \
+    .compile().as_text()
+sas = [l for l in txt.splitlines() if " select-and-scatter(" in l]
+assert sas and all(" = f32[" in l for l in sas), sas
+print("OK pool_bwd")
 """
 
 
 @pytest.mark.slow
 def test_flash_kernels_mosaic_compile_for_v5e():
-    """flash fwd/bwd + fused LRN must pass the real Mosaic pipeline."""
+    """flash fwd/bwd + fused LRN must pass the real Mosaic pipeline; the
+    bf16 pool backward must keep its select-and-scatter in f32."""
     r = subprocess.run(
         [sys.executable, "-c", _CODE.format(repo=REPO)],
         capture_output=True, text=True, timeout=900)
@@ -81,4 +94,5 @@ def test_flash_kernels_mosaic_compile_for_v5e():
                     f"{(r.stdout + r.stderr).strip()[-200:]}")
     assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-1500:]
     assert "OK fwd" in r.stdout and "OK bwd" in r.stdout \
-        and "OK lrn" in r.stdout and "OK lrn_bwd" in r.stdout
+        and "OK lrn" in r.stdout and "OK lrn_bwd" in r.stdout \
+        and "OK pool_bwd" in r.stdout

@@ -507,10 +507,15 @@ def test_engine_trace_out_records_hot_path_spans(tmp_path, clean_recorder):
     for want in ("prefetch_wait", "dispatch", "dispatch_window",
                  "hard_sync", "snapshot"):
         assert want in names, f"{want} span missing from {sorted(names)}"
-    # boundary args distinguish the sync kinds
+    # boundary args distinguish the sync kinds; a display boundary is
+    # none of them (it is shown when its last step drains, with the next
+    # steps already dispatched) and leaves its telemetry_dump instead
     bounds = {e["args"]["boundary"] for e in doc["traceEvents"]
               if e["name"] == "hard_sync"}
-    assert "display" in bounds and "final" in bounds
+    assert bounds == {"snapshot", "final"}
+    dumps = sorted(e["args"]["iter"] for e in doc["traceEvents"]
+                   if e["name"] == "telemetry_dump")
+    assert dumps == [2, 4, 6]
     # stats.yaml landed too (display boundary), atomically
     assert (tmp_path / "stats.yaml").exists()
     assert not glob.glob(str(tmp_path / "stats.yaml.tmp.*"))

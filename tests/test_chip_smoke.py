@@ -57,7 +57,7 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
     assert warm["phases"]["warm_resume"]["xla_entries_added"] == 0
     assert warm["phases"]["warm_resume"]["compiled_step"]["source"] == \
         "loaded"
-    assert len(cold["phases"]["kernels"]) == 6   # 14 at full size
+    assert len(cold["phases"]["kernels"]) == 8   # 16 at full size
     assert sorted(os.listdir(cache / "aot"))   # the step store rode along
 
 
@@ -89,7 +89,7 @@ def test_stats_yaml_reads_back(tmp_path):
     reg.add("train_iters", 24)
     reg.set_gauge("peak_bytes_in_use", 1932490240)
     reg.set_section("compiled_step", {"source": "loaded", "seconds": 0.1,
-                                      "pallas_custom_calls": 7})
+                                      "pallas_custom_calls": 4})
     reg.set_section("comm", {"per_layer": {"fc6": {"strategy": "sfb"}}})
     path = str(tmp_path / "stats.yaml")
     reg.dump_yaml(path)
@@ -97,5 +97,5 @@ def test_stats_yaml_reads_back(tmp_path):
     assert doc["counters"]["train_iters"] == "24.0"
     assert doc["gauges"]["peak_bytes_in_use"] == "1932490240"
     assert doc["compiled_step"] == {"source": "loaded", "seconds": "0.1",
-                                    "pallas_custom_calls": "7"}
+                                    "pallas_custom_calls": "4"}
     assert doc["comm"]["per_layer"]["fc6"]["strategy"] == "sfb"

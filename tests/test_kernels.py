@@ -2,10 +2,11 @@
 
 Three contracts, each pinned against the formulation it replaces:
 
-- **pool backward**: the custom-VJP strategies (Pallas plane kernel in
-  interpret mode; vectorized tap-sum) must match the select-and-scatter
-  reference arm — f32 tolerance and bf16, both layouts, first-max-wins
-  ties included, with the VMEM/taps-cap fallbacks routing safely;
+- **pool backward**: the custom VJP's two arms (the vectorized tap-sum of
+  the CPU mesh; select-and-scatter in f32, the TPU route since PR 24) must
+  match each other — f32 tolerance and bf16, both layouts, first-max-wins
+  ties bitwise — and Caffe's own backward loop at the benchmark
+  configurations' real geometries, summing overlaps in f32 under bf16;
 - **LRN**: Pallas fwd+bwd parity vs the XLA formulation in both layouts
   (f32 + bf16) and the routing defaults (XLA off-TPU, Pallas on TPU,
   ``POSEIDON_PALLAS_LRN=0`` opt-out, VMEM-cap fallback);
@@ -60,7 +61,7 @@ def _pool_grad(fn, x, k, s, p, layout):
 @pytest.mark.parametrize("geom", POOL_GEOMS)
 def test_pool_bwd_strategies_match_reference(rng_np, pool_env, method,
                                              layout, geom):
-    """taps and (interpret-mode) pallas backward == select-and-scatter."""
+    """The tap-sum backward == select-and-scatter."""
     k, s, p, h = geom
     fn = NN.max_pool if method == "max" else NN.ave_pool
     x = rng_np.randn(2, 5, h, h).astype(np.float32)
@@ -69,29 +70,26 @@ def test_pool_bwd_strategies_match_reference(rng_np, pool_env, method,
     x = jnp.asarray(x)
     pool_env("sas")
     ref = _pool_grad(fn, x, k, s, p, layout)
-    for strategy in ("taps", "pallas"):
-        pool_env(strategy)
-        got = _pool_grad(fn, x, k, s, p, layout)
-        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5,
-                                   err_msg=f"{method}/{strategy}/{layout}")
+    pool_env("taps")
+    got = _pool_grad(fn, x, k, s, p, layout)
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5,
+                               err_msg=f"{method}/{layout}")
 
 
 @pytest.mark.parametrize("method", ["max", "ave"])
 def test_pool_bwd_bf16(rng_np, pool_env, method):
-    """bf16 activations: kernel strategies track the reference within
-    bf16 resolution (the kernels recompute/accumulate in f32)."""
+    """bf16 activations: the tap-sum tracks the reference within bf16
+    resolution (both recompute and accumulate in f32)."""
     fn = NN.max_pool if method == "max" else NN.ave_pool
     x = jnp.asarray(rng_np.randn(2, 4, 9, 9).astype(np.float32)).astype(
         jnp.bfloat16)
     pool_env("sas")
     ref = _pool_grad(fn, x, (3, 3), (2, 2), (0, 0), "NCHW").astype(
         np.float32)
-    for strategy in ("taps", "pallas"):
-        pool_env(strategy)
-        got = _pool_grad(fn, x, (3, 3), (2, 2), (0, 0), "NCHW").astype(
-            np.float32)
-        np.testing.assert_allclose(got, ref, rtol=0.05, atol=0.1,
-                                   err_msg=f"{method}/{strategy}")
+    pool_env("taps")
+    got = _pool_grad(fn, x, (3, 3), (2, 2), (0, 0), "NCHW").astype(
+        np.float32)
+    np.testing.assert_allclose(got, ref, rtol=0.05, atol=0.1, err_msg=method)
 
 
 def test_pool_bwd_first_max_wins_ties(pool_env):
@@ -100,31 +98,35 @@ def test_pool_bwd_first_max_wins_ties(pool_env):
     x = jnp.ones((1, 3, 8, 8), jnp.float32)
     pool_env("sas")
     ref = _pool_grad(NN.max_pool, x, (3, 3), (2, 2), (1, 1), "NCHW")
-    for strategy in ("taps", "pallas"):
-        pool_env(strategy)
-        got = _pool_grad(NN.max_pool, x, (3, 3), (2, 2), (1, 1), "NCHW")
-        np.testing.assert_array_equal(got, ref, err_msg=strategy)
+    pool_env("taps")
+    got = _pool_grad(NN.max_pool, x, (3, 3), (2, 2), (1, 1), "NCHW")
+    np.testing.assert_array_equal(got, ref)
 
 
 def test_pool_bwd_strategy_routing(monkeypatch):
-    from poseidon_tpu.ops.nn import POOL_TAPS_CAP, _pool_bwd_strategy
+    from poseidon_tpu.ops.nn import POOL_TAPS_CAP, pool_bwd_route
     monkeypatch.delenv("POSEIDON_POOL_BWD", raising=False)
     # CPU-mesh default: taps (the CPU thunk-runtime win); the backend is
     # pinned so the suite says the same thing when it runs on the chip
     monkeypatch.setattr("poseidon_tpu.ops.pallas_kernels._interpret_default",
                         lambda: True)
-    assert _pool_bwd_strategy((3, 3)) == "taps"
+    assert pool_bwd_route((3, 3)) == ("taps", "cpu backend")
     # a global pool's window exceeds the taps cap: the reference arm
     # (select-and-scatter degenerates to a broadcast there anyway)
-    assert _pool_bwd_strategy((9, 9)) == "sas"
+    assert pool_bwd_route((9, 9))[0] == "sas"
     assert 9 * 9 > POOL_TAPS_CAP
-    # on-TPU default: the Pallas plane kernel
+    # lowering for the TPU: select-and-scatter, whatever the window (the
+    # v5e's table in PERF.md, PR 24, has no geometry where taps wins)
     monkeypatch.setattr("poseidon_tpu.ops.pallas_kernels._interpret_default",
                         lambda: False)
-    assert _pool_bwd_strategy((3, 3)) == "pallas"
-    # explicit override always wins
-    monkeypatch.setenv("POSEIDON_POOL_BWD", "sas")
-    assert _pool_bwd_strategy((3, 3)) == "sas"
+    for kernel in ((2, 2), (3, 3), (5, 5), (7, 7), (9, 9)):
+        assert pool_bwd_route(kernel)[0] == "sas"
+    assert pool_bwd_route((3, 3)) == ("sas", "")
+    # explicit override always wins; the deleted Pallas arm is no value
+    monkeypatch.setenv("POSEIDON_POOL_BWD", "taps")
+    assert pool_bwd_route((3, 3)) == ("taps", "POSEIDON_POOL_BWD=taps")
+    monkeypatch.setenv("POSEIDON_POOL_BWD", "pallas")
+    assert pool_bwd_route((3, 3)) == ("sas", "")
 
 
 def test_interpret_default_refuses_unknown_backends(monkeypatch):
@@ -144,7 +146,7 @@ def test_net_logs_and_records_kernel_routes(capsys, monkeypatch):
     """Which arm each pool backward / LRN takes is decided by the same
     function the op consults at trace time, logged once per layer at Net
     construction and kept on the net (the engine writes it to
-    stats.yaml): a shape the Pallas kernel cannot hold says so."""
+    stats.yaml): taps on the CPU mesh, select-and-scatter for the TPU."""
     from poseidon_tpu.core.net import Net
     from poseidon_tpu.models import zoo
     from poseidon_tpu.ops import pallas_kernels as PK
@@ -157,11 +159,13 @@ def test_net_logs_and_records_kernel_routes(capsys, monkeypatch):
         "norm1": "lrn=xla", "pool1": "pool_bwd=taps", "norm2": "lrn=xla",
         "pool2": "pool_bwd=taps", "pool5": "pool_bwd=taps"}
     assert "[kernel_route] pool1: pool_bwd -> taps" in capsys.readouterr().out
-    assert NN.pool_bwd_route(15, 15, (3, 3), (2, 2), (0, 0)) == ("taps", "")
-    monkeypatch.setenv("POSEIDON_POOL_BWD", "pallas")
-    assert NN.pool_bwd_route(15, 15, (3, 3), (2, 2), (0, 0))[0] == "pallas"
-    arm, note = NN.pool_bwd_route(8000, 8000, (3, 3), (2, 2), (0, 0))
-    assert arm == "taps" and "VMEM" in note
+    monkeypatch.setattr(PK, "_interpret_default", lambda: False)  # for TPU
+    net = Net(zoo.alexnet(num_classes=10), "TRAIN",
+              source_shapes={"data": (2, 3, 67, 67), "label": (2,)})
+    assert net.kernel_routes == {
+        "norm1": "lrn=pallas", "pool1": "pool_bwd=sas", "norm2": "lrn=pallas",
+        "pool2": "pool_bwd=sas", "pool5": "pool_bwd=sas"}
+    assert "[kernel_route] pool1: pool_bwd -> sas" in capsys.readouterr().out
 
 
 def test_one_channel_conv_takes_im2col_when_lowering_for_tpu(capsys,
@@ -190,21 +194,96 @@ def test_one_channel_conv_takes_im2col_when_lowering_for_tpu(capsys,
                                             "conv2": "direct"}
 
 
-def test_pool_plane_feasibility_guard(rng_np, pool_env, monkeypatch):
-    """An infeasible plane under forced-pallas must fall back to taps (and
-    still be correct), never die in the kernel."""
-    from poseidon_tpu.ops.pallas_kernels import pool_plane_feasible
-    assert pool_plane_feasible(55, 55, 27, 27, (3, 3))
-    assert not pool_plane_feasible(55, 55, 27, 27, (9, 9))   # taps blowup
-    assert not pool_plane_feasible(4000, 4000, 2000, 2000, (3, 3))  # VMEM
-    x = jnp.asarray(rng_np.randn(1, 2, 9, 9).astype(np.float32))
-    pool_env("sas")
-    ref = _pool_grad(NN.max_pool, x, (3, 3), (2, 2), (0, 0), "NCHW")
-    monkeypatch.setattr("poseidon_tpu.ops.pallas_kernels.pool_plane_feasible",
-                        lambda *a: False)
-    pool_env("pallas")
-    got = _pool_grad(NN.max_pool, x, (3, 3), (2, 2), (0, 0), "NCHW")
-    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+# the pooling geometries the benchmark's two configurations hold (AlexNet's
+# three; GoogLeNet's ceil-mode stride-2, its stride-1 inception pools and
+# its AVE heads), at small N and C
+REAL_POOL_GEOMS = [
+    ("max", (3, 3), (2, 2), (0, 0), 55),
+    ("max", (3, 3), (2, 2), (0, 0), 27),
+    ("max", (3, 3), (2, 2), (0, 0), 13),
+    ("max", (3, 3), (2, 2), (0, 0), 28),    # ceil mode: 28 -> 14
+    ("max", (3, 3), (2, 2), (0, 0), 14),    # ceil mode: 14 -> 7
+    ("max", (3, 3), (1, 1), (1, 1), 28),
+    ("max", (3, 3), (1, 1), (1, 1), 14),
+    ("max", (3, 3), (1, 1), (1, 1), 7),
+    ("ave", (5, 5), (3, 3), (0, 0), 14),
+    ("ave", (7, 7), (1, 1), (0, 0), 7),
+]
+
+
+def _caffe_pool_bwd(x, g, k, s, p, method):
+    """pooling_layer.cpp's Backward_cpu in numpy (NCHW): the first max of
+    a window takes its cotangent, AVE divides by the window clipped to the
+    padded extent; everything summed in f32."""
+    n, c, h, w = x.shape
+    dx = np.zeros(x.shape, np.float32)
+    for i in range(g.shape[2]):
+        for j in range(g.shape[3]):
+            hs, ws = i * s[0] - p[0], j * s[1] - p[1]
+            he, we = min(hs + k[0], h + p[0]), min(ws + k[1], w + p[1])
+            size = (he - hs) * (we - ws)
+            hs, ws, he, we = max(hs, 0), max(ws, 0), min(he, h), min(we, w)
+            if method == "ave":
+                dx[:, :, hs:he, ws:we] += g[:, :, i:i + 1, j:j + 1] / size
+                continue
+            first = x[:, :, hs:he, ws:we].reshape(n, c, -1).argmax(-1)
+            r, q = np.divmod(first, we - ws)
+            np.add.at(dx, (np.arange(n)[:, None], np.arange(c)[None, :],
+                           hs + r, ws + q), g[:, :, i, j])
+    return dx
+
+
+def _lowering_for(monkeypatch, backend):
+    monkeypatch.delenv("POSEIDON_POOL_BWD", raising=False)
+    monkeypatch.setattr("poseidon_tpu.ops.pallas_kernels._interpret_default",
+                        lambda: backend == "cpu")
+    return {"tpu": "sas", "cpu": "taps"}[backend]
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("geom", REAL_POOL_GEOMS,
+                         ids=lambda g: f"{g[0]}{g[1][0]}s{g[2][0]}p{g[3][0]}"
+                                       f"on{g[4]}")
+def test_pool_bwd_routes_match_caffe_at_real_geometries(
+        rng_np, monkeypatch, backend, dtype, geom):
+    """The formulation each backend's route lowers (select-and-scatter in
+    f32 for the TPU, the tap-sum for the CPU), run here on the CPU mesh,
+    against Caffe's own backward loop."""
+    method, k, s, p, h = geom
+    arm = _lowering_for(monkeypatch, backend)
+    assert NN.pool_bwd_route(k)[0] == arm
+    fn = NN.max_pool if method == "max" else NN.ave_pool
+    x = jnp.asarray(rng_np.randn(2, 3, h, h), dtype)
+    y, vjp = jax.vjp(lambda x_: fn(x_, k, s, p, "NCHW"), x)
+    g = jnp.asarray(rng_np.randn(*y.shape), dtype)
+    got = vjp(g)[0]
+    assert got.dtype == x.dtype
+    want = _caffe_pool_bwd(np.asarray(x, np.float32),
+                           np.asarray(g, np.float32), k, s, p, method)
+    # bf16: ONE rounding of the f32 sum (2**-8), not one per contribution
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -7
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("method", ["max", "ave"])
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_pool_bwd_bf16_sums_overlaps_in_f32(monkeypatch, backend, method):
+    """Input position (2, 2) of a 3x3 s2 pool lies in four windows. With
+    cotangents 256, 1, 1, 1 a bf16 accumulator stays at 256 (257 is no
+    bf16 number); the f32 sum 259 rounds once, to 260."""
+    _lowering_for(monkeypatch, backend)
+    x = np.zeros((1, 1, 5, 5), np.float32)
+    x[0, 0, 2, 2] = 1.0                     # the max of all four windows
+    g = np.array([[256.0, 1.0], [1.0, 1.0]], np.float32).reshape(1, 1, 2, 2)
+    fn = NN.max_pool if method == "max" else NN.ave_pool
+    scale = 1.0 if method == "max" else 9.0      # undo AVE's exact / 9
+    _, vjp = jax.vjp(lambda x_: fn(x_, (3, 3), (2, 2), (0, 0), "NCHW"),
+                     jnp.asarray(x, jnp.bfloat16))
+    dx = vjp(jnp.asarray(g * scale, jnp.bfloat16))[0]
+    assert dx.dtype == jnp.bfloat16
+    assert float(dx[0, 0, 2, 2]) == 260.0
 
 
 def test_pool_bwd_under_jit_and_in_net(rng_np, pool_env):

@@ -237,3 +237,52 @@ def test_pipeline_device_transform_falls_back_for_float_data(tmp_path):
     batch = next(pipe)
     assert batch["data"].dtype == np.float32
     pipe.close()
+
+
+# --------------------------------------------------------------------------- #
+# batch memory is recycled, and only when nobody can see it any more
+# --------------------------------------------------------------------------- #
+
+def _address(a: np.ndarray) -> int:
+    return a.__array_interface__["data"][0]
+
+
+@pytest.mark.parametrize("method", ["batch", "batch_u8"])
+def test_batch_memory_is_reused_once_released(datum_db, method):
+    """The next batch is written into the memory of a batch nobody holds
+    any more (no fresh pages, no page faults), and holds the same pixels a
+    fresh buffer would."""
+    path, _, _ = datum_db
+    b = native.NativeLMDBBatcher(path, crop_size=8, mirror=True, train=True)
+    idx = np.arange(16)
+    data, _ = getattr(b, method)(idx, seed=3)
+    want, where = data.copy(), _address(data)
+    del data
+    again, _ = getattr(b, method)(idx, seed=3)
+    assert _address(again) == where
+    np.testing.assert_array_equal(again, want)
+
+
+@pytest.mark.parametrize("keep", ["batch", "view", "device_array"])
+def test_batch_memory_is_never_reused_under_a_holder(datum_db, keep):
+    """A consumer that keeps a batch, a view of one, or a device array made
+    from one (the CPU backend aliases host memory; an accelerator's copy
+    may still be in flight) keeps its bytes: later batches go elsewhere."""
+    import jax
+
+    path, _, _ = datum_db
+    b = native.NativeLMDBBatcher(path, crop_size=8, mirror=True, train=True)
+    data, _ = b.batch(np.arange(16), seed=1)
+    want = data.copy()
+    held = {"batch": lambda: data, "view": lambda: data[3, 1],
+            "device_array": lambda: jax.device_put(data)}[keep]()
+    want = want[3, 1] if keep == "view" else want
+    del data
+    later = [b.batch(np.arange(16, 32), seed=s)[0] for s in range(4)]
+    np.testing.assert_array_equal(np.asarray(held), want)
+    assert len({_address(a) for a in later}) == 4
+    # ... and goes back on the free list with its last holder (a device
+    # array lets go when the runtime is done with it, not at `del`)
+    del held
+    if keep != "device_array":
+        assert len(b._buffers._free) == 1

@@ -126,6 +126,75 @@ def test_prefetch_disabled_for_stacked_paths(tmp_path):
 
 
 # --------------------------------------------------------------------------- #
+# a display boundary does not drain the device
+# --------------------------------------------------------------------------- #
+
+def _display_run(tmp_path, sub, capsys, mif, **solver_kw):
+    from poseidon_tpu.runtime.engine import Engine
+
+    out = tmp_path / sub
+    out.mkdir()
+    eng = Engine(_solver(max_iter=12, **solver_kw), memory_data=_memory_data(),
+                 output_dir=str(out), max_in_flight=mif)
+    eng.sp.display = 3
+    capsys.readouterr()
+    try:
+        eng.train()
+    finally:
+        eng.close()
+    rows = [(r["iter"], r["loss"]) for r in eng.metrics.rows]
+    lines = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith(("Iteration", "Snapshot"))]
+    return rows, lines
+
+
+@pytest.mark.parametrize("mif,slow_drain", [(1, False), (2, False),
+                                            (4, False), (2, True), (4, True)])
+def test_display_rows_are_the_serial_loops(tmp_path, capsys, monkeypatch,
+                                           mif, slow_drain):
+    """The displayed rows — which iterations, the mean over exactly that
+    boundary's steps — are those of the serial loop at every window depth,
+    although a boundary no longer waits for the device: it is shown when
+    its last step has drained, with later steps already dispatched. With
+    the drain slowed (an accelerator's lag, forced here) the rows stay the
+    same and each boundary's line still precedes the snapshot taken at
+    it."""
+    from poseidon_tpu.runtime import metrics
+
+    want, _ = _display_run(tmp_path, "serial", capsys, 1)
+    assert [it for it, _ in want] == [3, 6, 9, 12]
+    late = []
+    if slow_drain:
+        real_rows = metrics.scalar_rows
+
+        def slow_rows(m):
+            time.sleep(0.05)
+            return real_rows(m)
+
+        monkeypatch.setattr(metrics, "scalar_rows", slow_rows)
+        monkeypatch.setattr(metrics.AsyncScalarFetcher, "_already_ready",
+                            staticmethod(lambda m: False))
+        real_put = metrics.AsyncScalarFetcher.put
+
+        def put(self, first_iter, m):
+            real_put(self, first_iter, m)
+            late.append(self._pending)
+
+        monkeypatch.setattr(metrics.AsyncScalarFetcher, "put", put)
+    got, lines = _display_run(tmp_path, "windowed", capsys, mif,
+                              snapshot=6, snapshot_prefix="snap/d")
+    assert got == want
+    shown = [ln.split(",")[0] for ln in lines if ln.startswith("Iteration")]
+    assert shown == [f"Iteration {i}" for i in (3, 6, 9, 12)]
+    assert lines.index(next(ln for ln in lines if ln.startswith(
+        "Iteration 6"))) < lines.index(next(
+            ln for ln in lines if ln.startswith("Snapshot")))
+    if slow_drain:
+        # the loop really ran ahead of the drain at display boundaries too
+        assert max(late) == mif - 1
+
+
+# --------------------------------------------------------------------------- #
 # NaN abort rides the async drain
 # --------------------------------------------------------------------------- #
 

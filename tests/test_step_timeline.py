@@ -131,13 +131,14 @@ def test_engine_publishes_step_scopes_for_every_source(tmp_path,
         assert seen[run][1] == loss          # and the same arithmetic
 
 
-@pytest.mark.parametrize("arm", ["default_routes", "pallas_interpreted"])
+@pytest.mark.parametrize("arm", ["default_routes", "tpu_routes"])
 def test_pool_and_lrn_instructions_map_to_their_layer_and_pass(
         arm, monkeypatch):
     """Every instruction whose own metadata names the pool or the LRN
-    layer lands on that layer with the pass its path says — for the XLA
-    arms the CPU routes to, and for the Pallas kernels (interpreted here,
-    custom calls on the chip: same scopes, same join)."""
+    layer lands on that layer with the pass its path says — for the arms
+    the CPU routes to, and for the TPU's (select-and-scatter; the Pallas
+    LRN kernels, interpreted here and custom calls on the chip: same
+    scopes, same join)."""
     import re
 
     import jax
@@ -145,14 +146,14 @@ def test_pool_and_lrn_instructions_map_to_their_layer_and_pass(
     from poseidon_tpu.core.net import Net
     from poseidon_tpu.proto.messages import load_net_from_string
 
-    if arm == "pallas_interpreted":
-        monkeypatch.setenv("POSEIDON_POOL_BWD", "pallas")
+    if arm == "tpu_routes":
+        monkeypatch.setenv("POSEIDON_POOL_BWD", "sas")
         monkeypatch.setenv("POSEIDON_PALLAS_LRN", "1")
     net = Net(load_net_from_string(NET), "TRAIN",
               source_shapes={"data": (4, 4, 12, 12), "label": (4,)})
-    if arm == "pallas_interpreted":
+    if arm == "tpu_routes":
         assert net.kernel_routes == {"norm1": "lrn=pallas",
-                                     "pool1": "pool_bwd=pallas"}
+                                     "pool1": "pool_bwd=sas"}
     params = net.init(jax.random.PRNGKey(0))
     inputs = {"data": np.ones((4, 4, 12, 12), np.float32),
               "label": np.zeros((4,), np.int32)}
