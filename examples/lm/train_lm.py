@@ -24,6 +24,13 @@ The transformer family is this framework's beyond-the-reference flagship.
     # one real TPU chip (mesh collapses to 1x1):
     python examples/lm/train_lm.py --steps 500 --seq 2048 --bf16 --remat
 
+Which family trains where: the modern block (RMSNorm, RoPE, QK-norm, top-k
+dropless MoE, AdamW) — OLMoE-1B-7B — trains through the CLI as layers of a
+prototxt Net (`python -m poseidon_tpu train --solver
+examples/lm/olmoe_1b_7b_solver.prototxt --bf16`). This script is still the
+only way to train the GPT-2-style block (LayerNorm, learned positions, GELU,
+switch MoE) and the only user of the sp/tp/pp/ep step builders (ROADMAP D2).
+
 Data: the script's own source file, byte-level — no downloads. Loss should
 fall from ~5.5 (ln 256) toward ~2 as it memorizes the file.
 """

@@ -46,6 +46,20 @@ from jax import lax
 
 Tree = Dict[str, Dict[str, jax.Array]]
 
+# A layer with a leaf this large stays per-leaf (256 MiB of f32). The arena
+# exists so that costs do not scale with the NUMBER of tensors; a leaf of
+# hundreds of MB is a bandwidth-bound fusion of its own already, and packing
+# it would add a copy of itself per packed quantity (weight, gradient, each
+# history buffer) and two multiplier vectors as long, all resident at once:
+# OLMoE's 537 MB expert stacks and 412 MB embedding do not fit a 16 GB chip
+# that way. Every CNN leaf in the zoo is under it (AlexNet's fc6: 151 MB).
+MAX_LEAF_ELEMENTS = 64 * 2 ** 20
+
+
+def fits_arena(pdefs) -> bool:
+    """Whether a layer's leaves (its ParamDefs) are all arena-sized."""
+    return all(p.count <= MAX_LEAF_ELEMENTS for p in pdefs)
+
 
 @dataclass(frozen=True)
 class ArenaSlot:

@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..ops import elementwise as E
 from ..ops import losses as L
@@ -204,29 +205,160 @@ class InnerProductLayer(Layer):
 
     def setup(self, bottom_shapes):
         ip = self.lp.inner_product_param
-        n = bottom_shapes[0][0]
-        k = int(np.prod(bottom_shapes[0][1:]))
+        shape = bottom_shapes[0]
+        self.axis = ip.axis if ip.axis >= 0 else len(shape) + ip.axis
+        if not 1 <= self.axis < len(shape):
+            raise ValueError(f"{self.name}: inner_product axis {ip.axis} "
+                             f"outside a bottom of shape {shape}")
+        # axes before `axis` are kept (Caffe: M_ = count(0, axis)); the rest
+        # fold into one product. axis 1 is the classic (N, K) flatten.
+        self.lead = tuple(shape[:self.axis])
+        k = int(np.prod(shape[self.axis:]))
         self.bias_term = ip.bias_term
         self.params = [self._param("w", (ip.num_output, k), ip.weight_filler, 0)]
         if self.bias_term:
             self.params.append(self._param("b", (ip.num_output,), ip.bias_filler, 1))
-        return [(n, ip.num_output)]
+        return [self.lead + (ip.num_output,)]
 
     def apply(self, params, bottoms, ctx):
         w = params["w"]
         b = params.get("b") if self.bias_term else None
         x = bottoms[0]
+        if self.axis != 1:
+            x = x.reshape(-1, w.shape[1])
+        y = None
         if ctx.comm is not None:
             # SFB hook: the comm context may supply a sufficient-factor
             # custom-vjp matmul for this layer (SURVEY §2.3; the reference's
             # ComputeGradientFromSV path, inner_product_layer.cpp:126).
             y = ctx.comm.inner_product(self.name, x, w, b)
-            if y is not None:
-                return [y]
-            w = ctx.comm.tap_param(self.name, "w", w)
-            if b is not None:
-                b = ctx.comm.tap_param(self.name, "b", b)
-        return [NN.inner_product(x, w, b)]
+            if y is None:
+                w = ctx.comm.tap_param(self.name, "w", w)
+                if b is not None:
+                    b = ctx.comm.tap_param(self.name, "b", b)
+        if y is None:
+            y = NN.inner_product(x, w, b)
+        return [y if self.axis == 1 else y.reshape(self.lead + y.shape[-1:])]
+
+
+# --------------------------------------------------------------------------- #
+# Token-model layers: blobs are (batch, sequence, feature). Each is a thin
+# wrapper over a function of models/ or ops/ (imported at apply time: a CNN
+# never pays for them).
+# --------------------------------------------------------------------------- #
+
+def _tap_all(ctx, name, params):
+    """Every param of a layer through the comm context's gradient tap."""
+    if ctx.comm is None:
+        return params
+    return {k: ctx.comm.tap_param(name, k, v) for k, v in params.items()}
+
+
+class EmbedLayer(Layer):
+    """ids (N, S) -> (N, S, D): Caffe's Embed, a row lookup (no bias)."""
+    TYPE = "EMBED"
+
+    def setup(self, bottom_shapes):
+        ep = self.lp.embed_param
+        if ep.input_dim <= 0 or ep.num_output <= 0:
+            raise ValueError(f"{self.name}: embed_param needs input_dim and "
+                             f"num_output")
+        self.params = [self._param("w", (ep.input_dim, ep.num_output),
+                                   ep.weight_filler, 0)]
+        return [tuple(bottom_shapes[0]) + (ep.num_output,)]
+
+    def apply(self, params, bottoms, ctx):
+        from ..config import policy
+        w = _tap_all(ctx, self.name, params)["w"]
+        ids = bottoms[0].astype(jnp.int32)
+        # gather, then cast: the rows looked up, not the whole table
+        return [w[ids].astype(policy().compute_dtype)]
+
+
+class RMSNormLayer(Layer):
+    """x * rsqrt(mean(x^2) + eps) * g over the last axis; gain only (filled
+    with ones), statistics in f32."""
+    TYPE = "RMS_NORM"
+
+    def setup(self, bottom_shapes):
+        ones = FillerParameter(type="constant", value=1.0)
+        self.params = [self._param("g", (bottom_shapes[0][-1],), ones, 0)]
+        return [bottom_shapes[0]]
+
+    def apply(self, params, bottoms, ctx):
+        from ..models.transformer import rms_norm
+        g = _tap_all(ctx, self.name, params)["g"]
+        return [rms_norm(bottoms[0], g, self.lp.rms_norm_param.eps)]
+
+
+class AttentionLayer(Layer):
+    """Bottoms q, k, v of (N, S, D) -> (N, S, D): heads split, rotate-half
+    RoPE on q and k, causal softmax(q k^T / sqrt(Dh)) v, heads merged."""
+    TYPE = "ATTENTION"
+
+    def setup(self, bottom_shapes):
+        ap = self.lp.attention_param
+        if len(bottom_shapes) != 3 or len(set(bottom_shapes)) != 1 \
+                or len(bottom_shapes[0]) != 3:
+            raise ValueError(f"{self.name}: ATTENTION takes q, k, v of one "
+                             f"(N, S, D) shape, got {bottom_shapes}")
+        d = bottom_shapes[0][-1]
+        if ap.num_heads <= 0 or d % ap.num_heads or (d // ap.num_heads) % 2:
+            raise ValueError(f"{self.name}: {ap.num_heads} heads do not "
+                             f"split D={d} into even head sizes")
+        return [bottom_shapes[0]]
+
+    def apply(self, params, bottoms, ctx):
+        from ..models.transformer import rope_attention
+        ap = self.lp.attention_param
+        return [rope_attention(*bottoms, n_heads=ap.num_heads,
+                               rope_theta=ap.rope_theta)]
+
+
+class MoELayer(Layer):
+    """Bottom (N, S, D) -> top-k token-choice experts, dropless. Blobs:
+    router (E, D), gate and up (E, F, D), down (E, D, F). Tops: the output;
+    the load-balancing and router z losses (scalars, weighted by the
+    prototxt's ``loss_weight``); optionally the step's own routing as two
+    more scalars — tokens at the fullest expert over the mean, and
+    assignments no expert computed (0: nothing is dropped)."""
+    TYPE = "MOE"
+
+    def setup(self, bottom_shapes):
+        mp = self.lp.moe_param
+        n, s, d = bottom_shapes[0]
+        if not 0 < mp.top_k <= mp.num_experts or mp.expert_width <= 0:
+            raise ValueError(f"{self.name}: moe_param needs num_experts >= "
+                             f"top_k > 0 and expert_width")
+        if not 3 <= len(self.lp.top) <= 5:
+            raise ValueError(f"{self.name}: MOE has 3 to 5 tops (output, "
+                             f"balance loss, z loss[, load max/mean[, "
+                             f"dropped]]), got {len(self.lp.top)}")
+        e, f = mp.num_experts, mp.expert_width
+        self.params = [
+            self._param("router", (e, d), mp.weight_filler, 0),
+            self._param("gate", (e, f, d), mp.weight_filler, 1),
+            self._param("up", (e, f, d), mp.weight_filler, 2),
+            self._param("down", (e, d, f), mp.weight_filler, 3)]
+        return [(n, s, d)] + [()] * (len(self.lp.top) - 1)
+
+    def default_loss_weight(self) -> float:
+        return 0.0
+
+    def apply(self, params, bottoms, ctx):
+        from ..models.moe import moe_dropless
+        mp = self.lp.moe_param
+        p = _tap_all(ctx, self.name, params)
+        x = bottoms[0]
+        n, s, d = x.shape
+        y, lb, z, sizes = moe_dropless(
+            x.reshape(n * s, d), p["router"], p["gate"], p["up"], p["down"],
+            mp.top_k)
+        sizes = lax.stop_gradient(sizes).astype(jnp.float32)
+        total = float(n * s * mp.top_k)
+        stats = [jnp.max(sizes) * mp.num_experts / total,
+                 total - jnp.sum(sizes)]
+        return [y.reshape(n, s, d), lb, z] + stats[:len(self.lp.top) - 3]
 
 
 # --------------------------------------------------------------------------- #
@@ -547,9 +679,17 @@ class SoftmaxLossLayer(Layer):
         return [()]
 
     def apply(self, params, bottoms, ctx):
-        loss = L.softmax_loss(bottoms[0], bottoms[1])
+        axis = self.lp.softmax_param.axis
+        if axis not in (1, -1, bottoms[0].ndim - 1):
+            raise ValueError(f"{self.name}: softmax axis {axis} is neither "
+                             f"1 nor the last axis")
+        if axis != 1 and bottoms[0].ndim > 2:
+            # classes last: (N, S, V) logits against (N, S) targets
+            axis, loss = -1, L.softmax_loss_last_axis(*bottoms[:2])
+        else:
+            axis, loss = 1, L.softmax_loss(*bottoms[:2])
         if len(self.lp.top) >= 2:
-            return [loss, L.softmax(bottoms[0], axis=1)]
+            return [loss, L.softmax(bottoms[0], axis=axis)]
         return [loss]
 
 
@@ -711,7 +851,8 @@ class HDF5OutputLayer(Layer):
 REGISTRY: Dict[str, type] = {
     cls.TYPE: cls
     for cls in [
-        ConvolutionLayer, InnerProductLayer, PoolingLayer, LRNLayer,
+        ConvolutionLayer, InnerProductLayer, EmbedLayer, RMSNormLayer,
+        AttentionLayer, MoELayer, PoolingLayer, LRNLayer,
         Im2colLayer, ReLULayer, SigmoidLayer, TanHLayer, BNLLLayer,
         AbsValLayer, PowerLayer, ThresholdLayer, DropoutLayer, FlattenLayer,
         ConcatLayer, SliceLayer, SplitLayer, EltwiseLayer, MVNLayer,

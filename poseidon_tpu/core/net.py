@@ -376,12 +376,13 @@ class Net:
                     f"for the TPU)")
 
     def _plan_kernel_routes(self) -> None:
-        """Which arm each pooling backward (TRAIN nets) and cross-channel
-        LRN lowers to — which XLA formulation, or the Pallas LRN kernels —
-        from the SAME functions the ops consult at trace time, logged once
-        per layer. The routing is by platform and shape, which is legitimate;
+        """Which arm each pooling backward (TRAIN nets), cross-channel
+        LRN, ATTENTION and MOE layer lowers to — which XLA formulation or
+        which kernel — from the SAME functions the ops consult at trace
+        time, logged once per layer. The routing is by platform and shape,
+        which is legitimate;
         what is not is a run that cannot say which arm it took."""
-        from ..ops.pallas_kernels import lrn_route
+        from ..ops.pallas_kernels import attention_route, lrn_route
         from ..runtime.metrics import log
         self.kernel_routes: Dict[str, str] = {}
         for layer in self.layers:
@@ -394,6 +395,13 @@ class Net:
             elif layer.TYPE == "LRN" and layer.region == "ACROSS_CHANNELS":
                 what = "lrn"
                 arm, note = lrn_route(shape[2] * shape[3], shape[1])
+            elif layer.TYPE == "ATTENTION":
+                what = "attention"
+                arm, note = attention_route(shape[1])
+            elif layer.TYPE == "MOE":
+                from ..models.moe import GROUPED_MATMUL
+                what = "grouped_matmul"
+                arm, note = GROUPED_MATMUL, "sorted by expert, dropless"
             else:
                 continue
             self.kernel_routes[layer.name] = f"{what}={arm}"

@@ -124,6 +124,7 @@ class BatchPipeline:
         self.seed = seed
         self.shuffle = (phase == "TRAIN") if shuffle is None else shuffle
         self.tops = list(lp.top)
+        self.label_shape: tuple = ()    # per record; (seq_len,) for tokens
         # device_transform: ship uint8 crops and let the compiled step do
         # (x - mean) * scale on the accelerator — 4x fewer host->device
         # bytes and no per-pixel float math on the host (the TPU-native
@@ -186,11 +187,17 @@ class BatchPipeline:
         else:
             self.source = build_source(lp, shard, memory_data)
             self._n_records = len(self.source)
-            self.transformer = DataTransformer(_effective_transform(lp), phase,
-                                               seed=seed)
-            c, h, w = self.source.record_shape
-            self.data_shape = (batch_size,) + \
-                self.transformer.output_shape(c, h, w)
+            if self.source.tokens:
+                # sequences of ids: no image transform, targets per position
+                self.transformer = None
+                self.data_shape = (batch_size,) + self.source.record_shape
+                self.label_shape = self.data_shape[1:]
+            else:
+                self.transformer = DataTransformer(
+                    _effective_transform(lp), phase, seed=seed)
+                c, h, w = self.source.record_shape
+                self.data_shape = (batch_size,) + \
+                    self.transformer.output_shape(c, h, w)
         self._queue: queue.Queue = queue.Queue(maxsize=prefetch)
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._stop = threading.Event()
@@ -292,6 +299,8 @@ class BatchPipeline:
         by record and transformed in Python."""
         idx = np.fromiter((next(stream) for _ in range(self.batch_size)),
                           np.int64, count=self.batch_size)
+        if self.native is None and self.source.tokens:
+            return self.source.data_cat[idx], self.source.labels_cat[idx]
         if self.native is None:
             raw = np.empty((self.batch_size,) + self.source.record_shape,
                            np.float32)
@@ -517,5 +526,5 @@ def build_phase_pipelines(net_param, phase: str, batch_multiplier: int,
         pipes.append(pipe)
         shapes[lp.top[0]] = (per_dev,) + tuple(pipe.data_shape[1:])
         if len(lp.top) > 1:
-            shapes[lp.top[1]] = (per_dev,)
+            shapes[lp.top[1]] = (per_dev,) + tuple(pipe.label_shape)
     return pipes, shapes

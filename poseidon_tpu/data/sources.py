@@ -1,6 +1,8 @@
 """Data sources: the host-side counterparts of the reference's data layers.
 
-Each source yields (image_array, label) records; batching, augmentation and
+Each source yields (image_array, label) records — or, for a token file
+(``HDF5Source`` over integer 2-D ``data`` and ``label``), (ids, targets)
+sequences; batching, augmentation and
 device transfer are layered on top (pipeline.py). Backends mirror the layer
 catalog: DATA (LMDB and LevelDB via our readers), IMAGE_DATA (file lists +
 PIL/cv2 decode), HDF5_DATA, MEMORY_DATA, plus synthetic sources for
@@ -32,6 +34,10 @@ class Source:
     def record_shape(self) -> Tuple[int, int, int]:
         arr, _ = self.read(0)
         return tuple(arr.shape)  # type: ignore[return-value]
+
+    # a token source's record is a SEQUENCE of ids with a target per
+    # position: int32 both, no image transform, label shaped like the data
+    tokens = False
 
 
 class LMDBSource(Source):
@@ -105,7 +111,11 @@ class ImageListSource(Source):
 
 class HDF5Source(Source):
     """HDF5_DATA: 'source' is a text file listing .h5 files with datasets
-    'data' and 'label' (hdf5_data_layer.cpp)."""
+    'data' and 'label' (hdf5_data_layer.cpp).
+
+    A file whose ``data`` is a 2-D INTEGER dataset with a ``label`` of the
+    same shape is a token file: record i is (``seq_len`` ids, ``seq_len``
+    targets), both int32 — ids reach the device as integers."""
 
     def __init__(self, source: str):
         import h5py
@@ -113,17 +123,28 @@ class HDF5Source(Source):
             names = [l.strip() for l in f if l.strip()]
         data: List[np.ndarray] = []
         labels: List[np.ndarray] = []
+        kinds = set()
         for name in names:
             with h5py.File(name, "r") as h:
-                data.append(np.asarray(h["data"], np.float32))
-                labels.append(np.asarray(h["label"]).reshape(-1))
+                d, lab = h["data"], h["label"]
+                tok = (d.ndim == 2 and np.issubdtype(d.dtype, np.integer)
+                       and lab.shape == d.shape)
+                kinds.add(tok)
+                data.append(np.asarray(d, np.int32 if tok else np.float32))
+                labels.append(np.asarray(lab, np.int32) if tok
+                              else np.asarray(lab).reshape(-1))
+        if len(kinds) > 1:
+            raise ValueError(f"{source}: token files and image files mixed")
+        self.tokens = kinds == {True}
         self.data_cat = np.concatenate(data)
         self.labels_cat = np.concatenate(labels)
 
     def __len__(self) -> int:
         return len(self.data_cat)
 
-    def read(self, index: int) -> Tuple[np.ndarray, int]:
+    def read(self, index: int):
+        if self.tokens:
+            return self.data_cat[index], self.labels_cat[index]
         arr = self.data_cat[index]
         if arr.ndim == 1:
             arr = arr[:, None, None]

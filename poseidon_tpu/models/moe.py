@@ -33,6 +33,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..config import matmul_precision, policy
 from ..proto.messages import SolverParameter
 from ..solvers.updates import SolverState, make_update_fn
 from .transformer import (TransformerConfig, _dense, _layer_norm,
@@ -149,6 +150,82 @@ def moe_ffn(x: jax.Array, wg: jax.Array, w1e: jax.Array, w2e: jax.Array,
     mean_gate = jnp.mean(gates, axis=0)
     aux = cfg.aux_weight * n_exp * jnp.sum(frac * mean_gate)
     return y, aux
+
+
+# --------------------------------------------------------------------------- #
+# Token-choice top-k, dropless (OLMoE / Mixtral-style): what the MOE layer of
+# core/layers.py wraps, beside the switch path above.
+# --------------------------------------------------------------------------- #
+
+GROUPED_MATMUL = "ragged_dot"   # the arm every MOE layer takes (kernel_routes)
+
+
+def topk_route(logits: jax.Array, top_k: int):
+    """Router logits (T, E) f32 -> (probs (T, E), weights (T, k), experts
+    (T, k)): softmax over all experts in f32, the k largest kept, their
+    weights as they are (OLMoE's ``norm_topk_prob`` false)."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = lax.top_k(probs, top_k)
+    return probs, weights, experts
+
+
+def router_losses(logits: jax.Array, probs: jax.Array, sizes: jax.Array):
+    """(load-balancing loss, router z loss), unweighted, from the router's
+    logits and probabilities (T, E) and the assignments per expert (E,).
+    Load balancing is E * sum_e f_e P_e with f_e the fraction of tokens that
+    have expert e among their k (the f_e sum to k) and P_e the mean router
+    probability; z is mean(logsumexp(logits)^2)."""
+    n_tok, n_exp = logits.shape
+    f = sizes.astype(jnp.float32) / n_tok
+    lb = n_exp * jnp.sum(f * jnp.mean(probs, axis=0))
+    z = jnp.mean(jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1) ** 2)
+    return lb, z
+
+
+def _grouped(x, w, group_sizes):
+    """Rows of x (M, K), sorted by group, times their group's w[g] (N, K),
+    weights as Caffe stores them (out x in) -> (M, N). The weights reach
+    ``lax.ragged_dot`` as (G, K, N): the transpose rides the cast to the
+    compute dtype, and contracting the stored layout's last axis instead
+    (``ragged_dot_general``) ran this product at 26 TFLOP/s on the v5e
+    where this form runs at 132 (PR 25, PERF.md)."""
+    p = policy()
+    return lax.ragged_dot(
+        x.astype(p.compute_dtype),
+        jnp.swapaxes(w.astype(p.compute_dtype), 1, 2), group_sizes,
+        precision=matmul_precision())
+
+
+def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,
+                 up: jax.Array, down: jax.Array, top_k: int):
+    """Top-k token-choice MoE over flat tokens x (T, D), no capacity: every
+    token is computed by all k of its experts whatever the load.
+
+    router (E, D); gate, up (E, F, D); down (E, D, F). The T*k assignments
+    are sorted by expert, each projection is ONE grouped matmul over the
+    sorted rows (``lax.ragged_dot``), and the k results of a token
+    are summed with its router weights. Returns (y (T, D), load-balancing
+    loss, z loss, assignments per expert (E,) int32)."""
+    t, d = x.shape
+    n_exp = router.shape[0]
+    # the router runs in f32 whatever the policy: top-k is discontinuous,
+    # and a bf16 logit flips which experts a token gets
+    logits = lax.dot_general(x.astype(jnp.float32), router,
+                             (((1,), (1,)), ((), ())),
+                             precision=lax.Precision.HIGHEST)
+    probs, weights, experts = topk_route(logits, top_k)
+    flat_e = experts.reshape(-1)                    # (T*k,)
+    sizes = jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1)
+    lb, z = router_losses(logits, probs, sizes)
+
+    order = jnp.argsort(flat_e, stable=True)        # assignments by expert
+    xs = x[order // top_k]                          # (T*k, D) sorted rows
+    h = jax.nn.silu(_grouped(xs, gate, sizes)) * _grouped(xs, up, sizes)
+    out = _grouped(h, down, sizes)                  # (T*k, D)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * top_k))
+    out = out[back].reshape(t, top_k, d)
+    y = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1)
+    return y.astype(x.dtype), lb, z, sizes
 
 
 def moe_forward(params: Dict, cfg: MoEConfig, tokens: jax.Array,

@@ -112,6 +112,58 @@ def _dense(x, w):
         precision=matmul_precision())
 
 
+# --------------------------------------------------------------------------- #
+# The modern block's pieces (RMSNorm, rotary positions, QK-norm): what the
+# token layers of core/layers.py wrap, beside the GPT-2 pieces above.
+# --------------------------------------------------------------------------- #
+
+def rms_norm(x: jax.Array, g: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """x * rsqrt(mean(x^2) + eps) * g over the last axis; statistics and
+    the gain in f32, the result back in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + eps) * g.astype(jnp.float32)) \
+        .astype(x.dtype)
+
+
+def rope_tables(seq: int, d_head: int, theta: float = 10000.0):
+    """(cos, sin), each (seq, d_head) f32, of the rotate-half convention:
+    frequency i serves dims i and i + d_head/2."""
+    inv = 1.0 / theta ** (np.arange(0, d_head, 2) / d_head)  # host numpy
+    ang = np.arange(seq)[:, None] * inv[None, :]
+    ang = np.concatenate([ang, ang], axis=-1)
+    return (jnp.asarray(np.cos(ang), jnp.float32),
+            jnp.asarray(np.sin(ang), jnp.float32))
+
+
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate-half RoPE on x (B, H, S, Dh): x*cos + rotate_half(x)*sin,
+    in f32, back in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos + rot * sin).astype(x.dtype)
+
+
+def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
+                   rope_theta: float = 10000.0) -> jax.Array:
+    """q, k, v (B, S, D) -> (B, S, D): split into ``n_heads`` heads, rotary
+    positions on q and k, causal softmax(q k^T / sqrt(Dh)) v, heads merged. The
+    Pallas flash kernel where the sequence tiles (``maybe_flash_attention``),
+    the dense op elsewhere."""
+    b, s, d = q.shape
+    d_head = d // n_heads
+
+    def heads(t):
+        return t.reshape(b, s, n_heads, d_head).swapaxes(1, 2)
+
+    cos, sin = rope_tables(s, d_head, rope_theta)
+    att = maybe_flash_attention(apply_rope(heads(q), cos, sin),
+                                apply_rope(heads(k), cos, sin), heads(v),
+                                causal=True)
+    return att.swapaxes(1, 2).reshape(b, s, d)
+
+
 def attention_sublayer(cfg: TransformerConfig, x: jax.Array, blk: Dict,
                        *, seq_axis: Optional[str] = None) -> jax.Array:
     """ln1 -> fused qkv -> (flash | ring) attention -> wo residual. Shared

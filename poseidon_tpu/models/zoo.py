@@ -434,3 +434,83 @@ ZOO.update({
     "rcnn_ilsvrc13": (rcnn_ilsvrc13, caffenet_shapes),
     "finetune_flickr_style": (finetune_flickr_style, caffenet_shapes),
 })
+
+
+# --------------------------------------------------------------------------- #
+# OLMoE (arXiv:2409.02060): a token model as layers of the Net. The
+# published 1B-7B sizes are the defaults; tests pass small ones.
+# --------------------------------------------------------------------------- #
+
+def olmoe(batch: int = 2, source: str = "examples/lm/olmoe_tokens.txt",
+          n_layers: int = 16, hidden: int = 2048, heads: int = 16,
+          experts: int = 64, top_k: int = 8, expert_width: int = 1024,
+          vocab: int = 50304, rope_theta: float = 10000.0,
+          eps: float = 1e-5, init_std: float = 0.02,
+          balance_weight: float = 0.01, z_weight: float = 0.001,
+          name: str = "OLMoE-1B-7B") -> NetParameter:
+    """Pre-norm block with QK-norm over the full width, rotate-half RoPE,
+    top-k dropless SiLU-gated experts; RMSNorm gains carry decay_mult 0,
+    every matrix decay_mult 1 (AdamW's decoupled decay, solvers/updates.py).
+    The data layer's tops are ``tokens`` and ``targets``, both (N, S)."""
+    from ..proto.messages import (AttentionParameter, EltwiseParameter,
+                                  EmbedParameter, HDF5DataParameter,
+                                  MoEParameter, RMSNormParameter,
+                                  SoftmaxParameter)
+    w = gaussian(init_std)
+    layers: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+
+    def norm(lname, bottom, top):
+        layers.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[ParamSpec(lr_mult=1.0, decay_mult=0.0)],
+            rms_norm_param=RMSNormParameter(eps=eps)))
+
+    def proj(lname, bottom, top, n_out):
+        layers.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2, weight_filler=w)))
+
+    def add(lname, a, b, top):
+        layers.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation="SUM")))
+
+    layers.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["x0"],
+        embed_param=EmbedParameter(input_dim=vocab, num_output=hidden,
+                                   weight_filler=w)))
+    x = "x0"
+    for i in range(n_layers):
+        p = f"l{i}_"
+        norm(p + "attn_norm", x, p + "a")
+        for t in "qkv":
+            proj(p + t, p + "a", p + t, hidden)
+        norm(p + "q_norm", p + "q", p + "qn")
+        norm(p + "k_norm", p + "k", p + "kn")
+        layers.append(LayerParameter(
+            name=p + "attn", type="ATTENTION",
+            bottom=[p + "qn", p + "kn", p + "v"], top=[p + "att"],
+            attention_param=AttentionParameter(
+                num_heads=heads, rope_theta=rope_theta)))
+        proj(p + "o", p + "att", p + "ao", hidden)
+        add(p + "res1", x, p + "ao", p + "h")
+        norm(p + "ffn_norm", p + "h", p + "u")
+        layers.append(LayerParameter(
+            name=p + "moe", type="MOE", bottom=[p + "u"],
+            top=[p + "m", p + "balance_loss", p + "z_loss",
+                 p + "expert_load", p + "dropped"],
+            loss_weight=[0.0, balance_weight, z_weight, 0.0, 0.0],
+            moe_param=MoEParameter(num_experts=experts, top_k=top_k,
+                                   expert_width=expert_width,
+                                   weight_filler=w)))
+        add(p + "res2", p + "h", p + "m", p + "y")
+        x = p + "y"
+    norm("final_norm", x, "xf")
+    proj("lm_head", "xf", "logits", vocab)
+    layers.append(LayerParameter(
+        name="lm_loss", type="SOFTMAX_LOSS", bottom=["logits", "targets"],
+        top=["lm_loss"], softmax_param=SoftmaxParameter(axis=-1)))
+    return NetParameter(name=name, layers=layers)

@@ -44,6 +44,20 @@ def softmax_loss(logits, labels):
     return -jnp.sum(picked) / (n * h * w)
 
 
+def softmax_loss_last_axis(logits, labels):
+    """Classes on the LAST axis: logits (..., C) against integer labels
+    (...), the mean over every leading position — the token model's form
+    ((batch, sequence, vocabulary) against (batch, sequence)). Same clamp
+    as ``softmax_loss``; statistics in f32, and no (…, C) log-probability
+    array is formed: the loss is logsumexp minus the picked logit."""
+    labels = labels.reshape(logits.shape[:-1]).astype(jnp.int32)
+    x = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(x, axis=-1)
+    picked = jnp.take_along_axis(x, labels[..., None], axis=-1)[..., 0]
+    picked = jnp.maximum(picked - lse, jnp.log(_FLT_MIN))
+    return -jnp.mean(picked)
+
+
 def multinomial_logistic_loss(probs, labels):
     labels = labels.reshape(labels.shape[0]).astype(jnp.int32)
     p = probs.reshape(probs.shape[0], -1)

@@ -439,27 +439,38 @@ def pick_block(s: int) -> Optional[int]:
     return next((bs for bs in (128, 64, 32, 16, 8) if s % bs == 0), None)
 
 
+def attention_route(s: int, sk: Optional[int] = None):
+    """``(arm, note)`` for one attention geometry — THE routing decision:
+    ``maybe_flash_attention`` takes it at trace time and ``Net`` logs it
+    per ATTENTION layer at construction. ``"pallas_flash"`` when the
+    sequence tiles cleanly (divisible by a 128/64/32-row block,
+    self-attention lengths); ``"dense"`` on the CPU test mesh (the kernel
+    would run in interpret-mode emulation — strictly slower than the dense
+    op it replaces) and for shapes the kernel does not tile."""
+    block = pick_block(s)
+    if _interpret_default():
+        return "dense", "cpu backend"
+    if sk is not None and sk != s:
+        return "dense", "cross-attention lengths"
+    if block is None:
+        return "dense", f"no aligned block divides S={s}"
+    return "pallas_flash", f"block {block}"
+
+
 def maybe_flash_attention(q, k, v, causal: bool = False,
                           scale: Optional[float] = None) -> jax.Array:
-    """Route through the Pallas flash kernel when shapes tile cleanly
-    (seq divisible by a 128/64/32-row block, self-attention layout), else
-    the dense reference op — and say which, once per shape. The training
-    entry point for models/transformer.py and the Ulysses head-parallel
-    path."""
+    """Attention through :func:`attention_route`'s arm — and say which,
+    once per shape. The training entry point for models/transformer.py
+    (both blocks) and the Ulysses head-parallel path."""
     from .attention import attention
     s = q.shape[-2]
-    block = pick_block(s)
-    # on the CPU test mesh the kernel would run in interpret-mode
-    # emulation — strictly slower than the dense op it replaces
-    why = ("cpu backend" if _interpret_default()
-           else "cross-attention lengths" if k.shape[-2] != s
-           else f"no aligned block divides S={s}" if block is None
-           else "")
+    arm, note = attention_route(s, k.shape[-2])
     where = f"[kernel_route] attention S={s} D={q.shape[-1]}"
-    if not why:
+    if arm == "pallas_flash":
+        block = pick_block(s)
         _log_route_once(f"{where}: pallas flash, block {block}")
         return flash_attention(q, k, v, causal, scale, block, block)
-    _log_route_once(f"{where}: dense ({why})")
+    _log_route_once(f"{where}: dense ({note})")
     return attention(q, k, v, causal=causal, scale=scale)
 
 

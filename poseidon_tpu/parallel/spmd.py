@@ -752,6 +752,10 @@ def build_spmd_train_step(
 
     comm = comm or CommConfig()
     comm.wire_jnp_dtype()
+    if sp.solver_type == "ADAM" or sp.clip_gradients > 0:
+        raise ValueError("solver_type ADAM and clip_gradients run on the "
+                         "flat data mesh only: the fsdp-sharded update "
+                         "carries one history buffer and no global norm")
     for axis in SPMD_AXES:
         if axis not in mesh.shape:
             raise ValueError(f"plan mesh needs axis {axis!r}; build it "

@@ -80,8 +80,8 @@ def reconcile_comm_error(params, err: Dict, comm: Optional[CommConfig],
 
 
 def init_train_state(params, comm: Optional[CommConfig] = None,
-                     n_dev: int = 1) -> TrainState:
-    return TrainState(solver=init_state(params),
+                     n_dev: int = 1, solver_type: str = "SGD") -> TrainState:
+    return TrainState(solver=init_state(params, solver_type),
                       comm_error=init_comm_error(params, comm, n_dev))
 
 
@@ -265,8 +265,11 @@ def build_train_step(
     # (per-backward chained taps) takes precedence on the per-step path;
     # under iter_size > 1 there is no per-backward exchange, so the
     # accumulated sync rides the arena buckets either way.
-    dense_layers = [l for l in net.param_defs
-                    if comm.strategy_for(l) == DENSE]
+    # (a layer holding a leaf of hundreds of MB keeps the per-leaf rule and
+    # its per-leaf gradient tap: core/arena.fits_arena)
+    from ..core.arena import fits_arena
+    dense_layers = [l for l, defs in net.param_defs.items()
+                    if comm.strategy_for(l) == DENSE and fits_arena(defs)]
     arena = None
     if comm.param_arena and dense_layers and \
             (comm.dwbp_bucket_mb is None or iter_size > 1):
@@ -695,6 +698,10 @@ def build_ssp_train_step(
     it runs each FC layer at effective staleness 0).
     """
     import dataclasses
+    if sp.solver_type == "ADAM" or sp.clip_gradients > 0:
+        raise ValueError("solver_type ADAM and clip_gradients are not "
+                         "supported under SSP staleness (per-device "
+                         "histories carry one buffer, no global norm)")
     comm = comm or CommConfig()
     comm.wire_jnp_dtype()  # fail loudly on a bad wire_dtype string
     axis = comm.axis

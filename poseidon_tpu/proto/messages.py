@@ -271,6 +271,38 @@ class InnerProductParameter:
     bias_term: bool = True
     weight_filler: FillerParameter = field(default_factory=FillerParameter)
     bias_filler: FillerParameter = field(default_factory=FillerParameter)
+    # Caffe's later inner_product_param.axis: the first axis folded into the
+    # product; axes before it are kept (2 for a (batch, sequence, feature)
+    # blob: one product per token)
+    axis: int = 1
+
+
+@dataclass
+class EmbedParameter:
+    """Caffe's embed_param: a lookup of ``input_dim`` rows of ``num_output``
+    (no bias)."""
+    num_output: int = 0
+    input_dim: int = 0
+    weight_filler: FillerParameter = field(default_factory=FillerParameter)
+
+
+@dataclass
+class RMSNormParameter:
+    eps: float = 1e-5
+
+
+@dataclass
+class AttentionParameter:
+    num_heads: int = 1
+    rope_theta: float = 10000.0
+
+
+@dataclass
+class MoEParameter:
+    num_experts: int = 0
+    top_k: int = 1
+    expert_width: int = 0
+    weight_filler: FillerParameter = field(default_factory=FillerParameter)
 
 
 @dataclass
@@ -340,6 +372,9 @@ class SliceParameter:
 @dataclass
 class SoftmaxParameter:
     engine: str = "DEFAULT"
+    # Caffe's later softmax_param.axis, read by SOFTMAX_LOSS: the class axis
+    # (-1 for the (batch, sequence, vocabulary) logits of a token model)
+    axis: int = 1
 
 
 @dataclass
@@ -381,7 +416,8 @@ V2_TYPE_TO_V1 = {
     "SigmoidCrossEntropyLoss": "SIGMOID_CROSS_ENTROPY_LOSS", "Silence": "SILENCE",
     "Softmax": "SOFTMAX", "SoftmaxWithLoss": "SOFTMAX_LOSS", "Split": "SPLIT",
     "Slice": "SLICE", "TanH": "TANH", "WindowData": "WINDOW_DATA",
-    "Threshold": "THRESHOLD",
+    "Threshold": "THRESHOLD", "Embed": "EMBED", "RMSNorm": "RMS_NORM",
+    "Attention": "ATTENTION", "MoE": "MOE",
 }
 V1_TYPES = set(V2_TYPE_TO_V1.values()) | {"NONE"}
 
@@ -438,6 +474,10 @@ class LayerParameter:
     threshold_param: ThresholdParameter = field(default_factory=ThresholdParameter)
     window_data_param: WindowDataParameter = field(default_factory=WindowDataParameter)
     transform_param: TransformationParameter = field(default_factory=TransformationParameter)
+    embed_param: EmbedParameter = field(default_factory=EmbedParameter)
+    rms_norm_param: RMSNormParameter = field(default_factory=RMSNormParameter)
+    attention_param: AttentionParameter = field(default_factory=AttentionParameter)
+    moe_param: MoEParameter = field(default_factory=MoEParameter)
     blob_mode: str = "GLOBAL"  # Poseidon extension on LayerParameter level
 
     def canonical_type(self) -> str:
@@ -568,6 +608,8 @@ class SolverParameter:
     random_seed: int = -1
     solver_type: str = "SGD"
     delta: float = 1e-8
+    momentum2: float = 0.999     # ADAM's beta2 (Caffe's field)
+    clip_gradients: float = -1.0  # global L2-norm clip; < 0 = off (Caffe's)
     debug_info: bool = False
     iter_size: int = 1
 

@@ -358,7 +358,7 @@ def cmd_time(args) -> int:
             if lp.canonical_type() in DATA_SOURCE_TYPES:
                 from ..data.pipeline import layer_batch_size
                 b = layer_batch_size(lp) or args.batch_size
-                chw = None
+                chw, label_shape = None, ()
                 src = (lp.data_param.source or lp.image_data_param.source
                        or lp.hdf5_data_param.source
                        or lp.window_data_param.source)
@@ -369,8 +369,10 @@ def cmd_time(args) -> int:
                         from ..data.pipeline import build_source
                         from ..data.workload import Shard
                         s = build_source(lp, Shard(0, 1))
-                        arr, _ = s.read(0)
+                        arr, lab = s.read(0)
                         chw = arr.shape
+                        if s.tokens:    # a target per position
+                            label_shape = tuple(lab.shape)
                     except Exception:
                         chw = None
                 if chw is None:
@@ -381,7 +383,7 @@ def cmd_time(args) -> int:
                            lp.transform_param.crop_size)
                 shapes[lp.top[0]] = (b,) + tuple(chw)
                 if len(lp.top) > 1:
-                    shapes[lp.top[1]] = (b,)
+                    shapes[lp.top[1]] = (b,) + label_shape
         net = Net(net_param, "TRAIN", source_shapes=shapes)
     # the benchmark batch is whatever the model actually declares
     batch = net.blob_shapes[net.input_names[0]][0]

@@ -464,7 +464,7 @@ class Engine:
                                         self.comm)
         else:
             self.state = init_train_state(self.params, self.comm,
-                                          self.err_groups)
+                                          self.err_groups, sp.solver_type)
         # single-batch placement spec (test/eval batches and non-accumulated
         # train steps): the train step's input sharding minus the leading
         # [iter_size] micro-batch axis it gains under gradient accumulation
@@ -643,7 +643,8 @@ class Engine:
                                       jax.random.PRNGKey(0))
         groups = comm_error_groups(self.comm, self.mesh)
         state_avals = jax.eval_shape(
-            lambda p: init_train_state(p, self.comm, groups), params_avals)
+            lambda p: init_train_state(p, self.comm, groups,
+                                       self.sp.solver_type), params_avals)
         batch_avals = {}
         for k, s in self._train_shapes.items():
             g = (int(s[0]) * self.n_dev,) + tuple(int(d) for d in s[1:])

@@ -6,8 +6,19 @@ Spec: ``/root/reference/src/caffe/solver.cpp``
             (ComputeUpdateValue, solver.cpp:815-900)
 - Nesterov: h' = m*h + local_lr*g'; w -= (1+m)*h' - m*h     (solver.cpp:1013)
 - AdaGrad:  h += g'^2; w -= local_lr * g' / (sqrt(h)+delta) (solver.cpp:1240)
+- Adam:     Caffe's fields (momentum = beta1, momentum2 = beta2, delta = eps),
+            bias-corrected, with DECOUPLED weight decay (AdamW, what token
+            models train with — not Caffe's coupled L2): m = b1 m + (1-b1) g;
+            v = b2 v + (1-b2) g^2; w -= local_lr * ((m/(1-b1^t)) /
+            (sqrt(v/(1-b2^t)) + delta) + local_decay * w), t = iter + 1.
+            Two f32 moments per parameter: ``history`` is {"m": tree,
+            "v": tree}.
 Regularization: L2 adds decay*w to the gradient, L1 adds decay*sign(w);
 local_lr = base_rate * lr_mult, local_decay = weight_decay * decay_mult.
+``clip_gradients`` > 0 (Caffe's ClipGradients, solver.cpp) scales every
+gradient by clip / ||g|| when the global L2 norm over ALL parameters
+exceeds it, before regularization; off (the default) leaves the rule's
+arithmetic untouched.
 
 Iteration is carried as a traced scalar so the whole update compiles into the
 training step; LR schedules use only XLA-friendly math.
@@ -45,12 +56,52 @@ def learning_rate(sp: SolverParameter, it: jax.Array) -> jax.Array:
         steps = jnp.asarray(sp.stepvalue, jnp.float32)
         current_step = jnp.sum(it >= steps).astype(jnp.float32)
         return base * jnp.power(sp.gamma, current_step)
+    if policy == "cosine":
+        # linear warm-up over `stepsize` iterations, then a half cosine
+        # from base_lr down to gamma * base_lr at max_iter
+        frac = jnp.clip((it - sp.stepsize) / max(1, sp.max_iter - sp.stepsize),
+                        0.0, 1.0)
+        decayed = sp.gamma + (1.0 - sp.gamma) * 0.5 * (1.0 + jnp.cos(
+            jnp.pi * frac))
+        warm = jnp.minimum(1.0, (it + 1.0) / max(1, sp.stepsize))
+        return base * warm * decayed
     raise ValueError(f"unknown lr_policy {policy!r}")
 
 
 class SolverState(NamedTuple):
     it: jax.Array           # current iteration (traced scalar, int32)
-    history: Dict           # momentum / accumulated squared grads, like params
+    # momentum / accumulated squared grads, a tree like params; under ADAM
+    # {"m": tree, "v": tree}
+    history: Dict
+
+
+SOLVER_TYPES = ("SGD", "NESTEROV", "ADAGRAD", "ADAM")
+
+
+def _adam(sp: SolverParameter, w, g, m, v, local_rate, local_decay, t):
+    """One AdamW step on a leaf (scalar rate/decay) or on the flat buffer
+    (multiplier vectors): the same elementwise arithmetic either way."""
+    b1, b2 = sp.momentum, sp.momentum2
+    m_new = b1 * m + (1.0 - b1) * g
+    v_new = b2 * v + (1.0 - b2) * (g * g)
+    m_hat = m_new / (1.0 - jnp.power(jnp.float32(b1), t))
+    v_hat = v_new / (1.0 - jnp.power(jnp.float32(b2), t))
+    step = local_rate * (m_hat / (jnp.sqrt(v_hat) + sp.delta)
+                         + local_decay * w)
+    return (w - step).astype(w.dtype), m_new, v_new
+
+
+def clip_scale(sp: SolverParameter, *grad_trees):
+    """Caffe's ClipGradients: None when off, else the factor (<= 1) that
+    brings the global L2 norm of every gradient in ``grad_trees`` down to
+    ``clip_gradients``."""
+    if sp.clip_gradients <= 0:
+        return None
+    sumsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                for g in jax.tree_util.tree_leaves(grad_trees))
+    norm = jnp.sqrt(sumsq)
+    return jnp.where(norm > sp.clip_gradients,
+                     sp.clip_gradients / norm, 1.0)
 
 
 def _regularized(g, w, local_decay: float, reg_type: str):
@@ -64,10 +115,28 @@ def _regularized(g, w, local_decay: float, reg_type: str):
 
 
 def _leafwise_update(sp: SolverParameter, mults, rate, params, grads,
-                     history):
+                     history, it=None, scale=None):
     """One optimizer step over a per-leaf tree (the classic path; also the
-    per-leaf remainder — SFB/TOPK/LOCAL opt-outs — of an arena step)."""
+    per-leaf remainder — SFB/TOPK/LOCAL opt-outs — of an arena step).
+    ``it`` (ADAM's bias correction) and ``scale`` (the clip factor) are
+    the caller's: both span the whole parameter set, not this tree."""
     solver_type = sp.solver_type
+    if solver_type == "ADAM":
+        t = (it + 1).astype(jnp.float32)
+        new_params, new_m, new_v = {}, {}, {}
+        for lname, lparams in params.items():
+            new_params[lname], new_m[lname], new_v[lname] = {}, {}, {}
+            for pname, w in lparams.items():
+                g = grads[lname][pname].astype(jnp.float32)
+                if scale is not None:
+                    g = g * scale
+                lr_mult, decay_mult = mults[lname][pname]
+                new_params[lname][pname], new_m[lname][pname], \
+                    new_v[lname][pname] = _adam(
+                        sp, w, g, history["m"][lname][pname],
+                        history["v"][lname][pname], rate * lr_mult,
+                        sp.weight_decay * decay_mult, t)
+        return new_params, {"m": new_m, "v": new_v}
     momentum = sp.momentum
     weight_decay = sp.weight_decay
     reg_type = sp.regularization_type
@@ -83,7 +152,10 @@ def _leafwise_update(sp: SolverParameter, mults, rate, params, grads,
             local_rate = rate * lr_mult
             local_decay = weight_decay * decay_mult
             h = history[lname][pname]
-            g = _regularized(g.astype(jnp.float32), w, local_decay, reg_type)
+            g = g.astype(jnp.float32)
+            if scale is not None:
+                g = g * scale
+            g = _regularized(g, w, local_decay, reg_type)
             if solver_type == "SGD":
                 h_new = momentum * h + local_rate * g
                 step = h_new
@@ -112,8 +184,9 @@ def make_update_fn(sp: SolverParameter, mults: Dict[str, Dict[str, tuple]]):
         # the attribution residual (runtime/attribution.py)
         with jax.named_scope("optimizer_update"):
             rate = learning_rate(sp, state.it)
-            new_params, new_hist = _leafwise_update(sp, mults, rate, params,
-                                                    grads, state.history)
+            new_params, new_hist = _leafwise_update(
+                sp, mults, rate, params, grads, state.history, state.it,
+                clip_scale(sp, grads))
             return new_params, SolverState(it=state.it + 1, history=new_hist)
 
     return update
@@ -126,19 +199,27 @@ def make_flat_update_rule(sp: SolverParameter):
     precomputed full-buffer vectors; the SPMD sharded step
     (parallel/spmd.py) instead feeds each device its fsdp SHARD of the
     vectors, so the update touches 1/fsdp of the buffer per device with
-    identical elementwise math."""
+    identical elementwise math. Under ADAM ``flat_h`` is the pair
+    (flat_m, flat_v) and the rule takes the iteration as ``it=``."""
     solver_type = sp.solver_type
     momentum = sp.momentum
     reg_type = sp.regularization_type
     delta = sp.delta
-    if solver_type not in ("SGD", "NESTEROV", "ADAGRAD"):
+    if solver_type not in SOLVER_TYPES:
         raise ValueError(f"unknown solver_type {solver_type!r}")
     if reg_type not in ("L2", "L1"):
         raise ValueError(f"unknown regularization_type {reg_type!r}")
 
-    def fused(flat_w, flat_g, flat_h, rate, lr_vec, decay_vec):
+    def fused(flat_w, flat_g, flat_h, rate, lr_vec, decay_vec, it=None,
+              scale=None):
         local_rate = rate * lr_vec
         g = flat_g.astype(jnp.float32)
+        if scale is not None:
+            g = g * scale
+        if solver_type == "ADAM":
+            new_w, m, v = _adam(sp, flat_w, g, *flat_h, local_rate,
+                                decay_vec, (it + 1).astype(jnp.float32))
+            return new_w, (m, v)
         if solver_type == "SGD" and reg_type == "L2":
             from ..ops.pallas_kernels import maybe_fused_sgd
             r = maybe_fused_sgd(flat_w, g, flat_h, local_rate, decay_vec,
@@ -180,9 +261,9 @@ def make_fused_update_fn(sp: SolverParameter, layout):
     rule = make_flat_update_rule(sp)
     lr_np, decay_np = layout.mult_vectors(sp.weight_decay)
 
-    def fused(flat_w, flat_g, flat_h, rate):
+    def fused(flat_w, flat_g, flat_h, rate, it=None, scale=None):
         return rule(flat_w, flat_g, flat_h, rate, jnp.asarray(lr_np),
-                    jnp.asarray(decay_np))
+                    jnp.asarray(decay_np), it=it, scale=scale)
 
     return fused
 
@@ -198,22 +279,41 @@ def make_arena_update_fn(sp: SolverParameter, mults, layout):
     (snapshots never see the packed form); it is packed here for the fused
     pass and unpacked into the returned state."""
     fused = make_fused_update_fn(sp, layout)
+    adam = sp.solver_type == "ADAM"
 
     def update(flat_w, flat_g, excl_params, excl_grads, state: SolverState):
         with jax.named_scope("optimizer_update"):
             rate = learning_rate(sp, state.it)
-            flat_h = layout.pack(state.history)
-            new_flat_w, new_flat_h = fused(flat_w, flat_g, flat_h, rate)
-            excl_hist = layout.residual(state.history)
+            scale = clip_scale(sp, flat_g, excl_grads)
+            hist = state.history
+            if adam:   # two moments: each packed, stepped and unpacked
+                flat_h = tuple(layout.pack(hist[k]) for k in ("m", "v"))
+                excl_hist = {k: layout.residual(hist[k]) for k in ("m", "v")}
+            else:
+                flat_h = layout.pack(hist)
+                excl_hist = layout.residual(hist)
+            new_flat_w, new_flat_h = fused(flat_w, flat_g, flat_h, rate,
+                                           it=state.it, scale=scale)
             new_excl, new_excl_hist = _leafwise_update(
-                sp, mults, rate, excl_params, excl_grads, excl_hist)
+                sp, mults, rate, excl_params, excl_grads, excl_hist,
+                state.it, scale)
             new_params = layout.merge(layout.unpack(new_flat_w), new_excl)
-            new_hist = layout.merge(layout.unpack(new_flat_h), new_excl_hist)
+            if adam:
+                new_hist = {k: layout.merge(layout.unpack(f),
+                                            new_excl_hist[k])
+                            for k, f in zip(("m", "v"), new_flat_h)}
+            else:
+                new_hist = layout.merge(layout.unpack(new_flat_h),
+                                        new_excl_hist)
             return new_params, SolverState(it=state.it + 1, history=new_hist)
 
     return update
 
 
-def init_state(params) -> SolverState:
-    history = jax.tree_util.tree_map(jnp.zeros_like, params)
+def init_state(params, solver_type: str = "SGD") -> SolverState:
+    """Zero history shaped for ``solver_type``: one tree like ``params``,
+    or ADAM's two moments."""
+    zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)  # noqa: E731
+    history = {"m": zeros(), "v": zeros()} if solver_type == "ADAM" \
+        else zeros()
     return SolverState(it=jnp.zeros((), jnp.int32), history=history)

@@ -96,3 +96,96 @@ def test_flash_kernels_mosaic_compile_for_v5e():
     assert "OK fwd" in r.stdout and "OK bwd" in r.stdout \
         and "OK lrn" in r.stdout and "OK lrn_bwd" in r.stdout \
         and "OK pool_bwd" in r.stdout
+
+
+# The full-width, one-layer OLMoE train step (examples/lm/olmoe_1b_7b_*) as
+# `train --bf16` builds it, for one abstract v5e chip: the kernels that must
+# be in it, and the compiler's memory accounting that sized the cell's batch
+# (benchmark/cells/olmoe.l1.pack4k.json).
+_OLMOE_STEP = r"""
+import json, os, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ["POSEIDON_FORCE_PALLAS"] = "1"      # lower as for the TPU
+sys.path.insert(0, {repo!r})
+import jax, jax.numpy as jnp, numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("SKIP:", e)
+    sys.exit(3)
+from poseidon_tpu.config import set_perf_policy
+from poseidon_tpu.core.net import Net
+from poseidon_tpu.parallel import (CommConfig, build_train_step,
+                                   init_train_state)
+from poseidon_tpu.proto.messages import load_net, load_solver
+set_perf_policy()
+batch, seq = {batch}, 4096
+sp = load_solver(os.path.join({repo!r},
+                              "examples/lm/olmoe_1b_7b_solver.prototxt"))
+net = Net(load_net(os.path.join({repo!r}, sp.net)), "TRAIN",
+          source_shapes={{"tokens": (batch, seq), "targets": (batch, seq)}})
+mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+comm = CommConfig()
+ts = build_train_step(net, sp, mesh, comm, donate=True, donate_batch=True)
+rep = NamedSharding(mesh, P())
+shaped = lambda t, sh: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), t)
+params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+state = jax.eval_shape(
+    lambda p: init_train_state(p, comm, 1, sp.solver_type), params)
+tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                              sharding=ts.batch_sharding)
+compiled = ts.lowerable.lower(
+    shaped(params, rep), shaped(state, rep),
+    {{"tokens": tokens, "targets": tokens}},
+    jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
+ma, text = compiled.memory_analysis(), compiled.as_text()
+print("RESULT " + json.dumps({{
+    "parameters": net.param_count(),
+    "arena_parameters": ts.arena.total if ts.arena else 0,
+    "routes": net.kernel_routes,
+    "pallas_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+    "ragged_dot_fusions": text.count("ragged_dot_tiling"),
+    "dense_expert_dots": sum(
+        1 for l in text.splitlines()
+        if " dot(" in l or " convolution(" in l
+        if "[8192,64,1024]" in l or "[64,8192,1024]" in l),
+    "argument_gb": ma.argument_size_in_bytes / 1e9,
+    "temp_gb": ma.temp_size_in_bytes / 1e9,
+    "total_gb": (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                 - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 1e9}}))
+"""
+
+
+@pytest.mark.slow
+def test_olmoe_full_width_step_compiles_for_one_v5e():
+    """The flash kernels and the grouped matmul are in the compiled step
+    (no dense 64-expert fallback, no CPU or interpret arm), the huge leaves
+    stay out of the arena, and the step fits one chip at the cell's batch:
+    under 85% of the 16.9 GB the compiler allows (PR 22's sizing rule)."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _OLMOE_STEP.format(repo=REPO, batch=2)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next batch
+    assert got["parameters"] == 625_616_896
+    assert got["routes"] == {"l0_attn": "attention=pallas_flash",
+                             "l0_moe": "grouped_matmul=ragged_dot"}
+    assert got["pallas_custom_calls"] >= 3       # flash fwd, dq, dkv
+    assert got["ragged_dot_fusions"] >= 3        # gate, up, down (+ bwd)
+    assert got["dense_expert_dots"] == 0
+    # attention projections, router-free: only the small layers are packed
+    assert got["arena_parameters"] == 4 * 2048 * 2048 + 5 * 2048
+    assert 7.0 < got["argument_gb"] < 8.0        # weights + two moments
+    assert got["total_gb"] < 0.85 * 16.9

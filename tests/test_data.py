@@ -248,3 +248,36 @@ def test_libsvm_parser(tmp_path):
     assert dense.shape == (3, 4)
     np.testing.assert_allclose(dense[0], [0.5, 0, 1.5, 0])
     np.testing.assert_allclose(dense[1], [0, 2.0, 0, 0])
+
+
+def test_token_source_through_batch_pipeline(tmp_path):
+    """An HDF5 file of 2-D integer ``data`` and ``label`` is a token file:
+    each record is seq_len ids and seq_len targets, int32 end to end, read
+    by the same BatchPipeline and build_phase_pipelines as the images."""
+    import h5py
+    from poseidon_tpu.data.pipeline import build_phase_pipelines
+    from poseidon_tpu.data.sources import HDF5Source
+    from poseidon_tpu.proto.messages import load_net_from_string
+    ids = np.arange(6 * 16, dtype=np.int64).reshape(6, 16) % 50304
+    with h5py.File(tmp_path / "tok.h5", "w") as h:
+        h["data"] = ids
+        h["label"] = np.roll(ids, -1, axis=1)
+    (tmp_path / "tok.txt").write_text(str(tmp_path / "tok.h5") + "\n")
+    src = HDF5Source(str(tmp_path / "tok.txt"))
+    assert src.tokens and len(src) == 6 and src.record_shape == (16,)
+    net = load_net_from_string(f"""
+        layers {{ name: "t" type: HDF5_DATA top: "tokens" top: "targets"
+                 hdf5_data_param {{ source: "{tmp_path / 'tok.txt'}"
+                                    batch_size: 2 }} }}""")
+    pipes, shapes = build_phase_pipelines(net, "TEST", batch_multiplier=1)
+    assert shapes == {"tokens": (2, 16), "targets": (2, 16)}
+    seen = []
+    for _ in range(3):
+        b = next(pipes[0])
+        assert b["tokens"].dtype == b["targets"].dtype == np.int32
+        assert b["tokens"].shape == b["targets"].shape == (2, 16)
+        np.testing.assert_array_equal(b["targets"],
+                                      np.roll(b["tokens"], -1, axis=1))
+        seen.extend(b["tokens"][:, 0].tolist())
+    assert sorted(seen) == ids[:, 0].tolist()   # one epoch, every record
+    pipes[0].close()

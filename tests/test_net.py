@@ -243,3 +243,115 @@ def test_shared_weights_siamese():
     np.testing.assert_array_equal(exported["ip_a"][0], exported["ip_b"][0])
     reloaded = net.load_weights(net.init(jax.random.PRNGKey(9)), exported)
     np.testing.assert_array_equal(np.asarray(reloaded["ip_a"]["w"]), w)
+
+
+# --------------------------------------------------------------------------- #
+# token-model layers: (batch, sequence, feature) blobs
+# --------------------------------------------------------------------------- #
+
+def _token_net(extra=""):
+    from poseidon_tpu.proto.messages import load_net_from_string as load
+    return Net(load("""
+        layers { name: "embed" type: EMBED bottom: "tokens" top: "x"
+                 embed_param { input_dim: 97 num_output: 32
+                               weight_filler { type: "gaussian" std: 0.5 } } }
+        layers { name: "norm" type: RMS_NORM bottom: "x" top: "a"
+                 param { decay_mult: 0 } }
+        layers { name: "q" type: INNER_PRODUCT bottom: "a" top: "q"
+                 inner_product_param { num_output: 32 bias_term: false axis: 2
+                     weight_filler { type: "gaussian" std: 0.2 } } }
+        layers { name: "att" type: ATTENTION bottom: "q" bottom: "q"
+                 bottom: "a" top: "att" attention_param { num_heads: 4 } }
+        layers { name: "moe" type: MOE bottom: "att" top: "m" top: "bal"
+                 top: "z" loss_weight: 0 loss_weight: 0.01 loss_weight: 0.001
+                 moe_param { num_experts: 4 top_k: 2 expert_width: 16
+                     weight_filler { type: "gaussian" std: 0.2 } } }
+        layers { name: "head" type: INNER_PRODUCT bottom: "m" top: "logits"
+                 inner_product_param { num_output: 97 axis: 2
+                     weight_filler { type: "gaussian" std: 0.2 } } }
+        layers { name: "loss" type: SOFTMAX_LOSS bottom: "logits"
+                 bottom: "targets" top: "loss" softmax_param { axis: -1 } }
+        """ + extra), "TRAIN",
+        source_shapes={"tokens": (3, 8), "targets": (3, 8)})
+
+
+@pytest.mark.parametrize("blob,shape", [
+    ("x", (3, 8, 32)), ("a", (3, 8, 32)), ("q", (3, 8, 32)),
+    ("att", (3, 8, 32)), ("m", (3, 8, 32)), ("bal", ()), ("z", ()),
+    ("logits", (3, 8, 97)), ("loss", ())])
+def test_token_layer_shapes(blob, shape):
+    assert _token_net().blob_shapes[blob] == shape
+
+
+@pytest.mark.parametrize("layer,pname,shape,decay", [
+    ("embed", "w", (97, 32), 1.0), ("norm", "g", (32,), 0.0),
+    ("q", "w", (32, 32), 1.0), ("moe", "router", (4, 32), 1.0),
+    ("moe", "gate", (4, 16, 32), 1.0), ("moe", "up", (4, 16, 32), 1.0),
+    ("moe", "down", (4, 32, 16), 1.0), ("head", "w", (97, 32), 1.0),
+    ("head", "b", (97,), 1.0)])
+def test_token_layer_params(layer, pname, shape, decay):
+    net = _token_net()
+    pdef = next(p for p in net.param_defs[layer] if p.name == pname)
+    assert (pdef.shape, pdef.decay_mult) == (shape, decay)
+    if layer == "norm":     # gains start at one whatever the prototxt says
+        assert float(net.init(jax.random.PRNGKey(0))["norm"]["g"][0]) == 1.0
+
+
+def test_token_net_forward_pieces(rng_np):
+    """Each token layer against the arithmetic written out: the lookup, the
+    norm, a per-token product (axis 2, with a bias), the loss over the last
+    axis; the net's loss adds the two weighted MOE tops."""
+    net = _token_net()
+    params = net.init(jax.random.PRNGKey(1))
+    tok = jnp.asarray(rng_np.randint(0, 97, size=(3, 8)))
+    tgt = jnp.asarray(rng_np.randint(0, 97, size=(3, 8)))
+    out = net.apply(params, {"tokens": tok, "targets": tgt}, train=True,
+                    keep_blobs=True)
+    b = {k: np.asarray(v, np.float64) for k, v in out.blobs.items()}
+    emb = np.asarray(params["embed"]["w"], np.float64)
+    np.testing.assert_allclose(b["x"], emb[np.asarray(tok)], rtol=1e-6)
+    rms = np.sqrt((b["x"] ** 2).mean(-1, keepdims=True) + 1e-5)
+    np.testing.assert_allclose(b["a"], b["x"] / rms, rtol=1e-5)
+    np.testing.assert_allclose(
+        b["logits"], b["m"] @ np.asarray(params["head"]["w"], np.float64).T
+        + np.asarray(params["head"]["b"], np.float64), rtol=1e-4, atol=1e-5)
+    lse = np.log(np.exp(b["logits"]).sum(-1))
+    picked = np.take_along_axis(b["logits"], np.asarray(tgt)[..., None], -1)
+    np.testing.assert_allclose(b["loss"], (lse - picked[..., 0]).mean(),
+                               rtol=1e-5)
+    np.testing.assert_allclose(
+        float(out.loss), b["loss"] + 0.01 * b["bal"] + 0.001 * b["z"],
+        rtol=1e-5)
+    assert net.kernel_routes == {"att": "attention=dense",
+                                 "moe": "grouped_matmul=ragged_dot"}
+
+
+def test_attention_is_causal_and_positional(rng_np):
+    """A change to a later token leaves every earlier position's attention
+    output untouched (causal); rolling the sequence changes position 0's
+    (rotary positions are absolute in q.k only through their difference, so
+    a roll moves which keys precede it)."""
+    net = _token_net()
+    params = net.init(jax.random.PRNGKey(2))
+    tok = rng_np.randint(0, 97, size=(3, 8))
+    tgt = jnp.zeros((3, 8), jnp.int32)
+    run = lambda t: np.asarray(net.apply(  # noqa: E731
+        params, {"tokens": jnp.asarray(t), "targets": tgt}, train=True,
+        keep_blobs=True).blobs["att"])
+    base = run(tok)
+    later = tok.copy()
+    later[:, 5] = (later[:, 5] + 1) % 97
+    moved = run(later)
+    np.testing.assert_array_equal(moved[:, :5], base[:, :5])
+    assert np.abs(moved[:, 5:] - base[:, 5:]).max() > 1e-4
+
+
+def test_inner_product_axis_default_still_flattens():
+    """axis 1 (the default) is the classic flatten: a 4-D bottom becomes
+    (N, K), and its weight is (M, C*H*W)."""
+    from poseidon_tpu.proto.messages import load_net_from_string as load
+    net = Net(load("""layers { name: "ip" type: INNER_PRODUCT bottom: "data"
+        top: "ip" inner_product_param { num_output: 5 } }"""), "TRAIN",
+        source_shapes={"data": (2, 3, 4, 4)})
+    assert net.blob_shapes["ip"] == (2, 5)
+    assert net.param_defs["ip"][0].shape == (5, 48)

@@ -266,3 +266,59 @@ def test_v1_data_transform_migration():
     """)
     t = net.layers[0].transform_param
     assert t.scale == 0.5 and t.crop_size == 12 and t.mirror is True
+
+
+TOKEN_LAYERS = """
+layer { name: "embed" type: "Embed" bottom: "tokens" top: "x"
+        embed_param { input_dim: 512 num_output: 64
+                      weight_filler { type: "gaussian" std: 0.02 } } }
+layers { name: "n" type: RMS_NORM bottom: "x" top: "a"
+         param { decay_mult: 0 } rms_norm_param { eps: 1e-6 } }
+layers { name: "q" type: INNER_PRODUCT bottom: "a" top: "q"
+         inner_product_param { num_output: 64 bias_term: false axis: 2 } }
+layers { name: "att" type: ATTENTION bottom: "q" bottom: "q" bottom: "q"
+         top: "att" attention_param { num_heads: 4 rope_theta: 50000 } }
+layers { name: "moe" type: MOE bottom: "att" top: "m" top: "bal" top: "z"
+         loss_weight: 0 loss_weight: 0.01 loss_weight: 0.001
+         moe_param { num_experts: 8 top_k: 2 expert_width: 32 } }
+layers { name: "loss" type: SOFTMAX_LOSS bottom: "m" bottom: "targets"
+         top: "loss" softmax_param { axis: -1 } }
+"""
+
+
+@pytest.mark.parametrize("layer,ctype,field,want", [
+    ("embed", "EMBED", "embed_param.input_dim", 512),
+    ("embed", "EMBED", "embed_param.weight_filler.std", 0.02),
+    ("n", "RMS_NORM", "rms_norm_param.eps", 1e-6),
+    ("q", "INNER_PRODUCT", "inner_product_param.axis", 2),
+    ("q", "INNER_PRODUCT", "inner_product_param.bias_term", False),
+    ("att", "ATTENTION", "attention_param.num_heads", 4),
+    ("att", "ATTENTION", "attention_param.rope_theta", 50000.0),
+    ("moe", "MOE", "moe_param.top_k", 2),
+    ("moe", "MOE", "moe_param.expert_width", 32),
+    ("moe", "MOE", "moe_param.num_experts", 8),
+    ("moe", "MOE", "loss_weight", [0.0, 0.01, 0.001]),
+    ("loss", "SOFTMAX_LOSS", "softmax_param.axis", -1),
+])
+def test_parse_token_layers(layer, ctype, field, want):
+    """The token model's layer types and fields, in the V1 and the V2
+    spelling, and back out through net_to_prototxt unchanged."""
+    from poseidon_tpu.proto.messages import net_to_prototxt
+    net = load_net_from_string(TOKEN_LAYERS)
+    for n in (net, load_net_from_string(net_to_prototxt(net))):
+        lp = next(l for l in n.layers if l.name == layer)
+        assert lp.canonical_type() == ctype
+        got = lp
+        for part in field.split("."):
+            got = getattr(got, part)
+        assert got == want
+
+
+def test_parse_adam_solver_fields():
+    sp = load_solver_from_string(
+        'net: "x" solver_type: ADAM momentum: 0.9 momentum2: 0.95 '
+        'delta: 1e-8 clip_gradients: 1.0 lr_policy: "cosine"')
+    assert (sp.solver_type, sp.momentum2, sp.clip_gradients) == \
+        ("ADAM", 0.95, 1.0)
+    defaults = load_solver_from_string('net: "x"')
+    assert defaults.clip_gradients < 0 and defaults.momentum2 == 0.999
