@@ -1,37 +1,39 @@
-"""Flat parameter arena: packed leaves, static offsets, bucketed ranges.
+"""The arena: static offsets and bucketed ranges for gradient collectives.
 
 The reference's server tier (Bösen) stores parameters as contiguous table
-rows precisely so update and transmission costs do not scale with the
-NUMBER of tensors (server_table.cpp rows; SSPAggr ships row ranges). The
-JAX port instead carried GoogLeNet's ~120 small param/grad/momentum leaves
-through the whole step: the update phase compiled to a swarm of tiny fused
-kernels and the data-parallel sync was one collective per leaf (the round-5
-GoogLeNet MFU gap vs 16-leaf AlexNet). This module is the arena that fixes
-both:
+rows precisely so transmission costs do not scale with the NUMBER of
+tensors (server_table.cpp rows; SSPAggr ships row ranges). A data-parallel
+JAX step over GoogLeNet's ~120 leaves is otherwise one collective per leaf.
+This module is the contiguous-row analog for what crosses the wire:
 
 - **Offset table** (``ArenaSlot``): every DENSE f32 parameter leaf gets a
-  static ``[offset, offset+size)`` range in one flat f32 buffer. Slot order
-  is the DWBP order — REVERSE forward layer order, i.e. the order gradients
-  materialize during backward — so bucket 0's gradients exist first.
+  static ``[offset, offset+size)`` range in one flat f32 index space. Slot
+  order is the DWBP order — REVERSE forward layer order, i.e. the order
+  gradients materialize during backward — so bucket 0's gradients exist
+  first.
 - **Buckets**: the flat range is cut at exact ``bucket_mb`` element
   boundaries (leaves may span buckets), so the data-parallel gradient sync
   is exactly ``ceil(total_bytes / bucket_mb)`` collectives — never more,
   regardless of how leaf sizes pack (greedy whole-leaf bucketing has no
   such bound).
-- **Views** (``ArenaLayout.views``): a custom-vjp unpack from per-bucket
-  buffers to the per-leaf tree. Forward is slices+reshapes; backward
-  CONCATENATES each bucket's leaf cotangents, so the flat gradient is
-  assembled bucket-by-bucket as backward proceeds — each bucket's psum
-  depends only on its own leaves' gradients, preserving DWBP overlap.
-- **Multiplier segments**: per-leaf ``lr_mult`` / ``decay_mult`` expand to
-  precomputed arena-resident f32 vectors, so the whole SGD/Nesterov/AdaGrad
-  update runs as ONE fused elementwise pass over the buffer
-  (solvers/updates.make_fused_update_fn) instead of one fusion per leaf.
+- **Gradient buckets** (``pack_grad_buckets`` / ``unpack_buckets``): the
+  data-parallel step (parallel/trainer.py) concatenates ``jax.grad``'s
+  leaf gradients into one buffer per bucket — each from its own leaves
+  only, so its psum can issue as soon as its layers' backward is done,
+  preserving DWBP overlap — and slices the summed buffers back to leaves.
 
-The arena is an in-step representation only: parameters, solver history and
-checkpoints stay canonical per-leaf at every step boundary (pack/unpack are
-exact copies), so snapshots written before the arena existed round-trip
-bit-identically and ``--param_arena=false`` reads them the same way.
+Parameters and solver history never enter the flat buffer in that step:
+the forward consumes the canonical leaves and the optimizer update is the
+per-leaf rule (solvers/updates._leafwise_update), each leaf in the layout
+the compiler keeps it in. Packing them cost a relayout per leaf per packed
+quantity per step on the TPU (PERF.md, PR 26). With one device on the sync
+axes there is nothing to bucket and the step builds no arena at all.
+
+The flat buffer itself (``pack`` / ``unpack`` / ``views`` /
+``mult_vectors``) remains for the step whose state lives in it: the
+fsdp-sharded step of parallel/spmd.py, which shards the buffer over the
+fsdp axis, and the SSP tier's boundary delta exchange. Checkpoints are
+canonical per-leaf throughout and ``--param_arena`` does not change them.
 """
 
 from __future__ import annotations
@@ -48,11 +50,11 @@ Tree = Dict[str, Dict[str, jax.Array]]
 
 # A layer with a leaf this large stays per-leaf (256 MiB of f32). The arena
 # exists so that costs do not scale with the NUMBER of tensors; a leaf of
-# hundreds of MB is a bandwidth-bound fusion of its own already, and packing
-# it would add a copy of itself per packed quantity (weight, gradient, each
-# history buffer) and two multiplier vectors as long, all resident at once:
-# OLMoE's 537 MB expert stacks and 412 MB embedding do not fit a 16 GB chip
-# that way. Every CNN leaf in the zoo is under it (AlexNet's fc6: 151 MB).
+# hundreds of MB is a bandwidth-bound collective of its own already, and
+# packing it would add a copy of its gradient, resident beside the leaf:
+# OLMoE's 537 MB expert stacks and 412 MB embedding. Every CNN leaf in the
+# zoo is under it (AlexNet's fc6: 151 MB). On one device nothing is packed
+# and the cap decides nothing.
 MAX_LEAF_ELEMENTS = 64 * 2 ** 20
 
 
@@ -90,7 +92,7 @@ class ArenaLayout:
         # every bucket boundary snaps to a multiple of align and the buffer
         # is zero-padded up to one, so each bucket splits into exactly
         # align equal shards (reduce-scatter / all-gather operands). The
-        # padding tail carries zero lr/decay multipliers — the fused update
+        # padding tail carries zero lr/decay multipliers — the flat update
         # leaves it at zero — and pack/unpack ignore it, so the logical
         # (canonical per-leaf) contract is unchanged.
         self.align = max(1, int(align))
@@ -172,9 +174,6 @@ class ArenaLayout:
     def join_buckets(self, bufs: Sequence[jax.Array]) -> jax.Array:
         return bufs[0] if len(bufs) == 1 else jnp.concatenate(list(bufs))
 
-    def pack_buckets(self, tree: Tree) -> Tuple[jax.Array, ...]:
-        return self.split_buckets(self.pack(tree))
-
     # -------------------------------------------------------------- #
     def residual(self, tree: Tree) -> Tree:
         """The leaves NOT in the arena (SFB/TOPK/LOCAL/fused opt-outs)."""
@@ -194,62 +193,68 @@ class ArenaLayout:
         return out
 
     # -------------------------------------------------------------- #
+    def _bucket_slices(self, bufs: Sequence[jax.Array]) -> Tree:
+        out: Tree = {}
+        for s, pieces in zip(self.slots, self._slot_pieces):
+            parts = [lax.slice(bufs[bi],
+                               (lo - self.bucket_ranges[bi][0],),
+                               (hi - self.bucket_ranges[bi][0],))
+                     for bi, lo, hi in pieces]
+            leaf = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+            out.setdefault(s.layer, {})[s.pname] = leaf.reshape(s.shape)
+        return out
+
+    def unpack_buckets(self, bufs: Sequence[jax.Array]) -> Tree:
+        """Per-bucket buffers -> per-leaf tree (static slices + reshapes;
+        a leaf that spans buckets is concatenated from its pieces)."""
+        with jax.named_scope("arena_unpack"):
+            return self._bucket_slices(bufs)
+
+    def pack_grad_buckets(self, tree: Tree) -> Tuple[jax.Array, ...]:
+        """Per-leaf gradients -> one buffer per bucket, each concatenated
+        from ITS OWN leaves' pieces only (one copy, no pad-and-add): bucket
+        k depends on nothing but its layers' backward, so its psum can
+        issue as soon as that is done. Leaves of ``tree`` outside the
+        layout are ignored."""
+        # "arena_grads": the copies between backward matmuls and the
+        # bucketed psums
+        with jax.named_scope("arena_grads"):
+            outs = []
+            for bi, pieces in enumerate(self._bucket_pieces):
+                parts = []
+                covered = 0
+                for si, lo, hi in pieces:
+                    s = self.slots[si]
+                    leaf = self._leaf(tree, s).reshape(-1)
+                    parts.append(lax.slice(leaf, (lo - s.offset,),
+                                           (hi - s.offset,)))
+                    covered += hi - lo
+                blo, bhi = self.bucket_ranges[bi]
+                if covered < bhi - blo:
+                    # alignment tail (no slot behind it): the bucket must
+                    # still be bucket-shaped
+                    parts.append(jnp.zeros(bhi - blo - covered, self.dtype))
+                outs.append(parts[0] if len(parts) == 1 else
+                            jnp.concatenate(parts))
+            return tuple(outs)
+
     def views(self, *bufs: jax.Array) -> Tree:
-        """Per-bucket buffers -> per-leaf tree, as a custom-vjp pair so the
-        COTANGENT comes back packed: the backward concatenates each
-        bucket's leaf cotangents (one copy, no pad-and-add transpose), and
-        each bucket's gradient depends only on its own leaves — the psum
-        for bucket k can issue as soon as its layers' backward is done."""
+        """Per-bucket PARAMETER buffers -> per-leaf tree, as a custom-vjp
+        pair so the COTANGENT comes back packed (``pack_grad_buckets`` of
+        the leaf cotangents). For a step whose parameters live in the flat
+        buffer — the fsdp-sharded step of parallel/spmd.py; the data-
+        parallel step feeds its forward the canonical leaves and packs
+        ``jax.grad``'s leaf gradients with ``pack_grad_buckets`` itself."""
         if self._views is None:
             layout = self
 
-            def fwd_impl(bufs):
+            def slices(*bufs):
                 with jax.named_scope("arena_views"):
-                    out: Tree = {}
-                    for s, pieces in zip(layout.slots, layout._slot_pieces):
-                        parts = [lax.slice(
-                            bufs[bi],
-                            (lo - layout.bucket_ranges[bi][0],),
-                            (hi - layout.bucket_ranges[bi][0],))
-                            for bi, lo, hi in pieces]
-                        leaf = parts[0] if len(parts) == 1 else \
-                            jnp.concatenate(parts)
-                        out.setdefault(s.layer, {})[s.pname] = \
-                            leaf.reshape(s.shape)
-                    return out
+                    return layout._bucket_slices(bufs)
 
-            @jax.custom_vjp
-            def views_fn(*bufs):
-                return fwd_impl(bufs)
-
-            def views_fwd(*bufs):
-                return fwd_impl(bufs), None
-
-            def views_bwd(_, ct):
-                # "arena_grads": the per-bucket cotangent assembly — the
-                # copies between backward matmuls and the bucketed psums
-                with jax.named_scope("arena_grads"):
-                    outs = []
-                    for bi, pieces in enumerate(layout._bucket_pieces):
-                        parts = []
-                        covered = 0
-                        for si, lo, hi in pieces:
-                            s = layout.slots[si]
-                            leaf_ct = ct[s.layer][s.pname].reshape(-1)
-                            parts.append(lax.slice(leaf_ct, (lo - s.offset,),
-                                                   (hi - s.offset,)))
-                            covered += hi - lo
-                        blo, bhi = layout.bucket_ranges[bi]
-                        if covered < bhi - blo:
-                            # alignment tail (no slot behind it): the bucket
-                            # cotangent must still be bucket-shaped
-                            parts.append(jnp.zeros(bhi - blo - covered,
-                                                   layout.dtype))
-                        outs.append(parts[0] if len(parts) == 1 else
-                                    jnp.concatenate(parts))
-                    return tuple(outs)
-
-            views_fn.defvjp(views_fwd, views_bwd)
+            views_fn = jax.custom_vjp(slices)
+            views_fn.defvjp(lambda *bufs: (slices(*bufs), None),
+                            lambda _, ct: layout.pack_grad_buckets(ct))
             self._views = views_fn
         return self._views(*bufs)
 
@@ -259,8 +264,9 @@ class ArenaLayout:
         Each segment holds exactly the scalars the per-leaf update rule
         uses: f32(lr_mult) and f32(weight_decay * decay_mult) — the
         products taken in Python float first, like the per-leaf path, so
-        the fused pass is bit-identical. The alignment tail (if any) keeps
-        zero multipliers, so the fused update leaves it at zero."""
+        the flat rule (solvers/updates.make_flat_update_rule) is
+        bit-identical. The alignment tail (if any) keeps zero multipliers,
+        so that rule leaves it at zero."""
         lr = np.zeros(self.padded_total, np.float32)
         dec = np.zeros(self.padded_total, np.float32)
         for s in self.slots:

@@ -36,8 +36,7 @@ from .attention import NEG_INF
 # lowered program without changing any argument, so whatever keys a compiled
 # program by its inputs (the engine's AOT step store) must fold them in.
 LOWERING_ENV = ("POSEIDON_POOL_BWD", "POSEIDON_PALLAS_LRN",
-                "POSEIDON_LRN_BWD", "POSEIDON_PALLAS_UPDATE",
-                "POSEIDON_FORCE_PALLAS")
+                "POSEIDON_LRN_BWD", "POSEIDON_FORCE_PALLAS")
 
 
 def _interpret_default() -> bool:
@@ -727,77 +726,6 @@ def _lrn_fused_vjp_bwd(local_size, alpha, beta, k, tile, interpret, layout,
 
 
 _lrn_fused_cvjp.defvjp(_lrn_fused_vjp_fwd, _lrn_fused_vjp_bwd)
-
-
-# --------------------------------------------------------------------------- #
-# Fused flat-arena optimizer update (SGD + momentum + L2)
-# --------------------------------------------------------------------------- #
-
-_UPD_LANES = 1024          # minor dim: multiple of the 128-lane VPU width
-_UPD_ROWS = 256            # rows per grid step: 5 x (256, 1024) f32 = 5 MB VMEM
-
-
-def _sgd_update_kernel(w_ref, g_ref, h_ref, lr_ref, dec_ref, wout_ref,
-                       hout_ref, *, momentum: float):
-    """One VMEM tile of the fused SGD+momentum+L2 arena update — the exact
-    per-element rule of solvers/updates._leafwise_update: the zero-decay
-    segments keep the raw gradient (the per-leaf local_decay==0 skip), the
-    rest add decay*w; h' = m*h + lr*g'; w' = w - h'."""
-    w = w_ref[...]
-    g = g_ref[...]
-    dec = dec_ref[...]
-    g = jnp.where(dec == 0.0, g, g + dec * w)
-    h_new = momentum * h_ref[...] + lr_ref[...] * g
-    hout_ref[...] = h_new
-    wout_ref[...] = w - h_new
-
-
-def fused_sgd(w, g, h, local_rate, decay_vec, momentum: float,
-              interpret: Optional[bool] = None):
-    """Pallas variant of the flat-arena SGD+momentum+L2 update: one VMEM
-    pass producing (w', h') from five same-length f32 vectors. The buffer
-    is padded up to a (rows, 1024) tile grid; padding computes junk that is
-    sliced off (every input pads with zeros, so no NaN/inf can leak out of
-    a where())."""
-    if interpret is None:
-        interpret = _interpret_default()
-    n = w.shape[0]
-    lanes = _UPD_LANES
-    rows_total = _cdiv(n, lanes)
-    rows_block = min(_UPD_ROWS, rows_total)
-    grid_rows = _cdiv(rows_total, rows_block)
-    padded = grid_rows * rows_block * lanes
-
-    def shape2(v):
-        return jnp.pad(v, (0, padded - n)).reshape(-1, lanes)
-
-    spec = pl.BlockSpec((rows_block, lanes), lambda i: (i, 0),
-                        memory_space=pltpu.VMEM)
-    w2, h2 = pl.pallas_call(
-        functools.partial(_sgd_update_kernel, momentum=momentum),
-        name="sgd_update",
-        out_shape=(jax.ShapeDtypeStruct((grid_rows * rows_block, lanes),
-                                        jnp.float32),) * 2,
-        grid=(grid_rows,),
-        in_specs=[spec] * 5,
-        out_specs=(spec, spec),
-        interpret=interpret,
-    )(shape2(w), shape2(g), shape2(h), shape2(local_rate),
-      shape2(decay_vec))
-    return w2.reshape(-1)[:n], h2.reshape(-1)[:n]
-
-
-def maybe_fused_sgd(w, g, h, local_rate, decay_vec, momentum: float):
-    """Routing for the arena update's SGD+momentum+L2 shape. Default: None
-    (the XLA elementwise formulation — already one fused loop over the flat
-    buffer, and custom-call boundaries cost; the same lesson as
-    ``maybe_lrn_fused``). ``POSEIDON_PALLAS_UPDATE=1`` opts into the Pallas
-    kernel — kept Mosaic-compilable for the live-chip wall-clock A/B, and
-    exercised in interpret mode by the CPU suite."""
-    import os
-    if os.environ.get("POSEIDON_PALLAS_UPDATE") != "1":
-        return None
-    return fused_sgd(w, g, h, local_rate, decay_vec, momentum)
 
 
 def lrn_route(hw: int, channels: int):

@@ -746,8 +746,7 @@ def build_spmd_train_step(
     import dataclasses
 
     from ..solvers.updates import (SolverState, _leafwise_update,
-                                   learning_rate, make_arena_update_fn,
-                                   make_flat_update_rule)
+                                   learning_rate, make_flat_update_rule)
     from .trainer import TrainState, TrainStep, param_mults
 
     comm = comm or CommConfig()
@@ -789,8 +788,6 @@ def build_spmd_train_step(
         raise ValueError("sharded_state needs at least one arena (DENSE) "
                          "layer to shard")
     flat_rule = make_flat_update_rule(sp)
-    arena_update = (make_arena_update_fn(sp, mults, layout)
-                    if layout is not None and not fsdp_on else None)
     # joint-axes comm config for taps / SFB factor gathers: sync_axes ==
     # ("data", "fsdp") matches the batch spec's device order
     inner_cfg = dataclasses.replace(comm, axis="fsdp", dcn_axis="data")
@@ -808,8 +805,7 @@ def build_spmd_train_step(
     # fsdp-sharded multiplier segments, fed as explicit trailing step
     # arguments so each device holds only its 1/fsdp slice — a closure
     # constant would be replicated into every device's program. The
-    # replicated arm's full-buffer update keeps its layout-bound
-    # constants (make_fused_update_fn) and needs no trailing args.
+    # replicated arm updates per leaf and needs no multiplier vectors.
     if layout is not None and fsdp_on:
         lr_np, dec_np = _shard_mult_vectors(layout, sp, f)
         mult_spec = P("fsdp")
@@ -962,7 +958,8 @@ def build_spmd_train_step(
             mesh=mesh,
             batch_sharding=NamedSharding(mesh, batch_spec),
             replicated=NamedSharding(mesh, P()),
-            lowerable=lowerable, input_layout=input_layout, arena=layout)
+            lowerable=lowerable, input_layout=input_layout, arena=layout,
+            update_route="flat_fsdp")
 
     # ------------------------------------------------------------------ #
     # canonical-boundary layout (the engine/CLI step)
@@ -972,11 +969,10 @@ def build_spmd_train_step(
         if input_transform is not None:
             batch = input_transform(batch)
         if layout is not None:
-            arena_w = layout.pack(params)
-            arena_bufs = layout.split_buckets(arena_w)
+            arena_bufs = layout.split_buckets(layout.pack(params))
             excl_params = layout.residual(params)
         else:
-            arena_w, arena_bufs, excl_params = None, (), params
+            arena_bufs, excl_params = (), params
         bucket_grads, excl_grads, out = _forward_backward(
             arena_bufs, excl_params, batch, rng)
         bucket_grads = sharded_bucket_sync(bucket_grads, plan, comm.reduce,
@@ -1022,15 +1018,15 @@ def build_spmd_train_step(
                     new_excl_hist)
                 new_solver = SolverState(it=state.solver.it + 1,
                                          history=new_hist)
-            elif layout is not None:
-                # replicated arm: the existing fused full-buffer update
-                new_params, new_solver = arena_update(
-                    arena_w, layout.join_buckets(bucket_grads),
-                    excl_params, excl_grads, state.solver)
             else:
+                # replicated arm (and no arena at all): the per-leaf rule
+                # on the canonical leaves, the bucketed sums sliced back
+                grads = excl_grads
+                if layout is not None:
+                    grads = layout.merge(
+                        layout.unpack_buckets(bucket_grads), excl_grads)
                 new_params, new_hist = _leafwise_update(
-                    sp, mults, rate, excl_params, excl_grads,
-                    state.solver.history)
+                    sp, mults, rate, params, grads, state.solver.history)
                 new_solver = SolverState(it=state.solver.it + 1,
                                          history=new_hist)
         metrics = _metrics(out)
@@ -1056,7 +1052,9 @@ def build_spmd_train_step(
         mesh=mesh,
         batch_sharding=NamedSharding(mesh, batch_spec),
         replicated=NamedSharding(mesh, P()),
-        lowerable=lowerable, input_layout=input_layout, arena=layout)
+        lowerable=lowerable, input_layout=input_layout, arena=layout,
+        update_route="flat_fsdp" if layout is not None and fsdp_on
+        else "leaf")
 
 
 def sharded_state_avals(net, layout, plan: ShardingPlan,

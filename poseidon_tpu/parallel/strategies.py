@@ -132,20 +132,22 @@ class CommConfig:
     # exact shape). Distinctness is a prerequisite for overlap: a combined
     # collective can only start after the LAST gradient exists.
     dwbp_bucket_mb: Optional[float] = None
-    # Flat parameter arena (core/arena.py): pack DENSE f32 param leaves
-    # (and their grads + solver history, in-step) into one flat buffer with
-    # a static DWBP-ordered offset table, sync gradients as
-    # ceil(bytes / arena_bucket_mb) bucketed psums instead of one per leaf,
-    # and run the optimizer update as one fused elementwise pass with
-    # precomputed lr/decay multiplier segments. The update rule is
-    # bit-identical to the per-leaf path (the only step-level deltas are
-    # <= 1 ulp where XLA picks a different cross-replica reduction order
-    # for a bucketed all-reduce than for a tiny per-leaf psum); ON by
-    # default (the Bösen contiguous-row analog: costs must not scale with
-    # the NUMBER of tensors — GoogLeNet carries ~120).
+    # Arena gradient buckets (core/arena.py): with more than one device
+    # on the sync axes, sum DENSE f32 leaves' gradients as
+    # ceil(bytes / arena_bucket_mb) bucketed psums in DWBP order instead
+    # of one per leaf (False). Only gradients are packed; parameters and
+    # solver history stay per-leaf and the update is the per-leaf rule
+    # either way, so on one device the flag changes nothing. The only
+    # step-level deltas against per-leaf collectives are <= 1 ulp where XLA
+    # picks a different cross-replica reduction order for a bucketed
+    # all-reduce than for a tiny per-leaf psum. ON by default (the Bösen
+    # contiguous-row analog: transmission costs must not scale with the
+    # NUMBER of tensors — GoogLeNet carries ~120).
     # SFB/TOPK/LOCAL/DENSE_FUSED layers opt out and keep their custom
     # paths. An explicit dwbp_bucket_mb request (per-backward chained taps)
-    # takes precedence over the arena on the per-step sync path.
+    # takes precedence over the buckets on the per-step sync path. (The
+    # fsdp-sharded step of parallel/spmd.py needs it on: it shards the
+    # flat buffer itself.)
     param_arena: bool = True
     arena_bucket_mb: float = 4.0
     # Blocked top-k selection: when set, magnitude/random TOPK picks the
@@ -443,9 +445,9 @@ def chained_bucket_psums(bufs, axes: tuple, reduce: str,
 class CommContext:
     """Threaded through Net.apply; layers call back into it (core/layers.py).
 
-    ``arena_layers`` names the layers whose DENSE gradients ride the flat
-    parameter arena's bucketed post-backward psums instead of the in-
-    backward taps — ``tap_param`` leaves them untouched."""
+    ``arena_layers`` names the layers whose DENSE gradients ride the
+    arena's bucketed psums instead of the in-backward taps —
+    ``tap_param`` leaves them untouched."""
 
     def __init__(self, cfg: CommConfig, arena_layers=frozenset()):
         self.cfg = cfg

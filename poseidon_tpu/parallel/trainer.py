@@ -26,8 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.net import Net
 from ..proto.messages import SolverParameter
-from ..solvers.updates import (SolverState, init_state, make_arena_update_fn,
-                               make_update_fn)
+from ..solvers.updates import SolverState, init_state, make_update_fn
 from .strategies import (CommConfig, CommContext, DENSE, DENSE_FUSED, LOCAL,
                          SFB, TOPK, budget_topk_fraction,
                          chained_bucket_psums, comm_salt, topk_compress,
@@ -106,10 +105,16 @@ class TrainStep:
     # "NHWC" when the caller feeds channels-last directly so an NHWC-planned
     # net's hot path carries zero entry transposes — see core/net.py).
     input_layout: str = "NCHW"
-    # The flat-parameter-arena layout this step runs on (core/arena.py), or
-    # None when the per-leaf path is active. Introspection only — the step
-    # boundary representation is ALWAYS the canonical per-leaf tree.
+    # The arena layout (core/arena.py) whose buckets this step's DENSE
+    # gradients are summed in, or None when there is nothing to bucket: one
+    # device on the sync axes, or per-leaf collectives asked for.
+    # Introspection only — parameters and solver history never enter it
+    # (the sharding-planner step of parallel/spmd.py, which shards the flat
+    # buffer over fsdp, is the exception and says so in ``update_route``).
     arena: Optional[object] = None
+    # Which form the optimizer update takes: "leaf" (the per-leaf rule on
+    # the canonical leaves) or "flat_fsdp" (spmd.py's sharded flat buffer).
+    update_route: str = "leaf"
 
 
 def comm_error_groups(comm: Optional[CommConfig], mesh: Mesh) -> int:
@@ -255,34 +260,35 @@ def build_train_step(
                 f"replicated; use build_ssp_train_step for per-device "
                 f"divergent parameters")
 
-    # Flat parameter arena (core/arena.py): DENSE layers' params, grads and
-    # solver history travel packed inside the step — gradients land in
-    # DWBP-ordered bucket buffers via the views custom-vjp, the data-
-    # parallel sync is ceil(bytes / arena_bucket_mb) chained psums instead
-    # of one per leaf, and the optimizer update is one fused elementwise
-    # pass with precomputed multiplier segments. SFB/TOPK/DENSE_FUSED
-    # layers keep their custom per-leaf paths. An explicit dwbp_bucket_mb
-    # (per-backward chained taps) takes precedence on the per-step path;
-    # under iter_size > 1 there is no per-backward exchange, so the
-    # accumulated sync rides the arena buckets either way.
-    # (a layer holding a leaf of hundreds of MB keeps the per-leaf rule and
-    # its per-leaf gradient tap: core/arena.fits_arena)
+    # Gradient buckets (core/arena.py), only where there is someone to
+    # all-reduce with: with more than one device on the sync axes, DENSE
+    # layers' gradients are packed into DWBP-ordered bucket buffers as
+    # backward produces them, summed as ceil(bytes / arena_bucket_mb)
+    # chained psums instead of one per leaf, and sliced back to leaves.
+    # Parameters and solver history never enter the flat buffer: the
+    # forward consumes the canonical leaves and the update is the per-leaf
+    # rule, in whatever layout the compiler keeps each leaf. On one device
+    # there is no arena at all. SFB/TOPK/DENSE_FUSED layers keep their
+    # custom per-leaf paths. An explicit dwbp_bucket_mb (per-backward
+    # chained taps) takes precedence on the per-step path; under
+    # iter_size > 1 there is no per-backward exchange, so the accumulated
+    # sync rides the buckets either way.
+    # (a layer holding a leaf of hundreds of MB keeps its per-leaf
+    # gradient tap: core/arena.fits_arena)
     from ..core.arena import fits_arena
     dense_layers = [l for l, defs in net.param_defs.items()
                     if comm.strategy_for(l) == DENSE and fits_arena(defs)]
     arena = None
-    if comm.param_arena and dense_layers and \
+    if n_total > 1 and comm.param_arena and dense_layers and \
             (comm.dwbp_bucket_mb is None or iter_size > 1):
         arena = net.arena_layout(frozenset(dense_layers),
                                  comm.arena_bucket_mb)
-    arena_update = (make_arena_update_fn(sp, param_mults(net), arena)
-                    if arena is not None else None)
     ctx = CommContext(comm, arena_layers=arena.layers
                       if arena is not None else frozenset())
 
     if iter_size > 1:
-        # the arena covers DENSE layers' accumulated sync (bucketed psums);
-        # anything it does NOT cover still silently collapses to one dense
+        # the buckets cover DENSE layers' accumulated sync; anything they
+        # do NOT cover still silently collapses to one dense
         # post-accumulation psum per leaf — keep saying so
         sfb_layers = [l for l in net.param_defs
                       if comm.strategy_for(l) == SFB]
@@ -297,9 +303,10 @@ def build_train_step(
                 f"before one dense post-accumulation psum per leaf for "
                 f"{', '.join(what)}; per-backward comm strategies do not "
                 f"apply to the accumulated step (DENSE layers ride the "
-                f"parameter arena's buckets"
+                f"arena's gradient buckets"
                 + (")" if arena is not None else
-                   " when param_arena is on)"))
+                   " when param_arena is on and there is more than one "
+                   "device)"))
 
     topk_layers = [l for l in net.param_defs
                    if comm.strategy_for(l) == TOPK]
@@ -328,14 +335,11 @@ def build_train_step(
             flat_idx = flat_idx + mesh.shape[axis] * lax.axis_index(dcn)
         rng = jax.random.fold_in(rng, flat_idx)
 
-        # arena hot path: params packed once per step; the per-leaf tree
-        # the net consumes is rebuilt from bucket VIEWS whose custom-vjp
-        # concatenates each bucket's cotangents — gradients are "written
-        # into the arena" by backward itself
-        if arena is not None:
-            arena_w = arena.pack(params)
-            arena_bufs = arena.split_buckets(arena_w)
-            excl_params = arena.residual(params)
+        def loss_of(p, mb, lrng, lcomm):
+            o = net.apply(p, mb, train=True, rng=lrng, comm=lcomm,
+                          keep_blobs=bool(dump_blobs),
+                          input_layout=input_layout, remat=_remat)
+            return o.loss, o
 
         if iter_size > 1:
             # gradient accumulation: grad INSIDE the scan body so only one
@@ -345,25 +349,8 @@ def build_train_step(
                 if input_transform is not None:
                     mb = input_transform(mb)
                 mrng = jax.random.fold_in(rng, i)
-
-                if arena is not None:
-                    def micro_loss(bufs, excl):
-                        p = arena.merge(arena.views(*bufs), excl)
-                        o = net.apply(p, mb, train=True, rng=mrng,
-                                      comm=None, input_layout=input_layout,
-                                      remat=_remat)
-                        return o.loss, o
-
-                    g, o = jax.grad(micro_loss, argnums=(0, 1),
-                                    has_aux=True)(arena_bufs, excl_params)
-                else:
-                    def micro_loss(p):
-                        o = net.apply(p, mb, train=True, rng=mrng,
-                                      comm=None, input_layout=input_layout,
-                                      remat=_remat)
-                        return o.loss, o
-
-                    g, o = jax.grad(micro_loss, has_aux=True)(params)
+                g, o = jax.grad(lambda p: loss_of(p, mb, mrng, None),
+                                has_aux=True)(params)
                 acc = jax.tree_util.tree_map(jnp.add, acc, g)
                 m = {"loss": o.loss}
                 for name, val in o.outputs.items():
@@ -371,27 +358,19 @@ def build_train_step(
                         m[name] = val.astype(jnp.float32)
                 return acc, m
 
-            if arena is not None:
-                zeros = (tuple(jnp.zeros_like(b) for b in arena_bufs),
-                         jax.tree_util.tree_map(jnp.zeros_like, excl_params))
-            else:
-                zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
             grads, micro_ms = lax.scan(
-                accum_body, zeros, (jnp.arange(iter_size), batch))
+                accum_body, jax.tree_util.tree_map(jnp.zeros_like, params),
+                (jnp.arange(iter_size), batch))
             # Caffe's SGDSolver::Normalize: scale accumulated grads by 1/K
             grads = jax.tree_util.tree_map(lambda g: g / iter_size, grads)
             out_scalars = {k: jnp.mean(v) for k, v in micro_ms.items()}
-            if arena is not None:
-                # the accumulated sync rides the SAME arena buckets as the
-                # per-step path: ceil(bytes/bucket) collectives, not one
-                # dense psum per leaf
-                bucket_grads, grads = grads
-                bucket_grads = chained_bucket_psums(
-                    bucket_grads, axes, comm.reduce, comm.wire_dtype)
-            # post-accumulation sync for the remaining per-leaf layers the
+            # post-accumulation sync for the per-leaf layers the
             # per-backward taps would have handled (SFB / DENSE_FUSED, and
-            # DENSE itself when the arena is off)
+            # DENSE itself where there are no buckets); the bucketed layers
+            # follow below
             for lname in grads:
+                if lname in ctx.arena_layers:
+                    continue
                 if comm.strategy_for(lname) not in (LOCAL, TOPK):
                     for pname, g in grads[lname].items():
                         grads[lname][pname] = wire_psum(
@@ -400,31 +379,8 @@ def build_train_step(
         else:
             if input_transform is not None:
                 batch = input_transform(batch)
-
-            if arena is not None:
-                def loss_fn(bufs, excl):
-                    p = arena.merge(arena.views(*bufs), excl)
-                    o = net.apply(p, batch, train=True, rng=rng, comm=ctx,
-                                  keep_blobs=bool(dump_blobs),
-                                  input_layout=input_layout, remat=_remat)
-                    return o.loss, o
-
-                (bucket_grads, grads), out = jax.grad(
-                    loss_fn, argnums=(0, 1), has_aux=True)(arena_bufs,
-                                                           excl_params)
-                # the bucketed data-parallel sync: one DISTINCT (chained)
-                # collective per DWBP-ordered bucket, issued as its
-                # bucket's cotangents materialize mid-backward
-                bucket_grads = chained_bucket_psums(
-                    bucket_grads, axes, comm.reduce, comm.wire_dtype)
-            else:
-                def loss_fn(p):
-                    o = net.apply(p, batch, train=True, rng=rng, comm=ctx,
-                                  keep_blobs=bool(dump_blobs),
-                                  input_layout=input_layout, remat=_remat)
-                    return o.loss, o
-
-                grads, out = jax.grad(loss_fn, has_aux=True)(params)
+            grads, out = jax.grad(lambda p: loss_of(p, batch, rng, ctx),
+                                  has_aux=True)(params)
             out_scalars = {"loss": out.loss}
             for name, val in out.outputs.items():
                 if val.ndim == 0:
@@ -435,6 +391,17 @@ def build_train_step(
                 for pname, g in grads[lname].items():
                     grads[lname][pname] = wire_psum(g, axes, comm.reduce,
                                                     comm.wire_dtype)
+        if arena is not None:
+            # the bucketed data-parallel sync: each DWBP-ordered bucket is
+            # concatenated from its own leaves' gradients only, so its
+            # DISTINCT (chained) collective issues as those materialize
+            # mid-backward; the sums are sliced back to leaves for the
+            # per-leaf update (the same ceil(bytes/bucket) collectives for
+            # the accumulated gradient under iter_size > 1)
+            synced = arena.unpack_buckets(chained_bucket_psums(
+                arena.pack_grad_buckets(grads), axes, comm.reduce,
+                comm.wire_dtype))
+            grads = arena.merge(arena.residual(grads), synced)
         # Managed-comm tier: TOPK layers were left un-psummed by the tap;
         # compress the (residual-corrected) gradient, exchange only the
         # top-k entries, keep the remainder as next step's residual.
@@ -463,13 +430,7 @@ def build_train_step(
                 grads[lname][pname] = g_sync
                 lerr[pname] = resid[None]
             new_errors[lname] = lerr
-        if arena is not None:
-            # fused flat update for the arena + per-leaf rule for opt-outs
-            new_params, new_solver = arena_update(
-                arena_w, arena.join_buckets(bucket_grads), excl_params,
-                grads, state.solver)
-        else:
-            new_params, new_solver = update_fn(params, grads, state.solver)
+        new_params, new_solver = update_fn(params, grads, state.solver)
         metrics = {name: lax.psum(val.astype(jnp.float32), axes) / n_total
                    for name, val in out_scalars.items()}
         dumps = ({b: out.blobs[b] for b in (dump_blobs or ())}
@@ -772,14 +733,13 @@ def build_ssp_train_step(
     ici_ctx = (CommContext(dataclasses.replace(comm, dcn_axis=None))
                if dcn else None)
 
-    # Flat parameter arena for the SSP tier (flat mesh, "inc" server logic):
-    # the local update runs as one fused elementwise pass over the packed
-    # DENSE leaves, and the boundary delta exchange becomes
-    # ceil(bytes/arena_bucket_mb) psums over arena buckets instead of one
-    # per leaf. TOPK (compressed deltas) and LOCAL layers keep their
-    # per-leaf paths; adarevision consumes per-leaf raw gradient sums and a
-    # two-tier mesh taps DENSE gradients per-step intra-slice, so both fall
-    # back to the per-leaf step wholesale.
+    # Arena buckets for the SSP tier (flat mesh, "inc" server logic): the
+    # boundary delta exchange is ceil(bytes/arena_bucket_mb) psums over
+    # arena buckets instead of one per leaf. The local update is the
+    # per-leaf rule, as in the synchronous step. TOPK (compressed deltas)
+    # and LOCAL layers keep their per-leaf paths; adarevision consumes
+    # per-leaf raw gradient sums and a two-tier mesh taps DENSE gradients
+    # per-step intra-slice, so both exchange per leaf wholesale.
     dense_layers = [l for l in net.param_defs
                     if comm.strategy_for(l) == DENSE]
     arena = None
@@ -788,8 +748,6 @@ def build_ssp_train_step(
         arena = net.arena_layout(frozenset(dense_layers),
                                  comm.arena_bucket_mb,
                                  align=plan_fsdp)
-    arena_update = (make_arena_update_fn(sp, param_mults(net), arena)
-                    if arena is not None else None)
 
     def device_step(ssp: SSPState, batch, rng):
         if plan_fsdp > 1:
@@ -826,15 +784,8 @@ def build_ssp_train_step(
             gsum = {ln: {pn: gsum[ln][pn] + grads[ln][pn]
                          for pn in grads[ln]}
                     for ln in gsum}
-        if arena is not None:
-            # fused flat local update over the packed DENSE leaves
-            new_local, new_solver = arena_update(
-                arena.pack(local), arena.pack(grads),
-                arena.residual(local), arena.residual(grads),
-                SolverState(it=ssp.it, history=history))
-        else:
-            new_local, new_solver = update_fn(
-                local, grads, SolverState(it=ssp.it, history=history))
+        new_local, new_solver = update_fn(
+            local, grads, SolverState(it=ssp.it, history=history))
 
         do_sync = (new_solver.it % period) == 0
         scale = 1.0 / n_groups if comm.reduce == "mean" else 1.0

@@ -10,14 +10,12 @@ binary NetParameter (``.caffemodel``, written by rank 0) and per-worker
 - ``restore()`` rebuilds (params, TrainState) from the .npz;
   ``load_caffemodel()`` imports weights alone (CopyTrainedLayersFrom).
 
-**Snapshots are canonical per-leaf** — the flat parameter arena
-(core/arena.py) is an in-step representation only: the compiled train step
-packs params/grads/history into the flat buffers at entry and unpacks at
-exit, so every (params, state) this module sees is the per-leaf tree
-regardless of ``--param_arena``. Pre-arena snapshots therefore load into
-arena-backed runs unchanged, an arena run's snapshot reloads under
-``--param_arena=false`` bit-identically, and nothing here depends on the
-arena's offset table or bucket size (tested:
+**Snapshots are canonical per-leaf** — the arena (core/arena.py) buckets
+gradients inside the compiled train step and nothing else, so every
+(params, state) this module sees is the per-leaf tree regardless of
+``--param_arena``. A snapshot written with bucketed collectives reloads
+under ``--param_arena=false`` bit-identically and the other way round, and
+nothing here depends on the arena's offset table or bucket size (tested:
 test_runtime.test_arena_snapshot_portability).
 """
 

@@ -1134,13 +1134,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "negative = off (XLA's combiner decides)")
     t.add_argument("--param_arena", default="true",
                    choices=["true", "false"],
-                   help="flat parameter arena (ON by default): pack DENSE "
-                        "param/grad/momentum leaves into one flat buffer, "
-                        "sync gradients as ceil(bytes/arena_bucket_mb) "
-                        "bucketed collectives instead of one per leaf, and "
-                        "run the optimizer update as one fused pass; same "
-                        "numbers as the per-leaf path (update rule bitwise, "
-                        "steps within 1 ulp of collective reduction order)")
+                   help="gradient buckets (ON by default): with more than "
+                        "one device, sum DENSE layers' gradients as "
+                        "ceil(bytes/arena_bucket_mb) bucketed collectives "
+                        "instead of one per leaf (false = one per leaf); "
+                        "steps agree within 1 ulp of collective reduction "
+                        "order. Parameters and solver history are never "
+                        "packed, the update is per leaf either way, and on "
+                        "one device the flag changes nothing")
     t.add_argument("--arena_bucket_mb", type=float, default=None,
                    help="arena gradient-sync bucket size in MB (DWBP-"
                         "ordered exact element ranges; <= 0 = one bucket "

@@ -402,7 +402,7 @@ class Engine:
                 # NOTE: the SSP lowerable has the 3-arg (state, batch, rng)
                 # signature, not the wrapper's 4-arg one
                 lowerable=ssp_ts.lowerable,
-                arena=ssp_ts.arena)
+                arena=ssp_ts.arena)  # the boundary's delta buckets
         else:
             dump = sorted({b for _, bs in self._h5_train for b in bs})
             if dump and self.iter_size > 1:
@@ -825,7 +825,13 @@ class Engine:
         def phase(name: str) -> None:
             marks.append((name, time.perf_counter()))
 
-        doc: Dict[str, Any] = {"source": "jit"}
+        # which form the step took, readable without the HLO: where the
+        # optimizer update runs and how many buckets its gradients are
+        # summed in (0: one device, or per-leaf collectives)
+        arena = self.train_step.arena
+        doc: Dict[str, Any] = {
+            "source": "jit", "update_route": self.train_step.update_route,
+            "grad_buckets": arena.n_buckets if arena is not None else 0}
         try:
             cfg = compile_cache_config()
             key = step_key(

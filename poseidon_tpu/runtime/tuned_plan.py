@@ -510,6 +510,8 @@ def _build_step_arm(net_param, shapes, conv_layout: str, arena_mb: float,
                   conv_strategy=conv_strategy or None)
     sp = SolverParameter(base_lr=0.01, lr_policy="fixed", momentum=0.9,
                          weight_decay=5e-4)
+    # the knob under measurement: the gradient buckets' size (on one
+    # device the step builds no buckets and every arm is the same program)
     comm = CommConfig(param_arena=True, arena_bucket_mb=float(arena_mb))
     nhwc = net.conv_layout == "NHWC"
     in_layout = "NHWC" if nhwc else "NCHW"

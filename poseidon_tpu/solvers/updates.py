@@ -75,12 +75,8 @@ class SolverState(NamedTuple):
     history: Dict
 
 
-SOLVER_TYPES = ("SGD", "NESTEROV", "ADAGRAD", "ADAM")
-
-
 def _adam(sp: SolverParameter, w, g, m, v, local_rate, local_decay, t):
-    """One AdamW step on a leaf (scalar rate/decay) or on the flat buffer
-    (multiplier vectors): the same elementwise arithmetic either way."""
+    """One AdamW step on a leaf."""
     b1, b2 = sp.momentum, sp.momentum2
     m_new = b1 * m + (1.0 - b1) * g
     v_new = b2 * v + (1.0 - b2) * (g * g)
@@ -116,10 +112,10 @@ def _regularized(g, w, local_decay: float, reg_type: str):
 
 def _leafwise_update(sp: SolverParameter, mults, rate, params, grads,
                      history, it=None, scale=None):
-    """One optimizer step over a per-leaf tree (the classic path; also the
-    per-leaf remainder — SFB/TOPK/LOCAL opt-outs — of an arena step).
-    ``it`` (ADAM's bias correction) and ``scale`` (the clip factor) are
-    the caller's: both span the whole parameter set, not this tree."""
+    """One optimizer step over a per-leaf tree: one elementwise fusion per
+    leaf, in whatever layout the compiler keeps it. ``it`` (ADAM's bias
+    correction) and ``scale`` (the clip factor) are the caller's: both
+    span the whole parameter set, which ``params`` need not be."""
     solver_type = sp.solver_type
     if solver_type == "ADAM":
         t = (it + 1).astype(jnp.float32)
@@ -193,39 +189,27 @@ def make_update_fn(sp: SolverParameter, mults: Dict[str, Dict[str, tuple]]):
 
 
 def make_flat_update_rule(sp: SolverParameter):
-    """The fused flat update rule with the multiplier vectors as ARGUMENTS:
+    """The update rule over a FLAT f32 buffer, for the one step whose
+    parameters live in one: the fsdp-sharded step of parallel/spmd.py,
+    which feeds each device its 1/fsdp shard of the buffer and of the
+    per-leaf multipliers expanded to vectors (``ArenaLayout.mult_vectors``).
     fused(flat_w, flat_g, flat_h, rate, lr_vec, decay_vec) ->
-    (flat_w', flat_h'). ``make_fused_update_fn`` binds the arena layout's
-    precomputed full-buffer vectors; the SPMD sharded step
-    (parallel/spmd.py) instead feeds each device its fsdp SHARD of the
-    vectors, so the update touches 1/fsdp of the buffer per device with
-    identical elementwise math. Under ADAM ``flat_h`` is the pair
-    (flat_m, flat_v) and the rule takes the iteration as ``it=``."""
+    (flat_w', flat_h'): the arithmetic of ``_leafwise_update`` in the same
+    order, bit-identical to it (tests/test_arena.py). SGD, NESTEROV and
+    ADAGRAD; that step refuses ADAM and the clip."""
     solver_type = sp.solver_type
     momentum = sp.momentum
     reg_type = sp.regularization_type
     delta = sp.delta
-    if solver_type not in SOLVER_TYPES:
-        raise ValueError(f"unknown solver_type {solver_type!r}")
+    if solver_type not in ("SGD", "NESTEROV", "ADAGRAD"):
+        raise ValueError(f"no flat update rule for solver_type "
+                         f"{solver_type!r}")
     if reg_type not in ("L2", "L1"):
         raise ValueError(f"unknown regularization_type {reg_type!r}")
 
-    def fused(flat_w, flat_g, flat_h, rate, lr_vec, decay_vec, it=None,
-              scale=None):
+    def fused(flat_w, flat_g, flat_h, rate, lr_vec, decay_vec):
         local_rate = rate * lr_vec
         g = flat_g.astype(jnp.float32)
-        if scale is not None:
-            g = g * scale
-        if solver_type == "ADAM":
-            new_w, m, v = _adam(sp, flat_w, g, *flat_h, local_rate,
-                                decay_vec, (it + 1).astype(jnp.float32))
-            return new_w, (m, v)
-        if solver_type == "SGD" and reg_type == "L2":
-            from ..ops.pallas_kernels import maybe_fused_sgd
-            r = maybe_fused_sgd(flat_w, g, flat_h, local_rate, decay_vec,
-                                momentum)
-            if r is not None:
-                return r
         reg = flat_w if reg_type == "L2" else jnp.sign(flat_w)
         # the elementwise form of _regularized's local_decay == 0 skip:
         # untouched gradient where the segment's decay is zero
@@ -242,72 +226,6 @@ def make_flat_update_rule(sp: SolverParameter):
         return (flat_w - step).astype(flat_w.dtype), h_new
 
     return fused
-
-
-def make_fused_update_fn(sp: SolverParameter, layout):
-    """One fused elementwise pass over the flat arena buffer — the same
-    SGD/Nesterov/AdaGrad rule as ``_leafwise_update``, with the per-leaf
-    lr_mult / decay_mult scalars expanded into the layout's precomputed
-    arena-resident multiplier segments. Bit-identical to the per-leaf loop:
-    every scalar is rounded to f32 exactly where the per-leaf path rounds
-    it (see ArenaLayout.mult_vectors), the zero-decay skip becomes an
-    elementwise select of the untouched gradient, and the operation order
-    is unchanged.
-
-    Returns fused(flat_w, flat_g, flat_h, rate) -> (flat_w', flat_h').
-    The SGD+momentum+L2 shape (the Caffe default) can additionally route
-    through the Pallas kernel variant (ops/pallas_kernels.fused_sgd) —
-    opt-in via POSEIDON_PALLAS_UPDATE=1, same math, one VMEM pass."""
-    rule = make_flat_update_rule(sp)
-    lr_np, decay_np = layout.mult_vectors(sp.weight_decay)
-
-    def fused(flat_w, flat_g, flat_h, rate, it=None, scale=None):
-        return rule(flat_w, flat_g, flat_h, rate, jnp.asarray(lr_np),
-                    jnp.asarray(decay_np), it=it, scale=scale)
-
-    return fused
-
-
-def make_arena_update_fn(sp: SolverParameter, mults, layout):
-    """The arena step's optimizer update: the fused flat pass for arena
-    leaves + the per-leaf rule for opt-outs, one iteration bump.
-
-    update(flat_w, flat_g, excl_params, excl_grads, state)
-        -> (new_params_tree, new_state)
-
-    ``state.history`` is the CANONICAL per-leaf tree at every step boundary
-    (snapshots never see the packed form); it is packed here for the fused
-    pass and unpacked into the returned state."""
-    fused = make_fused_update_fn(sp, layout)
-    adam = sp.solver_type == "ADAM"
-
-    def update(flat_w, flat_g, excl_params, excl_grads, state: SolverState):
-        with jax.named_scope("optimizer_update"):
-            rate = learning_rate(sp, state.it)
-            scale = clip_scale(sp, flat_g, excl_grads)
-            hist = state.history
-            if adam:   # two moments: each packed, stepped and unpacked
-                flat_h = tuple(layout.pack(hist[k]) for k in ("m", "v"))
-                excl_hist = {k: layout.residual(hist[k]) for k in ("m", "v")}
-            else:
-                flat_h = layout.pack(hist)
-                excl_hist = layout.residual(hist)
-            new_flat_w, new_flat_h = fused(flat_w, flat_g, flat_h, rate,
-                                           it=state.it, scale=scale)
-            new_excl, new_excl_hist = _leafwise_update(
-                sp, mults, rate, excl_params, excl_grads, excl_hist,
-                state.it, scale)
-            new_params = layout.merge(layout.unpack(new_flat_w), new_excl)
-            if adam:
-                new_hist = {k: layout.merge(layout.unpack(f),
-                                            new_excl_hist[k])
-                            for k, f in zip(("m", "v"), new_flat_h)}
-            else:
-                new_hist = layout.merge(layout.unpack(new_flat_h),
-                                        new_excl_hist)
-            return new_params, SolverState(it=state.it + 1, history=new_hist)
-
-    return update
 
 
 def init_state(params, solver_type: str = "SGD") -> SolverState:

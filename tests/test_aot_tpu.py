@@ -165,9 +165,10 @@ print("RESULT " + json.dumps({{
 @pytest.mark.slow
 def test_olmoe_full_width_step_compiles_for_one_v5e():
     """The flash kernels and the grouped matmul are in the compiled step
-    (no dense 64-expert fallback, no CPU or interpret arm), the huge leaves
-    stay out of the arena, and the step fits one chip at the cell's batch:
-    under 85% of the 16.9 GB the compiler allows (PR 22's sizing rule)."""
+    (no dense 64-expert fallback, no CPU or interpret arm), a one-device
+    step packs nothing into an arena, and the step fits one chip at the
+    cell's batch: under 85% of the 16.9 GB the compiler allows (PR 22's
+    sizing rule)."""
     import json
     r = subprocess.run(
         [sys.executable, "-c", _OLMOE_STEP.format(repo=REPO, batch=2)],
@@ -185,7 +186,96 @@ def test_olmoe_full_width_step_compiles_for_one_v5e():
     assert got["pallas_custom_calls"] >= 3       # flash fwd, dq, dkv
     assert got["ragged_dot_fusions"] >= 3        # gate, up, down (+ bwd)
     assert got["dense_expert_dots"] == 0
-    # attention projections, router-free: only the small layers are packed
-    assert got["arena_parameters"] == 4 * 2048 * 2048 + 5 * 2048
+    # one device on the sync axes: no arena (until PR 26 the attention
+    # projections and norm gains, 16.8M, were packed with both moments)
+    assert got["arena_parameters"] == 0
     assert 7.0 < got["argument_gb"] < 8.0        # weights + two moments
     assert got["total_gb"] < 0.85 * 16.9
+
+
+# AlexNet's train step as `train --bf16` builds it at the benchmark's batch,
+# for ONE abstract v5e chip: with nobody to all-reduce with, the step builder
+# packs nothing (PR 26) — no arena scope, no buffer-length array or constant,
+# no collective left in the compiled program.
+_CNN_STEP = r"""
+import json, os, re, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ["POSEIDON_FORCE_PALLAS"] = "1"      # lower as for the TPU
+sys.path.insert(0, {repo!r})
+import jax, jax.numpy as jnp, numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("SKIP:", e)
+    sys.exit(3)
+from poseidon_tpu.config import set_perf_policy
+from poseidon_tpu.core.net import Net
+from poseidon_tpu.models import zoo
+from poseidon_tpu.parallel import (CommConfig, build_train_step,
+                                   init_train_state)
+from poseidon_tpu.proto.messages import SolverParameter
+set_perf_policy()
+batch = 512
+net = Net(zoo.alexnet(with_accuracy=False), "TRAIN",
+          source_shapes=zoo.alexnet_shapes(batch))
+sp = SolverParameter(base_lr=0.01, lr_policy="step", stepsize=100000,
+                     gamma=0.1, momentum=0.9, weight_decay=0.0005)
+mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+comm = CommConfig()
+ts = build_train_step(net, sp, mesh, comm, donate=True, donate_batch=True)
+rep = NamedSharding(mesh, P())
+shaped = lambda t, sh: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), t)
+params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+state = jax.eval_shape(lambda p: init_train_state(p, comm, 1), params)
+b = {{"data": jax.ShapeDtypeStruct((batch, 3, 227, 227), jnp.float32,
+                                  sharding=ts.batch_sharding),
+     "label": jax.ShapeDtypeStruct((batch,), jnp.int32,
+                                   sharding=ts.batch_sharding)}}
+compiled = ts.lowerable.lower(
+    shaped(params, rep), shaped(state, rep), b,
+    jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
+ma, text = compiled.memory_analysis(), compiled.as_text()
+n = net.param_count()
+print("RESULT " + json.dumps({{
+    "parameters": n, "arena": ts.arena is not None,
+    "update_route": ts.update_route,
+    "arena_op_names": sorted(set(re.findall(r"arena_[a-z]+", text))),
+    "optimizer_update_ops": text.count("optimizer_update"),
+    "buffer_length_arrays": text.count("f32[%d]" % n),
+    "all_reduces": len(re.findall(r" all-reduce(-start)?\(", text)),
+    "pallas_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+    "temp_gb": ma.temp_size_in_bytes / 1e9}}))
+"""
+
+
+@pytest.mark.slow
+def test_alexnet_one_chip_step_has_no_arena_for_one_v5e():
+    """The one-chip CNN step the benchmark's cells run: the update is
+    there (``optimizer_update`` op names), the arena is not — no
+    ``arena_*`` op name, no array of the flat buffer's length, no
+    collective — and the LRN kernels are still Pallas."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _CNN_STEP.format(repo=REPO)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)
+    assert got["parameters"] == 60_965_224
+    assert got["arena"] is False and got["update_route"] == "leaf"
+    assert got["arena_op_names"] == []
+    assert got["optimizer_update_ops"] >= 16     # one fusion a leaf at least
+    assert got["buffer_length_arrays"] == 0
+    assert got["all_reduces"] == 0
+    assert got["pallas_custom_calls"] == 4       # norm1, norm2: fwd and bwd
+    assert got["temp_gb"] < 3.0                  # 3.42 with the arena

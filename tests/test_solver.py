@@ -190,39 +190,3 @@ def test_cosine_policy_warms_up_then_decays():
     assert lr(60) == pytest.approx(4e-4 * (0.1 + 0.9 * 0.5), rel=1e-5)
     assert lr(110) == pytest.approx(4e-5, rel=1e-5)
     assert lr(500) == pytest.approx(4e-5, rel=1e-5)
-
-
-def test_adam_arena_update_matches_leafwise():
-    """The flat/arena rule the Engine uses against the leafwise rule, ADAM
-    with the clip on: same elementwise arithmetic, so ~1 ulp (the flat and
-    per-leaf fusions may contract FMAs differently, as for Nesterov)."""
-    import jax
-    from poseidon_tpu.core.net import Net
-    from poseidon_tpu.models import zoo
-    from poseidon_tpu.parallel.trainer import param_mults
-    from poseidon_tpu.solvers.updates import make_arena_update_fn
-    net = Net(zoo.lenet(with_accuracy=False), phase="TRAIN",
-              source_shapes=zoo.lenet_shapes(2))
-    sp = SolverParameter(base_lr=1e-3, lr_policy="fixed", momentum=0.9,
-                         momentum2=0.95, weight_decay=0.1,
-                         solver_type="ADAM", clip_gradients=1.0)
-    # ip2 stays outside the arena: the step's per-leaf remainder
-    layout = net.arena_layout(frozenset(net.param_defs) - {"ip2"})
-    mults = param_mults(net)
-    params = net.init(jax.random.PRNGKey(0))
-    rs = np.random.RandomState(1)
-    leaf, arena = make_update_fn(sp, mults), \
-        make_arena_update_fn(sp, mults, layout)
-    p1, s1 = params, init_state(params, "ADAM")
-    p2, s2 = params, init_state(params, "ADAM")
-    for _ in range(2):
-        grads = jax.tree_util.tree_map(
-            lambda x: jnp.asarray(rs.randn(*x.shape).astype(np.float32)),
-            params)
-        p1, s1 = leaf(p1, grads, s1)
-        p2, s2 = arena(layout.pack(p2), layout.pack(grads),
-                       layout.residual(p2), layout.residual(grads), s2)
-    for a, b in zip(jax.tree_util.tree_leaves((p1, s1.history)),
-                    jax.tree_util.tree_leaves((p2, s2.history))):
-        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-9)
-    assert jax.tree_util.tree_structure(s1) == jax.tree_util.tree_structure(s2)
