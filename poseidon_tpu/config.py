@@ -168,7 +168,7 @@ class ManagedCommConfig:
     # wire dtype for DCN delta payloads ('' = f32, today's wire byte for
     # byte; 'bf16'/'f16'/'int8' compress with EXACT error feedback —
     # quantization error rides the managed-communication residual).
-    # Resolution: --wire_dtype flag > TunedPlan knob > this default.
+    # The tier's fallback when no --wire_dtype flag rode async_cfg.
     wire_dtype: str = ""
 
 
@@ -271,11 +271,9 @@ class PipelineConfig:
     the dispatched step sequence is identical, only host blocking moves
     (tests/test_pipeline_overlap.py pins bitwise parity).
 
-    The dataclass defaults here are one row of the collapsed policy
-    surface: ``runtime/tuned_plan.BUILTIN_DEFAULTS`` reads them, a
-    persisted TunedPlan's measured winners replace them at CLI startup,
-    and an explicit flag overrides both (resolution provenance lands in
-    stats.yaml)."""
+    ``Engine.__init__`` reads these defaults for every knob its caller
+    leaves at None; ``train``'s ``--device_prefetch`` / ``--max_in_flight``
+    / ``--async_snapshot`` pass a value through and override them."""
 
     # host batches staged to device AHEAD of the step that consumes them
     # (data.pipeline.DevicePrefetcher depth); 0 disables the stage and the
@@ -303,13 +301,6 @@ def pipeline_config() -> PipelineConfig:
     return _pipeline
 
 
-def set_pipeline_config(**kwargs) -> None:
-    for k, v in kwargs.items():
-        if not hasattr(_pipeline, k):
-            raise AttributeError(k)
-        setattr(_pipeline, k, v)
-
-
 @dataclass
 class CompileCacheConfig:
     """Fast-restart state (runtime/compile_cache.py): the directory
@@ -320,7 +311,7 @@ class CompileCacheConfig:
     points) — every compile is a full JIT."""
 
     # the enabled cache directory ("" = not enabled); the AOT step store
-    # lives under <cache_dir>/aot, the tuned-policy store under /tuned
+    # lives under <cache_dir>/aot
     cache_dir: str = ""
     # serialize/reload the compiled train-step executable itself (skips
     # tracing AND compilation on a key match; best-effort — any mismatch

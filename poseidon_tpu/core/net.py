@@ -94,11 +94,8 @@ class Net:
         self.name = net_param.name
         # The layout is a GRAPH-level choice, fixed at construction: the
         # per-net override wins, else the ambient numeric policy's default.
-        # "auto" resolves through plan resolution (runtime/tuned_plan.py):
-        # an active TunedPlan's MEASURED conv_layout row answers first;
-        # without a plan the builtin per-backend table applies (NCHW on
-        # TPU — the NHWC plan measured 0.53x on the real v5e despite
-        # winning the transpose count; see numeric.resolve_conv_layout).
+        # "auto" resolves through the per-backend table of
+        # numeric.resolve_conv_layout (NCHW on the TPU and the CPU).
         # (Ops take explicit layout args; they no longer read the policy.)
         from ..numeric import resolve_conv_layout
         self.conv_layout = resolve_conv_layout(
@@ -107,11 +104,8 @@ class Net:
             raise ValueError(f"unknown conv_layout {self.conv_layout!r}")
         self.fuse_conv_epilogues = fuse_conv_epilogues
         # Conv lowering strategy, also a graph-level request resolved at
-        # construction — but to a PER-LAYER choice: "auto" measures each
-        # conv layer's candidates (direct/im2col/s2d) with short
-        # micro-runs and persists the winner (ops/conv_tune.py); a
-        # concrete value forces one strategy net-wide; "" keeps the
-        # legacy global conv_s2d policy.
+        # construction: a value forces one strategy net-wide; "" keeps
+        # the global conv_s2d policy.
         self.conv_strategy = (conv_strategy if conv_strategy is not None
                               else policy().conv_strategy) or ""
         if self.conv_strategy not in NN.CONV_STRATEGIES:
@@ -329,32 +323,15 @@ class Net:
 
     def _plan_conv_strategies(self) -> None:
         """Resolve each conv layer's lowering strategy. "" leaves the
-        legacy global-policy behavior (layer.conv_strategy stays None); a
-        concrete strategy is assigned net-wide; "auto" resolves a MEASURED
-        winner per layer through ops/conv_tune.py — keyed purely by
-        geometry, so GoogLeNet's shape-identical inception branches
-        measure once, and persisted through the compile-cache tuned store
-        so the next process with this job config skips the micro-runs."""
+        global-policy behavior (layer.conv_strategy stays None); a
+        concrete strategy is assigned net-wide."""
         req = self.conv_strategy
         convs = [l for l in self.layers if l.TYPE == "CONVOLUTION"]
         if not req:
             self._route_one_channel_convs(convs)
             return
-        if not convs:
-            return
-        if req != "auto":
-            for layer in convs:
-                layer.conv_strategy = req
-            return
-        from ..ops import conv_tune
-        from ..runtime.metrics import log
         for layer in convs:
-            n, c, h, w = self.blob_shapes[layer.lp.bottom[0]]
-            doc = conv_tune.resolve(
-                layer.name, c, h, w, layer.kernel, layer.stride, layer.pad,
-                layer.group, layer.params[0].shape[0], layer.run_layout, n)
-            layer.conv_strategy = doc["winner"]
-            log(f"[conv_strategy] {conv_tune.describe(doc)}")
+            layer.conv_strategy = req
 
     def _route_one_channel_convs(self, convs) -> None:
         """No strategy asked for, lowering for the TPU: a conv over ONE

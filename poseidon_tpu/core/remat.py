@@ -3,8 +3,8 @@
 Every perf lever so far (layout, arena, kernels, wire codec) attacks
 time; this module attacks MEMORY — the axis that actually bounds the
 per-chip batch, and through it MFU, on real TPUs. The mechanism follows
-the repo's cost-based-optimizer discipline (Caffe con Troll,
-arXiv:1504.04343, via ops/conv_tune.py and runtime/tuned_plan.py):
+a cost-based optimizer's discipline (Caffe con Troll,
+arXiv:1504.04343):
 recomputation is a scheduler-level memory/compute trade (TensorFlow,
 arXiv:1605.08695), so the choice of WHICH activations to drop is made
 from measured numbers, not vibes:
@@ -46,7 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 #                     elementwise/softmax tissue between dots)
 #   nothing_saveable  checkpoint blocks saving only block inputs — the
 #                     legacy remat=True behavior, maximal reclaim
-#   auto              defer to the RematPlan / TunedPlan row
+#   auto              defer to the RematPlan's row
 REMAT_POLICIES = ("none", "dots_saveable", "nothing_saveable", "auto")
 
 

@@ -46,7 +46,7 @@ class TransformerConfig:
     # (core/remat.REMAT_POLICIES): "none" | "dots_saveable" (keep matmul
     # results, recompute the cheap tissue between them — the measured
     # default) | "nothing_saveable" (save only block inputs, maximal
-    # reclaim) | "auto" (follow the RematPlan / TunedPlan row). The legacy
+    # reclaim) | "auto" (follow the RematPlan's row). The legacy
     # bools still work: True means dots_saveable, False means unset.
     remat: "bool | str" = False
 
@@ -225,8 +225,8 @@ def forward(params: Dict, cfg: TransformerConfig, tokens: jax.Array,
     """tokens (B, S_local) -> logits (B, S_local, V). With ``seq_axis``,
     attention runs as a ring over that mesh axis; everything else is local.
 
-    ``remat_policy`` is a plan-side override (the RematPlan / TunedPlan
-    row); it resolves against ``cfg.remat`` via
+    ``remat_policy`` is a plan-side override (the RematPlan's row); it
+    resolves against ``cfg.remat`` via
     ``core/remat.resolve_lm_policy`` — an explicit config flag that
     contradicts a concrete plan value refuses loudly."""
     x = embed_tokens(params, tokens, pos_offset)

@@ -48,13 +48,9 @@ class Policy:
     # exact up to float summation order; off by default so golden-value
     # tests compare the direct formulation.
     conv_s2d: bool = False
-    # Conv lowering strategy — per-LAYER, not global (the Caffe con Troll
-    # result: measured per-layer strategy choice is worth 3-4x in the
-    # small-filter regime). "" = legacy (conv_s2d decides), "auto" =
-    # measure direct/im2col/s2d per conv layer at Net construction with
-    # short micro-runs and persist the winner keyed by (layer shape,
-    # backend, device kind) — ops/conv_tune.py; a concrete value forces
-    # one strategy net-wide. Net(conv_strategy=...) overrides per net.
+    # Conv lowering strategy: "" = conv_s2d decides; "direct" | "im2col" |
+    # "s2d" forces one strategy net-wide. Net(conv_strategy=...) overrides
+    # per net.
     conv_strategy: str = ""
 
 
@@ -70,22 +66,14 @@ BF16_SMOKE_RTOL = 0.10
 BF16_SMOKE_ATOL = 0.05
 
 
-def resolve_conv_layout(layout: str, backend: str = None,
-                        consult_plan: bool = True) -> str:
+def resolve_conv_layout(layout: str, backend: str = None) -> str:
     """Resolve a conv_layout choice ("NCHW" | "NHWC" | "auto") against the
-    backend actually running the net.
-
-    "auto" first consults the active :mod:`runtime.tuned_plan` resolution:
-    when a measured TunedPlan is loaded for this run, its conv_layout
-    winner IS the auto answer — the per-backend table below became one
-    measured row of the plan (ROADMAP item 5). Without a plan (or with
-    ``consult_plan=False`` — the tune search uses this to build the
-    default arm) auto falls back to the built-in table:
+    backend actually running the net. "auto" is this table:
 
     - **tpu**: NCHW. The NHWC plan wins the HLO-transpose count (exactly
       the fc-boundary pair) but ran 0.53x in the one chip A/B on record
       (July 2026, before PRs 1-19; not re-measured on this code), so
-      auto stays NCHW until a measured plan shows >= 1.0 (ROADMAP S6).
+      auto stays NCHW until a chip A/B shows >= 1.0 (ROADMAP D3).
     - **gpu**: NHWC (tensor-core native conv layout).
     - **cpu** (and anything unknown): NCHW — the Caffe-parity default the
       golden-value suites run under.
@@ -94,11 +82,6 @@ def resolve_conv_layout(layout: str, backend: str = None,
     lay = (layout or "NCHW").upper()
     if lay != "AUTO":
         return lay
-    if consult_plan:
-        from .runtime.tuned_plan import active_plan_value
-        measured = active_plan_value("conv_layout")
-        if measured:
-            return str(measured).upper()
     if backend is None:
         import jax
         backend = jax.default_backend()
@@ -129,8 +112,8 @@ def set_policy(**kwargs) -> None:
 
 
 def set_perf_policy(**overrides) -> None:
-    """THE bf16 perf config, in one place (bench.py and ``train --bf16``
-    both route here): MXU-native bfloat16 compute plus the space-to-depth
+    """THE bf16 perf config, in one place (``train --bf16`` routes here):
+    MXU-native bfloat16 compute plus the space-to-depth
     stem rewrite — conv1's 3 input channels use 3/128 MXU lanes, and the
     rewrite is exact up to float summation order, so it rides every perf
     run by default. Caffe-parity (f32) runs never come through here, so

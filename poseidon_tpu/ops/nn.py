@@ -153,11 +153,8 @@ def _s2d_applicable(x, w, stride, group, layout: str) -> bool:
     return policy().conv_s2d and _s2d_shape_ok(x, w, stride, group, layout)
 
 
-# the per-layer lowering-strategy axis (Caffe con Troll's measured-choice
-# regime): "" = legacy (the global conv_s2d policy decides), "auto" is
-# resolved to a concrete winner per layer at Net construction
-# (ops/conv_tune.py) and never reaches conv2d
-CONV_STRATEGIES = ("", "auto", "direct", "im2col", "s2d")
+# the conv lowering strategies: "" = the global conv_s2d policy decides
+CONV_STRATEGIES = ("", "direct", "im2col", "s2d")
 
 
 def conv_strategy_applicable(strategy: str, x, w, stride, group,
@@ -224,9 +221,7 @@ def conv2d(
     "s2d" (the space-to-depth stem rewrite — exact up to float summation
     order), or None/"" for the legacy behavior (the global ``conv_s2d``
     policy decides). A strategy that cannot lower this conv (grouped
-    im2col, non-stem s2d) silently takes direct — the per-layer measured
-    choice (core/net.py + ops/conv_tune.py) only ever picks applicable
-    candidates.
+    im2col, non-stem s2d) silently takes direct.
 
     Epilogue (fused into the conv consumer so XLA emits one kernel per
     conv layer): ``y = act((conv(x, w) + b) * scale + shift)``, every
@@ -238,10 +233,9 @@ def conv2d(
     xc = x.astype(p.compute_dtype)
     wc = w.astype(p.compute_dtype)
     strategy = strategy or ""
-    if strategy not in CONV_STRATEGIES or strategy == "auto":
-        raise ValueError(f"conv2d: unresolved strategy {strategy!r} "
-                         f"(choose from {CONV_STRATEGIES[2:]}; 'auto' is "
-                         f"resolved per layer at Net construction)")
+    if strategy not in CONV_STRATEGIES:
+        raise ValueError(f"conv2d: unknown strategy {strategy!r} "
+                         f"(choose from {CONV_STRATEGIES[1:]})")
     use_s2d = (_s2d_applicable(xc, wc, stride, group, layout)
                if strategy == "" else
                strategy == "s2d" and _s2d_shape_ok(xc, wc, stride, group,

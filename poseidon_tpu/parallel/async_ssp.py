@@ -572,6 +572,7 @@ class ParamService:
         self._record_events = record_events
         self.events: List[Tuple] = []
         self._srv = socket.create_server((host, port))
+        self._srv.settimeout(0.25)   # before the accept thread exists
         self.port = self._srv.getsockname()[1]
         self._stop = threading.Event()
         self._threads: List[threading.Thread] = []
@@ -585,7 +586,6 @@ class ParamService:
 
     # ---- server loop ---------------------------------------------------- #
     def _accept_loop(self) -> None:
-        self._srv.settimeout(0.25)
         while not self._stop.is_set():
             try:
                 conn, _ = self._srv.accept()

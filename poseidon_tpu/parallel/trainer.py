@@ -149,7 +149,7 @@ def build_train_step(
     """Compiled SPMD train step over ``mesh``.
 
     ``remat_plan`` (a ``core/remat.RematPlan``, from ``--hbm_budget_gb``
-    or the TunedPlan's measured remat row) wraps the named layers'
+    or ``--remat``) wraps the named layers'
     forward bodies in ``jax.checkpoint`` inside ``Net.apply`` — stored
     activations drop until the step fits the HBM budget, at the cost of
     recomputing those layers' forwards during backward. Composes with

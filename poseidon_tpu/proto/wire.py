@@ -149,18 +149,11 @@ _wire_stats = {
 
 
 def wire_stats() -> Dict[str, int]:
-    """Process-wide codec telemetry (encode/decode time and bytes) for
-    ``bench.py comms``'s ``wire_encode_ms``/``wire_decode_ms`` lines.
+    """Process-wide codec telemetry (encode/decode time and bytes).
     Timers cover ONLY (de)serialization — socket time is excluded, so
     the numbers compare against link transfer time directly."""
     with _wire_stats_lock:
         return dict(_wire_stats)
-
-
-def reset_wire_stats() -> None:
-    with _wire_stats_lock:
-        for k in _wire_stats:
-            _wire_stats[k] = 0
 
 
 def wire_codec_enabled() -> bool:

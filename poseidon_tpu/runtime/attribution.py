@@ -666,10 +666,9 @@ def format_table(result: Dict, title: str = "") -> str:
 
 def measure_then_trace(run_step, trace_dir: str, iters: int = 3) -> Dict:
     """Run the TIMED loop first (min-wall over ``iters`` calls, the
-    one-sided-noise estimator bench.py uses), then capture exactly one
-    traced step into ``trace_dir``. Profiler overhead can therefore never
-    contaminate the reported step time — the same discipline as the
-    headline trace capture at the bottom of bench.main (and pinned by
+    estimator for one-sided noise), then capture exactly one traced step
+    into ``trace_dir``. Profiler overhead can therefore never contaminate
+    the reported step time (pinned by
     tests/test_attribution.py::test_trace_capture_stays_after_timing).
 
     ``run_step`` is a zero-arg callable that dispatches one step and

@@ -39,16 +39,12 @@ def _engine_from_args(args, phase_nets=True):
     from .engine import Engine
 
     import dataclasses
-    sp = getattr(args, "_loaded_solver", None) or load_solver(args.solver)
-    # sentinel None = "no explicit flag": the TunedPlan resolution in
-    # cmd_train already replaced these with plan/default values; a direct
-    # _engine_from_args caller (tests) gets the built-in defaults
-    arena_mb = getattr(args, "arena_bucket_mb", None)
+    sp = load_solver(args.solver)
     # --wire_dtype rides TWO tiers: the compiled collectives (CommConfig,
     # bf16/f16 only) and the managed DCN payload codec (async tier, which
     # also takes int8). int8 never enters the compiled config — the local
     # mesh stays at gradient dtype while the DCN frames compress.
-    wd_flag = getattr(args, "wire_dtype", None) or None
+    wd_flag = args.wire_dtype or None
     if wd_flag == "int8":
         if not getattr(args, "async_ssp", False):
             raise SystemExit(
@@ -65,7 +61,7 @@ def _engine_from_args(args, phase_nets=True):
                           else args.dwbp_bucket_mb),
                       param_arena=(getattr(args, "param_arena", "true")
                                    == "true"),
-                      arena_bucket_mb=4.0 if arena_mb is None else arena_mb,
+                      arena_bucket_mb=args.arena_bucket_mb,
                       server_logic=getattr(args, "server_logic", "inc"),
                       adarev_init_step=getattr(args, "adarev_init_step", 0.1))
     if args.sfb_auto:
@@ -73,7 +69,7 @@ def _engine_from_args(args, phase_nets=True):
         comm = dataclasses.replace(comm, default_strategy="dense")
     mesh = None
     mesh_cfg = None
-    mesh_spec = getattr(args, "mesh", "")
+    mesh_spec = args.mesh
     if mesh_spec:
         from ..config import MeshConfig
         mesh_cfg = MeshConfig.parse(mesh_spec)
@@ -128,14 +124,10 @@ def _engine_from_args(args, phase_nets=True):
             async_cfg["comm_priority_frac"] = v
         if getattr(args, "comm_adaptive", False):
             async_cfg["comm_adaptive"] = True
-        # wire dtype resolution, flag > TunedPlan > default: an explicit
-        # flag rides here (overriding the ManagedCommConfig the TunedPlan
-        # resolution installed); args.wire_dtype itself is NEVER mutated,
-        # so a plan-resolved dtype cannot leak into the compiled-tier
-        # CommConfig above
-        wd = getattr(args, "wire_dtype", "") or ""
-        if wd:
-            async_cfg["comm_wire_dtype"] = wd
+        # the managed DCN frames take the flag as given (int8 included);
+        # unset, the tier falls back to ManagedCommConfig.wire_dtype
+        if args.wire_dtype:
+            async_cfg["comm_wire_dtype"] = args.wire_dtype
         # two-tier fabric: this process leads an SPMD slice and the DCN
         # worker identity is the slice id (runtime/async_tier.FabricTier;
         # needs the POSEIDON_SLICE_ID/POSEIDON_SLICE_SIZE env contract)
@@ -146,26 +138,25 @@ def _engine_from_args(args, phase_nets=True):
         raise SystemExit("--slice composes the two-tier fabric on top of "
                          "the async tier; it requires --async_ssp")
     metrics_port = getattr(args, "metrics_port", -1)
-    spd = getattr(args, "steps_per_dispatch", None)
     return Engine(sp, comm=comm, mesh=mesh, mesh_cfg=mesh_cfg,
                   output_dir=args.output_dir,
                   staleness=staleness, sfb_auto=args.sfb_auto,
-                  steps_per_dispatch=1 if spd is None else spd,
+                  steps_per_dispatch=args.steps_per_dispatch,
                   device_transform=getattr(args, "device_transform", False),
                   async_ssp=async_cfg,
-                  device_prefetch=getattr(args, "device_prefetch", None),
-                  max_in_flight=getattr(args, "max_in_flight", None),
-                  async_snapshot=getattr(args, "async_snapshot", None),
+                  device_prefetch=args.device_prefetch,
+                  max_in_flight=args.max_in_flight,
+                  async_snapshot=args.async_snapshot,
                   trace_out=getattr(args, "trace_out", "") or None,
                   metrics_port=metrics_port if metrics_port >= 0 else None,
-                  hbm_budget_gb=getattr(args, "hbm_budget_gb", None),
-                  remat=getattr(args, "remat", None) or None)
+                  hbm_budget_gb=args.hbm_budget_gb,
+                  remat=args.remat or None)
 
 
 def _enable_compile_cache_from_args(args) -> None:
     """Stage the fast-restart layers (persistent XLA compile cache + AOT
     step store) before any program is compiled. Shared by train/serve/
-    bench_serve/tune. The location is not a flag: it is
+    bench_serve. The location is not a flag: it is
     ``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``
     (compile_cache.resolve_cache_dir)."""
     from .compile_cache import enable_compile_cache
@@ -174,68 +165,6 @@ def _enable_compile_cache_from_args(args) -> None:
     from .metrics import log
     log(f"compile cache: persistent XLA cache at {resolved} "
         f"(aot_steps={getattr(args, 'aot_steps', 'true')})")
-
-
-def _apply_tuned_plan_train(args) -> None:
-    """TunedPlan auto-load for cmd_train (runtime/tuned_plan.py): fold the
-    persisted plan for (train net, backend, n_devices) under the EXPLICIT
-    flags — flag > plan > built-in default, per knob — install the policy
-    (conv_layout / conv_strategy / pipeline config), publish the
-    resolution (the engine writes its provenance into stats.yaml), and
-    mutate the sentinel-defaulted args in place with the resolved values.
-    ``--tuned_plan off`` skips the store entirely (defaults + flags
-    only)."""
-    from .metrics import log
-    from .tuned_plan import (apply_training_resolution, load_plan, resolve,
-                             store_dir)
-
-    explicit = {}
-    if getattr(args, "conv_layout", ""):
-        explicit["conv_layout"] = args.conv_layout.upper()
-    if getattr(args, "conv_strategy", ""):
-        explicit["conv_strategy"] = args.conv_strategy
-    if getattr(args, "arena_bucket_mb", None) is not None:
-        explicit["arena_bucket_mb"] = args.arena_bucket_mb
-    if getattr(args, "mesh", ""):
-        explicit["mesh"] = args.mesh
-    if getattr(args, "device_prefetch", None) is not None:
-        explicit["device_prefetch"] = args.device_prefetch
-    if getattr(args, "max_in_flight", None) is not None:
-        explicit["max_in_flight"] = args.max_in_flight
-    if getattr(args, "steps_per_dispatch", None) is not None:
-        explicit["steps_per_dispatch"] = args.steps_per_dispatch
-    if getattr(args, "wire_dtype", ""):
-        explicit["wire_dtype"] = args.wire_dtype
-    if getattr(args, "remat", None) is not None:
-        explicit["remat"] = args.remat
-    if getattr(args, "hbm_budget_gb", None) is not None:
-        explicit["hbm_budget_gb"] = args.hbm_budget_gb
-
-    doc, store = None, ""
-    if getattr(args, "tuned_plan", "auto") != "off":
-        from ..proto.messages import load_solver
-        from .engine import resolve_nets
-        # parse once; _engine_from_args reuses the loaded SolverParameter
-        # instead of re-reading the solver + net prototxt from disk
-        args._loaded_solver = load_solver(args.solver)
-        train_param, _ = resolve_nets(args._loaded_solver)
-        model = (train_param.name or "net").lower()
-        store = store_dir()
-        doc = load_plan(model, cache_dir=store)
-        if doc is None:
-            log(f"[tuned_plan] no plan for {model!r} in {store}; "
-                f"built-in defaults apply (run `python -m poseidon_tpu "
-                f"tune --model ...` to measure one)")
-    res = resolve(doc, explicit, store=store)
-    knobs = apply_training_resolution(res)
-    log(f"[tuned_plan] {res.describe()}")
-    args.arena_bucket_mb = knobs["arena_bucket_mb"]
-    args.mesh = knobs["mesh"]
-    args.steps_per_dispatch = knobs["steps_per_dispatch"]
-    args.device_prefetch = knobs["device_prefetch"]
-    args.max_in_flight = knobs["max_in_flight"]
-    args.remat = knobs["remat"]
-    args.hbm_budget_gb = knobs["hbm_budget_gb"]
 
 
 def cmd_train(args) -> int:
@@ -255,20 +184,18 @@ def cmd_train(args) -> int:
                 "scripts/launch.py) instead of --hostfile/--node_id")
     else:
         # FIRST: jax.distributed.initialize refuses to run once anything
-        # has touched the backend, and the plan resolution below does
-        # (jax.default_backend() for the plan key)
+        # has touched the backend
         init_distributed(hostfile=args.hostfile or None,
                          node_id=args.node_id if args.node_id >= 0 else None)
     _enable_compile_cache_from_args(args)
+    from .. import config
     if args.bf16:
-        from .. import config
         config.set_perf_policy()
-    # TunedPlan resolution replaces the old ad-hoc per-flag policy pokes:
-    # conv_strategy / conv_layout land in the numeric policy, the pipeline
-    # knobs in PipelineConfig, and the engine-level knobs back onto args —
-    # explicit flags always win, plan values fill the gaps, built-in
-    # defaults bat last, with every source recorded in stats.yaml
-    _apply_tuned_plan_train(args)
+    # the two graph-level requests Net reads from the numeric policy at
+    # construction; every other knob reaches the Engine as an argument
+    config.set_policy(conv_layout=args.conv_layout.upper())
+    if args.conv_strategy:
+        config.set_policy(conv_strategy=args.conv_strategy)
     eng = _engine_from_args(args)
     eng.profile_steps = args.profile
     if args.snapshot == "auto":
@@ -535,37 +462,6 @@ layers { name: "prob" type: SOFTMAX bottom: "fc" top: "prob" }
 """
 
 
-def _resolve_serve_buckets(args) -> str:
-    """The serving bucket ladder through TunedPlan resolution: an explicit
-    --buckets flag wins; else the persisted plan for the deploy net (keyed
-    like train's: net name, backend, n_devices) supplies its measured
-    ladder; else the built-in default. The source is logged so a serving
-    log always says where its ladder came from."""
-    from .metrics import log
-    from .tuned_plan import BUILTIN_DEFAULTS, load_plan
-
-    spec = getattr(args, "buckets", "")
-    if spec:
-        return spec
-    if getattr(args, "model", "") and \
-            getattr(args, "tuned_plan", "auto") != "off":
-        try:
-            from ..proto.messages import load_net
-            model_name = (load_net(args.model).name or "").lower()
-        except Exception as e:  # noqa: BLE001 — the executor build will
-            model_name = ""     # surface a real model problem loudly
-            log(f"[tuned_plan] could not read {args.model!r} for plan "
-                f"lookup ({type(e).__name__}: {e}); default ladder")
-        if model_name:
-            doc = load_plan(model_name)
-            ladder = (doc or {}).get("knobs", {}).get("serve_buckets")
-            if ladder:
-                log(f"[tuned_plan] serve_buckets={ladder} "
-                    f"(plan {str(doc.get('key', '?'))[:12]})")
-                return ladder
-    return BUILTIN_DEFAULTS["serve_buckets"]
-
-
 def _build_serving_executor(model: str, weights: str, buckets: str,
                             device=None):
     """Shared by serve/bench_serve: deploy net (or the built-in synthetic
@@ -636,27 +532,7 @@ def build_serving_fleet(model: str, weights: str, buckets: str,
 LLM_PRESETS = ("tiny", "gpt_small")
 
 
-def _resolve_llm_knobs(args) -> dict:
-    """The LLM serving knobs through TunedPlan resolution (same idiom as
-    :func:`_resolve_serve_buckets`): a persisted plan's measured
-    ``llm_page_size``/``llm_decode_rungs``/``llm_prompt_buckets`` win over
-    the built-in defaults; the source is logged either way."""
-    from .metrics import log
-    from .tuned_plan import BUILTIN_DEFAULTS, load_plan
-
-    keys = ("llm_page_size", "llm_decode_rungs", "llm_prompt_buckets")
-    knobs = {k: BUILTIN_DEFAULTS[k] for k in keys}
-    if getattr(args, "tuned_plan", "auto") != "off":
-        doc = load_plan(args.model)
-        hits = {k: (doc or {}).get("knobs", {}).get(k) for k in keys}
-        knobs.update({k: v for k, v in hits.items() if v})
-        if any(hits.values()):
-            log(f"[tuned_plan] llm serving knobs {knobs} "
-                f"(plan {str((doc or {}).get('key', '?'))[:12]})")
-    return knobs
-
-
-def _build_generate_executor(preset: str, knobs: dict, device=None):
+def _build_generate_executor(preset: str, device=None):
     """A warmed paged-KV :class:`GenerateExecutor` over a named transformer
     preset. ``--generate`` serving has no snapshot format yet, so params
     are preset-initialized (the same smoke contract as an empty
@@ -664,7 +540,7 @@ def _build_generate_executor(preset: str, knobs: dict, device=None):
     import jax
     from ..models.transformer import (TransformerConfig, gpt_small_config,
                                       init_params)
-    from ..serving.continuous import GenerateExecutor, parse_rungs
+    from ..serving.continuous import DEFAULT_PROMPT_BUCKETS, GenerateExecutor
 
     if preset == "gpt_small":
         cfg = gpt_small_config(max_seq=512, remat=False)
@@ -679,12 +555,9 @@ def _build_generate_executor(preset: str, knobs: dict, device=None):
     params = init_params(cfg, jax.random.PRNGKey(0))
     # a preset smaller than the default ladder drops the buckets it
     # cannot hold rather than refusing to serve
-    buckets = tuple(b for b in parse_rungs(knobs["llm_prompt_buckets"])
-                    if b < cfg.max_seq)
-    return GenerateExecutor(
-        cfg, params, page_size=int(knobs["llm_page_size"]),
-        decode_rungs=parse_rungs(knobs["llm_decode_rungs"]),
-        prompt_buckets=buckets, device=device)
+    buckets = tuple(b for b in DEFAULT_PROMPT_BUCKETS if b < cfg.max_seq)
+    return GenerateExecutor(cfg, params, prompt_buckets=buckets,
+                            device=device)
 
 
 def _cmd_serve_generate(args) -> int:
@@ -703,7 +576,6 @@ def _cmd_serve_generate(args) -> int:
         raise SystemExit("--generate serves preset-initialized params; "
                          "--weights/--watch have no LLM snapshot format "
                          "to load yet")
-    knobs = _resolve_llm_knobs(args)
     replicas = max(1, getattr(args, "replicas", 1))
     fleet_mode = replicas > 1 or bool(getattr(args, "devices", ""))
     manager = None
@@ -713,8 +585,7 @@ def _cmd_serve_generate(args) -> int:
                                          replicas)
 
         def factory(device):
-            return _build_generate_executor(args.model, knobs,
-                                            device=device)
+            return _build_generate_executor(args.model, device=device)
 
         manager = ReplicaManager.build(factory, replicas, devices=devices,
                                        max_queue=args.max_queue)
@@ -723,7 +594,7 @@ def _cmd_serve_generate(args) -> int:
             f"({args.model}, page_size={ref.page_size}, "
             f"rungs={ref.decode_rungs}, buckets={ref.prompt_buckets})")
     else:
-        executor = _build_generate_executor(args.model, knobs)
+        executor = _build_generate_executor(args.model)
         log(f"serve: warmed generate executor ({args.model}, "
             f"page_size={executor.page_size}, "
             f"rungs={executor.decode_rungs}, "
@@ -792,7 +663,6 @@ def cmd_serve(args) -> int:
     # training tier pays: the persistent cache turns a restarted replica's
     # AOT bucket compiles into disk reads
     _enable_compile_cache_from_args(args)
-    args.buckets = _resolve_serve_buckets(args)
     watch = args.watch
     if watch == "auto":
         # derive the snapshot prefix from the weights path:
@@ -892,9 +762,8 @@ def cmd_serve(args) -> int:
 def run_serving_bench(executor, requests: int, concurrency: int, batch: int,
                       max_delay_ms: float = 5.0, max_queue: int = 64,
                       deadline_ms=None, fleet=None, offered_rps=None):
-    """The in-process serving bench driver shared by `bench_serve` and
-    bench.py's serving mode: port-0 server + the load generator, request
-    sizes cycling 1..batch over the bucket ladder. Pass ``fleet`` (a
+    """The in-process serving bench driver behind `bench_serve`: port-0
+    server + the load generator, request sizes cycling 1..batch over the bucket ladder. Pass ``fleet`` (a
     ReplicaManager; ``executor=None``) to stand the whole fleet behind
     the front door, and ``offered_rps`` for the open-loop arrival-rate
     mode. Returns (run_load result, server stats snapshot)."""
@@ -939,7 +808,6 @@ def cmd_bench_serve(args) -> int:
     import json
 
     _enable_compile_cache_from_args(args)
-    args.buckets = _resolve_serve_buckets(args)
     replicas = max(1, getattr(args, "replicas", 1))
     offered = (args.offered_rps if getattr(args, "offered_rps", 0) > 0
                else None)
@@ -984,45 +852,6 @@ def cmd_bench_serve(args) -> int:
     print(json.dumps({"metric": "serving_p99_ms",
                       "value": result["p99_ms"],
                       "unit": "ms", **result}), flush=True)
-    return 0
-
-
-def cmd_tune(args) -> int:
-    """The measured autotuner (runtime/tuned_plan.py, ROADMAP item 5):
-    short wall-clock trials over the whole policy space — conv_layout,
-    per-layer conv_strategy, arena_bucket_mb, mesh factorization, the
-    step-pipeline knobs, serving bucket rungs — persisted as ONE TunedPlan
-    with provenance next to the AOT executables. train/serve/bench_serve
-    auto-load the matching plan at startup; a second ``tune`` memo-hits
-    the store and skips re-measurement (--force re-tunes). Prints one
-    JSON summary line."""
-    import json
-
-    from .tuned_plan import run_tune
-
-    # the plan store rides the compile-cache dir (plans live next to the
-    # executables they tuned), so a zero-flag tune -> train round trips
-    _enable_compile_cache_from_args(args)
-    result = run_tune(args.model, smoke=args.smoke, force=args.force,
-                      deploy=args.deploy,
-                      windows=args.windows or None,
-                      iters=args.iters or None)
-    doc = result["doc"]
-    if args.out:
-        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
-                    exist_ok=True)
-        tmp = f"{args.out}.tmp.{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump(doc, f, indent=1, sort_keys=True)
-        os.replace(tmp, args.out)
-    print(json.dumps({
-        "metric": "tune", "model": doc["model"],
-        "backend": doc["backend"], "device_kind": doc["device_kind"],
-        "source": result["source"], "path": result["path"],
-        "knobs": doc["knobs"],
-        "search_cost_s": doc.get("search_cost_s"),
-        "tuned_vs_default_speedup": doc.get("ab", {}).get("speedup"),
-    }), flush=True)
     return 0
 
 
@@ -1120,7 +949,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "analog); with --async_ssp it also compresses the "
                         "managed DCN delta frames with exact error feedback "
                         "(int8 is DCN-only); empty = exchange at gradient "
-                        "dtype (flag > TunedPlan knob > f32 default)")
+                        "dtype")
     t.add_argument("--topk_block", type=int, default=0,
                    help="blocked top-k selection: pick top-k within blocks "
                         "of this many elements instead of one global sort "
@@ -1142,12 +971,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "order. Parameters and solver history are never "
                         "packed, the update is per leaf either way, and on "
                         "one device the flag changes nothing")
-    t.add_argument("--arena_bucket_mb", type=float, default=None,
+    t.add_argument("--arena_bucket_mb", type=float, default=4.0,
                    help="arena gradient-sync bucket size in MB (DWBP-"
                         "ordered exact element ranges; <= 0 = one bucket "
-                        "per leaf). Unset = TunedPlan value if one is "
-                        "persisted, else 4.0")
-    t.add_argument("--hbm_budget_gb", type=float, default=None,
+                        "per leaf)")
+    t.add_argument("--hbm_budget_gb", type=float, default=0.0,
                    help="per-device HBM budget (GiB) for the measured "
                         "remat planner (core/remat.py): the no-remat "
                         "train step compiles once, its real "
@@ -1156,16 +984,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "stored activations (jax.checkpoint on the "
                         "chosen layers) until the step fits. Negative = "
                         "auto-detect the device's own HBM limit; 0 = "
-                        "off. Unset = TunedPlan value if persisted, "
-                        "else off")
-    t.add_argument("--remat", default=None,
+                        "off")
+    t.add_argument("--remat", default="",
                    help="activation remat override: a comma-separated "
                         "layer list checkpoints exactly those layers "
                         "(no measuring compile), 'auto' plans against "
-                        "--hbm_budget_gb, 'none' forces remat off. "
-                        "Unset = TunedPlan value if persisted, else "
-                        "off. Conflicts with a persisted plan refuse "
-                        "loudly rather than silently arbitrating")
+                        "--hbm_budget_gb; empty or 'none' = off")
     t.add_argument("--bf16", action="store_true",
                    help="the documented bf16 training path: bfloat16 "
                         "compute (MXU-native) + the exact space-to-depth "
@@ -1175,31 +999,19 @@ def build_parser() -> argparse.ArgumentParser:
                         "numeric.BF16_SMOKE_* (tests/test_kernels.py). "
                         "Default f32 matches Caffe numerics exactly")
     t.add_argument("--conv_strategy", default="",
-                   choices=["", "auto", "direct", "im2col", "s2d"],
-                   help="conv lowering strategy: 'auto' MEASURES direct/"
-                        "im2col/s2d per conv layer at net construction "
-                        "(short micro-runs; winners logged and persisted "
-                        "in the compile-cache dir so the next run skips "
-                        "re-measurement), a concrete value forces one "
-                        "strategy net-wide; empty = the TunedPlan value "
-                        "if one is persisted, else the legacy global "
-                        "conv_s2d policy (on under --bf16)")
-    t.add_argument("--conv_layout", default="",
+                   choices=["", "direct", "im2col", "s2d"],
+                   help="conv lowering strategy: a value forces one "
+                        "strategy net-wide; empty = the global conv_s2d "
+                        "policy (on under --bf16), with a 1-input-channel "
+                        "conv lowered as im2col for the TPU")
+    t.add_argument("--conv_layout", default="auto",
                    type=lambda s: s.lower(),
-                   choices=["", "nchw", "nhwc", "auto"],
+                   choices=["nchw", "nhwc", "auto"],
                    help="internal activation layout for the whole graph "
                         "(core/net.py plans conv/pool/LRN natively in it; "
-                        "checkpoints stay canonical NCHW). Unset = the "
-                        "TunedPlan's measured row if one is persisted, "
-                        "else 'auto' (the per-backend table in "
-                        "numeric.resolve_conv_layout)")
-    t.add_argument("--tuned_plan", default="auto", choices=["auto", "off"],
-                   help="TunedPlan auto-load (runtime/tuned_plan.py): "
-                        "'auto' loads the persisted plan matching (train "
-                        "net, backend, device kind, devices) and fills "
-                        "every knob no explicit flag set — provenance "
-                        "lands in stats.yaml; 'off' = built-in defaults "
-                        "+ flags only")
+                        "checkpoints stay canonical NCHW). 'auto' = the "
+                        "per-backend table in numeric.resolve_conv_layout "
+                        "(NCHW on tpu and cpu)")
     t.add_argument("--mesh", default="",
                    help="named SPMD mesh spec, e.g. 'dp2,fsdp2,tp1' "
                         "(axes: dp = data parallel, fsdp = sharded "
@@ -1294,12 +1106,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="cluster hostfile ('<id> <ip> <port>' lines)")
     t.add_argument("--node_id", type=int, default=-1,
                    help="this process's hostfile id")
-    t.add_argument("--steps_per_dispatch", type=int, default=None,
+    t.add_argument("--steps_per_dispatch", type=int, default=1,
                    help="run K optimizer steps per compiled dispatch "
                         "(lax.scan): amortizes per-dispatch runtime "
                         "round-trip; falls back to single steps near "
-                        "display/test/snapshot boundaries (unset = "
-                        "TunedPlan value if persisted, else 1)")
+                        "display/test/snapshot boundaries")
     t.add_argument("--device_prefetch", type=int, default=None,
                    help="device-side input prefetch depth: a background "
                         "stage device_puts the next N host batches with "
@@ -1404,11 +1215,8 @@ def build_parser() -> argparse.ArgumentParser:
     sv.add_argument("--buckets", default="",
                     help="batch bucket ladder; every bucket is AOT-"
                          "compiled at startup (no trace on a request). "
-                         "Unset = the deploy net's TunedPlan ladder if "
-                         "one is persisted, else 1,4,16,64")
-    sv.add_argument("--tuned_plan", default="auto", choices=["auto", "off"],
-                    help="'auto' resolves an unset --buckets through the "
-                         "persisted TunedPlan; 'off' = built-in default")
+                         "Unset = serving.executor.DEFAULT_BUCKETS "
+                         "(1,4,16,64)")
     sv.add_argument("--max_delay_ms", type=float, default=5.0,
                     help="micro-batcher flush deadline: a queued request "
                          "never waits longer than this for batch company")
@@ -1435,9 +1243,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="LLM decode serving: --model names a transformer "
                          "preset (tiny|gpt_small) served through the "
                          "paged-KV continuous batcher — 'generate' wire "
-                         "op with streaming gen_chunk frames; page size/"
-                         "decode rungs/prompt buckets resolve through the "
-                         "persisted TunedPlan")
+                         "op with streaming gen_chunk frames; page size, "
+                         "decode rungs and prompt buckets are the "
+                         "constants of serving/continuous.py")
     sv.set_defaults(fn=cmd_serve)
 
     bs = sub.add_parser(
@@ -1448,10 +1256,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "conv net")
     bs.add_argument("--weights", default="")
     bs.add_argument("--buckets", default="",
-                    help="unset = TunedPlan ladder if persisted, else "
-                         "1,4,16,64")
-    bs.add_argument("--tuned_plan", default="auto",
-                    choices=["auto", "off"])
+                    help="unset = serving.executor.DEFAULT_BUCKETS "
+                         "(1,4,16,64)")
     bs.add_argument("--requests", type=int, default=200)
     bs.add_argument("--concurrency", type=int, default=4)
     bs.add_argument("--batch", type=int, default=8,
@@ -1468,38 +1274,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="open-loop mode: fixed arrival rate (req/s); "
                          "0 = closed loop")
     bs.set_defaults(fn=cmd_bench_serve)
-
-    tu = sub.add_parser(
-        "tune", help="measured autotuner: short wall-clock trials over "
-                     "the policy space (conv layout/strategy, arena "
-                     "buckets, mesh, pipeline, serving rungs), persisted "
-                     "as ONE TunedPlan that train/serve auto-load")
-    tu.add_argument("--model", default="lenet",
-                    choices=["lenet", "alexnet", "googlenet"],
-                    help="tune target (plan keyed by the net's name, so "
-                         "a train run on the same model auto-loads it)")
-    tu.add_argument("--smoke", action="store_true",
-                    help="tier-1-safe smoke: tiny shapes, 2-point search "
-                         "spaces, spmd mesh arms skipped (recorded as "
-                         "only-candidate rows, never silently)")
-    tu.add_argument("--force", action="store_true",
-                    help="re-measure even when a matching plan is "
-                         "persisted (default: memo-hit and skip)")
-    tu.add_argument("--deploy", default="",
-                    help="deploy prototxt for the serving-ladder trials "
-                         "(default: a synthetic probe net, labeled)")
-    tu.add_argument("--windows", type=int, default=0,
-                    help="interleaved timing windows per knob (0 = 2 "
-                         "smoke / 4 full)")
-    tu.add_argument("--iters", type=int, default=0,
-                    help="timed calls per window (0 = 2 smoke / 4 full)")
-    tu.add_argument("--out", default="",
-                    help="also write the plan JSON here (evidence copy; "
-                         "the store copy always lands next to the AOT "
-                         "executables)")
-    tu.add_argument("--aot_steps", default="true",
-                    choices=["true", "false"], help=argparse.SUPPRESS)
-    tu.set_defaults(fn=cmd_tune)
 
     ci = sub.add_parser("convert_imageset", help="image list -> LMDB")
     ci.add_argument("listfile")

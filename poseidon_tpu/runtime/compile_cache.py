@@ -39,13 +39,12 @@ import json
 import os
 import pickle
 import zlib
-from typing import Any, Dict, Optional
+from typing import Any, Optional
 
 __all__ = ["resolve_cache_dir", "enable_compile_cache",
            "cache_entries", "watch_cache_hits", "step_key",
            "code_fingerprint",
-           "save_step_executable", "load_step_executable", "aot_entries",
-           "load_tuned", "save_tuned", "tuned_path"]
+           "save_step_executable", "load_step_executable", "aot_entries"]
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -53,8 +52,8 @@ CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
 def resolve_cache_dir() -> str:
-    """THE cache location, for every layer (XLA cache, ``aot/``,
-    ``tuned/``) and every entry point: ``JAX_COMPILATION_CACHE_DIR`` when
+    """THE cache location, for every layer (XLA cache, ``aot/``) and
+    every entry point: ``JAX_COMPILATION_CACHE_DIR`` when
     the environment sets it — then jax itself already reads it and nothing
     in code sets another — else the fixed ``<checkout>/.jax_cache``
     (git-ignored). The path is part of the cache key, so it never comes
@@ -69,7 +68,7 @@ def resolve_cache_dir() -> str:
 def enable_compile_cache(aot_steps: bool = True) -> str:
     """Turn on the persistent compilation cache at
     :func:`resolve_cache_dir` (created if missing) and point the
-    ``aot/``/``tuned/`` stores at the same directory. Every program is
+    ``aot/`` store at the same directory. Every program is
     cached, even sub-second ones. Returns the directory. Must run before
     the programs it should cache are compiled (already-compiled programs
     in this process stay in the in-memory jit cache either way)."""
@@ -267,72 +266,4 @@ def load_step_executable(cache_dir: str, key: str):
         from .metrics import log
         log(f"compile_cache: failed to reload AOT step {key} "
             f"({type(e).__name__}: {e}); recompiling")
-        return None
-
-
-def describe(cache_dir: str) -> Dict[str, int]:
-    """Telemetry: entry counts for stats/bench output."""
-    return {"xla_cache_entries": cache_entries(cache_dir),
-            "aot_step_entries": aot_entries(cache_dir)}
-
-
-# --------------------------------------------------------------------------- #
-# Tuned-policy store: measured decisions persisted next to the executables
-# --------------------------------------------------------------------------- #
-#
-# The per-layer conv lowering-strategy choice (ops/conv_tune.py) is a
-# MEASURED decision keyed by (layer shape, backend, device kind) — the same
-# restart economics as the AOT executables above, so it lives in the same
-# cache directory: a restarted (or brand-new, elastically admitted) process
-# with the same job config loads the winner instead of re-measuring. One
-# JSON file per (namespace, key), atomic rename, any read failure = clean
-# miss. ROADMAP item 5's general `tune` mode is this store grown one
-# namespace per policy knob.
-
-def tuned_path(cache_dir: str, namespace: str, key: str) -> str:
-    return os.path.join(cache_dir, "tuned", f"{namespace}-{key}.json")
-
-
-def load_tuned(cache_dir: str, namespace: str, key: str) -> Optional[Dict]:
-    """The persisted decision document, or None on miss/any failure (a
-    torn or foreign entry degrades to a re-measure, never an abort — this
-    is called mid-Net-construction, where a raise would kill the run). A
-    clean miss (no file) is silent; a file that EXISTS but cannot be
-    parsed is logged loudly, because it means a writer died mid-write or
-    the store was hand-edited — the entry will be re-measured and
-    rewritten."""
-    if not cache_dir:
-        return None
-    path = tuned_path(cache_dir, namespace, key)
-    try:
-        with open(path) as f:
-            return json.load(f)
-    except FileNotFoundError:
-        return None
-    except (OSError, ValueError) as e:
-        from .metrics import log
-        log(f"compile_cache: tuned entry {namespace}-{key} at {path} is "
-            f"torn/unreadable ({type(e).__name__}: {e}); treating as a "
-            f"miss — will re-measure and overwrite")
-        return None
-
-
-def save_tuned(cache_dir: str, namespace: str, key: str,
-               doc: Dict) -> Optional[str]:
-    """Persist a decision document (atomic tmp + rename). Best-effort:
-    returns the path, or None when the store is disabled/unwritable."""
-    if not cache_dir:
-        return None
-    path = tuned_path(cache_dir, namespace, key)
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = f"{path}.tmp.{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump(doc, f, indent=1, sort_keys=True)
-        os.replace(tmp, path)
-        return path
-    except OSError as e:
-        from .metrics import log
-        log(f"compile_cache: tuned entry {namespace}-{key} not persisted "
-            f"({e}); will re-measure next process")
         return None

@@ -129,7 +129,7 @@ class Engine:
         t_build = time.perf_counter()
         self.sp = sp
         # step-pipeline knobs: explicit args win, else the global policy
-        # (config.PipelineConfig; CLI flags land there or here directly)
+        # (config.PipelineConfig)
         from ..config import pipeline_config
         _pc = pipeline_config()
         self.device_prefetch = int(_pc.device_prefetch
@@ -162,16 +162,6 @@ class Engine:
         self.staleness = staleness
         self.output_dir = output_dir
         self.stats = StatsRegistry()
-        # TunedPlan provenance (runtime/tuned_plan.py): when the CLI
-        # resolved a plan for this run, stats.yaml carries every knob's
-        # value + source (flag/plan/default) and which measured winners an
-        # explicit flag overrode — a stats artifact always says what
-        # policy was in effect and why
-        from .tuned_plan import active_resolution
-        self._plan_resolution = active_resolution()
-        if self._plan_resolution is not None:
-            self.stats.set_section("tuned_plan",
-                                   self._plan_resolution.provenance())
         self.rank = jax.process_index()
         self.world = jax.process_count()
         # --- telemetry spine ------------------------------------------- #
@@ -376,7 +366,7 @@ class Engine:
         if self.remat_plan is not None:
             log(self.remat_plan.describe(), rank=self.rank)
             # stats.yaml says WHAT dropped and WHY (budget, measured
-            # peak, claimed bytes) — the tuned-plan provenance discipline
+            # peak, claimed bytes)
             self.stats.set_section("remat", self.remat_plan.to_doc())
 
         # --- compiled steps ---------------------------------------------- #
@@ -596,7 +586,7 @@ class Engine:
           compile it against abstract batch avals, read the real
           ``memory_analysis()`` peak, and run the knapsack
           (source="measured"; the no-remat compile is the price of
-          measuring — the tuned store memoizes the decision);
+          measuring);
         - ``hbm_budget_gb`` < 0: auto-detect the device's own HBM limit
           (``default_budget_bytes``); refuses quietly on backends with
           no memory stats (the CPU proxy needs an explicit budget).

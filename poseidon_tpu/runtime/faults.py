@@ -145,6 +145,7 @@ class FaultProxy:
         self._refusing = False
         self._stop = threading.Event()
         self._srv = socket.create_server((host, port))
+        self._srv.settimeout(0.1)   # before the accept thread exists
         self.port = self._srv.getsockname()[1]
         self.addr = (host, self.port)
         t = threading.Thread(target=self._accept_loop, daemon=True)
@@ -228,7 +229,6 @@ class FaultProxy:
 
     # ---- data plane ----------------------------------------------------- #
     def _accept_loop(self) -> None:
-        self._srv.settimeout(0.1)
         while not self._stop.is_set():
             try:
                 conn, _ = self._srv.accept()

@@ -6,9 +6,9 @@ runs natively, and layout converts back to canonical NCHW only at genuine
 boundaries (FC flatten, blob export). This module makes that claim
 compiler-verifiable without hardware — the analog of ``hlo_comm.py`` for
 the layout plan: parse the program text, count the layout transposes, and
-let ``bench.py`` / ``scripts/aot_tpu_check.py`` emit the number next to
-``nhwc_speedup`` (the round-3 shim lost 1.9x precisely because the
-per-op boundary transposes did NOT cancel; a count pins the regression).
+let ``scripts/aot_tpu_check.py`` and the layout tests pin the number (the
+round-3 shim lost 1.9x precisely because the per-op boundary transposes
+did NOT cancel; a count pins the regression).
 
 Two program levels are parsed by the same entry points:
 

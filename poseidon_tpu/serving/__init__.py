@@ -19,7 +19,7 @@ subsystem next to training:
   failover on replica death, and rolling hot-reload
 - :mod:`client`    — small blocking client (retry_with_backoff) + load
   generator (closed-loop and open-loop offered-load modes) shared by
-  tests, bench.py's serving mode, and `bench_serve`
+  tests and `bench_serve`
 
 PEP-562 lazy exports keep ``import poseidon_tpu.serving`` jax-free until an
 executor is actually built (client/server/batcher never import jax).

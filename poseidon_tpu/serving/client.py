@@ -231,7 +231,7 @@ class ServingClient:
 
 
 # --------------------------------------------------------------------------- #
-# load generator (bench.py serving mode, `bench_serve`, and the tests)
+# load generator (`bench_serve` and the tests)
 # --------------------------------------------------------------------------- #
 
 def run_load(addr: Tuple[str, int],

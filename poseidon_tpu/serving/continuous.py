@@ -48,35 +48,22 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from ..runtime.metrics import LatencyWindow, log
-from ..runtime.tuned_plan import BUILTIN_DEFAULTS as _POLICY_DEFAULTS
 from .batcher import DeadlineError, ShedError, ShuttingDownError
 from .kv_pool import PagedKVPool, PoolExhausted
 
-__all__ = ["ContinuousScheduler", "GenerateExecutor", "parse_rungs",
-           "DEFAULT_PAGE_SIZE", "DEFAULT_DECODE_RUNGS",
-           "DEFAULT_PROMPT_BUCKETS"]
+__all__ = ["ContinuousScheduler", "GenerateExecutor", "DEFAULT_PAGE_SIZE",
+           "DEFAULT_DECODE_RUNGS", "DEFAULT_PROMPT_BUCKETS"]
 
-DEFAULT_PAGE_SIZE = int(_POLICY_DEFAULTS["llm_page_size"])
-DEFAULT_DECODE_RUNGS = tuple(
-    int(t) for t in _POLICY_DEFAULTS["llm_decode_rungs"].split(","))
-DEFAULT_PROMPT_BUCKETS = tuple(
-    int(t) for t in _POLICY_DEFAULTS["llm_prompt_buckets"].split(","))
-
-
-def parse_rungs(spec: str) -> Tuple[int, ...]:
-    """'1,2,4,8' -> (1, 2, 4, 8), validated ascending positives."""
-    try:
-        rungs = tuple(sorted({int(t) for t in spec.split(",") if t}))
-    except ValueError as e:
-        raise ValueError(f"bad rung spec {spec!r}: {e}") from None
-    if not rungs or rungs[0] < 1:
-        raise ValueError(f"bad rung spec {spec!r}: need positive sizes")
-    return rungs
+# KV page size, decode-batch rungs and prompt-length prefill buckets of a
+# GenerateExecutor whose caller names none (`serve --generate`).
+DEFAULT_PAGE_SIZE = 64
+DEFAULT_DECODE_RUNGS = (1, 2, 4, 8)
+DEFAULT_PROMPT_BUCKETS = (16, 64, 256)
 
 
 def _align(n: int, m: int) -> int:

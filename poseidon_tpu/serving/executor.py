@@ -28,18 +28,15 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..runtime.tuned_plan import BUILTIN_DEFAULTS as _POLICY_DEFAULTS
-
-# The built-in bucket ladder is one row of the collapsed policy surface
-# (runtime/tuned_plan.BUILTIN_DEFAULTS["serve_buckets"]): a measured
-# TunedPlan replaces it at the CLI resolution layer (runtime/cli.py), an
-# explicit --buckets flag overrides both.
-DEFAULT_BUCKETS = tuple(
-    int(tok) for tok in _POLICY_DEFAULTS["serve_buckets"].split(","))
+# The batch bucket ladder an executor warms when its caller names none.
+DEFAULT_BUCKETS = (1, 4, 16, 64)
 
 
 def parse_buckets(spec: str) -> Tuple[int, ...]:
-    """'1,4,16,64' -> (1, 4, 16, 64), validated ascending positives."""
+    """'1,4,16,64' -> (1, 4, 16, 64), validated ascending positives; an
+    empty spec (an unset --buckets) is DEFAULT_BUCKETS."""
+    if not spec:
+        return DEFAULT_BUCKETS
     try:
         buckets = tuple(sorted({int(tok) for tok in spec.split(",") if tok}))
     except ValueError as e:

@@ -101,6 +101,9 @@ class InferenceServer:
         self._shutting_down = False
         self._lock = threading.Lock()
         self._srv = socket.create_server((host, port))
+        # set here, not on the accept thread: a server shut down before
+        # that thread first runs would have it touch a closed socket
+        self._srv.settimeout(0.25)
         self.host = host
         self.port = self._srv.getsockname()[1]
         self.addr = (host, self.port)
@@ -125,7 +128,6 @@ class InferenceServer:
 
     # ---- accept/handle --------------------------------------------------- #
     def _accept_loop(self) -> None:
-        self._srv.settimeout(0.25)
         while not self._stop.is_set():
             try:
                 conn, _ = self._srv.accept()

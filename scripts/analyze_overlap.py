@@ -4,8 +4,9 @@ The reference's signature result is that per-layer gradient sync threads
 overlap communication with the remaining backward pass
 (/root/reference/src/caffe/solver.cpp:419-449). Our rebuild emits the psums
 mid-backward via custom_vjp taps and relies on XLA's latency-hiding
-scheduler to overlap them. bench.py's DENSE vs DENSE_FUSED A/B measures the
-end-to-end win; THIS script proves the mechanism from the trace: for every
+scheduler to overlap them. THIS script proves the mechanism from a trace
+(`alexnet.dp4.resident`'s `collective_exposed_ms_per_step` is the
+end-to-end reading): for every
 collective op on the device timeline, how much of its duration co-runs with
 at least one compute op.
 

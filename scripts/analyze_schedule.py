@@ -24,7 +24,7 @@ instruction order:
 
 Runs on the virtual 8-device CPU mesh (same SPMD partitioner and scheduler
 front-end XLA uses on TPU; the TPU backend additionally runs the
-latency-hiding scheduler, exercised by bench.py's LIBTPU escalation).
+latency-hiding scheduler).
 
 Prints ONE JSON line: {"metric": "dwbp_schedule", ...}.
 """

@@ -1062,54 +1062,8 @@ def section_kernels(topo) -> dict:
             "ok": n_fail == 0}
 
 
-# ------------------------------------------------------------------------- #
-# 11. TunedPlan autotuner registration (PR 14): the full-space re-tune
-# ------------------------------------------------------------------------- #
-
-def section_tune(topo) -> dict:
-    """Register the AlexNet/GoogLeNet FULL-space tune for a run on the
-    chip. The autotuner (runtime/tuned_plan.py) needs
-    MEASURED trials — real executions, which this AOT-only harness cannot
-    run against an abstract topology — so this section records the exact
-    search spaces and the commands that produce the evidence, and
-    structurally verifies the search-space builder + plan keying for the
-    8-chip topology (a drifted knob list would silently shrink the TPU
-    re-tune; this pins it)."""
-    from poseidon_tpu.runtime.tuned_plan import (BUILTIN_DEFAULTS, plan_key,
-                                                 search_space)
-
-    n = len(topo.devices)
-    spaces = {}
-    for model in ("alexnet", "googlenet"):
-        space = search_space(smoke=False, n_devices=n)
-        spaces[model] = {
-            "search_space": {k: [str(c) for c in v]
-                             for k, v in space.items()},
-            "plan_key_tpu": plan_key(model, "tpu", n),
-            "command": (f"python bench.py tune --model {model} --full "
-                        f"--force"),
-        }
-    # every collapsed knob must have a default AND appear in the space
-    # (pipeline covers device_prefetch+max_in_flight as one trial;
-    # remat_batch covers the remat/batch_size/hbm_budget_gb triple)
-    space_knobs = set(spaces["alexnet"]["search_space"])
-    covered = (space_knobs - {"pipeline", "remat_batch"}) | {
-        "device_prefetch", "max_in_flight",
-        "remat", "batch_size", "hbm_budget_gb"}
-    missing = sorted(set(BUILTIN_DEFAULTS) - covered)
-    ok = not missing and all(
-        len(s["search_space"]["mesh"]) >= 3 for s in spaces.values())
-    return {"ok": ok, "n_devices": n, "models": spaces,
-            "uncovered_knobs": missing,
-            "note": ("measured trials need a live TPU; run the recorded "
-                     "commands on the chip — plans persist "
-                     "via compile_cache keying and bench.py writes "
-                     "evidence/tuned_plans/<model>_tpu.json")}
-
-
 SECTIONS = {
     "pallas_mosaic": section_pallas_mosaic,
-    "tune": section_tune,
     "kernels": section_kernels,
     "dwbp": section_dwbp,
     "lm_modes": section_lm_modes,

@@ -15,8 +15,9 @@ import textwrap
 
 import pytest
 
-from poseidon_tpu.analysis import (Finding, filter_new, load_baseline,
-                                   pragma_suppressed, run_lints)
+from poseidon_tpu.analysis import (Finding, filter_new, iter_python_files,
+                                   load_baseline, pragma_suppressed,
+                                   run_lints)
 from poseidon_tpu.analysis import contracts as C
 from poseidon_tpu.analysis import jit_hygiene, threads
 
@@ -1129,3 +1130,91 @@ def test_thread_excepthook_records():
     # the OBJECT is recorded (idents get recycled across thread lifetimes)
     assert thread is t
     assert "intentional sanitizer probe" in msg
+
+
+# --------------------------------------------------------------------------- #
+# layering: the packages below runtime/ do not import from it
+# --------------------------------------------------------------------------- #
+
+# what the lower packages may take from poseidon_tpu.runtime: the logger
+# and the span recorder (both jax-free leaves of runtime/), and the one
+# named debt, core/remat.py's use of the attribution table (ROADMAP D13)
+_RUNTIME_ALLOWED = {
+    "poseidon_tpu.runtime.metrics.log",
+    "poseidon_tpu.runtime.spans.recorder",
+}
+_RUNTIME_DEBTS = {
+    ("poseidon_tpu/core/remat.py",
+     "poseidon_tpu.runtime.attribution.layer_cost_table"),
+}
+LOWER_PACKAGES = ["numeric.py", "config.py", "ops", "solvers", "proto",
+                  "core", "data"]
+
+
+def _runtime_imports(path: str, source: str = None):
+    """(line, dotted name) of everything ``path`` imports from
+    poseidon_tpu.runtime, relative imports resolved, function-level
+    imports included (an ``ast.walk`` sees every statement)."""
+    import ast
+    pkg = os.path.relpath(path, REPO)[:-3].split(os.sep)[:-1]
+    if source is None:
+        with open(path) as f:
+            source = f.read()
+    found = []
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = pkg[:len(pkg) - (node.level - 1)] if node.level else []
+            mod = ".".join(base + ([node.module] if node.module else []))
+            names = [f"{mod}.{a.name}" for a in node.names]
+        else:
+            continue
+        found += [(node.lineno, n) for n in names
+                  if n == "poseidon_tpu.runtime"
+                  or n.startswith("poseidon_tpu.runtime.")]
+    return found
+
+
+@pytest.mark.parametrize("package", LOWER_PACKAGES)
+def test_lower_packages_do_not_import_runtime(package):
+    """numeric, config, ops, solvers, proto, core and data sit below
+    runtime/: a knob resolved in runtime/ and read back from down here is
+    how the autotuner put a second source under every decision."""
+    files = iter_python_files([os.path.join(REPO, "poseidon_tpu", package)])
+    assert files, package
+    bad = []
+    for path in files:
+        rel = os.path.relpath(path, REPO)
+        bad += [f"{rel}:{line} imports {name}"
+                for line, name in _runtime_imports(path)
+                if name not in _RUNTIME_ALLOWED
+                and (rel, name) not in _RUNTIME_DEBTS]
+    assert not bad, "\n".join(bad)
+
+
+def test_layering_walk_sees_function_level_and_relative_imports():
+    """The rule must fire on what it exists to stop: numeric.py reaching
+    for the engine from inside a function, in every spelling."""
+    path = os.path.join(REPO, "poseidon_tpu", "numeric.py")
+    src = textwrap.dedent("""
+        from .runtime.metrics import log
+        import poseidon_tpu.runtime.engine
+        def resolve():
+            from .runtime.engine import Engine
+            from .runtime import spans, compile_cache as cc
+            from . import runtime
+            from .ops import nn
+    """)
+    assert [n for _, n in _runtime_imports(path, src)] == [
+        "poseidon_tpu.runtime.metrics.log",
+        "poseidon_tpu.runtime.engine",
+        "poseidon_tpu.runtime.engine.Engine",
+        "poseidon_tpu.runtime.spans",
+        "poseidon_tpu.runtime.compile_cache",
+        "poseidon_tpu.runtime",
+    ]
+    deep = os.path.join(REPO, "poseidon_tpu", "ops", "nn.py")
+    assert [n for _, n in _runtime_imports(
+        deep, "def f():\n    from ..runtime.tools import x\n")] == [
+        "poseidon_tpu.runtime.tools.x"]

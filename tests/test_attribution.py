@@ -11,8 +11,7 @@ timeline, and the live metrics endpoint.
   --trace_out timeline carries dispatch/hard-sync/snapshot/prefetch
   spans, and a real 2-worker async exchange records push/pull/gate/admit;
 - enabling spans costs <2% of a CPU LeNet step, and trace capture stays
-  AFTER the timed loop (the bench.py:718 discipline, now in
-  runtime/attribution.measure_then_trace);
+  AFTER the timed loop (runtime/attribution.measure_then_trace);
 - --metrics_port serves the live registry mid-train; stats.yaml lands
   atomically at every display boundary.
 """
@@ -424,8 +423,7 @@ def test_span_overhead_under_two_percent_of_lenet_step():
 
 def test_trace_capture_stays_after_timing(tmp_path, monkeypatch):
     """measure_then_trace runs EVERY timed step before the profiler ever
-    starts — attribution can never contaminate the timed loop (the
-    bench.py discipline the satellite pins)."""
+    starts — attribution can never contaminate the timed loop."""
     import jax
 
     order = []
@@ -689,26 +687,3 @@ layers { name: "ip" type: INNER_PRODUCT bottom: "data" top: "ip"
         assert snap["reloader"] is None      # none attached -> explicit
     finally:
         srv.shutdown()
-
-
-# --------------------------------------------------------------------------- #
-# bench satellites: trace_meta stamping
-# --------------------------------------------------------------------------- #
-
-def test_bench_trace_meta_is_self_describing(tmp_path):
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-
-    batch = {"data": np.zeros((4, 3, 8, 8), np.float32),
-             "label": np.zeros((4,), np.int32)}
-    meta = bench._trace_meta("alexnet", 64, batch, "cpu", "cpu")
-    assert meta["model"] == "alexnet"
-    assert meta["scan_steps"] == 64
-    assert meta["batch_shape"]["data"] == [4, 3, 8, 8]
-    assert meta["backend"] == "cpu"
-    assert "captured_at" in meta
-    bench._write_trace_meta(str(tmp_path), meta)
-    with open(tmp_path / "trace_meta.json") as f:
-        assert json.load(f) == meta

@@ -678,14 +678,13 @@ def test_joiner_tier_is_admitted_without_operator_action(monkeypatch):
 def test_cache_dir_from_env_is_never_overridden_in_code(jax_cache_env,
                                                         monkeypatch):
     """JAX_COMPILATION_CACHE_DIR set: that directory IS the cache — the
-    program never points jax at another one, its aot/ and tuned/ stores
-    live under it, and compiles land in it."""
+    program never points jax at another one, its aot/ store lives under
+    it, and compiles land in it."""
     import jax
     import jax.numpy as jnp
 
     from poseidon_tpu import config
     from poseidon_tpu.runtime import compile_cache as cc
-    from poseidon_tpu.runtime.tuned_plan import store_dir
 
     updates = []
     real_update = jax.config.update
@@ -696,8 +695,8 @@ def test_cache_dir_from_env_is_never_overridden_in_code(jax_cache_env,
     assert cache == jax_cache_env == cc.resolve_cache_dir()
     assert "jax_compilation_cache_dir" not in updates
     assert config.compile_cache_config().cache_dir == cache
-    assert store_dir() == cache
-    assert cc.tuned_path(store_dir(), "ns", "k").startswith(cache + os.sep)
+    assert cc._aot_path(cache, "k").startswith(
+        os.path.join(cache, "aot") + os.sep)
     before = cc.cache_entries(cache)
     x = jnp.ones((16, 16))
     jax.block_until_ready(jax.jit(lambda a: jnp.tanh(a) @ a.T)(x))
@@ -709,12 +708,10 @@ def test_cache_dir_defaults_to_checkout(monkeypatch):
     """Unset: a fixed <checkout>/.jax_cache resolved from __file__ — never
     tempfile, a pid, the clock or ~/.cache — for every store."""
     from poseidon_tpu.runtime import compile_cache as cc
-    from poseidon_tpu.runtime.tuned_plan import store_dir
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert cc.resolve_cache_dir() == os.path.join(repo, ".jax_cache")
-    assert store_dir() == cc.resolve_cache_dir()
     assert cc.resolve_cache_dir() == cc.resolve_cache_dir()
 
 

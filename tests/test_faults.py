@@ -979,11 +979,16 @@ def test_sever_group_is_atomic_and_ignores_unknown_ids():
                       what="tagged pairs for worker 0")
             assert proxy.sever_group(set()) == 0
             assert proxy.sever_group({7, 8, 9}) == 0
+            # the client reconnects 10 ms after a cut: refuse new
+            # connections while the survivors are counted, or its new
+            # (still untagged) pair lands in the list first on a busy host
+            proxy.refuse_new()
             with proxy._lock:
                 n_before = len(proxy._pairs)
             assert proxy.sever_group({0}) == 2
             with proxy._lock:
                 assert len(proxy._pairs) == n_before - 2
+            proxy.refuse_new(False)
         finally:
             cli.close()
     finally:

@@ -457,90 +457,39 @@ def test_conv_strategy_inapplicable_falls_back(rng_np):
 
 
 def test_conv2d_rejects_unresolved_auto(rng_np):
+    """"auto" named the per-layer micro-tuner's request; conv2d never
+    lowered it and still refuses it, now as any other unknown name."""
     x = jnp.zeros((1, 3, 8, 8), jnp.float32)
     w = jnp.zeros((4, 3, 3, 3), jnp.float32)
-    with pytest.raises(ValueError, match="auto"):
+    with pytest.raises(ValueError, match="unknown strategy 'auto'"):
         NN.conv2d(x, w, None, (1, 1), (0, 0), strategy="auto")
 
 
-def test_conv_tune_measures_then_persists(tmp_path):
-    """First resolve measures and writes the tuned store; a fresh memo
-    loads the persisted winner without re-measuring."""
-    from poseidon_tpu.ops import conv_tune
-    conv_tune.clear_memo()
-    kw = dict(c=3, h=9, w=9, kernel=(3, 3), stride=(2, 2), pad=(0, 0),
-              group=1, out_ch=4, layout="NCHW", batch=4,
-              cache_dir=str(tmp_path))
-    doc = conv_tune.resolve("convX", **kw)
-    assert doc["source"] == "measured"
-    assert doc["winner"] in doc["timings_ms"]
-    assert set(doc["timings_ms"]) == {"direct", "im2col", "s2d"}
-    assert doc["winner"] == min(doc["timings_ms"],
-                                key=doc["timings_ms"].get)
-    # memo hit within the process
-    assert conv_tune.resolve("convX", **kw)["source"] == "memo"
-    # fresh process simulation: memo cleared, store answers
-    conv_tune.clear_memo()
-    doc3 = conv_tune.resolve("convX", **kw)
-    assert doc3["source"] == "persisted"
-    assert doc3["winner"] == doc["winner"]
-    conv_tune.clear_memo()
-
-
-def test_conv_tune_single_candidate_skips_measurement(tmp_path):
-    from poseidon_tpu.ops import conv_tune
-    conv_tune.clear_memo()
-    doc = conv_tune.resolve("grouped", c=4, h=8, w=8, kernel=(3, 3),
-                            stride=(1, 1), pad=(1, 1), group=2, out_ch=8,
-                            layout="NCHW", batch=4,
-                            cache_dir=str(tmp_path))
-    assert doc == dict(doc, winner="direct", source="only-candidate")
-    assert doc["timings_ms"] == {}
-    conv_tune.clear_memo()
-
-
-def test_net_conv_strategy_plumbing(tmp_path):
-    """Net-level resolution: a forced strategy lands on every conv layer;
-    "auto" assigns each layer a measured winner and a re-built Net (fresh
-    memo) loads the persisted choices."""
+def test_net_conv_strategy_plumbing():
+    """Net-level resolution: a forced strategy lands on every conv layer
+    and the net traces and runs under it; no request leaves the layers to
+    the global conv_s2d policy; an unknown name ("auto" is one since the
+    micro-tuner went) is refused at construction."""
     from poseidon_tpu.core.net import Net
     from poseidon_tpu.models import zoo
-    from poseidon_tpu.ops import conv_tune
     shapes = zoo.lenet_shapes(4)
     net = Net(zoo.lenet(with_accuracy=False), "TRAIN", shapes,
               conv_strategy="im2col")
-    assert set(net.conv_strategy_plan().values()) == {"im2col"}
-    # legacy default: layers carry None (the global conv_s2d policy rules)
+    assert net.conv_strategy_plan() == {"conv1": "im2col",
+                                        "conv2": "im2col"}
+    params = net.init(jax.random.PRNGKey(0))
+    out = net.apply(params, {
+        "data": jnp.zeros(shapes["data"], jnp.float32),
+        "label": jnp.zeros(shapes["label"], jnp.int32)},
+        rng=jax.random.PRNGKey(1))
+    assert np.isfinite(float(out.loss))
+    # default: layers carry None (the global conv_s2d policy rules)
     net0 = Net(zoo.lenet(with_accuracy=False), "TRAIN", shapes)
     assert set(net0.conv_strategy_plan().values()) == {None}
-    with pytest.raises(ValueError, match="conv_strategy"):
-        Net(zoo.lenet(with_accuracy=False), "TRAIN", shapes,
-            conv_strategy="winograd")
-
-    conv_tune.clear_memo()
-    saved = config.compile_cache_config().cache_dir
-    config.set_compile_cache_config(cache_dir=str(tmp_path))
-    try:
-        net1 = Net(zoo.lenet(with_accuracy=False), "TRAIN", shapes,
-                   conv_strategy="auto")
-        plan = net1.conv_strategy_plan()
-        assert set(plan) == {"conv1", "conv2"}
-        assert all(v in ("direct", "im2col", "s2d")
-                   for v in plan.values())
-        conv_tune.clear_memo()
-        net2 = Net(zoo.lenet(with_accuracy=False), "TRAIN", shapes,
-                   conv_strategy="auto")
-        assert net2.conv_strategy_plan() == plan
-        # the resolved plan actually traces and runs
-        params = net1.init(jax.random.PRNGKey(0))
-        out = net1.apply(params, {
-            "data": jnp.zeros(shapes["data"], jnp.float32),
-            "label": jnp.zeros(shapes["label"], jnp.int32)},
-            rng=jax.random.PRNGKey(1))
-        assert np.isfinite(float(out.loss))
-    finally:
-        config.set_compile_cache_config(cache_dir=saved)
-        conv_tune.clear_memo()
+    for unknown in ("winograd", "auto"):
+        with pytest.raises(ValueError, match="conv_strategy"):
+            Net(zoo.lenet(with_accuracy=False), "TRAIN", shapes,
+                conv_strategy=unknown)
 
 
 # --------------------------------------------------------------------------- #

@@ -28,8 +28,7 @@ def _free_port():
 def test_train_touches_no_backend_before_init_distributed():
     """jax.distributed.initialize refuses to run once any backend exists,
     so `train` must call init_distributed before ANYTHING that touches
-    one (the TunedPlan resolution reads jax.default_backend() for its
-    key). A fresh process — backends already exist in this one."""
+    one. A fresh process — backends already exist in this one."""
     import subprocess
     code = f"""
 import sys

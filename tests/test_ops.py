@@ -272,8 +272,8 @@ def test_conv_space_to_depth_exact(rng_np, c, k, s, p, h):
 
 
 def test_s2d_real_stems_parity_and_perf_config_default(rng_np):
-    """The bf16 perf config (numeric.set_perf_policy — what bench.py and
-    ``train --bf16`` run) flips conv_s2d ON; this pins the rewrite at the
+    """The bf16 perf config (numeric.set_perf_policy — what
+    ``train --bf16`` runs) flips conv_s2d ON; this pins the rewrite at the
     REAL stem configurations. f32 parity is checked at float-sum-rebracket
     tolerance against the direct conv1 formulation for both stems:
     AlexNet conv1 (96x3x11x11 / s4 / p0 @ 227) and GoogLeNet conv1

@@ -320,21 +320,32 @@ def test_measured_peak_api_and_remat_arm_stay_bounded():
     maximal-remat arm's peak stays within 10% of the no-remat arm's on
     toy LeNet. Direction is deliberately NOT asserted here: on the CPU
     proxy the buffer arena is conv-scratch-dominated and a toy model's
-    checkpoint can land a few KiB either side — the reduction-magnitude
-    claim is bench.py memory's evidence on the conv models, not a unit
-    property. What this DOES catch is a remat wiring bug that doubles
-    buffers or breaks the measurement API."""
-    from poseidon_tpu.runtime.tuned_plan import _build_step_arm
+    checkpoint can land a few KiB either side. What this DOES catch is a
+    remat wiring bug that doubles buffers or breaks the measurement
+    API."""
+    import jax.numpy as jnp
 
-    shapes = {"data": (2, 1, 28, 28), "label": (2,)}
-    np_ = zoo.lenet(with_accuracy=False)
-    base = _build_step_arm(np_, shapes, "", 4.0, 1, "", remat="",
-                           measure_peak=True)
-    full = _build_step_arm(np_, shapes, "", 4.0, 1, "", remat="auto",
-                           measure_peak=True)
-    assert base.peak_bytes > 0, "memory_analysis() returned no peak"
-    assert full.peak_bytes > 0
-    assert abs(full.peak_bytes - base.peak_bytes) / base.peak_bytes < 0.10
+    from poseidon_tpu.runtime.attribution import layer_cost_table
+
+    net, batch = _lenet_setup()
+    comm = CommConfig(param_arena=True)
+    full_plan = remat_mod.plan_remat(
+        layer_cost_table(net), 0, 0,
+        candidates=remat_mod.remat_candidates(net), source="plan")
+    assert full_plan.active
+    p = net.init(jax.random.PRNGKey(0))
+    s = init_train_state(p, comm, N_DEV)
+    args = (p, s, {k: jnp.asarray(v) for k, v in batch.items()},
+            jax.random.PRNGKey(7))
+    peaks = []
+    for rp in (None, full_plan):
+        ts = build_train_step(net, SP, make_mesh(), comm, remat_plan=rp)
+        peaks.append(remat_mod.measured_peak_bytes(
+            ts.lowerable.lower(*args).compile()))
+    base, full = peaks
+    assert base > 0, "memory_analysis() returned no peak"
+    assert full > 0
+    assert abs(full - base) / base < 0.10
 
 
 def test_plan_for_net_step_measured_source():
@@ -352,30 +363,3 @@ def test_plan_for_net_step_measured_source():
     assert tight.active          # 1-byte budget cannot fit: must remat
     roomy = remat_mod.plan_for_net_step(net, ts.lowerable, args, 10**12)
     assert not roomy.active      # fits: identity plan
-
-
-# --------------------------------------------------------------------------- #
-# tuner integration: the (remat, batch) pair persists and memo-hits
-# --------------------------------------------------------------------------- #
-
-def test_tune_remat_batch_stage_persists_and_memo_hits(tmp_path):
-    from poseidon_tpu.runtime.tuned_plan import run_tune
-
-    first = run_tune("lenet", smoke=True, cache_dir=str(tmp_path),
-                     knobs=["remat_batch"], windows=2, iters=2)
-    assert first["source"] == "measured"
-    knobs = first["doc"]["knobs"]
-    trial = first["doc"]["trials"]["remat_batch"]
-    assert "remat" in knobs and "batch_size" in knobs \
-        and "hbm_budget_gb" in knobs
-    # the cap is recorded, never silent
-    assert trial["max_doublings"] >= 1
-    assert "winner" in trial
-    if knobs["remat"] == "":
-        # a default win must not ship a budget that would make every
-        # later train run re-pay the measuring compile
-        assert knobs["hbm_budget_gb"] == 0.0
-    second = run_tune("lenet", smoke=True, cache_dir=str(tmp_path),
-                      knobs=["remat_batch"], windows=2, iters=2)
-    assert second["source"] == "persisted"
-    assert second["doc"]["knobs"] == knobs

