@@ -374,7 +374,15 @@ class Net:
                 arm, note = lrn_route(shape[2] * shape[3], shape[1])
             elif layer.TYPE == "ATTENTION":
                 what = "attention"
-                arm, note = attention_route(shape[1])
+                arm, note = attention_route(
+                    shape[1], shape[1],
+                    shape[2] // layer.lp.attention_param.num_heads,
+                    jnp.dtype(policy().compute_dtype).itemsize)
+                if arm == "pallas_flash":
+                    # the tiles each flash kernel runs with and the live /
+                    # visited programs of its grid: stats.yaml carries them
+                    arm = f"{arm} ({note})"
+                    note = ""
             elif layer.TYPE == "MOE":
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"

@@ -2,9 +2,10 @@
 
 ``flash_attention``: blockwise attention entirely in VMEM — never
 materializes the (S, S) score matrix in HBM. Grid is (batch*heads,
-query-blocks); each program streams key/value blocks through the
-online-softmax recurrence (the same math as ops/attention.py's BlockAcc, here
-per 128-row tile). The backward pass is likewise Pallas and O(S) in HBM: the
+query-blocks, key-blocks); the online-softmax recurrence (the same math as
+ops/attention.py's BlockAcc) runs per (block_q, block_k) tile, sized by
+``flash_blocks`` from the sequence length, the head width and the operand
+itemsize. The backward pass is likewise Pallas and O(S) in HBM: the
 dq and dk/dv kernels below recompute scores blockwise from the saved
 (out, logsumexp) residuals, wired up via ``defvjp``.
 
@@ -75,28 +76,159 @@ def _cdiv(a: int, b: int) -> int:
 # Flash attention
 # --------------------------------------------------------------------------- #
 
-def _causal_mask(s, qi, kj, block_q, block_k, mode=None):
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T: both contract their minor dim
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _dot(a, b, dims):
+    """One MXU product of two tiles, accumulated in f32."""
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32,
+                           precision=matmul_precision())
+
+
+def _operand_dtype(dtype):
+    """What a tile is handed to the MXU as. Under the bf16 policy (DEFAULT
+    precision) the dtype it arrives in: a bf16 x bf16 product accumulated in
+    f32 is exact, an up-cast buys nothing. Under the f32 policy (HIGHEST)
+    f32, as ever."""
+    if matmul_precision() == lax.Precision.HIGHEST:
+        return jnp.float32
+    return dtype
+
+
+_BLOCK_LADDER = (1024, 512, 256, 128, 64, 32, 16, 8)
+# What Mosaic may use of VMEM for one flash program (128 MiB on the v5e, of
+# which a kernel gets 16 unless it asks), and the three quarters of that
+# the tile rule fills with what it can count; the rest is the compiler's
+# own (the iota, compare and cast temporaries of the masked path).
+_FLASH_VMEM_LIMIT = 32 * 2 ** 20
+_FLASH_VMEM_BUDGET = 24 * 2 ** 20
+# f32 score-shaped (block_q, block_k) temporaries a program's body names:
+# s and p forward; s, p, dp and ds in either backward sweep. An upper
+# bound: Mosaic reuses their buffers (1024 x 1024 compiles for the v5e
+# inside 16 MiB in all three kernels, not inside 8).
+_SCORE_TEMPS = {"fwd": 2, "dq": 4, "dkv": 4}
+
+
+def pick_block(s: int) -> Optional[int]:
+    """Largest clean tile height for a sequence length, MXU/VPU-aligned:
+    the one-dimensional half of the tile rule (``flash_blocks`` pairs two
+    such heights under a VMEM budget), and None where the kernels do not
+    tile at all.
+
+    Mosaic only needs the block's second-minor dim to be a multiple of the
+    8-row f32 sublane tile, so non-power-of-two sequence lengths that no
+    block of 32 rows or more divides (s=48, s=136, ...) still tile with a
+    smaller aligned block — falling back to None there routed perfectly
+    kernelable shapes onto the dense O(S^2) op."""
+    return next((bs for bs in _BLOCK_LADDER if s % bs == 0), None)
+
+
+def _flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
+                      itemsize: int) -> int:
+    """Live VMEM of one program: the score-shaped f32 temporaries, the
+    operand and result tiles (double-buffered by the pipeline) and the f32
+    accumulators."""
+    scores = _SCORE_TEMPS[kernel] * block_q * block_k * 4
+    q_tiles, k_tiles, acc_rows = {
+        "fwd": (2, 2, block_q),           # q, o | k, v | acc
+        "dq": (3, 2, block_q),            # q, dO, dq | k, v | dq_acc
+        "dkv": (2, 4, 2 * block_k),       # q, dO | k, v, dk, dv | two accs
+    }[kernel]
+    tiles = 2 * itemsize * d * (q_tiles * block_q + k_tiles * block_k)
+    return scores + tiles + acc_rows * d * 4
+
+
+def flash_blocks(kernel: str, s: int, d: int, itemsize: int):
+    """``(block_q, block_k)`` for one of the three flash kernels (``"fwd"``,
+    ``"dq"``, ``"dkv"``) — THE tile rule, a function of the sequence
+    length, the head width and the operand itemsize alone: the largest
+    aligned blocks dividing S whose live tiles fit the VMEM budget. A grid
+    step costs about 0.35 us on the v5e whatever it computes, ten times
+    the arithmetic of a 128 x 128 x 128 block, so a program is given as
+    much work as VMEM holds (1024 x 1024 at S=4096, D=128); of two
+    choices with equal work per program the wider K/V block wins (fewer
+    rescalings of the running softmax state per score). Lengths no large
+    block divides (S = 48, 136, ...) tile as they always did. None: no
+    aligned block divides S."""
+    best = None
+    for bq in _BLOCK_LADDER:
+        for bk in _BLOCK_LADDER:
+            if s % bq or s % bk or _flash_vmem_bytes(
+                    kernel, bq, bk, d, itemsize) > _FLASH_VMEM_BUDGET:
+                continue
+            if best is None or (bq * bk, bk) > (best[0] * best[1], best[1]):
+                best = (bq, bk)
+    return best
+
+
+def _causal_mask(s, qi, kj, block_q, block_k, mode=None, transposed=False):
     """Self-attention: mask by absolute tile position. Chunked (ring) mode:
     ``mode`` is a traced scalar describing how the K/V chunk aligns with the
     Q rows' chunk — +1 chunk strictly past (all live), 0 diagonal (in-chunk
-    triangle), -1 future (all masked)."""
+    triangle), -1 future (all masked). ``transposed``: ``s`` is the
+    (block_k, block_q) tile of the dK/dV sweep, keys down the rows."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
     rows = qi * block_q + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, shape, 1 if transposed else 0)
     cols = kj * block_k + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, shape, 0 if transposed else 1)
     if mode is None:
         return jnp.where(rows >= cols, s, NEG_INF)
     live = (mode > 0) | ((mode == 0) & (rows >= cols))
     return jnp.where(live, s, NEG_INF)
 
 
+def _on_live_blocks(update, causal: bool, chunk_mode: bool, qi, kj,
+                    block_q: int, block_k: int) -> None:
+    """Run ``update(masked)`` where block (qi, kj) has anything to add.
+    Causal self-attention knows that from the grid position: a block wholly
+    below the diagonal needs no mask, one the diagonal crosses is masked
+    element by element, one wholly above it is skipped (its operands are
+    not fetched either: ``_last_live_k`` / ``_first_live_q``). Ring
+    attention's chunk alignment is a traced scalar: every block takes the
+    masked path."""
+    if not causal:
+        update(False)
+    elif chunk_mode:
+        update(True)
+    else:
+        first_row, last_row = qi * block_q, qi * block_q + block_q - 1
+        first_col, last_col = kj * block_k, kj * block_k + block_k - 1
+        pl.when(last_col <= first_row)(lambda: update(False))
+        pl.when((first_col <= last_row) & (last_col > first_row))(
+            lambda: update(True))
+
+
+def _last_live_k(qi, block_q: int, block_k: int):
+    """Causal self-attention: the last K/V block a Q block attends to."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _first_live_q(kj, block_q: int, block_k: int):
+    """Causal self-attention: the first Q block that attends to a K/V block."""
+    return (kj * block_k) // block_q
+
+
+def flash_grid_programs(s: int, block_q: int, block_k: int, causal: bool):
+    """(live, visited) programs per head of one flash grid. A visited block
+    that is not live runs no body and names the operand tile already
+    resident, so it costs one empty grid step."""
+    n_qb, n_kb = s // block_q, s // block_k
+    if not causal:
+        return n_qb * n_kb, n_qb * n_kb
+    live = sum(min(n_kb, _last_live_k(qi, block_q, block_k) + 1)
+               for qi in range(n_qb))
+    return live, n_qb * n_kb
+
+
 def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
-                      block_k: int, n_kb: int, chunk_mode: bool):
+                      block_k: int, n_kb: int, chunk_mode: bool, op_dtype):
     """Grid (bh, q_blocks, k_blocks); only one (block_q, d) Q tile and one
     (block_k, d) K/V tile are VMEM-resident at a time. The online-softmax
-    state persists in scratch across the innermost (k-block) grid dimension.
-    Also emits the per-row logsumexp, which the O(S)-memory backward kernels
-    consume (flash attention paper's L = m + log l).
+    state persists in f32 scratch across the innermost (k-block) grid
+    dimension. Also emits the per-row logsumexp, which the O(S)-memory
+    backward kernels consume (flash attention paper's L = m + log l).
 
     ``chunk_mode`` (ring attention): a leading SMEM scalar describes the
     chunk alignment for causal masking (see _causal_mask)."""
@@ -112,75 +244,91 @@ def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
 
     @pl.when(kj == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    # causal self-attention: blocks entirely above the diagonal contribute
-    # nothing (static skip); chunked liveness is dynamic, handled by the mask
-    block_live = True if (not causal or chunk_mode) else \
-        (kj * block_k <= qi * block_q + block_q - 1)
-
-    @pl.when(block_live)
-    def _update():
-        q = q_ref[0].astype(jnp.float32)         # (block_q, d)
-        k_blk = k_ref[0].astype(jnp.float32)     # (block_k, d)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32,
-            precision=matmul_precision()) * scale
-        if causal:
+    def update(masked: bool):
+        q = q_ref[0].astype(op_dtype)             # (block_q, d)
+        k_blk = k_ref[0].astype(op_dtype)         # (block_k, d)
+        v_blk = v_ref[0].astype(op_dtype)
+        s = _dot(q, k_blk, _NT) * scale           # f32 from here on
+        if masked:
             s = _causal_mask(s, qi, kj, block_q, block_k, mode)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_ref[...]                       # (block_q, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32,
-            precision=matmul_precision())
-        m_ref[:, 0] = m_new
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(
+            p.astype(op_dtype), v_blk, _NN)
+        m_ref[...] = m_new
+
+    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k)
 
     @pl.when(kj == n_kb - 1)
     def _finalize():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         lsafe = jnp.where(l == 0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / lsafe[:, None]).astype(o_ref.dtype)
-        # lse layout is (bh, s, 1): a (block_q, 1) tile keeps the minor dim
-        # equal to the full array dim, which Mosaic's tiling rules require
-        # for block_q < 128 (the (1, 1, block_q) layout only lowered with
-        # full-length 128 tiles)
-        lse_ref[0] = (m_ref[:, 0] + jnp.log(lsafe))[:, None]
+        o_ref[0] = (acc_ref[...] / lsafe).astype(o_ref.dtype)
+        # this kernel and the dQ sweep want the row statistics as a column
+        # beside their (block_q, block_k) scores: (bh, s, 1), whose
+        # (block_q, 1) tile is legal at any block height. The dK/dV sweep
+        # reads them as rows (_flash_bwd).
+        lse_ref[0] = m_ref[...] + jnp.log(lsafe)
 
 
-def _check_blocks(s, block_q, block_k):
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
+def _blocks_for(kernel: str, q, block_q, block_k):
+    """The caller's blocks, or the tile rule's for this kernel."""
+    s, d = q.shape[-2:]
+    if block_q is None or block_k is None:
+        blocks = flash_blocks(kernel, s, d, q.dtype.itemsize)
+        if blocks is None:
+            raise ValueError(f"no aligned block divides seq len {s}")
+        return blocks
+    block_q, block_k = min(block_q, s), min(block_k, s)
     if s % block_q or s % block_k:
         raise ValueError(f"seq len {s} must divide by blocks "
                          f"({block_q}, {block_k})")
     return block_q, block_k
 
 
-def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: int,
-               block_k: int, interpret: bool, mode=None):
+def _kv_block_map(clamp: bool, block_q: int, block_k: int):
+    """Index map of a K/V tile on the (bh, q_blocks, k_blocks) grid.
+    ``clamp`` (causal self-attention): a block above the diagonal names the
+    last live K/V tile again, which is resident, so no copy is issued."""
+    if clamp:
+        return lambda i, j, kk: (
+            i, jnp.minimum(kk, _last_live_k(j, block_q, block_k)), 0)
+    return lambda i, j, kk: (i, kk, 0)
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_FLASH_VMEM_LIMIT)
+
+
+def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
+               block_k: Optional[int], interpret: bool, mode=None):
     """mode (traced int32 scalar) selects chunked causal masking for ring
-    attention; None = plain self-attention."""
+    attention; None = plain self-attention. Blocks of None: the tile rule's
+    (``flash_blocks``)."""
     b, h, s, d = q.shape
     bh = b * h
     q3 = q.reshape(bh, s, d)
     k3 = k.reshape(bh, s, d)
     v3 = v.reshape(bh, s, d)
-    block_q, block_k = _check_blocks(s, block_q, block_k)
+    block_q, block_k = _blocks_for("fwd", q, block_q, block_k)
     n_kb = s // block_k
     grid = (bh, s // block_q, n_kb)
     chunk = mode is not None
+    kmap = _kv_block_map(causal and not chunk, block_q, block_k)
+    qmap = lambda i, j, kk: (i, j, 0)
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_q, d), qmap, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), kmap, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), kmap, memory_space=pltpu.VMEM),
     ]
     args = [q3, k3, v3]
     if chunk:
@@ -189,33 +337,26 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: int,
     out, lse = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_kb=n_kb,
-                          chunk_mode=chunk),
+                          chunk_mode=chunk,
+                          op_dtype=_operand_dtype(q.dtype)),
         name="flash_fwd",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)),
         grid=grid,
         in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, d), qmap, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, 1), qmap, memory_space=pltpu.VMEM),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(*args)
     return out.reshape(b, h, s, d), lse.reshape(b, h, s)
-
-
-def _row_ref(ref):
-    """(block_q,) row statistics from a (1, block_q, 1) lse/delta tile."""
-    return ref[0, :, 0]
 
 
 # --------------------------------------------------------------------------- #
@@ -223,10 +364,11 @@ def _row_ref(ref):
 # --------------------------------------------------------------------------- #
 
 def _flash_dq_kernel(*refs, scale: float, causal: bool, block_q: int,
-                     block_k: int, n_kb: int, chunk_mode: bool):
+                     block_k: int, n_kb: int, chunk_mode: bool, op_dtype):
     """Grid (bh, q_blocks, k_blocks): accumulate dQ for one Q tile across all
     K/V tiles. p is recomputed from Q,K and the saved logsumexp — the score
-    matrix never exists outside one VMEM tile."""
+    matrix never exists outside one VMEM tile. The softmax scale on dS is
+    applied once, to the f32 accumulator."""
     if chunk_mode:
         mode_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, \
             dq_ref, dq_acc = refs
@@ -239,40 +381,36 @@ def _flash_dq_kernel(*refs, scale: float, causal: bool, block_q: int,
 
     @pl.when(kj == 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    block_live = True if (not causal or chunk_mode) else \
-        (kj * block_k <= qi * block_q + block_q - 1)
-
-    @pl.when(block_live)
-    def _update():
-        q = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        g = g_ref[0].astype(jnp.float32)
-        lse = _row_ref(lse_ref)                   # (block_q,)
-        delta = _row_ref(delta_ref)               # (block_q,)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32,
-            precision=matmul_precision()) * scale
-        if causal:
+    def update(masked: bool):
+        q = q_ref[0].astype(op_dtype)
+        k_blk = k_ref[0].astype(op_dtype)
+        v_blk = v_ref[0].astype(op_dtype)
+        g = g_ref[0].astype(op_dtype)
+        s = _dot(q, k_blk, _NT) * scale
+        if masked:
             s = _causal_mask(s, qi, kj, block_q, block_k, mode)
-        p = jnp.exp(s - lse[:, None])             # masked entries -> 0
-        dp = jnp.dot(g, v_blk.T, preferred_element_type=jnp.float32,
-            precision=matmul_precision())
-        ds = p * (dp - delta[:, None]) * scale
-        dq_acc[:] = dq_acc[:] + jnp.dot(
-            ds, k_blk, preferred_element_type=jnp.float32,
-            precision=matmul_precision())
+        p = jnp.exp(s - lse_ref[0])               # masked entries -> 0
+        dp = _dot(g, v_blk, _NT)
+        ds = p * (dp - delta_ref[0])              # lse, delta: (block_q, 1)
+        dq_acc[...] = dq_acc[...] + _dot(ds.astype(op_dtype), k_blk, _NN)
+
+    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k)
 
     @pl.when(kj == n_kb - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
-                      block_k: int, n_qb: int, chunk_mode: bool):
+                      block_k: int, n_qb: int, chunk_mode: bool, op_dtype):
     """Grid (bh, k_blocks, q_blocks): accumulate dK and dV for one K/V tile
-    across all Q tiles."""
+    across all Q tiles. The scores are computed TRANSPOSED, keys down the
+    rows ((block_k, block_q) = K Q^T), so that P^T dO and dS^T Q are plain
+    products of the tile as it lies and nothing score-shaped goes through a
+    transpose; the row statistics then broadcast down the sublanes from a
+    lane-dense (1, block_q) tile."""
     if chunk_mode:
         mode_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, \
             dk_ref, dv_ref, dk_acc, dv_acc = refs
@@ -286,107 +424,102 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
 
     @pl.when(qi == 0)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    block_live = True if (not causal or chunk_mode) else \
-        (qi * block_q + block_q - 1 >= kj * block_k)
+    def update(masked: bool):
+        q = q_ref[0].astype(op_dtype)
+        k_blk = k_ref[0].astype(op_dtype)
+        v_blk = v_ref[0].astype(op_dtype)
+        g = g_ref[0].astype(op_dtype)
+        st = _dot(k_blk, q, _NT) * scale          # (block_k, block_q)
+        if masked:
+            st = _causal_mask(st, qi, kj, block_q, block_k, mode,
+                              transposed=True)
+        pt = jnp.exp(st - lse_ref[0, 0])          # lse, delta: (1, block_q)
+        dv_acc[...] = dv_acc[...] + _dot(pt.astype(op_dtype), g, _NN)
+        dpt = _dot(v_blk, g, _NT)
+        dst = pt * (dpt - delta_ref[0, 0])
+        dk_acc[...] = dk_acc[...] + _dot(dst.astype(op_dtype), q, _NN)
 
-    @pl.when(block_live)
-    def _update():
-        q = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        g = g_ref[0].astype(jnp.float32)
-        lse = _row_ref(lse_ref)
-        delta = _row_ref(delta_ref)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32,
-            precision=matmul_precision()) * scale
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, mode)
-        p = jnp.exp(s - lse[:, None])             # (block_q, block_k)
-        dv_acc[:] = dv_acc[:] + jnp.dot(
-            p.T, g, preferred_element_type=jnp.float32,
-            precision=matmul_precision())
-        dp = jnp.dot(g, v_blk.T, preferred_element_type=jnp.float32,
-            precision=matmul_precision())
-        ds = p * (dp - delta[:, None]) * scale
-        dk_acc[:] = dk_acc[:] + jnp.dot(
-            ds.T, q, preferred_element_type=jnp.float32,
-            precision=matmul_precision())
+    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k)
 
     @pl.when(qi == n_qb - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
-               block_q: int, block_k: int, interpret: bool, mode=None,
-               delta=None):
-    """mode: see _flash_fwd. ``delta`` (rowsum(dO*O), global) may be passed
-    in by the ring backward, whose O is the merged global output."""
+               block_q: Optional[int], block_k: Optional[int],
+               interpret: bool, mode=None, delta=None):
+    """mode, blocks: see _flash_fwd (the rule sizes the two sweeps apart).
+    ``delta`` (rowsum(dO*O), global) may be passed in by the ring backward,
+    whose O is the merged global output."""
     b, h, s, d = q.shape
     bh = b * h
-    block_q, block_k = _check_blocks(s, block_q, block_k)
-    n_qb, n_kb = s // block_q, s // block_k
     if delta is None:
         # delta_i = rowsum(dO * O): one O(S*D) elementwise pass, XLA-fused
         delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1)                   # (b, h, s)
     r3 = lambda x: x.reshape(bh, s, x.shape[-1])
     q3, k3, v3, g3 = r3(q), r3(k), r3(v), r3(g)
-    lse3 = lse.reshape(bh, s, 1)
-    delta3 = delta.reshape(bh, s, 1)
     chunk = mode is not None
     mode_arg = [jnp.asarray(mode, jnp.int32).reshape(1)] if chunk else []
     smem = [pl.BlockSpec(memory_space=pltpu.SMEM)] if chunk else []
+    op_dtype = _operand_dtype(q.dtype)
+    clamp = causal and not chunk
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
-    qspec = pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0),
-                         memory_space=pltpu.VMEM)
-    rowq = pl.BlockSpec((1, block_q, 1), lambda i, j, kk: (i, j, 0),
-                        memory_space=pltpu.VMEM)
-
+    # dQ sweep: grid (bh, q_blocks, k_blocks), the forward's
+    bq, bk = _blocks_for("dq", q, block_q, block_k)
+    qmap = lambda i, j, kk: (i, j, 0)
+    qspec = vmem((1, bq, d), qmap)
+    kspec = vmem((1, bk, d), _kv_block_map(clamp, bq, bk))
+    rowq = vmem((1, bq, 1), qmap)
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_kb=n_kb,
-                          chunk_mode=chunk),
+                          block_q=bq, block_k=bk, n_kb=s // bk,
+                          chunk_mode=chunk, op_dtype=op_dtype),
         name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        grid=(bh, n_qb, n_kb),
+        grid=(bh, s // bq, s // bk),
         in_specs=smem + [qspec, kspec, kspec, qspec, rowq, rowq],
         out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(*mode_arg, q3, k3, v3, g3, lse3, delta3)
+    )(*mode_arg, q3, k3, v3, g3, lse.reshape(bh, s, 1),
+      delta.reshape(bh, s, 1))
 
-    # swapped grid: (bh, k_blocks, q_blocks)
-    qspec_t = pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, kk, 0),
-                           memory_space=pltpu.VMEM)
-    kspec_t = pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, j, 0),
-                           memory_space=pltpu.VMEM)
-    rowq_t = pl.BlockSpec((1, block_q, 1), lambda i, j, kk: (i, kk, 0),
-                          memory_space=pltpu.VMEM)
+    # dK/dV sweep: swapped grid (bh, k_blocks, q_blocks); the row statistics
+    # one lane-dense (1, block_q) row per Q block
+    bq, bk = _blocks_for("dkv", q, block_q, block_k)
+    n_qb = s // bq
+    if clamp:
+        # a Q block above the diagonal names the first live one again
+        qblk = lambda j, kk: jnp.maximum(kk, _first_live_q(j, bq, bk))
+    else:
+        qblk = lambda j, kk: kk
+    qspec_t = vmem((1, bq, d), lambda i, j, kk: (i, qblk(j, kk), 0))
+    kspec_t = vmem((1, bk, d), lambda i, j, kk: (i, j, 0))
+    rowq_t = vmem((1, 1, 1, bq), lambda i, j, kk: (i, qblk(j, kk), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_qb=n_qb,
-                          chunk_mode=chunk),
+                          block_q=bq, block_k=bk, n_qb=n_qb,
+                          chunk_mode=chunk, op_dtype=op_dtype),
         name="flash_bwd_dkv",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
-        grid=(bh, n_kb, n_qb),
+        grid=(bh, s // bk, n_qb),
         in_specs=smem + [qspec_t, kspec_t, kspec_t, qspec_t, rowq_t, rowq_t],
         out_specs=(kspec_t, kspec_t),
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(*mode_arg, q3, k3, v3, g3, lse3, delta3)
+    )(*mode_arg, q3, k3, v3, g3, lse.reshape(bh, n_qb, 1, bq),
+      delta.reshape(bh, n_qb, 1, bq))
 
     rs = lambda x: x.reshape(b, h, s, d)
     return rs(dq), rs(dk), rs(dv)
@@ -394,9 +527,13 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None):
-    """Pallas blockwise attention; (B, H, S, D) -> (B, H, S, D)."""
+                    scale: Optional[float] = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    interpret: Optional[bool] = None):
+    """Pallas blockwise attention; (B, H, S, D) -> (B, H, S, D). With no
+    blocks given each of the three kernels takes ``flash_blocks``' tiles;
+    a given (block_q, block_k) is used by all three."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
@@ -427,33 +564,31 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def pick_block(s: int) -> Optional[int]:
-    """Largest clean tile height for a sequence length, MXU/VPU-aligned.
-
-    Mosaic only needs the block's second-minor dim to be a multiple of the
-    8-row f32 sublane tile, so non-power-of-two sequence lengths that a
-    128/64/32 block cannot divide (s=48, s=136, ...) still tile with a
-    smaller aligned block — falling back to None there routed perfectly
-    kernelable shapes onto the dense O(S^2) op."""
-    return next((bs for bs in (128, 64, 32, 16, 8) if s % bs == 0), None)
-
-
-def attention_route(s: int, sk: Optional[int] = None):
+def attention_route(s: int, sk: int, d: int, itemsize: int,
+                    causal: bool = True):
     """``(arm, note)`` for one attention geometry — THE routing decision:
     ``maybe_flash_attention`` takes it at trace time and ``Net`` logs it
-    per ATTENTION layer at construction. ``"pallas_flash"`` when the
-    sequence tiles cleanly (divisible by a 128/64/32-row block,
-    self-attention lengths); ``"dense"`` on the CPU test mesh (the kernel
-    would run in interpret-mode emulation — strictly slower than the dense
-    op it replaces) and for shapes the kernel does not tile."""
-    block = pick_block(s)
+    per ATTENTION layer at construction, for Q and K/V lengths ``s`` and
+    ``sk``, head width ``d`` and operand ``itemsize``. ``"pallas_flash"`` when the
+    sequence tiles cleanly (an aligned block divides it, self-attention
+    lengths), the note then stating each kernel's ``block_q x block_k`` from
+    ``flash_blocks`` and the live / visited programs of its grid per head;
+    ``"dense"`` on the CPU test mesh (the kernel would run in
+    interpret-mode emulation — strictly slower than the dense op it
+    replaces) and for shapes the kernel does not tile."""
     if _interpret_default():
         return "dense", "cpu backend"
-    if sk is not None and sk != s:
+    if sk != s:
         return "dense", "cross-attention lengths"
-    if block is None:
+    if pick_block(s) is None:
         return "dense", f"no aligned block divides S={s}"
-    return "pallas_flash", f"block {block}"
+    parts = []
+    for kernel in ("fwd", "dq", "dkv"):
+        bq, bk = flash_blocks(kernel, s, d, itemsize)
+        live, visited = flash_grid_programs(s, bq, bk, causal)
+        parts.append(f"{kernel} {bq}x{bk} {live}/{visited}")
+    return "pallas_flash", ", ".join(parts) + \
+        "; block_q x block_k, live/visited programs a head"
 
 
 def maybe_flash_attention(q, k, v, causal: bool = False,
@@ -462,13 +597,12 @@ def maybe_flash_attention(q, k, v, causal: bool = False,
     once per shape. The training entry point for models/transformer.py
     (both blocks) and the Ulysses head-parallel path."""
     from .attention import attention
-    s = q.shape[-2]
-    arm, note = attention_route(s, k.shape[-2])
-    where = f"[kernel_route] attention S={s} D={q.shape[-1]}"
+    s, d = q.shape[-2:]
+    arm, note = attention_route(s, k.shape[-2], d, q.dtype.itemsize, causal)
+    where = f"[kernel_route] attention S={s} D={d}"
     if arm == "pallas_flash":
-        block = pick_block(s)
-        _log_route_once(f"{where}: pallas flash, block {block}")
-        return flash_attention(q, k, v, causal, scale, block, block)
+        _log_route_once(f"{where}: pallas flash, {note}")
+        return flash_attention(q, k, v, causal, scale)
     _log_route_once(f"{where}: dense ({note})")
     return attention(q, k, v, causal=causal, scale=scale)
 

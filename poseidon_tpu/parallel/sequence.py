@@ -44,9 +44,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, axis: str,
     ring_flash_attention (per-chunk flash kernels, O(S_local) HBM); the lax
     formulation below is the portable fallback."""
     from ..ops.pallas_kernels import _interpret_default, pick_block
-    blk = pick_block(q.shape[-2])
-    if blk is not None and not _interpret_default():
-        return ring_flash_attention(q, k, v, axis, causal, scale, blk)
+    if pick_block(q.shape[-2]) is not None and not _interpret_default():
+        return ring_flash_attention(q, k, v, axis, causal, scale)
     n = lax.psum(1, axis)
     my = lax.axis_index(axis)
     if scale is None:
@@ -95,12 +94,15 @@ def _chunk_mode(my, src, causal: bool):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def ring_flash_attention(q, k, v, axis: str, causal: bool = False,
-                         scale: Optional[float] = None, block: int = 128,
+                         scale: Optional[float] = None,
+                         block: Optional[int] = None,
                          interpret: Optional[bool] = None):
     """Exact ring attention where every chunk runs through the Pallas flash
     kernels: K/V rotate via ppermute; each arriving chunk's (out, lse) merge
     by logsumexp weighting — never more than one (S_local, S_local) score
-    TILE in VMEM, O(S_local) HBM. The backward re-rotates K/V and runs the
+    TILE in VMEM, O(S_local) HBM. ``block`` None: each kernel takes the
+    tile rule's blocks for the chunk length (``flash_blocks``), as plain
+    self-attention does. The backward re-rotates K/V and runs the
     flash dq/dk+dv kernels per chunk with the GLOBAL logsumexp; dK/dV
     accumulators travel the ring WITH their chunk, arriving home after the
     full rotation."""

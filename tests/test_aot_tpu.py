@@ -98,6 +98,75 @@ def test_flash_kernels_mosaic_compile_for_v5e():
         and "OK pool_bwd" in r.stdout
 
 
+# The three flash kernels at olmoe.l1.pack4k's own geometry — 2 sequences x
+# 16 heads of 128 at S 4096, bf16, causal — with the tiles the rule picks,
+# under the bf16 policy (`train --bf16`): a tile choice Mosaic rejects, or
+# one that does not fit VMEM, is found here and not on the chip.
+_FLASH_CELL = r"""
+import json, os, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, {repo!r})
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("SKIP:", e)
+    sys.exit(3)
+from poseidon_tpu.config import set_perf_policy
+from poseidon_tpu.ops import pallas_kernels as PK
+set_perf_policy()
+B, H, S, D = 2, 16, 4096, 128
+sh = SingleDeviceSharding(topo.devices[0])
+q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=sh)
+row = jax.ShapeDtypeStruct((B, H, S), jnp.float32, sharding=sh)
+scale = D ** -0.5
+fwd = lambda q, k, v: PK._flash_fwd(q, k, v, scale, True, None, None, False)
+bwd = lambda q, k, v, o, lse, g: PK._flash_bwd(
+    q, k, v, o, lse, g, scale, True, None, None, False)
+text = {{"fwd": jax.jit(fwd).lower(q, q, q).compile().as_text(),
+        "bwd": jax.jit(bwd).lower(q, q, q, q, row, q).compile().as_text()}}
+kernels = {{}}
+for kernel, name, where in (("fwd", "flash_fwd", "fwd"),
+                            ("dq", "flash_bwd_dq", "bwd"),
+                            ("dkv", "flash_bwd_dkv", "bwd")):
+    bq, bk = PK.flash_blocks(kernel, S, D, 2)
+    live, visited = PK.flash_grid_programs(S, bq, bk, True)
+    kernels[kernel] = {{
+        "blocks": [bq, bk], "live": B * H * live, "grid": B * H * visited,
+        "compiled": sum(1 for l in text[where].splitlines()
+                        if 'custom_call_target="tpu_custom_call"' in l
+                        and ("%" + name) in l.split("=")[0])}}
+print("RESULT " + json.dumps(kernels))
+"""
+
+
+def test_flash_kernels_compile_for_v5e_at_the_cell_geometry():
+    """flash_fwd, flash_bwd_dq and flash_bwd_dkv pass Mosaic for an abstract
+    v5e at bf16[32,4096,128], causal, with the rule's tiles; the tiles and
+    the programs per grid are printed for whoever reads the run."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _FLASH_CELL.format(repo=REPO)],
+        capture_output=True, text=True, timeout=600, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)
+    for kernel in ("fwd", "dq", "dkv"):
+        # 1024 x 1024: 512 programs a grid where 128 x 128 made 32,768,
+        # 320 of them live (10 of 16 block pairs a head)
+        assert got[kernel] == {"blocks": [1024, 1024], "live": 320,
+                               "grid": 512, "compiled": 1}, got
+
+
 # The full-width, one-layer OLMoE train step (examples/lm/olmoe_1b_7b_*) as
 # `train --bf16` builds it, for one abstract v5e chip: the kernels that must
 # be in it, and the compiler's memory accounting that sized the cell's batch
@@ -181,8 +250,11 @@ def test_olmoe_full_width_step_compiles_for_one_v5e():
                           if l.startswith("RESULT "))[7:])
     print(got)            # the accounting, for whoever sizes the next batch
     assert got["parameters"] == 625_616_896
-    assert got["routes"] == {"l0_attn": "attention=pallas_flash",
-                             "l0_moe": "grouped_matmul=ragged_dot"}
+    assert got["routes"] == {
+        "l0_attn": "attention=pallas_flash (fwd 1024x1024 10/16, "
+                   "dq 1024x1024 10/16, dkv 1024x1024 10/16; "
+                   "block_q x block_k, live/visited programs a head)",
+        "l0_moe": "grouped_matmul=ragged_dot"}
     assert got["pallas_custom_calls"] >= 3       # flash fwd, dq, dkv
     assert got["ragged_dot_fusions"] >= 3        # gate, up, down (+ bwd)
     assert got["dense_expert_dots"] == 0

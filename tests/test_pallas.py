@@ -19,34 +19,74 @@ def qkv():
     return mk(), mk(), mk()
 
 
+def _dense_f32(q, k, v, causal):
+    f32 = lambda t: t.astype(jnp.float32)
+    return attention(f32(q), f32(k), f32(v), causal=causal)
+
+
+def _rel_l2(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+# (block_q, block_k) of the three kernels: equal, rectangular either way
+# (S=128 in blocks of 32 and 64 has dead blocks on both sides of the
+# diagonal and live ones that need no mask), a full-sequence tile, and
+# None = what the tile rule picks for this geometry
+BLOCKS = [(32, 32), (32, 64), (64, 32), (128, 128), (None, None)]
+
+
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_matches_reference(qkv, causal):
+@pytest.mark.parametrize("blocks", BLOCKS)
+def test_flash_attention_matches_reference(qkv, causal, blocks):
     q, k, v = qkv
     want = attention(q, k, v, causal=causal)
-    got = flash_attention(q, k, v, causal, None, 32, 64)
+    got = flash_attention(q, k, v, causal, None, *blocks)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-5)
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("blocks", [(32, 32), (32, 64), (128, 128)])
+@pytest.mark.parametrize("blocks", BLOCKS)
 def test_flash_attention_gradients(qkv, causal, blocks):
     """The Pallas dq/dk/dv kernels (O(S) memory, recompute-from-lse) against
     the dense reference VJP, across block shapes incl. full-sequence tiles."""
     q, k, v = qkv
-    bq, bk = blocks
 
     def loss_ref(q, k, v):
         return jnp.sum(attention(q, k, v, causal=causal) ** 2)
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal, None, bq, bk) ** 2)
+        return jnp.sum(flash_attention(q, k, v, causal, None, *blocks) ** 2)
 
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(gr, gf, "qkv"):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=5e-3, atol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", [(32, 64), (64, 32), (None, None)])
+def test_flash_attention_bf16_operands_against_f32_dense(qkv, blocks):
+    """Under the bf16 policy the tiles reach the MXU as bf16 (p and dS cast
+    for their products too) while the softmax statistics and every
+    accumulator stay f32: forward and all three gradients within 1% relative
+    L2 of the dense op computed in f32 from the same bf16 inputs (bf16
+    carries 8 bits; measured 0.3-0.6%, on the v5e 0.5-0.6% at S=1024)."""
+    from poseidon_tpu.config import policy_scope
+    q, k, v = (t.astype(jnp.bfloat16) for t in qkv)
+    g = (q + v) * 0.5                                # some cotangent, bf16
+    want, vjp = jax.vjp(lambda *a: _dense_f32(*a, True), q, k, v)
+    want_g = vjp(g.astype(jnp.float32))
+    with policy_scope(compute_dtype=jnp.bfloat16):
+        got, vjp = jax.vjp(
+            lambda *a: flash_attention(*a, True, None, *blocks), q, k, v)
+        got_g = vjp(g)
+    assert got.dtype == jnp.bfloat16
+    assert _rel_l2(got, want) < 0.01
+    for a, b, name in zip(got_g, want_g, "qkv"):
+        assert a.dtype == jnp.bfloat16
+        assert _rel_l2(a, b) < 0.01, name
 
 
 def test_flash_attention_grad_under_jit_and_vmapless_batch(qkv):
@@ -66,7 +106,8 @@ def test_pick_block_non_power_of_two_lengths():
     dim to be a multiple of the 8-row f32 sublane tile), not fall back to
     None — s=48 tiles at 16, s=136 at 8; only unaligned lengths refuse."""
     from poseidon_tpu.ops.pallas_kernels import pick_block
-    assert pick_block(1024) == 128
+    assert pick_block(4096) == 1024
+    assert pick_block(1024) == 1024
     assert pick_block(384) == 128     # 3 * 128
     assert pick_block(96) == 32
     assert pick_block(48) == 16       # used to fall back to None
@@ -74,15 +115,102 @@ def test_pick_block_non_power_of_two_lengths():
     assert pick_block(24) == 8
     assert pick_block(100) is None    # 4 mod 8: no aligned block exists
     assert pick_block(7) is None
-    # the flash kernel really runs at the small-block rungs
+
+
+@pytest.mark.parametrize("s", [48, 136])
+def test_flash_runs_at_the_small_block_rungs(s):
+    """The rule's own tiles for the odd lengths (16 x 16, 8 x 8), forward
+    and gradients."""
     rs = np.random.RandomState(3)
-    q = jnp.asarray(rs.randn(1, 2, 48, 16).astype(np.float32))
-    from poseidon_tpu.ops.pallas_kernels import flash_attention
-    got = flash_attention(q, q, q, True, None, pick_block(48),
-                          pick_block(48), interpret=True)
-    want = attention(q, q, q, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    q = jnp.asarray(rs.randn(1, 2, s, 16).astype(np.float32))
+    loss = lambda f: lambda q_: jnp.sum(f(q_, q_, q_) ** 2)
+    flash = lambda a, b, c: flash_attention(a, b, c, True)
+    dense = lambda a, b, c: attention(a, b, c, causal=True)
+    np.testing.assert_allclose(np.asarray(flash(q, q, q)),
+                               np.asarray(dense(q, q, q)),
                                rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(jax.grad(loss(flash))(q)),
+                               np.asarray(jax.grad(loss(dense))(q)),
+                               rtol=5e-3, atol=5e-4)
+
+
+# (kernel, S, D, itemsize) -> (block_q, block_k): the cell's geometry first
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("s, d, itemsize, want", [
+    (4096, 128, 2, (1024, 1024)),      # olmoe.l1.pack4k: bf16[32,4096,128]
+    (4096, 128, 4, (1024, 1024)),
+    (2048, 64, 2, (1024, 1024)),
+    (1536, 128, 2, (512, 512)),        # 3 * 512
+    (384, 64, 2, (128, 128)),
+    (128, 32, 4, (128, 128)),
+    (48, 16, 4, (16, 16)),
+    (136, 16, 4, (8, 8)),
+    (100, 16, 4, None),
+])
+def test_flash_blocks_rule(kernel, s, d, itemsize, want):
+    """The tile rule is a function of (S, D, itemsize) alone, and what it
+    picks fits its own VMEM budget."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+    assert PK.flash_blocks(kernel, s, d, itemsize) == want
+    if want is not None:
+        assert PK._flash_vmem_bytes(kernel, *want, d, itemsize) \
+            <= PK._FLASH_VMEM_BUDGET < PK._FLASH_VMEM_LIMIT
+
+
+def test_flash_blocks_shrink_when_the_budget_binds():
+    """f32 operands 256 wide leave the backward sweeps (four score-shaped
+    temporaries) no room for 1024 x 1024; the forward (two) keeps it. Of
+    two halvings the one that keeps the K/V block wide wins, until the
+    dK/dV sweep's four K-side tiles and two accumulators weigh more."""
+    from poseidon_tpu.ops.pallas_kernels import flash_blocks
+    assert flash_blocks("fwd", 4096, 256, 4) == (1024, 1024)
+    assert flash_blocks("dq", 4096, 256, 4) == (512, 1024)
+    assert flash_blocks("dkv", 4096, 256, 4) == (512, 1024)
+    assert flash_blocks("dkv", 4096, 384, 4) == (1024, 512)
+
+
+@pytest.mark.parametrize("s, bq, bk, causal, want", [
+    (4096, 1024, 1024, True, (10, 16)),
+    (4096, 512, 512, True, (36, 64)),
+    (4096, 128, 128, True, (528, 1024)),
+    (128, 32, 64, True, (6, 8)),
+    (128, 64, 32, True, (6, 8)),
+    (128, 32, 64, False, (8, 8)),
+])
+def test_flash_grid_programs(s, bq, bk, causal, want):
+    """(live, visited) programs per head, and the clamped index maps: a
+    dead block names the last live K/V tile (first live Q tile) again."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+    assert PK.flash_grid_programs(s, bq, bk, causal) == want
+    if causal:
+        live = [(qi, kj) for qi in range(s // bq) for kj in range(s // bk)
+                if kj * bk <= qi * bq + bq - 1]
+        assert len(live) == want[0]
+        for qi in range(s // bq):
+            assert PK._last_live_k(qi, bq, bk) == max(
+                kj for q_, kj in live if q_ == qi)
+        for kj in range(s // bk):
+            assert PK._first_live_q(kj, bq, bk) == min(
+                qi for qi, k_ in live if k_ == kj)
+
+
+def test_attention_route_states_tiles_and_programs(monkeypatch):
+    """Lowering for the TPU, the note names each kernel's tiles and the
+    live / visited programs of its grid; on the CPU mesh the route is the
+    dense op."""
+    from poseidon_tpu.ops.pallas_kernels import attention_route
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert attention_route(4096, 4096, 128, 2) == ("dense", "cpu backend")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arm, note = attention_route(4096, 4096, 128, 2)
+    assert arm == "pallas_flash"
+    assert note == ("fwd 1024x1024 10/16, dq 1024x1024 10/16, "
+                    "dkv 1024x1024 10/16; block_q x block_k, "
+                    "live/visited programs a head")
+    assert ": " not in note          # it is a stats.yaml leaf
+    assert attention_route(4096, 2048, 128, 2)[0] == "dense"
+    assert attention_route(100, 100, 16, 4) == (
+        "dense", "no aligned block divides S=100")
 
 
 def test_maybe_flash_routing(qkv):
