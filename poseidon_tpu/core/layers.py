@@ -34,7 +34,7 @@ Shape = Tuple[int, ...]
 LOSS_TYPES = {
     "SOFTMAX_LOSS", "EUCLIDEAN_LOSS", "HINGE_LOSS", "INFOGAIN_LOSS",
     "MULTINOMIAL_LOGISTIC_LOSS", "SIGMOID_CROSS_ENTROPY_LOSS",
-    "CONTRASTIVE_LOSS",
+    "CONTRASTIVE_LOSS", "EXIT_LOSS",
 }
 DATA_SOURCE_TYPES = {"DATA", "IMAGE_DATA", "HDF5_DATA", "WINDOW_DATA", "MEMORY_DATA"}
 
@@ -359,6 +359,21 @@ class MoELayer(Layer):
         stats = [jnp.max(sizes) * mp.num_experts / total,
                  total - jnp.sum(sizes)]
         return [y.reshape(n, s, d), lb, z] + stats[:len(self.lp.top) - 3]
+
+
+class SiLUGateLayer(Layer):
+    """Bottoms gate, up of one shape -> silu(gate) * up: the gate of a dense
+    gated FFN whose three projections are per-token INNER_PRODUCTs."""
+    TYPE = "SILU_GATE"
+
+    def setup(self, bottom_shapes):
+        if len(bottom_shapes) != 2 or bottom_shapes[0] != bottom_shapes[1]:
+            raise ValueError(f"{self.name}: SILU_GATE takes gate and up of "
+                             f"one shape, got {bottom_shapes}")
+        return [bottom_shapes[0]]
+
+    def apply(self, params, bottoms, ctx):
+        return [jax.nn.silu(bottoms[0]) * bottoms[1]]
 
 
 # --------------------------------------------------------------------------- #
@@ -693,6 +708,53 @@ class SoftmaxLossLayer(Layer):
         return [loss]
 
 
+class SoftmaxNLLLayer(Layer):
+    """Logits (N, S, V), targets (N, S) -> -log softmax(logits)[target] at
+    every position, (N, S) f32. Not a loss of its own: what EXIT_LOSS
+    weights, one per pass."""
+    TYPE = "SOFTMAX_NLL"
+
+    def setup(self, bottom_shapes):
+        if len(bottom_shapes) != 2 or \
+                tuple(bottom_shapes[0][:-1]) != tuple(bottom_shapes[1]):
+            raise ValueError(f"{self.name}: SOFTMAX_NLL takes logits "
+                             f"(..., V) and targets (...), got "
+                             f"{bottom_shapes}")
+        return [tuple(bottom_shapes[1])]
+
+    def apply(self, params, bottoms, ctx):
+        return [L.softmax_nll_last_axis(*bottoms)]
+
+
+class ExitLossLayer(Layer):
+    """Bottoms: T per-position losses (N, S), one per pass of a looped LM,
+    then the T - 1 exit-gate logits (N, S, 1) of every pass but the last.
+    Top 0: the exit-probability-weighted loss less ``entropy_weight`` x the
+    exit distribution's entropy (``ops/losses.exit_weighted_loss``);
+    optionally T more scalar tops, the mean exit mass of each pass (they
+    sum to 1)."""
+    TYPE = "EXIT_LOSS"
+
+    def setup(self, bottom_shapes):
+        n = len(bottom_shapes)
+        self.passes = (n + 1) // 2
+        if n % 2 != 1 or len(self.lp.top) not in (1, 1 + self.passes) or \
+                any(tuple(s) != tuple(bottom_shapes[0])
+                    for s in bottom_shapes[:self.passes]):
+            raise ValueError(
+                f"{self.name}: EXIT_LOSS takes T losses of one shape and "
+                f"T - 1 gates, and has 1 or 1 + T tops; got {n} bottoms, "
+                f"{len(self.lp.top)} tops")
+        return [()] * len(self.lp.top)
+
+    def apply(self, params, bottoms, ctx):
+        loss, p = L.exit_weighted_loss(
+            bottoms[:self.passes], bottoms[self.passes:],
+            self.lp.exit_loss_param.entropy_weight)
+        mass = lax.stop_gradient(jnp.mean(p.reshape(self.passes, -1), axis=1))
+        return [loss] + [mass[t] for t in range(len(self.lp.top) - 1)]
+
+
 class EuclideanLossLayer(_ScalarTopLayer):
     TYPE = "EUCLIDEAN_LOSS"
 
@@ -857,6 +919,7 @@ REGISTRY: Dict[str, type] = {
         AbsValLayer, PowerLayer, ThresholdLayer, DropoutLayer, FlattenLayer,
         ConcatLayer, SliceLayer, SplitLayer, EltwiseLayer, MVNLayer,
         SilenceLayer, SoftmaxLayer, ArgMaxLayer, SoftmaxLossLayer,
+        SiLUGateLayer, SoftmaxNLLLayer, ExitLossLayer,
         EuclideanLossLayer, HingeLossLayer, MultinomialLogisticLossLayer,
         SigmoidCrossEntropyLossLayer, InfogainLossLayer, ContrastiveLossLayer,
         AccuracyLayer, DataLayer, ImageDataLayer, HDF5DataLayer,

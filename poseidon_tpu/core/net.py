@@ -184,6 +184,9 @@ class Net:
         self.param_defs: Dict[str, List[ParamDef]] = {}
         self._storage_of: Dict[Tuple[str, str], Tuple[str, str]] = {}
         shared_owner: Dict[str, Tuple[str, str, ParamDef]] = {}
+        # {share name: {"owner": "<layer>/<blob>", "uses": layers bound to
+        # it}}: what stats.yaml's ``shared_params`` section says
+        self.shared_params: Dict[str, Dict] = {}
         for layer in self.layers:
             if not layer.params:
                 continue
@@ -218,9 +221,12 @@ class Net:
                             f"{share_name!r} shape mismatch "
                             f"{pdef.shape} vs {odef.shape}")
                     self._storage_of[(layer.name, pdef.name)] = (olayer, opname)
+                    self.shared_params[share_name]["uses"] += 1
                 else:
                     if share_name:
                         shared_owner[share_name] = (layer.name, pdef.name, pdef)
+                        self.shared_params[share_name] = {
+                            "owner": f"{layer.name}/{pdef.name}", "uses": 1}
                     self._storage_of[(layer.name, pdef.name)] = (layer.name,
                                                                  pdef.name)
                     owned.append(pdef)
@@ -439,6 +445,34 @@ class Net:
         return sum(p.count for defs in self.param_defs.values() for p in defs)
 
     # ------------------------------------------------------------------ #
+    def _remat_units(self, remat) -> Dict[int, Tuple[Layer, ...]]:
+        """{index of a unit's first layer: its layers} for ``apply``'s
+        ``remat``: names checked, a segment's layers consecutive and in one
+        layout, no layer in two units."""
+        index = {l.name: i for i, l in enumerate(self.layers)}
+        units: Dict[int, Tuple[Layer, ...]] = {}
+        seen = set()
+        for unit in remat or ():
+            names = (unit,) if isinstance(unit, str) else tuple(unit)
+            unknown = sorted(set(names) - set(index))
+            if unknown:
+                raise ValueError(f"remat names unknown layers: {unknown}")
+            first = index[names[0]]
+            layers = tuple(self.layers[first:first + len(names)])
+            if tuple(l.name for l in layers) != names:
+                raise ValueError(f"remat segment {names}: its layers do not "
+                                 f"follow one another in the net")
+            if seen & set(names):
+                raise ValueError(f"remat names {sorted(seen & set(names))} "
+                                 f"twice")
+            if len({l.run_layout for l in layers}) > 1:
+                raise ValueError(f"remat segment {names} crosses a layout "
+                                 f"boundary of the NHWC plan")
+            seen.update(names)
+            units[first] = layers
+        return units
+
+    # ------------------------------------------------------------------ #
     def apply(
         self,
         params: Dict[str, Dict[str, jax.Array]],
@@ -458,12 +492,14 @@ class Net:
         Outputs and ``keep_blobs`` are ALWAYS canonical NCHW — export,
         HDF5 dumps and debug tooling never see the internal layout.
 
-        ``remat`` names layers (any iterable of layer names — usually a
-        ``core/remat.RematPlan.layer_set``) whose forward bodies run
-        under ``jax.checkpoint``: their top activations are dropped
-        after forward and recomputed from their (stored) bottoms during
-        backward. The wrap changes WHAT IS STORED, never the math —
-        remat arms are bitwise-equal to stored-activation arms."""
+        ``remat`` names what runs under ``jax.checkpoint`` (usually a
+        ``core/remat.RematPlan.units``): each item a layer name, or a tuple
+        of CONSECUTIVE layer names that share one checkpoint (a segment: a
+        transformer block, a head and its loss). What a unit takes from
+        outside stays stored as the checkpoint's input; everything it
+        makes is dropped after forward and recomputed during backward. A
+        chain of one-layer units stores every link; a segment stores only
+        its ends. The wrap changes WHAT IS STORED, never the math."""
         if train is None:
             train = self.phase == "TRAIN"
         if comm is not None:
@@ -493,15 +529,14 @@ class Net:
                 converted[key] = NN.to_layout(v, have, want)
             return converted[key]
 
-        remat_set = frozenset(remat) if remat else frozenset()
-        unknown = remat_set - {l.name for l in self.layers}
-        if unknown:
-            raise ValueError(f"remat names unknown layers: "
-                             f"{sorted(unknown)}")
+        unit_at = self._remat_units(remat)
         loss = jnp.zeros((), jnp.float32)
         outputs: Dict[str, jax.Array] = {}
-        for layer in self.layers:
-            lp = layer.lp
+        at = 0
+        while at < len(self.layers):
+            checkpointed = at in unit_at
+            unit = unit_at[at] if checkpointed else (self.layers[at],)
+            at += len(unit)
             # layer-scoped HLO metadata: xplane trace events carry the layer
             # name, so one profiled step attributes device time per layer
             # (no per-layer recompiles — the `time --per_layer` alternative
@@ -510,46 +545,64 @@ class Net:
             # runtime/attribution.py joins both back). Bottom layout
             # conversions sit INSIDE the scope: a boundary transpose bills
             # to the layer that demanded it, not to the residual row.
-            if layer.name in remat_set:
-                # budget-planner remat (core/remat.py): checkpoint this
-                # layer's body — bottoms/params stay stored as the
-                # checkpoint's inputs, tops recompute during backward.
+            if checkpointed:
+                # remat (core/remat.py): checkpoint the unit's body — what
+                # it takes from outside and its params stay stored as the
+                # checkpoint's inputs, its tops recompute during backward.
                 # The named_scope sits INSIDE the checkpointed function
                 # (the JIT106 contract): the recomputed ops must keep
-                # attributing to this layer, not the residual row. ctx
+                # attributing to their layer, not the residual row. ctx
                 # (rng/comm) is closed over, not differentiated — the
                 # recompute replays the same dropout masks and the comm
                 # taps' custom_vjp rules fire once, in backward order.
-                with jax.named_scope(layer.name):
-                    bottoms = [bottom_in(b, layer.run_layout)
-                               for b in lp.bottom]
-                lparams = (self._layer_params(params, layer, comm)
-                           if layer.params else {})
+                made: set = set()
+                taken: Dict[Tuple[str, str], jax.Array] = {}
+                for layer in unit:
+                    with jax.named_scope(layer.name):
+                        for b in layer.lp.bottom:
+                            if b not in made:
+                                taken[(b, layer.run_layout)] = bottom_in(
+                                    b, layer.run_layout)
+                    made.update(layer.lp.top)
+                lparams = [self._layer_params(params, layer, comm)
+                           if layer.params else {} for layer in unit]
 
-                def _body(lp_, bt_, _layer=layer):
-                    with jax.named_scope(_layer.name):
-                        return _layer.apply(lp_, bt_, ctx)
+                def _body(lps_, taken_, _unit=unit):
+                    local: Dict[str, jax.Array] = {}
+                    all_tops = []
+                    for layer_, lp_ in zip(_unit, lps_):
+                        with jax.named_scope(layer_.name):
+                            tops_ = layer_.apply(lp_, [
+                                local[b] if b in local
+                                else taken_[(b, layer_.run_layout)]
+                                for b in layer_.lp.bottom], ctx)
+                        local.update(zip(layer_.lp.top, tops_))
+                        all_tops.append(tops_)
+                    return all_tops
 
-                tops = jax.checkpoint(_body)(lparams, bottoms)
+                unit_tops = jax.checkpoint(_body)(lparams, taken)
             else:
+                layer = unit[0]
                 with jax.named_scope(layer.name):
                     bottoms = [bottom_in(b, layer.run_layout)
-                               for b in lp.bottom]
-                    tops = layer.apply(
+                               for b in layer.lp.bottom]
+                    unit_tops = [layer.apply(
                         self._layer_params(params, layer, comm)
                         if layer.params else {},
-                        bottoms, ctx)
-            weights = layer.loss_weights(len(tops))
-            for name, val, w in zip(lp.top, tops, weights):
-                blobs[name] = val
-                cur_layout[name] = layer.run_layout
-                converted.pop((name, "NCHW"), None)
-                converted.pop((name, "NHWC"), None)
-                if w:
-                    # Caffe sums the whole top blob into the objective when a
-                    # loss_weight is set on a non-scalar top (net.cpp) —
-                    # layout-invariant, so the sum needs no conversion.
-                    loss = loss + w * jnp.sum(val.astype(jnp.float32))
+                        bottoms, ctx)]
+            for layer, tops in zip(unit, unit_tops):
+                weights = layer.loss_weights(len(tops))
+                for name, val, w in zip(layer.lp.top, tops, weights):
+                    blobs[name] = val
+                    cur_layout[name] = layer.run_layout
+                    converted.pop((name, "NCHW"), None)
+                    converted.pop((name, "NHWC"), None)
+                    if w:
+                        # Caffe sums the whole top blob into the objective
+                        # when a loss_weight is set on a non-scalar top
+                        # (net.cpp) — layout-invariant, so the sum needs no
+                        # conversion.
+                        loss = loss + w * jnp.sum(val.astype(jnp.float32))
 
         def canonical(name: str) -> jax.Array:
             v = blobs[name]

@@ -138,6 +138,10 @@ class RematPlan:
     budget_bytes: int = 0               # the target (0 = no budget given)
     measured_peak_bytes: int = 0        # no-remat compiled peak (0 = n/a)
     layers: Tuple[str, ...] = ()
+    # runs of consecutive ``layers`` that share ONE checkpoint (a block, a
+    # head and its loss): only what such a run takes from outside is
+    # stored. A layer in no segment is checkpointed alone.
+    segments: Tuple[Tuple[str, ...], ...] = ()
     saved_bytes: int = 0                # analytic activation bytes dropped
     recompute_flops: float = 0.0        # analytic forward FLOPs re-paid
     lm_policy: str = "none"
@@ -148,6 +152,14 @@ class RematPlan:
         return frozenset(self.layers)
 
     @property
+    def units(self) -> Tuple:
+        """What ``Net.apply(remat=)`` takes: the segments, then every
+        other layer alone."""
+        grouped = {n for seg in self.segments for n in seg}
+        return self.segments + tuple(n for n in self.layers
+                                     if n not in grouped)
+
+    @property
     def active(self) -> bool:
         return bool(self.layers) or self.lm_policy != "none"
 
@@ -155,8 +167,10 @@ class RematPlan:
         if not self.active:
             return "remat: off (fits the budget)"
         mb = self.saved_bytes / 2**20
-        return (f"remat[{self.source}]: {len(self.layers)} layers, "
-                f"~{mb:.1f} MiB reclaimed, "
+        return (f"remat[{self.source}]: {len(self.layers)} layers"
+                + (f" ({len(self.segments)} segments)"
+                   if self.segments else "")
+                + f", ~{mb:.1f} MiB reclaimed, "
                 f"{self.recompute_flops / 1e6:.1f} MFLOP recompute"
                 + (f", lm={self.lm_policy}"
                    if self.lm_policy != "none" else ""))
@@ -165,6 +179,7 @@ class RematPlan:
         return {"budget_bytes": int(self.budget_bytes),
                 "measured_peak_bytes": int(self.measured_peak_bytes),
                 "layers": list(self.layers),
+                "segments": [list(seg) for seg in self.segments],
                 "saved_bytes": int(self.saved_bytes),
                 "recompute_flops": float(self.recompute_flops),
                 "lm_policy": self.lm_policy,
@@ -176,11 +191,54 @@ class RematPlan:
                    measured_peak_bytes=int(doc.get("measured_peak_bytes",
                                                    0)),
                    layers=tuple(doc.get("layers", ())),
+                   segments=tuple(tuple(seg)
+                                  for seg in doc.get("segments", ())),
                    saved_bytes=int(doc.get("saved_bytes", 0)),
                    recompute_flops=float(doc.get("recompute_flops", 0.0)),
                    lm_policy=normalize_policy(doc.get("lm_policy",
                                                       "none")),
                    source=str(doc.get("source", "plan")))
+
+
+def resolve_entries(layer_names: Sequence[str], entries: Sequence[str]
+                    ) -> Tuple[Tuple[str, ...], Tuple[Tuple[str, ...], ...]]:
+    """``--remat``'s entries -> ``(layers, segments)`` of a plan.
+
+    A plain entry is a layer name, checkpointed alone. An entry written
+    ``/regex/`` names SEGMENTS: the regex is matched at the start of every
+    layer name, and each maximal run of consecutive layers whose matched
+    text is the same shares one checkpoint — ``/p\\d+_l\\d+_/`` is one
+    segment per ``p<t>_l<i>_*`` block, ``/p\\d+_(?=head|nll)/`` one per
+    pass's head and loss. Unknown names and a regex that matches nothing
+    are refused."""
+    import itertools
+    import re
+    known = set(layer_names)
+    layers: List[str] = []
+    segments: List[Tuple[str, ...]] = []
+    for entry in entries:
+        if not (len(entry) > 2 and entry[0] == entry[-1] == "/"):
+            if entry not in known:
+                raise ValueError(f"--remat names unknown layers: [{entry!r}]")
+            layers.append(entry)
+            continue
+        pattern = re.compile(entry[1:-1])
+        matched = [(name, pattern.match(name)) for name in layer_names]
+        found = False
+        for key, run in itertools.groupby(
+                matched, key=lambda nm: nm[1].group(0) if nm[1] else None):
+            if key is None:
+                continue
+            found = True
+            names = tuple(name for name, _ in run)
+            layers.extend(names)
+            if len(names) > 1:
+                segments.append(names)
+        if not found:
+            raise ValueError(f"--remat {entry} matches no layer")
+    if len(set(layers)) != len(layers):
+        raise ValueError("--remat names a layer more than once")
+    return tuple(layers), tuple(segments)
 
 
 def remat_candidates(net) -> List[str]:

@@ -514,3 +514,99 @@ def olmoe(batch: int = 2, source: str = "examples/lm/olmoe_tokens.txt",
         name="lm_loss", type="SOFTMAX_LOSS", bottom=["logits", "targets"],
         top=["lm_loss"], softmax_param=SoftmaxParameter(axis=-1)))
     return NetParameter(name=name, layers=layers)
+
+
+def ouro(batch: int = 1, source: str = "examples/lm/ouro_tokens.txt",
+         n_layers: int = 48, passes: int = 4, hidden: int = 2048,
+         heads: int = 16, ffn_width: int = 5632, vocab: int = 49152,
+         rope_theta: float = 1e6, eps: float = 1e-6, init_std: float = 0.02,
+         entropy_weight: float = 0.1,
+         name: str = "Ouro-2.6B") -> NetParameter:
+    """A looped LM (arXiv:2510.25741): ONE stack of ``n_layers`` sandwich-
+    norm blocks (RMSNorm before and after attention and the SiLU-gated FFN)
+    applied ``passes`` times with the same weights. Pass t's layers are
+    ``p<t>_l<i>_<what>``; every blob of pass 1 owns its storage under a
+    name (``param { name: "l<i>_<what>_w" }``) that the later passes bind
+    to, so a weight is one leaf to the update, the clip and a snapshot.
+    Each pass ends in the shared final norm, whose output feeds the shared
+    head, the shared one-unit exit gate (all passes but the last) and the
+    next pass; EXIT_LOSS weights the passes' per-token losses by the exit
+    distribution. Gains carry decay_mult 0, matrices 1, as ``olmoe``."""
+    from ..proto.messages import (AttentionParameter, EltwiseParameter,
+                                  EmbedParameter, ExitLossParameter,
+                                  HDF5DataParameter, RMSNormParameter)
+    w = gaussian(init_std)
+    layers: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+
+    def norm(lname, shared, bottom, top):
+        layers.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[ParamSpec(name=shared + "_g", lr_mult=1.0,
+                             decay_mult=0.0)],
+            rms_norm_param=RMSNormParameter(eps=eps)))
+
+    def proj(lname, shared, bottom, top, n_out, bias=False):
+        spec = [ParamSpec(name=shared + "_w")]
+        if bias:
+            spec.append(ParamSpec(name=shared + "_b", decay_mult=0.0))
+        layers.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            param=spec, inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=bias, axis=2, weight_filler=w)))
+
+    def add(lname, a, b, top):
+        layers.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation="SUM")))
+
+    layers.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["h0"],
+        embed_param=EmbedParameter(input_dim=vocab, num_output=hidden,
+                                   weight_filler=w)))
+    x = "h0"
+    for t in range(1, passes + 1):
+        for i in range(n_layers):
+            p, sh = f"p{t}_l{i}_", f"l{i}_"
+            norm(p + "attn_norm", sh + "attn_norm", x, p + "a")
+            for c in "qkv":
+                proj(p + c, sh + c, p + "a", p + c, hidden)
+            layers.append(LayerParameter(
+                name=p + "attn", type="ATTENTION",
+                bottom=[p + "q", p + "k", p + "v"], top=[p + "att"],
+                attention_param=AttentionParameter(
+                    num_heads=heads, rope_theta=rope_theta)))
+            proj(p + "o", sh + "o", p + "att", p + "ao", hidden)
+            norm(p + "attn_out_norm", sh + "attn_out_norm", p + "ao",
+                 p + "aon")
+            add(p + "res1", x, p + "aon", p + "h")
+            norm(p + "ffn_norm", sh + "ffn_norm", p + "h", p + "m")
+            proj(p + "ffn_gate", sh + "ffn_gate", p + "m", p + "fg",
+                 ffn_width)
+            proj(p + "ffn_up", sh + "ffn_up", p + "m", p + "fu", ffn_width)
+            layers.append(LayerParameter(
+                name=p + "ffn_act", type="SILU_GATE",
+                bottom=[p + "fg", p + "fu"], top=[p + "fa"]))
+            proj(p + "ffn_down", sh + "ffn_down", p + "fa", p + "fd", hidden)
+            norm(p + "ffn_out_norm", sh + "ffn_out_norm", p + "fd",
+                 p + "fdn")
+            add(p + "res2", p + "h", p + "fdn", p + "y")
+            x = p + "y"
+        p = f"p{t}_"
+        norm(p + "final_norm", "final_norm", x, p + "hn")
+        x = p + "hn"
+        proj(p + "head", "head", x, p + "logits", vocab)
+        layers.append(LayerParameter(
+            name=p + "nll", type="SOFTMAX_NLL",
+            bottom=[p + "logits", "targets"], top=[p + "nll"]))
+        if t < passes:      # the last pass takes what mass is left
+            proj(p + "gate", "gate", x, p + "gate", 1, bias=True)
+    layers.append(LayerParameter(
+        name="exit_loss", type="EXIT_LOSS",
+        bottom=[f"p{t}_nll" for t in range(1, passes + 1)]
+        + [f"p{t}_gate" for t in range(1, passes)],
+        top=["exit_loss"] + [f"exit_mass_p{t}"
+                             for t in range(1, passes + 1)],
+        exit_loss_param=ExitLossParameter(entropy_weight=entropy_weight)))
+    return NetParameter(name=name, layers=layers)

@@ -835,7 +835,7 @@ def build_spmd_train_step(
         return jax.random.fold_in(rng, flat_idx)
 
     # layers whose forward bodies Net.apply wraps in jax.checkpoint
-    _remat = (frozenset(remat_plan.layers)
+    _remat = (remat_plan.units
               if remat_plan is not None and remat_plan.layers else None)
 
     def _forward_backward(arena_bufs, excl_params, batch, rng):

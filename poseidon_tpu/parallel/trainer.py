@@ -244,7 +244,7 @@ def build_train_step(
             input_layout=input_layout, remat_plan=remat_plan)
     comm.wire_jnp_dtype()  # fail loudly on a bad wire_dtype string
     # layers whose forward bodies Net.apply wraps in jax.checkpoint
-    _remat = (frozenset(remat_plan.layers)
+    _remat = (remat_plan.units
               if remat_plan is not None and remat_plan.layers else None)
     axis = comm.axis
     dcn = comm.dcn_axis

@@ -306,6 +306,13 @@ class MoEParameter:
 
 
 @dataclass
+class ExitLossParameter:
+    """EXIT_LOSS: the weight of the exit distribution's entropy bonus (the
+    looped LM's stage-I objective, arXiv:2510.25741)."""
+    entropy_weight: float = 0.0
+
+
+@dataclass
 class LRNParameter:
     local_size: int = 5
     alpha: float = 1.0
@@ -417,7 +424,8 @@ V2_TYPE_TO_V1 = {
     "Softmax": "SOFTMAX", "SoftmaxWithLoss": "SOFTMAX_LOSS", "Split": "SPLIT",
     "Slice": "SLICE", "TanH": "TANH", "WindowData": "WINDOW_DATA",
     "Threshold": "THRESHOLD", "Embed": "EMBED", "RMSNorm": "RMS_NORM",
-    "Attention": "ATTENTION", "MoE": "MOE",
+    "Attention": "ATTENTION", "MoE": "MOE", "SiLUGate": "SILU_GATE",
+    "SoftmaxNLL": "SOFTMAX_NLL", "ExitLoss": "EXIT_LOSS",
 }
 V1_TYPES = set(V2_TYPE_TO_V1.values()) | {"NONE"}
 
@@ -478,6 +486,7 @@ class LayerParameter:
     rms_norm_param: RMSNormParameter = field(default_factory=RMSNormParameter)
     attention_param: AttentionParameter = field(default_factory=AttentionParameter)
     moe_param: MoEParameter = field(default_factory=MoEParameter)
+    exit_loss_param: ExitLossParameter = field(default_factory=ExitLossParameter)
     blob_mode: str = "GLOBAL"  # Poseidon extension on LayerParameter level
 
     def canonical_type(self) -> str:

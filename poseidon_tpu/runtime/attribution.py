@@ -267,7 +267,10 @@ def step_scopes(hlo_text: str, net) -> Dict:
     can execute as an operation of its own (fusion bodies are left out: a
     trace names the fusion), ``types`` = {scope: the layer's TYPE, or
     "update" / "arena" / "sync" for the scopes around the layer graph},
-    and how many of those ``instructions`` are ``mapped``."""
+    ``recomputed`` = the mapped instructions that a ``jax.checkpoint``
+    replays during backward (their phase reads ``bwd``, where they run:
+    this list tells a forward op run a second time from backward's own
+    products), and how many of those ``instructions`` are ``mapped``."""
     layer_types = {layer.name: layer.TYPE for layer in net.layers}
     resolved, comp_insts, fusion_bodies = _resolve_scopes(
         hlo_text, _ScopeIndex(layer_types, STEP_EXTRA_SCOPES))
@@ -277,10 +280,14 @@ def step_scopes(hlo_text: str, net) -> Dict:
     types = {scope: (layer_types.get(scope) or STEP_EXTRA_SCOPES.get(scope)
                      or "sync")
              for scope in {scope for scope, _ in mapped.values()}}
+    replayed = {m.group(2) for m in map(_INST.match, (
+        line.strip() for line in hlo_text.splitlines()
+        if "/rematted_computation/" in line)) if m}
     return {"ops": {i: f"{scope}|{phase}"
                     for i, (scope, phase) in mapped.items()},
-            "types": types, "instructions": len(executed),
-            "mapped": len(mapped)}
+            "types": types,
+            "recomputed": sorted(i for i in mapped if i in replayed),
+            "instructions": len(executed), "mapped": len(mapped)}
 
 
 def _resolve_scopes(hlo_text: str, index: "_ScopeIndex"):

@@ -988,7 +988,12 @@ def build_parser() -> argparse.ArgumentParser:
     t.add_argument("--remat", default="",
                    help="activation remat override: a comma-separated "
                         "layer list checkpoints exactly those layers "
-                        "(no measuring compile), 'auto' plans against "
+                        "(no measuring compile); an entry written "
+                        "/regex/ checkpoints each run of consecutive "
+                        "layers whose names start with the same match "
+                        "as ONE segment, storing only what the run takes "
+                        "from outside (/p\\d+_l\\d+_/ = one per block of "
+                        "a looped LM); 'auto' plans against "
                         "--hbm_budget_gb; empty or 'none' = off")
     t.add_argument("--bf16", action="store_true",
                    help="the documented bf16 training path: bfloat16 "

@@ -258,6 +258,12 @@ class Engine:
         self.train_pipelines, train_shapes = self._build_pipelines(
             train_param, "TRAIN")
         self._train_shapes = train_shapes  # per-device; remat probe scales
+        # tops of a token source (HDF5 ids and targets): int32 whatever
+        # their rank
+        self._token_tops = frozenset(
+            t for p in self.train_pipelines
+            if getattr(getattr(p, "source", None), "tokens", False)
+            for t in p.tops)
         self.train_net = Net(train_param, "TRAIN", source_shapes=train_shapes)
         if self.mesh_cfg is not None and self.mesh_cfg.active:
             from ..parallel.spmd import ShardingPlan
@@ -339,7 +345,9 @@ class Engine:
         # yet the unhonored aliasing spec measurably slows the call path
         # (~10% on the 2-core bench box), so donate only where the
         # allocator actually recycles.
-        donate_batch = self._use_prefetch and jax.default_backend() != "cpu"
+        donate_batch = self.donates_batch(
+            self._use_prefetch, jax.default_backend(),
+            set(train_shapes) - self._token_tops)
         self._donate_batch = donate_batch
 
         # --- measured HBM budget planner (core/remat.py) ------------------ #
@@ -516,6 +524,12 @@ class Engine:
             "jax": jax.__version__})
         self.stats.set_section("kernel_routes",
                                dict(self.train_net.kernel_routes))
+        if self.train_net.shared_params:
+            # one leaf to the update, the clip and a snapshot, whatever
+            # number of layers reads it
+            self.stats.set_section("shared_params", {
+                name: f"{d['owner']} x{d['uses']}" for name, d in
+                self.train_net.shared_params.items()})
         self.stats.set_section("data_reader", {
             p.tops[0]: ("native" if getattr(p, "native", None) is not None
                         else "python")
@@ -575,13 +589,27 @@ class Engine:
             memory_data=self.memory_data,
             device_transform=(self._device_transform and phase == "TRAIN"))
 
+    @staticmethod
+    def donates_batch(use_prefetch: bool, backend: str,
+                      float_tops) -> bool:
+        """Whether the step donates its batch: only with a prefetcher
+        handing it a fresh device batch every step, only where the
+        allocator recycles (not the CPU), and only if some batch blob is
+        not token ids. A batch of int32 ids and targets has nothing to
+        alias — no result of the step is an integer array — so donating
+        it only earned XLA's "Some donated buffers were not usable:
+        int32[...]" at every token-model start."""
+        return bool(use_prefetch and backend != "cpu" and float_tops)
+
     def _plan_remat(self, remat, hbm_budget_gb, donate_batch):
         """Resolve the remat decision for this job config (called once,
         before step building). Three spellings:
 
-        - ``remat`` = comma-separated layer names: trust the operator,
-          price the list against the attribution table, skip the
-          measuring compile entirely (source="flag");
+        - ``remat`` = comma-separated layer names, or ``/regex/`` entries
+          that checkpoint runs of layers as one segment each
+          (``remat.resolve_entries``): trust the operator, price the list
+          against the attribution table, skip the measuring compile
+          entirely (source="flag");
         - ``remat`` = "auto" and/or a budget: build the NO-remat step,
           compile it against abstract batch avals, read the real
           ``memory_analysis()`` peak, and run the knapsack
@@ -598,19 +626,16 @@ class Engine:
                  if s.strip() and s.strip().lower() not in ("none",
                                                             "auto")]
         if names:
-            known = {l.name for l in self.train_net.layers}
-            unknown = sorted(set(names) - known)
-            if unknown:
-                raise ValueError(
-                    f"--remat names unknown layers: {unknown}")
+            layers, segments = remat_mod.resolve_entries(
+                [l.name for l in self.train_net.layers], names)
             return remat_mod.RematPlan(
                 budget_bytes=0,
-                layers=tuple(names),
+                layers=layers, segments=segments,
                 saved_bytes=sum(int(table.get(n, {}).get("act_bytes", 0))
-                                for n in names),
+                                for n in layers),
                 recompute_flops=sum(
                     float(table.get(n, {}).get("flops", 0.0)) / 3.0
-                    for n in names),
+                    for n in layers),
                 source="flag")
         if hbm_budget_gb is not None and hbm_budget_gb < 0:
             budget = remat_mod.default_budget_bytes()
@@ -640,8 +665,10 @@ class Engine:
             g = (int(s[0]) * self.n_dev,) + tuple(int(d) for d in s[1:])
             if self.iter_size > 1:
                 g = (self.iter_size,) + g
-            # rank-1 source blobs are the data layers' label tops
-            dt = jnp.int32 if len(s) == 1 else jnp.float32
+            # rank-1 source blobs are the data layers' label tops; a token
+            # source's ids and targets are integers at rank 2 as well
+            dt = jnp.int32 if len(s) == 1 or k in self._token_tops \
+                else jnp.float32
             batch_avals[k] = jax.ShapeDtypeStruct(g, dt)
         return remat_mod.plan_for_net_step(
             self.train_net, probe.lowerable,
@@ -776,10 +803,10 @@ class Engine:
         an executable's text the section is empty and says ``why``."""
         from .attribution import step_scopes
         doc = (step_scopes(text, self.train_net) if text is not None else
-               {"ops": {}, "types": {}, "instructions": 0, "mapped": 0,
-                "why": why[:500]})
+               {"ops": {}, "types": {}, "recomputed": [],
+                "instructions": 0, "mapped": 0, "why": why[:500]})
         self.stats.set_section("step_scopes", doc,
-                               snapshot_only=("ops", "types"))
+                               snapshot_only=("ops", "types", "recomputed"))
 
     def _record_placement(self, batch_shape, batch_devs,
                           params) -> None:  # static-ok: JIT102
@@ -853,7 +880,9 @@ class Engine:
                     "delta", "clip_gradients", "iter_size",
                     "random_seed")},
                 comm=str(self.comm),
-                donate_batch=self._donate_batch)
+                donate_batch=self._donate_batch,
+                # what runs under which checkpoint is part of the program
+                remat=self.remat_plan.units if self.remat_plan else ())
             exec_ = load_step_executable(cfg.cache_dir, key)
             source, stored = "loaded", "found"
             if exec_ is None:

@@ -265,6 +265,117 @@ def test_olmoe_full_width_step_compiles_for_one_v5e():
     assert got["total_gb"] < 0.85 * 16.9
 
 
+# The full-width Ouro train step (examples/lm/ouro_2_6b_*) as `train --bf16
+# --remat <the solver header's flags>` builds it at one sequence of 8,192, for
+# one abstract v5e chip: the compiler's memory accounting that fixed the
+# configuration's depth and the cell's batch (benchmark/configs/ouro_2_6b.json,
+# benchmark/cells/ouro.loop4.pack8k.json), at the depth in the files and one
+# layer deeper.
+_OURO_STEP = r"""
+import json, os, re, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ["POSEIDON_FORCE_PALLAS"] = "1"      # lower as for the TPU
+sys.path.insert(0, {repo!r})
+import jax, jax.numpy as jnp, numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("SKIP:", e)
+    sys.exit(3)
+from poseidon_tpu.config import set_perf_policy
+from poseidon_tpu.core.net import Net
+from poseidon_tpu.core.remat import RematPlan, resolve_entries
+from poseidon_tpu.models import zoo
+from poseidon_tpu.parallel import (CommConfig, build_train_step,
+                                   init_train_state)
+from poseidon_tpu.proto.messages import load_net, load_solver
+set_perf_policy()
+batch, seq, deeper = 1, 8192, {deeper}
+solver = os.path.join({repo!r}, "examples/lm/ouro_2_6b_solver.prototxt")
+sp = load_solver(solver)
+flags = re.search(r"--remat '([^']+)'", open(solver).read()).group(1)
+net_param = load_net(os.path.join({repo!r}, sp.net))
+depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4
+if deeper:
+    net_param = zoo.ouro(batch=batch, n_layers=depth + deeper)
+net = Net(net_param, "TRAIN",
+          source_shapes={{"tokens": (batch, seq), "targets": (batch, seq)}})
+layers, segments = resolve_entries([l.name for l in net.layers],
+                                   flags.split(","))
+plan = RematPlan(layers=layers, segments=segments, source="flag")
+mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+comm = CommConfig()
+ts = build_train_step(net, sp, mesh, comm, donate=True, donate_batch=False,
+                      remat_plan=plan)
+rep = NamedSharding(mesh, P())
+shaped = lambda t, sh: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), t)
+params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+state = jax.eval_shape(
+    lambda p: init_train_state(p, comm, 1, sp.solver_type), params)
+tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                              sharding=ts.batch_sharding)
+compiled = ts.lowerable.lower(
+    shaped(params, rep), shaped(state, rep),
+    {{"tokens": tokens, "targets": tokens}},
+    jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
+ma, text = compiled.memory_analysis(), compiled.as_text()
+print("RESULT " + json.dumps({{
+    "depth": depth + deeper, "parameters": net.param_count(),
+    "leaves": len(jax.tree.leaves(params)), "segments": len(segments),
+    "routes": sorted(set(net.kernel_routes.values())),
+    "pallas_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+    "argument_gb": ma.argument_size_in_bytes / 1e9,
+    "temp_gb": ma.temp_size_in_bytes / 1e9,
+    "total_gb": (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                 - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 1e9}}))
+"""
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("deeper", [0, 1])
+def test_ouro_full_width_step_fits_one_v5e_at_its_depth_and_no_deeper(deeper):
+    """At the depth of the example (and of the cell) the remat'd step is
+    under 85% of the 16.9 GB the compiler allows (PR 22's sizing rule) with
+    one sequence of 8,192; one layer deeper it is over. A shared weight is
+    one leaf (11 a layer + embedding, final norm, head, gate w and b), every
+    block application and every head is one checkpoint segment, the flash
+    kernels run forward, replayed forward, dQ and dK/dV in every
+    application. (With the loss's backward left to autodiff the same step
+    compiled at 15.0 GB at six layers; written out, 12.6.)"""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _OURO_STEP.format(repo=REPO, deeper=deeper)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next depth
+    depth = got["depth"]
+    assert got["parameters"] == 201_330_689 + depth * 51_388_416
+    assert got["leaves"] == 11 * depth + 5
+    assert got["segments"] == 4 * depth + 4
+    assert got["routes"] == [
+        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
+        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "head)"]
+    assert got["pallas_custom_calls"] == 4 * 4 * depth
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    if deeper:
+        assert got["total_gb"] > 0.85 * 16.9
+    else:
+        assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9
+
+
 # AlexNet's train step as `train --bf16` builds it at the benchmark's batch,
 # for ONE abstract v5e chip: with nobody to all-reduce with, the step builder
 # packs nothing (PR 26) — no arena scope, no buffer-length array or constant,

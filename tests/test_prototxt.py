@@ -2,6 +2,8 @@ import os
 
 import pytest
 
+from poseidon_tpu.proto.messages import ParamSpec
+
 from poseidon_tpu.proto import (
     load_net_from_string, load_solver_from_string, parse,
 )
@@ -283,6 +285,15 @@ layers { name: "moe" type: MOE bottom: "att" top: "m" top: "bal" top: "z"
          moe_param { num_experts: 8 top_k: 2 expert_width: 32 } }
 layers { name: "loss" type: SOFTMAX_LOSS bottom: "m" bottom: "targets"
          top: "loss" softmax_param { axis: -1 } }
+layer { name: "act" type: "SiLUGate" bottom: "q" bottom: "q" top: "act" }
+layers { name: "shared" type: INNER_PRODUCT bottom: "a" top: "s"
+         param { name: "q_w" } param { name: "q_b" decay_mult: 0 }
+         inner_product_param { num_output: 1 axis: 2 } }
+layers { name: "nll" type: SOFTMAX_NLL bottom: "m" bottom: "targets"
+         top: "nll" }
+layer { name: "exit" type: "ExitLoss" bottom: "nll" bottom: "nll"
+        bottom: "s" top: "exit" top: "mass1" top: "mass2"
+        exit_loss_param { entropy_weight: 0.1 } }
 """
 
 
@@ -299,6 +310,12 @@ layers { name: "loss" type: SOFTMAX_LOSS bottom: "m" bottom: "targets"
     ("moe", "MOE", "moe_param.num_experts", 8),
     ("moe", "MOE", "loss_weight", [0.0, 0.01, 0.001]),
     ("loss", "SOFTMAX_LOSS", "softmax_param.axis", -1),
+    ("act", "SILU_GATE", "bottom", ["q", "q"]),
+    ("shared", "INNER_PRODUCT", "param", [
+        ParamSpec(name="q_w"), ParamSpec(name="q_b", decay_mult=0.0)]),
+    ("nll", "SOFTMAX_NLL", "top", ["nll"]),
+    ("exit", "EXIT_LOSS", "exit_loss_param.entropy_weight", 0.1),
+    ("exit", "EXIT_LOSS", "top", ["exit", "mass1", "mass2"]),
 ])
 def test_parse_token_layers(layer, ctype, field, want):
     """The token model's layer types and fields, in the V1 and the V2
