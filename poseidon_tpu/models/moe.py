@@ -189,11 +189,45 @@ def _grouped(x, w, group_sizes):
     compute dtype, and contracting the stored layout's last axis instead
     (``ragged_dot_general``) ran this product at 26 TFLOP/s on the v5e
     where this form runs at 132 (PR 25, PERF.md)."""
-    p = policy()
-    return lax.ragged_dot(
-        x.astype(p.compute_dtype),
-        jnp.swapaxes(w.astype(p.compute_dtype), 1, 2), group_sizes,
-        precision=matmul_precision())
+    return _grouped_cast(x.astype(policy().compute_dtype), w, group_sizes)
+
+
+@jax.custom_vjp
+def _grouped_cast(xc, w, group_sizes):
+    return lax.ragged_dot(xc, jnp.swapaxes(w.astype(xc.dtype), 1, 2),
+                          group_sizes, precision=matmul_precision())
+
+
+def _grouped_fwd(xc, w, group_sizes):
+    return _grouped_cast(xc, w, group_sizes), (xc, w, group_sizes)
+
+
+# dW[g] = dy_g^T x_g: the ragged dimension (the sorted rows) contracts, and
+# with dy as the left operand the result is (G, N, K)
+_DW_DIMS = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_bwd(res, dy):
+    """The backward written out for the weight gradient's sake. dx = dy
+    times the stored (G, N, K) weights, the product autodiff makes too.
+    dw = dy_g^T x_g per group, born (G, N, K) as the stack is stored:
+    autodiff's x_g^T dy_g is (G, K, N), and on the v5e the compiler then ran
+    ``gate``'s and ``up``'s Adam fusions in the transposed layout, behind
+    six 537 MB relayout copies of weight and moments a stack (PR 30,
+    PERF.md). Same products, same kernel, same precision: operands in the
+    compute dtype, dw cast to the stack's dtype where the forward cast's
+    transpose did it."""
+    xc, w, group_sizes = res
+    prec = matmul_precision()
+    dx = lax.ragged_dot(dy, w.astype(xc.dtype), group_sizes, precision=prec)
+    dw = lax.ragged_dot_general(dy, xc, group_sizes, _DW_DIMS,
+                                precision=prec)
+    return dx, dw.astype(w.dtype), None
+
+
+_grouped_cast.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,

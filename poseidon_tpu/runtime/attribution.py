@@ -36,13 +36,15 @@ capture after).
 from __future__ import annotations
 
 import glob
+import math
 import os
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
     "load_trace_events", "trace_events_from_xspace", "hlo_scope_map",
-    "step_scopes", "scope_of", "comm_axis_of", "layer_cost_table",
+    "step_scopes", "param_relayouts", "scope_of", "comm_axis_of",
+    "layer_cost_table",
     "attribute", "format_table", "measure_then_trace",
 ]
 
@@ -288,6 +290,46 @@ def step_scopes(hlo_text: str, net) -> Dict:
             "types": types,
             "recomputed": sorted(i for i in mapped if i in replayed),
             "instructions": len(executed), "mapped": len(mapped)}
+
+
+_ENTRY_COPY = re.compile(
+    r"(?:ROOT\s+)?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* copy\(%([\w.\-]+)\)")
+
+
+def param_relayouts(hlo_text: str, min_bytes: int = 1 << 20) -> Dict:
+    """``{"copies": n, "mb": x}``: the ``copy`` instructions of the entry
+    computation, of ``min_bytes`` or more, that read an entry parameter or
+    whose result the root returns, and the megabytes they write. A train
+    step's parameters and results are the weights and the solver's history,
+    so such a copy is the compiler moving a leaf into the layout some
+    consumer wants and back, every step: OLMoE's step had twelve of 537 MB
+    around two expert stacks' Adam fusions until the weight gradient came in
+    the stored orientation (PR 30). A fact of the compiled step (stats
+    section ``compiled_step``), read from its text, never a time."""
+    from .hlo_comm import _DTYPE_BYTES
+    params, copies, returned = set(), [], set()
+    entry = False
+    for line in hlo_text.splitlines():
+        if not line[:1].isspace():
+            entry = line.startswith("ENTRY ")
+            continue
+        if not entry:
+            continue
+        ls = line.lstrip()
+        if ls.startswith("ROOT "):
+            returned.update(_REF.findall(ls))   # its own name among them
+        if " parameter(" in ls:
+            params.add(_INST.match(ls).group(2))
+        m = _ENTRY_COPY.match(ls) if " copy(" in ls else None
+        if m:
+            name, dtype, dims, operand = m.groups()
+            size = _DTYPE_BYTES.get(dtype, 4) * math.prod(
+                int(d) for d in dims.split(",") if d)
+            if size >= min_bytes:
+                copies.append((name, operand, size))
+    sizes = [size for name, operand, size in copies
+             if operand in params or name in returned]
+    return {"copies": len(sizes), "mb": round(sum(sizes) / 1e6, 1)}
 
 
 def _resolve_scopes(hlo_text: str, index: "_ScopeIndex"):

@@ -831,6 +831,7 @@ class Engine:
         persistent compile cache still accelerates)."""
         from ..config import compile_cache_config, policy
         from ..ops.pallas_kernels import LOWERING_ENV
+        from .attribution import param_relayouts
         from .compile_cache import (code_fingerprint, load_step_executable,
                                     save_step_executable, step_key,
                                     watch_cache_hits)
@@ -913,13 +914,15 @@ class Engine:
                 + f" (key {key[:12]}; in aot/: {stored})", rank=self.rank)
             # what the step that will run actually contains: the Pallas
             # custom calls the kernel routes promise (0 = interpreted or
-            # routed to XLA) and the arena's gradient all-reduces
+            # routed to XLA), the arena's gradient all-reduces, and the
+            # layout copies between the step's parameters and its results
             doc.update(source=source, stored=stored)
             phase("text_s")
             text = exec_.as_text()
             doc["pallas_custom_calls"] = text.count(
                 'custom_call_target="tpu_custom_call"')
             doc["gradient_all_reduces"] = count_gradient_all_reduces(text)
+            doc["param_relayouts"] = param_relayouts(text)
             phase("scope_map_s")
             self._publish_step_scopes(text)
         except Exception as e:  # noqa: BLE001 — warm start is best-effort

@@ -2,6 +2,8 @@
 a copy of benchmark/reference/olmoe.py): logits, the three losses and every
 parameter's gradient on seeded weights, at a small size on the CPU."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +13,7 @@ import olmoe_ref as ref
 from poseidon_tpu.config import policy_scope
 from poseidon_tpu.core.net import Net
 from poseidon_tpu.models import zoo
-from poseidon_tpu.models.moe import moe_dropless
+from poseidon_tpu.models.moe import _grouped, moe_dropless
 from poseidon_tpu.proto.messages import load_net_from_string
 
 SIZES = dict(n_layers=2, hidden=64, heads=4, experts=8, top_k=2,
@@ -121,6 +123,93 @@ def test_skewed_routing_stays_dropless():
     assert rel(y, y_ref) < 1e-5
     np.testing.assert_allclose(lb, lb_ref, rtol=1e-6)
     np.testing.assert_allclose(z, z_ref, rtol=1e-6)
+
+
+# rows per group of the sorted assignments, by case: even load, every row to
+# the two experts of ``test_skewed_routing_stays_dropless``, a group between
+# two others with no row at all
+_GROUP_ROWS = {"even": [24] * 8,
+               "skewed": [0, 0, 0, 96, 0, 0, 96, 0],
+               "empty_group": [10, 0, 25, 5, 0, 70, 1, 81]}
+
+
+def _grouped_plain(x, w, group_sizes):
+    """What ``_grouped`` computes, as a dense einsum per group over the
+    stored (N, K) weights (every row against every group, all but its own
+    masked out), for autodiff to differentiate."""
+    rows = jnp.repeat(jnp.arange(w.shape[0]), group_sizes,
+                      total_repeat_length=x.shape[0])
+    mine = jax.nn.one_hot(rows, w.shape[0], dtype=x.dtype)
+    return jnp.einsum("mk,gnk,mg->mn", x, w, mine,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize("compute", ["f32", "bf16"])
+@pytest.mark.parametrize("stack", ["gate", "down"])
+@pytest.mark.parametrize("case", sorted(_GROUP_ROWS))
+def test_grouped_backward_matches_autodiff_of_the_plain_form(case, stack,
+                                                             compute):
+    """``_grouped``'s backward is written out (the weight gradient born in
+    the stored orientation). At f32 it equals autodiff of the plain form
+    to 1e-6 in dx and dw; under the bf16 policy it stays inside the
+    tolerance ``test_bf16_policy_stays_near_reference`` uses. And dw is a
+    gradient OF the stack: its shape, its dtype."""
+    sizes = jnp.asarray(_GROUP_ROWS[case], jnp.int32)
+    m, d, f = int(sizes.sum()), 32, 16
+    k_in, n_out = (d, f) if stack == "gate" else (f, d)
+    keys = jax.random.split(jax.random.PRNGKey(17), 3)
+    x = jax.random.normal(keys[0], (m, k_in))
+    w = 0.3 * jax.random.normal(keys[1], (8, n_out, k_in))
+    ct = jax.random.normal(keys[2], (m, n_out))
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda x, w: jnp.sum(fn(x, w, sizes).astype(jnp.float32) * ct),
+            argnums=(0, 1)))(x, w)
+
+    want_dx, want_dw = grads(_grouped_plain)
+    if compute == "f32":
+        got_dx, got_dw = grads(_grouped)
+        tol = 1e-6
+    else:
+        with policy_scope(compute_dtype=jnp.bfloat16):
+            got_dx, got_dw = grads(_grouped)
+        tol = ref.TOLERANCE["bf16"]["logits_rel_l2"]
+    assert got_dw.shape == w.shape and got_dw.dtype == w.dtype
+    assert got_dx.shape == x.shape and got_dx.dtype == x.dtype
+    assert rel(got_dx, want_dx) < tol and rel(got_dw, want_dw) < tol
+    # a group with no row has no gradient, exactly
+    empty = np.asarray(sizes) == 0
+    assert not np.asarray(got_dw)[empty].any()
+
+
+def test_expert_stacks_are_transposed_in_the_forward_only():
+    """Lowered, not compiled: the StableHLO of ``jax.grad(moe_dropless)``
+    under the bf16 policy transposes a rank-3 array with E in front exactly
+    three times, the forward's (G, K, N) views of ``gate``, ``up`` and
+    ``down``. Autodiff of that forward made nine: three views again for dx,
+    and each weight gradient born (G, K, N) and turned back, which on the
+    v5e became twelve 537 MB relayout copies around two stacks' Adam
+    fusions (PR 30)."""
+    t, d, e, f, k = 96, 32, 8, 16, 2
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    x = jax.random.normal(keys[0], (t, d))
+    router = jax.random.normal(keys[4], (e, d))
+    gate, up = (0.3 * jax.random.normal(kk, (e, f, d)) for kk in keys[1:3])
+    down = 0.3 * jax.random.normal(keys[3], (e, d, f))
+
+    def loss(x, router, gate, up, down):
+        y, lb, z, _ = moe_dropless(x, router, gate, up, down, top_k=k)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + lb + z
+
+    with policy_scope(compute_dtype=jnp.bfloat16):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            x, router, gate, up, down).as_text()
+    stacks = re.findall(
+        rf"stablehlo\.transpose [^\n]*: \(tensor<{e}x(\d+)x(\d+)x(\w+)>\)",
+        text)
+    assert sorted(stacks) == sorted([(str(f), str(d), "bf16")] * 2
+                                    + [(str(d), str(f), "bf16")]), stacks
 
 
 def test_bf16_policy_stays_near_reference(model):

@@ -206,6 +206,78 @@ def test_scope_map_build_is_one_pass():
     assert len(smap) >= 4000
 
 
+# a hand-made optimized module: %w is copied on its way in (2 MB), %h on its
+# way out (4 MB); %small is under the floor, %mid is neither a parameter's
+# copy nor returned, and the copy inside the fusion body is no instruction
+# that runs on its own
+_RELAYOUT_TEXT = """HloModule jit_step, is_scheduled=true
+
+%fused_computation (param_0: f32[512,1024]) -> f32[512,1024] {
+  %param_0 = f32[512,1024]{1,0} parameter(0)
+  ROOT %copy.9 = f32[512,1024]{0,1} copy(%param_0)
+}
+
+ENTRY %main (w: f32[512,1024], h: f32[1024,1024], b: f32[1024]) -> (f32[512,1024], f32[1024,1024], f32[1024]) {
+  %w = f32[512,1024]{1,0:T(8,128)} parameter(0)
+  %h = f32[1024,1024]{1,0:T(8,128)} parameter(1)
+  %b = f32[1024]{0} parameter(2)
+  %copy.1 = f32[512,1024]{0,1:T(8,128)} copy(%w), metadata={op_name="params"}
+  %fusion.1 = f32[512,1024]{0,1:T(8,128)} fusion(%copy.1), kind=kLoop, calls=%fused_computation
+  %copy.2 = f32[512,1024]{1,0:T(8,128)} copy(%fusion.1)
+  %add.1 = f32[1024,1024]{0,1:T(8,128)} add(%h, %h)
+  %copy.3 = f32[1024,1024]{0,1:T(8,128)} copy(%add.1)
+  %copy.4 = f32[1024,1024]{1,0:T(8,128)} copy(%copy.3)
+  %copy.5 = f32[1024]{0} copy(%b)
+  %copy-start.1 = (f32[1024]{0}, f32[1024]{0:S(1)}, u32[]) copy-start(%b)
+  ROOT %tuple.1 = (f32[512,1024]{1,0}, f32[1024,1024]{1,0}, f32[1024]{0}) tuple(%copy.2, %copy.4, %copy.5)
+}
+"""
+
+
+@pytest.mark.parametrize("floor, want", [
+    # copy.1 (reads %w), copy.2 and copy.4 (returned): 2.1 + 2.1 + 4.2 MB;
+    # copy.3 feeds neither, copy.5 is 4 KB, copy.9 is inside a fusion
+    (1 << 20, {"copies": 3, "mb": 8.4}),
+    (3 << 20, {"copies": 1, "mb": 4.2}),
+    (1, {"copies": 4, "mb": 8.4}),
+    (1 << 30, {"copies": 0, "mb": 0.0}),
+])
+def test_param_relayouts_counts_copies_at_the_steps_boundary(floor, want):
+    assert A.param_relayouts(_RELAYOUT_TEXT, floor) == want
+
+
+def test_param_relayouts_of_one_parameter_copy():
+    """One copy of one entry parameter and nothing else: one copy, its
+    megabytes (the result's: what the copy writes)."""
+    text = """ENTRY %main (w: bf16[64,2048,1024]) -> bf16[64,2048,1024] {
+  %w = bf16[64,2048,1024]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %copy.7 = bf16[64,2048,1024]{1,2,0:T(8,128)(2,1)} copy(%w), sharding={replicated}
+  ROOT %negate.1 = bf16[64,2048,1024]{1,2,0:T(8,128)(2,1)} negate(%copy.7)
+}
+"""
+    assert A.param_relayouts(text) == {"copies": 1, "mb": 268.4}
+
+
+def test_engine_publishes_param_relayouts_of_the_step_that_runs(
+        tmp_path, jax_cache_env):
+    """The fact sits beside ``update_route`` and ``grad_buckets`` in stats
+    section ``compiled_step`` and in ``stats.yaml``; a two-layer net's step
+    copies no parameter of a megabyte (it has none)."""
+    from poseidon_tpu.runtime.compile_cache import enable_compile_cache
+    from poseidon_tpu.runtime.metrics import read_stats_yaml
+
+    enable_compile_cache()
+    eng, _ = _train(tmp_path)
+    step = eng.stats.sections["compiled_step"]
+    assert step["source"] == "compiled" and "error" not in step
+    assert step["param_relayouts"] == {"copies": 0, "mb": 0.0}
+    assert step["update_route"] == "leaf" and "grad_buckets" in step
+    doc = read_stats_yaml(str(tmp_path / "stats.yaml"))
+    # read back as written: every leaf a string
+    assert doc["compiled_step"]["param_relayouts"] == {"copies": "0",
+                                                       "mb": "0.0"}
+
+
 def test_map_changes_no_arithmetic_and_jit_path_says_why(tmp_path,
                                                          jax_cache_env):
     """The step whose text is read and mapped computes bitwise the loss of
