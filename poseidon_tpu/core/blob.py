@@ -30,6 +30,9 @@ class ParamDef:
     filler: FillerParameter
     lr_mult: float = 1.0
     decay_mult: float = 1.0
+    # the layer computes this leaf's next value itself (a top of the layer,
+    # ``Layer.updates``): no gradient, optimizer, decay or clip touches it
+    layer_updated: bool = False
     # fan_in for xavier-style fillers: count / shape[0], matching Caffe's
     # `blob->count() / blob->num()` (include/caffe/filler.hpp).
     @property

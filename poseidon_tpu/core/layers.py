@@ -15,6 +15,7 @@ generated in-graph from its fillers.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -292,36 +293,63 @@ class RMSNormLayer(Layer):
 
 
 class AttentionLayer(Layer):
-    """Bottoms q, k, v of (N, S, D) -> (N, S, D): heads split, rotate-half
-    RoPE on q and k, causal softmax(q k^T / sqrt(Dh)) v, heads merged."""
+    """Bottoms q (N, S, D), k and v (N, S, Dkv) -> (N, S, D): q split into
+    ``num_heads`` heads, k and v into ``num_kv_heads`` of the same width
+    (grouped-query attention; unset: as many as q, Dkv = D), rotate-half
+    RoPE on the first ``rotary_dims`` of every q and k head (unset: the
+    whole head), causal softmax(q k^T / sqrt(Dh)) v, heads merged. It
+    normalises nothing: a QK-norm is the layers before it."""
     TYPE = "ATTENTION"
 
     def setup(self, bottom_shapes):
         ap = self.lp.attention_param
-        if len(bottom_shapes) != 3 or len(set(bottom_shapes)) != 1 \
-                or len(bottom_shapes[0]) != 3:
-            raise ValueError(f"{self.name}: ATTENTION takes q, k, v of one "
-                             f"(N, S, D) shape, got {bottom_shapes}")
-        d = bottom_shapes[0][-1]
+        if len(bottom_shapes) != 3 or any(len(b) != 3 for b in bottom_shapes) \
+                or bottom_shapes[1] != bottom_shapes[2] \
+                or bottom_shapes[0][:2] != bottom_shapes[1][:2]:
+            raise ValueError(f"{self.name}: ATTENTION takes q (N, S, D) and "
+                             f"k, v of one (N, S, Dkv) shape, got "
+                             f"{bottom_shapes}")
+        d, d_kv = bottom_shapes[0][-1], bottom_shapes[1][-1]
         if ap.num_heads <= 0 or d % ap.num_heads or (d // ap.num_heads) % 2:
             raise ValueError(f"{self.name}: {ap.num_heads} heads do not "
                              f"split D={d} into even head sizes")
+        d_head = d // ap.num_heads
+        n_kv = ap.num_kv_heads or ap.num_heads
+        if ap.num_heads % n_kv or d_kv != n_kv * d_head:
+            raise ValueError(f"{self.name}: {n_kv} key-value heads of "
+                             f"{d_head} need k, v of width {n_kv * d_head} "
+                             f"and a whole number of query heads each; got "
+                             f"{d_kv} and {ap.num_heads} query heads")
+        if ap.rotary_dims % 2 or not 0 <= ap.rotary_dims <= d_head:
+            raise ValueError(f"{self.name}: rotary_dims {ap.rotary_dims} "
+                             f"is not an even part of a head of {d_head}")
         return [bottom_shapes[0]]
 
     def apply(self, params, bottoms, ctx):
         from ..models.transformer import rope_attention
         ap = self.lp.attention_param
         return [rope_attention(*bottoms, n_heads=ap.num_heads,
-                               rope_theta=ap.rope_theta)]
+                               rope_theta=ap.rope_theta,
+                               n_kv_heads=ap.num_kv_heads,
+                               rotary_dims=ap.rotary_dims)]
 
 
 class MoELayer(Layer):
-    """Bottom (N, S, D) -> top-k token-choice experts, dropless. Blobs:
-    router (E, D), gate and up (E, F, D), down (E, D, F). Tops: the output;
-    the load-balancing and router z losses (scalars, weighted by the
-    prototxt's ``loss_weight``); optionally the step's own routing as two
-    more scalars — tokens at the fullest expert over the mean, and
-    assignments no expert computed (0: nothing is dropped)."""
+    """Bottom (N, S, D) -> top-k token-choice experts, dropless. The router
+    scores ``num_experts``; this layer holds ``num_held`` of them from
+    ``held_first`` on (unset: all) and adds nothing for a token whose
+    expert is elsewhere: one rank's share of an expert-parallel layer, the
+    shares summing to the whole. Blobs: gate and up (G, F, D), down
+    (G, D, F) over the G held experts, and
+    - ``router_hidden`` 0: router (E, D) first. Tops: the output; the
+      load-balancing and router z losses (scalars, weighted by the
+      prototxt's ``loss_weight``);
+    - ``router_hidden`` > 0: no router here; a second bottom, the gates
+      (N, S, E) of the MOE_ROUTER layer before it. Tops: the output.
+    Then optionally the step's own routing as up to three more scalars:
+    assignments at the fullest HELD expert over their mean, assignments to
+    a held expert that no expert computed (0: nothing is dropped), and the
+    share of all assignments that fell on a held expert."""
     TYPE = "MOE"
 
     def setup(self, bottom_shapes):
@@ -330,35 +358,262 @@ class MoELayer(Layer):
         if not 0 < mp.top_k <= mp.num_experts or mp.expert_width <= 0:
             raise ValueError(f"{self.name}: moe_param needs num_experts >= "
                              f"top_k > 0 and expert_width")
-        if not 3 <= len(self.lp.top) <= 5:
-            raise ValueError(f"{self.name}: MOE has 3 to 5 tops (output, "
-                             f"balance loss, z loss[, load max/mean[, "
-                             f"dropped]]), got {len(self.lp.top)}")
+        self.gated = mp.router_hidden > 0
+        self.n_fixed = 1 if self.gated else 3
+        if not self.n_fixed <= len(self.lp.top) <= self.n_fixed + 3:
+            raise ValueError(
+                f"{self.name}: MOE has {self.n_fixed} to {self.n_fixed + 3} "
+                f"tops (output, " + ("" if self.gated else "balance loss, "
+                                     "z loss, ")
+                + f"[load max/mean[, dropped[, held share]]]), got "
+                f"{len(self.lp.top)}")
         e, f = mp.num_experts, mp.expert_width
-        self.params = [
-            self._param("router", (e, d), mp.weight_filler, 0),
-            self._param("gate", (e, f, d), mp.weight_filler, 1),
-            self._param("up", (e, f, d), mp.weight_filler, 2),
-            self._param("down", (e, d, f), mp.weight_filler, 3)]
+        self.held = mp.num_held or e
+        if mp.held_first < 0 or mp.held_first + self.held > e:
+            raise ValueError(f"{self.name}: experts {mp.held_first}.."
+                             f"{mp.held_first + self.held - 1} are not among "
+                             f"the {e} the router scores")
+        want = [(n, s, d), (n, s, e)] if self.gated else [(n, s, d)]
+        if [tuple(b) for b in bottom_shapes] != want:
+            raise ValueError(f"{self.name}: MOE with router_hidden "
+                             f"{mp.router_hidden} takes bottoms {want}, got "
+                             f"{bottom_shapes}")
+        g = self.held
+        self.params = [] if self.gated else [
+            self._param("router", (e, d), mp.weight_filler, 0)]
+        at = len(self.params)
+        self.params += [
+            self._param("gate", (g, f, d), mp.weight_filler, at),
+            self._param("up", (g, f, d), mp.weight_filler, at + 1),
+            self._param("down", (g, d, f), mp.weight_filler, at + 2)]
         return [(n, s, d)] + [()] * (len(self.lp.top) - 1)
 
     def default_loss_weight(self) -> float:
         return 0.0
 
     def apply(self, params, bottoms, ctx):
-        from ..models.moe import moe_dropless
+        from ..models.moe import moe_dropless, moe_gated
         mp = self.lp.moe_param
         p = _tap_all(ctx, self.name, params)
         x = bottoms[0]
         n, s, d = x.shape
-        y, lb, z, sizes = moe_dropless(
-            x.reshape(n * s, d), p["router"], p["gate"], p["up"], p["down"],
-            mp.top_k)
+        flat = x.reshape(n * s, d)
+        if self.gated:
+            y, sizes = moe_gated(
+                flat, bottoms[1].reshape(n * s, mp.num_experts), p["gate"],
+                p["up"], p["down"], mp.top_k, mp.held_first)
+            tops = [y.reshape(n, s, d)]
+        else:
+            y, lb, z, sizes = moe_dropless(
+                flat, p["router"], p["gate"], p["up"], p["down"], mp.top_k,
+                mp.held_first)
+            tops = [y.reshape(n, s, d), lb, z]
         sizes = lax.stop_gradient(sizes).astype(jnp.float32)
         total = float(n * s * mp.top_k)
-        stats = [jnp.max(sizes) * mp.num_experts / total,
-                 total - jnp.sum(sizes)]
-        return [y.reshape(n, s, d), lb, z] + stats[:len(self.lp.top) - 3]
+        if self.held == mp.num_experts:
+            stats = [jnp.max(sizes) * mp.num_experts / total,
+                     total - jnp.sum(sizes), jnp.sum(sizes) / total]
+        else:
+            here = sizes[mp.held_first:mp.held_first + self.held]
+            stats = [jnp.max(here) * self.held
+                     / jnp.maximum(jnp.sum(here), 1.0),
+                     jnp.zeros((), jnp.float32), jnp.sum(here) / total]
+        return tops + stats[:len(self.lp.top) - self.n_fixed]
+
+
+class MoERouterLayer(Layer):
+    """ZAYA1's router (``models/moe.mlp_router``), a layer of its own so
+    that its time has a scope. Bottoms: the normed hidden state (N, S, D)
+    and, in every layer but the first, the router state (N, S, R) of the
+    layer before. Tops: this layer's router state (N, S, R) f32; the gates
+    (N, S, E) f32, the chosen expert's probability and zero elsewhere (what
+    the MOE layer after it takes); the selection bias's next value (E,).
+    Blobs: down (R, D), mix (R,) (only with the second bottom), w1, w2
+    (R, R), w3 (E, R), bias (E,). ``bias`` is a LAYER-UPDATED leaf
+    (``updates``): the step takes its next value from the third top and no
+    gradient, optimizer, decay or clip touches it."""
+    TYPE = "MOE_ROUTER"
+
+    def setup(self, bottom_shapes):
+        mp = self.lp.moe_param
+        n, s, d = bottom_shapes[0]
+        e, r = mp.num_experts, mp.router_hidden
+        if e <= 0 or r <= 0 or mp.top_k != 1:
+            raise ValueError(f"{self.name}: MOE_ROUTER needs num_experts, "
+                             f"router_hidden and top_k 1")
+        self.mixes = len(bottom_shapes) == 2
+        if len(bottom_shapes) > 2 or len(self.lp.top) != 3 or (
+                self.mixes and tuple(bottom_shapes[1]) != (n, s, r)):
+            raise ValueError(f"{self.name}: MOE_ROUTER takes (N, S, D) and "
+                             f"optionally (N, S, {r}), and has 3 tops; got "
+                             f"{bottom_shapes}, {len(self.lp.top)} tops")
+        zero = FillerParameter(type="constant", value=0.0)
+        shapes = [("down", (r, d), mp.weight_filler)] \
+            + ([("mix", (r,), zero)] if self.mixes else []) \
+            + [("w1", (r, r), mp.weight_filler),
+               ("w2", (r, r), mp.weight_filler),
+               ("w3", (e, r), mp.weight_filler), ("bias", (e,), zero)]
+        self.params = [self._param(name, shape, filler, i)
+                       for i, (name, shape, filler) in enumerate(shapes)]
+        self.params[-1] = dataclasses.replace(self.params[-1],
+                                              layer_updated=True)
+        self.updates = {"bias": 2}          # param -> the top it becomes
+        return [(n, s, r), (n, s, e), (e,)]
+
+    def default_loss_weight(self) -> float:
+        return 0.0
+
+    def apply(self, params, bottoms, ctx):
+        from ..models.moe import mlp_router
+        p = _tap_all(ctx, self.name, params)
+        n, s, d = bottoms[0].shape
+        r, gates, bias = mlp_router(
+            bottoms[0].reshape(n * s, d),
+            bottoms[1].reshape(n * s, -1) if self.mixes else None,
+            p["down"], p.get("mix"), p["w1"], p["w2"], p["w3"], p["bias"])
+        return [r.reshape(n, s, -1), gates.reshape(n, s, -1), bias]
+
+
+# CCA (compressed convolutional attention, arXiv:2510.04476): what happens
+# to q and k in the latent between their projections and the attention.
+
+def _shift_tokens(x, by: int = 1):
+    """x (N, S, ...) -> x moved ``by`` positions later, zeros in front."""
+    if by == 0:
+        return x
+    pad = [(0, 0), (by, 0)] + [(0, 0)] * (x.ndim - 2)
+    return jnp.pad(x[:, :x.shape[1] - by], pad)
+
+
+class TokenShiftLayer(Layer):
+    """(N, S, D) -> the same with every token's row replaced by the token
+    before's, zeros at position 0."""
+    TYPE = "TOKEN_SHIFT"
+
+    def setup(self, bottom_shapes):
+        return [bottom_shapes[0]]
+
+    def apply(self, params, bottoms, ctx):
+        return [_shift_tokens(bottoms[0])]
+
+
+class _CCALayer(Layer):
+    def _heads(self, bottom_shapes):
+        cp = self.lp.cca_param
+        (n, s, lq), (_, _, lk) = bottom_shapes[:2]
+        if cp.num_heads % cp.num_kv_heads or lq % cp.num_heads \
+                or lk * cp.num_heads != lq * cp.num_kv_heads \
+                or any(tuple(b) != tuple(bottom_shapes[i % 2])
+                       for i, b in enumerate(bottom_shapes)):
+            raise ValueError(
+                f"{self.name}: {self.TYPE} takes q (N, S, H d) and k "
+                f"(N, S, G d) with H = {cp.num_heads}, G = "
+                f"{cp.num_kv_heads}; got {bottom_shapes}")
+        self.h, self.g, self.d = cp.num_heads, cp.num_kv_heads, \
+            lq // cp.num_heads
+        return [tuple(bottom_shapes[0]), tuple(bottom_shapes[1])]
+
+
+class CCAConvLayer(_CCALayer):
+    """Bottoms q~ (N, S, H d), k~ (N, S, G d) -> q_c, k_c of the same
+    shapes: the two causal convolutions over the sequence of c = [q~, k~].
+    Depthwise, ``time0`` taps: c1_t = sum_j dw[j] * c_{t-j} + dw_b per
+    channel. Then grouped, ``time1`` taps, one group a head (H + G groups
+    of d channels): c2_t[g] = sum_j gw[j, g] c1_{t-j}[g] + gw_b[g], gw[j, g]
+    a (d, d) matrix (out, in). Blobs: dw (time0, C), dw_b (C), gw
+    (time1, H + G, d, d), gw_b (C)."""
+    TYPE = "CCA_CONV"
+
+    def setup(self, bottom_shapes):
+        tops = self._heads(bottom_shapes)
+        cp = self.lp.cca_param
+        c, groups = (self.h + self.g) * self.d, self.h + self.g
+        zero = FillerParameter(type="constant", value=0.0)
+        self.params = [
+            self._param("dw", (cp.time0, c), cp.weight_filler, 0),
+            self._param("dw_b", (c,), zero, 1),
+            self._param("gw", (cp.time1, groups, self.d, self.d),
+                        cp.weight_filler, 2),
+            self._param("gw_b", (c,), zero, 3)]
+        return tops
+
+    def apply(self, params, bottoms, ctx):
+        from ..config import matmul_precision, policy
+        p = _tap_all(ctx, self.name, params)
+        cp = self.lp.cca_param
+        q, k = bottoms
+        n, s, lq = q.shape
+        c = jnp.concatenate([q, k], axis=-1)
+        dt = policy().compute_dtype
+        c32 = c.astype(jnp.float32)
+        c1 = sum(p["dw"][j] * _shift_tokens(c32, j)
+                 for j in range(cp.time0)) + p["dw_b"]
+        c1 = c1.astype(dt).reshape(n, s, self.h + self.g, self.d)
+        # the taps side by side on the contracted axis: one (time1 d) x d
+        # product a group
+        taps = jnp.concatenate([_shift_tokens(c1, j)
+                                for j in range(cp.time1)], axis=-1)
+        w = jnp.concatenate([p["gw"][j] for j in range(cp.time1)],
+                            axis=-1).astype(dt)            # (G, d, time1 d)
+        c2 = jnp.einsum("nsgi,goi->nsgo", taps, w,
+                        precision=matmul_precision(),
+                        preferred_element_type=jnp.float32)
+        c2 = (c2.reshape(n, s, -1) + p["gw_b"]).astype(c.dtype)
+        return [c2[..., :lq], c2[..., lq:]]
+
+
+class CCAQKMeanLayer(_CCALayer):
+    """Bottoms q~, k~, q_c, k_c -> q, k: the q-k mean joins the convolved
+    latent. Per query head h of key-value group h // (H / G): m_q[h] =
+    (q~[h] + k~[h // (H / G)]) / 2; per group, m_k[g] = the mean of its
+    query heads' m_q. q = q_c + m_q, k = k_c + m_k."""
+    TYPE = "CCA_QKMEAN"
+
+    def setup(self, bottom_shapes):
+        if len(bottom_shapes) != 4:
+            raise ValueError(f"{self.name}: CCA_QKMEAN takes q~, k~, q_c, "
+                             f"k_c")
+        return self._heads(bottom_shapes)
+
+    def apply(self, params, bottoms, ctx):
+        q0, k0, qc, kc = bottoms
+        n, s, _ = q0.shape
+        per = self.h // self.g
+        q4 = q0.astype(jnp.float32).reshape(n, s, self.g, per, self.d)
+        k4 = k0.astype(jnp.float32).reshape(n, s, self.g, 1, self.d)
+        m_q = (q4 + k4) / 2
+        m_k = jnp.mean(m_q, axis=3)
+        return [(qc.astype(jnp.float32) + m_q.reshape(q0.shape))
+                .astype(qc.dtype),
+                (kc.astype(jnp.float32) + m_k.reshape(k0.shape))
+                .astype(kc.dtype)]
+
+
+class CCAQKNormLayer(_CCALayer):
+    """Bottoms q, k -> each head over its d dims divided by its L2 norm and
+    times sqrt(d) (x * rsqrt(mean(x^2) + eps), statistics in f32); k's
+    heads also times ``tau`` (G,), a learned temperature per key-value
+    head, 1 at start."""
+    TYPE = "CCA_QKNORM"
+
+    def setup(self, bottom_shapes):
+        tops = self._heads(bottom_shapes)
+        ones = FillerParameter(type="constant", value=1.0)
+        self.params = [self._param("tau", (self.g,), ones, 0)]
+        return tops
+
+    def apply(self, params, bottoms, ctx):
+        tau = _tap_all(ctx, self.name, params)["tau"]
+        eps = self.lp.cca_param.eps
+
+        def unit(x, heads, scale=None):
+            x32 = x.astype(jnp.float32).reshape(x.shape[:2] + (heads, self.d))
+            y = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+            if scale is not None:
+                y = y * scale.astype(jnp.float32)[:, None]
+            return y.reshape(x.shape).astype(x.dtype)
+
+        return [unit(bottoms[0], self.h), unit(bottoms[1], self.g, tau)]
 
 
 class SiLUGateLayer(Layer):
@@ -914,7 +1169,8 @@ REGISTRY: Dict[str, type] = {
     cls.TYPE: cls
     for cls in [
         ConvolutionLayer, InnerProductLayer, EmbedLayer, RMSNormLayer,
-        AttentionLayer, MoELayer, PoolingLayer, LRNLayer,
+        AttentionLayer, MoELayer, MoERouterLayer, TokenShiftLayer,
+        CCAConvLayer, CCAQKMeanLayer, CCAQKNormLayer, PoolingLayer, LRNLayer,
         Im2colLayer, ReLULayer, SigmoidLayer, TanHLayer, BNLLLayer,
         AbsValLayer, PowerLayer, ThresholdLayer, DropoutLayer, FlattenLayer,
         ConcatLayer, SliceLayer, SplitLayer, EltwiseLayer, MVNLayer,

@@ -74,6 +74,10 @@ class NetOutputs:
     loss: jax.Array
     outputs: Dict[str, jax.Array]
     blobs: Dict[str, jax.Array] = field(default_factory=dict)
+    # {layer: {param: next value}} of the layer-updated leaves
+    # (``Net.layer_updates``): what the step stores in place of an
+    # optimizer's result
+    updates: Dict[str, Dict[str, jax.Array]] = field(default_factory=dict)
 
 
 class Net:
@@ -173,7 +177,16 @@ class Net:
             for t in layer.lp.top:
                 if t not in produced:
                     produced.append(t)
-        self.output_names = [t for t in produced if t not in consumed]
+        # Layer-updated leaves: {(layer, param): the top that is its next
+        # value}. Such a leaf is state the layer keeps outside the gradient
+        # (a router's selection bias, balanced by the step's own loads);
+        # its top is the step's business, not an output of the net.
+        self.layer_updates: Dict[Tuple[str, str], str] = {
+            (layer.name, pname): layer.lp.top[top]
+            for layer in self.layers
+            for pname, top in getattr(layer, "updates", {}).items()}
+        self.output_names = [t for t in produced if t not in consumed
+                             and t not in self.layer_updates.values()]
 
         # Cross-layer weight sharing (the reference's named params,
         # layer.hpp / net.cpp shared-blob machinery; what siamese nets use):
@@ -193,6 +206,10 @@ class Net:
             owned: List[ParamDef] = []
             for i, pdef in enumerate(layer.params):
                 share_name = layer.lp.param_spec(i).name
+                if share_name and pdef.layer_updated:
+                    raise ValueError(
+                        f"layer {layer.name!r}: {pdef.name!r} is updated by "
+                        f"its layer and cannot be shared ({share_name!r})")
                 if share_name and share_name in shared_owner:
                     olayer, opname, odef = shared_owner[share_name]
                     spec = layer.lp.param_spec(i)
@@ -389,6 +406,12 @@ class Net:
                     # visited programs of its grid: stats.yaml carries them
                     arm = f"{arm} ({note})"
                     note = ""
+                ap = layer.lp.attention_param
+                if ap.num_kv_heads and ap.num_kv_heads != ap.num_heads:
+                    # grouped-query attention: which way the key-value
+                    # heads reach their query heads
+                    arm += (f"; {ap.num_kv_heads} kv heads repeated x"
+                            f"{ap.num_heads // ap.num_kv_heads}")
             elif layer.TYPE == "MOE":
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"
@@ -398,6 +421,14 @@ class Net:
             self.kernel_routes[layer.name] = f"{what}={arm}"
             log(f"[kernel_route] {layer.name}: {what} -> {arm}"
                 + (f" ({note})" if note else ""))
+
+    def expert_share(self) -> Dict[str, Dict[str, int]]:
+        """{MOE layer: which of the router's experts it holds} — stats.yaml's
+        ``expert_share`` section."""
+        return {l.name: {"held_first": l.lp.moe_param.held_first,
+                         "num_held": l.held,
+                         "router_num_experts": l.lp.moe_param.num_experts}
+                for l in self.layers if l.TYPE == "MOE"}
 
     def conv_strategy_plan(self) -> Dict[str, Optional[str]]:
         """{conv layer name: resolved strategy} — what bench/tests print."""
@@ -612,9 +643,14 @@ class Net:
 
         for name in self.output_names:
             outputs[name] = canonical(name)
+        updates: Dict[str, Dict[str, jax.Array]] = {}
+        for (lname, pname), top in self.layer_updates.items():
+            updates.setdefault(lname, {})[pname] = jax.lax.stop_gradient(
+                blobs[top])
         return NetOutputs(
             loss=loss, outputs=outputs,
-            blobs={k: canonical(k) for k in blobs} if keep_blobs else {})
+            blobs={k: canonical(k) for k in blobs} if keep_blobs else {},
+            updates=updates)
 
     # ------------------------------------------------------------------ #
     def load_weights(self, params, layer_weights: Dict[str, List[np.ndarray]],

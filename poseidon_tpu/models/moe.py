@@ -230,17 +230,72 @@ def _grouped_bwd(res, dy):
 _grouped_cast.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+def expert_sizes(experts: jax.Array, n_exp: int):
+    """(T, k) chosen experts -> (the same flat (T*k,), assignments per
+    expert (E,) int32)."""
+    flat_e = experts.reshape(-1)
+    return flat_e, jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1)
+
+
+def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
+               sizes: jax.Array, gate: jax.Array, up: jax.Array,
+               down: jax.Array, held_first: int = 0):
+    """The experts' part of a dropless MoE, shared by every router: x
+    (T, D), each token's k router ``weights`` (T, k), its experts flat
+    (T*k,) and ``sizes`` (E,), the assignments per expert over ALL E the
+    router scores (``expert_sizes``).
+    The T*k assignments are sorted by expert, each projection is ONE grouped
+    matmul over the sorted rows (``lax.ragged_dot``) and a token's k results
+    are summed with its weights.
+
+    The stacks hold experts ``held_first .. held_first + G - 1``, G =
+    ``gate.shape[0]``. G = E is a layer that owns every expert it routes
+    to. G < E is one rank's share of an expert-parallel layer: assignments
+    to an absent expert sort behind the held ones, lie outside every group
+    of the grouped matmuls (no work) and add ZERO to y, so that the shares
+    of the ranks sum to the whole layer's output."""
+    t, d = x.shape
+    top_k = weights.shape[1]
+    n_exp, n_held = sizes.shape[0], gate.shape[0]
+    if n_held == n_exp:
+        order = jnp.argsort(flat_e, stable=True)    # assignments by expert
+        xs = x[order // top_k]                      # (T*k, D) sorted rows
+        h = jax.nn.silu(_grouped(xs, gate, sizes)) * _grouped(xs, up, sizes)
+        out = _grouped(h, down, sizes)              # (T*k, D)
+    else:
+        local = flat_e - held_first
+        here = (local >= 0) & (local < n_held)
+        order = jnp.argsort(jnp.where(here, local, n_held), stable=True)
+        sizes = sizes[held_first:held_first + n_held]
+        # rows past the last group belong to no expert: what a grouped
+        # matmul leaves there is masked on the way in (so is their
+        # cotangent) and on the way out
+        live = (jnp.arange(t * top_k) < jnp.sum(sizes))[:, None]
+
+        def grouped(rows, w):
+            return jnp.where(live, _grouped(jnp.where(live, rows, 0), w,
+                                            sizes), 0)
+
+        xs = x[order // top_k]
+        h = jax.nn.silu(grouped(xs, gate)) * grouped(xs, up)
+        out = grouped(h, down)
+        weights = weights * here.reshape(t, top_k)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * top_k))
+    out = out[back].reshape(t, top_k, d)
+    y = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1)
+    return y.astype(x.dtype)
+
+
 def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,
-                 up: jax.Array, down: jax.Array, top_k: int):
+                 up: jax.Array, down: jax.Array, top_k: int,
+                 held_first: int = 0):
     """Top-k token-choice MoE over flat tokens x (T, D), no capacity: every
     token is computed by all k of its experts whatever the load.
 
-    router (E, D); gate, up (E, F, D); down (E, D, F). The T*k assignments
-    are sorted by expert, each projection is ONE grouped matmul over the
-    sorted rows (``lax.ragged_dot``), and the k results of a token
-    are summed with its router weights. Returns (y (T, D), load-balancing
-    loss, z loss, assignments per expert (E,) int32)."""
-    t, d = x.shape
+    router (E, D) scores all E experts; gate, up (G, F, D) and down
+    (G, D, F) are the G experts held here (``expert_ffn``; G = E: all).
+    Returns (y (T, D), load-balancing loss, z loss, assignments per expert
+    (E,) int32)."""
     n_exp = router.shape[0]
     # the router runs in f32 whatever the policy: top-k is discontinuous,
     # and a bf16 logit flips which experts a token gets
@@ -248,18 +303,62 @@ def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,
                              (((1,), (1,)), ((), ())),
                              precision=lax.Precision.HIGHEST)
     probs, weights, experts = topk_route(logits, top_k)
-    flat_e = experts.reshape(-1)                    # (T*k,)
-    sizes = jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1)
+    flat_e, sizes = expert_sizes(experts, n_exp)
     lb, z = router_losses(logits, probs, sizes)
+    y = expert_ffn(x, weights, flat_e, sizes, gate, up, down, held_first)
+    return y, lb, z, sizes
 
-    order = jnp.argsort(flat_e, stable=True)        # assignments by expert
-    xs = x[order // top_k]                          # (T*k, D) sorted rows
-    h = jax.nn.silu(_grouped(xs, gate, sizes)) * _grouped(xs, up, sizes)
-    out = _grouped(h, down, sizes)                  # (T*k, D)
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * top_k))
-    out = out[back].reshape(t, top_k, d)
-    y = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1)
-    return y.astype(x.dtype), lb, z, sizes
+
+def moe_gated(x: jax.Array, gates: jax.Array, gate: jax.Array, up: jax.Array,
+              down: jax.Array, top_k: int, held_first: int = 0):
+    """``moe_dropless`` behind a router that is a layer of its own
+    (``mlp_router``): ``gates`` (T, E) f32 hold each token's k chosen
+    experts' weights and zero elsewhere. Returns (y, assignments per expert
+    (E,) int32)."""
+    weights, experts = lax.top_k(gates, top_k)
+    flat_e, sizes = expert_sizes(experts, gates.shape[1])
+    return expert_ffn(x, weights, flat_e, sizes, gate, up, down,
+                      held_first), sizes
+
+
+# the step u of mlp_router's balancing rule (the aux-loss-free sign rule at
+# DeepSeek-V3's rate); a constant until a configuration states a second value
+BIAS_UPDATE_RATE = 0.001
+
+
+def mlp_router(h: jax.Array, r_prev: Optional[jax.Array], down: jax.Array,
+               mix: jax.Array, w1: jax.Array, w2: jax.Array, w3: jax.Array,
+               bias: jax.Array, rate: float = BIAS_UPDATE_RATE):
+    """ZAYA1's router (arXiv:2511.17127) over flat tokens h (T, D), in f32
+    whatever the policy (top-1 is discontinuous):
+
+        r = h down^T + mix * r_prev              (T, R); r_prev: the layer
+                                                  before's r, None = zero
+        s = w3 gelu(w2 gelu(w1 r))               (T, E)
+        p = softmax(s);  e(t) = argmax_e (p_e + bias_e)
+
+    ``bias`` (E,) takes no gradient; it is balanced by the step's own
+    loads: bias_e + rate * sign(T / E - n_e), n_e the tokens that chose e.
+    Returns (r, gates (T, E) = p at the chosen expert and zero elsewhere,
+    the bias's next value)."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+
+    def mm(a, w):                                   # a (T, in), w (out, in)
+        return lax.dot_general(a, w.astype(f32), (((1,), (1,)), ((), ())),
+                               precision=hi)
+
+    r = mm(h.astype(f32), down)
+    if r_prev is not None:
+        r = r + mix.astype(f32) * r_prev.astype(f32)
+    s = mm(jax.nn.gelu(mm(jax.nn.gelu(mm(r, w1), approximate=False), w2),
+                       approximate=False), w3)
+    p = jax.nn.softmax(s, axis=-1)
+    bias = lax.stop_gradient(bias.astype(f32))
+    n_exp = p.shape[1]
+    chosen = jax.nn.one_hot(jnp.argmax(p + bias, axis=-1), n_exp, dtype=f32)
+    load = jnp.sum(chosen, axis=0)
+    bias_next = bias + rate * jnp.sign(p.shape[0] / n_exp - load)
+    return r, p * chosen, bias_next
 
 
 def moe_forward(params: Dict, cfg: MoEConfig, tokens: jax.Array,

@@ -146,21 +146,38 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
-                   rope_theta: float = 10000.0) -> jax.Array:
-    """q, k, v (B, S, D) -> (B, S, D): split into ``n_heads`` heads, rotary
-    positions on q and k, causal softmax(q k^T / sqrt(Dh)) v, heads merged. The
-    Pallas flash kernel where the sequence tiles (``maybe_flash_attention``),
-    the dense op elsewhere."""
+                   rope_theta: float = 10000.0, n_kv_heads: int = 0,
+                   rotary_dims: int = 0) -> jax.Array:
+    """q (B, S, D), k and v (B, S, Dkv) -> (B, S, D): q split into
+    ``n_heads`` heads, k and v into ``n_kv_heads`` of the same width (0 =
+    ``n_heads``, Dkv = D), rotary positions on q and k, causal
+    softmax(q k^T / sqrt(Dh)) v, heads merged. With fewer key-value heads
+    query head h reads key-value head h // (n_heads / n_kv_heads): k and v
+    are repeated to the query heads before the kernel (four copies of an
+    (S, 128) head cost microseconds; their gradients sum in autodiff).
+    ``rotary_dims`` (0 = the whole head): only the first that many dims of
+    a head rotate, the rest pass. The Pallas flash kernel where the sequence
+    tiles (``maybe_flash_attention``), the dense op elsewhere."""
     b, s, d = q.shape
     d_head = d // n_heads
+    n_kv = n_kv_heads or n_heads
 
-    def heads(t):
-        return t.reshape(b, s, n_heads, d_head).swapaxes(1, 2)
+    def heads(t, n):
+        return t.reshape(b, s, n, d_head).swapaxes(1, 2)
 
-    cos, sin = rope_tables(s, d_head, rope_theta)
-    att = maybe_flash_attention(apply_rope(heads(q), cos, sin),
-                                apply_rope(heads(k), cos, sin), heads(v),
-                                causal=True)
+    rot = rotary_dims or d_head
+    cos, sin = rope_tables(s, rot, rope_theta)
+
+    def rope(t):
+        if rot == d_head:
+            return apply_rope(t, cos, sin)
+        return jnp.concatenate([apply_rope(t[..., :rot], cos, sin),
+                                t[..., rot:]], axis=-1)
+
+    q, k, v = rope(heads(q, n_heads)), rope(heads(k, n_kv)), heads(v, n_kv)
+    if n_kv != n_heads:
+        k, v = (jnp.repeat(t, n_heads // n_kv, axis=1) for t in (k, v))
+    att = maybe_flash_attention(q, k, v, causal=True)
     return att.swapaxes(1, 2).reshape(b, s, d)
 
 

@@ -610,3 +610,130 @@ def ouro(batch: int = 1, source: str = "examples/lm/ouro_tokens.txt",
                              for t in range(1, passes + 1)],
         exit_loss_param=ExitLossParameter(entropy_weight=entropy_weight)))
     return NetParameter(name=name, layers=layers)
+
+
+def zaya1(batch: int = 1, source: str = "examples/lm/zaya1_tokens.txt",
+          n_layers: int = 40, hidden: int = 2048, heads: int = 8,
+          kv_heads: int = 2, head_dim: int = 128, experts: int = 16,
+          held: int = 0, held_first: int = 0, expert_width: int = 2048,
+          router_hidden: int = 256, vocab: int = 262272,
+          rope_theta: float = 5e6, rotary: float = 0.5, time0: int = 2,
+          time1: int = 2, eps: float = 1e-5, init_std: float = 0.02,
+          name: str = "ZAYA1-8B") -> NetParameter:
+    """ZAYA1 (arXiv:2511.17127): every layer is CCA attention
+    (arXiv:2510.04476) and a top-1 MoE behind an MLP router, both pre-norm.
+
+    CCA: q~ (``heads`` x ``head_dim``) and k~ (``kv_heads`` x ``head_dim``)
+    are projections of the normed state into a latent narrower than the
+    model; v is two half-width projections side by side, the second of the
+    token BEFORE (``l<i>_cca_shift``); [q~, k~] pass two causal
+    convolutions over the sequence (``l<i>_cca_conv``), take the q-k mean
+    (``l<i>_cca_qkmean``) and an L2 norm per head with a learned
+    temperature on k (``l<i>_cca_qknorm``); grouped-query ATTENTION with
+    rotary positions on ``rotary`` of a head; ``l<i>_o`` leaves the latent.
+
+    MoE: ``l<i>_router`` (MOE_ROUTER) scores all ``experts`` from the
+    normed state and the router state of the layer before, and keeps a
+    selection bias it balances itself; ``l<i>_moe`` holds ``held`` of the
+    experts from ``held_first`` on (0 = all): with fewer than all, the
+    net is one rank's share of an expert-parallel model. The embedding's
+    table is the head's (one leaf, ``tok_w``). Gains, biases, ``tau`` and
+    the router's ``mix`` carry decay_mult 0, every matrix 1."""
+    from ..proto.messages import (AttentionParameter, CCAParameter,
+                                  ConcatParameter, EltwiseParameter,
+                                  EmbedParameter, HDF5DataParameter,
+                                  MoEParameter, RMSNormParameter)
+    w = gaussian(init_std)
+    lq, lk = heads * head_dim, kv_heads * head_dim
+    layers: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+    no_decay = ParamSpec(lr_mult=1.0, decay_mult=0.0)
+
+    def norm(lname, bottom, top):
+        layers.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[no_decay], rms_norm_param=RMSNormParameter(eps=eps)))
+
+    def proj(lname, bottom, top, n_out, spec=()):
+        layers.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            param=list(spec), inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2, weight_filler=w)))
+
+    def add(lname, a, b, top):
+        layers.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation="SUM")))
+
+    def cca(lname, kind, bottoms, tops, spec=()):
+        layers.append(LayerParameter(
+            name=lname, type=kind, bottom=bottoms, top=tops,
+            param=list(spec), cca_param=CCAParameter(
+                num_heads=heads, num_kv_heads=kv_heads, time0=time0,
+                time1=time1, eps=eps, weight_filler=w)))
+
+    tied = [ParamSpec(name="tok_w")]
+    layers.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["x0"],
+        param=tied, embed_param=EmbedParameter(
+            input_dim=vocab, num_output=hidden, weight_filler=w)))
+    x = "x0"
+    for i in range(n_layers):
+        p = f"l{i}_"
+        norm(p + "attn_norm", x, p + "a")
+        proj(p + "q", p + "a", p + "q", lq)
+        proj(p + "k", p + "a", p + "k", lk)
+        proj(p + "v1", p + "a", p + "v1", lk // 2)
+        layers.append(LayerParameter(
+            name=p + "cca_shift", type="TOKEN_SHIFT", bottom=[p + "a"],
+            top=[p + "as"]))
+        proj(p + "v2", p + "as", p + "v2", lk // 2)
+        layers.append(LayerParameter(
+            name=p + "cca_vcat", type="CONCAT",
+            bottom=[p + "v1", p + "v2"], top=[p + "v"],
+            concat_param=ConcatParameter(concat_dim=2)))
+        cca(p + "cca_conv", "CCA_CONV", [p + "q", p + "k"],
+            [p + "qc", p + "kc"],
+            [ParamSpec(), no_decay, ParamSpec(), no_decay])
+        cca(p + "cca_qkmean", "CCA_QKMEAN",
+            [p + "q", p + "k", p + "qc", p + "kc"], [p + "qm", p + "km"])
+        cca(p + "cca_qknorm", "CCA_QKNORM", [p + "qm", p + "km"],
+            [p + "qn", p + "kn"], [no_decay])
+        layers.append(LayerParameter(
+            name=p + "attn", type="ATTENTION",
+            bottom=[p + "qn", p + "kn", p + "v"], top=[p + "att"],
+            attention_param=AttentionParameter(
+                num_heads=heads, rope_theta=rope_theta,
+                num_kv_heads=kv_heads,
+                rotary_dims=int(round(rotary * head_dim)))))
+        proj(p + "o", p + "att", p + "ao", hidden)
+        add(p + "res1", x, p + "ao", p + "h")
+        norm(p + "moe_norm", p + "h", p + "u")
+        moe = dict(num_experts=experts, top_k=1, expert_width=expert_width,
+                   router_hidden=router_hidden, weight_filler=w)
+        first = i == 0            # no router state before the first layer
+        layers.append(LayerParameter(
+            name=p + "router", type="MOE_ROUTER",
+            bottom=[p + "u"] + ([] if first else [f"l{i - 1}_r"]),
+            top=[p + "r", p + "gates", p + "bias_next"],
+            param=[ParamSpec()] + ([] if first else [no_decay])
+            + [ParamSpec(), ParamSpec(), ParamSpec(), no_decay],
+            moe_param=MoEParameter(**moe)))
+        layers.append(LayerParameter(
+            name=p + "moe", type="MOE", bottom=[p + "u", p + "gates"],
+            top=[p + "m", p + "expert_load", p + "dropped",
+                 p + "held_share"],
+            moe_param=MoEParameter(num_held=held, held_first=held_first,
+                                   **moe)))
+        add(p + "res2", p + "h", p + "m", p + "y")
+        x = p + "y"
+    norm("final_norm", x, "xf")
+    proj("lm_head", "xf", "logits", vocab, tied)
+    layers.append(LayerParameter(
+        name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
+        top=["nll"]))
+    # the mean over positions: the exit-weighted loss of ONE pass
+    layers.append(LayerParameter(
+        name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    return NetParameter(name=name, layers=layers)

@@ -747,10 +747,12 @@ def build_spmd_train_step(
 
     from ..solvers.updates import (SolverState, _leafwise_update,
                                    learning_rate, make_flat_update_rule)
-    from .trainer import TrainState, TrainStep, param_mults
+    from .trainer import (TrainState, TrainStep, param_mults,
+                          refuse_layer_updates)
 
     comm = comm or CommConfig()
     comm.wire_jnp_dtype()
+    refuse_layer_updates(net, "--mesh")
     if sp.solver_type == "ADAM" or sp.clip_gradients > 0:
         raise ValueError("solver_type ADAM and clip_gradients run on the "
                          "flat data mesh only: the fsdp-sharded update "

@@ -40,6 +40,18 @@ def param_mults(net: Net) -> Dict[str, Dict[str, tuple]]:
     }
 
 
+def refuse_layer_updates(net: Net, where: str) -> None:
+    """A leaf its layer updates from the step's own statistics (MOE_ROUTER's
+    selection bias) is one device's, one batch's: every device would balance
+    its own loads and the replicas would part. Refused by name until the
+    statistics are summed across the mesh (ROADMAP M2)."""
+    if net.layer_updates:
+        leaves = sorted("/".join(k) for k in net.layer_updates)
+        raise ValueError(
+            f"layer-updated leaves {leaves} run on one device with "
+            f"iter_size 1 only, not under {where}")
+
+
 class TrainState(NamedTuple):
     """Replicated per-step carry: solver state + managed-comm residuals.
 
@@ -251,6 +263,9 @@ def build_train_step(
     axes = comm.sync_axes  # (dcn, data) or (data,)
     update_fn = make_update_fn(sp, param_mults(net))
     n_total = int(np.prod([mesh.shape[a] for a in axes]))
+    if n_total > 1 or iter_size > 1:
+        refuse_layer_updates(net, f"{n_total} devices, iter_size "
+                                  f"{iter_size}")
 
     for lname in net.param_defs:
         if comm.strategy_for(lname) == LOCAL:
@@ -430,7 +445,9 @@ def build_train_step(
                 grads[lname][pname] = g_sync
                 lerr[pname] = resid[None]
             new_errors[lname] = lerr
-        new_params, new_solver = update_fn(params, grads, state.solver)
+        new_params, new_solver = update_fn(
+            params, grads, state.solver,
+            out.updates if net.layer_updates else None)
         metrics = {name: lax.psum(val.astype(jnp.float32), axes) / n_total
                    for name, val in out_scalars.items()}
         dumps = ({b: out.blobs[b] for b in (dump_blobs or ())}
@@ -659,6 +676,7 @@ def build_ssp_train_step(
     it runs each FC layer at effective staleness 0).
     """
     import dataclasses
+    refuse_layer_updates(net, "--staleness")
     if sp.solver_type == "ADAM" or sp.clip_gradients > 0:
         raise ValueError("solver_type ADAM and clip_gradients are not "
                          "supported under SSP staleness (per-device "

@@ -295,13 +295,41 @@ class RMSNormParameter:
 class AttentionParameter:
     num_heads: int = 1
     rope_theta: float = 10000.0
+    # grouped-query attention: k and v carry this many heads and query head
+    # h reads key-value head h // (num_heads / num_kv_heads); 0 = num_heads
+    num_kv_heads: int = 0
+    # rotary positions on the first ``rotary_dims`` of every head, the rest
+    # pass as they are; 0 = the whole head
+    rotary_dims: int = 0
 
 
 @dataclass
 class MoEParameter:
+    """MOE and MOE_ROUTER. ``num_experts`` is what the router scores;
+    ``num_held`` (0 = all) of them, from ``held_first`` on, have their
+    weights in this layer: one rank's share of an expert-parallel layer.
+    ``router_hidden`` 0 = the router is one (E, D) matrix inside MOE; > 0 =
+    a MOE_ROUTER layer of that width scores and MOE takes its gates."""
     num_experts: int = 0
     top_k: int = 1
     expert_width: int = 0
+    weight_filler: FillerParameter = field(default_factory=FillerParameter)
+    num_held: int = 0
+    held_first: int = 0
+    router_hidden: int = 0
+
+
+@dataclass
+class CCAParameter:
+    """The CCA_* layers (compressed convolutional attention, arXiv:
+    2510.04476): q of ``num_heads`` and k of ``num_kv_heads`` heads in one
+    latent; CCA_CONV's depthwise kernel spans ``time0`` positions and its
+    per-head grouped kernel ``time1``, both causal."""
+    num_heads: int = 1
+    num_kv_heads: int = 1
+    time0: int = 2
+    time1: int = 2
+    eps: float = 1e-5
     weight_filler: FillerParameter = field(default_factory=FillerParameter)
 
 
@@ -426,6 +454,9 @@ V2_TYPE_TO_V1 = {
     "Threshold": "THRESHOLD", "Embed": "EMBED", "RMSNorm": "RMS_NORM",
     "Attention": "ATTENTION", "MoE": "MOE", "SiLUGate": "SILU_GATE",
     "SoftmaxNLL": "SOFTMAX_NLL", "ExitLoss": "EXIT_LOSS",
+    "TokenShift": "TOKEN_SHIFT", "CCAConv": "CCA_CONV",
+    "CCAQKMean": "CCA_QKMEAN", "CCAQKNorm": "CCA_QKNORM",
+    "MoERouter": "MOE_ROUTER",
 }
 V1_TYPES = set(V2_TYPE_TO_V1.values()) | {"NONE"}
 
@@ -487,6 +518,7 @@ class LayerParameter:
     attention_param: AttentionParameter = field(default_factory=AttentionParameter)
     moe_param: MoEParameter = field(default_factory=MoEParameter)
     exit_loss_param: ExitLossParameter = field(default_factory=ExitLossParameter)
+    cca_param: CCAParameter = field(default_factory=CCAParameter)
     blob_mode: str = "GLOBAL"  # Poseidon extension on LayerParameter level
 
     def canonical_type(self) -> str:
@@ -717,8 +749,15 @@ def to_node(msg: Any) -> Node:
         value = getattr(msg, f.name)
         if isinstance(value, list):
             if f.name == "param" and cls_name == "LayerParameter":
-                for p in value:
-                    emit("param", p)
+                # specs are read back by position: a default one (an empty
+                # node) is dropped only behind the last that says something
+                nodes = [to_node(p) if dataclasses.is_dataclass(p) else p
+                         for p in value]
+                while nodes and isinstance(nodes[-1], Node) \
+                        and not nodes[-1].fields:
+                    nodes.pop()
+                for p in nodes:
+                    node.add("param", p)
                 continue
             for v in value:
                 emit(f.name, v)

@@ -530,6 +530,9 @@ class Engine:
             self.stats.set_section("shared_params", {
                 name: f"{d['owner']} x{d['uses']}" for name, d in
                 self.train_net.shared_params.items()})
+        expert_share = self.train_net.expert_share()
+        if expert_share:
+            self.stats.set_section("expert_share", expert_share)
         self.stats.set_section("data_reader", {
             p.tops[0]: ("native" if getattr(p, "native", None) is not None
                         else "python")
@@ -583,8 +586,12 @@ class Engine:
                          shard: Optional[Shard] = None):
         # Each host produces only its addressable devices' rows; the pipeline
         # shards the record space across hosts (shared_file_system-style).
+        # (the MESH's devices on this host: a mesh narrower than the host,
+        # '--mesh dp1' on four chips, feeds that many and no more)
+        local = sum(d.process_index == jax.process_index()
+                    for d in self.mesh.devices.flat)
         return build_phase_pipelines(
-            net_param, phase, batch_multiplier=jax.local_device_count(),
+            net_param, phase, batch_multiplier=local,
             shard=shard if shard is not None else self._data_shard,
             memory_data=self.memory_data,
             device_transform=(self._device_transform and phase == "TRAIN"))

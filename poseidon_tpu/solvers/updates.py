@@ -110,12 +110,35 @@ def _regularized(g, w, local_decay: float, reg_type: str):
     raise ValueError(f"unknown regularization_type {reg_type!r}")
 
 
+def _without(tree, taken):
+    """``tree`` ({layer: {param: x}}) less the leaves ``taken`` names."""
+    return {lname: {p: x for p, x in leaves.items()
+                    if p not in taken.get(lname, ())}
+            for lname, leaves in tree.items()}
+
+
 def _leafwise_update(sp: SolverParameter, mults, rate, params, grads,
-                     history, it=None, scale=None):
+                     history, it=None, scale=None, layer_updates=None):
     """One optimizer step over a per-leaf tree: one elementwise fusion per
     leaf, in whatever layout the compiler keeps it. ``it`` (ADAM's bias
     correction) and ``scale`` (the clip factor) are the caller's: both
-    span the whole parameter set, which ``params`` need not be."""
+    span the whole parameter set, which ``params`` need not be.
+    ``layer_updates`` ({layer: {param: next value}}): leaves their layer
+    updates itself. They take that value; no rule, decay or clip touches
+    them and their history stays as it is."""
+    if layer_updates:
+        new_params, new_hist = _leafwise_update(
+            sp, mults, rate, _without(params, layer_updates), grads,
+            history, it, scale)
+        trees = [(new_hist[k], history[k]) for k in ("m", "v")] \
+            if sp.solver_type == "ADAM" else [(new_hist, history)]
+        for lname, leaves in layer_updates.items():
+            for pname, value in leaves.items():
+                new_params[lname][pname] = value.astype(
+                    params[lname][pname].dtype)
+                for new, old in trees:
+                    new[lname][pname] = old[lname][pname]
+        return new_params, new_hist
     solver_type = sp.solver_type
     if solver_type == "ADAM":
         t = (it + 1).astype(jnp.float32)
@@ -174,15 +197,17 @@ def make_update_fn(sp: SolverParameter, mults: Dict[str, Dict[str, tuple]]):
     ``mults`` maps layer -> param name -> (lr_mult, decay_mult), from the
     net's ParamDefs (the reference's blobs_lr / weight_decay lists).
     """
-    def update(params, grads, state: SolverState):
+    def update(params, grads, state: SolverState, layer_updates=None):
         # scoped so one profiled step attributes the whole optimizer pass
         # as "optimizer_update" instead of leaking per-leaf fusions into
         # the attribution residual (runtime/attribution.py)
         with jax.named_scope("optimizer_update"):
             rate = learning_rate(sp, state.it)
+            if layer_updates:           # outside the clip's norm
+                grads = _without(grads, layer_updates)
             new_params, new_hist = _leafwise_update(
                 sp, mults, rate, params, grads, state.history, state.it,
-                clip_scale(sp, grads))
+                clip_scale(sp, grads), layer_updates)
             return new_params, SolverState(it=state.it + 1, history=new_hist)
 
     return update

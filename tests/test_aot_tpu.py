@@ -388,6 +388,68 @@ def test_ouro_full_width_step_fits_one_v5e_at_its_depth_and_no_deeper(deeper):
         assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9
 
 
+# The full-width ZAYA1 train step (examples/lm/zaya1_8b_*: 8 of 16 experts
+# held, an eighth of the tied vocabulary) as `train --bf16 --remat <the solver
+# header's flags>` builds it at the cell's two sequences of 8,192, for one
+# abstract v5e chip: the compiler's memory accounting that fixed the
+# configuration's depth (benchmark/configs/zaya1_8b.json,
+# benchmark/cells/zaya1.e8of16.pack8k.json), at the depth in the files and one
+# layer deeper.
+_ZAYA_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 2, 8192, {deeper}").replace(
+    "ouro_2_6b_solver", "zaya1_8b_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers)').replace(
+    "zoo.ouro(batch=batch, n_layers=depth + deeper)",
+    "zoo.zaya1(batch=batch, n_layers=depth + deeper, held=8, vocab=32784)")
+assert _ZAYA_STEP.count("zaya1") == 2 and "ouro" not in _ZAYA_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("deeper", [0, 1])
+def test_zaya_full_width_step_fits_one_v5e_at_its_depth_and_no_deeper(deeper):
+    """At the depth of the example (and of the cell) the step with one
+    checkpoint a layer is under 85% of the 16.9 GB the compiler allows
+    (PR 22's sizing rule) with two sequences of 8,192; one layer deeper it
+    is over. The tied table is one leaf, the routers' selection biases are
+    leaves like any other (weight and two moments in the arguments), the
+    flash kernels run forward, replayed forward, dQ and dK/dV in every
+    layer at 8 query heads (k and v repeated to them), and the three
+    grouped matmuls of every MOE layer run over the held experts' rows."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _ZAYA_STEP.format(repo=REPO, deeper=deeper)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next depth
+    depth = got["depth"]
+    assert depth == 7 + deeper
+    assert got["parameters"] == 67_143_680 - 256 + depth * 106_902_802
+    # embed, final norm; a layer: 2 gains, q k v1 v2 o, 4 conv blobs, tau,
+    # 3 stacks, the router's 6 (5 in the first layer)
+    assert got["leaves"] == 2 + 21 * depth - 1
+    assert got["segments"] == depth + 1
+    assert got["routes"] == [
+        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
+        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "head); 2 kv heads repeated x4", "grouped_matmul=ragged_dot"]
+    # 4 flash calls and 15 grouped matmuls (3 forward, 3 replayed, 9
+    # backward) a layer
+    assert got["pallas_custom_calls"] == 19 * depth
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    if deeper:
+        assert got["total_gb"] > 0.85 * 16.9
+    else:
+        assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9
+
+
 # AlexNet's train step as `train --bf16` builds it at the benchmark's batch,
 # for ONE abstract v5e chip: with nobody to all-reduce with, the step builder
 # packs nothing (PR 26) — no arena scope, no buffer-length array or constant,

@@ -83,6 +83,25 @@ def test_engine_end_to_end(tmp_path):
         eng.close()
 
 
+@pytest.mark.parametrize("devices", [1, 2])
+def test_engine_feeds_its_mesh_not_the_host(tmp_path, devices):
+    """A mesh narrower than the host (1 or 2 of the 8 CPU devices): every
+    step takes batch_size rows for each of the MESH's devices, not for each
+    of the host's, and trains."""
+    from poseidon_tpu.parallel import make_mesh
+    from poseidon_tpu.proto.messages import load_solver
+    from poseidon_tpu.runtime.engine import Engine
+
+    sp = load_solver(_write_mnistish_prototxt(tmp_path, max_iter=2))
+    eng = Engine(sp, memory_data=_memory_data(), output_dir=str(tmp_path),
+                 mesh=make_mesh(devices))
+    try:
+        assert [p.batch_size for p in eng.train_pipelines] == [8 * devices]
+        assert np.isfinite(eng.train()["loss"])
+    finally:
+        eng.close()
+
+
 def test_engine_snapshot_restore(tmp_path):
     from poseidon_tpu.proto.messages import load_solver
     from poseidon_tpu.runtime.engine import Engine
