@@ -394,7 +394,12 @@ class Net:
                 arm, note = NN.pool_bwd_route(layer.kernel)
             elif layer.TYPE == "LRN" and layer.region == "ACROSS_CHANNELS":
                 what = "lrn"
-                arm, note = lrn_route(shape[2] * shape[3], shape[1])
+                arm, note = lrn_route(
+                    shape[2] * shape[3], shape[1], shape[0],
+                    jnp.dtype(policy().compute_dtype).itemsize)
+                if arm == "pallas":
+                    # the orientation and block the kernels run with
+                    arm, note = f"{arm} ({note})", ""
             elif layer.TYPE == "ATTENTION":
                 what = "attention"
                 arm, note = attention_route(

@@ -407,8 +407,10 @@ def _ave_pool_ref(x, kernel, stride, pad, layout: str = "NCHW"):
 # ---- pooling backward strategies ------------------------------------------ #
 #
 # Two formulations, one per backend, each measured where it runs. On the
-# v5e the step keeps activations channel-minor (`{1,0,3,2}`: C on the lanes,
-# N on the sublanes, H and W major), where XLA's own select-and-scatter
+# v5e the compiler lays the step's activations out itself (batch-minor,
+# `{0,1,3,2}`: N on the lanes, C on the sublanes, H and W major, at a
+# per-chip batch that fills the lanes; channel-minor `{1,0,3,2}` below
+# that and for pool2's 256 channels), where XLA's own select-and-scatter
 # costs 8.65 ms of an AlexNet step of 68.9 and 9.91 ms of a GoogLeNet step
 # of 50.6 (bf16, 512 / 128 images; PERF.md, PR 24), against 42.2 / 42.5 ms
 # for the tap-sum, whose k*k interior pads do not fuse there. A custom call

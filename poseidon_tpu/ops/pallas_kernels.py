@@ -9,10 +9,10 @@ itemsize. The backward pass is likewise Pallas and O(S) in HBM: the
 dq and dk/dv kernels below recompute scores blockwise from the saved
 (out, logsumexp) residuals, wired up via ``defvjp``.
 
-``lrn_fused`` / ``lrn_fused_bwd``: cross-channel LRN in one VMEM pass per
-(H*W)-tile, forward and analytic backward, in both layouts. The default
-path on TPU (``lrn_route``; ``POSEIDON_PALLAS_LRN=0`` opts back out) with
-the XLA formulation on the CPU test mesh and beyond the VMEM tiling cap.
+``lrn_fused`` / ``lrn_fused_bwd``: cross-channel LRN, forward and analytic
+backward, in the orientation the compiled step holds the activation in
+(``_lrn_tile``). The default on TPU (``lrn_route``; ``POSEIDON_PALLAS_LRN=0``
+opts out), the XLA formulation on the CPU mesh and beyond the VMEM cap.
 
 Kernels run in interpret mode on the CPU test mesh so it exercises the same
 code path; any backend other than tpu/cpu is refused (``_interpret_default``).
@@ -611,158 +611,209 @@ def maybe_flash_attention(q, k, v, causal: bool = False,
 # Fused cross-channel LRN
 # --------------------------------------------------------------------------- #
 
-def _lrn_kernel(x_ref, o_ref, *, local_size: int, alpha: float, beta: float,
-                k: float, channels: int, channel_axis: int = 0):
-    """One LRN tile. ``channel_axis`` selects the block orientation:
-    0 = (C, T) channels x spatial tile (NCHW), 1 = (T, C) spatial tile x
-    channels (NHWC — the channel window then runs over the MINOR axis,
-    matching the net-level channels-last plan so the kernel needs no
-    operand layout change at its custom-call boundary)."""
-    x = x_ref[0].astype(jnp.float32)
-    pre = (local_size - 1) // 2
-    sq = x * x
-    pads = [(0, 0), (0, 0)]
-    pads[channel_axis] = (pre, local_size - pre - 1)
-    padded = jnp.pad(sq, pads)
-    windowed = jnp.zeros_like(sq)
-    for dc in range(local_size):
-        windowed = windowed + lax.slice_in_dim(padded, dc, dc + channels,
-                                               axis=channel_axis)
-    scale = k + (alpha / local_size) * windowed
-    o_ref[0] = (x * scale ** (-beta)).astype(o_ref.dtype)
+# About what one operand block holds: the backward's x, g and dx, each
+# double-buffered, stay well under the 16 MiB of scoped VMEM a kernel gets.
+_LRN_BLOCK_BYTES = 2 ** 20
+# What one trip of the in-kernel loop works on, in f32 vregs of 8 x 128: a
+# quarter of the 64 (x, g, the window sum and the powers are live together).
+_LRN_PIECE_VREGS = 16
 
 
 class LRNTileError(ValueError):
-    """No VMEM-legal spatial tiling exists for this channel count."""
+    """No VMEM-legal block exists for this channel count."""
 
 
-def _lrn_tile(hw: int, want: int, channels: int) -> tuple:
-    """(tile, padded_hw): a lane-legal spatial tiling. Mosaic requires the
-    block's minor dim to be a multiple of 128 OR the full array dim, and
-    one-tile-per-image VMEM-OOMs at GoogLeNet's norm2 scale (192 x 3136
-    bf16 + temps = 24.6 MB vs the 16 MB scoped limit — caught by the AOT
-    Mosaic gate, evidence/aot_tpu). Preference order, by the cost model:
+def _lrn_tile(hw: int, channels: int, batch: int, itemsize: int,
+              want: Optional[int] = None) -> tuple:
+    """``(channel_axis, block, rows)``: how one LRN geometry is handed to
+    the kernels — a function of the shape alone, as ``flash_blocks`` is.
 
-    1. the FULL spatial extent when its working set fits VMEM (always
-       layout-legal, zero pad/copy overhead — padding to lane multiples
-       measured +32% est. cycles on AlexNet's norms);
-    2. otherwise a 128-multiple tile with the extent padded up and the
-       pad sliced off after. LRN windows run over CHANNELS only, so zero
-       spatial padding is inert (scale = k > 0).
+    The operand is the logical transpose with the pixels leading.
+    ``channel_axis`` 1 is ``(HW, C, N)``, batch-minor: batch on the lanes,
+    channels on the sublanes, taken where the per-device batch fills the
+    lanes (a multiple of 128). ``channel_axis`` 2 is ``(HW, N, C)``,
+    channel-minor, below that. Those are the layouts the TPU compiler keeps
+    a CNN's activations in on either side of the call at such a batch, so
+    the transpose is a bitcast in the compiled step and no relayout copy
+    stands at the kernel's boundary (tests/test_aot_tpu.py counts them).
 
-    Raises :class:`LRNTileError` when the VMEM budget caps the tile below
-    128 lanes (channels > ~2560): emitting a 128-wide block anyway would
-    exceed the scoped VMEM limit at Mosaic compile time, so callers must
-    fall back to the XLA formulation instead (``lrn_fused`` does)."""
-    # ~8 f32 temps of (C, tile) live on the kernel stack (x, g, sq,
-    # padded, windowed, scale, r, out); stay under ~10 MB of the 16 MB
-    # scoped VMEM
+    ``block`` = (T, second, minor) is the BlockSpec: T pixels (``want``, or
+    about 1 MB an operand), the minor two dims the array's own or, for a
+    batch too large for that, a lane- / sublane-exact tile of it — nothing
+    is ever padded or cropped. ``rows`` is how many of the T pixels one
+    trip of the in-kernel loop takes, so that its f32 temporaries stay in
+    registers; T is a multiple of it.
+
+    Raises :class:`LRNTileError` beyond ~2560 channels, where the f32
+    temporaries of even a one-pixel, 128-wide slab outgrow the scoped
+    VMEM: callers fall back to the XLA formulation (``lrn_fused`` does)."""
+    # ~8 f32 temporaries of a (C, 128) slab (x, g, x^2, the window sum,
+    # scale, two powers, dx) within 10 MB of the 16 MB scoped VMEM
     budget = 10 * 2 ** 20
-    if channels * hw * 4 * 8 <= budget:
-        return hw, hw
-    cap = budget // (channels * 4 * 8)
-    if cap < 128:
+    if channels * 128 * 4 * 8 > budget:
         raise LRNTileError(
-            f"fused LRN: {channels} channels leave a VMEM tile budget of "
-            f"{cap} < 128 lanes (~8 f32 temps of (C, tile) must fit "
-            f"{budget >> 20} MB); use the XLA formulation for channel "
-            f"counts above ~{budget // (4 * 8 * 128)}")
-    want = max(128, (min(want, cap) // 128) * 128)
-    padded = -(-hw // want) * want
-    return want, padded
+            f"fused LRN: one pixel of {channels} channels x 128 needs "
+            f"{channels * 128 * 4 * 8 >> 20} MB of f32 temporaries, over "
+            f"{budget >> 20} MB of scoped VMEM; use the XLA formulation "
+            f"for channel counts above ~{budget // (4 * 8 * 128)}")
+    if batch % 128 == 0:
+        channel_axis, second, rows = 1, channels, 1
+        minor = max(128, min(batch, _LRN_BLOCK_BYTES
+                             // (channels * itemsize) // 128 * 128))
+    else:
+        channel_axis, minor = 2, channels
+        lane_tiles = _cdiv(channels, 128)           # vregs a row of C fills
+        # as many images as fill a piece, in whole bf16 sublane tiles
+        fit = max(16, _LRN_PIECE_VREGS // lane_tiles * 8 // 16 * 16)
+        second = min(batch, fit)
+        rows = max(1, _LRN_PIECE_VREGS // (_cdiv(second, 8) * lane_tiles))
+    if want is None:
+        want = max(1, _LRN_BLOCK_BYTES // (second * minor * itemsize))
+    t = max(rows, min(want, _cdiv(hw, rows) * rows) // rows * rows)
+    return channel_axis, (t, second, minor), rows
 
 
 def lrn_tile_feasible(hw: int, channels: int) -> bool:
-    """Whether a VMEM-legal tiling exists (see ``_lrn_tile``)."""
+    """Whether a VMEM-legal block exists (see ``_lrn_tile``)."""
     try:
-        _lrn_tile(hw, 512, channels)
+        _lrn_tile(hw, channels, 128, 4)
         return True
     except LRNTileError:
         return False
 
 
+def _lrn_window(v, size: int, pre: int, axis: int):
+    """sum of v[c - pre : c - pre + size] over the channel ``axis``, zeros
+    beyond its ends: ``size - 1`` rotations, each masked where it wrapped."""
+    c = v.shape[axis]
+    idx = lax.broadcasted_iota(jnp.int32, v.shape, axis)
+    out = v
+    for d in range(-pre, size - pre):
+        if d == 0 or abs(d) >= c:
+            continue
+        shifted = pltpu.roll(v, (-d) % c, axis)       # shifted[c] = v[c + d]
+        inside = (idx < c - d) if d > 0 else (idx >= -d)
+        out = out + jnp.where(inside, shifted, 0.0)
+    return out
+
+
+def _lrn_kernel(*refs, local_size: int, alpha: float, beta: float, k: float,
+                channel_axis: int, rows: int):
+    """LRN forward (``x_ref, o_ref``) or the analytic Caffe backward
+    (``x_ref, g_ref, o_ref``; lrn_layer.cpp CrossChannelBackward) on one
+    (T, C, N) or (T, N, C) block — ``channel_axis`` 1 or 2, the
+    orientation rule's (``_lrn_tile``), not the net's layout plan:
+
+        y_i  = x_i * scale_i^-beta
+        dx_i = g_i * scale_i^-beta
+               - (2*alpha*beta/n) * x_i * sum_{j: i in win(j)} g_j*y_j/scale_j
+
+    The transpose window is the forward window mirrored. The block is
+    worked through ``rows`` pixels (and, batch-minor, 128 lanes) at a
+    time, each piece loaded, finished and stored before the next, so no
+    whole-block f32 temporary exists; one power a pass, the second as the
+    first over ``scale``."""
+    x_ref, o_ref = refs[0], refs[-1]
+    g_ref = refs[1] if len(refs) == 3 else None
+    t, _, minor = x_ref.shape
+    # batch-minor: one pixel's (C, N) slab is taken 128 lanes at a time
+    slabs = minor // 128 if channel_axis == 1 else 1
+    pre = (local_size - 1) // 2
+    post = local_size - pre - 1
+
+    def piece(i, carry):
+        if channel_axis == 1:
+            at = (pl.ds(i // slabs * rows, rows), slice(None),
+                  pl.ds(pl.multiple_of(i % slabs * 128, 128), 128))
+        else:
+            at = (pl.ds(i * rows, rows), slice(None), slice(None))
+        x = x_ref[at].astype(jnp.float32)
+        scale = k + (alpha / local_size) * _lrn_window(
+            x * x, local_size, pre, channel_axis)
+        # scale^-beta. Where the result is rounded to bf16 the power is
+        # written out: on the v5e exp(log) is 1.7x faster than `**` and up
+        # to 6e-5 off, a 64th of a bf16 ulp; an f32 result keeps `**` (1e-6)
+        p = (scale ** -beta if o_ref.dtype == jnp.float32
+             else jnp.exp(-beta * jnp.log(scale)))
+        if g_ref is None:
+            out = x * p
+        else:
+            g = g_ref[at].astype(jnp.float32)
+            gp = g * p
+            rsum = _lrn_window(gp * x / scale, local_size, post,
+                               channel_axis)
+            out = gp - (2.0 * alpha * beta / local_size) * x * rsum
+        o_ref[at] = out.astype(o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, t // rows * slabs, piece, 0)
+
+
 def _lrn_shape(x, layout: str):
-    """(n, c, hw, reshape-to-3d, restore-from-3d) for either layout; the
-    3-D view keeps channels on the axis the kernel's block expects (major
-    for NCHW, MINOR for NHWC — channels-last stays channels-last through
-    the custom-call boundary, no operand relayout)."""
+    """(n, c, hw) of an activation in the net's logical ``layout``."""
     if layout == "NHWC":
         n, h, w, c = x.shape
-        return (n, c, h * w,
-                lambda a: a.reshape(n, h * w, c),
-                lambda a: a.reshape(n, h, w, c))
-    n, c, h, w = x.shape
-    return (n, c, h * w,
-            lambda a: a.reshape(n, c, h * w),
-            lambda a: a.reshape(n, c, h, w))
+    else:
+        n, c, h, w = x.shape
+    return n, c, h * w
 
 
-def _lrn_specs(c: int, tile: int, layout: str):
-    if layout == "NHWC":
-        return pl.BlockSpec((1, tile, c), lambda i, j: (i, j, 0),
-                            memory_space=pltpu.VMEM), 1
-    return pl.BlockSpec((1, c, tile), lambda i, j: (i, 0, j),
-                        memory_space=pltpu.VMEM), 0
-
-
-def _lrn_pad3(x2, hw: int, hw_p: int, layout: str):
-    if hw_p == hw:
-        return x2
-    pad = [(0, 0)] * 3
-    pad[1 if layout == "NHWC" else 2] = (0, hw_p - hw)
-    return jnp.pad(x2, pad)
-
-
-def _lrn_crop3(out, n: int, c: int, hw: int, layout: str):
-    if layout == "NHWC":
-        return lax.slice(out, (0, 0, 0), (n, hw, c))
-    return lax.slice(out, (0, 0, 0), (n, c, hw))
-
-
-def _lrn_fused_fwd_impl(x, local_size: int, alpha: float, beta: float,
-                        k: float, tile: int, interpret: Optional[bool],
-                        layout: str = "NCHW"):
+def _lrn_call(operands, local_size: int, alpha: float, beta: float, k: float,
+              tile: Optional[int], interpret: Optional[bool], layout: str):
+    """One LRN kernel over ``operands`` — (x,) forward, (x, g) backward —
+    in the orientation and blocks ``_lrn_tile`` gives their shape. The
+    transposes in and out are logical: in the compiled step they are
+    bitcasts wherever the rule's orientation is the compiler's own."""
     if interpret is None:
         interpret = _interpret_default()
-    n, c, hw, to3, from3 = _lrn_shape(x, layout)
-    tile, hw_p = _lrn_tile(hw, tile, c)
-    x2 = _lrn_pad3(to3(x), hw, hw_p, layout)
-    spec, caxis = _lrn_specs(c, tile, layout)
-    out_shape = ((n, hw_p, c) if layout == "NHWC" else (n, c, hw_p))
+    x = operands[0]
+    n, c, hw = _lrn_shape(x, layout)
+    channel_axis, block, rows = _lrn_tile(hw, c, n, x.dtype.itemsize, tile)
+    # logical (N, C, HW) or (N, HW, C) -> pixels leading, channels where
+    # the rule wants them
+    axes = {("NCHW", 1): (2, 1, 0), ("NCHW", 2): (2, 0, 1),
+            ("NHWC", 1): (1, 2, 0), ("NHWC", 2): (1, 0, 2)}[
+                layout, channel_axis]
+    flat = (n, hw, c) if layout == "NHWC" else (n, c, hw)
+    ops3 = [a.reshape(flat).transpose(axes) for a in operands]
+    spec = pl.BlockSpec(
+        block, (lambda i, j: (i, j, 0)) if channel_axis == 2
+        else (lambda i, j: (i, 0, j)), memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_lrn_kernel, local_size=local_size, alpha=alpha,
-                          beta=beta, k=k, channels=c, channel_axis=caxis),
-        name="lrn_fwd",
-        out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
-        grid=(n, hw_p // tile),
-        in_specs=[spec],
+                          beta=beta, k=k, channel_axis=channel_axis,
+                          rows=rows),
+        name="lrn_fwd" if len(operands) == 1 else "lrn_bwd",
+        out_shape=jax.ShapeDtypeStruct(ops3[0].shape, x.dtype),
+        grid=(_cdiv(hw, block[0]), _cdiv(n, block[3 - channel_axis])),
+        in_specs=[spec] * len(operands),
         out_specs=spec,
         interpret=interpret,
-    )(x2)
-    return from3(_lrn_crop3(out, n, c, hw, layout))
+    )(*ops3)
+    back = tuple(axes.index(a) for a in range(3))
+    return out.transpose(back).reshape(x.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
 def _lrn_fused_cvjp(x, local_size: int, alpha: float, beta: float,
-                    k: float, tile: int, interpret: Optional[bool],
-                    layout: str):
-    return _lrn_fused_fwd_impl(x, local_size, alpha, beta, k, tile,
-                               interpret, layout)
+                    k: float, tile: Optional[int],
+                    interpret: Optional[bool], layout: str):
+    return _lrn_call((x,), local_size, alpha, beta, k, tile, interpret,
+                     layout)
 
 
 def lrn_fused(x, local_size: int, alpha: float, beta: float, k: float = 1.0,
-              tile: int = 512, interpret: Optional[bool] = None,
+              tile: Optional[int] = None, interpret: Optional[bool] = None,
               layout: str = "NCHW"):
-    """Fused LRN: one VMEM pass per spatial tile, forward and analytic
-    backward. ``layout`` selects the block orientation — x is (N, C, H, W)
-    under NCHW, (N, H, W, C) under NHWC (the net-level channels-last plan
-    feeds this directly; no layout round-trip at the custom-call
-    boundary).
+    """Fused LRN, forward and analytic backward. ``layout`` is the net's
+    logical one — x is (N, C, H, W) under NCHW, (N, H, W, C) under NHWC;
+    the orientation the kernel runs in follows the shape alone
+    (``_lrn_tile``), and ``tile`` (pixels a block) is for tests.
 
-    Channel counts whose VMEM working set admits no 128-lane tile
-    (> ~2560 channels, see ``_lrn_tile``) fall back to the XLA
-    formulation — same numbers, no Mosaic scoped-VMEM blowup."""
-    n, c, hw, _, _ = _lrn_shape(x, layout)
+    Channel counts with no VMEM-legal block (> ~2560, see ``_lrn_tile``)
+    fall back to the XLA formulation — same numbers, no Mosaic
+    scoped-VMEM blowup."""
+    n, c, hw = _lrn_shape(x, layout)
     if not lrn_tile_feasible(hw, c):
         from .nn import lrn_across_channels
         return lrn_across_channels(x, local_size, alpha, beta, k, layout)
@@ -770,76 +821,18 @@ def lrn_fused(x, local_size: int, alpha: float, beta: float, k: float = 1.0,
                            layout)
 
 
-def _lrn_bwd_kernel(x_ref, g_ref, o_ref, *, local_size: int, alpha: float,
-                    beta: float, k: float, channels: int,
-                    channel_axis: int = 0):
-    """One-pass LRN backward (the analytic Caffe gradient,
-    lrn_layer.cpp CrossChannelBackward):
-
-        dx_i = g_i * scale_i^-beta
-               - (2*alpha*beta/n) * x_i * sum_{j: i in win(j)} g_j*y_j/scale_j
-
-    where g_j*y_j/scale_j = g_j * x_j * scale_j^(-beta-1). The transpose
-    window is the forward window mirrored (pad (post, pre) instead of
-    (pre, post)). Everything stays in one VMEM tile — the round-5 cycle
-    attribution put the recompute-through-XLA backward at ~2/3 of the LRN
-    layers' 29%-of-step cost (evidence/aot_tpu/layer_cycles.json).
-    ``channel_axis``: see ``_lrn_kernel``."""
-    x = x_ref[0].astype(jnp.float32)
-    g = g_ref[0].astype(jnp.float32)
-    pre = (local_size - 1) // 2
-    post = local_size - pre - 1
-    sq = x * x
-    fwd_pads = [(0, 0), (0, 0)]
-    fwd_pads[channel_axis] = (pre, post)
-    padded = jnp.pad(sq, fwd_pads)
-    windowed = jnp.zeros_like(sq)
-    for dc in range(local_size):
-        windowed = windowed + lax.slice_in_dim(padded, dc, dc + channels,
-                                               axis=channel_axis)
-    scale = k + (alpha / local_size) * windowed
-    r = g * x * scale ** (-beta - 1.0)
-    bwd_pads = [(0, 0), (0, 0)]
-    bwd_pads[channel_axis] = (post, pre)
-    rp = jnp.pad(r, bwd_pads)
-    rsum = jnp.zeros_like(r)
-    for dc in range(local_size):
-        rsum = rsum + lax.slice_in_dim(rp, dc, dc + channels,
-                                       axis=channel_axis)
-    dx = g * scale ** (-beta) - (2.0 * alpha * beta / local_size) * x * rsum
-    o_ref[0] = dx.astype(o_ref.dtype)
-
-
 def lrn_fused_bwd(x, g, local_size: int, alpha: float, beta: float,
-                  k: float = 1.0, tile: int = 512,
+                  k: float = 1.0, tile: Optional[int] = None,
                   interpret: Optional[bool] = None, layout: str = "NCHW"):
-    """Fused LRN backward: dx from (x, g) in one VMEM pass per tile."""
-    if interpret is None:
-        interpret = _interpret_default()
-    n, c, hw, to3, from3 = _lrn_shape(x, layout)
-    tile, hw_p = _lrn_tile(hw, tile, c)
-    x2 = _lrn_pad3(to3(x), hw, hw_p, layout)
-    g2 = _lrn_pad3(to3(g), hw, hw_p, layout)
-    spec, caxis = _lrn_specs(c, tile, layout)
-    out_shape = ((n, hw_p, c) if layout == "NHWC" else (n, c, hw_p))
-    out = pl.pallas_call(
-        functools.partial(_lrn_bwd_kernel, local_size=local_size,
-                          alpha=alpha, beta=beta, k=k, channels=c,
-                          channel_axis=caxis),
-        name="lrn_bwd",
-        out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
-        grid=(n, hw_p // tile),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        interpret=interpret,
-    )(x2, g2)
-    return from3(_lrn_crop3(out, n, c, hw, layout))
+    """Fused LRN backward: dx from (x, g) in one pass."""
+    return _lrn_call((x, g), local_size, alpha, beta, k, tile, interpret,
+                     layout)
 
 
 def _lrn_fused_vjp_fwd(x, local_size, alpha, beta, k, tile, interpret,
                        layout):
-    return _lrn_fused_fwd_impl(x, local_size, alpha, beta, k, tile,
-                               interpret, layout), x
+    return _lrn_call((x,), local_size, alpha, beta, k, tile, interpret,
+                     layout), x
 
 
 def _lrn_fused_vjp_bwd(local_size, alpha, beta, k, tile, interpret, layout,
@@ -862,13 +855,15 @@ def _lrn_fused_vjp_bwd(local_size, alpha, beta, k, tile, interpret, layout,
 _lrn_fused_cvjp.defvjp(_lrn_fused_vjp_fwd, _lrn_fused_vjp_bwd)
 
 
-def lrn_route(hw: int, channels: int):
+def lrn_route(hw: int, channels: int, batch: Optional[int] = None,
+              itemsize: int = 4):
     """``(arm, note)`` for one ACROSS_CHANNELS LRN geometry — THE routing
     decision: ``maybe_lrn_fused`` takes it at trace time and ``Net`` logs
     it per layer at construction. ``"pallas"`` on TPU (the fused fwd+bwd
-    kernels, either layout); ``"xla"`` on the CPU test mesh
+    kernels), with the orientation and block the per-device ``batch``
+    gives them in the note; ``"xla"`` on the CPU test mesh
     (interpret-mode emulation is strictly slower than the op it
-    replaces) and for channel counts beyond the VMEM tiling cap. Same
+    replaces) and for channel counts beyond the VMEM cap. Same
     numerics either way. ``POSEIDON_PALLAS_LRN`` forces an arm for A/B:
     ``0`` = XLA on TPU, ``1`` = the (interpreted) kernels on CPU."""
     import os
@@ -878,21 +873,26 @@ def lrn_route(hw: int, channels: int):
     if _interpret_default() and env != "1":
         return "xla", "cpu backend"
     if not lrn_tile_feasible(hw, channels):
-        return "xla", f"no VMEM-legal tile for {channels} channels"
-    return "pallas", ""
+        return "xla", f"no VMEM-legal block for {channels} channels"
+    if batch is None:
+        return "pallas", ""
+    channel_axis, block, _ = _lrn_tile(hw, channels, batch, itemsize)
+    orient = ("batch-minor HWxCxN" if channel_axis == 1
+              else "channel-minor HWxNxC")
+    return "pallas", "%s, block %dx%dx%d" % (orient, *block)
 
 
 def maybe_lrn_fused(x, local_size: int, alpha: float, beta: float,
                     k: float = 1.0, layout: str = "NCHW"):
-    """ACROSS_CHANNELS LRN through :func:`lrn_route`'s arm. The NCHW block
-    puts channels major, the NHWC entry keeps channels minor: no transpose
-    in the program, though on the v5e the compiler still copies between
-    its own channel-minor activation layout and the kernel's row-major
-    operands (1-1.5 ms each at AlexNet's norm1). One traced run each there
-    (PR 24): AlexNet's device step is 68.9 ms with the kernels and 75.95
-    through XLA, GoogLeNet's 50.6 and 50.4 (ROADMAP S6)."""
+    """ACROSS_CHANNELS LRN through :func:`lrn_route`'s arm. The kernels
+    take their operand pixels-leading, batch-minor where the batch fills
+    the lanes and channel-minor below — what the v5e's compiler holds the
+    neighbouring convolutions' and pools' arrays in at such a batch — so
+    the step has no relayout copy at their boundary (until PR 33 they took
+    row-major (N, C, HW) and each call cost a 1-1.5 ms copy per operand
+    at AlexNet's norm1)."""
     from .nn import lrn_across_channels
-    _, c, hw, _, _ = _lrn_shape(x, layout)
-    if lrn_route(hw, c)[0] == "pallas":
+    n, c, hw = _lrn_shape(x, layout)
+    if lrn_route(hw, c, n, x.dtype.itemsize)[0] == "pallas":
         return lrn_fused(x, local_size, alpha, beta, k, layout=layout)
     return lrn_across_channels(x, local_size, alpha, beta, k, layout)

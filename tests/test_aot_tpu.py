@@ -450,6 +450,111 @@ def test_zaya_full_width_step_fits_one_v5e_at_its_depth_and_no_deeper(deeper):
         assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9
 
 
+# The LRN kernels at the CNN cells' norm layers (AlexNet's two at batch 512,
+# GoogLeNet's two at 128: batch-minor) and at GoogLeNet's published batch 32
+# (channel-minor), forward and backward, through Mosaic; then a stand-in for
+# the layers around a norm layer — conv -> ReLU -> LRN -> max-pool -> conv,
+# value_and_grad — in which the kernels' operand orientation has to be the
+# one the compiler keeps its neighbours in: no copy, pad, slice or transpose
+# of the LRN operand's size in the entry computation (four copies each until
+# PR 33, and a pad and a slice at GoogLeNet's norm2).
+_LRN_BOUNDARY = r"""
+import json, math, os, re, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ["POSEIDON_FORCE_PALLAS"] = "1"      # lower as for the TPU
+sys.path.insert(0, {repo!r})
+import jax, jax.numpy as jnp
+from jax import lax
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("SKIP:", e)
+    sys.exit(3)
+from poseidon_tpu.config import set_perf_policy
+from poseidon_tpu.ops import nn as NN, pallas_kernels as PK
+set_perf_policy()
+sh = SingleDeviceSharding(topo.devices[0])
+S = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sh)
+calls = lambda text: text.count('custom_call_target="tpu_custom_call"')
+kernels = {{}}
+for n, c, side in ((512, 96, 55), (512, 256, 27), (128, 64, 56),
+                   (128, 192, 56), (32, 64, 56), (32, 192, 56)):
+    x = S(n, c, side, side)
+    fwd = jax.jit(lambda x: PK.lrn_fused(x, 5, 1e-4, 0.75, 1.0,
+                                         interpret=False))
+    bwd = jax.jit(lambda x, g: PK.lrn_fused_bwd(x, g, 5, 1e-4, 0.75, 1.0,
+                                                interpret=False))
+    kernels["%dx%dx%d" % (n, c, side * side)] = [
+        PK.lrn_route(side * side, c, n, 2)[1],
+        calls(fwd.lower(x).compile().as_text()),
+        calls(bwd.lower(x, x).compile().as_text())]
+
+def conv(x, w, stride, pad):
+    return lax.conv_general_dilated(
+        x, w, (stride, stride), [(pad, pad)] * 2,
+        dimension_numbers=("NCHW", "OIHW", "NCHW"))
+
+def standin(n, cin, side, c, k, stride, pad, cout):
+    def loss(w1, w2, x):
+        h = jnp.maximum(conv(x, w1, stride, pad), 0)
+        h = PK.maybe_lrn_fused(h, 5, 1e-4, 0.75)
+        h = NN.max_pool(h, (3, 3), (2, 2), (0, 0))
+        return jnp.sum(conv(h, w2, 1, 1).astype(jnp.float32) ** 2)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        S(c, cin, k, k), S(cout, c, 3, 3), S(n, cin, side, side)
+    ).compile().as_text()
+    out = (side + 2 * pad - k) // stride + 1
+    lines = text.splitlines()
+    entry = lines[next(i for i, l in enumerate(lines)
+                       if l.startswith("ENTRY ")):]
+    moved = []
+    for l in entry:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* "
+                     r"(copy|pad|slice|transpose)\(", l)
+        if m and math.prod(map(int, m.group(1).split(","))) \
+                >= n * c * out * out:
+            moved.append(m.group(2) + " " + m.group(1))
+    return {{"pallas_custom_calls": calls(text), "moved": moved}}
+
+print("RESULT " + json.dumps({{"kernels": kernels, "standin": {{
+    "alexnet_norm1": standin(512, 3, 227, 96, 11, 4, 0, 256),
+    "googlenet_norm1": standin(128, 3, 112, 64, 1, 2, 0, 192),
+    "googlenet_norm2": standin(128, 64, 56, 192, 3, 1, 1, 128)}}}}))
+"""
+
+
+def test_lrn_kernels_meet_their_neighbours_layout_for_v5e():
+    """Mosaic takes the LRN kernels at the cells' geometries in the
+    orientation the rule gives them, and around a norm layer the compiled
+    text moves no array of the operand's size."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _LRN_BOUNDARY.format(repo=REPO)],
+        capture_output=True, text=True, timeout=600, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)
+    assert got["kernels"] == {
+        "512x96x3025": ["batch-minor HWxCxN, block 10x96x512", 1, 1],
+        "512x256x729": ["batch-minor HWxCxN, block 4x256x512", 1, 1],
+        "128x64x3136": ["batch-minor HWxCxN, block 64x64x128", 1, 1],
+        "128x192x3136": ["batch-minor HWxCxN, block 21x192x128", 1, 1],
+        "32x64x3136": ["channel-minor HWxNxC, block 256x32x64", 1, 1],
+        "32x192x3136": ["channel-minor HWxNxC, block 84x32x192", 1, 1]}
+    for name, standin in got["standin"].items():
+        assert standin == {"pallas_custom_calls": 2, "moved": []}, name
+
+
+
 # AlexNet's train step as `train --bf16` builds it at the benchmark's batch,
 # for ONE abstract v5e chip: with nobody to all-reduce with, the step builder
 # packs nothing (PR 26) — no arena scope, no buffer-length array or constant,
@@ -507,6 +612,8 @@ print("RESULT " + json.dumps({{
     "buffer_length_arrays": text.count("f32[%d]" % n),
     "all_reduces": len(re.findall(r" all-reduce(-start)?\(", text)),
     "pallas_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+    "lrn_operand_copies": len(re.findall(
+        r"\[512,(?:96,3025|256,729|96,55,55|256,27,27)\]\S* copy\(", text)),
     "temp_gb": ma.temp_size_in_bytes / 1e9}}))
 """
 
@@ -516,7 +623,8 @@ def test_alexnet_one_chip_step_has_no_arena_for_one_v5e():
     """The one-chip CNN step the benchmark's cells run: the update is
     there (``optimizer_update`` op names), the arena is not — no
     ``arena_*`` op name, no array of the flat buffer's length, no
-    collective — and the LRN kernels are still Pallas."""
+    collective — and the LRN kernels are still Pallas, with no relayout
+    copy at their boundary."""
     import json
     r = subprocess.run(
         [sys.executable, "-c", _CNN_STEP.format(repo=REPO)],
@@ -535,4 +643,7 @@ def test_alexnet_one_chip_step_has_no_arena_for_one_v5e():
     assert got["buffer_length_arrays"] == 0
     assert got["all_reduces"] == 0
     assert got["pallas_custom_calls"] == 4       # norm1, norm2: fwd and bwd
+    # the kernels take their operands as the compiler holds them: the two
+    # copies left are pool2's own, channel-minor (nine until PR 33)
+    assert got["lrn_operand_copies"] <= 3
     assert got["temp_gb"] < 3.0                  # 3.42 with the arena

@@ -163,9 +163,14 @@ def test_net_logs_and_records_kernel_routes(capsys, monkeypatch):
     net = Net(zoo.alexnet(num_classes=10), "TRAIN",
               source_shapes={"data": (2, 3, 67, 67), "label": (2,)})
     assert net.kernel_routes == {
-        "norm1": "lrn=pallas", "pool1": "pool_bwd=sas", "norm2": "lrn=pallas",
+        "norm1": "lrn=pallas (channel-minor HWxNxC, block 240x2x96)",
+        "pool1": "pool_bwd=sas",
+        "norm2": "lrn=pallas (channel-minor HWxNxC, block 56x2x256)",
         "pool2": "pool_bwd=sas", "pool5": "pool_bwd=sas"}
-    assert "[kernel_route] pool1: pool_bwd -> sas" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "[kernel_route] pool1: pool_bwd -> sas" in out
+    assert ("[kernel_route] norm1: lrn -> pallas (channel-minor HWxNxC, "
+            "block 240x2x96)") in out
 
 
 def test_one_channel_conv_takes_im2col_when_lowering_for_tpu(capsys,
@@ -404,6 +409,79 @@ def test_lrn_analytic_xla_bwd_matches_autodiff(rng_np, monkeypatch, layout,
     monkeypatch.delenv("POSEIDON_LRN_BWD")
     got = np.asarray(jax.grad(f)(xj))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
+
+
+# the four norm layers of the benchmark's CNN cells at the cells' batch a
+# chip, GoogLeNet's published batch 32, and a batch that fills no lane tile
+_LRN_RULE = [
+    # n, c, hw, itemsize -> channel_axis, block, rows
+    ((512, 96, 3025, 2), (1, (10, 96, 512), 1)),
+    ((512, 256, 729, 2), (1, (4, 256, 512), 1)),
+    ((128, 64, 3136, 2), (1, (64, 64, 128), 1)),
+    ((128, 192, 3136, 2), (1, (21, 192, 128), 1)),
+    ((32, 64, 3136, 2), (2, (256, 32, 64), 4)),
+    ((32, 192, 3136, 2), (2, (84, 32, 192), 2)),
+    ((200, 96, 729, 4), (2, (21, 128, 96), 1)),
+    ((4096, 256, 729, 2), (1, (1, 256, 2048), 1)),
+]
+
+
+@pytest.mark.parametrize("geometry,want", _LRN_RULE)
+def test_lrn_tile_rule(geometry, want, monkeypatch):
+    """Orientation and block come from the shape alone: batch-minor where
+    the batch fills the lanes, channel-minor below; about 1 MB an operand
+    block; the minor two dims the array's own or an exact tile of them.
+    The route's note says which."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+    n, c, hw, itemsize = geometry
+    got = PK._lrn_tile(hw, c, n, itemsize)
+    assert got == want
+    axis, (t, second, minor), rows = got
+    assert t % rows == 0 and t * second * minor * itemsize <= 2 ** 20
+    assert (second, minor) == ((c, min(n, minor)) if axis == 1
+                               else (min(n, second), c))
+    monkeypatch.delenv("POSEIDON_PALLAS_LRN", raising=False)
+    monkeypatch.setattr(PK, "_interpret_default", lambda: False)
+    assert PK.lrn_route(hw, c, n, itemsize) == (
+        "pallas", "%s, block %dx%dx%d" % (
+            "batch-minor HWxCxN" if axis == 1 else "channel-minor HWxNxC",
+            t, second, minor))
+
+
+@pytest.mark.parametrize("local_size", [3, 5])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n,c,h,w", [
+    # batch 128 -> batch-minor, batch 4 -> channel-minor; 55 x 55 = 3025
+    # pixels in blocks of 16 leave a partial last block, as do 5 x 7
+    (128, 8, 55, 55),
+    (128, 64, 5, 7), (128, 96, 5, 7), (128, 192, 5, 7), (128, 256, 5, 7),
+    (4, 64, 55, 55), (4, 96, 55, 55), (4, 192, 55, 55), (4, 256, 55, 55)])
+def test_lrn_each_orientation_matches_xla(rng_np, n, c, h, w, dtype,
+                                          local_size):
+    """Both orientations of the one kernel body, forward and analytic
+    backward, against ``lrn_across_channels`` and its VJP."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+    from poseidon_tpu.ops.nn import lrn_across_channels
+    assert PK._lrn_tile(h * w, c, n, 4, 16)[0] == (1 if n == 128 else 2)
+    assert (h * w) % 16
+    x32 = jnp.asarray(rng_np.randn(n, c, h, w).astype(np.float32) * 8)
+    g32 = jnp.asarray(rng_np.randn(n, c, h, w).astype(np.float32))
+    x, g = x32.astype(dtype), g32.astype(dtype)
+    ref = lambda x_: lrn_across_channels(x_, local_size, 1e-4, 0.75, 1.0)
+    want, vjp = jax.vjp(ref, x)
+    # interpreted on the CPU mesh, compiled under POSEIDON_TEST_TPU=1
+    got = PK.lrn_fused(x, local_size, 1e-4, 0.75, 1.0, tile=16)
+    dgot = PK.lrn_fused_bwd(x, g, local_size, 1e-4, 0.75, 1.0, tile=16)
+    assert got.dtype == x.dtype and dgot.dtype == x.dtype
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    if dtype == "float32":
+        np.testing.assert_allclose(f32(got), f32(want), rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(f32(dgot), f32(vjp(g)[0]),
+                                   rtol=1e-4, atol=1e-5)
+    else:
+        np.testing.assert_allclose(f32(got), f32(want), rtol=0.05, atol=0.05)
+        np.testing.assert_allclose(f32(dgot), f32(vjp(g)[0]),
+                                   rtol=0.05, atol=0.05)
 
 
 def test_lrn_vmem_cap_falls_back_with_grad():

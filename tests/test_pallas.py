@@ -341,7 +341,7 @@ def test_lrn_tile_rejects_vmem_busting_channel_counts():
     assert not lrn_tile_feasible(128 * 128, 2561)
     assert not lrn_tile_feasible(128 * 128, 4096)
     with _pytest.raises(LRNTileError, match="XLA formulation"):
-        _lrn_tile(128 * 128, 512, 4096)
+        _lrn_tile(128 * 128, 4096, 128, 4)
 
 
 def test_lrn_fused_falls_back_to_xla_above_tile_cap():

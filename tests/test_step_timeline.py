@@ -152,8 +152,9 @@ def test_pool_and_lrn_instructions_map_to_their_layer_and_pass(
     net = Net(load_net_from_string(NET), "TRAIN",
               source_shapes={"data": (4, 4, 12, 12), "label": (4,)})
     if arm == "tpu_routes":
-        assert net.kernel_routes == {"norm1": "lrn=pallas",
-                                     "pool1": "pool_bwd=sas"}
+        assert net.kernel_routes == {
+            "norm1": "lrn=pallas (channel-minor HWxNxC, block 112x4x8)",
+            "pool1": "pool_bwd=sas"}
     params = net.init(jax.random.PRNGKey(0))
     inputs = {"data": np.ones((4, 4, 12, 12), np.float32),
               "label": np.zeros((4,), np.int32)}
