@@ -10,3 +10,6 @@ on JAX/XLA/pjit for TPU meshes. See ARCHITECTURE.md for the design map.
 __version__ = "0.1.0"
 
 from . import config  # noqa: F401
+# the process-wide span recorder's start-up phase opens here, at the
+# package's first import (stdlib only; runtime/__init__.py is lazy)
+from .runtime import spans  # noqa: F401

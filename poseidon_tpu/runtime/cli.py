@@ -168,34 +168,44 @@ def _enable_compile_cache_from_args(args) -> None:
 
 
 def cmd_train(args) -> int:
-    from .cluster import init_distributed
-    if getattr(args, "async_ssp", False):
-        # async-SSP: the processes stay INDEPENDENT jax runtimes — no
-        # jax.distributed world, no collective rendezvous; the only
-        # cross-process channel is the tier's parameter service. The tier
-        # reads the LOCAL launcher's env contract; a hostfile launch does
-        # not set it, and silently degrading to N isolated full-data runs
-        # would be worse than refusing.
-        if args.hostfile and "POSEIDON_PROC_ID" not in os.environ:
-            raise SystemExit(
-                "--async_ssp currently rides the launch_local env contract "
-                "(POSEIDON_PROC_ID/NUM_PROCS/COORDINATOR); for a hostfile "
-                "cluster, start each node under that env (see "
-                "scripts/launch.py) instead of --hostfile/--node_id")
-    else:
-        # FIRST: jax.distributed.initialize refuses to run once anything
-        # has touched the backend
-        init_distributed(hostfile=args.hostfile or None,
-                         node_id=args.node_id if args.node_id >= 0 else None)
-    _enable_compile_cache_from_args(args)
-    from .. import config
-    if args.bf16:
-        config.set_perf_policy()
-    # the two graph-level requests Net reads from the numeric policy at
-    # construction; every other knob reaches the Engine as an argument
-    config.set_policy(conv_layout=args.conv_layout.upper())
-    if args.conv_strategy:
-        config.set_policy(conv_strategy=args.conv_strategy)
+    from .spans import recorder
+    # the start-up timeline's first span (runtime/spans.py): everything
+    # this command does before it builds the Engine
+    with recorder.startup("cli_setup"):
+        from .cluster import init_distributed
+        if getattr(args, "async_ssp", False):
+            # async-SSP: the processes stay INDEPENDENT jax runtimes — no
+            # jax.distributed world, no collective rendezvous; the only
+            # cross-process channel is the tier's parameter service. The tier
+            # reads the LOCAL launcher's env contract; a hostfile launch does
+            # not set it, and silently degrading to N isolated full-data runs
+            # would be worse than refusing.
+            if args.hostfile and "POSEIDON_PROC_ID" not in os.environ:
+                raise SystemExit(
+                    "--async_ssp currently rides the launch_local env "
+                    "contract (POSEIDON_PROC_ID/NUM_PROCS/COORDINATOR); for "
+                    "a hostfile cluster, start each node under that env (see "
+                    "scripts/launch.py) instead of --hostfile/--node_id")
+        else:
+            # FIRST: jax.distributed.initialize refuses to run once anything
+            # has touched the backend
+            init_distributed(
+                hostfile=args.hostfile or None,
+                node_id=args.node_id if args.node_id >= 0 else None)
+        _enable_compile_cache_from_args(args)
+        from .. import config
+        if args.bf16:
+            config.set_perf_policy()
+        # the two graph-level requests Net reads from the numeric policy at
+        # construction; every other knob reaches the Engine as an argument
+        config.set_policy(conv_layout=args.conv_layout.upper())
+        if args.conv_strategy:
+            config.set_policy(conv_strategy=args.conv_strategy)
+        with recorder.startup("backend_init"):
+            # the program's own first touch of the backend; a caller that
+            # touched it already (the benchmark's harness) leaves ~0 here
+            import jax
+            jax.local_devices()
     eng = _engine_from_args(args)
     eng.profile_steps = args.profile
     if args.snapshot == "auto":

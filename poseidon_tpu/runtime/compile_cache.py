@@ -41,6 +41,8 @@ import pickle
 import zlib
 from typing import Any, Optional
 
+from .spans import recorder
+
 __all__ = ["resolve_cache_dir", "enable_compile_cache",
            "cache_entries", "watch_cache_hits", "step_key",
            "code_fingerprint",
@@ -227,14 +229,19 @@ def save_step_executable(cache_dir: str, key: str, compiled) -> Optional[str]:
     from .metrics import log
     tmp = None
     try:
-        payload = pickle.dumps(serialize(compiled))
-        blob = _pack(payload)
-        path = _aot_path(cache_dir, key)
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = f"{path}.tmp.{os.getpid()}"
-        with open(tmp, "wb") as f:
-            f.write(blob)
-        os.replace(tmp, path)
+        with recorder.startup("aot_serialize"):
+            payload = pickle.dumps(serialize(compiled))
+        with recorder.startup("aot_pack"):
+            blob = _pack(payload)
+        with recorder.startup("aot_write"):
+            path = _aot_path(cache_dir, key)
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            tmp = f"{path}.tmp.{os.getpid()}"
+            with open(tmp, "wb") as f:
+                f.write(blob)
+            os.replace(tmp, path)
+        recorder.note(aot_bytes_on_disk=len(blob),
+                      aot_bytes_serialized=len(payload))
         log(f"compile_cache: step executable stored at {path} "
             f"({len(blob) / 1e6:.1f} MB, {len(payload) / 1e6:.1f} MB "
             f"serialized)")
@@ -252,16 +259,27 @@ def load_step_executable(cache_dir: str, key: str):
     """Reload a serialized step executable; None on miss or ANY failure
     (a stale/foreign entry must degrade to a recompile, never an abort).
     The returned object is directly callable with the original call
-    signature."""
+    signature. A load's costs have different remedies and a start-up span
+    each: the file's read, the unpack (decompress and unpickle), the
+    runtime's own ``deserialize_and_load``."""
     path = _aot_path(cache_dir, key)
     if not os.path.exists(path):
         return None
-    from jax.experimental.serialize_executable import deserialize_and_load
-
     try:
-        with open(path, "rb") as f:
-            payload, in_tree, out_tree = pickle.loads(_unpack(f.read()))
-        return deserialize_and_load(payload, in_tree, out_tree)
+        with recorder.startup("aot_read"):
+            with open(path, "rb") as f:
+                blob = f.read()
+        with recorder.startup("aot_unpack"):
+            unpacked = _unpack(blob)
+            recorder.note(aot_bytes_on_disk=len(blob),
+                          aot_bytes_serialized=len(unpacked))
+            del blob                # a gigabyte each, and the runtime's
+            payload, in_tree, out_tree = pickle.loads(unpacked)
+            del unpacked            # load below needs the host's memory
+        with recorder.startup("aot_deserialize"):
+            from jax.experimental.serialize_executable import \
+                deserialize_and_load
+            return deserialize_and_load(payload, in_tree, out_tree)
     except Exception as e:  # noqa: BLE001 — miss, not abort
         from .metrics import log
         log(f"compile_cache: failed to reload AOT step {key} "

@@ -50,16 +50,29 @@ from .spans import NULL_SPAN, recorder as span_recorder
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-def _record_compile(event: str, duration: float, **_) -> None:
-    """A compile as a span on the host timeline, ending now: one inside a
-    measured window is then on the timeline and names its gap."""
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _record_compile(event: str, duration: float, fun_name: str = "",
+                    **_) -> None:
+    """A compile as a span on the host timeline, ending now, with the
+    program's name: one inside a measured window is then on the timeline
+    and names its gap, and one before the first step is on the start-up
+    timeline under whichever of its spans is open."""
     if event == _COMPILE_EVENT:
-        span_recorder.complete("compile", duration, "runtime")
+        span_recorder.complete("compile", duration, "runtime",
+                               {"program": fun_name} if fun_name else None)
 
 
-# one listener per process, like the recorder it writes to (a no-op while
-# that is disabled)
+def _count_cache_hit(event: str, **_) -> None:
+    if event == _CACHE_HIT_EVENT:
+        span_recorder.note(add=True, xla_cache_hits=1)
+
+
+# one listener each per process, like the recorder they write to (no-ops
+# once the start-up phase has closed, while that is disabled)
 jax.monitoring.register_event_duration_secs_listener(_record_compile)
+jax.monitoring.register_event_listener(_count_cache_hit)
 
 
 class TrainingDivergedError(RuntimeError):
@@ -126,7 +139,13 @@ class Engine:
         hbm_budget_gb: Optional[float] = None,
         remat: Optional[str] = None,
     ):
-        t_build = time.perf_counter()
+        # the start-up span the timer `engine_build` is read off, closed at
+        # the end of this function. Opened by hand and not by a decorator:
+        # a decorator's frame lies under every call jax makes while it
+        # lowers the fillers' programs, and moved them across the end of
+        # one of the interpreter's 16 KiB frame-stack chunks: 1.5 s of
+        # googlenet.lmdb's set-up on the chip (PERF.md, PR 34, finding 1)
+        build = span_recorder.startup("engine_build").__enter__()
         self.sp = sp
         # step-pipeline knobs: explicit args win, else the global policy
         # (config.PipelineConfig)
@@ -251,7 +270,8 @@ class Engine:
                 "(increase batch_size instead)", rank=self.rank)
             self.iter_size = 1
 
-        train_param, test_params = resolve_nets(sp)
+        with span_recorder.startup("net_build"):
+            train_param, test_params = resolve_nets(sp)
 
         # --- data pipelines for the train net ---------------------------- #
         self._train_param = train_param  # retained: reshard_data rebuilds
@@ -264,13 +284,16 @@ class Engine:
             t for p in self.train_pipelines
             if getattr(getattr(p, "source", None), "tokens", False)
             for t in p.tops)
-        self.train_net = Net(train_param, "TRAIN", source_shapes=train_shapes)
-        if self.mesh_cfg is not None and self.mesh_cfg.active:
-            from ..parallel.spmd import ShardingPlan
-            self.plan = ShardingPlan.build(
-                self.train_net, self.mesh_cfg, self.comm,
-                shard_params=self.mesh_cfg.shard,
-                enable_tp=self.mesh_cfg.shard)
+        with span_recorder.startup("net_build"):
+            self.train_net = Net(train_param, "TRAIN",
+                                 source_shapes=train_shapes)
+            if self.mesh_cfg is not None and self.mesh_cfg.active:
+                from ..parallel.spmd import ShardingPlan
+                self.plan = ShardingPlan.build(
+                    self.train_net, self.mesh_cfg, self.comm,
+                    shard_params=self.mesh_cfg.shard,
+                    enable_tp=self.mesh_cfg.shard)
+        if self.plan is not None:
             log(f"sharding plan: {self.plan.describe()}", rank=self.rank)
             if self.iter_size > 1:
                 log("WARNING: iter_size > 1 does not compose with --mesh "
@@ -292,7 +315,8 @@ class Engine:
         self.test_pipelines: List[List[BatchPipeline]] = []
         for i, tp in enumerate(test_params):
             pipes, shapes = self._build_pipelines(tp, "TEST")
-            self.test_nets.append(Net(tp, "TEST", source_shapes=shapes))
+            with span_recorder.startup("net_build"):
+                self.test_nets.append(Net(tp, "TEST", source_shapes=shapes))
             self.test_pipelines.append(pipes)
 
         if sfb_auto:
@@ -367,8 +391,9 @@ class Engine:
                 "staleness (the local-step path has no remat wiring yet)",
                 rank=self.rank)
         elif _want_plan:
-            self.remat_plan = self._plan_remat(remat, hbm_budget_gb,
-                                               donate_batch)
+            with span_recorder.startup("net_build"):
+                self.remat_plan = self._plan_remat(remat, hbm_budget_gb,
+                                                   donate_batch)
         if self.remat_plan is not None and not self.remat_plan.active:
             self.remat_plan = None  # fits the budget: identity plan
         if self.remat_plan is not None:
@@ -377,92 +402,29 @@ class Engine:
             # peak, claimed bytes)
             self.stats.set_section("remat", self.remat_plan.to_doc())
 
-        # --- compiled steps ---------------------------------------------- #
-        if staleness > 0:
-            # SSP (ssp_consistency_controller.cpp): each device runs local
-            # steps, reconciling every staleness+1 iters. The engine's view
-            # of "the params" is the replicated anchor (what the PS holds).
-            ssp_ts = build_ssp_train_step(self.train_net, sp, self.mesh,
-                                          staleness, self.comm,
-                                          input_transform=self._input_transform,
-                                          donate_batch=donate_batch,
-                                          plan=self.plan)
-            raw_step = ssp_ts.step
-
-            def _ssp_step(params, state, batch, rng):
-                state, m = raw_step(state, batch, rng)
-                return state.anchor_params, state, m
-
-            self.train_step = TrainStep(
-                step=_ssp_step, mesh=ssp_ts.mesh,
-                batch_sharding=ssp_ts.batch_sharding,
-                replicated=ssp_ts.replicated,
-                # NOTE: the SSP lowerable has the 3-arg (state, batch, rng)
-                # signature, not the wrapper's 4-arg one
-                lowerable=ssp_ts.lowerable,
-                arena=ssp_ts.arena)  # the boundary's delta buckets
-        else:
-            dump = sorted({b for _, bs in self._h5_train for b in bs})
-            if dump and self.iter_size > 1:
-                log("WARNING: iter_size > 1 ignored with HDF5_OUTPUT in "
-                    "the TRAIN net (per-iteration dump semantics)",
-                    rank=self.rank)
-                self.iter_size = 1
-            if dump and self.plan is not None:
-                log("WARNING: HDF5_OUTPUT in the TRAIN net is not dumped "
-                    "under --mesh sharding", rank=self.rank)
-                dump = []
-                self._h5_train = []
-            self.train_step = build_train_step(
-                self.train_net, sp, self.mesh, self.comm, dump_blobs=dump,
-                input_transform=self._input_transform,
-                iter_size=self.iter_size, donate_batch=donate_batch,
-                plan=self.plan, remat_plan=self.remat_plan)
-
-        # --- multi-step dispatch (scan chunks) ---------------------------- #
-        # K optimizer steps per compiled dispatch: amortizes the runtime's
-        # per-dispatch round-trip (whether it buys anything on a local
-        # chip is ROADMAP S3's A/B).
-        # The engine falls back to single steps near display/test/snapshot
-        # boundaries so solver cadence semantics are exact.
-        self.steps_per_dispatch = max(1, int(steps_per_dispatch))
-        self._scan_step = None
-        if self.steps_per_dispatch > 1:
-            if staleness > 0:
-                log("WARNING: steps_per_dispatch ignored under SSP "
-                    "staleness (the SSP step already batches local steps)",
-                    rank=self.rank)
-                self.steps_per_dispatch = 1
-            elif self._h5_train:
-                log("WARNING: steps_per_dispatch ignored with HDF5_OUTPUT "
-                    "in the TRAIN net (per-iteration dump semantics)",
-                    rank=self.rank)
-                self.steps_per_dispatch = 1
-            else:
-                self._scan_step = build_train_step(
-                    self.train_net, sp, self.mesh, self.comm,
-                    scan_steps=self.steps_per_dispatch,
-                    input_transform=self._input_transform,
-                    iter_size=self.iter_size,
-                    remat_plan=self.remat_plan)
-        self.eval_steps = [
-            build_eval_step(n, self.mesh, dcn_axis=self.comm.dcn_axis,
-                            plan=self.plan)
-            for n in self.test_nets]
+        with span_recorder.startup("step_build"):
+            self._build_steps(sp, staleness, donate_batch, steps_per_dispatch)
 
         # --- state -------------------------------------------------------- #
-        seed = sp.random_seed if sp.random_seed >= 0 else 1
-        self.rng = jax.random.PRNGKey(seed)
-        self.params = self.train_net.init(jax.random.fold_in(self.rng, 0))
-        self.err_groups = comm_error_groups(self.comm, self.mesh)
-        if staleness > 0:
-            # SSP groups = slices on a two-tier mesh, devices on a flat one
-            # (the same granularity comm_error_groups computes)
-            self.state = init_ssp_state(self.params, self.err_groups,
-                                        self.comm)
-        else:
-            self.state = init_train_state(self.params, self.comm,
-                                          self.err_groups, sp.solver_type)
+        with span_recorder.startup("param_init"):
+            seed = sp.random_seed if sp.random_seed >= 0 else 1
+            self.rng = jax.random.PRNGKey(seed)
+            self.params = self.train_net.init(
+                jax.random.fold_in(self.rng, 0))
+            self.err_groups = comm_error_groups(self.comm, self.mesh)
+            if staleness > 0:
+                # SSP groups = slices on a two-tier mesh, devices on a flat
+                # one (the same granularity comm_error_groups computes)
+                self.state = init_ssp_state(self.params, self.err_groups,
+                                            self.comm)
+            else:
+                self.state = init_train_state(self.params, self.comm,
+                                              self.err_groups,
+                                              sp.solver_type)
+            # to the point the leaves are ready: the device's tail of the
+            # fills is this span's, not the step load's or the first step's
+            # (0.1 s of googlenet.lmdb's set-up on the chip, PERF.md PR 34)
+            jax.block_until_ready((self.params, self.state))
         # single-batch placement spec (test/eval batches and non-accumulated
         # train steps): the train step's input sharding minus the leading
         # [iter_size] micro-batch axis it gains under gradient accumulation
@@ -579,7 +541,85 @@ class Engine:
                 return stats
 
             self._debug_fn = jax.jit(_debug)
-        self.stats.add_time("engine_build", time.perf_counter() - t_build)
+        build.__exit__(None, None, None)
+        self.stats.add_time("engine_build", build.dur_s)
+
+    def _build_steps(self, sp: SolverParameter, staleness: int,
+                     donate_batch: bool, steps_per_dispatch: int) -> None:
+        """The train step (sync or SSP), the scan-chunk step and the eval
+        steps of this job: ``__init__``'s ``step_build``."""
+        # --- compiled steps ---------------------------------------------- #
+        if staleness > 0:
+            # SSP (ssp_consistency_controller.cpp): each device runs local
+            # steps, reconciling every staleness+1 iters. The engine's view
+            # of "the params" is the replicated anchor (what the PS holds).
+            ssp_ts = build_ssp_train_step(self.train_net, sp, self.mesh,
+                                          staleness, self.comm,
+                                          input_transform=self._input_transform,
+                                          donate_batch=donate_batch,
+                                          plan=self.plan)
+            raw_step = ssp_ts.step
+
+            def _ssp_step(params, state, batch, rng):
+                state, m = raw_step(state, batch, rng)
+                return state.anchor_params, state, m
+
+            self.train_step = TrainStep(
+                step=_ssp_step, mesh=ssp_ts.mesh,
+                batch_sharding=ssp_ts.batch_sharding,
+                replicated=ssp_ts.replicated,
+                # NOTE: the SSP lowerable has the 3-arg (state, batch, rng)
+                # signature, not the wrapper's 4-arg one
+                lowerable=ssp_ts.lowerable,
+                arena=ssp_ts.arena)  # the boundary's delta buckets
+        else:
+            dump = sorted({b for _, bs in self._h5_train for b in bs})
+            if dump and self.iter_size > 1:
+                log("WARNING: iter_size > 1 ignored with HDF5_OUTPUT in "
+                    "the TRAIN net (per-iteration dump semantics)",
+                    rank=self.rank)
+                self.iter_size = 1
+            if dump and self.plan is not None:
+                log("WARNING: HDF5_OUTPUT in the TRAIN net is not dumped "
+                    "under --mesh sharding", rank=self.rank)
+                dump = []
+                self._h5_train = []
+            self.train_step = build_train_step(
+                self.train_net, sp, self.mesh, self.comm, dump_blobs=dump,
+                input_transform=self._input_transform,
+                iter_size=self.iter_size, donate_batch=donate_batch,
+                plan=self.plan, remat_plan=self.remat_plan)
+
+        # --- multi-step dispatch (scan chunks) ---------------------------- #
+        # K optimizer steps per compiled dispatch: amortizes the runtime's
+        # per-dispatch round-trip (whether it buys anything on a local
+        # chip is ROADMAP S3's A/B).
+        # The engine falls back to single steps near display/test/snapshot
+        # boundaries so solver cadence semantics are exact.
+        self.steps_per_dispatch = max(1, int(steps_per_dispatch))
+        self._scan_step = None
+        if self.steps_per_dispatch > 1:
+            if staleness > 0:
+                log("WARNING: steps_per_dispatch ignored under SSP "
+                    "staleness (the SSP step already batches local steps)",
+                    rank=self.rank)
+                self.steps_per_dispatch = 1
+            elif self._h5_train:
+                log("WARNING: steps_per_dispatch ignored with HDF5_OUTPUT "
+                    "in the TRAIN net (per-iteration dump semantics)",
+                    rank=self.rank)
+                self.steps_per_dispatch = 1
+            else:
+                self._scan_step = build_train_step(
+                    self.train_net, sp, self.mesh, self.comm,
+                    scan_steps=self.steps_per_dispatch,
+                    input_transform=self._input_transform,
+                    iter_size=self.iter_size,
+                    remat_plan=self.remat_plan)
+        self.eval_steps = [
+            build_eval_step(n, self.mesh, dcn_axis=self.comm.dcn_axis,
+                            plan=self.plan)
+            for n in self.test_nets]
 
     # ---------------------------------------------------------------- #
     def _build_pipelines(self, net_param: NetParameter, phase: str,
@@ -590,11 +630,13 @@ class Engine:
         # '--mesh dp1' on four chips, feeds that many and no more)
         local = sum(d.process_index == jax.process_index()
                     for d in self.mesh.devices.flat)
-        return build_phase_pipelines(
-            net_param, phase, batch_multiplier=local,
-            shard=shard if shard is not None else self._data_shard,
-            memory_data=self.memory_data,
-            device_transform=(self._device_transform and phase == "TRAIN"))
+        with span_recorder.startup("pipeline_open", {"phase": phase}):
+            return build_phase_pipelines(
+                net_param, phase, batch_multiplier=local,
+                shard=shard if shard is not None else self._data_shard,
+                memory_data=self.memory_data,
+                device_transform=(self._device_transform
+                                  and phase == "TRAIN"))
 
     @staticmethod
     def donates_batch(use_prefetch: bool, backend: str,
@@ -769,6 +811,8 @@ class Engine:
         batch shapes, which exist only once the first batch is drawn).
         ``it`` only labels the ``dispatch_execute`` span."""
         first = not self._placement_recorded
+        # the process's first step ends the start-up phase (runtime/spans.py)
+        starting = first and span_recorder.startup_open
         if first:
             # read BEFORE the dispatch: the step donates the batch
             sample = next(iter(batch.values()))
@@ -784,8 +828,10 @@ class Engine:
                     "executable to read: no compile-cache directory, SSP, "
                     "an HDF5 dump, iter_size > 1, a sharding plan or a "
                     "multi-process world"))
-        with span_recorder.span("dispatch_execute", "step",
-                                None if it is None else {"iter": it}):
+        tag = None if it is None else {"iter": it}
+        if starting:
+            first_step = span_recorder.startup("first_step", tag).__enter__()
+        with span_recorder.span("dispatch_execute", "step", tag):
             if self._aot_exec is not None:
                 # the lowerable's raw signature carries the (empty — AOT
                 # is disabled under HDF5_OUTPUT) dump slot; keep the
@@ -796,9 +842,24 @@ class Engine:
             else:
                 out = self.train_step.step(self.params, self.state, batch,
                                            rng)
+        if starting:
+            self._end_startup(first_step, out)
         if first:
             self._record_placement(sample.shape, batch_devs, out[0])
         return out
+
+    def _end_startup(self, first_step, out) -> None:
+        """The process's first step has been dispatched: wait until its
+        result is ready (the program's load onto the chip and the step's
+        own run are the open span ``first_step``'s, once), then the
+        recorder's start-up phase closes and its summary becomes stats
+        section ``startup``, the step's route first (``jit`` when no
+        executable was resolved)."""
+        jax.block_until_ready(out)
+        first_step.__exit__(None, None, None)
+        doc = span_recorder.end_startup()
+        self.stats.set_section("startup",
+                               {"route": doc.pop("route", "jit"), **doc})
 
     def _publish_step_scopes(self, text: Optional[str],
                              why: str = "") -> None:
@@ -833,23 +894,37 @@ class Engine:
     # static shapes/mesh ints), never steady-state:
     def _resolve_aot_step(self, batch, rng) -> None:  # static-ok: JIT102
         """Load — or compile + serialize — the step executable for this
-        exact (model, shapes, mesh, backend, policy) key. Best-effort:
-        any failure pins the jit path for the rest of the run (which the
-        persistent compile cache still accelerates)."""
-        from ..config import compile_cache_config, policy
-        from ..ops.pallas_kernels import LOWERING_ENV
-        from .attribution import param_relayouts
-        from .compile_cache import (code_fingerprint, load_step_executable,
-                                    save_step_executable, step_key,
-                                    watch_cache_hits)
-        from .hlo_comm import count_gradient_all_reduces
-        # where the seconds go, phase by phase: set-up runs before any
-        # span recorder is on, so it gets timers instead
-        marks = [("load_s", time.perf_counter())]
+        exact (model, shapes, mesh, backend, policy) key, under the
+        start-up span ``step_load``, and publish stats section
+        ``compiled_step``: what the step is and, read off the spans that
+        closed under ``step_load``, where its seconds went."""
+        with span_recorder.startup("step_load") as load:
+            doc = self._load_or_compile_step(batch, rng, load)
+        span_recorder.note(
+            route=doc["source"], key=load.args.get("key", ""),
+            pallas_custom_calls=doc.get("pallas_custom_calls", 0))
+        # under the keys they have always had
+        doc["phases"] = {
+            phase: round(sum(load.children.get(n, 0.0) for n in names), 3)
+            for phase, names in (
+                ("load_s", ("step_key", "aot_read", "aot_unpack",
+                            "aot_deserialize")),
+                ("trace_lower_s", ("step_trace_lower",)),
+                ("compile_s", ("step_compile",)),
+                ("store_s", ("aot_store",)),
+                ("text_s", ("step_text",)),
+                ("scope_map_s", ("scope_map",)))}
+        doc["seconds"] = round(load.dur_s, 3)
+        self.stats.set_section("compiled_step", doc)
 
-        def phase(name: str) -> None:
-            marks.append((name, time.perf_counter()))
-
+    def _load_or_compile_step(  # static-ok: JIT102
+            self, batch, rng, load) -> Dict[str, Any]:
+        """``_resolve_aot_step``'s work; returns the ``compiled_step``
+        section without its timings. Best-effort: any failure pins the
+        jit path for the rest of the run (which the persistent compile
+        cache still accelerates). ``load`` is the open ``step_load`` span,
+        which is told the key and the route."""
+        startup = span_recorder.startup
         # which form the step took, readable without the HLO: where the
         # optimizer update runs and how many buckets its gradients are
         # summed in (0: one device, or per-leaf collectives)
@@ -858,58 +933,34 @@ class Engine:
             "source": "jit", "update_route": self.train_step.update_route,
             "grad_buckets": arena.n_buckets if arena is not None else 0}
         try:
-            cfg = compile_cache_config()
-            key = step_key(
-                kind="train_step",
-                model=self.train_net.name or "net",
-                params={l: {p: (list(v.shape), str(v.dtype))
-                            for p, v in ps.items()}
-                        for l, ps in self.params.items()},
-                batch={k: (list(v.shape), str(v.dtype))
-                       for k, v in batch.items()},
-                mesh={k: int(v) for k, v in self.mesh.shape.items()},
-                backend=jax.default_backend(),
-                device_kind=jax.devices()[0].device_kind,
-                n_devices=self.n_dev,
-                jax_version=jax.__version__,
-                code=code_fingerprint(),
-                lowering_env={k: os.environ.get(k, "")
-                              for k in LOWERING_ENV},
-                numeric_policy=str(policy()),
-                conv_layout=self.train_net.conv_layout,
-                # compile-RELEVANT solver fields only: max_iter/display/
-                # snapshot cadence never reach the traced program, and
-                # folding them in would defeat the warm start for the
-                # standard resume-and-train-longer flow
-                solver={k: str(getattr(self.sp, k, None)) for k in (
-                    "solver_type", "base_lr", "lr_policy", "gamma",
-                    "power", "stepsize", "stepvalue", "momentum",
-                    "momentum2", "weight_decay", "regularization_type",
-                    "delta", "clip_gradients", "iter_size",
-                    "random_seed")},
-                comm=str(self.comm),
-                donate_batch=self._donate_batch,
-                # what runs under which checkpoint is part of the program
-                remat=self.remat_plan.units if self.remat_plan else ())
+            with startup("step_key"):
+                from ..config import compile_cache_config
+                from .attribution import param_relayouts
+                from .compile_cache import (load_step_executable,
+                                            save_step_executable,
+                                            watch_cache_hits)
+                from .hlo_comm import count_gradient_all_reduces
+                cfg = compile_cache_config()
+                key = self._aot_step_key(batch)
+            load.args["key"] = key[:12]
             exec_ = load_step_executable(cfg.cache_dir, key)
             source, stored = "loaded", "found"
             if exec_ is None:
                 source = "compiled"
                 low = self.train_step.lowerable or self.train_step.step
-                phase("trace_lower_s")
-                lowered = low.lower(self.params, self.state, batch, rng)
-                phase("compile_s")
-                with watch_cache_hits() as hits:
+                with startup("step_trace_lower"):
+                    lowered = low.lower(self.params, self.state, batch, rng)
+                with startup("step_compile"), watch_cache_hits() as hits:
                     exec_ = lowered.compile()
-                phase("store_s")
                 if hits:
                     # the XLA cache answered: trace paid, compile skipped;
                     # what it hands back is not re-serialized (see
                     # compile_cache.watch_cache_hits)
                     source, stored = "xla_cache", "no (xla cache hit)"
                 else:
-                    stored = "yes" if save_step_executable(
-                        cfg.cache_dir, key, exec_) else "no (see log)"
+                    with startup("aot_store"):
+                        stored = "yes" if save_step_executable(
+                            cfg.cache_dir, key, exec_) else "no (see log)"
             # the executable is good from here on, whatever the store did
             self._aot_exec = exec_
             log("aot warm start: " + {
@@ -924,14 +975,16 @@ class Engine:
             # routed to XLA), the arena's gradient all-reduces, and the
             # layout copies between the step's parameters and its results
             doc.update(source=source, stored=stored)
-            phase("text_s")
-            text = exec_.as_text()
-            doc["pallas_custom_calls"] = text.count(
-                'custom_call_target="tpu_custom_call"')
-            doc["gradient_all_reduces"] = count_gradient_all_reduces(text)
-            doc["param_relayouts"] = param_relayouts(text)
-            phase("scope_map_s")
-            self._publish_step_scopes(text)
+            load.args["route"] = source
+            with startup("step_text"):
+                text = exec_.as_text()
+                doc["pallas_custom_calls"] = text.count(
+                    'custom_call_target="tpu_custom_call"')
+                doc["gradient_all_reduces"] = count_gradient_all_reduces(
+                    text)
+                doc["param_relayouts"] = param_relayouts(text)
+            with startup("scope_map"):
+                self._publish_step_scopes(text)
         except Exception as e:  # noqa: BLE001 — warm start is best-effort
             # never silent: the reason goes to the log with its traceback
             # and into stats.yaml, where chip_smoke.py reads it
@@ -944,14 +997,46 @@ class Engine:
                 + "\n" + traceback.format_exc(), rank=self.rank)
             if self._aot_exec is not None:
                 self._publish_step_scopes(None, doc["error"])
-        phase("")
-        doc["phases"] = dict.fromkeys(
-            ("load_s", "trace_lower_s", "compile_s", "store_s", "text_s",
-             "scope_map_s"), 0.0)
-        for (name, t), (_, t_next) in zip(marks, marks[1:]):
-            doc["phases"][name] = round(doc["phases"][name] + t_next - t, 3)
-        doc["seconds"] = round(marks[-1][1] - marks[0][1], 3)
-        self.stats.set_section("compiled_step", doc)
+        return doc
+
+    def _aot_step_key(self, batch) -> str:  # static-ok: JIT102
+        """The AOT store's key for this job's train step: everything that
+        changes the compiled program."""
+        from ..config import policy
+        from ..ops.pallas_kernels import LOWERING_ENV
+        from .compile_cache import code_fingerprint, step_key
+        return step_key(
+            kind="train_step",
+            model=self.train_net.name or "net",
+            params={l: {p: (list(v.shape), str(v.dtype))
+                        for p, v in ps.items()}
+                    for l, ps in self.params.items()},
+            batch={k: (list(v.shape), str(v.dtype))
+                   for k, v in batch.items()},
+            mesh={k: int(v) for k, v in self.mesh.shape.items()},
+            backend=jax.default_backend(),
+            device_kind=jax.devices()[0].device_kind,
+            n_devices=self.n_dev,
+            jax_version=jax.__version__,
+            code=code_fingerprint(),
+            lowering_env={k: os.environ.get(k, "")
+                          for k in LOWERING_ENV},
+            numeric_policy=str(policy()),
+            conv_layout=self.train_net.conv_layout,
+            # compile-RELEVANT solver fields only: max_iter/display/
+            # snapshot cadence never reach the traced program, and
+            # folding them in would defeat the warm start for the
+            # standard resume-and-train-longer flow
+            solver={k: str(getattr(self.sp, k, None)) for k in (
+                "solver_type", "base_lr", "lr_policy", "gamma",
+                "power", "stepsize", "stepvalue", "momentum",
+                "momentum2", "weight_decay", "regularization_type",
+                "delta", "clip_gradients", "iter_size",
+                "random_seed")},
+            comm=str(self.comm),
+            donate_batch=self._donate_batch,
+            # what runs under which checkpoint is part of the program
+            remat=self.remat_plan.units if self.remat_plan else ())
 
     # ---------------------------------------------------------------- #
     def iteration(self) -> int:
@@ -959,6 +1044,11 @@ class Engine:
                    else self.state.solver.it)
 
     def restore_from(self, path: str):
+        with span_recorder.startup("restore",
+                                   {"file": os.path.basename(path)}):
+            self._restore_from(path)
+
+    def _restore_from(self, path: str) -> None:
         if path.endswith(".caffemodel"):
             self.params = load_caffemodel(path, self.train_net, self.params)
             if self.staleness > 0:
@@ -1158,10 +1248,14 @@ class Engine:
         profiling = False
 
         if sp.test_interval and sp.test_initialization and self.test_nets:
-            for i in range(len(self.test_nets)):
-                self.test(i)
-                self.test_metrics[i].flush_row(it)
+            with span_recorder.startup("initial_test"):
+                for i in range(len(self.test_nets)):
+                    self.test(i)
+                    self.test_metrics[i].flush_row(it)
 
+        # until the process's first step is done, the loop's first turn is
+        # on the start-up timeline (runtime/spans.py)
+        starting = span_recorder.startup_open
         try:
             while it < max_iter:
                 if sp.snapshot and it > 0 and it % sp.snapshot == 0:
@@ -1212,6 +1306,9 @@ class Engine:
                         if room >= self.steps_per_dispatch:
                             chunk = self.steps_per_dispatch
 
+                    if starting:
+                        first_wait = span_recorder.startup(
+                            "first_batch_wait", {"iter": it}).__enter__()
                     if chunk > 1:
                         t_in = time.perf_counter()
                         with span_recorder.span(
@@ -1225,15 +1322,22 @@ class Engine:
                         self._batches_taken += chunk * self.iter_size
                         self.stats.add_time("input_stall",
                                             time.perf_counter() - t_in)
+                        if starting:
+                            first_wait.__exit__(None, None, None)
                         t0 = time.time()
                         # the scan step folds rng by global iteration
                         # internally (solver.it + offset): pass the session
                         # rng unfolded so a chunked run's per-step streams
                         # match single-step dispatch
-                        with span_recorder.span("dispatch", "step",
-                                                {"iter": it, "chunk": chunk}):
+                        tag = {"iter": it, "chunk": chunk}
+                        if starting:
+                            first_step = span_recorder.startup(
+                                "first_step", tag).__enter__()
+                        with span_recorder.span("dispatch", "step", tag):
                             self.params, self.state, m = self._scan_step.step(
                                 self.params, self.state, batch, self.rng)
+                        if starting:
+                            self._end_startup(first_step, m)
                         it += chunk
                         at_display = bool(sp.display) and it % sp.display == 0
                     else:
@@ -1261,6 +1365,8 @@ class Engine:
                         self._batches_taken += self.iter_size
                         self.stats.add_time("input_stall",
                                             time.perf_counter() - t_in)
+                        if starting:
+                            first_wait.__exit__(None, None, None)
                         at_display = bool(sp.display) and \
                             (it + 1) % sp.display == 0
                         if at_display and self._debug_fn:
@@ -1308,6 +1414,7 @@ class Engine:
                                             {"iter": it}):
                         fetcher.put(it - chunk, m)
                     self._check_divergence(fetcher)
+                    starting = False
                     self.stats.add("train_iters", chunk)
                     self.stats.add_time("train_step", time.time() - t0)
                     if self._async_tier is not None:
@@ -1356,8 +1463,6 @@ class Engine:
                 last = self._absorb(fetcher.sync(), last)
             self._check_divergence(fetcher)
         finally:
-            self.stats.counters["steps_in_flight"] = round(
-                fetcher.mean_in_flight(), 3)
             fetcher.close()
             if profiling:
                 jax.profiler.stop_trace()

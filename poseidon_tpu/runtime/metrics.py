@@ -299,8 +299,6 @@ class AsyncScalarFetcher:
         self._pending = 0               # dispatches not yet materialized
         self._error: Optional[Exception] = None
         self._closed = False
-        self._puts = 0
-        self._pending_sum = 0
         self._thread = threading.Thread(target=self._drain_loop, daemon=True)
         self._thread.start()
 
@@ -337,8 +335,6 @@ class AsyncScalarFetcher:
                 raise self._error
             inline = (self._pending == 0 and not self._inbox
                       and self._already_ready(metrics))
-            self._puts += 1
-            self._pending_sum += 1 if inline else self._pending + 1
             if not inline:
                 self._pending += 1
                 self._inbox.append((first_iter, metrics))
@@ -376,12 +372,6 @@ class AsyncScalarFetcher:
             out = list(self._drained)
             self._drained.clear()
         return out
-
-    def mean_in_flight(self) -> float:
-        """Average window occupancy observed at dispatch time (1.0 = the
-        serial loop; -> max_in_flight as the pipeline fills)."""
-        with self._cond:
-            return self._pending_sum / self._puts if self._puts else 0.0
 
     def close(self) -> None:
         with self._cond:

@@ -9,7 +9,11 @@
   xplane's host plane, per thread, with ``train`` steps numbered;
 - what a disabled recorder costs: nothing allocated, no collector hook;
 - ``--trace_out`` no longer re-serializes the span buffer inside a display
-  interval.
+  interval;
+- set-up (ISSUE 34): until the first step is done the recorder keeps the
+  spans of category ``startup`` whatever its state, the Engine summarises
+  them once into stats section ``startup``, and ``compiled_step.phases`` /
+  ``.seconds`` and the timer ``engine_build`` are read off those spans.
 """
 
 import gc
@@ -80,6 +84,8 @@ def _engine(out, **kw):
 def clean_recorder():
     global_rec.disable()
     global_rec.clear()
+    if global_rec.startup_open:     # whichever test of the process is first
+        global_rec.end_startup()
     yield global_rec
     global_rec.disable()
     global_rec.clear()
@@ -626,3 +632,392 @@ def test_trace_out_is_not_rewritten_inside_display_intervals(
     assert len(dump_spans) == 4
     assert (tmp_path / "spans.json").exists()
     assert (tmp_path / "stats.yaml").exists()
+
+
+# --------------------------------------------------------------------------- #
+# E. set-up: the start-up phase of the recorder, and stats section `startup`
+# --------------------------------------------------------------------------- #
+
+STARTUP_NET = """
+name: "StartupNet"
+layers {
+  name: "src" type: DATA top: "data" top: "label"
+  data_param { source: "%s" batch_size: 8 backend: LMDB }
+  transform_param { scale: 0.00390625 }
+}
+layers {
+  name: "conv1" type: CONVOLUTION bottom: "data" top: "conv1"
+  convolution_param { num_output: 8 kernel_size: 5 stride: 2
+    weight_filler { type: "xavier" } bias_filler { type: "constant" } }
+}
+layers { name: "relu1" type: RELU bottom: "conv1" top: "conv1" }
+layers { name: "pool1" type: POOLING bottom: "conv1" top: "pool1"
+  pooling_param { pool: MAX kernel_size: 2 stride: 2 } }
+layers {
+  name: "ip1" type: INNER_PRODUCT bottom: "pool1" top: "ip1"
+  inner_product_param { num_output: 10
+    weight_filler { type: "xavier" } bias_filler { type: "constant" } }
+}
+layers { name: "loss" type: SOFTMAX_LOSS bottom: "ip1" bottom: "label"
+  top: "loss" }
+"""
+
+STARTUP_SOLVER = """
+net: "%s"
+base_lr: 0.01
+lr_policy: "fixed"
+momentum: 0.9
+display: 4
+max_iter: 11
+random_seed: 3
+snapshot: 0
+snapshot_after_train: false
+"""
+
+
+@pytest.fixture(scope="module")
+def startup_runs(tmp_path_factory):
+    """The user's ``train`` command twice in this process, each with the
+    start-up phase opened afresh, against ONE compile-cache directory: a
+    cold run (plain) and a warm one (``--trace_out``). What each left:
+    the ``startup`` and ``compiled_step`` sections, the phase's events,
+    stats.yaml, the state of the recorder after the run, the dumped
+    timeline."""
+    import json
+
+    from conftest import _set_jax_cache_dir
+    from poseidon_tpu.runtime import cli
+    from poseidon_tpu.runtime.engine import Engine
+    from poseidon_tpu.runtime.metrics import read_stats_yaml
+
+    root = tmp_path_factory.mktemp("startup")
+    lmdb = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "examples", "mnist", "mnist_train_lmdb")
+    (root / "net.prototxt").write_text(STARTUP_NET % lmdb)
+    (root / "solver.prototxt").write_text(
+        STARTUP_SOLVER % (root / "net.prototxt"))
+    built = []
+    real_init = Engine.__init__
+
+    def keeping(self, *args, **kwargs):
+        built.append(self)
+        real_init(self, *args, **kwargs)
+
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    runs = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("JAX_COMPILATION_CACHE_DIR", str(root / "cc"))
+        _set_jax_cache_dir(str(root / "cc"))
+        mp.setattr(Engine, "__init__", keeping)
+        for run, extra in (("cold", []),
+                           ("warm", ["--trace_out", "spans.json"])):
+            global_rec.disable()
+            global_rec.clear()
+            global_rec._begin_startup()     # a second `train` in one process
+            out = root / run
+            assert cli.main(["train", "--solver",
+                             str(root / "solver.prototxt"),
+                             "--output_dir", str(out)] + extra) == 0
+            snap = built[-1].stats.snapshot()
+            window = global_rec.trace_events()
+            phase = global_rec.trace_events(startup=True)
+            runs[run] = {
+                "sections": snap["sections"], "timers": snap["timers_sec"],
+                "events": phase[:len(phase) - len(window)],
+                "window": window,
+                "yaml": read_stats_yaml(str(out / "stats.yaml")),
+                "enabled_after": global_rec.enabled,
+                "open_after": global_rec.startup_open,
+                "null_after": global_rec.span("x") is spans_mod.NULL_SPAN}
+        with open(root / "warm" / "spans.json") as f:
+            runs["warm"]["dump"] = json.load(f)
+    _set_jax_cache_dir(before)
+    global_rec.clear()
+    return runs
+
+
+BOTH = pytest.mark.parametrize("run, route", [("cold", "compiled"),
+                                              ("warm", "loaded")])
+TOP_LEVEL = ["cli_setup", "engine_build", "first_batch_wait", "step_load",
+             "first_step"]
+
+
+@BOTH
+def test_startup_section_says_the_route_and_counts(startup_runs, run, route):
+    sec = startup_runs[run]["sections"]["startup"]
+    assert sec["route"] == route
+    assert sec["route"] == \
+        startup_runs[run]["sections"]["compiled_step"]["source"]
+    assert len(sec["key"]) == 12 and int(sec["key"], 16) >= 0
+    assert sec["events_dropped"] == 0 and sec["pallas_custom_calls"] == 0
+    # the step's bytes, written on the cold run and read back on the warm
+    assert sec["aot_bytes_serialized"] > sec["aot_bytes_on_disk"] > 0
+    assert sec["aot_bytes_serialized"] == startup_runs["cold"]["sections"][
+        "startup"]["aot_bytes_serialized"]
+    # parameter init, rng and the rest compile outside the step's load
+    # (in a process of its own; here jit's own caches may hold them all,
+    # and on the second run do), into a cache directory that starts empty
+    assert sec["compiles"] >= 0 and sec["compile_s"] >= 0
+    assert sec["compiles"] <= startup_runs["cold"]["sections"]["startup"][
+        "compiles"]
+    assert sec["xla_cache_hits"] == 0
+    assert startup_runs[run]["open_after"] is False
+
+
+@BOTH
+def test_startup_timeline_is_ordered_and_covers_the_stretch(
+        startup_runs, run, route):
+    sec = startup_runs[run]["sections"]["startup"]
+    assert list(sec["timeline"]) == TOP_LEVEL
+    at = [row["at_s"] for row in sec["timeline"].values()]
+    assert at == sorted(at) and at[0] >= 0
+    ends = [row["at_s"] + row["dur_s"] for row in sec["timeline"].values()]
+    assert all(b >= a - 0.002 for a, b in zip(ends, at[1:]))   # no overlap
+    assert 0.9 <= sec["coverage"] <= 1.0
+    assert sec["stretch_s"] == pytest.approx(ends[-1] - at[0], abs=0.005)
+    assert sec["spans"]["first_step"] > 0
+    want = {"compiled": {"step_trace_lower", "step_compile", "aot_store",
+                         "aot_serialize", "aot_pack", "aot_write"},
+            "loaded": {"aot_read", "aot_unpack", "aot_deserialize"}}
+    assert want[route] <= set(sec["spans"])
+    assert not want["loaded" if route == "compiled" else "compiled"] \
+        & set(sec["spans"])
+    assert {"backend_init", "net_build", "pipeline_open", "step_build",
+            "param_init", "step_key", "step_text",
+            "scope_map"} <= set(sec["spans"])
+    # the step's own compile is an event of the phase, inside its load
+    assert ("compile" in sec["spans"]) or route == "loaded"
+
+
+@BOTH
+def test_every_startup_span_lies_inside_its_parent(startup_runs, run, route):
+    events = startup_runs[run]["events"]
+    by_name = _by_name(events)
+    parents = {"backend_init": "cli_setup", "net_build": "engine_build",
+               "pipeline_open": "engine_build", "step_build": "engine_build",
+               "param_init": "engine_build", "step_key": "step_load",
+               "step_text": "step_load", "scope_map": "step_load",
+               "aot_read": "step_load", "aot_unpack": "step_load",
+               "aot_deserialize": "step_load",
+               "step_trace_lower": "step_load", "step_compile": "step_load",
+               "aot_store": "step_load", "aot_serialize": "aot_store",
+               "aot_pack": "aot_store", "aot_write": "aot_store"}
+    checked = 0
+    for e in events:
+        parent = (e.get("args") or {}).get("parent")
+        if e["name"] in TOP_LEVEL:
+            assert parent is None, e
+            continue
+        if e["name"] != "compile":
+            assert parent == parents[e["name"]], e
+        outer = by_name[parent][0]      # the containers are there once
+        assert len(by_name[parent]) == 1
+        # a compile's start is its end less the duration jax reports, on
+        # another clock: half a millisecond of grace
+        assert outer["ts"] - 500.0 <= e["ts"]
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1.0
+        checked += 1
+    assert checked >= 12
+    assert by_name["first_step"][0]["args"] == {"iter": 0}
+    assert by_name["first_batch_wait"][0]["args"] == {"iter": 0}
+    load = by_name["step_load"][0]["args"]
+    assert load == {"route": route, "key": startup_runs[run]["sections"][
+        "startup"]["key"]}
+    assert all(e["args"].get("program") for e in by_name.get("compile", ()))
+    assert ("compile" in by_name) or route == "loaded"
+
+
+@BOTH
+def test_compiled_step_timings_are_read_off_the_step_load_spans(
+        startup_runs, run, route):
+    events = startup_runs[run]["events"]
+    load = _by_name(events)["step_load"][0]
+    kids = [e for e in events
+            if (e.get("args") or {}).get("parent") == "step_load"
+            and e["name"] != "compile"]
+    # the children tile the load: within 1% (half a millisecond on a step
+    # this small, whose warm load is 30 ms)
+    assert sum(e["dur"] for e in kids) == pytest.approx(
+        load["dur"], rel=0.01, abs=500.0)
+    step = startup_runs[run]["sections"]["compiled_step"]
+    assert step["seconds"] == pytest.approx(load["dur"] / 1e6, abs=0.0006)
+    assert list(step["phases"]) == ["load_s", "trace_lower_s", "compile_s",
+                                    "store_s", "text_s", "scope_map_s"]
+    assert sum(step["phases"].values()) == pytest.approx(
+        step["seconds"], rel=0.01, abs=0.004)
+    spans = startup_runs[run]["sections"]["startup"]["spans"]
+    assert step["phases"]["compile_s"] == spans.get("step_compile", 0.0)
+    assert step["phases"]["store_s"] == spans.get("aot_store", 0.0)
+    assert (step["phases"]["compile_s"] > 0) == (route == "compiled")
+    assert (step["phases"]["load_s"] > spans["step_key"]) \
+        == (route == "loaded")
+    assert startup_runs[run]["timers"]["engine_build"] == pytest.approx(
+        spans["engine_build"], abs=0.0006)
+
+
+@BOTH
+def test_stats_yaml_of_a_plain_train_carries_the_startup_section(
+        startup_runs, run, route):
+    doc = startup_runs[run]["yaml"]["startup"]
+    assert doc["route"] == route
+    assert list(doc["timeline"]) == TOP_LEVEL
+    for row in doc["timeline"].values():
+        assert float(row["at_s"]) >= 0 and float(row["dur_s"]) >= 0
+    for key in ("key", "coverage", "stretch_s", "compiles", "compile_s",
+                "xla_cache_hits", "events_dropped", "aot_bytes_on_disk",
+                "aot_bytes_serialized", "pallas_custom_calls"):
+        assert key in doc, key
+    assert float(doc["spans"]["engine_build"]) > 0
+
+
+def test_recorder_is_as_before_once_the_first_step_is_done(startup_runs):
+    """A plain run: after step 0 the recorder is disabled again, its window
+    holds nothing of the ten steps that followed, no start-up event belongs
+    to a later step, and ``span()`` hands out the shared null span."""
+    cold = startup_runs["cold"]
+    assert cold["enabled_after"] is False and cold["null_after"] is True
+    assert cold["window"] == []
+    assert cold["sections"]["startup"]["spans"]["first_step"] > 0
+    assert {e["args"]["iter"] for e in cold["events"]
+            if "iter" in (e.get("args") or {})} == {0}
+    assert len([e for e in cold["events"] if e["name"] == "first_step"]) == 1
+    assert len(cold["events"]) < 100            # a few dozen, not per step
+
+
+def test_trace_out_timeline_holds_the_startup_spans_on_the_steps_clock(
+        startup_runs):
+    """Under ``--trace_out`` the Engine clears the recorder when it takes
+    it and the phase's spans are still in the dump, ahead of the window and
+    on its clock: ``first_step`` ends before iteration 1 is dispatched."""
+    events = startup_runs["warm"]["dump"]["traceEvents"]
+    by_name = _by_name(events)
+    for name in TOP_LEVEL + ["backend_init", "param_init", "aot_deserialize",
+                             "step_text"]:
+        assert by_name[name][0]["cat"] == "startup", name
+    first = by_name["first_step"][0]
+    next_dispatch = next(e for e in by_name["dispatch"]
+                         if e["args"]["iter"] == 1)
+    own_dispatch = next(e for e in by_name["dispatch"]
+                        if e["args"]["iter"] == 0)
+    assert own_dispatch["ts"] <= first["ts"]
+    assert first["ts"] + first["dur"] <= next_dispatch["ts"]
+    assert by_name["cli_setup"][0]["ts"] < by_name["engine_build"][0]["ts"] \
+        < first["ts"]
+    assert len(by_name["dispatch"]) == 11 and len(by_name["first_step"]) == 1
+    assert startup_runs["warm"]["dump"]["metadata"][
+        "dropped_startup_spans"] == 0
+    # the phase's compiles are there once
+    assert len([e for e in by_name.get("compile", ())
+                if e["ts"] < first["ts"]]) == len(
+        [e for e in startup_runs["warm"]["events"] if e["name"] == "compile"])
+
+
+def test_startup_cap_drops_and_counts():
+    rec = SpanRecorder()
+    rec.startup_cap = 4
+    for i in range(7):
+        with rec.startup("net_build", {"i": i}):
+            pass
+    assert rec.startup_dropped == 3
+    assert len(rec.trace_events(startup=True)) == 4
+    doc = rec.end_startup()
+    assert doc["events_dropped"] == 3
+    assert doc["timeline"]["net_build"]["n"] == 4
+    assert SpanRecorder.startup_cap == 512
+
+
+@pytest.mark.parametrize("state, kept", [
+    ("open", "startup"), ("closed_disabled", None),
+    ("closed_enabled", "window")])
+def test_startup_span_always_times_and_is_kept_by_the_state(state, kept):
+    """A start-up span is the timer of its region for every Engine of a
+    process, so it times whatever the recorder's state; where the event goes
+    is the state's: the phase while it is open, afterwards the window of an
+    enabled recorder, else nowhere."""
+    rec = SpanRecorder()
+    if state != "open":
+        rec.end_startup()
+    if state == "closed_enabled":
+        rec.enable()
+    try:
+        with rec.startup("engine_build") as outer:
+            with rec.startup("param_init", {"iter": 0}) as inner:
+                time.sleep(0.002)
+            rec.complete("compile", 0.001, "runtime", {"program": "jit(f)"})
+    finally:
+        rec.disable()
+    assert inner.dur_s >= 0.002 and outer.dur_s >= inner.dur_s
+    assert outer.children == {"param_init": inner.dur_s}
+    assert inner.args == {"iter": 0, "parent": "engine_build"}
+    window = rec.trace_events()
+    phase = [e for e in rec.trace_events(startup=True) if e not in window]
+    names = {"startup": [e["name"] for e in phase],
+             "window": [e["name"] for e in window], None: []}[kept]
+    assert names == (["param_init", "compile", "engine_build"] if kept
+                     else [])
+    assert (phase == []) == (kept != "startup")
+    assert (window == []) == (kept != "window")
+    if kept == "startup":
+        assert phase[1]["args"] == {"program": "jit(f)",
+                                    "parent": "engine_build"}
+        # a compile keeps the category its reporter gave it, so that it
+        # is no row of the timeline and no named time of its own
+        assert [e["cat"] for e in phase] == ["startup", "runtime", "startup"]
+    # the hot path's span() is what it was: the shared null span unless the
+    # recorder is enabled, whatever the phase
+    assert rec.span("dispatch") is spans_mod.NULL_SPAN
+
+
+def test_startup_summary_counts_compiles_outside_the_step_load():
+    rec = SpanRecorder()
+    with rec.startup("engine_build"):
+        time.sleep(0.003)
+        rec.complete("compile", 0.002, "runtime", {"program": "jit(init)"})
+    time.sleep(0.002)                           # a caller's gap, and in
+    # it a compile on a thread with no start-up span open (a harness's own)
+    rec.complete("compile", 0.002, "runtime", {"program": "jit(theirs)"})
+    with rec.startup("step_load"):
+        time.sleep(0.003)
+        rec.complete("compile", 0.002, "runtime", {"program": "jit(step)"})
+    rec.note(add=True, xla_cache_hits=1)
+    rec.note(route="compiled")
+    with rec.startup("first_step", {"iter": 0}):
+        time.sleep(0.001)
+    doc = rec.end_startup()
+    assert doc["compiles"] == 2 and doc["compile_s"] == pytest.approx(
+        0.004, abs=1e-6)
+    assert doc["spans"]["compile"] == pytest.approx(0.006, abs=1e-6)
+    assert doc["xla_cache_hits"] == 1 and doc["route"] == "compiled"
+    assert list(doc["timeline"]) == ["engine_build", "step_load",
+                                     "first_step"]
+    # the gap is not named: a compile no span of the program owns is no
+    # row of the timeline and no named time
+    assert 0.5 < doc["coverage"] < 0.9
+    # once closed, facts and counts are not taken any more
+    rec.note(route="x")
+    rec.note(add=True, xla_cache_hits=1)
+    assert rec.startup_open is False and rec.end_startup()["route"] == \
+        "compiled" and rec.end_startup()["xla_cache_hits"] == 1
+
+
+def test_exception_inside_a_startup_span_leaves_no_open_parent():
+    rec = SpanRecorder()
+    with pytest.raises(ValueError):
+        with rec.startup("engine_build"):
+            leaked = rec.startup("net_build")
+            leaked.__enter__()          # never closed: the build raised
+            raise ValueError("bad prototxt")
+    with rec.startup("engine_build") as again:
+        pass
+    assert again.parent is None and "parent" not in again.args
+    # `Engine.__init__` opens its span by hand; one a failed build left open
+    # is no parent of the next build's, and closing it late harms nothing
+    stale = rec.startup("engine_build").__enter__()
+    rec.startup("net_build").__enter__()
+    with rec.startup("engine_build") as third:
+        with rec.startup("net_build") as inner:
+            pass
+    assert third.parent is None and inner.parent is third
+    stale.__exit__(None, None, None)
+    assert rec._open_startup_spans() == []
