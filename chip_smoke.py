@@ -63,7 +63,9 @@ class Size:
         # the real kernel geometries (N, C, H). Size-5 LRN: AlexNet's two
         # and GoogLeNet's two norm layers at the benchmark cells' batch a
         # chip (batch-minor operands; the tiny one is channel-minor).
-        # 3x3 stride-2 max pools: AlexNet's pool1, pool2, pool5
+        # 3x3 stride-2 max pools: AlexNet's pool1, pool2, pool5, and pool1
+        # at a batch of 4 a chip (the kernel's channel-minor orientation,
+        # which no benchmark cell runs)
         self.lrn = ([("norm1", (2, 12, 14))] if tiny else
                     [("alexnet norm1", (512, 96, 55)),
                      ("alexnet norm2", (512, 256, 27)),
@@ -71,7 +73,8 @@ class Size:
                      ("googlenet norm2", (128, 192, 56))])
         self.pool = ([("pool1", (2, 12, 15))] if tiny else
                      [("pool1", (256, 96, 55)), ("pool2", (256, 256, 27)),
-                      ("pool5", (256, 256, 13))])
+                      ("pool5", (256, 256, 13)),
+                      ("pool1 channel-minor", (4, 96, 55))])
 
 
 # --------------------------------------------------------------------------- #
@@ -156,9 +159,9 @@ def open_device(tiny: bool) -> dict:
         raise SystemExit(2)
     if tiny:
         check(dev.platform == "cpu", "--cpu-tiny is the CPU rehearsal")
-        # the rehearsal takes the TPU's arms too: select-and-scatter pool
-        # backward, the Pallas LRN kernels through the interpreter
-        os.environ["POSEIDON_POOL_BWD"] = "sas"
+        # the rehearsal takes the TPU's arms too: the Pallas max-pool
+        # backward and LRN kernels, through the interpreter
+        os.environ["POSEIDON_POOL_BWD"] = "pallas"
         os.environ["POSEIDON_PALLAS_LRN"] = "1"
     else:
         check(staged, "async collective flags were not staged")
@@ -218,25 +221,27 @@ def check_run(name: str, run: dict, size: Size, device: dict, *,
     check(stats["device"]["platform"] == device["platform"]
           and int(stats["device"]["count"]) == n_dev,
           f"{name}: engine ran on {stats['device']}")
-    # nothing fell off the device path: every pool backward took XLA's
-    # select-and-scatter and every LRN its Pallas arm, and the step that
-    # ran holds exactly those kernels (an LRN forward + backward is two
-    # custom calls, a pool backward none)
+    # nothing fell off the device path: every (MAX) pool backward and
+    # every LRN took its Pallas arm, and the step that ran holds exactly
+    # those kernels (an LRN forward + backward is two custom calls, a pool
+    # backward one)
     routes = stats["kernel_routes"]
-    # the LRN operands' orientation follows the batch a device: the
+    # the kernels' operand orientation follows the batch a device: the
     # example's 256 fills the lanes, the rehearsal's 2 does not
-    lrn = f"lrn=pallas ({'channel' if size.tiny else 'batch'}-minor "
+    minor = f"({'channel' if size.tiny else 'batch'}-minor "
     check(set(routes) == {"norm1", "norm2", "pool1", "pool2", "pool5"}
-          and all(routes[k].startswith(lrn) for k in ("norm1", "norm2"))
-          and all(routes[k] == "pool_bwd=sas"
+          and all(routes[k].startswith("lrn=pallas " + minor)
+                  for k in ("norm1", "norm2"))
+          and all(routes[k].startswith("pool_bwd=pallas " + minor)
                   for k in ("pool1", "pool2", "pool5")),
           f"{name}: kernel routes {routes}")
     step = stats["compiled_step"]
     check("error" not in step and "pallas_custom_calls" in step,
           f"{name}: the engine could not resolve its step executable and "
           f"fell back: {step} (the log above has the traceback)")
-    expect = 0 if size.tiny else 2 * sum(
-        v.startswith("lrn=pallas") for v in routes.values())
+    expect = 0 if size.tiny else sum(
+        2 * v.startswith("lrn=pallas") + v.startswith("pool_bwd=pallas")
+        for v in routes.values())
     check(int(step["pallas_custom_calls"]) == expect,
           f"{name}: compiled step holds {step['pallas_custom_calls']} "
           f"Pallas custom calls, routing promises {expect}")
@@ -285,8 +290,8 @@ def check_kernels(size: Size) -> dict:
     device, at the CNN cells' geometries, to tests/test_kernels.py's
     tolerances:
     the Pallas LRN (compiled; interpreted only under --cpu-tiny) against
-    the XLA formulation, the pool backward's select-and-scatter against
-    the tap-sum."""
+    the XLA formulation, the Pallas max-pool backward and the tap-sum
+    against select-and-scatter."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -334,29 +339,37 @@ def check_kernels(size: Size) -> dict:
         for name, (n, c, h) in size.pool:
             x = jnp.asarray(rs.randn(n, c, h, h), dtype)
             grads = {}
-            for arm in ("sas", "taps"):
+            for arm in ("sas", "taps", "pallas"):
                 # the arm is read at trace time: a fresh function per arm
                 os.environ["POSEIDON_POOL_BWD"] = arm
-                grads[arm] = jax.jit(jax.grad(lambda x_: jnp.sum(
+                grad = jax.grad(lambda x_: jnp.sum(
                     NN.max_pool(x_, (3, 3), (2, 2), (0, 0), "NCHW").astype(
-                        jnp.float32) ** 2)))(x)
-            close(grads["sas"], grads["taps"], "pool_bwd", dtype,
-                  f"pool_bwd sas vs taps max {name} {dtype}")
+                        jnp.float32) ** 2))
+                grads[arm] = (compiled_kernel(grad, x) if arm == "pallas"
+                              else jax.jit(grad)(x))
+            for arm in ("taps", "pallas"):
+                close(grads["sas"], grads[arm], "pool_bwd", dtype,
+                      f"pool_bwd sas vs {arm} max {name} {dtype}")
     # bf16: the four windows over input (2, 2) send it 256 + 1 + 1 + 1; an
     # f32 sum rounds once, to 260, a bf16 accumulator stays at 256 (which
     # is what the TPU's select-and-scatter does once it has a bf16 result)
-    os.environ["POSEIDON_POOL_BWD"] = "sas"
-    x = jnp.zeros((1, 1, 5, 5), jnp.bfloat16).at[0, 0, 2, 2].set(1)
-    g = jnp.asarray([[[[256, 1], [1, 1]]]], jnp.bfloat16)
-    for method, pool, scale in (("max", NN.max_pool, 1),
-                                ("ave", NN.ave_pool, 9)):    # 9: AVE's / 9
+    # (in every plane of one 16 x 128 register tile of channels x batch:
+    # Mosaic does not take the kernel's blocks at a 1 x 1 tile)
+    x = jnp.zeros((128, 16, 5, 5), jnp.bfloat16).at[:, :, 2, 2].set(1)
+    g = jnp.broadcast_to(jnp.asarray([[256, 1], [1, 1]], jnp.bfloat16),
+                         (128, 16, 2, 2))
+    for arm, method, pool, scale in (
+            ("pallas", "max", NN.max_pool, 1), ("sas", "max", NN.max_pool, 1),
+            ("sas", "ave", NN.ave_pool, 9)):                 # 9: AVE's / 9
+        os.environ["POSEIDON_POOL_BWD"] = arm
         _, vjp = jax.vjp(jax.jit(lambda x_: pool(
             x_, (3, 3), (2, 2), (0, 0), "NCHW")), x)
         dx = jax.jit(vjp)(g * scale)[0]
-        got = facts[f"pool_bwd sas {method} bf16 overlap sum"] = float(
+        got = facts[f"pool_bwd {arm} {method} bf16 overlap sum"] = float(
             dx[0, 0, 2, 2])
-        check(got == 260.0, f"{method} pool backward summed its overlaps "
-                            f"in bf16: {dx[0, 0]}")
+        check(bool(jnp.all(dx[:, :, 2, 2] == 260.0)),
+              f"{method} pool backward ({arm}) summed its overlaps in "
+              f"bf16: {dx[0, 0]}")
     if forced_arm is None:
         del os.environ["POSEIDON_POOL_BWD"]
     else:

@@ -391,15 +391,15 @@ class Net:
             if layer.TYPE == "POOLING" and self.phase == "TRAIN" \
                     and layer.method in ("MAX", "AVE"):
                 what = "pool_bwd"
-                arm, note = NN.pool_bwd_route(layer.kernel)
+                arm, note = NN.pool_bwd_route(
+                    layer.kernel, layer.stride, layer.pad,
+                    layer.method.lower(), shape,
+                    jnp.dtype(policy().compute_dtype).itemsize)
             elif layer.TYPE == "LRN" and layer.region == "ACROSS_CHANNELS":
                 what = "lrn"
                 arm, note = lrn_route(
                     shape[2] * shape[3], shape[1], shape[0],
                     jnp.dtype(policy().compute_dtype).itemsize)
-                if arm == "pallas":
-                    # the orientation and block the kernels run with
-                    arm, note = f"{arm} ({note})", ""
             elif layer.TYPE == "ATTENTION":
                 what = "attention"
                 arm, note = attention_route(
@@ -423,6 +423,9 @@ class Net:
                 arm, note = GROUPED_MATMUL, "sorted by expert, dropless"
             else:
                 continue
+            if arm == "pallas" and note:
+                # the orientation and block the pool / LRN kernels run with
+                arm, note = f"{arm} ({note})", ""
             self.kernel_routes[layer.name] = f"{what}={arm}"
             log(f"[kernel_route] {layer.name}: {what} -> {arm}"
                 + (f" ({note})" if note else ""))

@@ -406,43 +406,66 @@ def _ave_pool_ref(x, kernel, stride, pad, layout: str = "NCHW"):
 
 # ---- pooling backward strategies ------------------------------------------ #
 #
-# Two formulations, one per backend, each measured where it runs. On the
-# v5e the compiler lays the step's activations out itself (batch-minor,
+# Three formulations, each measured where it runs. On the v5e the compiler
+# lays the step's activations out itself, pixels leading (batch-minor,
 # `{0,1,3,2}`: N on the lanes, C on the sublanes, H and W major, at a
 # per-chip batch that fills the lanes; channel-minor `{1,0,3,2}` below
-# that and for pool2's 256 channels), where XLA's own select-and-scatter
-# costs 8.65 ms of an AlexNet step of 68.9 and 9.91 ms of a GoogLeNet step
-# of 50.6 (bf16, 512 / 128 images; PERF.md, PR 24), against 42.2 / 42.5 ms
-# for the tap-sum, whose k*k interior pads do not fuse there. A custom call
-# with a row-major operand layout in this place costs far more than its own
-# time: every neighbour is relaid out around it. On the CPU the thunk
-# runtime runs select-and-scatter as one thunk per window (PR 7's #1
+# that). A MAX pool's backward there is the Pallas kernel
+# `pallas_kernels.maxpool_bwd`, which takes x and g in exactly that
+# orientation (the window axes are then the leading, untiled ones: a tap is
+# an address offset) and makes dx in one bf16 pass; until PR 35 it was
+# XLA's select-and-scatter on an f32 copy with a rounding pass behind it
+# (7.5 ms of an AlexNet step of 36.8, 9.9 of a GoogLeNet step of 29.4;
+# PERF.md), which AVE pools keep. The tap-sum's k*k interior pads do not
+# fuse on the chip (42 ms, PR 24). A custom call with a ROW-MAJOR operand
+# in this place costs far more than its own time: every neighbour is
+# relaid out around it (PR 24's deleted per-plane kernel). On the CPU the
+# thunk runtime runs select-and-scatter as one thunk per window (PR 7's #1
 # AlexNet sink), and the vectorized tap-sum wins.
 
-# above this many window taps the unrolled tap-sum loop stops making sense
+# above this many window taps the unrolled tap loops stop making sense
 # (a global pool is one window: its backward is a broadcast, which is
 # exactly what select-and-scatter degenerates to) — route to the reference
 POOL_TAPS_CAP = 64
 
 
-def pool_bwd_route(kernel):
+def pool_bwd_route(kernel, stride=None, pad=None, method=None, shape=None,
+                   itemsize: int = 4):
     """``(arm, note)`` for one pooling layer — THE routing decision:
     ``_pool_bwd`` takes it at trace time and ``Net`` logs it per layer at
-    construction. ``'sas'`` (select-and-scatter: plain autodiff through
-    ``reduce_window``, in f32) when lowering for the TPU and for windows
-    above ``POOL_TAPS_CAP``; ``'taps'`` (one strided slice and one
-    pad-and-add per window tap) on the CPU mesh. The rule has no shape in
-    it: on the chip sas is 2.7-21x ahead at every geometry of both
-    benchmark configurations (3x3 s2 on 13..112, 3x3 s1 p1 on 7..28, AVE
-    5x5 s3 on 14; PERF.md, PR 24). ``POSEIDON_POOL_BWD`` forces an arm."""
+    construction. The rule is the layer's and the shape's: ``shape`` is
+    the per-device logical ``(N, C, H, W)`` of the pool's input.
+
+    ``'pallas'`` (``pallas_kernels.maxpool_bwd``, one pass in the
+    activation dtype) for MAX pooling lowered for the TPU where a
+    VMEM-legal block of a size worth a program exists
+    (``pallas_kernels.maxpool_bwd_note``), with the kernel's operand
+    orientation and block in the note; ``'sas'`` (select-and-scatter: plain
+    autodiff through ``reduce_window``, in f32) for AVE pooling, for
+    windows above ``POOL_TAPS_CAP``, where no such block exists (the note
+    says why) and where the geometry is not given; ``'taps'`` (one strided
+    slice and one pad-and-add per window tap) on the CPU mesh.
+    ``POSEIDON_POOL_BWD`` forces an arm for A/B
+    (``pallas`` on the CPU runs the kernel interpreted; forced, it also
+    takes the blocks the rule finds too small to be worth a kernel, and
+    still never AVE pooling)."""
     import os
+    from .pallas_kernels import (PoolTileError, _interpret_default,
+                                 maxpool_bwd_note)
     env = os.environ.get("POSEIDON_POOL_BWD", "")
     if env in ("taps", "sas"):
         return env, f"POSEIDON_POOL_BWD={env}"
     if kernel[0] * kernel[1] > POOL_TAPS_CAP:
         return "sas", f"window above {POOL_TAPS_CAP} taps"
-    from .pallas_kernels import _interpret_default
-    return ("taps", "cpu backend") if _interpret_default() else ("sas", "")
+    if _interpret_default() and env != "pallas":
+        return "taps", "cpu backend"
+    if method != "max" or shape is None:
+        return "sas", ""
+    try:
+        return "pallas", maxpool_bwd_note(shape, kernel, stride, pad,
+                                          itemsize, floor=env != "pallas")
+    except PoolTileError as why:
+        return "sas", str(why)
 
 
 def _pool_flat_ids(shape, ah, aw, pw, stride, dh, dw):
@@ -520,10 +543,18 @@ def _pool_unpad(dxp, x_shape, pad, layout: str):
 
 
 def _pool_bwd(x, g, kernel, stride, pad, layout: str, method: str):
-    """One pooling backward through ``pool_bwd_route``'s arm. Either way
-    overlapping windows' contributions are summed in f32 and cast once to
-    ``x.dtype``."""
-    if pool_bwd_route(kernel)[0] == "sas":
+    """One pooling backward through ``pool_bwd_route``'s arm. Whichever it
+    is, the first maximum of a window takes its cotangent, overlapping
+    windows' contributions are summed in f32 and the sum is rounded once
+    to ``x.dtype``."""
+    shape = (x.shape if layout == "NCHW"
+             else (x.shape[0], x.shape[3], x.shape[1], x.shape[2]))
+    arm = pool_bwd_route(kernel, stride, pad, method, shape,
+                         x.dtype.itemsize)[0]
+    if arm == "pallas":
+        from .pallas_kernels import maxpool_bwd
+        return maxpool_bwd(x, g, kernel, stride, pad, layout)
+    if arm == "sas":
         ref = _max_pool_ref if method == "max" else _ave_pool_ref
         _, vjp = jax.vjp(lambda x_: ref(x_, kernel, stride, pad, layout),
                          x.astype(jnp.float32))

@@ -14,6 +14,11 @@ backward, in the orientation the compiled step holds the activation in
 (``_lrn_tile``). The default on TPU (``lrn_route``; ``POSEIDON_PALLAS_LRN=0``
 opts out), the XLA formulation on the CPU mesh and beyond the VMEM cap.
 
+``maxpool_bwd``: max-pool backward in one pass in the activation dtype,
+in the same orientation with the window axes leading (``_pool_plan``). The
+default for MAX pooling on TPU (``nn.pool_bwd_route``), the tap-sum on the
+CPU mesh, select-and-scatter for AVE pooling.
+
 Kernels run in interpret mode on the CPU test mesh so it exercises the same
 code path; any backend other than tpu/cpu is refused (``_interpret_default``).
 """
@@ -21,7 +26,8 @@ code path; any backend other than tpu/cpu is refused (``_interpret_default``).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -619,6 +625,18 @@ _LRN_BLOCK_BYTES = 2 ** 20
 _LRN_PIECE_VREGS = 16
 
 
+def _channel_axis(batch: int) -> int:
+    """Where the channels of a pixels-leading CNN operand go — the ONE rule
+    the LRN and the pooling kernels share, so that norm1 -> pool1 and
+    norm2 -> pool2 hand over in one orientation. 1 = batch-minor ``(...,
+    C, N)``: batch on the lanes, channels on the sublanes, where the
+    per-device batch fills the lanes (a multiple of 128). 2 =
+    channel-minor ``(..., N, C)`` below that. Those are the layouts the
+    TPU compiler keeps a CNN's activations in at such a batch, so the
+    logical transposes around a kernel are bitcasts in the compiled step."""
+    return 1 if batch % 128 == 0 else 2
+
+
 class LRNTileError(ValueError):
     """No VMEM-legal block exists for this channel count."""
 
@@ -656,12 +674,13 @@ def _lrn_tile(hw: int, channels: int, batch: int, itemsize: int,
             f"{channels * 128 * 4 * 8 >> 20} MB of f32 temporaries, over "
             f"{budget >> 20} MB of scoped VMEM; use the XLA formulation "
             f"for channel counts above ~{budget // (4 * 8 * 128)}")
-    if batch % 128 == 0:
-        channel_axis, second, rows = 1, channels, 1
+    channel_axis = _channel_axis(batch)
+    if channel_axis == 1:
+        second, rows = channels, 1
         minor = max(128, min(batch, _LRN_BLOCK_BYTES
                              // (channels * itemsize) // 128 * 128))
     else:
-        channel_axis, minor = 2, channels
+        minor = channels
         lane_tiles = _cdiv(channels, 128)           # vregs a row of C fills
         # as many images as fill a piece, in whole bf16 sublane tiles
         fit = max(16, _LRN_PIECE_VREGS // lane_tiles * 8 // 16 * 16)
@@ -896,3 +915,326 @@ def maybe_lrn_fused(x, local_size: int, alpha: float, beta: float,
     if lrn_route(hw, c, n, x.dtype.itemsize)[0] == "pallas":
         return lrn_fused(x, local_size, alpha, beta, k, layout=layout)
     return lrn_across_channels(x, local_size, alpha, beta, k, layout)
+
+
+# --------------------------------------------------------------------------- #
+# Max-pool backward
+# --------------------------------------------------------------------------- #
+
+# What Mosaic may use of VMEM for one pool-backward program (128 MiB on the
+# v5e) and what the block rule fills of it with the buffers it can count.
+_POOL_VMEM_LIMIT = 64 * 2 ** 20
+_POOL_VMEM_BUDGET = 40 * 2 ** 20
+# f32 vregs of 8 x 128 one chunk of windows may hold per live array (the
+# running max, its tap, the tap being read, the cotangent): 4 x 14 of 64
+_POOL_CHUNK_VREGS = 14
+# below this many bytes of dx a program the route keeps XLA's op
+_POOL_MIN_BLOCK_BYTES = 256 * 1024
+
+
+class PoolTileError(ValueError):
+    """No VMEM-legal block exists for this pooling geometry, or none worth
+    a kernel (``maxpool_bwd_note``); the message is the route's note."""
+
+
+class _PoolPlan(NamedTuple):
+    """How one max-pool backward geometry is handed to the kernel."""
+    channel_axis: int       # _channel_axis: 1 (H, W, C, N), 2 (H, W, N, C)
+    out: tuple              # (OH, OW)
+    rows: int               # dx rows a program owns (TH, a multiple of sh)
+    g_rows: int             # window rows whose first tap row is among them
+    halo: tuple             # (g above, g below, x above, x below) in rows
+    tile: tuple             # (second, minor) extent of every block
+    chunk: int              # windows of a row worked on at a time
+    chunks: int
+    width: int              # columns of the staged row, pads included
+
+
+def _pool_plan(h: int, w: int, c: int, n: int, kernel, stride, pad,
+               itemsize: int, rows: Optional[int] = None) -> _PoolPlan:
+    """The orientation, block and in-kernel chunk of one geometry — a
+    function of the shape alone, as ``_lrn_tile`` is; ``rows`` (dx rows a
+    block) is for tests.
+
+    The operand is the logical transpose with the window axes leading,
+    ``(H, W, C, N)`` or ``(H, W, N, C)`` by ``_channel_axis``. A block is
+    ``rows`` rows of all of W by ONE register tile of the two minor dims
+    (16 x 128 at two bytes, 8 x 128 at four; a dim no multiple of its tile
+    is taken whole): the window never crosses the minor dims, so the f32
+    staging a program needs is that of one tile. ``rows`` is the largest
+    count, evened over the blocks, whose buffers fit ``_POOL_VMEM_BUDGET``.
+    Raises :class:`PoolTileError` where not even ``stride`` rows do."""
+    from .nn import pool_out_size
+    (kh, kw), (sh, sw), (ph, pw) = kernel, stride, pad
+    oh, ow = pool_out_size(h, kh, sh, ph), pool_out_size(w, kw, sw, pw)
+    channel_axis = _channel_axis(n)
+    second, minor = (c, n) if channel_axis == 1 else (n, c)
+    sub = 32 // itemsize                      # sublanes of one packed tile
+    ts = sub if second % sub == 0 else second
+    tm = 128 if minor % 128 == 0 else minor
+    # f32 vregs one position of a tile fills, and the bytes it takes staged
+    # (f32) and as an operand block
+    vregs = _cdiv(ts, 8) * _cdiv(tm, 128)
+    staged, held = vregs * 4096, _cdiv(ts, sub) * _cdiv(tm, 128) * 4096
+    chunks = _cdiv(ow, max(1, _POOL_CHUNK_VREGS // vregs))
+    chunk = _cdiv(ow, chunks)
+    width = max(pw + w, (chunks * chunk - 1) * sw + kw)
+    # window rows above / below a block's own that reach into it, and the
+    # input rows those reach beyond it
+    g_up, g_dn = (kh - 1 - ph) // sh, _cdiv(ph, sh)
+    x_up, x_dn = g_up * sh + ph, max(0, (g_dn - 1) * sh + kh - ph)
+
+    def vmem(g_rows):
+        th = g_rows * sh
+        x_blk = (th + x_up + x_dn) * w * held
+        g_blk = (g_rows + g_up + g_dn) * ow * held
+        return (2 * (x_blk + g_blk + th * w * held)     # double-buffered
+                # x staged as f32 and the f32 accumulator
+                + 2 * (th + x_up + x_dn) * width * staged
+                + (g_rows + g_up + g_dn) * chunks * chunk * staged)
+
+    most = _cdiv(h, sh)
+    if rows is not None:
+        g_rows = max(1, min(most, rows // sh))
+    else:
+        g_rows = most
+        while g_rows > 1 and vmem(g_rows) > _POOL_VMEM_BUDGET:
+            g_rows -= 1
+        # the same number of blocks, evened out
+        g_rows = _cdiv(most, _cdiv(most, g_rows))
+    if vmem(g_rows) > _POOL_VMEM_BUDGET:
+        raise PoolTileError(
+            f"no VMEM-legal block: {g_rows * sh} rows of {w} x ({ts} x {tm})"
+            f" need {vmem(g_rows) >> 20} MB, over "
+            f"{_POOL_VMEM_BUDGET >> 20} MB")
+    return _PoolPlan(channel_axis, (oh, ow), g_rows * sh, g_rows,
+                     (g_up, g_dn, x_up, x_dn), (ts, tm), chunk, chunks, width)
+
+
+def _maxpool_bwd_kernel(x_ref, g_ref, dx_ref, xs, gs, acc, *, kernel, stride,
+                        pad, shape, plan: _PoolPlan):
+    """dx rows ``[i * rows, (i + 1) * rows)`` of one minor tile.
+
+    ``x_ref`` / ``g_ref`` hold those rows' halo too: every window that
+    touches the block and every input row those windows cover, rows beyond
+    the array unspecified. They are staged once as f32 — input rows and
+    columns beyond the array as -inf (Caffe's pad and the ceil-mode edge
+    are masked taps, never a padded copy), window rows and columns beyond
+    the output as 0 — so that every window of the block is the same code:
+    per chunk of windows the max and the FIRST tap that reaches it
+    (Caffe's ``>`` rule, as ``nn._pool_max_args``), then the cotangent
+    added at that tap's position of the f32 accumulator, the taps of one
+    row that land ``stride`` columns apart summed in registers first. Rows
+    of the accumulator outside the block collect their windows' share and
+    are dropped: the program that owns them computes it again. One
+    rounding, on the way out.
+
+    Written in ``lax`` primitives with the window's rows as (unrolled)
+    loops: the step traces and lowers one of these per MAX pool on every
+    start that the AOT store does not answer, and ``jnp``'s operators cost
+    three times the seconds there."""
+    (kh, kw), (sh, sw), (ph, pw) = kernel, stride, pad
+    h, w = shape
+    oh, ow = plan.out
+    g_up, g_dn, x_up, x_dn = plan.halo
+    th, gt, wc = plan.rows, plan.g_rows, plan.chunk
+    f32, i32 = jnp.float32, jnp.int32
+    i = pl.program_id(0)
+    row0 = lax.sub(lax.mul(i, th), x_up)       # the array row of xs[0]
+    win0 = lax.sub(lax.mul(i, gt), g_up)       # the window row of gs[0]
+    tile = plan.tile
+    # Mosaic pads a window at the array's far end only: the first block's
+    # windows start at row 0, its rows above the array are these
+    x_off, g_off = lax.max(lax.neg(row0), 0), lax.max(lax.neg(win0), 0)
+
+    x_pad = [(c, n) for c, n in ((0, pw), (pw + w, plan.width - pw - w))
+             if n]
+    g_pad = gs.shape[1] - ow
+
+    def outside(first, r, extent):
+        at = lax.add(first, r)
+        return lax.bitwise_or(lax.lt(at, 0), lax.ge(at, extent))
+
+    def filled(n, value):
+        return lax.full((n,) + tile, value, f32)
+
+    def stage_x(r, carry):
+        row = lax.convert_element_type(
+            x_ref[lax.max(lax.sub(r, x_off), 0)], f32)
+        xs[r, pl.ds(pw, w)] = lax.select(
+            lax.broadcast(outside(row0, r, h), row.shape),
+            filled(w, -jnp.inf), row)
+        for c, n in x_pad:
+            xs[r, pl.ds(c, n)] = filled(n, -jnp.inf)
+        acc[r] = filled(plan.width, 0.0)
+        return carry
+
+    def stage_g(r, carry):
+        row = lax.convert_element_type(
+            g_ref[lax.max(lax.sub(r, g_off), 0)], f32)
+        gs[r, pl.ds(0, ow)] = lax.select(
+            lax.broadcast(outside(win0, r, oh), row.shape),
+            filled(ow, 0.0), row)
+        if g_pad:
+            gs[r, pl.ds(ow, g_pad)] = filled(g_pad, 0.0)
+        return carry
+
+    lax.fori_loop(0, th + x_up + x_dn, stage_x, 0)
+    lax.fori_loop(0, gt + g_up + g_dn, stage_g, 0)
+
+    def cols(col, first, n):
+        start = lax.add(lax.mul(col, sw), first)
+        return pl.ds(start, n, stride=sw) if sw > 1 else pl.ds(start, n)
+
+    def window_chunk(j, carry):
+        r = lax.div(j, plan.chunks)
+        col = lax.mul(lax.rem(j, plan.chunks), wc)
+        top = lax.mul(r, sh)                   # the window row's first tap row
+        shape_ = (wc,) + tile
+
+        def tap(dh, dw):
+            return lax.broadcast(lax.add(lax.mul(dh, kw), dw), shape_)
+
+        def argmax_row(dh, best):
+            mx, arg = best
+            for dw in range(kw):
+                v = xs[lax.add(top, dh), cols(col, dw, wc)]
+                better = lax.gt(v, mx)
+                mx = lax.select(better, v, mx)
+                arg = lax.select(better, tap(dh, dw), arg)
+            return mx, arg
+
+        _, arg = lax.fori_loop(
+            0, kh, argmax_row,
+            (filled(wc, -jnp.inf), lax.full(shape_, 0, i32)), unroll=True)
+        g = gs[r, pl.ds(col, wc)]
+        zero = filled(wc, 0.0)
+
+        def scatter_row(dh, carry_):
+            # the taps of this row that land on one column class (dw = first,
+            # first + stride, ...) reach the accumulator as one sum
+            for first in range(min(sw, kw)):
+                dws = range(first, kw, sw)
+                sums = None
+                for m, dw in enumerate(dws):
+                    hit = lax.select(lax.eq(arg, tap(dh, dw)), g, zero)
+                    # window c's tap lands m columns of the class further on
+                    lead, trail = m, len(dws) - 1 - m
+                    parts = ([filled(lead, 0.0)] * bool(lead) + [hit]
+                             + [filled(trail, 0.0)] * bool(trail))
+                    if len(parts) > 1:
+                        hit = lax.concatenate(parts, 0)
+                    sums = hit if sums is None else lax.add(sums, hit)
+                at = (lax.add(top, dh), cols(col, first, wc + len(dws) - 1))
+                acc[at] = lax.add(acc[at], sums)
+            return carry_
+
+        lax.fori_loop(0, kh, scatter_row, 0, unroll=True)
+        return carry
+
+    lax.fori_loop(0, (gt + g_up + g_dn) * plan.chunks, window_chunk, 0)
+
+    def write(r, carry):
+        dx_ref[r] = lax.convert_element_type(
+            acc[lax.add(r, x_up), pl.ds(pw, w)], dx_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, th, write, 0)
+
+
+def maxpool_bwd(x, g, kernel, stride, pad, layout: str = "NCHW",
+                rows: Optional[int] = None,
+                interpret: Optional[bool] = None):
+    """Max-pool backward in one pass: dx from the pool's input ``x`` and the
+    cotangent ``g`` of its output, in ``x.dtype``, overlapping windows
+    summed in f32 and rounded once, the first maximum of a window taking
+    its cotangent. ``layout`` is the net's logical one; the orientation and
+    block follow the shape alone (``_pool_plan``), ``rows`` is for tests.
+
+    Each program owns a block of dx rows, so no two write one row; x is
+    read once plus the windows' reach beyond a block, g once plus a row or
+    two, dx written once. The transposes in and out are logical: bitcasts
+    in the compiled step wherever the rule's orientation is the
+    compiler's own.
+
+    The call goes through an inlined ``jit``: layers of one geometry
+    (GoogLeNet's inception_4b / 4c / 4d) trace the kernel once."""
+    if interpret is None:
+        interpret = _interpret_default()
+    return _maxpool_bwd(x, g, tuple(kernel), tuple(stride), tuple(pad),
+                        layout, rows, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7), inline=True)
+def _maxpool_bwd(x, g, kernel, stride, pad, layout, rows, interpret):
+    if layout == "NHWC":
+        (n, h, w, c), lead = x.shape, {1: (1, 2, 3, 0), 2: (1, 2, 0, 3)}
+    else:
+        (n, c, h, w), lead = x.shape, {1: (2, 3, 1, 0), 2: (2, 3, 0, 1)}
+    plan = _pool_plan(h, w, c, n, kernel, stride, pad, x.dtype.itemsize,
+                      rows)
+    axes = lead[plan.channel_axis]
+    g_up, g_dn, x_up, x_dn = plan.halo
+    th, gt, (ts, tm) = plan.rows, plan.g_rows, plan.tile
+    oh, ow = plan.out
+    blocks = _cdiv(h, th)
+    x4, g4 = x.transpose(axes), g.transpose(axes)
+
+    def haloed(rows_, up, dn, extent, cols):
+        # every dim by element, so that a block may start `up` rows above
+        # its own; the rows beyond the array's end (as far as the last
+        # block's window reaches) are never fetched
+        size = rows_ + up + dn
+        reach = max((blocks - 1) * rows_ - up, 0) + size
+        return pl.BlockSpec(
+            (pl.Element(size, (0, max(0, reach - extent))),
+             pl.Element(cols), pl.Element(ts), pl.Element(tm)),
+            lambda i, j, k: (lax.max(lax.sub(lax.mul(i, rows_), up), 0), 0,
+                             lax.mul(j, ts),
+                             lax.mul(k, tm) if tm == 128 else 0))
+
+    staged = (th + x_up + x_dn, plan.width, ts, tm)
+    out = pl.pallas_call(
+        functools.partial(_maxpool_bwd_kernel, kernel=kernel, stride=stride,
+                          pad=pad, shape=(h, w), plan=plan),
+        name="maxpool_bwd",
+        out_shape=jax.ShapeDtypeStruct(x4.shape, x.dtype),
+        grid=(blocks, x4.shape[2] // ts, x4.shape[3] // tm),
+        in_specs=[haloed(th, x_up, x_dn, h, w),
+                  haloed(gt, g_up, g_dn, oh, ow)],
+        out_specs=pl.BlockSpec((th, w, ts, tm),
+                               lambda i, j, k: (i, 0, j, k)),
+        scratch_shapes=[
+            pltpu.VMEM(staged, jnp.float32),
+            pltpu.VMEM((gt + g_up + g_dn, plan.chunks * plan.chunk, ts, tm),
+                       jnp.float32),
+            pltpu.VMEM(staged, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3,
+            vmem_limit_bytes=_POOL_VMEM_LIMIT),
+        interpret=interpret,
+    )(x4, g4)
+    return out.transpose(tuple(axes.index(a) for a in range(4)))
+
+
+def maxpool_bwd_note(shape, kernel, stride, pad, itemsize: int,
+                     floor: bool = True) -> str:
+    """How :func:`maxpool_bwd` would take the per-device logical
+    ``(N, C, H, W)`` input ``shape`` — ``"batch-minor HxWxCxN, block
+    14x55x16x128"``. Raises :class:`PoolTileError` with the reason where
+    the kernel should not run (``nn.pool_bwd_route`` then keeps
+    select-and-scatter): no VMEM-legal block, or a dx block under
+    ``_POOL_MIN_BLOCK_BYTES`` — every program is then start-up and the
+    kernel no faster than XLA's op (0.20 against 0.23 ms at GoogLeNet's
+    7 x 7 x 832 x 128, the only such geometry of the benchmark; PERF.md,
+    PR 35), while each call costs its lowering at every start (``floor``
+    False, the forced arm's, leaves that second rule out)."""
+    n, c, h, w = shape
+    plan = _pool_plan(h, w, c, n, kernel, stride, pad, itemsize)
+    block_bytes = math.prod((min(plan.rows, h), w, *plan.tile)) * itemsize
+    if floor and block_bytes < _POOL_MIN_BLOCK_BYTES:
+        raise PoolTileError("a dx block of %d KB is all per-program overhead"
+                            % (block_bytes >> 10))
+    orient = ("batch-minor HxWxCxN" if plan.channel_axis == 1
+              else "channel-minor HxWxNxC")
+    return "%s, block %dx%dx%dx%d" % (orient, plan.rows, w, *plan.tile)

@@ -67,10 +67,12 @@ txt = jax.jit(jax.grad(lambda x: lrn_fused(
     .compile().as_text()
 assert txt.count("tpu_custom_call") >= 1, "lrn bwd"
 print("OK lrn_bwd")
-# the TPU route of a bf16 pool backward is select-and-scatter with an F32
-# result: the compiler folds a bare f32 -> bf16 cast into the scatter, which
-# then sums overlapping windows in bf16 (seen on the v5e, PR 24)
+# the `sas` arm of a bf16 pool backward (the TPU's route for MAX pooling
+# until PR 35, still its A/B arm) is select-and-scatter with an F32 result:
+# the compiler folds a bare f32 -> bf16 cast into the scatter, which then
+# sums overlapping windows in bf16 (seen on the v5e, PR 24)
 os.environ["POSEIDON_FORCE_PALLAS"] = "1"      # lower as for the TPU
+os.environ["POSEIDON_POOL_BWD"] = "sas"
 from poseidon_tpu.ops import nn as NN
 xb = jax.ShapeDtypeStruct((8, 128, 27, 27), jnp.bfloat16, sharding=sh)
 txt = jax.jit(jax.grad(lambda x: jnp.sum(NN.max_pool(
@@ -551,7 +553,173 @@ def test_lrn_kernels_meet_their_neighbours_layout_for_v5e():
         "32x64x3136": ["channel-minor HWxNxC, block 256x32x64", 1, 1],
         "32x192x3136": ["channel-minor HWxNxC, block 84x32x192", 1, 1]}
     for name, standin in got["standin"].items():
-        assert standin == {"pallas_custom_calls": 2, "moved": []}, name
+        # LRN forward and backward, and since PR 35 the pool's backward
+        assert standin == {"pallas_custom_calls": 3, "moved": []}, name
+
+
+# The max-pool backward kernel (PR 35) at the thirteen MAX geometries the two
+# CNN cells hold (batch-minor at 512 / 128 images a chip; the route leaves
+# the 7 x 7 one on select-and-scatter, its block is too small) and two of
+# GoogLeNet's at its published 32 (channel-minor), through Mosaic with the
+# block the rule gives; then stand-ins for the layers around a pool — conv ->
+# ReLU -> LRN -> pool -> conv (AlexNet's pool1; GoogLeNet's ceil-mode pool1)
+# and concat -> pool 3x3 s1 p1 -> 1x1 conv beside a second reader of the
+# concat (an inception module's pool branch), value_and_grad — in which the
+# entry computation holds no copy, pad, convert or transpose of the pool
+# operand's size, no f32 array of it, and no select-and-scatter (with the
+# `sas` arm: an f32 copy or `pad_convert_fusion` of the input, the scatter
+# and a `reduce-precision_convert_fusion` behind it at every pool).
+_POOL_BOUNDARY = r"""
+import json, math, os, re, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ["POSEIDON_FORCE_PALLAS"] = "1"      # lower as for the TPU
+sys.path.insert(0, {repo!r})
+import jax, jax.numpy as jnp
+from jax import lax
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("SKIP:", e)
+    sys.exit(3)
+from poseidon_tpu.config import set_perf_policy
+from poseidon_tpu.ops import nn as NN, pallas_kernels as PK
+set_perf_policy()
+sh = SingleDeviceSharding(topo.devices[0])
+S = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sh)
+calls = lambda text: text.count('custom_call_target="tpu_custom_call"')
+S2, S1 = ((3, 3), (2, 2), (0, 0)), ((3, 3), (1, 1), (1, 1))
+kernels = {{}}
+for n, c, side, geom in (
+        (512, 96, 55, S2), (512, 256, 27, S2), (512, 256, 13, S2),
+        (128, 64, 112, S2), (128, 192, 56, S2), (128, 480, 28, S2),
+        (128, 832, 14, S2), (128, 192, 28, S1), (128, 256, 28, S1),
+        (128, 480, 14, S1), (128, 512, 14, S1), (128, 528, 14, S1),
+        (128, 832, 7, S1), (32, 64, 112, S2), (32, 192, 28, S1)):
+    out = NN.pool_out_size(side, geom[0][0], geom[1][0], geom[2][0])
+    text = jax.jit(lambda x, g: PK.maxpool_bwd(x, g, *geom, interpret=False)
+                   ).lower(S(n, c, side, side), S(n, c, out, out)
+                           ).compile().as_text()
+    kernels["%dx%dx%d s%d" % (n, c, side, geom[1][0])] = [
+        NN.pool_bwd_route(geom[0], geom[1], geom[2], "max",
+                          (n, c, side, side), 2)[1], calls(text)]
+
+def conv(x, w, stride, pad):
+    return lax.conv_general_dilated(
+        x, w, (stride, stride), [(pad, pad)] * 2,
+        dimension_numbers=("NCHW", "OIHW", "NCHW"))
+
+def boundary(text, count):
+    # entry-level instructions that move or widen an array of the pool
+    # operand's size, and what is left of select-and-scatter
+    lines = text.splitlines()
+    entry = lines[next(i for i, l in enumerate(lines)
+                       if l.startswith("ENTRY ")):]
+    moved = []
+    for l in entry:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]+)\]\S* "
+                     r"(copy|pad|convert|transpose|fusion)\(", l)
+        if not m or math.prod(map(int, m.group(3).split(","))) < count:
+            continue
+        op = m.group(4)
+        if op == "fusion":
+            # a fusion that only converts, pads or copies says so in its name
+            if not re.search(r"(^|_)(copy|pad|convert|transpose)(_|$)",
+                             m.group(1).split(".")[0]):
+                continue
+        moved.append("%s %s %s[%s]" % (op, m.group(1), m.group(2), m.group(3)))
+    # f32 arrays of the operand's size that the entry computation holds
+    # in memory (inside a fusion f32 is the VPU's arithmetic, not an array)
+    wide = set()
+    for l in entry:
+        if " = " in l:
+            result = re.split(r" [\w-]+\(", l.split(" = ", 1)[1], 1)[0]
+            wide.update(w for w in re.findall(r"f32\[[\d,]+\]", result)
+                        if math.prod(map(int, w[4:-1].split(","))) == count)
+    wide = sorted(wide)
+    return {{"pallas_custom_calls": calls(text), "moved": moved,
+            "f32_of_operand_size": wide,
+            "select_and_scatter": text.count(" select-and-scatter(")}}
+
+def alexnet_standin(n, cin, side, c, k, stride, pad, cout):
+    def loss(w1, w2, x):
+        h = jnp.maximum(conv(x, w1, stride, pad), 0)
+        h = PK.maybe_lrn_fused(h, 5, 1e-4, 0.75)
+        h = NN.max_pool(h, (3, 3), (2, 2), (0, 0))
+        return jnp.sum(conv(h, w2, 1, 1).astype(jnp.float32) ** 2)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        S(c, cin, k, k), S(cout, c, 3, 3), S(n, cin, side, side)
+    ).compile().as_text()
+    out = (side + 2 * pad - k) // stride + 1
+    return boundary(text, n * c * out * out)
+
+def inception_standin(n, c1, c2, side, cout):
+    def loss(w0, w1, w2, w3, x):
+        a = jnp.maximum(conv(x, w0, 1, 0), 0)
+        b = jnp.maximum(conv(x, w1, 1, 1), 0)
+        h = jnp.concatenate([a, b], axis=1)
+        p = jnp.maximum(conv(NN.max_pool(h, (3, 3), (1, 1), (1, 1)), w2, 1, 0), 0)
+        q = jnp.maximum(conv(h, w3, 1, 0), 0)
+        return jnp.sum(jnp.concatenate([p, q], axis=1).astype(jnp.float32) ** 2)
+    c = c1 + c2
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        S(c1, c, 1, 1), S(c2, c, 3, 3), S(cout, c, 1, 1), S(cout, c, 1, 1),
+        S(n, c, side, side)).compile().as_text()
+    return boundary(text, n * c * side * side)
+
+print("RESULT " + json.dumps({{"kernels": kernels, "standin": {{
+    "alexnet_pool1": alexnet_standin(512, 3, 227, 96, 11, 4, 0, 256),
+    "googlenet_pool1_ceil": alexnet_standin(128, 3, 224, 64, 7, 2, 3, 192),
+    "inception_3a_pool": inception_standin(128, 64, 128, 28, 32),
+    "inception_4e_pool": inception_standin(128, 256, 272, 14, 128)}}}}))
+"""
+
+
+def test_maxpool_bwd_kernel_meets_its_neighbours_layout_for_v5e():
+    """Mosaic takes the max-pool backward kernel at the cells' geometries
+    with the block the rule gives, and around a MAX pool the compiled text
+    moves or widens no array of the operand's size and keeps no
+    select-and-scatter."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _POOL_BOUNDARY.format(repo=REPO)],
+        capture_output=True, text=True, timeout=600, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)
+    bm, cm = "batch-minor HxWxCxN, block ", "channel-minor HxWxNxC, block "
+    assert got["kernels"] == {
+        "512x96x55 s2": [bm + "14x55x16x128", 1],
+        "512x256x27 s2": [bm + "28x27x16x128", 1],
+        "512x256x13 s2": [bm + "14x13x16x128", 1],
+        "128x64x112 s2": [bm + "6x112x16x128", 1],
+        "128x192x56 s2": [bm + "14x56x16x128", 1],
+        "128x480x28 s2": [bm + "28x28x16x128", 1],
+        "128x832x14 s2": [bm + "14x14x16x128", 1],
+        "128x192x28 s1": [bm + "14x28x16x128", 1],
+        "128x256x28 s1": [bm + "14x28x16x128", 1],
+        "128x480x14 s1": [bm + "14x14x16x128", 1],
+        "128x512x14 s1": [bm + "14x14x16x128", 1],
+        "128x528x14 s1": [bm + "14x14x16x128", 1],
+        # Mosaic takes it; the route keeps XLA's op there and says why
+        "128x832x7 s1": ["a dx block of 196 KB is all per-program overhead",
+                         1],
+        "32x64x112 s2": [cm + "6x112x16x64", 1],
+        "32x192x28 s1": [cm + "10x28x16x192", 1]}
+    for name, standin in got["standin"].items():
+        calls = 1 if name.startswith("inception") else 3   # + LRN fwd, bwd
+        assert standin == {
+            "pallas_custom_calls": calls, "moved": [],
+            "f32_of_operand_size": [], "select_and_scatter": 0}, name
+
 
 
 
@@ -614,6 +782,7 @@ print("RESULT " + json.dumps({{
     "pallas_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
     "lrn_operand_copies": len(re.findall(
         r"\[512,(?:96,3025|256,729|96,55,55|256,27,27)\]\S* copy\(", text)),
+    "select_and_scatter": text.count(" select-and-scatter("),
     "temp_gb": ma.temp_size_in_bytes / 1e9}}))
 """
 
@@ -623,8 +792,8 @@ def test_alexnet_one_chip_step_has_no_arena_for_one_v5e():
     """The one-chip CNN step the benchmark's cells run: the update is
     there (``optimizer_update`` op names), the arena is not — no
     ``arena_*`` op name, no array of the flat buffer's length, no
-    collective — and the LRN kernels are still Pallas, with no relayout
-    copy at their boundary."""
+    collective — and the LRN and max-pool backward kernels are Pallas,
+    with no relayout copy at their boundary and no select-and-scatter."""
     import json
     r = subprocess.run(
         [sys.executable, "-c", _CNN_STEP.format(repo=REPO)],
@@ -642,8 +811,11 @@ def test_alexnet_one_chip_step_has_no_arena_for_one_v5e():
     assert got["optimizer_update_ops"] >= 16     # one fusion a leaf at least
     assert got["buffer_length_arrays"] == 0
     assert got["all_reduces"] == 0
-    assert got["pallas_custom_calls"] == 4       # norm1, norm2: fwd and bwd
-    # the kernels take their operands as the compiler holds them: the two
-    # copies left are pool2's own, channel-minor (nine until PR 33)
-    assert got["lrn_operand_copies"] <= 3
+    # norm1, norm2: fwd and bwd; pool1, pool2, pool5: bwd (PR 35)
+    assert got["pallas_custom_calls"] == 7
+    assert got["select_and_scatter"] == 0
+    # the kernels take their operands as the compiler holds them (nine
+    # copies until PR 33; two, pool2's select-and-scatter's own, until the
+    # pools' backward took the LRN kernels' orientation in PR 35)
+    assert got["lrn_operand_copies"] == 0
     assert got["temp_gb"] < 3.0                  # 3.42 with the arena

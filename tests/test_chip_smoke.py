@@ -57,7 +57,7 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
     assert warm["phases"]["warm_resume"]["xla_entries_added"] == 0
     assert warm["phases"]["warm_resume"]["compiled_step"]["source"] == \
         "loaded"
-    assert len(cold["phases"]["kernels"]) == 8   # 24 at full size
+    assert len(cold["phases"]["kernels"]) == 11   # 35 at full size
     assert sorted(os.listdir(cache / "aot"))   # the step store rode along
 
 

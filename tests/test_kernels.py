@@ -2,11 +2,14 @@
 
 Three contracts, each pinned against the formulation it replaces:
 
-- **pool backward**: the custom VJP's two arms (the vectorized tap-sum of
-  the CPU mesh; select-and-scatter in f32, the TPU route since PR 24) must
-  match each other — f32 tolerance and bf16, both layouts, first-max-wins
-  ties bitwise — and Caffe's own backward loop at the benchmark
-  configurations' real geometries, summing overlaps in f32 under bf16;
+- **pool backward**: the custom VJP's three arms (the vectorized tap-sum of
+  the CPU mesh; select-and-scatter in f32, the TPU route of PR 24 and still
+  AVE pooling's; the one-pass Pallas max-pool kernel of PR 35, interpreted
+  here, in both operand orientations and with block edges inside windows)
+  must match each other — f32 tolerance and bf16, both layouts,
+  first-max-wins ties bitwise — and Caffe's own backward loop at the
+  benchmark configurations' real geometries, summing overlaps in f32 under
+  bf16;
 - **LRN**: Pallas fwd+bwd parity vs the XLA formulation in both layouts
   (f32 + bf16) and the routing defaults (XLA off-TPU, Pallas on TPU,
   ``POSEIDON_PALLAS_LRN=0`` opt-out, VMEM-cap fallback);
@@ -56,12 +59,17 @@ def _pool_grad(fn, x, k, s, p, layout):
     return np.asarray(jax.grad(f)(x))
 
 
-@pytest.mark.parametrize("method", ["max", "ave"])
+# (method, arm): the Pallas arm is MAX pooling's only (AVE stays on sas)
+POOL_ARMS = [("max", "taps"), ("ave", "taps"), ("max", "pallas")]
+
+
+@pytest.mark.parametrize("method,arm", POOL_ARMS)
 @pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
 @pytest.mark.parametrize("geom", POOL_GEOMS)
-def test_pool_bwd_strategies_match_reference(rng_np, pool_env, method,
+def test_pool_bwd_strategies_match_reference(rng_np, pool_env, method, arm,
                                              layout, geom):
-    """The tap-sum backward == select-and-scatter."""
+    """The tap-sum backward and the (interpreted) Pallas kernel ==
+    select-and-scatter."""
     k, s, p, h = geom
     fn = NN.max_pool if method == "max" else NN.ave_pool
     x = rng_np.randn(2, 5, h, h).astype(np.float32)
@@ -70,37 +78,50 @@ def test_pool_bwd_strategies_match_reference(rng_np, pool_env, method,
     x = jnp.asarray(x)
     pool_env("sas")
     ref = _pool_grad(fn, x, k, s, p, layout)
-    pool_env("taps")
+    pool_env(arm)
+    shape = (2, 5, h, h)
+    assert NN.pool_bwd_route(k, s, p, method, shape)[0] == arm
     got = _pool_grad(fn, x, k, s, p, layout)
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5,
-                               err_msg=f"{method}/{layout}")
+                               err_msg=f"{method}/{layout}/{arm}")
 
 
-@pytest.mark.parametrize("method", ["max", "ave"])
-def test_pool_bwd_bf16(rng_np, pool_env, method):
-    """bf16 activations: the tap-sum tracks the reference within bf16
-    resolution (both recompute and accumulate in f32)."""
+@pytest.mark.parametrize("method,arm", POOL_ARMS)
+def test_pool_bwd_bf16(rng_np, pool_env, method, arm):
+    """bf16 activations: the tap-sum and the Pallas kernel track the
+    reference within bf16 resolution (all recompute and accumulate in
+    f32)."""
     fn = NN.max_pool if method == "max" else NN.ave_pool
     x = jnp.asarray(rng_np.randn(2, 4, 9, 9).astype(np.float32)).astype(
         jnp.bfloat16)
     pool_env("sas")
     ref = _pool_grad(fn, x, (3, 3), (2, 2), (0, 0), "NCHW").astype(
         np.float32)
-    pool_env("taps")
+    pool_env(arm)
     got = _pool_grad(fn, x, (3, 3), (2, 2), (0, 0), "NCHW").astype(
         np.float32)
-    np.testing.assert_allclose(got, ref, rtol=0.05, atol=0.1, err_msg=method)
+    np.testing.assert_allclose(got, ref, rtol=0.05, atol=0.1,
+                               err_msg=f"{method}/{arm}")
 
 
-def test_pool_bwd_first_max_wins_ties(pool_env):
-    """Constant input: EVERY window position ties, so any argmax
-    divergence from Caffe's first-wins `>`-update rule shows up bitwise."""
-    x = jnp.ones((1, 3, 8, 8), jnp.float32)
+@pytest.mark.parametrize("value", [1.0, 0.0], ids=["ones", "relu_zeros"])
+@pytest.mark.parametrize("arm", ["taps", "pallas"])
+def test_pool_bwd_first_max_wins_ties(pool_env, arm, value):
+    """Constant input — ones, or the zeros a ReLU leaves in front of a
+    pool: EVERY window position ties, so any argmax divergence from
+    Caffe's first-wins `>`-update rule shows up bitwise."""
+    x = jnp.full((1, 3, 8, 8), value, jnp.float32)
+    w = jnp.arange(1.0, 1.0 + 3 * 5 * 5).reshape(1, 3, 5, 5)
+
+    def grad(x_):
+        return np.asarray(jax.grad(lambda a: jnp.sum(NN.max_pool(
+            a, (3, 3), (2, 2), (1, 1), "NCHW") * w))(x_))
+
     pool_env("sas")
-    ref = _pool_grad(NN.max_pool, x, (3, 3), (2, 2), (1, 1), "NCHW")
-    pool_env("taps")
-    got = _pool_grad(NN.max_pool, x, (3, 3), (2, 2), (1, 1), "NCHW")
-    np.testing.assert_array_equal(got, ref)
+    ref = grad(x)
+    pool_env(arm)
+    np.testing.assert_array_equal(grad(x), ref)
+    assert np.count_nonzero(ref) == 3 * 5 * 5      # one winner a window
 
 
 def test_pool_bwd_strategy_routing(monkeypatch):
@@ -122,11 +143,67 @@ def test_pool_bwd_strategy_routing(monkeypatch):
     for kernel in ((2, 2), (3, 3), (5, 5), (7, 7), (9, 9)):
         assert pool_bwd_route(kernel)[0] == "sas"
     assert pool_bwd_route((3, 3)) == ("sas", "")
-    # explicit override always wins; the deleted Pallas arm is no value
+    # explicit override always wins
     monkeypatch.setenv("POSEIDON_POOL_BWD", "taps")
     assert pool_bwd_route((3, 3)) == ("taps", "POSEIDON_POOL_BWD=taps")
+    # the Pallas arm is the rule's, not the switch's: without the layer's
+    # geometry the switch cannot put it anywhere
     monkeypatch.setenv("POSEIDON_POOL_BWD", "pallas")
     assert pool_bwd_route((3, 3)) == ("sas", "")
+
+
+S2, S1P1 = ((2, 2), (0, 0)), ((1, 1), (1, 1))
+
+
+ROUTE_CASES = [
+    # MAX pooling lowered for the TPU: the kernel, in the orientation the
+    # per-device batch gives (AlexNet's pool1 at 512 and 32 images a chip,
+    # GoogLeNet's ceil-mode pool1 and an inception pool at 128, LeNet's)
+    ("max", (3, 3), *S2, (512, 96, 55, 55), 2, "tpu",
+     ("pallas", "batch-minor HxWxCxN, block 14x55x16x128")),
+    ("max", (3, 3), *S2, (32, 96, 55, 55), 2, "tpu",
+     ("pallas", "channel-minor HxWxNxC, block 14x55x16x96")),
+    ("max", (3, 3), *S2, (128, 64, 112, 112), 2, "tpu", "pallas"),
+    ("max", (3, 3), *S1P1, (128, 512, 14, 14), 2, "tpu", "pallas"),
+    # a dx block too small to be worth a program (GoogLeNet's 5a / 5b)
+    ("max", (3, 3), *S1P1, (128, 832, 7, 7), 2, "tpu",
+     ("sas", "a dx block of 196 KB is all per-program overhead")),
+    ("max", (2, 2), *S2, (64, 20, 24, 24), 4, "tpu", "pallas"),
+    # AVE, global and oversized windows, a missing geometry: sas
+    ("ave", (5, 5), (3, 3), (0, 0), (128, 512, 14, 14), 2, "tpu",
+     ("sas", "")),
+    ("ave", (7, 7), (1, 1), (0, 0), (128, 1024, 7, 7), 2, "tpu",
+     ("sas", "")),
+    ("max", (9, 9), (1, 1), (0, 0), (128, 64, 9, 9), 2, "tpu",
+     ("sas", "window above 64 taps")),
+    ("max", (3, 3), *S2, None, 2, "tpu", ("sas", "")),
+    # a row of W too wide for any block: sas, and the note says why
+    ("max", (3, 3), *S2, (128, 64, 8, 40000), 2, "tpu",
+     ("sas", "no VMEM-legal block: 2 rows of 40000 x (16 x 128) need 5938 "
+             "MB, over 40 MB")),
+    # the CPU mesh: taps whatever the layer
+    ("max", (3, 3), *S2, (512, 96, 55, 55), 2, "cpu",
+     ("taps", "cpu backend")),
+    ("ave", (5, 5), (3, 3), (0, 0), (128, 512, 14, 14), 2, "cpu",
+     ("taps", "cpu backend")),
+]
+
+
+@pytest.mark.parametrize(
+    "case", ROUTE_CASES,
+    ids=lambda c: f"{c[0]}{c[1][0]}s{c[2][0]}p{c[3][0]}-{c[6]}-"
+                  + ("none" if c[4] is None else "x".join(map(str, c[4]))))
+def test_pool_bwd_route_is_the_layers_and_the_shapes(monkeypatch, case):
+    """`pool_bwd_route` with the layer's method, window and per-device
+    shape: the Pallas kernel for MAX pooling lowered for the TPU wherever a
+    block fits, select-and-scatter for AVE / global / oversized windows,
+    taps on the CPU mesh."""
+    method, kernel, stride, pad, shape, itemsize, backend, want = case
+    monkeypatch.delenv("POSEIDON_POOL_BWD", raising=False)
+    monkeypatch.setattr("poseidon_tpu.ops.pallas_kernels._interpret_default",
+                        lambda: backend == "cpu")
+    got = NN.pool_bwd_route(kernel, stride, pad, method, shape, itemsize)
+    assert (got[0] if isinstance(want, str) else got) == want
 
 
 def test_interpret_default_refuses_unknown_backends(monkeypatch):
@@ -146,7 +223,8 @@ def test_net_logs_and_records_kernel_routes(capsys, monkeypatch):
     """Which arm each pool backward / LRN takes is decided by the same
     function the op consults at trace time, logged once per layer at Net
     construction and kept on the net (the engine writes it to
-    stats.yaml): taps on the CPU mesh, select-and-scatter for the TPU."""
+    stats.yaml): taps on the CPU mesh, the Pallas kernel with its
+    orientation and block for a MAX pool lowered for the TPU."""
     from poseidon_tpu.core.net import Net
     from poseidon_tpu.models import zoo
     from poseidon_tpu.ops import pallas_kernels as PK
@@ -161,16 +239,58 @@ def test_net_logs_and_records_kernel_routes(capsys, monkeypatch):
     assert "[kernel_route] pool1: pool_bwd -> taps" in capsys.readouterr().out
     monkeypatch.setattr(PK, "_interpret_default", lambda: False)  # for TPU
     net = Net(zoo.alexnet(num_classes=10), "TRAIN",
-              source_shapes={"data": (2, 3, 67, 67), "label": (2,)})
+              source_shapes={"data": (16, 3, 131, 131), "label": (16,)})
     assert net.kernel_routes == {
-        "norm1": "lrn=pallas (channel-minor HWxNxC, block 240x2x96)",
-        "pool1": "pool_bwd=sas",
-        "norm2": "lrn=pallas (channel-minor HWxNxC, block 56x2x256)",
-        "pool2": "pool_bwd=sas", "pool5": "pool_bwd=sas"}
+        "norm1": "lrn=pallas (channel-minor HWxNxC, block 168x16x96)",
+        "pool1": "pool_bwd=pallas (channel-minor HxWxNxC, block 32x31x8x96)",
+        "norm2": "lrn=pallas (channel-minor HWxNxC, block 64x16x256)",
+        "pool2": "pool_bwd=pallas (channel-minor HxWxNxC, "
+                 "block 16x15x8x128)",
+        "pool5": "pool_bwd=sas"}
     out = capsys.readouterr().out
-    assert "[kernel_route] pool1: pool_bwd -> sas" in out
+    assert ("[kernel_route] pool1: pool_bwd -> pallas (channel-minor "
+            "HxWxNxC, block 32x31x8x96)") in out
+    # 16 x 256 x 7 x 7: the rule says why it kept XLA's op
+    assert ("[kernel_route] pool5: pool_bwd -> sas (a dx block of 196 KB "
+            "is all per-program overhead)") in out
     assert ("[kernel_route] norm1: lrn -> pallas (channel-minor HWxNxC, "
-            "block 240x2x96)") in out
+            "block 168x16x96)") in out
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_googlenet_names_an_arm_for_every_pooling_layer(monkeypatch,
+                                                        backend):
+    """GoogLeNet at the benchmark cell's 128 images a chip: eleven of its
+    thirteen MAX pools (four 3x3 s2 in ceil mode, seven 3x3 s1 p1 inside
+    the inception modules) take the Pallas kernel batch-minor when lowered
+    for the TPU; the two 3x3 s1 p1 on 7 x 7 (a dx block of 196 KB: all
+    per-program overhead) and the three AVE pools (5x5 s3 twice, the 7x7
+    head) stay on select-and-scatter; the CPU mesh takes the tap-sum for
+    all sixteen."""
+    from poseidon_tpu.core.net import Net
+    from poseidon_tpu.models import zoo
+    from poseidon_tpu.ops import pallas_kernels as PK
+    monkeypatch.delenv("POSEIDON_POOL_BWD", raising=False)
+    monkeypatch.setattr(PK, "_interpret_default", lambda: backend == "cpu")
+    with policy_scope(compute_dtype=jnp.bfloat16):
+        net = Net(zoo.googlenet(), "TRAIN",
+                  source_shapes={"data": (128, 3, 224, 224),
+                                 "label": (128,)})
+    pools = {k: v for k, v in net.kernel_routes.items()
+             if v.startswith("pool_bwd=")}
+    assert len(pools) == 16
+    ave = {"loss1/ave_pool", "loss2/ave_pool", "pool5/7x7_s1"}
+    if backend == "cpu":
+        assert set(pools.values()) == {"pool_bwd=taps"}
+        return
+    small = {"inception_5a/pool", "inception_5b/pool"}    # 7 x 7: too small
+    assert {k for k, v in pools.items() if v == "pool_bwd=sas"} == ave | small
+    assert all(v.startswith("pool_bwd=pallas (batch-minor HxWxCxN, block ")
+               for k, v in pools.items() if k not in ave | small)
+    assert pools["pool1/3x3_s2"] == (
+        "pool_bwd=pallas (batch-minor HxWxCxN, block 6x112x16x128)")
+    assert pools["inception_4e/pool"] == (
+        "pool_bwd=pallas (batch-minor HxWxCxN, block 14x14x16x128)")
 
 
 def test_one_channel_conv_takes_im2col_when_lowering_for_tpu(capsys,
@@ -206,6 +326,8 @@ REAL_POOL_GEOMS = [
     ("max", (3, 3), (2, 2), (0, 0), 55),
     ("max", (3, 3), (2, 2), (0, 0), 27),
     ("max", (3, 3), (2, 2), (0, 0), 13),
+    ("max", (3, 3), (2, 2), (0, 0), 112),   # ceil mode: 112 -> 56
+    ("max", (3, 3), (2, 2), (0, 0), 56),    # ceil mode: 56 -> 28
     ("max", (3, 3), (2, 2), (0, 0), 28),    # ceil mode: 28 -> 14
     ("max", (3, 3), (2, 2), (0, 0), 14),    # ceil mode: 14 -> 7
     ("max", (3, 3), (1, 1), (1, 1), 28),
@@ -238,26 +360,24 @@ def _caffe_pool_bwd(x, g, k, s, p, method):
     return dx
 
 
-def _lowering_for(monkeypatch, backend):
-    monkeypatch.delenv("POSEIDON_POOL_BWD", raising=False)
-    monkeypatch.setattr("poseidon_tpu.ops.pallas_kernels._interpret_default",
-                        lambda: backend == "cpu")
-    return {"tpu": "sas", "cpu": "taps"}[backend]
+def _geom_id(g):
+    return f"{g[0]}{g[1][0]}s{g[2][0]}p{g[3][0]}on{g[4]}"
 
 
-@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("arm", ["sas", "taps", "pallas"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("geom", REAL_POOL_GEOMS,
-                         ids=lambda g: f"{g[0]}{g[1][0]}s{g[2][0]}p{g[3][0]}"
-                                       f"on{g[4]}")
+@pytest.mark.parametrize("geom", REAL_POOL_GEOMS, ids=_geom_id)
 def test_pool_bwd_routes_match_caffe_at_real_geometries(
-        rng_np, monkeypatch, backend, dtype, geom):
-    """The formulation each backend's route lowers (select-and-scatter in
-    f32 for the TPU, the tap-sum for the CPU), run here on the CPU mesh,
-    against Caffe's own backward loop."""
+        rng_np, pool_env, arm, dtype, geom):
+    """Every formulation a route lowers (the Pallas kernel for MAX pooling
+    on the TPU, interpreted here; select-and-scatter in f32 for AVE there;
+    the tap-sum for the CPU), run on the CPU mesh, against Caffe's own
+    backward loop."""
     method, k, s, p, h = geom
-    arm = _lowering_for(monkeypatch, backend)
-    assert NN.pool_bwd_route(k)[0] == arm
+    pool_env(arm)
+    if arm == "pallas" and method == "ave":
+        arm = "sas"                      # the rule keeps AVE pooling on sas
+    assert NN.pool_bwd_route(k, s, p, method, (2, 3, h, h))[0] == arm
     fn = NN.max_pool if method == "max" else NN.ave_pool
     x = jnp.asarray(rng_np.randn(2, 3, h, h), dtype)
     y, vjp = jax.vjp(lambda x_: fn(x_, k, s, p, "NCHW"), x)
@@ -272,23 +392,78 @@ def test_pool_bwd_routes_match_caffe_at_real_geometries(
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("method", ["max", "ave"])
-@pytest.mark.parametrize("backend", ["tpu", "cpu"])
-def test_pool_bwd_bf16_sums_overlaps_in_f32(monkeypatch, backend, method):
+@pytest.mark.parametrize("orient", ["batch_minor", "channel_minor"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("geom",
+                         [g for g in REAL_POOL_GEOMS if g[0] == "max"],
+                         ids=_geom_id)
+def test_maxpool_bwd_kernel_matches_caffe_in_both_orientations(
+        rng_np, orient, dtype, geom):
+    """The kernel alone (interpreted) at every MAX geometry of the two
+    configurations, channels cut for the CPU: in both operand orientations
+    (a per-device batch of 128 is batch-minor, below it channel-minor),
+    with three or more blocks of rows, so that windows straddle block edges
+    and their halo rows decide, the ceil-mode row and column and Caffe's
+    pad as masked taps, and behind a ReLU, whose zeros tie: Caffe's first
+    maximum must win in every window, also one cut by a block edge."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+    _, k, s, p, h = geom
+    # (second, minor) tiles of 2 x 128 and 16 x 3: in bf16 the first is
+    # staged widened to f32, the second as packed 32-bit words
+    n, c = (128, 2) if orient == "batch_minor" else (16, 3)
+    oh = NN.pool_out_size(h, k[0], s[0], p[0])
+    rows = min(4 if h > 56 else 8, max(1, -(-h // 3) // s[0]) * s[0])
+    plan = PK._pool_plan(h, h, c, n, k, s, p, jnp.dtype(dtype).itemsize,
+                         rows)
+    assert plan.channel_axis == (1 if orient == "batch_minor" else 2)
+    assert plan.rows == rows and -(-h // rows) >= 2
+    x = jnp.asarray(np.maximum(rng_np.randn(n, c, h, h), 0), dtype)
+    g = jnp.asarray(rng_np.randn(n, c, oh, oh), dtype)
+    got = PK.maxpool_bwd(x, g, k, s, p, rows=rows, interpret=True)
+    assert got.dtype == x.dtype and got.shape == x.shape
+    want = _caffe_pool_bwd(np.asarray(x, np.float32),
+                           np.asarray(g, np.float32), k, s, p, "max")
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -7
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=tol, atol=tol)
+    # NHWC is the same kernel behind another logical transpose
+    if h <= 14:
+        nhwc = PK.maxpool_bwd(x.transpose(0, 2, 3, 1),
+                              g.transpose(0, 2, 3, 1), k, s, p,
+                              layout="NHWC", rows=rows, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(nhwc.transpose(0, 3, 1, 2), np.float32),
+            np.asarray(got, np.float32))
+
+
+@pytest.mark.parametrize("method,arm", [
+    ("max", "sas"), ("max", "taps"), ("ave", "sas"), ("ave", "taps"),
+    ("max", "pallas"), ("max", "pallas_halo")])
+def test_pool_bwd_bf16_sums_overlaps_in_f32(pool_env, method, arm):
     """Input position (2, 2) of a 3x3 s2 pool lies in four windows. With
     cotangents 256, 1, 1, 1 a bf16 accumulator stays at 256 (257 is no
-    bf16 number); the f32 sum 259 rounds once, to 260."""
-    _lowering_for(monkeypatch, backend)
-    x = np.zeros((1, 1, 5, 5), np.float32)
-    x[0, 0, 2, 2] = 1.0                     # the max of all four windows
-    g = np.array([[256.0, 1.0], [1.0, 1.0]], np.float32).reshape(1, 1, 2, 2)
+    bf16 number); the f32 sum 259 rounds once, to 260 — in every arm, and
+    in the Pallas kernel also where a block edge runs through the four
+    windows (rows 0-1 and 2-3 in different programs)."""
+    x = np.zeros((2, 16, 5, 5), np.float32)
+    x[:, :, 2, 2] = 1.0                     # the max of all four windows
+    g = np.broadcast_to(np.array([[256.0, 1.0], [1.0, 1.0]], np.float32),
+                        (2, 16, 2, 2))
     fn = NN.max_pool if method == "max" else NN.ave_pool
     scale = 1.0 if method == "max" else 9.0      # undo AVE's exact / 9
-    _, vjp = jax.vjp(lambda x_: fn(x_, (3, 3), (2, 2), (0, 0), "NCHW"),
-                     jnp.asarray(x, jnp.bfloat16))
-    dx = vjp(jnp.asarray(g * scale, jnp.bfloat16))[0]
+    xb, gb = jnp.asarray(x, jnp.bfloat16), jnp.asarray(g * scale,
+                                                       jnp.bfloat16)
+    if arm == "pallas_halo":
+        from poseidon_tpu.ops import pallas_kernels as PK
+        dx = PK.maxpool_bwd(xb, gb, (3, 3), (2, 2), (0, 0), rows=2,
+                            interpret=True)
+    else:
+        pool_env(arm)
+        _, vjp = jax.vjp(lambda x_: fn(x_, (3, 3), (2, 2), (0, 0), "NCHW"),
+                         xb)
+        dx = vjp(gb)[0]
     assert dx.dtype == jnp.bfloat16
-    assert float(dx[0, 0, 2, 2]) == 260.0
+    assert np.all(np.asarray(dx[:, :, 2, 2], np.float32) == 260.0)
 
 
 def test_pool_bwd_under_jit_and_in_net(rng_np, pool_env):

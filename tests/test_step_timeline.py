@@ -137,14 +137,16 @@ def test_engine_publishes_step_scopes_for_every_source(tmp_path,
         assert seen[run][1] == loss          # and the same arithmetic
 
 
-@pytest.mark.parametrize("arm", ["default_routes", "tpu_routes"])
+@pytest.mark.parametrize("arm", ["default_routes", "tpu_routes",
+                                 "tpu_routes_sas"])
 def test_pool_and_lrn_instructions_map_to_their_layer_and_pass(
         arm, monkeypatch):
     """Every instruction whose own metadata names the pool or the LRN
     layer lands on that layer with the pass its path says — for the arms
-    the CPU routes to, and for the TPU's (select-and-scatter; the Pallas
-    LRN kernels, interpreted here and custom calls on the chip: same
-    scopes, same join)."""
+    the CPU routes to, and for the TPU's (the Pallas max-pool backward
+    and LRN kernels, interpreted here and custom calls on the chip: same
+    scopes, same join — `pool_bwd_ms_per_step` reads the kernel where it
+    read the scatter; select-and-scatter, the A/B arm and AVE pooling's)."""
     import re
 
     import jax
@@ -152,15 +154,18 @@ def test_pool_and_lrn_instructions_map_to_their_layer_and_pass(
     from poseidon_tpu.core.net import Net
     from poseidon_tpu.proto.messages import load_net_from_string
 
-    if arm == "tpu_routes":
-        monkeypatch.setenv("POSEIDON_POOL_BWD", "sas")
+    pool_arm = {"tpu_routes": "pallas", "tpu_routes_sas": "sas"}.get(arm)
+    if pool_arm:
+        monkeypatch.setenv("POSEIDON_POOL_BWD", pool_arm)
         monkeypatch.setenv("POSEIDON_PALLAS_LRN", "1")
     net = Net(load_net_from_string(NET), "TRAIN",
               source_shapes={"data": (4, 4, 12, 12), "label": (4,)})
-    if arm == "tpu_routes":
-        assert net.kernel_routes == {
-            "norm1": "lrn=pallas (channel-minor HWxNxC, block 112x4x8)",
-            "pool1": "pool_bwd=sas"}
+    if pool_arm:
+        assert net.kernel_routes["norm1"] == (
+            "lrn=pallas (channel-minor HWxNxC, block 112x4x8)")
+        assert net.kernel_routes["pool1"].split(" ")[0] == (
+            f"pool_bwd={pool_arm}")
+        assert set(net.kernel_routes) == {"norm1", "pool1"}
     params = net.init(jax.random.PRNGKey(0))
     inputs = {"data": np.ones((4, 4, 12, 12), np.float32),
               "label": np.zeros((4,), np.int32)}
