@@ -278,18 +278,30 @@ class EmbedLayer(Layer):
 
 class RMSNormLayer(Layer):
     """x * rsqrt(mean(x^2) + eps) * g over the last axis; gain only (filled
-    with ones), statistics in f32."""
+    with ones), statistics in f32. With ``num_heads`` the last axis is that
+    many heads side by side: each is normalised over its own dims and ONE
+    gain of a head's width serves them all (a per-head QK-norm)."""
     TYPE = "RMS_NORM"
 
     def setup(self, bottom_shapes):
         ones = FillerParameter(type="constant", value=1.0)
-        self.params = [self._param("g", (bottom_shapes[0][-1],), ones, 0)]
+        width = bottom_shapes[0][-1]
+        self.heads = self.lp.rms_norm_param.num_heads
+        if self.heads < 0 or (self.heads and width % self.heads):
+            raise ValueError(f"{self.name}: {self.heads} heads do not split "
+                             f"a last axis of {width}")
+        self.params = [self._param(
+            "g", (width // self.heads if self.heads else width,), ones, 0)]
         return [bottom_shapes[0]]
 
     def apply(self, params, bottoms, ctx):
         from ..models.transformer import rms_norm
         g = _tap_all(ctx, self.name, params)["g"]
-        return [rms_norm(bottoms[0], g, self.lp.rms_norm_param.eps)]
+        x, eps = bottoms[0], self.lp.rms_norm_param.eps
+        if self.heads:
+            split = x.reshape(x.shape[:-1] + (self.heads, -1))
+            return [rms_norm(split, g, eps).reshape(x.shape)]
+        return [rms_norm(x, g, eps)]
 
 
 class AttentionLayer(Layer):
@@ -297,8 +309,12 @@ class AttentionLayer(Layer):
     ``num_heads`` heads, k and v into ``num_kv_heads`` of the same width
     (grouped-query attention; unset: as many as q, Dkv = D), rotate-half
     RoPE on the first ``rotary_dims`` of every q and k head (unset: the
-    whole head), causal softmax(q k^T / sqrt(Dh)) v, heads merged. It
-    normalises nothing: a QK-norm is the layers before it."""
+    whole head; ``rope: false``: no positions at all), causal
+    softmax(q k^T / sqrt(Dh)) v over every earlier token or, with a
+    ``window``, over the last that many (t - window < s <= t), heads
+    merged. Any whole number of query heads may share a key-value head (8
+    do in a 32 / 4 layer). It normalises nothing: a QK-norm is the layers
+    before it."""
     TYPE = "ATTENTION"
 
     def setup(self, bottom_shapes):
@@ -323,6 +339,13 @@ class AttentionLayer(Layer):
         if ap.rotary_dims % 2 or not 0 <= ap.rotary_dims <= d_head:
             raise ValueError(f"{self.name}: rotary_dims {ap.rotary_dims} "
                              f"is not an even part of a head of {d_head}")
+        if not ap.rope and ap.rotary_dims:
+            raise ValueError(f"{self.name}: rope false (no positions) and "
+                             f"rotary_dims {ap.rotary_dims} contradict "
+                             f"each other")
+        if ap.window < 0:
+            raise ValueError(f"{self.name}: window {ap.window} is negative "
+                             f"(0 = every earlier token)")
         return [bottom_shapes[0]]
 
     def apply(self, params, bottoms, ctx):
@@ -331,7 +354,8 @@ class AttentionLayer(Layer):
         return [rope_attention(*bottoms, n_heads=ap.num_heads,
                                rope_theta=ap.rope_theta,
                                n_kv_heads=ap.num_kv_heads,
-                               rotary_dims=ap.rotary_dims)]
+                               rotary_dims=ap.rotary_dims,
+                               window=ap.window, rope=ap.rope)]
 
 
 class MoELayer(Layer):
@@ -344,8 +368,9 @@ class MoELayer(Layer):
     - ``router_hidden`` 0: router (E, D) first. Tops: the output; the
       load-balancing and router z losses (scalars, weighted by the
       prototxt's ``loss_weight``);
-    - ``router_hidden`` > 0: no router here; a second bottom, the gates
-      (N, S, E) of the MOE_ROUTER layer before it. Tops: the output.
+    - ``router_hidden`` > 0 or ``score_func`` "sigmoid": no router here; a
+      second bottom, the gates (N, S, E) of the MOE_ROUTER layer before it
+      (each token's ``top_k`` weights, zero elsewhere). Tops: the output.
     Then optionally the step's own routing as up to three more scalars:
     assignments at the fullest HELD expert over their mean, assignments to
     a held expert that no expert computed (0: nothing is dropped), and the
@@ -358,7 +383,7 @@ class MoELayer(Layer):
         if not 0 < mp.top_k <= mp.num_experts or mp.expert_width <= 0:
             raise ValueError(f"{self.name}: moe_param needs num_experts >= "
                              f"top_k > 0 and expert_width")
-        self.gated = mp.router_hidden > 0
+        self.gated = mp.router_hidden > 0 or mp.score_func == "sigmoid"
         self.n_fixed = 1 if self.gated else 3
         if not self.n_fixed <= len(self.lp.top) <= self.n_fixed + 3:
             raise ValueError(
@@ -376,7 +401,8 @@ class MoELayer(Layer):
         want = [(n, s, d), (n, s, e)] if self.gated else [(n, s, d)]
         if [tuple(b) for b in bottom_shapes] != want:
             raise ValueError(f"{self.name}: MOE with router_hidden "
-                             f"{mp.router_hidden} takes bottoms {want}, got "
+                             f"{mp.router_hidden}, score_func "
+                             f"{mp.score_func!r} takes bottoms {want}, got "
                              f"{bottom_shapes}")
         g = self.held
         self.params = [] if self.gated else [
@@ -422,55 +448,91 @@ class MoELayer(Layer):
 
 
 class MoERouterLayer(Layer):
-    """ZAYA1's router (``models/moe.mlp_router``), a layer of its own so
-    that its time has a scope. Bottoms: the normed hidden state (N, S, D)
-    and, in every layer but the first, the router state (N, S, R) of the
-    layer before. Tops: this layer's router state (N, S, R) f32; the gates
-    (N, S, E) f32, the chosen expert's probability and zero elsewhere (what
-    the MOE layer after it takes); the selection bias's next value (E,).
-    Blobs: down (R, D), mix (R,) (only with the second bottom), w1, w2
-    (R, R), w3 (E, R), bias (E,). ``bias`` is a LAYER-UPDATED leaf
-    (``updates``): the step takes its next value from the third top and no
-    gradient, optimizer, decay or clip touches it."""
+    """A router that is a layer of its own, so that its time has a scope,
+    and that keeps a selection bias it balances itself. Two forms, by
+    ``moe_param``:
+
+    - ``router_hidden`` > 0 (``models/moe.mlp_router``, top-1): bottoms the
+      normed hidden state (N, S, D) and, in every layer but the first, the
+      router state (N, S, R) of the layer before. Tops: this layer's router
+      state (N, S, R) f32; the gates; the bias's next value. Blobs: down
+      (R, D), mix (R,) (only with the second bottom), w1, w2 (R, R), w3
+      (E, R), bias (E,).
+    - ``score_func`` "sigmoid" (``models/moe.sigmoid_router``, any top-k):
+      one bottom, the normed hidden state. Tops: the gates; the bias's next
+      value; optionally that value's largest magnitude, a scalar a display
+      carries. Blobs: w (E, D), bias (E,).
+
+    The gates (N, S, E) f32 hold each token's chosen experts' weights and
+    zero elsewhere (what the MOE layer after it takes). ``bias`` is a
+    LAYER-UPDATED leaf (``updates``): the step takes its next value from
+    the top after the gates and no gradient, optimizer, decay or clip
+    touches it; its rule's step is ``bias_update_rate``."""
     TYPE = "MOE_ROUTER"
 
     def setup(self, bottom_shapes):
         mp = self.lp.moe_param
         n, s, d = bottom_shapes[0]
         e, r = mp.num_experts, mp.router_hidden
-        if e <= 0 or r <= 0 or mp.top_k != 1:
-            raise ValueError(f"{self.name}: MOE_ROUTER needs num_experts, "
-                             f"router_hidden and top_k 1")
-        self.mixes = len(bottom_shapes) == 2
-        if len(bottom_shapes) > 2 or len(self.lp.top) != 3 or (
-                self.mixes and tuple(bottom_shapes[1]) != (n, s, r)):
-            raise ValueError(f"{self.name}: MOE_ROUTER takes (N, S, D) and "
-                             f"optionally (N, S, {r}), and has 3 tops; got "
-                             f"{bottom_shapes}, {len(self.lp.top)} tops")
         zero = FillerParameter(type="constant", value=0.0)
-        shapes = [("down", (r, d), mp.weight_filler)] \
-            + ([("mix", (r,), zero)] if self.mixes else []) \
-            + [("w1", (r, r), mp.weight_filler),
-               ("w2", (r, r), mp.weight_filler),
-               ("w3", (e, r), mp.weight_filler), ("bias", (e,), zero)]
+        self.sigmoid = mp.score_func == "sigmoid"
+        if mp.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"{self.name}: score_func {mp.score_func!r} is "
+                             f"neither softmax nor sigmoid")
+        if self.sigmoid:
+            if not 0 < mp.top_k <= e or r or len(bottom_shapes) != 1 \
+                    or len(self.lp.top) not in (2, 3):
+                raise ValueError(
+                    f"{self.name}: a sigmoid MOE_ROUTER needs num_experts >= "
+                    f"top_k > 0 and no router_hidden, takes (N, S, D) and "
+                    f"has 2 or 3 tops (gates, the bias's next value[, its "
+                    f"largest magnitude]); got {bottom_shapes}, "
+                    f"{len(self.lp.top)} tops")
+            shapes = [("w", (e, d), mp.weight_filler), ("bias", (e,), zero)]
+            tops = [(n, s, e), (e,)] + [()] * (len(self.lp.top) - 2)
+        else:
+            if e <= 0 or r <= 0 or mp.top_k != 1:
+                raise ValueError(f"{self.name}: an MLP MOE_ROUTER needs "
+                                 f"num_experts, router_hidden and top_k 1")
+            self.mixes = len(bottom_shapes) == 2
+            if len(bottom_shapes) > 2 or len(self.lp.top) != 3 or (
+                    self.mixes and tuple(bottom_shapes[1]) != (n, s, r)):
+                raise ValueError(
+                    f"{self.name}: MOE_ROUTER takes (N, S, D) and "
+                    f"optionally (N, S, {r}), and has 3 tops; got "
+                    f"{bottom_shapes}, {len(self.lp.top)} tops")
+            shapes = [("down", (r, d), mp.weight_filler)] \
+                + ([("mix", (r,), zero)] if self.mixes else []) \
+                + [("w1", (r, r), mp.weight_filler),
+                   ("w2", (r, r), mp.weight_filler),
+                   ("w3", (e, r), mp.weight_filler), ("bias", (e,), zero)]
+            tops = [(n, s, r), (n, s, e), (e,)]
+        self.updates = {"bias": tops.index((e,))}   # param -> the top it is
         self.params = [self._param(name, shape, filler, i)
                        for i, (name, shape, filler) in enumerate(shapes)]
         self.params[-1] = dataclasses.replace(self.params[-1],
                                               layer_updated=True)
-        self.updates = {"bias": 2}          # param -> the top it becomes
-        return [(n, s, r), (n, s, e), (e,)]
+        return tops
 
     def default_loss_weight(self) -> float:
         return 0.0
 
     def apply(self, params, bottoms, ctx):
-        from ..models.moe import mlp_router
+        from ..models.moe import mlp_router, sigmoid_router
+        mp = self.lp.moe_param
         p = _tap_all(ctx, self.name, params)
         n, s, d = bottoms[0].shape
+        flat = bottoms[0].reshape(n * s, d)
+        if self.sigmoid:
+            gates, bias = sigmoid_router(
+                flat, p["w"], p["bias"], mp.top_k, mp.route_scale,
+                mp.bias_update_rate)
+            return [gates.reshape(n, s, -1), bias,
+                    jnp.max(jnp.abs(bias))][:len(self.lp.top)]
         r, gates, bias = mlp_router(
-            bottoms[0].reshape(n * s, d),
-            bottoms[1].reshape(n * s, -1) if self.mixes else None,
-            p["down"], p.get("mix"), p["w1"], p["w2"], p["w3"], p["bias"])
+            flat, bottoms[1].reshape(n * s, -1) if self.mixes else None,
+            p["down"], p.get("mix"), p["w1"], p["w2"], p["w3"], p["bias"],
+            mp.bias_update_rate)
         return [r.reshape(n, s, -1), gates.reshape(n, s, -1), bias]
 
 

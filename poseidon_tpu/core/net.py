@@ -405,7 +405,8 @@ class Net:
                 arm, note = attention_route(
                     shape[1], shape[1],
                     shape[2] // layer.lp.attention_param.num_heads,
-                    jnp.dtype(policy().compute_dtype).itemsize)
+                    jnp.dtype(policy().compute_dtype).itemsize,
+                    window=layer.lp.attention_param.window)
                 if arm == "pallas_flash":
                     # the tiles each flash kernel runs with and the live /
                     # visited programs of its grid: stats.yaml carries them
@@ -417,6 +418,10 @@ class Net:
                     # heads reach their query heads
                     arm += (f"; {ap.num_kv_heads} kv heads repeated x"
                             f"{ap.num_heads // ap.num_kv_heads}")
+                if arm.startswith("dense") and 0 < ap.window < shape[1]:
+                    arm += f"; window {ap.window} as a dense mask"
+                if not ap.rope:
+                    arm += "; no positions"
             elif layer.TYPE == "MOE":
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"

@@ -321,14 +321,38 @@ def moe_gated(x: jax.Array, gates: jax.Array, gate: jax.Array, up: jax.Array,
                       held_first), sizes
 
 
-# the step u of mlp_router's balancing rule (the aux-loss-free sign rule at
-# DeepSeek-V3's rate); a constant until a configuration states a second value
-BIAS_UPDATE_RATE = 0.001
+def sigmoid_router(h: jax.Array, w: jax.Array, bias: jax.Array, top_k: int,
+                   route_scale: float, rate: float):
+    """A sigmoid router with a selection bias (the DeepSeek-V3 family's,
+    no group limit) over flat tokens h (T, D), in f32 whatever the policy
+    (top-k is discontinuous):
+
+        s = sigmoid(h w^T)                       (T, E)
+        chosen = the top_k largest of s + bias   the bias chooses,
+        weight_e = s_e / sum_chosen s * scale    it does not weigh
+
+    ``bias`` (E,) takes no gradient; it is balanced by the step's own
+    loads as ``mlp_router``'s is: bias_e + rate * sign(T k / E - n_e), n_e
+    the assignments to e (``rate``: the layer's ``bias_update_rate``).
+    Returns (gates (T, E) = the weights at the chosen experts and zero
+    elsewhere, the bias's next value)."""
+    f32 = jnp.float32
+    s = jax.nn.sigmoid(lax.dot_general(
+        h.astype(f32), w.astype(f32), (((1,), (1,)), ((), ())),
+        precision=lax.Precision.HIGHEST))
+    bias = lax.stop_gradient(bias.astype(f32))
+    _, experts = lax.top_k(s + bias, top_k)
+    chosen = jnp.sum(jax.nn.one_hot(experts, s.shape[1], dtype=f32), axis=1)
+    gates = s * chosen
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    mean_load = s.shape[0] * top_k / s.shape[1]
+    return gates * route_scale, bias + rate * jnp.sign(
+        mean_load - jnp.sum(chosen, axis=0))
 
 
 def mlp_router(h: jax.Array, r_prev: Optional[jax.Array], down: jax.Array,
                mix: jax.Array, w1: jax.Array, w2: jax.Array, w3: jax.Array,
-               bias: jax.Array, rate: float = BIAS_UPDATE_RATE):
+               bias: jax.Array, rate: float):
     """ZAYA1's router (arXiv:2511.17127) over flat tokens h (T, D), in f32
     whatever the policy (top-1 is discontinuous):
 
@@ -338,7 +362,8 @@ def mlp_router(h: jax.Array, r_prev: Optional[jax.Array], down: jax.Array,
         p = softmax(s);  e(t) = argmax_e (p_e + bias_e)
 
     ``bias`` (E,) takes no gradient; it is balanced by the step's own
-    loads: bias_e + rate * sign(T / E - n_e), n_e the tokens that chose e.
+    loads: bias_e + rate * sign(T / E - n_e), n_e the tokens that chose e
+    and ``rate`` the layer's ``bias_update_rate``.
     Returns (r, gates (T, E) = p at the chosen expert and zero elsewhere,
     the bias's next value)."""
     f32, hi = jnp.float32, lax.Precision.HIGHEST

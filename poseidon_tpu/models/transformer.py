@@ -147,7 +147,8 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
                    rope_theta: float = 10000.0, n_kv_heads: int = 0,
-                   rotary_dims: int = 0) -> jax.Array:
+                   rotary_dims: int = 0, window: int = 0,
+                   rope: bool = True) -> jax.Array:
     """q (B, S, D), k and v (B, S, Dkv) -> (B, S, D): q split into
     ``n_heads`` heads, k and v into ``n_kv_heads`` of the same width (0 =
     ``n_heads``, Dkv = D), rotary positions on q and k, causal
@@ -156,7 +157,9 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     are repeated to the query heads before the kernel (four copies of an
     (S, 128) head cost microseconds; their gradients sum in autodiff).
     ``rotary_dims`` (0 = the whole head): only the first that many dims of
-    a head rotate, the rest pass. The Pallas flash kernel where the sequence
+    a head rotate, the rest pass; ``rope`` false: nothing rotates, the
+    layer has no positions. ``window`` (0 = none): token t attends to s with
+    t - window < s <= t. The Pallas flash kernel where the sequence
     tiles (``maybe_flash_attention``), the dense op elsewhere."""
     b, s, d = q.shape
     d_head = d // n_heads
@@ -166,18 +169,21 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
         return t.reshape(b, s, n, d_head).swapaxes(1, 2)
 
     rot = rotary_dims or d_head
-    cos, sin = rope_tables(s, rot, rope_theta)
+    cos, sin = rope_tables(s, rot, rope_theta) if rope else (None, None)
 
-    def rope(t):
+    def rotate(t):
+        if not rope:
+            return t
         if rot == d_head:
             return apply_rope(t, cos, sin)
         return jnp.concatenate([apply_rope(t[..., :rot], cos, sin),
                                 t[..., rot:]], axis=-1)
 
-    q, k, v = rope(heads(q, n_heads)), rope(heads(k, n_kv)), heads(v, n_kv)
+    q, k, v = rotate(heads(q, n_heads)), rotate(heads(k, n_kv)), \
+        heads(v, n_kv)
     if n_kv != n_heads:
         k, v = (jnp.repeat(t, n_heads // n_kv, axis=1) for t in (k, v))
-    att = maybe_flash_attention(q, k, v, causal=True)
+    att = maybe_flash_attention(q, k, v, causal=True, window=window)
     return att.swapaxes(1, 2).reshape(b, s, d)
 
 

@@ -737,3 +737,153 @@ def zaya1(batch: int = 1, source: str = "examples/lm/zaya1_tokens.txt",
     layers.append(LayerParameter(
         name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
     return NetParameter(name=name, layers=layers)
+
+
+def trinity_mini(batch: int = 1,
+                 source: str = "examples/lm/trinity_mini_tokens.txt",
+                 n_layers: int = 32, dense_layers: int = 2,
+                 hidden: int = 2048, heads: int = 32, kv_heads: int = 4,
+                 head_dim: int = 128, window: int = 2048,
+                 global_every: int = 4, first_global: int = -1,
+                 dense_width: int = 6144,
+                 experts: int = 128, top_k: int = 8, held: int = 0,
+                 held_first: int = 0, expert_width: int = 1024,
+                 shared_width: int = 1024, route_scale: float = 2.826,
+                 bias_update_rate: float = 0.001, vocab: int = 200192,
+                 rope_theta: float = 10000.0, eps: float = 1e-5,
+                 init_std: float = 0.02,
+                 name: str = "Trinity-Mini") -> NetParameter:
+    """Trinity-Mini (config.json of arcee-ai/Trinity-Mini, ``afmoe``):
+    every layer is gated grouped-query attention and an FFN, each between a
+    norm before and a norm after it (four RMSNorms a layer):
+
+        h = x + N2(Attn(N1(x)));  y = h + N4(FFN(N3(h)))
+
+    Attention: q and a gate g (``heads`` x ``head_dim``), k and v
+    (``kv_heads`` x ``head_dim``) project the normed state; q and k take an
+    RMSNorm over each head's dims with one gain a projection
+    (``l<i>_q_norm``, ``l<i>_k_norm``); layer ``first_global`` (unset:
+    ``global_every`` - 1, as published) and every ``global_every``-th after
+    it is GLOBAL (``l<i>_attn_global``: every earlier token, no positions
+    at all), the others WINDOW layers (``l<i>_attn_window``: the last
+    ``window`` tokens, rotate-half rotary positions); the merged
+    heads times sigmoid(g) (``l<i>_gate_sig``, ``l<i>_gate_mul``) leave
+    through ``l<i>_o``.
+
+    FFN: the first ``dense_layers`` layers a SiLU-gated MLP of
+    ``dense_width``; the others ``l<i>_router`` (MOE_ROUTER: sigmoid scores
+    over all ``experts``, the ``top_k`` of score + selection bias chosen,
+    weighed by the unbiased scores over their sum times ``route_scale``,
+    the bias balanced by the layer at ``bias_update_rate``, its largest
+    magnitude a top that every display carries), ``l<i>_moe``
+    holding ``held`` of the experts from ``held_first`` on (0 = all: with
+    fewer the net is one rank's share of an expert-parallel model) and an
+    always-on shared expert (``l<i>_shared_*``) added unweighted.
+
+    The embedding's rows are scaled by sqrt(``hidden``) (``embed_scale``);
+    the head is untied. Gains and the selection bias carry decay_mult 0,
+    every matrix 1."""
+    from ..proto.messages import (AttentionParameter, EltwiseParameter,
+                                  EmbedParameter, HDF5DataParameter,
+                                  MoEParameter, PowerParameter,
+                                  RMSNormParameter)
+    w = gaussian(init_std)
+    lq, lk = heads * head_dim, kv_heads * head_dim
+    if first_global < 0:
+        first_global = global_every - 1
+    layers: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+    no_decay = ParamSpec(lr_mult=1.0, decay_mult=0.0)
+
+    def norm(lname, bottom, top, per_head=0):
+        layers.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[no_decay], rms_norm_param=RMSNormParameter(
+                eps=eps, num_heads=per_head)))
+
+    def proj(lname, bottom, top, n_out):
+        layers.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2, weight_filler=w)))
+
+    def eltwise(lname, a, b, top, operation="SUM"):
+        layers.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation=operation)))
+
+    def gated_mlp(p, bottom, top, width):
+        proj(p + "gate", bottom, p + "g", width)
+        proj(p + "up", bottom, p + "u", width)
+        layers.append(LayerParameter(
+            name=p + "act", type="SILU_GATE", bottom=[p + "g", p + "u"],
+            top=[p + "a"]))
+        proj(p + "down", p + "a", top, hidden)
+
+    layers.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["x_rows"],
+        embed_param=EmbedParameter(input_dim=vocab, num_output=hidden,
+                                   weight_filler=w)))
+    layers.append(LayerParameter(
+        name="embed_scale", type="POWER", bottom=["x_rows"], top=["x0"],
+        power_param=PowerParameter(scale=float(hidden) ** 0.5)))
+    x = "x0"
+    for i in range(n_layers):
+        p = f"l{i}_"
+        norm(p + "attn_norm", x, p + "a")
+        proj(p + "q", p + "a", p + "q", lq)
+        proj(p + "k", p + "a", p + "k", lk)
+        proj(p + "v", p + "a", p + "v", lk)
+        proj(p + "g", p + "a", p + "g", lq)
+        norm(p + "q_norm", p + "q", p + "qn", heads)
+        norm(p + "k_norm", p + "k", p + "kn", kv_heads)
+        is_global = i >= first_global \
+            and (i - first_global) % global_every == 0
+        layers.append(LayerParameter(
+            name=p + ("attn_global" if is_global else "attn_window"),
+            type="ATTENTION", bottom=[p + "qn", p + "kn", p + "v"],
+            top=[p + "att"], attention_param=AttentionParameter(
+                num_heads=heads, rope_theta=rope_theta,
+                num_kv_heads=kv_heads, rope=not is_global,
+                window=0 if is_global else window)))
+        layers.append(LayerParameter(
+            name=p + "gate_sig", type="SIGMOID", bottom=[p + "g"],
+            top=[p + "gs"]))
+        eltwise(p + "gate_mul", p + "att", p + "gs", p + "ag", "PROD")
+        proj(p + "o", p + "ag", p + "ao", hidden)
+        norm(p + "attn_out_norm", p + "ao", p + "aon")
+        eltwise(p + "res1", x, p + "aon", p + "h")
+        norm(p + "ffn_norm", p + "h", p + "u")
+        if i < dense_layers:
+            gated_mlp(p + "ffn_", p + "u", p + "f", dense_width)
+        else:
+            moe = dict(num_experts=experts, top_k=top_k,
+                       expert_width=expert_width, score_func="sigmoid",
+                       route_scale=route_scale,
+                       bias_update_rate=bias_update_rate, weight_filler=w)
+            layers.append(LayerParameter(
+                name=p + "router", type="MOE_ROUTER", bottom=[p + "u"],
+                top=[p + "gates", p + "bias_next", p + "bias_max_abs"],
+                param=[ParamSpec(), no_decay],
+                moe_param=MoEParameter(**moe)))
+            layers.append(LayerParameter(
+                name=p + "moe", type="MOE", bottom=[p + "u", p + "gates"],
+                top=[p + "m", p + "expert_load", p + "dropped",
+                     p + "held_share"],
+                moe_param=MoEParameter(num_held=held, held_first=held_first,
+                                       **moe)))
+            gated_mlp(p + "shared_", p + "u", p + "s", shared_width)
+            eltwise(p + "moe_sum", p + "m", p + "s", p + "f")
+        norm(p + "ffn_out_norm", p + "f", p + "fn")
+        eltwise(p + "res2", p + "h", p + "fn", p + "y")
+        x = p + "y"
+    norm("final_norm", x, "xf")
+    proj("lm_head", "xf", "logits", vocab)
+    layers.append(LayerParameter(
+        name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
+        top=["nll"]))
+    # the mean over positions: the exit-weighted loss of ONE pass
+    layers.append(LayerParameter(
+        name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    return NetParameter(name=name, layers=layers)

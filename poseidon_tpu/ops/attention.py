@@ -33,8 +33,10 @@ NEG_INF = -1e30
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = False, scale: Optional[float] = None,
-              bias: Optional[jax.Array] = None) -> jax.Array:
-    """Reference attention. q,k,v: (B, H, S, D) -> (B, H, Sq, D)."""
+              bias: Optional[jax.Array] = None,
+              window: Optional[int] = None) -> jax.Array:
+    """Reference attention. q,k,v: (B, H, S, D) -> (B, H, Sq, D).
+    ``window`` (causal): position t attends to s with t - window < s <= t."""
     p = policy()
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -48,6 +50,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((sq, sk), bool),
+                                    k=sk - sq - window)
         s = jnp.where(mask, s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     return lax.dot_general(

@@ -168,17 +168,21 @@ def flash_blocks(kernel: str, s: int, d: int, itemsize: int):
     return best
 
 
-def _causal_mask(s, qi, kj, block_q, block_k, mode=None, transposed=False):
+def _causal_mask(s, qi, kj, block_q, block_k, mode=None, transposed=False,
+                 window=None):
     """Self-attention: mask by absolute tile position. Chunked (ring) mode:
     ``mode`` is a traced scalar describing how the K/V chunk aligns with the
     Q rows' chunk — +1 chunk strictly past (all live), 0 diagonal (in-chunk
     triangle), -1 future (all masked). ``transposed``: ``s`` is the
-    (block_k, block_q) tile of the dK/dV sweep, keys down the rows."""
+    (block_k, block_q) tile of the dK/dV sweep, keys down the rows.
+    ``window``: row t also loses the columns at or before t - window."""
     shape = (block_k, block_q) if transposed else (block_q, block_k)
     rows = qi * block_q + lax.broadcasted_iota(
         jnp.int32, shape, 1 if transposed else 0)
     cols = kj * block_k + lax.broadcasted_iota(
         jnp.int32, shape, 0 if transposed else 1)
+    if window is not None:
+        return jnp.where((rows >= cols) & (rows - cols < window), s, NEG_INF)
     if mode is None:
         return jnp.where(rows >= cols, s, NEG_INF)
     live = (mode > 0) | ((mode == 0) & (rows >= cols))
@@ -186,14 +190,18 @@ def _causal_mask(s, qi, kj, block_q, block_k, mode=None, transposed=False):
 
 
 def _on_live_blocks(update, causal: bool, chunk_mode: bool, qi, kj,
-                    block_q: int, block_k: int) -> None:
+                    block_q: int, block_k: int, window=None,
+                    in_range=None) -> None:
     """Run ``update(masked)`` where block (qi, kj) has anything to add.
     Causal self-attention knows that from the grid position: a block wholly
     below the diagonal needs no mask, one the diagonal crosses is masked
     element by element, one wholly above it is skipped (its operands are
-    not fetched either: ``_last_live_k`` / ``_first_live_q``). Ring
-    attention's chunk alignment is a traced scalar: every block takes the
-    masked path."""
+    not fetched either: ``_last_live_k`` / ``_first_live_q``). With a
+    ``window`` the live blocks are a band: one wholly at or before the
+    band's lower edge is skipped as well, one the edge crosses is masked.
+    ``in_range``: the windowed dK/dV sweep's Q block lies inside the
+    sequence (its grid may step past the end). Ring attention's chunk
+    alignment is a traced scalar: every block takes the masked path."""
     if not causal:
         update(False)
     elif chunk_mode:
@@ -201,9 +209,19 @@ def _on_live_blocks(update, causal: bool, chunk_mode: bool, qi, kj,
     else:
         first_row, last_row = qi * block_q, qi * block_q + block_q - 1
         first_col, last_col = kj * block_k, kj * block_k + block_k - 1
-        pl.when(last_col <= first_row)(lambda: update(False))
-        pl.when((first_col <= last_row) & (last_col > first_row))(
-            lambda: update(True))
+        if window is None:
+            pl.when(last_col <= first_row)(lambda: update(False))
+            pl.when((first_col <= last_row) & (last_col > first_row))(
+                lambda: update(True))
+            return
+        # row - col lies in [first_row - last_col, last_row - first_col]
+        # over the block, and has to lie in [0, window)
+        inside = (last_col <= first_row) & (last_row - first_col < window)
+        live = (first_col <= last_row) & (first_row - last_col < window)
+        if in_range is not None:
+            live = live & in_range
+        pl.when(inside & live)(lambda: update(False))
+        pl.when(live & jnp.logical_not(inside))(lambda: update(True))
 
 
 def _last_live_k(qi, block_q: int, block_k: int):
@@ -216,20 +234,63 @@ def _first_live_q(kj, block_q: int, block_k: int):
     return (kj * block_k) // block_q
 
 
-def flash_grid_programs(s: int, block_q: int, block_k: int, causal: bool):
+def _at_least_0(x):
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+def _first_live_k(qi, block_q: int, block_k: int, window: int):
+    """Windowed self-attention: the first K/V block a Q block attends to
+    (the one that holds column first_row - window + 1)."""
+    return _at_least_0(qi * block_q - window + 1) // block_k
+
+
+def _last_live_q(kj, block_q: int, block_k: int, window: int):
+    """Windowed self-attention: the last Q block that attends to a K/V
+    block (the one that holds row last_col + window - 1), which may lie past
+    the sequence's end: callers cap it."""
+    return (kj * block_k + block_k + window - 2) // block_q
+
+
+def _band_steps(s: int, block_q: int, block_k: int, window: int,
+                over_q: bool = False) -> int:
+    """Windowed self-attention: the innermost grid extent, the most live
+    blocks any one outer block has — K/V blocks a Q block (the forward and
+    the dQ sweep), or with ``over_q`` Q blocks a K/V block (the dK/dV
+    sweep). The grid then starts each outer block at its first live block
+    and visits nothing before the band."""
+    n_qb, n_kb = s // block_q, s // block_k
+    if over_q:
+        return max(min(n_qb - 1, _last_live_q(kj, block_q, block_k, window))
+                   - _first_live_q(kj, block_q, block_k) + 1
+                   for kj in range(n_kb))
+    return max(_last_live_k(qi, block_q, block_k)
+               - _first_live_k(qi, block_q, block_k, window) + 1
+               for qi in range(n_qb))
+
+
+def flash_grid_programs(s: int, block_q: int, block_k: int, causal: bool,
+                        window: Optional[int] = None, over_q: bool = False):
     """(live, visited) programs per head of one flash grid. A visited block
     that is not live runs no body and names the operand tile already
-    resident, so it costs one empty grid step."""
+    resident, so it costs one empty grid step. With a ``window`` the grid
+    is the band's (``_band_steps``; ``over_q``: the dK/dV sweep's)."""
     n_qb, n_kb = s // block_q, s // block_k
     if not causal:
         return n_qb * n_kb, n_qb * n_kb
-    live = sum(min(n_kb, _last_live_k(qi, block_q, block_k) + 1)
+    if window is None:
+        live = sum(min(n_kb, _last_live_k(qi, block_q, block_k) + 1)
+                   for qi in range(n_qb))
+        return live, n_qb * n_kb
+    live = sum(_last_live_k(qi, block_q, block_k)
+               - _first_live_k(qi, block_q, block_k, window) + 1
                for qi in range(n_qb))
-    return live, n_qb * n_kb
+    outer = n_kb if over_q else n_qb
+    return live, outer * _band_steps(s, block_q, block_k, window, over_q)
 
 
 def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
-                      block_k: int, n_kb: int, chunk_mode: bool, op_dtype):
+                      block_k: int, n_kb: int, chunk_mode: bool, op_dtype,
+                      window=None):
     """Grid (bh, q_blocks, k_blocks); only one (block_q, d) Q tile and one
     (block_k, d) K/V tile are VMEM-resident at a time. The online-softmax
     state persists in f32 scratch across the innermost (k-block) grid
@@ -237,7 +298,10 @@ def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
     backward kernels consume (flash attention paper's L = m + log l).
 
     ``chunk_mode`` (ring attention): a leading SMEM scalar describes the
-    chunk alignment for causal masking (see _causal_mask)."""
+    chunk alignment for causal masking (see _causal_mask).
+
+    ``window``: the innermost grid dimension has ``n_kb`` = the band's
+    steps and starts at the Q block's first live K/V block."""
     if chunk_mode:
         mode_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, \
             acc_ref, m_ref, l_ref = refs
@@ -246,9 +310,11 @@ def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
         mode = None
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = kj = pl.program_id(2)
+    if window is not None:
+        kj = _first_live_k(qi, block_q, block_k, window) + step
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -260,7 +326,8 @@ def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         v_blk = v_ref[0].astype(op_dtype)
         s = _dot(q, k_blk, _NT) * scale           # f32 from here on
         if masked:
-            s = _causal_mask(s, qi, kj, block_q, block_k, mode)
+            s = _causal_mask(s, qi, kj, block_q, block_k, mode,
+                             window=window)
         m_prev = m_ref[...]                       # (block_q, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -270,9 +337,10 @@ def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
             p.astype(op_dtype), v_blk, _NN)
         m_ref[...] = m_new
 
-    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k)
+    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k,
+                    window)
 
-    @pl.when(kj == n_kb - 1)
+    @pl.when(step == n_kb - 1)
     def _finalize():
         l = l_ref[...]
         lsafe = jnp.where(l == 0, 1.0, l)
@@ -299,10 +367,16 @@ def _blocks_for(kernel: str, q, block_q, block_k):
     return block_q, block_k
 
 
-def _kv_block_map(clamp: bool, block_q: int, block_k: int):
+def _kv_block_map(clamp: bool, block_q: int, block_k: int, window=None):
     """Index map of a K/V tile on the (bh, q_blocks, k_blocks) grid.
     ``clamp`` (causal self-attention): a block above the diagonal names the
-    last live K/V tile again, which is resident, so no copy is issued."""
+    last live K/V tile again, which is resident, so no copy is issued.
+    ``window``: the grid's last dimension counts from the Q block's first
+    live K/V block."""
+    if window is not None:
+        return lambda i, j, kk: (
+            i, jnp.minimum(_first_live_k(j, block_q, block_k, window) + kk,
+                           _last_live_k(j, block_q, block_k)), 0)
     if clamp:
         return lambda i, j, kk: (
             i, jnp.minimum(kk, _last_live_k(j, block_q, block_k)), 0)
@@ -316,10 +390,12 @@ def _compiler_params():
 
 
 def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
-               block_k: Optional[int], interpret: bool, mode=None):
+               block_k: Optional[int], interpret: bool, mode=None,
+               window: Optional[int] = None):
     """mode (traced int32 scalar) selects chunked causal masking for ring
     attention; None = plain self-attention. Blocks of None: the tile rule's
-    (``flash_blocks``)."""
+    (``flash_blocks``). ``window`` (causal self-attention, below S): the
+    grid covers the band alone."""
     b, h, s, d = q.shape
     bh = b * h
     q3 = q.reshape(bh, s, d)
@@ -327,9 +403,13 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
     v3 = v.reshape(bh, s, d)
     block_q, block_k = _blocks_for("fwd", q, block_q, block_k)
     n_kb = s // block_k
+    extra = {}
+    if window is not None:
+        n_kb = _band_steps(s, block_q, block_k, window)
+        extra = {"window": window}
     grid = (bh, s // block_q, n_kb)
     chunk = mode is not None
-    kmap = _kv_block_map(causal and not chunk, block_q, block_k)
+    kmap = _kv_block_map(causal and not chunk, block_q, block_k, window)
     qmap = lambda i, j, kk: (i, j, 0)
     in_specs = [
         pl.BlockSpec((1, block_q, d), qmap, memory_space=pltpu.VMEM),
@@ -344,7 +424,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_kb=n_kb,
                           chunk_mode=chunk,
-                          op_dtype=_operand_dtype(q.dtype)),
+                          op_dtype=_operand_dtype(q.dtype), **extra),
         name="flash_fwd",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)),
@@ -370,7 +450,8 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
 # --------------------------------------------------------------------------- #
 
 def _flash_dq_kernel(*refs, scale: float, causal: bool, block_q: int,
-                     block_k: int, n_kb: int, chunk_mode: bool, op_dtype):
+                     block_k: int, n_kb: int, chunk_mode: bool, op_dtype,
+                     window=None):
     """Grid (bh, q_blocks, k_blocks): accumulate dQ for one Q tile across all
     K/V tiles. p is recomputed from Q,K and the saved logsumexp — the score
     matrix never exists outside one VMEM tile. The softmax scale on dS is
@@ -383,9 +464,11 @@ def _flash_dq_kernel(*refs, scale: float, causal: bool, block_q: int,
         q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs
         mode = None
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = kj = pl.program_id(2)
+    if window is not None:                  # the band's grid: see the forward
+        kj = _first_live_k(qi, block_q, block_k, window) + step
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -396,27 +479,34 @@ def _flash_dq_kernel(*refs, scale: float, causal: bool, block_q: int,
         g = g_ref[0].astype(op_dtype)
         s = _dot(q, k_blk, _NT) * scale
         if masked:
-            s = _causal_mask(s, qi, kj, block_q, block_k, mode)
+            s = _causal_mask(s, qi, kj, block_q, block_k, mode,
+                             window=window)
         p = jnp.exp(s - lse_ref[0])               # masked entries -> 0
         dp = _dot(g, v_blk, _NT)
         ds = p * (dp - delta_ref[0])              # lse, delta: (block_q, 1)
         dq_acc[...] = dq_acc[...] + _dot(ds.astype(op_dtype), k_blk, _NN)
 
-    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k)
+    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k,
+                    window)
 
-    @pl.when(kj == n_kb - 1)
+    @pl.when(step == n_kb - 1)
     def _finalize():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
-                      block_k: int, n_qb: int, chunk_mode: bool, op_dtype):
+                      block_k: int, n_qb: int, chunk_mode: bool, op_dtype,
+                      window=None, q_blocks=None):
     """Grid (bh, k_blocks, q_blocks): accumulate dK and dV for one K/V tile
     across all Q tiles. The scores are computed TRANSPOSED, keys down the
     rows ((block_k, block_q) = K Q^T), so that P^T dO and dS^T Q are plain
     products of the tile as it lies and nothing score-shaped goes through a
     transpose; the row statistics then broadcast down the sublanes from a
-    lane-dense (1, block_q) tile."""
+    lane-dense (1, block_q) tile.
+
+    ``window``: the innermost grid dimension has ``n_qb`` = the band's
+    steps and starts at the K/V block's first live Q block; ``q_blocks`` is
+    then how many Q blocks the sequence has (a step may lie past them)."""
     if chunk_mode:
         mode_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, \
             dk_ref, dv_ref, dk_acc, dv_acc = refs
@@ -426,9 +516,13 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
             dk_ref, dv_ref, dk_acc, dv_acc = refs
         mode = None
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = qi = pl.program_id(2)
+    in_range = None
+    if window is not None:
+        qi = _first_live_q(kj, block_q, block_k) + step
+        in_range = qi < q_blocks
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -441,16 +535,17 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
         st = _dot(k_blk, q, _NT) * scale          # (block_k, block_q)
         if masked:
             st = _causal_mask(st, qi, kj, block_q, block_k, mode,
-                              transposed=True)
+                              transposed=True, window=window)
         pt = jnp.exp(st - lse_ref[0, 0])          # lse, delta: (1, block_q)
         dv_acc[...] = dv_acc[...] + _dot(pt.astype(op_dtype), g, _NN)
         dpt = _dot(v_blk, g, _NT)
         dst = pt * (dpt - delta_ref[0, 0])
         dk_acc[...] = dk_acc[...] + _dot(dst.astype(op_dtype), q, _NN)
 
-    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k)
+    _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k,
+                    window, in_range)
 
-    @pl.when(qi == n_qb - 1)
+    @pl.when(step == n_qb - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -458,8 +553,10 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
 
 def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
                block_q: Optional[int], block_k: Optional[int],
-               interpret: bool, mode=None, delta=None):
-    """mode, blocks: see _flash_fwd (the rule sizes the two sweeps apart).
+               interpret: bool, mode=None, delta=None,
+               window: Optional[int] = None):
+    """mode, blocks, window: see _flash_fwd (the rule sizes the two sweeps
+    apart).
     ``delta`` (rowsum(dO*O), global) may be passed in by the ring backward,
     whose O is the merged global output."""
     b, h, s, d = q.shape
@@ -481,15 +578,19 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     bq, bk = _blocks_for("dq", q, block_q, block_k)
     qmap = lambda i, j, kk: (i, j, 0)
     qspec = vmem((1, bq, d), qmap)
-    kspec = vmem((1, bk, d), _kv_block_map(clamp, bq, bk))
+    kspec = vmem((1, bk, d), _kv_block_map(clamp, bq, bk, window))
     rowq = vmem((1, bq, 1), qmap)
+    n_kb, extra = s // bk, {}
+    if window is not None:
+        n_kb = _band_steps(s, bq, bk, window)
+        extra = {"window": window}
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, n_kb=s // bk,
-                          chunk_mode=chunk, op_dtype=op_dtype),
+                          block_q=bq, block_k=bk, n_kb=n_kb,
+                          chunk_mode=chunk, op_dtype=op_dtype, **extra),
         name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        grid=(bh, s // bq, s // bk),
+        grid=(bh, s // bq, n_kb),
         in_specs=smem + [qspec, kspec, kspec, qspec, rowq, rowq],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -501,8 +602,18 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     # dK/dV sweep: swapped grid (bh, k_blocks, q_blocks); the row statistics
     # one lane-dense (1, block_q) row per Q block
     bq, bk = _blocks_for("dkv", q, block_q, block_k)
-    n_qb = s // bq
-    if clamp:
+    n_qb = steps = s // bq
+    extra = {}
+    if window is not None:
+        # the grid's last dimension counts from the K/V block's first live
+        # Q block; past the band's last (or the sequence's) it names that
+        # one again
+        steps = _band_steps(s, bq, bk, window, over_q=True)
+        extra = {"window": window, "q_blocks": n_qb}
+        qblk = lambda j, kk: jnp.minimum(
+            _first_live_q(j, bq, bk) + kk,
+            jnp.minimum(_last_live_q(j, bq, bk, window), n_qb - 1))
+    elif clamp:
         # a Q block above the diagonal names the first live one again
         qblk = lambda j, kk: jnp.maximum(kk, _first_live_q(j, bq, bk))
     else:
@@ -512,12 +623,12 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     rowq_t = vmem((1, 1, 1, bq), lambda i, j, kk: (i, qblk(j, kk), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, n_qb=n_qb,
-                          chunk_mode=chunk, op_dtype=op_dtype),
+                          block_q=bq, block_k=bk, n_qb=steps,
+                          chunk_mode=chunk, op_dtype=op_dtype, **extra),
         name="flash_bwd_dkv",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
-        grid=(bh, s // bk, n_qb),
+        grid=(bh, s // bk, steps),
         in_specs=smem + [qspec_t, kspec_t, kspec_t, qspec_t, rowq_t, rowq_t],
         out_specs=(kspec_t, kspec_t),
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
@@ -531,54 +642,69 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     return rs(dq), rs(dk), rs(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _band_window(window: Optional[int], causal: bool, s: int):
+    """The window the kernels are built with: None where it masks nothing
+    (``window >= S`` is the causal kernel, traced as it always was)."""
+    if not window or window >= s:
+        return None
+    if not causal:
+        raise ValueError("a window needs causal attention")
+    return int(window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
     """Pallas blockwise attention; (B, H, S, D) -> (B, H, S, D). With no
     blocks given each of the three kernels takes ``flash_blocks``' tiles;
-    a given (block_q, block_k) is used by all three."""
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        interpret = _interpret_default()
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+    a given (block_q, block_k) is used by all three. ``window`` (causal):
+    token t attends to s with t - window < s <= t; the three kernels then
+    run, fetch and visit the band's blocks alone."""
+    out, _ = _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k,
+                            interpret, window)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                   window=None):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = _interpret_default()
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                          window=_band_window(window, causal, q.shape[-2]))
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window, res,
+                   g):
     q, k, v, out, lse = res
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = _interpret_default()
     return _flash_bwd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
-                      interpret)
+                      interpret,
+                      window=_band_window(window, causal, q.shape[-2]))
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def attention_route(s: int, sk: int, d: int, itemsize: int,
-                    causal: bool = True):
+                    causal: bool = True, window: Optional[int] = None):
     """``(arm, note)`` for one attention geometry — THE routing decision:
     ``maybe_flash_attention`` takes it at trace time and ``Net`` logs it
     per ATTENTION layer at construction, for Q and K/V lengths ``s`` and
     ``sk``, head width ``d`` and operand ``itemsize``. ``"pallas_flash"`` when the
     sequence tiles cleanly (an aligned block divides it, self-attention
     lengths), the note then stating each kernel's ``block_q x block_k`` from
-    ``flash_blocks`` and the live / visited programs of its grid per head;
+    ``flash_blocks`` and the live / visited programs of its grid per head
+    (with a ``window`` below S: the band's, and the note says so);
     ``"dense"`` on the CPU test mesh (the kernel would run in
     interpret-mode emulation — strictly slower than the dense op it
     replaces) and for shapes the kernel does not tile."""
@@ -588,29 +714,34 @@ def attention_route(s: int, sk: int, d: int, itemsize: int,
         return "dense", "cross-attention lengths"
     if pick_block(s) is None:
         return "dense", f"no aligned block divides S={s}"
+    window = _band_window(window, causal, s)
     parts = []
     for kernel in ("fwd", "dq", "dkv"):
         bq, bk = flash_blocks(kernel, s, d, itemsize)
-        live, visited = flash_grid_programs(s, bq, bk, causal)
+        live, visited = flash_grid_programs(s, bq, bk, causal, window,
+                                            over_q=kernel == "dkv")
         parts.append(f"{kernel} {bq}x{bk} {live}/{visited}")
     return "pallas_flash", ", ".join(parts) + \
-        "; block_q x block_k, live/visited programs a head"
+        "; block_q x block_k, live/visited programs a head" + \
+        (f"; window {window}: the band's grid" if window else "")
 
 
 def maybe_flash_attention(q, k, v, causal: bool = False,
-                          scale: Optional[float] = None) -> jax.Array:
+                          scale: Optional[float] = None,
+                          window: Optional[int] = None) -> jax.Array:
     """Attention through :func:`attention_route`'s arm — and say which,
     once per shape. The training entry point for models/transformer.py
     (both blocks) and the Ulysses head-parallel path."""
     from .attention import attention
     s, d = q.shape[-2:]
-    arm, note = attention_route(s, k.shape[-2], d, q.dtype.itemsize, causal)
+    arm, note = attention_route(s, k.shape[-2], d, q.dtype.itemsize, causal,
+                                window)
     where = f"[kernel_route] attention S={s} D={d}"
     if arm == "pallas_flash":
         _log_route_once(f"{where}: pallas flash, {note}")
-        return flash_attention(q, k, v, causal, scale)
+        return flash_attention(q, k, v, causal, scale, window=window)
     _log_route_once(f"{where}: dense ({note})")
-    return attention(q, k, v, causal=causal, scale=scale)
+    return attention(q, k, v, causal=causal, scale=scale, window=window)
 
 
 # --------------------------------------------------------------------------- #

@@ -289,6 +289,10 @@ class EmbedParameter:
 @dataclass
 class RMSNormParameter:
     eps: float = 1e-5
+    # > 0: the last axis is that many heads side by side, each normalised
+    # over its own dims, ONE gain of a head's width shared by all of them
+    # (a per-head QK-norm); 0 = over the whole last axis
+    num_heads: int = 0
 
 
 @dataclass
@@ -301,6 +305,11 @@ class AttentionParameter:
     # rotary positions on the first ``rotary_dims`` of every head, the rest
     # pass as they are; 0 = the whole head
     rotary_dims: int = 0
+    # false: no positions at all (q and k reach the scores as they come)
+    rope: bool = True
+    # > 0: token t attends to s with t - window < s <= t (a sliding window
+    # inside the causal mask); 0 = every earlier token
+    window: int = 0
 
 
 @dataclass
@@ -308,8 +317,14 @@ class MoEParameter:
     """MOE and MOE_ROUTER. ``num_experts`` is what the router scores;
     ``num_held`` (0 = all) of them, from ``held_first`` on, have their
     weights in this layer: one rank's share of an expert-parallel layer.
-    ``router_hidden`` 0 = the router is one (E, D) matrix inside MOE; > 0 =
-    a MOE_ROUTER layer of that width scores and MOE takes its gates."""
+    ``score_func`` "softmax" with ``router_hidden`` 0 = the router is one
+    (E, D) matrix inside MOE; with ``router_hidden`` > 0 = a MOE_ROUTER
+    layer, an MLP of that width, scores and MOE takes its gates.
+    ``score_func`` "sigmoid" = a MOE_ROUTER layer of one (E, D) matrix:
+    sigmoid scores, the ``top_k`` largest of score + selection bias chosen,
+    their weights the UNBIASED scores divided by their sum over the chosen
+    and times ``route_scale``. ``bias_update_rate``: the step of a
+    MOE_ROUTER's balancing rule on its selection bias."""
     num_experts: int = 0
     top_k: int = 1
     expert_width: int = 0
@@ -317,6 +332,9 @@ class MoEParameter:
     num_held: int = 0
     held_first: int = 0
     router_hidden: int = 0
+    score_func: str = "softmax"
+    route_scale: float = 1.0
+    bias_update_rate: float = 0.001
 
 
 @dataclass

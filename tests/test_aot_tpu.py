@@ -452,6 +452,67 @@ def test_zaya_full_width_step_fits_one_v5e_at_its_depth_and_no_deeper(deeper):
         assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9
 
 
+# The full-width Trinity-Mini train step (examples/lm/trinity_mini_*: 1 dense +
+# 4 MoE layers, 16 of 128 experts held, an eighth of the untied vocabulary) as
+# `train --bf16 --remat <the solver header's flags>` builds it, for one
+# abstract v5e chip: the compiler's memory accounting that fixed the cell's
+# batch (benchmark/configs/trinity_mini.json,
+# benchmark/cells/trinity.e16of128.pack8k.json) at the depth in the files, at
+# the batch chosen and one sequence more.
+_TRINITY_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 2 + {deeper}, 8192, 0").replace(
+    "ouro_2_6b_solver", "trinity_mini_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers)')
+assert _TRINITY_STEP.count("trinity") == 1 and "ouro_2" not in _TRINITY_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("more", [0, 1])
+def test_trinity_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
+    """At two sequences of 8,192 the step with one checkpoint a layer is
+    under 85% of the 16.9 GB the compiler allows (PR 22's sizing rule); at
+    three it is over. The window layers' three flash kernels run on the
+    band's grid (21 live of 24 visited programs a head where the causal
+    grid visits 64), the global layer's on the causal one; k and v reach
+    the 32 query heads by a repeat of 8; the selection biases are leaves
+    like any other (weight and two moments in the arguments)."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _TRINITY_STEP.format(repo=REPO, deeper=more)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next cut
+    assert got["depth"] == 5 and got["parameters"] == 705_474_304
+    # embed, head, final norm; a layer: 4 norms, q k v g o, 2 qk gains; the
+    # dense layer's 3; a MoE layer's router 2, 3 stacks, shared 3
+    assert got["leaves"] == 3 + 5 * 11 + 3 + 4 * 8
+    assert got["segments"] == 5 + 1
+    tiles = "fwd 1024x1024 {0}, dq 1024x1024 {0}, dkv 1024x1024 {0}; " \
+        "block_q x block_k, live/visited programs a head"
+    assert got["routes"] == [
+        "attention=pallas_flash (" + tiles.format("21/24")
+        + "; window 2048: the band's grid); 4 kv heads repeated x8",
+        "attention=pallas_flash (" + tiles.format("36/64")
+        + "); 4 kv heads repeated x8; no positions",
+        "grouped_matmul=ragged_dot"]
+    # 4 flash calls a layer; 15 grouped matmuls (3 forward, 3 replayed, 9
+    # backward) a MoE layer
+    assert got["pallas_custom_calls"] == 4 * 5 + 15 * 4
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    if more:
+        assert got["total_gb"] > 0.85 * 16.9           # 16.27 (PR 36)
+    else:
+        assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9   # 14.19 (PR 36)
+
+
 # The LRN kernels at the CNN cells' norm layers (AlexNet's two at batch 512,
 # GoogLeNet's two at 128: batch-minor) and at GoogLeNet's published batch 32
 # (channel-minor), forward and backward, through Mosaic; then a stand-in for

@@ -1,0 +1,81 @@
+"""The three token configurations that share the flash kernels, the grouped
+matmuls and the routers with Trinity-Mini (OLMoE, Ouro, ZAYA1) trace to the
+program they traced to before its window, sigmoid router and per-head norm
+arrived: the gradient's whole jaxpr at a small size, as lowered for the TPU
+(``POSEIDON_FORCE_PALLAS=1``: the Pallas arm, its kernel bodies, grids and
+index maps are in the text), hashed. The hashes were taken on the parent of
+PR 36 and are equal on it and on the change; at the cells' own sizes the
+lowered StableHLO of the three steps was compared on both trees in the
+sandbox (abstract v5e): equal outside the Mosaic payloads, and the payloads
+equal once their debug locations (pallas_kernels.py's line numbers) are
+stripped (CHANGES.md, PR 36).
+
+A PR that means to change what these configurations trace to (PR 32 did,
+and cost ``ouro.loop4.pack8k`` 9 s of set-up) updates the hashes and says
+so; one that does not has tripped over a shared path."""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from poseidon_tpu.core.net import Net
+from poseidon_tpu.models import zoo
+from poseidon_tpu.proto.messages import load_net_from_string
+
+N, S = 1, 2048      # 2 x 2 tiles of 1024: the causal clamp is in the maps
+BUILD = {
+    "olmoe": lambda: zoo.olmoe(
+        batch=N, n_layers=1, hidden=128, heads=1, experts=8, top_k=2,
+        expert_width=32, vocab=128),
+    "ouro": lambda: zoo.ouro(
+        batch=N, n_layers=1, passes=2, hidden=128, heads=1, ffn_width=64,
+        vocab=128),
+    "zaya": lambda: zoo.zaya1(
+        batch=N, n_layers=2, hidden=64, heads=2, kv_heads=1, head_dim=128,
+        experts=8, held=4, expert_width=32, router_hidden=16, vocab=128),
+}
+PARENT = {       # sha256 of the text, its length, its pallas_call equations
+    "olmoe": ("25c3770f48fbe6e7315504d351947292a5dcecef1a20bef3b4b89015be04"
+              "e49b", 77656, 3),
+    "ouro": ("2f7e8cf85387640af63eaea1da12d5efddf2e2e06aa209a410475edf3e73"
+             "1d48", 126180, 6),
+    "zaya": ("69ea43acba1fee552fb6444330b4ca8d5085dc25495f70bc87860f9ec904"
+             "582d", 181755, 6),
+}
+
+
+def traced(name: str) -> str:
+    net = Net(load_net_from_string(zoo.to_prototxt(BUILD[name]())), "TRAIN",
+              source_shapes={"tokens": (N, S), "targets": (N, S)})
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    batch = {k: jax.ShapeDtypeStruct((N, S), jnp.int32)
+             for k in ("tokens", "targets")}
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p, b: net.apply(p, b, train=True).loss))(params, batch))
+    return re.sub(r"0x[0-9a-f]+", "0x", text)      # function addresses
+
+
+@pytest.mark.parametrize("name", sorted(BUILD))
+def test_token_configuration_traces_to_the_parent_s_program(name,
+                                                            monkeypatch):
+    monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+    text = traced(name)
+    sha, chars, calls = PARENT[name]
+    assert "flash_fwd" in text and "flash_bwd_dkv" in text
+    assert (text.count("pallas_call["), len(text)) == (calls, chars)
+    assert hashlib.sha256(text.encode()).hexdigest() == sha
+
+
+def test_a_window_does_change_the_text(monkeypatch):
+    """The hash sees what it should: the same OLMoE net with a window on
+    its ATTENTION layer traces to another program."""
+    monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+    net = BUILD["olmoe"]()
+    for layer in net.layers:
+        if layer.type == "ATTENTION":
+            layer.attention_param.window = 512
+    monkeypatch.setitem(BUILD, "olmoe_window", lambda: net)
+    assert traced("olmoe_window") != traced("olmoe")

@@ -265,7 +265,7 @@ def test_grouped_query_flash_matches_dense(what):
     co = jax.random.normal(jax.random.fold_in(key, 3), (b, s, h * d))
 
     def through(flash):
-        def att(q_, k_, v_, causal, scale=None):
+        def att(q_, k_, v_, causal, scale=None, window=None):
             if flash:
                 return pk.flash_attention(q_, k_, v_, causal, scale, 64, 64,
                                           True)
