@@ -6,8 +6,10 @@ Drives the system's main path once, through the entry point a user calls
 bvlc AlexNet: batch 256 x 3 x 227 x 227 per chip, 1000 classes, the LMDB
 pipeline, device prefetch and the in-flight window live. Weights and data
 are random, made from a seed; only the solver's max_iter / display /
-test_iter / test_interval / snapshot are cut. It claims no speed — the
-times it prints are facts about this run on the device it names.
+test_iter / test_interval / snapshot are cut. Beside it, the routed
+kernels against their XLA arms and one held MOE layer at Trinity-Mini's
+shape through both rungs of ``expert_ffn``'s ladder. It claims no speed —
+the times it prints are facts about this run on the device it names.
 
     python chip_smoke.py             # on a TPU host (1 or 4 chips)
     python chip_smoke.py --cpu-tiny  # TEST ONLY: the same flow, cut to CPU
@@ -75,6 +77,10 @@ class Size:
                      [("pool1", (256, 96, 55)), ("pool2", (256, 256, 27)),
                       ("pool5", (256, 256, 13)),
                       ("pool1 channel-minor", (4, 96, 55))])
+        # one held MOE layer (tokens, top-k, experts routed / held, hidden,
+        # expert width): Trinity-Mini's, one of eight chips a layer
+        self.moe = ((64, 8, 16, 2, 32, 16) if tiny
+                    else (16384, 8, 128, 16, 2048, 1024))
 
 
 # --------------------------------------------------------------------------- #
@@ -377,6 +383,90 @@ def check_kernels(size: Size) -> dict:
     return facts
 
 
+def check_held_ladder(size: Size) -> dict:
+    """One held MOE layer at Trinity-Mini's shape through ``expert_ffn``'s
+    ladder against the full rung alone, forward and every gradient, under
+    the bf16 policy on this device: a routing at the even share (the prefix
+    rung) and one forced one row over the prefix (the overflow path, which
+    runs the full rung's own equations). ``ragged_dot`` with rows past the
+    last group, the P-row scatter-add and the conditional are all lowered
+    for the chip here and nowhere on the CPU."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from poseidon_tpu.config import policy_scope
+    from poseidon_tpu.models import moe
+
+    t, top_k, n_exp, n_held, d, f = size.moe
+    rows = t * top_k
+    prefix, full = moe.held_row_ladder(rows, n_held, n_exp)
+    check(full == rows and prefix < rows, f"no ladder at {size.moe}")
+    rs = np.random.RandomState(SEED)
+    f32 = jnp.float32
+    x = jnp.asarray(rs.randn(t, d), jnp.bfloat16)
+    weights = jnp.asarray(rs.rand(t, top_k) + 0.1, f32)
+    gate, up = (jnp.asarray(rs.randn(n_held, f, d) * d ** -0.5, f32)
+                for _ in range(2))
+    down = jnp.asarray(rs.randn(n_held, d, f) * f ** -0.5, f32)
+    cot = jnp.asarray(rs.randn(t, d), f32)
+
+    def routing(live):
+        experts = rs.randint(n_held, n_exp, size=rows)
+        experts[rs.permutation(rows)[:live]] = rs.randint(0, n_held, live)
+        return moe.expert_sizes(jnp.asarray(experts.reshape(t, top_k)),
+                                n_exp)
+
+    def ladder(x, weights, gate, up, down, flat_e, sizes):
+        return moe.expert_ffn(x, weights, flat_e, sizes, gate, up, down)
+
+    def alone(x, weights, gate, up, down, flat_e, sizes):
+        here = flat_e < n_held
+        order = jnp.argsort(jnp.where(here, flat_e, n_held), stable=True)
+        return moe._held_rows(x, weights, here, order, sizes[:n_held], gate,
+                              up, down, rows)
+
+    def stepped(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (lambda y: (jnp.sum(y.astype(f32) * cot), y))(fn(*a)),
+            argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    def ms(fn, *args, calls=5):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        jax.block_until_ready([fn(*args) for _ in range(calls)])
+        return (time.perf_counter() - t0) / calls * 1e3
+
+    facts = {"rows": rows, "prefix": prefix}
+    with policy_scope(compute_dtype=jnp.bfloat16):
+        both = {"ladder": stepped(ladder), "full rung alone": stepped(alone)}
+        text = both["ladder"].lower(x, weights, gate, up, down,
+                                    *routing(1)).as_text()
+        check("stablehlo.case" in text or "stablehlo.if" in text,
+              "the ladder traced no conditional")
+        for case, live in (("even share", rows * n_held // n_exp),
+                           ("one row over", prefix + 1)):
+            args = (x, weights, gate, up, down) + routing(live)
+            got = {k: fn(*args) for k, fn in both.items()}
+            (_, y), grads = got["ladder"]
+            (_, y0), grads0 = got["full rung alone"]
+            for name, a, b in zip(("y", "dx", "dweights", "dgate", "dup",
+                                   "ddown"), (y,) + grads, (y0,) + grads0):
+                a, b = (np.asarray(v, np.float32) for v in (a, b))
+                rel = float(np.linalg.norm(a - b)
+                            / max(np.linalg.norm(b), 1e-30))
+                facts[f"{case}: {name} largest difference"] = float(
+                    np.max(np.abs(a - b)))
+                facts[f"{case}: {name} relative l2"] = rel
+                check(np.any(b) and rel <= 4e-3,
+                      f"held ladder, {case}: {name} differs from the full "
+                      f"rung by {rel} (relative L2)")
+            for k, fn in both.items():
+                facts[f"{case}: {k} ms"] = ms(fn, *args)
+    return facts
+
+
 def child_cold(size: Size) -> dict:
     from poseidon_tpu.runtime.compile_cache import (aot_entries,
                                                     cache_entries,
@@ -443,6 +533,9 @@ def child_cold(size: Size) -> dict:
 
     # 5. the kernels, one by one, against their XLA arms
     phases["kernels"] = check_kernels(size)
+
+    # 6. a held MOE layer through both rungs of its ladder
+    phases["held_ladder"] = check_held_ladder(size)
     result["resume_from"] = snap
     result["net"] = net
     result["xla_entries_at_end"] = cache_entries(cache)

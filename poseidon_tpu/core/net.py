@@ -426,6 +426,13 @@ class Net:
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"
                 arm, note = GROUPED_MATMUL, "sorted by expert, dropless"
+                rungs = self._held_rungs(layer)
+                if len(rungs) > 1:
+                    # a share of the experts held: the row work runs over
+                    # a prefix of the sorted assignments while it holds
+                    # every live row (stats.yaml: prefix_hit_share)
+                    arm += (f"; held rows: prefix {rungs[0]} of "
+                            f"{rungs[-1]}, full on overflow")
             else:
                 continue
             if arm == "pallas" and note:
@@ -442,6 +449,25 @@ class Net:
                          "num_held": l.held,
                          "router_num_experts": l.lp.moe_param.num_experts}
                 for l in self.layers if l.TYPE == "MOE"}
+
+    def _held_rungs(self, layer: Layer) -> Tuple[int, ...]:
+        """The prefix lengths a MOE layer's held arm may run its row work
+        over (``models/moe.held_row_ladder``); one = no ladder."""
+        from ..models.moe import held_row_ladder
+        n, s, _ = self.blob_shapes[layer.lp.bottom[0]]
+        mp = layer.lp.moe_param
+        return held_row_ladder(n * s * mp.top_k, layer.held, mp.num_experts)
+
+    def held_row_ladders(self) -> Dict[str, Tuple[int, int]]:
+        """{a MOE layer's held-share top: (prefix rows, all T k rows)} for
+        the layers whose held arm runs a ladder and that publish their held
+        share: what a display's reader needs to say which rung a step
+        took."""
+        ladders = {l.lp.top[l.n_fixed + 2]: self._held_rungs(l)
+                   for l in self.layers
+                   if l.TYPE == "MOE" and len(l.lp.top) >= l.n_fixed + 3}
+        return {top: rungs for top, rungs in ladders.items()
+                if len(rungs) > 1}
 
     def conv_strategy_plan(self) -> Dict[str, Optional[str]]:
         """{conv layer name: resolved strategy} — what bench/tests print."""

@@ -25,6 +25,7 @@ reduction is a plain psum (exact, order-independent)."""
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -237,6 +238,84 @@ def expert_sizes(experts: jax.Array, n_exp: int):
     return flat_e, jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1)
 
 
+# Rows of the sorted assignments are handed to the grouped matmuls in
+# multiples of this many: the MXU's 128 rows, what the prefix rung of the
+# held arm is rounded up to.
+_ROW_TILE = 128
+
+
+def held_row_ladder(rows: int, n_held: int, n_exp: int):
+    """The static prefix lengths ("rungs") over which ``expert_ffn``'s held
+    arm may run its row work, shortest first; the last is always all
+    ``rows`` = T k sorted assignments. A rule of the shapes and nothing
+    else: the first rung is TWICE the even share of a rank that holds
+    ``n_held`` of ``n_exp`` experts, rounded up to ``_ROW_TILE``; where
+    that is all the rows or more (half the experts held: ZAYA1) the ladder
+    is the single full rung and no conditional is traced. (A middle rung at
+    four times the even share was tried for Trinity-Mini, whose first MoE
+    layer holds 35-40% of the assignments: the step then compiles at 15.23
+    GB, over the 85% it is sized by. PERF.md, PR 37.)"""
+    first = -(-2 * rows * n_held // n_exp // _ROW_TILE) * _ROW_TILE
+    return (first, rows) if first < rows else (rows,)
+
+
+def _combine(out, order, weights, dtype):
+    """All T k sorted rows ``out`` (T*k, D) back to their tokens by the
+    inverse permutation, a token's k summed with its ``weights`` (T, k) in
+    f32 and handed back as ``dtype``."""
+    t, top_k = weights.shape
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * top_k))
+    y = jnp.sum(out[back].reshape(t, top_k, -1).astype(jnp.float32)
+                * weights[..., None], axis=1)
+    return y.astype(dtype)
+
+
+def _held_rows(x, weights, here, order, sizes, gate, up, down, rows):
+    """The held arm's row work over the first ``rows`` of the sorted
+    assignments (``order``: the held experts' rows first, by expert; absent
+    ones behind them), as an f32-summed (T, D) in x's dtype. ``sizes`` are
+    the held experts' (G,), ``here`` (T*k,) says which assignments fell on
+    one. Exact whenever the live rows, ``sum(sizes)``, number at most
+    ``rows``.
+
+    ``rows`` = T k is the whole sort: the inverse permutation brings every
+    assignment's result back to its token and the k are summed over an
+    axis. A shorter prefix touches nothing of T k rows times a feature
+    width: P rows are gathered, multiplied, and scatter-added, weighted,
+    into the tokens' f32 sums (the same k terms in another order)."""
+    t, d = x.shape
+    top_k = weights.shape[1]
+    # rows past the last group belong to no expert: what a grouped
+    # matmul leaves there is masked on the way in (so is their
+    # cotangent) and on the way out
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+
+    def grouped(rows_, w):
+        return jnp.where(live, _grouped(jnp.where(live, rows_, 0), w,
+                                        sizes), 0)
+
+    full = rows == t * top_k
+    head = order if full else order[:rows]
+    tok = head // top_k
+    xs = x[tok]                                     # (rows, D)
+    h = jax.nn.silu(grouped(xs, gate)) * grouped(xs, up)
+    out = grouped(h, down)                          # (rows, D)
+    weights = weights * here.reshape(t, top_k)
+    if full:
+        return _combine(out, order, weights, x.dtype)
+    y = jnp.zeros((t, d), jnp.float32).at[tok].add(
+        out.astype(jnp.float32) * weights.reshape(-1)[head][:, None])
+    return y.astype(x.dtype)
+
+
+# A rung's body as the ladder calls it: a function of its own in the
+# program, so that the layers of one shape, the forward's and the backward's
+# conditionals share ONE trace and one lowering of each rung (on the chip's
+# host the ladder lowers in +0.9 s over the parent's arm this way, +2.9 s
+# traced in place; the compiler inlines the calls: PERF.md, PR 37)
+_held_rows_jit = jax.jit(_held_rows, static_argnums=8)
+
+
 def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
                sizes: jax.Array, gate: jax.Array, up: jax.Array,
                down: jax.Array, held_first: int = 0):
@@ -253,7 +332,18 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
     to. G < E is one rank's share of an expert-parallel layer: assignments
     to an absent expert sort behind the held ones, lie outside every group
     of the grouped matmuls (no work) and add ZERO to y, so that the shares
-    of the ranks sum to the whole layer's output."""
+    of the ranks sum to the whole layer's output.
+
+    The held arm's row work (gather, masks, activation, combine) runs over
+    a PREFIX of the sorted rows chosen at run time by the live count:
+    ``held_row_ladder`` gives the static lengths from T k, G and E alone
+    (twice the even share, then everything), a ``lax.cond`` takes the
+    shortest rung that holds every live row, and the full rung is the
+    overflow path that keeps the layer dropless for any routing. With one
+    rung (G / E >= 1/2) no conditional is traced. The ladder's derivative
+    is written out (``_held_ladder``, a ``custom_vjp``): the forward saves
+    its inputs and the backward differentiates the rung taken alone, since
+    autodiff through the conditional keeps the residuals of both rungs."""
     t, d = x.shape
     top_k = weights.shape[1]
     n_exp, n_held = sizes.shape[0], gate.shape[0]
@@ -261,29 +351,61 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
         order = jnp.argsort(flat_e, stable=True)    # assignments by expert
         xs = x[order // top_k]                      # (T*k, D) sorted rows
         h = jax.nn.silu(_grouped(xs, gate, sizes)) * _grouped(xs, up, sizes)
-        out = _grouped(h, down, sizes)              # (T*k, D)
-    else:
-        local = flat_e - held_first
-        here = (local >= 0) & (local < n_held)
-        order = jnp.argsort(jnp.where(here, local, n_held), stable=True)
-        sizes = sizes[held_first:held_first + n_held]
-        # rows past the last group belong to no expert: what a grouped
-        # matmul leaves there is masked on the way in (so is their
-        # cotangent) and on the way out
-        live = (jnp.arange(t * top_k) < jnp.sum(sizes))[:, None]
+        return _combine(_grouped(h, down, sizes), order, weights, x.dtype)
+    local = flat_e - held_first
+    here = (local >= 0) & (local < n_held)
+    order = jnp.argsort(jnp.where(here, local, n_held), stable=True)
+    sizes = sizes[held_first:held_first + n_held]
+    rungs = held_row_ladder(t * top_k, n_held, n_exp)
+    if len(rungs) == 1:
+        return _held_rows(x, weights, here, order, sizes, gate, up, down,
+                          rungs[0])
+    return _held_ladder(rungs, x, weights, gate, up, down, here, order,
+                        sizes)
 
-        def grouped(rows, w):
-            return jnp.where(live, _grouped(jnp.where(live, rows, 0), w,
-                                            sizes), 0)
 
-        xs = x[order // top_k]
-        h = jax.nn.silu(grouped(xs, gate)) * grouped(xs, up)
-        out = grouped(h, down)
-        weights = weights * here.reshape(t, top_k)
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * top_k))
-    out = out[back].reshape(t, top_k, d)
-    y = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1)
-    return y.astype(x.dtype)
+def _take_rung(rungs, sizes, branch, *operands):
+    """``branch(rows)(*operands)`` for the shortest rung that holds every
+    live row."""
+    prefix, full = rungs
+    return lax.cond(jnp.sum(sizes) <= prefix, branch(prefix), branch(full),
+                    *operands)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_ladder(rungs, x, weights, gate, up, down, here, order, sizes):
+    """``_held_rows`` over the rung the live count picks. Its derivative is
+    written out for the memory's sake: autodiff through the conditional
+    keeps BOTH rungs' residuals (zeros for the one not taken), and
+    Trinity-Mini's step at the cell's batch then no longer fits the chip
+    (PERF.md, PR 37). The forward saves what is live anyway; the backward
+    takes the same rung and differentiates it alone, its forward once more
+    inside the branch (prefix-sized where the prefix rung was taken)."""
+    return _take_rung(rungs, sizes,
+                      lambda rows: lambda *a: _held_rows_jit(*a, rows),
+                      x, weights, here, order, sizes, gate, up, down)
+
+
+def _held_ladder_fwd(rungs, x, weights, gate, up, down, here, order, sizes):
+    return (_held_ladder(rungs, x, weights, gate, up, down, here, order,
+                         sizes),
+            (x, weights, gate, up, down, here, order, sizes))
+
+
+def _held_ladder_bwd(rungs, res, dy):
+    *primals, here, order, sizes = res
+
+    def branch(rows):
+        def bwd(dy, *primals):
+            return jax.vjp(lambda x, weights, gate, up, down: _held_rows_jit(
+                x, weights, here, order, sizes, gate, up, down, rows),
+                *primals)[1](dy)
+        return bwd
+
+    return _take_rung(rungs, sizes, branch, dy, *primals) + (None,) * 3
+
+
+_held_ladder.defvjp(_held_ladder_fwd, _held_ladder_bwd)
 
 
 def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,

@@ -495,6 +495,9 @@ class Engine:
         expert_share = self.train_net.expert_share()
         if expert_share:
             self.stats.set_section("expert_share", expert_share)
+        # which rung of its ladder a held MOE layer's step took is read off
+        # the held share it displays (_absorb): {top: (prefix, rows)}
+        self._held_ladders = self.train_net.held_row_ladders()
         self.stats.set_section("data_reader", {
             p.tops[0]: ("native" if getattr(p, "native", None) is not None
                         else "python")
@@ -1161,6 +1164,13 @@ class Engine:
         for row_it, row in rows:
             self.metrics.accumulate(row)
             last = row
+            for top, (prefix, total) in self._held_ladders.items():
+                if top in row:
+                    # the live rows are the share times T k, exactly: the
+                    # prefix rung ran iff they fit it (models/moe)
+                    self.stats.add("held_layer_steps")
+                    self.stats.add("held_prefix_hits",
+                                   round(row[top] * total) <= prefix)
             if self._displays and self._displays[0][0] == row_it + 1:
                 self._display(*self._displays.popleft())
         return last
@@ -1187,6 +1197,13 @@ class Engine:
         for k, v in row.items():
             if k not in ("iter", "time"):
                 self.stats.set_gauge(f"train_{k}", round(v, 6))
+        if self._held_ladders:
+            # layer-steps that ran the prefix rung over layer-steps
+            # displayed so far (the counters beside it hold both counts)
+            done = self.stats.counters
+            self.stats.set_gauge("prefix_hit_share", round(
+                done["held_prefix_hits"]
+                / max(done["held_layer_steps"], 1.0), 6))
         with span_recorder.span("telemetry_dump", "artifact", {"iter": it}):
             self._dump_live_telemetry()
         if self._async_tier is not None:

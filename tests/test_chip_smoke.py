@@ -50,14 +50,18 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
         result = json.load(f)
     cold, warm = result["cold"], result["warm"]
     assert cold["cache_dir"] == str(cache)
-    assert sorted(cold["phases"]) == ["kernels", "resume", "train_bf16",
-                                      "train_f32", "train_sfb_auto"]
+    assert sorted(cold["phases"]) == ["held_ladder", "kernels", "resume",
+                                      "train_bf16", "train_f32",
+                                      "train_sfb_auto"]
     assert cold["phases"]["train_bf16"]["steps"] == 24
     assert cold["phases"]["resume"]["compiled_step"]["source"] == "loaded"
     assert warm["phases"]["warm_resume"]["xla_entries_added"] == 0
     assert warm["phases"]["warm_resume"]["compiled_step"]["source"] == \
         "loaded"
     assert len(cold["phases"]["kernels"]) == 11   # 35 at full size
+    ladder = cold["phases"]["held_ladder"]         # both rungs, both ways
+    assert (ladder["rows"], ladder["prefix"]) == (512, 128)
+    assert sum("relative l2" in k for k in ladder) == 12
     assert sorted(os.listdir(cache / "aot"))   # the step store rode along
 
 

@@ -79,3 +79,40 @@ def test_a_window_does_change_the_text(monkeypatch):
             layer.attention_param.window = 512
     monkeypatch.setitem(BUILD, "olmoe_window", lambda: net)
     assert traced("olmoe_window") != traced("olmoe")
+
+
+def _conditionals(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield len(eqn.params["branches"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _conditionals(sub)
+
+
+@pytest.mark.parametrize("held, per_layer", [(2, 1), (8, 0)])
+def test_a_held_share_under_half_traces_one_conditional_a_moe_layer(
+        held, per_layer):
+    """A small Trinity-Mini net (1 dense + 2 MoE layers, top-4 of 16): with
+    an eighth of the experts held each MOE layer's forward holds ONE
+    two-branch conditional (``expert_ffn``'s ladder: the prefix rung, the
+    full rung) and the gradient one more (the backward takes the same rung);
+    with half held the ladder is a single rung and no conditional is traced,
+    which is why ZAYA1's hash above stands."""
+    n, s = 1, 128
+    net = Net(load_net_from_string(zoo.to_prototxt(zoo.trinity_mini(
+        batch=n, n_layers=3, dense_layers=1, hidden=64, heads=4, kv_heads=2,
+        head_dim=16, window=32, first_global=2, dense_width=96, experts=16,
+        top_k=4, held=held, expert_width=32, shared_width=32, vocab=128))),
+        "TRAIN", source_shapes={"tokens": (n, s), "targets": (n, s)})
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    batch = {k: jax.ShapeDtypeStruct((n, s), jnp.int32)
+             for k in ("tokens", "targets")}
+
+    def loss(p, b):
+        return net.apply(p, b, train=True).loss
+
+    forward = list(_conditionals(jax.make_jaxpr(loss)(params, batch).jaxpr))
+    assert forward == [2] * (2 * per_layer)
+    both = list(_conditionals(
+        jax.make_jaxpr(jax.grad(loss))(params, batch).jaxpr))
+    assert both == [2] * (4 * per_layer)

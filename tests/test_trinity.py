@@ -427,7 +427,7 @@ def test_layers_refuse_what_they_cannot_mean():
 LR, WD, CLIP = 4e-3, 0.1, 0.05
 
 
-def _job(tmp_path, max_iter, snapshot=0):
+def _job(tmp_path, max_iter, snapshot=0, held=HELD, n=N, **sizes):
     import h5py
     from poseidon_tpu.proto.messages import load_solver
     rs = np.random.RandomState(7)
@@ -437,7 +437,8 @@ def _job(tmp_path, max_iter, snapshot=0):
         h["label"] = np.tile(stream[1:], (8, 1))
     (tmp_path / "tokens.txt").write_text(str(tmp_path / "tokens.h5") + "\n")
     (tmp_path / "net.prototxt").write_text(zoo.to_prototxt(zoo.trinity_mini(
-        batch=N, source=str(tmp_path / "tokens.txt"), held=HELD, **SIZES)))
+        batch=n, source=str(tmp_path / "tokens.txt"), held=held,
+        **{**SIZES, **sizes})))
     (tmp_path / "solver.prototxt").write_text(
         f'net: "{tmp_path / "net.prototxt"}"\nsolver_type: ADAM\n'
         f'base_lr: {LR}\nlr_policy: "fixed"\nmomentum: 0.9\n'
@@ -445,9 +446,66 @@ def _job(tmp_path, max_iter, snapshot=0):
         f'clip_gradients: {CLIP}\nmax_iter: {max_iter}\ndisplay: 1\n'
         f'snapshot: {snapshot}\nsnapshot_after_train: false\n'
         f'snapshot_prefix: "snap/trinity"\nrandom_seed: 3\n')
-    batch = {"tokens": jnp.tile(stream[:-1], (N, 1)),
-             "targets": jnp.tile(stream[1:], (N, 1))}
+    batch = {"tokens": jnp.tile(stream[:-1], (n, 1)),
+             "targets": jnp.tile(stream[1:], (n, 1))}
     return load_solver(str(tmp_path / "solver.prototxt")), batch
+
+
+def test_held_row_ladder_is_named_and_counted(tmp_path):
+    """2 of 16 experts held, top-2 of 256 tokens: the MOE layers'
+    ``kernel_routes`` note names the ladder (prefix = twice the even share,
+    full on overflow), and ``stats.yaml`` holds ``prefix_hit_share`` =
+    layer-steps that ran the prefix rung over layer-steps displayed, from a
+    routing on each side of the rung, forced through the routers' biases:
+    a step with no assignment on a held expert (the prefix rung), one with
+    every assignment on one (the full rung, nothing dropped), and back.
+    Half the experts held: one rung, no note, no counter."""
+    from poseidon_tpu.runtime.engine import Engine
+    from poseidon_tpu.runtime.metrics import read_stats_yaml
+    assert "held rows" not in build().kernel_routes["l1_moe"]
+    assert build().held_row_ladders() == {}
+    n, k = 4, 2
+    sp, _ = _job(tmp_path, max_iter=3, held=2, n=n, top_k=k)
+    prefix, rows = 128, n * S * k
+
+    def stats():
+        doc = read_stats_yaml(str(tmp_path / "out" / "stats.yaml"))
+        return [float(doc["gauges"]["prefix_hit_share"])] + [
+            float(doc["counters"][c]) for c in
+            ("held_layer_steps", "held_prefix_hits")]
+
+    eng = Engine(sp, output_dir=str(tmp_path / "out"), mesh=make_mesh(1))
+
+    def bias(*offsets):          # onto the routers' biases at experts 0, 1
+        params = dict(eng.params)
+        for i in MOE_LAYERS:
+            router = params[f"l{i}_router"]
+            params[f"l{i}_router"] = dict(router, bias=router["bias"].at[
+                :2].set(jnp.asarray(offsets, jnp.float32)))
+        eng.params = params
+
+    try:
+        routes = eng.stats.snapshot()["sections"]["kernel_routes"]
+        for i in MOE_LAYERS:
+            assert routes[f"l{i}_moe"] == (
+                f"grouped_matmul=ragged_dot; held rows: prefix {prefix} of "
+                f"{rows}, full on overflow")
+        assert eng.train_net.held_row_ladders() == {
+            f"l{i}_held_share": (prefix, rows) for i in MOE_LAYERS}
+        for offsets, share, want in (
+                ((-10.0, -10.0), 0.0, [1.0, 4.0, 4.0]),
+                ((10.0, 10.0), 1.0, [0.5, 8.0, 4.0]),
+                ((-10.0, -10.0), 0.0, [round(2 / 3, 6), 12.0, 8.0])):
+            bias(*offsets)
+            eng.train(max_iter=eng.iteration() + 1)
+            row = eng.metrics.rows[-1]
+            for i in MOE_LAYERS:
+                assert row[f"l{i}_held_share"] == share
+                assert row[f"l{i}_dropped"] == 0.0
+            assert np.isfinite(row["loss"])
+            assert stats() == want
+    finally:
+        eng.close()
 
 
 def test_selection_bias_follows_the_sign_rule_step_for_step(tmp_path):
