@@ -12,10 +12,11 @@ What differs is the model's: the keys read from its config.json and the
 layers the configuration runs (``layers_run``); top-8 routing, so the
 program's choice handed to the reference is eight experts a token and a
 MoE layer's counts are assignments; the routers' largest selection bias per
-display beside the routing; and the window / global split of what the flash
-kernels require. ``correct`` is decided as ``zaya_train`` decides it, by
-``step_check`` on the TIMED path and ``reference_check`` on the trained
-weights, against ``reference/trinity.py``.
+display beside the routing, every MoE layer's own held share per display and
+which rung of ``expert_ffn``'s ladder the window's layer-steps took; and the
+window / global split of what the flash kernels require. ``correct`` is
+decided as ``zaya_train`` decides it, by ``step_check`` on the TIMED path and
+``reference_check`` on the trained weights, against ``reference/trinity.py``.
 
 The per-layer readers get the keys ``lm_train`` hands them, ONE SEQUENCE as
 the sample; ``lm`` holds what this cell's readers add (``trinity``: the
@@ -369,6 +370,7 @@ def run(job: dict) -> dict:
 
         # ---- the measured window: opens and closes on a hard sync ------- #
         rows_before = len(eng.metrics.rows)
+        counted_before = eng.stats.snapshot()["counters"]
         with CompileCounter() as compiles:
             t0 = clock()
             window = feed.steps(n_steps)
@@ -410,7 +412,17 @@ def run(job: dict) -> dict:
             [v for k, v in r.items() if k.endswith(suffix)]
             for r in some_rows) if vals]
 
+    def per_layer(some_rows, suffix):
+        """{a layer's top: its value in every display that has it}"""
+        tops = sorted({k for r in some_rows for k in r if k.endswith(suffix)})
+        return {top: [r[top] for r in some_rows if top in r] for top in tops}
+
     held_share = per_display(rows, "_held_share")
+    held_by_layer = per_layer(rows, "_held_share")
+    # which rung each of the WINDOW's MoE layer-steps took: the Engine counts
+    # them step by step (cumulative; differenced over the window here)
+    held_prefix = {k: after["counters"].get(k, 0) - counted_before.get(k, 0)
+                   for k in ("held_prefix_hits", "held_layer_steps")}
     load = per_display(rows, "_expert_load")
     bias_max = [max(vals) for vals in (
         [v for k, v in r.items() if k.endswith("_bias_max_abs")]
@@ -466,7 +478,10 @@ def run(job: dict) -> dict:
                  "min": min(held_share, default=None),
                  "max": max(held_share, default=None),
                  "mean": sum(held_share) / max(1, len(held_share)),
-                 "per_display": held_share},
+                 "per_display": held_share,
+                 "warm_up_per_layer": per_layer(warm_rows, "_held_share"),
+                 "per_layer": held_by_layer,
+                 "window_prefix": held_prefix},
              "held_expert_load_max_over_mean": {
                  "warm_up": per_display(warm_rows, "_expert_load"),
                  "first_display": load[:1], "last_display": load[-1:],
@@ -516,6 +531,8 @@ def run(job: dict) -> dict:
                           "kernel_routes": sorted(set(sections.get(
                               "kernel_routes", {}).values())),
                           "held_share": held_share, "expert_load": load,
+                          "held_share_by_layer": held_by_layer,
+                          "held_prefix": held_prefix,
                           "dropped": dropped,
                           # the routing of the steps the profiler saw
                           "traced_held_share": per_display(

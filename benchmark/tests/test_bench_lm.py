@@ -237,8 +237,6 @@ def test_new_entries_follow_the_contract():
     config = next(c for c in BENCH["configs"] if c["name"] == "olmoe_1b_7b")
     assert config["reduced"] == CFG["reduced"] and config["source"] == \
         CFG["source"]
-    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
-    assert len(four) == 1 and len(BENCH["workloads"]) == 5
     for m in BENCH["per_layer"]:
         if m.get("workloads") == ["olmoe.l1.pack4k"]:
             assert m["moves"] == "mfu_required"

@@ -129,3 +129,45 @@ def test_every_declared_name_has_its_file():
         assert os.path.exists(os.path.join(BENCH_DIR, "layer_metrics",
                                            f"{m['name']}.py")), m["name"]
         assert m["moves"] in {e["name"] for e in BENCH["end_to_end"]}
+
+
+def test_entries_follow_the_contract():
+    """What the driver refuses before any run, for EVERY entry (the cells'
+    own test files check what each brought, never how many entries the
+    benchmark has or in which order: later PRs append)."""
+    cells = [w["name"] for w in BENCH["workloads"]]
+    configs = [c["name"] for c in BENCH["configs"]]
+    metrics = [m["name"] for k in ("end_to_end", "per_layer")
+               for m in BENCH[k]]
+    for names in (cells, configs, metrics):
+        assert len(names) == len(set(names))
+    assert len({c["file"] for c in BENCH["configs"]}) == len(configs)
+    assert len({(w["config"], w["traffic"])
+                for w in BENCH["workloads"]}) == len(cells)
+    assert {w["config"] for w in BENCH["workloads"]} == set(configs)
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert all(w["chips"] in (1, 4) for w in BENCH["workloads"]) \
+        and len(four) <= max(1, len(cells) // 4)
+    for w in BENCH["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert c["file"].startswith(BENCH["paths"][0] + "/")
+    keys = {"name", "unit", "better", "source"}
+    for kind, more in (("end_to_end", {"bound"}),
+                       ("per_layer", {"layer", "moves"})):
+        for m in BENCH[kind]:
+            assert keys | more <= set(m) <= keys | more | {"workloads"}, m
+            assert m["better"] in ("lower", "higher")
+            assert set(m.get("workloads", ())) <= set(cells)
+            if kind == "per_layer":
+                # a metric's cells report the end-to-end metric it moves
+                assert all(m["moves"] in declared("end_to_end", cell)
+                           for cell in m.get("workloads", cells))
+    for text in [x[k] for kind in ("workloads", "configs", "per_layer")
+                 for x in BENCH[kind] for k in ("why", "layer", "source")
+                 if k in x and isinstance(x[k], str)]:
+        assert 1 <= len(text) <= 200 and text.isascii() \
+            and text.isprintable(), text
+    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
+    assert all(m["bound"] <= 0.1 for m in BENCH["end_to_end"])

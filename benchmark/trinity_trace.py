@@ -63,8 +63,14 @@ def attention_glue_ms_per_step(run: dict) -> Optional[float]:
         and not device_trace.is_pallas(label))
 
 
+def published(run: dict, key: str):
+    """What the runner read off the program's own counters and display rows
+    under ``key`` (``run["lm"]``), None in a run that is not this cell's."""
+    return (run.get("lm") or {}).get(key) if is_ours(run) else None
+
+
 def mean_of(run: dict, key: str) -> Optional[float]:
-    values = (run.get("lm") or {}).get(key) if is_ours(run) else None
+    values = published(run, key)
     return sum(values) / len(values) if values else None
 
 
