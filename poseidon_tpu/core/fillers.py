@@ -40,4 +40,13 @@ def fill(rng: jax.Array, pdef: ParamDef, dtype=jnp.float32) -> jax.Array:
     if t == "xavier":
         scale = (3.0 / pdef.fan_in) ** 0.5
         return jax.random.uniform(rng, shape, dtype, minval=-scale, maxval=scale)
+    if t == "log_of_uniform":
+        # the log of a uniform draw in [min, max] (a decay rate's A_log)
+        return jnp.log(jax.random.uniform(rng, shape, dtype, minval=f.min,
+                                          maxval=f.max))
+    if t == "inv_softplus_log_uniform":
+        # x with softplus(x) log-uniform in [min, max] (a step size's bias)
+        step = jnp.exp(jax.random.uniform(
+            rng, shape, dtype, minval=jnp.log(f.min), maxval=jnp.log(f.max)))
+        return step + jnp.log(-jnp.expm1(-step))
     raise ValueError(f"unknown filler type {t!r}")

@@ -314,28 +314,46 @@ class AttentionLayer(Layer):
     ``window``, over the last that many (t - window < s <= t), heads
     merged. Any whole number of query heads may share a key-value head (8
     do in a 32 / 4 layer). It normalises nothing: a QK-norm is the layers
-    before it."""
+    before it.
+
+    Latent attention's two widths: with ``value_head_dim`` v's heads are
+    that wide and the top is (N, S, num_heads * value_head_dim); a FOURTH
+    bottom (N, S, Ds) is one key part every head shares: each key head is
+    its own Dh - Ds dims of k followed by it (k is then (N, S,
+    num_kv_heads * (Dh - Ds)))."""
     TYPE = "ATTENTION"
 
     def setup(self, bottom_shapes):
         ap = self.lp.attention_param
-        if len(bottom_shapes) != 3 or any(len(b) != 3 for b in bottom_shapes) \
-                or bottom_shapes[1] != bottom_shapes[2] \
-                or bottom_shapes[0][:2] != bottom_shapes[1][:2]:
+        shared = bottom_shapes[3][-1] if len(bottom_shapes) == 4 else 0
+        if len(bottom_shapes) not in (3, 4) \
+                or any(len(b) != 3 for b in bottom_shapes) \
+                or any(b[:2] != bottom_shapes[0][:2] for b in bottom_shapes) \
+                or (not shared and not ap.value_head_dim
+                    and bottom_shapes[1] != bottom_shapes[2]):
             raise ValueError(f"{self.name}: ATTENTION takes q (N, S, D) and "
-                             f"k, v of one (N, S, Dkv) shape, got "
-                             f"{bottom_shapes}")
-        d, d_kv = bottom_shapes[0][-1], bottom_shapes[1][-1]
+                             f"k, v of one (N, S, Dkv) shape (v of its own "
+                             f"with value_head_dim; a fourth bottom "
+                             f"(N, S, Ds) is a key part all heads share), "
+                             f"got {bottom_shapes}")
+        d, d_k, d_v = (b[-1] for b in bottom_shapes[:3])
         if ap.num_heads <= 0 or d % ap.num_heads or (d // ap.num_heads) % 2:
             raise ValueError(f"{self.name}: {ap.num_heads} heads do not "
                              f"split D={d} into even head sizes")
         d_head = d // ap.num_heads
         n_kv = ap.num_kv_heads or ap.num_heads
-        if ap.num_heads % n_kv or d_kv != n_kv * d_head:
+        self.v_head = ap.value_head_dim or d_head
+        if ap.num_heads % n_kv or d_k != n_kv * (d_head - shared) \
+                or d_v != n_kv * self.v_head or not 0 <= shared < d_head \
+                or ap.value_head_dim < 0:
+            latent = f" (of which {shared} shared) and values of " \
+                f"{self.v_head}" if shared or ap.value_head_dim else ""
             raise ValueError(f"{self.name}: {n_kv} key-value heads of "
-                             f"{d_head} need k, v of width {n_kv * d_head} "
-                             f"and a whole number of query heads each; got "
-                             f"{d_kv} and {ap.num_heads} query heads")
+                             f"{d_head}{latent} need k, v of width "
+                             f"{n_kv * (d_head - shared)}, "
+                             f"{n_kv * self.v_head} and a whole number of "
+                             f"query heads each; got {d_k}, {d_v} and "
+                             f"{ap.num_heads} query heads")
         if ap.rotary_dims % 2 or not 0 <= ap.rotary_dims <= d_head:
             raise ValueError(f"{self.name}: rotary_dims {ap.rotary_dims} "
                              f"is not an even part of a head of {d_head}")
@@ -346,16 +364,18 @@ class AttentionLayer(Layer):
         if ap.window < 0:
             raise ValueError(f"{self.name}: window {ap.window} is negative "
                              f"(0 = every earlier token)")
-        return [bottom_shapes[0]]
+        return [tuple(bottom_shapes[0][:2]) + (ap.num_heads * self.v_head,)]
 
     def apply(self, params, bottoms, ctx):
         from ..models.transformer import rope_attention
         ap = self.lp.attention_param
-        return [rope_attention(*bottoms, n_heads=ap.num_heads,
+        return [rope_attention(*bottoms[:3], n_heads=ap.num_heads,
                                rope_theta=ap.rope_theta,
                                n_kv_heads=ap.num_kv_heads,
                                rotary_dims=ap.rotary_dims,
-                               window=ap.window, rope=ap.rope)]
+                               window=ap.window, rope=ap.rope,
+                               k_shared=bottoms[3] if len(bottoms) == 4
+                               else None)]
 
 
 class MoELayer(Layer):
@@ -676,6 +696,150 @@ class CCAQKNormLayer(_CCALayer):
             return y.reshape(x.shape).astype(x.dtype)
 
         return [unit(bottoms[0], self.h), unit(bottoms[1], self.g, tau)]
+
+
+# The recurrent-state layers (KDA, arXiv:2510.26692): what happens to q, k
+# and v between their projections and the scan, the decay's form, the scan.
+
+class ShortConvLayer(Layer):
+    """(N, S, C) -> the same shape: a causal depthwise convolution over the
+    sequence, ``kernel_size`` taps a channel, y_t = sum_j w[j] * x_{t-j}
+    (zeros before a sequence's start: no state crosses it; nothing resets
+    inside a sequence), no bias, then SiLU. Taps
+    and sum in f32. Blob: w (kernel_size, C). A sibling of CCA_CONV, which
+    is that layer's two-bottom form with biases and a grouped second
+    convolution."""
+    TYPE = "SHORT_CONV"
+
+    def setup(self, bottom_shapes):
+        kp = self.lp.kda_param
+        if len(bottom_shapes) != 1 or len(bottom_shapes[0]) != 3 \
+                or kp.kernel_size <= 0:
+            raise ValueError(f"{self.name}: SHORT_CONV takes (N, S, C) and "
+                             f"a kernel_size > 0, got {bottom_shapes}")
+        self.params = [self._param(
+            "w", (kp.kernel_size, bottom_shapes[0][-1]), kp.weight_filler,
+            0)]
+        return [bottom_shapes[0]]
+
+    def apply(self, params, bottoms, ctx):
+        @jax.checkpoint         # a gradient keeps x and w, no f32 copy
+        def conv(x, w):
+            taps, s = w.shape[0], x.shape[1]
+            w = w.astype(jnp.float32)
+            # the zeros before the start once, in x's type; tap j reads the
+            # window that ends j tokens back
+            early = jnp.pad(x, [(0, 0), (taps - 1, 0), (0, 0)])
+            y = sum(w[j] * early[:, taps - 1 - j:taps - 1 - j + s]
+                    .astype(jnp.float32) for j in range(taps))
+            return jax.nn.silu(y).astype(x.dtype)
+
+        return [conv(bottoms[0], _tap_all(ctx, self.name, params)["w"])]
+
+
+def _split_heads(name, what, shape, heads):
+    if len(shape) != 3 or heads <= 0 or shape[-1] % heads:
+        raise ValueError(f"{name}: {what} takes (N, S, H d) with H = "
+                         f"{heads} heads, got {shape}")
+    return shape[-1] // heads
+
+
+class L2NormLayer(Layer):
+    """(N, S, H d) -> each of ``num_heads`` heads divided by its L2 norm,
+    x * rsqrt(sum x^2 + eps) over its own d dims, in f32. No blob."""
+    TYPE = "L2_NORM"
+
+    def setup(self, bottom_shapes):
+        _split_heads(self.name, self.TYPE, bottom_shapes[0],
+                     self.lp.kda_param.num_heads)
+        return [bottom_shapes[0]]
+
+    def apply(self, params, bottoms, ctx):
+        kp = self.lp.kda_param
+
+        @jax.checkpoint         # a gradient keeps x, no f32 copy
+        def unit(x):
+            x32 = x.astype(jnp.float32).reshape(
+                x.shape[:2] + (kp.num_heads, -1))
+            y = x32 * lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True)
+                                + kp.eps)
+            return y.reshape(x.shape).astype(x.dtype)
+
+        return [unit(bottoms[0])]
+
+
+class KDADecayLayer(Layer):
+    """(N, S, H d) -> the log-decay g = -exp(A_log) softplus(x + dt_bias),
+    <= 0, one a head AND channel, f32 whatever the compute policy (its
+    cumulative sums are the scan's exponents). Blobs: A_log (H,), dt_bias
+    (H d). A second top, optional: the mean of exp(g) over the step, a
+    scalar a display carries (does the state forget?)."""
+    TYPE = "KDA_DECAY"
+
+    def setup(self, bottom_shapes):
+        kp = self.lp.kda_param
+        _split_heads(self.name, self.TYPE, bottom_shapes[0], kp.num_heads)
+        if len(self.lp.top) not in (1, 2):
+            raise ValueError(f"{self.name}: KDA_DECAY has 1 or 2 tops (g[, "
+                             f"the mean decay])")
+        self.params = [
+            self._param("A_log", (kp.num_heads,), FillerParameter(
+                type="log_of_uniform", min=kp.a_min, max=kp.a_max), 0),
+            self._param("dt_bias", (bottom_shapes[0][-1],), FillerParameter(
+                type="inv_softplus_log_uniform", min=kp.dt_min,
+                max=kp.dt_max), 1)]
+        return [bottom_shapes[0]] + [()] * (len(self.lp.top) - 1)
+
+    def default_loss_weight(self) -> float:
+        return 0.0
+
+    def apply(self, params, bottoms, ctx):
+        p = _tap_all(ctx, self.name, params)
+        heads = self.lp.kda_param.num_heads
+
+        @jax.checkpoint         # a gradient keeps x and the two blobs
+        def decay(x, a_log, dt_bias):
+            x = x.astype(jnp.float32)
+            step = jax.nn.softplus(x + dt_bias.astype(jnp.float32))
+            rate = jnp.exp(a_log.astype(jnp.float32))
+            return -(step.reshape(x.shape[:2] + (heads, -1))
+                     * rate[:, None]).reshape(x.shape)
+
+        g = decay(bottoms[0], p["A_log"], p["dt_bias"])
+        mean = jnp.mean(jnp.exp(lax.stop_gradient(g)))
+        return [g, mean][:len(self.lp.top)]
+
+
+class KDAScanLayer(Layer):
+    """The recurrent-state layer. Bottoms q, k, g (N, S, H d_k), v
+    (N, S, H d_v), beta (N, S, H) -> o (N, S, H d_v): per head a state
+    (d_k, d_v), zero at a sequence's start,
+    S_t = (I - beta k k^T) Diag(exp(g)) S_{t-1} + beta k v^T,
+    o_t = S_t^T q_t d_k^-0.5 (``ops/kda.kda_scan``: chunks of the sequence,
+    f32 state). Nothing resets the state inside a sequence."""
+    TYPE = "KDA_SCAN"
+
+    def setup(self, bottom_shapes):
+        h = self.lp.kda_param.num_heads
+        if len(bottom_shapes) != 5:
+            raise ValueError(f"{self.name}: KDA_SCAN takes q, k, v, g, beta")
+        q, k, v, g, beta = (tuple(b) for b in bottom_shapes)
+        _split_heads(self.name, self.TYPE, q, h)
+        _split_heads(self.name, self.TYPE, v, h)
+        if not q == k == g or v[:2] != q[:2] or beta != q[:2] + (h,):
+            raise ValueError(
+                f"{self.name}: KDA_SCAN takes q, k, g of one (N, S, H d_k) "
+                f"shape, v (N, S, H d_v) and beta (N, S, {h}); got "
+                f"{bottom_shapes}")
+        return [v]
+
+    def apply(self, params, bottoms, ctx):
+        from ..ops.kda import kda_scan
+        h = self.lp.kda_param.num_heads
+        q, k, v, g, beta = bottoms
+        heads = lambda x: x.reshape(x.shape[:2] + (h, -1))
+        o = kda_scan(heads(q), heads(k), heads(v), heads(g), beta)
+        return [o.reshape(v.shape).astype(v.dtype)]
 
 
 class SiLUGateLayer(Layer):
@@ -1232,7 +1396,8 @@ REGISTRY: Dict[str, type] = {
     for cls in [
         ConvolutionLayer, InnerProductLayer, EmbedLayer, RMSNormLayer,
         AttentionLayer, MoELayer, MoERouterLayer, TokenShiftLayer,
-        CCAConvLayer, CCAQKMeanLayer, CCAQKNormLayer, PoolingLayer, LRNLayer,
+        CCAConvLayer, CCAQKMeanLayer, CCAQKNormLayer, ShortConvLayer,
+        L2NormLayer, KDADecayLayer, KDAScanLayer, PoolingLayer, LRNLayer,
         Im2colLayer, ReLULayer, SigmoidLayer, TanHLayer, BNLLLayer,
         AbsValLayer, PowerLayer, ThresholdLayer, DropoutLayer, FlattenLayer,
         ConcatLayer, SliceLayer, SplitLayer, EltwiseLayer, MVNLayer,

@@ -406,7 +406,8 @@ class Net:
                     shape[1], shape[1],
                     shape[2] // layer.lp.attention_param.num_heads,
                     jnp.dtype(policy().compute_dtype).itemsize,
-                    window=layer.lp.attention_param.window)
+                    window=layer.lp.attention_param.window,
+                    dv=layer.lp.attention_param.value_head_dim or None)
                 if arm == "pallas_flash":
                     # the tiles each flash kernel runs with and the live /
                     # visited programs of its grid: stats.yaml carries them
@@ -422,6 +423,17 @@ class Net:
                     arm += f"; window {ap.window} as a dense mask"
                 if not ap.rope:
                     arm += "; no positions"
+                if arm.startswith("dense") and ap.value_head_dim:
+                    arm += (f"; d {shape[2] // ap.num_heads}/"
+                            f"{ap.value_head_dim}")
+                if len(layer.lp.bottom) == 4:
+                    # latent attention: the one key part all heads share
+                    arm += (f"; k_pe repeated x"
+                            f"{ap.num_kv_heads or ap.num_heads}")
+            elif layer.TYPE == "KDA_SCAN":
+                from ..ops.kda import kda_route
+                what = "kda"
+                arm, note = kda_route(shape[1])[1], ""
             elif layer.TYPE == "MOE":
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"
@@ -449,6 +461,26 @@ class Net:
                          "num_held": l.held,
                          "router_num_experts": l.lp.moe_param.num_experts}
                 for l in self.layers if l.TYPE == "MOE"}
+
+    def recurrent_state(self) -> Dict[str, Dict[str, int]]:
+        """{KDA_SCAN layer: its states' shape, the scan's chunk and what
+        its backward keeps of them} — stats.yaml's ``recurrent_state``
+        section."""
+        from ..ops.kda import kda_chunk, state_bytes
+        out = {}
+        for l in self.layers:
+            if l.TYPE != "KDA_SCAN":
+                continue
+            (n, s, wk), (_, _, wv) = (self.blob_shapes[b]
+                                      for b in l.lp.bottom[:3:2])
+            h = l.lp.kda_param.num_heads
+            chunk = kda_chunk(s)
+            out[l.name] = {
+                "heads": h, "d_k": wk // h, "d_v": wv // h,
+                "chunk": chunk or 1, "chunks": s // (chunk or 1),
+                "saved_state_bytes": state_bytes(n, s, h, wk // h, wv // h)
+                if chunk else 0}
+        return out
 
     def _held_rungs(self, layer: Layer) -> Tuple[int, ...]:
         """The prefix lengths a MOE layer's held arm may run its row work

@@ -148,7 +148,8 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
                    rope_theta: float = 10000.0, n_kv_heads: int = 0,
                    rotary_dims: int = 0, window: int = 0,
-                   rope: bool = True) -> jax.Array:
+                   rope: bool = True,
+                   k_shared: Optional[jax.Array] = None) -> jax.Array:
     """q (B, S, D), k and v (B, S, Dkv) -> (B, S, D): q split into
     ``n_heads`` heads, k and v into ``n_kv_heads`` of the same width (0 =
     ``n_heads``, Dkv = D), rotary positions on q and k, causal
@@ -160,13 +161,19 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     a head rotate, the rest pass; ``rope`` false: nothing rotates, the
     layer has no positions. ``window`` (0 = none): token t attends to s with
     t - window < s <= t. The Pallas flash kernel where the sequence
-    tiles (``maybe_flash_attention``), the dense op elsewhere."""
+    tiles (``maybe_flash_attention``), the dense op elsewhere.
+
+    v's heads may have a width of their own (Dv / n_kv: the result is then
+    (B, S, n_heads * that)). ``k_shared`` (B, S, Ds): a key part that every
+    head shares; k's heads are then d_head - Ds wide and each key head is
+    its own dims followed by it, repeated to the heads before the kernel
+    as key-value heads are."""
     b, s, d = q.shape
     d_head = d // n_heads
     n_kv = n_kv_heads or n_heads
 
     def heads(t, n):
-        return t.reshape(b, s, n, d_head).swapaxes(1, 2)
+        return t.reshape(b, s, n, -1).swapaxes(1, 2)
 
     rot = rotary_dims or d_head
     cos, sin = rope_tables(s, rot, rope_theta) if rope else (None, None)
@@ -179,12 +186,19 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
         return jnp.concatenate([apply_rope(t[..., :rot], cos, sin),
                                 t[..., rot:]], axis=-1)
 
-    q, k, v = rotate(heads(q, n_heads)), rotate(heads(k, n_kv)), \
+    def key_heads(t):
+        t = heads(t, n_kv)
+        if k_shared is None:
+            return t
+        return jnp.concatenate([t, jnp.broadcast_to(
+            k_shared[:, None], (b, n_kv, s, k_shared.shape[-1]))], axis=-1)
+
+    q, k, v = rotate(heads(q, n_heads)), rotate(key_heads(k)), \
         heads(v, n_kv)
     if n_kv != n_heads:
         k, v = (jnp.repeat(t, n_heads // n_kv, axis=1) for t in (k, v))
     att = maybe_flash_attention(q, k, v, causal=True, window=window)
-    return att.swapaxes(1, 2).reshape(b, s, d)
+    return att.swapaxes(1, 2).reshape(b, s, n_heads * v.shape[-1])
 
 
 def attention_sublayer(cfg: TransformerConfig, x: jax.Array, blk: Dict,

@@ -887,3 +887,193 @@ def trinity_mini(batch: int = 1,
     layers.append(LayerParameter(
         name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
     return NetParameter(name=name, layers=layers)
+
+
+def kimi_linear(batch: int = 1,
+                source: str = "examples/lm/kimi_linear_tokens.txt",
+                n_layers: int = 27, dense_layers: int = 1,
+                hidden: int = 2304, heads: int = 32, head_dim: int = 128,
+                conv_taps: int = 4, full_every: int = 4,
+                kv_rank: int = 512, nope_dim: int = 128, rope_dim: int = 64,
+                v_dim: int = 128, dense_width: int = 9216,
+                experts: int = 256, top_k: int = 8, held: int = 0,
+                held_first: int = 0, expert_width: int = 1024,
+                shared_width: int = 1024, route_scale: float = 2.446,
+                bias_update_rate: float = 0.001, vocab: int = 163840,
+                eps: float = 1e-5, init_std: float = 0.02,
+                name: str = "Kimi-Linear-48B-A3B") -> NetParameter:
+    """Kimi-Linear-48B-A3B (config.json of
+    moonshotai/Kimi-Linear-48B-A3B-Instruct, ``kimi_linear``;
+    arXiv:2510.26692): every layer is a token mixer and an FFN, pre-norm,
+
+        h = x + Mix(N1(x));  y = h + FFN(N2(h))
+
+    Layer ``full_every`` (1-indexed) and every ``full_every``-th after it
+    mixes by latent attention (MLA), the others by the gated delta rule
+    with a per-channel decay (KDA).
+
+    KDA: q, k, v (``heads`` x ``head_dim``) project the normed state
+    (``l<i>_kda_{q,k,v}``), each passes a causal depthwise convolution of
+    ``conv_taps`` taps and SiLU (``l<i>_kda_conv_*``), q and k an L2 norm
+    per head (``l<i>_kda_l2_*``); the log-decay, one a head and channel,
+    is -exp(A_log) softplus(W_up W_down a + dt_bias) (``l<i>_kda_decay*``,
+    whose second top ``l<i>_decay_mean`` every display carries), the write
+    strength sigmoid(W_b a) a head (``l<i>_kda_beta*``); the recurrence
+    (``l<i>_kda_scan``: a state of ``head_dim`` x ``head_dim`` a head);
+    its output takes an RMSNorm per head with one gain
+    (``l<i>_kda_onorm``), times sigmoid of a low-rank gate
+    (``l<i>_kda_ogate*``), and leaves through ``l<i>_kda_o``. No
+    positions.
+
+    MLA: q (``heads`` x (``nope_dim`` + ``rope_dim``)) from the normed
+    state (``l<i>_mla_q``); one projection to a latent of ``kv_rank`` and a
+    ``rope_dim``-wide key part that all heads share (``l<i>_mla_kva``,
+    split by ``l<i>_mla_kva_split``); the normed latent
+    (``l<i>_mla_kvnorm``) gives each head ``nope_dim`` of key
+    (``l<i>_mla_kvb_k``) and ``v_dim`` of value (``l<i>_mla_kvb_v``: the
+    published one matrix, its rows sorted into keys and values); causal
+    attention with NO positions over keys [own part ; shared part] and the
+    narrower values (``l<i>_mla_attn``); ``l<i>_mla_o``.
+
+    FFN: the first ``dense_layers`` layers a SiLU-gated MLP of
+    ``dense_width``; the others a sigmoid router with a selection bias
+    over ``experts`` (``l<i>_router``), ``l<i>_moe`` holding ``held`` of
+    the experts from ``held_first`` on (0 = all: with fewer the net is one
+    rank's share of an expert-parallel model) and an always-on shared
+    expert (``l<i>_shared_*``) added unweighted, as ``trinity_mini``'s.
+
+    The head is untied. Gains, A_log, dt_bias and the selection bias carry
+    decay_mult 0, every matrix and the convolutions' taps 1."""
+    from ..proto.messages import (AttentionParameter, EltwiseParameter,
+                                  EmbedParameter, HDF5DataParameter,
+                                  KDAParameter, MoEParameter,
+                                  RMSNormParameter, SliceParameter)
+    w = gaussian(init_std)
+    width = heads * head_dim
+    layers: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+    no_decay = ParamSpec(lr_mult=1.0, decay_mult=0.0)
+
+    def norm(lname, bottom, top, per_head=0):
+        layers.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[no_decay], rms_norm_param=RMSNormParameter(
+                eps=eps, num_heads=per_head)))
+
+    def proj(lname, bottom, top, n_out):
+        layers.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2, weight_filler=w)))
+
+    def eltwise(lname, a, b, top, operation="SUM"):
+        layers.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation=operation)))
+
+    def sigmoid(lname, bottom, top):
+        layers.append(LayerParameter(name=lname, type="SIGMOID",
+                                     bottom=[bottom], top=[top]))
+
+    def gated_mlp(p, bottom, top, n_mid):
+        proj(p + "gate", bottom, p + "g", n_mid)
+        proj(p + "up", bottom, p + "u", n_mid)
+        layers.append(LayerParameter(
+            name=p + "act", type="SILU_GATE", bottom=[p + "g", p + "u"],
+            top=[p + "a"]))
+        proj(p + "down", p + "a", top, hidden)
+
+    def kda(p, a, top):
+        kp = dict(num_heads=heads)
+        taps = FillerParameter(type="uniform", min=-conv_taps ** -0.5,
+                               max=conv_taps ** -0.5)
+        for t in "qkv":
+            proj(p + "kda_" + t, a, p + t + "p", width)
+            layers.append(LayerParameter(
+                name=p + "kda_conv_" + t, type="SHORT_CONV",
+                bottom=[p + t + "p"], top=[p + t + "c"],
+                kda_param=KDAParameter(kernel_size=conv_taps,
+                                       weight_filler=taps)))
+        for t in "qk":
+            layers.append(LayerParameter(
+                name=p + "kda_l2_" + t, type="L2_NORM", bottom=[p + t + "c"],
+                top=[p + t + "n"], kda_param=KDAParameter(**kp)))
+        proj(p + "kda_decay_down", a, p + "fd", head_dim)
+        proj(p + "kda_decay_up", p + "fd", p + "fu", width)
+        layers.append(LayerParameter(
+            name=p + "kda_decay", type="KDA_DECAY", bottom=[p + "fu"],
+            top=[p + "gdec", p + "decay_mean"], param=[no_decay, no_decay],
+            kda_param=KDAParameter(**kp)))
+        proj(p + "kda_beta", a, p + "bl", heads)
+        sigmoid(p + "kda_beta_sig", p + "bl", p + "beta")
+        layers.append(LayerParameter(
+            name=p + "kda_scan", type="KDA_SCAN",
+            bottom=[p + "qn", p + "kn", p + "vc", p + "gdec", p + "beta"],
+            top=[p + "so"], kda_param=KDAParameter(**kp)))
+        norm(p + "kda_onorm", p + "so", p + "son", heads)
+        proj(p + "kda_ogate_down", a, p + "ogd", head_dim)
+        proj(p + "kda_ogate_up", p + "ogd", p + "ogu", width)
+        sigmoid(p + "kda_ogate_sig", p + "ogu", p + "ogs")
+        eltwise(p + "kda_ogate_mul", p + "son", p + "ogs", p + "sog", "PROD")
+        proj(p + "kda_o", p + "sog", top, hidden)
+
+    def mla(p, a, top):
+        proj(p + "mla_q", a, p + "q", heads * (nope_dim + rope_dim))
+        proj(p + "mla_kva", a, p + "kva", kv_rank + rope_dim)
+        layers.append(LayerParameter(
+            name=p + "mla_kva_split", type="SLICE", bottom=[p + "kva"],
+            top=[p + "c", p + "kpe"],
+            slice_param=SliceParameter(slice_dim=2, slice_point=[kv_rank])))
+        norm(p + "mla_kvnorm", p + "c", p + "cn")
+        proj(p + "mla_kvb_k", p + "cn", p + "kn", heads * nope_dim)
+        proj(p + "mla_kvb_v", p + "cn", p + "v", heads * v_dim)
+        layers.append(LayerParameter(
+            name=p + "mla_attn", type="ATTENTION",
+            bottom=[p + "q", p + "kn", p + "v", p + "kpe"], top=[p + "att"],
+            attention_param=AttentionParameter(
+                num_heads=heads, rope=False, value_head_dim=v_dim)))
+        proj(p + "mla_o", p + "att", top, hidden)
+
+    layers.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["x0"],
+        embed_param=EmbedParameter(input_dim=vocab, num_output=hidden,
+                                   weight_filler=w)))
+    x = "x0"
+    for i in range(n_layers):
+        p = f"l{i}_"
+        norm(p + "attn_norm", x, p + "a")
+        (mla if (i + 1) % full_every == 0 else kda)(p, p + "a", p + "ao")
+        eltwise(p + "res1", x, p + "ao", p + "h")
+        norm(p + "ffn_norm", p + "h", p + "u")
+        if i < dense_layers:
+            gated_mlp(p + "ffn_", p + "u", p + "f", dense_width)
+        else:
+            moe = dict(num_experts=experts, top_k=top_k,
+                       expert_width=expert_width, score_func="sigmoid",
+                       route_scale=route_scale,
+                       bias_update_rate=bias_update_rate, weight_filler=w)
+            layers.append(LayerParameter(
+                name=p + "router", type="MOE_ROUTER", bottom=[p + "u"],
+                top=[p + "gates", p + "bias_next", p + "bias_max_abs"],
+                param=[ParamSpec(), no_decay],
+                moe_param=MoEParameter(**moe)))
+            layers.append(LayerParameter(
+                name=p + "moe", type="MOE", bottom=[p + "u", p + "gates"],
+                top=[p + "m", p + "expert_load", p + "dropped",
+                     p + "held_share"],
+                moe_param=MoEParameter(num_held=held, held_first=held_first,
+                                       **moe)))
+            gated_mlp(p + "shared_", p + "u", p + "s", shared_width)
+            eltwise(p + "moe_sum", p + "m", p + "s", p + "f")
+        eltwise(p + "res2", p + "h", p + "f", p + "y")
+        x = p + "y"
+    norm("final_norm", x, "xf")
+    proj("lm_head", "xf", "logits", vocab)
+    layers.append(LayerParameter(
+        name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
+        top=["nll"]))
+    # the mean over positions: the exit-weighted loss of ONE pass
+    layers.append(LayerParameter(
+        name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    return NetParameter(name=name, layers=layers)

@@ -131,24 +131,29 @@ def pick_block(s: int) -> Optional[int]:
 
 
 def _flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
-                      itemsize: int) -> int:
+                      itemsize: int, dv: Optional[int] = None) -> int:
     """Live VMEM of one program: the score-shaped f32 temporaries, the
     operand and result tiles (double-buffered by the pipeline) and the f32
-    accumulators."""
+    accumulators. ``d`` is the width of a q / k head, ``dv`` of a v head
+    (None: the same)."""
+    dv = d if dv is None else dv
     scores = _SCORE_TEMPS[kernel] * block_q * block_k * 4
-    q_tiles, k_tiles, acc_rows = {
-        "fwd": (2, 2, block_q),           # q, o | k, v | acc
-        "dq": (3, 2, block_q),            # q, dO, dq | k, v | dq_acc
-        "dkv": (2, 4, 2 * block_k),       # q, dO | k, v, dk, dv | two accs
+    q_cols, k_cols, acc = {
+        "fwd": (d + dv, d + dv, block_q * dv),    # q, o | k, v | acc
+        "dq": (2 * d + dv, d + dv, block_q * d),  # q, dO, dq | k, v | dq_acc
+        "dkv": (d + dv, 2 * (d + dv),             # q, dO | k, v, dk, dv |
+                block_k * (d + dv)),              # two accs
     }[kernel]
-    tiles = 2 * itemsize * d * (q_tiles * block_q + k_tiles * block_k)
-    return scores + tiles + acc_rows * d * 4
+    tiles = 2 * itemsize * (q_cols * block_q + k_cols * block_k)
+    return scores + tiles + acc * 4
 
 
-def flash_blocks(kernel: str, s: int, d: int, itemsize: int):
+def flash_blocks(kernel: str, s: int, d: int, itemsize: int,
+                 dv: Optional[int] = None):
     """``(block_q, block_k)`` for one of the three flash kernels (``"fwd"``,
     ``"dq"``, ``"dkv"``) — THE tile rule, a function of the sequence
-    length, the head width and the operand itemsize alone: the largest
+    length, the head widths (``d`` of q and k, ``dv`` of v: None = the
+    same) and the operand itemsize alone: the largest
     aligned blocks dividing S whose live tiles fit the VMEM budget. A grid
     step costs about 0.35 us on the v5e whatever it computes, ten times
     the arithmetic of a 128 x 128 x 128 block, so a program is given as
@@ -161,7 +166,7 @@ def flash_blocks(kernel: str, s: int, d: int, itemsize: int):
     for bq in _BLOCK_LADDER:
         for bk in _BLOCK_LADDER:
             if s % bq or s % bk or _flash_vmem_bytes(
-                    kernel, bq, bk, d, itemsize) > _FLASH_VMEM_BUDGET:
+                    kernel, bq, bk, d, itemsize, dv) > _FLASH_VMEM_BUDGET:
                 continue
             if best is None or (bq * bk, bk) > (best[0] * best[1], best[1]):
                 best = (bq, bk)
@@ -352,11 +357,11 @@ def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         lse_ref[0] = m_ref[...] + jnp.log(lsafe)
 
 
-def _blocks_for(kernel: str, q, block_q, block_k):
+def _blocks_for(kernel: str, q, block_q, block_k, dv=None):
     """The caller's blocks, or the tile rule's for this kernel."""
     s, d = q.shape[-2:]
     if block_q is None or block_k is None:
-        blocks = flash_blocks(kernel, s, d, q.dtype.itemsize)
+        blocks = flash_blocks(kernel, s, d, q.dtype.itemsize, dv)
         if blocks is None:
             raise ValueError(f"no aligned block divides seq len {s}")
         return blocks
@@ -397,11 +402,12 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
     (``flash_blocks``). ``window`` (causal self-attention, below S): the
     grid covers the band alone."""
     b, h, s, d = q.shape
+    dv = v.shape[-1]                    # a v head may have its own width
     bh = b * h
     q3 = q.reshape(bh, s, d)
     k3 = k.reshape(bh, s, d)
-    v3 = v.reshape(bh, s, d)
-    block_q, block_k = _blocks_for("fwd", q, block_q, block_k)
+    v3 = v.reshape(bh, s, dv)
+    block_q, block_k = _blocks_for("fwd", q, block_q, block_k, dv)
     n_kb = s // block_k
     extra = {}
     if window is not None:
@@ -414,7 +420,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
     in_specs = [
         pl.BlockSpec((1, block_q, d), qmap, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), kmap, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), kmap, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, dv), kmap, memory_space=pltpu.VMEM),
     ]
     args = [q3, k3, v3]
     if chunk:
@@ -426,23 +432,23 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
                           chunk_mode=chunk,
                           op_dtype=_operand_dtype(q.dtype), **extra),
         name="flash_fwd",
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)),
         grid=grid,
         in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((1, block_q, d), qmap, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, dv), qmap, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_q, 1), qmap, memory_space=pltpu.VMEM),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(*args)
-    return out.reshape(b, h, s, d), lse.reshape(b, h, s)
+    return out.reshape(b, h, s, dv), lse.reshape(b, h, s)
 
 
 # --------------------------------------------------------------------------- #
@@ -560,6 +566,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     ``delta`` (rowsum(dO*O), global) may be passed in by the ring backward,
     whose O is the merged global output."""
     b, h, s, d = q.shape
+    d_v = v.shape[-1]                   # v, out and g: a v head's width
     bh = b * h
     if delta is None:
         # delta_i = rowsum(dO * O): one O(S*D) elementwise pass, XLA-fused
@@ -575,10 +582,11 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
     # dQ sweep: grid (bh, q_blocks, k_blocks), the forward's
-    bq, bk = _blocks_for("dq", q, block_q, block_k)
+    bq, bk = _blocks_for("dq", q, block_q, block_k, d_v)
     qmap = lambda i, j, kk: (i, j, 0)
-    qspec = vmem((1, bq, d), qmap)
-    kspec = vmem((1, bk, d), _kv_block_map(clamp, bq, bk, window))
+    kmap = _kv_block_map(clamp, bq, bk, window)
+    qspec, gspec = vmem((1, bq, d), qmap), vmem((1, bq, d_v), qmap)
+    kspec, vspec = vmem((1, bk, d), kmap), vmem((1, bk, d_v), kmap)
     rowq = vmem((1, bq, 1), qmap)
     n_kb, extra = s // bk, {}
     if window is not None:
@@ -591,7 +599,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
         name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         grid=(bh, s // bq, n_kb),
-        in_specs=smem + [qspec, kspec, kspec, qspec, rowq, rowq],
+        in_specs=smem + [qspec, kspec, vspec, gspec, rowq, rowq],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_compiler_params(),
@@ -601,7 +609,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
 
     # dK/dV sweep: swapped grid (bh, k_blocks, q_blocks); the row statistics
     # one lane-dense (1, block_q) row per Q block
-    bq, bk = _blocks_for("dkv", q, block_q, block_k)
+    bq, bk = _blocks_for("dkv", q, block_q, block_k, d_v)
     n_qb = steps = s // bq
     extra = {}
     if window is not None:
@@ -618,8 +626,10 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
         qblk = lambda j, kk: jnp.maximum(kk, _first_live_q(j, bq, bk))
     else:
         qblk = lambda j, kk: kk
-    qspec_t = vmem((1, bq, d), lambda i, j, kk: (i, qblk(j, kk), 0))
-    kspec_t = vmem((1, bk, d), lambda i, j, kk: (i, j, 0))
+    qmap_t = lambda i, j, kk: (i, qblk(j, kk), 0)
+    kmap_t = lambda i, j, kk: (i, j, 0)
+    qspec_t, gspec_t = vmem((1, bq, d), qmap_t), vmem((1, bq, d_v), qmap_t)
+    kspec_t, vspec_t = vmem((1, bk, d), kmap_t), vmem((1, bk, d_v), kmap_t)
     rowq_t = vmem((1, 1, 1, bq), lambda i, j, kk: (i, qblk(j, kk), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
@@ -627,18 +637,18 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
                           chunk_mode=chunk, op_dtype=op_dtype, **extra),
         name="flash_bwd_dkv",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
+                   jax.ShapeDtypeStruct((bh, s, d_v), v.dtype)),
         grid=(bh, s // bk, steps),
-        in_specs=smem + [qspec_t, kspec_t, kspec_t, qspec_t, rowq_t, rowq_t],
-        out_specs=(kspec_t, kspec_t),
+        in_specs=smem + [qspec_t, kspec_t, vspec_t, gspec_t, rowq_t, rowq_t],
+        out_specs=(kspec_t, vspec_t),
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                        pltpu.VMEM((bk, d_v), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(*mode_arg, q3, k3, v3, g3, lse.reshape(bh, n_qb, 1, bq),
       delta.reshape(bh, n_qb, 1, bq))
 
-    rs = lambda x: x.reshape(b, h, s, d)
+    rs = lambda x: x.reshape(b, h, s, x.shape[-1])
     return rs(dq), rs(dk), rs(dv)
 
 
@@ -696,7 +706,8 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def attention_route(s: int, sk: int, d: int, itemsize: int,
-                    causal: bool = True, window: Optional[int] = None):
+                    causal: bool = True, window: Optional[int] = None,
+                    dv: Optional[int] = None):
     """``(arm, note)`` for one attention geometry — THE routing decision:
     ``maybe_flash_attention`` takes it at trace time and ``Net`` logs it
     per ATTENTION layer at construction, for Q and K/V lengths ``s`` and
@@ -717,13 +728,14 @@ def attention_route(s: int, sk: int, d: int, itemsize: int,
     window = _band_window(window, causal, s)
     parts = []
     for kernel in ("fwd", "dq", "dkv"):
-        bq, bk = flash_blocks(kernel, s, d, itemsize)
+        bq, bk = flash_blocks(kernel, s, d, itemsize, dv)
         live, visited = flash_grid_programs(s, bq, bk, causal, window,
                                             over_q=kernel == "dkv")
         parts.append(f"{kernel} {bq}x{bk} {live}/{visited}")
     return "pallas_flash", ", ".join(parts) + \
         "; block_q x block_k, live/visited programs a head" + \
-        (f"; window {window}: the band's grid" if window else "")
+        (f"; window {window}: the band's grid" if window else "") + \
+        (f"; flash d {d}/{dv}" if dv not in (None, d) else "")
 
 
 def maybe_flash_attention(q, k, v, causal: bool = False,
@@ -734,9 +746,11 @@ def maybe_flash_attention(q, k, v, causal: bool = False,
     (both blocks) and the Ulysses head-parallel path."""
     from .attention import attention
     s, d = q.shape[-2:]
+    dv = v.shape[-1]
     arm, note = attention_route(s, k.shape[-2], d, q.dtype.itemsize, causal,
-                                window)
-    where = f"[kernel_route] attention S={s} D={d}"
+                                window, dv)
+    where = f"[kernel_route] attention S={s} D={d}" \
+        + (f"/{dv}" if dv != d else "")
     if arm == "pallas_flash":
         _log_route_once(f"{where}: pallas flash, {note}")
         return flash_attention(q, k, v, causal, scale, window=window)

@@ -310,6 +310,10 @@ class AttentionParameter:
     # > 0: token t attends to s with t - window < s <= t (a sliding window
     # inside the causal mask); 0 = every earlier token
     window: int = 0
+    # value heads of their own width (the scores' heads stay D / num_heads
+    # wide): v is (N, S, num_kv_heads * value_head_dim) and so is the top
+    # per query head; 0 = the key head's width
+    value_head_dim: int = 0
 
 
 @dataclass
@@ -348,6 +352,26 @@ class CCAParameter:
     time0: int = 2
     time1: int = 2
     eps: float = 1e-5
+    weight_filler: FillerParameter = field(default_factory=FillerParameter)
+
+
+@dataclass
+class KDAParameter:
+    """The recurrent-state layers (gated delta rule with a per-channel
+    decay, arXiv:2510.26692). SHORT_CONV: ``kernel_size`` causal taps a
+    channel (``weight_filler``), then SiLU.
+    L2_NORM: each of ``num_heads`` heads over its own dims, x * rsqrt(sum
+    x^2 + ``eps``). KDA_DECAY: g = -exp(A_log) softplus(x + dt_bias), A_log
+    (one a head) the log of a uniform draw in [``a_min``, ``a_max``],
+    dt_bias (one a channel) the inverse softplus of a log-uniform draw in
+    [``dt_min``, ``dt_max``]. KDA_SCAN: ``num_heads`` states."""
+    num_heads: int = 1
+    kernel_size: int = 4
+    eps: float = 1e-6
+    a_min: float = 1.0
+    a_max: float = 16.0
+    dt_min: float = 1e-3
+    dt_max: float = 1e-1
     weight_filler: FillerParameter = field(default_factory=FillerParameter)
 
 
@@ -474,7 +498,8 @@ V2_TYPE_TO_V1 = {
     "SoftmaxNLL": "SOFTMAX_NLL", "ExitLoss": "EXIT_LOSS",
     "TokenShift": "TOKEN_SHIFT", "CCAConv": "CCA_CONV",
     "CCAQKMean": "CCA_QKMEAN", "CCAQKNorm": "CCA_QKNORM",
-    "MoERouter": "MOE_ROUTER",
+    "MoERouter": "MOE_ROUTER", "ShortConv": "SHORT_CONV",
+    "L2Norm": "L2_NORM", "KDADecay": "KDA_DECAY", "KDAScan": "KDA_SCAN",
 }
 V1_TYPES = set(V2_TYPE_TO_V1.values()) | {"NONE"}
 
@@ -537,6 +562,7 @@ class LayerParameter:
     moe_param: MoEParameter = field(default_factory=MoEParameter)
     exit_loss_param: ExitLossParameter = field(default_factory=ExitLossParameter)
     cca_param: CCAParameter = field(default_factory=CCAParameter)
+    kda_param: KDAParameter = field(default_factory=KDAParameter)
     blob_mode: str = "GLOBAL"  # Poseidon extension on LayerParameter level
 
     def canonical_type(self) -> str:

@@ -495,6 +495,9 @@ class Engine:
         expert_share = self.train_net.expert_share()
         if expert_share:
             self.stats.set_section("expert_share", expert_share)
+        recurrent_state = self.train_net.recurrent_state()
+        if recurrent_state:
+            self.stats.set_section("recurrent_state", recurrent_state)
         # which rung of its ladder a held MOE layer's step took is read off
         # the held share it displays (_absorb): {top: (prefix, rows)}
         self._held_ladders = self.train_net.held_row_ladders()

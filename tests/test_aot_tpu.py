@@ -524,6 +524,73 @@ def test_trinity_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
         assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9
 
 
+# The full-width Kimi-Linear train step (examples/lm/kimi_linear_*: layers 1-5,
+# KDA + dense; KDA, KDA, MLA, KDA with a MoE each, 8 of 256 experts held, an
+# eighth of the untied vocabulary) as `train --bf16 --remat <the solver
+# header's flags>` builds it, for one abstract v5e chip: the compiler's
+# memory accounting that fixed the cell's batch
+# (benchmark/configs/kimi_linear_48b.json,
+# benchmark/cells/kimi_linear.e8of256.pack8k.json) at the depth in the files,
+# at the batch chosen and one sequence more.
+_KIMI_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 1 + {deeper}, 8192, 0").replace(
+    "ouro_2_6b_solver", "kimi_linear_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type in ("ATTENTION", "KDA_SCAN") '
+    'for l in net_param.layers)')
+assert _KIMI_STEP.count("kimi") == 1 and "ouro_2" not in _KIMI_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("more", [0, 1])
+def test_kimi_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
+    """At one sequence of 8,192 the step with one checkpoint a layer is
+    under 85% of the 16.9 GB the compiler allows (PR 22's sizing rule); at
+    two it is over. The four KDA layers' recurrences are the chunked scan
+    (128 chunks of 64, f32 state), the one MLA layer's three flash kernels
+    run at 192-wide scores over 128-wide values on the causal grid, the
+    shared key part repeated to the 32 heads; each MOE layer's held rows
+    run over the prefix rung of a two-rung ladder."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _KIMI_STEP.format(repo=REPO, deeper=more)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    if more and "Ran out of memory in memory space hbm" in r.stderr:
+        return            # over the whole chip: 15.83 of 15.75 GiB (PR 41)
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next cut
+    assert got["depth"] == 5 and got["parameters"] == 602_434_432
+    # embed, head, final norm; a layer: 2 norms; a KDA mixer 15, the MLA
+    # mixer 6; the dense layer's 3; a MoE layer's router 2, 3 stacks,
+    # shared 3
+    assert got["leaves"] == 3 + 5 * 2 + 4 * 15 + 6 + 3 + 4 * 8
+    assert got["segments"] == 5 + 1
+    rows = 8192 * 8 * (1 + more)
+    assert got["routes"] == [
+        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
+        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "head; flash d 192/128); no positions; k_pe repeated x32",
+        f"grouped_matmul=ragged_dot; held rows: prefix {rows // 16} of "
+        f"{rows}, full on overflow",
+        "kda=chunked C 64, 128 chunks, f32 state"]
+    # 4 flash calls in the MLA layer, 30 grouped matmuls and group-metadata
+    # calls a MoE layer (the ladder's two rungs: see the Trinity test)
+    assert got["pallas_custom_calls"] == 4 + 30 * 4
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    if more:
+        assert got["total_gb"] > 0.85 * 16.9   # 17.0 (PR 41)
+    else:
+        assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9   # 13.00 (PR 41)
+
+
+
 # The LRN kernels at the CNN cells' norm layers (AlexNet's two at batch 512,
 # GoogLeNet's two at 128: batch-minor) and at GoogLeNet's published batch 32
 # (channel-minor), forward and backward, through Mosaic; then a stand-in for

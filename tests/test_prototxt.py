@@ -294,6 +294,18 @@ layers { name: "nll" type: SOFTMAX_NLL bottom: "m" bottom: "targets"
 layer { name: "exit" type: "ExitLoss" bottom: "nll" bottom: "nll"
         bottom: "s" top: "exit" top: "mass1" top: "mass2"
         exit_loss_param { entropy_weight: 0.1 } }
+layers { name: "conv" type: SHORT_CONV bottom: "q" top: "qc"
+         kda_param { kernel_size: 3
+                     weight_filler { type: "uniform" min: -0.5 max: 0.5 } } }
+layer { name: "l2" type: "L2Norm" bottom: "qc" top: "qn"
+        kda_param { num_heads: 4 eps: 1e-5 } }
+layers { name: "decay" type: KDA_DECAY bottom: "q" top: "g" top: "g_mean"
+         kda_param { num_heads: 4 a_max: 8 dt_min: 0.01 } }
+layer { name: "scan" type: "KDAScan" bottom: "qn" bottom: "qn" bottom: "q"
+        bottom: "g" bottom: "beta" top: "o" kda_param { num_heads: 4 } }
+layers { name: "mla" type: ATTENTION bottom: "q" bottom: "k" bottom: "v"
+         bottom: "kpe" top: "lat"
+         attention_param { num_heads: 4 rope: false value_head_dim: 8 } }
 """
 
 
@@ -316,6 +328,16 @@ layer { name: "exit" type: "ExitLoss" bottom: "nll" bottom: "nll"
     ("nll", "SOFTMAX_NLL", "top", ["nll"]),
     ("exit", "EXIT_LOSS", "exit_loss_param.entropy_weight", 0.1),
     ("exit", "EXIT_LOSS", "top", ["exit", "mass1", "mass2"]),
+    ("conv", "SHORT_CONV", "kda_param.kernel_size", 3),
+    ("conv", "SHORT_CONV", "kda_param.weight_filler.min", -0.5),
+    ("l2", "L2_NORM", "kda_param.eps", 1e-5),
+    ("decay", "KDA_DECAY", "kda_param.a_max", 8.0),
+    ("decay", "KDA_DECAY", "kda_param.dt_min", 0.01),
+    ("decay", "KDA_DECAY", "top", ["g", "g_mean"]),
+    ("scan", "KDA_SCAN", "bottom", ["qn", "qn", "q", "g", "beta"]),
+    ("scan", "KDA_SCAN", "kda_param.num_heads", 4),
+    ("mla", "ATTENTION", "attention_param.value_head_dim", 8),
+    ("mla", "ATTENTION", "bottom", ["q", "k", "v", "kpe"]),
 ])
 def test_parse_token_layers(layer, ctype, field, want):
     """The token model's layer types and fields, in the V1 and the V2

@@ -1,6 +1,8 @@
-"""The three token configurations that share the flash kernels, the grouped
-matmuls and the routers with Trinity-Mini (OLMoE, Ouro, ZAYA1) trace to the
-program they traced to before its window, sigmoid router and per-head norm
+"""The token configurations that share the flash kernels, the grouped
+matmuls and the routers (OLMoE, Ouro, ZAYA1; since PR 41 Trinity-Mini, whose
+hash was taken on PR 41's parent, and Kimi-Linear's own) trace to the
+program they traced to before Trinity's window, sigmoid router and per-head
+norm, and before Kimi-Linear's two head widths in the flash kernels,
 arrived: the gradient's whole jaxpr at a small size, as lowered for the TPU
 (``POSEIDON_FORCE_PALLAS=1``: the Pallas arm, its kernel bodies, grids and
 index maps are in the text), hashed. The hashes were taken on the parent of
@@ -36,6 +38,17 @@ BUILD = {
     "zaya": lambda: zoo.zaya1(
         batch=N, n_layers=2, hidden=64, heads=2, kv_heads=1, head_dim=128,
         experts=8, held=4, expert_width=32, router_hidden=16, vocab=128),
+    # a dense layer and a MoE layer behind a window and a global layer
+    "trinity": lambda: zoo.trinity_mini(
+        batch=N, n_layers=2, dense_layers=1, hidden=128, heads=2, kv_heads=1,
+        head_dim=128, window=1024, first_global=1, dense_width=64,
+        experts=16, top_k=2, held=2, expert_width=32, shared_width=32,
+        vocab=128),
+    # KDA + dense; KDA, KDA, MLA (192 / 128) with a MoE each
+    "kimi": lambda: zoo.kimi_linear(
+        batch=N, n_layers=4, hidden=64, heads=2, head_dim=16, kv_rank=32,
+        nope_dim=128, rope_dim=64, v_dim=128, dense_width=64, experts=16,
+        top_k=2, held=2, expert_width=32, shared_width=32, vocab=128),
 }
 PARENT = {       # sha256 of the text, its length, its pallas_call equations
     "olmoe": ("25c3770f48fbe6e7315504d351947292a5dcecef1a20bef3b4b89015be04"
@@ -44,6 +57,13 @@ PARENT = {       # sha256 of the text, its length, its pallas_call equations
              "1d48", 126180, 6),
     "zaya": ("69ea43acba1fee552fb6444330b4ca8d5085dc25495f70bc87860f9ec904"
              "582d", 181755, 6),
+    # taken on the parent of PR 41 (b1aa3cb), equal on it and on the change
+    "trinity": ("4e06dfad6cd019b941e49df31f6ce9b7e418a048cd2e7479fe59e68c19"
+                "98a984", 201662, 6),
+    # PR 41's own (no parent has it): moves with ops/kda.py and the KDA
+    # layers, and with nothing else
+    "kimi": ("8dd14883f0b98e6b1466e841389faf50bce76500f0b8edd06936654d40cb"
+             "3bd2", 1054788, 3),
 }
 
 

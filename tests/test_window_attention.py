@@ -134,3 +134,82 @@ def test_attention_route_names_the_band(monkeypatch):
     causal = pk.attention_route(8192, 8192, 128, 2, True)[1]
     assert "window" not in causal
     assert pk.attention_route(8192, 8192, 128, 2, True, 8192)[1] == causal
+
+
+# --------------------------------------------------------------------------- #
+# value heads of their own width (latent attention: 192-wide scores over
+# 128-wide values)
+# --------------------------------------------------------------------------- #
+
+def _qkv_two_widths(seed, s, d, dv, heads=2):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    widths = (d, d, dv, dv)
+    return [jax.random.normal(k, (1, heads, s, w), jnp.float32)
+            for k, w in zip(keys, widths)]
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("s,d,dv,blocks", [
+    (256, 192, 128, (128, 64)),      # the configuration's widths
+    (64, 24, 16, (16, 32)),
+    (64, 16, 24, (32, 16)),          # and values wider than keys
+])
+def test_flash_kernels_take_two_head_widths(what, s, d, dv, blocks):
+    """The three kernels (interpret mode) against the XLA arm, causal: q and
+    k of one width, v, the output and its cotangent of another; dq and dk
+    come back at the first, dv at the second."""
+    q, k, v, g = _qkv_two_widths(3, s, d, dv)
+    flash = lambda q, k, v: pk.flash_attention(q, k, v, True, None, *blocks,
+                                               True)
+    dense = lambda q, k, v: attention(q, k, v, causal=True)
+    if what == "out":
+        got, want = flash(q, k, v), dense(q, k, v)
+        assert got.shape == (1, 2, s, dv)
+    else:
+        i = ("dq", "dk", "dv").index(what)
+        got, want = (jax.grad(lambda *a, f=f: jnp.sum(f(*a) * g),
+                              argnums=i)(q, k, v) for f in (flash, dense))
+        assert got.shape == (q, k, v)[i].shape
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_tile_rule_and_route_with_two_widths(monkeypatch):
+    """``flash_blocks`` counts both widths: equal widths give the rule's old
+    answer whatever way they are passed; at 8,192 x 192 / 128 in bf16 all
+    three kernels keep 1024 x 1024; the route's note names the widths."""
+    for kernel in ("fwd", "dq", "dkv"):
+        for s, d in ((4096, 128), (8192, 128), (2048, 64), (136, 32)):
+            assert pk.flash_blocks(kernel, s, d, 2) \
+                == pk.flash_blocks(kernel, s, d, 2, d)
+            assert pk._flash_vmem_bytes(kernel, 512, 256, d, 2) \
+                == pk._flash_vmem_bytes(kernel, 512, 256, d, 2, d)
+        assert pk.flash_blocks(kernel, 8192, 192, 2, 128) == (1024, 1024)
+    # the widths are counted: f32 operands at 192 / 128 no longer fit the
+    # dK/dV sweep's 1024 x 1024 (they do at 128 / 128)
+    assert pk.flash_blocks("dkv", 8192, 192, 4, 128) == (512, 1024)
+    assert pk.flash_blocks("dkv", 8192, 128, 4) == (1024, 1024)
+    monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+    arm, note = pk.attention_route(8192, 8192, 192, 2, dv=128)
+    assert arm == "pallas_flash" and note.endswith("; flash d 192/128")
+    assert "flash d" not in pk.attention_route(8192, 8192, 128, 2, dv=128)[1]
+
+
+def test_shared_key_part_is_joined_to_every_head():
+    """``rope_attention`` with a fourth operand: each key head is its own
+    dims followed by the shared part; equal to attention over keys built by
+    hand, and the shared part's gradient is the sum over heads."""
+    from poseidon_tpu.models.transformer import rope_attention
+    key = jax.random.PRNGKey(7)
+    b, s, h, own, shared, dv = 1, 16, 4, 6, 2, 4
+    q = jax.random.normal(key, (b, s, h * (own + shared)))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, h * own))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, h * dv))
+    kpe = jax.random.normal(jax.random.fold_in(key, 3), (b, s, shared))
+    got = rope_attention(q, k, v, n_heads=h, rope=False, k_shared=kpe)
+    assert got.shape == (b, s, h * dv)
+    full = jnp.concatenate([k.reshape(b, s, h, own), jnp.broadcast_to(
+        kpe[:, :, None], (b, s, h, shared))], -1)
+    heads = lambda t: t.reshape(b, s, h, -1).swapaxes(1, 2)
+    want = attention(heads(q), full.swapaxes(1, 2), heads(v), causal=True)
+    np.testing.assert_allclose(got, want.swapaxes(1, 2).reshape(b, s, -1),
+                               rtol=1e-5, atol=1e-6)
