@@ -433,7 +433,11 @@ class Net:
             elif layer.TYPE == "KDA_SCAN":
                 from ..ops.kda import kda_route
                 what = "kda"
-                arm, note = kda_route(shape[1])[1], ""
+                h = layer.lp.kda_param.num_heads
+                arm, note = kda_route(
+                    shape[1], shape[2] // h,
+                    self.blob_shapes[layer.lp.bottom[2]][2] // h, h,
+                    jnp.dtype(policy().compute_dtype).itemsize)[1], ""
             elif layer.TYPE == "MOE":
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"

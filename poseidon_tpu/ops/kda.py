@@ -43,6 +43,16 @@ backwards with the transposed three products, group by group from the last;
 the derivative of the chunk-local parts is autodiff's. The state, the
 cumulative sums and the triangular system are f32 at HIGHEST precision
 whatever the compute policy.
+
+Two implementations of that one chunked form, chosen by ``kda_route`` from
+what the code can see (the backend and the shape; no switch): where the
+backend compiles Mosaic, 64 divides S and both head widths are multiples of
+128, ``ops/kda_pallas.py``'s two kernels (a program owns ``kda_blocks``'s m
+chunks of a head, the state stays in VMEM from a sequence's first chunk to
+its last, the chunk-local parts and their pullback never visit HBM, the
+operands are read in place); everywhere else (the CPU mesh, the tiny nets'
+16-wide heads, a shorter chunk) the ``jax.numpy`` form below, which is also
+what the kernels are read beside. ``kda_recurrence`` is the oracle of both.
 """
 
 from __future__ import annotations
@@ -72,12 +82,34 @@ def state_bytes(batch: int, s: int, heads: int, d_k: int, d_v: int) -> int:
     return batch * heads * (s // kda_chunk(s)) * d_k * d_v * 4
 
 
-def kda_route(s: int):
-    """``(arm, note)`` for a sequence length, as ``Net`` logs it."""
+def kda_route(s: int, d_k: Optional[int] = None, d_v: Optional[int] = None,
+              heads: int = 1, itemsize: int = 2):
+    """``(arm, note)`` for a sequence length and a head's widths, as ``Net``
+    logs it — THE routing decision, of the backend and the shape alone:
+    ``pallas`` (``ops/kda_pallas.py``) where the backend compiles Mosaic, 64
+    divides S and both widths are multiples of 128 (``kda_blocks``: the
+    chunks a program); else the ``jax.numpy`` chunked form below (the CPU
+    mesh, narrow heads, a shorter chunk); else the recurrence."""
     c = kda_chunk(s)
     if c is None:
         return "recurrence", f"token by token (no chunk divides S={s})"
+    m = _pallas_blocks(s, d_k, d_v, heads, itemsize)
+    if m:
+        return "pallas", (f"pallas (C {c} x {m}, {s // c} chunks, "
+                          f"f32 state in VMEM)")
     return "chunked", f"chunked C {c}, {s // c} chunks, f32 state"
+
+
+def _pallas_blocks(s, d_k, d_v, heads, itemsize) -> Optional[int]:
+    """Chunks a program of the Pallas arm, None where it does not run: no
+    widths given, a shape the kernels cannot take, or a backend that would
+    interpret them."""
+    if not (d_k and d_v):
+        return None
+    from .kda_pallas import kda_blocks
+    from .pallas_kernels import _interpret_default
+    m = kda_blocks(s, d_k, d_v, heads, itemsize)
+    return m if m and not _interpret_default() else None
 
 
 def _mm(a, b, spec):
@@ -312,6 +344,11 @@ def kda_scan(q, k, v, g, beta, scale: Optional[float] = None,
     a multiple of the sub-block that divides S). Where no chunk divides S
     the token-by-token recurrence runs."""
     scale = q.shape[-1] ** -0.5 if scale is None else float(scale)
+    m = None if chunk else _pallas_blocks(
+        q.shape[1], q.shape[-1], v.shape[-1], q.shape[2], q.dtype.itemsize)
+    if m:
+        from .kda_pallas import kda_scan_pallas
+        return kda_scan_pallas(q, k, v, g, beta, scale, m, False)
     chunk = kda_chunk(q.shape[1]) if chunk is None else chunk
     if chunk is None:
         return kda_recurrence(q, k, v, g, beta, scale).astype(v.dtype)

@@ -7,6 +7,7 @@ written-out loop; logits, loss, every gradient and one whole train step;
 the expert shares summing to the whole layer with the shared expert counted
 ONCE; what the run says it ran; the example prototxts."""
 
+import functools
 import importlib.util
 import os
 import re
@@ -18,7 +19,7 @@ import pytest
 
 from poseidon_tpu.core.net import Net
 from poseidon_tpu.models import zoo
-from poseidon_tpu.ops import kda
+from poseidon_tpu.ops import kda, kda_pallas
 from poseidon_tpu.proto.messages import load_net_from_string
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -211,6 +212,173 @@ def test_groups_of_chunks_carry_the_state(what, monkeypatch):
             argnums=(0, 1, 2, 3, 4))(*args)
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             assert rel(a, b) < 2e-5
+
+
+# --------------------------------------------------------------------------- #
+# the scan's Pallas arm (ops/kda_pallas.py), its kernels interpreted
+# --------------------------------------------------------------------------- #
+
+GRADS = ("dq", "dk", "dv", "dg", "dbeta")
+
+
+def pallas_scan(m=2):
+    return lambda *a: kda_pallas.kda_scan_pallas(
+        *a, a[0].shape[-1] ** -0.5, m, True)
+
+
+def values_and_grads(f, args, co):
+    """o and the five gradients of sum(o * co), one compile."""
+    o, pull = jax.vjp(f, *args)
+    return dict(zip(("o",) + GRADS, (o,) + pull(co.astype(o.dtype))))
+
+
+@pytest.fixture(scope="module")
+def pallas_against_recurrence():
+    """{strong: (the Pallas arm's, the recurrence's)} at the cell's head
+    width: B = 2, S = 256 = two programs of two chunks, H = 2, d 128."""
+    out = {}
+    for strong in (False, True):
+        args = operands(0, s=256, d_k=128, d_v=128, strong=strong)
+        if strong:
+            per_chunk = jnp.sum(args[3].reshape(2, 4, 64, 2, 128), 2)
+            assert float(jnp.min(per_chunk)) < -150
+        co = jnp.asarray(np.random.RandomState(1).randn(2, 256, 2, 128),
+                         jnp.float32)
+        out[strong] = tuple(
+            jax.jit(functools.partial(values_and_grads, f))(args, co)
+            for f in (pallas_scan(), kda.kda_recurrence))
+    return out
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
+@pytest.mark.parametrize("what", ("o",) + GRADS)
+def test_pallas_scan_equals_the_recurrence(what, strong,
+                                           pallas_against_recurrence):
+    """The two kernels (forward with the states it saves; backward with the
+    chunk-local pullback by hand) against the token-by-token recurrence and
+    autodiff through it, f32 to rounding; ``strong``: a channel's log-decay
+    sums below -150 inside a chunk and every value stays finite."""
+    got, want = pallas_against_recurrence[strong]
+    assert np.all(np.isfinite(np.asarray(got[what])))
+    assert got[what].shape == want[what].shape
+    assert rel(got[what], want[what]) < 2e-5
+
+
+def test_pallas_scan_is_the_chunked_form_on_bf16_operands():
+    """q, k, v as the compute policy hands them (bf16; g and beta f32): the
+    two implementations of the chunked form cast up the same values, so
+    they differ by f32 rounding and o's own bf16 rounding alone; the
+    gradients come back in the operands' types."""
+    args = operands(7, b=1, s=128, d_k=128, d_v=128)
+    args = [x.astype(jnp.bfloat16) for x in args[:3]] + args[3:]
+    co = jnp.asarray(np.random.RandomState(2).randn(1, 128, 2, 128),
+                     jnp.float32)
+    got, want = (values_and_grads(f, args, co)
+                 for f in (pallas_scan(), kda.kda_scan))
+    for name, x in zip(("o",) + GRADS, args[2:3] + args):
+        assert got[name].dtype == want[name].dtype == x.dtype
+        assert got[name].shape == want[name].shape
+        # one bf16 ulp where a value sits on a rounding boundary
+        assert rel(got[name].astype(jnp.float32),
+                   want[name].astype(jnp.float32)) < 3e-3, name
+    for name in ("dg", "dbeta"):
+        assert rel(got[name], want[name]) < 2e-4, name
+
+
+def test_pallas_sequences_of_a_batch_do_not_share_state():
+    """The state scratch is zeroed at every sequence's first program:
+    sequence 0 alone and beside another, bit-equal output and gradients;
+    the other sequence's operands take no gradient from it."""
+    args = operands(3, s=128, d_k=128, d_v=128)
+    alone = [x[:1] for x in args]
+    f = pallas_scan()
+
+    def first(*a):
+        return jnp.sum(f(*a)[0] ** 2)
+
+    np.testing.assert_array_equal(f(*args)[:1], f(*alone))
+    both = jax.grad(first, argnums=(0, 1, 2, 3, 4))(*args)
+    for g, g_alone in zip(both, jax.grad(first, argnums=(0, 1, 2, 3, 4))(
+            *alone)):
+        np.testing.assert_array_equal(g[:1], g_alone)
+        assert not np.any(np.asarray(g[1:]))
+
+
+def test_pallas_backward_keeps_one_state_a_chunk_and_replays_once():
+    """What the Pallas arm's backward keeps is what ``state_bytes`` says:
+    the five operands and ONE (d_v, d_k) f32 state a chunk a head; under the
+    traffic's one checkpoint a layer the gradient holds the forward kernel
+    twice (the forward and its ONE replay, which saves the states) and the
+    backward kernel once."""
+    args = operands(4, b=1, s=256, d_k=128, d_v=128)
+    _, res = jax.eval_shape(
+        lambda *a: kda_pallas._vjp_fwd(*a, 0.25, 2, True), *args)
+    assert [r.shape for r in res[:5]] == [a.shape for a in args]
+    assert res[5].shape == (1, 2, 4, 128, 128) \
+        and res[5].dtype == jnp.float32
+    assert res[5].size * 4 == kda.state_bytes(1, 256, 2, 128, 128)
+
+    def loss(*a):
+        return jnp.sum(jax.checkpoint(pallas_scan())(*a))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args))
+    assert text.count("name=kda_scan_fwd") == 2, text.count("kda_scan_fwd")
+    assert text.count("name=kda_scan_bwd") == 1
+
+
+@pytest.mark.parametrize("s,m", [(192, 1), (384, 2), (256, 4)])
+def test_pallas_chunks_a_program(s, m):
+    """One chunk a program (a tile of 64 rows), several (tiles of 128, the
+    state and its gradient crossing tiles and programs), and the rule where
+    S / 64 is no multiple of the four it would like: 3 chunks -> 1 a
+    program, 6 -> 2, 4 -> 4."""
+    assert kda_pallas.kda_blocks(s, 128, 128) == m
+    args = operands(8, b=1, s=s, h=1, d_k=128, d_v=128)
+    co = jnp.asarray(np.random.RandomState(3).randn(1, s, 1, 128),
+                     jnp.float32)
+    got, want = (values_and_grads(f, args, co)
+                 for f in (pallas_scan(m), kda.kda_recurrence))
+    for name in ("o",) + GRADS:
+        assert rel(got[name], want[name]) < 2e-5, name
+
+
+def test_pallas_blocks_rule():
+    """Of the shape and the VMEM budget alone: none where 64 does not
+    divide S or a width is no multiple of 128; fewer chunks a program where
+    wide heads' blocks would pass half the kernels' VMEM limit."""
+    assert kda_pallas.kda_blocks(8192, 128, 128, 32) == 4
+    assert kda_pallas.kda_blocks(96, 128, 128) is None
+    assert kda_pallas.kda_blocks(128, 16, 128) is None
+    assert kda_pallas.kda_blocks(128, 128, 64) is None
+    assert kda_pallas.kda_blocks(8192, 512, 512, 32) == 2
+    assert kda_pallas.kda_blocks(8192, 1024, 1024, 32) is None
+
+
+def test_route_by_backend_and_shape(monkeypatch):
+    """The Pallas arm where Mosaic compiles (here: the AOT-for-the-chip
+    switch) and the kernels take the shape; the ``jax.numpy`` arms'
+    notes letter for letter everywhere else."""
+    old = ("chunked", "chunked C 64, 128 chunks, f32 state")
+    assert kda.kda_route(8192) == old
+    assert kda.kda_route(8192, 128, 128, 32) == old          # the CPU mesh
+    monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+    assert kda.kda_route(8192, 128, 128, 32) == (
+        "pallas", "pallas (C 64 x 4, 128 chunks, f32 state in VMEM)")
+    assert kda.kda_route(192, 128, 128, 2) == (
+        "pallas", "pallas (C 64 x 1, 3 chunks, f32 state in VMEM)")
+    assert kda.kda_route(8192) == old                         # no widths
+    assert kda.kda_route(8192, 16, 16, 4) == old              # the tiny nets
+    assert kda.kda_route(96, 128, 128, 2) == (
+        "chunked", "chunked C 48, 2 chunks, f32 state")
+    assert kda.kda_route(200, 128, 128, 2)[0] == "recurrence"
+    net = build(n=1, s=128, heads=2, head_dim=128, v_dim=128, nope_dim=128,
+                rope_dim=64)
+    for i in KDA_LAYERS:
+        assert net.kernel_routes[f"l{i}_kda_scan"] == (
+            "kda=pallas (C 64 x 2, 2 chunks, f32 state in VMEM)")
+    assert net.recurrent_state()["l0_kda_scan"] == {
+        "heads": 2, "d_k": 128, "d_v": 128, "chunk": 64, "chunks": 2,
+        "saved_state_bytes": 2 * 2 * 128 * 128 * 4}
 
 
 def test_short_conv_is_the_written_out_loop():
