@@ -78,9 +78,11 @@ class Size:
                       ("pool5", (256, 256, 13)),
                       ("pool1 channel-minor", (4, 96, 55))])
         # one held MOE layer (tokens, top-k, experts routed / held, hidden,
-        # expert width): Trinity-Mini's, one of eight chips a layer
-        self.moe = ((64, 8, 16, 2, 32, 16) if tiny
-                    else (16384, 8, 128, 16, 2048, 1024))
+        # expert width) of each token cell whose ranks share the experts
+        self.moe = ({"tiny": (64, 8, 16, 2, 32, 16)} if tiny else
+                    {"kimi_linear": (8192, 8, 256, 8, 2304, 1024),
+                     "trinity": (16384, 8, 128, 16, 2048, 1024),
+                     "zaya1": (16384, 1, 16, 8, 2048, 2048)})
 
 
 # --------------------------------------------------------------------------- #
@@ -383,14 +385,21 @@ def check_kernels(size: Size) -> dict:
     return facts
 
 
-def check_held_ladder(size: Size) -> dict:
-    """One held MOE layer at Trinity-Mini's shape through ``expert_ffn``'s
-    ladder against the full rung alone, forward and every gradient, under
-    the bf16 policy on this device: a routing at the even share (the prefix
-    rung) and one forced one row over the prefix (the overflow path, which
-    runs the full rung's own equations). ``ragged_dot`` with rows past the
-    last group, the P-row scatter-add and the conditional are all lowered
-    for the chip here and nowhere on the CPU."""
+HELD_SHARES = (0.03, 0.06, 0.125, 0.25, 0.5, 1.0)
+
+
+def check_held_chunks(size: Size) -> dict:
+    """One held MOE layer at each token cell's shape through
+    ``expert_ffn``'s chunked loop against the straight-line arm over all
+    T k rows, forward and every gradient, under the bf16 policy on this
+    device: a routing at the even share and one a chunk and a row long (two
+    trips, the second all padding but one row). Then the milliseconds of
+    forward + backward at 3% to 100% of the assignments live, for the
+    rule's chunk length beside half the even share, the even share and
+    twice it: what the rule was chosen by (PERF.md, PR 43). ``ragged_dot``
+    with rows past the last group, the P-row scatter-adds and the loop with
+    a trip count of the run are all lowered for the chip here and nowhere
+    on the CPU."""
     import time
 
     import jax
@@ -399,38 +408,7 @@ def check_held_ladder(size: Size) -> dict:
     from poseidon_tpu.config import policy_scope
     from poseidon_tpu.models import moe
 
-    t, top_k, n_exp, n_held, d, f = size.moe
-    rows = t * top_k
-    prefix, full = moe.held_row_ladder(rows, n_held, n_exp)
-    check(full == rows and prefix < rows, f"no ladder at {size.moe}")
-    rs = np.random.RandomState(SEED)
     f32 = jnp.float32
-    x = jnp.asarray(rs.randn(t, d), jnp.bfloat16)
-    weights = jnp.asarray(rs.rand(t, top_k) + 0.1, f32)
-    gate, up = (jnp.asarray(rs.randn(n_held, f, d) * d ** -0.5, f32)
-                for _ in range(2))
-    down = jnp.asarray(rs.randn(n_held, d, f) * f ** -0.5, f32)
-    cot = jnp.asarray(rs.randn(t, d), f32)
-
-    def routing(live):
-        experts = rs.randint(n_held, n_exp, size=rows)
-        experts[rs.permutation(rows)[:live]] = rs.randint(0, n_held, live)
-        return moe.expert_sizes(jnp.asarray(experts.reshape(t, top_k)),
-                                n_exp)
-
-    def ladder(x, weights, gate, up, down, flat_e, sizes):
-        return moe.expert_ffn(x, weights, flat_e, sizes, gate, up, down)
-
-    def alone(x, weights, gate, up, down, flat_e, sizes):
-        here = flat_e < n_held
-        order = jnp.argsort(jnp.where(here, flat_e, n_held), stable=True)
-        return moe._held_rows(x, weights, here, order, sizes[:n_held], gate,
-                              up, down, rows)
-
-    def stepped(fn):
-        return jax.jit(jax.value_and_grad(
-            lambda *a: (lambda y: (jnp.sum(y.astype(f32) * cot), y))(fn(*a)),
-            argnums=(0, 1, 2, 3, 4), has_aux=True))
 
     def ms(fn, *args, calls=5):
         jax.block_until_ready(fn(*args))
@@ -438,19 +416,68 @@ def check_held_ladder(size: Size) -> dict:
         jax.block_until_ready([fn(*args) for _ in range(calls)])
         return (time.perf_counter() - t0) / calls * 1e3
 
-    facts = {"rows": rows, "prefix": prefix}
-    with policy_scope(compute_dtype=jnp.bfloat16):
-        both = {"ladder": stepped(ladder), "full rung alone": stepped(alone)}
-        text = both["ladder"].lower(x, weights, gate, up, down,
-                                    *routing(1)).as_text()
-        check("stablehlo.case" in text or "stablehlo.if" in text,
-              "the ladder traced no conditional")
+    def one(shape):
+        t, top_k, n_exp, n_held, d, f = shape
+        rows = t * top_k
+        chunk = moe.held_chunk_rows(rows, n_held, n_exp)
+        rs = np.random.RandomState(SEED)
+        x = jnp.asarray(rs.randn(t, d), jnp.bfloat16)
+        weights = jnp.asarray(rs.rand(t, top_k) + 0.1, f32)
+        gate, up = (jnp.asarray(rs.randn(n_held, f, d) * d ** -0.5, f32)
+                    for _ in range(2))
+        down = jnp.asarray(rs.randn(n_held, d, f) * f ** -0.5, f32)
+        cot = jnp.asarray(rs.randn(t, d), f32)
+
+        def routing(live):
+            experts = rs.randint(n_held, n_exp, size=rows)
+            experts[rs.permutation(rows)[:live]] = rs.randint(0, n_held, live)
+            return moe.expert_sizes(jnp.asarray(experts.reshape(t, top_k)),
+                                    n_exp)
+
+        def by_held_expert(flat_e):
+            here = flat_e < n_held
+            return here, jnp.argsort(jnp.where(here, flat_e, n_held),
+                                     stable=True)
+
+        def rule(x, weights, gate, up, down, flat_e, sizes):
+            return moe.expert_ffn(x, weights, flat_e, sizes, gate, up, down)
+
+        def chunks_of(p):
+            def loop(x, weights, gate, up, down, flat_e, sizes):
+                return moe._held_chunks(p, x, weights, gate, up, down,
+                                        by_held_expert(flat_e)[1],
+                                        sizes[:n_held])
+            return loop
+
+        def straight(x, weights, gate, up, down, flat_e, sizes):
+            # every T k row gathered, multiplied and brought back by the
+            # inverse permutation: no loop, autodiff's derivative
+            here, order = by_held_expert(flat_e)
+            return moe._held_rows(x, weights, here, order, sizes[:n_held],
+                                  gate, up, down)
+
+        def stepped(fn):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: (lambda y: (jnp.sum(y.astype(f32) * cot), y))(
+                    fn(*a)), argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+        facts = {"rows": rows, "chunk": chunk,
+                 "loop": moe.held_rows_loop(rows, chunk)}
+        both = {"chunks": stepped(chunks_of(chunk)),
+                "straight line": stepped(straight)}
+        # what expert_ffn itself traces at this shape: the loop from three
+        # chunks on, straight-line rows below (ZAYA1), never a conditional
+        text = stepped(rule).lower(x, weights, gate, up, down,
+                                   *routing(1)).as_text()
+        check(("stablehlo.while" in text) == facts["loop"],
+              f"the held arm's loop at {shape}: not as held_rows_loop says")
+        check("stablehlo.case" not in text and "stablehlo.if" not in text,
+              "the held arm traced a conditional")
         for case, live in (("even share", rows * n_held // n_exp),
-                           ("one row over", prefix + 1)):
+                           ("over a chunk", min(chunk + 1, rows))):
             args = (x, weights, gate, up, down) + routing(live)
-            got = {k: fn(*args) for k, fn in both.items()}
-            (_, y), grads = got["ladder"]
-            (_, y0), grads0 = got["full rung alone"]
+            (_, y), grads = both["chunks"](*args)
+            (_, y0), grads0 = both["straight line"](*args)
             for name, a, b in zip(("y", "dx", "dweights", "dgate", "dup",
                                    "ddown"), (y,) + grads, (y0,) + grads0):
                 a, b = (np.asarray(v, np.float32) for v in (a, b))
@@ -459,12 +486,30 @@ def check_held_ladder(size: Size) -> dict:
                 facts[f"{case}: {name} largest difference"] = float(
                     np.max(np.abs(a - b)))
                 facts[f"{case}: {name} relative l2"] = rel
-                check(np.any(b) and rel <= 4e-3,
-                      f"held ladder, {case}: {name} differs from the full "
-                      f"rung by {rel} (relative L2)")
-            for k, fn in both.items():
-                facts[f"{case}: {k} ms"] = ms(fn, *args)
-    return facts
+                # dx, dgate and dup keep in f32 what autodiff rounds to
+                # bf16: a bf16 ulp apart at most (tests/test_moe.py)
+                check(np.any(b) and rel <= 2.0 ** -7,
+                      f"held chunks, {case}: {name} differs from the "
+                      f"straight line by {rel} (relative L2)")
+            facts[f"{case}: straight line ms"] = ms(both["straight line"],
+                                                    *args)
+        # the rule's length beside half the even share, the even share
+        # and twice it
+        tile = moe._ROW_TILE
+        even = -(-rows * n_held // (n_exp * tile)) * tile
+        lengths = sorted({max(even // 2 // tile, 1) * tile, even,
+                          min(2 * even, -(-rows // tile) * tile), chunk})
+        routings = {share: routing(int(share * rows))
+                    for share in HELD_SHARES}
+        for p in lengths:
+            fn = both["chunks"] if p == chunk else stepped(chunks_of(p))
+            facts[f"ms at chunk {p}"] = {
+                str(share): ms(fn, x, weights, gate, up, down, *routed)
+                for share, routed in routings.items()}
+        return facts
+
+    with policy_scope(compute_dtype=jnp.bfloat16):
+        return {name: one(shape) for name, shape in size.moe.items()}
 
 
 def child_cold(size: Size) -> dict:
@@ -534,8 +579,8 @@ def child_cold(size: Size) -> dict:
     # 5. the kernels, one by one, against their XLA arms
     phases["kernels"] = check_kernels(size)
 
-    # 6. a held MOE layer through both rungs of its ladder
-    phases["held_ladder"] = check_held_ladder(size)
+    # 6. a held MOE layer's chunked loop at the token cells' shapes
+    phases["held_chunks"] = check_held_chunks(size)
     result["resume_from"] = snap
     result["net"] = net
     result["xla_entries_at_end"] = cache_entries(cache)

@@ -442,13 +442,12 @@ class Net:
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"
                 arm, note = GROUPED_MATMUL, "sorted by expert, dropless"
-                rungs = self._held_rungs(layer)
-                if len(rungs) > 1:
-                    # a share of the experts held: the row work runs over
-                    # a prefix of the sorted assignments while it holds
-                    # every live row (stats.yaml: prefix_hit_share)
-                    arm += (f"; held rows: prefix {rungs[0]} of "
-                            f"{rungs[-1]}, full on overflow")
+                held = self._held_rows(layer)
+                if held:
+                    # a share of the experts held: the row work runs in
+                    # chunks of the sorted assignments, as many trips as
+                    # the live rows need (stats.yaml: held_row_fill)
+                    arm += f"; held rows: chunks of {held[0]} of {held[2]}"
             else:
                 continue
             if arm == "pallas" and note:
@@ -486,24 +485,31 @@ class Net:
                 if chunk else 0}
         return out
 
-    def _held_rungs(self, layer: Layer) -> Tuple[int, ...]:
-        """The prefix lengths a MOE layer's held arm may run its row work
-        over (``models/moe.held_row_ladder``); one = no ladder."""
-        from ..models.moe import held_row_ladder
+    def _held_rows(self, layer: Layer) -> Optional[Tuple[int, int, int]]:
+        """(the chunk of sorted rows a trip of a MOE layer's held arm takes,
+        ``models/moe.held_chunk_rows``; twice the even share of its T k
+        assignments, in whole row tiles; T k), or None where the layer
+        holds every expert or runs its rows as straight-line code
+        (``models/moe.held_rows_loop``)."""
+        from ..models.moe import _ROW_TILE, held_chunk_rows, held_rows_loop
         n, s, _ = self.blob_shapes[layer.lp.bottom[0]]
         mp = layer.lp.moe_param
-        return held_row_ladder(n * s * mp.top_k, layer.held, mp.num_experts)
+        rows = n * s * mp.top_k
+        chunk = held_chunk_rows(rows, layer.held, mp.num_experts)
+        if not held_rows_loop(rows, chunk):     # every expert held: one chunk
+            return None
+        twice = -(-2 * rows * layer.held // (mp.num_experts * _ROW_TILE))
+        return chunk, twice * _ROW_TILE, rows
 
-    def held_row_ladders(self) -> Dict[str, Tuple[int, int]]:
-        """{a MOE layer's held-share top: (prefix rows, all T k rows)} for
-        the layers whose held arm runs a ladder and that publish their held
-        share: what a display's reader needs to say which rung a step
-        took."""
-        ladders = {l.lp.top[l.n_fixed + 2]: self._held_rungs(l)
-                   for l in self.layers
-                   if l.TYPE == "MOE" and len(l.lp.top) >= l.n_fixed + 3}
-        return {top: rungs for top, rungs in ladders.items()
-                if len(rungs) > 1}
+    def held_row_ladders(self) -> Dict[str, Tuple[int, int, int]]:
+        """{a MOE layer's held-share top: (chunk rows, twice the even share,
+        all T k rows)} for the layers whose held rows run in chunks and that
+        publish their held share: what a display's reader needs to count
+        the trips a step's held arm made and the rows they ran."""
+        held = {l.lp.top[l.n_fixed + 2]: self._held_rows(l)
+                for l in self.layers
+                if l.TYPE == "MOE" and len(l.lp.top) >= l.n_fixed + 3}
+        return {top: rows for top, rows in held.items() if rows}
 
     def conv_strategy_plan(self) -> Dict[str, Optional[str]]:
         """{conv layer name: resolved strategy} — what bench/tests print."""

@@ -239,24 +239,30 @@ def expert_sizes(experts: jax.Array, n_exp: int):
 
 
 # Rows of the sorted assignments are handed to the grouped matmuls in
-# multiples of this many: the MXU's 128 rows, what the prefix rung of the
-# held arm is rounded up to.
+# multiples of this many: the MXU's 128 rows, what a chunk of the held arm
+# is rounded up to.
 _ROW_TILE = 128
 
 
-def held_row_ladder(rows: int, n_held: int, n_exp: int):
-    """The static prefix lengths ("rungs") over which ``expert_ffn``'s held
-    arm may run its row work, shortest first; the last is always all
-    ``rows`` = T k sorted assignments. A rule of the shapes and nothing
-    else: the first rung is TWICE the even share of a rank that holds
-    ``n_held`` of ``n_exp`` experts, rounded up to ``_ROW_TILE``; where
-    that is all the rows or more (half the experts held: ZAYA1) the ladder
-    is the single full rung and no conditional is traced. (A middle rung at
-    four times the even share was tried for Trinity-Mini, whose first MoE
-    layer holds 35-40% of the assignments: the step then compiles at 15.23
-    GB, over the 85% it is sized by. PERF.md, PR 37.)"""
-    first = -(-2 * rows * n_held // n_exp // _ROW_TILE) * _ROW_TILE
-    return (first, rows) if first < rows else (rows,)
+# A trip of the held arm's loop costs two scatter-adds into (T, D) and a
+# read-add-write of the three stacks' gradient sums whatever its length:
+# 2.5-3.5 ms on the v5e at the cells' shapes, the row work of some 8,000
+# rows (PERF.md, PR 43). No chunk is shorter.
+_CHUNK_FLOOR = 8192
+
+
+def held_chunk_rows(rows: int, n_held: int, n_exp: int) -> int:
+    """The chunk length P of ``expert_ffn``'s held arm: how many of the
+    ``rows`` = T k sorted assignments one trip of its loop takes. A rule of
+    the shapes and nothing else: the EVEN share of a rank that holds
+    ``n_held`` of ``n_exp`` experts, no less than ``_CHUNK_FLOOR`` and no
+    more than all the rows, in whole ``_ROW_TILE``s (8,192 of
+    Kimi-Linear's 65,536, where the even share is 2,048; 16,384 of
+    Trinity-Mini's 131,072; 8,192 of ZAYA1's 16,384). Half the even share,
+    the even share and twice it were measured at the three cells' shapes
+    before the floor was set (PERF.md, PR 43)."""
+    even = -(-rows * n_held // n_exp)
+    return -(-min(max(even, _CHUNK_FLOOR), rows) // _ROW_TILE) * _ROW_TILE
 
 
 def _combine(out, order, weights, dtype):
@@ -268,52 +274,6 @@ def _combine(out, order, weights, dtype):
     y = jnp.sum(out[back].reshape(t, top_k, -1).astype(jnp.float32)
                 * weights[..., None], axis=1)
     return y.astype(dtype)
-
-
-def _held_rows(x, weights, here, order, sizes, gate, up, down, rows):
-    """The held arm's row work over the first ``rows`` of the sorted
-    assignments (``order``: the held experts' rows first, by expert; absent
-    ones behind them), as an f32-summed (T, D) in x's dtype. ``sizes`` are
-    the held experts' (G,), ``here`` (T*k,) says which assignments fell on
-    one. Exact whenever the live rows, ``sum(sizes)``, number at most
-    ``rows``.
-
-    ``rows`` = T k is the whole sort: the inverse permutation brings every
-    assignment's result back to its token and the k are summed over an
-    axis. A shorter prefix touches nothing of T k rows times a feature
-    width: P rows are gathered, multiplied, and scatter-added, weighted,
-    into the tokens' f32 sums (the same k terms in another order)."""
-    t, d = x.shape
-    top_k = weights.shape[1]
-    # rows past the last group belong to no expert: what a grouped
-    # matmul leaves there is masked on the way in (so is their
-    # cotangent) and on the way out
-    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
-
-    def grouped(rows_, w):
-        return jnp.where(live, _grouped(jnp.where(live, rows_, 0), w,
-                                        sizes), 0)
-
-    full = rows == t * top_k
-    head = order if full else order[:rows]
-    tok = head // top_k
-    xs = x[tok]                                     # (rows, D)
-    h = jax.nn.silu(grouped(xs, gate)) * grouped(xs, up)
-    out = grouped(h, down)                          # (rows, D)
-    weights = weights * here.reshape(t, top_k)
-    if full:
-        return _combine(out, order, weights, x.dtype)
-    y = jnp.zeros((t, d), jnp.float32).at[tok].add(
-        out.astype(jnp.float32) * weights.reshape(-1)[head][:, None])
-    return y.astype(x.dtype)
-
-
-# A rung's body as the ladder calls it: a function of its own in the
-# program, so that the layers of one shape, the forward's and the backward's
-# conditionals share ONE trace and one lowering of each rung (on the chip's
-# host the ladder lowers in +0.9 s over the parent's arm this way, +2.9 s
-# traced in place; the compiler inlines the calls: PERF.md, PR 37)
-_held_rows_jit = jax.jit(_held_rows, static_argnums=8)
 
 
 def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
@@ -334,17 +294,16 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
     of the grouped matmuls (no work) and add ZERO to y, so that the shares
     of the ranks sum to the whole layer's output.
 
-    The held arm's row work (gather, masks, activation, combine) runs over
-    a PREFIX of the sorted rows chosen at run time by the live count:
-    ``held_row_ladder`` gives the static lengths from T k, G and E alone
-    (twice the even share, then everything), a ``lax.cond`` takes the
-    shortest rung that holds every live row, and the full rung is the
-    overflow path that keeps the layer dropless for any routing. With one
-    rung (G / E >= 1/2) no conditional is traced. The ladder's derivative
-    is written out (``_held_ladder``, a ``custom_vjp``): the forward saves
-    its inputs and the backward differentiates the rung taken alone, since
-    autodiff through the conditional keeps the residuals of both rungs."""
-    t, d = x.shape
+    The held arm's row work (gather, grouped matmuls, masks, activation,
+    combine) runs in chunks of P = ``held_chunk_rows(T k, G, E)`` sorted
+    rows under a loop whose trip count is the live rows' (``_held_chunks``):
+    ``ceil(live / P)`` trips, up to ``ceil(T k / P)``, so the cost follows
+    the share of the assignments this rank really holds and the layer is
+    dropless for any routing. No array of T k rows times a feature width
+    exists; what is left of T k are the sort and its vectors of scalars.
+    Where two chunks at most hold every row (half the experts held: ZAYA1)
+    there is nothing for a loop to skip, and the rows run as straight-line
+    code (``_held_rows``): a rule of the shapes, as the chunk is."""
     top_k = weights.shape[1]
     n_exp, n_held = sizes.shape[0], gate.shape[0]
     if n_held == n_exp:
@@ -356,56 +315,188 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
     here = (local >= 0) & (local < n_held)
     order = jnp.argsort(jnp.where(here, local, n_held), stable=True)
     sizes = sizes[held_first:held_first + n_held]
-    rungs = held_row_ladder(t * top_k, n_held, n_exp)
-    if len(rungs) == 1:
-        return _held_rows(x, weights, here, order, sizes, gate, up, down,
-                          rungs[0])
-    return _held_ladder(rungs, x, weights, gate, up, down, here, order,
-                        sizes)
+    chunk = held_chunk_rows(order.shape[0], n_held, n_exp)
+    if held_rows_loop(order.shape[0], chunk):
+        return _held_chunks(chunk, x, weights, gate, up, down, order, sizes)
+    return _held_rows(x, weights, here, order, sizes, gate, up, down)
 
 
-def _take_rung(rungs, sizes, branch, *operands):
-    """``branch(rows)(*operands)`` for the shortest rung that holds every
-    live row."""
-    prefix, full = rungs
-    return lax.cond(jnp.sum(sizes) <= prefix, branch(prefix), branch(full),
-                    *operands)
+def held_rows_loop(rows: int, chunk: int) -> bool:
+    """Whether the held arm's rows run under the loop: from three chunks
+    on. With one or two (ZAYA1: 8 of 16 experts held, 40% of the rows live
+    in the mean and up to 70%) the loop has at most one trip to skip and
+    pays for it: its cell ran 3.9% slower and its step compiled 1.27 GB
+    larger than with the straight-line rows (PERF.md, PR 43)."""
+    return -(-rows // chunk) > 2
+
+
+def _held_rows(x, weights, here, order, sizes, gate, up, down):
+    """The held arm's row work over ALL T k sorted assignments, as
+    straight-line code that autodiff differentiates: every row gathered
+    and multiplied, each assignment's result brought back to its token by
+    the inverse permutation and the k summed over an axis. ``here`` (T*k,)
+    says which assignments fell on a held expert."""
+    t, top_k = weights.shape
+    # rows past the last group belong to no expert: what a grouped
+    # matmul leaves there is masked on the way in (so is their
+    # cotangent) and on the way out
+    live = (jnp.arange(t * top_k) < jnp.sum(sizes))[:, None]
+
+    def grouped(rows_, w):
+        return jnp.where(live, _grouped(jnp.where(live, rows_, 0), w,
+                                        sizes), 0)
+
+    tok = order // top_k
+    xs = x[tok]                                     # (T*k, D)
+    h = jax.nn.silu(grouped(xs, gate)) * grouped(xs, up)
+    out = grouped(h, down)                          # (T*k, D)
+    weights = weights * here.reshape(t, top_k)
+    return _combine(out, order, weights, x.dtype)
+
+
+def _chunk(i, chunk, x, weights, order, sizes, ends, gate_t, up_t):
+    """Trip ``i`` of the held arm as far as the activation: rows
+    [i P, i P + P) of the sorted assignments ``order`` (padded to a whole
+    number of chunks), the held experts' groups clipped to them (``ends``:
+    the running sum of ``sizes``), and x's rows through ``gate_t`` and
+    ``up_t`` (G, D, F), already in the compute dtype. Returns (grouped,
+    groups, tok, head, xs, a, b, h): ``grouped(rows, w)`` is this chunk's
+    grouped matmul with w (G, K, N); rows at or past the live count belong
+    to no expert, and what a grouped matmul leaves there is masked on the
+    way in and on the way out."""
+    lo = i * chunk
+    head = lax.dynamic_slice(order, (lo,), (chunk,))
+    groups = (jnp.clip(ends, lo, lo + chunk)
+              - jnp.clip(ends - sizes, lo, lo + chunk))
+    live = (lo + jnp.arange(chunk) < ends[-1])[:, None]
+    prec = matmul_precision()
+
+    def grouped(rows, w):
+        return jnp.where(live, lax.ragged_dot(
+            jnp.where(live, rows, 0), w, groups, precision=prec), 0)
+
+    tok = head // weights.shape[1]
+    xs = x[tok].astype(gate_t.dtype)                # (P, D)
+    a, b = grouped(xs, gate_t), grouped(xs, up_t)
+    return grouped, groups, tok, head, xs, a, b, jax.nn.silu(a) * b
+
+
+def _trips(order, sizes, chunk):
+    """(``order`` padded with zeros to a whole number of chunks, so that a
+    trip's slice is always in range; the held experts' running sizes; the
+    trip count ceil(live / P), a value of the run)."""
+    ends = jnp.cumsum(sizes)
+    pad = -order.shape[0] % chunk
+    return jnp.pad(order, (0, pad)), ends, -(-ends[-1] // chunk)
+
+
+# The loops are functions of their own in the program, so that the layers
+# of one shape share ONE trace and one lowering of each (the compiler
+# inlines the calls). The compute dtype is an argument because the policy
+# is read while tracing and is no part of a jitted function's key.
+@partial(jax.jit, static_argnums=(0, 1))
+def _held_chunks_fwd(chunk, cdtype, x, weights, order, sizes, gate, up, down):
+    f32 = jnp.float32
+    order, ends, n = _trips(order, sizes, chunk)
+    # the stacks' casts and transposes are made once a pass, not once a trip
+    gate_t, up_t, down_t = (jnp.swapaxes(w.astype(cdtype), 1, 2)
+                            for w in (gate, up, down))
+
+    def trip(i, y):
+        grouped, _, tok, head, _, _, _, h = _chunk(
+            i, chunk, x, weights, order, sizes, ends, gate_t, up_t)
+        out = grouped(h, down_t)                    # (P, D)
+        return y.at[tok].add(out.astype(f32)
+                             * weights.reshape(-1)[head][:, None])
+
+    y = lax.fori_loop(0, n, trip, jnp.zeros(x.shape, f32))
+    return y.astype(x.dtype)
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _held_chunks_bwd(chunk, cdtype, x, weights, order, sizes, gate, up, down,
+                     dy):
+    f32 = jnp.float32
+    order, ends, n = _trips(order, sizes, chunk)
+    gate_c, up_c, down_c = (w.astype(cdtype) for w in (gate, up, down))
+    gate_t, up_t = jnp.swapaxes(gate_c, 1, 2), jnp.swapaxes(up_c, 1, 2)
+    prec = matmul_precision()
+
+    def trip(i, carry):
+        dx, dweights, dgate, dup, ddown = carry
+        grouped, groups, tok, head, xs, a, b, h = _chunk(
+            i, chunk, x, weights, order, sizes, ends, gate_t, up_t)
+
+        def dw(dys, rows):                          # (G, N, K), as stored
+            return lax.ragged_dot_general(dys, rows, groups, _DW_DIMS,
+                                          precision=prec).astype(f32)
+
+        dyr = dy[tok]                               # (P, D)
+        w = weights.reshape(-1)[head][:, None]
+        # y's row is w (h down^T): its pullback onto h is w (dy down), and
+        # onto w the product of h with the same dy down, so the chunk's
+        # ``out`` is not computed again
+        u = grouped(dyr.astype(cdtype), down_c).astype(f32)     # (P, F)
+        # a dead row's u is zero, so it adds nothing where the padding
+        # points
+        dweights = dweights.at[head].add(
+            jnp.sum(h.astype(f32) * u, axis=-1))
+        ddown = ddown + dw((dyr.astype(f32) * w).astype(cdtype), h)
+        dh = u * w
+        a32, b32 = a.astype(f32), b.astype(f32)
+        s = jax.nn.sigmoid(a32)
+        da = (dh * b32 * s * (1 + a32 * (1 - s))).astype(cdtype)
+        db = (dh * a32 * s).astype(cdtype)
+        dgate, dup = dgate + dw(da, xs), dup + dw(db, xs)
+        dxs = (grouped(da, gate_c).astype(f32)
+               + grouped(db, up_c).astype(f32))
+        return dx.at[tok].add(dxs), dweights, dgate, dup, ddown
+
+    dx, dweights, dgate, dup, ddown = lax.fori_loop(0, n, trip, (
+        jnp.zeros(x.shape, f32), jnp.zeros(order.shape, f32),
+        *(jnp.zeros(w.shape, f32) for w in (gate, up, down))))
+    # a stack's gradient is rounded to the compute dtype, as the cotangent
+    # of its cast is, and behind a barrier: the narrow copy is then what
+    # lives until the update, where the compiler would keep the f32 sums
+    # and round them there (0.8 GB of Trinity-Mini's step: PR 43)
+    narrow = lax.optimization_barrier(tuple(
+        g.astype(cdtype) for g in (dgate, dup, ddown)))
+    return (dx.astype(x.dtype),
+            dweights[:weights.size].reshape(weights.shape)
+            .astype(weights.dtype),
+            *(g.astype(w.dtype) for g, w in zip(narrow, (gate, up, down))))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_ladder(rungs, x, weights, gate, up, down, here, order, sizes):
-    """``_held_rows`` over the rung the live count picks. Its derivative is
-    written out for the memory's sake: autodiff through the conditional
-    keeps BOTH rungs' residuals (zeros for the one not taken), and
-    Trinity-Mini's step at the cell's batch then no longer fits the chip
-    (PERF.md, PR 37). The forward saves what is live anyway; the backward
-    takes the same rung and differentiates it alone, its forward once more
-    inside the branch (prefix-sized where the prefix rung was taken)."""
-    return _take_rung(rungs, sizes,
-                      lambda rows: lambda *a: _held_rows_jit(*a, rows),
-                      x, weights, here, order, sizes, gate, up, down)
+def _held_chunks(chunk, x, weights, gate, up, down, order, sizes):
+    """The held arm's row work: y (T, D) in x's dtype, the f32 sum of every
+    live assignment's weighted expert output, computed ``chunk`` sorted rows
+    a trip. ``order`` (T*k,) holds the held experts' rows first, by expert
+    (absent ones behind them), ``sizes`` (G,) the held experts' counts.
+
+    A loop whose trip count is a value of the run has no reverse
+    derivative, so it is written out: the forward saves its inputs alone,
+    and the backward makes the same trips, each recomputing its chunk's
+    activations, adding its rows' dx and dweights into (T, D) and (T k,) f32
+    sums and its share of the stacks' gradients into f32 sums in the
+    STORED (G, N, K) orientation (``_DW_DIMS``: PR 30)."""
+    return _held_chunks_fwd(chunk, jnp.dtype(policy().compute_dtype), x,
+                            weights, order, sizes, gate, up, down)
 
 
-def _held_ladder_fwd(rungs, x, weights, gate, up, down, here, order, sizes):
-    return (_held_ladder(rungs, x, weights, gate, up, down, here, order,
-                         sizes),
-            (x, weights, gate, up, down, here, order, sizes))
+def _held_chunks_vjp_fwd(chunk, x, weights, gate, up, down, order, sizes):
+    return (_held_chunks(chunk, x, weights, gate, up, down, order, sizes),
+            (x, weights, gate, up, down, order, sizes))
 
 
-def _held_ladder_bwd(rungs, res, dy):
-    *primals, here, order, sizes = res
-
-    def branch(rows):
-        def bwd(dy, *primals):
-            return jax.vjp(lambda x, weights, gate, up, down: _held_rows_jit(
-                x, weights, here, order, sizes, gate, up, down, rows),
-                *primals)[1](dy)
-        return bwd
-
-    return _take_rung(rungs, sizes, branch, dy, *primals) + (None,) * 3
+def _held_chunks_vjp_bwd(chunk, res, dy):
+    x, weights, gate, up, down, order, sizes = res
+    return _held_chunks_bwd(chunk, jnp.dtype(policy().compute_dtype), x,
+                            weights, order, sizes, gate, up, down,
+                            dy) + (None, None)
 
 
-_held_ladder.defvjp(_held_ladder_fwd, _held_ladder_bwd)
+_held_chunks.defvjp(_held_chunks_vjp_fwd, _held_chunks_vjp_bwd)
 
 
 def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,

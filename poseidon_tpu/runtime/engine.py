@@ -498,8 +498,9 @@ class Engine:
         recurrent_state = self.train_net.recurrent_state()
         if recurrent_state:
             self.stats.set_section("recurrent_state", recurrent_state)
-        # which rung of its ladder a held MOE layer's step took is read off
-        # the held share it displays (_absorb): {top: (prefix, rows)}
+        # how many chunks of its sorted rows a held MOE layer's step ran is
+        # read off the held share it displays (_absorb):
+        # {top: (chunk, twice the even share, rows)}
         self._held_ladders = self.train_net.held_row_ladders()
         self.stats.set_section("data_reader", {
             p.tops[0]: ("native" if getattr(p, "native", None) is not None
@@ -1167,13 +1168,20 @@ class Engine:
         for row_it, row in rows:
             self.metrics.accumulate(row)
             last = row
-            for top, (prefix, total) in self._held_ladders.items():
-                if top in row:
-                    # the live rows are the share times T k, exactly: the
-                    # prefix rung ran iff they fit it (models/moe)
-                    self.stats.add("held_layer_steps")
-                    self.stats.add("held_prefix_hits",
-                                   round(row[top] * total) <= prefix)
+            for top, (chunk, prefix, total) in self._held_ladders.items():
+                if top not in row:
+                    continue
+                # the live rows are the share times T k, exactly, and the
+                # held arm ran the chunks that hold them (models/moe)
+                live = round(row[top] * total)
+                trips = -(-live // chunk)
+                self.stats.add("held_chunk_trips", trips)
+                self.stats.add("held_rows_run", trips * chunk)
+                self.stats.add("held_rows_live", live)
+                # a fact of the routing: the layer-steps whose live rows
+                # number at most twice the even share
+                self.stats.add("held_layer_steps")
+                self.stats.add("held_prefix_hits", live <= prefix)
             if self._displays and self._displays[0][0] == row_it + 1:
                 self._display(*self._displays.popleft())
         return last
@@ -1201,9 +1209,12 @@ class Engine:
             if k not in ("iter", "time"):
                 self.stats.set_gauge(f"train_{k}", round(v, 6))
         if self._held_ladders:
-            # layer-steps that ran the prefix rung over layer-steps
-            # displayed so far (the counters beside it hold both counts)
+            # so far (the counters beside them hold the counts): live rows
+            # over the rows the held arms' chunks ran (1 = no padding), and
+            # layer-steps within twice the even share over layer-steps
             done = self.stats.counters
+            self.stats.set_gauge("held_row_fill", round(
+                done["held_rows_live"] / max(done["held_rows_run"], 1.0), 6))
             self.stats.set_gauge("prefix_hit_share", round(
                 done["held_prefix_hits"]
                 / max(done["held_layer_steps"], 1.0), 6))

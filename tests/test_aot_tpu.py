@@ -478,7 +478,7 @@ def test_trinity_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
     grid visits 64), the global layer's on the causal one; k and v reach
     the 32 query heads by a repeat of 8; the selection biases are leaves
     like any other (weight and two moments in the arguments); each MOE
-    layer's held rows run over the prefix rung of a two-rung ladder."""
+    layer's held rows run in chunks of 16,384 under one loop a pass."""
     import json
     r = subprocess.run(
         [sys.executable, "-c", _TRINITY_STEP.format(repo=REPO, deeper=more)],
@@ -502,25 +502,26 @@ def test_trinity_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
         + "; window 2048: the band's grid); 4 kv heads repeated x8",
         "attention=pallas_flash (" + tiles.format("36/64")
         + "); 4 kv heads repeated x8; no positions",
-        "grouped_matmul=ragged_dot; held rows: prefix 32768 of 131072, full "
-        "on overflow"]
-    # 4 flash calls a layer. A MoE layer's held arm is a ladder of two rungs
-    # (PR 37) and the compiled step holds both wherever it holds one: the
-    # forward, its replay and the backward (which runs its rung's forward
-    # once more) are a conditional each, so 2 x (3 + 3 + 3 + 6) = 30 grouped
-    # matmuls and 2 x 4 group-metadata calls (the parent's single rung: 12
-    # and 3)
-    assert got["pallas_custom_calls"] == 4 * 5 + 38 * 4
+        f"grouped_matmul=ragged_dot; held rows: chunks of {8192 * (2 + more)}"
+        f" of {65536 * (2 + more)}"]
+    # 4 flash calls a layer. A MoE layer's held arm is one loop a pass (PR
+    # 43): the forward's and its replay's hold 3 grouped matmuls and 1
+    # group-metadata call each, the backward's 8 and 2 (the chunk's a and
+    # b again, dy down, three weight gradients, two dx products): 18 where
+    # PR 37's two-rung ladder held 38
+    assert got["pallas_custom_calls"] == 4 * 5 + 18 * 4
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more:
-        assert got["total_gb"] > 0.85 * 16.9   # 16.27 (PR 36), 15.10 (PR 37)
+        # 14.95 = 88.5% (PR 43); 16.27 (PR 36), 15.10 (PR 37)
+        assert got["total_gb"] > 0.85 * 16.9
     else:
-        # 14.19 (PR 36); 14.227 = 84.2% with the ladder (PR 37; 14.352 with
-        # the rungs traced in place, 14.578 with the whole ladder a called
-        # function: the same equations, another packing of the heap).
-        # Autodiff THROUGH the conditional (both rungs' residuals kept) does
-        # not compile at all: 15.88 of 15.75 GB
+        # 13.223 = 78.2% with the chunked loop (PR 43; temporaries 4.76 GB);
+        # 14.19 (PR 36), 14.227 with the ladder (PR 37: what the cell's
+        # `why` quotes). The loop's f32 gradient sums handed to the update
+        # as they are compile at 15.64 GB: the compiler fuses the narrowing
+        # cast into the update and keeps the wide sums until then
+        # (`_held_chunks_bwd`'s barrier)
         assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9
 
 
@@ -547,13 +548,14 @@ assert _KIMI_STEP.count("kimi") == 1 and "ouro_2" not in _KIMI_STEP
 def test_kimi_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
     """At one sequence of 8,192 the step with one checkpoint a layer is
     under 85% of the 16.9 GB the compiler allows (PR 22's sizing rule); at
-    two it is over. The four KDA layers' recurrences are the scan's Pallas
+    two it was over until PR 43. The four KDA layers' recurrences are the scan's Pallas
     kernels (128 chunks of 64, four a program, the f32 state in VMEM: the
     forward, its replay and the backward, one call each a layer), the one
     MLA layer's three flash kernels
     run at 192-wide scores over 128-wide values on the causal grid, the
     shared key part repeated to the 32 heads; each MOE layer's held rows
-    run over the prefix rung of a two-rung ladder."""
+    run in chunks of 8,192 under one loop a pass. (Since PR 43 two
+    sequences compile under the rule too: "no larger" no longer holds.)"""
     import json
     r = subprocess.run(
         [sys.executable, "-c", _KIMI_STEP.format(repo=REPO, deeper=more)],
@@ -579,23 +581,27 @@ def test_kimi_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
         "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
         "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
         "head; flash d 192/128); no positions; k_pe repeated x32",
-        f"grouped_matmul=ragged_dot; held rows: prefix {rows // 16} of "
-        f"{rows}, full on overflow",
+        f"grouped_matmul=ragged_dot; held rows: chunks of 8192 of {rows}",
         "kda=pallas (C 64 x 4, 128 chunks, f32 state in VMEM)"]
-    # 4 flash calls in the MLA layer, 30 grouped matmuls and group-metadata
-    # calls a MoE layer (the ladder's two rungs: see the Trinity test), and
-    # a KDA layer's scan three times: forward, the replay, backward
-    assert got["pallas_custom_calls"] == 4 + 30 * 4 + 3 * 4
+    # 4 flash calls in the MLA layer; a MoE layer's held arm is one loop a
+    # pass (see the Trinity test): 4 calls in the forward's, 10 in the
+    # backward's, and no replay (nothing in the layer needs the MoE's
+    # output again); a KDA layer's scan three times: forward, the replay,
+    # backward
+    assert got["pallas_custom_calls"] == 4 + 14 * 4 + 3 * 4
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more:
-        # 15.02 = 88.8% with the scan's kernels (PR 42: no group's parts, no
-        # transposed copies; temporaries 7.79 GB); 17.0 with the jax.numpy
-        # scan (PR 41)
-        assert 0.85 * 16.9 < got["total_gb"] < 0.93 * 16.9
+        # 12.87 = 76.2% with the chunked loop (PR 43; temporaries 5.64 GB):
+        # the second sequence now PASSES the sizing rule (PERF.md section 7,
+        # 43d: the cell's batch is the benchmark's to raise); 15.02 = 88.8%
+        # with the ladder (PR 42), 17.0 with the jax.numpy scan (PR 41)
+        assert 0.70 * 16.9 < got["total_gb"] < 0.85 * 16.9
     else:
-        # 12.08 = 71.5% (PR 42; temporaries 4.85 GB); 13.00 (PR 41)
-        assert 0.66 * 16.9 < got["total_gb"] < 0.77 * 16.9
+        # 10.818 = 64.0% (PR 43; temporaries 3.59 GB); 12.08 (PR 42, which
+        # the ladder's full rung cost 1.26 GB of); 13.00 (PR 41: what the
+        # cell's `why` quotes)
+        assert 0.60 * 16.9 < got["total_gb"] < 0.70 * 16.9
 
 
 

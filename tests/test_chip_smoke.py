@@ -50,7 +50,7 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
         result = json.load(f)
     cold, warm = result["cold"], result["warm"]
     assert cold["cache_dir"] == str(cache)
-    assert sorted(cold["phases"]) == ["held_ladder", "kernels", "resume",
+    assert sorted(cold["phases"]) == ["held_chunks", "kernels", "resume",
                                       "train_bf16", "train_f32",
                                       "train_sfb_auto"]
     assert cold["phases"]["train_bf16"]["steps"] == 24
@@ -59,9 +59,13 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
     assert warm["phases"]["warm_resume"]["compiled_step"]["source"] == \
         "loaded"
     assert len(cold["phases"]["kernels"]) == 11   # 35 at full size
-    ladder = cold["phases"]["held_ladder"]         # both rungs, both ways
-    assert (ladder["rows"], ladder["prefix"]) == (512, 128)
-    assert sum("relative l2" in k for k in ladder) == 12
+    held = cold["phases"]["held_chunks"]["tiny"]   # one trip and two
+    assert (held["rows"], held["chunk"], held["loop"]) == (512, 512, False)
+    assert sum("relative l2" in k for k in held) == 12
+    assert sorted(k for k in held if k.startswith("ms at chunk")) == [
+        "ms at chunk 128", "ms at chunk 256", "ms at chunk 512"]
+    assert sorted(held["ms at chunk 256"]) == ["0.03", "0.06", "0.125",
+                                               "0.25", "0.5", "1.0"]
     assert sorted(os.listdir(cache / "aot"))   # the step store rode along
 
 

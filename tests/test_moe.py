@@ -135,12 +135,23 @@ def test_moe_remat_gradients_match():
 
 
 # --------------------------------------------------------------------------- #
-# expert_ffn's held arm: the ladder of prefix lengths against the full rung
+# expert_ffn's held arm: chunks of the sorted rows under a loop whose trip
+# count is the live rows', against the straight-line arm over all T k rows
 # --------------------------------------------------------------------------- #
 
 E_ALL, G_HELD, D_X, F_X = 16, 2, 32, 16
 TK = 512                        # T * k assignments in every case below
-P0 = 128                        # twice the even share, TK * 2 * 2 / 16
+P0 = 128                        # a chunk: the even share TK * 2 / 16, up to
+#                                 a row tile, with the floor lowered to it
+
+
+@pytest.fixture
+def short_chunks(monkeypatch):
+    """The rule's floor (8,192 rows) lowered to a row tile, so that
+    ``expert_ffn`` itself cuts these 512 rows into four chunks."""
+    from poseidon_tpu.models import moe
+    monkeypatch.setattr(moe, "_CHUNK_FLOOR", P0)
+    assert moe.held_chunk_rows(TK, G_HELD, E_ALL) == P0
 
 
 def _routing(top_k, live, held_first, seed=0):
@@ -163,17 +174,44 @@ def _routing(top_k, live, held_first, seed=0):
             jnp.asarray(rs.randn(G_HELD, D_X, F_X) * 0.3, f32))
 
 
-def _value_and_grads(args, held_first, dtype):
+def _sorted_by_held_expert(flat_e, held_first, n_held):
+    local = flat_e - held_first
+    here = (local >= 0) & (local < n_held)
+    return here, jnp.argsort(jnp.where(here, local, n_held), stable=True)
+
+
+def _straight_line(x, weights, flat_e, sizes, gate, up, down, held_first=0):
+    """The held arm with no loop, the reference the chunks are held to: ALL
+    T k sorted rows gathered, multiplied (rows past the last group masked on
+    the way in and out), brought back to their tokens by the inverse
+    permutation and the k summed in f32. Autodiff differentiates it."""
+    from poseidon_tpu.models import moe
+    n_held = gate.shape[0]
+    t, top_k = weights.shape
+    here, order = _sorted_by_held_expert(flat_e, held_first, n_held)
+    sizes = sizes[held_first:held_first + n_held]
+    live = (jnp.arange(t * top_k) < jnp.sum(sizes))[:, None]
+
+    def grouped(rows, w):
+        return jnp.where(live, moe._grouped(jnp.where(live, rows, 0), w,
+                                            sizes), 0)
+
+    xs = x[order // top_k]
+    h = jax.nn.silu(grouped(xs, gate)) * grouped(xs, up)
+    return moe._combine(grouped(h, down), order,
+                        weights * here.reshape(t, top_k), x.dtype)
+
+
+def _value_and_grads(ffn, args, held_first, dtype):
     """y and the gradients of sum(y * cot) with respect to x, weights, gate,
     up, down, under jit, grad and jax.checkpoint as a layer's remat unit
     runs it."""
     from poseidon_tpu.config import policy_scope
-    from poseidon_tpu.models.moe import expert_ffn
     x, weights, flat_e, sizes, gate, up, down = args
     cot = jnp.asarray(np.random.RandomState(1).randn(*x.shape), jnp.float32)
 
     def f(x, weights, gate, up, down):
-        y = jax.checkpoint(lambda *a: expert_ffn(
+        y = jax.checkpoint(lambda *a: ffn(
             a[0].astype(dtype), a[1], flat_e, sizes, *a[2:],
             held_first=held_first))(x, weights, gate, up, down)
         return jnp.sum(y.astype(jnp.float32) * cot), y
@@ -190,76 +228,80 @@ def _rel(a, b):
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
-LADDER_CASES = {          # (top-k, live rows, held_first)
-    "no_live_row": (8, 0, 0), "below_the_rung": (8, 50, 0),
-    "at_the_rung": (8, P0, 0), "one_over_the_rung": (8, P0 + 1, 0),
-    "twice_the_rung": (8, 2 * P0, 0),
+LADDER_CASES = {          # (top-k, live rows, held_first); P0 rows a chunk
+    "no_live_row": (8, 0, 0), "below_a_chunk": (8, 50, 0),
+    "exactly_a_chunk": (8, P0, 0), "a_chunk_and_a_row": (8, P0 + 1, 0),
+    "two_chunks": (8, 2 * P0, 0), "three_chunks_and_a_row": (8, 3 * P0 + 1, 0),
     "every_row_live": (8, TK, 0), "held_first_6": (8, 90, 6),
-    "held_first_14_over": (8, 300, 14), "top_1": (1, 100, 0),
-    "top_1_over": (1, 200, 3),
+    # two experts of about 150 rows: the second chunk splits both groups
+    "held_first_14_splits_a_group": (8, 300, 14), "top_1": (1, 100, 0),
+    "top_1_over": (1, 200, 3), "top_1_every_row_live": (1, TK, 0),
 }
 
 
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
 @pytest.mark.parametrize("case", sorted(LADDER_CASES))
-def test_held_ladder_equals_the_full_rung(case, dtype, monkeypatch):
-    """The ladder (prefix rung at twice the even share, full rung on
-    overflow) against the full rung alone: y and every gradient. A k-term
-    f32 sum in another order: 1e-6 relative in f32, a bf16 ulp's share
-    under the bf16 policy. Over the rung the ladder runs the full rung's own
-    equations (the test below), compiled inside a conditional: the compiler
-    fuses them otherwise, so equal to rounding and not to the bit."""
+def test_held_chunks_equal_the_straight_line_arm(case, dtype, short_chunks):
+    """The chunked loop against the straight-line arm over all T k rows: y
+    and every gradient. In f32 the same terms in another order: 1e-6
+    relative. Under the bf16 policy y is the straight line's to the bit;
+    the gradients are not: the loop keeps in f32 what autodiff rounds to
+    bf16 (the activation's derivative, the sum of a row's two dx products
+    and of a token's k), pulls y's row back through ``dy down`` where
+    autodiff recomputes ``h down^T``, and sums a stack's gradient over the
+    chunks in f32 before it is rounded to bf16 once. Within a bf16 ulp of
+    the straight line, and as near the f32 result as the straight line is
+    (to a quarter: both round a few times in bf16)."""
     from poseidon_tpu.models import moe
     top_k, live, held_first = LADDER_CASES[case]
-    assert moe.held_row_ladder(TK, G_HELD, E_ALL) == (P0, TK)
     args = _routing(top_k, live, held_first)
     assert int(jnp.sum(args[3][held_first:held_first + G_HELD])) == live
     dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
-    got = _value_and_grads(args, held_first, dt)
-    monkeypatch.setattr(moe, "held_row_ladder", lambda rows, g, e: (rows,))
-    want = _value_and_grads(args, held_first, dt)
-    tol = {"f32": 1e-6, "bf16": 4e-3}[dtype]
-    for name, a, b in zip(("y", "dx", "dweights", "dgate", "dup", "ddown"),
-                          got, want):
+    got = _value_and_grads(moe.expert_ffn, args, held_first, dt)
+    want = _value_and_grads(_straight_line, args, held_first, dt)
+    exact = want if dtype == "f32" else _value_and_grads(
+        _straight_line, args, held_first, jnp.float32)
+    tol = {"f32": 1e-6, "bf16": 2.0 ** -7}[dtype]
+    for name, a, b, c in zip(("y", "dx", "dweights", "dgate", "dup", "ddown"),
+                             got, want, exact):
         assert a.dtype == b.dtype and a.shape == b.shape, name
         assert _rel(a, b) <= tol, (name, _rel(a, b))
-        if live and name != "dweights":
-            assert np.any(np.asarray(a, np.float32)), name
+        assert _rel(a, c) <= 1.25 * _rel(b, c) + 1e-6, name
+        assert bool(np.any(np.asarray(a, np.float32))) == bool(live), name
 
 
-def test_held_ladder_is_a_rule_of_the_shapes():
-    """Twice the even share, rounded up to the row tile; a single full rung
-    (no conditional) from half the experts held."""
-    from poseidon_tpu.models.moe import held_row_ladder
-    assert held_row_ladder(131072, 16, 128) == (32768, 131072)   # Trinity
-    assert held_row_ladder(16384, 8, 16) == (16384,)             # ZAYA1
-    assert held_row_ladder(1000, 1, 16) == (128, 1000)
-    assert held_row_ladder(1024, 3, 16) == (384, 1024)
-    assert held_row_ladder(512, 4, 16) == (256, 512)
-    assert held_row_ladder(256, 7, 16) == (256,)
+def test_held_chunk_is_a_rule_of_the_shapes():
+    """The even share of the T k assignments, no less than the floor and no
+    more than all the rows, in whole row tiles: the three cells' shapes and
+    the small ones."""
+    from poseidon_tpu.models.moe import held_chunk_rows
+    assert held_chunk_rows(65536, 8, 256) == 8192      # Kimi-Linear: floor
+    assert held_chunk_rows(131072, 16, 128) == 16384   # Trinity: even share
+    assert held_chunk_rows(16384, 8, 16) == 8192       # ZAYA1: both
+    assert held_chunk_rows(196608, 16, 128) == 24576   # Trinity, 3 sequences
+    assert held_chunk_rows(100000, 3, 16) == 18816     # 18,750 up to a tile
+    assert held_chunk_rows(8192, 1, 16) == 8192        # every row: one chunk
+    assert held_chunk_rows(1000, 1, 16) == 1024        # one chunk, padded
+    assert held_chunk_rows(512, 4, 16) == 512
+    assert held_chunk_rows(100, 15, 16) == 128
 
 
-def test_full_rung_of_the_ladder_is_the_held_arm_alone():
-    """One two-branch conditional on the live count; the branch taken on
-    overflow is, equation for equation, what a single-rung layer traces
-    (ZAYA1's program, the parent's held arm)."""
+def test_two_chunks_at_most_run_as_straight_line_rows():
+    """The loop from three chunks on: with one or two (the rule's own
+    floor at these 512 rows; ZAYA1's 8 of 16 experts at the cell's shape)
+    ``expert_ffn`` traces, equation for equation, the straight-line arm
+    this file holds the chunks to, and no loop."""
     from poseidon_tpu.models import moe
+    assert [moe.held_rows_loop(r, c) for r, c in (
+        (65536, 8192), (131072, 16384), (16384, 8192), (16385, 8192),
+        (512, 512), (512, 128))] == [True, True, False, True, False, True]
+    assert not moe.held_rows_loop(TK, moe.held_chunk_rows(TK, G_HELD, E_ALL))
     x, weights, flat_e, sizes, gate, up, down = _routing(8, 50, 0)
-    jaxpr = jax.make_jaxpr(lambda *a: moe.expert_ffn(
+    got, want = (jax.make_jaxpr(lambda *a: f(
         a[0], a[1], flat_e, sizes, *a[2:]))(x, weights, gate, up, down)
-    conds = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "cond"]
-    assert len(conds) == 1 and len(conds[0].params["branches"]) == 2
-    here = flat_e < G_HELD
-    order = jnp.argsort(jnp.where(here, flat_e, G_HELD), stable=True)
-    alone = jax.make_jaxpr(lambda *a: moe._held_rows(*a, TK))(
-        x, weights, here, order, sizes[:G_HELD], gate, up, down)
-    # each branch is ONE call of the rung's body (a jitted function, so
-    # that every layer and pass shares its trace and lowering)
-    full, prefix = ([e.params["jaxpr"].jaxpr for e in b.jaxpr.eqns]
-                    for b in conds[0].params["branches"])   # False, True
-    assert len(full) == len(prefix) == 1
-    assert str(full[0]) == str(alone.jaxpr)
-    assert str(prefix[0]) != str(alone.jaxpr)
+        for f in (moe.expert_ffn, _straight_line))
+    assert str(got) == str(want)
+    assert "while" not in [e.primitive.name for e in _eqns(got.jaxpr)]
 
 
 def _eqns(jaxpr):
@@ -276,27 +318,97 @@ def _avals(jaxpr):
                 yield v.aval
 
 
-@pytest.mark.parametrize("pass_", ["forward", "gradient"])
-def test_prefix_rung_holds_no_array_of_all_the_rows(pass_):
-    """The prefix branch's jaxpr (and its gradient's) holds no array of T k
-    rows with a trailing feature axis: what is left of T k are vectors of
-    scalars (the sort key, ``order``, ``here``) and (T, k) weights. The full
-    rung, traced the same way, does hold them."""
+def _held_jaxpr(pass_, ffn=None):
     from poseidon_tpu.models import moe
     x, weights, flat_e, sizes, gate, up, down = _routing(8, 50, 0)
-    here = flat_e < G_HELD
-    order = jnp.argsort(jnp.where(here, flat_e, G_HELD), stable=True)
 
-    def wide(rows):
-        def f(x, weights, gate, up, down):
-            return jnp.sum(moe._held_rows(x, weights, here, order,
-                                          sizes[:G_HELD], gate, up, down,
-                                          rows).astype(jnp.float32))
-        fn = f if pass_ == "forward" else jax.grad(f, argnums=(0, 1, 2, 3, 4))
-        jaxpr = jax.make_jaxpr(fn)(x, weights, gate, up, down).jaxpr
-        return [a.shape for a in _avals(jaxpr) if len(a.shape) >= 2
-                and a.shape[-1] in (D_X, F_X)
-                and int(np.prod(a.shape[:-1])) == TK]
+    def f(x, weights, gate, up, down):
+        return jnp.sum((ffn or moe.expert_ffn)(
+            x, weights, flat_e, sizes, gate, up, down).astype(jnp.float32))
 
-    assert wide(P0) == []
-    assert (TK, D_X) in wide(TK)
+    fn = f if pass_ == "forward" else jax.grad(f, argnums=(0, 1, 2, 3, 4))
+    return jax.make_jaxpr(fn)(x, weights, gate, up, down).jaxpr
+
+
+def _wide(jaxpr):
+    """Shapes of T k rows times a feature width anywhere in the jaxpr."""
+    return [a.shape for a in _avals(jaxpr) if len(a.shape) >= 2
+            and a.shape[-1] in (D_X, F_X)
+            and int(np.prod(a.shape[:-1])) == TK]
+
+
+@pytest.mark.parametrize("pass_", ["forward", "gradient"])
+def test_held_arm_is_one_loop_over_chunks(pass_, short_chunks):
+    """One ``while`` a pass and no conditional: the forward's loop in the
+    forward's jaxpr; the gradient's holds the backward's and, until dead
+    code goes, the forward's beside it. Neither holds an array of T k rows
+    with a trailing feature axis: what is left of T k are vectors of scalars
+    (the sort key, ``order``, the weights' gradient) and (T, k) weights. The
+    straight-line arm, traced the same way, does hold them."""
+    jaxpr = _held_jaxpr(pass_)
+    names = [e.primitive.name for e in _eqns(jaxpr)]
+    assert "cond" not in names
+    assert names.count("while") == {"forward": 1, "gradient": 2}[pass_]
+    # the trip count is a value of the run: no loop is unrolled or scanned
+    assert "scan" not in names
+    assert _wide(jaxpr) == []
+    assert (TK, D_X) in _wide(_held_jaxpr(pass_, _straight_line))
+
+
+@pytest.mark.parametrize("pass_", ["forward", "gradient"])
+def test_stacks_are_cast_outside_the_loop(pass_, short_chunks):
+    """The stacks reach the loop's body in the compute dtype, cast and
+    transposed once a pass: no equation inside a ``while`` converts or
+    transposes an array of a stack's size."""
+    from poseidon_tpu.config import policy_scope
+    with policy_scope(compute_dtype=jnp.bfloat16):
+        jaxpr = _held_jaxpr(pass_)
+    stack = G_HELD * F_X * D_X
+    casts = {"inside": [], "outside": []}
+
+    def walk(jaxpr, where):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            # to the compute dtype: a chunk's share of a stack's gradient
+            # goes the other way, to the f32 sum, inside the loop
+            if name in ("convert_element_type", "transpose") and any(
+                    int(np.prod(v.aval.shape)) == stack
+                    and len(v.aval.shape) == 3
+                    and v.aval.dtype == jnp.bfloat16 for v in eqn.outvars):
+                casts[where].append(name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, "inside" if name == "while" else where)
+
+    walk(jaxpr, "outside")
+    assert casts["inside"] == []
+    # forward: three stacks cast and transposed; the backward's casts serve
+    # both orientations
+    assert casts["outside"].count("convert_element_type") >= 3
+    assert casts["outside"].count("transpose") >= 3
+
+
+def test_arm_that_holds_every_expert_is_untouched():
+    """G = E (OLMoE): the sort, one gather of all T k rows, three grouped
+    matmuls and the inverse permutation, equation for equation; no loop."""
+    from poseidon_tpu.models import moe
+    x, weights, flat_e, sizes, *_ = _routing(8, 50, 0)
+    rs = np.random.RandomState(3)
+    gate, up = (jnp.asarray(rs.randn(E_ALL, F_X, D_X), jnp.float32)
+                for _ in range(2))
+    down = jnp.asarray(rs.randn(E_ALL, D_X, F_X), jnp.float32)
+
+    def parents(x, weights, gate, up, down):
+        order = jnp.argsort(flat_e, stable=True)
+        xs = x[order // weights.shape[1]]
+        h = jax.nn.silu(moe._grouped(xs, gate, sizes)) \
+            * moe._grouped(xs, up, sizes)
+        return moe._combine(moe._grouped(h, down, sizes), order, weights,
+                            x.dtype)
+
+    def ours(x, weights, gate, up, down):
+        return moe.expert_ffn(x, weights, flat_e, sizes, gate, up, down)
+
+    got, want = (jax.make_jaxpr(f)(x, weights, gate, up, down)
+                 for f in (ours, parents))
+    assert str(got) == str(want)
+    assert "while" not in [e.primitive.name for e in _eqns(got.jaxpr)]

@@ -1,6 +1,7 @@
 """The token configurations that share the flash kernels, the grouped
-matmuls and the routers (OLMoE, Ouro, ZAYA1; since PR 41 Trinity-Mini, whose
-hash was taken on PR 41's parent, and Kimi-Linear's own) trace to the
+matmuls and the routers (OLMoE, Ouro, ZAYA1; since PR 41 Trinity-Mini and
+Kimi-Linear, whose hashes are PR 43's own: it changed their held arm) trace
+to the
 program they traced to before Trinity's window, sigmoid router and per-head
 norm, and before Kimi-Linear's two head widths in the flash kernels,
 arrived: the gradient's whole jaxpr at a small size, as lowered for the TPU
@@ -57,13 +58,15 @@ PARENT = {       # sha256 of the text, its length, its pallas_call equations
              "1d48", 126180, 6),
     "zaya": ("69ea43acba1fee552fb6444330b4ca8d5085dc25495f70bc87860f9ec904"
              "582d", 181755, 6),
-    # taken on the parent of PR 41 (b1aa3cb), equal on it and on the change
-    "trinity": ("4e06dfad6cd019b941e49df31f6ce9b7e418a048cd2e7479fe59e68c19"
-                "98a984", 201662, 6),
-    # PR 41's own (no parent has it): moves with ops/kda.py and the KDA
-    # layers, and with nothing else
-    "kimi": ("8dd14883f0b98e6b1466e841389faf50bce76500f0b8edd06936654d40cb"
-             "3bd2", 1054788, 3),
+    # PR 43's own (the held arm changed: at this size, 4,096 assignments,
+    # one chunk holds every row and the rows run as straight-line code
+    # where PR 37's two-rung ladder ran; ZAYA1's, above, is the parent's)
+    "trinity": ("d585d8bea6f40d1163dfe51cefbe012ac86f511c8100fffc577bd58e7d"
+                "4dd7b3", 149616, 6),
+    # PR 43's own likewise: moves with ops/kda.py, the KDA layers and the
+    # held arm, and with nothing else
+    "kimi": ("9bdcb9bc42efc615f3803c633d2f5a4bc1d7747b6ebfb674c0722604e860"
+             "6a83", 1005313, 3),
 }
 
 
@@ -101,23 +104,27 @@ def test_a_window_does_change_the_text(monkeypatch):
     assert traced("olmoe_window") != traced("olmoe")
 
 
-def _conditionals(jaxpr):
+def _control_flow(jaxpr):
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "cond":
-            yield len(eqn.params["branches"])
+        if eqn.primitive.name in ("cond", "while", "scan"):
+            yield eqn.primitive.name
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _conditionals(sub)
+            yield from _control_flow(sub)
 
 
 @pytest.mark.parametrize("held, per_layer", [(2, 1), (8, 0)])
-def test_a_held_share_under_half_traces_one_conditional_a_moe_layer(
-        held, per_layer):
-    """A small Trinity-Mini net (1 dense + 2 MoE layers, top-4 of 16): with
-    an eighth of the experts held each MOE layer's forward holds ONE
-    two-branch conditional (``expert_ffn``'s ladder: the prefix rung, the
-    full rung) and the gradient one more (the backward takes the same rung);
-    with half held the ladder is a single rung and no conditional is traced,
-    which is why ZAYA1's hash above stands."""
+def test_a_held_share_under_half_traces_one_loop_a_moe_layer(
+        held, per_layer, monkeypatch):
+    """A small Trinity-Mini net (1 dense + 2 MoE layers, top-4 of 16, the
+    chunk rule's floor lowered to a row tile): with an eighth of the
+    experts held each MOE layer's forward holds ONE ``while``
+    (``expert_ffn``'s chunks of the sorted rows, as many trips as the live
+    rows need) and the gradient one more (the backward makes the same
+    trips); with half held two chunks hold every row, the rows run as
+    straight-line code and no loop is traced, which is why ZAYA1's hash
+    above stands. No conditional either way."""
+    from poseidon_tpu.models import moe
+    monkeypatch.setattr(moe, "_CHUNK_FLOOR", 128)
     n, s = 1, 128
     net = Net(load_net_from_string(zoo.to_prototxt(zoo.trinity_mini(
         batch=n, n_layers=3, dense_layers=1, hidden=64, heads=4, kv_heads=2,
@@ -131,8 +138,8 @@ def test_a_held_share_under_half_traces_one_conditional_a_moe_layer(
     def loss(p, b):
         return net.apply(p, b, train=True).loss
 
-    forward = list(_conditionals(jax.make_jaxpr(loss)(params, batch).jaxpr))
-    assert forward == [2] * (2 * per_layer)
-    both = list(_conditionals(
+    forward = list(_control_flow(jax.make_jaxpr(loss)(params, batch).jaxpr))
+    assert forward == ["while"] * (2 * per_layer)
+    both = list(_control_flow(
         jax.make_jaxpr(jax.grad(loss))(params, batch).jaxpr))
-    assert both == [2] * (4 * per_layer)
+    assert both == ["while"] * (4 * per_layer)

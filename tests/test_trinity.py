@@ -451,28 +451,39 @@ def _job(tmp_path, max_iter, snapshot=0, held=HELD, n=N, **sizes):
     return load_solver(str(tmp_path / "solver.prototxt")), batch
 
 
-def test_held_row_ladder_is_named_and_counted(tmp_path):
+def test_held_row_ladder_is_named_and_counted(tmp_path, monkeypatch):
     """2 of 16 experts held, top-2 of 256 tokens: the MOE layers'
-    ``kernel_routes`` note names the ladder (prefix = twice the even share,
-    full on overflow), and ``stats.yaml`` holds ``prefix_hit_share`` =
-    layer-steps that ran the prefix rung over layer-steps displayed, from a
-    routing on each side of the rung, forced through the routers' biases:
-    a step with no assignment on a held expert (the prefix rung), one with
-    every assignment on one (the full rung, nothing dropped), and back.
-    Half the experts held: one rung, no note, no counter."""
+    ``kernel_routes`` note names the chunks the held rows run in, and
+    ``stats.yaml`` counts, from every step's displayed held shares, the
+    trips the held arms made (``held_chunk_trips``), the rows those ran
+    (``held_rows_run``) and the live rows among them (gauge
+    ``held_row_fill`` = live / run), beside ``prefix_hit_share`` =
+    layer-steps whose live rows number at most twice the even share over
+    layer-steps displayed. Routings on each side of that, forced through
+    the routers' biases: a step with no assignment on a held expert (no
+    trip), one with every assignment on one (every chunk, nothing dropped),
+    back, and one the routers choose themselves (a chunk partly filled).
+    Half the experts held: two chunks hold every row, the rows run as
+    straight-line code: no note, no counter."""
+    from poseidon_tpu.models import moe
     from poseidon_tpu.runtime.engine import Engine
     from poseidon_tpu.runtime.metrics import read_stats_yaml
+    # the rule's floor (8,192 rows) lowered to a row tile: these 512 rows
+    # are then cut at the even share, as the cells' are
+    monkeypatch.setattr(moe, "_CHUNK_FLOOR", 128)
     assert "held rows" not in build().kernel_routes["l1_moe"]
     assert build().held_row_ladders() == {}
     n, k = 4, 2
-    sp, _ = _job(tmp_path, max_iter=3, held=2, n=n, top_k=k)
-    prefix, rows = 128, n * S * k
+    sp, _ = _job(tmp_path, max_iter=4, held=2, n=n, top_k=k)
+    chunk, prefix, rows = 128, 128, n * S * k
 
     def stats():
         doc = read_stats_yaml(str(tmp_path / "out" / "stats.yaml"))
-        return [float(doc["gauges"]["prefix_hit_share"])] + [
-            float(doc["counters"][c]) for c in
-            ("held_layer_steps", "held_prefix_hits")]
+        return ([float(doc["gauges"][g]) for g in
+                 ("prefix_hit_share", "held_row_fill")]
+                + [float(doc["counters"][c]) for c in
+                   ("held_layer_steps", "held_prefix_hits",
+                    "held_chunk_trips", "held_rows_run")])
 
     eng = Engine(sp, output_dir=str(tmp_path / "out"), mesh=make_mesh(1))
 
@@ -488,14 +499,15 @@ def test_held_row_ladder_is_named_and_counted(tmp_path):
         routes = eng.stats.snapshot()["sections"]["kernel_routes"]
         for i in MOE_LAYERS:
             assert routes[f"l{i}_moe"] == (
-                f"grouped_matmul=ragged_dot; held rows: prefix {prefix} of "
-                f"{rows}, full on overflow")
+                f"grouped_matmul=ragged_dot; held rows: chunks of {chunk} "
+                f"of {rows}")
         assert eng.train_net.held_row_ladders() == {
-            f"l{i}_held_share": (prefix, rows) for i in MOE_LAYERS}
+            f"l{i}_held_share": (chunk, prefix, rows) for i in MOE_LAYERS}
         for offsets, share, want in (
-                ((-10.0, -10.0), 0.0, [1.0, 4.0, 4.0]),
-                ((10.0, 10.0), 1.0, [0.5, 8.0, 4.0]),
-                ((-10.0, -10.0), 0.0, [round(2 / 3, 6), 12.0, 8.0])):
+                ((-10.0, -10.0), 0.0, [1.0, 0.0, 4.0, 4.0, 0.0, 0.0]),
+                ((10.0, 10.0), 1.0, [0.5, 1.0, 8.0, 4.0, 16.0, 2048.0]),
+                ((-10.0, -10.0), 0.0,
+                 [round(2 / 3, 6), 1.0, 12.0, 8.0, 16.0, 2048.0])):
             bias(*offsets)
             eng.train(max_iter=eng.iteration() + 1)
             row = eng.metrics.rows[-1]
@@ -504,6 +516,21 @@ def test_held_row_ladder_is_named_and_counted(tmp_path):
                 assert row[f"l{i}_dropped"] == 0.0
             assert np.isfinite(row["loss"])
             assert stats() == want
+        # the routers' own choice: the two held experts get some of the
+        # assignments, so a layer's trips end in a chunk partly filled
+        bias(0.0, 0.0)
+        eng.train(max_iter=eng.iteration() + 1)
+        row = eng.metrics.rows[-1]
+        live = [round(row[f"l{i}_held_share"] * rows) for i in MOE_LAYERS]
+        assert all(v < rows for v in live) and any(
+            v % chunk for v in live)
+        trips = [-(-v // chunk) for v in live]
+        hits, fill, steps, within, made, run = stats()
+        assert (steps, within) == (16.0, 8.0 + sum(
+            v <= prefix for v in live)) and hits == round(within / steps, 6)
+        assert (made, run) == (16.0 + sum(trips),
+                               2048.0 + chunk * sum(trips))
+        assert fill == round((2048.0 + sum(live)) / run, 6) and fill < 1.0
     finally:
         eng.close()
 
