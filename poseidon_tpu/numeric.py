@@ -70,10 +70,11 @@ def resolve_conv_layout(layout: str, backend: str = None) -> str:
     """Resolve a conv_layout choice ("NCHW" | "NHWC" | "auto") against the
     backend actually running the net. "auto" is this table:
 
-    - **tpu**: NCHW. The NHWC plan wins the HLO-transpose count (exactly
-      the fc-boundary pair) but ran 0.53x in the one chip A/B on record
-      (July 2026, before PRs 1-19; not re-measured on this code), so
-      auto stays NCHW until a chip A/B shows >= 1.0 (ROADMAP D3).
+    - **tpu**: NCHW. The NHWC plan ran 0.53x in the first chip A/B (July
+      2026, before PRs 1-19) and 1.035x (alexnet.resident) / 1.013x
+      (googlenet.lmdb) on PR 43's code (builder's chip runs, PR 44;
+      PERF.md section 6): this row waits for a PR that claims the gain on
+      the driver's pairs (ROADMAP D3).
     - **gpu**: NHWC (tensor-core native conv layout).
     - **cpu** (and anything unknown): NCHW — the Caffe-parity default the
       golden-value suites run under.

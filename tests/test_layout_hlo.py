@@ -84,10 +84,14 @@ def test_native_nhwc_chain_backward_has_zero_transposes():
 # net-level: full optimizer steps, layout transposes only at FC boundaries
 # --------------------------------------------------------------------------- #
 
+def _net(model, layout, image, batch):
+    return Net(getattr(zoo, model)(num_classes=10, with_accuracy=False),
+               "TRAIN", {"data": (batch, 3, image, image),
+                         "label": (batch,)}, conv_layout=layout)
+
+
 def _alexnet(layout, image=227, batch=2):
-    return Net(zoo.alexnet(num_classes=10, with_accuracy=False), "TRAIN",
-               {"data": (batch, 3, image, image), "label": (batch,)},
-               conv_layout=layout)
+    return _net("alexnet", layout, image, batch)
 
 
 def test_alexnet_nhwc_train_step_le_2_layout_transposes():
@@ -119,10 +123,8 @@ def test_googlenet_nhwc_transposes_only_at_fc_boundaries():
     is degenerate 1x1; two aux heads flatten real 4x4x128 blobs): <= 2
     layout transposes per boundary, zero anywhere in the 9-inception
     conv/pool/concat body."""
-    net = Net(zoo.googlenet(num_classes=10, with_accuracy=False), "TRAIN",
-              {"data": (1, 3, 224, 224), "label": (1,)},
-              conv_layout="NHWC")
-    rep = HL.net_transpose_report(net, per_dev_batch=1, image=224)
+    rep = HL.net_transpose_report(_net("googlenet", "NHWC", 224, 1),
+                                  per_dev_batch=1, image=224)
     n_boundaries = 3  # loss3/classifier + two aux-head FCs
     assert rep["layout_transposes"] <= 2 * n_boundaries, rep
     # every surviving transpose is at an FC flatten (4x4x128 aux or the
@@ -131,10 +133,13 @@ def test_googlenet_nhwc_transposes_only_at_fc_boundaries():
         assert max(t["shape"]) in (128, 1024), rep
 
 
-def test_nchw_plan_has_zero_layout_transposes():
-    """The canonical plan is the identity: no layout machinery leaks in."""
-    rep = HL.net_transpose_report(_alexnet("NCHW"), per_dev_batch=2,
-                                  image=227)
+@pytest.mark.parametrize("model,image,batch", [
+    ("alexnet", 227, 2), ("googlenet", 224, 1)])
+def test_nchw_plan_has_zero_layout_transposes(model, image, batch):
+    """The canonical plan is the identity: no layout machinery leaks in
+    (GoogLeNet: the inception fan-out, CONCAT and three FC flattens too)."""
+    rep = HL.net_transpose_report(_net(model, "NCHW", image, batch),
+                                  per_dev_batch=batch, image=image)
     assert rep["layout_transposes"] == 0, rep
 
 
