@@ -388,13 +388,17 @@ class MoELayer(Layer):
     - ``router_hidden`` 0: router (E, D) first. Tops: the output; the
       load-balancing and router z losses (scalars, weighted by the
       prototxt's ``loss_weight``);
-    - ``router_hidden`` > 0 or ``score_func`` "sigmoid": no router here; a
-      second bottom, the gates (N, S, E) of the MOE_ROUTER layer before it
-      (each token's ``top_k`` weights, zero elsewhere). Tops: the output.
-    Then optionally the step's own routing as up to three more scalars:
+    - ``router_hidden`` > 0, ``score_func`` "sigmoid" or a SECOND BOTTOM:
+      no router here; the second bottom is the gates (N, S, E) of the
+      MOE_ROUTER layer before it (each token's ``top_k`` weights, zero
+      elsewhere). Tops: the output.
+    Then optionally the step's own routing as up to four more scalars:
     assignments at the fullest HELD expert over their mean, assignments to
-    a held expert that no expert computed (0: nothing is dropped), and the
-    share of all assignments that fell on a held expert."""
+    a held expert that no expert computed (0: nothing is dropped), the
+    share of all assignments that fell on a held expert, and the share of
+    the held experts' live rows' gate pre-activations that are <= 0 (what a
+    ReLU gate zeroes). An expert is down(act(gate x) * (up x)),
+    ``activation`` "silu" (the default) or "relu"."""
     TYPE = "MOE"
 
     def setup(self, bottom_shapes):
@@ -403,15 +407,21 @@ class MoELayer(Layer):
         if not 0 < mp.top_k <= mp.num_experts or mp.expert_width <= 0:
             raise ValueError(f"{self.name}: moe_param needs num_experts >= "
                              f"top_k > 0 and expert_width")
-        self.gated = mp.router_hidden > 0 or mp.score_func == "sigmoid"
+        from ..models.moe import EXPERT_ACTS
+        if mp.activation not in EXPERT_ACTS:
+            raise ValueError(f"{self.name}: activation {mp.activation!r} is "
+                             f"none of {EXPERT_ACTS}")
+        self.gated = mp.router_hidden > 0 or mp.score_func == "sigmoid" \
+            or len(bottom_shapes) == 2
         self.n_fixed = 1 if self.gated else 3
-        if not self.n_fixed <= len(self.lp.top) <= self.n_fixed + 3:
+        if not self.n_fixed <= len(self.lp.top) <= self.n_fixed + 4:
             raise ValueError(
-                f"{self.name}: MOE has {self.n_fixed} to {self.n_fixed + 3} "
+                f"{self.name}: MOE has {self.n_fixed} to {self.n_fixed + 4} "
                 f"tops (output, " + ("" if self.gated else "balance loss, "
                                      "z loss, ")
-                + f"[load max/mean[, dropped[, held share]]]), got "
-                f"{len(self.lp.top)}")
+                + f"[load max/mean[, dropped[, held share[, gate zero "
+                f"share]]]]), got {len(self.lp.top)}")
+        self.gate_zeros = len(self.lp.top) == self.n_fixed + 4
         e, f = mp.num_experts, mp.expert_width
         self.held = mp.num_held or e
         if mp.held_first < 0 or mp.held_first + self.held > e:
@@ -444,16 +454,18 @@ class MoELayer(Layer):
         x = bottoms[0]
         n, s, d = x.shape
         flat = x.reshape(n * s, d)
+        how = (mp.top_k, mp.held_first, mp.activation, self.gate_zeros)
         if self.gated:
             y, sizes = moe_gated(
                 flat, bottoms[1].reshape(n * s, mp.num_experts), p["gate"],
-                p["up"], p["down"], mp.top_k, mp.held_first)
-            tops = [y.reshape(n, s, d)]
+                p["up"], p["down"], *how)
+            losses = []
         else:
             y, lb, z, sizes = moe_dropless(
-                flat, p["router"], p["gate"], p["up"], p["down"], mp.top_k,
-                mp.held_first)
-            tops = [y.reshape(n, s, d), lb, z]
+                flat, p["router"], p["gate"], p["up"], p["down"], *how)
+            losses = [lb, z]
+        y, *zero_share = y if self.gate_zeros else (y,)
+        tops = [y.reshape(n, s, d)] + losses
         sizes = lax.stop_gradient(sizes).astype(jnp.float32)
         total = float(n * s * mp.top_k)
         if self.held == mp.num_experts:
@@ -464,13 +476,14 @@ class MoELayer(Layer):
             stats = [jnp.max(here) * self.held
                      / jnp.maximum(jnp.sum(here), 1.0),
                      jnp.zeros((), jnp.float32), jnp.sum(here) / total]
-        return tops + stats[:len(self.lp.top) - self.n_fixed]
+        return tops + (stats + zero_share)[:len(self.lp.top) - self.n_fixed]
 
 
 class MoERouterLayer(Layer):
-    """A router that is a layer of its own, so that its time has a scope,
-    and that keeps a selection bias it balances itself. Two forms, by
-    ``moe_param``:
+    """A router that is a layer of its own, so that its time has a scope
+    and it may score another blob than the experts compute on. Three forms,
+    by ``moe_param``, the first two keeping a selection bias they balance
+    themselves:
 
     - ``router_hidden`` > 0 (``models/moe.mlp_router``, top-1): bottoms the
       normed hidden state (N, S, D) and, in every layer but the first, the
@@ -482,6 +495,11 @@ class MoERouterLayer(Layer):
       one bottom, the normed hidden state. Tops: the gates; the bias's next
       value; optionally that value's largest magnitude, a scalar a display
       carries. Blobs: w (E, D), bias (E,).
+    - ``score_func`` "softmax" and no ``router_hidden``
+      (``models/moe.softmax_router``, any top-k): one bottom, the state the
+      router scores. Tops: the gates; the load-balancing and router z
+      losses (scalars, weighted by the prototxt's ``loss_weight``). Blob:
+      w (E, D). No bias, nothing layer-updated.
 
     The gates (N, S, E) f32 hold each token's chosen experts' weights and
     zero elsewhere (what the MOE layer after it takes). ``bias`` is a
@@ -496,9 +514,20 @@ class MoERouterLayer(Layer):
         e, r = mp.num_experts, mp.router_hidden
         zero = FillerParameter(type="constant", value=0.0)
         self.sigmoid = mp.score_func == "sigmoid"
+        self.plain = mp.score_func == "softmax" and not r
         if mp.score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"{self.name}: score_func {mp.score_func!r} is "
                              f"neither softmax nor sigmoid")
+        if self.plain:
+            if not 0 < mp.top_k <= e or len(bottom_shapes) != 1 \
+                    or len(self.lp.top) != 3:
+                raise ValueError(
+                    f"{self.name}: a softmax MOE_ROUTER needs num_experts "
+                    f">= top_k > 0, takes (N, S, D) and has 3 tops (gates, "
+                    f"balance loss, z loss); got {bottom_shapes}, "
+                    f"{len(self.lp.top)} tops")
+            self.params = [self._param("w", (e, d), mp.weight_filler, 0)]
+            return [(n, s, e), (), ()]
         if self.sigmoid:
             if not 0 < mp.top_k <= e or r or len(bottom_shapes) != 1 \
                     or len(self.lp.top) not in (2, 3):
@@ -538,11 +567,14 @@ class MoERouterLayer(Layer):
         return 0.0
 
     def apply(self, params, bottoms, ctx):
-        from ..models.moe import mlp_router, sigmoid_router
+        from ..models.moe import mlp_router, sigmoid_router, softmax_router
         mp = self.lp.moe_param
         p = _tap_all(ctx, self.name, params)
         n, s, d = bottoms[0].shape
         flat = bottoms[0].reshape(n * s, d)
+        if self.plain:
+            gates, lb, z = softmax_router(flat, p["w"], mp.top_k)
+            return [gates.reshape(n, s, -1), lb, z]
         if self.sigmoid:
             gates, bias = sigmoid_router(
                 flat, p["w"], p["bias"], mp.top_k, mp.route_scale,

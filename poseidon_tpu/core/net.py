@@ -448,6 +448,8 @@ class Net:
                     # chunks of the sorted assignments, as many trips as
                     # the live rows need (stats.yaml: held_row_fill)
                     arm += f"; held rows: chunks of {held[0]} of {held[2]}"
+                if layer.lp.moe_param.activation != "silu":
+                    arm += f"; act={layer.lp.moe_param.activation}"
             else:
                 continue
             if arm == "pallas" and note:

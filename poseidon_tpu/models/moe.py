@@ -276,9 +276,18 @@ def _combine(out, order, weights, dtype):
     return y.astype(dtype)
 
 
+EXPERT_ACTS = ("silu", "relu")
+
+
+def _act(a, act: str):
+    """The gate's activation in the experts' gated unit: act(a) * b."""
+    return jax.nn.silu(a) if act == "silu" else jax.nn.relu(a)
+
+
 def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
                sizes: jax.Array, gate: jax.Array, up: jax.Array,
-               down: jax.Array, held_first: int = 0):
+               down: jax.Array, held_first: int = 0, act: str = "silu",
+               gate_zeros: bool = False):
     """The experts' part of a dropless MoE, shared by every router: x
     (T, D), each token's k router ``weights`` (T, k), its experts flat
     (T*k,) and ``sizes`` (E,), the assignments per expert over ALL E the
@@ -303,22 +312,40 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
     exists; what is left of T k are the sort and its vectors of scalars.
     Where two chunks at most hold every row (half the experts held: ZAYA1)
     there is nothing for a loop to skip, and the rows run as straight-line
-    code (``_held_rows``): a rule of the shapes, as the chunk is."""
+    code (``_held_rows``): a rule of the shapes, as the chunk is.
+
+    An expert is down(act(gate x) * (up x)), ``act`` one of
+    ``EXPERT_ACTS``. With ``gate_zeros`` the result is (y, the share of
+    the held experts' LIVE rows' gate pre-activations that are <= 0, an f32
+    scalar without a gradient): what a ReLU gate zeroes, counted where the
+    pre-activation is at hand."""
     top_k = weights.shape[1]
     n_exp, n_held = sizes.shape[0], gate.shape[0]
     if n_held == n_exp:
         order = jnp.argsort(flat_e, stable=True)    # assignments by expert
         xs = x[order // top_k]                      # (T*k, D) sorted rows
-        h = jax.nn.silu(_grouped(xs, gate, sizes)) * _grouped(xs, up, sizes)
-        return _combine(_grouped(h, down, sizes), order, weights, x.dtype)
-    local = flat_e - held_first
-    here = (local >= 0) & (local < n_held)
-    order = jnp.argsort(jnp.where(here, local, n_held), stable=True)
-    sizes = sizes[held_first:held_first + n_held]
-    chunk = held_chunk_rows(order.shape[0], n_held, n_exp)
-    if held_rows_loop(order.shape[0], chunk):
-        return _held_chunks(chunk, x, weights, gate, up, down, order, sizes)
-    return _held_rows(x, weights, here, order, sizes, gate, up, down)
+        a = _grouped(xs, gate, sizes)
+        h = _act(a, act) * _grouped(xs, up, sizes)
+        y = _combine(_grouped(h, down, sizes), order, weights, x.dtype)
+        out = (y, jnp.sum(a <= 0, dtype=jnp.int32)) if gate_zeros else y
+    else:
+        local = flat_e - held_first
+        here = (local >= 0) & (local < n_held)
+        order = jnp.argsort(jnp.where(here, local, n_held), stable=True)
+        sizes = sizes[held_first:held_first + n_held]
+        chunk = held_chunk_rows(order.shape[0], n_held, n_exp)
+        if held_rows_loop(order.shape[0], chunk):
+            out = _held_chunks(chunk, x, weights, gate, up, down, order,
+                               sizes, act, gate_zeros)
+        else:
+            out = _held_rows(x, weights, here, order, sizes, gate, up, down,
+                             act, gate_zeros)
+    if not gate_zeros:
+        return out
+    # every arm counts; the live rows are the held experts' assignments
+    y, zeros = out
+    return y, zeros.astype(jnp.float32) / jnp.maximum(
+        jnp.sum(sizes).astype(jnp.float32) * gate.shape[1], 1.0)
 
 
 def held_rows_loop(rows: int, chunk: int) -> bool:
@@ -330,12 +357,14 @@ def held_rows_loop(rows: int, chunk: int) -> bool:
     return -(-rows // chunk) > 2
 
 
-def _held_rows(x, weights, here, order, sizes, gate, up, down):
+def _held_rows(x, weights, here, order, sizes, gate, up, down,
+               act: str = "silu", gate_zeros: bool = False):
     """The held arm's row work over ALL T k sorted assignments, as
     straight-line code that autodiff differentiates: every row gathered
     and multiplied, each assignment's result brought back to its token by
     the inverse permutation and the k summed over an axis. ``here`` (T*k,)
-    says which assignments fell on a held expert."""
+    says which assignments fell on a held expert. With ``gate_zeros``:
+    (y, the live rows' gate pre-activations <= 0, an int32 count)."""
     t, top_k = weights.shape
     # rows past the last group belong to no expert: what a grouped
     # matmul leaves there is masked on the way in (so is their
@@ -348,22 +377,26 @@ def _held_rows(x, weights, here, order, sizes, gate, up, down):
 
     tok = order // top_k
     xs = x[tok]                                     # (T*k, D)
-    h = jax.nn.silu(grouped(xs, gate)) * grouped(xs, up)
+    a = grouped(xs, gate)
+    h = _act(a, act) * grouped(xs, up)
     out = grouped(h, down)                          # (T*k, D)
     weights = weights * here.reshape(t, top_k)
-    return _combine(out, order, weights, x.dtype)
+    y = _combine(out, order, weights, x.dtype)
+    if not gate_zeros:
+        return y
+    return y, jnp.sum((a <= 0) & live, dtype=jnp.int32)
 
 
-def _chunk(i, chunk, x, weights, order, sizes, ends, gate_t, up_t):
+def _chunk(i, chunk, act, x, weights, order, sizes, ends, gate_t, up_t):
     """Trip ``i`` of the held arm as far as the activation: rows
     [i P, i P + P) of the sorted assignments ``order`` (padded to a whole
     number of chunks), the held experts' groups clipped to them (``ends``:
     the running sum of ``sizes``), and x's rows through ``gate_t`` and
     ``up_t`` (G, D, F), already in the compute dtype. Returns (grouped,
-    groups, tok, head, xs, a, b, h): ``grouped(rows, w)`` is this chunk's
-    grouped matmul with w (G, K, N); rows at or past the live count belong
-    to no expert, and what a grouped matmul leaves there is masked on the
-    way in and on the way out."""
+    groups, tok, head, xs, a, b, h, live): ``grouped(rows, w)`` is this
+    chunk's grouped matmul with w (G, K, N); rows at or past the live count
+    (``live`` (P, 1) false) belong to no expert, and what a grouped matmul
+    leaves there is masked on the way in and on the way out."""
     lo = i * chunk
     head = lax.dynamic_slice(order, (lo,), (chunk,))
     groups = (jnp.clip(ends, lo, lo + chunk)
@@ -378,7 +411,7 @@ def _chunk(i, chunk, x, weights, order, sizes, ends, gate_t, up_t):
     tok = head // weights.shape[1]
     xs = x[tok].astype(gate_t.dtype)                # (P, D)
     a, b = grouped(xs, gate_t), grouped(xs, up_t)
-    return grouped, groups, tok, head, xs, a, b, jax.nn.silu(a) * b
+    return grouped, groups, tok, head, xs, a, b, _act(a, act) * b, live
 
 
 def _trips(order, sizes, chunk):
@@ -394,8 +427,9 @@ def _trips(order, sizes, chunk):
 # of one shape share ONE trace and one lowering of each (the compiler
 # inlines the calls). The compute dtype is an argument because the policy
 # is read while tracing and is no part of a jitted function's key.
-@partial(jax.jit, static_argnums=(0, 1))
-def _held_chunks_fwd(chunk, cdtype, x, weights, order, sizes, gate, up, down):
+@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _held_chunks_fwd(chunk, cdtype, act, gate_zeros, x, weights, order,
+                     sizes, gate, up, down):
     f32 = jnp.float32
     order, ends, n = _trips(order, sizes, chunk)
     # the stacks' casts and transposes are made once a pass, not once a trip
@@ -403,19 +437,29 @@ def _held_chunks_fwd(chunk, cdtype, x, weights, order, sizes, gate, up, down):
                             for w in (gate, up, down))
 
     def trip(i, y):
-        grouped, _, tok, head, _, _, _, h = _chunk(
-            i, chunk, x, weights, order, sizes, ends, gate_t, up_t)
+        grouped, _, tok, head, _, a, _, h, live = _chunk(
+            i, chunk, act, x, weights, order, sizes, ends, gate_t, up_t)
         out = grouped(h, down_t)                    # (P, D)
         return y.at[tok].add(out.astype(f32)
-                             * weights.reshape(-1)[head][:, None])
+                             * weights.reshape(-1)[head][:, None]), a, live
 
-    y = lax.fori_loop(0, n, trip, jnp.zeros(x.shape, f32))
-    return y.astype(x.dtype)
+    if not gate_zeros:
+        y = lax.fori_loop(0, n, lambda i, y: trip(i, y)[0],
+                          jnp.zeros(x.shape, f32))
+        return y.astype(x.dtype)
+
+    def counting(i, carry):
+        y, a, live = trip(i, carry[0])
+        return y, carry[1] + jnp.sum((a <= 0) & live, dtype=jnp.int32)
+
+    y, zeros = lax.fori_loop(0, n, counting, (
+        jnp.zeros(x.shape, f32), jnp.zeros((), jnp.int32)))
+    return y.astype(x.dtype), zeros
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _held_chunks_bwd(chunk, cdtype, x, weights, order, sizes, gate, up, down,
-                     dy):
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _held_chunks_bwd(chunk, cdtype, act, x, weights, order, sizes, gate, up,
+                     down, dy):
     f32 = jnp.float32
     order, ends, n = _trips(order, sizes, chunk)
     gate_c, up_c, down_c = (w.astype(cdtype) for w in (gate, up, down))
@@ -424,8 +468,8 @@ def _held_chunks_bwd(chunk, cdtype, x, weights, order, sizes, gate, up, down,
 
     def trip(i, carry):
         dx, dweights, dgate, dup, ddown = carry
-        grouped, groups, tok, head, xs, a, b, h = _chunk(
-            i, chunk, x, weights, order, sizes, ends, gate_t, up_t)
+        grouped, groups, tok, head, xs, a, b, h, _ = _chunk(
+            i, chunk, act, x, weights, order, sizes, ends, gate_t, up_t)
 
         def dw(dys, rows):                          # (G, N, K), as stored
             return lax.ragged_dot_general(dys, rows, groups, _DW_DIMS,
@@ -444,9 +488,13 @@ def _held_chunks_bwd(chunk, cdtype, x, weights, order, sizes, gate, up, down,
         ddown = ddown + dw((dyr.astype(f32) * w).astype(cdtype), h)
         dh = u * w
         a32, b32 = a.astype(f32), b.astype(f32)
-        s = jax.nn.sigmoid(a32)
-        da = (dh * b32 * s * (1 + a32 * (1 - s))).astype(cdtype)
-        db = (dh * a32 * s).astype(cdtype)
+        if act == "silu":
+            s = jax.nn.sigmoid(a32)
+            da = (dh * b32 * s * (1 + a32 * (1 - s))).astype(cdtype)
+            db = (dh * a32 * s).astype(cdtype)
+        else:                           # relu: nothing passes a gate <= 0
+            da = jnp.where(a32 > 0, dh * b32, 0).astype(cdtype)
+            db = (dh * jnp.maximum(a32, 0)).astype(cdtype)
         dgate, dup = dgate + dw(da, xs), dup + dw(db, xs)
         dxs = (grouped(da, gate_c).astype(f32)
                + grouped(db, up_c).astype(f32))
@@ -467,12 +515,15 @@ def _held_chunks_bwd(chunk, cdtype, x, weights, order, sizes, gate, up, down,
             *(g.astype(w.dtype) for g, w in zip(narrow, (gate, up, down))))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_chunks(chunk, x, weights, gate, up, down, order, sizes):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 8, 9))
+def _held_chunks(chunk, x, weights, gate, up, down, order, sizes,
+                 act="silu", gate_zeros=False):
     """The held arm's row work: y (T, D) in x's dtype, the f32 sum of every
     live assignment's weighted expert output, computed ``chunk`` sorted rows
     a trip. ``order`` (T*k,) holds the held experts' rows first, by expert
     (absent ones behind them), ``sizes`` (G,) the held experts' counts.
+    With ``gate_zeros``: (y, the live rows' gate pre-activations <= 0, an
+    int32 count the forward's trips add up).
 
     A loop whose trip count is a value of the run has no reverse
     derivative, so it is written out: the forward saves its inputs alone,
@@ -480,20 +531,23 @@ def _held_chunks(chunk, x, weights, gate, up, down, order, sizes):
     activations, adding its rows' dx and dweights into (T, D) and (T k,) f32
     sums and its share of the stacks' gradients into f32 sums in the
     STORED (G, N, K) orientation (``_DW_DIMS``: PR 30)."""
-    return _held_chunks_fwd(chunk, jnp.dtype(policy().compute_dtype), x,
-                            weights, order, sizes, gate, up, down)
+    return _held_chunks_fwd(chunk, jnp.dtype(policy().compute_dtype), act,
+                            gate_zeros, x, weights, order, sizes, gate, up,
+                            down)
 
 
-def _held_chunks_vjp_fwd(chunk, x, weights, gate, up, down, order, sizes):
-    return (_held_chunks(chunk, x, weights, gate, up, down, order, sizes),
+def _held_chunks_vjp_fwd(chunk, x, weights, gate, up, down, order, sizes,
+                         act, gate_zeros):
+    return (_held_chunks(chunk, x, weights, gate, up, down, order, sizes,
+                         act, gate_zeros),
             (x, weights, gate, up, down, order, sizes))
 
 
-def _held_chunks_vjp_bwd(chunk, res, dy):
+def _held_chunks_vjp_bwd(chunk, act, gate_zeros, res, dy):
     x, weights, gate, up, down, order, sizes = res
-    return _held_chunks_bwd(chunk, jnp.dtype(policy().compute_dtype), x,
+    return _held_chunks_bwd(chunk, jnp.dtype(policy().compute_dtype), act, x,
                             weights, order, sizes, gate, up, down,
-                            dy) + (None, None)
+                            dy[0] if gate_zeros else dy) + (None, None)
 
 
 _held_chunks.defvjp(_held_chunks_vjp_fwd, _held_chunks_vjp_bwd)
@@ -501,14 +555,15 @@ _held_chunks.defvjp(_held_chunks_vjp_fwd, _held_chunks_vjp_bwd)
 
 def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,
                  up: jax.Array, down: jax.Array, top_k: int,
-                 held_first: int = 0):
+                 held_first: int = 0, act: str = "silu",
+                 gate_zeros: bool = False):
     """Top-k token-choice MoE over flat tokens x (T, D), no capacity: every
     token is computed by all k of its experts whatever the load.
 
     router (E, D) scores all E experts; gate, up (G, F, D) and down
     (G, D, F) are the G experts held here (``expert_ffn``; G = E: all).
     Returns (y (T, D), load-balancing loss, z loss, assignments per expert
-    (E,) int32)."""
+    (E,) int32); y is ``expert_ffn``'s (y, share) pair with ``gate_zeros``."""
     n_exp = router.shape[0]
     # the router runs in f32 whatever the policy: top-k is discontinuous,
     # and a bf16 logit flips which experts a token gets
@@ -518,20 +573,49 @@ def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,
     probs, weights, experts = topk_route(logits, top_k)
     flat_e, sizes = expert_sizes(experts, n_exp)
     lb, z = router_losses(logits, probs, sizes)
-    y = expert_ffn(x, weights, flat_e, sizes, gate, up, down, held_first)
+    y = expert_ffn(x, weights, flat_e, sizes, gate, up, down, held_first,
+                   act, gate_zeros)
     return y, lb, z, sizes
 
 
 def moe_gated(x: jax.Array, gates: jax.Array, gate: jax.Array, up: jax.Array,
-              down: jax.Array, top_k: int, held_first: int = 0):
+              down: jax.Array, top_k: int, held_first: int = 0,
+              act: str = "silu", gate_zeros: bool = False):
     """``moe_dropless`` behind a router that is a layer of its own
-    (``mlp_router``): ``gates`` (T, E) f32 hold each token's k chosen
-    experts' weights and zero elsewhere. Returns (y, assignments per expert
-    (E,) int32)."""
+    (``mlp_router``, ``sigmoid_router``, ``softmax_router``): ``gates``
+    (T, E) f32 hold each token's k chosen experts' weights and zero
+    elsewhere. Returns (y, assignments per expert (E,) int32); y is
+    ``expert_ffn``'s (y, share) pair with ``gate_zeros``."""
     weights, experts = lax.top_k(gates, top_k)
     flat_e, sizes = expert_sizes(experts, gates.shape[1])
     return expert_ffn(x, weights, flat_e, sizes, gate, up, down,
-                      held_first), sizes
+                      held_first, act, gate_zeros), sizes
+
+
+def softmax_router(h: jax.Array, w: jax.Array, top_k: int):
+    """A plain softmax router as a layer of its own, over flat tokens h
+    (T, D) that need not be what the experts compute on, in f32 whatever
+    the policy (top-k is discontinuous):
+
+        r = h w^T                                (T, E)
+        chosen = the top_k largest of r
+        weight_e = softmax over the chosen of r
+
+    (the softmax applied AFTER the choice: the k weights sum to 1).
+    Returns (gates (T, E) = the weights at the chosen experts and zero
+    elsewhere; the load-balancing and z losses of ``router_losses``,
+    unweighted, over the softmax of ALL E logits, as ``moe_dropless``
+    computes them)."""
+    f32 = jnp.float32
+    logits = lax.dot_general(h.astype(f32), w.astype(f32),
+                             (((1,), (1,)), ((), ())),
+                             precision=lax.Precision.HIGHEST)
+    top, experts = lax.top_k(logits, top_k)
+    picked = jax.nn.one_hot(experts, logits.shape[1], dtype=f32)  # (T,k,E)
+    gates = jnp.sum(picked * jax.nn.softmax(top, axis=-1)[..., None], axis=1)
+    _, sizes = expert_sizes(experts, logits.shape[1])
+    lb, z = router_losses(logits, jax.nn.softmax(logits, axis=-1), sizes)
+    return gates, lb, z
 
 
 def sigmoid_router(h: jax.Array, w: jax.Array, bias: jax.Array, top_k: int,

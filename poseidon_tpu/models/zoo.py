@@ -889,6 +889,120 @@ def trinity_mini(batch: int = 1,
     return NetParameter(name=name, layers=layers)
 
 
+def smallthinker(batch: int = 1,
+                 source: str = "examples/lm/smallthinker_21b_tokens.txt",
+                 n_layers: int = 52, hidden: int = 2560, heads: int = 28,
+                 kv_heads: int = 4, head_dim: int = 128, window: int = 4096,
+                 global_every: int = 4, first_global: int = 0,
+                 experts: int = 64, top_k: int = 6, held: int = 0,
+                 held_first: int = 0, expert_width: int = 768,
+                 vocab: int = 151936, rope_theta: float = 1.5e6,
+                 eps: float = 1e-6, init_std: float = 0.02,
+                 balance_weight: float = 0.01, z_weight: float = 0.001,
+                 name: str = "SmallThinker-21BA3B") -> NetParameter:
+    """SmallThinker-21BA3B-Instruct (config.json of
+    PowerInfer/SmallThinker-21BA3B-Instruct, ``smallthinker``;
+    arXiv:2507.20984): every layer is a pre-norm block of grouped-query
+    attention and a MoE whose router reads the PRE-attention state:
+
+        a = N1(x);  gates = Router(a);  h = x + Attn(a) W_o
+        y = h + MoE(N2(h), gates)
+
+    Attention: q (``heads`` x ``head_dim``, wider than ``hidden``), k and v
+    (``kv_heads`` x ``head_dim``) project the normed state, no bias, no
+    QK-norm, no gate; layer ``first_global`` and every ``global_every``-th
+    after it is GLOBAL (``l<i>_attn_global``: every earlier token, no
+    positions at all), the others WINDOW layers (``l<i>_attn_window``: the
+    last ``window`` tokens, rotate-half rotary positions): the published
+    ``sliding_window_layout`` and ``rope_layout`` [0, 1, 1, 1] a period.
+
+    MoE: ``l<i>_router`` (MOE_ROUTER, the plain softmax form) scores
+    ``l<i>_a`` over all ``experts``, chooses the ``top_k`` largest logits
+    and weighs them by the softmax over the chosen; its balance and z
+    losses are tops with ``balance_weight`` and ``z_weight``. ``l<i>_moe``
+    takes the post-attention normed state and those gates, holds ``held``
+    of the experts from ``held_first`` on (0 = all: with fewer the net is
+    one rank's share of an expert-parallel model) and runs ReGLU experts,
+    down(relu(gate u) * (up u)); its last top is the share of the held
+    experts' gate pre-activations that are <= 0. No shared expert, no
+    dense layer.
+
+    The head is untied. Gains carry decay_mult 0, every matrix 1."""
+    from ..proto.messages import (AttentionParameter, EltwiseParameter,
+                                  EmbedParameter, HDF5DataParameter,
+                                  MoEParameter, RMSNormParameter)
+    w = gaussian(init_std)
+    lq, lk = heads * head_dim, kv_heads * head_dim
+    layers: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+
+    def norm(lname, bottom, top):
+        layers.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[ParamSpec(lr_mult=1.0, decay_mult=0.0)],
+            rms_norm_param=RMSNormParameter(eps=eps)))
+
+    def proj(lname, bottom, top, n_out):
+        layers.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2, weight_filler=w)))
+
+    def add(lname, a, b, top):
+        layers.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation="SUM")))
+
+    layers.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["x0"],
+        embed_param=EmbedParameter(input_dim=vocab, num_output=hidden,
+                                   weight_filler=w)))
+    x = "x0"
+    for i in range(n_layers):
+        p = f"l{i}_"
+        norm(p + "attn_norm", x, p + "a")
+        moe = dict(num_experts=experts, top_k=top_k,
+                   expert_width=expert_width, weight_filler=w)
+        layers.append(LayerParameter(
+            name=p + "router", type="MOE_ROUTER", bottom=[p + "a"],
+            top=[p + "gates", p + "balance_loss", p + "z_loss"],
+            loss_weight=[0.0, balance_weight, z_weight],
+            moe_param=MoEParameter(**moe)))
+        proj(p + "q", p + "a", p + "q", lq)
+        proj(p + "k", p + "a", p + "k", lk)
+        proj(p + "v", p + "a", p + "v", lk)
+        is_global = i >= first_global \
+            and (i - first_global) % global_every == 0
+        layers.append(LayerParameter(
+            name=p + ("attn_global" if is_global else "attn_window"),
+            type="ATTENTION", bottom=[p + "q", p + "k", p + "v"],
+            top=[p + "att"], attention_param=AttentionParameter(
+                num_heads=heads, rope_theta=rope_theta,
+                num_kv_heads=kv_heads, rope=not is_global,
+                window=0 if is_global else window)))
+        proj(p + "o", p + "att", p + "ao", hidden)
+        add(p + "res1", x, p + "ao", p + "h")
+        norm(p + "ffn_norm", p + "h", p + "u")
+        layers.append(LayerParameter(
+            name=p + "moe", type="MOE", bottom=[p + "u", p + "gates"],
+            top=[p + "m", p + "expert_load", p + "dropped",
+                 p + "held_share", p + "gate_zero_share"],
+            moe_param=MoEParameter(num_held=held, held_first=held_first,
+                                   activation="relu", **moe)))
+        add(p + "res2", p + "h", p + "m", p + "y")
+        x = p + "y"
+    norm("final_norm", x, "xf")
+    proj("lm_head", "xf", "logits", vocab)
+    layers.append(LayerParameter(
+        name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
+        top=["nll"]))
+    # the mean over positions: the exit-weighted loss of ONE pass
+    layers.append(LayerParameter(
+        name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    return NetParameter(name=name, layers=layers)
+
+
 def kimi_linear(batch: int = 1,
                 source: str = "examples/lm/kimi_linear_tokens.txt",
                 n_layers: int = 27, dense_layers: int = 1,

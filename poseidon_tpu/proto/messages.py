@@ -328,7 +328,14 @@ class MoEParameter:
     sigmoid scores, the ``top_k`` largest of score + selection bias chosen,
     their weights the UNBIASED scores divided by their sum over the chosen
     and times ``route_scale``. ``bias_update_rate``: the step of a
-    MOE_ROUTER's balancing rule on its selection bias."""
+    MOE_ROUTER's balancing rule on its selection bias.
+    A MOE_ROUTER with ``score_func`` "softmax" and ``router_hidden`` 0 is
+    the plain softmax router as a layer of its own (one (E, D) matrix, the
+    ``top_k`` largest logits chosen, their weights the softmax over the
+    chosen; the balance and z losses are its tops): it may score another
+    blob than the experts compute on, and the MOE layer that takes its
+    gates as a second bottom has no router. ``activation``: the gate's
+    activation in an expert's gated unit, "silu" or "relu"."""
     num_experts: int = 0
     top_k: int = 1
     expert_width: int = 0
@@ -339,6 +346,7 @@ class MoEParameter:
     score_func: str = "softmax"
     route_scale: float = 1.0
     bias_update_rate: float = 0.001
+    activation: str = "silu"
 
 
 @dataclass

@@ -169,6 +169,45 @@ def test_flash_kernels_compile_for_v5e_at_the_cell_geometry():
                                "grid": 512, "compiled": 1}, got
 
 
+# The same three kernels at smallthinker.e16of64.pack16k's geometries — one
+# sequence x 28 heads of 128 at S 16,384, twice the longest sequence any
+# other cell runs — on the causal grid (the global layer: 16 x 16 tiles of
+# 1024) and on the band's grid at W 4096 (the window layers: at most 5 live
+# K blocks a Q block).
+_FLASH_16K = _FLASH_CELL.replace(
+    "B, H, S, D = 2, 16, 4096, 128", "B, H, S, D, W = 1, 28, 16384, 128, {window}"
+).replace("scale, True, None, None, False)",
+          "scale, True, None, None, False, window=W)").replace(
+    "PK.flash_grid_programs(S, bq, bk, True)",
+    "PK.flash_grid_programs(S, bq, bk, True, W, over_q=kernel == 'dkv')")
+assert _FLASH_16K.count("window=W") == 2 and "over_q" in _FLASH_16K
+
+
+@pytest.mark.parametrize("window, live, visited", [(None, 136, 256),
+                                                   (4096, 70, 80)])
+def test_flash_kernels_compile_for_v5e_at_16k_positions(window, live,
+                                                        visited):
+    """flash_fwd, flash_bwd_dq and flash_bwd_dkv pass Mosaic for an abstract
+    v5e at bf16[1,28,16384,128] with the rule's 1024 x 1024 tiles: causal
+    (136 live of 256 visited programs a head) and under a window of 4096
+    (the band's grid: 70 live of 80 visited, where the causal grid would
+    visit 256)."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _FLASH_16K.format(repo=REPO, window=window)],
+        capture_output=True, text=True, timeout=600, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)
+    for kernel in ("fwd", "dq", "dkv"):
+        assert got[kernel] == {"blocks": [1024, 1024], "live": 28 * live,
+                               "grid": 28 * visited, "compiled": 1}, got
+
+
 # The full-width, one-layer OLMoE train step (examples/lm/olmoe_1b_7b_*) as
 # `train --bf16` builds it, for one abstract v5e chip: the kernels that must
 # be in it, and the compiler's memory accounting that sized the cell's batch
@@ -603,6 +642,76 @@ def test_kimi_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
         # cell's `why` quotes)
         assert 0.60 * 16.9 < got["total_gb"] < 0.70 * 16.9
 
+
+
+# The full-width SmallThinker train step (examples/lm/smallthinker_21b_*:
+# published layers 0-3, global, window, window, window; 16 of 64 experts held,
+# an eighth of the untied vocabulary) as `train --bf16 --remat <the solver
+# header's flags>` builds it at sequences of 16,384, for one abstract v5e
+# chip: the compiler's memory accounting that fixed the cell's batch
+# (benchmark/cells/smallthinker.e16of64.pack16k.json), at the batch chosen
+# and one sequence more.
+_SMALLTHINKER_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 1 + {deeper}, 16384, 0").replace(
+    "ouro_2_6b_solver", "smallthinker_21b_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers)')
+assert _SMALLTHINKER_STEP.count("smallthinker") == 1 \
+    and "ouro_2" not in _SMALLTHINKER_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("more", [0, 1])
+def test_smallthinker_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(
+        more):
+    """At one sequence of 16,384 the step with one checkpoint a layer is
+    under 85% of the 16.9 GB the compiler allows (PR 22's sizing rule); at
+    two it is just over. The global layer's three flash kernels run on the
+    causal grid (136 live of 256 visited programs a head), the window
+    layers' on the band's (70 of 80 at W 4096); k and v reach the 28 query
+    heads by a repeat of 7; each MOE layer's held rows run in chunks of
+    24,576 (the even quarter of 98,304) under one loop a pass, ReLU in the
+    gate."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c",
+         _SMALLTHINKER_STEP.format(repo=REPO, deeper=more)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next cut
+    assert got["depth"] == 4 and got["parameters"] == 559_290_880
+    # embed, head, final norm; a layer: 2 norms, q k v o, router, 3 stacks
+    assert got["leaves"] == 3 + 4 * 10
+    assert got["segments"] == 4 + 1
+    tiles = "fwd 1024x1024 {0}, dq 1024x1024 {0}, dkv 1024x1024 {0}; " \
+        "block_q x block_k, live/visited programs a head"
+    rows = 16384 * 6 * (1 + more)
+    assert got["routes"] == [
+        "attention=pallas_flash (" + tiles.format("136/256")
+        + "); 4 kv heads repeated x7; no positions",
+        "attention=pallas_flash (" + tiles.format("70/80")
+        + "; window 4096: the band's grid); 4 kv heads repeated x7",
+        f"grouped_matmul=ragged_dot; held rows: chunks of {rows // 4} of "
+        f"{rows}; act=relu"]
+    # 4 flash calls a layer (forward, its replay, dq, dkv); a MoE layer's
+    # held arm is one loop a pass: 4 calls in the forward's, 10 in the
+    # backward's, no replay (see the Kimi test)
+    assert got["pallas_custom_calls"] == 4 * 4 + 14 * 4
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    if more:
+        # 14.411 = 85.3% (PR 45; temporaries 7.70 GB): over the rule's
+        # 14.365 by a hair; it compiles, so the chip could hold it
+        assert got["total_gb"] > 0.85 * 16.9
+    else:
+        # 11.156 = 66.0% (PR 45; temporaries 4.44 GB)
+        assert 0.60 * 16.9 < got["total_gb"] < 0.72 * 16.9
 
 
 # The LRN kernels at the CNN cells' norm layers (AlexNet's two at batch 512,
