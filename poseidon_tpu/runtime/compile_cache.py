@@ -16,9 +16,11 @@ config hits them:
 
 2. **AOT step-executable store** (``jax.experimental.serialize_executable``):
    the compiled train-step executable itself, serialized under
-   ``<cache_dir>/aot/`` keyed by (model, shapes, mesh, backend, policy).
-   A restart that matches the key skips tracing AND compilation — the
-   engine loads the executable and dispatches it directly (building on the
+   ``<cache_dir>/aot/`` keyed by what reaches the traced program (sources,
+   layers, shapes, mesh, backend, policy, solver: ``step_key``) and by
+   nothing else: not the seed, not the run's length. A start that matches
+   the key (a restart, a resume, a new seed) skips tracing AND compilation —
+   the engine loads the executable and dispatches it directly (building on the
    abstract-topology lower/compile flow of ``scripts/aot_tpu_check.py``,
    but serialized for the REAL local topology and reloaded across process
    boundaries).
@@ -116,10 +118,15 @@ def cache_entries(cache_dir: str) -> int:
 def watch_cache_hits():
     """Yields a list that grows by one for every compile inside the block
     that the persistent XLA cache answered (jax's own monitoring event).
-    An executable that came back from the cache is not serialized into the
-    AOT store: XLA:CPU writes one that loads and then dies at its first
-    dispatch (``NOT_FOUND: Function ... not found``), and the cache already
-    makes that start cheap."""
+    The Engine does not serialize into the AOT store an executable that
+    came back from the cache. The rule was written for XLA:CPU, which
+    writes an entry that loads and then dies at its first dispatch
+    (``NOT_FOUND: Function ... not found``), and is applied on every
+    backend: whether a TPU executable the cache answered survives the
+    round trip has not been read on the chip. A start meets that state
+    (XLA cache warm, ``aot/`` without the step) only after an edit to the
+    package that left the step's HLO as it was, or where ``aot/`` alone was
+    emptied; it then pays the trace and the lowering at every start."""
     import jax.monitoring as monitoring
     hits: list = []
 
@@ -152,11 +159,18 @@ def _canon(obj: Any) -> Any:
 
 
 def step_key(**parts: Any) -> str:
-    """Content key for a serialized step executable. Callers fold in
-    everything that changes the compiled program: model name, param
-    shapes, batch shapes/dtypes, mesh axes/shape, backend + device kind,
-    jax version, donation flags, numeric policy. Same parts -> same key on
-    a restarted process; ANY drift -> clean miss (never a stale load)."""
+    """Content key for a serialized step executable. The rule for what a
+    caller folds in: a part belongs in the key if and only if it reaches
+    the traced program. Everything that does (the sources, the net's
+    layers, parameter and batch shapes and dtypes, mesh, backend and
+    device kind, jax version, donation, numeric policy, lowering switches,
+    remat units, the solver fields the update is traced from) so that ANY
+    drift of the program is a clean miss, never a stale load; nothing that
+    does not (a seed, a run's length where no schedule reads it, display
+    and snapshot cadence, where the data comes from) so that a start whose
+    program is the stored one finds it. ``Engine._aot_step_key`` lists the
+    parts. Same parts -> same key on a restarted process, whatever the
+    order of the keywords."""
     blob = json.dumps(_canon(parts), sort_keys=True).encode()
     return hashlib.sha256(blob).hexdigest()[:32]
 

@@ -20,6 +20,7 @@ global batch size.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 from collections import deque
@@ -454,7 +455,8 @@ class Engine:
         # fast restart (runtime/compile_cache.py): when a compile-cache
         # dir is configured, the single-step hot path resolves through the
         # AOT step-executable store on first dispatch — a restarted-or-new
-        # worker whose (model, shapes, mesh, policy) key matches skips
+        # worker whose program is the stored one (_aot_step_key: whatever
+        # its seed, and its run's length where no schedule reads it) skips
         # tracing AND compilation entirely; a miss compiles once,
         # serializes for the next incarnation, and still rides the
         # persistent XLA cache. SSP local-step and HDF5-dump steps keep
@@ -761,12 +763,17 @@ class Engine:
             f"{shard.index}/{shard.count}", rank=self.rank)
         return True
 
+    def _device_transform_specs(self) -> Dict[str, Dict[str, Any]]:
+        """{top: {"mean_values", "scale"}} of the data layers whose
+        normalization ``--device_transform`` moved into the step."""
+        return {p.tops[0]: p.device_transform_spec
+                for p in self.train_pipelines
+                if getattr(p, "device_transform_spec", None) is not None}
+
     def _make_input_transform(self):
         """The device half of the uint8 ingest split: per data-layer
         (x - mean_values) * scale, traced into the compiled train step."""
-        specs = {p.tops[0]: p.device_transform_spec
-                 for p in self.train_pipelines
-                 if getattr(p, "device_transform_spec", None) is not None}
+        specs = self._device_transform_specs()
         if not specs:
             return None
         frozen = {top: (None if s["mean_values"] is None
@@ -901,8 +908,8 @@ class Engine:
     # static shapes/mesh ints), never steady-state:
     def _resolve_aot_step(self, batch, rng) -> None:  # static-ok: JIT102
         """Load — or compile + serialize — the step executable for this
-        exact (model, shapes, mesh, backend, policy) key, under the
-        start-up span ``step_load``, and publish stats section
+        job's key (``_aot_step_key``: what reaches the traced program),
+        under the start-up span ``step_load``, and publish stats section
         ``compiled_step``: what the step is and, read off the spans that
         closed under ``step_load``, where its seconds went."""
         with span_recorder.startup("step_load") as load:
@@ -1007,14 +1014,51 @@ class Engine:
         return doc
 
     def _aot_step_key(self, batch) -> str:  # static-ok: JIT102
-        """The AOT store's key for this job's train step: everything that
-        changes the compiled program."""
+        """The AOT store's key for this job's train step. The rule: a part
+        is in the key if and only if it reaches the traced program, so
+        that a start whose program is the stored one loads it and any
+        other misses (a stale load is worse than a slow start). The parts:
+
+        - the program's own sources (``code_fingerprint``), the jax
+          version, the backend, the device kind and count, the mesh;
+        - the train net: its name, every compute layer's definition as
+          parsed (a ratio, a window, an activation: same name and shapes,
+          another program), the parameters' and the batch's shapes and
+          dtypes, the activation layout, the remat units, the mean and
+          scale that ``--device_transform`` moves into the step;
+        - the numeric policy, the five lowering switches
+          (``LOWERING_ENV``), the comm config, whether the batch is
+          donated;
+        - the solver fields the update and the rate are traced from, and
+          ``max_iter`` only under a policy whose rate reads it
+          (``HORIZON_POLICIES``: the horizon is a constant of the program
+          there).
+
+        NOT in the key, because no traced line reads them: ``random_seed``
+        (the weights are made from it outside the step and ``rng`` is the
+        step's fourth argument; tests/test_elasticity.py lowers steps under
+        two seeds and compares the text), ``display``, the snapshot and
+        test cadence, data sources and their host-side transforms, and
+        ``max_iter`` under every other policy. So a new seed, a resume, a
+        longer run and another dataset of the same shapes all load."""
         from ..config import policy
         from ..ops.pallas_kernels import LOWERING_ENV
+        from ..solvers.updates import HORIZON_POLICIES
         from .compile_cache import code_fingerprint, step_key
+        solver_fields = [
+            "solver_type", "base_lr", "lr_policy", "gamma", "power",
+            "stepsize", "stepvalue", "momentum", "momentum2",
+            "weight_decay", "regularization_type", "delta",
+            "clip_gradients", "iter_size"]
+        if self.sp.lr_policy in HORIZON_POLICIES:
+            solver_fields.append("max_iter")
         return step_key(
             kind="train_step",
             model=self.train_net.name or "net",
+            # a caffemodel's weights ride a parsed layer as ``blobs``:
+            # values, not program
+            layers=[repr(dataclasses.replace(l.lp, blobs=[]))
+                    for l in self.train_net.layers],
             params={l: {p: (list(v.shape), str(v.dtype))
                         for p, v in ps.items()}
                     for l, ps in self.params.items()},
@@ -1030,16 +1074,13 @@ class Engine:
                           for k in LOWERING_ENV},
             numeric_policy=str(policy()),
             conv_layout=self.train_net.conv_layout,
-            # compile-RELEVANT solver fields only: max_iter/display/
-            # snapshot cadence never reach the traced program, and
-            # folding them in would defeat the warm start for the
-            # standard resume-and-train-longer flow
-            solver={k: str(getattr(self.sp, k, None)) for k in (
-                "solver_type", "base_lr", "lr_policy", "gamma",
-                "power", "stepsize", "stepvalue", "momentum",
-                "momentum2", "weight_decay", "regularization_type",
-                "delta", "clip_gradients", "iter_size",
-                "random_seed")},
+            # --device_transform's mean and scale are constants of the step
+            input_transform={
+                top: (None if spec["mean_values"] is None
+                      else spec["mean_values"].tolist(), spec["scale"])
+                for top, spec in self._device_transform_specs().items()},
+            solver={k: str(getattr(self.sp, k, None))
+                    for k in solver_fields},
             comm=str(self.comm),
             donate_batch=self._donate_batch,
             # what runs under which checkpoint is part of the program

@@ -34,6 +34,11 @@ import jax.numpy as jnp
 from ..proto.messages import SolverParameter
 
 
+# the policies whose rate reads ``max_iter``: there the run's length is a
+# constant of the traced step (the AOT store keys on it, runtime/engine.py)
+HORIZON_POLICIES = ("poly", "cosine")
+
+
 def learning_rate(sp: SolverParameter, it: jax.Array) -> jax.Array:
     it = it.astype(jnp.float32)
     policy = sp.lr_policy

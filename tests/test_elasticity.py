@@ -554,14 +554,24 @@ def _memory_data(n=64, seed=0):
             "label": rs.randint(0, 5, n)}
 
 
-def _small_engine(tmp_path, **kw):
+# the small net with a DROPOUT layer: a step that READS its ``rng`` argument
+_DROPNET = _SMALLNET.replace(
+    'layers { name: "loss"',
+    'layers { name: "drop1" type: DROPOUT bottom: "ip1" top: "ip1"\n'
+    '  dropout_param { dropout_ratio: 0.25 } }\n'
+    'layers { name: "loss"')
+
+
+def _small_engine(tmp_path, net=_SMALLNET, solver=None, **kw):
+    """``solver``: SolverParameter fields over the defaults below; the
+    other keywords are the Engine's."""
     from poseidon_tpu.proto.messages import (SolverParameter,
                                              load_net_from_string)
     from poseidon_tpu.runtime.engine import Engine
-    sp = SolverParameter(train_net_param=load_net_from_string(_SMALLNET),
-                         base_lr=0.01, lr_policy="fixed", momentum=0.9,
-                         display=0, max_iter=kw.pop("max_iter", 4),
-                         random_seed=3)
+    fields = dict(base_lr=0.01, lr_policy="fixed", momentum=0.9, display=0,
+                  max_iter=kw.pop("max_iter", 4), random_seed=3)
+    fields.update(solver or {})
+    sp = SolverParameter(train_net_param=load_net_from_string(net), **fields)
     return Engine(sp, memory_data=_memory_data(),
                   output_dir=str(tmp_path), **kw)
 
@@ -803,6 +813,170 @@ def test_engine_aot_warm_start_loads_across_engines(tmp_path,
     assert aot_entries(cache) == 1    # loaded, not re-serialized
     assert eng2.stats.sections["compiled_step"]["source"] == "loaded"
     assert last1["loss"] == last2["loss"]
+
+
+def test_engine_aot_loads_the_stored_step_on_a_new_seed(tmp_path,
+                                                        jax_cache_env):
+    """Two engines that differ ONLY in ``random_seed``, one cache dir: the
+    seed is not in the store's key, so the second LOADS the first's step —
+    and the loaded program fed the second seed's weights and ``rng`` is
+    the right program: its losses are those of a jit-path engine on the
+    second seed, bitwise, through a DROPOUT layer that reads the rng."""
+    from poseidon_tpu.runtime.compile_cache import (aot_entries,
+                                                    enable_compile_cache)
+
+    def losses(eng, jit_path=False):
+        if jit_path:
+            eng._aot_enabled = False
+        first = eng.train(max_iter=1)["loss"]
+        last = eng.train()["loss"]
+        eng.close()
+        return first, last
+
+    cache = enable_compile_cache()
+    eng1 = _small_engine(tmp_path / "r1", _DROPNET, max_iter=3)
+    on_3 = losses(eng1)
+    assert eng1.stats.sections["compiled_step"]["source"] == "compiled"
+    assert eng1.stats.sections["compiled_step"]["stored"] == "yes"
+
+    eng2 = _small_engine(tmp_path / "r2", _DROPNET, {"random_seed": 4},
+                         max_iter=3)
+    on_4 = losses(eng2)
+    assert eng2.stats.sections["compiled_step"]["source"] == "loaded"
+    assert eng2._aot_exec is not None and not eng2._aot_failed
+    assert aot_entries(cache) == 1
+
+    eng3 = _small_engine(tmp_path / "r3", _DROPNET, {"random_seed": 4},
+                         max_iter=3)
+    on_4_jit = losses(eng3, jit_path=True)
+    assert eng3._aot_exec is None
+    assert "compiled_step" not in eng3.stats.sections
+    assert on_4 == on_4_jit
+    assert on_4 != on_3, "the seed does change the run: its weights, its rng"
+
+
+def _lowered_step_text(eng) -> str:
+    """The train step as ``Engine._load_or_compile_step`` lowers it."""
+    batch = eng._next_batch(eng.train_pipelines)
+    low = eng.train_step.lowerable or eng.train_step.step
+    return low.lower(eng.params, eng.state, batch, eng.rng).as_text()
+
+
+def _token_engine(tmp_path, seed):
+    """OLMoE's block at cut widths (attention, a routed MoE layer with both
+    auxiliary losses) with a DROPOUT layer behind the embedding, ADAM and a
+    clip, through the Engine on one device."""
+    import h5py
+
+    from poseidon_tpu.models import zoo
+    from poseidon_tpu.parallel.mesh import make_mesh
+    from poseidon_tpu.proto.messages import load_solver
+    from poseidon_tpu.runtime.engine import Engine
+    tmp_path.mkdir(parents=True, exist_ok=True)
+    stream = np.random.RandomState(7).randint(0, 128, 33).astype(np.int32)
+    with h5py.File(tmp_path / "tokens.h5", "w") as h:
+        h["data"] = np.tile(stream[:-1], (4, 1))
+        h["label"] = np.tile(stream[1:], (4, 1))
+    (tmp_path / "tokens.txt").write_text(str(tmp_path / "tokens.h5") + "\n")
+    net = zoo.olmoe(batch=1, source=str(tmp_path / "tokens.txt"),
+                    n_layers=1, hidden=64, heads=4, experts=8, top_k=2,
+                    expert_width=32, vocab=128)
+    at = [l.name for l in net.layers].index("embed") + 1
+    net.layers.insert(at, zoo.dropout("embed_drop", "x0", 0.1))
+    (tmp_path / "net.prototxt").write_text(zoo.to_prototxt(net))
+    (tmp_path / "solver.prototxt").write_text(
+        f'net: "{tmp_path / "net.prototxt"}"\nsolver_type: ADAM\n'
+        f'base_lr: 0.004\nlr_policy: "cosine"\ngamma: 0.1\nstepsize: 2\n'
+        f'momentum: 0.9\nmomentum2: 0.95\nweight_decay: 0.1\n'
+        f'clip_gradients: 1.0\nmax_iter: 8\ndisplay: 1\nsnapshot: 0\n'
+        f'snapshot_after_train: false\nsnapshot_prefix: "snap/x"\n'
+        f'random_seed: {seed}\n')
+    return Engine(load_solver(str(tmp_path / "solver.prototxt")),
+                  output_dir=str(tmp_path), mesh=make_mesh(1))
+
+
+@pytest.mark.parametrize("model", ["cnn_with_dropout", "token_moe"])
+def test_lowered_step_text_is_the_same_under_two_seeds(model, tmp_path):
+    """Why the seed may leave the key: nothing the step is traced from
+    reads it. The weights are made from it outside the step and ``rng``
+    is an argument, so two engines on two seeds lower to the same text,
+    byte for byte — with a DROPOUT layer (the rng's reader) in both nets,
+    and routing, both router losses, ADAM and a clip in the token one. A
+    model whose traced program did read the seed would fail here, and
+    would need the seed back in ``Engine._aot_step_key``."""
+    texts = []
+    for seed in (3, 4):
+        eng = (_token_engine(tmp_path / str(seed), seed)
+               if model == "token_moe" else _small_engine(
+                   tmp_path / str(seed), _DROPNET, {"random_seed": seed}))
+        texts.append(_lowered_step_text(eng))
+        eng.close()
+    if model == "token_moe":
+        assert "top_k" in texts[0] or "sort" in texts[0]    # it routes
+    assert "rng_bit_generator" in texts[0] or "threefry" in texts[0] \
+        or "random" in texts[0], "no random draw in the step: a weak case"
+    assert texts[0] == texts[1]
+
+
+def _step_key_of(eng) -> str:
+    try:
+        return eng._aot_step_key(eng._next_batch(eng.train_pipelines))
+    finally:
+        eng.close()
+
+
+_KEY_CASES = {
+    # name: (what differs from _small_engine(net=_DROPNET), same key?)
+    "random_seed": ({"solver": {"random_seed": 4}}, True),
+    "display": ({"solver": {"display": 2}}, True),
+    "max_iter_under_a_fixed_rate": ({"solver": {"max_iter": 9}}, True),
+    "base_lr": ({"solver": {"base_lr": 0.02}}, False),
+    "clip_gradients": ({"solver": {"clip_gradients": 1.0}}, False),
+    "batch_shape": ({"net": _DROPNET.replace("batch_size: 8",
+                                             "batch_size: 16")}, False),
+    "remat_unit": ({"remat": "ip1"}, False),
+    "a_layer_s_definition": ({"net": _DROPNET.replace("0.25", "0.5")},
+                             False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KEY_CASES))
+def test_aot_step_key_follows_the_program_and_nothing_else(case, tmp_path):
+    """The store's key is equal where the traced program is (another seed,
+    another display cadence, a longer run at a fixed rate) and differs
+    where the program does (a rate, a clip, a batch shape, a remat unit, a
+    layer's own numbers under the same name and shapes): a stale load is
+    worse than a slow start."""
+    differs, same = _KEY_CASES[case]
+    changed = _small_engine(tmp_path / "case", **{"net": _DROPNET, **differs})
+    if "remat" in differs:
+        assert changed.remat_plan is not None and changed.remat_plan.units
+    base = _small_engine(tmp_path / "base", _DROPNET)
+    assert (_step_key_of(base) == _step_key_of(changed)) is same
+
+
+@pytest.mark.parametrize("policy, same", [
+    ("fixed", True), ("step", True), ("exp", True), ("inv", True),
+    ("sigmoid", True), ("multistep", True), ("poly", False),
+    ("cosine", False)])
+def test_max_iter_is_in_the_key_exactly_where_the_rate_reads_it(
+        policy, same, tmp_path):
+    """``max_iter`` reaches the traced program under ``poly`` and
+    ``cosine`` (the schedule's horizon is a constant of the step) and
+    under no other policy: the lowered text says so, and the key follows
+    the text — resume-and-train-longer loads the stored step at a fixed or
+    stepped rate and misses, rightly, where a longer run is another
+    schedule."""
+    fields = {"lr_policy": policy, "gamma": 0.5, "power": 0.5,
+              "stepsize": 2, "stepvalue": [2, 3]}
+    keys, texts = [], []
+    for max_iter in (4, 9):
+        eng = _small_engine(tmp_path / f"{max_iter}",
+                            solver={**fields, "max_iter": max_iter})
+        texts.append(_lowered_step_text(eng))
+        keys.append(_step_key_of(eng))
+    assert (texts[0] == texts[1]) is same
+    assert (keys[0] == keys[1]) is same
 
 
 def test_engine_aot_does_not_reserialize_an_xla_cache_hit(tmp_path,

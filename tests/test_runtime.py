@@ -739,6 +739,22 @@ random_seed: 5
             eng.close()
     assert abs(losses[True] - losses[False]) < 1e-4, losses
 
+    # the mean and the scale are constants of the step that runs them, so
+    # the AOT store's key follows them (a data layer is otherwise no part
+    # of the key: its source and host-side transform never reach the step)
+    keys = []
+    for mean in (128, 120):
+        net.write_text(net.read_text().replace("mean_value: 128",
+                                               f"mean_value: {mean}"))
+        eng = Engine(load_solver(str(solver)), output_dir=str(tmp_path),
+                     device_transform=True)
+        try:
+            keys.append(eng._aot_step_key(
+                eng._next_batch(eng.train_pipelines)))
+        finally:
+            eng.close()
+    assert keys[0] != keys[1]
+
     # SSP composes too (the step builder's input hook): u8 ingest + device
     # transform trains under staleness without error
     eng = Engine(sp, output_dir=str(tmp_path), device_transform=True,
