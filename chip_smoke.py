@@ -83,6 +83,19 @@ class Size:
                     {"kimi_linear": (8192, 8, 256, 8, 2304, 1024),
                      "trinity": (16384, 8, 128, 16, 2048, 1024),
                      "zaya1": (16384, 1, 16, 8, 2048, 2048)})
+        # the ATTENTION geometries of the six token cells (sequences, S,
+        # query / key-value heads, Dh, Dv, window; 0 = causal)
+        self.flash = ({"tiny": (2, 64, 4, 2, 128, 128, 24),
+                       "tiny narrow": (1, 64, 2, 2, 24, 16, 0)} if tiny else
+                      {"ouro": (1, 8192, 16, 16, 128, 128, 0),
+                       "olmoe": (2, 4096, 16, 16, 128, 128, 0),
+                       "zaya1": (2, 8192, 8, 2, 128, 128, 0),
+                       "trinity window": (2, 8192, 32, 4, 128, 128, 2048),
+                       "trinity global": (2, 8192, 32, 4, 128, 128, 0),
+                       "smallthinker window": (1, 16384, 28, 4, 128, 128,
+                                               4096),
+                       "smallthinker global": (1, 16384, 28, 4, 128, 128, 0),
+                       "kimi_linear": (1, 8192, 32, 32, 192, 128, 0)})
 
 
 # --------------------------------------------------------------------------- #
@@ -385,6 +398,85 @@ def check_kernels(size: Size) -> dict:
     return facts
 
 
+def check_flash(size: Size) -> dict:
+    """The three flash kernels at the token cells' ATTENTION geometries
+    against the dense op, the output and all three gradients under the bf16
+    policy on this device, on both operand forms: head-major (B, H, S, D)
+    everywhere, and token-major (B, S, H·D), a head a lane block in the
+    index maps, wherever ``flash_operand_form`` sends an ATTENTION layer
+    that way (Kimi's 192-wide heads stay head-major). Key-value heads are
+    repeated to the query heads outside the kernels, as ``rope_attention``
+    does; the dense op runs a head at a time (its scores are S x S)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    from poseidon_tpu.config import policy_scope
+    from poseidon_tpu.ops.attention import attention
+    from poseidon_tpu.ops.pallas_kernels import (flash_attention,
+                                                 flash_operand_form)
+
+    def one(shape):
+        b, s, h, g, d, dv, window = shape
+        rs = np.random.RandomState(SEED)
+        q, k, v, cot = (jnp.asarray(rs.randn(b, n, s, w) * 0.5, jnp.bfloat16)
+                        for n, w in ((h, d), (g, d), (g, dv), (h, dv)))
+        lanes = lambda t: t.swapaxes(1, 2).reshape(b, s, -1)
+        repeat = lambda t: jnp.repeat(t, h // g, axis=1)
+
+        def dense(q, k, v):
+            per_head = lambda t: t.reshape((b * h, 1, 1) + t.shape[2:])
+            # a head at a time in the backward too: its (S, S) scores are
+            # recomputed, not kept for every head at once
+            out = lax.map(jax.checkpoint(lambda a: attention(
+                *a, causal=True, window=window or None)),
+                tuple(per_head(t) for t in (q, repeat(k), repeat(v))))
+            return out.reshape(b, h, s, dv)
+
+        def head_major(q, k, v):
+            return flash_attention(q, repeat(k), repeat(v), True,
+                                   window=window or None)
+
+        def token_major(q, k, v):
+            out = flash_attention(lanes(q), lanes(repeat(k)),
+                                  lanes(repeat(v)), True,
+                                  window=window or None, heads=h)
+            return out.reshape(b, s, h, dv).swapaxes(1, 2)
+
+        def stepped(fn):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: (lambda y: (jnp.sum((y * cot).astype(
+                    jnp.float32)), y))(fn(*a)), argnums=(0, 1, 2),
+                has_aux=True))
+
+        forms = {"head-major": head_major}
+        if flash_operand_form(s, d, dv)[0]:
+            forms["token-major"] = token_major
+        (_, want), want_grads = stepped(dense)(q, k, v)
+        facts = {"operand form": flash_operand_form(s, d, dv)[1]}
+        for form, fn in forms.items():
+            fn = stepped(fn)
+            calls = fn.lower(q, k, v).as_text().count("tpu_custom_call")
+            check((calls == 3) != size.tiny,
+                  f"flash {form} at {shape}: {calls} Mosaic calls lowered")
+            (_, got), got_grads = fn(q, k, v)
+            for name, a, w in zip(("out", "dq", "dk", "dv"),
+                                  (got,) + got_grads, (want,) + want_grads):
+                a, w = (np.asarray(t, np.float32) for t in (a, w))
+                rel = float(np.linalg.norm(a - w)
+                            / max(np.linalg.norm(w), 1e-30))
+                facts[f"{form}: {name} relative l2"] = rel
+                # bf16 operands and a bf16 result on both sides
+                # (tests/test_pallas.py's bound)
+                check(np.any(w) and rel < 2e-2,
+                      f"flash {form} at {shape}: {name} differs from the "
+                      f"dense op by {rel} (relative L2)")
+        return facts
+
+    with policy_scope(compute_dtype=jnp.bfloat16):
+        return {name: one(shape) for name, shape in size.flash.items()}
+
+
 HELD_SHARES = (0.03, 0.06, 0.125, 0.25, 0.5, 1.0)
 
 
@@ -581,6 +673,10 @@ def child_cold(size: Size) -> dict:
 
     # 6. a held MOE layer's chunked loop at the token cells' shapes
     phases["held_chunks"] = check_held_chunks(size)
+
+    # 7. the flash kernels at the token cells' geometries, both operand
+    # forms, against the dense op
+    phases["flash"] = check_flash(size)
     result["resume_from"] = snap
     result["net"] = net
     result["xla_entries_at_end"] = cache_entries(cache)

@@ -15,6 +15,8 @@ rotate along "seq" only.
 
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass
 from typing import Dict, Optional
 
@@ -26,7 +28,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..config import matmul_precision, policy
 from ..core.remat import resolve_lm_policy, wrap_checkpoint
-from ..ops.pallas_kernels import maybe_flash_attention
+from ..ops.pallas_kernels import (flash_operand_form,
+                                  maybe_flash_attention)
 from ..parallel.sequence import ring_attention
 from ..proto.messages import SolverParameter
 from ..solvers.updates import SolverState, make_update_fn
@@ -155,13 +158,21 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     ``n_heads``, Dkv = D), rotary positions on q and k, causal
     softmax(q k^T / sqrt(Dh)) v, heads merged. With fewer key-value heads
     query head h reads key-value head h // (n_heads / n_kv_heads): k and v
-    are repeated to the query heads before the kernel (four copies of an
-    (S, 128) head cost microseconds; their gradients sum in autodiff).
+    are repeated to the query heads before the kernel (their gradients sum
+    in autodiff).
     ``rotary_dims`` (0 = the whole head): only the first that many dims of
     a head rotate, the rest pass; ``rope`` false: nothing rotates, the
     layer has no positions. ``window`` (0 = none): token t attends to s with
     t - window < s <= t. The Pallas flash kernel where the sequence
     tiles (``maybe_flash_attention``), the dense op elsewhere.
+
+    Where a head is whole vregs of lanes (``flash_operand_form``: widths
+    that are multiples of 128) nothing here moves a head: q, k and v stay
+    (B, S, H·Dh) through the rotation and the repeat
+    (``_rope_attention_lanes``), the kernels read them where the projections
+    left them and write (B, S, H·Dv) where the out-projection reads it.
+    Elsewhere q, k and v are transposed to (B, H, S, Dh) first and the
+    result back, each an HBM copy of the activation on the TPU.
 
     v's heads may have a width of their own (Dv / n_kv: the result is then
     (B, S, n_heads * that)). ``k_shared`` (B, S, Ds): a key part that every
@@ -171,11 +182,15 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     b, s, d = q.shape
     d_head = d // n_heads
     n_kv = n_kv_heads or n_heads
+    rot = rotary_dims or d_head
+    if flash_operand_form(s, d_head, v.shape[-1] // n_kv)[0]:
+        return _rope_attention_lanes(q, k, v, n_heads, n_kv, rot,
+                                     rope_theta if rope else None, window,
+                                     k_shared)
 
     def heads(t, n):
         return t.reshape(b, s, n, -1).swapaxes(1, 2)
 
-    rot = rotary_dims or d_head
     cos, sin = rope_tables(s, rot, rope_theta) if rope else (None, None)
 
     def rotate(t):
@@ -199,6 +214,101 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
         k, v = (jnp.repeat(t, n_heads // n_kv, axis=1) for t in (k, v))
     att = maybe_flash_attention(q, k, v, causal=True, window=window)
     return att.swapaxes(1, 2).reshape(b, s, n_heads * v.shape[-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _rope_lanes(x: jax.Array, n: int, rot: int, theta: float,
+                turn: int = 1) -> jax.Array:
+    """``apply_rope`` on x (B, S, n·Dh) as it lies, its ``n`` heads side by
+    side along the lanes and the first ``rot`` dims of each rotating, by
+    ``turn`` (1, or -1: the other way, which is the rotation's transpose
+    and so its backward). Nothing is reshaped to (B, S, n, Dh), which under
+    the TPU's (8, 128) tiling is another layout and a copy of x each way:
+    a dim's rotate-half partner is ``rot / 2`` lanes to its right or left.
+
+    The tables are the problem of this form: (S, Dh) ones do not broadcast
+    along the lanes of n heads, and (S, n·Dh) ones are n times the constant
+    and the traffic. So position t = hi·P + lo (P = 128 where it divides S)
+    and cos / sin (t f) come from the angle sums of two tables with all the
+    heads' lanes, (S / P, n·Dh) and (P, n·Dh), a few hundred KB each, which
+    broadcast over the rows of x seen as (B, S / P, P, n·Dh): a bitcast,
+    the row tiles stay whole. The products are f32, as the tables were."""
+    b, s, width = x.shape
+    d_head, half = width // n, rot // 2
+    period = math.gcd(s, 128)
+    inv = 1.0 / theta ** (np.arange(0, rot, 2) / rot)        # host numpy
+    # a head's lanes: frequency i serves dims i and i + rot/2, none past
+    # the rotating dims (angle 0: cos 1, sin 0, the dim passes);
+    # rotate_half's sign goes with the sine
+    freq = np.tile(np.concatenate([inv, inv, np.zeros(d_head - rot)]), n)
+    sign = np.tile(np.concatenate([-np.ones(half), np.ones(half),
+                                   np.zeros(d_head - rot)]), n) * turn
+    table = lambda fn, pos, by=1.0: jnp.asarray(
+        fn(pos[:, None] * freq) * by, jnp.float32)
+    hi, lo = np.arange(0, s, period), np.arange(period)
+    cos_hi, sin_hi = (table(fn, hi)[:, None] for fn in (np.cos, np.sin))
+    cos_lo, sin_lo = table(np.cos, lo), table(np.sin, lo)
+    cos_lo_s, sin_lo_s = table(np.cos, lo, sign), table(np.sin, lo, sign)
+    # between two barriers: the projection's matmul must not take a
+    # product with a table into its own fusion (it would write it out in
+    # f32, twice x's bytes, for the fusion below to read back), and the
+    # compiler must not move the reshapes inward: against (B, S, n·Dh) the
+    # tables do not broadcast, and it builds each at x's size instead
+    x = lax.optimization_barrier(x).reshape(b, s // period, period, width)
+    first = lax.broadcasted_iota(jnp.int32, (1, 1, 1, width), 3) \
+        % d_head < half
+    right = jnp.roll(x, half, axis=-1)                       # x[l - half]
+    partner = jnp.where(first, jnp.roll(right, -rot, axis=-1), right)
+    x32, p32 = x.astype(jnp.float32), partner.astype(jnp.float32)
+    # x cos(hi + lo) + partner sin(hi + lo), each product starting from x
+    # or its partner: a sum of tables alone would be an (S, n·Dh) f32 array
+    # for the compiler to build once a layer and keep
+    out = (x32 * cos_hi) * cos_lo - (x32 * sin_hi) * sin_lo \
+        + (p32 * sin_hi) * cos_lo_s + (p32 * cos_hi) * sin_lo_s
+    return lax.optimization_barrier(out.astype(x.dtype)).reshape(b, s, width)
+
+
+def _rope_lanes_fwd(x, n, rot, theta, turn):
+    return _rope_lanes(x, n, rot, theta, turn), None
+
+
+def _rope_lanes_bwd(n, rot, theta, turn, _, g):
+    return (_rope_lanes(g, n, rot, theta, -turn),)
+
+
+_rope_lanes.defvjp(_rope_lanes_fwd, _rope_lanes_bwd)
+
+
+def _repeat_lanes(t: jax.Array, n: int, times: int) -> jax.Array:
+    """(B, S, n·Dh) -> (B, S, n·times·Dh): each of the ``n`` heads side by
+    side along the lanes ``times`` times over, head h of the result being
+    head h // times (``jnp.repeat`` along the head axis, without one)."""
+    d_head = t.shape[-1] // n
+    return jnp.concatenate(
+        [t[..., i * d_head:(i + 1) * d_head]
+         for i in range(n) for _ in range(times)], axis=-1)
+
+
+def _rope_attention_lanes(q, k, v, n_heads: int, n_kv: int, rot: int,
+                          theta: Optional[float], window: int, k_shared):
+    """``rope_attention`` where a head is whole vregs of lanes: q, k and v
+    stay (B, S, n·Dh) from the projections to the kernels, which address a
+    head as a lane block, and the result is (B, S, n_heads·Dv) as the
+    out-projection reads it. ``theta`` None: no positions."""
+    b, s, _ = q.shape
+    if k_shared is not None:
+        # through four axes, so a copy of k on the TPU: no configuration
+        # has a shared key part beside lane-aligned heads (Kimi's are 192)
+        k = jnp.concatenate([k.reshape(b, s, n_kv, -1), jnp.broadcast_to(
+            k_shared[:, :, None], (b, s, n_kv, k_shared.shape[-1]))],
+            axis=-1).reshape(b, s, -1)
+    if theta is not None:
+        q, k = _rope_lanes(q, n_heads, rot, theta), \
+            _rope_lanes(k, n_kv, rot, theta)
+    if n_kv != n_heads:
+        k, v = (_repeat_lanes(t, n_kv, n_heads // n_kv) for t in (k, v))
+    return maybe_flash_attention(q, k, v, causal=True, window=window,
+                                 heads=n_heads)
 
 
 def attention_sublayer(cfg: TransformerConfig, x: jax.Array, blk: Dict,

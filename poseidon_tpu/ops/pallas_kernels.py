@@ -31,6 +31,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -357,11 +358,11 @@ def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         lse_ref[0] = m_ref[...] + jnp.log(lsafe)
 
 
-def _blocks_for(kernel: str, q, block_q, block_k, dv=None):
+def _blocks_for(kernel: str, s: int, d: int, itemsize: int, block_q, block_k,
+                dv=None):
     """The caller's blocks, or the tile rule's for this kernel."""
-    s, d = q.shape[-2:]
     if block_q is None or block_k is None:
-        blocks = flash_blocks(kernel, s, d, q.dtype.itemsize, dv)
+        blocks = flash_blocks(kernel, s, d, itemsize, dv)
         if blocks is None:
             raise ValueError(f"no aligned block divides seq len {s}")
         return blocks
@@ -372,20 +373,42 @@ def _blocks_for(kernel: str, q, block_q, block_k, dv=None):
     return block_q, block_k
 
 
-def _kv_block_map(clamp: bool, block_q: int, block_k: int, window=None):
-    """Index map of a K/V tile on the (bh, q_blocks, k_blocks) grid.
+def _operand_view(x, heads: Optional[int]):
+    """``(x3, H')``: an operand as the kernels address it, ``(B', S, H'·D)``
+    of which program ``i`` takes the lane block ``i % H'`` of batch row
+    ``i // H'``. Head-major ``(B, H, S, D)`` (``heads`` None) is the case
+    ``B' = B·H, H' = 1``; token-major ``(B, S, H·D)``, the form a projection
+    leaves its result in, is itself with ``H' = heads``."""
+    if heads is not None:
+        return x, heads
+    b, h, s, d = x.shape
+    return x.reshape(b * h, s, d), 1
+
+
+def _tile_at(hp: int):
+    """``at(i, row)``: the block index of program ``i``'s ``(1, block, D)``
+    tile at row block ``row`` of a ``(B', S, H'·D)`` operand."""
+    if hp == 1:
+        return lambda i, row: (i, row, 0)
+    return lambda i, row: (i // hp, row, i % hp)
+
+
+def _kv_block_map(clamp: bool, block_q: int, block_k: int, window=None,
+                  at=_tile_at(1)):
+    """Index map of a K/V tile on the (bh, q_blocks, k_blocks) grid; ``at``
+    places a row block in the operand (``_tile_at``).
     ``clamp`` (causal self-attention): a block above the diagonal names the
     last live K/V tile again, which is resident, so no copy is issued.
     ``window``: the grid's last dimension counts from the Q block's first
     live K/V block."""
     if window is not None:
-        return lambda i, j, kk: (
+        return lambda i, j, kk: at(
             i, jnp.minimum(_first_live_k(j, block_q, block_k, window) + kk,
-                           _last_live_k(j, block_q, block_k)), 0)
+                           _last_live_k(j, block_q, block_k)))
     if clamp:
-        return lambda i, j, kk: (
-            i, jnp.minimum(kk, _last_live_k(j, block_q, block_k)), 0)
-    return lambda i, j, kk: (i, kk, 0)
+        return lambda i, j, kk: at(
+            i, jnp.minimum(kk, _last_live_k(j, block_q, block_k)))
+    return lambda i, j, kk: at(i, kk)
 
 
 def _compiler_params():
@@ -396,27 +419,34 @@ def _compiler_params():
 
 def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
                block_k: Optional[int], interpret: bool, mode=None,
-               window: Optional[int] = None):
-    """mode (traced int32 scalar) selects chunked causal masking for ring
+               window: Optional[int] = None, heads: Optional[int] = None):
+    """q, k, v head-major ``(B, H, S, D)``, or with ``heads`` token-major
+    ``(B, S, heads·D)`` as the projections leave them: one grid
+    ``(B·H, q_blocks, k_blocks)`` either way, a head being a lane block in
+    the index maps (``_operand_view``). Returns the output in the operands'
+    form and the logsumexp ``(B, H, S)``.
+
+    mode (traced int32 scalar) selects chunked causal masking for ring
     attention; None = plain self-attention. Blocks of None: the tile rule's
     (``flash_blocks``). ``window`` (causal self-attention, below S): the
     grid covers the band alone."""
-    b, h, s, d = q.shape
-    dv = v.shape[-1]                    # a v head may have its own width
-    bh = b * h
-    q3 = q.reshape(bh, s, d)
-    k3 = k.reshape(bh, s, d)
-    v3 = v.reshape(bh, s, dv)
-    block_q, block_k = _blocks_for("fwd", q, block_q, block_k, dv)
+    (q3, hp), (k3, _), (v3, _) = (_operand_view(x, heads) for x in (q, k, v))
+    bp, s, _ = q3.shape
+    d, dv = q3.shape[-1] // hp, v3.shape[-1] // hp  # v heads: their own width
+    bh = bp * hp
+    block_q, block_k = _blocks_for("fwd", s, d, q.dtype.itemsize, block_q,
+                                   block_k, dv)
     n_kb = s // block_k
     extra = {}
     if window is not None:
         n_kb = _band_steps(s, block_q, block_k, window)
         extra = {"window": window}
     grid = (bh, s // block_q, n_kb)
+    at = _tile_at(hp)
     chunk = mode is not None
-    kmap = _kv_block_map(causal and not chunk, block_q, block_k, window)
-    qmap = lambda i, j, kk: (i, j, 0)
+    kmap = _kv_block_map(causal and not chunk, block_q, block_k, window, at)
+    qmap = lambda i, j, kk: at(i, j)
+    rowmap = lambda i, j, kk: (i, j, 0)
     in_specs = [
         pl.BlockSpec((1, block_q, d), qmap, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), kmap, memory_space=pltpu.VMEM),
@@ -432,13 +462,13 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
                           chunk_mode=chunk,
                           op_dtype=_operand_dtype(q.dtype), **extra),
         name="flash_fwd",
-        out_shape=(jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((bp, s, hp * dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)),
         grid=grid,
         in_specs=in_specs,
         out_specs=(
             pl.BlockSpec((1, block_q, dv), qmap, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), qmap, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, 1), rowmap, memory_space=pltpu.VMEM),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, dv), jnp.float32),
@@ -448,7 +478,10 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(*args)
-    return out.reshape(b, h, s, dv), lse.reshape(b, h, s)
+    h = heads or q.shape[1]
+    if heads is None:
+        out = out.reshape(q.shape[:-1] + (dv,))
+    return out, lse.reshape(bh // h, h, s)
 
 
 # --------------------------------------------------------------------------- #
@@ -560,34 +593,52 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
 def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
                block_q: Optional[int], block_k: Optional[int],
                interpret: bool, mode=None, delta=None,
-               window: Optional[int] = None):
-    """mode, blocks, window: see _flash_fwd (the rule sizes the two sweeps
-    apart).
-    ``delta`` (rowsum(dO*O), global) may be passed in by the ring backward,
-    whose O is the merged global output."""
-    b, h, s, d = q.shape
-    d_v = v.shape[-1]                   # v, out and g: a v head's width
-    bh = b * h
+               window: Optional[int] = None, heads: Optional[int] = None):
+    """mode, blocks, window, heads: see _flash_fwd (the rule sizes the two
+    sweeps apart); out and g come in the operands' form, lse is (B, H, S)
+    and dq, dk, dv leave in the operands' form.
+    ``delta`` (rowsum(dO*O), global, (B, H, S)) may be passed in by the ring
+    backward, whose O is the merged global output."""
+    hp = heads or 1
+    d, d_v = q.shape[-1] // hp, v.shape[-1] // hp   # v, out, g: a v head's
     if delta is None:
         # delta_i = rowsum(dO * O): one O(S*D) elementwise pass, XLA-fused
-        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                        axis=-1)                   # (b, h, s)
-    r3 = lambda x: x.reshape(bh, s, x.shape[-1])
-    q3, k3, v3, g3 = r3(q), r3(k), r3(v), r3(g)
+        if heads is None:
+            delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                            axis=-1)                   # (b, h, s)
+        else:
+            # the sum over a head's lanes as a product with the heads'
+            # 0 / 1 lane masks, which leaves it (b, h, s) as the kernels
+            # want it: (b, s, h, d_v) is another layout under the (8, 128)
+            # tiling, and a copy of dO's size. A product of two bf16 values
+            # is exact in f32 and the f32 policy's passes keep it so. Behind
+            # a barrier: the matmul that makes dO would take dO * O into its
+            # fusion and write it out in f32 for this one to read
+            lanes = np.kron(np.eye(hp, dtype=np.float32),
+                            np.ones(d_v, np.float32))
+            delta = jnp.einsum(
+                "bsl,hl->bhs", lax.optimization_barrier(g).astype(jnp.float32)
+                * out.astype(jnp.float32), lanes,
+                precision=lax.Precision.HIGHEST)
+    (q3, _), (k3, _), (v3, _), (g3, _) = (
+        _operand_view(x, heads) for x in (q, k, v, g))
+    bp, s, _ = q3.shape
+    bh = bp * hp
     chunk = mode is not None
     mode_arg = [jnp.asarray(mode, jnp.int32).reshape(1)] if chunk else []
     smem = [pl.BlockSpec(memory_space=pltpu.SMEM)] if chunk else []
     op_dtype = _operand_dtype(q.dtype)
     clamp = causal and not chunk
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    at = _tile_at(hp)
 
     # dQ sweep: grid (bh, q_blocks, k_blocks), the forward's
-    bq, bk = _blocks_for("dq", q, block_q, block_k, d_v)
-    qmap = lambda i, j, kk: (i, j, 0)
-    kmap = _kv_block_map(clamp, bq, bk, window)
+    bq, bk = _blocks_for("dq", s, d, q.dtype.itemsize, block_q, block_k, d_v)
+    qmap = lambda i, j, kk: at(i, j)
+    kmap = _kv_block_map(clamp, bq, bk, window, at)
     qspec, gspec = vmem((1, bq, d), qmap), vmem((1, bq, d_v), qmap)
     kspec, vspec = vmem((1, bk, d), kmap), vmem((1, bk, d_v), kmap)
-    rowq = vmem((1, bq, 1), qmap)
+    rowq = vmem((1, bq, 1), lambda i, j, kk: (i, j, 0))
     n_kb, extra = s // bk, {}
     if window is not None:
         n_kb = _band_steps(s, bq, bk, window)
@@ -597,7 +648,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
                           block_q=bq, block_k=bk, n_kb=n_kb,
                           chunk_mode=chunk, op_dtype=op_dtype, **extra),
         name="flash_bwd_dq",
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q3.shape, q.dtype),
         grid=(bh, s // bq, n_kb),
         in_specs=smem + [qspec, kspec, vspec, gspec, rowq, rowq],
         out_specs=qspec,
@@ -609,7 +660,8 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
 
     # dK/dV sweep: swapped grid (bh, k_blocks, q_blocks); the row statistics
     # one lane-dense (1, block_q) row per Q block
-    bq, bk = _blocks_for("dkv", q, block_q, block_k, d_v)
+    bq, bk = _blocks_for("dkv", s, d, q.dtype.itemsize, block_q, block_k,
+                         d_v)
     n_qb = steps = s // bq
     extra = {}
     if window is not None:
@@ -626,8 +678,8 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
         qblk = lambda j, kk: jnp.maximum(kk, _first_live_q(j, bq, bk))
     else:
         qblk = lambda j, kk: kk
-    qmap_t = lambda i, j, kk: (i, qblk(j, kk), 0)
-    kmap_t = lambda i, j, kk: (i, j, 0)
+    qmap_t = lambda i, j, kk: at(i, qblk(j, kk))
+    kmap_t = lambda i, j, kk: at(i, j)
     qspec_t, gspec_t = vmem((1, bq, d), qmap_t), vmem((1, bq, d_v), qmap_t)
     kspec_t, vspec_t = vmem((1, bk, d), kmap_t), vmem((1, bk, d_v), kmap_t)
     rowq_t = vmem((1, 1, 1, bq), lambda i, j, kk: (i, qblk(j, kk), 0, 0))
@@ -636,8 +688,8 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
                           block_q=bq, block_k=bk, n_qb=steps,
                           chunk_mode=chunk, op_dtype=op_dtype, **extra),
         name="flash_bwd_dkv",
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d_v), v.dtype)),
+        out_shape=(jax.ShapeDtypeStruct(k3.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v.dtype)),
         grid=(bh, s // bk, steps),
         in_specs=smem + [qspec_t, kspec_t, vspec_t, gspec_t, rowq_t, rowq_t],
         out_specs=(kspec_t, vspec_t),
@@ -648,8 +700,9 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     )(*mode_arg, q3, k3, v3, g3, lse.reshape(bh, n_qb, 1, bq),
       delta.reshape(bh, n_qb, 1, bq))
 
-    rs = lambda x: x.reshape(b, h, s, x.shape[-1])
-    return rs(dq), rs(dk), rs(dv)
+    # head-major: back to the caller's four axes (a reshape of nothing on
+    # the token-major form)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def _band_window(window: Optional[int], causal: bool, s: int):
@@ -662,52 +715,83 @@ def _band_window(window: Optional[int], causal: bool, s: int):
     return int(window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    window: Optional[int] = None):
-    """Pallas blockwise attention; (B, H, S, D) -> (B, H, S, D). With no
+                    window: Optional[int] = None,
+                    heads: Optional[int] = None):
+    """Pallas blockwise attention; (B, H, S, D) -> (B, H, S, Dv), or with
+    ``heads`` token-major (B, S, heads·D) -> (B, S, heads·Dv): the same
+    three kernels on the same grid, a head addressed as a lane block
+    (``_operand_view``), gradients in the operands' form. With no
     blocks given each of the three kernels takes ``flash_blocks``' tiles;
     a given (block_q, block_k) is used by all three. ``window`` (causal):
     token t attends to s with t - window < s <= t; the three kernels then
     run, fetch and visit the band's blocks alone."""
     out, _ = _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k,
-                            interpret, window)
+                            interpret, window, heads)
     return out
 
 
+def _flash_scale(q, scale, heads):
+    if scale is not None:
+        return scale
+    return (q.shape[-1] // (heads or 1)) ** -0.5
+
+
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                   window=None):
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
+                   window=None, heads=None):
     if interpret is None:
         interpret = _interpret_default()
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                          window=_band_window(window, causal, q.shape[-2]))
+    out, lse = _flash_fwd(q, k, v, _flash_scale(q, scale, heads), causal,
+                          block_q, block_k, interpret,
+                          window=_band_window(window, causal, q.shape[-2]),
+                          heads=heads)
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window, res,
-                   g):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window, heads,
+                   res, g):
     q, k, v, out, lse = res
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = _interpret_default()
-    return _flash_bwd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
-                      interpret,
-                      window=_band_window(window, causal, q.shape[-2]))
+    return _flash_bwd(q, k, v, out, lse, g, _flash_scale(q, scale, heads),
+                      causal, block_q, block_k, interpret,
+                      window=_band_window(window, causal, q.shape[-2]),
+                      heads=heads)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+TOKEN_MAJOR = "operands token-major (B,S,HxD)"
+
+
+def flash_operand_form(s: int, d: int, dv: Optional[int] = None):
+    """``(token_major, note)``: the form the flash kernels take the
+    projections' results in — a function of the shape alone. Token-major
+    ``(B, S, H·D)`` as they lie, where a head is whole vregs of lanes
+    (``d`` and ``dv`` multiples of 128) and the sequence tiles: no split /
+    merge transpose then stands between a projection and a kernel.
+    Head-major ``(B, H, S, D)`` elsewhere: a lane block narrower than a
+    vreg is not a legal tile."""
+    for width in (d, d if dv is None else dv):
+        if width % 128:
+            # no ": " inside: the note is a stats.yaml leaf
+            return False, (f"operands head-major (Dh {width}, not "
+                           f"lane-aligned)")
+    if pick_block(s) is None:
+        return False, f"operands head-major (S {s} does not tile)"
+    return True, TOKEN_MAJOR
+
+
 def attention_route(s: int, sk: int, d: int, itemsize: int,
                     causal: bool = True, window: Optional[int] = None,
-                    dv: Optional[int] = None):
+                    dv: Optional[int] = None,
+                    token_major: Optional[bool] = None):
     """``(arm, note)`` for one attention geometry — THE routing decision:
     ``maybe_flash_attention`` takes it at trace time and ``Net`` logs it
     per ATTENTION layer at construction, for Q and K/V lengths ``s`` and
@@ -715,7 +799,10 @@ def attention_route(s: int, sk: int, d: int, itemsize: int,
     sequence tiles cleanly (an aligned block divides it, self-attention
     lengths), the note then stating each kernel's ``block_q x block_k`` from
     ``flash_blocks`` and the live / visited programs of its grid per head
-    (with a ``window`` below S: the band's, and the note says so);
+    (with a ``window`` below S: the band's, and the note says so) and the
+    operands' form (``flash_operand_form``'s for a caller that holds the
+    projections' results, an ATTENTION layer; ``token_major``: the form a
+    caller handed them over in);
     ``"dense"`` on the CPU test mesh (the kernel would run in
     interpret-mode emulation — strictly slower than the dense op it
     replaces) and for shapes the kernel does not tile."""
@@ -732,30 +819,46 @@ def attention_route(s: int, sk: int, d: int, itemsize: int,
         live, visited = flash_grid_programs(s, bq, bk, causal, window,
                                             over_q=kernel == "dkv")
         parts.append(f"{kernel} {bq}x{bk} {live}/{visited}")
+    by_rule, form = flash_operand_form(s, d, dv)
+    if token_major not in (None, by_rule):
+        form = TOKEN_MAJOR if token_major \
+            else "operands head-major (the caller's)"
     return "pallas_flash", ", ".join(parts) + \
         "; block_q x block_k, live/visited programs a head" + \
         (f"; window {window}: the band's grid" if window else "") + \
-        (f"; flash d {d}/{dv}" if dv not in (None, d) else "")
+        (f"; flash d {d}/{dv}" if dv not in (None, d) else "") + \
+        f"; {form}"
 
 
 def maybe_flash_attention(q, k, v, causal: bool = False,
                           scale: Optional[float] = None,
-                          window: Optional[int] = None) -> jax.Array:
+                          window: Optional[int] = None,
+                          heads: Optional[int] = None) -> jax.Array:
     """Attention through :func:`attention_route`'s arm — and say which,
     once per shape. The training entry point for models/transformer.py
-    (both blocks) and the Ulysses head-parallel path."""
+    (both blocks) and the Ulysses head-parallel path. Operands and result
+    head-major ``(B, H, S, D)``, or with ``heads`` token-major
+    ``(B, S, heads·D)``, the form ``flash_operand_form`` names for the
+    caller that holds the projections' results (``rope_attention``)."""
     from .attention import attention
-    s, d = q.shape[-2:]
-    dv = v.shape[-1]
+    s = q.shape[-2]
+    d, dv = (t.shape[-1] // (heads or 1) for t in (q, v))
     arm, note = attention_route(s, k.shape[-2], d, q.dtype.itemsize, causal,
-                                window, dv)
+                                window, dv, token_major=heads is not None)
     where = f"[kernel_route] attention S={s} D={d}" \
         + (f"/{dv}" if dv != d else "")
     if arm == "pallas_flash":
         _log_route_once(f"{where}: pallas flash, {note}")
-        return flash_attention(q, k, v, causal, scale, window=window)
+        return flash_attention(q, k, v, causal, scale, window=window,
+                               heads=heads)
     _log_route_once(f"{where}: dense ({note})")
-    return attention(q, k, v, causal=causal, scale=scale, window=window)
+    if heads is not None:
+        # the dense op is head-major: the CPU test mesh's arm
+        b = q.shape[0]
+        q, k, v = (t.reshape(b, s, heads, -1).swapaxes(1, 2)
+                   for t in (q, k, v))
+    out = attention(q, k, v, causal=causal, scale=scale, window=window)
+    return out if heads is None else out.swapaxes(1, 2).reshape(b, s, -1)
 
 
 # --------------------------------------------------------------------------- #

@@ -50,8 +50,8 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
         result = json.load(f)
     cold, warm = result["cold"], result["warm"]
     assert cold["cache_dir"] == str(cache)
-    assert sorted(cold["phases"]) == ["held_chunks", "kernels", "resume",
-                                      "train_bf16", "train_f32",
+    assert sorted(cold["phases"]) == ["flash", "held_chunks", "kernels",
+                                      "resume", "train_bf16", "train_f32",
                                       "train_sfb_auto"]
     assert cold["phases"]["train_bf16"]["steps"] == 24
     assert cold["phases"]["resume"]["compiled_step"]["source"] == "loaded"
@@ -66,6 +66,13 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
         "ms at chunk 128", "ms at chunk 256", "ms at chunk 512"]
     assert sorted(held["ms at chunk 256"]) == ["0.03", "0.06", "0.125",
                                                "0.25", "0.5", "1.0"]
+    # the flash kernels on both operand forms where the route rule allows
+    flash = cold["phases"]["flash"]
+    assert flash["tiny"]["operand form"] == "operands token-major (B,S,HxD)"
+    assert flash["tiny narrow"]["operand form"] == \
+        "operands head-major (Dh 24, not lane-aligned)"
+    assert sum("relative l2" in k for k in flash["tiny"]) == 8
+    assert sum("relative l2" in k for k in flash["tiny narrow"]) == 4
     assert sorted(os.listdir(cache / "aot"))   # the step store rode along
 
 

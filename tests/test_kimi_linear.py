@@ -475,7 +475,8 @@ def test_mla_route_on_the_chip(monkeypatch):
                 head_dim=32)
     route = net.kernel_routes["l3_mla_attn"]
     assert route.startswith("attention=pallas_flash (fwd 256x256 1/1") \
-        and route.endswith("; flash d 192/128); no positions; "
+        and route.endswith("; flash d 192/128; operands head-major (Dh 192, "
+                           "not lane-aligned)); no positions; "
                            "k_pe repeated x2"), route
 
 

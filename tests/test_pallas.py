@@ -206,7 +206,8 @@ def test_attention_route_states_tiles_and_programs(monkeypatch):
     assert arm == "pallas_flash"
     assert note == ("fwd 1024x1024 10/16, dq 1024x1024 10/16, "
                     "dkv 1024x1024 10/16; block_q x block_k, "
-                    "live/visited programs a head")
+                    "live/visited programs a head; "
+                    "operands token-major (B,S,HxD)")
     assert ": " not in note          # it is a stats.yaml leaf
     assert attention_route(4096, 2048, 128, 2)[0] == "dense"
     assert attention_route(100, 100, 16, 4) == (
@@ -361,3 +362,134 @@ def test_lrn_fused_falls_back_to_xla_above_tile_cap():
         lambda x_: jnp.sum(lrn_fused(x_, 5, 1e-4, 0.75) ** 2))(x)
     np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_want),
                                rtol=1e-5, atol=1e-6)
+
+
+# --------------------------------------------------------------------------- #
+# token-major operands: (B, S, H·D) as the projections leave them, a head a
+# lane block in the kernels' index maps, against head-major (B, H, S, D)
+# --------------------------------------------------------------------------- #
+
+def _token_major(t):
+    """(B, H, S, D) -> (B, S, H·D)."""
+    b, h, s, d = t.shape
+    return t.swapaxes(1, 2).reshape(b, s, h * d)
+
+
+# name: (S, heads, kv heads, Dh, Dv, causal, window, blocks, dtype)
+TOKEN_MAJOR_CASES = {
+    "causal": (128, 3, 3, 32, 32, True, None, (32, 64), jnp.float32),
+    "full": (128, 3, 3, 32, 32, False, None, (64, 32), jnp.float32),
+    "rule_blocks": (128, 2, 2, 128, 128, True, None, (None, None),
+                    jnp.float32),
+    "window": (128, 2, 2, 16, 16, True, 40, (16, 32), jnp.float32),
+    "window_unequal": (64, 2, 2, 16, 16, True, 20, (32, 8), jnp.float32),
+    # two head widths, both whole vregs of lanes
+    "two_widths": (128, 2, 2, 256, 128, True, None, (64, 32), jnp.float32),
+    "values_wider": (128, 2, 2, 128, 256, True, 48, (32, 64), jnp.float32),
+    # grouped query: k and v repeated to the query heads outside the kernel
+    "grouped": (128, 4, 2, 128, 128, True, None, (64, 64), jnp.float32),
+    "grouped_window": (128, 8, 2, 32, 32, True, 50, (32, 32), jnp.float32),
+    "bf16": (128, 2, 2, 128, 128, True, None, (64, 64), jnp.bfloat16),
+    "bf16_window": (128, 4, 1, 128, 128, True, 40, (32, 64), jnp.bfloat16),
+    "small_block": (64, 2, 2, 128, 128, True, None, (8, 8), jnp.float32),
+    "small_block_window": (64, 3, 3, 16, 16, True, 12, (8, 16),
+                           jnp.float32),
+}
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", sorted(TOKEN_MAJOR_CASES))
+def test_token_major_operands_match_head_major(case, what):
+    """The three kernels (interpret mode) on (B, S, H·D) operands with
+    ``heads`` against the same kernels on (B, H, S, D): the output and all
+    three gradients, the key-value heads' gradients summed over the query
+    heads that read them where they are repeated."""
+    from poseidon_tpu.models.transformer import _repeat_lanes
+    s, h, g, d, dv, causal, window, blocks, dtype = TOKEN_MAJOR_CASES[case]
+    rs = np.random.RandomState(sum(map(ord, case)))
+    mk = lambda n, w: jnp.asarray(
+        rs.randn(2, n, s, w).astype(np.float32) * 0.3).astype(dtype)
+    q, k, v, co = mk(h, d), mk(g, d), mk(g, dv), mk(h, dv)
+
+    def head_major(q, k, v):
+        k, v = (jnp.repeat(t, h // g, axis=1) for t in (k, v))
+        out = flash_attention(q, k, v, causal, None, *blocks, True, window)
+        return _token_major(out)
+
+    def token_major(q, k, v):
+        q, k, v = (_token_major(t) for t in (q, k, v))
+        if g != h:
+            k, v = (_repeat_lanes(t, g, h // g) for t in (k, v))
+        return flash_attention(q, k, v, causal, None, *blocks, True, window,
+                               h)
+
+    if what == "out":
+        got, want = token_major(q, k, v), head_major(q, k, v)
+        assert got.shape == (2, s, h * dv) and got.dtype == dtype
+    else:
+        i = ("dq", "dk", "dv").index(what)
+        loss = lambda f: lambda *a: jnp.sum(
+            (f(*a) * _token_major(co)).astype(jnp.float32))
+        got, want = (jax.grad(loss(f), argnums=i)(q, k, v)
+                     for f in (token_major, head_major))
+        assert got.shape == (q, k, v)[i].shape and got.dtype == dtype
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-6
+    assert _rel_l2(got, want) < tol
+
+
+def test_token_major_operands_against_the_dense_op(qkv):
+    """And against the dense op directly, forward and backward."""
+    q, k, v = qkv
+    dense = lambda *a: _token_major(attention(*a, causal=True))
+    flash = lambda *a: flash_attention(
+        *(_token_major(t) for t in a), True, None, 32, 64, True, None, H)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), rtol=2e-4,
+                               atol=2e-5)
+    gr, gf = (jax.grad(lambda *a, f=f: jnp.sum(f(*a) ** 2),
+                       argnums=(0, 1, 2))(q, k, v) for f in (dense, flash))
+    for a, b in zip(gr, gf):
+        np.testing.assert_allclose(b, a, rtol=5e-3, atol=5e-4)
+
+
+@pytest.mark.parametrize("s,d,dv,want", [
+    (8192, 128, None, (True, "operands token-major (B,S,HxD)")),
+    (4096, 128, 128, (True, "operands token-major (B,S,HxD)")),
+    (16384, 256, 128, (True, "operands token-major (B,S,HxD)")),
+    (8192, 192, 128,
+     (False, "operands head-major (Dh 192, not lane-aligned)")),
+    (8192, 128, 64, (False, "operands head-major (Dh 64, not lane-aligned)")),
+    (256, 64, None, (False, "operands head-major (Dh 64, not lane-aligned)")),
+    (100, 128, None, (False, "operands head-major (S 100 does not tile)")),
+])
+def test_operand_form_rule(s, d, dv, want):
+    """Token-major where a head is whole vregs of lanes and the sequence
+    tiles, head-major elsewhere: by the shape alone."""
+    from poseidon_tpu.ops.pallas_kernels import flash_operand_form
+    assert flash_operand_form(s, d, dv) == want
+
+
+def test_attention_route_names_the_operand_form(monkeypatch):
+    from poseidon_tpu.ops.pallas_kernels import attention_route
+    monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+    note = lambda *a, **kw: attention_route(*a, **kw)[1]
+    assert note(8192, 8192, 128, 2).endswith(
+        "; operands token-major (B,S,HxD)")
+    assert note(8192, 8192, 192, 2, dv=128).endswith(
+        "; flash d 192/128; operands head-major (Dh 192, not lane-aligned)")
+    assert note(2048, 2048, 64, 2).endswith(
+        "; operands head-major (Dh 64, not lane-aligned)")
+    # a caller that hands the operands over head-major says so
+    assert note(8192, 8192, 128, 2, token_major=False).endswith(
+        "; operands head-major (the caller's)")
+    assert ": " not in note(8192, 8192, 192, 2, dv=128)  # a stats.yaml leaf
+
+
+def test_maybe_flash_routing_with_token_major_operands(qkv):
+    """Off-TPU a token-major caller gets the dense op on its heads split
+    out: bit-identical to attention() merged back."""
+    from poseidon_tpu.ops.pallas_kernels import maybe_flash_attention
+    q, k, v = qkv
+    got = maybe_flash_attention(*(_token_major(t) for t in (q, k, v)),
+                                causal=True, heads=H)
+    np.testing.assert_array_equal(
+        got, _token_major(attention(q, k, v, causal=True)))

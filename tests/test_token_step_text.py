@@ -14,8 +14,9 @@ equal once their debug locations (pallas_kernels.py's line numbers) are
 stripped (CHANGES.md, PR 36).
 
 A PR that means to change what these configurations trace to (PR 32 did,
-and cost ``ouro.loop4.pack8k`` 9 s of set-up) updates the hashes and says
-so; one that does not has tripped over a shared path."""
+and cost ``ouro.loop4.pack8k`` 9 s of set-up; PR 47 did, for the four whose
+heads are 128 wide, and left Kimi-Linear's alone) updates the hashes and
+says so; one that does not has tripped over a shared path."""
 
 import hashlib
 import re
@@ -52,17 +53,20 @@ BUILD = {
         top_k=2, held=2, expert_width=32, shared_width=32, vocab=128),
 }
 PARENT = {       # sha256 of the text, its length, its pallas_call equations
-    "olmoe": ("25c3770f48fbe6e7315504d351947292a5dcecef1a20bef3b4b89015be04"
-              "e49b", 77656, 3),
-    "ouro": ("2f7e8cf85387640af63eaea1da12d5efddf2e2e06aa209a410475edf3e73"
-             "1d48", 126180, 6),
-    "zaya": ("69ea43acba1fee552fb6444330b4ca8d5085dc25495f70bc87860f9ec904"
-             "582d", 181755, 6),
-    # PR 43's own (the held arm changed: at this size, 4,096 assignments,
-    # one chunk holds every row and the rows run as straight-line code
-    # where PR 37's two-rung ladder ran; ZAYA1's, above, is the parent's)
-    "trinity": ("d585d8bea6f40d1163dfe51cefbe012ac86f511c8100fffc577bd58e7d"
-                "4dd7b3", 149616, 6),
+    # PR 47's own, all four: it meant to change them. Every ATTENTION layer
+    # here has 128-wide heads, so q, k, v reach the kernels token-major
+    # (``flash_operand_form``): no transposes, rotary along the lanes, the
+    # repeat as lane slices, a head as a lane block in the index maps; the
+    # same number of pallas_call equations. Kimi's (192 / 128: head-major)
+    # is still the one PR 43 took, to the byte
+    "olmoe": ("c95c2e07f6f0e5f2f0e2313eee9bc34d0c3c809106c5f37c86d1a2ccccf2"
+              "544e", 90564, 3),
+    "ouro": ("80f533feb0ebfce4737018eebe5c76128c68b109d33a9f65198b5299a8e4"
+             "d147", 148212, 6),
+    "zaya": ("88039d360826c975969ef376180bf657e195c75345dcab93043ff5402422"
+             "41d9", 202519, 6),
+    "trinity": ("66f5b8eed4b69871badff25b26bd757d23b25e2d6d39228db001544846"
+                "d62889", 163462, 6),
     # PR 43's own likewise: moves with ops/kda.py, the KDA layers and the
     # held arm, and with nothing else
     "kimi": ("9bdcb9bc42efc615f3803c633d2f5a4bc1d7747b6ebfb674c0722604e860"

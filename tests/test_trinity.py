@@ -134,11 +134,17 @@ def test_kernel_route_names_the_band_on_the_chip(monkeypatch):
     net = build(n=1, s=256, window=64)
     assert net.kernel_routes["l0_attn_window"].startswith(
         "attention=pallas_flash (fwd 256x256 1/1")
+    # every ATTENTION layer's route names its operands' form: narrow heads
+    # head-major, whole vregs of lanes token-major
+    assert all("; operands head-major (Dh " in r and "not lane-aligned)" in r
+               for n, r in net.kernel_routes.items() if "_attn_" in n)
     net = build(n=1, s=256, window=64, head_dim=128, hidden=128, heads=2,
                 kv_heads=1)
     route = net.kernel_routes["l0_attn_window"]
-    assert "window 64: the band's grid" in route \
-        and route.endswith("1 kv heads repeated x2")
+    assert "window 64: the band's grid; operands token-major (B,S,HxD))" \
+        in route and route.endswith("1 kv heads repeated x2")
+    assert "; operands token-major (B,S,HxD))" \
+        in net.kernel_routes["l4_attn_global"]
     assert "window" not in net.kernel_routes["l4_attn_global"] \
         and net.kernel_routes["l4_attn_global"].endswith("no positions")
 

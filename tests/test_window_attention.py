@@ -190,7 +190,8 @@ def test_tile_rule_and_route_with_two_widths(monkeypatch):
     assert pk.flash_blocks("dkv", 8192, 128, 4) == (1024, 1024)
     monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
     arm, note = pk.attention_route(8192, 8192, 192, 2, dv=128)
-    assert arm == "pallas_flash" and note.endswith("; flash d 192/128")
+    assert arm == "pallas_flash" and note.endswith(
+        "; flash d 192/128; operands head-major (Dh 192, not lane-aligned)")
     assert "flash d" not in pk.attention_route(8192, 8192, 128, 2, dv=128)[1]
 
 
@@ -213,3 +214,121 @@ def test_shared_key_part_is_joined_to_every_head():
     want = attention(heads(q), full.swapaxes(1, 2), heads(v), causal=True)
     np.testing.assert_allclose(got, want.swapaxes(1, 2).reshape(b, s, -1),
                                rtol=1e-5, atol=1e-6)
+
+
+# --------------------------------------------------------------------------- #
+# rope_attention where a head is whole vregs of lanes: q, k and v stay
+# (B, S, H·Dh) from the projections to the kernels (rotation, key-value
+# repeat and shared key part along the lanes), against the head-major route
+# --------------------------------------------------------------------------- #
+
+# name: (heads, kv heads, Dh, Dv, rotary_dims, rope, window, shared)
+LANE_CASES = {
+    "mha": (2, 2, 128, 128, 0, True, 0, 0),
+    "no_positions": (2, 2, 128, 128, 0, False, 0, 0),
+    "partial_rotary": (2, 1, 128, 128, 64, True, 0, 0),
+    "grouped": (4, 2, 128, 128, 0, True, 0, 0),
+    "grouped_window": (4, 1, 128, 128, 0, True, 24, 0),
+    "grouped_window_no_positions": (4, 2, 128, 128, 0, False, 24, 0),
+    "values_wider": (2, 1, 128, 256, 32, True, 0, 0),
+    "shared_key_part": (2, 2, 256, 128, 64, True, 0, 64),
+}
+
+
+@pytest.mark.parametrize("kernels", ["dense", "flash"])
+@pytest.mark.parametrize("case", sorted(LANE_CASES))
+def test_rope_attention_lanes_route_matches_head_major(case, kernels,
+                                                       monkeypatch):
+    """``rope_attention`` takes the token-major route at lane-aligned head
+    widths (``flash_operand_form``); turned off, the same call takes the
+    head-major one: outputs and the gradients of every operand agree, with
+    the dense op and with the interpreted kernels under both."""
+    from poseidon_tpu.models import transformer as tr
+    h, g, d, dv, rot, rope, window, shared = LANE_CASES[case]
+    b, s = 2, 64
+    key = jax.random.split(jax.random.PRNGKey(len(case)), 5)
+    q = jax.random.normal(key[0], (b, s, h * d))
+    k = jax.random.normal(key[1], (b, s, g * (d - shared)))
+    v = jax.random.normal(key[2], (b, s, g * dv))
+    co = jax.random.normal(key[3], (b, s, h * dv))
+    kpe = jax.random.normal(key[4], (b, s, shared)) if shared else None
+    forms = []
+    if kernels == "flash":
+        def att(q_, k_, v_, causal, scale=None, window=None, heads=None):
+            forms.append(heads)
+            return pk.flash_attention(q_, k_, v_, causal, scale, 32, 16,
+                                      True, window, heads)
+        monkeypatch.setattr(tr, "maybe_flash_attention", att)
+
+    def run():
+        f = lambda q_, k_, v_, kpe_: tr.rope_attention(      # noqa: E731
+            q_, k_, v_, h, 1e4, g, rot, window, rope, kpe_)
+        out, vjp = jax.vjp(f, q, k, v, kpe)
+        return (out,) + vjp(co)[:4 if shared else 3]
+
+    assert tr.flash_operand_form(s, d, dv)[0]
+    got = run()
+    monkeypatch.setattr(tr, "flash_operand_form",
+                        lambda *a: (False, "operands head-major (test)"))
+    want = run()
+    if kernels == "flash":              # both routes were taken
+        assert h in forms and None in forms
+    assert got[0].shape == (b, s, h * dv)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape
+        np.testing.assert_allclose(a, w, rtol=2e-5, atol=2e-5)
+
+
+def test_rope_attention_keeps_narrow_heads_head_major(monkeypatch):
+    """Heads that are not whole vregs of lanes (192 / 128, 64) go to the
+    kernels head-major, as they always did."""
+    from poseidon_tpu.models import transformer as tr
+    seen = []
+
+    def att(q_, k_, v_, causal, scale=None, window=None, heads=None):
+        seen.append((q_.shape, heads))
+        return attention(q_, k_, v_, causal=causal, scale=scale,
+                         window=window)
+    monkeypatch.setattr(tr, "maybe_flash_attention", att)
+    x = jnp.ones((1, 32, 2 * 192))
+    tr.rope_attention(x, x[..., :2 * 128], x[..., :2 * 128], 2,
+                      rotary_dims=64, k_shared=x[..., :64])
+    tr.rope_attention(x[..., :128], x[..., :128], x[..., :128], 2)
+    assert seen == [((1, 2, 32, 192), None), ((1, 2, 32, 64), None)]
+
+
+@pytest.mark.parametrize("s,n,d,rot", [
+    (256, 2, 128, 128),     # two table rows of 128 positions
+    (384, 3, 128, 64),      # partial rotary, three
+    (192, 2, 128, 128),     # 128 does not divide S: periods of 64
+    (136, 1, 256, 32),      # periods of 8
+    (64, 4, 128, 128),      # one period: the low table alone
+])
+def test_rope_along_the_lanes_is_apply_rope(s, n, d, rot):
+    """``_rope_lanes`` on (B, S, n·Dh), its cos / sin from the angle sums
+    of a (S / P, n·Dh) and a (P, n·Dh) table, against ``apply_rope`` on
+    (B, n, S, Dh) with the (S, Dh) tables: values to f32 rounding, and the
+    hand-written backward (the rotation the other way) against autodiff's."""
+    from poseidon_tpu.models import transformer as tr
+    b = 2
+    x = jax.random.normal(jax.random.PRNGKey(s), (b, s, n * d))
+    g = jax.random.normal(jax.random.PRNGKey(s + 1), (b, s, n * d))
+    cos, sin = tr.rope_tables(s, rot, 1e4)
+
+    def by_head(x_):
+        t = x_.reshape(b, s, n, d).swapaxes(1, 2)
+        t = jnp.concatenate([tr.apply_rope(t[..., :rot], cos, sin),
+                             t[..., rot:]], axis=-1)
+        return t.swapaxes(1, 2).reshape(b, s, n * d)
+
+    want, want_vjp = jax.vjp(by_head, x)
+    got, got_vjp = jax.vjp(lambda x_: tr._rope_lanes(x_, n, rot, 1e4), x)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+    np.testing.assert_allclose(got_vjp(g)[0], want_vjp(g)[0], rtol=0,
+                               atol=2e-6)
+    # bf16 in, bf16 out: the same rounding of the same f32 values
+    xb = x.astype(jnp.bfloat16)
+    assert tr._rope_lanes(xb, n, rot, 1e4).dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        tr._rope_lanes(xb, n, rot, 1e4).astype(jnp.float32),
+        by_head(xb).astype(jnp.float32), rtol=0, atol=2e-2)
