@@ -96,6 +96,16 @@ class Size:
                                                4096),
                        "smallthinker global": (1, 16384, 28, 4, 128, 128, 0),
                        "kimi_linear": (1, 8192, 32, 32, 192, 128, 0)})
+        # the recurrences of the two delta-rule cells (sequences, S, heads,
+        # d_k, d_v, one decay a head?, beta's ceiling): Olmo-Hybrid's Gated
+        # DeltaNet at 96 / 192 with beta up to 1.9, Kimi's KDA at 128 / 128.
+        # The oracle walks the tokens one by one, so S and the heads are
+        # cut; a head's widths, what routes the arm, are not
+        self.scan = ({"tiny per-head": (1, 128, 2, 12, 24, True, 1.9),
+                      "tiny per-channel": (1, 128, 2, 16, 16, False, 1.0)}
+                     if tiny else
+                     {"olmo_hybrid": (1, 1024, 4, 96, 192, True, 1.9),
+                      "kimi_linear": (1, 1024, 4, 128, 128, False, 1.0)})
 
 
 # --------------------------------------------------------------------------- #
@@ -477,6 +487,55 @@ def check_flash(size: Size) -> dict:
         return {name: one(shape) for name, shape in size.flash.items()}
 
 
+def check_scan(size: Size) -> dict:
+    """The gated delta rule's scan at the two delta-rule cells' head widths
+    against the token-by-token recurrence on this device, the output and all
+    five gradients, f32 operands: the per-head decay at 96 / 192 with every
+    write at beta 1.9, the per-channel decay at 128 / 128, each through the
+    arm ``kda_route`` chose for its shape here (in the facts, with the
+    reason where it is not ``pallas``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from poseidon_tpu.ops.kda import kda_recurrence, kda_route, kda_scan
+
+    def one(shape):
+        b, s, h, d_k, d_v, per_head, beta_max = shape
+        rs = np.random.RandomState(SEED)
+        q, k = rs.randn(2, b, s, h, d_k)
+        q /= np.linalg.norm(q, axis=-1, keepdims=True)
+        k /= np.linalg.norm(k, axis=-1, keepdims=True)
+        g = -np.exp(rs.uniform(-6, 0, size=(b, s, h) if per_head
+                               else (b, s, h, d_k)))
+        beta = np.full((b, s, h), beta_max) if per_head \
+            else beta_max / (1 + np.exp(-rs.randn(b, s, h)))
+        args = [jnp.asarray(x, jnp.float32)
+                for x in (q, k, rs.randn(b, s, h, d_v), g, beta)]
+        cot = jnp.asarray(rs.randn(b, s, h, d_v), jnp.float32)
+
+        def stepped(fn):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: (lambda y: (jnp.sum(y * cot), y))(
+                    fn(*a).astype(jnp.float32)),
+                argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+        arm, note = kda_route(s, d_k, d_v, h, 4, per_head=per_head)
+        facts = {"arm": arm, "route": note}
+        (_, got), got_grads = stepped(kda_scan)(*args)
+        (_, want), want_grads = stepped(kda_recurrence)(*args)
+        for name, a, w in zip(("out", "dq", "dk", "dv", "dg", "dbeta"),
+                              (got,) + got_grads, (want,) + want_grads):
+            a, w = (np.asarray(t, np.float64) for t in (a, w))
+            rel = float(np.linalg.norm(a - w) / max(np.linalg.norm(w), 1e-30))
+            facts[f"{name} relative l2"] = rel
+            check(np.all(np.isfinite(a)) and np.any(w) and rel < 1e-3,
+                  f"scan at {shape} ({arm}): {name} differs from the "
+                  f"recurrence by {rel} (relative L2)")
+        return facts
+
+    return {name: one(shape) for name, shape in size.scan.items()}
+
+
 HELD_SHARES = (0.03, 0.06, 0.125, 0.25, 0.5, 1.0)
 
 
@@ -677,6 +736,10 @@ def child_cold(size: Size) -> dict:
     # 7. the flash kernels at the token cells' geometries, both operand
     # forms, against the dense op
     phases["flash"] = check_flash(size)
+
+    # 8. the delta-rule scans at the two cells' head widths, each through
+    # the arm kda_route chose, against the recurrence
+    phases["scan"] = check_scan(size)
     result["resume_from"] = snap
     result["net"] = net
     result["xla_entries_at_end"] = cache_entries(cache)

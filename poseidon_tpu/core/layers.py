@@ -802,7 +802,8 @@ class L2NormLayer(Layer):
 
 class KDADecayLayer(Layer):
     """(N, S, H d) -> the log-decay g = -exp(A_log) softplus(x + dt_bias),
-    <= 0, one a head AND channel, f32 whatever the compute policy (its
+    <= 0, one a head AND channel (d = 1, a bottom of (N, S, H): ONE a
+    head, Gated DeltaNet's), f32 whatever the compute policy (its
     cumulative sums are the scan's exponents). Blobs: A_log (H,), dt_bias
     (H d). A second top, optional: the mean of exp(g) over the step, a
     scalar a display carries (does the state forget?)."""
@@ -843,35 +844,49 @@ class KDADecayLayer(Layer):
 
 
 class KDAScanLayer(Layer):
-    """The recurrent-state layer. Bottoms q, k, g (N, S, H d_k), v
-    (N, S, H d_v), beta (N, S, H) -> o (N, S, H d_v): per head a state
-    (d_k, d_v), zero at a sequence's start,
+    """The recurrent-state layer. Bottoms q, k (N, S, H d_k), v
+    (N, S, H d_v), g (N, S, H d_k) (a decay a channel) or (N, S, H) (one a
+    head), beta (N, S, H) -> o (N, S, H d_v): per head a state (d_k, d_v),
+    zero at a sequence's start,
     S_t = (I - beta k k^T) Diag(exp(g)) S_{t-1} + beta k v^T,
     o_t = S_t^T q_t d_k^-0.5 (``ops/kda.kda_scan``: chunks of the sequence,
-    f32 state). Nothing resets the state inside a sequence."""
+    f32 state; which arm runs follows from g's shape, the two widths and the
+    backend, and ``Net`` logs it). beta may pass 1 (up to 2: a write with a
+    negative eigenvalue along k). Nothing resets the state inside a
+    sequence. A second top, optional: the share of the step's (token, head)
+    writes with beta > 1, a scalar a display carries."""
     TYPE = "KDA_SCAN"
 
     def setup(self, bottom_shapes):
         h = self.lp.kda_param.num_heads
-        if len(bottom_shapes) != 5:
-            raise ValueError(f"{self.name}: KDA_SCAN takes q, k, v, g, beta")
+        if len(bottom_shapes) != 5 or len(self.lp.top) not in (1, 2):
+            raise ValueError(f"{self.name}: KDA_SCAN takes q, k, v, g, beta "
+                             f"and has 1 or 2 tops (o[, the share of beta "
+                             f"> 1])")
         q, k, v, g, beta = (tuple(b) for b in bottom_shapes)
-        _split_heads(self.name, self.TYPE, q, h)
+        d_k = _split_heads(self.name, self.TYPE, q, h)
         _split_heads(self.name, self.TYPE, v, h)
-        if not q == k == g or v[:2] != q[:2] or beta != q[:2] + (h,):
+        self.per_head = g == q[:2] + (h,) and d_k != 1
+        if q != k or not (g == q or self.per_head) or v[:2] != q[:2] \
+                or beta != q[:2] + (h,):
             raise ValueError(
-                f"{self.name}: KDA_SCAN takes q, k, g of one (N, S, H d_k) "
-                f"shape, v (N, S, H d_v) and beta (N, S, {h}); got "
-                f"{bottom_shapes}")
-        return [v]
+                f"{self.name}: KDA_SCAN takes q, k of one (N, S, H d_k) "
+                f"shape, g of that shape or (N, S, {h}), v (N, S, H d_v) "
+                f"and beta (N, S, {h}); got {bottom_shapes}")
+        return [v] + [()] * (len(self.lp.top) - 1)
 
     def apply(self, params, bottoms, ctx):
         from ..ops.kda import kda_scan
         h = self.lp.kda_param.num_heads
         q, k, v, g, beta = bottoms
         heads = lambda x: x.reshape(x.shape[:2] + (h, -1))
-        o = kda_scan(heads(q), heads(k), heads(v), heads(g), beta)
-        return [o.reshape(v.shape).astype(v.dtype)]
+        o = kda_scan(heads(q), heads(k), heads(v),
+                     g if self.per_head else heads(g), beta)
+        tops = [o.reshape(v.shape).astype(v.dtype)]
+        if len(self.lp.top) == 2:
+            tops.append(jnp.mean(
+                (lax.stop_gradient(beta) > 1).astype(jnp.float32)))
+        return tops
 
 
 class SiLUGateLayer(Layer):

@@ -434,10 +434,12 @@ class Net:
                 from ..ops.kda import kda_route
                 what = "kda"
                 h = layer.lp.kda_param.num_heads
+                # the note names the arm and, where it is not pallas, why
                 arm, note = kda_route(
                     shape[1], shape[2] // h,
                     self.blob_shapes[layer.lp.bottom[2]][2] // h, h,
-                    jnp.dtype(policy().compute_dtype).itemsize)[1], ""
+                    jnp.dtype(policy().compute_dtype).itemsize,
+                    per_head=layer.per_head)[1], ""
             elif layer.TYPE == "MOE":
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"
@@ -483,8 +485,12 @@ class Net:
             out[l.name] = {
                 "heads": h, "d_k": wk // h, "d_v": wv // h,
                 "chunk": chunk or 1, "chunks": s // (chunk or 1),
-                "saved_state_bytes": state_bytes(n, s, h, wk // h, wv // h)
+                "saved_state_bytes": state_bytes(
+                    n, s, h, wk // h, wv // h, l.per_head,
+                    jnp.dtype(policy().compute_dtype).itemsize)
                 if chunk else 0}
+            if l.per_head:          # one decay a head (a channel: unsaid)
+                out[l.name]["decay"] = "head"
         return out
 
     def _held_rows(self, layer: Layer) -> Optional[Tuple[int, int, int]]:

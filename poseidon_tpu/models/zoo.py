@@ -1191,3 +1191,176 @@ def kimi_linear(batch: int = 1,
     layers.append(LayerParameter(
         name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
     return NetParameter(name=name, layers=layers)
+
+
+def olmo_hybrid(batch: int = 1,
+                source: str = "examples/lm/olmo_hybrid_7b_tokens.txt",
+                n_layers: int = 32, hidden: int = 3840, heads: int = 30,
+                heads_held: int = 0,
+                key_head_dim: int = 96, value_head_dim: int = 192,
+                conv_taps: int = 4, full_every: int = 4,
+                attn_head_dim: int = 128, ffn_width: int = 11008,
+                vocab: int = 100352, eps: float = 1e-6,
+                init_std: float = 0.02,
+                name: str = "Olmo-Hybrid-7B") -> NetParameter:
+    """Olmo-Hybrid-7B (config.json of allenai/Olmo-Hybrid-7B,
+    ``olmo_hybrid``): every layer is a token mixer and a dense FFN in the
+    family's (Olmo 2 / 3) REORDERED norm, an RMSNorm on each sublayer's
+    OUTPUT and none on its input,
+
+        h = x + N_a(Mix(x));  y = h + N_f(FFN(h))
+        FFN(h) = W_d (SiLU(W_g h) * W_u h)           no bias anywhere
+
+    a final RMSNorm before the untied head. Layer ``full_every``
+    (1-indexed) and every ``full_every``-th after it mixes by full
+    attention, the others by Gated DeltaNet (``layer_types`` = (linear,
+    linear, linear, full) x 8).
+
+    Linear layer (``l<i>_gdn_*``), per head of d_k ``key_head_dim`` and d_v
+    ``value_head_dim``, per token t:
+
+        q, k, v = SiLU(conv4(W_q x)), SiLU(conv4(W_k x)), SiLU(conv4(W_v x))
+        q = q / |q|_2 * d_k^-1/2 ;  k = k / |k|_2         per head, f32
+        beta_t = 2 sigmoid(W_b x_t)                       one a head, (0, 2)
+        g_t    = -exp(A_log) softplus(W_a x_t + dt_bias)  ONE a head, <= 0
+        Sbar_t = exp(g_t) S_{t-1}
+        S_t    = Sbar_t + beta_t k_t (v_t - Sbar_t^T k_t)^T    S_0 = 0, f32
+        o_t    = S_t^T q_t
+        Mix(x) = W_o [RMSNorm_dv(o_t) * SiLU(W_z x_t)]    one d_v-wide gain
+
+    as layers: ``l<i>_gdn_{q,k,v}`` project x, ``l<i>_gdn_conv_*`` the
+    causal depthwise convolutions and their SiLU, ``l<i>_gdn_l2_{q,k}``
+    (q's scale is the scan's), ``l<i>_gdn_a`` and ``l<i>_gdn_decay`` (whose
+    second top ``l<i>_decay_mean`` every display carries: the mean exp(g),
+    does the state forget?), ``l<i>_gdn_b``, ``l<i>_gdn_beta_sig`` and
+    ``l<i>_gdn_beta`` (POWER: the doubling; ``linear_allow_neg_eigval``),
+    ``l<i>_gdn_scan`` (KDA_SCAN with g of (N, S, H); its second top
+    ``l<i>_beta_over_one`` is the share of writes with beta > 1),
+    ``l<i>_gdn_onorm``, ``l<i>_gdn_z`` and ``l<i>_gdn_gate`` (SILU_GATE),
+    ``l<i>_gdn_o``.
+
+    Full layer (``l<i>_attn_*``): q = N_q(W_q x), k = N_k(W_k x) with an
+    RMSNorm over ALL the held heads' channels (one gain vector each), v =
+    W_v x, causal softmax at ``attn_head_dim``^-1/2 with NO positions
+    (``rope_theta`` is null in the source), ``l<i>_attn_o``.
+
+    ``heads_held`` of the ``heads`` heads of BOTH mixers (0 = all): with
+    fewer the net is one chip's share of a model whose layers are shared
+    by heads (WHICH heads decides no shape, so the net does not ask). Every projection of both
+    mixers then emits, and W_o consumes, the held heads' columns only; the
+    partial W_o result goes on into N_a and the residual as it is — nothing
+    stands in for the other chips or their all-reduce, and the whole-vector
+    QK-norm's mean square runs over the held channels. The FFN stays whole.
+
+    Gains, A_log and dt_bias carry decay_mult 0, every matrix and the
+    convolutions' taps 1."""
+    from ..proto.messages import (AttentionParameter, EltwiseParameter,
+                                  EmbedParameter, HDF5DataParameter,
+                                  KDAParameter, PowerParameter,
+                                  RMSNormParameter)
+    held = heads_held or heads
+    if not 0 < held <= heads:
+        raise ValueError(f"olmo_hybrid: {held} heads held of {heads}")
+    if held < heads:
+        name = f"{name} ({held} of {heads} heads)"
+    w = gaussian(init_std)
+    no_decay = ParamSpec(lr_mult=1.0, decay_mult=0.0)
+    layers: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+
+    def norm(lname, bottom, top, per_head=0):
+        layers.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[no_decay], rms_norm_param=RMSNormParameter(
+                eps=eps, num_heads=per_head)))
+
+    def proj(lname, bottom, top, n_out):
+        layers.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2, weight_filler=w)))
+
+    def add(lname, a, b, top):
+        layers.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation="SUM")))
+
+    def silu_gate(lname, gate, up, top):
+        layers.append(LayerParameter(
+            name=lname, type="SILU_GATE", bottom=[gate, up], top=[top]))
+
+    def gdn(p, x, top):
+        kp = dict(num_heads=held)
+        taps = FillerParameter(type="uniform", min=-conv_taps ** -0.5,
+                               max=conv_taps ** -0.5)
+        for t, d in (("q", key_head_dim), ("k", key_head_dim),
+                     ("v", value_head_dim)):
+            proj(p + "gdn_" + t, x, p + t + "p", held * d)
+            layers.append(LayerParameter(
+                name=p + "gdn_conv_" + t, type="SHORT_CONV",
+                bottom=[p + t + "p"], top=[p + t + "c"],
+                kda_param=KDAParameter(kernel_size=conv_taps,
+                                       weight_filler=taps)))
+        for t in "qk":
+            layers.append(LayerParameter(
+                name=p + "gdn_l2_" + t, type="L2_NORM", bottom=[p + t + "c"],
+                top=[p + t + "n"], kda_param=KDAParameter(**kp)))
+        proj(p + "gdn_a", x, p + "al", held)
+        layers.append(LayerParameter(
+            name=p + "gdn_decay", type="KDA_DECAY", bottom=[p + "al"],
+            top=[p + "gdec", p + "decay_mean"], param=[no_decay, no_decay],
+            kda_param=KDAParameter(**kp)))
+        proj(p + "gdn_b", x, p + "bl", held)
+        layers.append(LayerParameter(name=p + "gdn_beta_sig", type="SIGMOID",
+                                     bottom=[p + "bl"], top=[p + "bs"]))
+        layers.append(LayerParameter(
+            name=p + "gdn_beta", type="POWER", bottom=[p + "bs"],
+            top=[p + "beta"], power_param=PowerParameter(scale=2.0)))
+        layers.append(LayerParameter(
+            name=p + "gdn_scan", type="KDA_SCAN",
+            bottom=[p + "qn", p + "kn", p + "vc", p + "gdec", p + "beta"],
+            top=[p + "so", p + "beta_over_one"],
+            kda_param=KDAParameter(**kp)))
+        norm(p + "gdn_onorm", p + "so", p + "son", held)
+        proj(p + "gdn_z", x, p + "z", held * value_head_dim)
+        silu_gate(p + "gdn_gate", p + "z", p + "son", p + "sog")
+        proj(p + "gdn_o", p + "sog", top, hidden)
+
+    def attn(p, x, top):
+        for t in "qkv":
+            proj(p + "attn_" + t, x, p + t, held * attn_head_dim)
+        norm(p + "attn_qnorm", p + "q", p + "qn")
+        norm(p + "attn_knorm", p + "k", p + "kn")
+        layers.append(LayerParameter(
+            name=p + "attn_sdpa", type="ATTENTION",
+            bottom=[p + "qn", p + "kn", p + "v"], top=[p + "att"],
+            attention_param=AttentionParameter(num_heads=held, rope=False)))
+        proj(p + "attn_o", p + "att", top, hidden)
+
+    layers.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["x0"],
+        embed_param=EmbedParameter(input_dim=vocab, num_output=hidden,
+                                   weight_filler=w)))
+    x = "x0"
+    for i in range(n_layers):
+        p = f"l{i}_"
+        (attn if (i + 1) % full_every == 0 else gdn)(p, x, p + "mo")
+        norm(p + "mix_norm", p + "mo", p + "mn")
+        add(p + "res1", x, p + "mn", p + "h")
+        proj(p + "ffn_gate", p + "h", p + "fg", ffn_width)
+        proj(p + "ffn_up", p + "h", p + "fu", ffn_width)
+        silu_gate(p + "ffn_act", p + "fg", p + "fu", p + "fa")
+        proj(p + "ffn_down", p + "fa", p + "fo", hidden)
+        norm(p + "ffn_norm", p + "fo", p + "fn")
+        add(p + "res2", p + "h", p + "fn", p + "y")
+        x = p + "y"
+    norm("final_norm", x, "xf")
+    proj("lm_head", "xf", "logits", vocab)
+    layers.append(LayerParameter(
+        name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
+        top=["nll"]))
+    # the mean over positions: the exit-weighted loss of ONE pass
+    layers.append(LayerParameter(
+        name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    return NetParameter(name=name, layers=layers)

@@ -1,9 +1,13 @@
-"""The gated delta rule with a per-channel decay (KDA, arXiv:2510.26692) as
-a chunked scan: forward and a hand-written backward.
+"""The gated delta rule as a chunked scan, forward and a hand-written
+backward, with a decay a head AND channel (KDA, arXiv:2510.26692) or ONE a
+head (Gated DeltaNet, arXiv:2412.06464).
 
-Per head, a state S (d_k, d_v), S_0 = 0, and per token t a query and a key
-q_t, k_t (d_k), a value v_t (d_v), a log-decay g_t <= 0 (d_k, one a
-CHANNEL) and a write strength beta_t:
+Per head, a state S (d_k, d_v) (the two widths need not be equal), S_0 = 0,
+and per token t a query and a key q_t, k_t (d_k), a value v_t (d_v), a
+log-decay g_t <= 0 (d_k, one a CHANNEL; or a scalar, one a HEAD: g comes as
+(B, S, H, d_k) or as (B, S, H)) and a write strength beta_t in (0, 2) (past
+1 the write's eigenvalue 1 - beta along k is negative; the unit-lower
+system below is the same in form):
 
     Sbar_t = Diag(exp(g_t)) S_{t-1}
     S_t    = Sbar_t + beta_t k_t (v_t - Sbar_t^T k_t)^T
@@ -37,6 +41,13 @@ product of two factors that are each at most 1, exp(G_i - r) and
 exp(r - G_j) with r the cumulative sum at the end of the sub-block before
 i's (so G_i <= r <= G_j).
 
+One decay a head (``_chunk_parts_head``): the ratio of a token pair is ONE
+number, exp(G_i - G_j), so A and B are (K K^T) and (Q K^T) times a C x C
+grid of ratios formed element by element, each with i >= j (at most 1, no
+exp(-G)). The sub-block rule is not needed there: it exists because a
+per-channel ratio inside a PRODUCT over channels has to be split into two
+factors, and a grid of scalars is never split.
+
 The backward (``custom_vjp``) keeps the five operands and ONE state a chunk
 a head (d_k x d_v f32), recomputes a group's parts and walks its chunks
 backwards with the transposed three products, group by group from the last;
@@ -44,15 +55,26 @@ the derivative of the chunk-local parts is autodiff's. The state, the
 cumulative sums and the triangular system are f32 at HIGHEST precision
 whatever the compute policy.
 
-Two implementations of that one chunked form, chosen by ``kda_route`` from
-what the code can see (the backend and the shape; no switch): where the
-backend compiles Mosaic, 64 divides S and both head widths are multiples of
-128, ``ops/kda_pallas.py``'s two kernels (a program owns ``kda_blocks``'s m
-chunks of a head, the state stays in VMEM from a sequence's first chunk to
-its last, the chunk-local parts and their pullback never visit HBM, the
-operands are read in place); everywhere else (the CPU mesh, the tiny nets'
-16-wide heads, a shorter chunk) the ``jax.numpy`` form below, which is also
-what the kernels are read beside. ``kda_recurrence`` is the oracle of both.
+Which arm takes which, chosen by ``kda_route`` from what the code can see
+(the decay's shape, the widths, the backend; no switch):
+
+- ``pallas`` (``ops/kda_pallas.py``'s two kernels, each with an arm for
+  either decay: a program owns ``kda_blocks``'s m chunks of a head, the
+  state stays in VMEM from a sequence's first chunk to its last, the
+  chunk-local parts and their pullback never visit HBM): a backend that
+  compiles Mosaic, 64 divides S, and either a decay a CHANNEL with d_k and
+  d_v both multiples of 128 (a head is a lane block of (N, S, H d), read in
+  place), or one decay a HEAD at any widths, q, k and v padded with zero
+  lanes to multiples of 128 on the way in (Gated DeltaNet's 96 / 192 run
+  at 128 / 256: one padded copy of each operand, a quarter of the lanes
+  and 44% of the state's products wasted, o sliced back);
+- ``chunked`` (the ``jax.numpy`` form below, which is also what the kernels
+  are read beside): everything else that has a chunk — a decay a channel
+  at widths that are no lane multiples, a chunk shorter than 64, the CPU
+  mesh. ``kda_route``'s note says which of these it was;
+- ``recurrence``: no chunk divides S.
+
+``kda_recurrence`` is the oracle of all of them.
 """
 
 from __future__ import annotations
@@ -76,31 +98,73 @@ def kda_chunk(s: int) -> Optional[int]:
     return next((c for c in (64, 48, 32, 16) if s % c == 0), None)
 
 
-def state_bytes(batch: int, s: int, heads: int, d_k: int, d_v: int) -> int:
+def state_bytes(batch: int, s: int, heads: int, d_k: int, d_v: int,
+                per_head: bool = False, itemsize: int = 2) -> int:
     """What the backward keeps of the recurrence: one f32 state a chunk a
-    head a sequence."""
+    head a sequence, at the widths the arm that runs keeps it (the Pallas
+    arm's padded lanes where it pads)."""
+    if _pallas_blocks(s, d_k, d_v, heads, itemsize, per_head):
+        d_k, d_v = _kernel_widths(d_k, d_v, per_head)
     return batch * heads * (s // kda_chunk(s)) * d_k * d_v * 4
 
 
 def kda_route(s: int, d_k: Optional[int] = None, d_v: Optional[int] = None,
-              heads: int = 1, itemsize: int = 2):
-    """``(arm, note)`` for a sequence length and a head's widths, as ``Net``
+              heads: int = 1, itemsize: int = 2, per_head: bool = False):
+    """``(arm, note)`` for a sequence length, a head's widths and the
+    decay's shape (``per_head``: one g a head, not a channel), as ``Net``
     logs it — THE routing decision, of the backend and the shape alone:
     ``pallas`` (``ops/kda_pallas.py``) where the backend compiles Mosaic, 64
-    divides S and both widths are multiples of 128 (``kda_blocks``: the
-    chunks a program); else the ``jax.numpy`` chunked form below (the CPU
-    mesh, narrow heads, a shorter chunk); else the recurrence."""
+    divides S and the widths are multiples of 128 or, with one decay a
+    head, are padded to them (``kda_blocks``: the chunks a program); else
+    the ``jax.numpy`` chunked form below, the note saying WHY the kernels
+    did not take it; else the recurrence."""
     c = kda_chunk(s)
     if c is None:
         return "recurrence", f"token by token (no chunk divides S={s})"
-    m = _pallas_blocks(s, d_k, d_v, heads, itemsize)
+    m = _pallas_blocks(s, d_k, d_v, heads, itemsize, per_head)
     if m:
-        return "pallas", (f"pallas (C {c} x {m}, {s // c} chunks, "
-                          f"f32 state in VMEM)")
-    return "chunked", f"chunked C {c}, {s // c} chunks, f32 state"
+        padded = _lanes(d_k), _lanes(d_v)
+        return "pallas", (
+            f"pallas (C {c} x {m}, {s // c} chunks, f32 state in VMEM"
+            + (", one decay a head" if per_head else "")
+            + (f", lanes {d_k} / {d_v} padded to {padded[0]} / {padded[1]}"
+               if padded != (d_k, d_v) else "") + ")")
+    why = _pallas_refusal(s, d_k, d_v, heads, itemsize, per_head)
+    return "chunked", (f"chunked C {c}, {s // c} chunks, f32 state"
+                       + (", one decay a head" if per_head else "")
+                       + (f"; not pallas: {why}" if why else ""))
 
 
-def _pallas_blocks(s, d_k, d_v, heads, itemsize) -> Optional[int]:
+def _lanes(d: int) -> int:
+    """A head's width rounded up to whole lane blocks of 128."""
+    return -(-d // 128) * 128
+
+
+def _kernel_widths(d_k, d_v, per_head):
+    """The widths the kernels would run a head at: its own or, with one
+    decay a head, padded to lane blocks (a zero channel of q and k adds
+    nothing to a score and leaves its row of the state zero; a zero column
+    of v is a zero column of o). A decay a CHANNEL is not padded: Kimi's
+    widths are lane blocks already, and nothing else runs that arm."""
+    return (_lanes(d_k), _lanes(d_v)) if per_head else (d_k, d_v)
+
+
+def _pallas_refusal(s, d_k, d_v, heads, itemsize, per_head) -> str:
+    """Why the Pallas arm does not take a shape ('' where no widths were
+    given): the kernels' own refusal (``kda_pallas.kda_refusal``), else the
+    backend."""
+    if not (d_k and d_v):
+        return ""
+    from .kda_pallas import kda_refusal
+    from .pallas_kernels import _interpret_default
+    return kda_refusal(s, *_kernel_widths(d_k, d_v, per_head), heads,
+                       itemsize) or (
+        "this backend would interpret the kernels"
+        if _interpret_default() else "")
+
+
+def _pallas_blocks(s, d_k, d_v, heads, itemsize,
+                   per_head: bool = False) -> Optional[int]:
     """Chunks a program of the Pallas arm, None where it does not run: no
     widths given, a shape the kernels cannot take, or a backend that would
     interpret them."""
@@ -108,8 +172,23 @@ def _pallas_blocks(s, d_k, d_v, heads, itemsize) -> Optional[int]:
         return None
     from .kda_pallas import kda_blocks
     from .pallas_kernels import _interpret_default
-    m = kda_blocks(s, d_k, d_v, heads, itemsize)
+    m = kda_blocks(s, *_kernel_widths(d_k, d_v, per_head), heads, itemsize)
     return m if m and not _interpret_default() else None
+
+
+def _pallas_scan(q, k, v, g, beta, scale, m, interpret):
+    """The Pallas arm at the widths the kernels run (``_kernel_widths``):
+    zero lanes appended to q, k, v on the way in, o sliced back."""
+    from .kda_pallas import kda_scan_pallas
+    d_k, d_v = q.shape[-1], v.shape[-1]
+    wide_k, wide_v = _kernel_widths(d_k, d_v, g.ndim == 3)
+    if (wide_k, wide_v) == (d_k, d_v):      # lane blocks as they come
+        return kda_scan_pallas(q, k, v, g, beta, scale, m, interpret)
+    wide = lambda x, to: jnp.pad(
+        x, [(0, 0)] * 3 + [(0, to - x.shape[-1])])
+    o = kda_scan_pallas(wide(q, wide_k), wide(k, wide_k), wide(v, wide_v),
+                        g, beta, scale, m, interpret)
+    return o[..., :d_v]
 
 
 def _mm(a, b, spec):
@@ -118,10 +197,12 @@ def _mm(a, b, spec):
 
 
 def kda_recurrence(q, k, v, g, beta, scale: Optional[float] = None):
-    """q, k, g (B, S, H, d_k), v (B, S, H, d_v), beta (B, S, H) -> o
-    (B, S, H, d_v) f32: the recurrence as written, a ``lax.scan`` over t."""
+    """q, k (B, S, H, d_k), g the same or (B, S, H) (one a head), v
+    (B, S, H, d_v), beta (B, S, H) -> o (B, S, H, d_v) f32: the recurrence
+    as written, a ``lax.scan`` over t."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    g = g[..., None] if g.ndim == 3 else g
     b, _, h, d_k = q.shape
 
     def step(state, xs):
@@ -231,6 +312,31 @@ def _chunk_parts(q, k, v, g, beta):
             k * jnp.exp(last - gc), jnp.exp(last[..., 0, :]))
 
 
+def _chunk_parts_head(q, k, v, g, beta):
+    """``_chunk_parts`` with ONE decay a head: g (B, H, N, C). Every ratio
+    is exp(G_i - G_j), i >= j, one number a token pair on the C x C grid
+    (at most 1; never a product with exp(-G)), so A and B are one product
+    and one mask each. ``last`` comes back (.., 1): it scales the whole
+    state."""
+    c, d_k = q.shape[-2:]
+    gc = jnp.cumsum(g, axis=-1)
+    i = jnp.arange(c)
+    lower = i[:, None] >= i[None, :]
+    ratio = jnp.where(lower, jnp.exp(jnp.where(
+        lower, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
+    both = _mm(jnp.concatenate([k, q], -2), k, "...ic,...jc->...ij")
+    a = jnp.where(i[:, None] > i[None, :], both[..., :c, :] * ratio, 0.0)
+    b = both[..., c:, :] * ratio
+    decay = jnp.exp(gc)[..., None]
+    kg = k * decay
+    solved = _mm(_unit_lower_inverse(beta[..., None] * a),
+                 beta[..., None] * jnp.concatenate([kg, v], -1),
+                 "...ij,...jk->...ik")
+    last = gc[..., -1:]
+    return (solved[..., :d_k], solved[..., d_k:], q * decay, b,
+            k * jnp.exp(last - gc)[..., None], jnp.exp(last))
+
+
 def _group(s: int, chunk: int) -> int:
     """Chunks a group: the chunk-local parts are computed for ``_GROUP``
     tokens at a time (what they hold of HBM is a group's, not the
@@ -257,9 +363,15 @@ def _merge(x):
 
 def _parts_of(q, k, v, g, beta):
     """One group's operands (B, H, G, C, ..) in their own types -> its
-    chunk-local parts, chunks leading (G, B, H, ...)."""
+    chunk-local parts, chunks leading (G, B, H, ...). g (.., d_k) a channel
+    or (.., 1) a head."""
     f32 = lambda x: x.astype(jnp.float32)
-    parts = _chunk_parts(f32(q), f32(k), f32(v), f32(g), f32(beta[..., 0]))
+    if g.shape[-1] == 1:        # one decay a head (d_k 1: the same thing)
+        parts = _chunk_parts_head(f32(q), f32(k), f32(v), f32(g[..., 0]),
+                                  f32(beta[..., 0]))
+    else:
+        parts = _chunk_parts(f32(q), f32(k), f32(v), f32(g),
+                             f32(beta[..., 0]))
     return tuple(jnp.moveaxis(p, 2, 0) for p in parts)
 
 
@@ -315,7 +427,9 @@ def _kda_bwd(scale, chunk, res, d_o):
                    _mm(d_out, state, "bhcv,bhkv->bhck"),
                    _mm(d_out, wrote, "bhiv,bhjv->bhij"),
                    _mm(wrote, d_state, "bhcv,bhkv->bhck"),
-                   jnp.sum(state * d_state, -1))
+                   # last is (d_k) a channel, (1) a head: the whole state's
+                   jnp.sum(state * d_state, -1) if last.shape[-1] > 1
+                   else jnp.sum(state * d_state, (-2, -1))[..., None])
         d_prev = _mm(qg, d_out, "bhck,bhcv->bhkv") \
             + last[..., None] * d_state \
             - _mm(w, d_wrote, "bhck,bhcv->bhkv")
@@ -339,20 +453,23 @@ _kda_chunked.defvjp(_kda_fwd, _kda_bwd)
 
 def kda_scan(q, k, v, g, beta, scale: Optional[float] = None,
              chunk: Optional[int] = None):
-    """q, k, g (B, S, H, d_k), v (B, S, H, d_v), beta (B, S, H) -> o
-    (B, S, H, d_v) in v's type. ``chunk``: tokens a chunk (None: ``kda_chunk``'s;
-    a multiple of the sub-block that divides S). Where no chunk divides S
-    the token-by-token recurrence runs."""
+    """q, k (B, S, H, d_k), g the same (a decay a channel) or (B, S, H) (one
+    a head), v (B, S, H, d_v), beta (B, S, H) -> o (B, S, H, d_v) in v's
+    type. ``chunk``: tokens a chunk (None: ``kda_chunk``'s; a multiple of
+    the sub-block that divides S). Where no chunk divides S the
+    token-by-token recurrence runs."""
     scale = q.shape[-1] ** -0.5 if scale is None else float(scale)
+    per_head = g.ndim == 3
     m = None if chunk else _pallas_blocks(
-        q.shape[1], q.shape[-1], v.shape[-1], q.shape[2], q.dtype.itemsize)
+        q.shape[1], q.shape[-1], v.shape[-1], q.shape[2], q.dtype.itemsize,
+        per_head)
     if m:
-        from .kda_pallas import kda_scan_pallas
-        return kda_scan_pallas(q, k, v, g, beta, scale, m, False)
+        return _pallas_scan(q, k, v, g, beta, scale, m, False)
     chunk = kda_chunk(q.shape[1]) if chunk is None else chunk
     if chunk is None:
         return kda_recurrence(q, k, v, g, beta, scale).astype(v.dtype)
     if q.shape[1] % chunk or chunk % _SUB:
         raise ValueError(f"chunk {chunk} is not a multiple of {_SUB} that "
                          f"divides S={q.shape[1]}")
-    return _kda_chunked(q, k, v, g, beta, scale, int(chunk))
+    return _kda_chunked(q, k, v, g[..., None] if per_head else g, beta,
+                        scale, int(chunk))

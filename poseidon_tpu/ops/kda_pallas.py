@@ -1,6 +1,16 @@
 """The chunked gated delta rule (``ops/kda.py`` has the mathematics) as two
-Pallas (Mosaic) kernels: forward and backward. One program owns ``m``
-chunks of 64 tokens of ONE head of ONE sequence; the grid's last axis walks
+Pallas (Mosaic) kernels: forward and backward, at head widths that are
+multiples of 128 (``kda_refusal`` names what they do not take), each with
+two arms for the chunk-local parts: a decay a CHANNEL (g (N, S, H d_k):
+``_local`` / ``_local_pullback``, everything below about sub-blocks) and
+ONE decay a head (g (N, S, H), Gated DeltaNet: ``_local_head`` /
+``_local_head_pullback``, whose ratios are one number a token pair on the
+(R, R) grid, so a and b are one product and one mask; ``ops/kda.kda_scan``
+pads such a scan's 96 / 192 lanes to 128 / 256 on the way in; the arm earns
+its lines against g broadcast over the key lanes into the per-channel arm,
+which the same cell ran 2.5% slower end to end: PERF.md, PR 48). One program
+owns ``m`` chunks of 64 tokens of ONE head of ONE sequence; the grid's last
+axis walks
 the sequence (forward from the first chunk, backward from the last) and the
 head's f32 state — its gradient in the backward — stays in a VMEM scratch
 from one program to the next. Everything a chunk computes without the state
@@ -60,9 +70,9 @@ def kda_blocks(s: int, d_k: int, d_v: int, heads: int = 1,
     double-buffered (q, k, v, d_o and dq, dk, dv in the operands' type; g, dg
     and the saved states f32; beta's H columns), the f32 parts of a tile of
     two chunks, and the state's gradient. None where the kernels cannot
-    take the shape: 64 does not divide S, or a head's width is no multiple
-    of 128."""
-    if s % CHUNK or d_k % 128 or d_v % 128:
+    take the shape (``kda_refusal`` says which): 64 does not divide S, or
+    a head's width is no multiple of 128."""
+    if _shape_refusal(s, d_k, d_v):
         return None
     budget = _compiler_params().vmem_limit_bytes // 2
     for m in (4, 2, 1):
@@ -75,6 +85,24 @@ def kda_blocks(s: int, d_k: int, d_v: int, heads: int = 1,
         if blocks + parts + 2 * d_k * d_v * 4 <= budget:
             return m
     return None
+
+
+def _shape_refusal(s: int, d_k: int, d_v: int) -> str:
+    if s % CHUNK:
+        return f"{CHUNK} does not divide S={s}"
+    if d_k % 128 or d_v % 128:
+        return (f"heads of {d_k} / {d_v} are no lane blocks of (N, S, H d) "
+                f"(multiples of 128)")
+    return ""
+
+
+def kda_refusal(s: int, d_k: int, d_v: int, heads: int = 1,
+                itemsize: int = 2) -> str:
+    """Why ``kda_blocks`` is None for a shape, '' where it is not: what
+    ``kda_route`` tells ``Net``'s log when a scan runs ``chunked``."""
+    return _shape_refusal(s, d_k, d_v) or (
+        "" if kda_blocks(s, d_k, d_v, heads, itemsize)
+        else "no block fits the kernels' VMEM budget")
 
 
 def _mm(a, b, ca: int, cb: int):
@@ -180,7 +208,16 @@ def _local(q, k, v, g, beta):
         rows_a[lo // _SUB], rows_b[lo // _SUB] = both[:_SUB], both[_SUB:]
     a = a + jnp.concatenate(rows_a, 0)
     b = b + jnp.concatenate(rows_b, 0)
-    low = beta * a
+    return _solved(q, k, v, beta, gc, a, b)
+
+
+def _unit_lower_inverse(low):
+    """(I + low)^-1 of a tile's (R, R) strictly lower matrix, block diagonal
+    a chunk: forward substitution inside the 16 x 16 diagonal blocks (one
+    chunk a program: from the 2 x 2 blocks), then by doubling, the inverse
+    of a 2s block from those of its two s blocks, as products."""
+    r = low.shape[0]
+    row, col, _ = _grid_masks(r)
     if r == 2 * CHUNK:
         t, s = _sub_block_inverses(low), _SUB
     else:       # one chunk a program: the doubling from the 2 x 2 blocks up
@@ -192,6 +229,15 @@ def _local(q, k, v, g, beta):
                             & (row // s != col // s), low, 0.0)
         t = t - _mm(t, _mm(quarter, t, 1, 0), 1, 0)
         s *= 2
+    return t
+
+
+def _solved(q, k, v, beta, gc, a, b, **more):
+    """What follows a and b in a tile's chunk-local parts, for gc (R, d_k)
+    (a decay a channel) or (R, 1) (one a head): the system solved against
+    Diag(beta) [K exp(G) | V], and the decayed operands."""
+    d_k = q.shape[1]
+    t = _unit_lower_inverse(beta * a)
     decay = jnp.exp(gc)
     kg = k * decay
     solved = _mm(t, beta * jnp.concatenate([kg, v], 1), 1, 0)
@@ -199,7 +245,98 @@ def _local(q, k, v, g, beta):
     erev = jnp.exp(gl - gc)
     return dict(gc=gc, a=a, b=b, t=t, w=solved[:, :d_k], u=solved[:, d_k:],
                 decay=decay, kg=kg, qg=q * decay, erev=erev, krev=k * erev,
-                gl=gl)
+                gl=gl, **more)
+
+
+def _chunk_decay(gl, state):
+    """exp(G_C) of one chunk as a row over the state's (d_v, d_k) lanes:
+    gl (64, d_k) a channel or (64, 1) a head (the column spread along the
+    lanes BEFORE its first row is taken: Mosaic broadcasts along one of
+    sublanes and lanes at a time)."""
+    return jnp.exp(jnp.broadcast_to(gl, (gl.shape[0], state.shape[1]))[:1])
+
+
+def _as_row(column, row, col):
+    """(R, 1) -> (1, R), on the vector unit (the diagonal of its
+    broadcast)."""
+    return jnp.sum(jnp.where(row == col, column, 0.0), 0, keepdims=True)
+
+
+def _as_column(line, row, col):
+    """(1, R) -> (R, 1)."""
+    return jnp.sum(jnp.where(row == col, line, 0.0), 1, keepdims=True)
+
+
+def _local_head(q, k, v, g, beta):
+    """``_local`` with ONE decay a head: g (R, 1). Every ratio is one
+    number a token pair, exp(G_i - G_j) with i >= j on the (R, R) grid (at
+    most 1), so a and b are one product and one mask: no offsets, no
+    sub-block factors. gc, decay, erev and gl come back (R, 1); ``ratio`` is
+    kept for the pullback."""
+    r = q.shape[0]
+    row, col, same = _grid_masks(r)
+    lower = same & (col <= row)
+    gc = jnp.sum(jnp.where(lower, _as_row(g, row, col), 0.0), 1,
+                 keepdims=True)
+    ratio = jnp.where(lower, jnp.exp(jnp.minimum(
+        gc - _as_row(gc, row, col), 0.0)), 0.0)
+    both = _mm(jnp.concatenate([k, q], 0), k, 1, 1)          # (2R, R)
+    a = jnp.where(col < row, both[:r] * ratio, 0.0)
+    return _solved(q, k, v, beta, gc, a, both[r:] * ratio, ratio=ratio)
+
+
+def _solved_pullback(k, v, beta, p, d_w, d_u, d_qg, d_krev):
+    """The pullback through ``_solved``'s products, shared by both decays:
+    -> (d_a, d_beta (R, 1), d_v, d_kg, and d_q, d_k, d_gc as far as the
+    decayed operands give them; d_gc per channel, (R, d_k))."""
+    d_k = k.shape[1]
+    row, col, same = _grid_masks(k.shape[0])
+    # through [w | u] = t rhs and t = (I + beta a)^-1
+    kv = jnp.concatenate([p["kg"], v], 1)
+    d_rhs = _mm(p["t"], jnp.concatenate([d_w, d_u], 1), 0, 0)
+    # d t = d_solved rhs^T and d (I + beta a) = -t^T d_t t^T, as one product
+    d_m = jnp.where(same & (col < row),
+                    -_mm(d_rhs, jnp.concatenate([p["w"], p["u"]], 1), 1, 1),
+                    0.0)
+    d_beta = jnp.sum(d_m * p["a"], -1, keepdims=True) \
+        + jnp.sum(d_rhs * kv, -1, keepdims=True)
+    d_kg = beta * d_rhs[:, :d_k]
+    # through kg, qg, krev
+    back = d_krev * p["krev"]
+    return (beta * d_m, d_beta, beta * d_rhs[:, d_k:],
+            d_qg * p["decay"], d_kg * p["decay"] + d_krev * p["erev"],
+            d_kg * p["kg"] + d_qg * p["qg"] - back, back)
+
+
+def _local_head_pullback(q, k, v, beta, p, d_w, d_u, d_qg, d_b, d_krev,
+                         d_gl):
+    """The pullback of ``_local_head`` by hand; d_gl (R, 1) = every row its
+    chunk's d last * last -> dq, dk, dv and dg, dbeta as ROWS (1, R)."""
+    r = q.shape[0]
+    row, col, same = _grid_masks(r)
+    d_a, d_beta, d_v, d_q, d_k_, d_gc, back = _solved_pullback(
+        k, v, beta, p, d_w, d_u, d_qg, d_krev)
+    d_gc = jnp.sum(d_gc, 1, keepdims=True)                   # (R, 1)
+    back = jnp.sum(back, 1, keepdims=True)
+    ends = jnp.concatenate(
+        [jnp.broadcast_to(jnp.sum(back[c:c + CHUNK], 0, keepdims=True),
+                          (CHUNK, 1)) for c in range(0, r, CHUNK)], 0)
+    rows = lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+    d_gc = d_gc + jnp.where(rows % CHUNK == CHUNK - 1, ends + d_gl, 0.0)
+    # through a = (k k^T) ratio, b = (q k^T) ratio, ratio = exp(G_i - G_j)
+    moved = d_a * p["a"] + d_b * p["b"]
+    d_gc = d_gc + jnp.sum(moved, 1, keepdims=True) \
+        - _as_column(jnp.sum(moved, 0, keepdims=True), row, col)
+    d_scores = jnp.concatenate([d_a, d_b], 0) * jnp.concatenate(
+        [p["ratio"], p["ratio"]], 0)                         # (2R, R)
+    along = _mm(d_scores, k, 1, 0)                           # rows i
+    d_k_ = d_k_ + along[:r] \
+        + _mm(d_scores, jnp.concatenate([k, q], 0), 0, 0)    # rows j
+    d_q = d_q + along[r:]
+    # through the cumulative sum: a reverse cumulative sum inside the chunk
+    d_g = jnp.sum(jnp.where(same & (col <= row), d_gc, 0.0), 0,
+                  keepdims=True)
+    return d_q, d_k_, d_v, d_g, _as_row(d_beta, row, col)
 
 
 def _local_pullback(q, k, v, beta, p, d_w, d_u, d_qg, d_b, d_krev, d_gl):
@@ -209,26 +346,10 @@ def _local_pullback(q, k, v, beta, p, d_w, d_u, d_qg, d_b, d_krev, d_gl):
     dbeta as a ROW (1, R)."""
     r, d_k = q.shape
     row, col, same = _grid_masks(r)
-    gc, a, t = p["gc"], p["a"], p["t"]
-    # through [w | u] = t rhs and t = (I + beta a)^-1
-    kv = jnp.concatenate([p["kg"], v], 1)
-    d_solved = jnp.concatenate([d_w, d_u], 1)
-    d_rhs = _mm(t, d_solved, 0, 0)
-    # d t = d_solved rhs^T and d (I + beta a) = -t^T d_t t^T, as one product
-    d_m = jnp.where(same & (col < row),
-                    -_mm(d_rhs, jnp.concatenate([p["w"], p["u"]], 1), 1, 1),
-                    0.0)
-    d_a = beta * d_m
-    d_beta = jnp.sum(d_m * a, -1, keepdims=True) \
-        + jnp.sum(d_rhs * kv, -1, keepdims=True)
-    d_beta = jnp.sum(jnp.where(row == col, d_beta, 0.0), 0, keepdims=True)
-    d_kg = beta * d_rhs[:, :d_k]
-    d_v = beta * d_rhs[:, d_k:]
-    # through kg, qg, krev, last
-    back = d_krev * p["krev"]
-    d_k_ = d_kg * p["decay"] + d_krev * p["erev"]
-    d_q = d_qg * p["decay"]
-    d_gc = d_kg * p["kg"] + d_qg * p["qg"] - back
+    gc = p["gc"]
+    d_a, d_beta, d_v, d_q, d_k_, d_gc, back = _solved_pullback(
+        k, v, beta, p, d_w, d_u, d_qg, d_krev)
+    d_beta = _as_row(d_beta, row, col)
     ends = jnp.concatenate(
         [jnp.broadcast_to(jnp.sum(back[c:c + CHUNK], 0, keepdims=True),
                           (CHUNK, d_k)) for c in range(0, r, CHUNK)], 0)
@@ -289,11 +410,23 @@ def _tile_operands(refs, rs):
     return tuple(ref[0, rs, :].astype(_F32) for ref in refs)
 
 
+def _tile_parts(refs, g_ref, g_head, beta, rs):
+    """A tile's operands and chunk-local parts: ``g_head`` None for a decay
+    a channel (g read from its (1, rows, d_k) block), this head's (rows, 1)
+    column for one decay a head."""
+    q, k, v = _tile_operands(refs, rs)
+    if g_head is None:
+        g, = _tile_operands((g_ref,), rs)
+        return q, k, v, _local(q, k, v, g, beta[rs])
+    return q, k, v, _local_head(q, k, v, g_head[rs], beta[rs])
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
-                scale: float, m: int, tile: int):
+                scale: float, m: int, tile: int, per_head: bool):
     """Grid (B, H, S / (m 64)), the last axis in order. ``rest``: the saved
     states' block (1, 1, m, d_v, d_k) where the backward wants them, then
-    the state scratch (d_v, d_k)."""
+    the state scratch (d_v, d_k). ``per_head``: g comes as beta does, a
+    (1, rows, H) block of which the program takes its column."""
     state = rest[-1]
     saved = rest[0] if len(rest) == 2 else None
 
@@ -303,10 +436,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
 
     r = tile * CHUNK
     beta = _column(beta_ref, m * CHUNK)
+    g_head = _column(g_ref, m * CHUNK) if per_head else None
     for ti in range(m // tile):
         rs = slice(ti * r, (ti + 1) * r)
-        q, k, v, g = _tile_operands((q_ref, k_ref, v_ref, g_ref), rs)
-        p = _local(q, k, v, g, beta[rs])
+        _, _, _, p = _tile_parts((q_ref, k_ref, v_ref), g_ref, g_head, beta,
+                                 rs)
         st = state[...]
         wrote, read = [], []
         for c in range(tile):
@@ -317,8 +451,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
                        st, 1, 1)
             wrote.append(p["u"][cs] - both[:CHUNK])
             read.append(both[CHUNK:])
-            last = jnp.exp(p["gl"][cs][:1])
-            st = st * last + _mm(wrote[-1], p["krev"][cs], 0, 0)
+            st = st * _chunk_decay(p["gl"][cs], st) \
+                + _mm(wrote[-1], p["krev"][cs], 0, 0)
         state[...] = st
         o = scale * (jnp.concatenate(read, 0)
                      + _mm(p["b"], jnp.concatenate(wrote, 0), 1, 0))
@@ -327,7 +461,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, saved, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_state, *,
-                scale: float, m: int, tile: int):
+                scale: float, m: int, tile: int, per_head: bool):
     """The same grid walked from the sequence's last program (the index
     maps), and inside a program from its last chunk; ``d_state`` (d_v, d_k)
     carries the state's gradient."""
@@ -337,11 +471,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, saved, do_ref,
 
     r = tile * CHUNK
     beta = _column(beta_ref, m * CHUNK)
+    g_head = _column(g_ref, m * CHUNK) if per_head else None
     row, col, same = _grid_masks(r)
     for ti in reversed(range(m // tile)):
         rs = slice(ti * r, (ti + 1) * r)
-        q, k, v, g = _tile_operands((q_ref, k_ref, v_ref, g_ref), rs)
-        p = _local(q, k, v, g, beta[rs])
+        q, k, v, p = _tile_parts((q_ref, k_ref, v_ref), g_ref, g_head, beta,
+                                 rs)
         d_out = scale * do_ref[0, rs, :].astype(_F32)
         chunks = [slice(c * CHUNK, (c + 1) * CHUNK) for c in range(tile)]
         states = [saved[0, 0, ti * tile + c] for c in range(tile)]
@@ -359,21 +494,28 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, saved, do_ref,
             d_w[c], d_qg[c] = -both[:CHUNK], both[CHUNK:]
             d_krev[c] = _mm(wrote[cs], ds, 1, 0)
             last = jnp.exp(p["gl"][cs][:1])
-            d_gl[c] = jnp.broadcast_to(
-                jnp.sum(st * ds, 0, keepdims=True) * last,
-                (CHUNK, last.shape[1]))
-            ds = ds * last + _mm(
+            # d last: a channel's (1, d_k), or the whole state's (1, 1)
+            d_last = jnp.sum(st * ds, 0, keepdims=True)
+            if per_head:
+                d_last = jnp.sum(d_last, 1, keepdims=True)
+            d_gl[c] = jnp.broadcast_to(d_last * last,
+                                       (CHUNK, last.shape[1]))
+            ds = ds * _chunk_decay(p["gl"][cs], ds) + _mm(
                 jnp.concatenate([d_out[cs], d_u[c]], 0),
                 jnp.concatenate([p["qg"][cs], -p["w"][cs]], 0), 0, 0)
         d_state[...] = ds
         cat = lambda xs: jnp.concatenate(xs, 0)
-        d_q, d_k, d_v, d_g, d_beta = _local_pullback(
+        pullback = _local_head_pullback if per_head else _local_pullback
+        d_q, d_k, d_v, d_g, d_beta = pullback(
             q, k, v, beta[rs], p, cat(d_w), cat(d_u), cat(d_qg), d_b,
             cat(d_krev), cat(d_gl))
         dq_ref[0, rs, :] = d_q.astype(dq_ref.dtype)
         dk_ref[0, rs, :] = d_k.astype(dk_ref.dtype)
         dv_ref[0, rs, :] = d_v.astype(dv_ref.dtype)
-        dg_ref[0, rs, :] = d_g.astype(dg_ref.dtype)
+        if per_head:            # a row, as d beta
+            dg_ref[0, 0, 0, :, rs] = d_g
+        else:
+            dg_ref[0, rs, :] = d_g.astype(dg_ref.dtype)
         dbeta_ref[0, 0, 0, :, rs] = d_beta
 
 
@@ -407,6 +549,7 @@ def _forward(q, k, v, g, beta, scale, m, interpret, with_states: bool):
     b, s, h, d_k, d_v, rows, grid, tile = _geometry(q, v, m)
     flat = lambda x: x.reshape(b, s, -1)
     qs, vs, bs, ss = _specs(rows, d_k, d_v, h, grid[2], False)
+    per_head = g.ndim == 3
     out_shape = [jax.ShapeDtypeStruct((b, s, h * d_v), v.dtype)]
     out_specs = [vs]
     if with_states:
@@ -414,9 +557,10 @@ def _forward(q, k, v, g, beta, scale, m, interpret, with_states: bool):
             (b, h, s // CHUNK, d_v, d_k), _F32))
         out_specs.append(ss)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, m=m, tile=tile),
-        name="kda_scan_fwd", grid=grid,
-        in_specs=[qs, qs, vs, qs, bs],
+        functools.partial(_fwd_kernel, scale=scale, m=m, tile=tile,
+                          per_head=per_head),
+        name="gdn_scan_fwd" if per_head else "kda_scan_fwd", grid=grid,
+        in_specs=[qs, qs, vs, bs if per_head else qs, bs],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((d_v, d_k), _F32)],
         compiler_params=_compiler_params(), interpret=interpret,
@@ -430,32 +574,42 @@ def _backward(q, k, v, g, beta, states, d_o, scale, m, interpret):
     n = grid[2]
     flat = lambda x: x.reshape(b, s, -1)
     qs, vs, bs, ss = _specs(rows, d_k, d_v, h, n, True)
+    per_head = g.ndim == 3
+    line = pl.BlockSpec(            # one number a token, written as a row
+        (1, 1, 1, 1, rows), lambda b_, i, c: (b_, i, n - 1 - c, 0, 0),
+        memory_space=pltpu.VMEM)
+    lines = jax.ShapeDtypeStruct((b, h, n, 1, rows), _F32)
     d_q, d_k_, d_v_, d_g, d_beta = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale, m=m, tile=tile),
-        name="kda_scan_bwd", grid=grid,
-        in_specs=[qs, qs, vs, qs, bs, ss, vs],
-        out_specs=[qs, qs, vs, qs, pl.BlockSpec(
-            (1, 1, 1, 1, rows), lambda b_, i, c: (b_, i, n - 1 - c, 0, 0),
-            memory_space=pltpu.VMEM)],
+        functools.partial(_bwd_kernel, scale=scale, m=m, tile=tile,
+                          per_head=per_head),
+        name="gdn_scan_bwd" if per_head else "kda_scan_bwd", grid=grid,
+        in_specs=[qs, qs, vs, bs if per_head else qs, bs, ss, vs],
+        out_specs=[qs, qs, vs, line if per_head else qs, line],
         out_shape=[jax.ShapeDtypeStruct((b, s, h * d_k), q.dtype),
                    jax.ShapeDtypeStruct((b, s, h * d_k), k.dtype),
                    jax.ShapeDtypeStruct((b, s, h * d_v), v.dtype),
-                   jax.ShapeDtypeStruct((b, s, h * d_k), g.dtype),
-                   jax.ShapeDtypeStruct((b, h, n, 1, rows), _F32)],
+                   lines if per_head
+                   else jax.ShapeDtypeStruct((b, s, h * d_k), g.dtype),
+                   lines],
         scratch_shapes=[pltpu.VMEM((d_v, d_k), _F32)],
         compiler_params=_compiler_params(), interpret=interpret,
     )(flat(q), flat(k), flat(v), flat(g), beta, states, flat(d_o))
-    d_beta = d_beta.reshape(b, h, s).swapaxes(1, 2).astype(beta.dtype)
+    token_major = lambda x, like: x.reshape(b, h, s).swapaxes(1, 2).astype(
+        like.dtype)
     return (d_q.reshape(q.shape), d_k_.reshape(k.shape),
-            d_v_.reshape(v.shape), d_g.reshape(g.shape), d_beta)
+            d_v_.reshape(v.shape),
+            token_major(d_g, g) if per_head else d_g.reshape(g.shape),
+            token_major(d_beta, beta))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def kda_scan_pallas(q, k, v, g, beta, scale: float, m: int,
                     interpret: bool):
-    """``ops/kda.kda_scan``'s Pallas arm: q, k, g (B, S, H, d_k), v
-    (B, S, H, d_v), beta (B, S, H) -> o (B, S, H, d_v) in v's type; ``m``
-    chunks of 64 a program (``kda_blocks``)."""
+    """``ops/kda.kda_scan``'s Pallas arm: q, k (B, S, H, d_k), g the same
+    (a decay a channel) or (B, S, H) f32 (one a head), v (B, S, H, d_v),
+    beta (B, S, H) -> o (B, S, H, d_v) in v's type; ``m`` chunks of 64 a
+    program (``kda_blocks``). Both widths multiples of 128 (``kda_scan``
+    pads a per-head scan's lanes to them)."""
     return _forward(q, k, v, g, beta, scale, m, interpret, False)
 
 

@@ -365,14 +365,16 @@ class CCAParameter:
 
 @dataclass
 class KDAParameter:
-    """The recurrent-state layers (gated delta rule with a per-channel
-    decay, arXiv:2510.26692). SHORT_CONV: ``kernel_size`` causal taps a
-    channel (``weight_filler``), then SiLU.
+    """The recurrent-state layers (gated delta rule with a decay a head and
+    channel, arXiv:2510.26692, or one a head, Gated DeltaNet). SHORT_CONV:
+    ``kernel_size`` causal taps a channel (``weight_filler``), then SiLU.
     L2_NORM: each of ``num_heads`` heads over its own dims, x * rsqrt(sum
     x^2 + ``eps``). KDA_DECAY: g = -exp(A_log) softplus(x + dt_bias), A_log
     (one a head) the log of a uniform draw in [``a_min``, ``a_max``],
-    dt_bias (one a channel) the inverse softplus of a log-uniform draw in
-    [``dt_min``, ``dt_max``]. KDA_SCAN: ``num_heads`` states."""
+    dt_bias (one a channel of its bottom: one a HEAD where the bottom is
+    (N, S, H)) the inverse softplus of a log-uniform draw in [``dt_min``,
+    ``dt_max``]. KDA_SCAN: ``num_heads`` states, their two widths read off
+    q's and v's bottoms."""
     num_heads: int = 1
     kernel_size: int = 4
     eps: float = 1e-6

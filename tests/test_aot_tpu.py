@@ -654,6 +654,72 @@ def test_kimi_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
 
 
 
+# The full-width Olmo-Hybrid train step (examples/lm/olmo_hybrid_7b_*:
+# published layers 0-3, linear x3 + full; 15 of the 30 heads of both mixers
+# held, an eighth of the untied vocabulary) as `train --bf16 --remat <the
+# solver header's flags>` builds it at one sequence of 8,192, for one abstract
+# v5e chip: the compiler's memory accounting that fixed the cell's batch
+# (benchmark/cells/olmo_hybrid.p1.pack8k.json).
+_OLMO_HYBRID_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 1 + {deeper}, 8192, 0").replace(
+    "ouro_2_6b_solver", "olmo_hybrid_7b_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type in ("ATTENTION", "KDA_SCAN") '
+    'for l in net_param.layers)')
+assert _OLMO_HYBRID_STEP.count("olmo_hybrid") == 1 \
+    and "ouro_2" not in _OLMO_HYBRID_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("more", [0, 1])
+def test_olmo_hybrid_full_width_step_fits_one_v5e_at_one_and_two_sequences(
+        more):
+    """At one sequence of 8,192 and 15 of 30 heads the step with one
+    checkpoint a layer is under 85% of the 16.9 GB the compiler allows (PR
+    22's sizing rule): 12.17 GB = 72.0% (9.20 GB of arguments + 2.97 GB of
+    temporaries; 12.37 with the ``jax.numpy`` scan; PR 48), and at two it
+    still is (13.68 GB = 80.9%: ISSUE 48 fixes the cell at one). The three
+    Gated DeltaNet layers' recurrences are the scan's Pallas kernels'
+    per-head arm (128 chunks of 64, four a program, the f32 state in VMEM,
+    heads of 96 / 192 in lanes padded to 128 / 256: the forward, its replay
+    and the backward, one call each a layer), the full layer's three flash
+    kernels run at 15 heads of 128, token-major, no positions: Mosaic
+    compiles all of them for the v5e here."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c",
+         _OLMO_HYBRID_STEP.format(repo=REPO, deeper=more)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next cut
+    assert got["depth"] == 4 and got["parameters"] == 766_241_946
+    # embed, head, final norm; a layer: 2 norms + 3 FFN; a linear mixer 13
+    # (q k v z o a b, 3 convs, A_log + dt_bias, out-norm), the full one 6
+    assert got["leaves"] == 3 + 4 * 5 + 3 * 13 + 6
+    assert got["segments"] == 4 + 1
+    assert got["routes"] == [
+        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
+        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "head; operands token-major (B,S,HxD)); no positions",
+        "kda=pallas (C 64 x 4, 128 chunks, f32 state in VMEM, one decay a "
+        "head, lanes 96 / 192 padded to 128 / 256)"]
+    # 4 flash calls in the full layer; a linear layer's scan three times:
+    # forward, the replay, backward
+    assert got["pallas_custom_calls"] == 4 + 3 * 3
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    if more:
+        assert 0.76 * 16.9 < got["total_gb"] < 0.85 * 16.9
+    else:
+        assert 0.68 * 16.9 < got["total_gb"] < 0.76 * 16.9
+
+
 # The full-width SmallThinker train step (examples/lm/smallthinker_21b_*:
 # published layers 0-3, global, window, window, window; 16 of 64 experts held,
 # an eighth of the untied vocabulary) as `train --bf16 --remat <the solver

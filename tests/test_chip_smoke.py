@@ -51,8 +51,8 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
     cold, warm = result["cold"], result["warm"]
     assert cold["cache_dir"] == str(cache)
     assert sorted(cold["phases"]) == ["flash", "held_chunks", "kernels",
-                                      "resume", "train_bf16", "train_f32",
-                                      "train_sfb_auto"]
+                                      "resume", "scan", "train_bf16",
+                                      "train_f32", "train_sfb_auto"]
     assert cold["phases"]["train_bf16"]["steps"] == 24
     assert cold["phases"]["resume"]["compiled_step"]["source"] == "loaded"
     assert warm["phases"]["warm_resume"]["xla_entries_added"] == 0
@@ -73,6 +73,13 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
         "operands head-major (Dh 24, not lane-aligned)"
     assert sum("relative l2" in k for k in flash["tiny"]) == 8
     assert sum("relative l2" in k for k in flash["tiny narrow"]) == 4
+    # the delta-rule scans, each through the arm kda_route chose
+    scan = cold["phases"]["scan"]
+    assert scan["tiny per-head"]["arm"] == scan["tiny per-channel"]["arm"] \
+        == "chunked"
+    assert "one decay a head" in scan["tiny per-head"]["route"]
+    assert all(sum("relative l2" in k for k in v) == 6
+               for v in scan.values())
     assert sorted(os.listdir(cache / "aot"))   # the step store rode along
 
 

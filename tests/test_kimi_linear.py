@@ -357,19 +357,25 @@ def test_pallas_blocks_rule():
 def test_route_by_backend_and_shape(monkeypatch):
     """The Pallas arm where Mosaic compiles (here: the AOT-for-the-chip
     switch) and the kernels take the shape; the ``jax.numpy`` arms'
-    notes letter for letter everywhere else."""
+    notes letter for letter everywhere else, each with WHY the kernels
+    did not take the shape (PR 48: the note names the reason)."""
     old = ("chunked", "chunked C 64, 128 chunks, f32 state")
     assert kda.kda_route(8192) == old
-    assert kda.kda_route(8192, 128, 128, 32) == old          # the CPU mesh
+    assert kda.kda_route(8192, 128, 128, 32) == (            # the CPU mesh
+        "chunked", old[1] + "; not pallas: this backend would interpret "
+        "the kernels")
     monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
     assert kda.kda_route(8192, 128, 128, 32) == (
         "pallas", "pallas (C 64 x 4, 128 chunks, f32 state in VMEM)")
     assert kda.kda_route(192, 128, 128, 2) == (
         "pallas", "pallas (C 64 x 1, 3 chunks, f32 state in VMEM)")
     assert kda.kda_route(8192) == old                         # no widths
-    assert kda.kda_route(8192, 16, 16, 4) == old              # the tiny nets
+    assert kda.kda_route(8192, 16, 16, 4) == (               # the tiny nets
+        "chunked", old[1] + "; not pallas: heads of 16 / 16 are no lane "
+        "blocks of (N, S, H d) (multiples of 128)")
     assert kda.kda_route(96, 128, 128, 2) == (
-        "chunked", "chunked C 48, 2 chunks, f32 state")
+        "chunked", "chunked C 48, 2 chunks, f32 state; not pallas: 64 does "
+        "not divide S=96")
     assert kda.kda_route(200, 128, 128, 2)[0] == "recurrence"
     net = build(n=1, s=128, heads=2, head_dim=128, v_dim=128, nope_dim=128,
                 rope_dim=64)
@@ -460,7 +466,9 @@ def test_leaves_scopes_and_routes(model):
         and types["l3_mla_kva_split"] == "SLICE"
     for i in KDA_LAYERS:
         assert net.kernel_routes[f"l{i}_kda_scan"] \
-            == "kda=chunked C 64, 2 chunks, f32 state"
+            == ("kda=chunked C 64, 2 chunks, f32 state; not pallas: heads "
+                "of 16 / 16 are no lane blocks of (N, S, H d) (multiples "
+                "of 128)")
     assert net.kernel_routes["l3_mla_attn"] == (
         "attention=dense; no positions; d 24/16; k_pe repeated x4")
     assert net.recurrent_state() == {

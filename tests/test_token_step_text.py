@@ -1,6 +1,8 @@
 """The token configurations that share the flash kernels, the grouped
 matmuls and the routers (OLMoE, Ouro, ZAYA1; since PR 41 Trinity-Mini and
-Kimi-Linear, whose hashes are PR 43's own: it changed their held arm) trace
+Kimi-Linear, whose hashes are PR 43's own: it changed their held arm; since
+PR 48 Olmo-Hybrid, which ADAPTED ops/kda.py and the KDA layers and left
+Kimi's text as it was, to the byte) trace
 to the
 program they traced to before Trinity's window, sigmoid router and per-head
 norm, and before Kimi-Linear's two head widths in the flash kernels,
@@ -51,6 +53,12 @@ BUILD = {
         batch=N, n_layers=4, hidden=64, heads=2, head_dim=16, kv_rank=32,
         nope_dim=128, rope_dim=64, v_dim=128, dense_width=64, experts=16,
         top_k=2, held=2, expert_width=32, shared_width=32, vocab=128),
+    # one period, Gated DeltaNet x3 (one decay a head, heads of 16 / 32 in
+    # the kernels' padded lanes) + full attention, 1 of 2 heads held
+    "olmo_hybrid": lambda: zoo.olmo_hybrid(
+        batch=N, n_layers=4, hidden=128, heads=2, heads_held=1,
+        key_head_dim=16, value_head_dim=32, attn_head_dim=128, ffn_width=64,
+        vocab=128),
 }
 PARENT = {       # sha256 of the text, its length, its pallas_call equations
     # PR 47's own, all four: it meant to change them. Every ATTENTION layer
@@ -71,6 +79,11 @@ PARENT = {       # sha256 of the text, its length, its pallas_call equations
     # held arm, and with nothing else
     "kimi": ("9bdcb9bc42efc615f3803c633d2f5a4bc1d7747b6ebfb674c0722604e860"
              "6a83", 1005313, 3),
+    # PR 48's own, the configuration's first: 3 flash calls and, a linear
+    # layer, the per-head scan's forward and backward kernels. Moves with
+    # ops/kda.py, ops/kda_pallas.py, the KDA layers and zoo.olmo_hybrid
+    "olmo_hybrid": ("9b956fadf0457a8b3421d6009a1539b423ff4f5881b9e2703183d7"
+                    "efd567532f", 825485, 9),
 }
 
 
