@@ -45,6 +45,7 @@ from ..ops import nn as NN
 from ..proto.messages import NetParameter, NetState, LayerParameter
 from .blob import ParamDef
 from .fillers import fill
+from .remat import checkpoint_unit
 from .layers import (ApplyCtx, DATA_SOURCE_TYPES, LAYOUT_AGNOSTIC,
                      LAYOUT_SPATIAL, Layer, create_layer)
 
@@ -603,6 +604,7 @@ class Net:
         keep_blobs: bool = False,
         input_layout: str = "NCHW",
         remat=None,
+        remat_keep: Tuple[str, ...] = (),
     ) -> NetOutputs:
         """``input_layout`` names the physical layout of the CALLER's 4-D
         input blobs ("NCHW" default — the Caffe contract). Under an NHWC
@@ -619,7 +621,11 @@ class Net:
         outside stays stored as the checkpoint's input; everything it
         makes is dropped after forward and recomputed during backward. A
         chain of one-layer units stores every link; a segment stores only
-        its ends. The wrap changes WHAT IS STORED, never the math."""
+        its ends. ``remat_keep`` (a plan's ``keep``) names what a unit
+        stores of its own making besides: the results of its Pallas forward
+        kernels, which their backward kernels read, so that the replay
+        runs no kernel a second time. The wrap changes WHAT IS STORED,
+        never the math."""
         if train is None:
             train = self.phase == "TRAIN"
         if comm is not None:
@@ -700,7 +706,8 @@ class Net:
                         all_tops.append(tops_)
                     return all_tops
 
-                unit_tops = jax.checkpoint(_body)(lparams, taken)
+                unit_tops = checkpoint_unit(_body, remat_keep)(lparams,
+                                                               taken)
             else:
                 layer = unit[0]
                 with jax.named_scope(layer.name):

@@ -122,6 +122,49 @@ def wrap_checkpoint(fn, policy_name: str):
 
 
 # --------------------------------------------------------------------------- #
+# what a checkpointed unit keeps of its own making
+# --------------------------------------------------------------------------- #
+
+# The share of the device's memory limit (``default_budget_bytes``) that a
+# COMPILED step may take while its units keep named values, where the user
+# gave no ``--hbm_budget_gb``: PERF.md section 7 (49a) has the chip runs it
+# was fixed from.
+KEEP_SHARE = 0.88
+
+
+def keep_rungs(made=None) -> List[Tuple[str, ...]]:
+    """What a plan's units may keep (``RematPlan.keep``), most first: the
+    results of the Pallas forward kernels, which are the residuals of their
+    own backward kernels, so that a unit's replay runs no kernel twice.
+    The order of giving up is by milliseconds a gigabyte: a scan's chunk
+    states are large beside its forward (18-25 ms/GB on the v5e), a flash
+    kernel's output and row statistics small beside its (75 ms/GB), so the
+    scans' go first. Of a program that makes the names ``made`` (None: any
+    of them), the rungs that differ: ``[()]`` where it makes none."""
+    from ..ops.kda import SCAN_SAVED
+    from ..ops.pallas_kernels import FLASH_SAVED
+    rungs: List[Tuple[str, ...]] = []
+    for rung in (SCAN_SAVED + FLASH_SAVED, FLASH_SAVED, ()):
+        rung = tuple(n for n in rung if made is None or n in made)
+        if rung not in rungs:
+            rungs.append(rung)
+    return rungs
+
+
+def checkpoint_unit(fn, keep: Sequence[str] = ()):
+    """``fn`` as one of ``Net.apply``'s checkpointed units: it stores what
+    it takes from outside and, of what it makes, the values named in
+    ``keep`` (``checkpoint_name`` tags: the Pallas forward kernels'
+    results, :func:`keep_rungs`). With none it is bare ``jax.checkpoint``,
+    the program it always was."""
+    import jax
+    if not keep:
+        return jax.checkpoint(fn)
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*keep))
+
+
+# --------------------------------------------------------------------------- #
 # the plan
 # --------------------------------------------------------------------------- #
 
@@ -146,6 +189,10 @@ class RematPlan:
     recompute_flops: float = 0.0        # analytic forward FLOPs re-paid
     lm_policy: str = "none"
     source: str = "analytic"            # analytic | measured | plan | flag
+    # the named values every unit keeps through its backward instead of
+    # replaying the kernel that wrote them (``keep_rungs``); none: a unit
+    # keeps only what it takes from outside
+    keep: Tuple[str, ...] = ()
 
     @property
     def layer_set(self) -> frozenset:
@@ -160,10 +207,17 @@ class RematPlan:
                                      if n not in grouped)
 
     @property
+    def apply_args(self) -> Dict:
+        """``Net.apply``'s two keywords for this plan."""
+        return {"remat": self.units, "remat_keep": self.keep}
+
+    @property
     def active(self) -> bool:
         return bool(self.layers) or self.lm_policy != "none"
 
-    def describe(self) -> str:
+    def describe(self, kept: Optional[Dict] = None) -> str:
+        """One line of the log; ``kept`` is the Engine's decision on what
+        the units keep, once its step is compiled or loaded."""
         if not self.active:
             return "remat: off (fits the budget)"
         mb = self.saved_bytes / 2**20
@@ -173,7 +227,13 @@ class RematPlan:
                 + f", ~{mb:.1f} MiB reclaimed, "
                 f"{self.recompute_flops / 1e6:.1f} MFLOP recompute"
                 + (f", lm={self.lm_policy}"
-                   if self.lm_policy != "none" else ""))
+                   if self.lm_policy != "none" else "")
+                + (f"; units keep {'+'.join(self.keep) or 'nothing'}"
+                   f" ({kept['kept_bytes'] / 2**20:.1f} MiB in "
+                   f"{kept['kept_units']}): the step compiles at "
+                   f"{kept['compiled_peak_bytes'] / 1e9:.2f} GB, held to "
+                   f"{kept['held_to_bytes'] / 1e9:.2f}, "
+                   f"{kept['compiles']} compile(s)" if kept else ""))
 
     def to_doc(self) -> Dict:
         return {"budget_bytes": int(self.budget_bytes),
@@ -183,7 +243,8 @@ class RematPlan:
                 "saved_bytes": int(self.saved_bytes),
                 "recompute_flops": float(self.recompute_flops),
                 "lm_policy": self.lm_policy,
-                "source": self.source}
+                "source": self.source,
+                "keep": list(self.keep)}
 
     @classmethod
     def from_doc(cls, doc: Dict) -> "RematPlan":
@@ -197,7 +258,8 @@ class RematPlan:
                    recompute_flops=float(doc.get("recompute_flops", 0.0)),
                    lm_policy=normalize_policy(doc.get("lm_policy",
                                                       "none")),
-                   source=str(doc.get("source", "plan")))
+                   source=str(doc.get("source", "plan")),
+                   keep=tuple(doc.get("keep", ())))
 
 
 def resolve_entries(layer_names: Sequence[str], entries: Sequence[str]

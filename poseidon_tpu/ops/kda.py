@@ -85,6 +85,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 _SUB = 16                  # tokens a sub-block: ratios inside it elementwise
 _GROUP = 1024              # tokens whose chunk-local parts live at once
@@ -407,8 +408,13 @@ def _kda_chunked(q, k, v, g, beta, scale, chunk):
     return _forward(q, k, v, g, beta, scale, chunk)[0]
 
 
+SCAN_SAVED = ("scan_out", "scan_states")   # either arm's forward results
+
+
 def _kda_fwd(q, k, v, g, beta, scale, chunk):
     o, states = _forward(q, k, v, g, beta, scale, chunk)
+    # named as the flash kernel's results are (pallas_kernels._flash_vjp_fwd)
+    o, states = map(checkpoint_name, (o, states), SCAN_SAVED)
     return o, (q, k, v, g, beta, states)
 
 

@@ -51,9 +51,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .kda import SCAN_SAVED
 from .pallas_kernels import _compiler_params
 
 CHUNK = 64                 # tokens a chunk: the only one the kernels take
@@ -615,6 +617,8 @@ def kda_scan_pallas(q, k, v, g, beta, scale: float, m: int,
 
 def _vjp_fwd(q, k, v, g, beta, scale, m, interpret):
     o, states = _forward(q, k, v, g, beta, scale, m, interpret, True)
+    # named as the flash kernel's results are (pallas_kernels._flash_vjp_fwd)
+    o, states = map(checkpoint_name, (o, states), SCAN_SAVED)
     return o, (q, k, v, g, beta, states)
 
 

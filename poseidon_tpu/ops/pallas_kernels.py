@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -736,6 +737,9 @@ def flash_attention(q, k, v, causal: bool = False,
     return out
 
 
+FLASH_SAVED = ("flash_out", "flash_lse")   # the forward kernel's two results
+
+
 def _flash_scale(q, scale, heads):
     if scale is not None:
         return scale
@@ -750,6 +754,11 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                           block_q, block_k, interpret,
                           window=_band_window(window, causal, q.shape[-2]),
                           heads=heads)
+    # what the kernel wrote is what its backward needs: named, so that a
+    # checkpoint whose policy asks for these keeps them and its replay runs
+    # no second forward (core/remat.py); elsewhere a name is the identity
+    # and lowers to nothing
+    out, lse = map(checkpoint_name, (out, lse), FLASH_SAVED)
     return out, (q, k, v, out, lse)
 
 

@@ -837,15 +837,15 @@ def build_spmd_train_step(
         return jax.random.fold_in(rng, flat_idx)
 
     # layers whose forward bodies Net.apply wraps in jax.checkpoint
-    _remat = (remat_plan.units
-              if remat_plan is not None and remat_plan.layers else None)
+    _remat = (remat_plan.apply_args
+              if remat_plan is not None and remat_plan.layers else {})
 
     def _forward_backward(arena_bufs, excl_params, batch, rng):
         if layout is not None:
             def loss_fn(bufs, excl):
                 p = layout.merge(layout.views(*bufs), excl)
                 o = net.apply(p, batch, train=True, rng=rng, comm=ctx,
-                              input_layout=input_layout, remat=_remat)
+                              input_layout=input_layout, **_remat)
                 return o.loss, o
 
             (bucket_grads, excl_grads), out = jax.grad(
@@ -854,7 +854,7 @@ def build_spmd_train_step(
         else:
             def loss_fn(excl):
                 o = net.apply(excl, batch, train=True, rng=rng, comm=ctx,
-                              input_layout=input_layout, remat=_remat)
+                              input_layout=input_layout, **_remat)
                 return o.loss, o
 
             excl_grads, out = jax.grad(loss_fn, has_aux=True)(excl_params)

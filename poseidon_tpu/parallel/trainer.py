@@ -256,8 +256,8 @@ def build_train_step(
             input_layout=input_layout, remat_plan=remat_plan)
     comm.wire_jnp_dtype()  # fail loudly on a bad wire_dtype string
     # layers whose forward bodies Net.apply wraps in jax.checkpoint
-    _remat = (remat_plan.units
-              if remat_plan is not None and remat_plan.layers else None)
+    _remat = (remat_plan.apply_args
+              if remat_plan is not None and remat_plan.layers else {})
     axis = comm.axis
     dcn = comm.dcn_axis
     axes = comm.sync_axes  # (dcn, data) or (data,)
@@ -353,7 +353,7 @@ def build_train_step(
         def loss_of(p, mb, lrng, lcomm):
             o = net.apply(p, mb, train=True, rng=lrng, comm=lcomm,
                           keep_blobs=bool(dump_blobs),
-                          input_layout=input_layout, remat=_remat)
+                          input_layout=input_layout, **_remat)
             return o.loss, o
 
         if iter_size > 1:

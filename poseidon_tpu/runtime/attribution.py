@@ -132,6 +132,37 @@ def _scope_components(op_name: str) -> List[str]:
     return comps
 
 
+def named_values(jaxpr, plan) -> List[Tuple[str, int, int]]:
+    """``(name, unit, bytes)`` of every value that a unit of ``plan`` (a
+    ``core/remat.RematPlan``) names (``checkpoint_name``, a name in
+    ``plan.keep``) in the forward pass of a traced gradient: what the unit
+    keeps. ``unit`` indexes ``plan.units``; the value's layer is read off
+    its scope, as a device trace's instructions are. A replay (the
+    ``remat2`` equations) names what it makes again and keeps nothing."""
+    import jax
+    unit_of = {layer: at for at, unit in enumerate(plan.units)
+               for layer in ((unit,) if isinstance(unit, str) else unit)}
+    found: List[Tuple[str, int, int]] = []
+
+    def walk(inner):
+        for eqn in inner.eqns:
+            if eqn.primitive.name == "remat2":
+                continue
+            if eqn.primitive.name == "name" \
+                    and eqn.params["name"] in plan.keep:
+                unit = next((unit_of[c] for c in _scope_components(
+                    str(eqn.source_info.name_stack)) if c in unit_of), None)
+                aval = eqn.outvars[0].aval
+                if unit is not None:
+                    found.append((eqn.params["name"], unit,
+                                  aval.size * aval.dtype.itemsize))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
 # collective named scopes emitted by the comm machinery (strategies.py
 # arena buckets, spmd.py mesh collectives): each carries its mesh axis in
 # the name, so a profiled step attributes comm time PER AXIS instead of

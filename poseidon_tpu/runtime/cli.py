@@ -1004,7 +1004,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "as ONE segment, storing only what the run takes "
                         "from outside (/p\\d+_l\\d+_/ = one per block of "
                         "a looped LM); 'auto' plans against "
-                        "--hbm_budget_gb; empty or 'none' = off")
+                        "--hbm_budget_gb; empty or 'none' = off. A unit "
+                        "also keeps what its Pallas forward kernels "
+                        "(flash attention, the KDA / Gated DeltaNet scan) "
+                        "wrote, so that the backward's replay runs none "
+                        "of them twice, while the compiled step stays "
+                        "within --hbm_budget_gb (none given: 88%% of the "
+                        "device's memory); stats.yaml's remat section "
+                        "says what was kept")
     t.add_argument("--bf16", action="store_true",
                    help="the documented bf16 training path: bfloat16 "
                         "compute (MXU-native) + the exact space-to-depth "

@@ -48,7 +48,8 @@ from .spans import recorder
 __all__ = ["resolve_cache_dir", "enable_compile_cache",
            "cache_entries", "watch_cache_hits", "step_key",
            "code_fingerprint",
-           "save_step_executable", "load_step_executable", "aot_entries"]
+           "save_step_executable", "load_step_executable", "load_step_note",
+           "aot_entries"]
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -232,12 +233,15 @@ def _unpack(blob: bytes) -> bytes:
     return zlib.decompress(blob)
 
 
-def save_step_executable(cache_dir: str, key: str, compiled) -> Optional[str]:
+def save_step_executable(cache_dir: str, key: str, compiled,
+                         note: Optional[dict] = None) -> Optional[str]:
     """Serialize a jax Compiled object under the AOT store (atomic tmp +
     rename — a torn write can never shadow a good entry). Returns the
     entry path, or None — logged, never raised — when the program does not
     serialize on this backend or the store cannot be written: the caller
-    holds a good executable either way (best-effort by design)."""
+    holds a good executable either way (best-effort by design). ``note``:
+    what the program's text no longer says of how it was built (JSON
+    beside the entry, written first; ``load_step_note`` reads it)."""
     from jax.experimental.serialize_executable import serialize
 
     from .metrics import log
@@ -250,6 +254,11 @@ def save_step_executable(cache_dir: str, key: str, compiled) -> Optional[str]:
         with recorder.startup("aot_write"):
             path = _aot_path(cache_dir, key)
             os.makedirs(os.path.dirname(path), exist_ok=True)
+            if note is not None:
+                tmp = f"{_note_path(path)}.tmp.{os.getpid()}"
+                with open(tmp, "w") as f:
+                    json.dump(note, f)
+                os.replace(tmp, _note_path(path))
             tmp = f"{path}.tmp.{os.getpid()}"
             with open(tmp, "wb") as f:
                 f.write(blob)
@@ -267,6 +276,20 @@ def save_step_executable(cache_dir: str, key: str, compiled) -> Optional[str]:
         if tmp and os.path.exists(tmp):
             os.unlink(tmp)             # no half-written gigabyte left behind
         return None
+
+
+def _note_path(entry: str) -> str:
+    return entry[:-len(".aotexec")] + ".json"
+
+
+def load_step_note(cache_dir: str, key: str) -> dict:
+    """The note ``save_step_executable`` left beside an entry; ``{}`` where
+    there is none to read."""
+    try:
+        with open(_note_path(_aot_path(cache_dir, key))) as f:
+            return json.load(f)
+    except (OSError, ValueError):
+        return {}
 
 
 def load_step_executable(cache_dir: str, key: str):

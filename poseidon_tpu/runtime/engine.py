@@ -382,7 +382,10 @@ class Engine:
         # attribution table's act_bytes column); --remat either forces an
         # explicit layer list (skipping the measuring compile) or says
         # "auto" (plan against the budget) / "none" (off). The plan is
-        # computed ONCE here, then rides build_train_step(remat_plan=).
+        # computed ONCE here, then rides build_train_step(remat_plan=);
+        # what its units keep of their own making (the Pallas forward
+        # kernels' results) is decided later, on the compiled step
+        # (_compile_step).
         self.remat_plan = None
         self.hbm_budget_gb = hbm_budget_gb
         _want_plan = ((remat or "").strip().lower() not in ("", "none")
@@ -593,11 +596,15 @@ class Engine:
                     "under --mesh sharding", rank=self.rank)
                 dump = []
                 self._h5_train = []
-            self.train_step = build_train_step(
-                self.train_net, sp, self.mesh, self.comm, dump_blobs=dump,
-                input_transform=self._input_transform,
+            # (_compile_step builds the step again with these where what
+            # its units keep changes)
+            self._train_step_args = dict(
+                dump_blobs=dump, input_transform=self._input_transform,
                 iter_size=self.iter_size, donate_batch=donate_batch,
-                plan=self.plan, remat_plan=self.remat_plan)
+                plan=self.plan)
+            self.train_step = build_train_step(
+                self.train_net, sp, self.mesh, self.comm,
+                **self._train_step_args, remat_plan=self.remat_plan)
 
         # --- multi-step dispatch (scan chunks) ---------------------------- #
         # K optimizer steps per compiled dispatch: amortizes the runtime's
@@ -951,21 +958,29 @@ class Engine:
                 from ..config import compile_cache_config
                 from .attribution import param_relayouts
                 from .compile_cache import (load_step_executable,
-                                            save_step_executable,
-                                            watch_cache_hits)
+                                            load_step_note,
+                                            save_step_executable)
                 from .hlo_comm import count_gradient_all_reduces
                 cfg = compile_cache_config()
                 key = self._aot_step_key(batch)
             load.args["key"] = key[:12]
             exec_ = load_step_executable(cfg.cache_dir, key)
             source, stored = "loaded", "found"
-            if exec_ is None:
+            if exec_ is not None:
+                # a loaded step is whatever was decided when it was stored
+                kept = load_step_note(cfg.cache_dir, key).get(
+                    "remat_keep") if self.remat_plan is not None else None
+            else:
                 source = "compiled"
-                low = self.train_step.lowerable or self.train_step.step
-                with startup("step_trace_lower"):
-                    lowered = low.lower(self.params, self.state, batch, rng)
-                with startup("step_compile"), watch_cache_hits() as hits:
-                    exec_ = lowered.compile()
+                exec_, hits, kept = self._compile_step(batch, rng)
+            if kept and self.remat_plan is not None:
+                self.remat_plan = dataclasses.replace(
+                    self.remat_plan, keep=tuple(kept["keep"]))
+                log(self.remat_plan.describe(kept), rank=self.rank)
+                doc["remat_keep"] = kept
+                self.stats.set_section(
+                    "remat", {**self.remat_plan.to_doc(), **kept})
+            if source == "compiled":
                 if hits:
                     # the XLA cache answered: trace paid, compile skipped;
                     # what it hands back is not re-serialized (see
@@ -974,7 +989,9 @@ class Engine:
                 else:
                     with startup("aot_store"):
                         stored = "yes" if save_step_executable(
-                            cfg.cache_dir, key, exec_) else "no (see log)"
+                            cfg.cache_dir, key, exec_,
+                            note={"remat_keep": kept} if kept else None
+                        ) else "no (see log)"
             # the executable is good from here on, whatever the store did
             self._aot_exec = exec_
             log("aot warm start: " + {
@@ -1013,6 +1030,86 @@ class Engine:
                 self._publish_step_scopes(None, doc["error"])
         return doc
 
+    def _compile_step(self, batch, rng):  # static-ok: JIT102
+        """Trace, lower and compile the train step -> (executable, whether
+        the XLA cache answered its compile, what its units keep: None
+        where no layer runs under a checkpoint).
+
+        Under a remat plan the units first keep every name of
+        ``remat.keep_rungs``' first rung: what their Pallas forward
+        kernels wrote, so that the backward's replay runs none of them a
+        second time. That costs memory, and a user who passes ``--remat``
+        wants memory first: the names stay only while the COMPILED step
+        (``measured_peak_bytes``) is within ``--hbm_budget_gb``, or with
+        none given ``KEEP_SHARE`` of the device's limit. Over it, or
+        refused by the compiler, the scans' results go, then the flash
+        kernels', and the step is the one that keeps nothing. A rung that
+        names nothing more than the next of what this program makes is
+        passed over, so a cold start compiles at most three times and a
+        net with no such kernel once; on a backend with no memory
+        statistics, with no budget given, the names stay."""
+        from ..core import remat as remat_mod
+        from .attribution import named_values
+        from .compile_cache import watch_cache_hits
+        startup = span_recorder.startup
+        plan = self.remat_plan
+        units = plan is not None and bool(plan.layers)
+
+        def trace(keep):
+            if units and keep != self.remat_plan.keep:
+                self.remat_plan = dataclasses.replace(plan, keep=keep)
+                self.train_step = build_train_step(
+                    self.train_net, self.sp, self.mesh, self.comm,
+                    **self._train_step_args, remat_plan=self.remat_plan)
+            with startup("step_trace_lower"):
+                traced = self.train_step.lowerable.trace(
+                    self.params, self.state, batch, rng)
+                return traced, traced.lower()
+
+        traced, lowered = trace(remat_mod.keep_rungs()[0] if units else ())
+        # what the units name is read off the program, once; the rungs are
+        # those that differ in what they keep of it
+        named = named_values(traced.jaxpr, self.remat_plan) \
+            if units else []
+        rungs = remat_mod.keep_rungs({name for name, _, _ in named})
+        budget = int(self.hbm_budget_gb * 2**30) \
+            if self.hbm_budget_gb and self.hbm_budget_gb > 0 \
+            else int(remat_mod.KEEP_SHARE * remat_mod.default_budget_bytes())
+        compiles = 0
+        while True:
+            keep = rungs.pop(0)
+            if compiles:
+                traced, lowered = trace(keep)
+            exec_, refused = None, ""
+            try:
+                with startup("step_compile"), watch_cache_hits() as hits:
+                    exec_ = lowered.compile()
+            except Exception as e:  # noqa: BLE001 — more than the chip holds
+                if not rungs:
+                    raise
+                refused = f"{type(e).__name__}: {e}"[:200]
+            compiles += 1
+            if not units:
+                return exec_, hits, None
+            peak = remat_mod.measured_peak_bytes(exec_) if exec_ else 0
+            kept = [(n, u, b) for n, u, b in named if n in keep]
+            doc = {"keep": sorted({n for n, _, _ in kept}),
+                   "kept_units": len({u for _, u, _ in kept}),
+                   "kept_bytes": sum(b for _, _, b in kept),
+                   "compiled_peak_bytes": peak, "held_to_bytes": budget,
+                   "compiles": compiles}
+            if exec_ is not None and (not rungs or not budget
+                                      or peak <= budget):
+                return exec_, hits, doc
+            log(f"remat: the step whose units keep {'+'.join(doc['keep'])}"
+                f" ({doc['kept_bytes'] / 1e9:.2f} GB in "
+                f"{doc['kept_units']}) "
+                + (f"was refused ({refused})" if refused else
+                   f"compiles at {peak / 1e9:.2f} GB, over its "
+                   f"{budget / 1e9:.2f}") + ": keeping less",
+                rank=self.rank)
+            del exec_               # a compiled program holds device memory
+
     def _aot_step_key(self, batch) -> str:  # static-ok: JIT102
         """The AOT store's key for this job's train step. The rule: a part
         is in the key if and only if it reaches the traced program, so
@@ -1024,8 +1121,10 @@ class Engine:
         - the train net: its name, every compute layer's definition as
           parsed (a ratio, a window, an activation: same name and shapes,
           another program), the parameters' and the batch's shapes and
-          dtypes, the activation layout, the remat units, the mean and
-          scale that ``--device_transform`` moves into the step;
+          dtypes, the activation layout, the remat units and the
+          ``--hbm_budget_gb`` that what they keep is held to
+          (``_compile_step``), the mean and scale that
+          ``--device_transform`` moves into the step;
         - the numeric policy, the five lowering switches
           (``LOWERING_ENV``), the comm config, whether the batch is
           donated;
@@ -1083,8 +1182,12 @@ class Engine:
                     for k in solver_fields},
             comm=str(self.comm),
             donate_batch=self._donate_batch,
-            # what runs under which checkpoint is part of the program
-            remat=self.remat_plan.units if self.remat_plan else ())
+            # what runs under which checkpoint is part of the program, and
+            # what its units keep follows from the program, the device and
+            # the budget the user gave (_compile_step): the budget, not
+            # the decision, so that a warm start needs no compile to know
+            remat=self.remat_plan.units if self.remat_plan else (),
+            hbm_budget_gb=float(self.hbm_budget_gb or 0))
 
     # ---------------------------------------------------------------- #
     def iteration(self) -> int:

@@ -937,6 +937,14 @@ _KEY_CASES = {
     "remat_unit": ({"remat": "ip1"}, False),
     "a_layer_s_definition": ({"net": _DROPNET.replace("0.25", "0.5")},
                              False),
+    # what a unit keeps follows from the program, the device and the
+    # budget the user gave (Engine._compile_step): the budget is in the key
+    # and the decision is not, so no start needs a compile to know its key
+    "two_budgets": ({"remat": "ip1", "hbm_budget_gb": 2.0,
+                     "base": {"remat": "ip1", "hbm_budget_gb": 1.0}}, False),
+    "no_budget_on_two_seeds": ({"remat": "ip1",
+                                "solver": {"random_seed": 4},
+                                "base": {"remat": "ip1"}}, True),
 }
 
 
@@ -945,13 +953,16 @@ def test_aot_step_key_follows_the_program_and_nothing_else(case, tmp_path):
     """The store's key is equal where the traced program is (another seed,
     another display cadence, a longer run at a fixed rate) and differs
     where the program does (a rate, a clip, a batch shape, a remat unit, a
-    layer's own numbers under the same name and shapes): a stale load is
-    worse than a slow start."""
+    layer's own numbers under the same name and shapes, the memory budget
+    a checkpointed step is held to): a stale load is worse than a slow
+    start."""
     differs, same = _KEY_CASES[case]
+    differs = dict(differs)
+    base = differs.pop("base", {})       # what both sides have
     changed = _small_engine(tmp_path / "case", **{"net": _DROPNET, **differs})
     if "remat" in differs:
         assert changed.remat_plan is not None and changed.remat_plan.units
-    base = _small_engine(tmp_path / "base", _DROPNET)
+    base = _small_engine(tmp_path / "base", **{"net": _DROPNET, **base})
     assert (_step_key_of(base) == _step_key_of(changed)) is same
 
 

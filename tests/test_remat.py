@@ -20,6 +20,7 @@ Four properties, pinned at tier-1 cost:
 """
 
 import os
+import re
 
 import jax
 import numpy as np
@@ -363,3 +364,309 @@ def test_plan_for_net_step_measured_source():
     assert tight.active          # 1-byte budget cannot fit: must remat
     roomy = remat_mod.plan_for_net_step(net, ts.lowerable, args, 10**12)
     assert not roomy.active      # fits: identity plan
+
+
+# --------------------------------------------------------------------------- #
+# what a unit keeps: the Pallas forward kernels' named results (PR 49)
+# --------------------------------------------------------------------------- #
+
+from poseidon_tpu.ops.kda import SCAN_SAVED                     # noqa: E402
+from poseidon_tpu.ops.pallas_kernels import FLASH_SAVED         # noqa: E402
+from poseidon_tpu.proto.messages import load_net_from_string   # noqa: E402
+
+UNITS = "/l\\d+_/,/lm_/"            # the token cells' --remat: a layer a unit
+KEEPS = {"replayed": (), "flash": FLASH_SAVED,
+         "all": SCAN_SAVED + FLASH_SAVED}
+
+
+def _hybrid(n=1, s=256, source="tokens.txt", **kw):
+    """Two layers: a Gated DeltaNet scan (l0), then full ATTENTION (l1)."""
+    return zoo.olmo_hybrid(batch=n, source=source, **{**dict(
+        n_layers=2, full_every=2, hidden=128, heads=2, heads_held=1,
+        key_head_dim=16, value_head_dim=32, attn_head_dim=128, ffn_width=64,
+        vocab=128), **kw})
+
+
+def _hybrid_net(n=1, s=256, **kw):
+    return Net(load_net_from_string(zoo.to_prototxt(_hybrid(n, s, **kw))),
+               "TRAIN", source_shapes={"tokens": (n, s), "targets": (n, s)})
+
+
+def _unit_plan(net, keep=()):
+    layers, segments = remat_mod.resolve_entries(
+        [l.name for l in net.layers], UNITS.split(","))
+    return RematPlan(layers=layers, segments=segments, keep=tuple(keep),
+                     source="flag")
+
+
+def _token_avals(net, n=1, s=256):
+    import jax.numpy as jnp
+    return (jax.eval_shape(net.init, jax.random.PRNGKey(0)),
+            {k: jax.ShapeDtypeStruct((n, s), jnp.int32)
+             for k in ("tokens", "targets")})
+
+
+@pytest.mark.parametrize("kernel, forward", [("flash", "name=flash_fwd"),
+                                            ("scan", "name=gdn_scan_fwd")])
+@pytest.mark.parametrize("arm", sorted(KEEPS))
+def test_a_unit_that_keeps_a_kernel_s_results_runs_it_once(
+        arm, kernel, forward, monkeypatch):
+    """The gradient's jaxpr of the two-layer net under one checkpoint a
+    layer, lowered for the TPU: a unit's Pallas forward kernel stands twice
+    (the forward, the backward's replay) where its results are not kept and
+    once where they are; the backward kernels once either way."""
+    monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+    net = _hybrid_net()
+    plan = _unit_plan(net, KEEPS[arm])
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: net.apply(
+        p, b, train=True, **plan.apply_args).loss))(*_token_avals(net))
+    text = str(jaxpr)
+    kept = set(FLASH_SAVED if kernel == "flash" else SCAN_SAVED) \
+        <= set(KEEPS[arm])
+    assert text.count(forward) == (1 if kept else 2)
+    assert text.count("name=flash_bwd_dq") == 1
+    assert text.count("name=gdn_scan_bwd") == 1
+    # what the program names under its units, as the Engine reads it
+    from poseidon_tpu.runtime.attribution import named_values
+    named = named_values(jaxpr, plan)
+    assert {n for n, _, _ in named} == set(KEEPS[arm])
+    assert {u for n, u, _ in named if n in FLASH_SAVED} <= {1}
+    assert {u for n, u, _ in named if n in SCAN_SAVED} <= {0}
+    if arm == "all":
+        sizes = {n: b for n, _, b in named}
+        # o (1, 256, 1 head of 128 lanes) and lse (1, 1, 256) in f32; the
+        # scan's o at its padded 128 lanes and 4 chunk states of 128 x 128
+        assert sizes == {"flash_out": 256 * 128 * 4, "flash_lse": 256 * 4,
+                         "scan_out": 256 * 128 * 4,
+                         "scan_states": 4 * 128 * 128 * 4}
+
+
+def test_keep_rungs_follow_what_the_program_makes():
+    """The order of giving up: the scans' results first, the flash
+    kernels' last, then nothing; a rung that keeps no more than the next
+    of what the program makes is no rung."""
+    every = SCAN_SAVED + FLASH_SAVED
+    assert remat_mod.keep_rungs() == [every, FLASH_SAVED, ()]
+    assert remat_mod.keep_rungs(set(every)) == [every, FLASH_SAVED, ()]
+    assert remat_mod.keep_rungs(set(FLASH_SAVED)) == [FLASH_SAVED, ()]
+    assert remat_mod.keep_rungs(set(SCAN_SAVED)) == [SCAN_SAVED, ()]
+    assert remat_mod.keep_rungs(set()) == [()]
+    plan = RematPlan(layers=("a",), keep=FLASH_SAVED, source="flag")
+    assert RematPlan.from_doc(plan.to_doc()) == plan
+    assert plan.apply_args == {"remat": ("a",), "remat_keep": FLASH_SAVED}
+
+
+def _grads(net, params, batch, plan):
+    args = plan.apply_args if plan is not None else {}
+    return jax.jit(jax.value_and_grad(lambda p: net.apply(
+        p, batch, train=True, **args).loss))(params)
+
+
+@pytest.mark.parametrize("arm", ["replayed", "all"])
+def test_loss_and_every_gradient_bitwise_across_the_arms(arm):
+    """Stored (no checkpoint) against replayed and kept: the loss and every
+    leaf's gradient, bit for bit (the CPU's arms: the chunked scan names
+    its results, attention is the dense op)."""
+    import jax.numpy as jnp
+    net = _hybrid_net(s=128)
+    params = net.init(jax.random.PRNGKey(3))
+    key = jax.random.PRNGKey(5)
+    batch = {"tokens": jax.random.randint(key, (1, 128), 0, 128),
+             "targets": jax.random.randint(jax.random.fold_in(key, 1),
+                                           (1, 128), 0, 128)}
+    want = _grads(net, params, batch, None)
+    got = _grads(net, params, batch, _unit_plan(net, KEEPS[arm]))
+    assert float(jnp.abs(want[0])) > 0
+    _tree_equal(want, got, arm)
+
+
+@pytest.mark.parametrize("arm", ["replayed", "kept"])
+def test_flash_kernel_under_a_unit_bitwise(arm):
+    """The flash kernels interpreted, under one unit with the projection
+    that follows them: output and the three gradients equal the stored
+    arm's bit for bit, kept or replayed."""
+    import jax.numpy as jnp
+    from poseidon_tpu.ops.pallas_kernels import flash_attention
+    q, k, v, w = (jax.random.normal(jax.random.PRNGKey(i), shape)
+                  for i, shape in enumerate(
+                      [(1, 2, 64, 16)] * 3 + [(16, 16)]))
+
+    def body(q, k, v):
+        return jnp.tanh(flash_attention(q, k, v, True, interpret=True) @ w)
+
+    def run(f):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a) ** 2), argnums=(0, 1, 2)))(q, k, v)
+
+    unit = remat_mod.checkpoint_unit(
+        body, FLASH_SAVED if arm == "kept" else ())
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(unit(*a) ** 2), argnums=(0, 1, 2)))(q, k, v))
+    assert text.count("name=flash_fwd") == (1 if arm == "kept" else 2)
+    _tree_equal(run(body), run(unit), arm)
+
+
+def _token_engine(tmp_path, **kw):
+    """The two-layer net through the Engine, a sequence a device: HDF5
+    tokens, ADAM with a clip, one checkpoint a layer where ``remat`` says
+    so."""
+    import h5py
+
+    from poseidon_tpu.proto.messages import load_solver
+    from poseidon_tpu.runtime.engine import Engine
+    tmp_path.mkdir(parents=True, exist_ok=True)
+    stream = np.random.RandomState(7).randint(0, 128, 129).astype(np.int32)
+    with h5py.File(tmp_path / "tokens.h5", "w") as h:
+        h["data"] = np.tile(stream[:-1], (2 * N_DEV, 1))
+        h["label"] = np.tile(stream[1:], (2 * N_DEV, 1))
+    (tmp_path / "tokens.txt").write_text(str(tmp_path / "tokens.h5") + "\n")
+    (tmp_path / "net.prototxt").write_text(zoo.to_prototxt(_hybrid(
+        s=128, source=str(tmp_path / "tokens.txt"), hidden=64,
+        attn_head_dim=16)))
+    (tmp_path / "solver.prototxt").write_text(
+        f'net: "{tmp_path / "net.prototxt"}"\nsolver_type: ADAM\n'
+        f'base_lr: 0.004\nlr_policy: "fixed"\nmomentum: 0.9\n'
+        f'momentum2: 0.95\nweight_decay: 0.1\nclip_gradients: 1.0\n'
+        f'max_iter: 3\ndisplay: 0\nsnapshot: 0\n'
+        f'snapshot_after_train: false\nsnapshot_prefix: "snap/x"\n'
+        f'random_seed: 3\n')
+    return Engine(load_solver(str(tmp_path / "solver.prototxt")),
+                  output_dir=str(tmp_path), **kw)
+
+
+def _lowered_text(eng) -> str:
+    batch = eng._next_batch(eng.train_pipelines)
+    return eng.train_step.lowerable.lower(
+        eng.params, eng.state, batch, eng.rng).as_text()
+
+
+@pytest.fixture(scope="module")
+def engine_arms(tmp_path_factory):
+    """name -> (loss after 3 steps, parameters, stats sections, the lowered
+    text of the step the Engine ended on) of the Engine's arms, one cache
+    directory: stored (no checkpoint); replayed (``--remat`` through jit:
+    no compiled step to measure, so the units keep nothing, the parent's
+    program); kept (``--remat``, no budget: on a backend with no memory
+    statistics the names stay); warm (the same job again: loads the kept
+    step); tight (``--hbm_budget_gb`` too small for anything). The cache
+    is this fixture's while it runs (``conftest.jax_cache_env`` is a
+    test's)."""
+    from conftest import _set_jax_cache_dir
+
+    from poseidon_tpu import config
+    from poseidon_tpu.runtime.compile_cache import enable_compile_cache
+    tmp_path = tmp_path_factory.mktemp("arms")
+    before = jax.config.jax_compilation_cache_dir
+    arms = {}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+        _set_jax_cache_dir(str(tmp_path / "cc"))
+        enable_compile_cache()
+        try:
+            for name, kw in (
+                    ("stored", {}), ("replayed", {"remat": UNITS}),
+                    ("kept", {"remat": UNITS}), ("warm", {"remat": UNITS}),
+                    ("tight", {"remat": UNITS, "hbm_budget_gb": 1e-6})):
+                eng = _token_engine(tmp_path / name, **kw)
+                try:
+                    eng._aot_enabled = name != "replayed"
+                    loss = eng.train()["loss"]
+                    arms[name] = (loss, jax.device_get(eng.params),
+                                  dict(eng.stats.sections),
+                                  _lowered_text(eng))
+                finally:
+                    eng.close()
+        finally:
+            config.set_compile_cache_config(cache_dir="", aot_steps=True)
+            _set_jax_cache_dir(before)
+    return arms
+
+
+@pytest.mark.parametrize("arm", ["replayed", "kept", "warm", "tight"])
+def test_engine_arms_bitwise_and_counted(arm, engine_arms):
+    """Through Engine steps: every arm's loss and updated parameters equal
+    the stored arm's bit for bit, and ``stats.yaml: remat`` and the facts
+    line's ``compiled_step`` say what the units keep, in how many bytes
+    and units, the compiled peak, what it was held to, and the compiles
+    the decision took (a loaded step: what was decided when stored)."""
+    loss, params, sections, text = engine_arms[arm]
+    assert loss == engine_arms["stored"][0]
+    _tree_equal(engine_arms["stored"][1], params, arm)
+    doc = sections["remat"]
+    if arm == "replayed":
+        assert doc["keep"] == [] and "compiled_step" not in sections
+        return
+    assert sections["compiled_step"]["remat_keep"] == {
+        k: doc[k] for k in ("keep", "kept_units", "kept_bytes",
+                            "compiled_peak_bytes", "held_to_bytes",
+                            "compiles")}
+    # (tight: the XLA cache may answer with the replayed arm's program,
+    # which is the same program)
+    assert sections["compiled_step"]["source"] in {
+        "warm": ("loaded",), "kept": ("compiled",),
+        "tight": ("compiled", "xla_cache")}[arm]
+    assert doc["compiled_peak_bytes"] > 0
+    if arm == "tight":
+        # nothing fits: the parent's program, text for text
+        assert (doc["keep"], doc["kept_units"], doc["kept_bytes"]) == \
+            ([], 0, 0)
+        assert doc["compiles"] == 2 and doc["held_to_bytes"] == 1073
+        assert text == engine_arms["replayed"][3]
+        assert text != engine_arms["kept"][3]
+    else:
+        # the scan's o (1, 128, 1, 32) and two chunk states (16 x 32), f32
+        assert doc["keep"] == sorted(SCAN_SAVED)
+        assert (doc["kept_units"], doc["compiles"]) == (1, 1)
+        assert doc["kept_bytes"] == 128 * 32 * 4 + 2 * 16 * 32 * 4
+        assert doc["held_to_bytes"] == 0       # no statistics, no budget
+        assert doc == engine_arms["kept"][2]["remat"]
+
+
+def test_stats_yaml_carries_the_kept_counter(tmp_path, jax_cache_env):
+    """What a reader of the run's ``stats.yaml`` finds."""
+    from poseidon_tpu.runtime.compile_cache import enable_compile_cache
+    from poseidon_tpu.runtime.metrics import read_stats_yaml
+    enable_compile_cache()
+    eng = _token_engine(tmp_path, remat=UNITS)
+    try:
+        eng.train(max_iter=1)
+        eng.stats.dump_yaml(str(tmp_path / "stats.yaml"))
+    finally:
+        eng.close()
+    doc = read_stats_yaml(str(tmp_path / "stats.yaml"))   # leaves: strings
+    assert doc["remat"]["keep"] == str(sorted(SCAN_SAVED))
+    assert doc["remat"]["kept_units"] == "1"
+    assert int(doc["remat"]["kept_bytes"]) == 128 * 32 * 4 + 2 * 16 * 32 * 4
+    assert int(doc["remat"]["compiled_peak_bytes"]) > 0
+    assert doc["compiled_step"]["remat_keep"]["compiles"] == "1"
+
+
+@pytest.mark.parametrize("kernel", ["flash", "scan"])
+def test_no_checkpoint_lowers_to_the_same_text_without_the_tags(
+        kernel, monkeypatch):
+    """Outside a checkpoint that asks for it a name is the identity and
+    lowers to nothing: the gradient of the two-layer net with NO unit
+    lowers to the text it has with ``checkpoint_name`` taken out of the
+    three modules (the Pallas arms as lowered for the TPU, outside the
+    Mosaic payloads, and the ``jax.numpy`` scan the CPU runs)."""
+    from poseidon_tpu.ops import kda, kda_pallas, pallas_kernels
+    platforms = None
+    if kernel == "flash":
+        monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+        platforms = ("tpu",)
+    net = _hybrid_net()
+
+    def lowered():
+        text = jax.jit(jax.grad(lambda p, b: net.apply(
+            p, b, train=True).loss)).trace(*_token_avals(net)).lower(
+                lowering_platforms=platforms).as_text()
+        # private functions are numbered as a process meets them, and a
+        # Mosaic payload (its debug locations) is not the same bytes twice
+        text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+        return re.sub(r'backend_config = "[^"]*"', "", text)
+
+    tagged = lowered()
+    for module in (kda, kda_pallas, pallas_kernels):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    assert ("tpu_custom_call" in tagged) == (kernel == "flash")
+    assert tagged == lowered()
