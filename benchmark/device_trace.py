@@ -121,6 +121,38 @@ def load(path: str, platform: str, align_name: Optional[str] = None) -> dict:
     return out
 
 
+def step_starts(ops: List[Op], n_steps: int) -> List[float]:
+    """Where each of ``n_steps`` identical steps begins on one chip's
+    operation line: the starts of the operation that ran exactly once a
+    step and came first (every step runs the same programs in the same
+    order, so its k-th start is the k-th step's). Operations inside loops
+    or conditionals, whose count follows the data, are not candidates."""
+    count: Dict[str, int] = {}
+    for name, _, _ in ops:
+        count[name] = count.get(name, 0) + 1
+    mark = next((name for name, _, _ in ops if count[name] == n_steps), None)
+    if mark is None:
+        raise RuntimeError(f"no operation ran exactly once in each of the "
+                           f"{n_steps} traced steps: they cannot be told "
+                           f"apart")
+    return [start for name, start, _ in ops if name == mark]
+
+
+def since_step(trace: dict, n_steps: int, first: int) -> Tuple[dict, float]:
+    """``load``'s result from step ``first`` (counted from 0) of the
+    ``n_steps`` it holds, chip by chip (the steps a window runs and does not
+    count go), and the earliest such start: where the counted window
+    opens."""
+    cuts = {chip: step_starts(ops, n_steps)[first]
+            for chip, ops in trace["devices"].items()}
+    opens = min(cuts.values())
+    return {"devices": {chip: [op for op in ops if op[1] >= cuts[chip]]
+                        for chip, ops in trace["devices"].items()},
+            "async": {chip: [op for op in ops
+                             if op[1] >= cuts.get(chip, opens)]
+                      for chip, ops in trace["async"].items()}}, opens
+
+
 def union(ops: Iterable[Op]) -> List[Interval]:
     """Merged intervals in which at least one operation runs."""
     merged: List[List[float]] = []

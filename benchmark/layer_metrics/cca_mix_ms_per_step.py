@@ -4,12 +4,12 @@ grouped convolution, q-k mean, L2 norm with its temperature) plus the
 ATTENTION layers' time outside their Pallas calls (rotary positions on half
 a head, head split and merge, the key-value heads' repeat)."""
 
-import zaya_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    mix = zaya_trace.part_ms_per_step(run, "cca_mix")
-    glue = zaya_trace.attention_glue_ms_per_step(run)
+    mix = lm_trace.part_ms_per_step(run, "cca_mix")
+    glue = lm_trace.attention_ms_per_step(run, pallas=False)
     if mix is None or glue is None:
         return None
     return mix + glue

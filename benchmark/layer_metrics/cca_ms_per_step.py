@@ -4,8 +4,8 @@ two convolutions, the q-k mean, the L2 norm, the ATTENTION layer (rotary
 positions and the flash kernels) and the residual add — forward, backward
 and what remat replays."""
 
-import zaya_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    return zaya_trace.part_ms_per_step(run, "cca")
+    return lm_trace.part_ms_per_step(run, "cca")

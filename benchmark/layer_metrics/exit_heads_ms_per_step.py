@@ -4,8 +4,8 @@ projection, the per-token loss over it, the exit gate — and in the
 exit-weighted loss over all passes (the scopes the configuration names
 under ``scopes.exit_heads``)."""
 
-import looplm_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    return looplm_trace.pattern_ms_per_step(run, "exit_heads")
+    return lm_trace.part_ms_per_step(run, "exit_heads")

@@ -1,11 +1,9 @@
-"""Kernels layer: device milliseconds per step, forward, recomputed forward
-and backward, in the dense gated FFN of every application of every layer —
-the gate, up and down projections and the SiLU gate between them (the
-scopes the configuration names under ``scopes.ffn``). Its two norms are
-not in it."""
+"""Kernels layer: device milliseconds per step in the dense FFN (the
+configuration's ``ffn`` scopes: the gate and up projections, their product and
+the down projection): forward, backward and replay."""
 
-import looplm_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    return looplm_trace.pattern_ms_per_step(run, "ffn")
+    return lm_trace.part_ms_per_step(run, "ffn")

@@ -1,12 +1,12 @@
-"""Graph layer: the share of a step's token-to-expert assignments that fell
-on an expert this rank holds, from the step's own routing as the MOE layers
-publish it per display (``*_held_share``; mean over the window's displays and
-layers), in percent. 50 = an even split over 8 of 16; the rows of the
-grouped matmuls, and so ``held_moe_ms_per_step``, follow it."""
+"""Graph layer: the share of all expert assignments that went to an expert
+this chip holds, mean over the window's displays and MoE layers
+(``l<i>_held_share``, the step's own routing), in percent. An even split
+reads held / all experts; the required FLOPs assume it, so read
+``mfu_required`` beside this."""
 
-import zaya_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    share = zaya_trace.mean_of(run, "held_share")
+    share = lm_trace.mean_of(run, "held_share")
     return None if share is None else 100.0 * share

@@ -1,13 +1,10 @@
-"""Graph layer: assignments to a HELD expert that no expert computed, the
-largest per-display value the MOE layers published in the window
-(``*_dropped``). The layer is dropless by construction (every row of a held
-expert lies in its group of the grouped matmul): 0, a check of ``correct``;
-an assignment to an absent expert is not a drop, it is the other rank's."""
+"""Graph layer: the largest ``l<i>_dropped`` any display of the window showed.
+0 by construction — every row of a held expert lies in its group — and a
+check of ``correct`` (the runner's ``no_dropped_token``)."""
 
-import zaya_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    dropped = (run.get("lm") or {}).get("dropped") \
-        if zaya_trace.is_ours(run) else None
+    dropped = lm_trace.section(run).get("dropped")
     return max(dropped) if dropped else None

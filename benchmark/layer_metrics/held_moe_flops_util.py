@@ -1,19 +1,20 @@
 """Kernels layer: the held experts' share of the compute roofline — the
 required FLOPs of the assignments the TRACED steps really routed to an
 expert held here (the MOE layers' ``held_share`` of the display those steps
-fill x tokens x layers x ``flops_zaya.expert_flops_per_assignment``; what
-remat replays counts as zero) over ``held_moe_ms_per_step`` of the same
-steps x the chip's bf16 peak. The share drifts from step to step as the
-routers learn, so both come from the same steps."""
+fill x ``assignments_per_step`` x ``flops_per_assignment``, the
+configuration's ``flops_*.expert_flops_per_assignment``; what remat replays
+counts as zero) over ``held_moe_ms_per_step`` of the same steps x the chip's
+bf16 peak. The share drifts from step to step as the routers learn, so both
+come from the same steps."""
 
-import zaya_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    ms = zaya_trace.part_ms_per_step(run, "held_moe")
-    share = zaya_trace.mean_of(run, "traced_held_share")
-    if not ms or share is None or not run.get("peak_flops_per_s"):
+    share = lm_trace.mean_of(run, "traced_held_share")
+    if share is None:
         return None
     lm = run["lm"]
     need = share * lm["assignments_per_step"] * lm["flops_per_assignment"]
-    return 100.0 * need / (ms / 1e3 * run["peak_flops_per_s"])
+    return lm_trace.flops_util(run, need,
+                               lm_trace.part_ms_per_step(run, "held_moe"))

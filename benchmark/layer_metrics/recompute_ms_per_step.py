@@ -3,8 +3,8 @@ activation remat runs a second time, during backward (the instructions the
 program's map lists under ``recomputed``; they are part of ``bwd_ms_per_step``,
 where they run). What the memory that remat frees costs in time."""
 
-import looplm_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    return looplm_trace.recomputed_ms_per_step(run)
+    return lm_trace.recomputed_ms_per_step(run)

@@ -1,10 +1,9 @@
-"""Kernels layer: device milliseconds per step in the MOE_ROUTER layers — the
-down-projection, the mix with the layer before's state, the three-layer GELU
-MLP, softmax, the biased argmax and the bias's balancing rule, all in f32 —
-forward, backward and replay."""
+"""Kernels layer: device milliseconds per step in the MoE routers (the
+configuration's ``router`` scopes, ``l<i>_router``: the projection or MLP, the
+scores, the top-k and its weights): forward, backward and replay."""
 
-import zaya_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    return zaya_trace.part_ms_per_step(run, "router")
+    return lm_trace.part_ms_per_step(run, "router")

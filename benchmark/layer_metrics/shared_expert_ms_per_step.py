@@ -1,10 +1,9 @@
-"""Kernels layer: device milliseconds per step in the shared expert every
-token takes (``l<i>_shared_{gate,up,act,down}``: a SiLU-gated MLP of 1024)
-and its sum with the routed part (``l<i>_moe_sum``), forward, backward and
-replay."""
+"""Kernels layer: device milliseconds per step in the shared expert (the
+configuration's ``shared_expert`` scopes: its three projections, its gate and
+the sum with the routed part): forward, backward and replay."""
 
-import trinity_trace
+import lm_trace
 
 
 def reduce(run: dict):
-    return trinity_trace.part_ms_per_step(run, "shared_expert")
+    return lm_trace.part_ms_per_step(run, "shared_expert")

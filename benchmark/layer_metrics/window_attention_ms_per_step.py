@@ -1,15 +1,9 @@
-"""Kernels layer: device milliseconds per step in the WINDOW layers' ATTENTION
-scopes (``l<i>_attn_window``: head split and merge, rotary positions, the
-key-value heads' repeat and the three flash kernels over the band), forward,
-backward and what remat replays."""
+"""Kernels layer: device milliseconds per step in the window ATTENTION layers
+(TYPE ``ATTENTION``, named ``l<i>_attn_window``): forward, backward and
+replay, kernels and the operations around them."""
 
 import lm_trace
-import trinity_trace
 
 
 def reduce(run: dict):
-    if not trinity_trace.is_ours(run):
-        return None
-    return lm_trace.self_ms_per_step(
-        run, lambda _, scope, kind: kind == "ATTENTION"
-        and scope.endswith("_attn_window"))
+    return lm_trace.attention_ms_per_step(run, "window")

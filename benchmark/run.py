@@ -65,6 +65,9 @@ def main() -> int:
     ap.add_argument("--keep-trace", default="",
                     help="with --trace 1: copy the raw profiler trace here "
                          "(to look at one by hand)")
+    ap.add_argument("--keep-layers", default="",
+                    help="with --trace 1: write what the per-layer readers "
+                         "reduce (the runner's ``layers``) here as JSON")
     args = ap.parse_args()
 
     bench = load_json(os.path.join(ROOT, "BENCHMARK.json"), "the benchmark")
@@ -112,6 +115,9 @@ def main() -> int:
             "failed": int(result["failed"]), "metrics": {}, "device": device}
     if args.trace:
         layers = result["layers"]
+        if args.keep_layers:
+            with open(args.keep_layers, "w") as f:
+                json.dump(layers, f)
         for metric in bench["per_layer"]:
             if not applies(metric, cell["name"]):
                 continue
@@ -131,7 +137,11 @@ def main() -> int:
                 line["metrics"][metric["name"]] = {
                     "value": float(result["end_to_end"][metric["name"]]),
                     "unit": metric["unit"]}
-    print(json.dumps({"facts": result["facts"]}), flush=True)
+    # the facts line: what the runner saw, and the run's own end-to-end
+    # readings whichever kind of line follows (a traced run's untraced pace
+    # and set-up time are what its per-layer metrics are read against)
+    print(json.dumps({"facts": result["facts"],
+                      "end_to_end": result["end_to_end"]}), flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -140,6 +140,7 @@ class LmdbFeed:
             last = eng.train(max_iter=self.it + n)
         except TrainingDivergedError as e:
             done, last = max(0, e.iteration - self.it), {}
+        self.step_s = (time.perf_counter() - self.stamps[0]) / n
         self.it += n
         losses = [r["loss"] for r in eng.metrics.rows[rows:]]
         if "loss" in last:
@@ -213,6 +214,7 @@ class ResidentFeed:
                     drain(0)
                 stamps.append(time.perf_counter())
         self.it += n
+        self.step_s = (stamps[-1] - stamps[0]) / n
         bad = sum(1 for v in losses if not math.isfinite(v))
         return {"attempted": n, "failed": bad, "losses": losses,
                 "stamps": stamps}
@@ -243,10 +245,27 @@ class CompileCounter:
         return False
 
 
+# How long a cell runs under the profiler BEFORE the steps that count, in whole
+# multiples of its ``trace_steps`` and in the same ``Engine.train`` call. A
+# process's first profiler session stalls every host thread at once for
+# 0.18-0.30 s somewhere in its first 0.7 s (PERF.md, section 5,
+# ``alexnet.lmdb``, has the readings): a cell whose steps are shorter than
+# that reads the stall as its own idle time unless it has passed.
+LEAD_SECONDS = 1.0
+
+
 def trace_window(feed, steps: int, platform: str, trace_dir: str) -> dict:
-    """A short window of its own under the profiler. The span recorder's
+    """A short window of its own under the profiler: ONE call of the feed
+    for the uncounted lead steps (``LEAD_SECONDS`` at the pace of the feed's
+    last call, the measured window's) and the ``steps`` that every reader
+    sees, so that the counted steps run where the measured window's do, deep
+    inside a call, with the loop ahead of the device. The span recorder's
     clock (perf_counter) and the profiler's meet in one event: an instant
-    span and a ``TraceAnnotation`` of the same name, taken back to back."""
+    span and a ``TraceAnnotation`` of the same name, taken back to back. The
+    trace is cut where the chip began the first counted step
+    (``device_trace.since_step``), the recorder's spans with it
+    (``rows_from``: where the counted steps' display rows start in
+    ``Engine.metrics.rows``)."""
     import jax.profiler
     from poseidon_tpu.runtime.spans import recorder
     shutil.rmtree(trace_dir, ignore_errors=True)
@@ -254,12 +273,15 @@ def trace_window(feed, steps: int, platform: str, trace_dir: str) -> dict:
     opts.python_tracer_level = 0     # the Python tracer's events are not read
     opts.host_tracer_level = 1
     recorder.clear()
+    rows = feed.eng.metrics.rows
+    rows_before = len(rows)
+    lead = steps * max(1, math.ceil(LEAD_SECONDS / (steps * feed.step_s)))
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
         recorder.instant(ALIGN)
         with jax.profiler.TraceAnnotation(ALIGN):
             pass
-        feed.steps(steps)
+        feed.steps(lead + steps)
     finally:
         jax.profiler.stop_trace()
     trace = device_trace.load(device_trace.newest_xplane(trace_dir),
@@ -267,12 +289,18 @@ def trace_window(feed, steps: int, platform: str, trace_dir: str) -> dict:
     events = recorder.trace_events()
     mark = next((e["ts"] for e in events if e["name"] == ALIGN), None)
     align_ns = trace.pop("align_ns")
+    counted, opens_ns = device_trace.since_step(trace, lead + steps, lead)
     spans = []
     if mark is not None and align_ns is not None:
+        ends_by = (opens_ns - align_ns) / 1e3 + mark     # recorder's clock, us
         spans = [{"name": e["name"], "dur_ns": e["dur"] * 1e3,
                   "start_ns": align_ns + (e["ts"] - mark) * 1e3}
-                 for e in events if e.get("ph") == "X"]
-    return dict(trace, steps=steps, spans=spans)
+                 for e in events
+                 if e.get("ph") == "X" and e["ts"] + e["dur"] >= ends_by]
+    # the lead steps' display rows are the first ones of the call
+    lead_rows = (len(rows) - rows_before) * lead // (lead + steps)
+    return dict(counted, steps=steps, lead_steps=lead, spans=spans,
+                rows_from=rows_before + lead_rows)
 
 
 # --------------------------------------------------------------------------- #
@@ -488,6 +516,7 @@ def run(job: dict) -> dict:
         "facts": facts,
         # what the per-layer readers (layer_metrics/*.py) reduce
         "layers": {"steps": window["attempted"], "window_s": seconds,
+                   "setup_s": setup_s,
                    "batch_per_chip": batch,
                    "flops_per_image": flops_per_image,
                    "peak_flops_per_s": peak,

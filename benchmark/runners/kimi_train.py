@@ -16,16 +16,17 @@ What is this file's own, and why: ``MODEL_KEYS`` / ``reference_sizes`` (the
 model's keys; ``trinity_train``'s two checks read ``reference_sizes`` as a
 global of their module, and an accepted benchmark file is not this PR's to
 edit, so ``kimi_sizes`` swaps it in for the length of a call); ``run`` (a
-module-level ``MODEL_KEYS``, ``flops_trinity`` and the ``trinity`` marker
-are written into ``trinity_train.run``); the second control
+module-level ``MODEL_KEYS`` and ``flops_trinity`` are written into
+``trinity_train.run``); the second control
 (``state_control``: the reference with its recurrence's state rounded to
 bf16 after every token has to lie outside a limit, as the float8 control
 does); the KDA layers' mean decay per display; and ``compared``, every
 number that decided ``correct`` beside its limit, in the facts line.
 
 The per-layer readers get the keys ``lm_train`` hands them, ONE SEQUENCE as
-the sample; ``lm`` holds what this cell's readers add (``kimi``: the marker
-they look for; ``scopes``: the configuration's layer-name patterns).
+the sample; ``lm`` holds what the token cells' readers add, under the keys
+every token runner shares (``lm_trace``: ``scopes``, the configuration's
+layer-name patterns by part; the required work; the display rows' series).
 """
 
 from __future__ import annotations
@@ -253,7 +254,7 @@ def run(job: dict) -> dict:
                                  dev["platform"],
                                  os.path.join(work, "trace"))
             recorder.disable()
-            traced_rows = eng.metrics.rows[rows_before + len(rows):]
+            traced_rows = eng.metrics.rows[trace["rows_from"]:]
             if job.get("keep_trace"):
                 shutil.copytree(os.path.join(work, "trace"),
                                 job["keep_trace"], dirs_exist_ok=True)
@@ -379,6 +380,7 @@ def run(job: dict) -> dict:
         # what the per-layer readers (layer_metrics/*.py) reduce: the keys
         # caffe_train hands them, one sequence as the sample, plus "lm"
         "layers": {"steps": window["attempted"], "window_s": seconds,
+                   "setup_s": setup_s,
                    "batch_per_chip": batch,
                    "flops_per_image": flops_per_sequence,
                    "peak_flops_per_s": peak,
@@ -387,13 +389,13 @@ def run(job: dict) -> dict:
                    "spans": window_spans, "stats": after,
                    "memory_peak_bytes": memory_peak,
                    "trace": trace,
-                   "lm": {"kimi": True, "seq_len": seq,
+                   "lm": {"seq_len": seq,
                           "flops_per_step": {
                               k: v * seq * batch
                               for k, v in per_token.items()},
                           "flash_per_step": flops_kimi.flash_attention_step(
                               model, batch, seq),
-                          "kda_scan_per_step": flops_kimi.kda_scan_step(
+                          "delta_scan_per_step": flops_kimi.kda_scan_step(
                               model, batch, seq),
                           "flops_per_assignment":
                               flops_kimi.expert_flops_per_assignment(model),
@@ -404,7 +406,6 @@ def run(job: dict) -> dict:
                           "kernel_routes": routes,
                           "held_share": held_share, "expert_load": load,
                           "held_share_by_layer": held_by_layer,
-                          "held_prefix": held_prefix,
                           "dropped": dropped,
                           # the routing of the steps the profiler saw
                           "traced_held_share": per_display(

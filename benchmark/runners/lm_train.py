@@ -289,6 +289,7 @@ def run(job: dict) -> dict:
         # what the per-layer readers (layer_metrics/*.py) reduce: the keys
         # caffe_train hands them, one sequence as the sample, plus "lm"
         "layers": {"steps": window["attempted"], "window_s": seconds,
+                   "setup_s": setup_s,
                    "batch_per_chip": batch,
                    "flops_per_image": flops_per_sequence,
                    "peak_flops_per_s": peak,
@@ -304,6 +305,6 @@ def run(job: dict) -> dict:
                           "flash_per_step": flops_lm.flash_attention_step(
                               model, batch, seq),
                           "peaks": peaks,
-                          "head_scopes": cfg["scopes"]["head"],
+                          "scopes": cfg["scopes"],
                           "expert_load": load, "dropped": dropped}},
     }

@@ -23,14 +23,14 @@ program's own bf16 noise in the logits, so the logits cannot hold the state
 to f32); ``gate_cosine``, the direction of the first update of the leaves
 that only the scan's d g and d beta feed (``GATE_LEAVES``: too small for
 ``cosine_from``, and a norm cannot see a sign); the linear layers' mean
-decay and share of beta > 1 per display, in the facts (the driver's contract
-for ``BENCHMARK.json`` allows "1 to 128" per-layer metrics and the file had
-120: 8 of this cell's are declared); and ``compared``, every number that
-decided ``correct`` beside its limit, LAST in the facts line.
+decay and share of beta > 1 per display, in the facts (facts of the run, no
+metric: nothing a later PR is meant to move); and ``compared``, every number
+that decided ``correct`` beside its limit, LAST in the facts line.
 
 The per-layer readers get the keys ``lm_train`` hands them, ONE SEQUENCE as
-the sample; ``lm`` holds what this cell's readers add (``olmo_hybrid``: the
-marker they look for; ``scopes``: the configuration's layer-name patterns).
+the sample; ``lm`` holds what the token cells' readers add, under the keys
+every token runner shares (``lm_trace``: ``scopes``, the configuration's
+layer-name patterns by part; the required work; the display rows' series).
 """
 
 from __future__ import annotations
@@ -539,6 +539,7 @@ def run(job: dict) -> dict:
         # what the per-layer readers (layer_metrics/*.py) reduce: the keys
         # caffe_train hands them, one sequence as the sample, plus "lm"
         "layers": {"steps": window["attempted"], "window_s": seconds,
+                   "setup_s": setup_s,
                    "batch_per_chip": batch,
                    "flops_per_image": flops_per_sequence,
                    "peak_flops_per_s": peak,
@@ -547,14 +548,14 @@ def run(job: dict) -> dict:
                    "spans": window_spans, "stats": after,
                    "memory_peak_bytes": memory_peak,
                    "trace": trace,
-                   "lm": {"olmo_hybrid": True, "seq_len": seq,
+                   "lm": {"seq_len": seq,
                           "flops_per_step": {
                               k: v * seq * batch
                               for k, v in per_token.items()},
                           "flash_per_step":
                               flops_olmo_hybrid.flash_attention_step(
                                   model, batch, seq),
-                          "gdn_scan_per_step":
+                          "delta_scan_per_step":
                               flops_olmo_hybrid.gdn_scan_step(
                                   model, batch, seq),
                           "peaks": peaks,

@@ -21,13 +21,13 @@ and no bias, so that check would skip the very leaf the new router arm
 trains and fail on an empty stack; this one compares EVERY leaf, with the
 same numbers: loss, worst norm, worst cosine, the float8 control);
 ``expected_first_loss`` (the two auxiliary terms); ``run`` (the model's
-keys, ``flops_smallthinker``, the ``smallthinker`` marker, the gate-zero
-share per display); and ``compared``, every number that decided ``correct``
+keys, ``flops_smallthinker``, the gate-zero share per display); and ``compared``, every number that decided ``correct``
 beside its limit, last in the facts line.
 
 The per-layer readers get the keys ``lm_train`` hands them, ONE SEQUENCE as
-the sample; ``lm`` holds what this cell's readers add (``smallthinker``: the
-marker they look for; ``scopes``: the configuration's layer-name patterns).
+the sample; ``lm`` holds what the token cells' readers add, under the keys
+every token runner shares (``lm_trace``: ``scopes``, the configuration's
+layer-name patterns by part; the required work; the display rows' series).
 """
 
 from __future__ import annotations
@@ -330,7 +330,7 @@ def run(job: dict) -> dict:
                                  dev["platform"],
                                  os.path.join(work, "trace"))
             recorder.disable()
-            traced_rows = eng.metrics.rows[rows_before + len(rows):]
+            traced_rows = eng.metrics.rows[trace["rows_from"]:]
             if job.get("keep_trace"):
                 shutil.copytree(os.path.join(work, "trace"),
                                 job["keep_trace"], dirs_exist_ok=True)
@@ -460,6 +460,7 @@ def run(job: dict) -> dict:
         # what the per-layer readers (layer_metrics/*.py) reduce: the keys
         # caffe_train hands them, one sequence as the sample, plus "lm"
         "layers": {"steps": window["attempted"], "window_s": seconds,
+                   "setup_s": setup_s,
                    "batch_per_chip": batch,
                    "flops_per_image": flops_per_sequence,
                    "peak_flops_per_s": peak,
@@ -468,7 +469,7 @@ def run(job: dict) -> dict:
                    "spans": window_spans, "stats": after,
                    "memory_peak_bytes": memory_peak,
                    "trace": trace,
-                   "lm": {"smallthinker": True, "seq_len": seq,
+                   "lm": {"seq_len": seq,
                           "flops_per_step": {
                               k: v * seq * batch
                               for k, v in per_token.items()},

@@ -19,9 +19,9 @@ decided as ``zaya_train`` decides it, by ``step_check`` on the TIMED path and
 ``reference_check`` on the trained weights, against ``reference/trinity.py``.
 
 The per-layer readers get the keys ``lm_train`` hands them, ONE SEQUENCE as
-the sample; ``lm`` holds what this cell's readers add (``trinity``: the
-marker they look for; ``scopes``: the configuration's layer-name patterns;
-``kernel_routes``: the arms the program states).
+the sample; ``lm`` holds what the token cells' readers add, under the keys
+every token runner shares (``lm_trace``: ``scopes``, the configuration's
+layer-name patterns by part; the required work; the display rows' series).
 """
 
 from __future__ import annotations
@@ -388,7 +388,7 @@ def run(job: dict) -> dict:
                                  dev["platform"],
                                  os.path.join(work, "trace"))
             recorder.disable()
-            traced_rows = eng.metrics.rows[rows_before + len(rows):]
+            traced_rows = eng.metrics.rows[trace["rows_from"]:]
             if job.get("keep_trace"):
                 shutil.copytree(os.path.join(work, "trace"),
                                 job["keep_trace"], dirs_exist_ok=True)
@@ -508,6 +508,7 @@ def run(job: dict) -> dict:
         # what the per-layer readers (layer_metrics/*.py) reduce: the keys
         # caffe_train hands them, one sequence as the sample, plus "lm"
         "layers": {"steps": window["attempted"], "window_s": seconds,
+                   "setup_s": setup_s,
                    "batch_per_chip": batch,
                    "flops_per_image": flops_per_sequence,
                    "peak_flops_per_s": peak,
@@ -516,7 +517,7 @@ def run(job: dict) -> dict:
                    "spans": window_spans, "stats": after,
                    "memory_peak_bytes": memory_peak,
                    "trace": trace,
-                   "lm": {"trinity": True, "seq_len": seq,
+                   "lm": {"seq_len": seq,
                           "flops_per_step": {
                               k: v * seq * batch
                               for k, v in per_token.items()},
@@ -532,7 +533,6 @@ def run(job: dict) -> dict:
                               "kernel_routes", {}).values())),
                           "held_share": held_share, "expert_load": load,
                           "held_share_by_layer": held_by_layer,
-                          "held_prefix": held_prefix,
                           "dropped": dropped,
                           # the routing of the steps the profiler saw
                           "traced_held_share": per_display(

@@ -28,3 +28,10 @@ def fact(run: dict, name: str) -> Optional[float]:
     had no such boundary), or None without the section."""
     sec = section(run)
     return None if sec is None else float(sec.get(name, 0.0))
+
+
+def timeline(run: dict, name: str) -> Optional[dict]:
+    """The top-level start-up span ``name`` as the program's ``timeline``
+    has it (``at_s`` from the OS's start of the process, ``dur_s``), or None
+    without the section or the row."""
+    return ((section(run) or {}).get("timeline") or {}).get(name)

@@ -17,23 +17,15 @@ import pytest
 import flops_kimi
 import tokengen
 from conftest import BENCH_DIR, ROOT
-from layer_metrics import (kda_glue_ms_per_step, kda_ms_per_step,
-                           kda_scan_ms_per_step, kda_scan_roofline,
-                           kimi_head_ms_per_step,
-                           kimi_held_assignment_share,
-                           kimi_held_dropped_assignments,
-                           kimi_held_load_max_over_mean,
-                           kimi_held_moe_flops_util,
-                           kimi_held_moe_ms_per_step,
-                           kimi_held_prefix_hit_share,
-                           kimi_held_share_layer_max,
-                           kimi_recompute_ms_per_step,
-                           kimi_router_ms_per_step,
-                           kimi_shared_expert_ms_per_step,
-                           kimi_tokens_per_s_per_chip,
-                           mla_attention_ms_per_step,
-                           mla_flash_attention_roofline,
-                           mla_glue_ms_per_step)
+from layer_metrics import (attention_glue_ms_per_step, attention_ms_per_step,
+                           delta_glue_ms_per_step, delta_ms_per_step,
+                           delta_scan_ms_per_step, delta_scan_roofline,
+                           flash_attention_roofline, head_ms_per_step,
+                           held_assignment_share, held_dropped_assignments,
+                           held_load_max_over_mean, held_moe_flops_util,
+                           held_moe_ms_per_step, held_share_layer_max,
+                           recompute_ms_per_step, router_ms_per_step,
+                           shared_expert_ms_per_step, tokens_per_s_per_chip)
 from test_bench_run import BENCH, declared, run_cell
 
 CELL = "kimi_linear.e8of256.pack8k"
@@ -291,50 +283,47 @@ def small_run(scopes=SCOPES, lm=True):
            "peak_flops_per_s": PEAKS["bf16_flops_per_s"],
            "stats": {"sections": {"step_scopes": scopes} if scopes else {}}}
     if lm:
-        run["lm"] = {"kimi": True, "seq_len": 8192,
+        run["lm"] = {"seq_len": 8192,
                      "scopes": CFG["scopes"], "peaks": PEAKS,
                      "flash_per_step": {"flops": 2e3, "bytes": 100.0},
-                     "kda_scan_per_step": {"flops": 1e3, "bytes": 500.0},
+                     "delta_scan_per_step": {"flops": 1e3, "bytes": 500.0},
                      "flops_per_assignment": 10.0,
                      "assignments_per_step": 1000,
                      "held_share": [0.02, 0.03, 0.04],
                      "held_share_by_layer": {
                          "l1_held_share": [0.01, 0.05, 0.06],
                          "l2_held_share": [0.03, 0.01, 0.02]},
-                     "held_prefix": {"held_prefix_hits": 39,
-                                     "held_layer_steps": 40},
                      "traced_held_share": [0.25],
                      "expert_load": [1.2, 1.4], "dropped": [0.0, 0.0]}
     return run
 
 
 READERS = [
-    (kda_ms_per_step, 30e-6),                      # (10 + 40 + 4 + 6) ns / 2
-    (kda_scan_ms_per_step, 20e-6),
+    (delta_ms_per_step, 30e-6),                      # (10 + 40 + 4 + 6) ns / 2
+    (delta_scan_ms_per_step, 20e-6),
     # bytes-bound: 500 / 1e11 = 5 ns against 20 ns of scan a step
-    (kda_scan_roofline, 100 * 5e-9 / 20e-9),
-    (kda_glue_ms_per_step, 5e-6),                  # (4 + 6) / 2
-    (mla_attention_ms_per_step, 11e-6),            # (20 + 2) / 2
+    (delta_scan_roofline, 100 * 5e-9 / 20e-9),
+    (delta_glue_ms_per_step, 5e-6),                  # (4 + 6) / 2
+    (attention_ms_per_step, 11e-6),            # (20 + 2) / 2
     # flops-bound: 2e3 / 1e12 = 2 ns against 10 ns of kernel a step
-    (mla_flash_attention_roofline, 100 * 2e-9 / 10e-9),
-    (mla_glue_ms_per_step, 2e-6),                  # (2 + 2) / 2
-    (kimi_router_ms_per_step, 4e-6),
-    (kimi_shared_expert_ms_per_step, 7e-6),
-    (kimi_held_moe_ms_per_step, 15e-6),
+    (flash_attention_roofline, 100 * 2e-9 / 10e-9),
+    (attention_glue_ms_per_step, 2e-6),                  # (2 + 2) / 2
+    (router_ms_per_step, 4e-6),
+    (shared_expert_ms_per_step, 7e-6),
+    (held_moe_ms_per_step, 15e-6),
     # the TRACED steps' 0.25 x 1000 assignments x 10 FLOPs over 15 ns x 1e12
-    (kimi_held_moe_flops_util, 100 * 2.5e3 / (15e-9 * 1e12)),
-    (kimi_held_assignment_share, 3.0),
-    (kimi_held_load_max_over_mean, 1.3),
-    (kimi_held_dropped_assignments, 0.0),
-    (kimi_held_prefix_hit_share, 97.5),            # 39 of 40 layer-steps
-    (kimi_held_share_layer_max, 6.0),              # l1's third display
-    (kimi_head_ms_per_step, 7e-6),                 # (12 + 2) / 2
-    (kimi_recompute_ms_per_step, 22e-6),           # (40 + 4) ns / 2
-    (kimi_tokens_per_s_per_chip, 10 * 2 * 8192 / 4.0),
+    (held_moe_flops_util, 100 * 2.5e3 / (15e-9 * 1e12)),
+    (held_assignment_share, 3.0),
+    (held_load_max_over_mean, 1.3),
+    (held_dropped_assignments, 0.0),
+    (held_share_layer_max, 6.0),              # l1's third display
+    (head_ms_per_step, 7e-6),                 # (12 + 2) / 2
+    (recompute_ms_per_step, 22e-6),           # (40 + 4) ns / 2
+    (tokens_per_s_per_chip, 10 * 2 * 8192 / 4.0),
 ]
-COUNTERS = (kimi_held_assignment_share, kimi_tokens_per_s_per_chip,
-            kimi_held_load_max_over_mean, kimi_held_dropped_assignments,
-            kimi_held_prefix_hit_share, kimi_held_share_layer_max)
+COUNTERS = (held_assignment_share, tokens_per_s_per_chip,
+            held_load_max_over_mean, held_dropped_assignments,
+            held_share_layer_max)
 
 
 @pytest.mark.parametrize("reader, want", READERS)
@@ -344,34 +333,18 @@ def test_each_reader_on_a_hand_made_run(reader, want):
 
 @pytest.mark.parametrize("reader", [r for r, _ in READERS])
 def test_each_reader_finds_nothing_on_a_program_without_it(reader):
-    """The parent's program or another cell's run: no map, another
-    runner's ``lm`` section (Trinity's, ZAYA1's), no trace — None, and
-    nothing raised."""
+    """A program or a run without what the reader reads: no map, no ``lm``
+    section, no trace — None, and nothing raised. (Which CELLS report a
+    metric is its ``workloads`` list's to say, not the reader's: no reader
+    looks for a cell's name.)"""
     assert reader.reduce(small_run(scopes=None, lm=False)) is None
-    assert reader.reduce(small_run(lm=False)) is None
-    for other_lm in ({"trinity": True, "seq_len": 8192, "peaks": PEAKS,
-                      "scopes": {"router": "x", "held_moe": "y",
-                                 "head": "z"},
-                      "flash_per_step": {"window": {"flops": 1, "bytes": 1},
-                                         "global": {"flops": 1, "bytes": 1}},
-                      "held_share": [0.5], "expert_load": [1.0],
-                      "dropped": [0.0], "traced_held_share": [0.5],
-                      "held_prefix": {"held_prefix_hits": 1,
-                                      "held_layer_steps": 1},
-                      "held_share_by_layer": {"l1_held_share": [0.1]}},
-                     {"zaya": True, "seq_len": 8192, "peaks": PEAKS,
-                      "scopes": {"router": "x", "held_moe": "y"},
-                      "flash_per_step": {"flops": 1, "bytes": 1},
-                      "held_share": [0.5], "expert_load": [1.0],
-                      "dropped": [0.0], "traced_held_share": [0.5]}):
-        other = small_run(lm=False)
-        other["lm"] = other_lm
-        assert reader.reduce(other) is None
+    if reader is not recompute_ms_per_step:   # reads the map alone
+        assert reader.reduce(small_run(lm=False)) is None
     if reader not in COUNTERS:                # those need no trace
         assert reader.reduce(dict(small_run(), trace=None)) is None
     # the program's map without this model's scopes (the parent's): the
     # time readers find nothing under their patterns and read 0 or nothing
-    if reader in (kda_scan_roofline, mla_flash_attention_roofline):
+    if reader in (delta_scan_roofline, flash_attention_roofline):
         bare = small_run(scopes={"ops": {"q.1": "l0_q|fwd"},
                                  "types": {"l0_q": "INNER_PRODUCT"}})
         assert reader.reduce(bare) is None
@@ -531,8 +504,12 @@ def test_cpu_tiny_rehearsal_of_the_kimi_cell(trace):
     assert facts["state_control"]["logits_rel_l2"] > 0
     assert facts["kernel_routes"] == [
         "attention=dense; no positions; d 6/4; k_pe repeated x32",
-        "grouped_matmul=ragged_dot; held rows: prefix 128 of 1024, full on "
-        "overflow", "kda=chunked C 64, 2 chunks, f32 state"]
+        # what the program prints since PR 43 (``; held rows: chunks of P of
+        # T k`` on the chip; at these cut sizes two chunks hold every row and
+        # the note says nothing) and PR 47 (why the scan is not the kernel)
+        "grouped_matmul=ragged_dot",
+        "kda=chunked C 64, 2 chunks, f32 state; not pallas: heads of 4 / 4 "
+        "are no lane blocks of (N, S, H d) (multiples of 128)"]
     assert facts["remat_segments"] == DEPTH + 1
     assert facts["expert_share"]["l1_moe"] == {
         "held_first": 0, "num_held": 8, "router_num_experts": 256}
@@ -546,29 +523,31 @@ def test_cpu_tiny_rehearsal_of_the_kimi_cell(trace):
     assert sorted(share["per_layer"]) == [f"l{i}_held_share"
                                           for i in range(1, DEPTH)]
     rungs = share["window_prefix"]
-    assert rungs["held_layer_steps"] == (DEPTH - 1) * line["attempted"] \
+    # counted where a layer's held rows run in chunks (PR 43): every
+    # layer-step on the chip, none at these cut sizes
+    assert rungs["held_layer_steps"] in (0, (DEPTH - 1) * line["attempted"]) \
         and 0 <= rungs["held_prefix_hits"] <= rungs["held_layer_steps"]
     names = set(line["metrics"])
     if trace:
         # all of the cell's per-layer metrics but those that need a chip's
         # peaks, its memory statistics or its Pallas kernels
         assert names == declared("per_layer", CELL) - {
-            "busy_flops_util", "peak_hbm_gb", "kimi_held_moe_flops_util",
-            "kda_scan_roofline", "mla_flash_attention_roofline"}
+            "busy_flops_util", "peak_hbm_gb", "held_moe_flops_util",
+            "delta_scan_roofline", "flash_attention_roofline"}
         m = {k: v["value"] for k, v in line["metrics"].items()}
         assert m["scope_coverage"] >= 95.0
-        parts = ("kda_ms_per_step", "mla_attention_ms_per_step",
-                 "kimi_router_ms_per_step", "kimi_shared_expert_ms_per_step",
-                 "kimi_held_moe_ms_per_step", "kimi_head_ms_per_step")
+        parts = ("delta_ms_per_step", "attention_ms_per_step",
+                 "router_ms_per_step", "shared_expert_ms_per_step",
+                 "held_moe_ms_per_step", "head_ms_per_step")
         assert all(m[k] > 0 for k in parts)
-        assert m["kda_scan_ms_per_step"] + m["kda_glue_ms_per_step"] \
-            < m["kda_ms_per_step"]
+        assert m["delta_scan_ms_per_step"] + m["delta_glue_ms_per_step"] \
+            < m["delta_ms_per_step"]
         # on the CPU the whole ATTENTION layer is glue (no Pallas call)
-        assert m["mla_glue_ms_per_step"] >= m["mla_attention_ms_per_step"]
+        assert m["attention_glue_ms_per_step"] >= m["attention_ms_per_step"]
         assert sum(m[k] for k in parts) \
             < m["fwd_ms_per_step"] + m["bwd_ms_per_step"]
-        assert 0 <= m["kimi_held_assignment_share"] \
-            <= m["kimi_held_share_layer_max"] < 100
+        assert 0 <= m["held_assignment_share"] \
+            <= m["held_share_layer_max"] < 100
     else:
         assert names == declared("end_to_end", CELL) - {"mfu_required"}
         assert line["metrics"]["images_per_s_per_chip"]["value"] == \
@@ -576,28 +555,29 @@ def test_cpu_tiny_rehearsal_of_the_kimi_cell(trace):
 
 
 def test_new_entries_follow_the_contract():
-    cell = BENCH["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == (CELL, "kimi_linear_48b", "packed8k_ep32", 1)
+    cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == ("kimi_linear_48b", "packed8k_ep32", 1)
     assert "layers 1-5 of 27" in cell["why"] \
         and f"{BATCH} x 8192" in cell["why"]
-    config = BENCH["configs"][-1]
-    assert config["name"] == "kimi_linear_48b"
+    config = next(c for c in BENCH["configs"]
+                  if c["name"] == "kimi_linear_48b")
     assert config["reduced"] == CFG["reduced"] == [
         "num_hidden_layers", "num_experts", "vocab_size"]
     assert config["source"] == CFG["source"] \
         and config["file"] == "benchmark/configs/kimi_linear_48b.json"
-    mine = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
-    # at the END of the list, one reader each
-    assert BENCH["per_layer"][-len(mine):] == mine
-    assert sorted(m["name"] for m in mine) \
-        == sorted(r.__name__.rsplit(".", 1)[-1] for r, _ in READERS)
+    mine = [m for m in BENCH["per_layer"]
+            if CELL in m.get("workloads", ())]
+    # every reader tested above is declared for this cell, under the name
+    # the cells that share the measurement share (ISSUE 50)
+    assert {r.__name__.rsplit(".", 1)[-1] for r, _ in READERS} \
+        <= {m["name"] for m in mine}
     for text in (cell["why"], config["why"], config["source"],
                  *(m["layer"] for m in mine)):
         assert 1 <= len(text) <= 200 and text.isascii() \
             and text.isprintable(), text
     layers = {m["layer"] for m in BENCH["per_layer"]
-              if m.get("workloads") != [CELL]}
+              if CELL not in m.get("workloads", ())}
     for m in mine:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}

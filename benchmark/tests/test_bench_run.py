@@ -27,13 +27,43 @@ def declared(kind: str, cell: str) -> set:
             if "workloads" not in m or cell in m["workloads"]}
 
 
+def assert_setup_is_accounted_for(stdout: str, m: dict,
+                                  layers_path: str) -> None:
+    """``setup_s`` has a name for every stretch (ISSUE 50): what the harness
+    does before the program, the program's start-up up to the end of its
+    first step, what the harness does after it. The three big program spans
+    are metrics of their own; the rest of the program's stretch (the other
+    top-level spans and the gaps between them) is what ``startup_coverage``
+    and the timeline say of it. The ends are read on two clocks that start
+    within a second of each other (the OS's process start, ``run.py``'s
+    first line)."""
+    facts_line = json.loads(stdout.strip().splitlines()[-2])
+    setup_s = facts_line["end_to_end"]["setup_s"]
+    with open(layers_path) as f:
+        layers = json.load(f)
+    assert layers["setup_s"] == setup_s
+    startup = layers["stats"]["sections"]["startup"]
+    named = (m["setup_before_program_s"], m["engine_build_s"],
+             m["step_load_s"], m["first_step_run_s"],
+             m["setup_after_first_step_s"])
+    rest = startup["stretch_s"] - sum(named[1:4])
+    assert all(v >= 0 for v in named) and rest > -0.05
+    assert sum(named) + rest == pytest.approx(setup_s, abs=1.5)
+    # the second end holds the warm-up's timed display, and no more than
+    # what lies between the program's start and the window
+    timed = facts_line["facts"]["step_s_warmup"] * 4
+    assert timed < named[4] < setup_s - named[0]
+
+
 @pytest.mark.parametrize("cell,trace", [
     ("alexnet.lmdb", 0), ("alexnet.lmdb", 1),
     ("alexnet.resident", 0), ("alexnet.dp4.resident", 1)])
-def test_cpu_tiny_rehearsal(cell, trace):
+def test_cpu_tiny_rehearsal(cell, trace, tmp_path):
     chips = next(w["chips"] for w in BENCH["workloads"] if w["name"] == cell)
+    kept = str(tmp_path / "layers.json")
     done = run_cell("--workload", cell, "--seed", "3", "--seconds", "1",
-                    "--trace", str(trace), "--cpu-tiny")
+                    "--trace", str(trace), "--cpu-tiny",
+                    *(("--keep-layers", kept) if trace else ()))
     assert done.returncode == 0, done.stderr[-3000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     keys = {"correct", "attempted", "failed", "metrics", "device"}
@@ -56,6 +86,9 @@ def test_cpu_tiny_rehearsal(cell, trace):
         assert 0 < len(line["breakdown"]["idle_gaps"]) <= 5
         spans = {name for name, _ in line["breakdown"]["idle_gaps"]}
         assert spans - {"unattributed"}, "no gap met an engine span"
+        assert_setup_is_accounted_for(
+            done.stdout, {k: v["value"] for k, v in line["metrics"].items()},
+            kept)
     else:
         # no peak FLOP/s for a CPU: the MFU is left out, not made up
         assert names == declared("end_to_end", cell) - {"mfu_required"}

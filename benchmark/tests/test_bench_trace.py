@@ -26,6 +26,33 @@ def test_union_gaps_total():
     assert dt.union([]) == [] and dt.gaps([]) == []
 
 
+def test_the_traced_window_is_cut_where_a_counted_step_begins():
+    """The traced window counts what the chip ran from the first counted
+    step on (ISSUE 50): a step's start is the start of the first operation
+    that ran exactly once in each step; what ran before the cut goes, on
+    every chip, asynchronous spans too, and the window and the busy time
+    follow. An operation in a loop, whose count follows the data, is no
+    mark."""
+    step = [("rng", 0.0, 1.0), ("body", 2.0, 1.0), ("body", 4.0, 1.0),
+            ("conv", 6.0, 3.0)]
+    ops = [(name, start + 10.0 * k, dur)
+           for k in range(3) for name, start, dur in step]
+    ops.insert(2, ("body", 3.0, 0.5))          # one more trip in step 0
+    assert dt.step_starts(ops, 3) == [0.0, 10.0, 20.0]
+    late = [(name, start + 0.5, dur) for name, start, dur in ops]
+    trace = {"devices": {"0": ops, "1": late},
+             "async": {"0": [("copy-start", 5.0, 20.0),
+                             ("copy-start.2", 21.0, 2.0)]}}
+    cut, opens = dt.since_step(trace, 3, 2)
+    assert opens == 20.0
+    assert cut["devices"] == {"0": ops[-4:], "1": late[-4:]}
+    assert cut["async"] == {"0": [("copy-start.2", 21.0, 2.0)]}
+    assert dt.window(cut["devices"]) == (20.0, 29.5)
+    assert dt.window(trace["devices"]) == (0.0, 29.5)       # left as it was
+    with pytest.raises(RuntimeError, match="exactly once"):
+        dt.step_starts(ops, 4)
+
+
 def test_self_time_and_leaves():
     assert dt.self_times(OPS) == [22.0, 8.0, 20.0, 20.0, 15.0]
     assert [op[0] for op in dt.leaves(OPS)] == [

@@ -20,18 +20,17 @@ from layer_metrics import (attention_gate_ms_per_step,
                            global_attention_ms_per_step,
                            global_flash_attention_roofline,
                            shared_expert_ms_per_step,
-                           sigmoid_router_ms_per_step,
-                           trinity_attention_glue_ms_per_step,
-                           trinity_head_ms_per_step,
-                           trinity_held_assignment_share,
-                           trinity_held_dropped_assignments,
-                           trinity_held_load_max_over_mean,
-                           trinity_held_moe_flops_util,
-                           trinity_held_moe_ms_per_step,
-                           trinity_held_prefix_hit_share,
-                           trinity_held_share_layer_max,
-                           trinity_recompute_ms_per_step,
-                           trinity_tokens_per_s_per_chip,
+                           router_ms_per_step,
+                           attention_glue_ms_per_step,
+                           head_ms_per_step,
+                           held_assignment_share,
+                           held_dropped_assignments,
+                           held_load_max_over_mean,
+                           held_moe_flops_util,
+                           held_moe_ms_per_step,
+                           held_share_layer_max,
+                           recompute_ms_per_step,
+                           tokens_per_s_per_chip,
                            window_attention_ms_per_step,
                            window_flash_attention_roofline,
                            window_visited_over_live_programs)
@@ -286,7 +285,7 @@ def small_run(scopes=SCOPES, lm=True):
            "peak_flops_per_s": PEAKS["bf16_flops_per_s"],
            "stats": {"sections": {"step_scopes": scopes} if scopes else {}}}
     if lm:
-        run["lm"] = {"trinity": True, "seq_len": 8192,
+        run["lm"] = {"seq_len": 8192,
                      "scopes": CFG["scopes"], "peaks": PEAKS,
                      "kernel_routes": ROUTES,
                      "flash_per_step": {
@@ -298,8 +297,6 @@ def small_run(scopes=SCOPES, lm=True):
                      "held_share_by_layer": {
                          "l1_held_share": [0.12, 0.2, 0.23],
                          "l2_held_share": [0.08, 0.05, 0.07]},
-                     "held_prefix": {"held_prefix_hits": 39,
-                                     "held_layer_steps": 40},
                      "traced_held_share": [0.25],
                      "expert_load": [1.2, 1.4], "dropped": [0.0, 0.0]}
     return run
@@ -313,21 +310,20 @@ READERS = [
     # bytes-bound: 500 / 1e11 = 5 ns against 10 ns of kernel a step
     (global_flash_attention_roofline, 100 * 5e-9 / 10e-9),
     (window_visited_over_live_programs, 72 / 63),
-    (trinity_attention_glue_ms_per_step, 3e-6),    # (4 + 2) / 2
+    (attention_glue_ms_per_step, 3e-6),    # (4 + 2) / 2
     (attention_gate_ms_per_step, 4e-6),            # (6 + 2) / 2
-    (sigmoid_router_ms_per_step, 4e-6),
+    (router_ms_per_step, 4e-6),
     (shared_expert_ms_per_step, 7e-6),
-    (trinity_held_moe_ms_per_step, 15e-6),
+    (held_moe_ms_per_step, 15e-6),
     # the TRACED steps' 0.25 x 1000 assignments x 10 FLOPs over 15 ns x 1e12
-    (trinity_held_moe_flops_util, 100 * 2.5e3 / (15e-9 * 1e12)),
-    (trinity_held_assignment_share, 12.5),
-    (trinity_held_load_max_over_mean, 1.3),
-    (trinity_held_dropped_assignments, 0.0),
-    (trinity_held_prefix_hit_share, 97.5),         # 39 of 40 layer-steps
-    (trinity_held_share_layer_max, 23.0),          # l1's third display
-    (trinity_head_ms_per_step, 7e-6),              # (12 + 2) / 2
-    (trinity_recompute_ms_per_step, 17e-6),        # (30 + 4) ns / 2
-    (trinity_tokens_per_s_per_chip, 10 * 2 * 8192 / 4.0),
+    (held_moe_flops_util, 100 * 2.5e3 / (15e-9 * 1e12)),
+    (held_assignment_share, 12.5),
+    (held_load_max_over_mean, 1.3),
+    (held_dropped_assignments, 0.0),
+    (held_share_layer_max, 23.0),          # l1's third display
+    (head_ms_per_step, 7e-6),              # (12 + 2) / 2
+    (recompute_ms_per_step, 17e-6),        # (30 + 4) ns / 2
+    (tokens_per_s_per_chip, 10 * 2 * 8192 / 4.0),
 ]
 
 
@@ -338,29 +334,17 @@ def test_each_reader_on_a_hand_made_run(reader, want):
 
 @pytest.mark.parametrize("reader", [r for r, _ in READERS])
 def test_each_reader_finds_nothing_on_a_program_without_it(reader):
-    """The parent's program or another cell's run: no map, another
-    runner's ``lm`` section (OLMoE's, Ouro's, ZAYA1's), no trace — None, and
-    nothing raised."""
+    """A program or a run without what the reader reads: no map, no ``lm``
+    section, no trace — None, and nothing raised. (Which CELLS report a
+    metric is its ``workloads`` list's to say, not the reader's: no reader
+    looks for a cell's name.)"""
     assert reader.reduce(small_run(scopes=None, lm=False)) is None
-    assert reader.reduce(small_run(lm=False)) is None
-    for other_lm in ({"seq_len": 4096, "peaks": PEAKS, "head_scopes": [],
-                      "flops_per_step": {}, "flash_per_step": {},
-                      "expert_load": [3.5], "dropped": [0.0]},
-                     {"seq_len": 8192, "peaks": PEAKS, "flops_per_step": {},
-                      "scopes": {"ffn": "x", "exit_heads": "y"},
-                      "flash_per_step": {}, "exit_mass": []},
-                     {"zaya": True, "seq_len": 8192, "peaks": PEAKS,
-                      "scopes": {"router": "x", "held_moe": "y"},
-                      "flash_per_step": {"flops": 1, "bytes": 1},
-                      "held_share": [0.5], "expert_load": [1.0],
-                      "dropped": [0.0], "traced_held_share": [0.5]}):
-        other = small_run(lm=False)
-        other["lm"] = other_lm
-        assert reader.reduce(other) is None
-    counters = (trinity_held_assignment_share, trinity_tokens_per_s_per_chip,
-                trinity_held_load_max_over_mean,
-                trinity_held_dropped_assignments,
-                trinity_held_prefix_hit_share, trinity_held_share_layer_max,
+    if reader is not recompute_ms_per_step:   # reads the map alone
+        assert reader.reduce(small_run(lm=False)) is None
+    counters = (held_assignment_share, tokens_per_s_per_chip,
+                held_load_max_over_mean,
+                held_dropped_assignments,
+                held_share_layer_max,
                 window_visited_over_live_programs)
     if reader not in counters:                # those need no trace
         assert reader.reduce(dict(small_run(), trace=None)) is None
@@ -691,8 +675,10 @@ def test_cpu_tiny_rehearsal_of_the_trinity_cell(trace):
     assert facts["kernel_routes"] == [
         "attention=dense; 4 kv heads repeated x8; no positions",
         "attention=dense; 4 kv heads repeated x8; window 16 as a dense mask",
-        "grouped_matmul=ragged_dot; held rows: prefix 256 of 1024, full on "
-        "overflow"]
+        # what the program prints since PR 43: the held rows run in chunks
+        # (``; held rows: chunks of P of T k`` on the chip); at these cut
+        # sizes two chunks hold every row and the note says nothing
+        "grouped_matmul=ragged_dot"]
     assert facts["remat_segments"] == DEPTH + 1
     assert facts["shared_params"] == {}
     assert facts["expert_share"]["l1_moe"] == {
@@ -707,14 +693,16 @@ def test_cpu_tiny_rehearsal_of_the_trinity_cell(trace):
                                           for i in range(1, DEPTH)]
     assert max(max(v) for v in share["per_layer"].values()) >= share["max"]
     rungs = share["window_prefix"]
-    assert rungs["held_layer_steps"] == (DEPTH - 1) * line["attempted"] \
+    # counted where a layer's held rows run in chunks (PR 43): every
+    # layer-step on the chip, none at these cut sizes
+    assert rungs["held_layer_steps"] in (0, (DEPTH - 1) * line["attempted"]) \
         and 0 <= rungs["held_prefix_hits"] <= rungs["held_layer_steps"]
     names = set(line["metrics"])
     if trace:
         # all of the cell's per-layer metrics but those that need a chip's
         # peaks, its memory statistics or its Pallas kernels
         assert names == declared("per_layer", CELL) - {
-            "busy_flops_util", "peak_hbm_gb", "trinity_held_moe_flops_util",
+            "busy_flops_util", "peak_hbm_gb", "held_moe_flops_util",
             "window_flash_attention_roofline",
             "global_flash_attention_roofline",
             "window_visited_over_live_programs"}
@@ -722,20 +710,18 @@ def test_cpu_tiny_rehearsal_of_the_trinity_cell(trace):
         assert m["scope_coverage"] >= 95.0
         parts = ("window_attention_ms_per_step",
                  "global_attention_ms_per_step",
-                 "attention_gate_ms_per_step", "sigmoid_router_ms_per_step",
-                 "shared_expert_ms_per_step", "trinity_held_moe_ms_per_step",
-                 "trinity_head_ms_per_step")
+                 "attention_gate_ms_per_step", "router_ms_per_step",
+                 "shared_expert_ms_per_step", "held_moe_ms_per_step",
+                 "head_ms_per_step")
         assert all(m[k] > 0 for k in parts)
         # on the CPU the whole ATTENTION layer is glue (no Pallas call)
-        assert m["trinity_attention_glue_ms_per_step"] == pytest.approx(
+        assert m["attention_glue_ms_per_step"] == pytest.approx(
             m["window_attention_ms_per_step"]
             + m["global_attention_ms_per_step"])
         assert sum(m[k] for k in parts) \
             < m["fwd_ms_per_step"] + m["bwd_ms_per_step"]
-        assert 0 < m["trinity_held_assignment_share"] \
-            <= m["trinity_held_share_layer_max"] < 100
-        assert m["trinity_held_prefix_hit_share"] == pytest.approx(
-            100.0 * rungs["held_prefix_hits"] / rungs["held_layer_steps"])
+        assert 0 < m["held_assignment_share"] \
+            <= m["held_share_layer_max"] < 100
     else:
         assert names == declared("end_to_end", CELL) - {"mfu_required"}
         assert line["metrics"]["images_per_s_per_chip"]["value"] == \
@@ -753,15 +739,18 @@ def test_new_entries_follow_the_contract():
         "num_hidden_layers", "num_experts", "vocab_size"]
     assert config["source"] == CFG["source"] \
         and config["file"] == "benchmark/configs/trinity_mini.json"
-    mine = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
-    assert sorted(m["name"] for m in mine) \
-        == sorted(r.__name__.rsplit(".", 1)[-1] for r, _ in READERS)
+    mine = [m for m in BENCH["per_layer"]
+            if CELL in m.get("workloads", ())]
+    # every reader tested above is declared for this cell, under the name
+    # the cells that share the measurement share (ISSUE 50)
+    assert {r.__name__.rsplit(".", 1)[-1] for r, _ in READERS} \
+        <= {m["name"] for m in mine}
     for text in (cell["why"], config["why"], config["source"],
                  *(m["layer"] for m in mine)):
         assert 1 <= len(text) <= 200 and text.isascii() \
             and text.isprintable(), text
     layers = {m["layer"] for m in BENCH["per_layer"]
-              if m.get("workloads") != [CELL]}
+              if CELL not in m.get("workloads", ())}
     for m in mine:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
