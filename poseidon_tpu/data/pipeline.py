@@ -94,6 +94,15 @@ def layer_batch_size(lp: LayerParameter) -> int:
     }[t]
 
 
+# While the recorder is enabled the prefetcher waits for every LAND_EVERY-th
+# batch to land on the device (``producer_h2d_land``), and for the batch
+# before it, so that the sampled copy has the link to itself. Every batch
+# was tried first: in ``alexnet.lmdb`` (316.6 MB a batch) one transfer alone
+# takes 32 ms, the step 29.9, so copies that may not overlap made the cell
+# 28% slower under tracing (PERF.md section 6, PR 51).
+LAND_EVERY = 16
+
+
 def _batch_args(batch_no: int) -> Optional[Dict]:
     """Span args of a producer span — built only while the recorder is on,
     so the producer loops of an untraced run allocate nothing for it."""
@@ -144,7 +153,8 @@ class BatchPipeline:
             self._n_records = len(self.window.fg) + len(self.window.bg)
             self.data_shape = (batch_size,) + self.window.record_shape
             self._queue = queue.Queue(maxsize=prefetch)
-            self._thread = threading.Thread(target=self._worker, daemon=True)
+            self._thread = threading.Thread(target=self._worker,
+                                            name="reader", daemon=True)
             self._stop = threading.Event()
             self._thread.start()
             return
@@ -199,7 +209,8 @@ class BatchPipeline:
                 self.data_shape = (batch_size,) + \
                     self.transformer.output_shape(c, h, w)
         self._queue: queue.Queue = queue.Queue(maxsize=prefetch)
-        self._thread = threading.Thread(target=self._worker, daemon=True)
+        self._thread = threading.Thread(target=self._worker, name="reader",
+                                        daemon=True)
         self._stop = threading.Event()
         self._thread.start()
 
@@ -405,7 +416,8 @@ class DevicePrefetcher:
         if not self.passthrough:
             self._queue: queue.Queue = queue.Queue(maxsize=self.depth)
             self._stop = threading.Event()
-            self._thread = threading.Thread(target=self._worker, daemon=True)
+            self._thread = threading.Thread(target=self._worker,
+                                            name="prefetcher", daemon=True)
             self._thread.start()
 
     @staticmethod
@@ -417,8 +429,15 @@ class DevicePrefetcher:
         """Dequeue one host batch from every pipeline and place it.
         ``producer_h2d`` spans the ``place_batch`` calls alone: what it
         takes this thread to hand the bytes to the runtime. ``device_put``
-        returns before the copy has landed on the device, so the transfer
-        itself can end after the span does."""
+        returns before the copy has landed on the device: while the
+        recorder is enabled (and only then) this thread also waits, for
+        every ``LAND_EVERY``-th batch, until the batch's arrays are ready,
+        under ``producer_h2d_land``: the two spans together are what ONE
+        batch's copy takes with the link to itself, because the batch
+        before the sampled one is waited for too (under no span: it lands
+        behind its own predecessor) before it is handed on. Either wait
+        holds the next batch's copy back on this thread, which is why it
+        is a sample; an untraced run waits for nothing here."""
         host: Dict[str, np.ndarray] = {}
         for pipe in self.pipes:
             host.update(next(pipe))
@@ -426,8 +445,17 @@ class DevicePrefetcher:
         if args is not None:
             args["bytes"] = sum(v.nbytes for v in host.values())
         with _spans.span("producer_h2d", "input", args):
-            return {k: place_batch(v, self.sharding)
-                    for k, v in host.items()}
+            batch = {k: place_batch(v, self.sharding)
+                     for k, v in host.items()}
+        turn = batch_no % LAND_EVERY if args is not None else None
+        if turn == LAND_EVERY - 1:          # clear the link for the sample
+            import jax
+            jax.block_until_ready(batch)
+        elif turn == 0:
+            import jax
+            with _spans.span("producer_h2d_land", "input", args):
+                jax.block_until_ready(batch)
+        return batch
 
     def _worker(self):
         batch_no = -1

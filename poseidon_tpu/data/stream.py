@@ -75,7 +75,8 @@ class DiskStreamer:
         self._done = False
         self._error: Optional[BaseException] = None
         self._stop = threading.Event()
-        self._io = threading.Thread(target=self._io_loop, daemon=True)
+        self._io = threading.Thread(target=self._io_loop, name="stream_io",
+                                    daemon=True)
         self._io.start()
 
     # -- IO thread: the DiskReader ------------------------------------- #

@@ -576,11 +576,13 @@ class ParamService:
         self.port = self._srv.getsockname()[1]
         self._stop = threading.Event()
         self._threads: List[threading.Thread] = []
-        t = threading.Thread(target=self._accept_loop, daemon=True)
+        t = threading.Thread(target=self._accept_loop, name="async_accept",
+                             daemon=True)
         t.start()
         self._threads.append(t)
         if self.liveness_timeout_s > 0:
-            m = threading.Thread(target=self._monitor_loop, daemon=True)
+            m = threading.Thread(target=self._monitor_loop,
+                                 name="async_monitor", daemon=True)
             m.start()
             self._threads.append(m)
 
@@ -597,7 +599,7 @@ class ParamService:
             # retain them — reconnect/heartbeat churn over a long run would
             # grow the list without bound on the service host
             threading.Thread(target=self._serve, args=(conn,),
-                             daemon=True).start()
+                             name="async_serve", daemon=True).start()
 
     def _monitor_loop(self) -> None:
         """Evict workers silent past the liveness timeout. Detection is
@@ -1058,7 +1060,8 @@ class AsyncSSPClient:
         self.blocked_s = 0.0     # cumulative gate wait (telemetry)
         self.gate_blocks = 0
         self.dead: Optional[BaseException] = None
-        self._sender = threading.Thread(target=self._send_loop, daemon=True)
+        self._sender = threading.Thread(target=self._send_loop,
+                                        name="async_sender", daemon=True)
         self._sender.start()
 
     # ---- channel (re)establishment -------------------------------------- #

@@ -281,7 +281,8 @@ class AsyncSnapshotWriter:
             except BaseException as e:  # noqa: BLE001 — surfaced on join
                 self._error = e
 
-        self._thread = threading.Thread(target=_write, daemon=True)
+        self._thread = threading.Thread(target=_write, name="ckpt_writer",
+                                        daemon=True)
         self._thread.start()
         return paths
 

@@ -882,6 +882,18 @@ class Engine:
         self.stats.set_section("startup",
                                {"route": doc.pop("route", "jit"), **doc})
 
+    def _publish_stalls(self) -> None:
+        """While the span recorder is enabled, a ``train`` call ends with
+        the stall ledger of the recorder's window (``spans.stall_ledger``:
+        every late step since ``clear()`` with a cause) as stats section
+        ``stalls``, beside what making it took (``summary_ms``)."""
+        if not span_recorder.enabled:
+            return
+        t = time.perf_counter()
+        doc = span_recorder.stalls(self.max_in_flight)
+        doc["summary_ms"] = round((time.perf_counter() - t) * 1e3, 3)
+        self.stats.set_section("stalls", doc)
+
     def _publish_step_scopes(self, text: Optional[str],
                              why: str = "") -> None:
         """Stats section ``step_scopes``: which layer and pass each
@@ -1353,15 +1365,11 @@ class Engine:
             if k not in ("iter", "time"):
                 self.stats.set_gauge(f"train_{k}", round(v, 6))
         if self._held_ladders:
-            # so far (the counters beside them hold the counts): live rows
-            # over the rows the held arms' chunks ran (1 = no padding), and
-            # layer-steps within twice the even share over layer-steps
+            # so far (the counters beside it hold the counts): live rows
+            # over the rows the held arms' chunks ran (1 = no padding)
             done = self.stats.counters
             self.stats.set_gauge("held_row_fill", round(
                 done["held_rows_live"] / max(done["held_rows_run"], 1.0), 6))
-            self.stats.set_gauge("prefix_hit_share", round(
-                done["held_prefix_hits"]
-                / max(done["held_layer_steps"], 1.0), 6))
         with span_recorder.span("telemetry_dump", "artifact", {"iter": it}):
             self._dump_live_telemetry()
         if self._async_tier is not None:
@@ -1393,6 +1401,7 @@ class Engine:
         # are un-materialized, so the loop runs ahead of the device by a
         # bounded number of steps instead of hard-syncing every iteration
         fetcher = AsyncScalarFetcher(self.max_in_flight)
+        span_recorder.set_role("train")     # this thread's row of the timeline
         self._displays.clear()      # a run that raised may have left some
         if self._use_prefetch and self._device_feed is None:
             self._device_feed = DevicePrefetcher(
@@ -1661,6 +1670,7 @@ class Engine:
             # background write (and surface its failure loudly)
             self._snap_writer.wait()
         self.stats.add_time("train_total", time.time() - t_start)
+        self._publish_stalls()
         self._write_artifacts()
         written = self._dump_span_timeline()
         if written:

@@ -246,7 +246,7 @@ class MetricsServer:
         self.host = host
         self.port = self._srv.server_address[1]
         self._thread = threading.Thread(target=self._srv.serve_forever,
-                                        daemon=True)
+                                        name="metrics_server", daemon=True)
         self._thread.start()
 
     def close(self) -> None:
@@ -299,7 +299,8 @@ class AsyncScalarFetcher:
         self._pending = 0               # dispatches not yet materialized
         self._error: Optional[Exception] = None
         self._closed = False
-        self._thread = threading.Thread(target=self._drain_loop, daemon=True)
+        self._thread = threading.Thread(target=self._drain_loop,
+                                        name="drainer", daemon=True)
         self._thread.start()
 
     @staticmethod
@@ -384,11 +385,15 @@ class AsyncScalarFetcher:
         holds the lock. This is the moment a step's metrics are host
         floats — on the drainer's thread, or on the train thread when the
         dispatch had already finished — and the ``step_done`` instant
-        marks it on the span timeline."""
+        marks it on the span timeline (the steps of one scan-chunk dispatch
+        complete together and say so: ``dispatch`` = their first)."""
         for i, row in enumerate(rows):
             it = first_iter + i
             if _spans.enabled:
-                _spans.instant("step_done", "step", {"iter": it})
+                _spans.instant(
+                    "step_done", "step",
+                    {"iter": it} if len(rows) == 1
+                    else {"iter": it, "dispatch": first_iter})
             self._drained.append((it, row))
             if self.divergence is None:
                 for k in self.watch_keys:

@@ -13,7 +13,9 @@ With a number ``gaps``, also the that many longest idle gaps of the first
 chip's "XLA Ops" line, each with every program span that overlaps it, by
 thread line: what the train thread, the reader, the prefetcher and the
 drainer were doing while the chip waited, read from the one file with no
-join. Exit code 1 when no such span is in the trace.
+join, and whether the heartbeat saw the whole process stopped there
+(``host_freeze``: in the trace a mark at the wake the heartbeat got, drawn
+here ``ms`` back from it). Exit code 1 when no such span is in the trace.
 """
 
 from __future__ import annotations
@@ -26,8 +28,9 @@ import warnings
 
 SPANS = {"prefetch_wait", "dispatch", "dispatch_rng", "dispatch_execute",
          "dispatch_window", "hard_sync", "snapshot", "telemetry_dump",
-         "producer_read", "producer_queue_full", "producer_h2d", "step_done",
-         "gc_pause", "resident_copy"}
+         "producer_read", "producer_queue_full", "producer_h2d",
+         "producer_h2d_land", "step_done", "gc_pause", "host_freeze",
+         "resident_copy"}
 
 
 def idle_gaps(ops, n):
@@ -71,9 +74,14 @@ def main(path: str, gaps: int = 0) -> int:
             for ev in line.events:
                 if ev.name in SPANS:
                     names[ev.name] += 1
+                    args = dict(ev.stats)
+                    start = ev.start_ns
+                    if ev.name == "host_freeze" and "ms" in args:
+                        # a mark at the wake the heartbeat GOT: the freeze
+                        # reaches ``ms`` back from it (runtime/spans.py)
+                        start -= int(float(args["ms"]) * 1e6)
                     host.setdefault(i, []).append(
-                        (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
-                         dict(ev.stats)))
+                        (start, ev.start_ns + ev.duration_ns, ev.name, args))
                 elif ev.name == "train":
                     steps.append(dict(ev.stats).get("step_num"))
             if names or steps:

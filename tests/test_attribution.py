@@ -408,8 +408,10 @@ def test_span_overhead_under_two_percent_of_lenet_step():
         return (time.perf_counter() - t0) / n
 
     disabled = min(span_cost() for _ in range(3))
+    rec.end_startup()   # past the start-up phase the heartbeat runs too
     rec.enable()        # with jax imported: the TraceAnnotation is entered
     try:
+        assert rec._heartbeat._thread.is_alive()
         enabled = min(span_cost() for _ in range(3))
     finally:
         rec.disable()

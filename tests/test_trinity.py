@@ -463,9 +463,9 @@ def test_held_row_ladder_is_named_and_counted(tmp_path, monkeypatch):
     ``stats.yaml`` counts, from every step's displayed held shares, the
     trips the held arms made (``held_chunk_trips``), the rows those ran
     (``held_rows_run``) and the live rows among them (gauge
-    ``held_row_fill`` = live / run), beside ``prefix_hit_share`` =
-    layer-steps whose live rows number at most twice the even share over
-    layer-steps displayed. Routings on each side of that, forced through
+    ``held_row_fill`` = live / run), beside the layer-steps displayed and
+    those whose live rows number at most twice the even share (counters
+    ``held_layer_steps`` / ``held_prefix_hits``). Routings on each side of that, forced through
     the routers' biases: a step with no assignment on a held expert (no
     trip), one with every assignment on one (every chunk, nothing dropped),
     back, and one the routers choose themselves (a chunk partly filled).
@@ -485,8 +485,7 @@ def test_held_row_ladder_is_named_and_counted(tmp_path, monkeypatch):
 
     def stats():
         doc = read_stats_yaml(str(tmp_path / "out" / "stats.yaml"))
-        return ([float(doc["gauges"][g]) for g in
-                 ("prefix_hit_share", "held_row_fill")]
+        return ([float(doc["gauges"]["held_row_fill"])]
                 + [float(doc["counters"][c]) for c in
                    ("held_layer_steps", "held_prefix_hits",
                     "held_chunk_trips", "held_rows_run")])
@@ -510,10 +509,9 @@ def test_held_row_ladder_is_named_and_counted(tmp_path, monkeypatch):
         assert eng.train_net.held_row_ladders() == {
             f"l{i}_held_share": (chunk, prefix, rows) for i in MOE_LAYERS}
         for offsets, share, want in (
-                ((-10.0, -10.0), 0.0, [1.0, 0.0, 4.0, 4.0, 0.0, 0.0]),
-                ((10.0, 10.0), 1.0, [0.5, 1.0, 8.0, 4.0, 16.0, 2048.0]),
-                ((-10.0, -10.0), 0.0,
-                 [round(2 / 3, 6), 1.0, 12.0, 8.0, 16.0, 2048.0])):
+                ((-10.0, -10.0), 0.0, [0.0, 4.0, 4.0, 0.0, 0.0]),
+                ((10.0, 10.0), 1.0, [1.0, 8.0, 4.0, 16.0, 2048.0]),
+                ((-10.0, -10.0), 0.0, [1.0, 12.0, 8.0, 16.0, 2048.0])):
             bias(*offsets)
             eng.train(max_iter=eng.iteration() + 1)
             row = eng.metrics.rows[-1]
@@ -531,9 +529,9 @@ def test_held_row_ladder_is_named_and_counted(tmp_path, monkeypatch):
         assert all(v < rows for v in live) and any(
             v % chunk for v in live)
         trips = [-(-v // chunk) for v in live]
-        hits, fill, steps, within, made, run = stats()
+        fill, steps, within, made, run = stats()
         assert (steps, within) == (16.0, 8.0 + sum(
-            v <= prefix for v in live)) and hits == round(within / steps, 6)
+            v <= prefix for v in live))
         assert (made, run) == (16.0 + sum(trips),
                                2048.0 + chunk * sum(trips))
         assert fill == round((2048.0 + sum(live)) / run, 6) and fill < 1.0
