@@ -244,10 +244,12 @@ def expert_sizes(experts: jax.Array, n_exp: int):
 _ROW_TILE = 128
 
 
-# A trip of the held arm's loop costs two scatter-adds into (T, D) and a
-# read-add-write of the three stacks' gradient sums whatever its length:
-# 2.5-3.5 ms on the v5e at the cells' shapes, the row work of some 8,000
-# rows (PERF.md, PR 43). No chunk is shorter.
+# A trip of the held arm's loop costs two sums of its rows into (T, D) f32
+# and a read-add-write of the three stacks' gradient sums whatever its
+# length: 2.5-3.5 ms on the v5e at Kimi-Linear's and Trinity's shapes, the
+# row work of some 8,000 rows (PERF.md, PR 43), and about 5 ms at
+# SmallThinker's (T 16,384 x 2,560) since its sums run on the MXU
+# (``held_sum_on_mxu``; 14 ms before: PERF.md, PR 53). No chunk is shorter.
 _CHUNK_FLOOR = 8192
 
 
@@ -263,6 +265,27 @@ def held_chunk_rows(rows: int, n_held: int, n_exp: int) -> int:
     before the floor was set (PERF.md, PR 43)."""
     even = -(-rows * n_held // n_exp)
     return -(-min(max(even, _CHUNK_FLOOR), rows) // _ROW_TILE) * _ROW_TILE
+
+
+# Widths D at which XLA's scatter-add of rows into a (T, D) f32 array runs
+# off its fast path on the v5e: it costs 0.4 us (2,560) and 1.8 us (5,120)
+# a row of the TARGET, whatever the rows added, where the twelve other
+# widths read from 512 to 4,096 pay 0.01-0.2 (7.5 / 31.4 ms for 8,192 rows
+# into 16,384 x 2,560 / x 5,120 against 1.65 into x 2,048 and 2.0 into
+# x 2,816; PERF.md, PR 53). No formula of the width tells them apart.
+_SCATTER_CLIFFS = frozenset({2560, 5120})
+
+
+def held_sum_on_mxu(width: int) -> bool:
+    """How a trip of ``expert_ffn``'s held arm sums its chunk's rows into
+    the (T, D) f32 carry: on the MXU (``_tile_sum``) where XLA's
+    scatter-add is off its fast path at D = ``width``
+    (``_SCATTER_CLIFFS``: SmallThinker's 2,560, 9.9 ms a sum of 24,576 rows
+    against 5.0), by the scatter-add at every other width, where the two
+    cost the same to a tenth or the scatter less (Trinity's 2,048: 2.1 ms
+    a sum in the layer against 2.8; Kimi-Linear's 2,304). A rule of the
+    shapes and nothing else, as the chunk is."""
+    return width in _SCATTER_CLIFFS
 
 
 def _combine(out, order, weights, dtype):
@@ -310,6 +333,9 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
     the share of the assignments this rank really holds and the layer is
     dropless for any routing. No array of T k rows times a feature width
     exists; what is left of T k are the sort and its vectors of scalars.
+    A trip's rows are summed into (T, D) f32 on the MXU (``_tile_sum``) or
+    by XLA's scatter-add, whichever the width D runs faster
+    (``held_sum_on_mxu``): the same f32 terms either way.
     Where two chunks at most hold every row (half the experts held: ZAYA1)
     there is nothing for a loop to skip, and the rows run as straight-line
     code (``_held_rows``): a rule of the shapes, as the chunk is.
@@ -414,6 +440,39 @@ def _chunk(i, chunk, act, x, weights, order, sizes, ends, gate_t, up_t):
     return grouped, groups, tok, head, xs, a, b, _act(a, act) * b, live
 
 
+def _tile_sum(rows, scale, tok, live, t):
+    """A chunk's rows summed by token on the MXU: (T, D) f32 whose row
+    ``tok[p]`` holds the sum of ``scale[p] * rows[p]`` over the ``live``
+    rows p (rows (P, D), scale (P,) f32 or None for 1, live (P, 1)); dead
+    rows, wherever they point, add nothing.
+
+    The P positions are brought into token-TILE order (``tok // tile``, tile
+    = ``_ROW_TILE`` tokens or T where T is smaller; dead rows last, outside
+    every group; the order inside a tile is free), the rows gathered in that
+    order, and ONE ``ragged_dot_general`` with the ragged dimension
+    contracted (``_DW_DIMS``) multiplies each tile's rows by their one-hot
+    columns ``tok % tile``, which carry ``scale``: (T / tile, tile, D). A
+    one-hot column is exact and at ``Precision.HIGHEST`` the f32 products
+    and sums are f32's, so the result is the serial ``.at[tok].add``'s to
+    f32 rounding. Both operands are f32: the chip's grouped-matmul kernel
+    takes no f32 columns beside bf16 rows, and bf16 columns cannot carry an
+    f32 weight in one product (three bf16 slabs, three products, ran slower
+    than this one: PERF.md, PR 53)."""
+    f32 = jnp.float32
+    tile = min(_ROW_TILE, t)
+    n_tiles = -(-t // tile)
+    key = jnp.where(live[:, 0], tok // tile, n_tiles)
+    by_tile = jnp.argsort(key)
+    in_tile = jnp.sum(key[:, None] == jnp.arange(n_tiles), axis=0,
+                      dtype=jnp.int32)
+    cols = jax.nn.one_hot(tok[by_tile] % tile, tile, dtype=f32)  # (P, tile)
+    if scale is not None:
+        cols = cols * scale[by_tile][:, None]
+    y = lax.ragged_dot_general(cols, rows[by_tile].astype(f32), in_tile,
+                               _DW_DIMS, precision=lax.Precision.HIGHEST)
+    return y.reshape(n_tiles * tile, -1)[:t]
+
+
 def _trips(order, sizes, chunk):
     """(``order`` padded with zeros to a whole number of chunks, so that a
     trip's slice is always in range; the held experts' running sizes; the
@@ -431,6 +490,7 @@ def _trips(order, sizes, chunk):
 def _held_chunks_fwd(chunk, cdtype, act, gate_zeros, x, weights, order,
                      sizes, gate, up, down):
     f32 = jnp.float32
+    on_mxu = held_sum_on_mxu(x.shape[1])
     order, ends, n = _trips(order, sizes, chunk)
     # the stacks' casts and transposes are made once a pass, not once a trip
     gate_t, up_t, down_t = (jnp.swapaxes(w.astype(cdtype), 1, 2)
@@ -440,6 +500,9 @@ def _held_chunks_fwd(chunk, cdtype, act, gate_zeros, x, weights, order,
         grouped, _, tok, head, _, a, _, h, live = _chunk(
             i, chunk, act, x, weights, order, sizes, ends, gate_t, up_t)
         out = grouped(h, down_t)                    # (P, D)
+        if on_mxu:
+            return y + _tile_sum(out, weights.reshape(-1)[head], tok, live,
+                                 x.shape[0]), a, live
         return y.at[tok].add(out.astype(f32)
                              * weights.reshape(-1)[head][:, None]), a, live
 
@@ -461,6 +524,7 @@ def _held_chunks_fwd(chunk, cdtype, act, gate_zeros, x, weights, order,
 def _held_chunks_bwd(chunk, cdtype, act, x, weights, order, sizes, gate, up,
                      down, dy):
     f32 = jnp.float32
+    on_mxu = held_sum_on_mxu(x.shape[1])
     order, ends, n = _trips(order, sizes, chunk)
     gate_c, up_c, down_c = (w.astype(cdtype) for w in (gate, up, down))
     gate_t, up_t = jnp.swapaxes(gate_c, 1, 2), jnp.swapaxes(up_c, 1, 2)
@@ -468,7 +532,7 @@ def _held_chunks_bwd(chunk, cdtype, act, x, weights, order, sizes, gate, up,
 
     def trip(i, carry):
         dx, dweights, dgate, dup, ddown = carry
-        grouped, groups, tok, head, xs, a, b, h, _ = _chunk(
+        grouped, groups, tok, head, xs, a, b, h, live = _chunk(
             i, chunk, act, x, weights, order, sizes, ends, gate_t, up_t)
 
         def dw(dys, rows):                          # (G, N, K), as stored
@@ -498,7 +562,9 @@ def _held_chunks_bwd(chunk, cdtype, act, x, weights, order, sizes, gate, up,
         dgate, dup = dgate + dw(da, xs), dup + dw(db, xs)
         dxs = (grouped(da, gate_c).astype(f32)
                + grouped(db, up_c).astype(f32))
-        return dx.at[tok].add(dxs), dweights, dgate, dup, ddown
+        dx = (dx + _tile_sum(dxs, None, tok, live, x.shape[0]) if on_mxu
+              else dx.at[tok].add(dxs))
+        return dx, dweights, dgate, dup, ddown
 
     dx, dweights, dgate, dup, ddown = lax.fori_loop(0, n, trip, (
         jnp.zeros(x.shape, f32), jnp.zeros(order.shape, f32),
@@ -529,8 +595,9 @@ def _held_chunks(chunk, x, weights, gate, up, down, order, sizes,
     derivative, so it is written out: the forward saves its inputs alone,
     and the backward makes the same trips, each recomputing its chunk's
     activations, adding its rows' dx and dweights into (T, D) and (T k,) f32
-    sums and its share of the stacks' gradients into f32 sums in the
-    STORED (G, N, K) orientation (``_DW_DIMS``: PR 30)."""
+    sums (dx as the forward adds y's rows: ``held_sum_on_mxu``; dweights by
+    a scatter-add of scalars) and its share of the stacks' gradients into
+    f32 sums in the STORED (G, N, K) orientation (``_DW_DIMS``: PR 30)."""
     return _held_chunks_fwd(chunk, jnp.dtype(policy().compute_dtype), act,
                             gate_zeros, x, weights, order, sizes, gate, up,
                             down)

@@ -780,8 +780,10 @@ def test_smallthinker_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(
         f"{rows}; act=relu"]
     # 4 flash calls a layer (forward, its replay, dq, dkv); a MoE layer's
     # held arm is one loop a pass: 4 calls in the forward's, 10 in the
-    # backward's, no replay (see the Kimi test)
-    assert got["pallas_custom_calls"] == 4 * 4 + 14 * 4
+    # backward's, no replay (see the Kimi test), and since PR 53, at this
+    # width alone (``held_sum_on_mxu``: 2,560), the grouped product that
+    # sums a trip's rows into (T, D) and its group-metadata call in each
+    assert got["pallas_custom_calls"] == 4 * 4 + (14 + 4) * 4
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more:

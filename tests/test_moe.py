@@ -154,6 +154,22 @@ def short_chunks(monkeypatch):
     assert moe.held_chunk_rows(TK, G_HELD, E_ALL) == P0
 
 
+@pytest.fixture
+def sums_on_mxu(monkeypatch):
+    """The table of ``held_sum_on_mxu`` holds these cases' width, so that
+    the loop's trips sum their rows on the MXU (``_tile_sum``). The rule is
+    read while tracing and is no part of the jitted loops' keys: their
+    traces are dropped before and after."""
+    from poseidon_tpu.models import moe
+    monkeypatch.setattr(moe, "_SCATTER_CLIFFS", frozenset({D_X}))
+    loops = (moe._held_chunks_fwd, moe._held_chunks_bwd)
+    for f in loops:
+        f.clear_cache()
+    yield
+    for f in loops:
+        f.clear_cache()
+
+
 def _routing(top_k, live, held_first, seed=0):
     """(x, weights, flat_e, sizes, gate, up, down) with exactly ``live`` of
     the TK assignments on a held expert, spread over the held ones."""
@@ -268,6 +284,33 @@ def test_held_chunks_equal_the_straight_line_arm(case, dtype, short_chunks):
         assert _rel(a, b) <= tol, (name, _rel(a, b))
         assert _rel(a, c) <= 1.25 * _rel(b, c) + 1e-6, name
         assert bool(np.any(np.asarray(a, np.float32))) == bool(live), name
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(LADDER_CASES))
+def test_held_chunks_that_sum_on_the_mxu_equal_the_straight_line_arm(
+        case, dtype, short_chunks, sums_on_mxu):
+    """The same ladder with the trips' two (T, D) sums on the MXU: y and
+    every gradient against the straight-line arm, to the same tolerances
+    (the sums are the scatter-add's terms in another order)."""
+    test_held_chunks_equal_the_straight_line_arm(case, dtype, short_chunks)
+
+
+def test_held_sum_is_a_rule_of_the_width(short_chunks):
+    """``held_sum_on_mxu``: the MXU at the widths where XLA's scatter-add
+    was read off its fast path (SmallThinker's 2,560 among them), the
+    scatter-add at every other (Trinity's 2,048, Kimi-Linear's 2,304,
+    these tests' 32), where a trip's forward scatters into (T, D) as it
+    did."""
+    from poseidon_tpu.models import moe
+    assert all(moe.held_sum_on_mxu(d) for d in (2560, 5120))
+    assert not any(moe.held_sum_on_mxu(d) for d in (
+        D_X, 512, 1024, 2048, 2304, 3072, 4096))
+    inside = list(_inside_whiles(_held_jaxpr("forward")))
+    assert [e.invars[0].aval.shape for e in inside
+            if e.primitive.name == "scatter-add"] == [(TK // 8, D_X)]
+    assert not [e for e in inside if e.primitive.name == "ragged_dot_general"
+                and e.outvars[0].aval.shape == (1, TK // 8, D_X)]
 
 
 def test_held_chunk_is_a_rule_of_the_shapes():
@@ -412,3 +455,147 @@ def test_arm_that_holds_every_expert_is_untouched():
                  for f in (ours, parents))
     assert str(got) == str(want)
     assert "while" not in [e.primitive.name for e in _eqns(got.jaxpr)]
+
+
+# --------------------------------------------------------------------------- #
+# The chunk's rows summed into (T, D) on the MXU (``_tile_sum``) against the
+# serial ``.at[tok].add`` it took the place of, kept here as the reference.
+# --------------------------------------------------------------------------- #
+
+
+def _scatter_sum(rows, scale, tok, live, t):
+    """What a trip added to its (T, D) f32 carry before the MXU sum: every
+    row times its f32 scale, scatter-added by token; a dead row adds zero
+    where it points."""
+    return jnp.zeros((t, rows.shape[1]), jnp.float32).at[tok].add(
+        rows.astype(jnp.float32) * jnp.where(live, scale[:, None], 0))
+
+
+def _front(flat, first, at, total, rs):
+    """A permutation of ``total`` assignments with ``flat`` at positions
+    ``at ..``, drawn from ``first`` (those allowed before the rest)."""
+    rest = np.setdiff1d(first, flat)
+    rs.shuffle(rest)
+    head = np.concatenate([rest[:at], flat, rest[at:]])
+    tail = np.setdiff1d(np.arange(total), head)
+    rs.shuffle(tail)
+    return np.concatenate([head, tail])
+
+
+def _sum_case(case):
+    """(T, k, chunk P, the sorted assignments ``order`` (T k,), live rows,
+    the token the case is about or None)."""
+    rs = np.random.RandomState(5)
+    every = np.arange(512)
+    if case == "token_whole_in_one_chunk":      # its 8 rows inside chunk 0
+        return 64, 8, 128, _front(np.arange(40, 48), every, 17, 512, rs), \
+            300, 5
+    if case == "token_split_over_two_chunks":   # 4 rows each side of row 128
+        return 64, 8, 128, _front(np.arange(40, 48), every, 124, 512, rs), \
+            300, 5
+    if case == "one_tile_holds_a_chunks_live_rows":
+        # T = 256 is two tiles; chunk 0 holds tokens under 128 alone, and the
+        # 60 live rows of chunk 1 are tokens from 128 on
+        low = rs.permutation(256)[:128]
+        high = 256 + rs.permutation(256)
+        return 256, 2, 128, np.concatenate(
+            [low, high[:60], np.setdiff1d(every, np.concatenate(
+                [low, high[:60]]))]), 188, None
+    if case == "dead_rows_and_padding":
+        # P = 384: the second trip holds 16 live rows, 112 dead ones with
+        # tokens of their own, and 256 rows of ``_trips``' padding (token 0)
+        return 64, 8, 384, rs.permutation(512), 400, 0
+    if case == "t_below_a_tile":
+        return 24, 4, 128, rs.permutation(96), 96, None
+    assert case == "t_not_a_multiple_of_a_tile"     # 200 = 128 + 72
+    return 200, 2, 128, rs.permutation(400), 391, 199
+
+
+SUM_CASES = ("token_whole_in_one_chunk", "token_split_over_two_chunks",
+             "one_tile_holds_a_chunks_live_rows", "dead_rows_and_padding",
+             "t_below_a_tile", "t_not_a_multiple_of_a_tile")
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("case", SUM_CASES)
+def test_tile_sum_equals_the_scatter_add(case, dtype):
+    """The loop's trips over ``_trips``' padded order, each trip's rows
+    summed into the carry by ``_tile_sum`` and by the scatter-add: the same
+    f32 terms (a row in the compute dtype times its f32 weight) in another
+    order, 1e-6 relative in f32 and in bf16 rows alike. Dead rows hold
+    numbers here (the loop masks them to zero) and must add nothing."""
+    from poseidon_tpu.models import moe
+    t, top_k, chunk, order, n_live, token = _sum_case(case)
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
+    rs = np.random.RandomState(9)
+    weights = jnp.asarray(rs.rand(t * top_k) + 0.1, jnp.float32)
+    padded, ends, n = moe._trips(jnp.asarray(order, jnp.int32),
+                                 jnp.asarray([n_live], jnp.int32), chunk)
+    assert int(n) == -(-n_live // chunk) and padded.shape[0] % chunk == 0
+    got, want = (jnp.zeros((t, D_X), jnp.float32),) * 2
+    exact = np.zeros((t, D_X))
+    for i in range(int(n)):
+        head = padded[i * chunk:(i + 1) * chunk]
+        tok = head // top_k
+        live = (i * chunk + jnp.arange(chunk) < ends[-1])[:, None]
+        rows = jnp.asarray(rs.randn(chunk, D_X), dt)
+        got = got + jax.jit(moe._tile_sum, static_argnums=4)(
+            rows, weights[head], tok, live, t)
+        want = want + _scatter_sum(rows, weights[head], tok, live, t)
+        np.add.at(exact, np.asarray(tok), np.asarray(rows, np.float64)
+                  * np.asarray(jnp.where(live, weights[head][:, None], 0),
+                               np.float64))
+        if case == "one_tile_holds_a_chunks_live_rows":
+            tiles = set(np.asarray(tok)[np.asarray(live[:, 0])] // 128)
+            assert tiles == {i}
+    assert got.dtype == jnp.float32 and got.shape == (t, D_X)
+    assert _rel(got, want) <= 1e-6, _rel(got, want)
+    assert _rel(got, exact) <= 1e-6
+    if token is not None:       # the row the case is about, not the norm
+        assert _rel(got[token], exact[token]) <= 1e-6
+        assert np.any(exact[token])
+    if case.startswith("token_"):
+        at = np.flatnonzero(np.asarray(order) // top_k == token)
+        assert len(at) == top_k
+        assert len(set(at // chunk)) == (1 if "whole" in case else 2)
+
+
+def _inside_whiles(jaxpr):
+    """The equations inside every ``while`` of the jaxpr, bodies within
+    bodies included."""
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "while":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("pass_", ["forward", "gradient"])
+def test_no_scatter_add_into_t_by_d_under_the_loop(pass_, short_chunks,
+                                                   sums_on_mxu):
+    """Where the sums run on the MXU (``held_sum_on_mxu``), inside the
+    ``while`` of either pass a chunk's rows reach the (T, D) f32 carry
+    through a grouped product with the ragged dimension contracted,
+    (T / tile, tile, D), and a plain add: no ``scatter-add`` has a (T, D)
+    operand. The only scatter left there is the weights' gradient's, (T k,)
+    scalars, in the backward's loop. No array of T k rows times a feature
+    width appears."""
+    jaxpr = _held_jaxpr(pass_)
+    t = TK // 8
+    inside = list(_inside_whiles(jaxpr))
+    scattered = [e.invars[0].aval.shape for e in inside
+                 if e.primitive.name.startswith("scatter")]
+    assert scattered == {"forward": [], "gradient": [(TK,)]}[pass_]
+    sums = [e for e in inside if e.primitive.name == "ragged_dot_general"
+            and e.outvars[0].aval.shape == (1, t, D_X)]
+    # the forward's sum; the gradient's jaxpr holds the forward's loop too
+    assert len(sums) == {"forward": 1, "gradient": 2}[pass_]
+    assert all(e.outvars[0].aval.dtype == jnp.float32
+               and set(e.params["precision"]) == {jax.lax.Precision.HIGHEST}
+               for e in sums)
+    assert _wide(jaxpr) == []
+    # the reference of this file does scatter (T, D) rows
+    ref = jax.make_jaxpr(lambda r, s, k: _scatter_sum(
+        r, s, k, jnp.ones((P0, 1), bool), t))(
+            jnp.zeros((P0, D_X)), jnp.zeros((P0,)), jnp.zeros((P0,), int))
+    assert [e.invars[0].aval.shape for e in _eqns(ref.jaxpr)
+            if e.primitive.name == "scatter-add"] == [(t, D_X)]
