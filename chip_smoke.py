@@ -106,6 +106,10 @@ class Size:
                      if tiny else
                      {"olmo_hybrid": (1, 1024, 4, 96, 192, True, 1.9),
                       "kimi_linear": (1, 1024, 4, 128, 128, False, 1.0)})
+        # Granite's Mamba-2 scan (sequences, S, heads, P, N): heads of 64
+        # with a state of 128, two heads a lane block, B and C shared
+        self.ssd_scan = ({"tiny ssd": (1, 128, 4, 8, 16)} if tiny else
+                         {"granite_h": (1, 1024, 8, 64, 128)})
 
 
 # --------------------------------------------------------------------------- #
@@ -493,7 +497,9 @@ def check_scan(size: Size) -> dict:
     five gradients, f32 operands: the per-head decay at 96 / 192 with every
     write at beta 1.9, the per-channel decay at 128 / 128, each through the
     arm ``kda_route`` chose for its shape here (in the facts, with the
-    reason where it is not ``pallas``)."""
+    reason where it is not ``pallas``); and Mamba-2's scan at Granite's 64 x
+    64 x 128 (two heads a lane block) through the arm ``ssd_route`` chose,
+    the output and all six gradients."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -533,7 +539,41 @@ def check_scan(size: Size) -> dict:
                   f"recurrence by {rel} (relative L2)")
         return facts
 
-    return {name: one(shape) for name, shape in size.scan.items()}
+    def ssd(shape):
+        """Mamba-2's scan through the arm ``ssd_route`` chose, forward and
+        all six gradients (x, dt, a, B, C, D) against the recurrence."""
+        from poseidon_tpu.ops.ssd import ssd_recurrence, ssd_route, ssd_scan
+        b, s, h, p, n = shape
+        rs = np.random.RandomState(SEED)
+        dt = np.log1p(np.exp(rs.randn(b, s, h) - 3.0))
+        args = [jnp.asarray(x, jnp.float32) for x in (
+            rs.randn(b, s, h, p), dt, -dt * rs.uniform(1, 16, h),
+            0.3 * rs.randn(b, s, n), 0.3 * rs.randn(b, s, n),
+            1 + 0.3 * rs.randn(h))]
+        cot = jnp.asarray(rs.randn(b, s, h, p), jnp.float32)
+
+        def stepped(fn):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: (lambda y: (jnp.sum(y * cot), y))(
+                    fn(*a).astype(jnp.float32)),
+                argnums=tuple(range(6)), has_aux=True))
+
+        arm, note = ssd_route(s, h, p, n)
+        facts = {"arm": arm, "route": note}
+        (_, got), got_grads = stepped(ssd_scan)(*args)
+        (_, want), want_grads = stepped(ssd_recurrence)(*args)
+        for name, a, w in zip(("out", "dx", "ddt", "da", "dB", "dC", "dD"),
+                              (got,) + got_grads, (want,) + want_grads):
+            a, w = (np.asarray(t, np.float64) for t in (a, w))
+            rel = float(np.linalg.norm(a - w) / max(np.linalg.norm(w), 1e-30))
+            facts[f"{name} relative l2"] = rel
+            check(np.all(np.isfinite(a)) and np.any(w) and rel < 1e-3,
+                  f"ssd scan at {shape} ({arm}): {name} differs from the "
+                  f"recurrence by {rel} (relative L2)")
+        return facts
+
+    return {**{name: one(shape) for name, shape in size.scan.items()},
+            **{name: ssd(shape) for name, shape in size.ssd_scan.items()}}
 
 
 HELD_SHARES = (0.03, 0.06, 0.125, 0.25, 0.5, 1.0)
@@ -737,8 +777,9 @@ def child_cold(size: Size) -> dict:
     # forms, against the dense op
     phases["flash"] = check_flash(size)
 
-    # 8. the delta-rule scans at the two cells' head widths, each through
-    # the arm kda_route chose, against the recurrence
+    # 8. the delta-rule scans at the two cells' head widths and Mamba-2's
+    # at Granite's, each through the arm its route chose, against the
+    # recurrence
     phases["scan"] = check_scan(size)
     result["resume_from"] = snap
     result["net"] = net

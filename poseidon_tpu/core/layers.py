@@ -310,7 +310,10 @@ class AttentionLayer(Layer):
     (grouped-query attention; unset: as many as q, Dkv = D), rotate-half
     RoPE on the first ``rotary_dims`` of every q and k head (unset: the
     whole head; ``rope: false``: no positions at all), causal
-    softmax(q k^T / sqrt(Dh)) v over every earlier token or, with a
+    softmax(q k^T / sqrt(Dh)) v (``scale`` > 0: softmax(scale q k^T) v, a
+    model's own multiplier; 0, the default, is 1 / sqrt(Dh) and leaves every
+    net that does not set it the lowered step it had) over every earlier
+    token or, with a
     ``window``, over the last that many (t - window < s <= t), heads
     merged. Any whole number of query heads may share a key-value head (8
     do in a 32 / 4 layer). It normalises nothing: a QK-norm is the layers
@@ -364,6 +367,9 @@ class AttentionLayer(Layer):
         if ap.window < 0:
             raise ValueError(f"{self.name}: window {ap.window} is negative "
                              f"(0 = every earlier token)")
+        if ap.scale < 0:
+            raise ValueError(f"{self.name}: scale {ap.scale} is negative "
+                             f"(0 = 1 / sqrt(Dh))")
         return [tuple(bottom_shapes[0][:2]) + (ap.num_heads * self.v_head,)]
 
     def apply(self, params, bottoms, ctx):
@@ -375,7 +381,7 @@ class AttentionLayer(Layer):
                                rotary_dims=ap.rotary_dims,
                                window=ap.window, rope=ap.rope,
                                k_shared=bottoms[3] if len(bottoms) == 4
-                               else None)]
+                               else None, scale=ap.scale or None)]
 
 
 class MoELayer(Layer):
@@ -737,10 +743,12 @@ class ShortConvLayer(Layer):
     """(N, S, C) -> the same shape: a causal depthwise convolution over the
     sequence, ``kernel_size`` taps a channel, y_t = sum_j w[j] * x_{t-j}
     (zeros before a sequence's start: no state crosses it; nothing resets
-    inside a sequence), no bias, then SiLU. Taps
-    and sum in f32. Blob: w (kernel_size, C). A sibling of CCA_CONV, which
-    is that layer's two-bottom form with biases and a grouped second
-    convolution."""
+    inside a sequence), then SiLU. Taps and sum in f32. Blob: w
+    (kernel_size, C) and, with ``bias_term`` (Mamba-2's
+    ``mamba_conv_bias``), b (C,) added before the SiLU; without it, the
+    default, the layer and its lowered step are what every net had. A
+    sibling of CCA_CONV, which is that layer's two-bottom form with biases
+    and a grouped second convolution."""
     TYPE = "SHORT_CONV"
 
     def setup(self, bottom_shapes):
@@ -752,11 +760,14 @@ class ShortConvLayer(Layer):
         self.params = [self._param(
             "w", (kp.kernel_size, bottom_shapes[0][-1]), kp.weight_filler,
             0)]
+        if kp.bias_term:
+            self.params.append(self._param(
+                "b", (bottom_shapes[0][-1],), kp.bias_filler, 1))
         return [bottom_shapes[0]]
 
     def apply(self, params, bottoms, ctx):
         @jax.checkpoint         # a gradient keeps x and w, no f32 copy
-        def conv(x, w):
+        def conv(x, w, *b):
             taps, s = w.shape[0], x.shape[1]
             w = w.astype(jnp.float32)
             # the zeros before the start once, in x's type; tap j reads the
@@ -764,9 +775,12 @@ class ShortConvLayer(Layer):
             early = jnp.pad(x, [(0, 0), (taps - 1, 0), (0, 0)])
             y = sum(w[j] * early[:, taps - 1 - j:taps - 1 - j + s]
                     .astype(jnp.float32) for j in range(taps))
+            if b:
+                y = y + b[0].astype(jnp.float32)
             return jax.nn.silu(y).astype(x.dtype)
 
-        return [conv(bottoms[0], _tap_all(ctx, self.name, params)["w"])]
+        p = _tap_all(ctx, self.name, params)
+        return [conv(bottoms[0], p["w"], *([p["b"]] if "b" in p else []))]
 
 
 def _split_heads(name, what, shape, heads):
@@ -806,22 +820,26 @@ class KDADecayLayer(Layer):
     head, Gated DeltaNet's), f32 whatever the compute policy (its
     cumulative sums are the scan's exponents). Blobs: A_log (H,), dt_bias
     (H d). A second top, optional: the mean of exp(g) over the step, a
-    scalar a display carries (does the state forget?)."""
+    scalar a display carries (does the state forget?). A third and a
+    fourth, optional (Mamba-2's SSD_SCAN, whose write is dt x B^T, takes the
+    step itself as well): dt = softplus(x + dt_bias) in the bottom's shape,
+    f32, and its mean over the step, a scalar. With one or two tops the
+    layer and its lowered step are what every net had."""
     TYPE = "KDA_DECAY"
 
     def setup(self, bottom_shapes):
         kp = self.lp.kda_param
         _split_heads(self.name, self.TYPE, bottom_shapes[0], kp.num_heads)
-        if len(self.lp.top) not in (1, 2):
-            raise ValueError(f"{self.name}: KDA_DECAY has 1 or 2 tops (g[, "
-                             f"the mean decay])")
+        if not 1 <= len(self.lp.top) <= 4:
+            raise ValueError(f"{self.name}: KDA_DECAY has 1 to 4 tops (g[, "
+                             f"the mean decay[, dt[, the mean dt]]])")
         self.params = [
             self._param("A_log", (kp.num_heads,), FillerParameter(
                 type="log_of_uniform", min=kp.a_min, max=kp.a_max), 0),
             self._param("dt_bias", (bottom_shapes[0][-1],), FillerParameter(
                 type="inv_softplus_log_uniform", min=kp.dt_min,
                 max=kp.dt_max), 1)]
-        return [bottom_shapes[0]] + [()] * (len(self.lp.top) - 1)
+        return [bottom_shapes[0], (), bottom_shapes[0], ()][:len(self.lp.top)]
 
     def default_loss_weight(self) -> float:
         return 0.0
@@ -829,18 +847,22 @@ class KDADecayLayer(Layer):
     def apply(self, params, bottoms, ctx):
         p = _tap_all(ctx, self.name, params)
         heads = self.lp.kda_param.num_heads
+        with_step = len(self.lp.top) > 2
 
         @jax.checkpoint         # a gradient keeps x and the two blobs
         def decay(x, a_log, dt_bias):
             x = x.astype(jnp.float32)
             step = jax.nn.softplus(x + dt_bias.astype(jnp.float32))
             rate = jnp.exp(a_log.astype(jnp.float32))
-            return -(step.reshape(x.shape[:2] + (heads, -1))
-                     * rate[:, None]).reshape(x.shape)
+            g = -(step.reshape(x.shape[:2] + (heads, -1))
+                  * rate[:, None]).reshape(x.shape)
+            return (g, step) if with_step else g
 
-        g = decay(bottoms[0], p["A_log"], p["dt_bias"])
-        mean = jnp.mean(jnp.exp(lax.stop_gradient(g)))
-        return [g, mean][:len(self.lp.top)]
+        out = decay(bottoms[0], p["A_log"], p["dt_bias"])
+        g, *step = out if with_step else (out,)
+        tops = [g, jnp.mean(jnp.exp(lax.stop_gradient(g)))]
+        tops += [t for s in step for t in (s, jnp.mean(lax.stop_gradient(s)))]
+        return tops[:len(self.lp.top)]
 
 
 class KDAScanLayer(Layer):
@@ -887,6 +909,43 @@ class KDAScanLayer(Layer):
             tops.append(jnp.mean(
                 (lax.stop_gradient(beta) > 1).astype(jnp.float32)))
         return tops
+
+
+class SSDScanLayer(Layer):
+    """Mamba-2's selective scan. Bottoms x (N, S, H P), dt and a = dt A
+    (N, S, H) f32 (KDA_DECAY's third and first tops), B and C (N, S, N_state)
+    that ALL ``num_heads`` heads share -> y (N, S, H P): per head a state
+    (P, N_state), zero at a sequence's start,
+    H_t = exp(a_t) H_{t-1} + dt_t x_t B_t^T, y_t = H_t C_t + D x_t
+    (``ops/ssd.ssd_scan``: chunks of the sequence, one C B^T grid a chunk
+    for all heads, f32 state; which arm runs follows from the shape and the
+    backend, and ``Net`` logs it). Blob: D (H,), the skip, filled with
+    ones. Nothing resets the state inside a sequence."""
+    TYPE = "SSD_SCAN"
+
+    def setup(self, bottom_shapes):
+        h = self.lp.kda_param.num_heads
+        if len(bottom_shapes) != 5:
+            raise ValueError(f"{self.name}: SSD_SCAN takes x, dt, a, B, C")
+        x, dt, a, b, c = (tuple(t) for t in bottom_shapes)
+        _split_heads(self.name, self.TYPE, x, h)
+        if dt != x[:2] + (h,) or a != dt or b != c or len(b) != 3 \
+                or b[:2] != x[:2]:
+            raise ValueError(
+                f"{self.name}: SSD_SCAN takes x (N, S, H P), dt and a "
+                f"(N, S, {h}) and B, C of one (N, S, N_state) shape; got "
+                f"{bottom_shapes}")
+        self.params = [self._param(
+            "D", (h,), FillerParameter(type="constant", value=1.0), 0)]
+        return [x]
+
+    def apply(self, params, bottoms, ctx):
+        from ..ops.ssd import ssd_scan
+        x, dt, a, b, c = bottoms
+        heads = x.reshape(x.shape[:2] + (self.lp.kda_param.num_heads, -1))
+        y = ssd_scan(heads, dt, a, b, c,
+                     _tap_all(ctx, self.name, params)["D"])
+        return [y.reshape(x.shape).astype(x.dtype)]
 
 
 class SiLUGateLayer(Layer):
@@ -1444,7 +1503,8 @@ REGISTRY: Dict[str, type] = {
         ConvolutionLayer, InnerProductLayer, EmbedLayer, RMSNormLayer,
         AttentionLayer, MoELayer, MoERouterLayer, TokenShiftLayer,
         CCAConvLayer, CCAQKMeanLayer, CCAQKNormLayer, ShortConvLayer,
-        L2NormLayer, KDADecayLayer, KDAScanLayer, PoolingLayer, LRNLayer,
+        L2NormLayer, KDADecayLayer, KDAScanLayer, SSDScanLayer, PoolingLayer,
+        LRNLayer,
         Im2colLayer, ReLULayer, SigmoidLayer, TanHLayer, BNLLLayer,
         AbsValLayer, PowerLayer, ThresholdLayer, DropoutLayer, FlattenLayer,
         ConcatLayer, SliceLayer, SplitLayer, EltwiseLayer, MVNLayer,

@@ -441,6 +441,15 @@ class Net:
                     self.blob_shapes[layer.lp.bottom[2]][2] // h, h,
                     jnp.dtype(policy().compute_dtype).itemsize,
                     per_head=layer.per_head)[1], ""
+            elif layer.TYPE == "SSD_SCAN":
+                from ..ops.ssd import ssd_route
+                what = "ssd_scan"
+                h = layer.lp.kda_param.num_heads
+                # the note names the arm and, where it is chunked on a shape
+                # the kernels refuse, the reason
+                arm, note = ssd_route(
+                    shape[1], h, shape[2] // h,
+                    self.blob_shapes[layer.lp.bottom[3]][2])[1], ""
             elif layer.TYPE == "MOE":
                 from ..models.moe import GROUPED_MATMUL
                 what = "grouped_matmul"
@@ -471,12 +480,26 @@ class Net:
                 for l in self.layers if l.TYPE == "MOE"}
 
     def recurrent_state(self) -> Dict[str, Dict[str, int]]:
-        """{KDA_SCAN layer: its states' shape, the scan's chunk and what
-        its backward keeps of them} — stats.yaml's ``recurrent_state``
-        section."""
+        """{KDA_SCAN or SSD_SCAN layer: its states' shape, the scan's chunk
+        and what its backward keeps of them} — stats.yaml's
+        ``recurrent_state`` section."""
+        from ..ops import ssd
         from ..ops.kda import kda_chunk, state_bytes
         out = {}
         for l in self.layers:
+            if l.TYPE == "SSD_SCAN":
+                # Mamba-2: a state (P, N_state) a head, B and C shared
+                (n, s, w), (_, _, n_state) = (self.blob_shapes[b]
+                                              for b in l.lp.bottom[:4:3])
+                h = l.lp.kda_param.num_heads
+                chunk = ssd.scan_chunk(s, h, w // h, n_state)
+                out[l.name] = {
+                    "heads": h, "d_k": n_state, "d_v": w // h,
+                    "chunk": chunk or 1, "chunks": s // (chunk or 1),
+                    "saved_state_bytes": ssd.state_bytes(
+                        n, s, h, w // h, n_state),
+                    "decay": "head"}
+                continue
             if l.TYPE != "KDA_SCAN":
                 continue
             (n, s, wk), (_, _, wv) = (self.blob_shapes[b]

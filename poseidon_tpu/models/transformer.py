@@ -152,7 +152,8 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
                    rope_theta: float = 10000.0, n_kv_heads: int = 0,
                    rotary_dims: int = 0, window: int = 0,
                    rope: bool = True,
-                   k_shared: Optional[jax.Array] = None) -> jax.Array:
+                   k_shared: Optional[jax.Array] = None,
+                   scale: Optional[float] = None) -> jax.Array:
     """q (B, S, D), k and v (B, S, Dkv) -> (B, S, D): q split into
     ``n_heads`` heads, k and v into ``n_kv_heads`` of the same width (0 =
     ``n_heads``, Dkv = D), rotary positions on q and k, causal
@@ -163,7 +164,8 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     ``rotary_dims`` (0 = the whole head): only the first that many dims of
     a head rotate, the rest pass; ``rope`` false: nothing rotates, the
     layer has no positions. ``window`` (0 = none): token t attends to s with
-    t - window < s <= t. The Pallas flash kernel where the sequence
+    t - window < s <= t. ``scale`` (None: 1 / sqrt(Dh)): what multiplies
+    q k^T. The Pallas flash kernel where the sequence
     tiles (``maybe_flash_attention``), the dense op elsewhere.
 
     Where a head is whole vregs of lanes (``flash_operand_form``: widths
@@ -186,7 +188,7 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     if flash_operand_form(s, d_head, v.shape[-1] // n_kv)[0]:
         return _rope_attention_lanes(q, k, v, n_heads, n_kv, rot,
                                      rope_theta if rope else None, window,
-                                     k_shared)
+                                     k_shared, scale)
 
     def heads(t, n):
         return t.reshape(b, s, n, -1).swapaxes(1, 2)
@@ -212,7 +214,8 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
         heads(v, n_kv)
     if n_kv != n_heads:
         k, v = (jnp.repeat(t, n_heads // n_kv, axis=1) for t in (k, v))
-    att = maybe_flash_attention(q, k, v, causal=True, window=window)
+    att = maybe_flash_attention(q, k, v, causal=True, window=window,
+                                scale=scale)
     return att.swapaxes(1, 2).reshape(b, s, n_heads * v.shape[-1])
 
 
@@ -290,7 +293,8 @@ def _repeat_lanes(t: jax.Array, n: int, times: int) -> jax.Array:
 
 
 def _rope_attention_lanes(q, k, v, n_heads: int, n_kv: int, rot: int,
-                          theta: Optional[float], window: int, k_shared):
+                          theta: Optional[float], window: int, k_shared,
+                          scale: Optional[float] = None):
     """``rope_attention`` where a head is whole vregs of lanes: q, k and v
     stay (B, S, n·Dh) from the projections to the kernels, which address a
     head as a lane block, and the result is (B, S, n_heads·Dv) as the
@@ -308,7 +312,7 @@ def _rope_attention_lanes(q, k, v, n_heads: int, n_kv: int, rot: int,
     if n_kv != n_heads:
         k, v = (_repeat_lanes(t, n_kv, n_heads // n_kv) for t in (k, v))
     return maybe_flash_attention(q, k, v, causal=True, window=window,
-                                 heads=n_heads)
+                                 heads=n_heads, scale=scale)
 
 
 def attention_sublayer(cfg: TransformerConfig, x: jax.Array, blk: Dict,

@@ -1364,3 +1364,173 @@ def olmo_hybrid(batch: int = 1,
     layers.append(LayerParameter(
         name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
     return NetParameter(name=name, layers=layers)
+
+
+def granite_hybrid(batch: int = 1,
+                   source: str = "examples/lm/granite_h_micro_tokens.txt",
+                   layers: int = 40, vocab_rows: int = 100352,
+                   hidden: int = 2048, attn_every: int = 10,
+                   attn_at: int = 5, heads: int = 32, kv_heads: int = 8,
+                   ssd_heads: int = 64, ssd_head_dim: int = 64,
+                   state: int = 128, conv_taps: int = 4,
+                   ffn_width: int = 8192, eps: float = 1e-5,
+                   embedding_multiplier: float = 12.0,
+                   attention_multiplier: float = 0.015625,
+                   residual_multiplier: float = 0.22,
+                   logits_scaling: float = 8.0, init_std: float = 0.02,
+                   name: str = "Granite-4.0-H-Micro") -> NetParameter:
+    """Granite-4.0-H-Micro (config.json of ibm-granite/granite-4.0-h-micro,
+    ``granitemoehybrid`` with no experts): 40 pre-norm layers, nine Mamba-2
+    mixers to one attention mixer (``layer_types``: attention at layers 5,
+    15, 25, 35; a layer i mixes by attention where i % ``attn_every`` ==
+    ``attn_at``), a SwiGLU MLP in every layer, four scalar multipliers, a
+    table tied to the head, no bias but the convolution's:
+
+        h_0 = 12 E[ids]
+        u = h + 0.22 Mix(N1(h));  h' = u + 0.22 MLP(N2(u))
+        MLP(y) = W_out (SiLU(a) * b),  [a, b] = W_in y
+        logits = N_f(h_L) E^T / 8
+
+    Mamba-2 layer (``l<i>_ssd_*``), H ``ssd_heads`` heads of P
+    ``ssd_head_dim``, a state of N ``state`` a head, ONE group of B / C:
+
+        [z, xBC, dt~] = W_in y                 (H P | H P + 2 N | H)
+        [x, B, C] = SiLU(conv4(xBC) + b_conv)  causal, depthwise
+        dt = softplus(dt~ + dt_bias);  a = -exp(A_log) dt      one a head
+        H_t = exp(a_t) H_{t-1} + dt_t x_t B_t^T;  y_t = H_t C_t + D x_t
+        Mix = W_out N_g(y * SiLU(z))           gate, THEN one norm over H P
+
+    as layers: ``l<i>_ssd_in`` (one INNER_PRODUCT) and ``l<i>_ssd_in_split``
+    (SLICE), ``l<i>_ssd_conv`` (SHORT_CONV with ``bias_term``) and
+    ``l<i>_ssd_conv_split``, ``l<i>_ssd_decay`` (KDA_DECAY with four tops:
+    a, ``l<i>_ssd_decay_mean``, dt, ``l<i>_ssd_dt_mean``; the two means are
+    scalars every display carries), ``l<i>_ssd_scan`` (SSD_SCAN, blob D),
+    ``l<i>_ssd_gate`` (SILU_GATE), ``l<i>_ssd_onorm``, ``l<i>_ssd_out``.
+
+    Attention layer (``l<i>_attn_*``): ``heads`` query and ``kv_heads``
+    key-value heads of hidden / heads, NO positions, causal
+    softmax(``attention_multiplier`` q k^T) v (ATTENTION's ``scale``).
+
+    The multipliers are layers the Net has: POWER's scale on the embedding
+    (``embed_scale``) and on the final norm's result (``lm_scale``: a
+    division by 8 commutes with the tied product and is exact in any
+    float), ELTWISE SUM's coeff on the residuals. ``layers`` of the 40 (the
+    first that many: 10 is one period) and ``vocab_rows`` of the 100,352
+    rows of the table give one pipeline stage's cut.
+
+    Gains, A_log, dt_bias, D and the convolution's bias carry decay_mult 0,
+    every matrix and the convolution's taps 1."""
+    from ..proto.messages import (AttentionParameter, EltwiseParameter,
+                                  EmbedParameter, HDF5DataParameter,
+                                  KDAParameter, PowerParameter,
+                                  RMSNormParameter, SliceParameter)
+    if layers != 40 or vocab_rows != 100352:
+        name = f"{name} ({layers} of 40 layers, {vocab_rows} rows)"
+    w = gaussian(init_std)
+    no_decay = ParamSpec(lr_mult=1.0, decay_mult=0.0)
+    tied = [ParamSpec(name="tok_w")]
+    net: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+
+    def norm(lname, bottom, top):
+        net.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[no_decay], rms_norm_param=RMSNormParameter(eps=eps)))
+
+    def proj(lname, bottom, top, n_out, spec=()):
+        net.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            param=list(spec), inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2, weight_filler=w)))
+
+    def split(lname, bottom, tops, points):
+        net.append(LayerParameter(
+            name=lname, type="SLICE", bottom=[bottom], top=list(tops),
+            slice_param=SliceParameter(slice_dim=2,
+                                       slice_point=list(points))))
+
+    def scaled(lname, bottom, top, by):
+        net.append(LayerParameter(
+            name=lname, type="POWER", bottom=[bottom], top=[top],
+            power_param=PowerParameter(scale=by)))
+
+    def residual(lname, a, b, top):
+        net.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(
+                operation="SUM", coeff=[1.0, residual_multiplier])))
+
+    def silu_gate(lname, gate, up, top):
+        net.append(LayerParameter(
+            name=lname, type="SILU_GATE", bottom=[gate, up], top=[top]))
+
+    def mamba(p, y, top):
+        inner = ssd_heads * ssd_head_dim
+        kp = dict(num_heads=ssd_heads)
+        taps = FillerParameter(type="uniform", min=-conv_taps ** -0.5,
+                               max=conv_taps ** -0.5)
+        proj(p + "ssd_in", y, p + "zxd", 2 * inner + 2 * state + ssd_heads)
+        split(p + "ssd_in_split", p + "zxd", [p + "z", p + "xbc", p + "dtr"],
+              [inner, 2 * inner + 2 * state])
+        net.append(LayerParameter(
+            name=p + "ssd_conv", type="SHORT_CONV", bottom=[p + "xbc"],
+            top=[p + "xbcc"], param=[ParamSpec(), no_decay],
+            kda_param=KDAParameter(kernel_size=conv_taps, weight_filler=taps,
+                                   bias_term=True, bias_filler=taps)))
+        split(p + "ssd_conv_split", p + "xbcc", [p + "xs", p + "B", p + "C"],
+              [inner, inner + state])
+        net.append(LayerParameter(
+            name=p + "ssd_decay", type="KDA_DECAY", bottom=[p + "dtr"],
+            top=[p + "a", p + "ssd_decay_mean", p + "dt",
+                 p + "ssd_dt_mean"], param=[no_decay, no_decay],
+            kda_param=KDAParameter(**kp)))
+        net.append(LayerParameter(
+            name=p + "ssd_scan", type="SSD_SCAN",
+            bottom=[p + "xs", p + "dt", p + "a", p + "B", p + "C"],
+            top=[p + "sy"], param=[no_decay], kda_param=KDAParameter(**kp)))
+        silu_gate(p + "ssd_gate", p + "z", p + "sy", p + "sg")
+        norm(p + "ssd_onorm", p + "sg", p + "sn")
+        proj(p + "ssd_out", p + "sn", top, hidden)
+
+    def attention(p, y, top):
+        d_head = hidden // heads
+        for t, n in (("q", heads), ("k", kv_heads), ("v", kv_heads)):
+            proj(p + "attn_" + t, y, p + t, n * d_head)
+        net.append(LayerParameter(
+            name=p + "attn_sdpa", type="ATTENTION",
+            bottom=[p + "q", p + "k", p + "v"], top=[p + "att"],
+            attention_param=AttentionParameter(
+                num_heads=heads, num_kv_heads=kv_heads, rope=False,
+                scale=attention_multiplier)))
+        proj(p + "attn_o", p + "att", top, hidden)
+
+    net.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["x0"],
+        param=tied, embed_param=EmbedParameter(
+            input_dim=vocab_rows, num_output=hidden, weight_filler=w)))
+    scaled("embed_scale", "x0", "h0", embedding_multiplier)
+    h = "h0"
+    for i in range(layers):
+        p = f"l{i}_"
+        norm(p + "norm1", h, p + "n1")
+        (attention if i % attn_every == attn_at else mamba)(
+            p, p + "n1", p + "mo")
+        residual(p + "res1", h, p + "mo", p + "u")
+        norm(p + "norm2", p + "u", p + "n2")
+        proj(p + "ffn_in", p + "n2", p + "fab", 2 * ffn_width)
+        split(p + "ffn_split", p + "fab", [p + "fa", p + "fb"], [ffn_width])
+        silu_gate(p + "ffn_act", p + "fa", p + "fb", p + "fg")
+        proj(p + "ffn_out", p + "fg", p + "fo", hidden)
+        residual(p + "res2", p + "u", p + "fo", p + "y")
+        h = p + "y"
+    norm("final_norm", h, "xf")
+    scaled("lm_scale", "xf", "xs", 1.0 / logits_scaling)
+    proj("lm_head", "xs", "logits", vocab_rows, tied)
+    net.append(LayerParameter(
+        name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
+        top=["nll"]))
+    # the mean over positions: the exit-weighted loss of ONE pass
+    net.append(LayerParameter(
+        name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    return NetParameter(name=name, layers=net)

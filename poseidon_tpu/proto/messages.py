@@ -314,6 +314,11 @@ class AttentionParameter:
     # wide): v is (N, S, num_kv_heads * value_head_dim) and so is the top
     # per query head; 0 = the key head's width
     value_head_dim: int = 0
+    # what multiplies q k^T before the softmax; 0 = 1 / sqrt(D / num_heads)
+    # (a model with a multiplier of its own sets it: Granite's
+    # attention_multiplier 1/64 at heads of 64). The default leaves every
+    # net that does not set it the lowered step it had.
+    scale: float = 0.0
 
 
 @dataclass
@@ -374,9 +379,14 @@ class KDAParameter:
     dt_bias (one a channel of its bottom: one a HEAD where the bottom is
     (N, S, H)) the inverse softplus of a log-uniform draw in [``dt_min``,
     ``dt_max``]. KDA_SCAN: ``num_heads`` states, their two widths read off
-    q's and v's bottoms."""
+    q's and v's bottoms. SSD_SCAN (Mamba-2): ``num_heads`` states of
+    (x's width / num_heads) x (B's width). ``bias_term`` (SHORT_CONV): a
+    bias a channel before the SiLU (``bias_filler``; Mamba-2's
+    ``mamba_conv_bias``); false, the default, is the layer every net had."""
     num_heads: int = 1
     kernel_size: int = 4
+    bias_term: bool = False
+    bias_filler: FillerParameter = field(default_factory=FillerParameter)
     eps: float = 1e-6
     a_min: float = 1.0
     a_max: float = 16.0
@@ -510,6 +520,7 @@ V2_TYPE_TO_V1 = {
     "CCAQKMean": "CCA_QKMEAN", "CCAQKNorm": "CCA_QKNORM",
     "MoERouter": "MOE_ROUTER", "ShortConv": "SHORT_CONV",
     "L2Norm": "L2_NORM", "KDADecay": "KDA_DECAY", "KDAScan": "KDA_SCAN",
+    "SSDScan": "SSD_SCAN",
 }
 V1_TYPES = set(V2_TYPE_TO_V1.values()) | {"NONE"}
 

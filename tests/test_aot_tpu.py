@@ -720,6 +720,64 @@ def test_olmo_hybrid_full_width_step_fits_one_v5e_at_one_and_two_sequences(
         assert 0.68 * 16.9 < got["total_gb"] < 0.76 * 16.9
 
 
+# The full-width Granite-4.0-H-Micro train step (examples/lm/granite_h_micro_*:
+# published layers 0-9, one period of nine Mamba-2 layers and the attention
+# layer; an eighth of the tied vocabulary) as `train --bf16 --remat <the
+# solver header's flags>` builds it at one sequence of 8,192, for one abstract
+# v5e chip: the compiler's memory accounting that fixed the cell's batch
+# (benchmark/cells/granite_h.p1.pack8k.json), and at two.
+_GRANITE_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 1 + {deeper}, 8192, 0").replace(
+    "ouro_2_6b_solver", "granite_h_micro_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type in ("ATTENTION", "SSD_SCAN") '
+    'for l in net_param.layers)')
+assert _GRANITE_STEP.count("granite_h_micro") == 1 \
+    and "ouro_2" not in _GRANITE_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("more", [0, 1])
+def test_granite_full_width_step_fits_one_v5e_at_one_and_two_sequences(more):
+    """At one sequence of 8,192 the step with one checkpoint a layer is
+    under ``remat.KEEP_SHARE`` of the 16.9 GB the compiler allows with the
+    scans' and the flash kernels' forward results kept. The nine Mamba-2
+    layers' recurrences are the scan's Pallas kernels (32 chunks of 256,
+    eight heads a program, two a lane block, the f32 states in VMEM: forward
+    and backward, one call each a layer where the checkpoint keeps what the
+    forward wrote), the attention layer's flash kernels run at 32 heads of
+    64 head-major with no positions: Mosaic compiles all of them for the
+    v5e here."""
+    import json
+    from poseidon_tpu.core.remat import KEEP_SHARE
+    r = subprocess.run(
+        [sys.executable, "-c", _GRANITE_STEP.format(repo=REPO, deeper=more)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next cut
+    assert got["depth"] == 10 and got["parameters"] == 772_160_448
+    # the table, final norm; a layer: 2 norms + 2 MLP; a mamba mixer 8 (in,
+    # conv w + b, A_log + dt_bias, D, out-norm, out), the attention one 4
+    assert got["leaves"] == 2 + 10 * 4 + 9 * 8 + 4
+    assert got["segments"] == 10 + 1
+    assert got["routes"] == [
+        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
+        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "head; operands head-major (Dh 64, not lane-aligned)); 8 kv heads "
+        "repeated x4; no positions",
+        "ssd_scan=pallas (Q 256, 32 chunks, 8 heads a program, 2 a lane "
+        "block, one C B^T grid a program, f32 states in VMEM)"]
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    assert got["total_gb"] < KEEP_SHARE * 16.9
+
+
 # The full-width SmallThinker train step (examples/lm/smallthinker_21b_*:
 # published layers 0-3, global, window, window, window; 16 of 64 experts held,
 # an eighth of the untied vocabulary) as `train --bf16 --remat <the solver

@@ -79,7 +79,11 @@ def test_cpu_tiny_rehearsal_runs_every_phase(tmp_path):
         == "chunked"
     assert "one decay a head" in scan["tiny per-head"]["route"]
     assert all(sum("relative l2" in k for k in v) == 6
-               for v in scan.values())
+               for name, v in scan.items() if name != "tiny ssd")
+    # Mamba-2's scan through the arm ssd_route chose, six gradients
+    assert scan["tiny ssd"]["arm"] == "chunked" \
+        and "not pallas" in scan["tiny ssd"]["route"]
+    assert sum("relative l2" in k for k in scan["tiny ssd"]) == 7
     assert sorted(os.listdir(cache / "aot"))   # the step store rode along
 
 
