@@ -270,6 +270,15 @@ def check_run(name: str, run: dict, size: Size, device: dict, *,
           and all(routes[k].startswith("pool_bwd=pallas " + minor)
                   for k in ("pool1", "pool2", "pool5")),
           f"{name}: kernel routes {routes}")
+    # no run here names a layout: `auto` is the channels-last plan on the
+    # chip (conv / pool / LRN natively, the one boundary at fc6's flatten)
+    # and NCHW on the CPU rehearsal, and the run says so
+    plan = stats["conv_layout"]
+    on_chip = device["platform"] != "cpu"
+    check(plan["asked"] == "auto" and plan["why"] == device["platform"]
+          and plan["resolved"] == ("NHWC" if on_chip else "NCHW")
+          and plan["boundaries"] == ("pool5->fc6" if on_chip else "none"),
+          f"{name}: layout plan {plan}")
     step = stats["compiled_step"]
     check("error" not in step and "pallas_custom_calls" in step,
           f"{name}: the engine could not resolve its step executable and "

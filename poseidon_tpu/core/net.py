@@ -100,13 +100,20 @@ class Net:
         # The layout is a GRAPH-level choice, fixed at construction: the
         # per-net override wins, else the ambient numeric policy's default.
         # "auto" resolves through the per-backend table of
-        # numeric.resolve_conv_layout (NCHW on the TPU and the CPU).
-        # (Ops take explicit layout args; they no longer read the policy.)
+        # numeric.resolve_conv_layout (NHWC on the TPU and the GPU, NCHW on
+        # the CPU). (Ops take explicit layout args; they no longer read
+        # the policy.)
         from ..numeric import resolve_conv_layout
-        self.conv_layout = resolve_conv_layout(
-            conv_layout or policy().conv_layout)
+        asked = (conv_layout or policy().conv_layout or "NCHW").upper()
+        backend = jax.default_backend() if asked == "AUTO" else None
+        self.conv_layout = resolve_conv_layout(asked, backend)
         if self.conv_layout not in NN.LAYOUTS:
             raise ValueError(f"unknown conv_layout {self.conv_layout!r}")
+        # what _plan_layouts logs and stats.yaml carries beside the plan:
+        # what was asked for, and the backend that decided where it was left
+        # to the table
+        self._layout_asked = (("auto", backend) if backend
+                              else (asked, "asked for"))
         self.fuse_conv_epilogues = fuse_conv_epilogues
         # Conv lowering strategy, also a graph-level request resolved at
         # construction: a value forces one strategy net-wide; "" keeps
@@ -321,18 +328,32 @@ class Net:
         canonical layers (FC flatten, im2col, dropout rng, unknown types)
         force the genuine NCHW boundary. The walk mirrors ``apply``'s, so
         apply can replay it to know every blob's physical layout at every
-        program point (in-place chains may re-layout a name mid-net)."""
+        program point (in-place chains may re-layout a name mid-net).
+
+        Which plan the net took, why, and what it holds is logged in one
+        line, like every other routing decision, and kept as
+        ``layout_plan`` (stats.yaml's ``conv_layout`` section): the layers
+        that run channels-last of those that touch a 4-D blob, and the
+        boundary conversions ``apply`` will make (a 4-D bottom that arrives
+        in the other layout, ``<blob>-><layer>``; a 4-D output exported
+        canonical, ``<blob>->out``). A net without a 4-D blob counts 0."""
+        from ..runtime.metrics import log
         self.input_layouts: Dict[str, str] = {}
         nhwc = self.conv_layout == "NHWC"
         for name in self.input_names:
             four_d = len(self.blob_shapes[name]) == 4
             self.input_layouts[name] = "NHWC" if (nhwc and four_d) else "NCHW"
-        if not nhwc:
-            return
         cur = dict(self.input_layouts)
+        four_d_layers = channels_last = 0
+        boundaries: List[str] = []
+        converted: set = set()      # apply's cache: (blob, layout) it holds
         for layer in self.layers:
             b4 = [b for b in layer.lp.bottom
                   if len(self.blob_shapes[b]) == 4]
+            t4 = [t for t in layer.lp.top if len(self.blob_shapes[t]) == 4]
+            four_d_layers += bool(b4 or t4)
+            if not nhwc:
+                continue
             if layer.LAYOUT_KIND == LAYOUT_SPATIAL:
                 run = "NHWC"
             elif layer.LAYOUT_KIND == LAYOUT_AGNOSTIC:
@@ -341,9 +362,42 @@ class Net:
             else:
                 run = "NCHW"
             layer.run_layout = run
-            for t in layer.lp.top:
-                if len(self.blob_shapes[t]) == 4:
-                    cur[t] = run
+            channels_last += run == "NHWC"
+            for b in b4:
+                if cur.get(b, "NCHW") != run and (b, run) not in converted:
+                    converted.add((b, run))
+                    boundaries.append(f"{b}->{layer.name}")
+            for t in t4:
+                cur[t] = run
+                converted -= {(t, "NCHW"), (t, "NHWC")}
+        boundaries += [f"{o}->out" for o in self.output_names
+                       if len(self.blob_shapes[o]) == 4
+                       and cur.get(o, "NCHW") == "NHWC"]
+        asked, why = self._layout_asked
+        self.layout_plan: Dict[str, object] = {
+            "asked": asked, "resolved": self.conv_layout, "why": why,
+            "layers_with_4d_blob": four_d_layers,
+            "channels_last_layers": channels_last,
+            "boundary_conversions": len(boundaries),
+            "boundaries": ", ".join(boundaries) or "none",
+            # a caller that feeds canonical batches (the Engine) pays one
+            # transpose for each of these at the step's entry
+            "inputs_channels_last": sum(
+                v == "NHWC" for v in self.input_layouts.values())}
+        head = f"{self.conv_layout} ({why})"
+        if asked == "auto":
+            head = f"auto -> {head}"
+        if not four_d_layers:
+            what = "no 4-D blob, nothing to plan"
+        elif not nhwc:
+            what = (f"{four_d_layers} layers with a 4-D blob run "
+                    f"canonical, no boundary")
+        else:
+            what = (f"{channels_last} of {four_d_layers} layers with a 4-D "
+                    f"blob run channels-last, {len(boundaries)} boundary "
+                    f"conversion(s)"
+                    + (f": {', '.join(boundaries)}" if boundaries else ""))
+        log(f"[conv_layout] {head}: {what}")
 
     def _plan_conv_strategies(self) -> None:
         """Resolve each conv layer's lowering strategy. "" leaves the

@@ -40,8 +40,9 @@ class Policy:
     # per-op transpose shim this replaces lost 1.9x: its boundary pairs
     # did not cancel across pool/LRN/concat seams.)
     conv_layout: str = "NCHW"
-    # "auto" resolves per-backend at Net construction (resolve_conv_layout):
-    # explicit "nchw"/"nhwc" always win.
+    # "auto" (what `train` sets unless --conv_layout names a plan) resolves
+    # per-backend at Net construction (resolve_conv_layout: NHWC on the TPU
+    # and the GPU, NCHW on the CPU); explicit "nchw"/"nhwc" always win.
     # Space-to-depth stem transform: rewrite few-channel strided convs
     # (AlexNet/GoogLeNet conv1: 3 input channels use 3/128 MXU lanes) as an
     # exact stride-1 conv over s*s-times more channels. Mathematically
@@ -70,11 +71,16 @@ def resolve_conv_layout(layout: str, backend: str = None) -> str:
     """Resolve a conv_layout choice ("NCHW" | "NHWC" | "auto") against the
     backend actually running the net. "auto" is this table:
 
-    - **tpu**: NCHW. The NHWC plan ran 0.53x in the first chip A/B (July
-      2026, before PRs 1-19) and 1.035x (alexnet.resident) / 1.013x
-      (googlenet.lmdb) on PR 43's code (builder's chip runs, PR 44;
-      PERF.md section 6): this row waits for a PR that claims the gain on
-      the driver's pairs (ROADMAP D3).
+    - **tpu**: NHWC since PR 55. On the v5e the channels-last plan takes
+      conv1's weight gradient (K = 3 x 11 x 11 on a 128-wide MXU; 2.12 ms
+      of AlexNet's 29 ms step under NCHW, 1.09 under the plan) out of the
+      step's largest ops: +3.4% images/s/chip in alexnet.resident, +2.6%
+      in alexnet.dp4.resident, +1.2% in googlenet.lmdb, a warm start's
+      setup_s level (builder's chip runs, PR 55, the parent's archive
+      against the committed files; PERF.md section 6; the driver's pairs
+      are PR 55's lines of PERF_LEDGER.jsonl). The LRN and pool kernels
+      run the same blocks under both plans (PRs 33, 35), which is what
+      turned the 0.53x of the first chip A/B (July 2026, before PRs 1-19).
     - **gpu**: NHWC (tensor-core native conv layout).
     - **cpu** (and anything unknown): NCHW — the Caffe-parity default the
       golden-value suites run under.
@@ -86,7 +92,7 @@ def resolve_conv_layout(layout: str, backend: str = None) -> str:
     if backend is None:
         import jax
         backend = jax.default_backend()
-    return "NHWC" if backend == "gpu" else "NCHW"
+    return "NHWC" if backend in ("tpu", "gpu") else "NCHW"
 
 
 _policy = Policy()

@@ -1033,7 +1033,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "(core/net.py plans conv/pool/LRN natively in it; "
                         "checkpoints stay canonical NCHW). 'auto' = the "
                         "per-backend table in numeric.resolve_conv_layout "
-                        "(NCHW on tpu and cpu)")
+                        "(NHWC on tpu and gpu, NCHW on cpu); the run's log "
+                        "([conv_layout]) and stats.yaml (conv_layout:) say "
+                        "which plan it took")
     t.add_argument("--mesh", default="",
                    help="named SPMD mesh spec, e.g. 'dp2,fsdp2,tp1' "
                         "(axes: dp = data parallel, fsdp = sharded "

@@ -482,7 +482,7 @@ class Engine:
 
         # what this run is, where a reader of stats.yaml will look for it:
         # the device as jax reports it, which kernel arm each layer took,
-        # which reader feeds each data layer
+        # which layout plan the graph runs, which reader feeds each data layer
         dev = jax.local_devices()[0]
         self.stats.set_section("device", {
             "platform": dev.platform, "kind": dev.device_kind,
@@ -491,6 +491,10 @@ class Engine:
             "jax": jax.__version__})
         self.stats.set_section("kernel_routes",
                                dict(self.train_net.kernel_routes))
+        # which whole-graph plan the net took (asked for / resolved / why),
+        # how many layers run channels-last and the boundaries it holds
+        self.stats.set_section("conv_layout",
+                               dict(self.train_net.layout_plan))
         if self.train_net.shared_params:
             # one leaf to the update, the clip and a snapshot, whatever
             # number of layers reads it

@@ -1243,7 +1243,7 @@ try:
 except Exception as e:
     print("SKIP:", e)
     sys.exit(3)
-from poseidon_tpu.config import set_perf_policy
+from poseidon_tpu.config import resolve_conv_layout, set_perf_policy
 from poseidon_tpu.core.net import Net
 from poseidon_tpu.models import zoo
 from poseidon_tpu.parallel import (CommConfig, build_train_step,
@@ -1251,8 +1251,11 @@ from poseidon_tpu.parallel import (CommConfig, build_train_step,
 from poseidon_tpu.proto.messages import SolverParameter
 set_perf_policy()
 batch = 512
+# the plan `--conv_layout auto` resolves to on the chip (channels-last since
+# PR 55); this process's backend is the CPU
 net = Net(zoo.alexnet(with_accuracy=False), "TRAIN",
-          source_shapes=zoo.alexnet_shapes(batch))
+          source_shapes=zoo.alexnet_shapes(batch),
+          conv_layout=resolve_conv_layout("auto", "tpu"))
 sp = SolverParameter(base_lr=0.01, lr_policy="step", stepsize=100000,
                      gamma=0.1, momentum=0.9, weight_decay=0.0005)
 mesh = Mesh(np.array(topo.devices[:1]), ("data",))
@@ -1280,8 +1283,10 @@ print("RESULT " + json.dumps({{
     "buffer_length_arrays": text.count("f32[%d]" % n),
     "all_reduces": len(re.findall(r" all-reduce(-start)?\(", text)),
     "pallas_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+    "conv_layout": net.layout_plan,
     "lrn_operand_copies": len(re.findall(
-        r"\[512,(?:96,3025|256,729|96,55,55|256,27,27)\]\S* copy\(", text)),
+        r"\[512,(?:96,3025|256,729|96,55,55|256,27,27|3025,96|729,256"
+        r"|55,55,96|27,27,256)\]\S* copy\(", text)),
     "select_and_scatter": text.count(" select-and-scatter("),
     "temp_gb": ma.temp_size_in_bytes / 1e9}}))
 """
@@ -1306,6 +1311,8 @@ def test_alexnet_one_chip_step_has_no_arena_for_one_v5e():
                           if l.startswith("RESULT "))[7:])
     print(got)
     assert got["parameters"] == 60_965_224
+    assert got["conv_layout"]["resolved"] == "NHWC"
+    assert got["conv_layout"]["boundaries"] == "pool5->fc6"
     assert got["arena"] is False and got["update_route"] == "leaf"
     assert got["arena_op_names"] == []
     assert got["optimizer_update_ops"] >= 16     # one fusion a leaf at least

@@ -45,12 +45,11 @@ def _rel_l2(got, want):
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
-def _seeded_params(net, seed):
+def _he_scaled(params, seed):
     """He-scaled weights and small biases in place of the prototxt's
     gaussian(0.01) fillers, under which every prediction is the same
     number whatever a layer in the middle does; the classifiers a fiftieth
     of that, so that the logits stay near 1 and the loss near ln(classes)."""
-    params = net.init(jax.random.PRNGKey(seed))
     for i, (lname, lp) in enumerate(sorted(params.items())):
         key = jax.random.PRNGKey(1000 * seed + i)
         fan_in = int(np.prod(lp["w"].shape[1:]))
@@ -61,6 +60,10 @@ def _seeded_params(net, seed):
             lp["b"] = 0.1 * jax.random.normal(jax.random.fold_in(key, 1),
                                               lp["b"].shape)
     return params
+
+
+def _seeded_params(net, seed):
+    return _he_scaled(net.init(jax.random.PRNGKey(seed)), seed)
 
 
 LAYOUTS = ["NCHW", "NHWC"]
@@ -132,18 +135,20 @@ def _googlenet_cut(text):
     pool, dropout, inner product, loss) moved up. (A whole-plane AVE pool
     of these 784 values sums in the activation dtype: in bf16 on the CPU
     the predictions were 41% off; ROADMAP D18.)"""
-    preamble, *layers = re.split(r"(?m)^(?=layers \{)", text)
-    blocks = {re.search(r'name: "([^"]+)"', b)[1]: b for b in layers}
-    names = list(blocks)
-    body = names[:names.index("inception_3a/output") + 1]
-    pool = blocks["pool5/7x7_s1"].replace(
+    preamble, *blocks = re.split(r"(?m)^(?=layers \{)", text)
+    # by position up to inception_3a (both DATA layers share one name: an
+    # Engine's TRAIN net reads the first), by name for the head
+    names = [re.search(r'name: "([^"]+)"', b)[1] for b in blocks]
+    by_name = dict(zip(names, blocks))
+    pool = by_name["pool5/7x7_s1"].replace(
         'bottom: "inception_5b/output"', 'bottom: "inception_3a/output"')
     # 28 x 28 here, not pool5's 7 x 7: the same 49-tap window, stepped by 7
     pool = pool.replace("kernel_size: 7", "kernel_size: 7 stride: 7")
     assert "inception_3a" in pool and "stride: 7" in pool
     return preamble + "".join(
-        [blocks[n] for n in body] + [pool] + [blocks[n] for n in (
-            "pool5/drop_7x7_s1", "loss3/classifier", "loss3/loss3")])
+        blocks[:names.index("inception_3a/output") + 1] + [pool]
+        + [by_name[n] for n in ("pool5/drop_7x7_s1", "loss3/classifier",
+                                "loss3/loss3")])
 
 
 CONFIGS = {

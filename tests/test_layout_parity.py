@@ -433,13 +433,15 @@ def test_s2d_stem_rewrite_parity_nhwc():
 
 
 def test_conv_layout_auto_resolves_per_backend():
-    """``conv_layout="auto"`` resolves at Net construction: NCHW on TPU
-    (NHWC ran 0.53x in the July-2026 chip A/B despite winning the
-    HLO-transpose count), NHWC on GPU (tensor-core native), NCHW on CPU /
-    unknown backends; explicit overrides pass through untouched."""
+    """``conv_layout="auto"`` resolves at Net construction: NHWC on TPU
+    since PR 55 (conv1's weight gradient leaves the top of AlexNet's step
+    on the v5e: PERF.md section 6; the July-2026 chip A/B's 0.53x predates
+    the LRN and pool kernels that run the same blocks under both plans),
+    NHWC on GPU (tensor-core native), NCHW on CPU / unknown backends;
+    explicit overrides pass through untouched."""
     from poseidon_tpu.numeric import resolve_conv_layout
 
-    assert resolve_conv_layout("auto", backend="tpu") == "NCHW"
+    assert resolve_conv_layout("auto", backend="tpu") == "NHWC"
     assert resolve_conv_layout("auto", backend="gpu") == "NHWC"
     assert resolve_conv_layout("auto", backend="cpu") == "NCHW"
     assert resolve_conv_layout("auto", backend="something_else") == "NCHW"
