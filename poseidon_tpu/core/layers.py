@@ -35,7 +35,7 @@ Shape = Tuple[int, ...]
 LOSS_TYPES = {
     "SOFTMAX_LOSS", "EUCLIDEAN_LOSS", "HINGE_LOSS", "INFOGAIN_LOSS",
     "MULTINOMIAL_LOGISTIC_LOSS", "SIGMOID_CROSS_ENTROPY_LOSS",
-    "CONTRASTIVE_LOSS", "EXIT_LOSS",
+    "CONTRASTIVE_LOSS", "EXIT_LOSS", "WEIGHTED_MEAN_LOSS",
 }
 DATA_SOURCE_TYPES = {"DATA", "IMAGE_DATA", "HDF5_DATA", "WINDOW_DATA", "MEMORY_DATA"}
 
@@ -323,7 +323,14 @@ class AttentionLayer(Layer):
     that wide and the top is (N, S, num_heads * value_head_dim); a FOURTH
     bottom (N, S, Ds) is one key part every head shares: each key head is
     its own Dh - Ds dims of k followed by it (k is then (N, S,
-    num_kv_heads * (Dh - Ds)))."""
+    num_kv_heads * (Dh - Ds))).
+
+    ``rotary_shared`` (with the fourth bottom): the positions are on that
+    shared part, rotated ONCE a token before the heads take it, and on the
+    last Ds dims of every q head, the dims that meet it in the scores;
+    k's own dims and the rest of q pass as they come (DeepSeek-V3's
+    decoupled rotary part: GLM-4.7-Flash's 192 + 64). ``rotary_dims`` is
+    then Ds or unset."""
     TYPE = "ATTENTION"
 
     def setup(self, bottom_shapes):
@@ -360,10 +367,16 @@ class AttentionLayer(Layer):
         if ap.rotary_dims % 2 or not 0 <= ap.rotary_dims <= d_head:
             raise ValueError(f"{self.name}: rotary_dims {ap.rotary_dims} "
                              f"is not an even part of a head of {d_head}")
-        if not ap.rope and ap.rotary_dims:
+        if not ap.rope and (ap.rotary_dims or ap.rotary_shared):
             raise ValueError(f"{self.name}: rope false (no positions) and "
-                             f"rotary_dims {ap.rotary_dims} contradict "
-                             f"each other")
+                             f"rotary_dims {ap.rotary_dims} / rotary_shared "
+                             f"{ap.rotary_shared} contradict each other")
+        if ap.rotary_shared and (not shared or shared % 2
+                                 or ap.rotary_dims not in (0, shared)):
+            raise ValueError(f"{self.name}: rotary_shared rotates the fourth "
+                             f"bottom (an even width, got {shared}) and as "
+                             f"many last dims of every q head; rotary_dims "
+                             f"{ap.rotary_dims} is neither that nor unset")
         if ap.window < 0:
             raise ValueError(f"{self.name}: window {ap.window} is negative "
                              f"(0 = every earlier token)")
@@ -381,7 +394,8 @@ class AttentionLayer(Layer):
                                rotary_dims=ap.rotary_dims,
                                window=ap.window, rope=ap.rope,
                                k_shared=bottoms[3] if len(bottoms) == 4
-                               else None, scale=ap.scale or None)]
+                               else None, scale=ap.scale or None,
+                               rotary_shared=ap.rotary_shared)]
 
 
 class MoELayer(Layer):
@@ -598,23 +612,43 @@ class MoERouterLayer(Layer):
 # to q and k in the latent between their projections and the attention.
 
 def _shift_tokens(x, by: int = 1):
-    """x (N, S, ...) -> x moved ``by`` positions later, zeros in front."""
+    """x (N, S, ...) -> x moved ``by`` positions later, zeros in front
+    (``by`` < 0: earlier, zeros behind)."""
     if by == 0:
         return x
-    pad = [(0, 0), (by, 0)] + [(0, 0)] * (x.ndim - 2)
-    return jnp.pad(x[:, :x.shape[1] - by], pad)
+    rest = [(0, 0)] * (x.ndim - 2)
+    if by < 0:
+        return jnp.pad(x[:, -by:], [(0, 0), (0, -by)] + rest)
+    return jnp.pad(x[:, :x.shape[1] - by], [(0, 0), (by, 0)] + rest)
 
 
 class TokenShiftLayer(Layer):
-    """(N, S, D) -> the same with every token's row replaced by the token
-    before's, zeros at position 0."""
+    """(N, S, ...) -> the same with every token's row replaced by the row
+    ``offset`` positions on, top(t) = bottom(t + offset), zeros where that
+    falls outside the sequence: -1, the default, is the token before's
+    (zeros at position 0); 1 the next token's (zeros at the last). A second
+    top marks the rows that are real: (N, S) f32, 1 where t + offset lies
+    inside the sequence and 0 on the filled ones — what WEIGHTED_MEAN_LOSS
+    takes to leave them out of a mean."""
     TYPE = "TOKEN_SHIFT"
 
     def setup(self, bottom_shapes):
-        return [bottom_shapes[0]]
+        self.offset = self.lp.token_shift_param.offset
+        n, s = bottom_shapes[0][:2]
+        if len(self.lp.top) not in (1, 2) or not 0 < abs(self.offset) < s:
+            raise ValueError(f"{self.name}: TOKEN_SHIFT has 1 or 2 tops and "
+                             f"a non-zero offset inside the sequence of {s}; "
+                             f"got {len(self.lp.top)} tops, offset "
+                             f"{self.offset}")
+        return [bottom_shapes[0], (n, s)][:len(self.lp.top)]
 
     def apply(self, params, bottoms, ctx):
-        return [_shift_tokens(bottoms[0])]
+        x = bottoms[0]
+        tops = [_shift_tokens(x, -self.offset)]
+        if len(self.lp.top) == 2:
+            tops.append(_shift_tokens(jnp.ones(x.shape[:2], jnp.float32),
+                                      -self.offset))
+        return tops
 
 
 class _CCALayer(Layer):
@@ -1342,6 +1376,27 @@ class ExitLossLayer(Layer):
         return [loss] + [mass[t] for t in range(len(self.lp.top) - 1)]
 
 
+class WeightedMeanLossLayer(_ScalarTopLayer):
+    """Per-position losses (N, S) and weights (N, S) -> sum(loss x weight) /
+    sum(weight), a scalar in f32: with TOKEN_SHIFT's second top as the
+    weights, the mean over the positions that have a target (a prediction
+    module's S - 1 of S), the others left out and not given a made-up one.
+    The weights take no gradient."""
+    TYPE = "WEIGHTED_MEAN_LOSS"
+
+    def setup(self, bottom_shapes):
+        if len(bottom_shapes) != 2 \
+                or tuple(bottom_shapes[0]) != tuple(bottom_shapes[1]):
+            raise ValueError(f"{self.name}: WEIGHTED_MEAN_LOSS takes losses "
+                             f"and weights of one shape, got {bottom_shapes}")
+        return [()]
+
+    def apply(self, params, bottoms, ctx):
+        per, w = (b.astype(jnp.float32) for b in bottoms)
+        w = lax.stop_gradient(w)
+        return [jnp.sum(per * w) / jnp.sum(w)]
+
+
 class EuclideanLossLayer(_ScalarTopLayer):
     TYPE = "EUCLIDEAN_LOSS"
 
@@ -1509,7 +1564,7 @@ REGISTRY: Dict[str, type] = {
         AbsValLayer, PowerLayer, ThresholdLayer, DropoutLayer, FlattenLayer,
         ConcatLayer, SliceLayer, SplitLayer, EltwiseLayer, MVNLayer,
         SilenceLayer, SoftmaxLayer, ArgMaxLayer, SoftmaxLossLayer,
-        SiLUGateLayer, SoftmaxNLLLayer, ExitLossLayer,
+        SiLUGateLayer, SoftmaxNLLLayer, ExitLossLayer, WeightedMeanLossLayer,
         EuclideanLossLayer, HingeLossLayer, MultinomialLogisticLossLayer,
         SigmoidCrossEntropyLossLayer, InfogainLossLayer, ContrastiveLossLayer,
         AccuracyLayer, DataLayer, ImageDataLayer, HDF5DataLayer,

@@ -483,8 +483,9 @@ class Net:
                             f"{ap.value_head_dim}")
                 if len(layer.lp.bottom) == 4:
                     # latent attention: the one key part all heads share
-                    arm += (f"; k_pe repeated x"
-                            f"{ap.num_kv_heads or ap.num_heads}")
+                    arm += ("; k_pe rotated once, joined x"
+                            if ap.rotary_shared else "; k_pe repeated x") \
+                        + f"{ap.num_kv_heads or ap.num_heads}"
             elif layer.TYPE == "KDA_SCAN":
                 from ..ops.kda import kda_route
                 what = "kda"

@@ -153,7 +153,8 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
                    rotary_dims: int = 0, window: int = 0,
                    rope: bool = True,
                    k_shared: Optional[jax.Array] = None,
-                   scale: Optional[float] = None) -> jax.Array:
+                   scale: Optional[float] = None,
+                   rotary_shared: bool = False) -> jax.Array:
     """q (B, S, D), k and v (B, S, Dkv) -> (B, S, D): q split into
     ``n_heads`` heads, k and v into ``n_kv_heads`` of the same width (0 =
     ``n_heads``, Dkv = D), rotary positions on q and k, causal
@@ -180,15 +181,19 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     (B, S, n_heads * that)). ``k_shared`` (B, S, Ds): a key part that every
     head shares; k's heads are then d_head - Ds wide and each key head is
     its own dims followed by it, repeated to the heads before the kernel
-    as key-value heads are."""
+    as key-value heads are. ``rotary_shared``: the positions are on that
+    shared part, rotated once a token (Ds lanes) before the heads take it,
+    and on the LAST Ds dims of every q head; nothing else rotates."""
     b, s, d = q.shape
     d_head = d // n_heads
     n_kv = n_kv_heads or n_heads
     rot = rotary_dims or d_head
+    if rotary_shared:
+        rot = k_shared.shape[-1]
     if flash_operand_form(s, d_head, v.shape[-1] // n_kv)[0]:
         return _rope_attention_lanes(q, k, v, n_heads, n_kv, rot,
                                      rope_theta if rope else None, window,
-                                     k_shared, scale)
+                                     k_shared, scale, rotary_shared)
 
     def heads(t, n):
         return t.reshape(b, s, n, -1).swapaxes(1, 2)
@@ -210,8 +215,17 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
         return jnp.concatenate([t, jnp.broadcast_to(
             k_shared[:, None], (b, n_kv, s, k_shared.shape[-1]))], axis=-1)
 
-    q, k, v = rotate(heads(q, n_heads)), rotate(key_heads(k)), \
-        heads(v, n_kv)
+    if rotary_shared:
+        # the one shared part turns before the heads take it; of q the dims
+        # that meet it, a head's last
+        k_shared = apply_rope(k_shared, cos, sin)
+        q = heads(q, n_heads)
+        q = jnp.concatenate([q[..., :d_head - rot], apply_rope(
+            q[..., d_head - rot:], cos, sin)], axis=-1)
+        k = key_heads(k)
+    else:
+        q, k = rotate(heads(q, n_heads)), rotate(key_heads(k))
+    v = heads(v, n_kv)
     if n_kv != n_heads:
         k, v = (jnp.repeat(t, n_heads // n_kv, axis=1) for t in (k, v))
     att = maybe_flash_attention(q, k, v, causal=True, window=window,
@@ -219,11 +233,12 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     return att.swapaxes(1, 2).reshape(b, s, n_heads * v.shape[-1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
 def _rope_lanes(x: jax.Array, n: int, rot: int, theta: float,
-                turn: int = 1) -> jax.Array:
+                turn: int = 1, at: int = 0) -> jax.Array:
     """``apply_rope`` on x (B, S, n·Dh) as it lies, its ``n`` heads side by
-    side along the lanes and the first ``rot`` dims of each rotating, by
+    side along the lanes and the ``rot`` dims of each from dim ``at`` on
+    (0: its first) rotating, by
     ``turn`` (1, or -1: the other way, which is the rotation's transpose
     and so its backward). Nothing is reshaped to (B, S, n, Dh), which under
     the TPU's (8, 128) tiling is another layout and a copy of x each way:
@@ -243,9 +258,10 @@ def _rope_lanes(x: jax.Array, n: int, rot: int, theta: float,
     # a head's lanes: frequency i serves dims i and i + rot/2, none past
     # the rotating dims (angle 0: cos 1, sin 0, the dim passes);
     # rotate_half's sign goes with the sine
-    freq = np.tile(np.concatenate([inv, inv, np.zeros(d_head - rot)]), n)
-    sign = np.tile(np.concatenate([-np.ones(half), np.ones(half),
-                                   np.zeros(d_head - rot)]), n) * turn
+    rest = [np.zeros(at), np.zeros(d_head - rot - at)]
+    freq = np.tile(np.concatenate([rest[0], inv, inv, rest[1]]), n)
+    sign = np.tile(np.concatenate([rest[0], -np.ones(half), np.ones(half),
+                                   rest[1]]), n) * turn
     table = lambda fn, pos, by=1.0: jnp.asarray(
         fn(pos[:, None] * freq) * by, jnp.float32)
     hi, lo = np.arange(0, s, period), np.arange(period)
@@ -258,8 +274,10 @@ def _rope_lanes(x: jax.Array, n: int, rot: int, theta: float,
     # compiler must not move the reshapes inward: against (B, S, n·Dh) the
     # tables do not broadcast, and it builds each at x's size instead
     x = lax.optimization_barrier(x).reshape(b, s // period, period, width)
-    first = lax.broadcasted_iota(jnp.int32, (1, 1, 1, width), 3) \
-        % d_head < half
+    first = lax.broadcasted_iota(jnp.int32, (1, 1, 1, width), 3) % d_head
+    # the lanes whose partner is to their right: the rotating dims' first
+    # half (a dim outside them meets sine 0 whichever it is handed)
+    first = first < half if not at else (first >= at) & (first < at + half)
     right = jnp.roll(x, half, axis=-1)                       # x[l - half]
     partner = jnp.where(first, jnp.roll(right, -rot, axis=-1), right)
     x32, p32 = x.astype(jnp.float32), partner.astype(jnp.float32)
@@ -271,12 +289,12 @@ def _rope_lanes(x: jax.Array, n: int, rot: int, theta: float,
     return lax.optimization_barrier(out.astype(x.dtype)).reshape(b, s, width)
 
 
-def _rope_lanes_fwd(x, n, rot, theta, turn):
-    return _rope_lanes(x, n, rot, theta, turn), None
+def _rope_lanes_fwd(x, n, rot, theta, turn, at):
+    return _rope_lanes(x, n, rot, theta, turn, at), None
 
 
-def _rope_lanes_bwd(n, rot, theta, turn, _, g):
-    return (_rope_lanes(g, n, rot, theta, -turn),)
+def _rope_lanes_bwd(n, rot, theta, turn, at, _, g):
+    return (_rope_lanes(g, n, rot, theta, -turn, at),)
 
 
 _rope_lanes.defvjp(_rope_lanes_fwd, _rope_lanes_bwd)
@@ -294,19 +312,34 @@ def _repeat_lanes(t: jax.Array, n: int, times: int) -> jax.Array:
 
 def _rope_attention_lanes(q, k, v, n_heads: int, n_kv: int, rot: int,
                           theta: Optional[float], window: int, k_shared,
-                          scale: Optional[float] = None):
+                          scale: Optional[float] = None,
+                          rotary_shared: bool = False):
     """``rope_attention`` where a head is whole vregs of lanes: q, k and v
     stay (B, S, n·Dh) from the projections to the kernels, which address a
     head as a lane block, and the result is (B, S, n_heads·Dv) as the
-    out-projection reads it. ``theta`` None: no positions."""
-    b, s, _ = q.shape
+    out-projection reads it. ``theta`` None: no positions.
+
+    A shared key part is joined to the heads ALONG THE LANES: the kernels'
+    k is written once, each head's own dims followed by the shared part,
+    one concatenate of lane slices as ``_repeat_lanes``' is (its transpose,
+    the backward, sums the heads' slices of dk into the shared part's
+    gradient in the same pass). The write itself stays: the kernels read a
+    key head as one lane block of k (a K index map that reads the shared
+    part from its own array would save it, ROADMAP M4). With
+    ``rotary_shared`` (GLM-4.7-Flash: 20 heads of [192 ; 64] / 256) that
+    part rotates first, ``rot`` lanes a token, and q's heads turn their
+    last ``rot`` dims where they lie; otherwise q and the joined k turn
+    their heads' first ``rot`` dims."""
+    if rotary_shared:
+        k_shared = _rope_lanes(k_shared, 1, rot, theta)
+        q = _rope_lanes(q, n_heads, rot, theta, 1,
+                        q.shape[-1] // n_heads - rot)
     if k_shared is not None:
-        # through four axes, so a copy of k on the TPU: no configuration
-        # has a shared key part beside lane-aligned heads (Kimi's are 192)
-        k = jnp.concatenate([k.reshape(b, s, n_kv, -1), jnp.broadcast_to(
-            k_shared[:, :, None], (b, s, n_kv, k_shared.shape[-1]))],
-            axis=-1).reshape(b, s, -1)
-    if theta is not None:
+        own = k.shape[-1] // n_kv
+        k = jnp.concatenate(
+            [part for i in range(n_kv)
+             for part in (k[..., i * own:(i + 1) * own], k_shared)], axis=-1)
+    if theta is not None and not rotary_shared:
         q, k = _rope_lanes(q, n_heads, rot, theta), \
             _rope_lanes(k, n_kv, rot, theta)
     if n_kv != n_heads:

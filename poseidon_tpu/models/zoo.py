@@ -6,6 +6,15 @@ same architectures are constructed programmatically as ``NetParameter``s (the
 public, well-known LeNet / CIFAR-10-quick / AlexNet / GoogLeNet definitions);
 ``to_prototxt`` round-trips them to text for zoo compatibility. Each builder
 takes the batch size so the same definition serves train/test/bench shapes.
+
+Beside them, the token models that train through the same ``Net`` (each
+builder with no arguments writes its whole published model; the committed
+``examples/lm/*_train.prototxt`` are the cut their headers state):
+``olmoe``, ``ouro``, ``zaya1``, ``trinity_mini``, ``kimi_linear``,
+``smallthinker``, ``olmo_hybrid``, ``granite_hybrid`` and ``glm_flash``
+(GLM-4.7-Flash: latent attention whose rotary part rotates in every layer
+and a multi-token-prediction module that shares the embedding and the
+head).
 """
 
 from __future__ import annotations
@@ -1190,6 +1199,197 @@ def kimi_linear(batch: int = 1,
     # the mean over positions: the exit-weighted loss of ONE pass
     layers.append(LayerParameter(
         name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    return NetParameter(name=name, layers=layers)
+
+
+def glm_flash(batch: int = 1, n_layers: int = 47, held: int = 0,
+              vocab: int = 154880, mtp: int = 1,
+              source: str = "examples/lm/glm_4_7_flash_tokens.txt",
+              dense_layers: int = 1, hidden: int = 2048, heads: int = 20,
+              q_rank: int = 768, kv_rank: int = 512, nope_dim: int = 192,
+              rope_dim: int = 64, v_dim: int = 256, dense_width: int = 10240,
+              experts: int = 64, top_k: int = 4, held_first: int = 0,
+              expert_width: int = 1536, shared_width: int = 1536,
+              route_scale: float = 1.8, bias_update_rate: float = 0.001,
+              rope_theta: float = 1e6, eps: float = 1e-5,
+              mtp_weight: float = 0.3, init_std: float = 0.02,
+              name: str = "GLM-4.7-Flash") -> NetParameter:
+    """GLM-4.7-Flash (config.json of zai-org/GLM-4.7-Flash,
+    ``glm4_moe_lite``, 30B-A3B; the DeepSeek-V3 block, arXiv:2412.19437):
+    every layer is latent attention and an FFN, pre-norm,
+
+        h = x + MLA(N1(x));  y = h + FFN(N2(h))
+
+    MLA: a query latent of ``q_rank`` (``l<i>_mla_qa``) takes an RMSNorm
+    (``l<i>_mla_qnorm``) and gives ``heads`` heads of [``nope_dim`` ;
+    ``rope_dim``] (``l<i>_mla_qb``); one projection to a latent of
+    ``kv_rank`` and a ``rope_dim``-wide key part that all heads share
+    (``l<i>_mla_kva``, split by ``l<i>_mla_kva_split``); the normed latent
+    (``l<i>_mla_kvnorm``) gives each head ``nope_dim`` of key
+    (``l<i>_mla_kvb_k``) and ``v_dim`` of value (``l<i>_mla_kvb_v``: the
+    published one matrix, its rows sorted into keys and values); causal
+    attention over keys [own part ; shared part] with rotate-half rotary
+    positions on the SHARED part, turned once a token, and on the last
+    ``rope_dim`` dims of every q head, nothing else (``l<i>_mla_attn``,
+    ``rotary_shared``); ``l<i>_mla_o``.
+
+    FFN: the first ``dense_layers`` layers a SiLU-gated MLP of
+    ``dense_width``; the others a sigmoid router with a selection bias
+    over ``experts`` (``l<i>_router``: the ``top_k`` of score + bias
+    chosen, weighed by the unbiased scores over their sum times
+    ``route_scale``, the bias balanced by the layer), ``l<i>_moe`` holding
+    ``held`` of the experts from ``held_first`` on (0 = all: with fewer the
+    net is one rank's share of an expert-parallel model) and an always-on
+    shared expert (``l<i>_shared_*``) added unweighted, as
+    ``trinity_mini``'s and ``kimi_linear``'s.
+
+    The head is untied (``final_norm``, ``lm_head``, ``lm_nll``,
+    ``lm_loss``). With ``mtp`` 1 (0: none), a multi-token-prediction module
+    of depth 1 (DeepSeek-V3 section 2.2) stands between the last layer and
+    the head: the embedding of token t+1 (``mtp_embed``: the main table,
+    looked up with the targets) and the last layer's output BEFORE the
+    final norm take an RMSNorm each (``mtp_enorm``, ``mtp_hnorm``), side by
+    side (``mtp_cat``, the embedding first) through ``mtp_eh``
+    (2 ``hidden`` -> ``hidden``) into one more sparse layer of its own
+    weights (``mtp_mla_*``, ``mtp_router``, ``mtp_moe``, ``mtp_shared_*``)
+    at the same positions; after the main head, ``mtp_snorm`` and
+    ``mtp_head`` (the main head's matrix) give the logits of token t+2,
+    ``mtp_shift`` the targets one on with the last position marked,
+    ``mtp_nll`` and ``mtp_loss`` the mean over the S - 1 positions that
+    have a second-next token, weighted ``mtp_weight`` in the objective.
+    ``embed`` / ``mtp_embed`` share ``tok_w`` and ``lm_head`` / ``mtp_head``
+    share ``head_w``: one array each, its gradient the sum of both users'.
+    The main head between the module's block and the module's head makes
+    them two ``/mtp_/`` remat units.
+
+    Gains and the selection biases carry decay_mult 0, every matrix 1."""
+    from ..proto.messages import (AttentionParameter, ConcatParameter,
+                                  EltwiseParameter, EmbedParameter,
+                                  HDF5DataParameter, MoEParameter,
+                                  RMSNormParameter, SliceParameter,
+                                  TokenShiftParameter)
+    if mtp not in (0, 1):
+        raise ValueError(f"glm_flash: mtp {mtp} is neither 0 nor 1 (the "
+                         f"published depth)")
+    w = gaussian(init_std)
+    layers: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+    no_decay = ParamSpec(lr_mult=1.0, decay_mult=0.0)
+
+    def norm(lname, bottom, top):
+        layers.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[no_decay], rms_norm_param=RMSNormParameter(eps=eps)))
+
+    def proj(lname, bottom, top, n_out, spec=()):
+        layers.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            param=list(spec), inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2, weight_filler=w)))
+
+    def add(lname, a, b, top):
+        layers.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation="SUM")))
+
+    def embed(lname, bottom, top):
+        layers.append(LayerParameter(
+            name=lname, type="EMBED", bottom=[bottom], top=[top],
+            param=[ParamSpec(name="tok_w")] if mtp else [],
+            embed_param=EmbedParameter(input_dim=vocab, num_output=hidden,
+                                       weight_filler=w)))
+
+    def gated_mlp(p, bottom, top, n_mid):
+        proj(p + "gate", bottom, p + "g", n_mid)
+        proj(p + "up", bottom, p + "u", n_mid)
+        layers.append(LayerParameter(
+            name=p + "act", type="SILU_GATE", bottom=[p + "g", p + "u"],
+            top=[p + "a"]))
+        proj(p + "down", p + "a", top, hidden)
+
+    def block(p, x, sparse):
+        norm(p + "attn_norm", x, p + "a")
+        proj(p + "mla_qa", p + "a", p + "cq", q_rank)
+        norm(p + "mla_qnorm", p + "cq", p + "cqn")
+        proj(p + "mla_qb", p + "cqn", p + "q", heads * (nope_dim + rope_dim))
+        proj(p + "mla_kva", p + "a", p + "kva", kv_rank + rope_dim)
+        layers.append(LayerParameter(
+            name=p + "mla_kva_split", type="SLICE", bottom=[p + "kva"],
+            top=[p + "c", p + "kpe"],
+            slice_param=SliceParameter(slice_dim=2, slice_point=[kv_rank])))
+        norm(p + "mla_kvnorm", p + "c", p + "cn")
+        proj(p + "mla_kvb_k", p + "cn", p + "kn", heads * nope_dim)
+        proj(p + "mla_kvb_v", p + "cn", p + "v", heads * v_dim)
+        layers.append(LayerParameter(
+            name=p + "mla_attn", type="ATTENTION",
+            bottom=[p + "q", p + "kn", p + "v", p + "kpe"], top=[p + "att"],
+            attention_param=AttentionParameter(
+                num_heads=heads, rope_theta=rope_theta,
+                value_head_dim=v_dim, rotary_shared=True)))
+        proj(p + "mla_o", p + "att", p + "ao", hidden)
+        add(p + "res1", x, p + "ao", p + "h")
+        norm(p + "ffn_norm", p + "h", p + "u")
+        if not sparse:
+            gated_mlp(p + "ffn_", p + "u", p + "f", dense_width)
+        else:
+            moe = dict(num_experts=experts, top_k=top_k,
+                       expert_width=expert_width, score_func="sigmoid",
+                       route_scale=route_scale,
+                       bias_update_rate=bias_update_rate, weight_filler=w)
+            layers.append(LayerParameter(
+                name=p + "router", type="MOE_ROUTER", bottom=[p + "u"],
+                top=[p + "gates", p + "bias_next", p + "bias_max_abs"],
+                param=[ParamSpec(), no_decay],
+                moe_param=MoEParameter(**moe)))
+            layers.append(LayerParameter(
+                name=p + "moe", type="MOE", bottom=[p + "u", p + "gates"],
+                top=[p + "m", p + "expert_load", p + "dropped",
+                     p + "held_share"],
+                moe_param=MoEParameter(num_held=held, held_first=held_first,
+                                       **moe)))
+            gated_mlp(p + "shared_", p + "u", p + "s", shared_width)
+            add(p + "moe_sum", p + "m", p + "s", p + "f")
+        add(p + "res2", p + "h", p + "f", p + "y")
+        return p + "y"
+
+    embed("embed", "tokens", "x0")
+    x = "x0"
+    for i in range(n_layers):
+        x = block(f"l{i}_", x, sparse=i >= dense_layers)
+    if mtp:
+        # token t+1 is the targets' token t: no shift before the lookup
+        embed("mtp_embed", "targets", "mtp_e")
+        norm("mtp_enorm", "mtp_e", "mtp_en")
+        norm("mtp_hnorm", x, "mtp_hn")
+        layers.append(LayerParameter(
+            name="mtp_cat", type="CONCAT", bottom=["mtp_en", "mtp_hn"],
+            top=["mtp_eh_in"], concat_param=ConcatParameter(concat_dim=2)))
+        proj("mtp_eh", "mtp_eh_in", "mtp_z", hidden)
+        z = block("mtp_", "mtp_z", sparse=True)
+    head = [ParamSpec(name="head_w")] if mtp else []
+    norm("final_norm", x, "xf")
+    proj("lm_head", "xf", "logits", vocab, head)
+    layers.append(LayerParameter(
+        name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
+        top=["nll"]))
+    # the mean over positions: the exit-weighted loss of ONE pass
+    layers.append(LayerParameter(
+        name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    if mtp:
+        norm("mtp_snorm", z, "mtp_zf")
+        proj("mtp_head", "mtp_zf", "mtp_logits", vocab, head)
+        layers.append(LayerParameter(
+            name="mtp_shift", type="TOKEN_SHIFT", bottom=["targets"],
+            top=["mtp_targets", "mtp_real"],
+            token_shift_param=TokenShiftParameter(offset=1)))
+        layers.append(LayerParameter(
+            name="mtp_nll", type="SOFTMAX_NLL",
+            bottom=["mtp_logits", "mtp_targets"], top=["mtp_nll_pos"]))
+        layers.append(LayerParameter(
+            name="mtp_loss", type="WEIGHTED_MEAN_LOSS",
+            bottom=["mtp_nll_pos", "mtp_real"], top=["mtp_loss"],
+            loss_weight=[mtp_weight]))
     return NetParameter(name=name, layers=layers)
 
 

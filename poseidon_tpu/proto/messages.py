@@ -319,6 +319,13 @@ class AttentionParameter:
     # attention_multiplier 1/64 at heads of 64). The default leaves every
     # net that does not set it the lowered step it had.
     scale: float = 0.0
+    # latent attention's decoupled positions: the rotation is on the SHARED
+    # key part (the fourth bottom, rotated once a token before the heads
+    # take it) and on the LAST ``rotary_dims`` of every q head, the dims
+    # that meet it in the scores (0 = the shared part's width, and nothing
+    # else is allowed: the two rotate together); k's own dims and the rest
+    # of q pass. False: ``rotary_dims`` are a head's first dims, as ever.
+    rotary_shared: bool = False
 
 
 @dataclass
@@ -400,6 +407,15 @@ class ExitLossParameter:
     """EXIT_LOSS: the weight of the exit distribution's entropy bonus (the
     looped LM's stage-I objective, arXiv:2510.25741)."""
     entropy_weight: float = 0.0
+
+
+@dataclass
+class TokenShiftParameter:
+    """TOKEN_SHIFT: top(t) = bottom(t + ``offset``) along the sequence,
+    zeros where t + offset falls outside it. -1 (the default) is the token
+    before (a causal look-back); 1 is the next token (a prediction module's
+    targets: the second-next token is the targets' next)."""
+    offset: int = -1
 
 
 @dataclass
@@ -520,7 +536,7 @@ V2_TYPE_TO_V1 = {
     "CCAQKMean": "CCA_QKMEAN", "CCAQKNorm": "CCA_QKNORM",
     "MoERouter": "MOE_ROUTER", "ShortConv": "SHORT_CONV",
     "L2Norm": "L2_NORM", "KDADecay": "KDA_DECAY", "KDAScan": "KDA_SCAN",
-    "SSDScan": "SSD_SCAN",
+    "SSDScan": "SSD_SCAN", "WeightedMeanLoss": "WEIGHTED_MEAN_LOSS",
 }
 V1_TYPES = set(V2_TYPE_TO_V1.values()) | {"NONE"}
 
@@ -584,6 +600,7 @@ class LayerParameter:
     exit_loss_param: ExitLossParameter = field(default_factory=ExitLossParameter)
     cca_param: CCAParameter = field(default_factory=CCAParameter)
     kda_param: KDAParameter = field(default_factory=KDAParameter)
+    token_shift_param: TokenShiftParameter = field(default_factory=TokenShiftParameter)
     blob_mode: str = "GLOBAL"  # Poseidon extension on LayerParameter level
 
     def canonical_type(self) -> str:

@@ -855,6 +855,80 @@ def test_smallthinker_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(
         assert 0.60 * 16.9 < got["total_gb"] < 0.72 * 16.9
 
 
+# The full-width GLM-4.7-Flash train step (examples/lm/glm_4_7_flash_*:
+# published layers 0-4, the dense one and four sparse ones, 8 of 64 experts
+# held, an eighth of the untied vocabulary, and the prediction module that
+# shares the table and the head) as `train --bf16 --remat <the solver header's
+# flags>` builds it at sequences of 8,192, for one abstract v5e chip: the
+# compiler's memory accounting that fixed the cell's batch
+# (benchmark/cells/glm_flash.e8of64.pack8k.json), at the batch chosen, one
+# sequence fewer and one more.
+_GLM_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 2 + {deeper}, 8192, 0").replace(
+    "ouro_2_6b_solver", "glm_4_7_flash_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers)')
+assert _GLM_STEP.count("glm_4_7_flash") == 1 and "ouro_2" not in _GLM_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("more", [-1, 0, 1])
+def test_glm_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
+    """At two sequences of 8,192 the step with one checkpoint a layer, two
+    around the prediction module and one around the head is under 85% of
+    the 16.9 GB the compiler allows (PR 22's sizing rule); at three it is
+    over. All six latent-attention blocks' three flash kernels run at heads
+    of 256 / 256, token-major, in 1024 x 1024 tiles (Mosaic compiles them
+    for the v5e here: every operand tile twice as wide as any other
+    cell's), the shared key part rotated once and joined to the 20 heads
+    along the lanes; each MOE layer's held rows run in chunks of 8,192 under
+    one loop a pass; the embedding and the head are ONE leaf each."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _GLM_STEP.format(repo=REPO, deeper=more)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next cut
+    # six blocks: layers 0-4 and the module's
+    assert got["depth"] == 6 and got["parameters"] == 706_518_848
+    # embed, head, final norm; a block: 2 norms, 6 projections, 2 latent
+    # gains; the dense layer's 3; a sparse block's router 2, 3 stacks,
+    # shared 3; the module's 2 norms, W_eh, head norm
+    assert got["leaves"] == 3 + 6 * 10 + 3 + 5 * 8 + 4
+    # five layers, the module's block, the module's head, the head
+    assert got["segments"] == 5 + 2 + 1
+    rows = 8192 * 4 * (2 + more)
+    assert got["routes"] == [
+        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
+        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "head; operands token-major (B,S,HxD)); k_pe rotated once, joined "
+        "x20",
+        f"grouped_matmul=ragged_dot; held rows: chunks of "
+        f"{max(8192, 4096 * (2 + more))} of {rows}"]
+    # 4 flash calls a block (forward, its replay, dq, dkv); a sparse
+    # block's held arm is one loop a pass: 4 calls in the forward's, 10 in
+    # the backward's
+    assert got["pallas_custom_calls"] == 4 * 6 + 14 * 5
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    if more > 0:
+        # 15.45 = 91.4% (PR 56; temporaries 6.98 GB)
+        assert got["total_gb"] > 0.85 * 16.9
+    elif more == 0:
+        # 13.50 = 79.9% (PR 56; temporaries 5.02 GB): what the cell's `why`
+        # quotes
+        assert 0.72 * 16.9 < got["total_gb"] < 0.85 * 16.9
+    else:
+        # 11.62 = 68.7% (PR 56; temporaries 3.14 GB)
+        assert 0.60 * 16.9 < got["total_gb"] < 0.72 * 16.9
+
+
 # The LRN kernels at the CNN cells' norm layers (AlexNet's two at batch 512,
 # GoogLeNet's two at 128: batch-minor) and at GoogLeNet's published batch 32
 # (channel-minor), forward and backward, through Mosaic; then a stand-in for

@@ -2,7 +2,8 @@
 matmuls and the routers (OLMoE, Ouro, ZAYA1; since PR 41 Trinity-Mini and
 Kimi-Linear, whose hashes are PR 43's own: it changed their held arm; since
 PR 48 Olmo-Hybrid, which ADAPTED ops/kda.py and the KDA layers and left
-Kimi's text as it was, to the byte) trace
+Kimi's text as it was, to the byte; since PR 56 GLM-4.7-Flash, which added
+``rotary_shared`` beside them and left all six texts as they were) trace
 to the
 program they traced to before Trinity's window, sigmoid router and per-head
 norm, and before Kimi-Linear's two head widths in the flash kernels,
@@ -59,6 +60,14 @@ BUILD = {
         batch=N, n_layers=4, hidden=128, heads=2, heads_held=1,
         key_head_dim=16, value_head_dim=32, attn_head_dim=128, ffn_width=64,
         vocab=128),
+    # the dense layer, a sparse layer and the prediction module: three
+    # latent-attention blocks of 256 / 256 (token-major), the shared key
+    # part rotated once and joined along the lanes (``rotary_shared``)
+    "glm": lambda: zoo.glm_flash(
+        batch=N, n_layers=2, held=2, vocab=128, hidden=64, heads=2,
+        q_rank=32, kv_rank=32, nope_dim=192, rope_dim=64, v_dim=256,
+        dense_width=64, experts=16, top_k=2, expert_width=32,
+        shared_width=32),
 }
 PARENT = {       # sha256 of the text, its length, its pallas_call equations
     # PR 47's own, all four: it meant to change them. Every ATTENTION layer
@@ -84,6 +93,15 @@ PARENT = {       # sha256 of the text, its length, its pallas_call equations
     # ops/kda.py, ops/kda_pallas.py, the KDA layers and zoo.olmo_hybrid
     "olmo_hybrid": ("9b956fadf0457a8b3421d6009a1539b423ff4f5881b9e2703183d7"
                     "efd567532f", 825485, 9),
+    # PR 56's own, the configuration's first: 3 flash calls a block, three
+    # blocks. Moves with ``_rope_attention_lanes``' rotary_shared arm,
+    # TOKEN_SHIFT's mark, WEIGHTED_MEAN_LOSS, zoo.glm_flash and whatever the
+    # five above move with; PR 56 left all of THEIRS as they were, which is
+    # its proof that a net that does not ask for ``rotary_shared`` (Kimi's
+    # ``rope: false`` with a shared key part; the four that rotate a head's
+    # first dims) keeps the program it had
+    "glm": ("66e47bc26f19cb1dd44b7144a85f5731ccc4674eb6574dd373edeeb0293339"
+            "57", 258167, 9),
 }
 
 
@@ -104,7 +122,7 @@ def traced(name: str) -> str:
 # that asks for them they are the identity, so the hashes above are taken
 # with the tags out, and what the tags add is counted.
 NAMES = {"olmoe": 2, "ouro": 4, "zaya": 4, "trinity": 4, "kimi": 8,
-         "olmo_hybrid": 8}
+         "olmo_hybrid": 8, "glm": 6}
 
 
 @pytest.mark.parametrize("name", sorted(BUILD))

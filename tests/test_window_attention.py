@@ -332,3 +332,126 @@ def test_rope_along_the_lanes_is_apply_rope(s, n, d, rot):
     np.testing.assert_allclose(
         tr._rope_lanes(xb, n, rot, 1e4).astype(jnp.float32),
         by_head(xb).astype(jnp.float32), rtol=0, atol=2e-2)
+
+
+# --------------------------------------------------------------------------- #
+# rotary_shared (latent attention's decoupled positions): the shared key
+# part turns once a token, q's heads turn their LAST dims; against a
+# reference written head by head
+# --------------------------------------------------------------------------- #
+
+# name: (heads, Dh, Dv, shared = rotating dims, S)
+ROTARY_SHARED_CASES = {
+    "head_major": (4, 24, 16, 8, 32),          # narrow heads: transposed
+    "token_major": (2, 256, 256, 64, 64),      # GLM's 192 + 64 / 256
+    "token_major_values_narrow": (3, 128, 128, 32, 192),   # periods of 64
+}
+
+
+def _per_head_rotary_shared(q, k, v, kpe, h, theta):
+    """Head by head, nothing shared in code with ``rope_attention``: the
+    angle of position t and pair (j, j + R/2) is t theta^(-2j/R)."""
+    b, s, _ = q.shape
+    r = kpe.shape[-1]
+    ang = np.arange(s)[:, None] * theta ** (-np.arange(0, r, 2) / r)
+    cos, sin = jnp.asarray(np.cos(ang), q.dtype), jnp.asarray(np.sin(ang),
+                                                              q.dtype)
+
+    def turn(x):                                   # (B, S, R)
+        a, c = x[..., :r // 2], x[..., r // 2:]
+        return jnp.concatenate([a * cos - c * sin, c * cos + a * sin], -1)
+
+    d, own, dv = q.shape[-1] // h, k.shape[-1] // h, v.shape[-1] // h
+    outs = []
+    for i in range(h):
+        qh = q[..., i * d:(i + 1) * d]
+        qh = jnp.concatenate([qh[..., :d - r], turn(qh[..., d - r:])], -1)
+        kh = jnp.concatenate([k[..., i * own:(i + 1) * own], turn(kpe)], -1)
+        scores = jnp.einsum("bqd,bkd->bqk", qh, kh) / np.sqrt(d)
+        scores = jnp.where(np.tril(np.ones((s, s), bool)), scores, -jnp.inf)
+        outs.append(jax.nn.softmax(scores, -1) @ v[..., i * dv:(i + 1) * dv])
+    return jnp.concatenate(outs, -1)
+
+
+@pytest.mark.parametrize("kernels", ["dense", "flash"])
+@pytest.mark.parametrize("case", sorted(ROTARY_SHARED_CASES))
+def test_rotary_shared_equals_a_per_head_reference(case, kernels,
+                                                   monkeypatch):
+    """The rotation on the shared key part and on the matching tail of
+    every q head, on the head-major and the token-major route, with the
+    dense op and the interpreted kernels: outputs and all four operands'
+    gradients (the shared part's is the sum over heads, through one
+    rotation) equal the per-head reference's."""
+    from poseidon_tpu.models import transformer as tr
+    h, d, dv, shared, s = ROTARY_SHARED_CASES[case]
+    b, theta = 2, 1e6
+    key = jax.random.split(jax.random.PRNGKey(len(case)), 5)
+    q = jax.random.normal(key[0], (b, s, h * d))
+    k = jax.random.normal(key[1], (b, s, h * (d - shared)))
+    v = jax.random.normal(key[2], (b, s, h * dv))
+    kpe = jax.random.normal(key[3], (b, s, shared))
+    co = jax.random.normal(key[4], (b, s, h * dv))
+    forms = []
+    if kernels == "flash":
+        def att(q_, k_, v_, causal, scale=None, window=None, heads=None):
+            forms.append(heads)
+            return pk.flash_attention(q_, k_, v_, causal, scale, 32, 16,
+                                      True, window, heads)
+        monkeypatch.setattr(tr, "maybe_flash_attention", att)
+    assert tr.flash_operand_form(s, d, dv)[0] == case.startswith("token")
+    got, vjp = jax.vjp(lambda *ops: tr.rope_attention(
+        *ops[:3], n_heads=h, rope_theta=theta, k_shared=ops[3],
+        rotary_shared=True), q, k, v, kpe)
+    want, want_vjp = jax.vjp(lambda *ops: _per_head_rotary_shared(
+        *ops, h, theta), q, k, v, kpe)
+    if kernels == "flash":
+        assert forms == [h if case.startswith("token") else None]
+    np.testing.assert_allclose(got, want, rtol=3e-5, atol=3e-5)
+    for a, w in zip(vjp(co), want_vjp(co)):
+        assert a.shape == w.shape
+        np.testing.assert_allclose(a, w, rtol=3e-5, atol=3e-5)
+
+
+def test_rotary_shared_is_not_the_first_dims_rotation():
+    """What a net got before ``rotary_shared`` from ``rotary_dims`` = the
+    shared width (a head's FIRST dims and every key head's, the shared
+    part appended unrotated) is another function: the option is not a
+    spelling of it."""
+    from poseidon_tpu.models import transformer as tr
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 2 * 24))
+    kpe = x[..., :8] * 0.5
+    ops = (x, x[..., :2 * 16], x[..., :2 * 16])
+    a = tr.rope_attention(*ops, 2, 1e4, rotary_dims=8, k_shared=kpe)
+    b = tr.rope_attention(*ops, 2, 1e4, k_shared=kpe, rotary_shared=True)
+    assert float(jnp.max(jnp.abs(a - b))) > 1e-2
+
+
+@pytest.mark.parametrize("s,n,d,rot,at", [
+    (256, 20, 256, 64, 192),    # GLM's q: the last 64 of 256, 20 heads
+    (192, 2, 128, 32, 96),      # periods of 64
+    (64, 1, 64, 64, 0),         # the shared part itself: one head, all of it
+    (136, 3, 128, 32, 40),      # a rotating part in a head's middle
+])
+def test_rope_along_the_lanes_at_an_offset(s, n, d, rot, at):
+    """``_rope_lanes`` with ``at``: the ``rot`` dims of every head from dim
+    ``at`` on rotate, the others pass; values and the hand-written backward
+    against ``apply_rope`` on the slice."""
+    from poseidon_tpu.models import transformer as tr
+    b = 2
+    x = jax.random.normal(jax.random.PRNGKey(s), (b, s, n * d))
+    g = jax.random.normal(jax.random.PRNGKey(s + 1), (b, s, n * d))
+    cos, sin = tr.rope_tables(s, rot, 1e6)
+
+    def by_head(x_):
+        t = x_.reshape(b, s, n, d).swapaxes(1, 2)
+        t = jnp.concatenate([t[..., :at],
+                             tr.apply_rope(t[..., at:at + rot], cos, sin),
+                             t[..., at + rot:]], axis=-1)
+        return t.swapaxes(1, 2).reshape(b, s, n * d)
+
+    want, want_vjp = jax.vjp(by_head, x)
+    got, got_vjp = jax.vjp(
+        lambda x_: tr._rope_lanes(x_, n, rot, 1e6, 1, at), x)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+    np.testing.assert_allclose(got_vjp(g)[0], want_vjp(g)[0], rtol=0,
+                               atol=2e-6)
