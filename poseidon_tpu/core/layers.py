@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import elementwise as E
 from ..ops import losses as L
@@ -201,8 +202,17 @@ class ConvolutionLayer(Layer):
                 for x in bottoms]
 
 
+# The names a gated unit's products carry (``checkpoint_name``): a product a
+# SILU_GATE reads, and the product that reads the SILU_GATE. ``Net`` says
+# which layers those are (``_plan_gated_products``); a checkpointed unit may
+# keep them (``core/remat.keep_rungs``) so that its replay runs no FFN matmul
+# a second time.
+FFN_SAVED = ("ffn_in", "ffn_out")
+
+
 class InnerProductLayer(Layer):
     TYPE = "INNER_PRODUCT"
+    saved_as: Optional[str] = None      # one of FFN_SAVED, by Net's plan
 
     def setup(self, bottom_shapes):
         ip = self.lp.inner_product_param
@@ -239,6 +249,9 @@ class InnerProductLayer(Layer):
                     b = ctx.comm.tap_param(self.name, "b", b)
         if y is None:
             y = NN.inner_product(x, w, b)
+        if self.saved_as:
+            # on the product itself: what reads it may be slices of it
+            y = checkpoint_name(y, self.saved_as)
         return [y if self.axis == 1 else y.reshape(self.lead + y.shape[-1:])]
 
 

@@ -276,6 +276,7 @@ class Net:
         self._plan_layouts()
         self._plan_conv_strategies()
         self._plan_kernel_routes()
+        self._plan_gated_products()
 
     # ------------------------------------------------------------------ #
     def arena_layout(self, include=None, bucket_mb: float = 4.0,
@@ -319,6 +320,41 @@ class Net:
                     break
                 if top in nxt.lp.bottom or top in nxt.lp.top:
                     break
+
+    def _plan_gated_products(self) -> None:
+        """Name the products of every gated unit (``layers.FFN_SAVED``), so
+        that a checkpointed unit can keep them and replay none of them
+        (``core/remat.keep_rungs``): ``ffn_in`` on each INNER_PRODUCT whose
+        result a SILU_GATE reads, directly or as the halves of a SLICE (a
+        dense gated FFN's gate and up, or the one product of both that is
+        then cut in two), ``ffn_out`` on the INNER_PRODUCT that reads the
+        SILU_GATE's result (the down product). Read off the net, not off
+        names, so a mixer's gated output projection counts too. The name
+        sits on the product, never on the slices: kept slices would cost a
+        copy each. So a product of which the gate reads only a PART is not
+        named (a Mamba-2 mixer's input projection, cut into z, xBC and dt:
+        kept whole it is 139 MB a layer for z's 67, and Granite's step with
+        those names compiles at 15.80 GB where 14.88 is allowed; compiler
+        accounting, sandbox, PR 57). Outside a checkpoint that asks for it
+        a name lowers to nothing."""
+        maker: Dict[str, Layer] = {}        # blob -> the layer that wrote it
+        whole: Dict[str, Layer] = {}        # a SLICE -> who made its bottom
+        for layer in self.layers:
+            made_by = [maker.get(b) for b in layer.lp.bottom]
+            if layer.TYPE == "SLICE":
+                whole[layer.name] = made_by[0]
+            elif layer.TYPE == "SILU_GATE":
+                for src in set(made_by):
+                    if src is not None and src.TYPE == "SLICE" \
+                            and set(src.lp.top) <= set(layer.lp.bottom):
+                        src = whole[src.name]
+                    if src is not None and src.TYPE == "INNER_PRODUCT":
+                        src.saved_as = "ffn_in"
+            elif layer.TYPE == "INNER_PRODUCT" and made_by[0] is not None \
+                    and made_by[0].TYPE == "SILU_GATE":
+                layer.saved_as = "ffn_out"
+            for top in layer.lp.top:
+                maker[top] = layer
 
     def _plan_layouts(self) -> None:
         """Assign each layer's run layout and each external input's entry

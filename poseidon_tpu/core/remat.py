@@ -133,18 +133,33 @@ KEEP_SHARE = 0.88
 
 
 def keep_rungs(made=None) -> List[Tuple[str, ...]]:
-    """What a plan's units may keep (``RematPlan.keep``), most first: the
-    results of the Pallas forward kernels, which are the residuals of their
-    own backward kernels, so that a unit's replay runs no kernel twice.
-    The order of giving up is by milliseconds a gigabyte: a scan's chunk
-    states are large beside its forward (18-25 ms/GB on the v5e), a flash
-    kernel's output and row statistics small beside its (75 ms/GB), so the
-    scans' go first. Of a program that makes the names ``made`` (None: any
-    of them), the rungs that differ: ``[()]`` where it makes none."""
+    """What a plan's units may keep (``RematPlan.keep``), most first, so
+    that a unit's replay runs nothing expensive twice: the products of its
+    gated FFN (``layers.FFN_SAVED``: gate and up, and the down product
+    where a norm reads it), and the results of the Pallas forward kernels,
+    which are the residuals of their own backward kernels.
+
+        FFN + scan + flash  ->  scan + flash  ->  flash  ->  ()
+
+    The order of giving up is by milliseconds a gigabyte on the v5e: an
+    (S, F) product contracted over D returns D FLOPs a byte, 12 ms/GB at D
+    2048 and 25 at D 3840 at the 155-166 TFLOP/s these scopes run (a down
+    product, contracted over F, 72), so the FFN's go first; a scan's chunk
+    states are large beside its forward (18-25 ms/GB), a flash kernel's
+    output and row statistics small beside its (75 ms/GB), so the scans' go
+    next. Of a program that makes the names ``made`` (None: any of them),
+    the rungs that differ: ``[()]`` where it makes none. Which rung a step
+    ends on is the Engine's to say (``_compile_step``): the first whose
+    COMPILED step is within the budget, and a rung whose floor (the
+    arguments + the units' stored inputs + its kept bytes, all read off the
+    trace: ``runtime/attribution.unit_residuals``) already exceeds it is
+    passed over without a compile."""
     from ..ops.kda import SCAN_SAVED
     from ..ops.pallas_kernels import FLASH_SAVED
+    from .layers import FFN_SAVED
     rungs: List[Tuple[str, ...]] = []
-    for rung in (SCAN_SAVED + FLASH_SAVED, FLASH_SAVED, ()):
+    for rung in (FFN_SAVED + SCAN_SAVED + FLASH_SAVED,
+                 SCAN_SAVED + FLASH_SAVED, FLASH_SAVED, ()):
         rung = tuple(n for n in rung if made is None or n in made)
         if rung not in rungs:
             rungs.append(rung)
@@ -154,9 +169,9 @@ def keep_rungs(made=None) -> List[Tuple[str, ...]]:
 def checkpoint_unit(fn, keep: Sequence[str] = ()):
     """``fn`` as one of ``Net.apply``'s checkpointed units: it stores what
     it takes from outside and, of what it makes, the values named in
-    ``keep`` (``checkpoint_name`` tags: the Pallas forward kernels'
-    results, :func:`keep_rungs`). With none it is bare ``jax.checkpoint``,
-    the program it always was."""
+    ``keep`` (``checkpoint_name`` tags: a gated FFN's products and the
+    Pallas forward kernels' results, :func:`keep_rungs`). With none it is
+    bare ``jax.checkpoint``, the program it always was."""
     import jax
     if not keep:
         return jax.checkpoint(fn)
@@ -230,7 +245,10 @@ class RematPlan:
                    if self.lm_policy != "none" else "")
                 + (f"; units keep {'+'.join(self.keep) or 'nothing'}"
                    f" ({kept['kept_bytes'] / 2**20:.1f} MiB in "
-                   f"{kept['kept_units']}): the step compiles at "
+                   f"{kept['kept_units']}"
+                   + "".join(f", {name} {b / 2**20:.1f}" for name, b in
+                             kept.get("kept_bytes_by_name", {}).items())
+                   + f"): the step compiles at "
                    f"{kept['compiled_peak_bytes'] / 1e9:.2f} GB, held to "
                    f"{kept['held_to_bytes'] / 1e9:.2f}, "
                    f"{kept['compiles']} compile(s)" if kept else ""))

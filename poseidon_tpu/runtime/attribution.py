@@ -132,35 +132,89 @@ def _scope_components(op_name: str) -> List[str]:
     return comps
 
 
-def named_values(jaxpr, plan) -> List[Tuple[str, int, int]]:
-    """``(name, unit, bytes)`` of every value that a unit of ``plan`` (a
-    ``core/remat.RematPlan``) names (``checkpoint_name``, a name in
-    ``plan.keep``) in the forward pass of a traced gradient: what the unit
-    keeps. ``unit`` indexes ``plan.units``; the value's layer is read off
-    its scope, as a device trace's instructions are. A replay (the
-    ``remat2`` equations) names what it makes again and keeps nothing."""
+def unit_residuals(jaxpr, plan, batch_args: Sequence[int] = ()
+                   ) -> Tuple[List[Tuple[str, int, int]], int]:
+    """What the units of ``plan`` (a ``core/remat.RematPlan``) hold from the
+    forward pass of a traced gradient for their replays, read off what the
+    replays read: ``(named, stored)``. A replay is a ``remat2`` equation of
+    the backward pass (``differentiated``; a checkpoint inside a layer
+    leaves one in the forward pass too, which is no replay).
+
+    ``named`` is ``(name, unit, bytes)`` of every value a unit names
+    (``checkpoint_name``, a name in ``plan.keep``) that a replay reads:
+    what the unit KEEPS. A name nothing reads again costs nothing (jax
+    stores no such value) and is not listed; a replay names what it makes
+    again and keeps nothing. ``unit`` indexes ``plan.units``; the value's
+    layer is read off its scope, as a device trace's instructions are.
+
+    ``stored`` is the bytes of the other values the replays read that the
+    forward pass made from the batch (``batch_args``: which of the jaxpr's
+    arguments are the batch's): the units' stored inputs. A cotangent (made
+    after the first replay) is none; a value made from the parameters alone
+    (a cast weight) is left out, since the compiler may never write it; so
+    is what a nested call hides. Both numbers are floors."""
     import jax
+    from jax.extend.core import Var
     unit_of = {layer: at for at, unit in enumerate(plan.units)
                for layer in ((unit,) if isinstance(unit, str) else unit)}
     found: List[Tuple[str, int, int]] = []
+    stored = 0
 
-    def walk(inner):
+    def nbytes(var) -> int:
+        return var.aval.size * var.aval.dtype.itemsize
+
+    def replay(eqn) -> bool:
+        return eqn.primitive.name == "remat2" \
+            and eqn.params.get("differentiated", True)
+
+    def walk(inner, from_batch) -> Dict:
+        """-> {a value of this jaxpr that carries a name: its entry}."""
+        nonlocal stored
+        named: Dict = {}
+        forward = set()                     # made before the first replay
+        from_batch = set(from_batch)
+        reads = {v for eqn in inner.eqns if replay(eqn)
+                 for v in eqn.invars if isinstance(v, Var)}
+        backward = False
         for eqn in inner.eqns:
-            if eqn.primitive.name == "remat2":
+            if replay(eqn):
+                backward = True
                 continue
+            if not backward:
+                forward.update(eqn.outvars)
+            args = [v for v in eqn.invars if isinstance(v, Var)]
+            if any(v in from_batch for v in args):
+                from_batch.update(eqn.outvars)
+            if eqn.primitive.name == "reduce_precision" \
+                    and args and args[0] in named:
+                # jax's guard between a kept value and its use, a no-op
+                named[eqn.outvars[0]] = named.pop(args[0])
             if eqn.primitive.name == "name" \
                     and eqn.params["name"] in plan.keep:
                 unit = next((unit_of[c] for c in _scope_components(
                     str(eqn.source_info.name_stack)) if c in unit_of), None)
-                aval = eqn.outvars[0].aval
                 if unit is not None:
-                    found.append((eqn.params["name"], unit,
-                                  aval.size * aval.dtype.itemsize))
+                    named[eqn.outvars[0]] = (eqn.params["name"], unit,
+                                             nbytes(eqn.outvars[0]))
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
+                same = len(sub.invars) == len(eqn.invars)
+                inside = walk(sub, [i for i, o in zip(sub.invars, eqn.invars)
+                                    if isinstance(o, Var)
+                                    and o in from_batch] if same else [])
+                # a name given inside a call rides the call's result
+                if len(sub.outvars) == len(eqn.outvars):
+                    named.update((out, inside[o]) for o, out in zip(
+                        sub.outvars, eqn.outvars)
+                        if isinstance(o, Var) and o in inside)
+        found.extend(entry for var, entry in named.items() if var in reads)
+        stored += sum(
+            nbytes(v) for v in reads
+            if v in forward and v in from_batch and v not in named)
+        return named
 
-    walk(jaxpr.jaxpr)
-    return found
+    top = jaxpr.jaxpr
+    walk(top, [top.invars[i] for i in batch_args])
+    return found, stored
 
 
 # collective named scopes emitted by the comm machinery (strategies.py

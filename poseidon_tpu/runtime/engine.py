@@ -1052,20 +1052,26 @@ class Engine:
         where no layer runs under a checkpoint).
 
         Under a remat plan the units first keep every name of
-        ``remat.keep_rungs``' first rung: what their Pallas forward
-        kernels wrote, so that the backward's replay runs none of them a
-        second time. That costs memory, and a user who passes ``--remat``
-        wants memory first: the names stay only while the COMPILED step
-        (``measured_peak_bytes``) is within ``--hbm_budget_gb``, or with
-        none given ``KEEP_SHARE`` of the device's limit. Over it, or
-        refused by the compiler, the scans' results go, then the flash
-        kernels', and the step is the one that keeps nothing. A rung that
-        names nothing more than the next of what this program makes is
-        passed over, so a cold start compiles at most three times and a
-        net with no such kernel once; on a backend with no memory
-        statistics, with no budget given, the names stay."""
+        ``remat.keep_rungs``' first rung: their gated FFNs' products and
+        what their Pallas forward kernels wrote, so that the backward's
+        replay runs none of them a second time. That costs memory, and a
+        user who passes ``--remat`` wants memory first: the names stay only
+        while the COMPILED step (``measured_peak_bytes``) is within
+        ``--hbm_budget_gb``, or with none given ``KEEP_SHARE`` of the
+        device's limit. Over it, or refused by the compiler, the FFNs'
+        products go, then the scans' results, then the flash kernels', and
+        the step is the one that keeps nothing. A rung that names nothing
+        more than the next of what this program makes is passed over, and
+        so is, without a compile, one whose floor is over the budget: what
+        is certainly live as the backward starts, the step's arguments +
+        the units' stored inputs + the rung's kept bytes, all read off the
+        trace (``attribution.unit_residuals``) and taken as spread evenly
+        over the mesh, the least a device can hold. So a cold start
+        compiles at most four times and a net with no such value once; on
+        a backend with no memory statistics, with no budget given, the
+        names stay."""
         from ..core import remat as remat_mod
-        from .attribution import named_values
+        from .attribution import unit_residuals
         from .compile_cache import watch_cache_hits
         startup = span_recorder.startup
         plan = self.remat_plan
@@ -1083,18 +1089,37 @@ class Engine:
                 return traced, traced.lower()
 
         traced, lowered = trace(remat_mod.keep_rungs()[0] if units else ())
-        # what the units name is read off the program, once; the rungs are
+        # what the units hold is read off the program, once; the rungs are
         # those that differ in what they keep of it
-        named = named_values(traced.jaxpr, self.remat_plan) \
-            if units else []
+        n_lead = len(jax.tree_util.tree_leaves((self.params, self.state)))
+        named, stored = unit_residuals(
+            traced.jaxpr, self.remat_plan, range(
+                n_lead, n_lead + len(jax.tree_util.tree_leaves(batch)))
+        ) if units else ([], 0)
+        arguments = sum(a.size * a.dtype.itemsize
+                        for a in traced.jaxpr.in_avals)
         rungs = remat_mod.keep_rungs({name for name, _, _ in named})
         budget = int(self.hbm_budget_gb * 2**30) \
             if self.hbm_budget_gb and self.hbm_budget_gb > 0 \
             else int(remat_mod.KEEP_SHARE * remat_mod.default_budget_bytes())
-        compiles = 0
+        compiles, passed_over = 0, []
         while True:
             keep = rungs.pop(0)
-            if compiles:
+            kept = [(n, u, b) for n, u, b in named if n in keep]
+            by_name = {name: sum(b for n, _, b in kept if n == name)
+                       for name in sorted({n for n, _, _ in kept})}
+            floor = (arguments + stored + sum(by_name.values())) // self.n_dev
+            if rungs and budget and floor > budget:
+                passed_over.append("+".join(by_name))
+                log(f"remat: the step whose units keep {passed_over[-1]} "
+                    f"holds {floor / 1e9:.2f} GB as its backward starts "
+                    f"({arguments / 1e9:.2f} of arguments, "
+                    f"{stored / 1e9:.2f} of stored inputs, "
+                    f"{sum(by_name.values()) / 1e9:.2f} kept), over its "
+                    f"{budget / 1e9:.2f}: passed over without a compile",
+                    rank=self.rank)
+                continue
+            if compiles or passed_over:
                 traced, lowered = trace(keep)
             exec_, refused = None, ""
             try:
@@ -1108,12 +1133,13 @@ class Engine:
             if not units:
                 return exec_, hits, None
             peak = remat_mod.measured_peak_bytes(exec_) if exec_ else 0
-            kept = [(n, u, b) for n, u, b in named if n in keep]
-            doc = {"keep": sorted({n for n, _, _ in kept}),
+            doc = {"keep": list(by_name),
                    "kept_units": len({u for _, u, _ in kept}),
-                   "kept_bytes": sum(b for _, _, b in kept),
-                   "compiled_peak_bytes": peak, "held_to_bytes": budget,
-                   "compiles": compiles}
+                   "kept_bytes": sum(by_name.values()),
+                   "kept_bytes_by_name": by_name,
+                   "floor_bytes": floor, "compiled_peak_bytes": peak,
+                   "held_to_bytes": budget, "compiles": compiles,
+                   "passed_over": list(passed_over)}
             if exec_ is not None and (not rungs or not budget
                                       or peak <= budget):
                 return exec_, hits, doc

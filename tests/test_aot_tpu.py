@@ -778,6 +778,103 @@ def test_granite_full_width_step_fits_one_v5e_at_one_and_two_sequences(more):
     assert got["total_gb"] < KEEP_SHARE * 16.9
 
 
+# PR 57: the same three steps with ``remat.keep_rungs``' FIRST rung kept (the
+# gated FFNs' products beside the scans' and the flash kernels' results), as
+# ``Engine._compile_step`` first tries them: what the units keep by name, the
+# floor it compares with the budget before it compiles, and the compiled size
+# it compares after.
+def _kept(step: str) -> str:
+    step = step.replace(
+        "from poseidon_tpu.core.remat import RematPlan, resolve_entries",
+        "from poseidon_tpu.core.remat import (RematPlan, keep_rungs,\n"
+        "                                     resolve_entries)\n"
+        "from poseidon_tpu.runtime.attribution import unit_residuals").replace(
+        'plan = RematPlan(layers=layers, segments=segments, source="flag")',
+        'plan = RematPlan(layers=layers, segments=segments, source="flag",\n'
+        '                 keep=keep_rungs()[0])').replace(
+        "compiled = ts.lowerable.lower(", "traced = ts.lowerable.trace(").replace(
+        "    jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()",
+        "    jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep))\n"
+        "n_lead = len(jax.tree.leaves((params, state)))\n"
+        "named, stored = unit_residuals(traced.jaxpr, plan,\n"
+        "                               range(n_lead, n_lead + 2))\n"
+        "by_name = {{}}\n"
+        "for name, _, b in named:\n"
+        "    by_name[name] = by_name.get(name, 0) + b / 1e9\n"
+        "floor = sum(a.size * a.dtype.itemsize\n"
+        "            for a in traced.jaxpr.in_avals) + stored\n"
+        "print('FLOOR ' + json.dumps({{'kept_gb': by_name,\n"
+        "    'stored_gb': stored / 1e9,\n"
+        "    'floor_gb': floor / 1e9 + sum(by_name.values())}}), flush=True)\n"
+        "compiled = traced.lower().compile()")
+    assert "keep_rungs()[0]" in step and "FLOOR" in step \
+        and "traced.lower().compile()" in step
+    return step
+
+
+_KEPT_STEPS = {"olmo_hybrid": _OLMO_HYBRID_STEP, "granite": _GRANITE_STEP,
+               "ouro": _OURO_STEP}
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("cell", ["olmo_hybrid", "granite", "ouro"])
+def test_step_with_the_ffn_rung_kept_for_one_v5e(cell):
+    """What ``Engine._compile_step`` meets on a v5e's cold start with the
+    first rung (compiler accounting, sandbox, PR 57; beside PR 48's and PR
+    54's records above: 12.17 / 12.09 GB keeping nothing, 12.47 / 12.25 GB
+    with the kernels' results, the very numbers the chip's Engine reported).
+
+    Olmo-Hybrid: 13.55 GB with 1.58 GB of ``ffn_in`` (four layers' gate and
+    up, three ``gdn_z``) and 0.44 of ``ffn_out`` (four down products, three
+    ``gdn_o``: a norm reads each again) beside 0.98 of kernel results;
+    floor 12.51 GB. Granite: 14.80 GB with 2.68 GB of ``ffn_in`` (ten
+    ``l<i>_ffn_in``; nothing reads ``l<i>_ffn_out`` again, so it is not
+    kept; ``l<i>_ssd_in`` is not named, see ``Net._plan_gated_products``:
+    with it the step compiled at 15.80), floor 13.56: under ``KEEP_SHARE``
+    of the 16.9 GB by 0.07. Ouro: 6.11 GB of FFN products over 28
+    applications put the floor at 14.77 GB, 0.11 under the budget, so the
+    rung is not passed over, and the compiler refuses the step (18.66 of
+    15.75 GiB): the Engine falls to PR 49's rung after one failed compile."""
+    import json
+    from poseidon_tpu.core.remat import KEEP_SHARE
+    r = subprocess.run(
+        [sys.executable, "-c",
+         _kept(_KEPT_STEPS[cell]).format(repo=REPO, deeper=0)],
+        capture_output=True, text=True, timeout=2400, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    lines = {l.split(" ", 1)[0]: json.loads(l.split(" ", 1)[1])
+             for l in r.stdout.splitlines()
+             if l.startswith(("FLOOR ", "RESULT "))}
+    print(lines)          # the accounting, for whoever adds the next name
+    floor = lines["FLOOR"]
+    kept = {k: round(v, 2) for k, v in floor["kept_gb"].items()}
+    budget = KEEP_SHARE * 16.9
+    if cell == "ouro":
+        assert kept == {"ffn_in": 5.17, "ffn_out": 0.94, "flash_out": 0.94,
+                        "flash_lse": 0.01}
+        assert 14.6 < floor["floor_gb"] < budget
+        assert r.returncode != 0 and "RESOURCE_EXHAUSTED" in r.stderr
+        return
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = lines["RESULT"]
+    if cell == "olmo_hybrid":
+        assert kept == {"ffn_in": 1.58, "ffn_out": 0.44, "scan_out": 0.19,
+                        "scan_states": 0.75, "flash_out": 0.03,
+                        "flash_lse": 0.0}
+        assert 12.4 < floor["floor_gb"] < 12.6
+        assert 13.4 < got["total_gb"] < 13.7
+        # the scans run forward and backward, no replay
+        assert got["pallas_custom_calls"] == 3 + 2 * 3
+    else:
+        assert kept == {"ffn_in": 2.68, "scan_out": 0.6, "scan_states": 0.6,
+                        "flash_out": 0.03, "flash_lse": 0.0}
+        assert 13.4 < floor["floor_gb"] < 13.7
+        assert 14.7 < got["total_gb"] < budget
+    assert floor["floor_gb"] < got["total_gb"]
+
+
 # The full-width SmallThinker train step (examples/lm/smallthinker_21b_*:
 # published layers 0-3, global, window, window, window; 16 of 64 experts held,
 # an eighth of the untied vocabulary) as `train --bf16 --remat <the solver

@@ -367,16 +367,19 @@ def test_plan_for_net_step_measured_source():
 
 
 # --------------------------------------------------------------------------- #
-# what a unit keeps: the Pallas forward kernels' named results (PR 49)
+# what a unit keeps: the Pallas forward kernels' named results (PR 49) and its
+# gated FFN's products (PR 57)
 # --------------------------------------------------------------------------- #
 
+from poseidon_tpu.core.layers import FFN_SAVED                  # noqa: E402
 from poseidon_tpu.ops.kda import SCAN_SAVED                     # noqa: E402
 from poseidon_tpu.ops.pallas_kernels import FLASH_SAVED         # noqa: E402
 from poseidon_tpu.proto.messages import load_net_from_string   # noqa: E402
 
 UNITS = "/l\\d+_/,/lm_/"            # the token cells' --remat: a layer a unit
 KEEPS = {"replayed": (), "flash": FLASH_SAVED,
-         "all": SCAN_SAVED + FLASH_SAVED}
+         "all": SCAN_SAVED + FLASH_SAVED,                   # PR 49's first
+         "ffn": FFN_SAVED + SCAN_SAVED + FLASH_SAVED}       # keep_rungs' first
 
 
 def _hybrid(n=1, s=256, source="tokens.txt", **kw):
@@ -427,9 +430,20 @@ def test_a_unit_that_keeps_a_kernel_s_results_runs_it_once(
     assert text.count("name=flash_bwd_dq") == 1
     assert text.count("name=gdn_scan_bwd") == 1
     # what the program names under its units, as the Engine reads it
-    from poseidon_tpu.runtime.attribution import named_values
-    named = named_values(jaxpr, plan)
+    from poseidon_tpu.runtime.attribution import unit_residuals
+    n_params = len(jax.tree.leaves(net.param_defs))
+    named, stored = unit_residuals(
+        jaxpr, plan, batch_args=range(n_params, n_params + 2))
+    # the units' stored inputs: the residual stream (1, 256, 128) into each
+    # layer and into the head, f32; the tokens and targets are arguments
+    assert stored == 3 * 256 * 128 * 4
     assert {n for n, _, _ in named} == set(KEEPS[arm])
+    if arm == "ffn":
+        # a layer's gate, up and down (its norm reads the down product),
+        # the linear layer's z and gated output projection besides
+        assert sorted((n, u) for n, u, _ in named if n in FFN_SAVED) == \
+            [("ffn_in", 0)] * 3 + [("ffn_in", 1)] * 2 \
+            + [("ffn_out", 0)] * 2 + [("ffn_out", 1)]
     assert {u for n, u, _ in named if n in FLASH_SAVED} <= {1}
     assert {u for n, u, _ in named if n in SCAN_SAVED} <= {0}
     if arm == "all":
@@ -446,7 +460,8 @@ def test_keep_rungs_follow_what_the_program_makes():
     kernels' last, then nothing; a rung that keeps no more than the next
     of what the program makes is no rung."""
     every = SCAN_SAVED + FLASH_SAVED
-    assert remat_mod.keep_rungs() == [every, FLASH_SAVED, ()]
+    first = FFN_SAVED + every
+    assert remat_mod.keep_rungs() == [first, every, FLASH_SAVED, ()]
     assert remat_mod.keep_rungs(set(every)) == [every, FLASH_SAVED, ()]
     assert remat_mod.keep_rungs(set(FLASH_SAVED)) == [FLASH_SAVED, ()]
     assert remat_mod.keep_rungs(set(SCAN_SAVED)) == [SCAN_SAVED, ()]
@@ -456,17 +471,85 @@ def test_keep_rungs_follow_what_the_program_makes():
     assert plan.apply_args == {"remat": ("a",), "remat_keep": FLASH_SAVED}
 
 
+@pytest.mark.parametrize("made, rungs", [
+    # all three families: the FFN's products go first, then the scans'
+    (FFN_SAVED + SCAN_SAVED + FLASH_SAVED,
+     [FFN_SAVED + SCAN_SAVED + FLASH_SAVED, SCAN_SAVED + FLASH_SAVED,
+      FLASH_SAVED, ()]),
+    # two
+    (FFN_SAVED + FLASH_SAVED, [FFN_SAVED + FLASH_SAVED, FLASH_SAVED, ()]),
+    (FFN_SAVED + SCAN_SAVED, [FFN_SAVED + SCAN_SAVED, SCAN_SAVED, ()]),
+    # one: a down product that nothing reads again is not made
+    (FFN_SAVED, [FFN_SAVED, ()]),
+    (("ffn_in",), [("ffn_in",), ()]),
+    (SCAN_SAVED, [SCAN_SAVED, ()]),
+    # none
+    ((), [()]),
+], ids=["ffn+scan+flash", "ffn+flash", "ffn+scan", "ffn", "ffn_in", "scan",
+        "none"])
+def test_keep_rungs_with_the_ffn_names(made, rungs):
+    """``keep_rungs(made)`` for programs that make all three families of
+    names, two, one, none: FFN + scan + flash -> scan + flash -> flash ->
+    (), each of what the program makes, no rung twice."""
+    got = remat_mod.keep_rungs(set(made))
+    assert got == rungs and len(set(got)) == len(got)
+
+
+def _replayed_products(jaxpr) -> list:
+    """The scopes of the ``dot_general``s that the replays (the ``remat2``
+    equations) of a traced gradient run of the FORWARD pass: jax marks
+    those ``rematted_computation/<layer>``; a replay's backward products
+    carry the layer's scope alone."""
+    found = []
+
+    def walk(inner, replay):
+        for eqn in inner.eqns:
+            scope = str(eqn.source_info.name_stack)
+            if replay and eqn.primitive.name == "dot_general" \
+                    and "rematted_computation/" in scope:
+                found.append(scope.rsplit("/", 1)[-1])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, replay or eqn.primitive.name == "remat2")
+
+    walk(jaxpr.jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("arm", sorted(KEEPS))
+def test_a_unit_that_keeps_its_ffn_products_replays_none(arm):
+    """The traced gradient of the two-layer net, one checkpoint a layer:
+    the replay of a unit that keeps the FFN names runs no ``dot_general``
+    of a gate, an up or a down product (nor of the linear mixer's z and
+    gated output projection, which the same rule names); every other arm
+    replays each of them once, and all arms replay the other products (q,
+    k, v, the head)."""
+    net = _hybrid_net()
+    plan = _unit_plan(net, KEEPS[arm])
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: net.apply(
+        p, b, train=True, **plan.apply_args).loss))(*_token_avals(net))
+    replayed = _replayed_products(jaxpr)
+    gated = sorted(f"l{i}_ffn_{p}" for i in (0, 1)
+                   for p in ("gate", "up", "down")) \
+        + ["l0_gdn_o", "l0_gdn_z"]
+    assert {l.name for l in net.layers if getattr(l, "saved_as", None)} \
+        == set(gated)
+    assert sorted(n for n in replayed if n in gated) == \
+        ([] if arm == "ffn" else sorted(gated))
+    assert {"l0_gdn_q", "l0_gdn_k", "l0_gdn_v", "lm_head"} <= set(replayed)
+
+
 def _grads(net, params, batch, plan):
     args = plan.apply_args if plan is not None else {}
     return jax.jit(jax.value_and_grad(lambda p: net.apply(
         p, batch, train=True, **args).loss))(params)
 
 
-@pytest.mark.parametrize("arm", ["replayed", "all"])
+@pytest.mark.parametrize("arm", ["replayed", "all", "ffn"])
 def test_loss_and_every_gradient_bitwise_across_the_arms(arm):
-    """Stored (no checkpoint) against replayed and kept: the loss and every
-    leaf's gradient, bit for bit (the CPU's arms: the chunked scan names
-    its results, attention is the dense op)."""
+    """Stored (no checkpoint) against replayed and kept, a case a rung that
+    the CPU's program tells apart: the loss and every leaf's gradient, bit
+    for bit (the chunked scan names its results and the gated FFNs their
+    products; attention is the dense op, so ``flash`` is ``replayed``)."""
     import jax.numpy as jnp
     net = _hybrid_net(s=128)
     params = net.init(jax.random.PRNGKey(3))
@@ -598,8 +681,9 @@ def test_engine_arms_bitwise_and_counted(arm, engine_arms):
         return
     assert sections["compiled_step"]["remat_keep"] == {
         k: doc[k] for k in ("keep", "kept_units", "kept_bytes",
+                            "kept_bytes_by_name", "floor_bytes",
                             "compiled_peak_bytes", "held_to_bytes",
-                            "compiles")}
+                            "compiles", "passed_over")}
     # (tight: the XLA cache may answer with the replayed arm's program,
     # which is the same program)
     assert sections["compiled_step"]["source"] in {
@@ -610,16 +694,73 @@ def test_engine_arms_bitwise_and_counted(arm, engine_arms):
         # nothing fits: the parent's program, text for text
         assert (doc["keep"], doc["kept_units"], doc["kept_bytes"]) == \
             ([], 0, 0)
-        assert doc["compiles"] == 2 and doc["held_to_bytes"] == 1073
+        # the arguments alone are over it: both rungs that keep something
+        # are passed over, and the one compile is the last rung's
+        assert doc["compiles"] == 1 and doc["held_to_bytes"] == 1073
+        assert doc["passed_over"] == [
+            "ffn_in+ffn_out+scan_out+scan_states", "scan_out+scan_states"]
+        assert doc["floor_bytes"] > 1073 and doc["kept_bytes_by_name"] == {}
         assert text == engine_arms["replayed"][3]
         assert text != engine_arms["kept"][3]
     else:
-        # the scan's o (1, 128, 1, 32) and two chunk states (16 x 32), f32
-        assert doc["keep"] == sorted(SCAN_SAVED)
-        assert (doc["kept_units"], doc["compiles"]) == (1, 1)
-        assert doc["kept_bytes"] == 128 * 32 * 4 + 2 * 16 * 32 * 4
+        # the scan's o (1, 128, 1, 32) and two chunk states (16 x 32), f32;
+        # two layers' gate and up (128 x 64) and the linear layer's z
+        # (128 x 32); two down products and the gated output projection
+        # (128 x 64), which the norms behind them read again
+        assert doc["keep"] == sorted(FFN_SAVED + SCAN_SAVED)
+        assert (doc["kept_units"], doc["compiles"]) == (2, 1)
+        assert doc["kept_bytes_by_name"] == {
+            "ffn_in": (4 * 64 + 32) * 128 * 4, "ffn_out": 3 * 64 * 128 * 4,
+            "scan_out": 128 * 32 * 4, "scan_states": 2 * 16 * 32 * 4}
+        assert doc["kept_bytes"] == sum(doc["kept_bytes_by_name"].values())
+        assert doc["floor_bytes"] > doc["kept_bytes"] // N_DEV
+        assert doc["passed_over"] == []
         assert doc["held_to_bytes"] == 0       # no statistics, no budget
         assert doc == engine_arms["kept"][2]["remat"]
+
+
+@pytest.mark.parametrize("case", ["falls", "passed_over"])
+def test_compile_step_under_a_fake_budget(case, tmp_path, jax_cache_env,
+                                          engine_arms, monkeypatch):
+    """``Engine._compile_step`` with the compiler's accounting faked.
+    ``falls``: the first rung's step (the FFN names) compiles over the
+    budget, the next (the scan names, PR 49's first) within it: two
+    compiles, the second rung's names. ``passed_over``: a budget between
+    the two rungs' floors (arguments + stored inputs + kept bytes, a
+    device's even share): the first rung is not compiled at all."""
+    from poseidon_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    kept = engine_arms["kept"][2]["remat"]
+    ffn = sum(b for n, b in kept["kept_bytes_by_name"].items()
+              if n in FFN_SAVED)
+    peaks = []
+
+    def fake_peak(compiled):
+        peaks.append(2**31 if case == "falls" and not peaks else 1)
+        return peaks[-1]
+
+    monkeypatch.setattr(remat_mod, "measured_peak_bytes", fake_peak)
+    budget = 2**30 if case == "falls" \
+        else kept["floor_bytes"] - ffn // (2 * N_DEV)
+    eng = _token_engine(tmp_path, remat=UNITS, hbm_budget_gb=budget / 2**30)
+    try:
+        loss = eng.train()["loss"]
+        doc = eng.stats.sections["remat"]
+    finally:
+        eng.close()
+    assert loss == engine_arms["stored"][0]
+    assert doc["keep"] == sorted(SCAN_SAVED) and doc["kept_units"] == 1
+    assert doc["kept_bytes_by_name"] == {
+        n: kept["kept_bytes_by_name"][n] for n in sorted(SCAN_SAVED)}
+    assert doc["held_to_bytes"] == budget
+    assert doc["floor_bytes"] == kept["floor_bytes"] - ffn // N_DEV
+    if case == "falls":
+        assert (doc["compiles"], doc["passed_over"]) == (2, [])
+        assert peaks == [2**31, 1] and doc["compiled_peak_bytes"] == 1
+    else:
+        assert (doc["compiles"], doc["passed_over"]) == (
+            1, ["ffn_in+ffn_out+scan_out+scan_states"])
+        assert peaks == [1]
 
 
 def test_stats_yaml_carries_the_kept_counter(tmp_path, jax_cache_env):
@@ -634,9 +775,14 @@ def test_stats_yaml_carries_the_kept_counter(tmp_path, jax_cache_env):
     finally:
         eng.close()
     doc = read_stats_yaml(str(tmp_path / "stats.yaml"))   # leaves: strings
-    assert doc["remat"]["keep"] == str(sorted(SCAN_SAVED))
-    assert doc["remat"]["kept_units"] == "1"
-    assert int(doc["remat"]["kept_bytes"]) == 128 * 32 * 4 + 2 * 16 * 32 * 4
+    assert doc["remat"]["keep"] == str(sorted(FFN_SAVED + SCAN_SAVED))
+    assert doc["remat"]["kept_units"] == "2"
+    by_name = {k: int(v)
+               for k, v in doc["remat"]["kept_bytes_by_name"].items()}
+    assert by_name["scan_out"] + by_name["scan_states"] \
+        == 128 * 32 * 4 + 2 * 16 * 32 * 4
+    assert int(doc["remat"]["kept_bytes"]) == sum(by_name.values())
+    assert doc["remat"]["passed_over"] == "[]"
     assert int(doc["remat"]["compiled_peak_bytes"]) > 0
     assert doc["compiled_step"]["remat_keep"]["compiles"] == "1"
 

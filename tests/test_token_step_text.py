@@ -121,17 +121,22 @@ def traced(name: str) -> str:
 # statistics / chunk states in the backward's rule). Outside a checkpoint
 # that asks for them they are the identity, so the hashes above are taken
 # with the tags out, and what the tags add is counted.
-NAMES = {"olmoe": 2, "ouro": 4, "zaya": 4, "trinity": 4, "kimi": 8,
-         "olmo_hybrid": 8, "glm": 6}
+# PR 57: so do a gated unit's products (``core/layers.FFN_SAVED``), one
+# ``name`` equation a product, in the forward: three a SILU_GATE (the two
+# products it reads and the one that reads it; Olmo-Hybrid's linear mixer's
+# z and gated output projection two).
+NAMES = {"olmoe": 2, "ouro": 4 + 3 * 2, "zaya": 4, "trinity": 4 + 3 * 2,
+         "kimi": 8 + 3 * 4, "olmo_hybrid": 8 + 18, "glm": 6 + 3 * 3}
 
 
 @pytest.mark.parametrize("name", sorted(BUILD))
 def test_token_configuration_traces_to_the_parent_s_program(name,
                                                             monkeypatch):
+    from poseidon_tpu.core import layers
     from poseidon_tpu.ops import kda, kda_pallas, pallas_kernels
     monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
     assert traced(name).count("= name[") == NAMES[name]
-    for module in (kda, kda_pallas, pallas_kernels):
+    for module in (kda, kda_pallas, pallas_kernels, layers):
         monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
     text = traced(name)
     sha, chars, calls = PARENT[name]
