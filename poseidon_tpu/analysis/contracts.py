@@ -267,8 +267,7 @@ def build_contract(model: str) -> Dict:
     # analytic activation-bytes column the knapsack prices against, per
     # model. Pure shape math.
     from ..core import remat as remat_mod
-    from ..runtime.attribution import layer_cost_table
-    table = layer_cost_table(net)
+    table = net.cost_table()
     zero_plan = remat_mod.plan_remat(
         table, 0, 0, candidates=remat_mod.remat_candidates(net),
         source="analytic")

@@ -16,7 +16,8 @@ generated in-graph from its fillers.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+import math
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,10 @@ LAYOUT_AGNOSTIC = "agnostic"
 LAYOUT_CANONICAL = "canonical"
 
 
+def _elems(shapes) -> int:
+    return sum(math.prod(s) for s in shapes)
+
+
 def _remap_axis(axis: int, layout: str, ndim: int) -> int:
     """Map a Caffe NCHW-semantics axis onto the physical layout."""
     if layout != "NHWC" or ndim != 4:
@@ -77,7 +82,6 @@ class ApplyCtx:
 
 class Layer:
     TYPE = "NONE"
-    N_PARAMS = 0  # informational; actual defs built in setup
     # layout contract class (see module docstring constants); the safe
     # default is canonical — an unknown op never silently consumes NHWC
     LAYOUT_KIND = LAYOUT_CANONICAL
@@ -122,6 +126,34 @@ class Layer:
     def apply(self, params: Dict[str, jax.Array], bottoms: List[jax.Array],
               ctx: ApplyCtx) -> List[jax.Array]:
         raise NotImplementedError
+
+    # -- what a layer says of itself: ``Net`` asks every layer and switches
+    # -- on no type; a type's fact sits beside the ``apply`` it is a fact of
+    def kernel_route(self, bottom_shapes: List[Shape], itemsize: int
+                     ) -> Optional[Tuple[str, str, str]]:
+        """(what, arm, note): the kernel or XLA formulation ``apply`` lowers
+        to on these bottoms at this compute itemsize, from the SAME route
+        function the op consults at trace time (``Net.kernel_routes``).
+        None: one lowering, nothing to report."""
+        return None
+
+    def stats_sections(self, bottom_shapes: List[Shape], itemsize: int
+                       ) -> Dict[str, Dict]:
+        """{section of stats.yaml: this layer's facts} (``Net.layer_facts``)."""
+        return {}
+
+    def display_counters(self, bottom_shapes: List[Shape]
+                         ) -> Dict[str, Callable[[float], Dict[str, float]]]:
+        """{a scalar top a display carries: its value of one step -> the
+        {counter: increment} the Engine adds for that step}."""
+        return {}
+
+    def forward_flops(self, bottom_shapes: List[Shape],
+                      top_shapes: List[Shape], defs: List[ParamDef]) -> float:
+        """Forward FLOPs of one step from the shapes and the parameter
+        definitions this layer OWNS (``Net.cost_table``); by default an
+        elementwise estimate, one op an element of the larger side."""
+        return float(max(_elems(bottom_shapes), _elems(top_shapes)))
 
 
 # --------------------------------------------------------------------------- #
@@ -201,6 +233,13 @@ class ConvolutionLayer(Layer):
                           strategy=self.conv_strategy)
                 for x in bottoms]
 
+    def forward_flops(self, bottom_shapes, top_shapes, defs):
+        if not defs or len(defs[0].shape) != 4:
+            return super().forward_flops(bottom_shapes, top_shapes, defs)
+        k, cg, r, s = defs[0].shape             # exact MACs, x2 for mul+add
+        n, _, ho, wo = top_shapes[0]
+        return 2.0 * n * ho * wo * k * cg * r * s
+
 
 # The names a gated unit's products carry (``checkpoint_name``): a product a
 # SILU_GATE reads, and the product that reads the SILU_GATE. ``Net`` says
@@ -253,6 +292,13 @@ class InnerProductLayer(Layer):
             # on the product itself: what reads it may be slices of it
             y = checkpoint_name(y, self.saved_as)
         return [y if self.axis == 1 else y.reshape(self.lead + y.shape[-1:])]
+
+    def forward_flops(self, bottom_shapes, top_shapes, defs):
+        if not defs:
+            return super().forward_flops(bottom_shapes, top_shapes, defs)
+        wcount = max((p.count for p in defs if len(p.shape) == 2),
+                     default=sum(p.count for p in defs))
+        return 2.0 * bottom_shapes[0][0] * wcount
 
 
 # --------------------------------------------------------------------------- #
@@ -410,6 +456,34 @@ class AttentionLayer(Layer):
                                else None, scale=ap.scale or None,
                                rotary_shared=ap.rotary_shared)]
 
+    def kernel_route(self, bottom_shapes, itemsize):
+        from ..ops.pallas_kernels import attention_route
+        ap = self.lp.attention_param
+        _, s, d = bottom_shapes[0]
+        d_head = d // ap.num_heads
+        arm, note = attention_route(s, s, d_head, itemsize, window=ap.window,
+                                    dv=ap.value_head_dim or None)
+        if arm == "pallas_flash":
+            # the tiles each flash kernel runs with and the live / visited
+            # programs of its grid: stats.yaml carries them
+            arm, note = f"{arm} ({note})", ""
+        if ap.num_kv_heads and ap.num_kv_heads != ap.num_heads:
+            # which way the key-value heads reach their query heads
+            arm += (f"; {ap.num_kv_heads} kv heads repeated x"
+                    f"{ap.num_heads // ap.num_kv_heads}")
+        if arm.startswith("dense") and 0 < ap.window < s:
+            arm += f"; window {ap.window} as a dense mask"
+        if not ap.rope:
+            arm += "; no positions"
+        if arm.startswith("dense") and ap.value_head_dim:
+            arm += f"; d {d_head}/{ap.value_head_dim}"
+        if len(bottom_shapes) == 4:
+            # latent attention: the one key part all heads share
+            arm += ("; k_pe rotated once, joined x" if ap.rotary_shared
+                    else "; k_pe repeated x") \
+                + f"{ap.num_kv_heads or ap.num_heads}"
+        return "attention", arm, note
+
 
 class MoELayer(Layer):
     """Bottom (N, S, D) -> top-k token-choice experts, dropless. The router
@@ -510,6 +584,39 @@ class MoELayer(Layer):
                      / jnp.maximum(jnp.sum(here), 1.0),
                      jnp.zeros((), jnp.float32), jnp.sum(here) / total]
         return tops + (stats + zero_share)[:len(self.lp.top) - self.n_fixed]
+
+    def _held_plan(self, bottom_shapes):
+        from ..models.moe import held_rows_plan
+        n, s, _ = bottom_shapes[0]
+        mp = self.lp.moe_param
+        return held_rows_plan(n * s * mp.top_k, self.held, mp.num_experts)
+
+    def kernel_route(self, bottom_shapes, itemsize):
+        from ..models.moe import GROUPED_MATMUL
+        arm = GROUPED_MATMUL
+        held = self._held_plan(bottom_shapes)
+        if held:
+            # the row work runs in chunks of the sorted assignments, as many
+            # trips as the live rows need (stats.yaml: held_chunk_trips)
+            arm += f"; held rows: chunks of {held[0]} of {held[2]}"
+        if self.lp.moe_param.activation != "silu":
+            arm += f"; act={self.lp.moe_param.activation}"
+        return "grouped_matmul", arm, "sorted by expert, dropless"
+
+    def stats_sections(self, bottom_shapes, itemsize):
+        mp = self.lp.moe_param
+        return {"expert_share": {"held_first": mp.held_first,
+                                 "num_held": self.held,
+                                 "router_num_experts": mp.num_experts}}
+
+    def display_counters(self, bottom_shapes):
+        # held rows in chunks and the held share displayed: the share says
+        # how many trips the step's held arm made
+        from ..models.moe import held_share_counts
+        held = self._held_plan(bottom_shapes)
+        if not held or len(self.lp.top) < self.n_fixed + 3:
+            return {}
+        return {self.lp.top[self.n_fixed + 2]: held_share_counts(*held)}
 
 
 class MoERouterLayer(Layer):
@@ -957,6 +1064,31 @@ class KDAScanLayer(Layer):
                 (lax.stop_gradient(beta) > 1).astype(jnp.float32)))
         return tops
 
+    def _widths(self, bottom_shapes):       # heads, a head's d_k and d_v
+        h = self.lp.kda_param.num_heads
+        return h, bottom_shapes[0][2] // h, bottom_shapes[2][2] // h
+
+    def kernel_route(self, bottom_shapes, itemsize):
+        from ..ops.kda import kda_route
+        h, d_k, d_v = self._widths(bottom_shapes)
+        # the route's note names the arm and, where it is not pallas, why
+        return "kda", kda_route(bottom_shapes[0][1], d_k, d_v, h, itemsize,
+                                per_head=self.per_head)[1], ""
+
+    def stats_sections(self, bottom_shapes, itemsize):
+        from ..ops.kda import kda_chunk, state_bytes
+        n, s, _ = bottom_shapes[0]
+        h, d_k, d_v = self._widths(bottom_shapes)
+        chunk = kda_chunk(s)
+        facts = {"heads": h, "d_k": d_k, "d_v": d_v, "chunk": chunk or 1,
+                 "chunks": s // (chunk or 1),
+                 "saved_state_bytes": state_bytes(
+                     n, s, h, d_k, d_v, self.per_head, itemsize)
+                 if chunk else 0}
+        if self.per_head:           # one decay a head (a channel: unsaid)
+            facts["decay"] = "head"
+        return {"recurrent_state": facts}
+
 
 class SSDScanLayer(Layer):
     """Mamba-2's selective scan. Bottoms x (N, S, H P), dt and a = dt A
@@ -993,6 +1125,26 @@ class SSDScanLayer(Layer):
         y = ssd_scan(heads, dt, a, b, c,
                      _tap_all(ctx, self.name, params)["D"])
         return [y.reshape(x.shape).astype(x.dtype)]
+
+    def kernel_route(self, bottom_shapes, itemsize):
+        from ..ops.ssd import ssd_route
+        h = self.lp.kda_param.num_heads
+        _, s, w = bottom_shapes[0]
+        # the note names the arm and, chunked on a shape the kernels
+        # refuse, the reason
+        return "ssd_scan", ssd_route(s, h, w // h, bottom_shapes[3][2])[1], ""
+
+    def stats_sections(self, bottom_shapes, itemsize):
+        from ..ops import ssd
+        # a state (P, N_state) a head, B and C shared
+        h = self.lp.kda_param.num_heads
+        (n, s, w), n_state = bottom_shapes[0], bottom_shapes[3][2]
+        chunk = ssd.scan_chunk(s, h, w // h, n_state)
+        return {"recurrent_state": {
+            "heads": h, "d_k": n_state, "d_v": w // h, "chunk": chunk or 1,
+            "chunks": s // (chunk or 1),
+            "saved_state_bytes": ssd.state_bytes(n, s, h, w // h, n_state),
+            "decay": "head"}}
 
 
 class SiLUGateLayer(Layer):
@@ -1051,6 +1203,18 @@ class PoolingLayer(Layer):
                                        lay)]
         raise ValueError(f"unknown pool method {self.method}")
 
+    def kernel_route(self, bottom_shapes, itemsize):
+        # the backward's arm: a TRAIN net's MAX and AVE pools have one
+        if self.phase != "TRAIN" or self.method not in ("MAX", "AVE"):
+            return None
+        return ("pool_bwd",) + tuple(NN.pool_bwd_route(
+            self.kernel, self.stride, self.pad, self.method.lower(),
+            bottom_shapes[0], itemsize))
+
+    def forward_flops(self, bottom_shapes, top_shapes, defs):
+        ksz = max(1, int(self.lp.pooling_param.kernel_size))
+        return float(_elems(top_shapes)) * ksz * ksz
+
 
 class LRNLayer(Layer):
     TYPE = "LRN"
@@ -1076,6 +1240,17 @@ class LRNLayer(Layer):
                                     layout=self.run_layout)]
         return [NN.lrn_within_channel(x, self.local_size, self.alpha,
                                       self.beta, self.run_layout)]
+
+    def kernel_route(self, bottom_shapes, itemsize):
+        if self.region != "ACROSS_CHANNELS":
+            return None
+        from ..ops.pallas_kernels import lrn_route
+        n, c, h, w = bottom_shapes[0]
+        return ("lrn",) + tuple(lrn_route(h * w, c, n, itemsize))
+
+    def forward_flops(self, bottom_shapes, top_shapes, defs):
+        return float(_elems(bottom_shapes)) \
+            * (2 * max(1, int(self.local_size)) + 4)
 
 
 class Im2colLayer(Layer):
@@ -1296,6 +1471,9 @@ class SoftmaxLayer(Layer):
         axis = _remap_axis(1, self.run_layout, bottoms[0].ndim)
         return [L.softmax(bottoms[0], axis=axis)]
 
+    def forward_flops(self, bottom_shapes, top_shapes, defs):
+        return 5.0 * _elems(bottom_shapes)
+
 
 class ArgMaxLayer(Layer):
     TYPE = "ARGMAX"
@@ -1340,6 +1518,8 @@ class SoftmaxLossLayer(Layer):
         if len(self.lp.top) >= 2:
             return [loss, L.softmax(bottoms[0], axis=axis)]
         return [loss]
+
+    forward_flops = SoftmaxLayer.forward_flops
 
 
 class SoftmaxNLLLayer(Layer):

@@ -33,6 +33,7 @@ consumes a conv's top folds into the conv's epilogue (``ops/nn.conv2d``'s
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -466,95 +467,23 @@ class Net:
                     f"im2col (the direct conv does not finish compiling "
                     f"for the TPU)")
 
+    def _bottom_shapes(self, layer: Layer) -> List[Shape]:
+        return [self.blob_shapes[b] for b in layer.lp.bottom]
+
     def _plan_kernel_routes(self) -> None:
-        """Which arm each pooling backward (TRAIN nets), cross-channel
-        LRN, ATTENTION and MOE layer lowers to — which XLA formulation or
-        which kernel — from the SAME functions the ops consult at trace
-        time, logged once per layer. The routing is by platform and shape,
-        which is legitimate;
-        what is not is a run that cannot say which arm it took."""
-        from ..ops.pallas_kernels import attention_route, lrn_route
+        """Which arm — which XLA formulation or which kernel — each layer
+        that has more than one lowers to, as the layer says
+        (``Layer.kernel_route``), logged once per layer. The routing is by
+        platform and shape, which is legitimate; what is not is a run that
+        cannot say which arm it took."""
         from ..runtime.metrics import log
+        itemsize = jnp.dtype(policy().compute_dtype).itemsize
         self.kernel_routes: Dict[str, str] = {}
         for layer in self.layers:
-            shape = (self.blob_shapes[layer.lp.bottom[0]]
-                     if layer.lp.bottom else ())
-            if layer.TYPE == "POOLING" and self.phase == "TRAIN" \
-                    and layer.method in ("MAX", "AVE"):
-                what = "pool_bwd"
-                arm, note = NN.pool_bwd_route(
-                    layer.kernel, layer.stride, layer.pad,
-                    layer.method.lower(), shape,
-                    jnp.dtype(policy().compute_dtype).itemsize)
-            elif layer.TYPE == "LRN" and layer.region == "ACROSS_CHANNELS":
-                what = "lrn"
-                arm, note = lrn_route(
-                    shape[2] * shape[3], shape[1], shape[0],
-                    jnp.dtype(policy().compute_dtype).itemsize)
-            elif layer.TYPE == "ATTENTION":
-                what = "attention"
-                arm, note = attention_route(
-                    shape[1], shape[1],
-                    shape[2] // layer.lp.attention_param.num_heads,
-                    jnp.dtype(policy().compute_dtype).itemsize,
-                    window=layer.lp.attention_param.window,
-                    dv=layer.lp.attention_param.value_head_dim or None)
-                if arm == "pallas_flash":
-                    # the tiles each flash kernel runs with and the live /
-                    # visited programs of its grid: stats.yaml carries them
-                    arm = f"{arm} ({note})"
-                    note = ""
-                ap = layer.lp.attention_param
-                if ap.num_kv_heads and ap.num_kv_heads != ap.num_heads:
-                    # grouped-query attention: which way the key-value
-                    # heads reach their query heads
-                    arm += (f"; {ap.num_kv_heads} kv heads repeated x"
-                            f"{ap.num_heads // ap.num_kv_heads}")
-                if arm.startswith("dense") and 0 < ap.window < shape[1]:
-                    arm += f"; window {ap.window} as a dense mask"
-                if not ap.rope:
-                    arm += "; no positions"
-                if arm.startswith("dense") and ap.value_head_dim:
-                    arm += (f"; d {shape[2] // ap.num_heads}/"
-                            f"{ap.value_head_dim}")
-                if len(layer.lp.bottom) == 4:
-                    # latent attention: the one key part all heads share
-                    arm += ("; k_pe rotated once, joined x"
-                            if ap.rotary_shared else "; k_pe repeated x") \
-                        + f"{ap.num_kv_heads or ap.num_heads}"
-            elif layer.TYPE == "KDA_SCAN":
-                from ..ops.kda import kda_route
-                what = "kda"
-                h = layer.lp.kda_param.num_heads
-                # the note names the arm and, where it is not pallas, why
-                arm, note = kda_route(
-                    shape[1], shape[2] // h,
-                    self.blob_shapes[layer.lp.bottom[2]][2] // h, h,
-                    jnp.dtype(policy().compute_dtype).itemsize,
-                    per_head=layer.per_head)[1], ""
-            elif layer.TYPE == "SSD_SCAN":
-                from ..ops.ssd import ssd_route
-                what = "ssd_scan"
-                h = layer.lp.kda_param.num_heads
-                # the note names the arm and, where it is chunked on a shape
-                # the kernels refuse, the reason
-                arm, note = ssd_route(
-                    shape[1], h, shape[2] // h,
-                    self.blob_shapes[layer.lp.bottom[3]][2])[1], ""
-            elif layer.TYPE == "MOE":
-                from ..models.moe import GROUPED_MATMUL
-                what = "grouped_matmul"
-                arm, note = GROUPED_MATMUL, "sorted by expert, dropless"
-                held = self._held_rows(layer)
-                if held:
-                    # a share of the experts held: the row work runs in
-                    # chunks of the sorted assignments, as many trips as
-                    # the live rows need (stats.yaml: held_row_fill)
-                    arm += f"; held rows: chunks of {held[0]} of {held[2]}"
-                if layer.lp.moe_param.activation != "silu":
-                    arm += f"; act={layer.lp.moe_param.activation}"
-            else:
+            route = layer.kernel_route(self._bottom_shapes(layer), itemsize)
+            if route is None:
                 continue
+            what, arm, note = route
             if arm == "pallas" and note:
                 # the orientation and block the pool / LRN kernels run with
                 arm, note = f"{arm} ({note})", ""
@@ -562,77 +491,59 @@ class Net:
             log(f"[kernel_route] {layer.name}: {what} -> {arm}"
                 + (f" ({note})" if note else ""))
 
-    def expert_share(self) -> Dict[str, Dict[str, int]]:
-        """{MOE layer: which of the router's experts it holds} — stats.yaml's
-        ``expert_share`` section."""
-        return {l.name: {"held_first": l.lp.moe_param.held_first,
-                         "num_held": l.held,
-                         "router_num_experts": l.lp.moe_param.num_experts}
-                for l in self.layers if l.TYPE == "MOE"}
-
-    def recurrent_state(self) -> Dict[str, Dict[str, int]]:
-        """{KDA_SCAN or SSD_SCAN layer: its states' shape, the scan's chunk
-        and what its backward keeps of them} — stats.yaml's
-        ``recurrent_state`` section."""
-        from ..ops import ssd
-        from ..ops.kda import kda_chunk, state_bytes
-        out = {}
-        for l in self.layers:
-            if l.TYPE == "SSD_SCAN":
-                # Mamba-2: a state (P, N_state) a head, B and C shared
-                (n, s, w), (_, _, n_state) = (self.blob_shapes[b]
-                                              for b in l.lp.bottom[:4:3])
-                h = l.lp.kda_param.num_heads
-                chunk = ssd.scan_chunk(s, h, w // h, n_state)
-                out[l.name] = {
-                    "heads": h, "d_k": n_state, "d_v": w // h,
-                    "chunk": chunk or 1, "chunks": s // (chunk or 1),
-                    "saved_state_bytes": ssd.state_bytes(
-                        n, s, h, w // h, n_state),
-                    "decay": "head"}
-                continue
-            if l.TYPE != "KDA_SCAN":
-                continue
-            (n, s, wk), (_, _, wv) = (self.blob_shapes[b]
-                                      for b in l.lp.bottom[:3:2])
-            h = l.lp.kda_param.num_heads
-            chunk = kda_chunk(s)
-            out[l.name] = {
-                "heads": h, "d_k": wk // h, "d_v": wv // h,
-                "chunk": chunk or 1, "chunks": s // (chunk or 1),
-                "saved_state_bytes": state_bytes(
-                    n, s, h, wk // h, wv // h, l.per_head,
-                    jnp.dtype(policy().compute_dtype).itemsize)
-                if chunk else 0}
-            if l.per_head:          # one decay a head (a channel: unsaid)
-                out[l.name]["decay"] = "head"
+    def layer_facts(self) -> Dict[str, Dict[str, Dict]]:
+        """{section of stats.yaml: {layer: its facts}} as the layers state
+        them (``Layer.stats_sections``): ``expert_share``, which of the
+        router's experts a MOE layer holds; ``recurrent_state``, a scan's
+        states' shape, its chunk and what its backward keeps of them."""
+        itemsize = jnp.dtype(policy().compute_dtype).itemsize
+        out: Dict[str, Dict[str, Dict]] = {}
+        for layer in self.layers:
+            for section, facts in layer.stats_sections(
+                    self._bottom_shapes(layer), itemsize).items():
+                out.setdefault(section, {})[layer.name] = facts
         return out
 
-    def _held_rows(self, layer: Layer) -> Optional[Tuple[int, int, int]]:
-        """(the chunk of sorted rows a trip of a MOE layer's held arm takes,
-        ``models/moe.held_chunk_rows``; twice the even share of its T k
-        assignments, in whole row tiles; T k), or None where the layer
-        holds every expert or runs its rows as straight-line code
-        (``models/moe.held_rows_loop``)."""
-        from ..models.moe import _ROW_TILE, held_chunk_rows, held_rows_loop
-        n, s, _ = self.blob_shapes[layer.lp.bottom[0]]
-        mp = layer.lp.moe_param
-        rows = n * s * mp.top_k
-        chunk = held_chunk_rows(rows, layer.held, mp.num_experts)
-        if not held_rows_loop(rows, chunk):     # every expert held: one chunk
-            return None
-        twice = -(-2 * rows * layer.held // (mp.num_experts * _ROW_TILE))
-        return chunk, twice * _ROW_TILE, rows
+    def display_counters(self) -> Dict[str, object]:
+        """{a displayed top: its value of one step -> {counter: increment}}
+        over every layer (``Layer.display_counters``)."""
+        return {top: count for layer in self.layers
+                for top, count in layer.display_counters(
+                    self._bottom_shapes(layer)).items()}
 
-    def held_row_ladders(self) -> Dict[str, Tuple[int, int, int]]:
-        """{a MOE layer's held-share top: (chunk rows, twice the even share,
-        all T k rows)} for the layers whose held rows run in chunks and that
-        publish their held share: what a display's reader needs to count
-        the trips a step's held arm made and the rows they ran."""
-        held = {l.lp.top[l.n_fixed + 2]: self._held_rows(l)
-                for l in self.layers
-                if l.TYPE == "MOE" and len(l.lp.top) >= l.n_fixed + 3}
-        return {top: rows for top, rows in held.items() if rows}
+    def cost_table(self, dtype_bytes: int = 4) -> Dict[str, Dict]:
+        """{layer: {flops, bytes, act_bytes, intensity}} for one train step
+        (fwd+bwd), from blob/param shapes — the analytic model the
+        attribution table's FLOPs column joins from (XLA's cost_analysis
+        reports only the whole-module total). Each layer states its forward
+        FLOPs (``Layer.forward_flops``): conv/FC exact MAC counts,
+        pool/LRN/elementwise per-element op estimates — they exist to rank
+        sinks and compute intensity, not to be a simulator. Backward = dW +
+        dX = 2x forward. Bytes = activations in + out + params, x3 for the
+        backward's re-reads and gradient writes.
+
+        ``act_bytes`` is the layer's STORED forward activation footprint —
+        the top blobs autodiff keeps live until the backward pass consumes
+        them. It is the per-layer column core/remat.py's budget knapsack
+        ranks against recompute FLOPs; an in-place top (same name as a
+        bottom) still counts once, matching what the trace stores."""
+        out: Dict[str, Dict] = {}
+        for layer in self.layers:
+            bots = self._bottom_shapes(layer)
+            tops = [self.blob_shapes[t] for t in layer.lp.top]
+            defs = self.param_defs.get(layer.name, [])
+            out_elems = sum(math.prod(t) for t in tops)
+            moved = sum(math.prod(b) for b in bots) + out_elems \
+                + sum(p.count for p in defs)
+            flops = 3.0 * layer.forward_flops(bots, tops, defs)
+            bytes_ = 3.0 * moved * dtype_bytes
+            out[layer.name] = {
+                "flops": flops,
+                "bytes": bytes_,
+                "act_bytes": int(out_elems) * int(dtype_bytes),
+                "intensity": round(flops / bytes_, 3) if bytes_ else None,
+            }
+        return out
 
     def conv_strategy_plan(self) -> Dict[str, Optional[str]]:
         """{conv layer name: resolved strategy} — what bench/tests print."""

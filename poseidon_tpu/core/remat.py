@@ -10,8 +10,8 @@ arXiv:1605.08695), so the choice of WHICH activations to drop is made
 from measured numbers, not vibes:
 
 - the analytic side is the ``act_bytes`` column of
-  ``runtime/attribution.layer_cost_table`` — each layer's stored forward
-  activation footprint, priced against its forward recompute FLOPs;
+  ``core/net.Net.cost_table`` — each layer's stored forward activation
+  footprint, priced against its forward recompute FLOPs;
 - the measured side is the compiled no-remat step's real
   ``compiled.memory_analysis()`` peak (the same call
   scripts/aot_tpu_check.py records per mesh arm), which anchors how many
@@ -152,8 +152,8 @@ def keep_rungs(made=None) -> List[Tuple[str, ...]]:
     ends on is the Engine's to say (``_compile_step``): the first whose
     COMPILED step is within the budget, and a rung whose floor (the
     arguments + the units' stored inputs + its kept bytes, all read off the
-    trace: ``runtime/attribution.unit_residuals``) already exceeds it is
-    passed over without a compile."""
+    trace by the Engine's ``unit_residuals``) already exceeds it is passed
+    over without a compile."""
     from ..ops.kda import SCAN_SAVED
     from ..ops.pallas_kernels import FLASH_SAVED
     from .layers import FFN_SAVED
@@ -208,10 +208,6 @@ class RematPlan:
     # replaying the kernel that wrote them (``keep_rungs``); none: a unit
     # keeps only what it takes from outside
     keep: Tuple[str, ...] = ()
-
-    @property
-    def layer_set(self) -> frozenset:
-        return frozenset(self.layers)
 
     @property
     def units(self) -> Tuple:
@@ -342,7 +338,7 @@ def plan_remat(cost_table: Dict[str, Dict], budget_bytes: int,
                source: str = "analytic") -> RematPlan:
     """The greedy cheapest-recompute-per-byte knapsack.
 
-    ``cost_table`` is ``attribution.layer_cost_table(net)`` (the
+    ``cost_table`` is ``net.cost_table()`` (the
     ``act_bytes`` + ``flops`` columns); ``peak_bytes`` is the NO-remat
     step's peak — measured via :func:`measured_peak_bytes` when a
     compile is affordable, else the analytic activation total. Layers
@@ -440,10 +436,9 @@ def plan_for_net_step(net, lowerable, example_args: tuple,
     remat is a trace-time property, so the no-remat compile is the
     price of measuring (paid once per job config; the tuned store
     memoizes the decision across processes)."""
-    from ..runtime.attribution import layer_cost_table
     compiled = lowerable.lower(*example_args).compile()
     peak = measured_peak_bytes(compiled)
-    table = layer_cost_table(net)
+    table = net.cost_table()
     if peak <= 0:
         # no memory API: fall back to the analytic activation total so a
         # budget still produces a usable (if uncalibrated) plan

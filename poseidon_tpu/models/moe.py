@@ -383,6 +383,36 @@ def held_rows_loop(rows: int, chunk: int) -> bool:
     return -(-rows // chunk) > 2
 
 
+def held_rows_plan(rows: int, n_held: int, n_exp: int
+                   ) -> Optional[Tuple[int, int, int]]:
+    """What ``expert_ffn`` does with the ``rows`` = T k assignments of a
+    layer holding ``n_held`` of ``n_exp`` experts, for who names or counts
+    it: (the chunk a trip of its loop takes; twice the even share of the
+    rows, in whole row tiles; T k), or None where the rows run as
+    straight-line code (``held_rows_loop``)."""
+    chunk = held_chunk_rows(rows, n_held, n_exp)
+    if not held_rows_loop(rows, chunk):
+        return None
+    twice = -(-2 * rows * n_held // (n_exp * _ROW_TILE))
+    return chunk, twice * _ROW_TILE, rows
+
+
+def held_share_counts(chunk: int, twice_even: int, rows: int):
+    """``_trips`` read from the host: from the held share a layer of this
+    ``held_rows_plan`` displays for one step to what that step's held arm
+    did, as increments of stats.yaml's counters. The live rows are the
+    share times T k, exactly, and the loop ran the chunks that hold them;
+    ``held_prefix_hits`` is a fact of the routing: live rows at most twice
+    the even share."""
+    def counts(share: float) -> Dict[str, float]:
+        live = round(share * rows)
+        trips = -(-live // chunk)
+        return {"held_chunk_trips": trips, "held_rows_run": trips * chunk,
+                "held_rows_live": live, "held_layer_steps": 1,
+                "held_prefix_hits": live <= twice_even}
+    return counts
+
+
 def _held_rows(x, weights, here, order, sizes, gate, up, down,
                act: str = "silu", gate_zeros: bool = False):
     """The held arm's row work over ALL T k sorted assignments, as

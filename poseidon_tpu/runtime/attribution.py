@@ -23,7 +23,7 @@ top-3 sinks). The pipeline:
    ``step_scopes`` (``step_scopes`` below), which is what the benchmark's
    per-pass and per-layer-type metrics join a device trace with.
 4. ``attribute`` folds event durations into a per-layer table — fwd/bwd
-   ms, %-of-traced-op-time, analytic FLOPs (``layer_cost_table``), arithmetic
+   ms, %-of-traced-op-time, analytic FLOPs (``Net.cost_table``), arithmetic
    intensity, per-layer MFU against a peak — with an ``(unattributed)``
    residual row so coverage is honest: named rows + residual always sum
    to the traced op time.
@@ -44,8 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 __all__ = [
     "load_trace_events", "trace_events_from_xspace", "hlo_scope_map",
     "step_scopes", "param_relayouts", "scope_of", "comm_axis_of",
-    "layer_cost_table",
-    "attribute", "format_table", "measure_then_trace",
+    "attribute", "measure_then_trace",
 ]
 
 
@@ -546,75 +545,6 @@ def _resolve_scopes(hlo_text: str, index: "_ScopeIndex"):
 
 
 # --------------------------------------------------------------------------- #
-# analytic per-layer cost model (FLOPs + bytes -> arithmetic intensity)
-# --------------------------------------------------------------------------- #
-
-def _shape_elems(shape) -> int:
-    n = 1
-    for d in shape:
-        n *= int(d)
-    return n
-
-
-def layer_cost_table(net, dtype_bytes: int = 4) -> Dict[str, Dict]:
-    """{layer: {flops, bytes, act_bytes, intensity}} for one train step
-    (fwd+bwd), from blob/param shapes — the analytic model the FLOPs
-    column joins from (XLA's cost_analysis reports only the whole-module
-    total).
-
-    Conv/FC are exact MAC counts (x2 for mul+add; backward = dW + dX =
-    2x forward). Pool/LRN/elementwise are per-element op estimates —
-    they exist to rank sinks and compute intensity, not to be a
-    simulator. Bytes = activations in + out + params, x3 for the
-    backward's re-reads and gradient writes.
-
-    ``act_bytes`` is the layer's STORED forward activation footprint —
-    the top blobs autodiff keeps live until the backward pass consumes
-    them. It is the per-layer column core/remat.py's budget knapsack
-    ranks against recompute FLOPs; an in-place top (same name as a
-    bottom) still counts once, matching what the trace stores."""
-    out: Dict[str, Dict] = {}
-    for layer in net.layers:
-        lp = layer.lp
-        tops = [net.blob_shapes[t] for t in lp.top if t in net.blob_shapes]
-        bots = [net.blob_shapes[b] for b in lp.bottom
-                if b in net.blob_shapes]
-        out_elems = sum(_shape_elems(s) for s in tops)
-        in_elems = sum(_shape_elems(s) for s in bots)
-        defs = net.param_defs.get(layer.name, [])
-        pcount = sum(p.count for p in defs)
-        t = layer.TYPE
-        if t == "CONVOLUTION" and defs and len(defs[0].shape) == 4:
-            k, cg, r, s = defs[0].shape
-            n, _, ho, wo = tops[0]
-            fwd = 2.0 * n * ho * wo * k * cg * r * s
-        elif t in ("INNER_PRODUCT",) and defs:
-            batch = bots[0][0] if bots else 1
-            wcount = max((p.count for p in defs if len(p.shape) == 2),
-                         default=pcount)
-            fwd = 2.0 * batch * wcount
-        elif t == "POOLING":
-            ksz = max(1, int(getattr(lp.pooling_param, "kernel_size", 2)))
-            fwd = float(out_elems) * ksz * ksz
-        elif t == "LRN":
-            local = max(1, int(getattr(lp.lrn_param, "local_size", 5)))
-            fwd = float(in_elems) * (2 * local + 4)
-        elif t in ("SOFTMAX", "SOFTMAX_LOSS"):
-            fwd = 5.0 * in_elems
-        else:
-            fwd = float(max(in_elems, out_elems))
-        flops = 3.0 * fwd                       # fwd + (dW + dX) backward
-        bytes_ = 3.0 * (in_elems + out_elems + pcount) * dtype_bytes
-        out[layer.name] = {
-            "flops": flops,
-            "bytes": bytes_,
-            "act_bytes": int(out_elems) * int(dtype_bytes),
-            "intensity": round(flops / bytes_, 3) if bytes_ else None,
-        }
-    return out
-
-
-# --------------------------------------------------------------------------- #
 # the attribution table
 # --------------------------------------------------------------------------- #
 
@@ -766,32 +696,6 @@ def attribute(events: Sequence[Dict], scope_map: Dict[str, Tuple[str, str]],
         "tracer_overhead_ms_stripped": round(per_event_oh * len(ops) / 1e3,
                                              3),
     }
-
-
-def format_table(result: Dict, title: str = "") -> str:
-    """Human-readable rendering of one attribution result."""
-    lines = []
-    if title:
-        lines.append(title)
-    hdr = (f"{'layer':<28}{'fwd ms':>9}{'bwd ms':>9}{'total':>9}"
-           f"{'%traced':>8}{'GFLOPs':>9}{'F/B':>7}{'MFU':>7}")
-    lines.append(hdr)
-    lines.append("-" * len(hdr))
-    for r in result["rows"]:
-        gf = r.get("flops")
-        lines.append(
-            f"{r['layer']:<28}{r['fwd_ms']:>9.3f}{r['bwd_ms']:>9.3f}"
-            f"{r['total_ms']:>9.3f}{r['pct_of_traced']:>8.2f}"
-            f"{(gf / 1e9 if gf else 0):>9.2f}"
-            f"{(r.get('intensity') or 0):>7.1f}"
-            f"{(r.get('mfu') if r.get('mfu') is not None else float('nan')):>7.3f}")
-    res = result["residual"]
-    lines.append(f"{res['layer']:<28}{'':>9}{'':>9}"
-                 f"{res['total_ms']:>9.3f}{res['pct_of_traced']:>8.2f}")
-    lines.append(f"named coverage: {result['coverage']:.1%} of "
-                 f"{result['total_ms']:.3f} ms traced op time; top sinks: "
-                 f"{', '.join(result['top_sinks'])}")
-    return "\n".join(lines)
 
 
 # --------------------------------------------------------------------------- #

@@ -501,16 +501,11 @@ class Engine:
             self.stats.set_section("shared_params", {
                 name: f"{d['owner']} x{d['uses']}" for name, d in
                 self.train_net.shared_params.items()})
-        expert_share = self.train_net.expert_share()
-        if expert_share:
-            self.stats.set_section("expert_share", expert_share)
-        recurrent_state = self.train_net.recurrent_state()
-        if recurrent_state:
-            self.stats.set_section("recurrent_state", recurrent_state)
-        # how many chunks of its sorted rows a held MOE layer's step ran is
-        # read off the held share it displays (_absorb):
-        # {top: (chunk, twice the even share, rows)}
-        self._held_ladders = self.train_net.held_row_ladders()
+        # what the layers state of themselves (expert_share,
+        # recurrent_state), and what a top they display counts for (_absorb)
+        for section, facts in self.train_net.layer_facts().items():
+            self.stats.set_section(section, facts)
+        self._display_counters = self.train_net.display_counters()
         self.stats.set_section("data_reader", {
             p.tops[0]: ("native" if getattr(p, "native", None) is not None
                         else "python")
@@ -689,8 +684,7 @@ class Engine:
           no memory stats (the CPU proxy needs an explicit budget).
         """
         from ..core import remat as remat_mod
-        from .attribution import layer_cost_table
-        table = layer_cost_table(self.train_net)
+        table = self.train_net.cost_table()
         names = [s.strip() for s in str(remat or "").split(",")
                  if s.strip() and s.strip().lower() not in ("none",
                                                             "auto")]
@@ -1354,20 +1348,10 @@ class Engine:
         for row_it, row in rows:
             self.metrics.accumulate(row)
             last = row
-            for top, (chunk, prefix, total) in self._held_ladders.items():
-                if top not in row:
-                    continue
-                # the live rows are the share times T k, exactly, and the
-                # held arm ran the chunks that hold them (models/moe)
-                live = round(row[top] * total)
-                trips = -(-live // chunk)
-                self.stats.add("held_chunk_trips", trips)
-                self.stats.add("held_rows_run", trips * chunk)
-                self.stats.add("held_rows_live", live)
-                # a fact of the routing: the layer-steps whose live rows
-                # number at most twice the even share
-                self.stats.add("held_layer_steps")
-                self.stats.add("held_prefix_hits", live <= prefix)
+            for top, counts in self._display_counters.items():
+                if top in row:
+                    for name, by in counts(row[top]).items():
+                        self.stats.add(name, by)
             if self._displays and self._displays[0][0] == row_it + 1:
                 self._display(*self._displays.popleft())
         return last
@@ -1394,12 +1378,6 @@ class Engine:
         for k, v in row.items():
             if k not in ("iter", "time"):
                 self.stats.set_gauge(f"train_{k}", round(v, 6))
-        if self._held_ladders:
-            # so far (the counters beside it hold the counts): live rows
-            # over the rows the held arms' chunks ran (1 = no padding)
-            done = self.stats.counters
-            self.stats.set_gauge("held_row_fill", round(
-                done["held_rows_live"] / max(done["held_rows_run"], 1.0), 6))
         with span_recorder.span("telemetry_dump", "artifact", {"iter": it}):
             self._dump_live_telemetry()
         if self._async_tier is not None:

@@ -850,7 +850,6 @@ def section_memory(topo) -> dict:
                                             build_spmd_train_step,
                                             sharded_state_avals)
     from poseidon_tpu.proto.messages import SolverParameter
-    from poseidon_tpu.runtime.attribution import layer_cost_table
 
     def mem(compiled) -> dict:
         ma = compiled.memory_analysis()
@@ -884,7 +883,7 @@ def section_memory(topo) -> dict:
     rng_aval = jax.ShapeDtypeStruct(
         (2,), jnp.uint32, sharding=NamedSharding(mesh, P()))
     max_plan = remat_mod.plan_remat(
-        layer_cost_table(net), 0, 0,
+        net.cost_table(), 0, 0,
         candidates=remat_mod.remat_candidates(net), source="plan")
     for arm, rp in (("no_remat", None), ("max_remat", max_plan)):
         t0 = time.time()

@@ -1137,24 +1137,22 @@ def test_thread_excepthook_records():
 # --------------------------------------------------------------------------- #
 
 # what the lower packages may take from poseidon_tpu.runtime: the logger
-# and the span recorder (both jax-free leaves of runtime/), and the one
-# named debt, core/remat.py's use of the attribution table (ROADMAP D13)
+# and the span recorder (both jax-free leaves of runtime/); no named debt
+# is left
 _RUNTIME_ALLOWED = {
     "poseidon_tpu.runtime.metrics.log",
     "poseidon_tpu.runtime.spans.recorder",
 }
-_RUNTIME_DEBTS = {
-    ("poseidon_tpu/core/remat.py",
-     "poseidon_tpu.runtime.attribution.layer_cost_table"),
-}
+_RUNTIME_DEBTS = set()
 LOWER_PACKAGES = ["numeric.py", "config.py", "ops", "solvers", "proto",
                   "core", "data"]
 
 
-def _runtime_imports(path: str, source: str = None):
-    """(line, dotted name) of everything ``path`` imports from
-    poseidon_tpu.runtime, relative imports resolved, function-level
-    imports included (an ``ast.walk`` sees every statement)."""
+def _imports_of(path: str, source: str = None,
+                of: str = "poseidon_tpu.runtime"):
+    """(line, dotted name) of everything ``path`` imports from the package
+    ``of``, relative imports resolved, function-level imports included (an
+    ``ast.walk`` sees every statement)."""
     import ast
     pkg = os.path.relpath(path, REPO)[:-3].split(os.sep)[:-1]
     if source is None:
@@ -1171,8 +1169,7 @@ def _runtime_imports(path: str, source: str = None):
         else:
             continue
         found += [(node.lineno, n) for n in names
-                  if n == "poseidon_tpu.runtime"
-                  or n.startswith("poseidon_tpu.runtime.")]
+                  if n == of or n.startswith(of + ".")]
     return found
 
 
@@ -1187,7 +1184,7 @@ def test_lower_packages_do_not_import_runtime(package):
     for path in files:
         rel = os.path.relpath(path, REPO)
         bad += [f"{rel}:{line} imports {name}"
-                for line, name in _runtime_imports(path)
+                for line, name in _imports_of(path)
                 if name not in _RUNTIME_ALLOWED
                 and (rel, name) not in _RUNTIME_DEBTS]
     assert not bad, "\n".join(bad)
@@ -1206,7 +1203,7 @@ def test_layering_walk_sees_function_level_and_relative_imports():
             from . import runtime
             from .ops import nn
     """)
-    assert [n for _, n in _runtime_imports(path, src)] == [
+    assert [n for _, n in _imports_of(path, src)] == [
         "poseidon_tpu.runtime.metrics.log",
         "poseidon_tpu.runtime.engine",
         "poseidon_tpu.runtime.engine.Engine",
@@ -1215,6 +1212,92 @@ def test_layering_walk_sees_function_level_and_relative_imports():
         "poseidon_tpu.runtime",
     ]
     deep = os.path.join(REPO, "poseidon_tpu", "ops", "nn.py")
-    assert [n for _, n in _runtime_imports(
+    assert [n for _, n in _imports_of(
         deep, "def f():\n    from ..runtime.tools import x\n")] == [
         "poseidon_tpu.runtime.tools.x"]
+
+
+def test_core_imports_models_only_from_the_layer_catalog():
+    """core/ sits below models/. The layer catalog calls its layers'
+    mathematics where it lives today (ROADMAP D21); ``Net``, the remat
+    planner and the rest of core/ ask the layers and import no model."""
+    files = iter_python_files([os.path.join(REPO, "poseidon_tpu", "core")])
+    assert files
+    bad = [f"{os.path.relpath(path, REPO)}:{line} imports {name}"
+           for path in files if os.path.basename(path) != "layers.py"
+           for line, name in _imports_of(path, of="poseidon_tpu.models")]
+    assert not bad, "\n".join(bad)
+    seen = _imports_of(
+        os.path.join(REPO, "poseidon_tpu", "core", "net.py"),
+        "def f():\n    from ..models.moe import GROUPED_MATMUL\n",
+        of="poseidon_tpu.models")
+    assert [n for _, n in seen] == ["poseidon_tpu.models.moe.GROUPED_MATMUL"]
+
+
+# the layer types whose routes, stats sections, counters and FLOPs their
+# classes state (``Layer.kernel_route`` / ``stats_sections`` /
+# ``display_counters`` / ``forward_flops``)
+_SELF_DESCRIBING = {"POOLING", "LRN", "ATTENTION", "KDA_SCAN", "SSD_SCAN",
+                    "MOE", "SOFTMAX"}
+
+
+def _type_switches(source: str):
+    """(line, type name) of every comparison of a ``.TYPE`` attribute (or
+    of a name bound to one in the same module, ``t = layer.TYPE``) with one
+    of ``_SELF_DESCRIBING``, alone or in a tuple, list or set."""
+    import ast
+    tree = ast.parse(source)
+
+    def is_type(node):
+        return isinstance(node, ast.Attribute) and node.attr == "TYPE"
+
+    aliases = {t.id for node in ast.walk(tree)
+               if isinstance(node, ast.Assign) and is_type(node.value)
+               for t in node.targets if isinstance(t, ast.Name)}
+    found = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Compare):
+            continue
+        sides = [node.left] + list(node.comparators)
+        if not any(is_type(x) or (isinstance(x, ast.Name)
+                                  and x.id in aliases) for x in sides):
+            continue
+        for side in sides:
+            items = side.elts if isinstance(
+                side, (ast.Tuple, ast.List, ast.Set)) else [side]
+            found += [(node.lineno, c.value) for c in items
+                      if isinstance(c, ast.Constant)
+                      and c.value in _SELF_DESCRIBING]
+    return found
+
+
+def test_no_module_switches_on_a_self_describing_layer_type():
+    """What is true of one layer type lives in its class: outside
+    core/layers.py nothing in the package asks a layer whether it is a
+    POOLING, LRN, ATTENTION, KDA_SCAN, SSD_SCAN, MOE or SOFTMAX layer. (The
+    graph-pattern passes match CONVOLUTION / RELU / INNER_PRODUCT / SLICE /
+    SILU_GATE / HDF5_OUTPUT: shapes of the GRAPH, which no one layer
+    knows.)"""
+    files = iter_python_files([os.path.join(REPO, "poseidon_tpu")])
+    bad = []
+    for path in files:
+        rel = os.path.relpath(path, REPO)
+        if rel == os.path.join("poseidon_tpu", "core", "layers.py"):
+            continue
+        with open(path) as f:
+            bad += [f"{rel}:{line} compares a layer's TYPE with {name!r}"
+                    for line, name in _type_switches(f.read())]
+    assert not bad, "\n".join(bad)
+    # the rule fires on the spellings it exists to stop
+    assert [n for _, n in _type_switches(textwrap.dedent("""
+        def plan(net):
+            for layer in net.layers:
+                t = layer.TYPE
+                if layer.TYPE == "POOLING" and layer.method == "MAX":
+                    pass
+                elif t in ("SOFTMAX", "SOFTMAX_LOSS"):
+                    pass
+                elif "MOE" != layer.TYPE or layer.TYPE == "CONVOLUTION":
+                    pass
+    """))] == ["POOLING", "SOFTMAX", "MOE"]
+

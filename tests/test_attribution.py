@@ -322,7 +322,7 @@ def test_real_cpu_trace_attributes_lenet(tmp_path):
     smap = A.hlo_scope_map(compiled.as_text(),
                            {layer.name for layer in net.layers})
     out = A.attribute(
-        events, smap, cost_table=A.layer_cost_table(net),
+        events, smap, cost_table=net.cost_table(),
         tracer_overhead_ms=max(
             timing["traced_step_ms"] - timing["step_ms"], 0.0))
     assert out["total_ms"] > 0
@@ -334,7 +334,7 @@ def test_real_cpu_trace_attributes_lenet(tmp_path):
 
 def test_layer_cost_table_conv_and_fc_flops():
     net = _lenet_net(4)
-    table = A.layer_cost_table(net)
+    table = net.cost_table()
     # conv1: 20 filters of 1x5x5 over 24x24 outputs, batch 4, x3 fwd+bwd
     assert table["conv1"]["flops"] == pytest.approx(
         3 * 2 * 4 * 24 * 24 * 20 * 25)

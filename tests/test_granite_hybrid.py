@@ -232,7 +232,7 @@ def test_leaves_scopes_and_routes(model):
             "chunk; not pallas: neither 256 nor 128 divides S=48")
     assert net.kernel_routes["l5_attn_sdpa"] == \
         "attention=dense; 2 kv heads repeated x4; no positions"
-    assert net.recurrent_state() == {
+    assert net.layer_facts()["recurrent_state"] == {
         f"l{i}_ssd_scan": {"heads": 16, "d_k": 16, "d_v": 8, "chunk": 16,
                            "chunks": 3, "decay": "head", "saved_state_bytes":
                            N * 16 * 3 * 8 * 16 * 4} for i in MAMBA}

@@ -382,7 +382,7 @@ def test_route_by_backend_and_shape(monkeypatch):
     for i in KDA_LAYERS:
         assert net.kernel_routes[f"l{i}_kda_scan"] == (
             "kda=pallas (C 64 x 2, 2 chunks, f32 state in VMEM)")
-    assert net.recurrent_state()["l0_kda_scan"] == {
+    assert net.layer_facts()["recurrent_state"]["l0_kda_scan"] == {
         "heads": 2, "d_k": 128, "d_v": 128, "chunk": 64, "chunks": 2,
         "saved_state_bytes": 2 * 2 * 128 * 128 * 4}
 
@@ -471,7 +471,7 @@ def test_leaves_scopes_and_routes(model):
                 "of 128)")
     assert net.kernel_routes["l3_mla_attn"] == (
         "attention=dense; no positions; d 24/16; k_pe repeated x4")
-    assert net.recurrent_state() == {
+    assert net.layer_facts()["recurrent_state"] == {
         f"l{i}_kda_scan": {"heads": 4, "d_k": 16, "d_v": 16, "chunk": 64,
                            "chunks": 2, "saved_state_bytes":
                            N * 4 * 2 * 16 * 16 * 4} for i in KDA_LAYERS}

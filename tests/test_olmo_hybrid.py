@@ -256,7 +256,7 @@ def test_leaves_scopes_and_routes(model):
             "kda=chunked C 64, 2 chunks, f32 state, one decay a head; not "
             "pallas: this backend would interpret the kernels")
     assert net.kernel_routes["l3_attn_sdpa"] == "attention=dense; no positions"
-    assert net.recurrent_state() == {
+    assert net.layer_facts()["recurrent_state"] == {
         f"l{i}_gdn_scan": {"heads": H, "d_k": 12, "d_v": 24, "chunk": 64,
                            "chunks": 2, "decay": "head", "saved_state_bytes":
                            N * H * 2 * 12 * 24 * 4} for i in LINEAR}

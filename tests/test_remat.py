@@ -169,8 +169,7 @@ def _run_steps(net, batch, remat_plan, n_steps=3):
 
 def test_lenet_step_bitwise_parity_under_max_remat():
     net, batch = _lenet_setup()
-    from poseidon_tpu.runtime.attribution import layer_cost_table
-    plan = plan_remat(layer_cost_table(net), 0, 0,
+    plan = plan_remat(net.cost_table(), 0, 0,
                       candidates=remat_mod.remat_candidates(net))
     assert plan.active
     p0, s0, m0 = _run_steps(net, batch, None)
@@ -244,7 +243,6 @@ def test_spmd_dp2_fsdp2_bitwise_parity():
     from poseidon_tpu.parallel.spmd import (ShardingPlan,
                                             build_spmd_train_step,
                                             named_mesh)
-    from poseidon_tpu.runtime.attribution import layer_cost_table
 
     cfg = MeshConfig.parse("dp2,fsdp2")
     mesh = named_mesh(cfg)
@@ -252,7 +250,7 @@ def test_spmd_dp2_fsdp2_bitwise_parity():
     net = Net(zoo.lenet(with_accuracy=False), phase="TRAIN",
               source_shapes=zoo.lenet_shapes(4))
     plan = ShardingPlan.build(net, cfg, comm)
-    rplan = plan_remat(layer_cost_table(net), 0, 0,
+    rplan = plan_remat(net.cost_table(), 0, 0,
                        candidates=remat_mod.remat_candidates(net))
     rs = np.random.RandomState(0)
     batch = {"data": rs.randn(16, 1, 28, 28).astype(np.float32),
@@ -326,12 +324,10 @@ def test_measured_peak_api_and_remat_arm_stay_bounded():
     API."""
     import jax.numpy as jnp
 
-    from poseidon_tpu.runtime.attribution import layer_cost_table
-
     net, batch = _lenet_setup()
     comm = CommConfig(param_arena=True)
     full_plan = remat_mod.plan_remat(
-        layer_cost_table(net), 0, 0,
+        net.cost_table(), 0, 0,
         candidates=remat_mod.remat_candidates(net), source="plan")
     assert full_plan.active
     p = net.init(jax.random.PRNGKey(0))

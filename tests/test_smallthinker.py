@@ -97,7 +97,7 @@ def test_leaves_scopes_and_routes(model):
     assert params["l1_moe"]["gate"].shape == (HELD, 32, 64)
     assert params["l1_router"]["w"].shape == (E, 64)
     assert params["l0_q"]["w"].shape == (8 * 16, 64)     # q wider than D
-    assert net.expert_share()["l3_moe"] == {
+    assert net.layer_facts()["expert_share"]["l3_moe"] == {
         "held_first": 0, "num_held": HELD, "router_num_experts": E}
     types = {l.name: l.TYPE for l in net.layers}
     assert [n for n, t in types.items() if t == "ATTENTION"] == [

@@ -110,7 +110,7 @@ def test_leaves_scopes_and_routes(model):
     assert "l0_router" not in params and "l0_ffn_gate" in params
     assert net.layer_updates == {
         (f"l{i}_router", "bias"): f"l{i}_bias_next" for i in MOE_LAYERS}
-    assert net.expert_share()["l4_moe"] == {
+    assert net.layer_facts()["expert_share"]["l4_moe"] == {
         "held_first": 0, "num_held": HELD, "router_num_experts": E}
     types = {l.name: l.TYPE for l in net.layers}
     # window and global told apart by name, every new scope typed
@@ -462,10 +462,10 @@ def test_held_row_ladder_is_named_and_counted(tmp_path, monkeypatch):
     ``kernel_routes`` note names the chunks the held rows run in, and
     ``stats.yaml`` counts, from every step's displayed held shares, the
     trips the held arms made (``held_chunk_trips``), the rows those ran
-    (``held_rows_run``) and the live rows among them (gauge
-    ``held_row_fill`` = live / run), beside the layer-steps displayed and
-    those whose live rows number at most twice the even share (counters
-    ``held_layer_steps`` / ``held_prefix_hits``). Routings on each side of that, forced through
+    (``held_rows_run``) and the live rows among them (``held_rows_live``;
+    live / run is how full the chunks were), beside the layer-steps
+    displayed and those whose live rows number at most twice the even share
+    (counters ``held_layer_steps`` / ``held_prefix_hits``). Routings on each side of that, forced through
     the routers' biases: a step with no assignment on a held expert (no
     trip), one with every assignment on one (every chunk, nothing dropped),
     back, and one the routers choose themselves (a chunk partly filled).
@@ -478,15 +478,17 @@ def test_held_row_ladder_is_named_and_counted(tmp_path, monkeypatch):
     # are then cut at the even share, as the cells' are
     monkeypatch.setattr(moe, "_CHUNK_FLOOR", 128)
     assert "held rows" not in build().kernel_routes["l1_moe"]
-    assert build().held_row_ladders() == {}
+    assert build().display_counters() == {}
     n, k = 4, 2
     sp, _ = _job(tmp_path, max_iter=4, held=2, n=n, top_k=k)
     chunk, prefix, rows = 128, 128, n * S * k
 
     def stats():
         doc = read_stats_yaml(str(tmp_path / "out" / "stats.yaml"))
-        return ([float(doc["gauges"]["held_row_fill"])]
-                + [float(doc["counters"][c]) for c in
+        done = {c: float(v) for c, v in doc["counters"].items()}
+        return ([round(done["held_rows_live"]
+                       / max(done["held_rows_run"], 1.0), 6)]
+                + [done[c] for c in
                    ("held_layer_steps", "held_prefix_hits",
                     "held_chunk_trips", "held_rows_run")])
 
@@ -506,8 +508,9 @@ def test_held_row_ladder_is_named_and_counted(tmp_path, monkeypatch):
             assert routes[f"l{i}_moe"] == (
                 f"grouped_matmul=ragged_dot; held rows: chunks of {chunk} "
                 f"of {rows}")
-        assert eng.train_net.held_row_ladders() == {
-            f"l{i}_held_share": (chunk, prefix, rows) for i in MOE_LAYERS}
+        assert sorted(eng.train_net.display_counters()) == [
+            f"l{i}_held_share" for i in MOE_LAYERS]
+        assert moe.held_rows_plan(rows, 2, E) == (chunk, prefix, rows)
         for offsets, share, want in (
                 ((-10.0, -10.0), 0.0, [0.0, 4.0, 4.0, 0.0, 0.0]),
                 ((10.0, 10.0), 1.0, [1.0, 8.0, 4.0, 16.0, 2048.0]),

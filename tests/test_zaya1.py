@@ -99,7 +99,7 @@ def test_leaves_shares_and_routes(model):
     assert net.layer_updates == {("l0_router", "bias"): "l0_bias_next",
                                  ("l1_router", "bias"): "l1_bias_next"}
     assert "l0_bias_next" not in net.output_names
-    assert net.expert_share()["l1_moe"] == {
+    assert net.layer_facts()["expert_share"]["l1_moe"] == {
         "held_first": 0, "num_held": HELD, "router_num_experts": E}
     assert net.kernel_routes["l0_attn"].endswith("2 kv heads repeated x2")
 
