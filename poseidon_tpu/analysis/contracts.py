@@ -54,7 +54,7 @@ _SPECS = {
     # collective census against the planned schedule (parallel/spmd.py).
     # GoogLeNet skips it for compile budget — its schedule shape (conv
     # arena buckets + gathered-column classifier heads) is covered by
-    # AlexNet, and its arena bucket count is already pinned above.
+    # AlexNet.
     "lenet": {"image": 28, "channels": 1, "classes": 10,
               "optimized": True, "nhwc": False, "mesh": True},
     "alexnet": {"image": 67, "channels": 3, "classes": 10,
@@ -198,7 +198,6 @@ def build_contract(model: str) -> Dict:
     lowered = ts.lowerable.lower(params, state, batch, jax.random.PRNGKey(7))
     txt = lowered.as_text()
     census = _dtype_census(txt)
-    arena_buckets = ts.arena.n_buckets if ts.arena is not None else None
     contract: Dict = {
         "model": model,
         "generated_with": {"jax": jax.__version__,
@@ -207,12 +206,13 @@ def build_contract(model: str) -> Dict:
         "config": {"image": spec["image"], "channels": spec["channels"],
                    "batch": _BATCH, "num_classes": spec["classes"],
                    "conv_layout": net.conv_layout,
-                   "param_arena": cc.param_arena,
-                   "arena_bucket_mb": cc.arena_bucket_mb,
-                   "arena_buckets": arena_buckets,
+                   "param_leaves": len(jax.tree_util.tree_leaves(params)),
                    "donate": True, "donate_batch": True},
         "stablehlo": {
-            # the PR-4 acceptance counter: bucketed psums, never per-leaf
+            # one sum a gradient leaf, issued where backward makes it
+            # (PR 59: no buckets); the lowered count bounds the compiled
+            # one from above, the compiler's combiner only ever merges.
+            # Leaves under 256 elements (small biases) are not counted.
             "gradient_all_reduces": count_gradient_all_reduces_stablehlo(txt),
             # the PR-3 counter under the default (per-backend) layout
             "layout_transposes": count_layout_transposes(txt),

@@ -93,7 +93,6 @@ REQUIRED_SCOPES: Dict[str, Tuple[str, ...]] = {
     "poseidon_tpu/core/arena.py": ("arena_pack", "arena_unpack",
                                    "arena_views", "arena_grads"),
     "poseidon_tpu/solvers/updates.py": ("optimizer_update",),
-    "poseidon_tpu/parallel/strategies.py": ("grad_sync_bucket",),
     "poseidon_tpu/core/net.py": (),
 }
 

@@ -355,13 +355,24 @@ def _flag_value(args: str, name: str):
 
 def enable_tpu_async_collectives(check_backend: bool = True) -> bool:
     """Turn on libtpu's async collective fusion for all-reduce — OFF by
-    default in libtpu, but it is the TPU backend's mechanism for hiding
-    gradient all-reduces behind remaining backward compute (each bucket's
-    collective is fused into an ``async_collective_fusion`` program whose
-    DMA phases interleave with a backward conv/matmul — measured on the
-    v5e compiler: 6/6 bucketed DWBP all-reduces fused with 18 compute ops,
-    0 for the end-of-backward fused sync; evidence/aot_tpu/dwbp.json).
-    Pair with ``CommConfig.dwbp_bucket_mb`` on multi-chip meshes.
+    default in libtpu, but it is the TPU backend's mechanism for running
+    a gradient all-reduce beside compute: the collective is fused into an
+    ``async_collective_fusion`` program whose DMA phases interleave with
+    one backward conv/matmul. What the chip showed (four v5e chips,
+    AlexNet 4 x 512 bf16; PERF.md section 6, PR 59): the flags make a
+    collective asynchronous only where the program's dependencies leave
+    the scheduler a reason to start it early. The default data-parallel
+    step's 16 per-leaf psums compile to two SYNCHRONOUS all-reduces after
+    the last backward op (``compiled_step.gradient_all_reduces`` 2,
+    ``gradient_all_reduces_async`` 0; 4.27 ms a step exposed); the
+    chained taps of ``CommConfig.dwbp_bucket_mb=0`` compile to 15, four of
+    them (fc8, fc7, fc6, conv5) asynchronous and mid-backward. The AOT
+    census this docstring used to cite (evidence/aot_tpu/dwbp.json: "6/6
+    bucketed DWBP all-reduces fused with 18 compute ops") was of a 67-pixel
+    AlexNet on an abstract v5e-8 and no timing; the bucketed step it stood
+    for read 14 of 61 asynchronous and 3.31 ms exposed on the chip.
+    libtpu's other data-parallel options (MaxText's set) change nothing in
+    this program's compiled text and are not staged here.
 
     Each flag is checked INDEPENDENTLY against the existing
     ``LIBTPU_INIT_ARGS``: an explicitly-set flag is honored in either

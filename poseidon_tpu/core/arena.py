@@ -1,39 +1,37 @@
-"""The arena: static offsets and bucketed ranges for gradient collectives.
+"""The arena: static offsets and bucket ranges of one flat f32 buffer.
 
 The reference's server tier (Bösen) stores parameters as contiguous table
-rows precisely so transmission costs do not scale with the NUMBER of
-tensors (server_table.cpp rows; SSPAggr ships row ranges). A data-parallel
-JAX step over GoogLeNet's ~120 leaves is otherwise one collective per leaf.
-This module is the contiguous-row analog for what crosses the wire:
+rows (server_table.cpp rows; SSPAggr ships row ranges). This module is the
+contiguous-row analog for the two steps whose STATE lives in such a buffer:
 
-- **Offset table** (``ArenaSlot``): every DENSE f32 parameter leaf gets a
-  static ``[offset, offset+size)`` range in one flat f32 index space. Slot
-  order is the DWBP order — REVERSE forward layer order, i.e. the order
-  gradients materialize during backward — so bucket 0's gradients exist
-  first.
+- the fsdp-sharded step of parallel/spmd.py, which shards the buffer over
+  the fsdp axis: its parameters are ``views`` of the buffer's buckets, the
+  cotangent comes back packed (``pack_grad_buckets``), and each bucket is
+  reduce-scattered over fsdp;
+- the SSP tier's boundary delta exchange (``build_ssp_train_step``), which
+  packs the accumulated delta and sums it one bucket at a time.
+
+``--param_arena`` keeps its meaning there and only there. The synchronous
+data-parallel step of ``parallel/trainer.build_train_step`` packs NOTHING
+since PR 59: each DENSE gradient is summed by the tap in its own layer's
+backward, in the layout the compiler keeps the leaf in. Bösen's reason for
+contiguous rows (transmission cost over Ethernet must not scale with the
+number of tensors) is, on ICI under XLA, the compiler's all-reduce
+combiner's job; packing 244 MB of AlexNet gradients into 61 flat 4 MB
+buffers, gating them into a chain and slicing them back cost 6.4 ms of a
+40 ms step on four v5e chips (PERF.md section 6, PR 59).
+
+- **Offset table** (``ArenaSlot``): every included f32 parameter leaf gets
+  a static ``[offset, offset+size)`` range in one flat f32 index space.
+  Slot order is the DWBP order — REVERSE forward layer order, i.e. the
+  order gradients materialize during backward — so bucket 0's gradients
+  exist first.
 - **Buckets**: the flat range is cut at exact ``bucket_mb`` element
-  boundaries (leaves may span buckets), so the data-parallel gradient sync
-  is exactly ``ceil(total_bytes / bucket_mb)`` collectives — never more,
-  regardless of how leaf sizes pack (greedy whole-leaf bucketing has no
-  such bound).
-- **Gradient buckets** (``pack_grad_buckets`` / ``unpack_buckets``): the
-  data-parallel step (parallel/trainer.py) concatenates ``jax.grad``'s
-  leaf gradients into one buffer per bucket — each from its own leaves
-  only, so its psum can issue as soon as its layers' backward is done,
-  preserving DWBP overlap — and slices the summed buffers back to leaves.
+  boundaries (leaves may span buckets), so an exchange is exactly
+  ``ceil(total_bytes / bucket_mb)`` collectives.
 
-Parameters and solver history never enter the flat buffer in that step:
-the forward consumes the canonical leaves and the optimizer update is the
-per-leaf rule (solvers/updates._leafwise_update), each leaf in the layout
-the compiler keeps it in. Packing them cost a relayout per leaf per packed
-quantity per step on the TPU (PERF.md, PR 26). With one device on the sync
-axes there is nothing to bucket and the step builds no arena at all.
-
-The flat buffer itself (``pack`` / ``unpack`` / ``views`` /
-``mult_vectors``) remains for the step whose state lives in it: the
-fsdp-sharded step of parallel/spmd.py, which shards the buffer over the
-fsdp axis, and the SSP tier's boundary delta exchange. Checkpoints are
-canonical per-leaf throughout and ``--param_arena`` does not change them.
+Checkpoints are canonical per-leaf throughout and ``--param_arena`` does
+not change them.
 """
 
 from __future__ import annotations
@@ -47,20 +45,6 @@ import numpy as np
 from jax import lax
 
 Tree = Dict[str, Dict[str, jax.Array]]
-
-# A layer with a leaf this large stays per-leaf (256 MiB of f32). The arena
-# exists so that costs do not scale with the NUMBER of tensors; a leaf of
-# hundreds of MB is a bandwidth-bound collective of its own already, and
-# packing it would add a copy of its gradient, resident beside the leaf:
-# OLMoE's 537 MB expert stacks and 412 MB embedding. Every CNN leaf in the
-# zoo is under it (AlexNet's fc6: 151 MB). On one device nothing is packed
-# and the cap decides nothing.
-MAX_LEAF_ELEMENTS = 64 * 2 ** 20
-
-
-def fits_arena(pdefs) -> bool:
-    """Whether a layer's leaves (its ParamDefs) are all arena-sized."""
-    return all(p.count <= MAX_LEAF_ELEMENTS for p in pdefs)
 
 
 @dataclass(frozen=True)
@@ -217,7 +201,7 @@ class ArenaLayout:
         issue as soon as that is done. Leaves of ``tree`` outside the
         layout are ignored."""
         # "arena_grads": the copies between backward matmuls and the
-        # bucketed psums
+        # buckets' reduce-scatters
         with jax.named_scope("arena_grads"):
             outs = []
             for bi, pieces in enumerate(self._bucket_pieces):
@@ -242,9 +226,7 @@ class ArenaLayout:
         """Per-bucket PARAMETER buffers -> per-leaf tree, as a custom-vjp
         pair so the COTANGENT comes back packed (``pack_grad_buckets`` of
         the leaf cotangents). For a step whose parameters live in the flat
-        buffer — the fsdp-sharded step of parallel/spmd.py; the data-
-        parallel step feeds its forward the canonical leaves and packs
-        ``jax.grad``'s leaf gradients with ``pack_grad_buckets`` itself."""
+        buffer: the fsdp-sharded step of parallel/spmd.py."""
         if self._views is None:
             layout = self
 

@@ -578,8 +578,11 @@ class SpmdCommContext(CommContext):
     axes (the inner CommConfig's sync_axes)."""
 
     def __init__(self, cfg: CommConfig, plan: ShardingPlan, arena_layers):
-        super().__init__(cfg, arena_layers=arena_layers)
+        super().__init__(cfg)
         self.plan = plan
+        # layers whose gradients come back packed in the flat buffer's
+        # buckets (``ArenaLayout.views``' cotangent) and are summed there
+        self.arena_layers = frozenset(arena_layers)
 
     def is_tp_leaf(self, layer: str, pname: str) -> bool:
         """Net._layer_params' size-mismatch escape hatch: ONLY a leaf the
@@ -588,8 +591,8 @@ class SpmdCommContext(CommContext):
         return lp is not None and lp.placement == "tp"
 
     def tap_param(self, layer: str, pname: str, w):
-        if layer in self.plan.tp_layers:
-            return w            # synced per-leaf after backward
+        if layer in self.plan.tp_layers or layer in self.arena_layers:
+            return w            # synced after backward: per leaf / bucket
         return super().tap_param(layer, pname, w)
 
     def inner_product(self, layer: str, x, w, b):
@@ -636,8 +639,8 @@ def hierarchical_psum(g, plan: ShardingPlan, reduce: str,
 
 def sharded_bucket_sync(bufs, plan: ShardingPlan, reduce: str,
                         wire: Optional[str]):
-    """The sharding-aware replacement for ``chained_bucket_psums``: per
-    DWBP-ordered bucket, reduce-scatter over fsdp (the gradient lands as
+    """The flat buffer's gradient sync: per DWBP-ordered bucket,
+    reduce-scatter over fsdp (the gradient lands as
     this device's 1/fsdp shard) then all-reduce the shard over data,
     chained by the finite-token gate so XLA's combiners cannot re-merge
     buckets (distinctness is the prerequisite for mid-backward overlap).

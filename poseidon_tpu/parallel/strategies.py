@@ -122,32 +122,33 @@ class CommConfig:
     # The adarevision server's init_step_size flag (its gflags default 0.1)
     adarev_init_step: float = 0.1
     # DWBP bucketing (solver.cpp:419-449 per-blob sync threads, recast).
-    # None (default): plain in-backward taps — XLA's all-reduce combiner may
-    # merge them into one collective (it does: scripts/analyze_schedule.py),
-    # which is optimal when the runtime cannot overlap anyway. A number:
-    # chain the taps into ~this-many-MB buckets via ordering tokens, forcing
-    # one DISTINCT collective per bucket that issues the moment its bucket's
-    # gradients materialize mid-backward — the reference's overlap structure.
-    # 0 = one bucket per parameter (per-blob granularity, the reference's
-    # exact shape). Distinctness is a prerequisite for overlap: a combined
-    # collective can only start after the LAST gradient exists.
+    # None (default): plain in-backward taps, one psum a leaf; XLA's
+    # all-reduce combiner may merge them. A number: chain the taps into
+    # ~this-many-MB buckets via ordering tokens, forcing one DISTINCT
+    # collective per bucket; 0 = one per parameter (the reference's exact
+    # shape). What the chip showed (four v5e chips, AlexNet 4 x 512 bf16;
+    # PERF.md section 6, PR 59): under None the compiler keeps fc6's 151 MB
+    # all-reduce apart, merges the other 15 leaves into one of 93 MB, and
+    # schedules both, synchronous, after the last convolution's backward
+    # (4.27 ms a step, all exposed); under 0 the chain makes the
+    # collectives a critical path, the scheduler starts fc8's, fc7's and
+    # fc6's mid-backward inside async_collective_fusions, and the cell
+    # reads 2.25% more (14,266 -> 14,586 images/s/chip in two pairs, for
+    # 0.5 GB more memory). The default stays None: the gate is a data
+    # dependency that costs a pass over each gradient, and a net of many
+    # small leaves (GoogLeNet's 128) wants them merged, not chained.
     dwbp_bucket_mb: Optional[float] = None
-    # Arena gradient buckets (core/arena.py): with more than one device
-    # on the sync axes, sum DENSE f32 leaves' gradients as
-    # ceil(bytes / arena_bucket_mb) bucketed psums in DWBP order instead
-    # of one per leaf (False). Only gradients are packed; parameters and
-    # solver history stay per-leaf and the update is the per-leaf rule
-    # either way, so on one device the flag changes nothing. The only
-    # step-level deltas against per-leaf collectives are <= 1 ulp where XLA
-    # picks a different cross-replica reduction order for a bucketed
-    # all-reduce than for a tiny per-leaf psum. ON by default (the Bösen
-    # contiguous-row analog: transmission costs must not scale with the
-    # NUMBER of tensors — GoogLeNet carries ~120).
-    # SFB/TOPK/LOCAL/DENSE_FUSED layers opt out and keep their custom
-    # paths. An explicit dwbp_bucket_mb request (per-backward chained taps)
-    # takes precedence over the buckets on the per-step sync path. (The
-    # fsdp-sharded step of parallel/spmd.py needs it on: it shards the
-    # flat buffer itself.)
+    # The flat parameter buffer (core/arena.py) of the two steps whose
+    # STATE lives in one: the fsdp-sharded step of parallel/spmd.py, which
+    # shards the buffer itself and needs this on, and the SSP tier's
+    # boundary delta exchange (build_ssp_train_step), which sums
+    # ceil(bytes / arena_bucket_mb) buckets instead of one delta per leaf
+    # (False). The synchronous data-parallel step of build_train_step
+    # reads neither field since PR 59: it sums each DENSE gradient leaf by
+    # the tap in its own backward. (Packing them into 4 MB buckets, the
+    # Bösen contiguous-row analog, cost 6.4 ms of AlexNet's 40.2 ms step
+    # on four v5e chips in copies and gates: 12,215 -> 14,262 images/s/chip
+    # without it; PERF.md section 6, PR 59.)
     param_arena: bool = True
     arena_bucket_mb: float = 4.0
     # Blocked top-k selection: when set, magnitude/random TOPK picks the
@@ -415,43 +416,11 @@ def topk_compress(g: jax.Array, fraction: float, error: jax.Array,
     return sent.reshape(g.shape), new_error
 
 
-def chained_bucket_psums(bufs, axes: tuple, reduce: str,
-                         wire: Optional[str]):
-    """The arena's bucketed gradient sync: one ``wire_psum`` per bucket
-    buffer, chained by the same finite-token gate as ``_chained_sync_tap``
-    so XLA's all-reduce combiner cannot re-merge the buckets into one
-    end-of-backward collective (a merge would create a cycle). Buckets are
-    DWBP-ordered (bucket 0 = the last layers, whose gradients materialize
-    first in backward), so each collective can issue mid-backward the
-    moment its bucket's leaf cotangents are concatenated — the reference's
-    per-blob sync-thread overlap (solver.cpp:419-449) at bucket
-    granularity. The gate is the identity for finite tokens: values are
-    bit-identical to independent per-bucket (and per-leaf) psums."""
-    out = []
-    tok = None
-    # one named scope per bucket: a profiled step attributes each bucket's
-    # collective (and its overlap window) individually in the xplane
-    for i, g in enumerate(bufs):
-        with jax.named_scope(f"grad_sync_bucket{i}"):
-            if tok is not None:
-                g = jnp.where(tok < jnp.inf, g, jnp.full_like(g, jnp.nan))
-            s = wire_psum(g, axes, reduce, wire)
-            t = s[0].astype(jnp.float32)
-            tok = t if tok is None else jnp.minimum(tok, t)
-            out.append(s)
-    return tuple(out)
-
-
 class CommContext:
-    """Threaded through Net.apply; layers call back into it (core/layers.py).
+    """Threaded through Net.apply; layers call back into it (core/layers.py)."""
 
-    ``arena_layers`` names the layers whose DENSE gradients ride the
-    arena's bucketed psums instead of the in-backward taps —
-    ``tap_param`` leaves them untouched."""
-
-    def __init__(self, cfg: CommConfig, arena_layers=frozenset()):
+    def __init__(self, cfg: CommConfig):
         self.cfg = cfg
-        self.arena_layers = frozenset(arena_layers)
         self._token = None
         self._pending: list = []
         self._bucket_bytes = 0.0
@@ -470,10 +439,6 @@ class CommContext:
         # conv weights, (M, K=C*H*W) FC weights) — the layout plan presents
         # weights to NHWC convs via dimension numbers, never a reshaped
         # copy, so the cotangent psummed here is canonical under any plan.
-        if layer in self.arena_layers:
-            # the trainer psums this layer's gradient inside its arena
-            # bucket after (the relevant part of) backward — no tap here
-            return w
         strat = self.cfg.strategy_for(layer)
         if strat in (LOCAL, TOPK, DENSE_FUSED):
             # LOCAL: never synced. TOPK: the trainer compresses + psums the

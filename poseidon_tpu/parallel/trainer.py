@@ -28,9 +28,8 @@ from ..core.net import Net
 from ..proto.messages import SolverParameter
 from ..solvers.updates import SolverState, init_state, make_update_fn
 from .strategies import (CommConfig, CommContext, DENSE, DENSE_FUSED, LOCAL,
-                         SFB, TOPK, budget_topk_fraction,
-                         chained_bucket_psums, comm_salt, topk_compress,
-                         wire_psum)
+                         SFB, TOPK, budget_topk_fraction, comm_salt,
+                         topk_compress, wire_psum)
 
 
 def param_mults(net: Net) -> Dict[str, Dict[str, tuple]]:
@@ -117,12 +116,12 @@ class TrainStep:
     # "NHWC" when the caller feeds channels-last directly so an NHWC-planned
     # net's hot path carries zero entry transposes — see core/net.py).
     input_layout: str = "NCHW"
-    # The arena layout (core/arena.py) whose buckets this step's DENSE
-    # gradients are summed in, or None when there is nothing to bucket: one
-    # device on the sync axes, or per-leaf collectives asked for.
-    # Introspection only — parameters and solver history never enter it
-    # (the sharding-planner step of parallel/spmd.py, which shards the flat
-    # buffer over fsdp, is the exception and says so in ``update_route``).
+    # The arena layout (core/arena.py) of the two steps whose STATE lives
+    # in the flat buffer: the sharding-planner step of parallel/spmd.py
+    # (it shards the buffer over fsdp and says so in ``update_route``) and
+    # the SSP step's boundary delta exchange. None for the data-parallel
+    # step of ``build_train_step``, which sums each gradient leaf where
+    # backward makes it and packs nothing (PR 59).
     arena: Optional[object] = None
     # Which form the optimizer update takes: "leaf" (the per-leaf rule on
     # the canonical leaves) or "flat_fsdp" (spmd.py's sharded flat buffer).
@@ -165,7 +164,7 @@ def build_train_step(
     forward bodies in ``jax.checkpoint`` inside ``Net.apply`` — stored
     activations drop until the step fits the HBM budget, at the cost of
     recomputing those layers' forwards during backward. Composes with
-    the arena, the mesh planner and donation unchanged: remat changes
+    the mesh planner and donation unchanged: remat changes
     what XLA's buffer assignment keeps live, never the math (remat arms
     are bitwise-equal to stored-activation arms).
 
@@ -230,11 +229,10 @@ def build_train_step(
     one micro-batch), averages the accumulated gradients, then syncs and
     updates ONCE. batch_size B at iter_size K is numerically equivalent to
     batch_size B*K (tested). There is no per-micro-batch backward exchange
-    to tap (the DWBP/SFB structures are per-step mechanisms), so the
-    post-accumulation sync routes DENSE layers through the flat parameter
-    arena's buckets — ceil(bytes/arena_bucket_mb) collectives — while SFB
-    and DENSE_FUSED layers get one dense psum per accumulated leaf; TOPK
-    compression still applies, on the accumulated gradient.
+    to tap (the DWBP/SFB structures are per-step mechanisms), so every
+    DENSE, SFB and DENSE_FUSED layer gets one dense psum per accumulated
+    leaf after the scan; TOPK compression still applies, on the
+    accumulated gradient.
 
     ``donate_batch=True`` additionally donates the batch buffers: with a
     device-side input prefetch stage (``data.pipeline.DevicePrefetcher``)
@@ -275,53 +273,35 @@ def build_train_step(
                 f"replicated; use build_ssp_train_step for per-device "
                 f"divergent parameters")
 
-    # Gradient buckets (core/arena.py), only where there is someone to
-    # all-reduce with: with more than one device on the sync axes, DENSE
-    # layers' gradients are packed into DWBP-ordered bucket buffers as
-    # backward produces them, summed as ceil(bytes / arena_bucket_mb)
-    # chained psums instead of one per leaf, and sliced back to leaves.
-    # Parameters and solver history never enter the flat buffer: the
-    # forward consumes the canonical leaves and the update is the per-leaf
-    # rule, in whatever layout the compiler keeps each leaf. On one device
-    # there is no arena at all. SFB/TOPK/DENSE_FUSED layers keep their
-    # custom per-leaf paths. An explicit dwbp_bucket_mb (per-backward
-    # chained taps) takes precedence on the per-step path; under
-    # iter_size > 1 there is no per-backward exchange, so the accumulated
-    # sync rides the buckets either way.
-    # (a layer holding a leaf of hundreds of MB keeps its per-leaf
-    # gradient tap: core/arena.fits_arena)
-    from ..core.arena import fits_arena
-    dense_layers = [l for l, defs in net.param_defs.items()
-                    if comm.strategy_for(l) == DENSE and fits_arena(defs)]
-    arena = None
-    if n_total > 1 and comm.param_arena and dense_layers and \
-            (comm.dwbp_bucket_mb is None or iter_size > 1):
-        arena = net.arena_layout(frozenset(dense_layers),
-                                 comm.arena_bucket_mb)
-    ctx = CommContext(comm, arena_layers=arena.layers
-                      if arena is not None else frozenset())
+    # The data-parallel sum of DENSE gradients: each leaf is summed by the
+    # tap in its own layer's backward (CommContext.tap_param), in whatever
+    # layout the compiler keeps the leaf — no pack into flat buckets, no
+    # gate chain, no unpack (PR 59: on four v5e chips those copies and
+    # gates cost AlexNet 6.4 ms of a 40 ms step; merging small collectives
+    # is the compiler's all-reduce combiner's job). An explicit
+    # dwbp_bucket_mb keeps its chained taps; SFB/TOPK/DENSE_FUSED keep
+    # their paths. ``param_arena`` decides nothing here: it belongs to the
+    # two steps whose state lives in the flat buffer (parallel/spmd.py's
+    # fsdp step, build_ssp_train_step's boundary exchange).
+    ctx = CommContext(comm)
 
     if iter_size > 1:
-        # the buckets cover DENSE layers' accumulated sync; anything they
-        # do NOT cover still silently collapses to one dense
-        # post-accumulation psum per leaf — keep saying so
+        # no per-backward exchange exists under accumulation: every synced
+        # leaf collapses to one dense post-accumulation psum — keep saying
+        # so where a per-backward strategy was asked for
         sfb_layers = [l for l in net.param_defs
                       if comm.strategy_for(l) == SFB]
         what = []
         if sfb_layers:
             what.append(f"SFB layers {sfb_layers}")
-        if comm.dwbp_bucket_mb is not None and arena is None:
+        if comm.dwbp_bucket_mb is not None:
             what.append(f"dwbp_bucket_mb={comm.dwbp_bucket_mb}")
         if what:
             from ..runtime.metrics import log
             log(f"WARNING: iter_size={iter_size} accumulates gradients "
                 f"before one dense post-accumulation psum per leaf for "
                 f"{', '.join(what)}; per-backward comm strategies do not "
-                f"apply to the accumulated step (DENSE layers ride the "
-                f"arena's gradient buckets"
-                + (")" if arena is not None else
-                   " when param_arena is on and there is more than one "
-                   "device)"))
+                f"apply to the accumulated step")
 
     topk_layers = [l for l in net.param_defs
                    if comm.strategy_for(l) == TOPK]
@@ -379,13 +359,10 @@ def build_train_step(
             # Caffe's SGDSolver::Normalize: scale accumulated grads by 1/K
             grads = jax.tree_util.tree_map(lambda g: g / iter_size, grads)
             out_scalars = {k: jnp.mean(v) for k, v in micro_ms.items()}
-            # post-accumulation sync for the per-leaf layers the
-            # per-backward taps would have handled (SFB / DENSE_FUSED, and
-            # DENSE itself where there are no buckets); the bucketed layers
-            # follow below
+            # post-accumulation sync: one sum a leaf for every layer the
+            # per-backward taps would have handled (DENSE / SFB /
+            # DENSE_FUSED)
             for lname in grads:
-                if lname in ctx.arena_layers:
-                    continue
                 if comm.strategy_for(lname) not in (LOCAL, TOPK):
                     for pname, g in grads[lname].items():
                         grads[lname][pname] = wire_psum(
@@ -406,17 +383,6 @@ def build_train_step(
                 for pname, g in grads[lname].items():
                     grads[lname][pname] = wire_psum(g, axes, comm.reduce,
                                                     comm.wire_dtype)
-        if arena is not None:
-            # the bucketed data-parallel sync: each DWBP-ordered bucket is
-            # concatenated from its own leaves' gradients only, so its
-            # DISTINCT (chained) collective issues as those materialize
-            # mid-backward; the sums are sliced back to leaves for the
-            # per-leaf update (the same ceil(bytes/bucket) collectives for
-            # the accumulated gradient under iter_size > 1)
-            synced = arena.unpack_buckets(chained_bucket_psums(
-                arena.pack_grad_buckets(grads), axes, comm.reduce,
-                comm.wire_dtype))
-            grads = arena.merge(arena.residual(grads), synced)
         # Managed-comm tier: TOPK layers were left un-psummed by the tap;
         # compress the (residual-corrected) gradient, exchange only the
         # top-k entries, keep the remainder as next step's residual.
@@ -508,7 +474,6 @@ def build_train_step(
             scan_steps=scan_steps,
             iter_size=iter_size if iter_size > 1 else None,
             input_layout=input_layout,
-            arena=arena,
         )
 
     sharded = shard_map(
@@ -535,7 +500,6 @@ def build_train_step(
         lowerable=jitted,
         iter_size=iter_size if iter_size > 1 else None,
         input_layout=input_layout,
-        arena=arena,
     )
 
 

@@ -216,12 +216,13 @@ def unit_residuals(jaxpr, plan, batch_args: Sequence[int] = ()
     return found, stored
 
 
-# collective named scopes emitted by the comm machinery (strategies.py
-# arena buckets, spmd.py mesh collectives): each carries its mesh axis in
-# the name, so a profiled step attributes comm time PER AXIS instead of
-# lumping it into the residual row. Matched as whole path components.
+# collective named scopes emitted by the comm machinery (spmd.py mesh
+# collectives, the SSP boundary's delta buckets): each carries its mesh
+# axis in the name, so a profiled step attributes comm time PER AXIS
+# instead of lumping it into the residual row. Matched as whole path
+# components.
 COMM_SCOPE_RE = re.compile(
-    r"^(grad_sync_bucket\d+|grad_rs_bucket\d+|grad_ar_bucket\d+"
+    r"^(grad_rs_bucket\d+|grad_ar_bucket\d+"
     r"|param_ag_bucket\d+|hist_ag_bucket\d+|delta_rs_bucket\d+"
     r"|delta_ar_bucket\d+|delta_ag_bucket\d+"
     r"|tp_fwd_[\w.\-]+|tp_dx_[\w.\-]+"
@@ -231,7 +232,7 @@ _COMM_AXIS_PREFIX = (
     ("grad_rs_bucket", "fsdp"), ("param_ag_bucket", "fsdp"),
     ("hist_ag_bucket", "fsdp"), ("delta_rs_bucket", "fsdp"),
     ("delta_ag_bucket", "fsdp"), ("grad_ar_bucket", "data"),
-    ("delta_ar_bucket", "data"), ("grad_sync_bucket", "data"),
+    ("delta_ar_bucket", "data"),
     ("tp_fwd_", "tp"), ("tp_dx_", "tp"),
 )
 

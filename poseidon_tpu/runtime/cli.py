@@ -973,18 +973,21 @@ def build_parser() -> argparse.ArgumentParser:
                         "negative = off (XLA's combiner decides)")
     t.add_argument("--param_arena", default="true",
                    choices=["true", "false"],
-                   help="gradient buckets (ON by default): with more than "
-                        "one device, sum DENSE layers' gradients as "
-                        "ceil(bytes/arena_bucket_mb) bucketed collectives "
-                        "instead of one per leaf (false = one per leaf); "
-                        "steps agree within 1 ulp of collective reduction "
-                        "order. Parameters and solver history are never "
-                        "packed, the update is per leaf either way, and on "
-                        "one device the flag changes nothing")
+                   help="the flat parameter buffer of the two steps whose "
+                        "state lives in one (ON by default): the fsdp-"
+                        "sharded step of --mesh, which needs it, and the "
+                        "--staleness boundary exchange, which sums "
+                        "ceil(bytes/arena_bucket_mb) buckets instead of "
+                        "one delta per leaf (false = one per leaf). The "
+                        "synchronous data-parallel step packs nothing "
+                        "under either value: it sums each gradient leaf "
+                        "where backward makes it. Checkpoints are per "
+                        "leaf either way")
     t.add_argument("--arena_bucket_mb", type=float, default=4.0,
-                   help="arena gradient-sync bucket size in MB (DWBP-"
-                        "ordered exact element ranges; <= 0 = one bucket "
-                        "per leaf)")
+                   help="bucket size in MB of the flat buffer's exchanges "
+                        "(--mesh fsdp reduce-scatters, --staleness "
+                        "boundary sums; DWBP-ordered exact element "
+                        "ranges; <= 0 = one bucket per leaf)")
     t.add_argument("--hbm_budget_gb", type=float, default=0.0,
                    help="per-device HBM budget (GiB) for the measured "
                         "remat planner (core/remat.py): the no-remat "

@@ -957,8 +957,9 @@ class Engine:
         which is told the key and the route."""
         startup = span_recorder.startup
         # which form the step took, readable without the HLO: where the
-        # optimizer update runs and how many buckets its gradients are
-        # summed in (0: one device, or per-leaf collectives)
+        # optimizer update runs and how many buckets of the flat buffer
+        # are exchanged (the fsdp step's and the SSP boundary's; 0 in the
+        # data-parallel step, which sums each gradient leaf on its own)
         arena = self.train_step.arena
         doc: Dict[str, Any] = {
             "source": "jit", "update_route": self.train_step.update_route,
@@ -970,7 +971,7 @@ class Engine:
                 from .compile_cache import (load_step_executable,
                                             load_step_note,
                                             save_step_executable)
-                from .hlo_comm import count_gradient_all_reduces
+                from .hlo_comm import gradient_all_reduce_census
                 cfg = compile_cache_config()
                 key = self._aot_step_key(batch)
             load.args["key"] = key[:12]
@@ -1013,16 +1014,18 @@ class Engine:
                 + f" (key {key[:12]}; in aot/: {stored})", rank=self.rank)
             # what the step that will run actually contains: the Pallas
             # custom calls the kernel routes promise (0 = interpreted or
-            # routed to XLA), the arena's gradient all-reduces, and the
-            # layout copies between the step's parameters and its results
+            # routed to XLA), the gradient all-reduces and how many of
+            # them the compiler made asynchronous, and the layout copies
+            # between the step's parameters and its results
             doc.update(source=source, stored=stored)
             load.args["route"] = source
             with startup("step_text"):
                 text = exec_.as_text()
                 doc["pallas_custom_calls"] = text.count(
                     'custom_call_target="tpu_custom_call"')
-                doc["gradient_all_reduces"] = count_gradient_all_reduces(
-                    text)
+                (doc["gradient_all_reduces"],
+                 doc["gradient_all_reduces_async"]) = \
+                    gradient_all_reduce_census(text)
                 doc["param_relayouts"] = param_relayouts(text)
             with startup("scope_map"):
                 self._publish_step_scopes(text)

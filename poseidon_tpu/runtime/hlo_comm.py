@@ -104,9 +104,11 @@ def parse_collectives(hlo_text: str) -> List[Collective]:
     ones behind a mean) are kept — filter by payload_bytes if unwanted."""
     out: List[Collective] = []
     for line in hlo_text.splitlines():
-        if "-done" in line or " = " not in line:
+        if " = " not in line:
             continue
         m = _OP_RE.search(line)
+        # a ``-done`` op never matches (its name is followed by "-done(");
+        # an operand named ``%copy-done.3`` must not hide the line
         if m is None:
             continue
         kind = m.group(1)
@@ -151,17 +153,47 @@ def parse_collectives(hlo_text: str) -> List[Collective]:
     return out
 
 
+def gradient_all_reduce_census(hlo_text: str,
+                               min_payload_bytes: int = 1024) -> tuple:
+    """(gradient all-reduces, how many of them are asynchronous) in a
+    compiled step: all-reduce ops with a non-trivial replica group and a
+    payload big enough to be a gradient (the metrics / mean-divisor psums
+    are scalars and fall under the threshold). Asynchronous means the
+    compiler gave the collective a chance to run beside compute: an
+    ``all-reduce-start`` / ``-done`` pair, or, on the TPU, an all-reduce
+    inside an ``async_collective_fusion`` computation (its DMA phases
+    interleave with the compute ops fused beside it). The TPU's text
+    repeats such an all-reduce in the fusion's start and done computations
+    (the ``async_collective_fusion_config`` in their backend_config tells
+    them apart from a plain one); each is counted once, where it runs."""
+    total = n_async = 0
+    comp = ""
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and line[:1] in ("%", "E"):
+            comp = line.split(" ", 1)[0].lstrip("%")
+            continue
+        if "all-reduce" not in line:
+            continue
+        if not any(c.kind == "all-reduce" and c.group_size > 1
+                   and c.payload_bytes >= min_payload_bytes
+                   for c in parse_collectives(line)):
+            continue
+        if "all-reduce-start(" in line:
+            n_async += 1
+        elif '"async_collective_fusion_config"' in line:
+            if not comp.startswith("async_collective_fusion"):
+                continue        # the start / done clone of a fused one
+            n_async += 1
+        total += 1
+    return total, n_async
+
+
 def count_gradient_all_reduces(hlo_text: str,
                                min_payload_bytes: int = 1024) -> int:
-    """Gradient all-reduces in a compiled step: all-reduce ops with a
-    non-trivial replica group and a payload big enough to be a gradient
-    (the metrics / mean-divisor psums are scalars and fall under the
-    threshold). This is the flat-parameter-arena acceptance counter: the
-    data-parallel step must carry <= ceil(total_grad_bytes /
-    arena_bucket_mb) of these, vs one per leaf on the per-leaf path."""
-    return sum(1 for c in parse_collectives(hlo_text)
-               if c.kind == "all-reduce" and c.group_size > 1
-               and c.payload_bytes >= min_payload_bytes)
+    """Gradient all-reduces in a compiled step
+    (``gradient_all_reduce_census``): at most one per synced leaf on the
+    data-parallel path, fewer where the compiler's combiner merges."""
+    return gradient_all_reduce_census(hlo_text, min_payload_bytes)[0]
 
 
 # one stablehlo.all_reduce op, non-greedy to ITS result type: the reduction
@@ -183,7 +215,7 @@ def collective_census_stablehlo(text: str,
     (pre-XLA) program whose payload is at least ``min_elements`` elements
     — the cheap, combiner-proof census the SPMD planner's
     ``collective_schedule`` is diffed against (analysis/contracts.py).
-    Lowered counts are exact for the planned schedule: the arena's
+    Lowered counts are exact for the planned schedule: the flat buffer's
     chained buckets cannot legally merge, and XLA only ever merges,
     never splits."""
     out = {"all_reduce": 0, "reduce_scatter": 0, "all_gather": 0}
@@ -203,10 +235,8 @@ def count_gradient_all_reduces_stablehlo(text: str,
     big net. Counts ``stablehlo.all_reduce`` ops whose f32 payload is big
     enough to be a gradient (metrics / mean-divisor psums are scalars).
     An upper bound on the compiled count: XLA's combiner may merge
-    all-reduces but never splits one — and the arena's chained bucket
-    psums cannot legally merge at all (the chain would cycle), which
-    ``count_gradient_all_reduces`` pins on the compiled text where the
-    compile is affordable."""
+    all-reduces but never splits one (``count_gradient_all_reduces``
+    reads the compiled text where the compile is affordable)."""
     n = 0
     for m in _STABLEHLO_AR_RE.finditer(text):
         dims = m.group(1).rstrip("x")

@@ -1429,22 +1429,24 @@ net = Net(zoo.alexnet(with_accuracy=False), "TRAIN",
           conv_layout=resolve_conv_layout("auto", "tpu"))
 sp = SolverParameter(base_lr=0.01, lr_policy="step", stepsize=100000,
                      gamma=0.1, momentum=0.9, weight_decay=0.0005)
-mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+mesh = Mesh(np.array(topo.devices[:{chips}]), ("data",))
 comm = CommConfig()
 ts = build_train_step(net, sp, mesh, comm, donate=True, donate_batch=True)
 rep = NamedSharding(mesh, P())
 shaped = lambda t, sh: jax.tree.map(
     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), t)
 params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
-state = jax.eval_shape(lambda p: init_train_state(p, comm, 1), params)
-b = {{"data": jax.ShapeDtypeStruct((batch, 3, 227, 227), jnp.float32,
+state = jax.eval_shape(lambda p: init_train_state(p, comm, {chips}), params)
+g = batch * {chips}
+b = {{"data": jax.ShapeDtypeStruct((g, 3, 227, 227), jnp.float32,
                                   sharding=ts.batch_sharding),
-     "label": jax.ShapeDtypeStruct((batch,), jnp.int32,
+     "label": jax.ShapeDtypeStruct((g,), jnp.int32,
                                    sharding=ts.batch_sharding)}}
 compiled = ts.lowerable.lower(
     shaped(params, rep), shaped(state, rep), b,
     jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
 ma, text = compiled.memory_analysis(), compiled.as_text()
+from poseidon_tpu.runtime.hlo_comm import gradient_all_reduce_census
 n = net.param_count()
 print("RESULT " + json.dumps({{
     "parameters": n, "arena": ts.arena is not None,
@@ -1453,6 +1455,9 @@ print("RESULT " + json.dumps({{
     "optimizer_update_ops": text.count("optimizer_update"),
     "buffer_length_arrays": text.count("f32[%d]" % n),
     "all_reduces": len(re.findall(r" all-reduce(-start)?\(", text)),
+    "gradient_all_reduces": gradient_all_reduce_census(text),
+    "grad_sync_bucket_ops": text.count("grad_sync_bucket"),
+    "bucket_buffers": text.count("f32[1000000]"),
     "pallas_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
     "conv_layout": net.layout_plan,
     "lrn_operand_copies": len(re.findall(
@@ -1472,7 +1477,7 @@ def test_alexnet_one_chip_step_has_no_arena_for_one_v5e():
     with no relayout copy at their boundary and no select-and-scatter."""
     import json
     r = subprocess.run(
-        [sys.executable, "-c", _CNN_STEP.format(repo=REPO)],
+        [sys.executable, "-c", _CNN_STEP.format(repo=REPO, chips=1)],
         capture_output=True, text=True, timeout=1500, cwd=REPO)
     if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
         pytest.skip(f"libtpu AOT unavailable: "
@@ -1497,3 +1502,33 @@ def test_alexnet_one_chip_step_has_no_arena_for_one_v5e():
     # pools' backward took the LRN kernels' orientation in PR 35)
     assert got["lrn_operand_copies"] == 0
     assert got["temp_gb"] < 3.0                  # 3.42 with the arena
+
+
+@pytest.mark.slow
+def test_alexnet_four_chip_step_packs_nothing_for_four_v5e():
+    """The four-chip step of ``alexnet.dp4.resident`` since PR 59: each of
+    the 16 gradient leaves is summed where backward makes it, so the
+    compiled program holds no ``arena_*`` / ``grad_sync_bucket`` op name,
+    no 4 MB bucket buffer (1,491 mentions of ``f32[1000000]`` before) and
+    at most one gradient all-reduce a leaf (the compiler merges 15 of them
+    and keeps fc6's 151 MB apart: 2, none asynchronous, where the 61
+    bucket all-reduces were; PERF.md section 6, PR 59), under less
+    temporary memory than the bucketed step's 2.73 GB."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _CNN_STEP.format(repo=REPO, chips=4)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)
+    assert got["arena"] is False and got["update_route"] == "leaf"
+    assert got["arena_op_names"] == [] and got["grad_sync_bucket_ops"] == 0
+    assert got["bucket_buffers"] == 0 and got["buffer_length_arrays"] == 0
+    total, n_async = got["gradient_all_reduces"]
+    assert 1 <= total <= 16 and n_async <= total
+    assert got["pallas_custom_calls"] == 7
+    assert got["temp_gb"] < 2.5

@@ -168,3 +168,68 @@ def test_collective_permute_ring_counted():
     s = measured_comm_summary(colls)
     assert s["n_collectives"] == 2
     assert s["by_kind"]["collective-permute"] > 0
+
+
+# What the TPU compiler's text looks like around gradient all-reduces (cut
+# from the four-chip AlexNet step, PR 59): a plain one in the entry
+# computation; one inside an ``async_collective_fusion`` computation, whose
+# start and done computations repeat it; a merged tuple whose operand list
+# names a ``%copy-done``; and the scalar psum behind a mean.
+_TPU_TEXT = """\
+%fused_computation.322 (param_0.651: f32[384,192,3,3]) -> (f32[384,192,3,3], u32[]) {
+  %all-reduce.61 = f32[384,192,3,3]{0,1,3,2:T(8,128)} all-reduce(%param_0.651), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_19.22, backend_config={"async_collective_fusion_config":{"flag_start":"-1","flag_end":"-1"}}
+}
+%async_collective_fusion.289 (param_0.655: f32[384,192,3,3], param_1.838: f32[384,192,3,3]) -> f32[384,192,3,3] {
+  %all-reduce.63 = f32[384,192,3,3]{0,1,3,2:T(8,128)S(1)} all-reduce(%param_0.655), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_19.22, backend_config={"async_collective_fusion_config":{"flag_start":"2","flag_end":"17"}}
+}
+%fused_computation.324 (param_0.657: f32[384,192,3,3]) -> f32[384,192,3,3] {
+  %all-reduce.65 = f32[384,192,3,3]{0,1,3,2:T(8,128)} all-reduce(%param_0.657), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_19.22, backend_config={"async_collective_fusion_config":{"flag_start":"2","flag_end":"18"}}
+}
+ENTRY %main.1 (p0: f32[4096,9216]) -> f32[4096,9216] {
+  %all-reduce.84 = (f32[96]{0:T(128)}, f32[]{:T(128)}, f32[96,3,11,11]{0,1,3,2:T(4,128)}, /*index=3*/f32[256]{0:T(256)}) all-reduce(%fusion.53, %constant.324, %slice_convert_fusion, %copy-done.3), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%region_1.2
+  %all-reduce.45 = f32[4096,9216]{1,0:T(8,128)} all-reduce(%convolution_convert_fusion), channel_id=3, replica_groups={{0,1,2,3}}, to_apply=%region_1.2
+  %all-reduce.9 = f32[]{:T(128)} all-reduce(%constant.1), channel_id=4, replica_groups={{0,1,2,3}}, to_apply=%region_1.2
+}
+"""
+
+
+def test_gradient_all_reduce_census_counts_each_collective_once():
+    """An all-reduce the TPU compiler fused into an
+    ``async_collective_fusion`` is one asynchronous collective, not the
+    three lines its text spends on it; a merged tuple whose operands name a
+    ``%copy-done`` is parsed like any other (it was skipped as a ``-done``
+    op before PR 59); scalars are not gradients."""
+    from poseidon_tpu.runtime.hlo_comm import (count_gradient_all_reduces,
+                                               gradient_all_reduce_census)
+    assert gradient_all_reduce_census(_TPU_TEXT) == (3, 1)
+    assert count_gradient_all_reduces(_TPU_TEXT) == 3
+    merged = [c for c in parse_collectives(_TPU_TEXT)
+              if c.shape == (96,)]
+    assert len(merged) == 1 and merged[0].group_size == 4
+    assert merged[0].payload_bytes == 4 * (96 + 1 + 96 * 3 * 11 * 11 + 256)
+
+
+def test_gradient_all_reduce_census_counts_start_done_pairs_as_async():
+    text = (
+        "%ars = (f32[500,300]{1,0}, f32[500,300]{1,0}) all-reduce-start("
+        "%g), replica_groups={{0,1},{2,3}}, to_apply=%add\n"
+        "%ard = f32[500,300]{1,0} all-reduce-done(%ars)\n"
+        "%ar = f32[500,300]{1,0} all-reduce(%h), "
+        "replica_groups={{0,1},{2,3}}, to_apply=%add\n")
+    from poseidon_tpu.runtime.hlo_comm import gradient_all_reduce_census
+    assert gradient_all_reduce_census(text) == (2, 1)
+
+
+@pytest.mark.parametrize("comm,expect_async", [
+    (CommConfig(), 0), (CommConfig(dwbp_bucket_mb=0), 0)],
+    ids=["plain_taps", "chained_taps"])
+def test_census_of_the_compiled_cpu_step(lenet_net, comm, expect_async):
+    """On the CPU backend every gradient all-reduce is synchronous, and
+    the census agrees with the collectives the parser lists."""
+    from poseidon_tpu.runtime.hlo_comm import gradient_all_reduce_census
+    _, text = _compiled_text(lenet_net, comm, make_mesh())
+    n, n_async = gradient_all_reduce_census(text, min_payload_bytes=40)
+    assert n_async == expect_async
+    assert n == sum(1 for c in parse_collectives(text)
+                    if c.kind == "all-reduce" and c.group_size > 1
+                    and c.payload_bytes >= 40) >= 1

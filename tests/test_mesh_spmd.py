@@ -514,7 +514,7 @@ def test_comm_scopes_attribute_per_axis():
                         ("grad_ar_bucket3", "data"),
                         ("param_ag_bucket1", "fsdp"),
                         ("hist_ag_bucket0", "fsdp"),
-                        ("grad_sync_bucket2", "data"),
+                        ("delta_ar_bucket2", "data"),
                         ("delta_rs_bucket0", "fsdp"),
                         ("tp_fwd_ip1", "tp"),
                         ("tp_dx_ip1", "tp"),

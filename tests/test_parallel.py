@@ -101,6 +101,64 @@ def test_dense_dp_matches_single_device(mesh, lenet_net, rng_np):
                 rtol=2e-2, atol=1.5e-3, err_msg=f"{l}/{k}")
 
 
+@pytest.mark.parametrize("param_arena", [True, False])
+def test_multi_device_step_packs_nothing(mesh, lenet_net, rng_np,
+                                         param_arena):
+    """The route of PR 59, read in the COMPILED eight-device step: each
+    DENSE gradient is summed where backward makes it, so the program holds
+    no ``arena_*`` / ``grad_sync_bucket`` scope, no 1-D f32 buffer wider
+    than a bias (a bucket of LeNet's 431,080 parameters was one), and at
+    most one gradient all-reduce a leaf — fewer where the compiler's
+    combiner merges — whatever ``param_arena`` says."""
+    import re
+
+    from poseidon_tpu.runtime.hlo_comm import gradient_all_reduce_census
+    sp = SolverParameter(base_lr=0.01, lr_policy="fixed", momentum=0.9,
+                         weight_decay=0.0005)
+    params = lenet_net.init(jax.random.PRNGKey(0))
+    comm = CommConfig(param_arena=param_arena)
+    ts = build_train_step(lenet_net, sp, mesh, comm, donate=False)
+    assert ts.arena is None and ts.update_route == "leaf"
+    text = ts.lowerable.lower(
+        params, init_train_state(params, comm, N_DEV), _global_batch(rng_np),
+        jax.random.PRNGKey(1)).compile().as_text()
+    assert "arena_" not in text and "grad_sync_bucket" not in text
+    leaves = jax.tree_util.tree_leaves(params)
+    widest_bias = max(v.size for v in leaves if v.ndim == 1)
+    assert max(int(n) for n in re.findall(r"f32\[(\d+)\]", text)) \
+        <= widest_bias
+    n, n_async = gradient_all_reduce_census(text, min_payload_bytes=40)
+    assert 1 <= n <= len(leaves) and 0 <= n_async <= n, (n, n_async)
+
+
+def test_dense_dp_mean_matches_single_device_on_whole_batch(mesh, lenet_net,
+                                                            rng_np):
+    """``reduce="mean"`` (the default): after N steps the eight-device
+    step's parameters equal ONE device's on the concatenated batch — the
+    mean of eight equal shards' mean gradients is the whole batch's — to
+    the tolerances of ``test_dense_dp_matches_single_device``."""
+    from poseidon_tpu.runtime.hlo_layout import build_plain_step
+    sp = SolverParameter(base_lr=0.01, lr_policy="fixed", momentum=0.9,
+                         weight_decay=0.0005)
+    params = lenet_net.init(jax.random.PRNGKey(0))
+    batch = _global_batch(rng_np)
+    ts = build_train_step(lenet_net, sp, mesh, CommConfig(), donate=False)
+    whole = Net(zoo.lenet(with_accuracy=False), phase="TRAIN",
+                source_shapes=zoo.lenet_shapes(BATCH))
+    plain = jax.jit(build_plain_step(whole, sp))
+    p, s = params, init_train_state(params)
+    wp, ws = params, init_state(params)
+    for step, (rtol, atol) in enumerate(
+            [(1e-5, 1e-6), (2e-2, 1.5e-3), (2e-2, 1.5e-3)]):
+        p, s, _ = ts.step(p, s, batch, jax.random.PRNGKey(99))
+        wp, ws = plain(wp, ws, batch, jax.random.PRNGKey(99))
+        for l in wp:
+            for k in wp[l]:
+                np.testing.assert_allclose(
+                    np.asarray(p[l][k]), np.asarray(wp[l][k]),
+                    rtol=rtol, atol=atol, err_msg=f"step {step + 1} {l}/{k}")
+
+
 def test_sfb_matches_dense(mesh, lenet_net, rng_np):
     sp = SolverParameter(base_lr=0.01, lr_policy="fixed", momentum=0.9)
     params = lenet_net.init(jax.random.PRNGKey(0))
@@ -354,43 +412,73 @@ def test_dwbp_bucket_grouping(mesh, lenet_net, rng_np):
     assert bucketed < per_blob, (bucketed, per_blob)
 
 
-def test_arena_sfb_topk_layers_opt_out(mesh, lenet_net, rng_np):
-    """SFB and TOPK layers keep their custom comm paths under the flat
-    parameter arena: the arena layout excludes them, and a mixed-strategy
-    step is bit-identical with the arena on and off (same SFB factor
-    gathers, same TOPK compression + error feedback, same DENSE arena
-    leaves)."""
-    import dataclasses
+@pytest.mark.parametrize("param_arena", [True, False])
+def test_sfb_topk_layers_keep_their_paths_beside_dense_taps(
+        mesh, lenet_net, rng_np, param_arena):
+    """SFB and TOPK layers keep their custom comm paths beside the DENSE
+    layers' per-leaf taps, whatever ``param_arena`` says (it decides
+    nothing in this step, PR 59): a mixed-strategy step equals the
+    reference built from the parts — ``jax.grad`` on each shard, a plain
+    ``lax.psum`` mean for the DENSE leaves AND for the SFB layer (the
+    factor exchange reconstructs the same global gradient), the TOPK layer
+    through ``topk_compress`` on the raw local gradient — to f32
+    rounding, TOPK error-feedback residuals included."""
+    from jax import lax, shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from poseidon_tpu.parallel.strategies import comm_salt, topk_compress
+    from poseidon_tpu.parallel.trainer import param_mults
+    from poseidon_tpu.solvers.updates import make_update_fn
     sp = SolverParameter(base_lr=0.01, lr_policy="fixed", momentum=0.9,
                          weight_decay=0.0005)
     params = lenet_net.init(jax.random.PRNGKey(0))
     batch = _global_batch(rng_np)
     comm = CommConfig(layer_strategies={"ip1": SFB, "conv2": "topk"},
-                      topk_fraction=0.1)
-    results = []
-    for arena_on in (True, False):
-        cc = dataclasses.replace(comm, param_arena=arena_on)
-        ts = build_train_step(lenet_net, sp, mesh, cc, donate=False)
-        if arena_on:
-            # opt-outs: only the DENSE layers live in the arena
-            assert ts.arena is not None
-            assert ts.arena.layers == {"conv1", "ip2"}
-        p, s = params, init_train_state(params, cc, N_DEV)
-        for i in range(2):
-            p, s, m = ts.step(p, s, batch, jax.random.PRNGKey(i))
-        results.append((p, s))
-    (p1, s1), (p2, s2) = results
-    for l in p1:
-        for k in p1[l]:
-            np.testing.assert_array_equal(
-                np.asarray(p1[l][k]), np.asarray(p2[l][k]),
-                err_msg=f"{l}/{k}")
-    # TOPK error-feedback residuals agree too (same compression inputs)
-    for l in s1.comm_error:
-        for k in s1.comm_error[l]:
-            np.testing.assert_array_equal(
-                np.asarray(s1.comm_error[l][k]),
-                np.asarray(s2.comm_error[l][k]), err_msg=f"err {l}/{k}")
+                      topk_fraction=0.1, param_arena=param_arena)
+    ts = build_train_step(lenet_net, sp, mesh, comm, donate=False)
+    assert ts.arena is None
+    p, s = params, init_train_state(params, comm, N_DEV)
+    for i in range(2):
+        p, s, m = ts.step(p, s, batch, jax.random.PRNGKey(i))
+
+    update = make_update_fn(sp, param_mults(lenet_net))
+
+    def device_step(params, solver, err, batch, rng):
+        rng = jax.random.fold_in(rng, lax.axis_index("data"))
+        grads = jax.grad(lambda q: lenet_net.apply(
+            q, batch, train=True, rng=rng).loss)(params)
+        new_err = {}
+        for l in grads:
+            for k, g in grads[l].items():
+                if l == "conv2":
+                    sent, resid = topk_compress(
+                        g, 0.1, err[l][k][0], "magnitude", solver.it,
+                        salt=comm_salt(l, k))
+                    grads[l][k] = lax.psum(sent, "data") / N_DEV
+                    new_err.setdefault(l, {})[k] = resid[None]
+                else:
+                    grads[l][k] = lax.psum(g, "data") / N_DEV
+        params, solver = update(params, grads, solver)
+        return params, solver, new_err
+
+    ref = jax.jit(shard_map(
+        device_step, mesh=mesh,
+        in_specs=(P(), P(), P("data"), P("data"), P()),
+        out_specs=(P(), P(), P("data")), check_vma=False))
+    s0 = init_train_state(params, comm, N_DEV)
+    rp, rs, rerr = params, s0.solver, s0.comm_error
+    for i in range(2):
+        rp, rs, rerr = ref(rp, rs, rerr, batch, jax.random.PRNGKey(i))
+    for l in p:
+        for k in p[l]:
+            np.testing.assert_allclose(
+                np.asarray(p[l][k]), np.asarray(rp[l][k]),
+                rtol=1e-4, atol=1e-6, err_msg=f"{l}/{k}")
+    for l in s.comm_error:
+        for k in s.comm_error[l]:
+            np.testing.assert_allclose(
+                np.asarray(s.comm_error[l][k]), np.asarray(rerr[l][k]),
+                rtol=1e-4, atol=1e-6, err_msg=f"err {l}/{k}")
 
 
 def test_auto_strategies_picks_sfb_for_big_fc():

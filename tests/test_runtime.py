@@ -142,12 +142,18 @@ def test_engine_snapshot_restore(tmp_path):
         eng3.close()
 
 
-def test_arena_snapshot_portability(tmp_path):
-    """Snapshots are canonical per-leaf under the flat parameter arena: a
-    per-leaf snapshot written before the arena existed loads into an
-    arena-backed run, trains, re-snapshots, and that snapshot reloads with
-    --param_arena=false bit-identically — the same training continuation
-    either way (params, momentum history, iteration)."""
+@pytest.mark.parametrize("staleness", [0, 1], ids=["sync", "ssp"])
+def test_arena_snapshot_portability(tmp_path, staleness):
+    """Snapshots are canonical per-leaf whatever ``--param_arena`` says: a
+    snapshot written by the synchronous data-parallel step loads into a
+    run with the flag on, trains, re-snapshots, and that snapshot reloads
+    with --param_arena=false bit-identically — the same training
+    continuation either way (params, history, iteration). ``ssp``: the
+    step whose boundary exchange still packs the flat buffer, so the flag
+    chooses between two programs there; ``sync``: the data-parallel step,
+    which holds no arena under either value (PR 59) and must keep resuming
+    what the bucketed step of earlier PRs wrote (per-leaf then as now)."""
+    import jax
     from poseidon_tpu.parallel import CommConfig
     from poseidon_tpu.proto.messages import load_solver
     from poseidon_tpu.runtime.checkpoint import restore
@@ -155,13 +161,14 @@ def test_arena_snapshot_portability(tmp_path):
 
     solver_path = _write_mnistish_prototxt(tmp_path, max_iter=6)
 
-    def run(arena: bool, outdir: str, resume=None, to_iter=6):
+    def run(arena: bool, outdir: str, resume=None, to_iter=6, stale=0):
         sp = load_solver(solver_path)
         sp.snapshot_after_train = True
         eng = Engine(sp, comm=CommConfig(param_arena=arena),
-                     memory_data=_memory_data(), output_dir=outdir)
+                     memory_data=_memory_data(), output_dir=outdir,
+                     staleness=stale)
         try:
-            assert (eng.train_step.arena is not None) == arena
+            assert (eng.train_step.arena is not None) == (arena and stale > 0)
             if resume:
                 eng.restore_from(resume)
             eng.train(max_iter=to_iter)
@@ -170,37 +177,30 @@ def test_arena_snapshot_portability(tmp_path):
         return os.path.join(outdir, "snap",
                             f"smallnet_iter_{to_iter}.solverstate.npz")
 
-    # 1) the "pre-arena" snapshot: a per-leaf run to iter 6
+    def assert_same(a, b, what):
+        ta, tb = restore(a), restore(b)
+        la, lb = jax.tree_util.tree_leaves(ta), jax.tree_util.tree_leaves(tb)
+        assert len(la) == len(lb) and len(la) > 8
+        for x, y in zip(la, lb):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                          err_msg=what)
+
+    # 1) the snapshot the synchronous per-leaf step writes, at iter 6
     base = run(False, str(tmp_path / "leaf"))
     assert os.path.exists(base)
-    # 2) continue 6 -> 9 under the arena, and per-leaf as the reference
-    snap_arena = run(True, str(tmp_path / "arena9"), resume=base, to_iter=9)
-    snap_leaf = run(False, str(tmp_path / "leaf9"), resume=base, to_iter=9)
-    pa, sa = restore(snap_arena)
-    pl, sl = restore(snap_leaf)
-    assert int(sa.solver.it) == int(sl.solver.it) == 9
-    for l in pa:
-        for k in pa[l]:
-            np.testing.assert_array_equal(
-                np.asarray(pa[l][k]), np.asarray(pl[l][k]),
-                err_msg=f"params {l}/{k}")
-            np.testing.assert_array_equal(
-                np.asarray(sa.solver.history[l][k]),
-                np.asarray(sl.solver.history[l][k]),
-                err_msg=f"history {l}/{k}")
-    # 3) the arena run's snapshot reloads into a per-leaf run and trains —
+    # 2) continue 6 -> 9 with the flag on, and off as the reference
+    snap_arena = run(True, str(tmp_path / "arena9"), resume=base, to_iter=9,
+                     stale=staleness)
+    snap_leaf = run(False, str(tmp_path / "leaf9"), resume=base, to_iter=9,
+                    stale=staleness)
+    assert_same(snap_arena, snap_leaf, "iter 9")
+    # 3) the flag-on run's snapshot reloads into a flag-off run and trains —
     # continuation parity 9 -> 12 across the representation boundary
     snap_a12 = run(False, str(tmp_path / "a12"), resume=snap_arena,
-                   to_iter=12)
+                   to_iter=12, stale=staleness)
     snap_l12 = run(True, str(tmp_path / "l12"), resume=snap_leaf,
-                   to_iter=12)
-    pa12, _ = restore(snap_a12)
-    pl12, _ = restore(snap_l12)
-    for l in pa12:
-        for k in pa12[l]:
-            np.testing.assert_array_equal(
-                np.asarray(pa12[l][k]), np.asarray(pl12[l][k]),
-                err_msg=f"12 {l}/{k}")
+                   to_iter=12, stale=staleness)
+    assert_same(snap_a12, snap_l12, "iter 12")
 
 
 def test_stale_snapshot_tmp_swept_and_never_shadows(tmp_path):
