@@ -40,6 +40,10 @@ def fill(rng: jax.Array, pdef: ParamDef, dtype=jnp.float32) -> jax.Array:
     if t == "xavier":
         scale = (3.0 / pdef.fan_in) ** 0.5
         return jax.random.uniform(rng, shape, dtype, minval=-scale, maxval=scale)
+    if t == "diagonal":
+        # ``value`` on the diagonal of a square matrix, 0 off it (the bias of
+        # a hyper-connection's mix: near the identity after the projection)
+        return f.value * jnp.eye(shape[-2], shape[-1], dtype=dtype)
     if t == "log_of_uniform":
         # the log of a uniform draw in [min, max] (a decay rate's A_log)
         return jnp.log(jax.random.uniform(rng, shape, dtype, minval=f.min,

@@ -389,7 +389,12 @@ class AttentionLayer(Layer):
     last Ds dims of every q head, the dims that meet it in the scores;
     k's own dims and the rest of q pass as they come (DeepSeek-V3's
     decoupled rotary part: GLM-4.7-Flash's 192 + 64). ``rotary_dims`` is
-    then Ds or unset."""
+    then Ds or unset.
+
+    ``rope_factor`` > 1: the frequencies are YaRN's blend of theta's and
+    theta's divided by it (``models/transformer.rope_frequencies``, with
+    ``rope_original_positions``, ``rope_beta_fast``, ``rope_beta_slow``);
+    1, the default, is plain theta."""
     TYPE = "ATTENTION"
 
     def setup(self, bottom_shapes):
@@ -442,13 +447,23 @@ class AttentionLayer(Layer):
         if ap.scale < 0:
             raise ValueError(f"{self.name}: scale {ap.scale} is negative "
                              f"(0 = 1 / sqrt(Dh))")
+        if ap.rope_factor < 1 or (ap.rope_factor > 1 and not (
+                ap.rope and ap.rope_original_positions > 0
+                and ap.rope_beta_fast > ap.rope_beta_slow > 0)):
+            raise ValueError(f"{self.name}: rope_factor {ap.rope_factor} "
+                             f"(1 = plain theta) needs rope, original "
+                             f"positions and rope_beta_fast > "
+                             f"rope_beta_slow > 0")
         return [tuple(bottom_shapes[0][:2]) + (ap.num_heads * self.v_head,)]
 
     def apply(self, params, bottoms, ctx):
-        from ..models.transformer import rope_attention
+        from ..models.transformer import Yarn, rope_attention
         ap = self.lp.attention_param
+        theta = ap.rope_theta if ap.rope_factor == 1.0 else Yarn(
+            ap.rope_theta, ap.rope_factor, ap.rope_original_positions,
+            ap.rope_beta_fast, ap.rope_beta_slow)
         return [rope_attention(*bottoms[:3], n_heads=ap.num_heads,
-                               rope_theta=ap.rope_theta,
+                               rope_theta=theta,
                                n_kv_heads=ap.num_kv_heads,
                                rotary_dims=ap.rotary_dims,
                                window=ap.window, rope=ap.rope,
@@ -482,6 +497,8 @@ class AttentionLayer(Layer):
             arm += ("; k_pe rotated once, joined x" if ap.rotary_shared
                     else "; k_pe repeated x") \
                 + f"{ap.num_kv_heads or ap.num_heads}"
+        if ap.rope_factor > 1:
+            arm += f"; yarn x{ap.rope_factor:g}"
         return "attention", arm, note
 
 
@@ -1162,6 +1179,159 @@ class SiLUGateLayer(Layer):
         return [jax.nn.silu(bottoms[0]) * bottoms[1]]
 
 
+# The residual stream of hyper-connections (``ops/hyper.py``): n residual
+# states of the hidden size side by side, (N, S, n C).
+
+class _HyperLayer(Layer):
+    """What the five stream layers share: ``hyper_param.streams`` and the
+    check that a stream blob (N, S, n C) is n whole hidden states."""
+
+    def _n(self) -> int:
+        n = self.lp.hyper_param.streams
+        if n < 1:
+            raise ValueError(f"{self.name}: hyper_param.streams {n} < 1")
+        return n
+
+    def _stream(self, shape) -> Tuple[int, int, int, int]:
+        """(N, S, n, C) of a stream blob's shape."""
+        n = self._n()
+        if len(shape) != 3 or shape[-1] % n:
+            raise ValueError(f"{self.name}: a stream is (N, S, n C) with n "
+                             f"= {n}, got {tuple(shape)}")
+        return shape[0], shape[1], n, shape[-1] // n
+
+    def _coef(self, stream_shape, coef_shape):
+        b, s, n, _ = self._stream(stream_shape)
+        if tuple(coef_shape) != (b, s, n * (n + 2)):
+            raise ValueError(f"{self.name}: the coefficients of an HC_MAP "
+                             f"are (N, S, n (n + 2)) = "
+                             f"{(b, s, n * (n + 2))}, got {coef_shape}")
+
+
+class HCStartLayer(_HyperLayer):
+    """(N, S, C) -> the stream (N, S, n C): every stream a copy."""
+    TYPE = "HC_START"
+
+    def setup(self, bottom_shapes):
+        return [tuple(bottom_shapes[0][:-1])
+                + (bottom_shapes[0][-1] * self._n(),)]
+
+    def apply(self, params, bottoms, ctx):
+        from ..ops.hyper import hc_start
+        return [hc_start(bottoms[0], self._n())]
+
+
+class HCEndLayer(_HyperLayer):
+    """The stream (N, S, n C) -> (N, S, C): the streams' sum."""
+    TYPE = "HC_END"
+
+    def setup(self, bottom_shapes):
+        b, s, _, c = self._stream(bottom_shapes[0])
+        return [(b, s, c)]
+
+    def apply(self, params, bottoms, ctx):
+        from ..ops.hyper import hc_end
+        return [hc_end(bottoms[0], self._n())]
+
+
+class HCMapLayer(_HyperLayer):
+    """The stream (N, S, n C) -> a sub-layer's coefficients a token,
+    (N, S, n (n + 2)) f32: p (n) for HC_READ, q (n) and the doubly
+    stochastic M (n n) for HC_WRITE (``ops/hyper.hc_map``); then three
+    scalars a display carries: what the Sinkhorn iterations leave (the
+    largest |rowsum - 1| or |colsum - 1| of the step's tokens), the mean of
+    p and the mean of q. Blobs: phi_pre, phi_post (n, n C), phi_res
+    (n n, n C) from ``weight_filler``; b_pre (n,) = logit(1 / n), b_post
+    (n,) = 0 (q = 1), b_res (n, n) = ``RES_DIAG`` on the diagonal (the mix
+    starts near the identity); a_pre, a_post, a_res (1,) = ``SCALE``."""
+    TYPE = "HC_MAP"
+    SCALE, RES_DIAG = 0.01, 4.0
+
+    def setup(self, bottom_shapes):
+        hp = self.lp.hyper_param
+        b, s, n, c = self._stream(bottom_shapes[0])
+        if len(self.lp.top) != 4 or hp.sinkhorn_iters < 0 or hp.clamp <= 0:
+            raise ValueError(f"{self.name}: HC_MAP has 4 tops "
+                             f"(coefficients, res_err, pre_mean, "
+                             f"post_mean), sinkhorn_iters >= 0 and clamp "
+                             f"> 0; got {len(self.lp.top)} tops")
+        const = lambda v: FillerParameter(type="constant", value=v)
+        shapes = [
+            ("phi_pre", (n, n * c), hp.weight_filler),
+            ("phi_post", (n, n * c), hp.weight_filler),
+            ("phi_res", (n * n, n * c), hp.weight_filler),
+            ("b_pre", (n,), const(-math.log(n - 1.0) if n > 1 else 30.0)),
+            ("b_post", (n,), const(0.0)),
+            ("b_res", (n, n), FillerParameter(type="diagonal",
+                                              value=self.RES_DIAG)),
+            ("a_pre", (1,), const(self.SCALE)),
+            ("a_post", (1,), const(self.SCALE)),
+            ("a_res", (1,), const(self.SCALE))]
+        self.params = [self._param(name, shape, filler, i)
+                       for i, (name, shape, filler) in enumerate(shapes)]
+        return [(b, s, n * (n + 2)), (), (), ()]
+
+    def apply(self, params, bottoms, ctx):
+        from ..ops.hyper import hc_map
+        hp = self.lp.hyper_param
+        return list(hc_map(bottoms[0], _tap_all(ctx, self.name, params),
+                           self._n(), hp.sinkhorn_iters, hp.eps, hp.clamp))
+
+    def forward_flops(self, bottom_shapes, top_shapes, defs):
+        b, s, n, c = self._stream(bottom_shapes[0])
+        # the projection, the statistic, and the loop's 4 n n a pass
+        return float(b * s) * (2.0 * n * c * n * (n + 2) + 2.0 * n * c
+                               + 4.0 * n * n
+                               * self.lp.hyper_param.sinkhorn_iters)
+
+
+class HCReadLayer(_HyperLayer):
+    """Bottoms the stream (N, S, n C) and an HC_MAP's coefficients ->
+    (N, S, C): a sub-layer's input, sum_j p_j X_j."""
+    TYPE = "HC_READ"
+
+    def setup(self, bottom_shapes):
+        if len(bottom_shapes) != 2:
+            raise ValueError(f"{self.name}: HC_READ takes the stream and "
+                             f"its coefficients, got {bottom_shapes}")
+        self._coef(*bottom_shapes)
+        b, s, _, c = self._stream(bottom_shapes[0])
+        return [(b, s, c)]
+
+    def apply(self, params, bottoms, ctx):
+        from ..ops.hyper import hc_read
+        return [hc_read(bottoms[0], bottoms[1], self._n())]
+
+    def forward_flops(self, bottom_shapes, top_shapes, defs):
+        return 2.0 * _elems(bottom_shapes[:1])
+
+
+class HCWriteLayer(_HyperLayer):
+    """Bottoms the stream (N, S, n C), a sub-layer's output (N, S, C) and
+    the HC_MAP's coefficients -> the stream after it,
+    X'_i = sum_j M_ij X_j + q_i y."""
+    TYPE = "HC_WRITE"
+
+    def setup(self, bottom_shapes):
+        if len(bottom_shapes) != 3:
+            raise ValueError(f"{self.name}: HC_WRITE takes the stream, the "
+                             f"sub-layer's output and the coefficients, "
+                             f"got {bottom_shapes}")
+        b, s, _, c = self._stream(bottom_shapes[0])
+        self._coef(bottom_shapes[0], bottom_shapes[2])
+        if tuple(bottom_shapes[1]) != (b, s, c):
+            raise ValueError(f"{self.name}: the sub-layer's output is "
+                             f"{(b, s, c)}, got {bottom_shapes[1]}")
+        return [tuple(bottom_shapes[0])]
+
+    def apply(self, params, bottoms, ctx):
+        from ..ops.hyper import hc_write
+        return [hc_write(bottoms[0], bottoms[1], bottoms[2], self._n())]
+
+    def forward_flops(self, bottom_shapes, top_shapes, defs):
+        return 2.0 * (self._n() + 1) * _elems(bottom_shapes[:1])
+
+
 # --------------------------------------------------------------------------- #
 # Vision layers
 # --------------------------------------------------------------------------- #
@@ -1757,7 +1927,8 @@ REGISTRY: Dict[str, type] = {
         AbsValLayer, PowerLayer, ThresholdLayer, DropoutLayer, FlattenLayer,
         ConcatLayer, SliceLayer, SplitLayer, EltwiseLayer, MVNLayer,
         SilenceLayer, SoftmaxLayer, ArgMaxLayer, SoftmaxLossLayer,
-        SiLUGateLayer, SoftmaxNLLLayer, ExitLossLayer, WeightedMeanLossLayer,
+        SiLUGateLayer, HCStartLayer, HCEndLayer, HCMapLayer, HCReadLayer,
+        HCWriteLayer, SoftmaxNLLLayer, ExitLossLayer, WeightedMeanLossLayer,
         EuclideanLossLayer, HingeLossLayer, MultinomialLogisticLossLayer,
         SigmoidCrossEntropyLossLayer, InfogainLossLayer, ContrastiveLossLayer,
         AccuracyLayer, DataLayer, ImageDataLayer, HDF5DataLayer,

@@ -18,7 +18,7 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -129,10 +129,52 @@ def rms_norm(x: jax.Array, g: jax.Array, eps: float = 1e-5) -> jax.Array:
         .astype(x.dtype)
 
 
-def rope_tables(seq: int, d_head: int, theta: float = 10000.0):
+class Yarn(NamedTuple):
+    """YaRN's blended rotary frequencies in the DeepSeek-V3 form (a
+    ``rope_scaling`` block of type "yarn"): what ``rope_frequencies`` takes
+    in plain theta's place. Hashable: it rides ``_rope_lanes``' static
+    arguments."""
+    theta: float
+    factor: float
+    original_positions: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+
+
+def rope_frequencies(rot: int, theta) -> np.ndarray:
+    """The ``rot / 2`` rotary frequencies of a head, on the host (float64).
+    ``theta`` a number: f_i = theta^(-2i/rot), nothing else. ``theta`` a
+    :class:`Yarn`: with c(b) = rot ln(P / (2 pi b)) / (2 ln theta) the pair
+    that makes b turns over the P original positions, low = floor(c(
+    beta_fast)), high = ceil(c(beta_slow)) (both inside 0 .. rot/2 - 1),
+    m_i = 1 - clip((i - low) / (high - low), 0, 1), pair i turns by
+    f_i m_i + (f_i / factor) (1 - m_i): the fast pairs as they were, the
+    slow ones ``factor`` times slower, a linear blend between. cos and sin
+    take no factor (a model's mscale belongs to the scores' scale). A
+    ``factor`` of 1 is plain theta."""
+    yarn = isinstance(theta, Yarn)
+    inv = 1.0 / (theta.theta if yarn else theta) ** (np.arange(0, rot, 2)
+                                                     / rot)
+    if not yarn or theta.factor == 1:
+        return inv
+
+    def pair(turns):
+        return rot * math.log(theta.original_positions
+                              / (2 * math.pi * turns)) \
+            / (2 * math.log(theta.theta))
+
+    low = max(math.floor(pair(theta.beta_fast)), 0)
+    high = min(math.ceil(pair(theta.beta_slow)), rot // 2 - 1)
+    keep = 1.0 - np.clip((np.arange(rot // 2) - low)
+                         / max(high - low, 1e-3), 0.0, 1.0)
+    return inv / theta.factor * (1.0 - keep) + inv * keep
+
+
+def rope_tables(seq: int, d_head: int, theta=10000.0):
     """(cos, sin), each (seq, d_head) f32, of the rotate-half convention:
-    frequency i serves dims i and i + d_head/2."""
-    inv = 1.0 / theta ** (np.arange(0, d_head, 2) / d_head)  # host numpy
+    frequency i serves dims i and i + d_head/2. ``theta``: a number, or a
+    :class:`Yarn` for its blended frequencies (``rope_frequencies``)."""
+    inv = rope_frequencies(d_head, theta)                    # host numpy
     ang = np.arange(seq)[:, None] * inv[None, :]
     ang = np.concatenate([ang, ang], axis=-1)
     return (jnp.asarray(np.cos(ang), jnp.float32),
@@ -149,7 +191,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
-                   rope_theta: float = 10000.0, n_kv_heads: int = 0,
+                   rope_theta=10000.0, n_kv_heads: int = 0,
                    rotary_dims: int = 0, window: int = 0,
                    rope: bool = True,
                    k_shared: Optional[jax.Array] = None,
@@ -164,7 +206,8 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     in autodiff).
     ``rotary_dims`` (0 = the whole head): only the first that many dims of
     a head rotate, the rest pass; ``rope`` false: nothing rotates, the
-    layer has no positions. ``window`` (0 = none): token t attends to s with
+    layer has no positions. ``rope_theta``: a number, or a :class:`Yarn`
+    for its blended frequencies (``rope_frequencies``). ``window`` (0 = none): token t attends to s with
     t - window < s <= t. ``scale`` (None: 1 / sqrt(Dh)): what multiplies
     q k^T. The Pallas flash kernel where the sequence
     tiles (``maybe_flash_attention``), the dense op elsewhere.
@@ -183,7 +226,9 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
     its own dims followed by it, repeated to the heads before the kernel
     as key-value heads are. ``rotary_shared``: the positions are on that
     shared part, rotated once a token (Ds lanes) before the heads take it,
-    and on the LAST Ds dims of every q head; nothing else rotates."""
+    and on the LAST Ds dims of every q head; nothing else rotates (on the
+    head-major form, Xing4.0's 192 / 128, after the head split; on the
+    lanes form where q lies)."""
     b, s, d = q.shape
     d_head = d // n_heads
     n_kv = n_kv_heads or n_heads
@@ -234,7 +279,7 @@ def rope_attention(q: jax.Array, k: jax.Array, v: jax.Array, n_heads: int,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def _rope_lanes(x: jax.Array, n: int, rot: int, theta: float,
+def _rope_lanes(x: jax.Array, n: int, rot: int, theta,
                 turn: int = 1, at: int = 0) -> jax.Array:
     """``apply_rope`` on x (B, S, n·Dh) as it lies, its ``n`` heads side by
     side along the lanes and the ``rot`` dims of each from dim ``at`` on
@@ -243,6 +288,7 @@ def _rope_lanes(x: jax.Array, n: int, rot: int, theta: float,
     and so its backward). Nothing is reshaped to (B, S, n, Dh), which under
     the TPU's (8, 128) tiling is another layout and a copy of x each way:
     a dim's rotate-half partner is ``rot / 2`` lanes to its right or left.
+    ``theta`` is what ``rope_frequencies`` takes: a number, or a ``Yarn``.
 
     The tables are the problem of this form: (S, Dh) ones do not broadcast
     along the lanes of n heads, and (S, n·Dh) ones are n times the constant
@@ -254,7 +300,7 @@ def _rope_lanes(x: jax.Array, n: int, rot: int, theta: float,
     b, s, width = x.shape
     d_head, half = width // n, rot // 2
     period = math.gcd(s, 128)
-    inv = 1.0 / theta ** (np.arange(0, rot, 2) / rot)        # host numpy
+    inv = rope_frequencies(rot, theta)                       # host numpy
     # a head's lanes: frequency i serves dims i and i + rot/2, none past
     # the rotating dims (angle 0: cos 1, sin 0, the dim passes);
     # rotate_half's sign goes with the sine

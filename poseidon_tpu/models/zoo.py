@@ -11,14 +11,17 @@ Beside them, the token models that train through the same ``Net`` (each
 builder with no arguments writes its whole published model; the committed
 ``examples/lm/*_train.prototxt`` are the cut their headers state):
 ``olmoe``, ``ouro``, ``zaya1``, ``trinity_mini``, ``kimi_linear``,
-``smallthinker``, ``olmo_hybrid``, ``granite_hybrid`` and ``glm_flash``
+``smallthinker``, ``olmo_hybrid``, ``granite_hybrid``, ``glm_flash``
 (GLM-4.7-Flash: latent attention whose rotary part rotates in every layer
 and a multi-token-prediction module that shares the embedding and the
-head).
+head) and ``xing4`` (Xing4.0-29B-A4B: ``glm_flash``'s block on a residual
+stream of four hidden states, manifold-constrained hyper-connections, with
+YaRN's rotary frequencies).
 """
 
 from __future__ import annotations
 
+import math
 from typing import Dict, List, Optional, Tuple
 
 from ..proto.messages import (  # noqa: F401
@@ -1213,7 +1216,12 @@ def glm_flash(batch: int = 1, n_layers: int = 47, held: int = 0,
               route_scale: float = 1.8, bias_update_rate: float = 0.001,
               rope_theta: float = 1e6, eps: float = 1e-5,
               mtp_weight: float = 0.3, init_std: float = 0.02,
-              name: str = "GLM-4.7-Flash") -> NetParameter:
+              name: str = "GLM-4.7-Flash", streams: int = 0,
+              sinkhorn_iters: int = 20, hc_eps: float = 1e-6,
+              hc_clamp: float = 30.0, attn_scale: float = 0.0,
+              rope_factor: float = 1.0, rope_original_positions: int = 4096,
+              rope_beta_fast: float = 32.0, rope_beta_slow: float = 1.0
+              ) -> NetParameter:
     """GLM-4.7-Flash (config.json of zai-org/GLM-4.7-Flash,
     ``glm4_moe_lite``, 30B-A3B; the DeepSeek-V3 block, arXiv:2412.19437):
     every layer is latent attention and an FFN, pre-norm,
@@ -1262,12 +1270,31 @@ def glm_flash(batch: int = 1, n_layers: int = 47, held: int = 0,
     The main head between the module's block and the module's head makes
     them two ``/mtp_/`` remat units.
 
+    ``attn_scale`` (0: 1 / sqrt(head)) is what multiplies the scores;
+    ``rope_factor`` > 1 asks ATTENTION for YaRN's blended frequencies
+    (``rope_original_positions``, ``rope_beta_fast``, ``rope_beta_slow``).
+
+    ``streams`` n > 0 (0: the plain residual above, the net every caller
+    had): the residual state is a STREAM of n hidden states side by side
+    (hyper-connections with the manifold-constrained mapping,
+    ``ops/hyper.py``). ``hc_start`` copies the embedding to the n streams;
+    each sub-layer S of a block (``a``: attention, ``f``: the FFN, the
+    shared expert inside it) maps the stream to its coefficients
+    (``<p>hc_S_map``: one statistic, three projections, two sigmoids, the
+    n x n mix through ``sinkhorn_iters`` Sinkhorn iterations), reads its
+    input (``<p>hc_S_read``, into the sub-layer's norm) and writes the
+    stream after it (``<p>hc_S_write``) where the plain block has
+    ``<p>res1`` / ``<p>res2``; ``hc_end`` sums the streams before the final
+    norm (and the module's ``mtp_hnorm``). The module's block runs on a
+    stream of its own (``mtp_hc_start``, ``mtp_hc_end``). The mappings
+    carry decay_mult 0.
+
     Gains and the selection biases carry decay_mult 0, every matrix 1."""
     from ..proto.messages import (AttentionParameter, ConcatParameter,
                                   EltwiseParameter, EmbedParameter,
-                                  HDF5DataParameter, MoEParameter,
-                                  RMSNormParameter, SliceParameter,
-                                  TokenShiftParameter)
+                                  HDF5DataParameter, HyperParameter,
+                                  MoEParameter, RMSNormParameter,
+                                  SliceParameter, TokenShiftParameter)
     if mtp not in (0, 1):
         raise ValueError(f"glm_flash: mtp {mtp} is neither 0 nor 1 (the "
                          f"published depth)")
@@ -1308,8 +1335,26 @@ def glm_flash(batch: int = 1, n_layers: int = 47, held: int = 0,
             top=[p + "a"]))
         proj(p + "down", p + "a", top, hidden)
 
+    def hyper(lname, kind, bottoms, tops):
+        layers.append(LayerParameter(
+            name=lname, type=kind, bottom=bottoms, top=tops,
+            param=[no_decay] * 9 if kind == "HC_MAP" else [],
+            hyper_param=HyperParameter(
+                streams=streams, sinkhorn_iters=sinkhorn_iters, eps=hc_eps,
+                clamp=hc_clamp, weight_filler=w)))
+
+    def sublayer_in(p, s, x, top):
+        """The stream ``x`` -> sub-layer ``s``'s input: its mapping's
+        coefficients (and what a display shows of them), then the read."""
+        hyper(f"{p}hc_{s}_map", "HC_MAP", [x],
+              [f"{p}{s}_coef"] + [f"{p}hc_{s}_{what}" for what in (
+                  "res_err", "pre_mean", "post_mean")])
+        hyper(f"{p}hc_{s}_read", "HC_READ", [x, f"{p}{s}_coef"], [top])
+
     def block(p, x, sparse):
-        norm(p + "attn_norm", x, p + "a")
+        if streams:
+            sublayer_in(p, "a", x, p + "ah")
+        norm(p + "attn_norm", p + "ah" if streams else x, p + "a")
         proj(p + "mla_qa", p + "a", p + "cq", q_rank)
         norm(p + "mla_qnorm", p + "cq", p + "cqn")
         proj(p + "mla_qb", p + "cqn", p + "q", heads * (nope_dim + rope_dim))
@@ -1326,10 +1371,19 @@ def glm_flash(batch: int = 1, n_layers: int = 47, held: int = 0,
             bottom=[p + "q", p + "kn", p + "v", p + "kpe"], top=[p + "att"],
             attention_param=AttentionParameter(
                 num_heads=heads, rope_theta=rope_theta,
-                value_head_dim=v_dim, rotary_shared=True)))
+                value_head_dim=v_dim, rotary_shared=True, scale=attn_scale,
+                rope_factor=rope_factor,
+                rope_original_positions=rope_original_positions,
+                rope_beta_fast=rope_beta_fast,
+                rope_beta_slow=rope_beta_slow)))
         proj(p + "mla_o", p + "att", p + "ao", hidden)
-        add(p + "res1", x, p + "ao", p + "h")
-        norm(p + "ffn_norm", p + "h", p + "u")
+        if streams:
+            hyper(p + "hc_a_write", "HC_WRITE",
+                  [x, p + "ao", p + "a_coef"], [p + "h"])
+            sublayer_in(p, "f", p + "h", p + "fh")
+        else:
+            add(p + "res1", x, p + "ao", p + "h")
+        norm(p + "ffn_norm", p + "fh" if streams else p + "h", p + "u")
         if not sparse:
             gated_mlp(p + "ffn_", p + "u", p + "f", dense_width)
         else:
@@ -1350,13 +1404,29 @@ def glm_flash(batch: int = 1, n_layers: int = 47, held: int = 0,
                                        **moe)))
             gated_mlp(p + "shared_", p + "u", p + "s", shared_width)
             add(p + "moe_sum", p + "m", p + "s", p + "f")
-        add(p + "res2", p + "h", p + "f", p + "y")
+        if streams:
+            hyper(p + "hc_f_write", "HC_WRITE",
+                  [p + "h", p + "f", p + "f_coef"], [p + "y"])
+        else:
+            add(p + "res2", p + "h", p + "f", p + "y")
         return p + "y"
 
+    def residual_state(p, h, run):
+        """``run`` on the residual state that starts as ``h``: the hidden
+        state itself, or the stream between its start and its end."""
+        if not streams:
+            return run(h)
+        hyper(p + "hc_start", "HC_START", [h], [p + "xs"])
+        hyper(p + "hc_end", "HC_END", [run(p + "xs")], [p + "xe"])
+        return p + "xe"
+
+    def trunk(x):
+        for i in range(n_layers):
+            x = block(f"l{i}_", x, sparse=i >= dense_layers)
+        return x
+
     embed("embed", "tokens", "x0")
-    x = "x0"
-    for i in range(n_layers):
-        x = block(f"l{i}_", x, sparse=i >= dense_layers)
+    x = residual_state("", "x0", trunk)
     if mtp:
         # token t+1 is the targets' token t: no shift before the lookup
         embed("mtp_embed", "targets", "mtp_e")
@@ -1366,7 +1436,8 @@ def glm_flash(batch: int = 1, n_layers: int = 47, held: int = 0,
             name="mtp_cat", type="CONCAT", bottom=["mtp_en", "mtp_hn"],
             top=["mtp_eh_in"], concat_param=ConcatParameter(concat_dim=2)))
         proj("mtp_eh", "mtp_eh_in", "mtp_z", hidden)
-        z = block("mtp_", "mtp_z", sparse=True)
+        z = residual_state("mtp_", "mtp_z",
+                           lambda h: block("mtp_", h, sparse=True))
     head = [ParamSpec(name="head_w")] if mtp else []
     norm("final_norm", x, "xf")
     proj("lm_head", "xf", "logits", vocab, head)
@@ -1391,6 +1462,53 @@ def glm_flash(batch: int = 1, n_layers: int = 47, held: int = 0,
             bottom=["mtp_nll_pos", "mtp_real"], top=["mtp_loss"],
             loss_weight=[mtp_weight]))
     return NetParameter(name=name, layers=layers)
+
+
+def xing4(batch: int = 1, n_layers: int = 40, held: int = 0,
+          vocab: int = 131072, mtp: int = 1,
+          source: str = "examples/lm/xing4_0_29b_a4b_tokens.txt",
+          dense_layers: int = 2, hidden: int = 3584, heads: int = 32,
+          q_rank: int = 768, kv_rank: int = 512, nope_dim: int = 128,
+          rope_dim: int = 64, v_dim: int = 128, dense_width: int = 9216,
+          experts: int = 64, top_k: int = 4, held_first: int = 0,
+          expert_width: int = 1024, shared_width: int = 1024,
+          route_scale: float = 2.0, rope_theta: float = 10000.0,
+          eps: float = 1e-6, streams: int = 4, sinkhorn_iters: int = 20,
+          hc_eps: float = 1e-6, hc_clamp: float = 30.0,
+          rope_factor: float = 64.0, rope_original_positions: int = 4096,
+          rope_beta_fast: float = 32.0, rope_beta_slow: float = 1.0,
+          mscale_all_dim: float = 1.0, **rest) -> NetParameter:
+    """Xing4.0-29B-A4B (config.json of XingChen-AGI/Xing4.0-29B-A4B,
+    ``xing4_0``): ``glm_flash``'s block — latent attention in every layer
+    (32 heads of [128 ; 64] against values of 128, the 64-wide shared key
+    part and every q head's last 64 dims rotating), ``dense_layers``
+    leading dense layers, then sigmoid top-4 routers over 64 experts with a
+    shared expert, the prediction module — on a residual state of
+    ``streams`` = 4 hidden states (``glm_flash``'s ``streams``:
+    manifold-constrained hyper-connections, a read, a write and a 4 x 4
+    Sinkhorn-projected mix a token and sub-layer), with YaRN's rotary
+    frequencies (``rope_factor`` 64 over 4,096 positions) and the scores'
+    scale (0.1 ``mscale_all_dim`` ln(``rope_factor``) + 1)^2 / sqrt(192)
+    (YaRN's mscale squared, the DeepSeek-V3 form; cos and sin take none).
+    With no arguments the whole published model: 40 layers, 2 of them
+    dense, 64 experts, 131,072 rows, the module. ``rest`` goes to
+    ``glm_flash`` (``bias_update_rate``, ``mtp_weight``, ``init_std``)."""
+    mscale = 0.1 * mscale_all_dim * math.log(rope_factor) + 1.0 \
+        if rope_factor > 1 else 1.0
+    return glm_flash(
+        batch=batch, n_layers=n_layers, held=held, vocab=vocab, mtp=mtp,
+        source=source, dense_layers=dense_layers, hidden=hidden, heads=heads,
+        q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope_dim, rope_dim=rope_dim,
+        v_dim=v_dim, dense_width=dense_width, experts=experts, top_k=top_k,
+        held_first=held_first, expert_width=expert_width,
+        shared_width=shared_width, route_scale=route_scale,
+        rope_theta=rope_theta, eps=eps, streams=streams,
+        sinkhorn_iters=sinkhorn_iters, hc_eps=hc_eps, hc_clamp=hc_clamp,
+        attn_scale=mscale * mscale / math.sqrt(nope_dim + rope_dim),
+        rope_factor=rope_factor,
+        rope_original_positions=rope_original_positions,
+        rope_beta_fast=rope_beta_fast, rope_beta_slow=rope_beta_slow,
+        **{"name": "Xing4.0-29B-A4B", **rest})
 
 
 def olmo_hybrid(batch: int = 1,

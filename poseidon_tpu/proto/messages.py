@@ -325,7 +325,22 @@ class AttentionParameter:
     # that meet it in the scores (0 = the shared part's width, and nothing
     # else is allowed: the two rotate together); k's own dims and the rest
     # of q pass. False: ``rotary_dims`` are a head's first dims, as ever.
+    # Runs on heads that are whole vregs of lanes (GLM-4.7-Flash's 256 / 256,
+    # where q and k rotate as they lie) and on the head-major form
+    # (Xing4.0's 192 / 128: q's tails rotate after the head split).
     rotary_shared: bool = False
+    # YaRN's blended frequencies (the DeepSeek-V3 form, ``rope_scaling`` of
+    # type "yarn"): with ``rope_factor`` > 1 pair i of a head's R / 2 turns
+    # by f_i = theta^(-2i/R) where it is fast, by f_i / rope_factor where it
+    # is slow, and by a linear blend between the pairs that make
+    # ``rope_beta_fast`` and ``rope_beta_slow`` turns over
+    # ``rope_original_positions`` (``models/transformer.rope_frequencies``).
+    # cos and sin take no factor: a model's mscale goes into ``scale``.
+    # 1, the default, is plain theta and the lowered step every net had.
+    rope_factor: float = 1.0
+    rope_original_positions: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
 
 
 @dataclass
@@ -416,6 +431,27 @@ class TokenShiftParameter:
     before (a causal look-back); 1 is the next token (a prediction module's
     targets: the second-next token is the targets' next)."""
     offset: int = -1
+
+
+@dataclass
+class HyperParameter:
+    """The residual STREAM of hyper-connections (arXiv:2409.19606; the
+    manifold-constrained mapping of arXiv:2512.24880): ``streams`` (n)
+    residual states of the hidden size C side by side, (N, S, n C).
+    HC_START copies a hidden state to the n streams, HC_END sums them.
+    HC_MAP makes a sub-layer's coefficients a token from the stream: one
+    RMS over all n C values (``eps``, no gain), three projections
+    (``weight_filler``), two sigmoids and the n x n mix
+    exp(clip(., -``clamp``, ``clamp``)) projected onto the doubly
+    stochastic matrices by ``sinkhorn_iters`` row-then-column
+    normalisations (``eps`` in each division). HC_READ is the sub-layer's
+    input, sum_j p_j X_j; HC_WRITE the stream after it,
+    X'_i = sum_j R_ij X_j + q_i y."""
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp: float = 30.0
+    weight_filler: FillerParameter = field(default_factory=FillerParameter)
 
 
 @dataclass
@@ -537,6 +573,8 @@ V2_TYPE_TO_V1 = {
     "MoERouter": "MOE_ROUTER", "ShortConv": "SHORT_CONV",
     "L2Norm": "L2_NORM", "KDADecay": "KDA_DECAY", "KDAScan": "KDA_SCAN",
     "SSDScan": "SSD_SCAN", "WeightedMeanLoss": "WEIGHTED_MEAN_LOSS",
+    "HCStart": "HC_START", "HCMap": "HC_MAP", "HCRead": "HC_READ",
+    "HCWrite": "HC_WRITE", "HCEnd": "HC_END",
 }
 V1_TYPES = set(V2_TYPE_TO_V1.values()) | {"NONE"}
 
@@ -601,6 +639,7 @@ class LayerParameter:
     cca_param: CCAParameter = field(default_factory=CCAParameter)
     kda_param: KDAParameter = field(default_factory=KDAParameter)
     token_shift_param: TokenShiftParameter = field(default_factory=TokenShiftParameter)
+    hyper_param: HyperParameter = field(default_factory=HyperParameter)
     blob_mode: str = "GLOBAL"  # Poseidon extension on LayerParameter level
 
     def canonical_type(self) -> str:

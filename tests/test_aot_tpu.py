@@ -1026,6 +1026,78 @@ def test_glm_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
         assert 0.60 * 16.9 < got["total_gb"] < 0.72 * 16.9
 
 
+# The full-width Xing4.0-29B-A4B train step (examples/lm/xing4_0_29b_a4b_*:
+# published layers 0 and 2-5, the dense one and four sparse ones, on a
+# residual stream of four hidden states; 8 of 64 experts held, an eighth of
+# the untied vocabulary, no prediction module) as `train --bf16 --remat <the
+# solver header's flags>` builds it at sequences of 8,192, for one abstract
+# v5e chip: the compiler's memory accounting that fixed the cell's batch
+# (benchmark/cells/xing4.e8of64.hc4.json), at the batch chosen and at the
+# next.
+_XING_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 1 + {deeper}, 8192, 0").replace(
+    "ouro_2_6b_solver", "xing4_0_29b_a4b_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers)')
+assert _XING_STEP.count("xing4_0_29b_a4b") == 1 \
+    and "ouro_2" not in _XING_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("more", [0, 1])
+def test_xing_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
+    """At ONE sequence of 8,192 the step with one checkpoint a layer and one
+    around the head is under 85% of the 16.9 GB the compiler allows (PR 22's
+    sizing rule): state alone is 72% of the chip, and what a step stores
+    between the layers is the four-stream residual state, 235 MB a
+    boundary; at two it is over. All five latent-attention blocks' three
+    flash kernels run at heads of 192 / 128 on the head-major form (Mosaic
+    compiles them for the v5e here with ``rotary_shared``: the shared key
+    part and q's tails rotated by YaRN's angles after the head split, the
+    shared part joined to the 32 heads); each MOE layer's held rows run in
+    chunks of 8,192 under one loop a pass; the stream's passes are XLA
+    fusions (no kernel of their own)."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _XING_STEP.format(repo=REPO, deeper=more)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next cut
+    assert got["depth"] == 5 and got["parameters"] == 759_346_446
+    # embed, head, final norm; a block: 2 norms, 6 projections, 2 latent
+    # gains and two mappings of nine leaves; the dense layer's 3; a sparse
+    # block's router 2, 3 stacks, shared 3
+    assert got["leaves"] == 3 + 5 * (10 + 2 * 9) + 3 + 4 * 8
+    # five layers and the head
+    assert got["segments"] == 5 + 1
+    rows = 8192 * 4 * (1 + more)
+    assert got["routes"] == [
+        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
+        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "head; flash d 192/128; operands head-major (Dh 192, not "
+        "lane-aligned)); k_pe rotated once, joined x32; yarn x64",
+        f"grouped_matmul=ragged_dot; held rows: chunks of 8192 of {rows}"]
+    # 4 flash calls a block (forward, its replay, dq, dkv); a sparse
+    # block's held arm is one loop a pass, 18 calls a block at hidden 3584
+    # (GLM's 14 at 2048)
+    assert got["pallas_custom_calls"] == 4 * 5 + 18 * 4
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    if more:
+        # 16.13 = 95.5% (PR 60; temporaries 7.02 GB)
+        assert got["total_gb"] > 0.85 * 16.9
+    else:
+        # 14.24 = 84.3% (PR 60; temporaries 5.13 GB): what the cell's `why`
+        # quotes
+        assert 0.80 * 16.9 < got["total_gb"] < 0.85 * 16.9
+
+
 # The LRN kernels at the CNN cells' norm layers (AlexNet's two at batch 512,
 # GoogLeNet's two at 128: batch-minor) and at GoogLeNet's published batch 32
 # (channel-minor), forward and backward, through Mosaic; then a stand-in for

@@ -3,7 +3,11 @@ matmuls and the routers (OLMoE, Ouro, ZAYA1; since PR 41 Trinity-Mini and
 Kimi-Linear, whose hashes are PR 43's own: it changed their held arm; since
 PR 48 Olmo-Hybrid, which ADAPTED ops/kda.py and the KDA layers and left
 Kimi's text as it was, to the byte; since PR 56 GLM-4.7-Flash, which added
-``rotary_shared`` beside them and left all six texts as they were) trace
+``rotary_shared`` beside them and left all six texts as they were; since PR
+60 Xing4.0, which added YaRN's frequencies to ``rope_tables`` /
+``_rope_lanes`` and a stream option to ``zoo.glm_flash``, left all seven as
+they were and took SmallThinker's and Granite's on its parent, so that all
+nine token configurations the benchmark had are held) trace
 to the
 program they traced to before Trinity's window, sigmoid router and per-head
 norm, and before Kimi-Linear's two head widths in the flash kernels,
@@ -68,6 +72,25 @@ BUILD = {
         q_rank=32, kv_rank=32, nope_dim=192, rope_dim=64, v_dim=256,
         dense_width=64, experts=16, top_k=2, expert_width=32,
         shared_width=32),
+    # a window layer and a global layer, 2 query heads on 1 key-value head,
+    # the softmax router on the pre-attention state, 4 of 8 experts held
+    "smallthinker": lambda: zoo.smallthinker(
+        batch=N, n_layers=2, hidden=128, heads=2, kv_heads=1, head_dim=128,
+        window=1024, global_every=2, first_global=1, experts=8, top_k=2,
+        held=4, expert_width=32, vocab=128),
+    # a Mamba-2 layer and an attention layer (no positions, a scale of its
+    # own), the four multipliers, the tied table
+    "granite": lambda: zoo.granite_hybrid(
+        batch=N, layers=2, vocab_rows=128, hidden=128, attn_every=2,
+        attn_at=1, heads=2, kv_heads=1, ssd_heads=8, ssd_head_dim=64,
+        state=128, ffn_width=64),
+    # the dense layer and a sparse layer on a stream of four: two
+    # latent-attention blocks of 192 / 128 (head-major), the shared key part
+    # and q's tails rotated by YaRN's angles after the head split
+    "xing": lambda: zoo.xing4(
+        batch=N, n_layers=2, dense_layers=1, held=2, vocab=128, mtp=0,
+        hidden=128, heads=2, q_rank=32, kv_rank=32, dense_width=64,
+        experts=16, top_k=2, expert_width=32, shared_width=32),
 }
 PARENT = {       # sha256 of the text, its length, its pallas_call equations
     # PR 47's own, all four: it meant to change them. Every ATTENTION layer
@@ -102,6 +125,19 @@ PARENT = {       # sha256 of the text, its length, its pallas_call equations
     # first dims) keeps the program it had
     "glm": ("66e47bc26f19cb1dd44b7144a85f5731ccc4674eb6574dd373edeeb0293339"
             "57", 258167, 9),
+    # taken on PR 60's PARENT (e88a838) and equal on its change: the two
+    # token configurations the seven above left out. SmallThinker's moves
+    # with the window arm, the softmax router and the relu experts;
+    # Granite's with ops/ssd.py, ops/ssd_pallas.py and the SSD layers
+    "smallthinker": ("579a8858e56898e1159fa4fac710c0c75c010ec8c2dcea4b7e06cd"
+                     "7c2a1d4c3b", 170086, 6),
+    "granite": ("fd1140e332f98d150cc1dd45907284d2872f9f8a7dbaedc62f88570ac3"
+                "ac28e0", 200195, 5),
+    # PR 60's own, the configuration's first: 3 flash calls a block, two
+    # blocks. Moves with ops/hyper.py, the HC layers, ``rope_frequencies``,
+    # zoo.xing4 and whatever GLM's moves with
+    "xing": ("f4650cc4a39421e92adcf88ec6d0ae56e643e979fc2bb799243a828b42b0b4"
+             "23", 399647, 6),
 }
 
 
@@ -126,17 +162,20 @@ def traced(name: str) -> str:
 # products it reads and the one that reads it; Olmo-Hybrid's linear mixer's
 # z and gated output projection two).
 NAMES = {"olmoe": 2, "ouro": 4 + 3 * 2, "zaya": 4, "trinity": 4 + 3 * 2,
-         "kimi": 8 + 3 * 4, "olmo_hybrid": 8 + 18, "glm": 6 + 3 * 3}
+         "kimi": 8 + 3 * 4, "olmo_hybrid": 8 + 18, "glm": 6 + 3 * 3,
+         "smallthinker": 4, "granite": 8, "xing": 4 + 3 * 2}
 
 
 @pytest.mark.parametrize("name", sorted(BUILD))
 def test_token_configuration_traces_to_the_parent_s_program(name,
                                                             monkeypatch):
     from poseidon_tpu.core import layers
-    from poseidon_tpu.ops import kda, kda_pallas, pallas_kernels
+    from poseidon_tpu.ops import (kda, kda_pallas, pallas_kernels, ssd,
+                                  ssd_pallas)
     monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
     assert traced(name).count("= name[") == NAMES[name]
-    for module in (kda, kda_pallas, pallas_kernels, layers):
+    tagged = [m for m in (ssd, ssd_pallas) if hasattr(m, "checkpoint_name")]
+    for module in [kda, kda_pallas, pallas_kernels, layers] + tagged:
         monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
     text = traced(name)
     sha, chars, calls = PARENT[name]
