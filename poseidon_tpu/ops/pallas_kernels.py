@@ -5,9 +5,11 @@ materializes the (S, S) score matrix in HBM. Grid is (batch*heads,
 query-blocks, key-blocks); the online-softmax recurrence (the same math as
 ops/attention.py's BlockAcc) runs per (block_q, block_k) tile, sized by
 ``flash_blocks`` from the sequence length, the head width and the operand
-itemsize. The backward pass is likewise Pallas and O(S) in HBM: the
-dq and dk/dv kernels below recompute scores blockwise from the saved
-(out, logsumexp) residuals, wired up via ``defvjp``.
+itemsize. The backward pass is likewise Pallas and O(S) in HBM: one sweep
+over the (K block, Q block) pairs recomputes the scores blockwise from the
+saved (out, logsumexp) residuals and makes dK, dV and, summed into a head's
+resident rows, dQ (the paper's two sweeps where those rows do not fit VMEM),
+wired up via ``defvjp``.
 
 ``lrn_fused`` / ``lrn_fused_bwd``: cross-channel LRN, forward and analytic
 backward, in the orientation the compiled step holds the activation in
@@ -86,6 +88,7 @@ def _cdiv(a: int, b: int) -> int:
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T: both contract their minor dim
 _NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b: both contract their major dim
 
 
 def _dot(a, b, dims):
@@ -111,11 +114,18 @@ _BLOCK_LADDER = (1024, 512, 256, 128, 64, 32, 16, 8)
 # own (the iota, compare and cast temporaries of the masked path).
 _FLASH_VMEM_LIMIT = 32 * 2 ** 20
 _FLASH_VMEM_BUDGET = 24 * 2 ** 20
+# The backward's single sweep asks for twice both, half the chip's VMEM: at
+# 1024 x 1024 beside a head's dQ rows it counts 32 MiB at S 8,192 x 128, 40
+# at 16,384 x 128 and 44 at 8,192 x 256 (``_flash_vmem_bytes``).
+_SWEEP_VMEM_LIMIT = 2 * _FLASH_VMEM_LIMIT
+_SWEEP_VMEM_BUDGET = 2 * _FLASH_VMEM_BUDGET
 # f32 score-shaped (block_q, block_k) temporaries a program's body names:
-# s and p forward; s, p, dp and ds in either backward sweep. An upper
+# s and p forward; s, p, dp and ds in either of the two backward sweeps; in
+# the single sweep (``"bwd"``: the dK/dV sweep that sums dQ too) a fifth,
+# the transposed ds in the operand dtype that two products read. An upper
 # bound: Mosaic reuses their buffers (1024 x 1024 compiles for the v5e
-# inside 16 MiB in all three kernels, not inside 8).
-_SCORE_TEMPS = {"fwd": 2, "dq": 4, "dkv": 4}
+# inside 16 MiB in the three older kernels, not inside 8).
+_SCORE_TEMPS = {"fwd": 2, "dq": 4, "dkv": 4, "bwd": 5}
 
 
 def pick_block(s: int) -> Optional[int]:
@@ -133,27 +143,41 @@ def pick_block(s: int) -> Optional[int]:
 
 
 def _flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
-                      itemsize: int, dv: Optional[int] = None) -> int:
+                      itemsize: int, dv: Optional[int] = None,
+                      s: int = 0) -> int:
     """Live VMEM of one program: the score-shaped f32 temporaries, the
     operand and result tiles (double-buffered by the pipeline) and the f32
     accumulators. ``d`` is the width of a q / k head, ``dv`` of a v head
-    (None: the same)."""
+    (None: the same). The single sweep (``"bwd"``) is the dK/dV sweep plus
+    one head's whole dQ, ``s`` rows: the f32 sums and the result's block,
+    double-buffered like any other (4.2 + 4.2 MB at 8,192 x 128 bf16)."""
     dv = d if dv is None else dv
     scores = _SCORE_TEMPS[kernel] * block_q * block_k * 4
+    dkv = (d + dv, 2 * (d + dv),                  # q, dO | k, v, dk, dv |
+           block_k * (d + dv))                    # two accs
     q_cols, k_cols, acc = {
         "fwd": (d + dv, d + dv, block_q * dv),    # q, o | k, v | acc
         "dq": (2 * d + dv, d + dv, block_q * d),  # q, dO, dq | k, v | dq_acc
-        "dkv": (d + dv, 2 * (d + dv),             # q, dO | k, v, dk, dv |
-                block_k * (d + dv)),              # two accs
+        "dkv": dkv, "bwd": dkv,
     }[kernel]
     tiles = 2 * itemsize * (q_cols * block_q + k_cols * block_k)
-    return scores + tiles + acc * 4
+    rows = s * d * (4 + 2 * itemsize) if kernel == "bwd" else 0
+    return scores + tiles + acc * 4 + rows
+
+
+def _fits_vmem(kernel: str, block_q: int, block_k: int, d: int,
+               itemsize: int, dv: Optional[int], s: int) -> bool:
+    """Whether a program's count is inside its kernel's budget."""
+    budget = _SWEEP_VMEM_BUDGET if kernel == "bwd" else _FLASH_VMEM_BUDGET
+    return _flash_vmem_bytes(kernel, block_q, block_k, d, itemsize, dv,
+                             s) <= budget
 
 
 def flash_blocks(kernel: str, s: int, d: int, itemsize: int,
                  dv: Optional[int] = None):
-    """``(block_q, block_k)`` for one of the three flash kernels (``"fwd"``,
-    ``"dq"``, ``"dkv"``) — THE tile rule, a function of the sequence
+    """``(block_q, block_k)`` for one of the flash kernels (``"fwd"``; the
+    backward's single sweep ``"bwd"``, or its two, ``"dq"`` and ``"dkv"``)
+    — THE tile rule, a function of the sequence
     length, the head widths (``d`` of q and k, ``dv`` of v: None = the
     same) and the operand itemsize alone: the largest
     aligned blocks dividing S whose live tiles fit the VMEM budget. A grid
@@ -163,12 +187,14 @@ def flash_blocks(kernel: str, s: int, d: int, itemsize: int,
     choices with equal work per program the wider K/V block wins (fewer
     rescalings of the running softmax state per score). Lengths no large
     block divides (S = 48, 136, ...) tile as they always did. None: no
-    aligned block divides S."""
+    aligned block divides S, or (``"bwd"``) a head's dQ rows leave no tile
+    room in the budget (S 65,536 x 128): the backward is then the two
+    sweeps (``_single_sweep_blocks``)."""
     best = None
     for bq in _BLOCK_LADDER:
         for bk in _BLOCK_LADDER:
-            if s % bq or s % bk or _flash_vmem_bytes(
-                    kernel, bq, bk, d, itemsize, dv) > _FLASH_VMEM_BUDGET:
+            if s % bq or s % bk or not _fits_vmem(kernel, bq, bk, d,
+                                                  itemsize, dv, s):
                 continue
             if best is None or (bq * bk, bk) > (best[0] * best[1], best[1]):
                 best = (bq, bk)
@@ -352,10 +378,11 @@ def _flash_fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         l = l_ref[...]
         lsafe = jnp.where(l == 0, 1.0, l)
         o_ref[0] = (acc_ref[...] / lsafe).astype(o_ref.dtype)
-        # this kernel and the dQ sweep want the row statistics as a column
-        # beside their (block_q, block_k) scores: (bh, s, 1), whose
-        # (block_q, 1) tile is legal at any block height. The dK/dV sweep
-        # reads them as rows (_flash_bwd).
+        # this kernel (and the dQ sweep, where it runs) wants the row
+        # statistics as a column beside its (block_q, block_k) scores:
+        # (bh, s, 1), whose (block_q, 1) tile is legal at any block height.
+        # The backward's sweep over transposed scores reads them as rows
+        # (_flash_bwd).
         lse_ref[0] = m_ref[...] + jnp.log(lsafe)
 
 
@@ -412,10 +439,17 @@ def _kv_block_map(clamp: bool, block_q: int, block_k: int, window=None,
     return lambda i, j, kk: at(i, kk)
 
 
-def _compiler_params():
+def _compiler_params(single_sweep: bool = False):
+    """``single_sweep``: the grid's second dimension carries a sum too (dQ
+    over the K blocks) and may not be split or reordered (one TensorCore a
+    v5e chip, so nothing is lost), and the head's dQ rows want the larger
+    limit."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_FLASH_VMEM_LIMIT)
+        dimension_semantics=("parallel",
+                             "arbitrary" if single_sweep else "parallel",
+                             "arbitrary"),
+        vmem_limit_bytes=_SWEEP_VMEM_LIMIT if single_sweep
+        else _FLASH_VMEM_LIMIT)
 
 
 def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
@@ -486,14 +520,18 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: Optional[int],
 
 
 # --------------------------------------------------------------------------- #
-# Flash attention backward: O(S) memory, two sweeps (flash attention paper)
+# Flash attention backward: O(S) memory. One sweep over the (K block, Q block)
+# pairs where a head's dQ rows stay in VMEM beside the tiles, else the flash
+# attention paper's two
 # --------------------------------------------------------------------------- #
 
 def _flash_dq_kernel(*refs, scale: float, causal: bool, block_q: int,
                      block_k: int, n_kb: int, chunk_mode: bool, op_dtype,
                      window=None):
     """Grid (bh, q_blocks, k_blocks): accumulate dQ for one Q tile across all
-    K/V tiles. p is recomputed from Q,K and the saved logsumexp — the score
+    K/V tiles: the first of the two sweeps, run where a head's dQ does not
+    fit beside the dK/dV sweep's tiles. p is recomputed from Q,K and the
+    saved logsumexp — the score
     matrix never exists outside one VMEM tile. The softmax scale on dS is
     applied once, to the f32 accumulator."""
     if chunk_mode:
@@ -536,7 +574,7 @@ def _flash_dq_kernel(*refs, scale: float, causal: bool, block_q: int,
 
 def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
                       block_k: int, n_qb: int, chunk_mode: bool, op_dtype,
-                      window=None, q_blocks=None):
+                      window=None, q_blocks=None, n_kb=None):
     """Grid (bh, k_blocks, q_blocks): accumulate dK and dV for one K/V tile
     across all Q tiles. The scores are computed TRANSPOSED, keys down the
     rows ((block_k, block_q) = K Q^T), so that P^T dO and dS^T Q are plain
@@ -546,15 +584,22 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
 
     ``window``: the innermost grid dimension has ``n_qb`` = the band's
     steps and starts at the K/V block's first live Q block; ``q_blocks`` is
-    then how many Q blocks the sequence has (a step may lie past them)."""
-    if chunk_mode:
-        mode_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, \
-            dk_ref, dv_ref, dk_acc, dv_acc = refs
-        mode = mode_ref[0]
+    then how many Q blocks the sequence has (a step may lie past them).
+
+    ``n_kb`` (the single sweep: how many K blocks the grid has): the pair's
+    share of dQ, dS K, is taken from the same transposed dS by one more
+    product and summed into the pair's rows of a scratch that holds the
+    head's whole dQ, (S, d) f32: zeroed at the head's first program, written
+    out once, scaled, at its last, to a result block that is the head's
+    whole slab. A Q block's shares arrive in the order of the K blocks, the
+    dQ sweep's own order. The scores are then recomputed ONCE a backward."""
+    refs = list(refs)
+    mode = refs.pop(0)[0] if chunk_mode else None
+    q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref = refs[:8]
+    if n_kb is None:
+        dk_acc, dv_acc = refs[8:]
     else:
-        q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, \
-            dk_ref, dv_ref, dk_acc, dv_acc = refs
-        mode = None
+        dq_ref, dk_acc, dv_acc, dq_acc = refs[8:]
     kj = pl.program_id(1)
     step = qi = pl.program_id(2)
     in_range = None
@@ -566,6 +611,11 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if n_kb is not None:
+        @pl.when((step == 0) & (kj == 0))
+        def _init_head():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def update(masked: bool):
         q = q_ref[0].astype(op_dtype)
@@ -579,8 +629,11 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
         pt = jnp.exp(st - lse_ref[0, 0])          # lse, delta: (1, block_q)
         dv_acc[...] = dv_acc[...] + _dot(pt.astype(op_dtype), g, _NN)
         dpt = _dot(v_blk, g, _NT)
-        dst = pt * (dpt - delta_ref[0, 0])
-        dk_acc[...] = dk_acc[...] + _dot(dst.astype(op_dtype), q, _NN)
+        dst = (pt * (dpt - delta_ref[0, 0])).astype(op_dtype)
+        dk_acc[...] = dk_acc[...] + _dot(dst, q, _NN)
+        if n_kb is not None:
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            dq_acc[rows, :] = dq_acc[rows, :] + _dot(dst, k_blk, _TN)
 
     _on_live_blocks(update, causal, chunk_mode, qi, kj, block_q, block_k,
                     window, in_range)
@@ -590,14 +643,33 @@ def _flash_dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
+    if n_kb is not None:
+        @pl.when((step == n_qb - 1) & (kj == n_kb - 1))
+        def _finalize_head():
+            dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _single_sweep_blocks(s: int, d: int, itemsize: int, block_q, block_k,
+                         dv=None):
+    """The single-sweep backward's blocks (the rule's, or the caller's), or
+    None where a head's dQ rows do not fit in VMEM beside them: the
+    residency rule, read off the same count the tile rule reads. None is
+    the two sweeps."""
+    if block_q is None or block_k is None:
+        return flash_blocks("bwd", s, d, itemsize, dv)
+    blocks = _blocks_for("bwd", s, d, itemsize, block_q, block_k, dv)
+    return blocks if _fits_vmem("bwd", *blocks, d, itemsize, dv, s) else None
+
 
 def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
                block_q: Optional[int], block_k: Optional[int],
                interpret: bool, mode=None, delta=None,
                window: Optional[int] = None, heads: Optional[int] = None):
-    """mode, blocks, window, heads: see _flash_fwd (the rule sizes the two
-    sweeps apart); out and g come in the operands' form, lse is (B, H, S)
-    and dq, dk, dv leave in the operands' form.
+    """mode, blocks, window, heads: see _flash_fwd; out and g come in the
+    operands' form, lse is (B, H, S)
+    and dq, dk, dv leave in the operands' form. One sweep where a head's dQ
+    stays resident (``_single_sweep_blocks``: every shape a cell runs), else
+    the dQ sweep and the dK/dV sweep, each with the rule's tiles for it.
     ``delta`` (rowsum(dO*O), global, (B, H, S)) may be passed in by the ring
     backward, whose O is the merged global output."""
     hp = heads or 1
@@ -632,37 +704,42 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     clamp = causal and not chunk
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     at = _tile_at(hp)
+    itemsize = q.dtype.itemsize
+    single = _single_sweep_blocks(s, d, itemsize, block_q, block_k, d_v)
 
-    # dQ sweep: grid (bh, q_blocks, k_blocks), the forward's
-    bq, bk = _blocks_for("dq", s, d, q.dtype.itemsize, block_q, block_k, d_v)
-    qmap = lambda i, j, kk: at(i, j)
-    kmap = _kv_block_map(clamp, bq, bk, window, at)
-    qspec, gspec = vmem((1, bq, d), qmap), vmem((1, bq, d_v), qmap)
-    kspec, vspec = vmem((1, bk, d), kmap), vmem((1, bk, d_v), kmap)
-    rowq = vmem((1, bq, 1), lambda i, j, kk: (i, j, 0))
-    n_kb, extra = s // bk, {}
-    if window is not None:
-        n_kb = _band_steps(s, bq, bk, window)
-        extra = {"window": window}
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, n_kb=n_kb,
-                          chunk_mode=chunk, op_dtype=op_dtype, **extra),
-        name="flash_bwd_dq",
-        out_shape=jax.ShapeDtypeStruct(q3.shape, q.dtype),
-        grid=(bh, s // bq, n_kb),
-        in_specs=smem + [qspec, kspec, vspec, gspec, rowq, rowq],
-        out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(*mode_arg, q3, k3, v3, g3, lse.reshape(bh, s, 1),
-      delta.reshape(bh, s, 1))
+    if single is None:
+        # dQ sweep: grid (bh, q_blocks, k_blocks), the forward's; the row
+        # statistics a (block_q, 1) column a Q block
+        bq, bk = _blocks_for("dq", s, d, itemsize, block_q, block_k, d_v)
+        qmap = lambda i, j, kk: at(i, j)
+        kmap = _kv_block_map(clamp, bq, bk, window, at)
+        qspec, gspec = vmem((1, bq, d), qmap), vmem((1, bq, d_v), qmap)
+        kspec, vspec = vmem((1, bk, d), kmap), vmem((1, bk, d_v), kmap)
+        rowq = vmem((1, bq, 1), lambda i, j, kk: (i, j, 0))
+        n_kb, extra = s // bk, {}
+        if window is not None:
+            n_kb = _band_steps(s, bq, bk, window)
+            extra = {"window": window}
+        dq = pl.pallas_call(
+            functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
+                              block_q=bq, block_k=bk, n_kb=n_kb,
+                              chunk_mode=chunk, op_dtype=op_dtype, **extra),
+            name="flash_bwd_dq",
+            out_shape=jax.ShapeDtypeStruct(q3.shape, q.dtype),
+            grid=(bh, s // bq, n_kb),
+            in_specs=smem + [qspec, kspec, vspec, gspec, rowq, rowq],
+            out_specs=qspec,
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            compiler_params=_compiler_params(),
+            interpret=interpret,
+        )(*mode_arg, q3, k3, v3, g3, lse.reshape(bh, s, 1),
+          delta.reshape(bh, s, 1))
 
     # dK/dV sweep: swapped grid (bh, k_blocks, q_blocks); the row statistics
-    # one lane-dense (1, block_q) row per Q block
-    bq, bk = _blocks_for("dkv", s, d, q.dtype.itemsize, block_q, block_k,
-                         d_v)
+    # one lane-dense (1, block_q) row per Q block. The single sweep is this
+    # grid with the head's dQ as a third result
+    bq, bk = single or _blocks_for("dkv", s, d, itemsize, block_q, block_k,
+                                   d_v)
     n_qb = steps = s // bq
     extra = {}
     if window is not None:
@@ -684,22 +761,34 @@ def _flash_bwd(q, k, v, out, lse, g, scale: float, causal: bool,
     qspec_t, gspec_t = vmem((1, bq, d), qmap_t), vmem((1, bq, d_v), qmap_t)
     kspec_t, vspec_t = vmem((1, bk, d), kmap_t), vmem((1, bk, d_v), kmap_t)
     rowq_t = vmem((1, 1, 1, bq), lambda i, j, kk: (i, qblk(j, kk), 0, 0))
-    dk, dv = pl.pallas_call(
+    out_shape = [jax.ShapeDtypeStruct(k3.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v3.shape, v.dtype)]
+    out_specs = [kspec_t, vspec_t]
+    scratch = [pltpu.VMEM((bk, d), jnp.float32),
+               pltpu.VMEM((bk, d_v), jnp.float32)]
+    if single:
+        # the head's whole dQ: a block whose index is constant over the
+        # head's programs, so it is written back when the head changes
+        extra["n_kb"] = s // bk
+        out_shape.append(jax.ShapeDtypeStruct(q3.shape, q.dtype))
+        out_specs.append(vmem((1, s, d), lambda i, j, kk: at(i, 0)))
+        scratch.append(pltpu.VMEM((s, d), jnp.float32))
+    dk, dv, *dq_rows = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, n_qb=steps,
                           chunk_mode=chunk, op_dtype=op_dtype, **extra),
-        name="flash_bwd_dkv",
-        out_shape=(jax.ShapeDtypeStruct(k3.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v3.shape, v.dtype)),
+        name="flash_bwd" if single else "flash_bwd_dkv",
+        out_shape=out_shape,
         grid=(bh, s // bk, steps),
         in_specs=smem + [qspec_t, kspec_t, vspec_t, gspec_t, rowq_t, rowq_t],
-        out_specs=(kspec_t, vspec_t),
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d_v), jnp.float32)],
-        compiler_params=_compiler_params(),
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(single_sweep=bool(single)),
         interpret=interpret,
     )(*mode_arg, q3, k3, v3, g3, lse.reshape(bh, n_qb, 1, bq),
       delta.reshape(bh, n_qb, 1, bq))
+    if single:
+        dq, = dq_rows
 
     # head-major: back to the caller's four axes (a reshape of nothing on
     # the token-major form)
@@ -726,11 +815,11 @@ def flash_attention(q, k, v, causal: bool = False,
                     heads: Optional[int] = None):
     """Pallas blockwise attention; (B, H, S, D) -> (B, H, S, Dv), or with
     ``heads`` token-major (B, S, heads·D) -> (B, S, heads·Dv): the same
-    three kernels on the same grid, a head addressed as a lane block
+    kernels on the same grid, a head addressed as a lane block
     (``_operand_view``), gradients in the operands' form. With no
-    blocks given each of the three kernels takes ``flash_blocks``' tiles;
-    a given (block_q, block_k) is used by all three. ``window`` (causal):
-    token t attends to s with t - window < s <= t; the three kernels then
+    blocks given each kernel takes ``flash_blocks``' tiles;
+    a given (block_q, block_k) is used by all. ``window`` (causal):
+    token t attends to s with t - window < s <= t; the kernels then
     run, fetch and visit the band's blocks alone."""
     out, _ = _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k,
                             interpret, window, heads)
@@ -808,7 +897,9 @@ def attention_route(s: int, sk: int, d: int, itemsize: int,
     sequence tiles cleanly (an aligned block divides it, self-attention
     lengths), the note then stating each kernel's ``block_q x block_k`` from
     ``flash_blocks`` and the live / visited programs of its grid per head
-    (with a ``window`` below S: the band's, and the note says so) and the
+    (``fwd ..., bwd ...`` where the backward is the single sweep, ``fwd ...,
+    dq ..., dkv ...`` where a head's dQ rows do not fit and it is the two;
+    with a ``window`` below S: the band's, and the note says so) and the
     operands' form (``flash_operand_form``'s for a caller that holds the
     projections' results, an ATTENTION layer; ``token_major``: the form a
     caller handed them over in);
@@ -822,11 +913,14 @@ def attention_route(s: int, sk: int, d: int, itemsize: int,
     if pick_block(s) is None:
         return "dense", f"no aligned block divides S={s}"
     window = _band_window(window, causal, s)
+    # the backward that will run: one sweep where a head's dQ rows stay
+    # resident, the two sweeps' kernels where they do not
+    single = flash_blocks("bwd", s, d, itemsize, dv) is not None
     parts = []
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "bwd") if single else ("fwd", "dq", "dkv"):
         bq, bk = flash_blocks(kernel, s, d, itemsize, dv)
         live, visited = flash_grid_programs(s, bq, bk, causal, window,
-                                            over_q=kernel == "dkv")
+                                            over_q=kernel in ("dkv", "bwd"))
         parts.append(f"{kernel} {bq}x{bk} {live}/{visited}")
     by_rule, form = flash_operand_form(s, d, dv)
     if token_major not in (None, by_rule):

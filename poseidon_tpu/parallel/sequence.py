@@ -103,7 +103,8 @@ def ring_flash_attention(q, k, v, axis: str, causal: bool = False,
     TILE in VMEM, O(S_local) HBM. ``block`` None: each kernel takes the
     tile rule's blocks for the chunk length (``flash_blocks``), as plain
     self-attention does. The backward re-rotates K/V and runs the
-    flash dq/dk+dv kernels per chunk with the GLOBAL logsumexp; dK/dV
+    flash backward (one sweep, or two: ``_flash_bwd``) per chunk with the
+    GLOBAL logsumexp; dK/dV
     accumulators travel the ring WITH their chunk, arriving home after the
     full rotation."""
     out, _ = _ring_flash_fwd_impl(q, k, v, axis, causal, scale, block,

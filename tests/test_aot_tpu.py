@@ -100,12 +100,13 @@ def test_flash_kernels_mosaic_compile_for_v5e():
         and "OK pool_bwd" in r.stdout
 
 
-# The three flash kernels at olmoe.l1.pack4k's own geometry — 2 sequences x
-# 16 heads of 128 at S 4096, bf16, causal — with the tiles the rule picks,
-# under the bf16 policy (`train --bf16`): a tile choice Mosaic rejects, or
-# one that does not fit VMEM, is found here and not on the chip.
+# The flash kernels at the token cells' ATTENTION geometries, bf16, causal,
+# with the tiles the rule picks, under the bf16 policy (`train --bf16`), in
+# the operand form ``flash_operand_form`` sends each: a tile choice Mosaic
+# rejects, or a head's dQ rows that do not fit VMEM beside the single
+# sweep's tiles, is found here and not on the chip.
 _FLASH_CELL = r"""
-import json, os, sys
+import json, os, re, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 sys.path.insert(0, {repo!r})
@@ -122,38 +123,71 @@ except Exception as e:
 from poseidon_tpu.config import set_perf_policy
 from poseidon_tpu.ops import pallas_kernels as PK
 set_perf_policy()
-B, H, S, D = 2, 16, 4096, 128
+B, H, S, D, DV, W = {geometry}
+lanes, _ = PK.flash_operand_form(S, D, DV)
 sh = SingleDeviceSharding(topo.devices[0])
-q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=sh)
+like = lambda w: jax.ShapeDtypeStruct(
+    (B, S, H * w) if lanes else (B, H, S, w), jnp.bfloat16, sharding=sh)
+q, v = like(D), like(DV)
 row = jax.ShapeDtypeStruct((B, H, S), jnp.float32, sharding=sh)
-scale = D ** -0.5
-fwd = lambda q, k, v: PK._flash_fwd(q, k, v, scale, True, None, None, False)
+scale, heads = D ** -0.5, H if lanes else None
+fwd = lambda q, k, v: PK._flash_fwd(q, k, v, scale, True, None, None, False,
+                                    window=W, heads=heads)
 bwd = lambda q, k, v, o, lse, g: PK._flash_bwd(
-    q, k, v, o, lse, g, scale, True, None, None, False)
-text = {{"fwd": jax.jit(fwd).lower(q, q, q).compile().as_text(),
-        "bwd": jax.jit(bwd).lower(q, q, q, q, row, q).compile().as_text()}}
-kernels = {{}}
-for kernel, name, where in (("fwd", "flash_fwd", "fwd"),
-                            ("dq", "flash_bwd_dq", "bwd"),
-                            ("dkv", "flash_bwd_dkv", "bwd")):
-    bq, bk = PK.flash_blocks(kernel, S, D, 2)
-    live, visited = PK.flash_grid_programs(S, bq, bk, True)
+    q, k, v, o, lse, g, scale, True, None, None, False, window=W,
+    heads=heads)
+text = {{"fwd": jax.jit(fwd).lower(q, q, v).compile().as_text(),
+        "bwd": jax.jit(bwd).lower(q, q, v, v, row, v).compile().as_text()}}
+kernels = {{"token_major": lanes}}
+for kernel, name in (("fwd", "flash_fwd"), ("bwd", "flash_bwd")):
+    bq, bk = PK.flash_blocks(kernel, S, D, 2, DV)
+    live, visited = PK.flash_grid_programs(S, bq, bk, True, W,
+                                           over_q=kernel == "bwd")
     kernels[kernel] = {{
         "blocks": [bq, bk], "live": B * H * live, "grid": B * H * visited,
-        "compiled": sum(1 for l in text[where].splitlines()
+        "vmem_mib": PK._flash_vmem_bytes(kernel, bq, bk, D, 2, DV, S) / 2**20,
+        "compiled": sum(1 for l in text[kernel].splitlines()
                         if 'custom_call_target="tpu_custom_call"' in l
-                        and ("%" + name) in l.split("=")[0])}}
+                        and re.search("%" + name + r"\b", l.split("=")[0]))}}
+kernels["calls"] = sum(t.count('custom_call_target="tpu_custom_call"')
+                       for t in text.values())
 print("RESULT " + json.dumps(kernels))
 """
 
 
-def test_flash_kernels_compile_for_v5e_at_the_cell_geometry():
-    """flash_fwd, flash_bwd_dq and flash_bwd_dkv pass Mosaic for an abstract
-    v5e at bf16[32,4096,128], causal, with the rule's tiles; the tiles and
-    the programs per grid are printed for whoever reads the run."""
+# cell: ((sequences, heads, S, Dh, Dv, window), token-major?, live, visited
+# programs a head, the VMEM the rule counts for the single sweep in MiB)
+_FLASH_GEOMETRIES = {
+    # 2 x 16 heads of 128 at S 4096: 1024 x 1024 makes 512 programs a grid
+    # where 128 x 128 made 32,768, 10 of 16 block pairs a head live
+    "olmoe": ((2, 16, 4096, 128, 128, None), True, 10, 16, 28),
+    # one sequence x 28 heads of 128 at S 16,384, twice the longest any
+    # other cell runs: the causal grid (the global layer) and the band's at
+    # W 4096 (the window layers: at most 5 live blocks a block), where the
+    # causal grid would visit 256
+    "smallthinker_global": ((1, 28, 16384, 128, 128, None), True, 136, 256,
+                            40),
+    "smallthinker_window": ((1, 28, 16384, 128, 128, 4096), True, 70, 80,
+                            40),
+    # latent attention: 20 heads of 256 / 256 along the lanes, the widest
+    # dQ rows of any cell (8.4 + 8.4 MB); 32 heads of 192 / 128, head-major
+    "glm_flash": ((2, 20, 8192, 256, 256, None), True, 36, 64, 44),
+    "kimi_xing4": ((1, 32, 8192, 192, 128, None), False, 36, 64, 37),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_FLASH_GEOMETRIES))
+def test_flash_kernels_compile_for_v5e_at_the_cell_geometry(cell):
+    """flash_fwd and the single-sweep flash_bwd pass Mosaic for an abstract
+    v5e at the cell's geometry with the rule's 1024 x 1024 tiles, the head's
+    dQ rows resident: two calls, no dQ sweep. The tiles, the programs a
+    grid and the VMEM the rule reckons are printed for whoever reads the
+    run: the proof of fit without a chip."""
     import json
+    geometry, lanes, live, visited, mib = _FLASH_GEOMETRIES[cell]
     r = subprocess.run(
-        [sys.executable, "-c", _FLASH_CELL.format(repo=REPO)],
+        [sys.executable, "-c",
+         _FLASH_CELL.format(repo=REPO, geometry=geometry)],
         capture_output=True, text=True, timeout=600, cwd=REPO)
     if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
         pytest.skip(f"libtpu AOT unavailable: "
@@ -161,51 +195,13 @@ def test_flash_kernels_compile_for_v5e_at_the_cell_geometry():
     assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
     got = json.loads(next(l for l in r.stdout.splitlines()
                           if l.startswith("RESULT "))[7:])
-    print(got)
-    for kernel in ("fwd", "dq", "dkv"):
-        # 1024 x 1024: 512 programs a grid where 128 x 128 made 32,768,
-        # 320 of them live (10 of 16 block pairs a head)
-        assert got[kernel] == {"blocks": [1024, 1024], "live": 320,
-                               "grid": 512, "compiled": 1}, got
-
-
-# The same three kernels at smallthinker.e16of64.pack16k's geometries — one
-# sequence x 28 heads of 128 at S 16,384, twice the longest sequence any
-# other cell runs — on the causal grid (the global layer: 16 x 16 tiles of
-# 1024) and on the band's grid at W 4096 (the window layers: at most 5 live
-# K blocks a Q block).
-_FLASH_16K = _FLASH_CELL.replace(
-    "B, H, S, D = 2, 16, 4096, 128", "B, H, S, D, W = 1, 28, 16384, 128, {window}"
-).replace("scale, True, None, None, False)",
-          "scale, True, None, None, False, window=W)").replace(
-    "PK.flash_grid_programs(S, bq, bk, True)",
-    "PK.flash_grid_programs(S, bq, bk, True, W, over_q=kernel == 'dkv')")
-assert _FLASH_16K.count("window=W") == 2 and "over_q" in _FLASH_16K
-
-
-@pytest.mark.parametrize("window, live, visited", [(None, 136, 256),
-                                                   (4096, 70, 80)])
-def test_flash_kernels_compile_for_v5e_at_16k_positions(window, live,
-                                                        visited):
-    """flash_fwd, flash_bwd_dq and flash_bwd_dkv pass Mosaic for an abstract
-    v5e at bf16[1,28,16384,128] with the rule's 1024 x 1024 tiles: causal
-    (136 live of 256 visited programs a head) and under a window of 4096
-    (the band's grid: 70 live of 80 visited, where the causal grid would
-    visit 256)."""
-    import json
-    r = subprocess.run(
-        [sys.executable, "-c", _FLASH_16K.format(repo=REPO, window=window)],
-        capture_output=True, text=True, timeout=600, cwd=REPO)
-    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
-        pytest.skip(f"libtpu AOT unavailable: "
-                    f"{(r.stdout + r.stderr).strip()[-200:]}")
-    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
-    got = json.loads(next(l for l in r.stdout.splitlines()
-                          if l.startswith("RESULT "))[7:])
-    print(got)
-    for kernel in ("fwd", "dq", "dkv"):
-        assert got[kernel] == {"blocks": [1024, 1024], "live": 28 * live,
-                               "grid": 28 * visited, "compiled": 1}, got
+    print(cell, got)
+    assert got["token_major"] is lanes and got["calls"] == 2
+    assert got["fwd"].pop("vmem_mib") < got["bwd"].pop("vmem_mib") == mib
+    heads = geometry[0] * geometry[1]
+    for kernel in ("fwd", "bwd"):
+        assert got[kernel] == {"blocks": [1024, 1024], "live": heads * live,
+                               "grid": heads * visited, "compiled": 1}, got
 
 
 # The full-width, one-layer OLMoE train step (examples/lm/olmoe_1b_7b_*) as
@@ -300,11 +296,11 @@ def test_olmoe_full_width_step_compiles_for_one_v5e():
     assert got["parameters"] == 625_616_896
     assert got["routes"] == {
         "l0_attn": "attention=pallas_flash (fwd 1024x1024 10/16, "
-                   "dq 1024x1024 10/16, dkv 1024x1024 10/16; "
+                   "bwd 1024x1024 10/16; "
                    "block_q x block_k, live/visited programs a head; "
                    "operands token-major (B,S,HxD))",
         "l0_moe": "grouped_matmul=ragged_dot"}
-    assert got["pallas_custom_calls"] >= 3       # flash fwd, dq, dkv
+    assert got["pallas_custom_calls"] >= 2       # flash fwd, bwd
     # gate, up, down: forward, dx, dw; the weight gradients leave their
     # calls as the stacks are stored, so nothing between a parameter and
     # its update is copied into another layout (12 x 537 MB until PR 30)
@@ -419,10 +415,10 @@ def test_ouro_full_width_step_fits_one_v5e_at_its_depth_and_no_deeper(deeper):
     assert got["leaves"] == 11 * depth + 5
     assert got["segments"] == 4 * depth + 4
     assert got["routes"] == [
-        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
-        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "attention=pallas_flash (fwd 1024x1024 36/64, bwd 1024x1024 36/64; "
+        "block_q x block_k, live/visited programs a "
         "head; operands token-major (B,S,HxD))"]
-    assert got["pallas_custom_calls"] == 4 * 4 * depth
+    assert got["pallas_custom_calls"] == 3 * 4 * depth
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if deeper:
@@ -483,13 +479,13 @@ def test_zaya_full_width_step_fits_one_v5e_at_its_depth_and_no_deeper(deeper):
     assert got["leaves"] == 2 + 21 * depth - 1
     assert got["segments"] == depth + 1
     assert got["routes"] == [
-        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
-        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "attention=pallas_flash (fwd 1024x1024 36/64, bwd 1024x1024 36/64; "
+        "block_q x block_k, live/visited programs a "
         "head; operands token-major (B,S,HxD)); 2 kv heads repeated x4",
         "grouped_matmul=ragged_dot"]
-    # 4 flash calls and 15 grouped matmuls (3 forward, 3 replayed, 9
+    # 3 flash calls and 15 grouped matmuls (3 forward, 3 replayed, 9
     # backward) a layer
-    assert got["pallas_custom_calls"] == 19 * depth
+    assert got["pallas_custom_calls"] == 18 * depth
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if deeper:
@@ -541,7 +537,7 @@ def test_trinity_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
     # dense layer's 3; a MoE layer's router 2, 3 stacks, shared 3
     assert got["leaves"] == 3 + 5 * 11 + 3 + 4 * 8
     assert got["segments"] == 5 + 1
-    tiles = "fwd 1024x1024 {0}, dq 1024x1024 {0}, dkv 1024x1024 {0}; " \
+    tiles = "fwd 1024x1024 {0}, bwd 1024x1024 {0}; " \
         "block_q x block_k, live/visited programs a head"
     assert got["routes"] == [
         "attention=pallas_flash (" + tiles.format("21/24")
@@ -552,12 +548,12 @@ def test_trinity_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
         "no positions",
         f"grouped_matmul=ragged_dot; held rows: chunks of {8192 * (2 + more)}"
         f" of {65536 * (2 + more)}"]
-    # 4 flash calls a layer. A MoE layer's held arm is one loop a pass (PR
+    # 3 flash calls a layer. A MoE layer's held arm is one loop a pass (PR
     # 43): the forward's and its replay's hold 3 grouped matmuls and 1
     # group-metadata call each, the backward's 8 and 2 (the chunk's a and
     # b again, dy down, three weight gradients, two dx products): 18 where
     # PR 37's two-rung ladder held 38
-    assert got["pallas_custom_calls"] == 4 * 5 + 18 * 4
+    assert got["pallas_custom_calls"] == 3 * 5 + 18 * 4
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more:
@@ -626,18 +622,18 @@ def test_kimi_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
     assert got["segments"] == 5 + 1
     rows = 8192 * 8 * (1 + more)
     assert got["routes"] == [
-        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
-        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "attention=pallas_flash (fwd 1024x1024 36/64, bwd 1024x1024 36/64; "
+        "block_q x block_k, live/visited programs a "
         "head; flash d 192/128; operands head-major (Dh 192, not "
         "lane-aligned)); no positions; k_pe repeated x32",
         f"grouped_matmul=ragged_dot; held rows: chunks of 8192 of {rows}",
         "kda=pallas (C 64 x 4, 128 chunks, f32 state in VMEM)"]
-    # 4 flash calls in the MLA layer; a MoE layer's held arm is one loop a
+    # 3 flash calls in the MLA layer; a MoE layer's held arm is one loop a
     # pass (see the Trinity test): 4 calls in the forward's, 10 in the
     # backward's, and no replay (nothing in the layer needs the MoE's
     # output again); a KDA layer's scan three times: forward, the replay,
     # backward
-    assert got["pallas_custom_calls"] == 4 + 14 * 4 + 3 * 4
+    assert got["pallas_custom_calls"] == 3 + 14 * 4 + 3 * 4
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more:
@@ -704,14 +700,14 @@ def test_olmo_hybrid_full_width_step_fits_one_v5e_at_one_and_two_sequences(
     assert got["leaves"] == 3 + 4 * 5 + 3 * 13 + 6
     assert got["segments"] == 4 + 1
     assert got["routes"] == [
-        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
-        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "attention=pallas_flash (fwd 1024x1024 36/64, bwd 1024x1024 36/64; "
+        "block_q x block_k, live/visited programs a "
         "head; operands token-major (B,S,HxD)); no positions",
         "kda=pallas (C 64 x 4, 128 chunks, f32 state in VMEM, one decay a "
         "head, lanes 96 / 192 padded to 128 / 256)"]
-    # 4 flash calls in the full layer; a linear layer's scan three times:
+    # 3 flash calls in the full layer; a linear layer's scan three times:
     # forward, the replay, backward
-    assert got["pallas_custom_calls"] == 4 + 3 * 3
+    assert got["pallas_custom_calls"] == 3 + 3 * 3
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more:
@@ -767,8 +763,8 @@ def test_granite_full_width_step_fits_one_v5e_at_one_and_two_sequences(more):
     assert got["leaves"] == 2 + 10 * 4 + 9 * 8 + 4
     assert got["segments"] == 10 + 1
     assert got["routes"] == [
-        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
-        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "attention=pallas_flash (fwd 1024x1024 36/64, bwd 1024x1024 36/64; "
+        "block_q x block_k, live/visited programs a "
         "head; operands head-major (Dh 64, not lane-aligned)); 8 kv heads "
         "repeated x4; no positions",
         "ssd_scan=pallas (Q 256, 32 chunks, 8 heads a program, 2 a lane "
@@ -866,7 +862,7 @@ def test_step_with_the_ffn_rung_kept_for_one_v5e(cell):
         assert 12.4 < floor["floor_gb"] < 12.6
         assert 13.4 < got["total_gb"] < 13.7
         # the scans run forward and backward, no replay
-        assert got["pallas_custom_calls"] == 3 + 2 * 3
+        assert got["pallas_custom_calls"] == 2 + 2 * 3
     else:
         assert kept == {"ffn_in": 2.68, "scan_out": 0.6, "scan_states": 0.6,
                         "flash_out": 0.03, "flash_lse": 0.0}
@@ -921,7 +917,7 @@ def test_smallthinker_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(
     # embed, head, final norm; a layer: 2 norms, q k v o, router, 3 stacks
     assert got["leaves"] == 3 + 4 * 10
     assert got["segments"] == 4 + 1
-    tiles = "fwd 1024x1024 {0}, dq 1024x1024 {0}, dkv 1024x1024 {0}; " \
+    tiles = "fwd 1024x1024 {0}, bwd 1024x1024 {0}; " \
         "block_q x block_k, live/visited programs a head"
     rows = 16384 * 6 * (1 + more)
     assert got["routes"] == [
@@ -933,12 +929,12 @@ def test_smallthinker_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(
         "4 kv heads repeated x7",
         f"grouped_matmul=ragged_dot; held rows: chunks of {rows // 4} of "
         f"{rows}; act=relu"]
-    # 4 flash calls a layer (forward, its replay, dq, dkv); a MoE layer's
+    # 3 flash calls a layer (forward, its replay, the backward); a MoE layer's
     # held arm is one loop a pass: 4 calls in the forward's, 10 in the
     # backward's, no replay (see the Kimi test), and since PR 53, at this
     # width alone (``held_sum_on_mxu``: 2,560), the grouped product that
     # sums a trip's rows into (T, D) and its group-metadata call in each
-    assert got["pallas_custom_calls"] == 4 * 4 + (14 + 4) * 4
+    assert got["pallas_custom_calls"] == 3 * 4 + (14 + 4) * 4
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more:
@@ -1002,16 +998,16 @@ def test_glm_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
     assert got["segments"] == 5 + 2 + 1
     rows = 8192 * 4 * (2 + more)
     assert got["routes"] == [
-        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
-        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "attention=pallas_flash (fwd 1024x1024 36/64, bwd 1024x1024 36/64; "
+        "block_q x block_k, live/visited programs a "
         "head; operands token-major (B,S,HxD)); k_pe rotated once, joined "
         "x20",
         f"grouped_matmul=ragged_dot; held rows: chunks of "
         f"{max(8192, 4096 * (2 + more))} of {rows}"]
-    # 4 flash calls a block (forward, its replay, dq, dkv); a sparse
+    # 3 flash calls a block (forward, its replay, the backward); a sparse
     # block's held arm is one loop a pass: 4 calls in the forward's, 10 in
     # the backward's
-    assert got["pallas_custom_calls"] == 4 * 6 + 14 * 5
+    assert got["pallas_custom_calls"] == 3 * 6 + 14 * 5
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more > 0:
@@ -1078,15 +1074,15 @@ def test_xing_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
     assert got["segments"] == 5 + 1
     rows = 8192 * 4 * (1 + more)
     assert got["routes"] == [
-        "attention=pallas_flash (fwd 1024x1024 36/64, dq 1024x1024 36/64, "
-        "dkv 1024x1024 36/64; block_q x block_k, live/visited programs a "
+        "attention=pallas_flash (fwd 1024x1024 36/64, bwd 1024x1024 36/64; "
+        "block_q x block_k, live/visited programs a "
         "head; flash d 192/128; operands head-major (Dh 192, not "
         "lane-aligned)); k_pe rotated once, joined x32; yarn x64",
         f"grouped_matmul=ragged_dot; held rows: chunks of 8192 of {rows}"]
-    # 4 flash calls a block (forward, its replay, dq, dkv); a sparse
+    # 3 flash calls a block (forward, its replay, the backward); a sparse
     # block's held arm is one loop a pass, 18 calls a block at hidden 3584
     # (GLM's 14 at 2048)
-    assert got["pallas_custom_calls"] == 4 * 5 + 18 * 4
+    assert got["pallas_custom_calls"] == 3 * 5 + 18 * 4
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     if more:
@@ -1214,7 +1210,7 @@ def test_lrn_kernels_meet_their_neighbours_layout_for_v5e():
 # forward and the three gradients' in the backward — and no array of that
 # size with a head's width as its minor axis at all (under the (8, 128)
 # tiling (B, S, H, Dh) is another layout than (B, S, H·Dh)), with exactly
-# three Pallas calls, four with the replay.
+# two Pallas calls, three with the replay.
 _ATTENTION_BOUNDARY = r"""
 import json, math, os, re, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -1279,7 +1275,7 @@ print("RESULT " + json.dumps({{
 def test_flash_kernels_meet_the_projections_layout_for_v5e():
     """Between the projections' matmuls and the flash kernels the compiled
     text moves no array of q's size, forward or backward, and a layer is
-    three Pallas calls (four with a checkpoint's replay)."""
+    two Pallas calls (three with a checkpoint's replay)."""
     import json
     r = subprocess.run(
         [sys.executable, "-c", _ATTENTION_BOUNDARY.format(repo=REPO)],
@@ -1296,7 +1292,7 @@ def test_flash_kernels_meet_the_projections_layout_for_v5e():
         "operands head-major (Dh 192, not lane-aligned)"]
     for name, standin in got.items():
         assert standin == {
-            "pallas_custom_calls": 4 if name.endswith("replay") else 3,
+            "pallas_custom_calls": 3 if name.endswith("replay") else 2,
             "moved": [], "four_axes": []}, name
 
 

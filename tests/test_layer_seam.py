@@ -6,7 +6,11 @@ counters and the cost table equal ``layer_seam_golden.json``, which was
 captured from the accessors of the commit BEFORE the facts moved into the
 layer classes (``Net._plan_kernel_routes``'s per-type chain,
 ``Net.expert_share`` / ``recurrent_state`` / ``held_row_ladders`` with the
-Engine's arithmetic, ``attribution.layer_cost_table``; PR 58)."""
+Engine's arithmetic, ``attribution.layer_cost_table``; PR 58). PR 61
+re-took the ATTENTION routes' notes for the TPU (``fwd ..., bwd ...`` where
+they read ``fwd ..., dq ..., dkv ...``: the flash backward became one sweep,
+and ``attention_route`` names the backward that runs); nothing else in the
+file moved."""
 
 import json
 import os

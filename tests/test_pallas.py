@@ -1,5 +1,8 @@
 """Pallas kernels (interpret mode on CPU) vs reference ops."""
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -135,7 +138,7 @@ def test_flash_runs_at_the_small_block_rungs(s):
 
 
 # (kernel, S, D, itemsize) -> (block_q, block_k): the cell's geometry first
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "bwd"])
 @pytest.mark.parametrize("s, d, itemsize, want", [
     (4096, 128, 2, (1024, 1024)),      # olmoe.l1.pack4k: bf16[32,4096,128]
     (4096, 128, 4, (1024, 1024)),
@@ -153,20 +156,27 @@ def test_flash_blocks_rule(kernel, s, d, itemsize, want):
     from poseidon_tpu.ops import pallas_kernels as PK
     assert PK.flash_blocks(kernel, s, d, itemsize) == want
     if want is not None:
-        assert PK._flash_vmem_bytes(kernel, *want, d, itemsize) \
-            <= PK._FLASH_VMEM_BUDGET < PK._FLASH_VMEM_LIMIT
+        assert PK._fits_vmem(kernel, *want, d, itemsize, None, s)
+        assert PK._FLASH_VMEM_BUDGET < PK._FLASH_VMEM_LIMIT \
+            and PK._SWEEP_VMEM_BUDGET < PK._SWEEP_VMEM_LIMIT
 
 
 def test_flash_blocks_shrink_when_the_budget_binds():
-    """f32 operands 256 wide leave the backward sweeps (four score-shaped
-    temporaries) no room for 1024 x 1024; the forward (two) keeps it. Of
-    two halvings the one that keeps the K/V block wide wins, until the
-    dK/dV sweep's four K-side tiles and two accumulators weigh more."""
+    """f32 operands 256 wide leave the two backward sweeps (four
+    score-shaped temporaries) no room for 1024 x 1024; the forward (two)
+    keeps it. Of two halvings the one that keeps the K/V block wide wins,
+    until the dK/dV sweep's four K-side tiles and two accumulators weigh
+    more. The single sweep counts a fifth temporary and the head's dQ rows
+    against twice the budget: f32 operands 256 wide keep 1024 x 1024 at S
+    4,096 and not at 8,192."""
     from poseidon_tpu.ops.pallas_kernels import flash_blocks
     assert flash_blocks("fwd", 4096, 256, 4) == (1024, 1024)
     assert flash_blocks("dq", 4096, 256, 4) == (512, 1024)
     assert flash_blocks("dkv", 4096, 256, 4) == (512, 1024)
     assert flash_blocks("dkv", 4096, 384, 4) == (1024, 512)
+    assert flash_blocks("bwd", 4096, 256, 4) == (1024, 1024)
+    assert flash_blocks("bwd", 8192, 256, 4) == (512, 1024)
+    assert flash_blocks("bwd", 16384, 256, 2) == (1024, 512)
 
 
 @pytest.mark.parametrize("s, bq, bk, causal, want", [
@@ -204,9 +214,8 @@ def test_attention_route_states_tiles_and_programs(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     arm, note = attention_route(4096, 4096, 128, 2)
     assert arm == "pallas_flash"
-    assert note == ("fwd 1024x1024 10/16, dq 1024x1024 10/16, "
-                    "dkv 1024x1024 10/16; block_q x block_k, "
-                    "live/visited programs a head; "
+    assert note == ("fwd 1024x1024 10/16, bwd 1024x1024 10/16; "
+                    "block_q x block_k, live/visited programs a head; "
                     "operands token-major (B,S,HxD)")
     assert ": " not in note          # it is a stats.yaml leaf
     assert attention_route(4096, 2048, 128, 2)[0] == "dense"
@@ -493,3 +502,213 @@ def test_maybe_flash_routing_with_token_major_operands(qkv):
                                 causal=True, heads=H)
     np.testing.assert_array_equal(
         got, _token_major(attention(q, k, v, causal=True)))
+
+
+# --------------------------------------------------------------------------- #
+# the backward's single sweep (dQ summed inside the dK/dV kernel) against the
+# dense op, and against the two sweeps it replaces wherever a head's dQ rows
+# stay resident (every shape here: a test forces the two through the rule)
+# --------------------------------------------------------------------------- #
+
+# name: (S, heads, Dh, Dv, causal, window, blocks, token-major, dtype)
+SWEEP_CASES = {
+    "equal_tiles": (128, 3, 32, 32, True, None, (32, 32), False, jnp.float32),
+    "wide_k": (128, 3, 32, 32, True, None, (32, 64), False, jnp.float32),
+    "wide_q": (128, 3, 32, 32, True, None, (64, 32), False, jnp.float32),
+    "one_tile": (128, 3, 32, 32, True, None, (128, 128), False, jnp.float32),
+    "rule_tiles": (128, 3, 32, 32, True, None, (None, None), False,
+                   jnp.float32),
+    "not_causal": (128, 3, 32, 32, False, None, (32, 64), False,
+                   jnp.float32),
+    "not_causal_wide_q": (128, 3, 32, 32, False, None, (64, 32), False,
+                          jnp.float32),
+    "s48": (48, 2, 16, 16, True, None, (None, None), False, jnp.float32),
+    "s136": (136, 2, 16, 16, True, None, (None, None), False, jnp.float32),
+    "window": (128, 2, 16, 16, True, 40, (16, 32), False, jnp.float32),
+    "window_unequal": (64, 2, 16, 16, True, 20, (32, 8), False, jnp.float32),
+    # the cells' windows, on tiles of 512 and 1024 (one head of 8)
+    "window_1024": (2048, 1, 8, 8, True, 1024, (512, 512), False,
+                    jnp.float32),
+    "window_2048": (4096, 1, 8, 8, True, 2048, (1024, 1024), False,
+                    jnp.bfloat16),
+    # the cells' head widths, in the form flash_operand_form sends each
+    "d128": (128, 2, 128, 128, True, None, (64, 32), True, jnp.bfloat16),
+    "d128_window": (128, 2, 128, 128, True, 48, (32, 64), True,
+                    jnp.bfloat16),
+    "d192_128": (128, 2, 192, 128, True, None, (32, 64), False,
+                 jnp.bfloat16),
+    "d256": (128, 2, 256, 256, True, None, (64, 64), True, jnp.bfloat16),
+    "d64": (128, 2, 64, 64, True, None, (64, 32), False, jnp.bfloat16),
+    "d128_f32": (64, 2, 128, 128, True, None, (8, 8), True, jnp.float32),
+}
+
+
+def _ulp(x, dtype):
+    """The unit in the last place of ``dtype`` at magnitude ``x``."""
+    bits = jnp.finfo(dtype).nmant
+    return 2.0 ** (np.floor(np.log2(x)) - bits)
+
+
+@pytest.fixture(scope="module")
+def sweeps():
+    """dq, dk, dv of every case: the single sweep's, the two sweeps' (the
+    residency rule patched to refuse) and the dense op's in f32."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+
+    def grads(case):
+        s, h, d, dv, causal, window, blocks, lanes, dtype = SWEEP_CASES[case]
+        rs = np.random.RandomState(sum(map(ord, case)))
+        mk = lambda w: jnp.asarray(
+            rs.randn(2, h, s, w).astype(np.float32) * 0.3).astype(dtype)
+        q, k, v, co = mk(d), mk(d), mk(dv), mk(dv)
+        form = _token_major if lanes else (lambda t: t)
+
+        def flash(q, k, v):
+            out = flash_attention(form(q), form(k), form(v), causal, None,
+                                  *blocks, True, window, h if lanes else None)
+            return jnp.sum((out * form(co)).astype(jnp.float32))
+
+        def dense(q, k, v):
+            out = attention(*(t.astype(jnp.float32) for t in (q, k, v)),
+                            causal=causal, window=window)
+            return jnp.sum(out * co.astype(jnp.float32))
+
+        single = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(PK, "_single_sweep_blocks", lambda *a: None)
+            two = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+        return single, two, jax.grad(dense, argnums=(0, 1, 2))(q, k, v)
+
+    return functools.lru_cache(maxsize=None)(grads)
+
+
+@pytest.mark.parametrize("what", ["dq", "dk", "dv"])
+@pytest.mark.parametrize("sweep", ["single", "two"])
+@pytest.mark.parametrize("case", sorted(SWEEP_CASES))
+def test_backward_sweeps_against_the_dense_op(sweeps, case, sweep, what):
+    """Either backward (interpret mode) against the dense f32 op's VJP at
+    the file's tolerances: f32 operands as
+    ``test_flash_attention_gradients``, bf16 operands within 1% relative
+    L2 as ``test_flash_attention_bf16_operands_against_f32_dense``."""
+    i = ("dq", "dk", "dv").index(what)
+    single, two, want = sweeps(case)
+    got = (single if sweep == "single" else two)[i]
+    dtype = SWEEP_CASES[case][-1]
+    assert got.dtype == dtype and got.shape == want[i].shape
+    if dtype == jnp.bfloat16:
+        assert _rel_l2(got, want[i]) < 0.01
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want[i]),
+                                   rtol=5e-3, atol=5e-4)
+
+
+@pytest.mark.parametrize("case", sorted(SWEEP_CASES))
+def test_single_sweep_is_the_two_sweeps(sweeps, case):
+    """dK and dV are the dK/dV sweep's own, bit for bit. dQ's shares are
+    summed in the dQ sweep's order, one product's operands the other way
+    round: equal to f32 rounding of the sums, which a bf16 result shows as
+    at most one unit in its last place (counted at a thousandth of the
+    largest entry or above: below that a sum's own rounding is the
+    entry)."""
+    single, two, _ = sweeps(case)
+    dtype = SWEEP_CASES[case][-1]
+    for a, b in zip(single[1:], two[1:]):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    a, b = (np.asarray(t, np.float32) for t in (single[0], two[0]))
+    if dtype == jnp.bfloat16:
+        at = np.maximum(np.maximum(np.abs(a), np.abs(b)),
+                        1e-3 * np.abs(b).max())
+        assert np.all(np.abs(a - b) <= _ulp(at, dtype))
+    else:
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+# a head's dQ rows beside the tiles, in MiB as ``_flash_vmem_bytes`` counts
+# them, at the ten token cells' ATTENTION geometries (S, Dh, Dv; bf16)
+CELL_GEOMETRIES = {
+    "olmoe": (4096, 128, 128, 28), "ouro": (8192, 128, 128, 32),
+    "zaya1": (8192, 128, 128, 32), "trinity": (8192, 128, 128, 32),
+    "smallthinker": (16384, 128, 128, 40), "olmo_hybrid": (8192, 128, 128, 32),
+    "glm_flash": (8192, 256, 256, 44), "kimi_linear": (8192, 192, 128, 37),
+    "xing4": (8192, 192, 128, 37), "granite_h": (8192, 64, 64, 26),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_GEOMETRIES))
+def test_dq_rows_stay_resident_at_the_cells_geometries(cell):
+    """The residency rule is the tile rule's own count: at every cell's
+    geometry the single sweep keeps the dK/dV sweep's 1024 x 1024 tiles
+    with the head's dQ rows beside them, inside the budget."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+    s, d, dv, mib = CELL_GEOMETRIES[cell]
+    assert PK.flash_blocks("bwd", s, d, 2, dv) == (1024, 1024) \
+        == PK.flash_blocks("dkv", s, d, 2, dv)
+    assert PK._single_sweep_blocks(s, d, 2, None, None, dv) == (1024, 1024)
+    count = PK._flash_vmem_bytes("bwd", 1024, 1024, d, 2, dv, s)
+    assert count == mib * 2 ** 20 <= PK._SWEEP_VMEM_BUDGET \
+        < PK._SWEEP_VMEM_LIMIT
+    rows = s * d * (4 + 2 * 2)              # f32 sums + the result, twice
+    assert count - PK._flash_vmem_bytes("dkv", 1024, 1024, d, 2, dv) \
+        == rows + 1024 * 1024 * 4           # and the fifth temporary
+
+
+@pytest.mark.parametrize("s, d, itemsize, blocks, want", [
+    (32768, 128, 2, (None, None), (512, 1024)),  # rows 32 MiB: smaller tiles
+    (65536, 128, 2, (None, None), None),         # rows 64 MiB: the two sweeps
+    (32768, 256, 2, (None, None), None),
+    (16384, 128, 4, (None, None), (512, 1024)),   # f32: rows 24 MiB
+    (65536, 128, 2, (128, 128), None),           # a caller's tiles likewise
+    (8192, 128, 2, (128, 256), (128, 256)),
+])
+def test_dq_rows_that_do_not_fit_leave_the_two_sweeps(s, d, itemsize, blocks,
+                                                      want):
+    from poseidon_tpu.ops import pallas_kernels as PK
+    assert PK._single_sweep_blocks(s, d, itemsize, *blocks) == want
+    if blocks == (None, None):
+        assert PK.flash_blocks("bwd", s, d, itemsize) == want
+        # the two sweeps' own tiles do not depend on the rows
+        assert PK.flash_blocks("dkv", s, d, itemsize) == (1024, 1024)
+
+
+def test_backward_launches_one_kernel_or_two(monkeypatch):
+    """What is traced: ``flash_bwd`` alone where the rows stay resident,
+    ``flash_bwd_dq`` and ``flash_bwd_dkv`` where the rule refuses, and in
+    ring attention's chunk mode (a traced alignment) the same choice."""
+    from poseidon_tpu.ops import pallas_kernels as PK
+    q = jax.ShapeDtypeStruct((1, 2, 128, 32), jnp.float32)
+    row = jax.ShapeDtypeStruct((1, 2, 128), jnp.float32)
+    mode = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def names(**kw):
+        f = lambda q, lse, mode: PK._flash_bwd(
+            q, q, q, q, lse, q, 1.0, True, 32, 64, True,
+            **({"mode": mode, "delta": lse} if kw.get("chunk") else {}))
+        text = str(jax.make_jaxpr(f)(q, row, mode))
+        return sorted(set(re.findall(r"name=(flash_\w+)", text)))
+
+    assert names() == names(chunk=True) == ["flash_bwd"]
+    monkeypatch.setattr(PK, "_SWEEP_VMEM_BUDGET", 2 ** 16)
+    assert names() == names(chunk=True) == ["flash_bwd_dkv", "flash_bwd_dq"]
+
+
+def test_attention_route_names_the_backward_that_runs(monkeypatch):
+    """``fwd ..., bwd ...`` where the single sweep runs, the three-kernel
+    form where a head's dQ rows do not fit; either way the words
+    ``benchmark/lm_trace.window_visited_over_live`` reads."""
+    import re
+    from poseidon_tpu.ops import pallas_kernels as PK
+    monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+    arm, note = PK.attention_route(16384, 16384, 128, 2, window=4096)
+    assert arm == "pallas_flash" and note == (
+        "fwd 1024x1024 70/80, bwd 1024x1024 70/80; block_q x block_k, "
+        "live/visited programs a head; window 4096: the band's grid; "
+        "operands token-major (B,S,HxD)")
+    assert re.findall(r"\b(\d+)/(\d+)\b", note) == [("70", "80")] * 2
+    assert PK.attention_route(65536, 65536, 128, 2)[1].startswith(
+        "fwd 1024x1024 2080/4096, dq 1024x1024 2080/4096, "
+        "dkv 1024x1024 2080/4096; block_q x block_k")
+    assert PK.attention_route(8192, 8192, 192, 2, dv=128)[1] == (
+        "fwd 1024x1024 36/64, bwd 1024x1024 36/64; block_q x block_k, "
+        "live/visited programs a head; flash d 192/128; "
+        "operands head-major (Dh 192, not lane-aligned)")

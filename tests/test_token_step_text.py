@@ -22,7 +22,10 @@ stripped (CHANGES.md, PR 36).
 
 A PR that means to change what these configurations trace to (PR 32 did,
 and cost ``ouro.loop4.pack8k`` 9 s of set-up; PR 47 did, for the four whose
-heads are 128 wide, and left Kimi-Linear's alone) updates the hashes and
+heads are 128 wide, and left Kimi-Linear's alone; PR 61 did, for all ten:
+the flash backward became one sweep, so every ATTENTION layer's backward is
+one ``flash_bwd`` call where it was ``flash_bwd_dq`` and ``flash_bwd_dkv``,
+and all ten hashes below are PR 61's own) updates the hashes and
 says so; one that does not has tripped over a shared path."""
 
 import hashlib
@@ -93,51 +96,38 @@ BUILD = {
         experts=16, top_k=2, expert_width=32, shared_width=32),
 }
 PARENT = {       # sha256 of the text, its length, its pallas_call equations
-    # PR 47's own, all four: it meant to change them. Every ATTENTION layer
-    # here has 128-wide heads, so q, k, v reach the kernels token-major
-    # (``flash_operand_form``): no transposes, rotary along the lanes, the
-    # repeat as lane slices, a head as a lane block in the index maps; the
-    # same number of pallas_call equations. Kimi's (192 / 128: head-major)
-    # is still the one PR 43 took, to the byte
-    "olmoe": ("c95c2e07f6f0e5f2f0e2313eee9bc34d0c3c809106c5f37c86d1a2ccccf2"
-              "544e", 90564, 3),
-    "ouro": ("80f533feb0ebfce4737018eebe5c76128c68b109d33a9f65198b5299a8e4"
-             "d147", 148212, 6),
-    "zaya": ("88039d360826c975969ef376180bf657e195c75345dcab93043ff5402422"
-             "41d9", 202519, 6),
-    "trinity": ("66f5b8eed4b69871badff25b26bd757d23b25e2d6d39228db001544846"
-                "d62889", 163462, 6),
-    # PR 43's own likewise: moves with ops/kda.py, the KDA layers and the
-    # held arm, and with nothing else
-    "kimi": ("9bdcb9bc42efc615f3803c633d2f5a4bc1d7747b6ebfb674c0722604e860"
-             "6a83", 1005313, 3),
-    # PR 48's own, the configuration's first: 3 flash calls and, a linear
-    # layer, the per-head scan's forward and backward kernels. Moves with
-    # ops/kda.py, ops/kda_pallas.py, the KDA layers and zoo.olmo_hybrid
-    "olmo_hybrid": ("9b956fadf0457a8b3421d6009a1539b423ff4f5881b9e2703183d7"
-                    "efd567532f", 825485, 9),
-    # PR 56's own, the configuration's first: 3 flash calls a block, three
-    # blocks. Moves with ``_rope_attention_lanes``' rotary_shared arm,
-    # TOKEN_SHIFT's mark, WEIGHTED_MEAN_LOSS, zoo.glm_flash and whatever the
-    # five above move with; PR 56 left all of THEIRS as they were, which is
-    # its proof that a net that does not ask for ``rotary_shared`` (Kimi's
-    # ``rope: false`` with a shared key part; the four that rotate a head's
-    # first dims) keeps the program it had
-    "glm": ("66e47bc26f19cb1dd44b7144a85f5731ccc4674eb6574dd373edeeb0293339"
-            "57", 258167, 9),
-    # taken on PR 60's PARENT (e88a838) and equal on its change: the two
-    # token configurations the seven above left out. SmallThinker's moves
-    # with the window arm, the softmax router and the relu experts;
-    # Granite's with ops/ssd.py, ops/ssd_pallas.py and the SSD layers
-    "smallthinker": ("579a8858e56898e1159fa4fac710c0c75c010ec8c2dcea4b7e06cd"
-                     "7c2a1d4c3b", 170086, 6),
-    "granite": ("fd1140e332f98d150cc1dd45907284d2872f9f8a7dbaedc62f88570ac3"
-                "ac28e0", 200195, 5),
-    # PR 60's own, the configuration's first: 3 flash calls a block, two
-    # blocks. Moves with ops/hyper.py, the HC layers, ``rope_frequencies``,
-    # zoo.xing4 and whatever GLM's moves with
-    "xing": ("f4650cc4a39421e92adcf88ec6d0ae56e643e979fc2bb799243a828b42b0b4"
-             "23", 399647, 6),
+    # PR 61's own, all ten: it meant to change them (the docstring's rule).
+    # Every ATTENTION layer's backward is ONE ``flash_bwd`` equation, the
+    # dK/dV sweep with the head's dQ rows resident, where the parent traced
+    # ``flash_bwd_dq`` and ``flash_bwd_dkv``: one pallas_call equation fewer
+    # an attention application (OLMoE 3 -> 2, Ouro 6 -> 4, Olmo-Hybrid 9 ->
+    # 8, GLM 9 -> 6, ...), and the ``(B·H, S, 1)`` reshapes of lse and delta
+    # the dQ sweep read are gone. What each text moves with besides is what
+    # PRs 43-60 wrote here: the operand form (PR 47: 128-wide heads reach
+    # the kernels token-major, Kimi's and Xing4's 192 / 128 and Granite's 64
+    # head-major), ops/kda.py and the KDA layers (Kimi, Olmo-Hybrid),
+    # ``rotary_shared`` and zoo.glm_flash (GLM, Xing4), ops/ssd*.py
+    # (Granite), the window arm and the routers (Trinity, SmallThinker)
+    "olmoe": ("ad18f4e82814adf5f6058aaae4a664f60c51ef3ad3e97f16008defb"
+              "553bd647c", 84417, 2),
+    "ouro": ("947ada320c05c63e413ddff896969b9dd219d1e6f072bbd63cef1259"
+             "7a8fad5e", 135371, 4),
+    "zaya": ("19e15fa91a0c60aad395ce83fbf745d29635f4450a194cd31b6f0203"
+             "4aa06015", 189673, 4),
+    "trinity": ("bbb01cf1b159d5de251b82acd660eb0f97b3606c4117afd38769c"
+                "4865e36632a", 150089, 4),
+    "kimi": ("5fad9c60eaa421edf5f59a086cc9ff4327cb461fcb9808cdbb804f11"
+             "dbf5e2e2", 998335, 2),
+    "olmo_hybrid": ("7ac299981440efeb95547d8224b924765b207a945ac3a500d"
+                    "dd36bec3b17f29c", 819329, 8),
+    "glm": ("6aa53f1db20fdc5e855994c029543c277197fe0d3b57d831ab5aa644d"
+            "ff93cad", 239053, 6),
+    "smallthinker": ("d58e40d50e4d06270599ea9f3bdc827adc3cae30713298d2"
+                     "cae9efddb7655618", 156693, 4),
+    "granite": ("d0178bcc48ca90081f3a237083c4947b6f0e7fe1b07d9c5000d3c"
+                "b686a0a1bf5", 194092, 4),
+    "xing": ("b8f6a68b149c539aa29cd8abac18c9e8238b00bb3152b9b0c653cb1d"
+             "e7012d7f", 386577, 4),
 }
 
 
@@ -179,7 +169,8 @@ def test_token_configuration_traces_to_the_parent_s_program(name,
         monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
     text = traced(name)
     sha, chars, calls = PARENT[name]
-    assert "flash_fwd" in text and "flash_bwd_dkv" in text
+    assert "name=flash_fwd" in text and re.search(r"name=flash_bwd\b", text)
+    assert "flash_bwd_dq" not in text and "flash_bwd_dkv" not in text
     assert (text.count("pallas_call["), len(text)) == (calls, chars)
     assert hashlib.sha256(text.encode()).hexdigest() == sha
 

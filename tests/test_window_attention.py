@@ -177,17 +177,20 @@ def test_tile_rule_and_route_with_two_widths(monkeypatch):
     """``flash_blocks`` counts both widths: equal widths give the rule's old
     answer whatever way they are passed; at 8,192 x 192 / 128 in bf16 all
     three kernels keep 1024 x 1024; the route's note names the widths."""
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "dq", "dkv", "bwd"):
         for s, d in ((4096, 128), (8192, 128), (2048, 64), (136, 32)):
             assert pk.flash_blocks(kernel, s, d, 2) \
                 == pk.flash_blocks(kernel, s, d, 2, d)
-            assert pk._flash_vmem_bytes(kernel, 512, 256, d, 2) \
-                == pk._flash_vmem_bytes(kernel, 512, 256, d, 2, d)
+            assert pk._flash_vmem_bytes(kernel, 512, 256, d, 2, s=s) \
+                == pk._flash_vmem_bytes(kernel, 512, 256, d, 2, d, s)
         assert pk.flash_blocks(kernel, 8192, 192, 2, 128) == (1024, 1024)
     # the widths are counted: f32 operands at 192 / 128 no longer fit the
-    # dK/dV sweep's 1024 x 1024 (they do at 128 / 128)
+    # dK/dV sweep's 1024 x 1024 (they do at 128 / 128), nor at 192 / 256
+    # the single sweep's (they do at 192 / 128)
     assert pk.flash_blocks("dkv", 8192, 192, 4, 128) == (512, 1024)
     assert pk.flash_blocks("dkv", 8192, 128, 4) == (1024, 1024)
+    assert pk.flash_blocks("bwd", 8192, 192, 4, 256) == (512, 1024)
+    assert pk.flash_blocks("bwd", 8192, 192, 4, 128) == (1024, 1024)
     monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
     arm, note = pk.attention_route(8192, 8192, 192, 2, dv=128)
     assert arm == "pallas_flash" and note.endswith(
