@@ -1149,7 +1149,8 @@ class SSDScanLayer(Layer):
         _, s, w = bottom_shapes[0]
         # the note names the arm and, chunked on a shape the kernels
         # refuse, the reason
-        return "ssd_scan", ssd_route(s, h, w // h, bottom_shapes[3][2])[1], ""
+        return "ssd_scan", ssd_route(s, h, w // h, bottom_shapes[3][2],
+                                     itemsize)[1], ""
 
     def stats_sections(self, bottom_shapes, itemsize):
         from ..ops import ssd

@@ -36,7 +36,9 @@ and the state at its start. d B and d C are sums over the heads (the
 einsums' own), d dt collects from the write and, through a = dt A outside,
 from the decay's exponent, d D is a sum over tokens. The steps, the
 cumulative sums, every ratio and the carried state are f32, products at
-HIGHEST precision, whatever the compute policy.
+HIGHEST precision, whatever the compute policy (the kernels sum the same
+terms in the MXU passes that do not multiply by the zero low parts of a
+bf16 operand: ``ops/ssd_pallas.py``).
 
 Which arm runs, chosen by ``ssd_route`` from the shape and the backend (no
 switch):
@@ -101,19 +103,28 @@ def state_bytes(batch: int, s: int, heads: int, p: int, n: int) -> int:
     return batch * heads * (s // q) * p * n * 4 if q else 0
 
 
-def ssd_route(s: int, heads: int, p: int, n: int):
+def ssd_route(s: int, heads: int, p: int, n: int, itemsize: int = 4):
     """``(arm, note)`` for a sequence length and a scan's widths, as ``Net``
     logs it — THE routing decision, of the backend and the shape alone:
     ``pallas`` with the chunk and the heads a program, else ``chunked`` with
-    the reason the kernels did not take it, else ``recurrence``."""
+    the reason the kernels did not take it, else ``recurrence``.
+    ``itemsize``: the bytes of the compute type x, B and C arrive in, from
+    which the Pallas arm's note says what share of six MXU passes a product
+    its forward / backward kernels run (``ssd_pallas.mxu_passes``)."""
     from .pallas_kernels import _interpret_default
-    from .ssd_pallas import heads_a_program, ssd_refusal
+    from .ssd_pallas import heads_a_program, mxu_passes, ssd_refusal
     q = _pallas_chunk(s, heads, p, n)
     if q:
+        hp = heads_a_program(heads, p)
+        six = mxu_passes(q, p, n, hp, _F32)
+        ran = mxu_passes(q, p, n, hp,
+                         jnp.bfloat16 if itemsize == 2 else _F32)
         return "pallas", (
-            f"pallas (Q {q}, {s // q} chunks, {heads_a_program(heads, p)} "
+            f"pallas (Q {q}, {s // q} chunks, {hp} "
             f"heads a program, {max(1, 128 // p)} a lane block, one C B^T "
-            f"grid a program, f32 states in VMEM)")
+            f"grid a program, f32 states in VMEM, passes "
+            f"{ran[0] / six[0]:.2f} / {ran[1] / six[1]:.2f} of six a "
+            f"product)")
     q = ssd_chunk(s)
     if q is None:
         return "recurrence", f"token by token (no chunk divides S={s})"

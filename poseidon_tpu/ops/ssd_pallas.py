@@ -28,8 +28,17 @@ program's block (hp, Q): a head's row is a sublane, its column form is the
 diagonal of its broadcast (vector unit). Lane-segment sums (a head's 64 of
 a block's 128 lanes) are products with a 0 / 1 selector of 8 rows.
 
-Every product is f32 at HIGHEST precision, but the grid C B^T of operands
-that come in bf16: their products are exact in f32 as they are.
+A product runs the MXU passes its operands' types need (``_mm``, by dtype
+alone). f32 x f32 at HIGHEST is six bf16 passes: each operand's high,
+middle and low eight bits (``split3``), the six pairings that matter. x,
+B, C and d y stay in the type they ARRIVE in up to the product, and a bf16
+operand has no middle and no low part, so beside it an f32 operand (a
+state, the masked grid, d y times its decay: made here) needs its three
+parts once each, THREE passes, and two bf16 operands ONE: the same terms
+HIGHEST would sum, less those that multiply by zero. Nothing is rounded
+that did not arrive rounded; f32 operands run the six passes as before.
+``_products`` is the account, ``mxu_passes`` its sum, and the route's note
+says what share of six passes a product a run's kernels make.
 """
 
 from __future__ import annotations
@@ -49,6 +58,7 @@ from .pallas_kernels import _compiler_params
 
 _HI = lax.Precision.HIGHEST
 _F32 = jnp.float32
+_BF16 = jnp.bfloat16
 _LANES = 128
 
 
@@ -89,20 +99,66 @@ def ssd_blocks(s: int, heads: int, p: int, n: int) -> Optional[int]:
     return 256 if s % 256 == 0 else 128
 
 
+def split3(a):
+    """f32 ``a`` as three bf16 parts, hi + mid + lo == a: 8 + 8 + 8 bits of
+    its 24, each subtraction exact. Bit for bit down to |a| = 2^-102; a
+    smaller number's parts under 2^-126, the smallest normal one, are
+    flushed (here as in the MXU), which is all the sum is then short by."""
+    hi = a.astype(_BF16)
+    rest = a - hi.astype(_F32)
+    mid = rest.astype(_BF16)
+    return hi, mid, (rest - mid.astype(_F32)).astype(_BF16)
+
+
 def _mm(a, b, ca: int, cb: int):
-    """a, b f32 2-D, contracted over a's dimension ``ca`` and b's ``cb``."""
-    return lax.dot_general(a, b, (((ca,), (cb,)), ((), ())), precision=_HI,
-                           preferred_element_type=_F32)
+    """a, b 2-D, contracted over a's dimension ``ca`` and b's ``cb`` into
+    f32, in the MXU passes their types need (the module's account): bf16
+    operands go as they are, an f32 one beside a bf16 one as its
+    ``split3`` parts, the smallest first; two f32 operands (anything not
+    bf16 is cast) at HIGHEST."""
+    dot = functools.partial(
+        lax.dot_general, dimension_numbers=(((ca,), (cb,)), ((), ())),
+        preferred_element_type=_F32)
+    short_a, short_b = a.dtype == _BF16, b.dtype == _BF16
+    if short_a and short_b:
+        return dot(a, b)
+    if short_a or short_b:
+        hi, mid, lo = (dot(a, part) if short_a else dot(part, b)
+                       for part in split3(b if short_a else a))
+        return lo + mid + hi
+    return dot(a.astype(_F32), b.astype(_F32), precision=_HI)
 
 
-def _score_grid(c_ref, b_ref):
-    """C B^T (Q, Q) of a chunk, for all heads: bf16 operands as they are
-    (their products are exact in f32), anything else f32 at HIGHEST."""
-    c, b = c_ref[0], b_ref[0]
-    if c.dtype != jnp.bfloat16:
-        return _mm(c.astype(_F32), b.astype(_F32), 1, 1)
-    return lax.dot_general(c, b, (((1,), (1,)), ((), ())),
-                           preferred_element_type=_F32)
+def _products(q: int, p: int, n: int, heads: int):
+    """-> (forward's, backward's) Q-row products of one program (a chunk of
+    ``heads`` heads): (product, FLOP, how many of its two operands ARRIVE,
+    in the operands' type; the others are f32 made here). A lane block's
+    products take its 128 lanes whole, a head's the other heads' lanes
+    zeroed. Not listed: the backward's 8-row selector products (two a lane
+    block, one a head), under 3% of its MACs, which follow the same rule."""
+    blocks = heads * p // _LANES
+    grid, state, head = 2 * q * q * n, 2 * q * n * _LANES, 2 * q * q * _LANES
+    return (
+        (("C B^T", grid, 2),
+         ("C . state", blocks * state, 1),
+         ("(grid ratio dt) . x_j", heads * head, 1),
+         ("(x write)^T . B", blocks * state, 1)),
+        (("C B^T", grid, 2),
+         ("(d y decay) . state, (x write) . d state", 2 * blocks * state, 0),
+         ("B . d state, C . state, (d y decay)^T . C", 3 * blocks * state, 1),
+         ("d y_j . x^T", heads * head, 2),
+         ("(grid ratio dt)^T . d y_j", heads * head, 1),
+         ("d grid . B, d grid^T . C", 2 * grid, 1)))
+
+
+def mxu_passes(q: int, p: int, n: int, heads: int, dtype):
+    """-> (forward, backward) FLOP x MXU passes of one program's products
+    with x, B, C and d y in ``dtype``: ``_mm``'s rule over ``_products``
+    (six, three or one pass by the operands that arrive, where they arrive
+    in bf16; six whatever arrives in f32)."""
+    passes = (6, 3, 1) if jnp.dtype(dtype) == _BF16 else (6, 6, 6)
+    return tuple(sum(flop * passes[arrive] for _, flop, arrive in rows)
+                 for rows in _products(q, p, n, heads))
 
 
 def _masks(q: int):
@@ -174,23 +230,24 @@ def _fwd_kernel(x_ref, dtr_ref, lr_ref, b_ref, c_ref, d_ref, y_ref, *rest,
                 (_LANES, state.shape[1]), _F32)
 
     masks = _masks(q)
-    grid = _score_grid(c_ref, b_ref)
-    b32, c32 = b_ref[0].astype(_F32), c_ref[0].astype(_F32)
+    bs, cs = b_ref[0], c_ref[0]             # as they arrived, like x below
+    grid = _mm(cs, bs, 1, 1)
     for k in range(width // _LANES):
         ls = slice(k * _LANES, (k + 1) * _LANES)
         heads, lane, _, spread = _block_heads(lr_ref, dtr_ref, k, p, q,
                                               masks)
-        xb = x_ref[0, :, ls].astype(_F32)
+        xs = x_ref[0, :, ls]
+        xb = xs.astype(_F32)
         st = state[rows(k), :]
-        y = spread["decay"] * _mm(c32, st, 1, 1) + d_ref[:, ls] * xb
+        y = spread["decay"] * _mm(cs, st, 1, 1) + d_ref[:, ls] * xb
         for j, h in enumerate(heads):
             y = y + _mm(grid * h["ratio"] * h["dt_row"],
-                        jnp.where(lane == j, xb, 0.0), 1, 0)
+                        jnp.where(lane == j, xs, 0.0), 1, 0)
         y_ref[0, :, ls] = y.astype(y_ref.dtype)
         if saved is not None:
             saved[0, 0, ls, :] = st
         state[rows(k), :] = spread["keep"] * st \
-            + _mm(xb * spread["write"], b32, 0, 0)
+            + _mm(xb * spread["write"], bs, 0, 0)
 
 
 def _bwd_kernel(x_ref, dtr_ref, lr_ref, b_ref, c_ref, d_ref, saved, dy_ref,
@@ -209,38 +266,40 @@ def _bwd_kernel(x_ref, dtr_ref, lr_ref, b_ref, c_ref, d_ref, saved, dy_ref,
                 (_LANES, d_state.shape[1]), _F32)
 
     masks = _masks(q)
-    grid = _score_grid(c_ref, b_ref)
-    b32, c32 = b_ref[0].astype(_F32), c_ref[0].astype(_F32)
-    ones = jnp.ones((8, q), _F32)
+    bs, cs = b_ref[0], c_ref[0]         # as they arrived, like x and d y
+    grid = _mm(cs, bs, 1, 1)
+    # the two 0 / 1 selectors, exact in the operands' type
+    ones = jnp.ones((8, q), x_ref.dtype)
     last = lax.broadcasted_iota(jnp.int32, (1, q), 1) == q - 1
-    # row r of the selector picks head r's lanes of a lane block
+    # row r of ``pick`` picks head r's lanes of a lane block
     pick = jnp.where(
         lax.broadcasted_iota(jnp.int32, (8, _LANES), 1) // p
-        == lax.broadcasted_iota(jnp.int32, (8, _LANES), 0), 1.0, 0.0)
+        == lax.broadcasted_iota(jnp.int32, (8, _LANES), 0), 1.0, 0.0
+        ).astype(x_ref.dtype)
     d_grid = jnp.zeros((q, q), _F32)
-    d_b = jnp.zeros(b32.shape, _F32)
-    d_c = jnp.zeros(c32.shape, _F32)
+    d_b = jnp.zeros(bs.shape, _F32)
+    d_c = jnp.zeros(cs.shape, _F32)
     per_block = max(1, _LANES // p)
     for k in range(width // _LANES):
         ls = slice(k * _LANES, (k + 1) * _LANES)
         heads, lane, sub, spread = _block_heads(lr_ref, dtr_ref, k, p, q,
                                                 masks)
-        xb = x_ref[0, :, ls].astype(_F32)
-        dyb = dy_ref[0, :, ls].astype(_F32)
+        xs, dys = x_ref[0, :, ls], dy_ref[0, :, ls]
+        xb, dyb = xs.astype(_F32), dys.astype(_F32)
         st = saved[0, 0, ls, :]
         ds = d_state[rows(k), :]
         dyd = dyb * spread["decay"]
         d_c = d_c + _mm(dyd, st, 1, 0)
-        wrote = _mm(b32, ds, 1, 1)                   # d (x rev dt), (Q, 128)
+        wrote = _mm(bs, ds, 1, 1)                    # d (x rev dt), (Q, 128)
         d_b = d_b + _mm(xb * spread["write"], ds, 1, 0)
         # a head's sums over its own lanes, as rows (8, Q)
-        seg_l = _mm(pick, dyd * _mm(c32, st, 1, 1), 1, 1)
+        seg_l = _mm(pick, dyd * _mm(cs, st, 1, 1), 1, 1)
         seg_u = _mm(pick, wrote * xb * spread["rev"], 1, 1)
         carried = jnp.sum(ds * st, 1, keepdims=True)             # (128, 1)
         dxb = d_ref[:, ls] * dyb + wrote * spread["write"]
         for j, h in enumerate(heads):
-            dyj = jnp.where(lane == j, dyb, 0.0)
-            dm = _mm(dyj, xb, 1, 1) * h["ratio"]     # d (E G dt) on i >= j
+            dyj = jnp.where(lane == j, dys, 0.0)
+            dm = _mm(dyj, xs, 1, 1) * h["ratio"]     # d (E G dt) on i >= j
             scaled = dm * h["dt_row"]
             d_grid = d_grid + scaled
             dxb = dxb + _mm(grid * h["ratio"] * h["dt_row"], dyj, 0, 0)
@@ -260,9 +319,9 @@ def _bwd_kernel(x_ref, dtr_ref, lr_ref, b_ref, c_ref, d_ref, saved, dy_ref,
         dx_ref[0, :, ls] = dxb.astype(dx_ref.dtype)
         dd_ref[0, 0, :, ls] = jnp.sum(dyb * xb, 0, keepdims=True)
         d_state[rows(k), :] = spread["keep"] * ds \
-            + _mm(dyd, c32, 0, 0)
-    d_c = d_c + _mm(d_grid, b32, 1, 0)
-    d_b = d_b + _mm(d_grid, c32, 0, 0)
+            + _mm(dyd, cs, 0, 0)
+    d_c = d_c + _mm(d_grid, bs, 1, 0)
+    d_b = d_b + _mm(d_grid, cs, 0, 0)
 
     @pl.when(pl.program_id(2) == 0)
     def _():

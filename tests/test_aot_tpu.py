@@ -768,7 +768,8 @@ def test_granite_full_width_step_fits_one_v5e_at_one_and_two_sequences(more):
         "head; operands head-major (Dh 64, not lane-aligned)); 8 kv heads "
         "repeated x4; no positions",
         "ssd_scan=pallas (Q 256, 32 chunks, 8 heads a program, 2 a lane "
-        "block, one C B^T grid a program, f32 states in VMEM)"]
+        "block, one C B^T grid a program, f32 states in VMEM, passes 0.47 / "
+        "0.47 of six a product)"]
     # weights + two moments, 12 bytes a parameter
     assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
     assert got["total_gb"] < KEEP_SHARE * 16.9

@@ -180,6 +180,96 @@ def test_pallas_kernels_take_bf16_operands_and_keep_states():
     assert rel(y.astype(jnp.float32), want) < 4e-3      # y's own rounding
 
 
+def test_split3_is_exact_over_the_kernels_range():
+    """hi + mid + lo == a bit for bit: the ratios exp(L_i - L_j) down to
+    exp(-70), states up to 1e4, zeros, both signs. Below 2^-102 a part
+    falls under the smallest normal number and is flushed, on this CPU as
+    in the MXU: the sum is then short by less than that number."""
+    r = np.random.RandomState(7)
+    f32 = lambda t: np.asarray(t, np.float32)
+    values = np.concatenate([
+        f32(np.exp(-r.uniform(0, 70, 50000))), f32(1e4 * r.randn(50000)),
+        f32(-np.exp(-r.uniform(0, 70, 50000))), f32(r.randn(50000)),
+        np.zeros(8, np.float32), f32([1.0, -1.0, 2.0 ** -102, 3e38])])
+    tiny = f32(np.exp(-r.uniform(70, 110, 50000)) * np.sign(r.randn(50000)))
+
+    def back(a):
+        parts = jax.jit(ssd_pallas.split3)(jnp.asarray(a))
+        assert all(t.dtype == jnp.bfloat16 for t in parts)
+        hi, mid, lo = (np.asarray(t.astype(jnp.float32)) for t in parts)
+        return (hi + mid) + lo
+
+    assert np.array_equal(back(values), values)
+    assert np.max(np.abs(back(tiny).astype(np.float64) - tiny)) <= 2.0 ** -126
+
+
+# (heads, P, S, Q): heads of 64 with padding (10 -> 16, two programs a chunk,
+# two chunks); heads of 128, one a lane block
+SHORT = {"h10_p64": (10, 64, 256, 128), "h3_p128": (3, 128, 256, 256)}
+
+
+@pytest.mark.parametrize("decay", ["strong", "weak"])
+@pytest.mark.parametrize("shape", sorted(SHORT))
+def test_bf16_operands_sum_what_the_six_pass_products_sum(shape, decay):
+    """The kernels on bf16 x, B, C, d y (one and three passes a product)
+    against the SAME values handed in as f32 (six passes, the parent's
+    products): what leaves in f32 (the states, d dt, d a, d D) to 1e-5
+    (d a under strong decay to 1e-4: sums of terms near f32's floor, as in
+    ``close``); what leaves in the operands' type (y, d x, d B, d C) equal
+    to the f32 instance's after ITS rounding to bf16, but for a few
+    neighbours one unit in the last place apart (or, where an element is
+    what large terms cancel to, the f32 sums' own last place)."""
+    h, p, s, q = SHORT[shape]
+    x, dt, b, c, a_log, d, d_y = operands(5, decay, 1, s, h, p, 128)
+    a = -jnp.exp(a_log) * dt
+    short = [t.astype(jnp.bfloat16) for t in (x, b, c, d_y)]
+
+    def both(x, b, c, d_y):
+        y, states = ssd_pallas._forward(x, dt, a, b, c, d, q, True, True)
+        return (y, states) + ssd_pallas._backward(
+            x, dt, a, b, c, d, states, d_y, q, True)
+
+    got = both(*short)
+    want = both(*(t.astype(jnp.float32) for t in short))
+    for name, g, w in zip(("y", "states", "dx", "ddt", "da", "dB", "dC",
+                           "dD"), got, want):
+        assert float(jnp.max(jnp.abs(w))) > 0 or name == "states", name
+        if g.dtype == jnp.float32:
+            limit = 1e-4 if (name, decay) == ("da", "strong") else 1e-5
+            assert w.dtype == jnp.float32 and rel(g, w) < limit, name
+            continue
+        assert g.dtype == jnp.bfloat16 and w.dtype == jnp.float32, name
+        g, w = (np.asarray(t.astype(jnp.bfloat16).astype(jnp.float32))
+                for t in (g, w))
+        assert np.mean(g != w) < 1e-3, (name, np.mean(g != w))
+        assert np.all(np.abs(g - w) <= np.abs(w) * 2.0 ** -7
+                      + 1e-6 * np.max(np.abs(w))), name
+
+
+def test_mxu_passes_count_the_products_the_types_need(monkeypatch):
+    """FLOP x passes at Granite's shape (Q 256, P 64, N 128): a lane block
+    more of a program, a program of eight heads, 256 programs a call; and
+    the route's note carries the share of six passes a product."""
+    def counts(dtype):
+        two, four, eight = (ssd_pallas.mxu_passes(256, 64, 128, heads, dtype)
+                            for heads in (2, 4, 8))
+        return ([round((b - a) / 1e6) for a, b in zip(two, four)],
+                [round(256 * t / 1e9) for t in eight])
+
+    assert counts(jnp.float32) == ([302, 654], [335, 747])
+    assert counts(jnp.bfloat16) == ([151, 310], [159, 348])
+    assert counts(jnp.float16) == counts(jnp.float32)   # cast, not split
+    # the same four lane blocks as four heads of 128: no lanes zeroed, half
+    # the products a head
+    assert [round(t / 1e6) for t in ssd_pallas.mxu_passes(
+        256, 128, 128, 4, jnp.bfloat16)] == [419, 1091]
+    monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
+    assert ssd.ssd_route(8192, 64, 64, 128, 2)[1].endswith(
+        "f32 states in VMEM, passes 0.47 / 0.47 of six a product)")
+    assert ssd.ssd_route(8192, 64, 64, 128, 4)[1].endswith(
+        "f32 states in VMEM, passes 1.00 / 1.00 of six a product)")
+
+
 def test_route_names_the_arm_and_why(monkeypatch):
     assert ssd.ssd_route(8192, 64, 64, 128) == (
         "chunked", "chunked Q 256, 32 chunks, f32 state, one C B^T grid a "
@@ -188,7 +278,8 @@ def test_route_names_the_arm_and_why(monkeypatch):
     monkeypatch.setenv("POSEIDON_FORCE_PALLAS", "1")
     assert ssd.ssd_route(8192, 64, 64, 128) == (
         "pallas", "pallas (Q 256, 32 chunks, 8 heads a program, 2 a lane "
-        "block, one C B^T grid a program, f32 states in VMEM)")
+        "block, one C B^T grid a program, f32 states in VMEM, passes 1.00 / "
+        "1.00 of six a product)")
     assert ssd.ssd_route(384, 64, 64, 128)[1].startswith("pallas (Q 128, 3")
     for shape, why in (((48, 16, 8, 16), "neither 256 nor 128 divides S=48"),
                        ((256, 16, 8, 128), "heads of 8 are no whole part"),

@@ -25,8 +25,9 @@ and cost ``ouro.loop4.pack8k`` 9 s of set-up; PR 47 did, for the four whose
 heads are 128 wide, and left Kimi-Linear's alone; PR 61 did, for all ten:
 the flash backward became one sweep, so every ATTENTION layer's backward is
 one ``flash_bwd`` call where it was ``flash_bwd_dq`` and ``flash_bwd_dkv``,
-and all ten hashes below are PR 61's own) updates the hashes and
-says so; one that does not has tripped over a shared path."""
+and all ten hashes below were PR 61's own; PR 62 did, for Granite alone:
+the scan kernels' products, the nine other hashes stayed) updates the
+hashes and says so; one that does not has tripped over a shared path."""
 
 import hashlib
 import re
@@ -124,8 +125,12 @@ PARENT = {       # sha256 of the text, its length, its pallas_call equations
             "ff93cad", 239053, 6),
     "smallthinker": ("d58e40d50e4d06270599ea9f3bdc827adc3cae30713298d2"
                      "cae9efddb7655618", 156693, 4),
-    "granite": ("d0178bcc48ca90081f3a237083c4947b6f0e7fe1b07d9c5000d3c"
-                "b686a0a1bf5", 194092, 4),
+    # PR 62: ``ops/ssd_pallas._mm`` chooses a product's passes by its
+    # operands' types. This f32 trace keeps the parent's 119 products, all
+    # at HIGHEST; B and C are read once a program where they were read
+    # twice, and the selector ``pick`` is cast to x's type
+    "granite": ("9ffa4295b12cccfe41d7a1add11f495f042cc4ae7a9765022e629"
+                "b815a735f73", 194050, 4),
     "xing": ("b8f6a68b149c539aa29cd8abac18c9e8238b00bb3152b9b0c653cb1d"
              "e7012d7f", 386577, 4),
 }
