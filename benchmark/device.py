@@ -6,16 +6,39 @@ from __future__ import annotations
 import json
 import os
 import sys
+import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+
+# Seconds the runtime took to hand the chips over: the ONE call
+# ``jax.devices()`` below, the process's first touch of the backend. Nothing of
+# the repository runs inside it, and identical processes on one machine read
+# 5.6-10.3 s for it within ten minutes (PERF.md, section 6, PR 63): run.py
+# takes it out of ``setup_s`` and reports it beside, as ``backend_start_s``.
+BACKEND_START_S = 0.0
+
+
+def not_set_up() -> dict:
+    """Seconds that run.py takes out of an end-to-end metric, by the metric's
+    name: the runtime's start of the chips is not the repository's set-up."""
+    return {"setup_s": BACKEND_START_S}
+
+
+def layer_facts() -> dict:
+    """What run.py adds to the runner's ``layers`` for the per-layer readers
+    (``layer_metrics/backend_start_s.py``)."""
+    return {"backend_start_s": BACKEND_START_S}
 
 
 def require(chips: int, cpu_rehearsal: bool) -> dict:
     """``{"platform", "kind", "count"}`` of the devices jax holds, or exit
     code 2 with no result when they are not ``chips`` TPU chips. Only the
     tests' ``--cpu-tiny`` rehearsal may run on the CPU, and it says so."""
+    global BACKEND_START_S
     import jax
+    t = time.perf_counter()
     devices = jax.devices()
+    BACKEND_START_S = time.perf_counter() - t
     info = {"platform": devices[0].platform, "kind": devices[0].device_kind,
             "count": len(devices)}
     wanted = "cpu" if cpu_rehearsal else "tpu"

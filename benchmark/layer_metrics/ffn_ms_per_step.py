@@ -1,6 +1,7 @@
 """Kernels layer: device milliseconds per step in the dense FFN (the
 configuration's ``ffn`` scopes: the gate and up projections, their product and
-the down projection): forward, backward and replay."""
+the down projection; Granite's SwiGLU MLP makes both in ONE input projection
+of 16,384 and splits it): forward, backward and replay."""
 
 import lm_trace
 

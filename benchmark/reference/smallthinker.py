@@ -78,17 +78,31 @@ import jax.numpy as jnp
 # another order. bf16, every limit from readings on the v5e (PERF.md, PR 45):
 # the program under bf16 over its seeds, and this reference with its matmul
 # inputs rounded to float8 e4m3, the nearest precision below, which has to
-# fail at least one (it fails logits_rel_l2):
+# fail at least one (it fails logits_rel_l2, and no other):
 # - logits_rel_l2: bf16 0.0020-0.0025 over seven seeds, float8
-#   0.0088-0.0103: 4.5e-3 between, a factor of two from either.
-# - update_cosine (the worst leaf of 2**16 numbers or more: a router's
-#   matrix (64, 2560) in three seeds of seven, a held stack of layer 3 in
-#   three, layer 1's k in one: fresh logits are near-ties, so the step's
-#   free-running top-6 differs between bf16 and f32 inputs for 400-750
-#   assignments a layer): bf16 0.9715-0.9849. The precision hardly moves it
-#   (float8 0.9536-0.9804: the router is never rounded), so the limit stands
-#   below the readings with room for fresh seeds, 0.93, where a leaf updated
-#   in another direction reads far lower.
+#   0.0088-0.0103: 4.5e-3 between, a factor of two from either (PRs 53 and
+#   63, fourteen runs: bf16 0.0023-0.0030, float8 0.0097-0.0130).
+# - update_cosine, the direction of the WHOLE update, every leaf as one
+#   vector (ISSUE 63; PERF.md 53a and section 6, PR 63): bf16 0.9951-0.9968
+#   on eleven seeds of twelve and 0.9756 on one (3053000007), whose batch
+#   has two frequent token ids (515 and 405 of 16,384 tokens) with their 6th
+#   and 7th router scores in layer 0 nearer (0.0003, 0.0008) than bf16's
+#   error of a score (0.003 rms): layer 0's router reads a function of the
+#   id alone, so every token of such an id changes an expert at once, and
+#   the free-running top-6 differs for 579 tokens there against 121-274 on
+#   the other seeds. The precision hardly moves the number (float8
+#   0.9914-0.9959, on that seed 0.9920: the router is never rounded), so it
+#   has no upper reading from the control; a state left unchanged reads 0.
+#   The limit stays PR 45's 0.93, three times the worst seed's gap.
+# - leaf_cosine, the least cosine of ONE leaf of 2**16 numbers or more (what
+#   update_cosine was until ISSUE 63: a router's matrix (64, 2560) or a held
+#   stack of layer 3 in most seeds): bf16 0.9722-0.9874 on eleven seeds of
+#   twelve, 0.9052 on 3053000007 (l0_router; then l1_k 0.9228, l0_q 0.9375);
+#   PR 45's seven seeds read 0.9715-0.9849; float8 0.9488-0.9768, no upper
+#   reading either. Adam's first change of a number is the rate times its
+#   gradient's sign, so a leaf of the wrong sign reads -1 and one left
+#   unmoved 0: 0.8, twice the worst seed's gap from 1 and five times nearer
+#   to it than an unmoved leaf.
 # - loss_rel, update_norm_rel: the precision hardly moves them either.
 #   loss_rel 0.4e-5-2.1e-5: the accepted cells' 2.5e-4 (12 times of room).
 #   update_norm_rel 0.0014-0.0048 (a held stack of layer 2 or 3; float8
@@ -101,10 +115,12 @@ import jax.numpy as jnp
 TOLERANCE = {
     "f32": {"logits_rel_l2": 2e-4, "loss_rel": 1e-5,
             "step_loss_rel": 1e-5, "update_norm_rel": 1e-3,
-            "update_cosine": 0.999, "cosine_from": 2 ** 16},
+            "update_cosine": 0.999, "leaf_cosine": 0.999,
+            "cosine_from": 2 ** 16},
     "bf16": {"logits_rel_l2": 4.5e-3, "loss_rel": 2.5e-4,
              "step_loss_rel": None, "update_norm_rel": 0.1,
-             "update_cosine": 0.93, "cosine_from": 2 ** 16},
+             "update_cosine": 0.93, "leaf_cosine": 0.8,
+             "cosine_from": 2 ** 16},
 }
 # at a CPU rehearsal's widths (hidden 64, 64 positions, 128 tokens a step) a
 # logit is a sum of 64 products, not 2560, and a handful of tokens change an
@@ -114,7 +130,8 @@ TOLERANCE_TINY = {
     "f32": dict(TOLERANCE["f32"], cosine_from=2 ** 6),
     "bf16": {"logits_rel_l2": 6e-2, "loss_rel": 5e-3,
              "step_loss_rel": 5e-3, "update_norm_rel": 0.5,
-             "update_cosine": 0.7, "cosine_from": 2 ** 10},
+             "update_cosine": 0.7, "leaf_cosine": 0.5,
+             "cosine_from": 2 ** 10},
 }
 
 

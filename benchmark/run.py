@@ -31,6 +31,7 @@ import json                        # noqa: E402
 import os                          # noqa: E402
 import sys                         # noqa: E402
 
+import device as device_mod        # noqa: E402
 import device_trace                # noqa: E402
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -109,12 +110,20 @@ def main() -> int:
         "work_dir": os.path.join(BENCH_DIR, ".work"),
         "keep_trace": args.keep_trace})
 
+    # what is the machine's and not the repository's work leaves the
+    # end-to-end numbers and is reported beside them (device.py says what:
+    # the runtime's start of the chips); the readers keep the runner's whole
+    # readings, and that part beside them
+    end_to_end = dict(result["end_to_end"])
+    for name, seconds in device_mod.not_set_up().items():
+        end_to_end[name] -= seconds
+
     device = dict(result["device"])
     line = {"correct": bool(result["correct"]),
             "attempted": int(result["attempted"]),
             "failed": int(result["failed"]), "metrics": {}, "device": device}
     if args.trace:
-        layers = result["layers"]
+        layers = dict(result["layers"], **device_mod.layer_facts())
         if args.keep_layers:
             with open(args.keep_layers, "w") as f:
                 json.dump(layers, f)
@@ -133,15 +142,15 @@ def main() -> int:
     else:
         for metric in bench["end_to_end"]:
             if applies(metric, cell["name"]) \
-                    and metric["name"] in result["end_to_end"]:
+                    and metric["name"] in end_to_end:
                 line["metrics"][metric["name"]] = {
-                    "value": float(result["end_to_end"][metric["name"]]),
+                    "value": float(end_to_end[metric["name"]]),
                     "unit": metric["unit"]}
     # the facts line: what the runner saw, and the run's own end-to-end
     # readings whichever kind of line follows (a traced run's untraced pace
     # and set-up time are what its per-layer metrics are read against)
-    print(json.dumps({"facts": result["facts"],
-                      "end_to_end": result["end_to_end"]}), flush=True)
+    print(json.dumps({"facts": result["facts"], "end_to_end": end_to_end,
+                      **device_mod.layer_facts()}), flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

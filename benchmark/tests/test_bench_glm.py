@@ -17,18 +17,19 @@ import pytest
 import flops_glm
 import tokengen
 from conftest import BENCH_DIR, ROOT
-from layer_metrics import (glm_attention_glue_ms_per_step,
-                           glm_attention_ms_per_step,
-                           glm_flash_attention_roofline, glm_head_ms_per_step,
-                           glm_held_dropped_assignments,
-                           glm_held_load_max_over_mean,
-                           glm_held_moe_flops_util, glm_held_moe_ms_per_step,
-                           glm_mla_proj_ms_per_step, glm_mtp_loss_over_main,
-                           glm_mtp_ms_per_step, glm_recompute_ms_per_step,
-                           glm_router_ms_per_step,
-                           glm_shared_expert_ms_per_step,
-                           glm_tokens_per_s_per_chip)
-from test_bench_run import BENCH, declared, run_cell
+from layer_metrics import (attention_glue_ms_per_step,
+                           attention_ms_per_step,
+                           flash_attention_roofline, head_ms_per_step,
+                           held_assignment_share,
+                           held_dropped_assignments,
+                           held_load_max_over_mean,
+                           held_moe_flops_util, held_moe_ms_per_step,
+                           mla_proj_ms_per_step, mtp_loss_over_main,
+                           mtp_ms_per_step, recompute_ms_per_step,
+                           router_ms_per_step,
+                           shared_expert_ms_per_step,
+                           tokens_per_s_per_chip)
+from test_bench_run import BENCH, declared, entries_of, run_cell
 
 CELL = "glm_flash.e8of64.pack8k"
 with open(os.path.join(BENCH_DIR, "configs", "glm_4_7_flash.json")) as f:
@@ -300,27 +301,29 @@ def small_run(scopes=SCOPES, lm=True):
 
 READERS = [
     # (10 + 6 + 40 + 20 + 2 + 4) ns / 2 steps
-    (glm_attention_ms_per_step, 41e-6),
+    (attention_ms_per_step, 41e-6),
     # flops-bound: 6e3 / 1e12 = 6 ns against (40 + 20) / 2 ns of kernel
-    (glm_flash_attention_roofline, 100 * 6e-9 / 30e-9),
-    (glm_attention_glue_ms_per_step, 6e-6),       # (6 + 2 + 4) / 2
-    (glm_mla_proj_ms_per_step, 5e-6),
+    (flash_attention_roofline, 100 * 6e-9 / 30e-9),
+    (attention_glue_ms_per_step, 6e-6),       # (6 + 2 + 4) / 2
+    (mla_proj_ms_per_step, 5e-6),
     # every mtp_* scope: 20 + 4 + 10 + 14 + 2 + 16 + 4
-    (glm_mtp_ms_per_step, 35e-6),
-    (glm_head_ms_per_step, 14e-6),                # (12 + 14 + 2) / 2
-    (glm_mtp_loss_over_main, 1.02),
-    (glm_held_moe_ms_per_step, 20e-6),
+    (mtp_ms_per_step, 35e-6),
+    (head_ms_per_step, 14e-6),                # (12 + 14 + 2) / 2
+    (mtp_loss_over_main, 1.02),
+    (held_moe_ms_per_step, 20e-6),
     # the TRACED steps' 0.25 x 1000 assignments x 10 FLOPs over 20 ns x 1e12
-    (glm_held_moe_flops_util, 100 * 2.5e3 / (20e-9 * 1e12)),
-    (glm_shared_expert_ms_per_step, 8e-6),
-    (glm_router_ms_per_step, 4e-6),
-    (glm_held_load_max_over_mean, 1.3),
-    (glm_held_dropped_assignments, 0.0),
-    (glm_recompute_ms_per_step, 13e-6),           # (6 + 20) ns / 2
-    (glm_tokens_per_s_per_chip, 10 * 2 * 8192 / 4.0),
+    (held_moe_flops_util, 100 * 2.5e3 / (20e-9 * 1e12)),
+    (shared_expert_ms_per_step, 8e-6),
+    (router_ms_per_step, 4e-6),
+    (held_assignment_share, 13.0),            # the window's displays, in %
+    (held_load_max_over_mean, 1.3),
+    (held_dropped_assignments, 0.0),
+    (recompute_ms_per_step, 13e-6),           # (6 + 20) ns / 2
+    (tokens_per_s_per_chip, 10 * 2 * 8192 / 4.0),
 ]
-COUNTERS = (glm_tokens_per_s_per_chip, glm_held_load_max_over_mean,
-            glm_held_dropped_assignments, glm_mtp_loss_over_main)
+COUNTERS = (tokens_per_s_per_chip, held_assignment_share,
+            held_load_max_over_mean, held_dropped_assignments,
+            mtp_loss_over_main)
 
 
 @pytest.mark.parametrize("reader, want", READERS)
@@ -335,13 +338,13 @@ def test_each_reader_finds_nothing_on_a_program_without_it(reader):
     metric is its ``workloads`` list's to say, not the reader's: no reader
     looks for a cell's name.)"""
     assert reader.reduce(small_run(scopes=None, lm=False)) is None
-    if reader is not glm_recompute_ms_per_step:   # reads the map alone
+    if reader is not recompute_ms_per_step:   # reads the map alone
         assert reader.reduce(small_run(lm=False)) is None
     if reader not in COUNTERS:                    # those need no trace
         assert reader.reduce(dict(small_run(), trace=None)) is None
     # the program's map without this model's scopes (the parent's): the
     # roofline finds no kernel time under its pattern and reads nothing
-    if reader is glm_flash_attention_roofline:
+    if reader is flash_attention_roofline:
         bare = small_run(scopes={"ops": {"qb.1": "l0_q|fwd"},
                                  "types": {"l0_q": "INNER_PRODUCT"}})
         assert reader.reduce(bare) is None
@@ -551,24 +554,24 @@ def test_cpu_tiny_rehearsal_of_the_glm_cell(trace):
         # all of the cell's per-layer metrics but those that need a chip's
         # peaks, its memory statistics or its Pallas kernels
         assert names == declared("per_layer", CELL) - {
-            "busy_flops_util", "peak_hbm_gb", "glm_flash_attention_roofline",
-            "glm_held_moe_flops_util"}
+            "busy_flops_util", "peak_hbm_gb", "flash_attention_roofline",
+            "held_moe_flops_util"}
         m = {k: v["value"] for k, v in line["metrics"].items()}
         assert m["scope_coverage"] >= 95.0
-        parts = ("glm_attention_ms_per_step", "glm_held_moe_ms_per_step",
-                 "glm_shared_expert_ms_per_step", "glm_router_ms_per_step",
-                 "glm_head_ms_per_step")
+        parts = ("attention_ms_per_step", "held_moe_ms_per_step",
+                 "shared_expert_ms_per_step", "router_ms_per_step",
+                 "head_ms_per_step")
         assert all(m[k] > 0 for k in parts)
-        assert m["glm_attention_glue_ms_per_step"] \
-            + m["glm_mla_proj_ms_per_step"] \
-            == pytest.approx(m["glm_attention_ms_per_step"])  # all dense
+        assert m["attention_glue_ms_per_step"] \
+            + m["mla_proj_ms_per_step"] \
+            == pytest.approx(m["attention_ms_per_step"])  # all dense
         assert sum(m[k] for k in parts) \
             < m["fwd_ms_per_step"] + m["bwd_ms_per_step"]
-        assert 0 < m["glm_mtp_ms_per_step"] \
+        assert 0 < m["mtp_ms_per_step"] \
             < m["fwd_ms_per_step"] + m["bwd_ms_per_step"]
-        assert m["glm_recompute_ms_per_step"] < m["bwd_ms_per_step"]
-        assert 0.9 < m["glm_mtp_loss_over_main"] < 1.1
-        assert m["glm_held_dropped_assignments"] == 0.0
+        assert m["recompute_ms_per_step"] < m["bwd_ms_per_step"]
+        assert 0.9 < m["mtp_loss_over_main"] < 1.1
+        assert m["held_dropped_assignments"] == 0.0
     else:
         assert names == declared("end_to_end", CELL) - {"mfu_required"}
         assert line["metrics"]["images_per_s_per_chip"]["value"] == \
@@ -667,14 +670,7 @@ def test_new_entries_follow_the_contract():
         == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
     assert config["source"] == CFG["source"] \
         and config["file"] == "benchmark/configs/glm_4_7_flash.json"
-    assert BENCH["workloads"][-1] is cell and BENCH["configs"][-1] is config
-    mine = [m for m in BENCH["per_layer"]
-            if m.get("workloads") == [CELL]]
-    # every reader tested above is declared for this cell alone, and every
-    # metric declared for this cell alone has its reader tested above
-    assert {r.__name__.rsplit(".", 1)[-1] for r, _ in READERS} \
-        == {m["name"] for m in mine}
-    assert BENCH["per_layer"][-len(mine):] == mine     # at the list's end
+    mine = entries_of(CELL, [r for r, _ in READERS])
     for text in (cell["why"], config["why"], config["source"],
                  *(m["layer"] for m in mine)):
         assert 1 <= len(text) <= 200 and text.isascii() \

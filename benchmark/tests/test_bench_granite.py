@@ -17,16 +17,13 @@ import pytest
 
 import flops_granite
 from conftest import BENCH_DIR, ROOT
-from layer_metrics import (granite_attention_ms_per_step,
-                           granite_ffn_flops_util,
-                           granite_ffn_ms_per_step,
-                           granite_flash_attention_roofline,
-                           granite_head_ms_per_step,
-                           granite_recompute_ms_per_step,
-                           granite_tokens_per_s_per_chip, ssd_decay_mean,
-                           ssd_glue_ms_per_step, ssd_ms_per_step,
-                           ssd_scan_ms_per_step, ssd_scan_roofline)
-from test_bench_run import BENCH, declared, run_cell
+from layer_metrics import (attention_ms_per_step, ffn_flops_util,
+                           ffn_ms_per_step, flash_attention_roofline,
+                           head_ms_per_step, recompute_ms_per_step,
+                           ssd_decay_mean, ssd_glue_ms_per_step,
+                           ssd_ms_per_step, ssd_scan_ms_per_step,
+                           ssd_scan_roofline, tokens_per_s_per_chip)
+from test_bench_run import BENCH, declared, entries_of, run_cell
 
 CELL = "granite_h.p1.pack8k"
 with open(os.path.join(BENCH_DIR, "configs",
@@ -223,18 +220,18 @@ READERS = [
     # bytes-bound: 500 / 1e11 = 5 ns against 20 ns of scan a step
     (ssd_scan_roofline, 100 * 5e-9 / 20e-9),
     (ssd_glue_ms_per_step, 3e-6),
-    (granite_attention_ms_per_step, 12e-6),           # (20 + 4) / 2
+    (attention_ms_per_step, 12e-6),                   # (20 + 4) / 2
     # flops-bound: 1e3 / 1e12 = 1 ns against 10 ns of kernel a step
-    (granite_flash_attention_roofline, 100 * 1e-9 / 10e-9),
-    (granite_ffn_ms_per_step, 15e-6),
+    (flash_attention_roofline, 100 * 1e-9 / 10e-9),
+    (ffn_ms_per_step, 15e-6),
     # 3e3 FLOPs over the MLP scopes' 15 ns a step x 1e12
-    (granite_ffn_flops_util, 100 * 3e3 / (15e-9 * 1e12)),
-    (granite_head_ms_per_step, 7e-6),                 # (12 + 2) / 2
-    (granite_recompute_ms_per_step, 8e-6),            # (10 + 6) / 2
+    (ffn_flops_util, 100 * 3e3 / (15e-9 * 1e12)),
+    (head_ms_per_step, 7e-6),                         # (12 + 2) / 2
+    (recompute_ms_per_step, 8e-6),                    # (10 + 6) / 2
     (ssd_decay_mean, 0.8),
-    (granite_tokens_per_s_per_chip, 10 * S / 4.0),
+    (tokens_per_s_per_chip, 10 * S / 4.0),
 ]
-MAP_ONLY = (granite_recompute_ms_per_step,)    # reads the map alone
+MAP_ONLY = (recompute_ms_per_step,)            # reads the map alone
 
 
 @pytest.mark.parametrize("reader, want", READERS)
@@ -431,17 +428,17 @@ def test_cpu_tiny_rehearsal_of_the_granite_cell(trace):
         # peaks, its memory statistics or its Pallas kernels
         assert names == declared("per_layer", CELL) - {
             "busy_flops_util", "peak_hbm_gb", "ssd_scan_roofline",
-            "granite_flash_attention_roofline", "granite_ffn_flops_util"}
+            "flash_attention_roofline", "ffn_flops_util"}
         m = {k: v["value"] for k, v in line["metrics"].items()}
         assert m["scope_coverage"] >= 95.0
-        parts = ("ssd_ms_per_step", "granite_attention_ms_per_step",
-                 "granite_ffn_ms_per_step", "granite_head_ms_per_step")
+        parts = ("ssd_ms_per_step", "attention_ms_per_step",
+                 "ffn_ms_per_step", "head_ms_per_step")
         assert all(m[k] > 0 for k in parts)
         assert m["ssd_scan_ms_per_step"] + m["ssd_glue_ms_per_step"] \
             < m["ssd_ms_per_step"]
         assert sum(m[k] for k in parts) \
             < m["fwd_ms_per_step"] + m["bwd_ms_per_step"]
-        assert m["granite_recompute_ms_per_step"] < m["bwd_ms_per_step"]
+        assert m["recompute_ms_per_step"] < m["bwd_ms_per_step"]
         assert 0.0 < m["ssd_decay_mean"] < 1.0
     else:
         assert names == declared("end_to_end", CELL) - {"mfu_required"}
@@ -526,12 +523,7 @@ def test_new_entries_follow_the_contract():
         == ["num_hidden_layers", "vocab_size"]
     assert config["source"] == CFG["source"] \
         and config["file"] == "benchmark/configs/granite_4_0_h_micro.json"
-    mine = [m for m in BENCH["per_layer"]
-            if m.get("workloads") == [CELL]]
-    # every reader tested above is declared for this cell alone, and every
-    # metric declared for this cell alone has its reader tested above
-    assert {r.__name__.rsplit(".", 1)[-1] for r, _ in READERS} \
-        == {m["name"] for m in mine}
+    mine = entries_of(CELL, [r for r, _ in READERS])
     for text in (cell["why"], config["why"], config["source"],
                  *(m["layer"] for m in mine)):
         assert 1 <= len(text) <= 200 and text.isascii() \

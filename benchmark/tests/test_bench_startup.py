@@ -121,3 +121,52 @@ def test_new_entries_follow_the_contract():
     layers = {m["layer"] for m in BENCH["per_layer"] if m not in mine}
     assert {m["layer"] for m in mine} <= layers
     assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
+
+
+# --------------------------------------------------------------------------- #
+# PR 63, second round: the runtime's start of the chips leaves ``setup_s``
+# --------------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("run,want", [
+    ({"backend_start_s": 6.5, "setup_s": 16.5}, 6.5),
+    ({"backend_start_s": 0.0}, 0.0),
+    ({"setup_s": 16.5}, None),          # a parent's layers: left out
+    ({}, None)])
+def test_backend_start_is_what_run_py_handed(run, want):
+    got = reader("backend_start_s").reduce(run)
+    assert got == want and (want is None or isinstance(got, float))
+
+
+def test_backend_start_s_entry_is_every_cell_s():
+    entry = next(m for m in BENCH["per_layer"]
+                 if m["name"] == "backend_start_s")
+    assert entry == {"name": "backend_start_s", "unit": "s",
+                     "better": "lower", "source": "host_clock",
+                     "layer": "device", "moves": "setup_s"}
+    assert any(m["layer"] == "device" and m is not entry
+               for m in BENCH["per_layer"])
+
+
+def test_require_times_the_one_call_that_starts_the_backend(monkeypatch):
+    """``device.require`` clocks ``jax.devices()`` alone: not the import of
+    jax before it, nothing after it."""
+    import time
+    import types
+
+    import device as device_mod
+
+    class Dev:
+        platform, device_kind = "cpu", "cpu"
+
+    def devices():
+        time.sleep(0.05)
+        return [Dev()]
+
+    monkeypatch.setitem(__import__("sys").modules, "jax",
+                        types.SimpleNamespace(devices=devices))
+    monkeypatch.setattr(device_mod, "BACKEND_START_S", 0.0)
+    t = time.perf_counter()
+    info = device_mod.require(1, cpu_rehearsal=True)
+    whole = time.perf_counter() - t
+    assert info == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert 0.05 <= device_mod.BACKEND_START_S <= whole

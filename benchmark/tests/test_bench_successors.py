@@ -1,19 +1,29 @@
-"""One name per measurement (ISSUE 50): ``BENCHMARK.json``'s ``per_layer`` says
-each measurement once, under a successor name with a ``workloads`` list, and
-every value a cell reports under a successor equals, to the last bit, what
-the same ``layers`` dict gave under the cell's old name.
+"""One name per measurement (ISSUE 50, again ISSUE 63): ``BENCHMARK.json``'s
+``per_layer`` says each measurement once, under a successor name with a
+``workloads`` list, and every value a cell reports under a successor equals,
+to the last bit, what the same ``layers`` dict gave under the cell's old name.
 
 The ``layers`` are recorded: ``data/layers/<cell>.json.gz`` is what the
 runner handed the readers in one ``--cpu-tiny --trace 1`` rehearsal of the
 cell (``run.py --keep-layers``), with a v5e's peaks put in so that the
 rooflines and utilisations have something to divide by.
-``data/parent_values.json`` is what the PARENT's readers returned on those
-dicts (``parent_values.py``); where a copy of the parent is unpacked at
-``.parent/`` its readers are run again, live, and have to agree with the
-table. ``data/renames.json`` is the rename table: successor -> {cell: the
-name the cell reported it under before; null where the cell joins}."""
+``data/parent_values.json`` is what the readers of the cell's PARENT
+returned on those dicts (``parent_values.py``): for the eleven cells ISSUE 50
+folded, PR 50's parent; for the three ISSUE 63 folded (Granite, GLM, Xing4),
+PR 63's (``data/renames.json`` ``parents`` says which). Where a copy of a
+parent is unpacked at ``.parent/`` and lists a cell's metrics under the
+recorded names, its readers are run again, live, and have to agree with the
+table. ``data/renames.json`` ``renames`` is the rename table: successor ->
+{cell: the name the cell reported it under on its parent; null where the cell
+joins}. ``data/names_pr62.json`` is every cell's list of names on PR 63's
+parent.
+
+Every entry is found by NAME and every cell in a list by MEMBERSHIP: a later
+PR that appends a cell, a configuration and entries of its own turns nothing
+here red (``test_an_appended_cell_and_entry_turn_nothing_red``)."""
 
 import ast
+import copy
 import gzip
 import importlib
 import json
@@ -24,6 +34,7 @@ import sys
 import pytest
 
 from conftest import BENCH_DIR, ROOT
+from test_bench_run import STALLS     # the three cells of ISSUE 63 join them
 
 DATA = os.path.join(BENCH_DIR, "tests", "data")
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -33,15 +44,30 @@ with open(os.path.join(DATA, "renames.json")) as f:
 RENAMES, RETIRED = TABLE["renames"], TABLE["retired"]
 with open(os.path.join(DATA, "parent_values.json")) as f:
     PARENT_VALUES = json.load(f)
+with open(os.path.join(DATA, "names_pr62.json")) as f:
+    NAMES_PR62 = json.load(f)
 CELLS = [w["name"] for w in BENCH["workloads"]]
-PARENT = os.path.join(ROOT, ".parent", "benchmark")
-# per-layer metrics a cell, ISSUE 50's table
-COUNTS = dict(zip(CELLS, (39, 30, 39, 32, 38, 38, 42, 48, 48, 46, 41)))
+RECORDED = sorted(PARENT_VALUES)           # the cells with a record
+FOLDED_63 = ("granite_h.p1.pack8k", "glm_flash.e8of64.pack8k",
+             "xing4.e8of64.hc4")
+PARENT = os.path.join(ROOT, ".parent")
 SETUP_ENDS = ("setup_before_program_s", "setup_after_first_step_s")
+# entries younger than the eleven older cells' record (PRs 50 and 51): no
+# value of their parent to hold them to
+YOUNGER = set(SETUP_ENDS) | set(STALLS) | {"h2d_land_ms_per_batch",
+                                           "h2d_gb_per_s"}
+# what ``run.py`` takes out of ``setup_s`` since PR 63's second round: EVERY
+# cell reports it, and no record of a parent holds it
+BACKEND = "backend_start_s"
+YOUNGER.add(BACKEND)
 
 
 def applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
+
+
+def names_of(bench: dict, cell: str) -> list:
+    return [m["name"] for m in bench["per_layer"] if applies(m, cell)]
 
 
 def recorded(cell: str) -> dict:
@@ -51,65 +77,138 @@ def recorded(cell: str) -> dict:
 
 
 def old_name(metric: str, cell: str):
-    """The name under which ``cell`` reported ``metric`` on the parent;
-    None where it did not (a cell that joins, a new metric)."""
-    if metric in SETUP_ENDS:
+    """The name under which ``cell`` reported ``metric`` on its parent; None
+    where the table says that it joins."""
+    return RENAMES.get(metric, {}).get(cell, metric)
+
+
+def live_parent_values(cell: str):
+    """What ``.parent/``'s readers return on the cell's recorded layers, if
+    a parent is unpacked there and it is the one the record is of (it lists
+    the cell's metrics under the recorded names); else None."""
+    path = os.path.join(PARENT, "BENCHMARK.json")
+    if not os.path.exists(path):
         return None
-    return RENAMES[metric][cell] if metric in RENAMES else metric
+    with open(path) as f:
+        theirs = json.load(f)
+    if set(names_of(theirs, cell)) != set(PARENT_VALUES[cell]):
+        return None
+    live = subprocess.run(
+        [sys.executable, os.path.join(BENCH_DIR, "tests", "parent_values.py"),
+         os.path.join(PARENT, "benchmark"),
+         os.path.join(DATA, "layers", cell + ".json.gz"), cell],
+        capture_output=True, text=True, check=True).stdout
+    return json.loads(live.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_value_equals_the_parent_s_on_the_same_layers(cell):
+def check_cell(bench: dict, cell: str) -> dict:
+    """Every value ``cell`` reports under ``bench``'s names on its recorded
+    layers against its parent's under the old names; -> what joined and
+    what is younger than the record."""
     layers, want = recorded(cell), PARENT_VALUES[cell]
-    if os.path.isdir(os.path.join(PARENT, "layer_metrics")):
-        live = subprocess.run(
-            [sys.executable, os.path.join(BENCH_DIR, "tests",
-                                          "parent_values.py"), PARENT,
-             os.path.join(DATA, "layers", cell + ".json.gz"), cell],
-            capture_output=True, text=True, check=True).stdout
-        assert json.loads(live.strip().splitlines()[-1]) == want
-    mine = [m["name"] for m in BENCH["per_layer"] if applies(m, cell)]
-    assert len(mine) == COUNTS[cell]
-    read, joined = set(), []
-    for name in mine:
+    read, joined, younger = set(), [], []
+    for name in names_of(bench, cell):
         got = importlib.import_module(f"layer_metrics.{name}").reduce(layers)
         old = old_name(name, cell)
         if old is None:
             joined.append(name)
             assert got is not None, name     # a cell that joins reads it
-            continue
-        read.add(old)
-        assert got == want[old], (name, old)      # to the last bit
+        elif old not in want:
+            younger.append(name)             # nothing to hold it to
+        else:
+            read.add(old)
+            assert got == want[old], (name, old)      # to the last bit
     # nothing the parent reported in this cell went unread but the retired
     assert set(want) - read == {n for n in RETIRED if n in want}
     assert sum(want[old] is not None for old in read) >= len(read) - 5
-    assert set(joined) - set(SETUP_ENDS) == {
-        "ouro.loop4.pack8k": {"tokens_per_s_per_chip"},
-        "olmo_hybrid.p1.pack8k": {"tokens_per_s_per_chip",
-                                  "head_ms_per_step", "ffn_ms_per_step"},
-    }.get(cell, set())
+    return {"joined": set(joined), "younger": set(younger)}
 
 
-def test_the_rename_table_accounts_for_every_name_of_the_parent():
-    parent = {name for by_cell in PARENT_VALUES.values() for name in by_cell}
-    now = {m["name"] for m in BENCH["per_layer"]}
-    gone = parent - now
-    renamed = {old for by_cell in RENAMES.values()
-               for old in by_cell.values() if old}
-    assert gone == (renamed - now) | set(RETIRED)
-    assert now - parent == (set(RENAMES) - parent) | set(SETUP_ENDS)
-    assert len(parent) == 128 and len(now) == 76 <= 80
+JOINED = {
+    "ouro.loop4.pack8k": {"tokens_per_s_per_chip"},
+    "olmo_hybrid.p1.pack8k": {"tokens_per_s_per_chip", "head_ms_per_step",
+                              "ffn_ms_per_step"},
+    "granite_h.p1.pack8k": set(STALLS),
+    "glm_flash.e8of64.pack8k": set(STALLS) | {"held_assignment_share"},
+    "xing4.e8of64.hc4": set(STALLS) | {"held_assignment_share",
+                                       "tokens_per_s_per_chip"},
+}
+
+
+@pytest.mark.parametrize("cell", RECORDED)
+def test_every_value_equals_the_parent_s_on_the_same_layers(cell):
+    live = live_parent_values(cell)
+    assert live is None or live == PARENT_VALUES[cell]
+    found = check_cell(BENCH, cell)
+    assert found["joined"] == JOINED.get(cell, set())
+    assert found["younger"] <= ({BACKEND} if cell in FOLDED_63 else YOUNGER)
+
+
+@pytest.mark.parametrize("cell", sorted(NAMES_PR62))
+def test_a_cell_lists_what_it_listed_on_pr_63_s_parent(cell):
+    """The eleven older cells print exactly the names they printed before,
+    plus ``backend_start_s`` (every cell's) and nothing else; the three
+    folded ones each old name's successor and what they join."""
+    then, now = NAMES_PR62[cell], names_of(BENCH, cell)
+    assert len(set(now)) == len(now)
+    now.remove(BACKEND)
+    if cell not in FOLDED_63:
+        assert sorted(now) == sorted(then)
+        return
+    succ = {old: s for s, by in RENAMES.items()
+            for c, old in by.items() if c == cell and old}
+    assert set(now) == {succ.get(n, n) for n in then} | JOINED[cell]
+    assert len(now) == len(then) + len(JOINED[cell])
+    assert {n for n in then if n.startswith(("granite_", "glm_", "xing_"))} \
+        == set(succ)
+
+
+def test_the_rename_table_accounts_for_every_old_name():
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    assert len(by_name) == len(BENCH["per_layer"]) <= 128
     for successor, by_cell in RENAMES.items():
-        assert by_name[successor]["workloads"] == [
-            c for c in CELLS if c in by_cell], successor
+        listed = by_name[successor]["workloads"]      # found by name
+        assert set(by_cell) <= set(listed), successor     # by membership
+        assert listed == sorted(listed, key=CELLS.index), successor
+    old = {n for by_cell in RENAMES.values() for n in by_cell.values() if n}
+    # an old name is gone unless it is the successor's own
+    assert old & set(by_name) <= set(RENAMES)
+    assert not set(RETIRED) & set(by_name)
+    # ISSUE 63: all 38 names with a cell's prefix, 32 folded and 6 renamed
+    prefixed = {n for n in old
+                if n.startswith(("granite_", "glm_", "xing_"))}
+    assert len(prefixed) == 38 and not prefixed & set(by_name)
+    assert not [n for n in by_name
+                if n.startswith(("granite_", "glm_", "xing_"))]
+    assert RENAMES["mla_proj_ms_per_step"] == {
+        "glm_flash.e8of64.pack8k": "glm_mla_proj_ms_per_step",
+        "xing4.e8of64.hc4": "xing_mla_proj_ms_per_step"}
     for name in SETUP_ENDS:
         assert by_name[name] == {
             "name": name, "unit": "s", "better": "lower",
             "source": {"setup_before_program_s": "program_span",
                        "setup_after_first_step_s": "host_clock"}[name],
             "layer": "entry", "moves": "setup_s"}
-    assert [m["name"] for m in BENCH["per_layer"][-2:]] == list(SETUP_ENDS)
+
+
+def test_an_appended_cell_and_entry_turn_nothing_red():
+    """What the next ``model_config`` PR does to the file: a cell, a
+    configuration and entries of its own at the lists' ends. Every rule
+    above holds on that file as on this one."""
+    bench = copy.deepcopy(BENCH)
+    bench["workloads"].append(dict(bench["workloads"][-1], name="dummy.c1",
+                                   config="dummy", traffic="dummy_mix"))
+    bench["configs"].append(dict(bench["configs"][-1], name="dummy"))
+    for name in ("dummy_attention_ms_per_step", "dummy_own_ms_per_step"):
+        bench["per_layer"].append(
+            {"name": name, "unit": "ms", "better": "lower",
+             "source": "device_trace", "layer": "kernels",
+             "moves": "mfu_required", "workloads": ["dummy.c1"]})
+    for cell in RECORDED:
+        assert names_of(bench, cell) == names_of(BENCH, cell)
+        check_cell(bench, cell)
+    assert names_of(bench, "dummy.c1")[-2:] == [
+        "dummy_attention_ms_per_step", "dummy_own_ms_per_step"]
 
 
 def test_entries_follow_the_contract():

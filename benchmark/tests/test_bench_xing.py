@@ -19,21 +19,23 @@ import pytest
 import flops_xing
 import tokengen
 from conftest import BENCH_DIR, ROOT
-from layer_metrics import (xing_attention_glue_ms_per_step,
-                           xing_attention_ms_per_step,
-                           xing_flash_attention_roofline,
-                           xing_hc_map_ms_per_step, xing_hc_ms_per_step,
-                           xing_hc_res_err, xing_hc_stream_roofline,
-                           xing_head_ms_per_step,
-                           xing_held_dropped_assignments,
-                           xing_held_load_max_over_mean,
-                           xing_held_moe_flops_util,
-                           xing_held_moe_ms_per_step,
-                           xing_mla_proj_ms_per_step,
-                           xing_recompute_ms_per_step,
-                           xing_router_ms_per_step,
-                           xing_shared_expert_ms_per_step)
-from test_bench_run import BENCH, declared, run_cell
+from layer_metrics import (attention_glue_ms_per_step,
+                           attention_ms_per_step,
+                           flash_attention_roofline,
+                           hc_map_ms_per_step, hc_ms_per_step,
+                           hc_res_err, hc_stream_roofline,
+                           head_ms_per_step,
+                           held_assignment_share,
+                           held_dropped_assignments,
+                           held_load_max_over_mean,
+                           held_moe_flops_util,
+                           held_moe_ms_per_step,
+                           mla_proj_ms_per_step,
+                           recompute_ms_per_step,
+                           router_ms_per_step,
+                           shared_expert_ms_per_step,
+                           tokens_per_s_per_chip)
+from test_bench_run import BENCH, declared, entries_of, run_cell
 
 CELL = "xing4.e8of64.hc4"
 NAME = "xing4_0_29b_a4b"
@@ -327,29 +329,31 @@ def small_run(scopes=SCOPES, lm=True):
 
 READERS = [
     # every hc scope: (24 + 6 + 10 + 4 + 2) ns / 2 steps
-    (xing_hc_ms_per_step, 23e-6),
-    (xing_hc_map_ms_per_step, 12e-6),
+    (hc_ms_per_step, 23e-6),
+    (hc_map_ms_per_step, 12e-6),
     # bytes-bound: 1200 / 1e11 = 12 ns against (24 + 6 + 10) / 2 ns
-    (xing_hc_stream_roofline, 100 * 12e-9 / 20e-9),
-    (xing_hc_res_err, 4e-5),
+    (hc_stream_roofline, 100 * 12e-9 / 20e-9),
+    (hc_res_err, 4e-5),
     # (10 + 6 + 40 + 20 + 2 + 4) ns / 2 steps
-    (xing_attention_ms_per_step, 41e-6),
+    (attention_ms_per_step, 41e-6),
     # flops-bound: 6e3 / 1e12 = 6 ns against (40 + 20) / 2 ns of kernel
-    (xing_flash_attention_roofline, 100 * 6e-9 / 30e-9),
-    (xing_attention_glue_ms_per_step, 6e-6),      # (6 + 2 + 4) / 2
-    (xing_mla_proj_ms_per_step, 5e-6),
-    (xing_head_ms_per_step, 7e-6),                # (12 + 2) / 2
-    (xing_held_moe_ms_per_step, 20e-6),
+    (flash_attention_roofline, 100 * 6e-9 / 30e-9),
+    (attention_glue_ms_per_step, 6e-6),      # (6 + 2 + 4) / 2
+    (mla_proj_ms_per_step, 5e-6),
+    (head_ms_per_step, 7e-6),                # (12 + 2) / 2
+    (held_moe_ms_per_step, 20e-6),
     # the TRACED steps' 0.25 x 1000 assignments x 10 FLOPs over 20 ns x 1e12
-    (xing_held_moe_flops_util, 100 * 2.5e3 / (20e-9 * 1e12)),
-    (xing_shared_expert_ms_per_step, 8e-6),
-    (xing_router_ms_per_step, 4e-6),
-    (xing_held_load_max_over_mean, 1.3),
-    (xing_held_dropped_assignments, 0.0),
-    (xing_recompute_ms_per_step, 18e-6),          # (6 + 20 + 10) ns / 2
+    (held_moe_flops_util, 100 * 2.5e3 / (20e-9 * 1e12)),
+    (shared_expert_ms_per_step, 8e-6),
+    (router_ms_per_step, 4e-6),
+    (held_assignment_share, 13.0),           # the window's displays, in %
+    (held_load_max_over_mean, 1.3),
+    (held_dropped_assignments, 0.0),
+    (recompute_ms_per_step, 18e-6),          # (6 + 20 + 10) ns / 2
+    (tokens_per_s_per_chip, 10 * 1 * 8192 / 4.0),
 ]
-COUNTERS = (xing_held_load_max_over_mean, xing_held_dropped_assignments,
-            xing_hc_res_err)
+COUNTERS = (held_assignment_share, held_load_max_over_mean,
+            held_dropped_assignments, hc_res_err, tokens_per_s_per_chip)
 
 
 @pytest.mark.parametrize("reader, want", READERS)
@@ -362,13 +366,13 @@ def test_each_reader_finds_nothing_on_a_program_without_it(reader):
     """A program or a run without what the reader reads: no map, no ``lm``
     section, no trace — None, and nothing raised."""
     assert reader.reduce(small_run(scopes=None, lm=False)) is None
-    if reader is not xing_recompute_ms_per_step:  # reads the map alone
+    if reader is not recompute_ms_per_step:  # reads the map alone
         assert reader.reduce(small_run(lm=False)) is None
     if reader not in COUNTERS:                    # those need no trace
         assert reader.reduce(dict(small_run(), trace=None)) is None
     # the program's map without this model's scopes (the parent's): a
     # roofline finds no time under its pattern and reads nothing
-    if reader in (xing_flash_attention_roofline, xing_hc_stream_roofline):
+    if reader in (flash_attention_roofline, hc_stream_roofline):
         bare = small_run(scopes={"ops": {"qb.1": "l0_q|fwd"},
                                  "types": {"l0_q": "INNER_PRODUCT"}})
         assert reader.reduce(bare) is None
@@ -641,23 +645,23 @@ def test_cpu_tiny_rehearsal_of_the_xing_cell(trace):
         # all of the cell's per-layer metrics but those that need a chip's
         # peaks, its memory statistics or its Pallas kernels
         assert names == declared("per_layer", CELL) - {
-            "busy_flops_util", "peak_hbm_gb", "xing_flash_attention_roofline",
-            "xing_held_moe_flops_util", "xing_hc_stream_roofline"}
+            "busy_flops_util", "peak_hbm_gb", "flash_attention_roofline",
+            "held_moe_flops_util", "hc_stream_roofline"}
         m = {k: v["value"] for k, v in line["metrics"].items()}
         assert m["scope_coverage"] >= 95.0
-        parts = ("xing_attention_ms_per_step", "xing_held_moe_ms_per_step",
-                 "xing_shared_expert_ms_per_step", "xing_router_ms_per_step",
-                 "xing_head_ms_per_step", "xing_hc_ms_per_step")
+        parts = ("attention_ms_per_step", "held_moe_ms_per_step",
+                 "shared_expert_ms_per_step", "router_ms_per_step",
+                 "head_ms_per_step", "hc_ms_per_step")
         assert all(m[k] > 0 for k in parts)
-        assert m["xing_attention_glue_ms_per_step"] \
-            + m["xing_mla_proj_ms_per_step"] \
-            == pytest.approx(m["xing_attention_ms_per_step"])  # all dense
+        assert m["attention_glue_ms_per_step"] \
+            + m["mla_proj_ms_per_step"] \
+            == pytest.approx(m["attention_ms_per_step"])  # all dense
         assert sum(m[k] for k in parts) \
             < m["fwd_ms_per_step"] + m["bwd_ms_per_step"]
-        assert 0 < m["xing_hc_map_ms_per_step"] < m["xing_hc_ms_per_step"]
-        assert m["xing_recompute_ms_per_step"] < m["bwd_ms_per_step"]
-        assert m["xing_held_dropped_assignments"] == 0.0
-        assert m["xing_hc_res_err"] == stream["res_err_max"]
+        assert 0 < m["hc_map_ms_per_step"] < m["hc_ms_per_step"]
+        assert m["recompute_ms_per_step"] < m["bwd_ms_per_step"]
+        assert m["held_dropped_assignments"] == 0.0
+        assert m["hc_res_err"] == stream["res_err_max"]
     else:
         assert names == declared("end_to_end", CELL) - {"mfu_required"}
         assert line["metrics"]["images_per_s_per_chip"]["value"] == \
@@ -786,17 +790,8 @@ def test_new_entries_follow_the_contract():
         "num_nextn_predict_layers"]
     assert config["source"] == CFG["source"] \
         and config["file"] == f"benchmark/configs/{NAME}.json"
-    mine = [m for m in BENCH["per_layer"]
-            if m.get("workloads") == [CELL]]
-    # every reader tested above is declared for this cell alone, and every
-    # metric declared for this cell alone has its reader tested above
-    assert {r.__name__.rsplit(".", 1)[-1] for r, _ in READERS} \
-        == {m["name"] for m in mine} and len(mine) == 16
-    # no accepted entry was edited to take the cell in: with sixteen of its
-    # own the benchmark stands at its cap of 128, so the cell reports no
-    # tokens a second (images_per_s_per_chip x 8192 says the same)
-    assert not [m["name"] for m in BENCH["per_layer"]
-                if CELL in m.get("workloads", ()) and m not in mine]
+    # ISSUE 63 made the room PR 60 lacked: its tokens a second are among them
+    mine = entries_of(CELL, [r for r, _ in READERS])
     for text in (cell["why"], config["why"], config["source"],
                  *(m["layer"] for m in mine)):
         assert 1 <= len(text) <= 200 and text.isascii() \
