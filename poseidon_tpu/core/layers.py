@@ -339,16 +339,26 @@ class RMSNormLayer(Layer):
     """x * rsqrt(mean(x^2) + eps) * g over the last axis; gain only (filled
     with ones), statistics in f32. With ``num_heads`` the last axis is that
     many heads side by side: each is normalised over its own dims and ONE
-    gain of a head's width serves them all (a per-head QK-norm)."""
+    gain of a head's width serves them all (a per-head QK-norm). With
+    ``num_groups`` it is that many groups of channels side by side: each is
+    normalised over its own channels under a gain of the WHOLE width
+    (Mamba-2's gated norm with more than one group of B / C)."""
     TYPE = "RMS_NORM"
 
     def setup(self, bottom_shapes):
         ones = FillerParameter(type="constant", value=1.0)
         width = bottom_shapes[0][-1]
         self.heads = self.lp.rms_norm_param.num_heads
+        self.groups = self.lp.rms_norm_param.num_groups
         if self.heads < 0 or (self.heads and width % self.heads):
             raise ValueError(f"{self.name}: {self.heads} heads do not split "
                              f"a last axis of {width}")
+        if self.groups < 0 or (self.groups and (
+                width % self.groups or self.heads)):
+            raise ValueError(
+                f"{self.name}: {self.groups} groups do not split a last "
+                f"axis of {width}, or num_heads is set beside them (a gain "
+                f"a head's width OR one of the whole width)")
         self.params = [self._param(
             "g", (width // self.heads if self.heads else width,), ones, 0)]
         return [bottom_shapes[0]]
@@ -357,8 +367,10 @@ class RMSNormLayer(Layer):
         from ..models.transformer import rms_norm
         g = _tap_all(ctx, self.name, params)["g"]
         x, eps = bottoms[0], self.lp.rms_norm_param.eps
-        if self.heads:
-            split = x.reshape(x.shape[:-1] + (self.heads, -1))
+        if self.heads or self.groups:
+            split = x.reshape(x.shape[:-1] + (self.heads or self.groups, -1))
+            if self.groups:             # the gain's own slice a group
+                g = g.reshape(split.shape[-2:])
             return [rms_norm(split, g, eps).reshape(x.shape)]
         return [rms_norm(x, g, eps)]
 
@@ -522,7 +534,10 @@ class MoELayer(Layer):
     share of all assignments that fell on a held expert, and the share of
     the held experts' live rows' gate pre-activations that are <= 0 (what a
     ReLU gate zeroes). An expert is down(act(gate x) * (up x)),
-    ``activation`` "silu" (the default) or "relu"."""
+    ``activation`` "silu" (the default) or "relu"; with ``activation``
+    "relu2" it is UNGATED, down(relu(up x)^2): the layer then has no gate
+    blob (two stacks, up and down), and the fourth scalar counts the up
+    pre-activations <= 0 (what the squared ReLU zeroes)."""
     TYPE = "MOE"
 
     def setup(self, bottom_shapes):
@@ -531,10 +546,13 @@ class MoELayer(Layer):
         if not 0 < mp.top_k <= mp.num_experts or mp.expert_width <= 0:
             raise ValueError(f"{self.name}: moe_param needs num_experts >= "
                              f"top_k > 0 and expert_width")
-        from ..models.moe import EXPERT_ACTS
+        from ..models.moe import EXPERT_ACTS, UNGATED_ACT
         if mp.activation not in EXPERT_ACTS:
-            raise ValueError(f"{self.name}: activation {mp.activation!r} is "
-                             f"none of {EXPERT_ACTS}")
+            raise ValueError(
+                f"{self.name}: activation {mp.activation!r} is none of "
+                f"{EXPERT_ACTS} (silu, relu: a gated unit of three stacks; "
+                f"relu2: the ungated squared ReLU of two)")
+        self.ungated = mp.activation == UNGATED_ACT
         self.gated = mp.router_hidden > 0 or mp.score_func == "sigmoid" \
             or len(bottom_shapes) == 2
         self.n_fixed = 1 if self.gated else 3
@@ -562,10 +580,11 @@ class MoELayer(Layer):
         self.params = [] if self.gated else [
             self._param("router", (e, d), mp.weight_filler, 0)]
         at = len(self.params)
+        stacks = ([] if self.ungated else [("gate", (g, f, d))]) \
+            + [("up", (g, f, d)), ("down", (g, d, f))]
         self.params += [
-            self._param("gate", (g, f, d), mp.weight_filler, at),
-            self._param("up", (g, f, d), mp.weight_filler, at + 1),
-            self._param("down", (g, d, f), mp.weight_filler, at + 2)]
+            self._param(name, shape, mp.weight_filler, at + i)
+            for i, (name, shape) in enumerate(stacks)]
         return [(n, s, d)] + [()] * (len(self.lp.top) - 1)
 
     def default_loss_weight(self) -> float:
@@ -581,12 +600,12 @@ class MoELayer(Layer):
         how = (mp.top_k, mp.held_first, mp.activation, self.gate_zeros)
         if self.gated:
             y, sizes = moe_gated(
-                flat, bottoms[1].reshape(n * s, mp.num_experts), p["gate"],
-                p["up"], p["down"], *how)
+                flat, bottoms[1].reshape(n * s, mp.num_experts),
+                p.get("gate"), p["up"], p["down"], *how)
             losses = []
         else:
             y, lb, z, sizes = moe_dropless(
-                flat, p["router"], p["gate"], p["up"], p["down"], *how)
+                flat, p["router"], p.get("gate"), p["up"], p["down"], *how)
             losses = [lb, z]
         y, *zero_share = y if self.gate_zeros else (y,)
         tops = [y.reshape(n, s, d)] + losses
@@ -618,6 +637,8 @@ class MoELayer(Layer):
             arm += f"; held rows: chunks of {held[0]} of {held[2]}"
         if self.lp.moe_param.activation != "silu":
             arm += f"; act={self.lp.moe_param.activation}"
+        if self.ungated:
+            arm += "; ungated"
         return "grouped_matmul", arm, "sorted by expert, dropless"
 
     def stats_sections(self, bottom_shapes, itemsize):
@@ -1109,27 +1130,30 @@ class KDAScanLayer(Layer):
 
 class SSDScanLayer(Layer):
     """Mamba-2's selective scan. Bottoms x (N, S, H P), dt and a = dt A
-    (N, S, H) f32 (KDA_DECAY's third and first tops), B and C (N, S, N_state)
-    that ALL ``num_heads`` heads share -> y (N, S, H P): per head a state
-    (P, N_state), zero at a sequence's start,
+    (N, S, H) f32 (KDA_DECAY's third and first tops), B and C
+    (N, S, G N_state): ``num_groups`` (G; 1, the default, is what every net
+    had) groups side by side, head h of the ``num_heads`` reading group
+    h // (H / G) -> y (N, S, H P): per head a state (P, N_state), zero at a
+    sequence's start,
     H_t = exp(a_t) H_{t-1} + dt_t x_t B_t^T, y_t = H_t C_t + D x_t
     (``ops/ssd.ssd_scan``: chunks of the sequence, one C B^T grid a chunk
-    for all heads, f32 state; which arm runs follows from the shape and the
-    backend, and ``Net`` logs it). Blob: D (H,), the skip, filled with
-    ones. Nothing resets the state inside a sequence."""
+    for a group's heads, f32 state; which arm runs follows from the shape
+    and the backend, and ``Net`` logs it). Blob: D (H,), the skip, filled
+    with ones. Nothing resets the state inside a sequence."""
     TYPE = "SSD_SCAN"
 
     def setup(self, bottom_shapes):
-        h = self.lp.kda_param.num_heads
+        h, g = self.lp.kda_param.num_heads, self.lp.kda_param.num_groups
         if len(bottom_shapes) != 5:
             raise ValueError(f"{self.name}: SSD_SCAN takes x, dt, a, B, C")
         x, dt, a, b, c = (tuple(t) for t in bottom_shapes)
         _split_heads(self.name, self.TYPE, x, h)
         if dt != x[:2] + (h,) or a != dt or b != c or len(b) != 3 \
-                or b[:2] != x[:2]:
+                or b[:2] != x[:2] or g < 1 or h % g or b[2] % g:
             raise ValueError(
                 f"{self.name}: SSD_SCAN takes x (N, S, H P), dt and a "
-                f"(N, S, {h}) and B, C of one (N, S, N_state) shape; got "
+                f"(N, S, {h}) and B, C of one (N, S, G N_state) shape, "
+                f"num_groups {g} dividing the heads and B's width; got "
                 f"{bottom_shapes}")
         self.params = [self._param(
             "D", (h,), FillerParameter(type="constant", value=1.0), 0)]
@@ -1138,31 +1162,42 @@ class SSDScanLayer(Layer):
     def apply(self, params, bottoms, ctx):
         from ..ops.ssd import ssd_scan
         x, dt, a, b, c = bottoms
-        heads = x.reshape(x.shape[:2] + (self.lp.kda_param.num_heads, -1))
+        kp = self.lp.kda_param
+        heads = x.reshape(x.shape[:2] + (kp.num_heads, -1))
+        if kp.num_groups > 1:
+            b, c = (t.reshape(t.shape[:2] + (kp.num_groups, -1))
+                    for t in (b, c))
         y = ssd_scan(heads, dt, a, b, c,
                      _tap_all(ctx, self.name, params)["D"])
         return [y.reshape(x.shape).astype(x.dtype)]
 
+    def _widths(self, bottom_shapes):   # heads, P, N_state, groups
+        kp = self.lp.kda_param
+        return (kp.num_heads, bottom_shapes[0][2] // kp.num_heads,
+                bottom_shapes[3][2] // kp.num_groups, kp.num_groups)
+
     def kernel_route(self, bottom_shapes, itemsize):
         from ..ops.ssd import ssd_route
-        h = self.lp.kda_param.num_heads
-        _, s, w = bottom_shapes[0]
+        h, p, n_state, g = self._widths(bottom_shapes)
         # the note names the arm and, chunked on a shape the kernels
-        # refuse, the reason
-        return "ssd_scan", ssd_route(s, h, w // h, bottom_shapes[3][2],
-                                     itemsize)[1], ""
+        # refuse, the reason; with more than one group, ``groups=<G>``
+        return "ssd_scan", ssd_route(bottom_shapes[0][1], h, p, n_state,
+                                     itemsize, g)[1], ""
 
     def stats_sections(self, bottom_shapes, itemsize):
         from ..ops import ssd
-        # a state (P, N_state) a head, B and C shared
-        h = self.lp.kda_param.num_heads
-        (n, s, w), n_state = bottom_shapes[0], bottom_shapes[3][2]
-        chunk = ssd.scan_chunk(s, h, w // h, n_state)
-        return {"recurrent_state": {
-            "heads": h, "d_k": n_state, "d_v": w // h, "chunk": chunk or 1,
+        # a state (P, N_state) a head, B and C shared by a group's heads
+        n, s, _ = bottom_shapes[0]
+        h, p, n_state, g = self._widths(bottom_shapes)
+        chunk = ssd.scan_chunk(s, h, p, n_state, g)
+        facts = {
+            "heads": h, "d_k": n_state, "d_v": p, "chunk": chunk or 1,
             "chunks": s // (chunk or 1),
-            "saved_state_bytes": ssd.state_bytes(n, s, h, w // h, n_state),
-            "decay": "head"}}
+            "saved_state_bytes": ssd.state_bytes(n, s, h, p, n_state, g),
+            "decay": "head"}
+        if g > 1:                       # one group: unsaid, as it was
+            facts["groups"] = g
+        return {"recurrent_state": facts}
 
 
 class SiLUGateLayer(Layer):

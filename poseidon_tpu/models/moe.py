@@ -299,12 +299,31 @@ def _combine(out, order, weights, dtype):
     return y.astype(dtype)
 
 
-EXPERT_ACTS = ("silu", "relu")
+# What an expert is, by its ``act``: "silu" / "relu", the gated unit
+# down(act(gate x) * (up x)) of three stacks; "relu2", the UNGATED squared
+# ReLU down(relu(up x)^2) of two (``gate`` is None: Nemotron-H's expert).
+EXPERT_ACTS = ("silu", "relu", "relu2")
+UNGATED_ACT = "relu2"
 
 
 def _act(a, act: str):
-    """The gate's activation in the experts' gated unit: act(a) * b."""
+    """The activation on an expert's pre-activation ``a``: the gate's in a
+    gated unit (act(a) * b), the whole unit's where there is no gate."""
+    if act == UNGATED_ACT:
+        return jnp.square(jax.nn.relu(a))
     return jax.nn.silu(a) if act == "silu" else jax.nn.relu(a)
+
+
+def _hidden(grouped, xs, gate, up, act: str):
+    """(a, h) of rows ``xs`` in the straight-line arms: the pre-activation
+    the activation reads and the unit's hidden rows, ``grouped(rows, w)``
+    being the arm's grouped matmul. With a gate a = gate x and h = act(a) *
+    (up x); without (``gate`` None) a = up x and h = act(a)."""
+    if gate is None:
+        a = grouped(xs, up)
+        return a, _act(a, act)
+    a = grouped(xs, gate)
+    return a, _act(a, act) * grouped(xs, up)
 
 
 def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
@@ -320,7 +339,7 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
     are summed with its weights.
 
     The stacks hold experts ``held_first .. held_first + G - 1``, G =
-    ``gate.shape[0]``. G = E is a layer that owns every expert it routes
+    ``up.shape[0]``. G = E is a layer that owns every expert it routes
     to. G < E is one rank's share of an expert-parallel layer: assignments
     to an absent expert sort behind the held ones, lie outside every group
     of the grouped matmuls (no work) and add ZERO to y, so that the shares
@@ -341,17 +360,25 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
     code (``_held_rows``): a rule of the shapes, as the chunk is.
 
     An expert is down(act(gate x) * (up x)), ``act`` one of
-    ``EXPERT_ACTS``. With ``gate_zeros`` the result is (y, the share of
-    the held experts' LIVE rows' gate pre-activations that are <= 0, an f32
-    scalar without a gradient): what a ReLU gate zeroes, counted where the
-    pre-activation is at hand."""
+    ``EXPERT_ACTS``; with ``act`` "relu2" it has NO gate (``gate`` None):
+    down(relu(up x)^2), through every arm below (``_hidden``; the held
+    arm's pullback then has d up = 2 relu(a) d h on the live rows and no
+    d gate). With ``gate_zeros`` the result is (y, the share of the held
+    experts' LIVE rows' pre-activations — the gate's, or up's where there is
+    no gate — that are <= 0, an f32 scalar without a gradient): what a ReLU
+    zeroes, counted where the pre-activation is at hand."""
+    if (gate is None) != (act == UNGATED_ACT):
+        raise ValueError(
+            f"expert_ffn: act {act!r} of {EXPERT_ACTS} "
+            + ("is the ungated expert's and takes no gate stack"
+               if gate is not None else "is a gated unit's and needs one"))
     top_k = weights.shape[1]
-    n_exp, n_held = sizes.shape[0], gate.shape[0]
+    n_exp, n_held = sizes.shape[0], up.shape[0]
     if n_held == n_exp:
         order = jnp.argsort(flat_e, stable=True)    # assignments by expert
         xs = x[order // top_k]                      # (T*k, D) sorted rows
-        a = _grouped(xs, gate, sizes)
-        h = _act(a, act) * _grouped(xs, up, sizes)
+        a, h = _hidden(lambda rows, w: _grouped(rows, w, sizes), xs, gate,
+                       up, act)
         y = _combine(_grouped(h, down, sizes), order, weights, x.dtype)
         out = (y, jnp.sum(a <= 0, dtype=jnp.int32)) if gate_zeros else y
     else:
@@ -371,7 +398,7 @@ def expert_ffn(x: jax.Array, weights: jax.Array, flat_e: jax.Array,
     # every arm counts; the live rows are the held experts' assignments
     y, zeros = out
     return y, zeros.astype(jnp.float32) / jnp.maximum(
-        jnp.sum(sizes).astype(jnp.float32) * gate.shape[1], 1.0)
+        jnp.sum(sizes).astype(jnp.float32) * up.shape[1], 1.0)
 
 
 def held_rows_loop(rows: int, chunk: int) -> bool:
@@ -433,8 +460,7 @@ def _held_rows(x, weights, here, order, sizes, gate, up, down,
 
     tok = order // top_k
     xs = x[tok]                                     # (T*k, D)
-    a = grouped(xs, gate)
-    h = _act(a, act) * grouped(xs, up)
+    a, h = _hidden(grouped, xs, gate, up, act)
     out = grouped(h, down)                          # (T*k, D)
     weights = weights * here.reshape(t, top_k)
     y = _combine(out, order, weights, x.dtype)
@@ -448,7 +474,8 @@ def _chunk(i, chunk, act, x, weights, order, sizes, ends, gate_t, up_t):
     [i P, i P + P) of the sorted assignments ``order`` (padded to a whole
     number of chunks), the held experts' groups clipped to them (``ends``:
     the running sum of ``sizes``), and x's rows through ``gate_t`` and
-    ``up_t`` (G, D, F), already in the compute dtype. Returns (grouped,
+    ``up_t`` (G, D, F), already in the compute dtype (``gate_t`` None: no
+    gate, ``b`` None). Returns (grouped,
     groups, tok, head, xs, a, b, h, live): ``grouped(rows, w)`` is this
     chunk's grouped matmul with w (G, K, N); rows at or past the live count
     (``live`` (P, 1) false) belong to no expert, and what a grouped matmul
@@ -465,7 +492,10 @@ def _chunk(i, chunk, act, x, weights, order, sizes, ends, gate_t, up_t):
             jnp.where(live, rows, 0), w, groups, precision=prec), 0)
 
     tok = head // weights.shape[1]
-    xs = x[tok].astype(gate_t.dtype)                # (P, D)
+    xs = x[tok].astype(up_t.dtype)                  # (P, D)
+    if gate_t is None:
+        a, b = grouped(xs, up_t), None
+        return grouped, groups, tok, head, xs, a, b, _act(a, act), live
     a, b = grouped(xs, gate_t), grouped(xs, up_t)
     return grouped, groups, tok, head, xs, a, b, _act(a, act) * b, live
 
@@ -523,8 +553,9 @@ def _held_chunks_fwd(chunk, cdtype, act, gate_zeros, x, weights, order,
     on_mxu = held_sum_on_mxu(x.shape[1])
     order, ends, n = _trips(order, sizes, chunk)
     # the stacks' casts and transposes are made once a pass, not once a trip
-    gate_t, up_t, down_t = (jnp.swapaxes(w.astype(cdtype), 1, 2)
-                            for w in (gate, up, down))
+    gate_t, up_t, down_t = (
+        None if w is None else jnp.swapaxes(w.astype(cdtype), 1, 2)
+        for w in (gate, up, down))
 
     def trip(i, y):
         grouped, _, tok, head, _, a, _, h, live = _chunk(
@@ -556,8 +587,10 @@ def _held_chunks_bwd(chunk, cdtype, act, x, weights, order, sizes, gate, up,
     f32 = jnp.float32
     on_mxu = held_sum_on_mxu(x.shape[1])
     order, ends, n = _trips(order, sizes, chunk)
-    gate_c, up_c, down_c = (w.astype(cdtype) for w in (gate, up, down))
-    gate_t, up_t = jnp.swapaxes(gate_c, 1, 2), jnp.swapaxes(up_c, 1, 2)
+    gate_c, up_c, down_c = (None if w is None else w.astype(cdtype)
+                            for w in (gate, up, down))
+    gate_t, up_t = (None if w is None else jnp.swapaxes(w, 1, 2)
+                    for w in (gate_c, up_c))
     prec = matmul_precision()
 
     def trip(i, carry):
@@ -581,34 +614,42 @@ def _held_chunks_bwd(chunk, cdtype, act, x, weights, order, sizes, gate, up,
             jnp.sum(h.astype(f32) * u, axis=-1))
         ddown = ddown + dw((dyr.astype(f32) * w).astype(cdtype), h)
         dh = u * w
-        a32, b32 = a.astype(f32), b.astype(f32)
-        if act == "silu":
-            s = jax.nn.sigmoid(a32)
-            da = (dh * b32 * s * (1 + a32 * (1 - s))).astype(cdtype)
-            db = (dh * a32 * s).astype(cdtype)
-        else:                           # relu: nothing passes a gate <= 0
-            da = jnp.where(a32 > 0, dh * b32, 0).astype(cdtype)
-            db = (dh * jnp.maximum(a32, 0)).astype(cdtype)
-        dgate, dup = dgate + dw(da, xs), dup + dw(db, xs)
-        dxs = (grouped(da, gate_c).astype(f32)
-               + grouped(db, up_c).astype(f32))
+        if gate is None:                # relu(a)^2: d a = 2 relu(a) d h
+            da = (2 * jnp.maximum(a.astype(f32), 0) * dh).astype(cdtype)
+            dup = dup + dw(da, xs)
+            dxs = grouped(da, up_c).astype(f32)
+        else:
+            a32, b32 = a.astype(f32), b.astype(f32)
+            if act == "silu":
+                s = jax.nn.sigmoid(a32)
+                da = (dh * b32 * s * (1 + a32 * (1 - s))).astype(cdtype)
+                db = (dh * a32 * s).astype(cdtype)
+            else:                       # relu: nothing passes a gate <= 0
+                da = jnp.where(a32 > 0, dh * b32, 0).astype(cdtype)
+                db = (dh * jnp.maximum(a32, 0)).astype(cdtype)
+            dgate, dup = dgate + dw(da, xs), dup + dw(db, xs)
+            dxs = (grouped(da, gate_c).astype(f32)
+                   + grouped(db, up_c).astype(f32))
         dx = (dx + _tile_sum(dxs, None, tok, live, x.shape[0]) if on_mxu
               else dx.at[tok].add(dxs))
         return dx, dweights, dgate, dup, ddown
 
     dx, dweights, dgate, dup, ddown = lax.fori_loop(0, n, trip, (
         jnp.zeros(x.shape, f32), jnp.zeros(order.shape, f32),
-        *(jnp.zeros(w.shape, f32) for w in (gate, up, down))))
+        *(None if w is None else jnp.zeros(w.shape, f32)
+          for w in (gate, up, down))))
     # a stack's gradient is rounded to the compute dtype, as the cotangent
     # of its cast is, and behind a barrier: the narrow copy is then what
     # lives until the update, where the compiler would keep the f32 sums
     # and round them there (0.8 GB of Trinity-Mini's step: PR 43)
     narrow = lax.optimization_barrier(tuple(
-        g.astype(cdtype) for g in (dgate, dup, ddown)))
+        None if g is None else g.astype(cdtype)
+        for g in (dgate, dup, ddown)))
     return (dx.astype(x.dtype),
             dweights[:weights.size].reshape(weights.shape)
             .astype(weights.dtype),
-            *(g.astype(w.dtype) for g, w in zip(narrow, (gate, up, down))))
+            *(None if g is None else g.astype(w.dtype)
+              for g, w in zip(narrow, (gate, up, down))))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 8, 9))
@@ -658,7 +699,8 @@ def moe_dropless(x: jax.Array, router: jax.Array, gate: jax.Array,
     token is computed by all k of its experts whatever the load.
 
     router (E, D) scores all E experts; gate, up (G, F, D) and down
-    (G, D, F) are the G experts held here (``expert_ffn``; G = E: all).
+    (G, D, F) are the G experts held here (``expert_ffn``; G = E: all;
+    ``gate`` None with ``act`` "relu2", the ungated expert).
     Returns (y (T, D), load-balancing loss, z loss, assignments per expert
     (E,) int32); y is ``expert_ffn``'s (y, share) pair with ``gate_zeros``."""
     n_exp = router.shape[0]

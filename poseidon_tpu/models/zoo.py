@@ -14,9 +14,11 @@ builder with no arguments writes its whole published model; the committed
 ``smallthinker``, ``olmo_hybrid``, ``granite_hybrid``, ``glm_flash``
 (GLM-4.7-Flash: latent attention whose rotary part rotates in every layer
 and a multi-token-prediction module that shares the embedding and the
-head) and ``xing4`` (Xing4.0-29B-A4B: ``glm_flash``'s block on a residual
+head), ``xing4`` (Xing4.0-29B-A4B: ``glm_flash``'s block on a residual
 stream of four hidden states, manifold-constrained hyper-connections, with
-YaRN's rotary frequencies).
+YaRN's rotary frequencies) and ``nemotron_h`` (NVIDIA-Nemotron-3-Nano-30B-A3B:
+one sub-layer a layer by a pattern string, Mamba-2 with eight groups of
+B / C, ungated squared-ReLU experts).
 """
 
 from __future__ import annotations
@@ -1845,6 +1847,210 @@ def granite_hybrid(batch: int = 1,
     norm("final_norm", h, "xf")
     scaled("lm_scale", "xf", "xs", 1.0 / logits_scaling)
     proj("lm_head", "xs", "logits", vocab_rows, tied)
+    net.append(LayerParameter(
+        name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
+        top=["nll"]))
+    # the mean over positions: the exit-weighted loss of ONE pass
+    net.append(LayerParameter(
+        name="lm_loss", type="EXIT_LOSS", bottom=["nll"], top=["lm_loss"]))
+    return NetParameter(name=name, layers=net)
+
+
+NEMOTRON_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def nemotron_h(batch: int = 1, pattern: str = NEMOTRON_PATTERN,
+               held: int = 0, held_first: int = 0,
+               vocab_rows: int = 131072,
+               source: str = "examples/lm/nemotron_3_nano_30b_a3b_tokens"
+                             ".txt",
+               hidden: int = 2688, ssd_heads: int = 64,
+               ssd_head_dim: int = 64, state: int = 128, groups: int = 8,
+               conv_taps: int = 4, heads: int = 32, kv_heads: int = 2,
+               head_dim: int = 128, experts: int = 128, top_k: int = 6,
+               expert_width: int = 1856, shared_width: int = 3712,
+               route_scale: float = 2.5, bias_update_rate: float = 0.001,
+               eps: float = 1e-5, init_std: float = 0.02,
+               init_layers: int = 52,
+               name: str = "NVIDIA-Nemotron-3-Nano-30B-A3B") -> NetParameter:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (config.json of
+    nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16, ``nemotron_h``): every layer
+    is ONE sub-layer behind one norm, which one by its letter in ``pattern``
+    (``hybrid_override_pattern``: ``M`` Mamba-2, ``E`` mixture of experts,
+    ``*`` attention; the published 52 are 23 / 23 / 6), a final norm, an
+    untied head, no bias but the convolution's:
+
+        h_0 = E[ids];  h' = h + Sub_i(RMSNorm(h));  logits = W_head N_f(h_L)
+
+    ``M`` (``l<i>_ssd_*``), H ``ssd_heads`` heads of P ``ssd_head_dim`` (an
+    inner width of H P, NOT ``expand`` x hidden), a state of N ``state`` a
+    head, G ``groups`` groups of B / C, head h reading group h // (H / G):
+
+        [z | xBC | dt~] = W_in y               (H P | H P + 2 G N | H)
+        [x | B | C] = SiLU(conv4(xBC) + b_conv)    causal, depthwise
+        dt = softplus(dt~ + dt_bias);  a = -exp(A_log) dt      one a head
+        H_t = exp(a_t) H_{t-1} + dt_t x_t B_{t,g(h)}^T
+        y_t = H_t C_{t,g(h)} + D_h x_t
+        Sub = W_out N_G(y * SiLU(z))   gate THEN norm; N_G: RMS over each
+                                       group's H P / G channels, one gain
+                                       of H P
+
+    as layers: ``l<i>_ssd_in``, ``l<i>_ssd_in_split``, ``l<i>_ssd_conv``
+    (SHORT_CONV with ``bias_term``), ``l<i>_ssd_conv_split``,
+    ``l<i>_ssd_decay`` (KDA_DECAY, four tops: a, ``l<i>_ssd_decay_mean``,
+    dt, ``l<i>_ssd_dt_mean``), ``l<i>_ssd_scan`` (SSD_SCAN with
+    ``num_groups``), ``l<i>_ssd_gate``, ``l<i>_ssd_onorm`` (RMS_NORM with
+    ``num_groups``), ``l<i>_ssd_out``.
+
+    ``*`` (``l<i>_attn_{q,k,v,sdpa,o}``): ``heads`` query and ``kv_heads``
+    key-value heads of ``head_dim`` (q and o wider than the hidden state),
+    causal softmax(q k^T / sqrt(head_dim)) v, NO positions.
+
+    ``E`` (``l<i>_moe_*``): ``l<i>_moe_router`` (MOE_ROUTER: sigmoid scores
+    over all ``experts`` in f32, the ``top_k`` of score + selection bias
+    chosen, weighed by the unbiased scores over their sum times
+    ``route_scale``, the bias balanced by the layer at
+    ``bias_update_rate``), ``l<i>_moe_experts`` (MOE with ``activation``
+    "relu2": UNGATED experts W2 relu(W1 y)^2 of ``expert_width``, two
+    stacks, holding ``held`` of them from ``held_first`` on; 0 = all: with
+    fewer the net is one rank's share of an expert-parallel model; its
+    scalar tops are the held load, the dropped assignments, the held share
+    and the share of pre-activations the ReLU zeroes) and the shared expert
+    W2_s relu(W1_s y)^2 of ``shared_width`` as INNER_PRODUCT
+    (``l<i>_moe_shared_up``) -> RELU (``_shared_relu``) -> POWER 2
+    (``_shared_sq``) -> INNER_PRODUCT (``_shared_down``), added unweighted
+    (``l<i>_moe_sum``).
+
+    ``pattern`` (any string of the three letters: the first nine published,
+    ``MEMEM*EME``, are the benchmark's cut) and ``vocab_rows`` of the
+    131,072 rows of the table AND of the head give one rank's cut.
+    Out-projections (``ssd_out``, ``attn_o``, ``moe_shared_down``) are
+    initialised std / sqrt(2 ``init_layers``) (``rescale_prenorm_residual``
+    at the PUBLISHED depth, whatever the cut); the experts' two stacks share
+    the MOE layer's one filler. Gains, A_log, dt_bias, D, the convolution's
+    bias and the selection bias carry decay_mult 0, every matrix and the
+    convolution's taps 1."""
+    from ..proto.messages import (AttentionParameter, EltwiseParameter,
+                                  EmbedParameter, HDF5DataParameter,
+                                  KDAParameter, MoEParameter, PowerParameter,
+                                  RMSNormParameter, SliceParameter)
+    if set(pattern) - set("ME*") or not pattern:
+        raise ValueError(f"nemotron_h: pattern {pattern!r} is no string of "
+                         f"M (Mamba-2), E (experts) and * (attention)")
+    if pattern != NEMOTRON_PATTERN or vocab_rows != 131072 or held:
+        name = (f"{name} (layers {pattern}, {held or experts} of {experts} "
+                f"experts, {vocab_rows} rows)")
+    w = gaussian(init_std)
+    w_out = gaussian(init_std / math.sqrt(2 * init_layers))
+    no_decay = ParamSpec(lr_mult=1.0, decay_mult=0.0)
+    net: List[LayerParameter] = [LayerParameter(
+        name="tokens", type="HDF5_DATA", top=["tokens", "targets"],
+        hdf5_data_param=HDF5DataParameter(source=source, batch_size=batch))]
+
+    def norm(lname, bottom, top, in_groups=0):
+        net.append(LayerParameter(
+            name=lname, type="RMS_NORM", bottom=[bottom], top=[top],
+            param=[no_decay], rms_norm_param=RMSNormParameter(
+                eps=eps, num_groups=in_groups)))
+
+    def proj(lname, bottom, top, n_out, filler=w):
+        net.append(LayerParameter(
+            name=lname, type="INNER_PRODUCT", bottom=[bottom], top=[top],
+            inner_product_param=InnerProductParameter(
+                num_output=n_out, bias_term=False, axis=2,
+                weight_filler=filler)))
+
+    def split(lname, bottom, tops, points):
+        net.append(LayerParameter(
+            name=lname, type="SLICE", bottom=[bottom], top=list(tops),
+            slice_param=SliceParameter(slice_dim=2,
+                                       slice_point=list(points))))
+
+    def add(lname, a, b, top):
+        net.append(LayerParameter(
+            name=lname, type="ELTWISE", bottom=[a, b], top=[top],
+            eltwise_param=EltwiseParameter(operation="SUM")))
+
+    def mamba(p, y, top):
+        inner, bc = ssd_heads * ssd_head_dim, groups * state
+        kp = dict(num_heads=ssd_heads)
+        taps = FillerParameter(type="uniform", min=-conv_taps ** -0.5,
+                               max=conv_taps ** -0.5)
+        proj(p + "ssd_in", y, p + "zxd", 2 * inner + 2 * bc + ssd_heads)
+        split(p + "ssd_in_split", p + "zxd", [p + "z", p + "xbc", p + "dtr"],
+              [inner, 2 * inner + 2 * bc])
+        net.append(LayerParameter(
+            name=p + "ssd_conv", type="SHORT_CONV", bottom=[p + "xbc"],
+            top=[p + "xbcc"], param=[ParamSpec(), no_decay],
+            kda_param=KDAParameter(kernel_size=conv_taps, weight_filler=taps,
+                                   bias_term=True, bias_filler=taps)))
+        split(p + "ssd_conv_split", p + "xbcc", [p + "xs", p + "B", p + "C"],
+              [inner, inner + bc])
+        net.append(LayerParameter(
+            name=p + "ssd_decay", type="KDA_DECAY", bottom=[p + "dtr"],
+            top=[p + "a", p + "ssd_decay_mean", p + "dt",
+                 p + "ssd_dt_mean"], param=[no_decay, no_decay],
+            kda_param=KDAParameter(**kp)))
+        net.append(LayerParameter(
+            name=p + "ssd_scan", type="SSD_SCAN",
+            bottom=[p + "xs", p + "dt", p + "a", p + "B", p + "C"],
+            top=[p + "sy"], param=[no_decay],
+            kda_param=KDAParameter(num_groups=groups, **kp)))
+        net.append(LayerParameter(
+            name=p + "ssd_gate", type="SILU_GATE", bottom=[p + "z", p + "sy"],
+            top=[p + "sg"]))
+        norm(p + "ssd_onorm", p + "sg", p + "sn", groups)
+        proj(p + "ssd_out", p + "sn", top, hidden, w_out)
+
+    def attention(p, y, top):
+        for t, n in (("q", heads), ("k", kv_heads), ("v", kv_heads)):
+            proj(p + "attn_" + t, y, p + t, n * head_dim)
+        net.append(LayerParameter(
+            name=p + "attn_sdpa", type="ATTENTION",
+            bottom=[p + "q", p + "k", p + "v"], top=[p + "att"],
+            attention_param=AttentionParameter(
+                num_heads=heads, num_kv_heads=kv_heads, rope=False)))
+        proj(p + "attn_o", p + "att", top, hidden, w_out)
+
+    def sparse(p, y, top):
+        moe = dict(num_experts=experts, top_k=top_k,
+                   expert_width=expert_width, score_func="sigmoid",
+                   route_scale=route_scale,
+                   bias_update_rate=bias_update_rate, weight_filler=w)
+        net.append(LayerParameter(
+            name=p + "moe_router", type="MOE_ROUTER", bottom=[y],
+            top=[p + "gates", p + "bias_next", p + "bias_max_abs"],
+            param=[ParamSpec(), no_decay], moe_param=MoEParameter(**moe)))
+        net.append(LayerParameter(
+            name=p + "moe_experts", type="MOE", bottom=[y, p + "gates"],
+            top=[p + "m", p + "expert_load", p + "dropped",
+                 p + "held_share", p + "act_zero_share"],
+            moe_param=MoEParameter(num_held=held, held_first=held_first,
+                                   activation="relu2", **moe)))
+        proj(p + "moe_shared_up", y, p + "su", shared_width)
+        net.append(LayerParameter(
+            name=p + "moe_shared_relu", type="RELU", bottom=[p + "su"],
+            top=[p + "sr"]))
+        net.append(LayerParameter(
+            name=p + "moe_shared_sq", type="POWER", bottom=[p + "sr"],
+            top=[p + "sq"], power_param=PowerParameter(power=2.0)))
+        proj(p + "moe_shared_down", p + "sq", p + "sd", hidden, w_out)
+        add(p + "moe_sum", p + "m", p + "sd", top)
+
+    net.append(LayerParameter(
+        name="embed", type="EMBED", bottom=["tokens"], top=["h0"],
+        embed_param=EmbedParameter(
+            input_dim=vocab_rows, num_output=hidden, weight_filler=w)))
+    h = "h0"
+    sub = {"M": mamba, "E": sparse, "*": attention}
+    for i, letter in enumerate(pattern):
+        p = f"l{i}_"
+        norm(p + "norm", h, p + "n")
+        sub[letter](p, p + "n", p + "o")
+        add(p + "res", h, p + "o", p + "y")
+        h = p + "y"
+    norm("final_norm", h, "xf")
+    proj("lm_head", "xf", "logits", vocab_rows)
     net.append(LayerParameter(
         name="lm_nll", type="SOFTMAX_NLL", bottom=["logits", "targets"],
         top=["nll"]))

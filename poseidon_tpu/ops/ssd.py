@@ -1,14 +1,22 @@
 """Mamba-2's selective scan (state-space duality, arXiv:2405.21060) with B
-and C SHARED by every head: the recurrence, its chunked form and the
-backward.
+and C shared by the heads of a GROUP: the recurrence, its chunked form and
+the backward.
 
 Per head h a state H (P, N), zero at a sequence's start, and per token t a
 value x_t (P), a step dt_t > 0 and a log-decay a_t = dt_t A_h <= 0 (one
-number a head), and a write key B_t and a read key C_t (N) that ALL heads
-share (``n_groups`` 1):
+number a head), and a write key B_t and a read key C_t (N) a GROUP of
+heads: with G groups (``n_groups``) head h reads group g(h) = h // (H / G),
+so G = 1 (Granite-4.0-H) is one B and one C for ALL heads and G = 8
+(Nemotron-H) one for every eight of 64:
 
-    H_t = exp(a_t) H_{t-1} + dt_t x_t B_t^T
-    y_t = H_t C_t + D_h x_t
+    H_t = exp(a_t) H_{t-1} + dt_t x_t B_{t,g(h)}^T
+    y_t = H_t C_{t,g(h)} + D_h x_t
+
+b and c come as (B, S, N) (one group: what every caller had) or as
+(B, S, G, N). The groups share nothing, so the ``jax.numpy`` arms run a
+group's heads as the one-group form under ``jax.vmap`` (``_by_group``) and
+the one-group program is what it was; the kernels index a program's B / C
+block by its group (``ops/ssd_pallas.py``).
 
 No delta term (nothing reads the state back before the write: the gated
 delta rule of ``ops/kda.py`` solves a unit-lower system a chunk, this has
@@ -24,15 +32,15 @@ ratio is ever formed with exp(-L)) and H the state at the chunk's start:
           + exp(L_i) H C_i + D x_i
     H'  = exp(L_Q) H + sum_j exp(L_Q - L_j) dt_j x_j B_j^T
 
-``C_i . B_j`` is ONE (Q, Q) grid a chunk for all heads; a head brings its own
-mask of ratios and its own dt x.
+``C_i . B_j`` is ONE (Q, Q) grid a chunk for all heads of a group; a head
+brings its own mask of ratios and its own dt x.
 
 The backward (``custom_vjp``) is the chunk-local ``jax.vjp`` with the
 state's pullback carried in reverse: the forward keeps its operands and ONE
 f32 state a chunk a head (P x N), the backward walks the chunks from the
 last, rebuilds a chunk with the forward's own code (``_chunk_step``) and
 pulls (d y of the chunk, d state at its end) back to the chunk's operands
-and the state at its start. d B and d C are sums over the heads (the
+and the state at its start. d B and d C are sums over a GROUP's heads (the
 einsums' own), d dt collects from the write and, through a = dt A outside,
 from the decay's exponent, d D is a sum over tokens. The steps, the
 cumulative sums, every ratio and the carried state are f32, products at
@@ -47,8 +55,9 @@ switch):
   a program owns one chunk of up to eight heads, two heads of 64 a lane
   block, the states stay in VMEM across the sequence, the (Q, Q) grid is
   made once a program and the heads' masks never visit HBM): a backend that
-  compiles Mosaic, a chunk of 256 or 128 divides S, P divides 128 and N is
-  a multiple of 128;
+  compiles Mosaic, a chunk of 256 or 128 divides S, P divides 128, N is
+  a multiple of 128 and, with more than one group, a group's heads fill
+  whole programs (no program spans two groups);
 - ``chunked`` (the ``jax.numpy`` form below, which the kernels are read
   beside): every other shape with a chunk, and the CPU mesh; the note says
   which of these it was;
@@ -78,42 +87,48 @@ def ssd_chunk(s: int) -> Optional[int]:
     return next((q for q in (256, 128, 64, 32, 16, 8) if s % q == 0), None)
 
 
-def _pallas_chunk(s, heads, p, n) -> Optional[int]:
+def _pallas_chunk(s, heads, p, n, groups: int = 1) -> Optional[int]:
     """The chunk the Pallas arm runs, None where it does not: a shape the
     kernels refuse, or a backend that would interpret them."""
     from .pallas_kernels import _interpret_default
     from .ssd_pallas import ssd_blocks
-    q = ssd_blocks(s, heads, p, n)
+    q = ssd_blocks(s, heads, p, n, groups)
     return q if q and not _interpret_default() else None
 
 
-def scan_chunk(s: int, heads: int, p: int, n: int) -> Optional[int]:
+def scan_chunk(s: int, heads: int, p: int, n: int,
+               groups: int = 1) -> Optional[int]:
     """Tokens a chunk of the arm that runs a shape here (the kernels' where
     they take it, else ``ssd_chunk``'s; None: the recurrence)."""
-    return _pallas_chunk(s, heads, p, n) or ssd_chunk(s)
+    return _pallas_chunk(s, heads, p, n, groups) or ssd_chunk(s)
 
 
-def state_bytes(batch: int, s: int, heads: int, p: int, n: int) -> int:
+def state_bytes(batch: int, s: int, heads: int, p: int, n: int,
+                groups: int = 1) -> int:
     """What the backward keeps of the recurrence: one f32 state a chunk a
     head a sequence (the Pallas arm's zero heads where it pads)."""
-    q = scan_chunk(s, heads, p, n)
-    if _pallas_chunk(s, heads, p, n):
+    q = scan_chunk(s, heads, p, n, groups)
+    if _pallas_chunk(s, heads, p, n, groups):
         from .ssd_pallas import padded_heads
         heads = padded_heads(heads, p)
     return batch * heads * (s // q) * p * n * 4 if q else 0
 
 
-def ssd_route(s: int, heads: int, p: int, n: int, itemsize: int = 4):
+def ssd_route(s: int, heads: int, p: int, n: int, itemsize: int = 4,
+              groups: int = 1):
     """``(arm, note)`` for a sequence length and a scan's widths, as ``Net``
     logs it — THE routing decision, of the backend and the shape alone:
     ``pallas`` with the chunk and the heads a program, else ``chunked`` with
-    the reason the kernels did not take it, else ``recurrence``.
+    the reason the kernels did not take it (a program that would span two
+    of ``groups`` groups of B / C among them), else ``recurrence``. With
+    more than one group the note ends ``; groups=<G>``.
     ``itemsize``: the bytes of the compute type x, B and C arrive in, from
     which the Pallas arm's note says what share of six MXU passes a product
     its forward / backward kernels run (``ssd_pallas.mxu_passes``)."""
     from .pallas_kernels import _interpret_default
     from .ssd_pallas import heads_a_program, mxu_passes, ssd_refusal
-    q = _pallas_chunk(s, heads, p, n)
+    said = f"; groups={groups}" if groups > 1 else ""
+    q = _pallas_chunk(s, heads, p, n, groups)
     if q:
         hp = heads_a_program(heads, p)
         six = mxu_passes(q, p, n, hp, _F32)
@@ -124,16 +139,17 @@ def ssd_route(s: int, heads: int, p: int, n: int, itemsize: int = 4):
             f"heads a program, {max(1, 128 // p)} a lane block, one C B^T "
             f"grid a program, f32 states in VMEM, passes "
             f"{ran[0] / six[0]:.2f} / {ran[1] / six[1]:.2f} of six a "
-            f"product)")
+            f"product){said}")
     q = ssd_chunk(s)
     if q is None:
-        return "recurrence", f"token by token (no chunk divides S={s})"
-    why = ssd_refusal(s, heads, p, n) or (
+        return "recurrence", (f"token by token (no chunk divides S={s})"
+                              f"{said}")
+    why = ssd_refusal(s, heads, p, n, groups) or (
         "this backend would interpret the kernels"
         if _interpret_default() else "")
     return "chunked", (f"chunked Q {q}, {s // q} chunks, f32 state, one "
                        f"C B^T grid a chunk"
-                       + (f"; not pallas: {why}" if why else ""))
+                       + (f"; not pallas: {why}" if why else "") + said)
 
 
 def _mm(a, b, spec):
@@ -141,9 +157,30 @@ def _mm(a, b, spec):
                       preferred_element_type=_F32)
 
 
+def _by_group(scan, x, dt, a, b, c, d):
+    """``scan`` (a one-group form: b, c (B, S, N)) over the G groups of b,
+    c (B, S, G, N): head h = g H / G + j is head j of group g, the groups
+    share nothing, so each runs as the one-group form (``jax.vmap`` over the
+    group axis; d B and d C then sum over a group's heads alone)."""
+    g = b.shape[2]
+    if x.shape[2] % g:
+        raise ValueError(f"{x.shape[2]} heads do not split into {g} groups "
+                         f"of B / C")
+
+    def split(t, axis):
+        return t.reshape(t.shape[:axis] + (g, -1) + t.shape[axis + 1:])
+
+    y = jax.vmap(scan, in_axes=(2, 2, 2, 2, 2, 0), out_axes=2)(
+        split(x, 2), split(dt, 2), split(a, 2), b, c, split(d, 0))
+    return y.reshape(x.shape)
+
+
 def ssd_recurrence(x, dt, a, b, c, d):
-    """x (B, S, H, P), dt and a (B, S, H), b and c (B, S, N), d (H,) ->
-    y (B, S, H, P) f32: the recurrence as written, a ``lax.scan`` over t."""
+    """x (B, S, H, P), dt and a (B, S, H), b and c (B, S, N) or
+    (B, S, G, N), d (H,) -> y (B, S, H, P) f32: the recurrence as written,
+    a ``lax.scan`` over t."""
+    if b.ndim == 4:
+        return _by_group(ssd_recurrence, x, dt, a, b, c, d)
     x, dt, a, b, c, d = (t.astype(_F32) for t in (x, dt, a, b, c, d))
 
     def step(state, xs):
@@ -162,9 +199,10 @@ def ssd_recurrence(x, dt, a, b, c, d):
 
 
 def _chunk_step(state, x, dt, a, b, c):
-    """One chunk of every head. state (B, H, P, N) f32 at its start; x
-    (B, Q, H, P), dt and a (B, Q, H), b and c (B, Q, N) in their own types
-    -> (y (B, Q, H, P) f32 without the skip, the state at its end)."""
+    """One chunk of every head of ONE group. state (B, H, P, N) f32 at its
+    start; x (B, Q, H, P), dt and a (B, Q, H), b and c (B, Q, N) in their
+    own types -> (y (B, Q, H, P) f32 without the skip, the state at its
+    end)."""
     x, dt, a, b, c = (t.astype(_F32) for t in (x, dt, a, b, c))
     q = x.shape[1]
     lt = jnp.cumsum(a, 1).swapaxes(1, 2)                   # (B, H, Q)
@@ -247,14 +285,16 @@ _ssd_chunked.defvjp(_ssd_fwd, _ssd_bwd)
 
 
 def ssd_scan(x, dt, a, b, c, d, chunk: Optional[int] = None):
-    """x (B, S, H, P), dt and a = dt A (B, S, H) f32, b and c (B, S, N), d
-    (H,) -> y (B, S, H, P) in x's type. ``chunk``: tokens a chunk of the
+    """x (B, S, H, P), dt and a = dt A (B, S, H) f32, b and c (B, S, N) (one
+    group for all heads) or (B, S, G, N) (head h reads group h // (H / G)),
+    d (H,) -> y (B, S, H, P) in x's type. ``chunk``: tokens a chunk of the
     ``jax.numpy`` form (None: the route's arm, ``ssd_route``). Where no
     chunk divides S the token-by-token recurrence runs."""
     s, h, p = x.shape[1:]
     n = b.shape[-1]
+    groups = b.shape[2] if b.ndim == 4 else 1
     if chunk is None:
-        q = _pallas_chunk(s, h, p, n)
+        q = _pallas_chunk(s, h, p, n, groups)
         if q:
             from .ssd_pallas import ssd_scan_pallas
             return ssd_scan_pallas(x, dt, a, b, c, d, q, False)
@@ -263,4 +303,7 @@ def ssd_scan(x, dt, a, b, c, d, chunk: Optional[int] = None):
         return ssd_recurrence(x, dt, a, b, c, d).astype(x.dtype)
     if s % chunk:
         raise ValueError(f"chunk {chunk} does not divide S={s}")
+    if b.ndim == 4:
+        return _by_group(
+            lambda *one: _ssd_chunked(*one, int(chunk)), x, dt, a, b, c, d)
     return _ssd_chunked(x, dt, a, b, c, d, int(chunk))

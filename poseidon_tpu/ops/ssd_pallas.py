@@ -1,7 +1,7 @@
 """Mamba-2's chunked selective scan (``ops/ssd.py`` has the mathematics) as
 two Pallas (Mosaic) kernels, ``ssd_scan_fwd`` and ``ssd_scan_bwd``.
 
-The grid is (sequence, chunk, group of heads), the groups innermost: a
+The grid is (sequence, chunk, program of heads), the programs innermost: a
 program owns ONE chunk of Q tokens (256, or 128 where 256 does not divide S)
 of up to eight heads, and the f32 states of ALL heads — their gradients in
 the backward — stay in one VMEM scratch (H P, N) from a sequence's first
@@ -9,9 +9,18 @@ chunk to its last (the backward walks the chunks from the last: the index
 maps). What no head owns is made once a program and serves the heads it
 holds: the (Q, Q) grid C B^T, and in the backward its gradient, summed over
 the program's heads before the two products that turn it into d C and d B.
-d B and d C are (S, N) blocks that the groups of one chunk ACCUMULATE in
-VMEM (the block's index does not move while the group axis runs), so no
+d B and d C are (S, N) blocks that the programs of one chunk ACCUMULATE in
+VMEM (the block's index does not move while the program axis runs), so no
 (H, S, N) array ever exists.
+
+B and C may come in G groups, (B, S, G N) along the lanes, head h reading
+group h // (H / G) (``n_groups``; Nemotron-H has 8 for 64 heads, Granite
+1). A program's heads all lie in ONE group (``ssd_refusal`` names the
+shapes where they would not), so the same kernels serve: the B / C block's
+index map picks the program's group, and the d B / d C blocks accumulate
+over the H / (G hp) programs that share a group and start afresh at the
+first program of the next. With G = 1 the index maps and the program are
+what they were.
 
 Operands are read in place: x is (B, S, H P) as the convolution leaves it,
 and a program's heads are ``W`` = heads x P of its lanes. Inside, the work
@@ -77,24 +86,31 @@ def padded_heads(heads: int, p: int) -> int:
     return -(-heads // hp) * hp
 
 
-def ssd_refusal(s: int, heads: int, p: int, n: int) -> str:
-    """Why the kernels do not take a shape, '' where they do."""
+def ssd_refusal(s: int, heads: int, p: int, n: int, groups: int = 1) -> str:
+    """Why the kernels do not take a shape, '' where they do. ``groups``:
+    the groups of B / C the heads share (a program's heads read ONE)."""
     if s % 128:
         return f"neither 256 nor 128 divides S={s}"
     if p not in (16, 32, 64, 128):
         return f"heads of {p} are no whole part of a lane block of 128"
     if n % _LANES:
         return f"a state of {n} a head is no multiple of 128 lanes"
+    if groups > 1 and (heads % groups
+                       or (heads // groups) % heads_a_program(heads, p)):
+        return (f"{heads} heads in {groups} groups of B / C are no whole "
+                f"programs of {heads_a_program(heads, p)} heads a group: a "
+                f"program would span two groups")
     if padded_heads(heads, p) * p * n * 4 \
             > _compiler_params().vmem_limit_bytes // 4:
         return "the heads' states do not fit the kernels' VMEM budget"
     return ""
 
 
-def ssd_blocks(s: int, heads: int, p: int, n: int) -> Optional[int]:
+def ssd_blocks(s: int, heads: int, p: int, n: int,
+               groups: int = 1) -> Optional[int]:
     """Tokens a chunk (Q) of the kernels, None where they refuse the shape
     (``ssd_refusal`` says why)."""
-    if ssd_refusal(s, heads, p, n):
+    if ssd_refusal(s, heads, p, n, groups):
         return None
     return 256 if s % 256 == 0 else 128
 
@@ -252,10 +268,12 @@ def _fwd_kernel(x_ref, dtr_ref, lr_ref, b_ref, c_ref, d_ref, y_ref, *rest,
 
 def _bwd_kernel(x_ref, dtr_ref, lr_ref, b_ref, c_ref, d_ref, saved, dy_ref,
                 dx_ref, db_ref, dc_ref, ddt_ref, dl_ref, dd_ref, d_state, *,
-                p: int):
+                p: int, share: int = 0):
     """The same grid with the chunks from the last (the index maps);
     ``d_state`` (H P, N) carries the states' gradients. d dt and d L leave
-    as rows, d D as a chunk's partial sums over tokens."""
+    as rows, d D as a chunk's partial sums over tokens. ``share``: how many
+    programs in a row read one group of B / C and sum into one d B / d C
+    block (0: all of them, one group)."""
     q, width = x_ref.shape[1:]
     rows = _state_rows(width)
 
@@ -323,12 +341,19 @@ def _bwd_kernel(x_ref, dtr_ref, lr_ref, b_ref, c_ref, d_ref, saved, dy_ref,
     d_c = d_c + _mm(d_grid, bs, 1, 0)
     d_b = d_b + _mm(d_grid, cs, 0, 0)
 
-    @pl.when(pl.program_id(2) == 0)
+    if share == 1:              # a group a program: nothing to sum over
+        db_ref[0] = d_b
+        dc_ref[0] = d_c
+        return
+    # this program's place among those that share its d B / d C block
+    at = lambda: pl.program_id(2) % share if share else pl.program_id(2)
+
+    @pl.when(at() == 0)
     def _():
         db_ref[0] = d_b
         dc_ref[0] = d_c
 
-    @pl.when(pl.program_id(2) != 0)
+    @pl.when(at() != 0)
     def _():
         db_ref[0] += d_b
         dc_ref[0] += d_c
@@ -366,14 +391,17 @@ def _operands(x, dt, a, d, q):
             jnp.repeat(pad(d.astype(_F32), 0), p)[None], hp, wide)
 
 
-def _specs(q, width, n, hp, n_chunks, reverse: bool):
+def _specs(q, width, n, hp, n_chunks, reverse: bool, share: int = 0):
+    """``share``: the programs in a row that read one group of B / C (0:
+    one group for all, block 0 of the lanes)."""
     at = (lambda c: n_chunks - 1 - c) if reverse else (lambda c: c)
+    group = (lambda g: g // share) if share else (lambda g: 0)
     vmem = pltpu.VMEM
     lanes = pl.BlockSpec((1, q, width), lambda b, c, g: (b, at(c), g),
                          memory_space=vmem)
     rows = pl.BlockSpec((1, 1, hp, q), lambda b, c, g: (b, g, 0, at(c)),
                         memory_space=vmem)
-    shared = pl.BlockSpec((1, q, n), lambda b, c, g: (b, at(c), 0),
+    shared = pl.BlockSpec((1, q, n), lambda b, c, g: (b, at(c), group(g)),
                           memory_space=vmem)
     skip = pl.BlockSpec((1, width), lambda b, c, g: (0, g),
                         memory_space=vmem)
@@ -382,15 +410,25 @@ def _specs(q, width, n, hp, n_chunks, reverse: bool):
     return lanes, rows, shared, skip, saved, at
 
 
+def _groups(b_, c_, h: int, hp: int):
+    """b, c (B, S, N) or (B, S, G, N) -> (both laid (B, S, G N) along the
+    lanes, N, the programs in a row that share a group: 0 with one group)."""
+    n = b_.shape[-1]
+    if b_.ndim == 3:
+        return b_, c_, n, 0
+    flat = lambda t: t.reshape(t.shape[:2] + (-1,))
+    return flat(b_), flat(c_), n, h // (b_.shape[2] * hp)
+
+
 def _forward(x, dt, a, b_, c_, d, q, interpret, with_states: bool):
     """-> y (B, S, H, P) in x's type and, ``with_states``, the f32 state at
     every chunk's start (B, S / Q, H' P, N)."""
     b, s, h, p = x.shape
-    n = b_.shape[-1]
     xs, dtr, lr, skip_d, hp, wide = _operands(x, dt, a, d, q)
+    b_, c_, n, share = _groups(b_, c_, h, hp)
     width, n_chunks = hp * p, s // q
     lanes, rows, shared, skip, saved, _ = _specs(q, width, n, hp, n_chunks,
-                                                 False)
+                                                 False, share)
     out_shape = [jax.ShapeDtypeStruct(xs.shape, x.dtype)]
     out_specs = [lanes]
     if with_states:
@@ -411,19 +449,21 @@ def _forward(x, dt, a, b_, c_, d, q, interpret, with_states: bool):
 
 def _backward(x, dt, a, b_, c_, d, states, d_y, q, interpret):
     b, s, h, p = x.shape
-    n = b_.shape[-1]
     xs, dtr, lr, skip_d, hp, wide = _operands(x, dt, a, d, q)
+    b_shape, c_shape = b_.shape, c_.shape
+    b_, c_, n, share = _groups(b_, c_, h, hp)
     d_ys = jnp.pad(d_y, [(0, 0), (0, 0), (0, wide - h), (0, 0)]).reshape(
         xs.shape)
     width, n_chunks = hp * p, s // q
     lanes, rows, shared, skip, saved, at = _specs(q, width, n, hp, n_chunks,
-                                                  True)
+                                                  True, share)
     partial = pl.BlockSpec((1, 1, 1, width),
                            lambda b, c, g: (b, at(c), 0, g),
                            memory_space=pltpu.VMEM)
     f32 = lambda shape: jax.ShapeDtypeStruct(shape, _F32)
     d_x, d_b, d_c, d_dt, d_l, d_d = pl.pallas_call(
-        functools.partial(_bwd_kernel, p=p), name="ssd_scan_bwd",
+        functools.partial(_bwd_kernel, p=p, share=share),
+        name="ssd_scan_bwd",
         grid=(b, n_chunks, wide // hp),
         in_specs=[lanes, rows, rows, shared, shared, skip, saved, lanes],
         out_specs=[lanes, shared, shared, rows, rows, partial],
@@ -439,14 +479,15 @@ def _backward(x, dt, a, b_, c_, d, states, d_y, q, interpret):
     d_d = jnp.sum(d_d, (0, 1, 2)).reshape(wide, p).sum(1)[:h]
     return (d_x.reshape(b, s, wide, p)[:, :, :h],
             _columns(d_dt, h).astype(dt.dtype), d_a.astype(a.dtype),
-            d_b.astype(b_.dtype), d_c.astype(c_.dtype), d_d.astype(d.dtype))
+            d_b.astype(b_.dtype).reshape(b_shape),
+            d_c.astype(c_.dtype).reshape(c_shape), d_d.astype(d.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def ssd_scan_pallas(x, dt, a, b, c, d, q: int, interpret: bool):
     """``ops/ssd.ssd_scan``'s Pallas arm: x (B, S, H, P), dt and a
-    (B, S, H), b and c (B, S, N), d (H,) -> y (B, S, H, P) in x's type;
-    ``q`` tokens a chunk (``ssd_blocks``)."""
+    (B, S, H), b and c (B, S, N) or (B, S, G, N), d (H,) -> y (B, S, H, P)
+    in x's type; ``q`` tokens a chunk (``ssd_blocks``)."""
     return _forward(x, dt, a, b, c, d, q, interpret, False)
 
 

@@ -293,6 +293,11 @@ class RMSNormParameter:
     # over its own dims, ONE gain of a head's width shared by all of them
     # (a per-head QK-norm); 0 = over the whole last axis
     num_heads: int = 0
+    # > 0: the last axis is that many groups of channels side by side, each
+    # normalised over its own channels, under ONE gain of the whole width
+    # (Mamba-2's gated norm at ``n_groups`` > 1); 0 = what every net had.
+    # Not with ``num_heads``
+    num_groups: int = 0
 
 
 @dataclass
@@ -361,8 +366,11 @@ class MoEParameter:
     ``top_k`` largest logits chosen, their weights the softmax over the
     chosen; the balance and z losses are its tops): it may score another
     blob than the experts compute on, and the MOE layer that takes its
-    gates as a second bottom has no router. ``activation``: the gate's
-    activation in an expert's gated unit, "silu" or "relu"."""
+    gates as a second bottom has no router. ``activation``: what an expert
+    is. "silu" or "relu": the gated unit down(act(gate x) * (up x)), three
+    stacks a layer. "relu2": NO gate, down(relu(up x)^2), TWO stacks a
+    layer (Nemotron-H's squared-ReLU expert); this one value is the
+    ungated expert's only switch."""
     num_experts: int = 0
     top_k: int = 1
     expert_width: int = 0
@@ -402,10 +410,14 @@ class KDAParameter:
     (N, S, H)) the inverse softplus of a log-uniform draw in [``dt_min``,
     ``dt_max``]. KDA_SCAN: ``num_heads`` states, their two widths read off
     q's and v's bottoms. SSD_SCAN (Mamba-2): ``num_heads`` states of
-    (x's width / num_heads) x (B's width). ``bias_term`` (SHORT_CONV): a
+    (x's width / num_heads) x (B's width / ``num_groups``); B and C hold
+    ``num_groups`` groups side by side and head h reads group
+    h // (num_heads / num_groups) (1, the default: every head shares one B
+    and one C, the layer every net had). ``bias_term`` (SHORT_CONV): a
     bias a channel before the SiLU (``bias_filler``; Mamba-2's
     ``mamba_conv_bias``); false, the default, is the layer every net had."""
     num_heads: int = 1
+    num_groups: int = 1
     kernel_size: int = 4
     bias_term: bool = False
     bias_filler: FillerParameter = field(default_factory=FillerParameter)

@@ -1095,6 +1095,71 @@ def test_xing_full_width_step_fits_one_v5e_at_its_batch_and_no_larger(more):
         assert 0.80 * 16.9 < got["total_gb"] < 0.85 * 16.9
 
 
+# The full-width Nemotron-3-Nano-30B-A3B train step
+# (examples/lm/nemotron_3_nano_30b_a3b_*: published layers 0-8, MEMEM*EME; 8
+# of 128 ungated experts held, an eighth of the table's and the untied head's
+# rows) as `train --bf16 --remat <the solver header's flags>` builds it at
+# sequences of 8,192, for one abstract v5e chip: the compiler's memory
+# accounting that fixed the cell's batch
+# (benchmark/cells/nemotron_h.e8of128.pack8k.json), at one sequence and at
+# the two chosen.
+_NEMOTRON_STEP = _OURO_STEP.replace(
+    "batch, seq, deeper = 1, 8192, {deeper}",
+    "batch, seq, deeper = 1 + {deeper}, 8192, 0").replace(
+    "ouro_2_6b_solver", "nemotron_3_nano_30b_a3b_solver").replace(
+    'depth = sum(l.type == "ATTENTION" for l in net_param.layers) // 4',
+    'depth = sum(l.type in ("ATTENTION", "SSD_SCAN", "MOE") '
+    'for l in net_param.layers)')
+assert _NEMOTRON_STEP.count("nemotron_3_nano_30b_a3b") == 1 \
+    and "ouro_2" not in _NEMOTRON_STEP
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("more", [0, 1])
+def test_nemotron_full_width_step_fits_one_v5e_at_one_and_two_sequences(more):
+    """At TWO sequences of 8,192 (the cell's batch) the step with one
+    checkpoint a layer and one around the head is under 85% of the 16.9 GB
+    the compiler allows (PR 22's sizing rule); one sequence is recorded
+    beside it. The four Mamba-2 layers' recurrences are the scan's Pallas
+    kernels with EIGHT groups of B / C, a group a program (Mosaic compiles
+    the grouped index maps for the v5e here); the attention layer's flash
+    kernels run at 32 / 2 heads of 128 token-major with no positions; each
+    MOE layer's held rows of UNGATED experts run in chunks of 8,192 under
+    one loop a pass."""
+    import json
+    r = subprocess.run(
+        [sys.executable, "-c", _NEMOTRON_STEP.format(repo=REPO, deeper=more)],
+        capture_output=True, text=True, timeout=1500, cwd=REPO)
+    if r.returncode == 3 or "lockfile" in (r.stdout + r.stderr):
+        pytest.skip(f"libtpu AOT unavailable: "
+                    f"{(r.stdout + r.stderr).strip()[-200:]}")
+    assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
+    got = json.loads(next(l for l in r.stdout.splitlines()
+                          if l.startswith("RESULT "))[7:])
+    print(got)            # the accounting, for whoever sizes the next cut
+    # nine layers; the trained parameters and the four selection biases
+    assert got["depth"] == 9 and got["parameters"] == 666_962_944 + 512
+    # embed, head, final norm; a norm a layer; a Mamba-2 mixer 8 (in, conv
+    # w + b, A_log + dt_bias, D, out-norm, out), the attention one 4, a
+    # sparse one 6 (router w + bias, TWO stacks, shared up + down)
+    assert got["leaves"] == 3 + 9 + 4 * 8 + 4 + 4 * 6
+    assert got["segments"] == 9 + 1
+    rows = 8192 * 6 * (1 + more)
+    assert got["routes"] == [
+        "attention=pallas_flash (fwd 1024x1024 36/64, bwd 1024x1024 36/64; "
+        "block_q x block_k, live/visited programs a "
+        "head; operands token-major (B,S,HxD)); 2 kv heads repeated x16; no "
+        "positions",
+        f"grouped_matmul=ragged_dot; held rows: chunks of 8192 of {rows}; "
+        f"act=relu2; ungated",
+        "ssd_scan=pallas (Q 256, 32 chunks, 8 heads a program, 2 a lane "
+        "block, one C B^T grid a program, f32 states in VMEM, passes 0.47 / "
+        "0.47 of six a product); groups=8"]
+    # weights + two moments, 12 bytes a parameter
+    assert abs(got["argument_gb"] - 12e-9 * got["parameters"]) < 0.01
+    assert got["total_gb"] < 0.85 * 16.9
+
+
 # The LRN kernels at the CNN cells' norm layers (AlexNet's two at batch 512,
 # GoogLeNet's two at 128: batch-minor) and at GoogLeNet's published batch 32
 # (channel-minor), forward and backward, through Mosaic; then a stand-in for

@@ -407,7 +407,7 @@ def test_gate_zero_share_is_the_direct_count(arm, short_chunks):
 def test_silu_stays_the_default_and_an_unknown_activation_is_refused():
     from poseidon_tpu.proto.messages import MoEParameter
     assert MoEParameter().activation == "silu" and moe.EXPERT_ACTS == (
-        "silu", "relu")
+        "silu", "relu", "relu2")        # PR 64: the ungated squared ReLU
     text = zoo.to_prototxt(zoo.smallthinker(batch=1, n_layers=1, **{
         k: v for k, v in SIZES.items() if k != "n_layers"}))
     assert text.count('activation: "relu"') == 1

@@ -7,7 +7,9 @@ Kimi's text as it was, to the byte; since PR 56 GLM-4.7-Flash, which added
 60 Xing4.0, which added YaRN's frequencies to ``rope_tables`` /
 ``_rope_lanes`` and a stream option to ``zoo.glm_flash``, left all seven as
 they were and took SmallThinker's and Granite's on its parent, so that all
-nine token configurations the benchmark had are held) trace
+nine token configurations the benchmark had are held; since PR 64
+Nemotron-3-Nano, which ADAPTED ops/ssd*.py, models/moe.expert_ffn and the
+MOE / SSD_SCAN / RMS_NORM layers and left all ten as they were) trace
 to the
 program they traced to before Trinity's window, sigmoid router and per-head
 norm, and before Kimi-Linear's two head widths in the flash kernels,
@@ -95,6 +97,14 @@ BUILD = {
         batch=N, n_layers=2, dense_layers=1, held=2, vocab=128, mtp=0,
         hidden=128, heads=2, q_rank=32, kv_rank=32, dense_width=64,
         experts=16, top_k=2, expert_width=32, shared_width=32),
+    # one layer of each letter: a Mamba-2 layer with two groups of B / C (a
+    # group a program of eight heads), a sparse layer of ungated experts, 2
+    # of 16 held, and an attention layer of 2 / 1 heads of 128
+    "nemotron": lambda: zoo.nemotron_h(
+        batch=N, pattern="ME*", held=2, vocab_rows=128, hidden=128,
+        ssd_heads=16, ssd_head_dim=64, state=128, groups=2, heads=2,
+        kv_heads=1, head_dim=128, experts=16, top_k=2, expert_width=32,
+        shared_width=32),
 }
 PARENT = {       # sha256 of the text, its length, its pallas_call equations
     # PR 61's own, all ten: it meant to change them (the docstring's rule).
@@ -133,6 +143,11 @@ PARENT = {       # sha256 of the text, its length, its pallas_call equations
                 "b815a735f73", 194050, 4),
     "xing": ("b8f6a68b149c539aa29cd8abac18c9e8238b00bb3152b9b0c653cb1d"
              "e7012d7f", 386577, 4),
+    # PR 64's own (a new configuration; the ten above are its parent's, to
+    # the byte: the groups of B / C, the ungated expert and the grouped
+    # norm are arguments whose defaults trace to what was there)
+    "nemotron": ("926313e00323c7d16deb1ab32b3f505579109572098ef4424362c31d"
+                 "32b058bf", 205773, 4),
 }
 
 
@@ -158,7 +173,7 @@ def traced(name: str) -> str:
 # z and gated output projection two).
 NAMES = {"olmoe": 2, "ouro": 4 + 3 * 2, "zaya": 4, "trinity": 4 + 3 * 2,
          "kimi": 8 + 3 * 4, "olmo_hybrid": 8 + 18, "glm": 6 + 3 * 3,
-         "smallthinker": 4, "granite": 8, "xing": 4 + 3 * 2}
+         "smallthinker": 4, "granite": 8, "xing": 4 + 3 * 2, "nemotron": 4}
 
 
 @pytest.mark.parametrize("name", sorted(BUILD))
